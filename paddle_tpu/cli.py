@@ -1312,6 +1312,8 @@ def main(argv=None):
     p_ver.set_defaults(fn=cmd_version)
 
     args = parser.parse_args(argv)
+    from . import chip
+    chip.enable_compile_cache()
     return args.fn(args)
 
 

@@ -81,6 +81,12 @@ class CUDAPlace(TPUPlace):
     TPU backend unchanged (BASELINE.json north star)."""
 
 
+def _cpu_forced() -> bool:
+    """jax was told to use the CPU and nothing else (JAX_PLATFORMS=cpu:
+    the test harness, the CPU rehearsals of chip_smoke.py)."""
+    return jax.config.jax_platforms == "cpu"
+
+
 def place_device(place: Place):
     """Resolve a Place to a concrete jax.Device."""
     if isinstance(place, CPUPlace):
@@ -93,7 +99,17 @@ def place_device(place: Place):
                 cpus = jax.local_devices()
         return cpus[min(place.device_id, len(cpus) - 1)]
     devs = jax.local_devices()
-    accel = [d for d in devs if d.platform != "cpu"] or devs
+    accel = [d for d in devs if d.platform != "cpu"]
+    if not accel:
+        # an accelerator place runs on the CPU only where the platform was
+        # forced to it (JAX_PLATFORMS=cpu: the test harness, the CPU
+        # rehearsals) — never because the accelerator failed to show up
+        if not _cpu_forced():
+            raise RuntimeError(
+                f"{place!r} asks for an accelerator and jax found none "
+                f"(devices: {devs}); set JAX_PLATFORMS=cpu to rehearse "
+                f"on the CPU, or use CPUPlace")
+        accel = devs
     return accel[min(place.device_id, len(accel) - 1)]
 
 
@@ -419,7 +435,7 @@ def pack_to_padded(flat: np.ndarray, lod: List[List[int]]):
         if bsz and len(flat):
             # vectorized scatter: row r of flat lands at
             # [batch(r), r - start(batch(r))] — no per-sample Python loop in
-            # the feed path (VERDICT r2 weak #7)
+            # the feed path
             batch_idx = np.repeat(np.arange(bsz), lengths)
             time_idx = np.arange(offs[-1]) - np.repeat(offs[:-1], lengths)
             padded[batch_idx, time_idx] = flat[: offs[-1]]
@@ -485,15 +501,6 @@ def padded_to_pack(padded: np.ndarray, lengths: np.ndarray,
             [outer_offs.tolist(), inner_offs.tolist()])
 
 
-def _aval_of(x):
-    shape = getattr(x, "shape", None)
-    dtype = getattr(x, "dtype", None)
-    if shape is None or dtype is None:
-        arr = np.asarray(x)
-        shape, dtype = arr.shape, arr.dtype
-    return jax.ShapeDtypeStruct(tuple(shape), dtype)
-
-
 def _hlo_supplier(fn, feed_vals, state_vals, rng_counter):
     """Zero-arg lazy supplier of the block's AOT-compiled executable for
     the profiler's per-op device table (.as_text() gives the optimized HLO
@@ -504,7 +511,7 @@ def _hlo_supplier(fn, feed_vals, state_vals, rng_counter):
     recompile unless the persistent compilation cache covers it, which is
     why the profiler caps its supplier registry and only traced sessions
     pay this — at stop_profiler, never inside the timed region."""
-    avals = jax.tree_util.tree_map(_aval_of,
+    avals = jax.tree_util.tree_map(memory_mod.aval_of,
                                    (feed_vals, state_vals, rng_counter))
 
     def supply():
@@ -527,11 +534,13 @@ def _cost_supplier(executor, program, feed_vals, state_vals, window=False):
     (roofline.program_cost) for the same compiled block _hlo_supplier
     describes. Same discipline: captures only avals. window=True strips
     the leading [K] steps axis off each feed so the table is per-step."""
-    feed_avals = {n: _aval_of(v) for n, v in feed_vals.items()}
+    feed_avals = {n: memory_mod.aval_of(v)
+                  for n, v in feed_vals.items()}
     if window:
         feed_avals = {n: jax.ShapeDtypeStruct(a.shape[1:], a.dtype)
                       for n, a in feed_avals.items()}
-    state_avals = {n: _aval_of(v) for n, v in state_vals.items()}
+    state_avals = {n: memory_mod.aval_of(v)
+                   for n, v in state_vals.items()}
 
     def cost():
         from . import roofline
@@ -843,6 +852,7 @@ class Executor:
             if isinstance(v, LoDTensor):
                 v = np.asarray(v.array())   # lod-carrying state fell back
             state_vals[n] = v
+        state_vals = self._commit_state(program, state_vals, feed_vals)
         rng_counter = scope.find_var("__rng_counter__") or 0
 
         state_keys = sorted(state_vals)
@@ -1016,6 +1026,61 @@ class Executor:
         sizes that could never fit in host or device memory. Returns the
         memory.ProgramMemory record (also kept in memory.records())."""
         program = program if program is not None else default_main_program()
+        compiled, feed_vals, state_vals, rng = self._aot_block(
+            program, feed, fetch_list, scope)
+        return memory_mod.analyze(
+            compiled.fn, feed_vals, state_vals, rng,
+            program=telemetry.program_label(program),
+            place=f"{type(self.place).__name__}:{self.place.device_id}",
+            top_k=top_k)
+
+    def compiled_hlo(self, program=None, feed=None, fetch_list=None,
+                     scope=None) -> str:
+        """Optimized HLO text of the step as run() compiles it for this
+        feed — what the device executes, custom calls included. Compile
+        only, like static_memory_analysis; a recompile unless the
+        persistent compilation cache covers it."""
+        program = program if program is not None else default_main_program()
+        compiled, feed_vals, state_vals, rng = self._aot_block(
+            program, feed, fetch_list, scope)
+        return _hlo_supplier(compiled.fn, feed_vals, state_vals,
+                             np.uint32(rng))().as_text()
+
+    def _commit_state(self, program, state_vals, feed_vals):
+        """Place state where the step's outputs will live, before the
+        compiled call: on this executor's device when a feed is committed
+        to one, or per the step's in_shardings on a mesh. The state a step
+        returns is committed as soon as one input was (a device_put feed
+        is) and, on a mesh, carries the mesh in its type; state that
+        starts out as numpy or as the startup program's uncommitted
+        single-device outputs does neither, so the second call would see
+        another argument mapping (off-mesh) or other avals (on a mesh)
+        than the first, and the whole step would compile twice. jit would
+        make the same transfer on the first call anyway. With nothing
+        committed (numpy feeds) the step stays unplaced, call after call,
+        and follows jax.default_device."""
+        mesh = getattr(program, "_mesh", None)
+        if mesh is None:
+            if not any(getattr(v, "committed", False)
+                       for v in feed_vals.values()):
+                return state_vals
+            loose = {n: v for n, v in state_vals.items()
+                     if not getattr(v, "committed", False)}
+            where = self.device
+        elif mesh.is_multi_process:
+            return state_vals    # every process holds only its shards
+        else:
+            loose = {n: v for n, v in state_vals.items()
+                     if getattr(getattr(v, "sharding", None), "mesh",
+                                None) != mesh}
+            where = loose and self._shardings(program, sorted(loose), [])[1]
+        if not loose:
+            return state_vals
+        return {**state_vals, **jax.device_put(loose, where)}
+
+    def _aot_block(self, program, feed, fetch_list, scope):
+        """(compiled block, feed_vals, state_vals, rng_counter) gathered
+        as run() gathers them, for the compile-only entry points."""
         scope = scope if scope is not None else global_scope()
         fetch_names = [v.name if isinstance(v, Variable) else str(v)
                        for v in list(fetch_list or [])]
@@ -1056,12 +1121,8 @@ class Executor:
         compiled = self._compile(
             program, sorted(state_vals), sorted(feed_vals), fetch_names,
             self._persistable_outputs(program), lod_map)
-        return memory_mod.analyze(
-            compiled.fn, feed_vals, state_vals,
-            scope.find_var("__rng_counter__") or 0,
-            program=telemetry.program_label(program),
-            place=f"{type(self.place).__name__}:{self.place.device_id}",
-            top_k=top_k)
+        return (compiled, feed_vals, state_vals,
+                scope.find_var("__rng_counter__") or 0)
 
     def _run_impl(self, program, feed, fetch_list, feed_var_name,
                   fetch_var_name, scope, return_numpy, use_program_cache,
@@ -1180,6 +1241,8 @@ class Executor:
 
         state_keys = sorted(state_vals)  # incl. @SEQLEN side channels
         if jit_mode:
+            state_vals = self._commit_state(program, state_vals,
+                                            feed_vals)
             key = (id(program), getattr(program, "_version", 0),
                    tuple(sorted(feed_vals)), tuple(fetch_names),
                    tuple(state_keys), self.place,

@@ -117,10 +117,6 @@ define("vlog", 0,
        "(reference glog VLOG levels)")
 define("record_ops", "",
        "file path: append every executed op type (tools/op_coverage.py)")
-define("test_platform", "cpu",
-       "jax platform the test suite forces (tests/conftest.py)")
-define("xla_cache", "",
-       "persistent XLA compilation cache dir override (tests/conftest.py)")
 define("max_loop_iters", 128,
        "default while-loop step-scope recording capacity "
        "(While(max_iters=...) overrides per loop)")

@@ -322,6 +322,23 @@ def latest_record(prog_label: str) -> Optional[ProgramMemory]:
         return _RECORDS.get(prog_label)
 
 
+def aval_of(x):
+    """Shape, dtype and — for an array committed to its device, or an
+    aval that names one — the sharding of `x`, and nothing that keeps its
+    buffer alive. The sharding makes an AOT lower() from avals the same
+    computation, under the same persistent-cache key, as the jit call on
+    the arrays was (an uncommitted array lowers unplaced there too)."""
+    import jax
+    shape = getattr(x, "shape", None)
+    dtype = getattr(x, "dtype", None)
+    if shape is None or dtype is None:
+        arr = np.asarray(x)
+        shape, dtype = arr.shape, arr.dtype
+    uncommitted = getattr(x, "committed", None) is False
+    sharding = None if uncommitted else getattr(x, "sharding", None)
+    return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=sharding)
+
+
 def analyze(fn, feed_vals, state_vals, rng_counter=0, *, program="?",
             place="?", signature=None, top_k: int = 8) -> ProgramMemory:
     """AOT-lower the jitted block fn from avals (shapes/dtypes only — the
@@ -331,16 +348,8 @@ def analyze(fn, feed_vals, state_vals, rng_counter=0, *, program="?",
     compilation cache covers it."""
     import jax
 
-    def _aval(x):
-        shape = getattr(x, "shape", None)
-        dtype = getattr(x, "dtype", None)
-        if shape is None or dtype is None:
-            arr = np.asarray(x)
-            shape, dtype = arr.shape, arr.dtype
-        return jax.ShapeDtypeStruct(shape, dtype)
-
     avals = jax.tree_util.tree_map(
-        _aval, (feed_vals, state_vals, np.uint32(rng_counter)))
+        aval_of, (feed_vals, state_vals, np.uint32(rng_counter)))
     with warnings.catch_warnings():
         # backends without donation support (CPU) warn per compile; the
         # executor's jit call already surfaced it once — the audit below
@@ -814,17 +823,24 @@ class HeadroomModel:
 
 def default_budget(device=None) -> int:
     """HBM budget for headroom estimates: the device's bytes_limit when
-    memory_stats reports one, else the hbm_budget_bytes flag, else 16 GiB
-    (a v5e-class chip)."""
-    if device is not None:
-        try:
-            stats = device.memory_stats()
-            if stats and stats.get("bytes_limit"):
-                return int(stats["bytes_limit"])
-        except Exception:
-            pass
+    memory_stats reports one, else the hbm_budget_bytes flag, else the
+    HBM size on record for the device kind (chip.PEAKS; an unknown
+    accelerator raises). The CPU reports nothing and has no row: it
+    keeps a 16 GiB stand-in so headroom arithmetic can be rehearsed."""
+    import jax
+    from . import chip
+    device = device if device is not None else jax.devices()[0]
+    try:
+        stats = device.memory_stats()
+        if stats and stats.get("bytes_limit"):
+            return int(stats["bytes_limit"])
+    except Exception:
+        pass
     v = int(flags.get("hbm_budget_bytes") or 0)
-    return v if v > 0 else 16 * GiB
+    if v > 0:
+        return v
+    row = chip.peaks(device)
+    return row.hbm_bytes if row else 16 * GiB
 
 
 def what_if(measure: Callable[[int], ProgramMemory],

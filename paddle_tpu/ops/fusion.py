@@ -548,6 +548,11 @@ def _kernel_ineligible(ctx, g: Group, env) -> Optional[str]:
     two-pass centered variance via the compose fallback."""
     if g.bn.attr("is_test", False):
         return "kernel_is_test"
+    mesh = getattr(ctx.program, "_mesh", None)
+    if mesh is not None and mesh.size > 1:
+        # same rule as pallas_conv.ineligible: a bare Mosaic call cannot
+        # be partitioned, and batch statistics span the whole batch
+        return "kernel_mesh"
     xname = _first(g.bn.desc.input("X"))
     x = env.get(xname)
     if getattr(x, "ndim", 0) != 4 or \
@@ -647,7 +652,8 @@ def _conv_stats_pallas(ctx, g: Group, env) -> bool:
         return False
     nhwc_in = ctx.layouts.get(xname) == layout_mod.NHWC
     x_nhwc = xc if nhwc_in else jnp.transpose(xc, (0, 2, 3, 1))
-    if pallas_conv.ineligible(x_nhwc, wc, s, p, d, groups) is not None:
+    if pallas_conv.ineligible(x_nhwc, wc, s, p, d, groups,
+                              getattr(ctx.program, "_mesh", None)) is not None:
         return False
     n = x_nhwc.shape[0]
     co, _, kh, kw = wc.shape
@@ -759,6 +765,7 @@ def _pallas_bn_act(x2, scale, bias, eps, act_fn):
                                m_total=float(m_total))
     outs = pl.pallas_call(
         kernel,
+        name="bn_act",
         grid=grid,
         in_specs=[x_spec, vec_spec, vec_spec],
         out_specs=out_specs,

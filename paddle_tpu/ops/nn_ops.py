@@ -11,6 +11,8 @@ framework/data_layout_transform.cc becomes a compiler concern).
 
 from __future__ import annotations
 
+import collections
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -298,7 +300,8 @@ def _conv2d(ctx, op_, ins):
     if not nhwc_in:
         x = jnp.transpose(x, (0, 2, 3, 1))
     qmode = getattr(ctx, "quant_mode", None)
-    reason = pallas_conv.ineligible(x, w, s, p, d, groups)
+    reason = pallas_conv.ineligible(x, w, s, p, d, groups,
+                                    getattr(ctx.program, "_mesh", None))
     if reason is None:
         pallas_conv.count_hit(op_.type)
         qreason = quant.ineligible_conv(x, w, s, p, d, groups, qmode) \
@@ -379,7 +382,8 @@ def _conv2d_grad(ctx, op_, ins):
     x_nhwc_in = ctx.layout_of(op_.desc.inputs["Input"][0]) == layout_mod.NHWC
     (xc, wc), _ = mxu_cast(ctx, x, w)
     x_nhwc = xc if x_nhwc_in else jnp.transpose(xc, (0, 2, 3, 1))
-    reason = pallas_conv.ineligible(x_nhwc, wc, s, p, d, groups)
+    reason = pallas_conv.ineligible(x_nhwc, wc, s, p, d, groups,
+                                    getattr(ctx.program, "_mesh", None))
     if reason is not None:
         pallas_conv.count_fallback(op_.type, reason)
         # The forward lowering already counted itself when the forward
@@ -813,12 +817,11 @@ def _sdpa_grad(fwd, no_grad_set):
 
 def _flash_auto_threshold():
     """Sequence length at which auto-selection flips from the XLA einsum
-    path to the Pallas flash kernel. Below it the einsum wins end-to-end
-    (the custom call is a fusion barrier); at/above it flash WINS with
-    the r5-tuned 512/1024 tiles — measured on v5e in the transformer
-    bench: 1.13x at T=2048, 1.32x at 4096, 1.65x at 8192 over the einsum
-    path (bench.py BENCH_MODE=transformer). Env-tunable for other
-    chips."""
+    path to the Pallas flash kernel: below it the einsum path fuses
+    better (the custom call is a fusion barrier), at/above it flash is
+    meant to win on top of its O(T) memory. The crossing was placed by a
+    sweep that predates PR 1; it is not measured on today's code
+    (bench.py BENCH_MODE=transformer is the comparison)."""
     import os
     return int(os.environ.get("PADDLE_TPU_FLASH_AUTO_T", "2048"))
 
@@ -835,11 +838,57 @@ def _ring_uses_flash(op_, q, mesh):
     return flash_ring_eligible(q, mesh, "sp")
 
 
-def _sdpa_paths(ctx, op_, q, k, v):
-    """(mode, mesh): 'ring' under sequence_parallel with an sp mesh,
-    'flash' when use_flash (True, or 'auto' at long T) and the shape
-    tiles, else 'einsum'. Auto-selection (VERDICT r4 #2): the default
-    config gets whichever path is faster for its shape, no user flag."""
+_FlashPlacement = collections.namedtuple("_FlashPlacement",
+                                         "mesh qkv lse")
+
+
+def _flash_partition(program, q):
+    """How the flash kernels sit in a GSPMD-partitioned step: XLA cannot
+    partition a Mosaic custom call (it gathers every operand and runs the
+    whole call on each device), so under a mesh of more than one device
+    they run inside shard_map — batch over the data and fsdp axes, heads
+    over the tensor axis of the program's planner layout. Attention is
+    independent per (example, head), so the shards need no collective.
+    An axis whose size does not divide its dim stays replicated. Returns
+    (placement, shard): placement None off-mesh, else the mesh with the
+    specs of a [B, T, H, D] operand and of a [B, H, T] row statistic
+    (LSE); shard(x) is the aval of x's per-device block, which is what
+    the kernels' gate must pass."""
+    mesh = getattr(program, "_mesh", None)
+    if mesh is None or mesh.size == 1 or q.ndim != 4:
+        return None, lambda x: x
+    from jax.sharding import PartitionSpec
+    from ..parallel.planner import SpecLayout
+    plan = getattr(program, "_sharding_plan", None)
+    layout = plan.layout if plan is not None else SpecLayout()
+
+    def fit(axes, dim):
+        took, n = [], 1
+        for a in axes:
+            if a in mesh.axis_names and dim % (n * mesh.shape[a]) == 0:
+                took.append(a)
+                n *= mesh.shape[a]
+        return (tuple(took) or None), n
+
+    batch, nb = fit((layout.data_axis, layout.fsdp_axis), q.shape[0])
+    heads, nh = fit((layout.tensor_axis,), q.shape[2])
+
+    def shard(x):
+        b, t, h, d = x.shape
+        return jax.ShapeDtypeStruct((b // nb, t, h // nh, d), x.dtype)
+
+    return _FlashPlacement(mesh, PartitionSpec(batch, None, heads, None),
+                           PartitionSpec(batch, heads, None)), shard
+
+
+def _sdpa_paths(ctx, op_, q, k, v, count=False):
+    """(mode, how): 'ring' under sequence_parallel with an sp mesh (how =
+    the mesh), 'flash' when use_flash (True, or 'auto' at long T) and the
+    gate passes the per-device shape (how = _flash_partition's placement),
+    else 'einsum'. Auto-selection: the default config gets whichever path
+    is faster for its shape, no user flag. `count` books a declined flash
+    request under pallas_fallback_total (the forward op passes it; the
+    grad op recomputes the same static decision in silence)."""
     from . import pallas_attention
     mesh = getattr(ctx.program, "_mesh", None)
     if op_.attr("sequence_parallel", False) and mesh is not None and \
@@ -848,8 +897,13 @@ def _sdpa_paths(ctx, op_, q, k, v):
     uf = op_.attr("use_flash", "auto")
     if uf == "auto":
         uf = q.shape[1] >= _flash_auto_threshold()
-    if uf and pallas_attention.supports(q, k, v):
-        return "flash", None
+    if uf:
+        partition, shard = _flash_partition(ctx.program, q)
+        reason = pallas_attention.ineligible(shard(q), shard(k), shard(v))
+        if reason is None:
+            return "flash", partition
+        if count:
+            pallas_attention.count_fallback(reason)
     return "einsum", None
 
 
@@ -877,7 +931,7 @@ def _scaled_dot_product_attention(ctx, op_, ins):
     from ..parallel.ring_attention import (attention_reference,
                                            attention_reference_lse,
                                            ring_attention_sharded)
-    mode, mesh = _sdpa_paths(ctx, op_, q, k, v)
+    mode, mesh = _sdpa_paths(ctx, op_, q, k, v, count=True)
     if mode == "ring":
         out, lse = ring_attention_sharded(
             q, k, v, mesh, axis="sp", causal=causal,
@@ -886,8 +940,16 @@ def _scaled_dot_product_attention(ctx, op_, ins):
         # Pallas flash attention (ops/pallas_attention.py): O(T) memory
         # online-softmax VMEM kernel
         from . import pallas_attention
-        out, lse = pallas_attention._forward(q, k, v, causal,
+
+        def fwd(q, k, v):
+            return pallas_attention._forward(q, k, v, causal,
                                              return_lse=True)
+
+        if mesh is not None:        # a _FlashPlacement
+            fwd = jax.shard_map(
+                fwd, mesh=mesh.mesh, in_specs=(mesh.qkv,) * 3,
+                out_specs=(mesh.qkv, mesh.lse), check_vma=False)
+        out, lse = fwd(q, k, v)
     else:
         out = attention_reference(q, k, v, causal=causal)
         lse = attention_reference_lse(q, k, causal=causal)
@@ -917,10 +979,18 @@ def _sdpa_grad_kernel(ctx, op_, ins):
         o = jnp.asarray(ins["Out"][0]).astype(q.dtype)
         lse = jnp.asarray(ins["LSE"][0])
         scale = 1.0 / (q.shape[-1] ** 0.5)
-        delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                        axis=-1).transpose(0, 2, 1)
-        dq, dk, dv = pallas_attention.flash_attention_bwd_block(
-            q, k, v, do, lse, delta, 0, 0, scale, causal)
+
+        def bwd(q, k, v, do, o, lse):
+            delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                            axis=-1).transpose(0, 2, 1)
+            return pallas_attention.flash_attention_bwd_block(
+                q, k, v, do, lse, delta, 0, 0, scale, causal)
+
+        if mesh is not None:        # a _FlashPlacement
+            bwd = jax.shard_map(
+                bwd, mesh=mesh.mesh, in_specs=(mesh.qkv,) * 5 + (mesh.lse,),
+                out_specs=(mesh.qkv,) * 3, check_vma=False)
+        dq, dk, dv = bwd(q, k, v, do, o, lse)
     elif mode == "ring":
         if _ring_uses_flash(op_, q, mesh):
             # direct blockwise ring backward from the saved (Out, LSE):

@@ -10,9 +10,11 @@ grid walks one output row per step with an H *input* block of size 1 —
 at block size 1 the BlockSpec index map addresses *rows*, so
 strided/dilated input-row selection (`oh*stride + kh*dilation`) happens
 in the index map and no halo exchange or revisit is needed. Inside the
-kernel the kw taps unroll as a Python loop of strided row slices feeding
-[W-ish, Ci] x [Ci, Co] MXU dots into an f32 accumulator that carries
-across the sequential (innermost) reduction dim of the grid:
+kernel the kw taps unroll as a Python loop of unit-stride row slices (a
+strided conv's row is de-interleaved into its width phases first,
+`_deinterleave`) feeding [W-ish, Ci] x [Ci, Co] MXU dots into an f32
+accumulator that carries across the sequential (innermost) reduction dim
+of the grid:
 
   forward      grid (N, OH/BH, Co/128, KH*Ci/128 * BH), acc [BH, OW, 128]
   grad-filter  grid (KH, Ci/128, Co/128, N*OH), acc [KW, 128, 128]
@@ -21,7 +23,7 @@ across the sequential (innermost) reduction dim of the grid:
                  (lo = (K-1)*d - p, hi = H - Hd + p), so one kernel body
                  serves both directions.
 
-BH is the multi-row pipelining factor (BENCH_r06's headroom spend): the
+BH is the multi-row pipelining factor: the
 filter tile is by far the heaviest HBM stream of the row-walk (for a
 3x3 C=128 ResNet block each output row re-reads KH*KW*Ci*Co filter
 bytes against one input row), so the reduction dim is extended by BH
@@ -82,7 +84,7 @@ _LANE = 128
 # a reason string produced but not listed here would ship an unlabelled
 # fallback counter).
 FALLBACK_REASONS = frozenset(
-    {"disabled", "rank", "groups", "dtype", "channels", "attrs",
+    {"disabled", "mesh", "rank", "groups", "dtype", "channels", "attrs",
      "geometry"})
 
 # VMEM width budget: each grid step keeps a [Wp, 128] bf16 input row, an
@@ -95,7 +97,7 @@ FALLBACK_REASONS = frozenset(
 _MAX_W = 2048
 
 
-def ineligible(x, w, strides, paddings, dilations, groups=1):
+def ineligible(x, w, strides, paddings, dilations, groups=1, mesh=None):
     """None when the Pallas kernels apply, else the fallback reason.
 
     `x` is the NHWC operand *post mxu_cast* (AMP O1/O2 convs are bf16 by
@@ -103,10 +105,16 @@ def ineligible(x, w, strides, paddings, dilations, groups=1):
     predicate is shared verbatim by forward and grad routing — see the
     module docstring for why they must agree — so it also encodes the
     grad-input geometry: transposed-conv padding stays non-negative iff
-    p <= (K-1)*d per spatial dim.
+    p <= (K-1)*d per spatial dim. `mesh` is the program's SPMD mesh: XLA
+    cannot partition a Mosaic custom call (it would gather every operand
+    and run the whole conv on each device), and these kernels are not
+    wrapped in shard_map, so a step partitioned over more than one
+    device keeps lax.conv, which GSPMD does partition.
     """
     if not PALLAS_CONV:
         return "disabled"
+    if mesh is not None and mesh.size > 1:
+        return "mesh"
     if getattr(x, "ndim", 0) != 4 or getattr(w, "ndim", 0) != 4:
         return "rank"
     if (groups or 1) != 1:
@@ -137,9 +145,11 @@ def ineligible(x, w, strides, paddings, dilations, groups=1):
     return None
 
 
-def supports(x, w, strides, paddings, dilations, groups=1) -> bool:
+def supports(x, w, strides, paddings, dilations, groups=1,
+             mesh=None) -> bool:
     """Static eligibility, pallas_attention.supports-style."""
-    return ineligible(x, w, strides, paddings, dilations, groups) is None
+    return ineligible(x, w, strides, paddings, dilations, groups,
+                      mesh) is None
 
 
 _SUPPRESS_COUNTERS = False
@@ -167,8 +177,8 @@ def count_fallback(op: str, reason: str):
     from .. import telemetry
     telemetry.counter(
         "pallas_fallback_total",
-        "conv lowerings that fell back from the Pallas kernel suite to "
-        "the lax.conv path, by op and gating reason",
+        "lowerings that fell back from a Pallas kernel to the XLA path "
+        "(lax.conv, einsum attention), by op and gating reason",
         labels=("op", "reason")).labels(op=op, reason=reason).inc()
 
 
@@ -184,15 +194,40 @@ def count_hit(op: str):
 
 # --- kernel bodies ------------------------------------------------------
 
+def _phases(kw_n, dw, sw):
+    """The width phases (column index mod stride) the kw taps read."""
+    return sorted({(kw * dw) % sw for kw in range(kw_n)})
+
+
+def _deinterleave(xp, kw_n, dw, sw):
+    """[N, Hp, Wp, C] -> [N, Hp, P*Wq, C] for a strided conv: the columns
+    of each phase the taps read, contiguous and phase after phase (Wq =
+    ceil(Wp/sw)). A stride-sw tap is then a unit-stride slice of its
+    phase — Mosaic refuses a strided value slice outright and a strided
+    ref load of a packed (non-32-bit) dtype, so the stride is taken out
+    of the row by XLA before the kernel sees it. Identity at stride 1;
+    a 1x1 stride-2 conv carries only the half of the row it reads."""
+    if sw == 1:
+        return xp
+    n, hp, wp, c = xp.shape
+    wq = -(-wp // sw)
+    xq = jnp.pad(xp, ((0, 0), (0, 0), (0, wq * sw - wp), (0, 0)))
+    xq = xq.reshape(n, hp, wq, sw, c)
+    return jnp.concatenate(
+        [xq[:, :, :, ph, :] for ph in _phases(kw_n, dw, sw)], axis=2)
+
+
 def _taps(x_row, kw_n, dw, sw, ow):
-    """The kw tap slices of one padded input row: [OW, 128] each, strided
-    by the conv stride. Slice bounds always fit the padded width — the
-    widest tap ends at (KW-1)*dw + (OW-1)*sw + 1 = Wp by the output-dim
-    equation."""
+    """The kw tap slices of one padded, de-interleaved input row: [OW,
+    128] each. Tap kw reads padded columns kw*dw + i*sw, i.e. OW
+    consecutive columns of phase (kw*dw) % sw from (kw*dw) // sw on;
+    they fit the phase — the widest tap ends at padded column
+    (KW-1)*dw + (OW-1)*sw <= Wp - 1 by the output-dim equation."""
+    phases = _phases(kw_n, dw, sw)
+    wq = x_row.shape[0] // len(phases)
     for kw in range(kw_n):
-        yield lax.slice(x_row, (kw * dw, 0),
-                        (kw * dw + (ow - 1) * sw + 1, x_row.shape[1]),
-                        (sw, 1))
+        off = phases.index((kw * dw) % sw) * wq + (kw * dw) // sw
+        yield lax.slice(x_row, (off, 0), (off + ow, x_row.shape[1]))
 
 
 def _dot_i32(a, b, dims):
@@ -342,9 +377,10 @@ def _conv_call(x, w_hwio, strides, dilations, pads, out_dtype=None,
     sh, sw = strides
     dh, dw = dilations
     xp = jnp.pad(x, ((0, 0), tuple(pads[0]), tuple(pads[1]), (0, 0)))
-    hp, wp = xp.shape[1], xp.shape[2]
-    oh = (hp - ((kh - 1) * dh + 1)) // sh + 1
-    ow = (wp - ((kw_n - 1) * dw + 1)) // sw + 1
+    oh = (xp.shape[1] - ((kh - 1) * dh + 1)) // sh + 1
+    ow = (xp.shape[2] - ((kw_n - 1) * dw + 1)) // sw + 1
+    xp = _deinterleave(xp, kw_n, dw, sw)
+    wp = xp.shape[2]
     n_ci = ci // _LANE
     n_s = kh * n_ci
     out_dtype = out_dtype or x.dtype
@@ -374,7 +410,8 @@ def _conv_call(x, w_hwio, strides, dilations, pads, out_dtype=None,
         kernel = functools.partial(_fwd_kernel, kw_n=kw_n, dw=dw, sw=sw,
                                    ow=ow, n_s=n_s, bh=bh)
         return pl.pallas_call(
-            kernel, grid=grid, in_specs=in_specs, out_specs=o_spec,
+            kernel, name="conv2d" if dq is None else "conv2d_q8",
+            grid=grid, in_specs=in_specs, out_specs=o_spec,
             out_shape=jax.ShapeDtypeStruct((n, oh, ow, co), out_dtype),
             scratch_shapes=[pltpu.VMEM((bh, ow, _LANE), acc_dtype)],
             interpret=_interpret(),
@@ -396,7 +433,7 @@ def _conv_call(x, w_hwio, strides, dilations, pads, out_dtype=None,
     kernel = functools.partial(_fwd_stats_kernel, kw_n=kw_n, dw=dw, sw=sw,
                                ow=ow, n_s=n_s, n_n=n, n_oh=oh)
     return pl.pallas_call(
-        kernel, grid=grid, in_specs=[x_spec, w_spec],
+        kernel, name="conv2d_stats", grid=grid, in_specs=[x_spec, w_spec],
         out_specs=[o_spec, vec_spec, vec_spec],
         out_shape=[jax.ShapeDtypeStruct((n, oh, ow, co), out_dtype),
                    jax.ShapeDtypeStruct((1, co), jnp.float32),
@@ -479,7 +516,8 @@ def conv2d_grad_filter(x, dout, kernel_hw, strides, paddings, dilations,
     sh, sw = strides
     ph, pw = paddings
     dh, dw = dilations
-    xp = jnp.pad(x, ((0, 0), (ph, ph), (pw, pw), (0, 0)))
+    xp = _deinterleave(jnp.pad(x, ((0, 0), (ph, ph), (pw, pw), (0, 0))),
+                       kw_n, dw, sw)
     wp = xp.shape[2]
     m_n = n * oh
     grid = (kh, ci // _LANE, co // _LANE, m_n)
@@ -493,7 +531,8 @@ def conv2d_grad_filter(x, dout, kernel_hw, strides, paddings, dilations,
     kernel = functools.partial(_wgrad_kernel, kw_n=kw_n, dw=dw, sw=sw,
                                ow=ow, m_n=m_n)
     g_hwio = pl.pallas_call(
-        kernel, grid=grid, in_specs=[x_spec, do_spec], out_specs=o_spec,
+        kernel, name="conv2d_grad_filter", grid=grid,
+        in_specs=[x_spec, do_spec], out_specs=o_spec,
         out_shape=jax.ShapeDtypeStruct((kh, kw_n, ci, co),
                                        out_dtype or x.dtype),
         scratch_shapes=[_scratch((kw_n, _LANE, _LANE))],
@@ -522,7 +561,7 @@ def bn_apply(x2, scale, bias, mean, var, eps, act_fn):
         out_shape.append(jax.ShapeDtypeStruct((m_total, c), x2.dtype))
     kernel = functools.partial(_bn_apply_kernel, eps=eps, act=act_fn)
     outs = pl.pallas_call(
-        kernel, grid=grid,
+        kernel, name="bn_apply", grid=grid,
         in_specs=[x_spec, vec_spec, vec_spec, vec_spec, vec_spec],
         out_specs=out_specs, out_shape=out_shape,
         interpret=_interpret(),

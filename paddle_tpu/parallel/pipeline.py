@@ -50,10 +50,7 @@ def gpipe(stage_fn, stacked_params, x_microbatches, mesh, axis: str = "pp"):
     only its stage's weights). x_microbatches: [M, B, ...] microbatches
     (replicated in; every device sees the stream but only stage 0 consumes
     it). Returns [M, B, ...] final-stage outputs (replicated out)."""
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     p_size = mesh.shape[axis]

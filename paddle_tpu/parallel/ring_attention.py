@@ -183,8 +183,8 @@ def _ring_bwd_local(q, k, v, do, o, lse, axis_name, causal, scale):
     block gradients between the resident Q and the visiting K/V shard on
     the Pallas backward kernels; dQ accumulates locally while the dK/dV
     accumulators rotate WITH their K/V shard, arriving home complete after
-    P hops. Memory stays O(T/P) — no einsum recompute, no [Tq, Tk] scores
-    (closes VERDICT r3 missing #1 / weak #1)."""
+    P hops. Memory stays O(T/P) — no einsum recompute, no [Tq, Tk]
+    scores."""
     from ..ops.pallas_attention import flash_attention_bwd_block
     from ._collectives import mark_varying
 
@@ -223,30 +223,12 @@ def _ring_bwd_local(q, k, v, do, o, lse, axis_name, causal, scale):
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
-def _shard_map_fn():
-    try:
-        from jax import shard_map
-    except ImportError:              # older jax
-        from jax.experimental.shard_map import shard_map
-    return shard_map
-
-
 def _sm(mesh, flash, **smkw):
     # check_vma off on the flash path: the pallas HLO interpreter's
     # dynamic_slice hits a varying-manifest false positive when inputs
     # alias (jax suggests exactly this workaround in its error).
-    # Probe the signature — functools.partial would defer an unknown-
-    # kwarg TypeError to the call site, past any try/except here.
-    shard_map = _shard_map_fn()
-    kw = {}
-    if flash:
-        import inspect
-        try:
-            if "check_vma" in inspect.signature(shard_map).parameters:
-                kw["check_vma"] = False
-        except (TypeError, ValueError):
-            pass
-    return functools.partial(shard_map, mesh=mesh, **kw, **smkw)
+    kw = {"check_vma": False} if flash else {}
+    return functools.partial(jax.shard_map, mesh=mesh, **kw, **smkw)
 
 
 def flash_ring_eligible(q, mesh, axis: str = "sp") -> bool:

@@ -214,8 +214,8 @@ def finish_trace_report(steps: Optional[int] = None, probe: bool = True):
 
 
 def _print_device_table(trace_dir, sorted_key=None):
-    """Per-IR-op device-time attribution for the whole-block jit (VERDICT
-    r4 #8; reference ParseEvents, platform/profiler.h:137-166): xplane
+    """Per-IR-op device-time attribution for the whole-block jit
+    (reference ParseEvents, platform/profiler.h:137-166): xplane
     per-instruction timings joined with each compiled module's
     metadata op_name (which carries the executor's pd.<op_type> named
     scope), enriched by roofline.py with analytic FLOPs/bytes, achieved

@@ -527,14 +527,13 @@ def ensure_probes(probe: bool = True) -> Dict[str, Optional[float]]:
 
 
 def nominal_tflops() -> Optional[float]:
-    """Datasheet peak for mfu_nominal: BENCH_PEAK_TFLOPS (shared with
-    bench.py, default 197 = v5e bf16) on TPU; None on CPU (no meaningful
-    nominal — mfu_vs_sustained is the honest number there)."""
-    env = os.environ.get("PADDLE_TPU_PEAK_TFLOPS") \
-        or os.environ.get("BENCH_PEAK_TFLOPS")
-    if env:
-        return float(env)
-    return 197.0 if _platform() == "tpu" else None
+    """Datasheet bf16 peak for mfu_nominal, from the one table keyed by
+    device_kind (chip.PEAKS; an accelerator that is not in it raises);
+    None on CPU (no meaningful nominal — mfu_vs_sustained is the honest
+    number there)."""
+    from . import chip
+    row = chip.peaks()
+    return row.bf16_tflops if row else None
 
 
 # --- waterfall / timeline ---------------------------------------------------

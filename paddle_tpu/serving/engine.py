@@ -287,7 +287,7 @@ class ServingEngine:
         Caller holds self._lock (the `_locked` suffix is the repo's
         convention for that contract; the thread lint enforces it)."""
         import jax
-        from ..executor import _aval_of
+        from ..memory import aval_of
 
         ex = self._executables.get(bucket)
         if ex is not None:
@@ -308,7 +308,7 @@ class ServingEngine:
         feed_avals = {
             n: jax.ShapeDtypeStruct((bucket,) + shape[1:], dtype)
             for n, (shape, dtype) in self._feed_meta.items()}
-        state_avals = {n: _aval_of(v) for n, v in self._state.items()}
+        state_avals = {n: aval_of(v) for n, v in self._state.items()}
         t0 = time.perf_counter()
         ex = self._compiled.fn.lower(
             feed_avals, state_avals, np.uint32(0)).compile()

@@ -2,7 +2,7 @@
 the spawned child imports ONLY this file (stdlib + master.py loaded by
 path), never the paddle_tpu package __init__ (which imports jax). Spawn
 instead of fork because forking a jax-initialized parent is the documented
-deadlock hazard (VERDICT r3 weak #6)."""
+deadlock hazard."""
 
 import os
 

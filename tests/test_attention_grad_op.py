@@ -94,7 +94,7 @@ def test_grads_match_einsum_autodiff(use_flash):
 
 
 class TestAutoSelection:
-    """use_flash defaults to 'auto' (VERDICT r4 #2): einsum below the
+    """use_flash defaults to 'auto': einsum below the
     threshold T (fuses into neighboring HLO), flash at/above it; explicit
     True/False always wins."""
 

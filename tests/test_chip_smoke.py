@@ -1,0 +1,439 @@
+"""CPU-side guards of the bring-up on the chip: none of these describes a
+topology (tests/test_tpu_compile.py does), so they count wherever the
+suite runs. chip_smoke.py's phases run here at tiny sizes with the Pallas
+kernels interpreted — the guide's first rehearsal — and the pieces that
+keep a CPU from passing for a chip are pinned: the device check, the
+accelerator place, the peak table, the one compile cache, bench.py's
+single attempt."""
+
+import json
+import os
+import subprocess
+import sys
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import bench  # noqa: E402
+import chip_smoke  # noqa: E402
+from paddle_tpu import chip, executor as executor_mod, memory, roofline  # noqa: E402
+from paddle_tpu.ops import pallas_attention, pallas_conv  # noqa: E402
+
+
+# --- chip_smoke.py ----------------------------------------------------------
+
+def test_smoke_refuses_the_cpu():
+    """No accelerator: non-zero exit and no result line."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, os.path.join(REPO, "chip_smoke.py")],
+                       capture_output=True, text=True, env=env, timeout=300)
+    assert r.returncode != 0
+    assert '"ok"' not in r.stdout
+    assert "TPU" in r.stderr
+
+
+@pytest.fixture(scope="module")
+def trained():
+    """train_phase at a tiny size (ResNet-18, 32x32): its record and the
+    trained program serve_phase saves."""
+    return chip_smoke.train_phase(batch=2, side=32, classes=10, depth=18,
+                                  steps=1, window=2, compiled=False)
+
+
+def test_train_phase_tiny(trained):
+    record, _ = trained
+    assert record["phase"] == "train" and len(record["losses"]) == 3
+    assert all(np.isfinite(record["losses"]))
+    # stride-2 convs at C % 128 == 0 take the kernels, interpreted here
+    assert record["pallas_kernel_total"]["op=conv2d_grad"] > 0
+    assert "reason=geometry" not in json.dumps(record)
+    json.dumps(record)                     # one JSON line
+
+
+def test_serve_phase_tiny(trained):
+    _, state = trained
+    record = chip_smoke.serve_phase(state, side=32, max_batch=4,
+                                    request_rows=(1, 3))
+    assert record["buckets"] == [1, 4]
+    assert record["max_abs_diff_vs_executor"] <= record["atol"]
+
+
+def test_serve_phase_wants_two_buckets(trained):
+    _, state = trained
+    with pytest.raises(AssertionError, match="one bucket"):
+        chip_smoke.serve_phase(state, side=32, max_batch=4,
+                               request_rows=(3, 4))
+
+
+def test_lm_phase_tiny():
+    record = chip_smoke.lm_phase(batch=2, seqlen=128, d_model=64, n_head=2,
+                                 n_layer=1, vocab=128, steps=2,
+                                 compiled=False)
+    assert record["first_loss_rel_diff"] < record["rtol"]
+    assert record["flash_declined"] == {}
+
+
+def test_multichip_phase_on_virtual_devices():
+    """The guide's second rehearsal: the 4-chip phase on four of the
+    harness's virtual CPU devices — planner mesh, flash under shard_map,
+    loss parity with one device, a parameter spread over all four."""
+    record = chip_smoke.multichip_phase(
+        jax.devices()[:4], batch=4, seqlen=128, d_model=64, n_head=4,
+        n_layer=1, vocab=128, steps=2, compiled=False)
+    assert record["sharded_param"]["devices"] == 4
+    assert sum(record["collectives"].values()) > 0
+    # the TPU scheduler options are not for the CPU: counted, not hidden
+    assert record["overlap_options_accepted"] is False
+    assert any("reason=platform" in k
+               for k in record["overlap_fallback_total"])
+
+
+# --- no fallback that hides the device --------------------------------------
+
+def test_tpu_place_needs_an_accelerator(monkeypatch):
+    assert executor_mod._cpu_forced()          # the harness forces it
+    assert executor_mod.place_device(
+        executor_mod.TPUPlace(0)).platform == "cpu"
+    monkeypatch.setattr(executor_mod, "_cpu_forced", lambda: False)
+    with pytest.raises(RuntimeError, match="found none"):
+        executor_mod.place_device(executor_mod.TPUPlace(0))
+    # a CPUPlace is always honest
+    assert executor_mod.place_device(
+        executor_mod.CPUPlace()).platform == "cpu"
+
+
+@pytest.mark.parametrize("meshed", [False, True],
+                         ids=["device_put_feed", "fsdp_tp_mesh"])
+def test_second_step_does_not_recompile(meshed):
+    """What the first chip runs showed: state that starts out as the
+    startup program's uncommitted outputs made the second call of a step
+    another computation than the first — a committed feed changed the
+    argument mapping, a mesh changed the avals — and ResNet-50 compiled
+    twice. The executor places the state before the first call."""
+    import paddle_tpu as fluid
+    from paddle_tpu import telemetry
+    from paddle_tpu.parallel import planner
+    from paddle_tpu.parallel.mesh import make_mesh
+
+    main, startup, loss = chip_smoke._build_lm(
+        seqlen=32, d_model=32, n_head=2, n_layer=1, vocab=64,
+        use_flash=False)
+    feed = chip_smoke._lm_feed(4, 32, 64)
+    exe = fluid.Executor(fluid.CPUPlace())
+    if meshed:
+        planner.plan(main, make_mesh((2, 2), ("fsdp", "tp"),
+                                     devices=jax.devices()[:4]))
+    else:
+        feed = {k: jax.device_put(v, exe.device) for k, v in feed.items()}
+
+    def compiles():
+        return sum(telemetry.read_series(
+            "jax_backend_compiles_total").values())
+
+    with executor_mod.scope_guard(executor_mod.Scope()):
+        exe.run(startup)
+        exe.run(main, feed=feed, fetch_list=[loss])
+        after_first = compiles()
+        for _ in range(2):
+            exe.run(main, feed=feed, fetch_list=[loss])
+    assert compiles() == after_first
+
+
+def _device(platform, kind, stats=None):
+    return types.SimpleNamespace(platform=platform, device_kind=kind,
+                                 memory_stats=lambda: stats)
+
+
+def test_peak_table_knows_v5e_and_nothing_else():
+    row = chip.peaks(_device("tpu", "TPU v5 lite"))
+    assert (row.bf16_tflops, row.int8_tops, row.hbm_gbps) == (
+        197.0, 393.0, 819.0)
+    assert row.hbm_bytes == 16 * 1024 ** 3
+    with pytest.raises(chip.UnknownDeviceError, match="TPU v99"):
+        chip.peaks(_device("tpu", "TPU v99"))
+    assert chip.peaks(_device("cpu", "cpu")) is None
+    assert roofline.nominal_tflops() is None   # this process is on the CPU
+
+
+def test_peak_overrides_are_gone(monkeypatch):
+    monkeypatch.setenv("BENCH_PEAK_TFLOPS", "1")
+    monkeypatch.setenv("PADDLE_TPU_PEAK_TFLOPS", "1")
+    assert roofline.nominal_tflops() is None
+    assert bench._peak_tflops() is None and bench._mfu(1e12) is None
+
+
+def test_default_budget_assumes_no_hbm_size():
+    gib = 1024 ** 3
+    assert memory.default_budget(_device("cpu", "cpu")) == 16 * gib
+    assert memory.default_budget(
+        _device("tpu", "TPU v5 lite", {"bytes_limit": 7})) == 7
+    assert memory.default_budget(_device("tpu", "TPU v5 lite")) == 16 * gib
+    with pytest.raises(chip.UnknownDeviceError):
+        memory.default_budget(_device("tpu", "TPU v99"))
+
+
+def test_describe_names_the_device():
+    assert chip.describe() == {"platform": "cpu", "device_kind": "cpu",
+                               "device_count": len(jax.devices())}
+
+
+# --- one compile cache ------------------------------------------------------
+
+@pytest.fixture
+def config_updates(monkeypatch):
+    seen = {}
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: seen.__setitem__(k, v))
+    return seen
+
+
+def test_cache_helper_leaves_the_variable_alone(monkeypatch, config_updates):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+    assert chip.enable_compile_cache() is None
+    # no directory, no threshold: only the key-stability setting
+    assert config_updates == {"jax_traceback_in_locations_limit": 1}
+
+
+def test_cache_helper_uses_the_checkout(monkeypatch, config_updates):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    path = chip.enable_compile_cache()
+    assert path == os.path.join(REPO, ".xla_cache")
+    assert config_updates["jax_compilation_cache_dir"] == path
+    assert chip.enable_compile_cache() == path   # fixed, not derived
+
+
+def test_harness_uses_the_same_cache():
+    """conftest.py calls the helper: no private variable, no second
+    directory (unless the environment placed the cache itself)."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        assert jax.config.jax_compilation_cache_dir == os.path.join(
+            REPO, ".xla_cache")
+    src = open(os.path.join(REPO, "tests", "conftest.py")).read()
+    assert "enable_compile_cache()" in src
+    assert "PADDLE_TPU_XLA_CACHE" not in src
+
+
+# --- kernels and gates agree ------------------------------------------------
+
+def _lax_conv(x, w, s, p, d):
+    return jax.lax.conv_general_dilated(
+        x, jnp.transpose(w, (2, 3, 1, 0)), window_strides=s,
+        padding=[(p[0], p[0]), (p[1], p[1])], rhs_dilation=d,
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        preferred_element_type=jnp.float32)
+
+
+# (H, W, K, stride, padding, dilation): the strides Mosaic refused as
+# strided value slices, now de-interleaved — every phase combination
+@pytest.mark.parametrize("h,w_,k,s,p,d", [
+    (8, 8, 3, 2, 1, 1),      # ResNet basic block 3x3 s2: phases {0, 1}
+    (8, 8, 1, 2, 0, 1),      # ResNet shortcut 1x1 s2: phase {0} only
+    (9, 11, 3, 2, 1, 2),     # dilation 2 at stride 2: one phase, offsets
+    (10, 10, 3, 3, 1, 1),    # stride 3: three phases
+    (7, 7, 3, 1, 1, 1),      # stride 1: the identity case
+])
+def test_strided_conv_gate_and_kernels_agree(h, w_, k, s, p, d):
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.standard_normal((2, h, w_, 128)), jnp.bfloat16)
+    w = jnp.asarray(rng.standard_normal((128, 128, k, k)) * 0.05,
+                    jnp.bfloat16)
+    args = ((s, s), (p, p), (d, d))
+    assert pallas_conv.ineligible(x, w, *args) is None
+    f32 = jnp.float32
+    want, vjp = jax.vjp(lambda w_: _lax_conv(x.astype(f32), w_, *args),
+                        w.astype(f32))
+    got = pallas_conv.conv2d(x, w, *args, out_dtype=f32)
+    np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
+    # grad-filter takes the same taps
+    dout = jnp.asarray(rng.standard_normal(want.shape), jnp.bfloat16)
+    gw = pallas_conv.conv2d_grad_filter(x, dout, (k, k), *args,
+                                        out_dtype=f32)
+    np.testing.assert_allclose(gw, vjp(dout.astype(f32))[0],
+                               rtol=2e-2, atol=2e-1)
+
+
+def test_deinterleave_is_identity_at_stride_one():
+    x = jnp.arange(2 * 3 * 7 * 4, dtype=jnp.float32).reshape(2, 3, 7, 4)
+    assert pallas_conv._deinterleave(x, 3, 1, 1) is x
+    # stride 2, 3 taps: phase 0 then phase 1, each ceil(7/2) = 4 wide
+    y = pallas_conv._deinterleave(x, 3, 1, 2)
+    assert y.shape == (2, 3, 8, 4)
+    np.testing.assert_array_equal(y[:, :, :4], x[:, :, 0::2])
+    np.testing.assert_array_equal(y[:, :, 4:7], x[:, :, 1::2])
+
+
+def test_conv_gate_declines_a_partitioned_step():
+    """XLA cannot partition a Mosaic call and the conv kernels are not
+    under shard_map: a mesh of more than one device keeps lax.conv."""
+    from paddle_tpu.parallel.mesh import make_mesh
+    x = jax.ShapeDtypeStruct((8, 14, 14, 128), jnp.bfloat16)
+    w = jax.ShapeDtypeStruct((128, 128, 3, 3), jnp.bfloat16)
+    args = ((1, 1), (1, 1), (1, 1))
+    many = make_mesh((4,), ("dp",), devices=jax.devices()[:4])
+    one = make_mesh((1,), ("dp",), devices=jax.devices()[:1])
+    assert pallas_conv.ineligible(x, w, *args, mesh=many) == "mesh"
+    assert pallas_conv.ineligible(x, w, *args, mesh=one) is None
+    assert "mesh" in pallas_conv.FALLBACK_REASONS
+
+
+def test_declined_flash_is_counted_and_takes_einsum():
+    """12 heads cannot be grouped by 8: use_flash=True keeps the einsum
+    path and books pallas_fallback_total{reason="heads"}."""
+    import paddle_tpu as fluid
+    from paddle_tpu import telemetry
+
+    def run(use_flash):
+        main, startup = fluid.Program(), fluid.Program()
+        with fluid.program_guard(main, startup):
+            q = fluid.layers.data(name="q", shape=[-1, 16, 12, 8],
+                                  append_batch_size=False)
+            out = fluid.layers.fused_attention(q, q, q, causal=True,
+                                               use_flash=use_flash)
+        exe = fluid.Executor(fluid.CPUPlace())
+        x = np.random.default_rng(1).standard_normal(
+            (2, 16, 12, 8)).astype(np.float32)
+        return exe.run(main, feed={"q": x}, fetch_list=[out])[0]
+
+    def declined():
+        return telemetry.read_series("pallas_fallback_total").get(
+            "op=scaled_dot_product_attention,reason=heads", 0)
+
+    before = declined()
+    einsum = run(False)
+    assert declined() == before            # nobody asked for flash
+    np.testing.assert_array_equal(run(True), einsum)
+    assert declined() > before             # counted per trace
+
+
+def test_flash_head_groups_match_one_loop():
+    """16 heads walk the grid in two groups of 8; the answer is the
+    reference's, forward and backward."""
+    from paddle_tpu.parallel.ring_attention import attention_reference
+    rng = np.random.default_rng(2)
+    q, k, v = (jnp.asarray(rng.standard_normal((1, 128, 16, 8)),
+                           jnp.float32) for _ in range(3))
+    assert pallas_attention._head_block(16) == 8
+    assert pallas_attention._head_block(6) == 6
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v) ** 2)
+
+    flash = lambda q, k, v: pallas_attention.flash_attention(q, k, v, True)
+    ref = lambda q, k, v: attention_reference(q, k, v, causal=True)
+    np.testing.assert_allclose(flash(q, k, v), ref(q, k, v),
+                               rtol=2e-4, atol=2e-5)
+    for a, b in zip(jax.grad(loss(flash), (0, 1, 2))(q, k, v),
+                    jax.grad(loss(ref), (0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(a, b, rtol=2e-3, atol=2e-4)
+
+
+# --- the smoke's own check: counted families are in the compiled step -------
+
+def _hlo(**calls):
+    """Optimized-HLO lines as the chip's compiler writes a Mosaic call."""
+    return "\n".join(
+        f'  %c.{i} = bf16[8]{{0}} custom-call(%a), custom_call_target='
+        f'"tpu_custom_call", frontend_attributes={{kernel_metadata={{}}}}, '
+        f'metadata={{op_name="jit(fn)/{name}/pallas_call" stack_frame_id=3}}'
+        for name, n in calls.items() for i in range(n))
+
+
+def test_smoke_counts_kernel_families():
+    text = _hlo(**{"pd.fused_conv_bn_act/conv2d_stats": 3,
+                   "pd.fused_conv_bn_act/bn_apply": 3,
+                   "pd.fused_conv_bn_act/bn_act": 1,
+                   "pd.conv2d_grad/conv2d": 3,
+                   "pd.conv2d_grad/conv2d_grad_filter": 3,
+                   "pd.scaled_dot_product_attention_grad/shard_map/"
+                   "transpose(jvp(flash_dq))": 2})
+    calls = chip_smoke._mosaic_calls(text + "\n  %d = f32[] add(%x, %y)")
+    assert calls == {"conv2d_grad/conv2d": 3,
+                     "conv2d_grad/conv2d_grad_filter": 3,
+                     "fused_conv_bn_act/bn_act": 1,
+                     "fused_conv_bn_act/bn_apply": 3,
+                     "fused_conv_bn_act/conv2d_stats": 3,
+                     "scaled_dot_product_attention_grad/flash_dq": 2}
+    # hits are booked per trace of the step; the HLO is one step
+    hits = {"op=conv2d_grad": 6, "op=fused_conv_bn_act": 6}
+    assert chip_smoke._check_conv_kernels(hits, calls) == 2
+
+
+@pytest.mark.parametrize("hits,lost,match", [
+    # a family the gate counted, whose kernel is not in the step
+    ({"op=fused_conv_bn_act": 6, "op=conv2d_grad": 6},
+     "fused_conv_bn_act/bn_apply", "0 bn_apply"),
+    ({"op=fused_conv_bn_act": 6, "op=conv2d_grad": 6},
+     "conv2d_grad/conv2d_grad_filter", "0 conv2d_grad_filter"),
+    # every kernel present, but the families disagree on the traces
+    ({"op=fused_conv_bn_act": 6, "op=conv2d_grad": 3}, None,
+     "do not reconcile"),
+])
+def test_smoke_refuses_a_lost_kernel_family(hits, lost, match):
+    calls = {"conv2d_grad/conv2d": 3, "conv2d_grad/conv2d_grad_filter": 3,
+             "fused_conv_bn_act/bn_apply": 3,
+             "fused_conv_bn_act/conv2d_stats": 3}
+    calls.pop(lost, None)
+    with pytest.raises(AssertionError, match=match):
+        chip_smoke._check_conv_kernels(hits, calls)
+
+
+def test_smoke_wants_three_flash_kernels_per_layer():
+    calls = {"scaled_dot_product_attention/flash_fwd": 4,
+             "scaled_dot_product_attention_grad/flash_dq": 4,
+             "scaled_dot_product_attention_grad/flash_dkv": 4}
+    chip_smoke._check_flash_kernels(calls, 4)
+    calls.pop("scaled_dot_product_attention_grad/flash_dkv")
+    with pytest.raises(AssertionError, match="0 flash_dkv"):
+        chip_smoke._check_flash_kernels(calls, 4)
+
+
+# --- bench.py: one attempt, the device named --------------------------------
+
+def test_bench_timed_loop_makes_one_attempt():
+    calls = []
+
+    def step():
+        calls.append(1)
+        raise RuntimeError("RESOURCE_EXHAUSTED: Ran out of memory in "
+                           "memory space vmem")
+
+    with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+        bench._timed_loop(step, 2, 4)
+    assert len(calls) == 1
+
+
+def test_bench_failure_line_names_device_and_exits_1(monkeypatch, capsys):
+    attempts = []
+
+    def lost():
+        raise RuntimeError("UNAVAILABLE: the device is gone")
+
+    def boom(mode):
+        attempts.append(mode)
+        # a device lost in the attempt cannot be asked what it is: the
+        # line names what main() saw before the family ran
+        monkeypatch.setattr(chip, "describe", lost)
+        raise RuntimeError("INTERNAL: Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(bench, "_dispatch", boom)
+    monkeypatch.setattr(bench, "_DEVICE", None)
+    monkeypatch.setenv("BENCH_MODE", "resnet")
+    monkeypatch.setenv("BENCH_HISTORY", "off")
+    assert bench.main() == 1
+    assert attempts == ["resnet"]              # no rebuild, no retry
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert line["value"] is None and "Mosaic" in line["errors"][0]
+    assert (line["platform"], line["device_kind"]) == ("cpu", "cpu")
+    assert line["device_count"] == len(jax.devices())
+
+
+def test_bench_has_no_retry_layer():
+    for name in ("_is_transient", "_retrying", "BenchError", "RETRIES",
+                 "_TRANSIENT_MARKERS", "PEAK_TFLOPS"):
+        assert not hasattr(bench, name), name

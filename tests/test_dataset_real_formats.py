@@ -1,5 +1,4 @@
-"""Every dataset module must parse the REFERENCE's real on-disk format
-(VERDICT r4 missing #1). Each test builds a tiny format-faithful fixture
+"""Every dataset module must parse the REFERENCE's real on-disk format. Each test builds a tiny format-faithful fixture
 (the same container type, member layout and record syntax as the upstream
 release), points DATA_HOME at it, and checks the reader yields the real
 records — then that removing the fixture falls back to synthetic."""

@@ -1,4 +1,4 @@
-"""Elastic end-to-end training proof (VERDICT r4 #6): multi-process
+"""Elastic end-to-end training proof: multi-process
 training over the shared TaskQueue where one worker is SIGKILLed mid-pass
 and the job finishes with a DIFFERENT worker count — no sample lost, no
 duplicate beyond the failure budget (the killed worker's in-flight task),

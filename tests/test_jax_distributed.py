@@ -1,6 +1,6 @@
 """Real multi-process jax.distributed smoke: two spawned processes, CPU
 backend, localhost coordinator, multihost.initialize + a cross-process
-psum + one dp-sharded train step (closes VERDICT r3 weak #4 — multi-host
+psum + one dp-sharded train step (multi-host
 was previously simulated-only). Reference analogue: the localhost pserver
 test, python/paddle/fluid/tests/unittests/test_recv_op.py:26-36."""
 

@@ -474,6 +474,9 @@ class TestBenchAuto:
         env = dict(os.environ, JAX_PLATFORMS="cpu", BENCH_PERF="0",
                    BENCH_STEPS="2", BENCH_WARMUP="1", BENCH_BATCH="8",
                    BENCH_FC_HIDDEN="32",
+                   # a test run is not a measurement: keep its CPU line
+                   # out of the repo's standing ledger
+                   BENCH_HISTORY="0",
                    # skip the session roofline probe: its 4096^3 matmul
                    # warmup costs minutes on shared CI hosts
                    BENCH_ROOFLINE="0")
@@ -489,6 +492,9 @@ class TestBenchAuto:
         assert fc[0]["steps_per_call_mode"] == "auto"
         assert isinstance(fc[0]["steps_per_call"], int)
         assert 1 <= fc[0]["steps_per_call"] <= 64
+        # every line names the device it ran on
+        assert (fc[0]["platform"], fc[0]["device_kind"]) == ("cpu", "cpu")
+        assert fc[0]["device_count"] >= 1 and fc[0]["mfu"] is None
 
 
 class TestExecutorIntegration:

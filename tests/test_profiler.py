@@ -47,7 +47,7 @@ class TestProfiler:
         assert "mul" in captured and "reduce_sum" in captured
 
     def test_jit_device_table_attributes_hot_op(self, capsys, tmp_path):
-        """Per-op device-time attribution in JIT mode (VERDICT r4 #8):
+        """Per-op device-time attribution in JIT mode:
         the xplane trace joined with the compiled HLO's pd.<op> scopes
         must rank the known-hot op — a 768x768 matmul dwarfing the other
         ops — first, like the reference's ParseEvents table

@@ -1,6 +1,6 @@
 """Program-level pipeline parallelism (reference ancestor:
 gserver/gradientmachines/ParallelNeuralNetwork.h layer-to-device
-assignment; VERDICT r2 missing #2): a Program split at cut vars into
+assignment): a Program split at cut vars into
 pp=4 stages on the 8-device CPU mesh must train with losses matching
 single-device execution exactly (mean-loss microbatching contract)."""
 

@@ -1,0 +1,193 @@
+"""The main path's Pallas kernels, compiled at real widths for a TPU v5e
+that is described and not attached (on-chip-measurement guide, section 2).
+
+Interpret mode cannot see what Mosaic refuses: a strided value slice, a
+head loop that outgrows VMEM. Every shape the gates pass must compile
+here; every shape a gate declines is pinned by its reason instead. This
+is the only file that describes a chip: the topology call lives in a
+module-scoped fixture (never at import, in a skipif or in parametrize),
+the compiles run in the test's own process, and the kernels are steered
+out of the interpreter with monkeypatch, not through an option of the
+program.
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.ops import fusion, pallas_attention, pallas_conv
+
+BF16 = jnp.bfloat16
+KERNEL = 'custom_call_target="tpu_custom_call"'
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip; conftest.py turns the cache
+    on, so it goes off around these compiles."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def mosaic(monkeypatch, no_persistent_cache):
+    """Kernels lower for Mosaic, not the interpreter. pallas_conv imports
+    `_interpret` by name, so both modules are patched (fusion reads
+    pallas_attention's at call time)."""
+    monkeypatch.setattr(pallas_attention, "_interpret", lambda: False)
+    monkeypatch.setattr(pallas_conv, "_interpret", lambda: False)
+
+
+def _compile(fn, one_chip, *shapes):
+    """Names of the Mosaic calls in `fn` compiled for the described chip:
+    each pallas_call's `name`, which the optimized HLO keeps in op_name
+    (autodiff wraps it: transpose(jvp(flash_dq))) and chip_smoke.py
+    counts kernel families by."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return sorted(
+        re.search(r'op_name="[^"]*?(\w+)\)*/pallas_call', line).group(1)
+        for line in text.splitlines() if KERNEL in line)
+
+
+# ResNet-50 widths under AMP O2 (C % 128 == 0 from stage 2's outputs on),
+# batch cut to 32: (name, x NHWC, w OIHW, stride, padding)
+CONV = [
+    ("3x3_s1", (32, 28, 28, 128), (128, 128, 3, 3), 1, 1),
+    ("1x1_s1", (32, 56, 56, 256), (128, 256, 1, 1), 1, 0),
+    ("3x3_s2", (32, 28, 28, 256), (256, 256, 3, 3), 2, 1),
+    ("1x1_s2", (32, 56, 56, 256), (512, 256, 1, 1), 2, 0),
+]
+CONV_IDS = [c[0] for c in CONV]
+
+
+def _geometry(x, w, s, p):
+    oh = (x[1] + 2 * p - w[2]) // s + 1
+    return (x[0], oh, oh, w[0])
+
+
+@pytest.mark.parametrize("name,x,w,s,p", CONV, ids=CONV_IDS)
+def test_conv_forward_compiles(mosaic, one_chip, name, x, w, s, p):
+    args = ((s, s), (p, p), (1, 1))
+    assert pallas_conv.ineligible(jax.ShapeDtypeStruct(x, BF16),
+                                  jax.ShapeDtypeStruct(w, BF16),
+                                  *args) is None
+    assert _compile(lambda a, b: pallas_conv.conv2d(a, b, *args),
+                    one_chip, (x, BF16), (w, BF16)) == ["conv2d"]
+
+
+@pytest.mark.parametrize("name,x,w,s,p", CONV, ids=CONV_IDS)
+def test_conv_grads_compile(mosaic, one_chip, name, x, w, s, p):
+    """grad-input and grad-filter behind the same gate as the forward."""
+    args = ((s, s), (p, p), (1, 1))
+    dout = _geometry(x, w, s, p)
+
+    def grads(a, d, b):
+        return (pallas_conv.conv2d_grad_input(d, b, a.shape[1:3], *args),
+                pallas_conv.conv2d_grad_filter(a, d, b.shape[2:], *args))
+
+    # grad-input is the forward kernel on the flipped filter
+    assert _compile(grads, one_chip, (x, BF16), (dout, BF16),
+                    (w, BF16)) == ["conv2d", "conv2d_grad_filter"]
+
+
+def test_conv_stats_stride2_compiles(mosaic, one_chip):
+    _, x, w, s, p = CONV[2]
+    assert _compile(
+        lambda a, b: pallas_conv.conv2d_stats(a, b, (s, s), (p, p), (1, 1)),
+        one_chip, (x, BF16), (w, BF16)) == ["conv2d_stats"]
+
+
+def test_conv_q8_stride2_compiles(mosaic, one_chip):
+    _, x, w, s, p = CONV[2]
+    assert _compile(
+        lambda a, b, dq: pallas_conv.conv2d_q8(a, b, (s, s), (p, p), (1, 1),
+                                               dq),
+        one_chip, (x, jnp.int8), (w, jnp.int8),
+        ((w[0],), jnp.float32)) == ["conv2d_q8"]
+
+
+def test_bn_act_compiles(mosaic, one_chip):
+    """fusion's bn+act kernel over the [N*H*W, C] view of stage 2."""
+    m, c = 32 * 56 * 56, 256
+    assert _compile(
+        lambda a, sc, b: fusion._pallas_bn_act(a, sc, b, 1e-5,
+                                               jax.nn.relu)[:2],
+        one_chip, ((m, c), BF16), ((c,), jnp.float32),
+        ((c,), jnp.float32)) == ["bn_act"]
+
+
+def test_bn_apply_compiles(mosaic, one_chip):
+    """The normalize(+act) half of the fused conv+bn+act window, whose
+    statistics come from conv2d_stats: the same stage-2 view."""
+    m, c = 32 * 56 * 56, 256
+    vec = ((c,), jnp.float32)
+    assert _compile(
+        lambda a, sc, b, mu, var: pallas_conv.bn_apply(
+            a, sc, b, mu, var, 1e-5, jax.nn.relu),
+        one_chip, ((m, c), BF16), vec, vec, vec, vec) == ["bn_apply"]
+
+
+def _flash_fwd_bwd(q, k, v):
+    def loss(q, k, v):
+        out = pallas_attention.flash_attention(q, k, v, True)
+        return out.astype(jnp.float32).sum()
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+# T=2048 is the smallest length with the wide (512/1024) tiles, where 16
+# heads in one unrolled loop ran out of VMEM; the gate now walks them in
+# groups of 8, so VMEM no longer grows with H. A tile of heads x D past
+# 1024 lanes is refused too (the forward at 8 x 256), so D=256 passes the
+# gate with 4 heads, the widest it admits.
+@pytest.mark.parametrize("shape", [(1, 1024, 8, 64), (1, 2048, 16, 64),
+                                   (1, 2048, 4, 256)],
+                         ids=["8_heads", "16_heads", "head_dim_256"])
+def test_flash_fwd_bwd_compiles(mosaic, one_chip, shape):
+    q = jax.ShapeDtypeStruct(shape, BF16)
+    assert pallas_attention.ineligible(q, q, q) is None
+    assert _compile(_flash_fwd_bwd, one_chip, *[(shape, BF16)] * 3) == [
+        "flash_dkv", "flash_dq", "flash_fwd"]
+
+
+@pytest.mark.parametrize("shape,reason", [
+    ((1, 2048, 12, 64), "heads"),        # > 8 and not a multiple of 8
+    ((1, 2048, 8, 256), "head_dim"),     # 8 x 256 lanes: forward refused
+    ((1, 2048, 16, 128), None),          # two groups of 8 x 128
+    ((1, 2048, 2, 512), None),
+    ((1, 2050, 8, 64), "seq"),
+])
+def test_flash_gate_declines(shape, reason):
+    """What the gate declines takes the einsum path with a counted reason
+    (pallas_fallback_total); nothing here reaches the compiler (the
+    shapes passed here, reason None, were compiled for this chip while
+    PR 21 was written, 29 s and 24 s: too long to keep)."""
+    q = jax.ShapeDtypeStruct(shape, BF16)
+    assert pallas_attention.ineligible(q, q, q) == reason
+    assert reason is None or reason in pallas_attention.FALLBACK_REASONS
+    # the ring path's per-shard check declines the same shapes
+    assert pallas_attention.block_supports(q, q) == (reason is None)

@@ -1,4 +1,4 @@
-"""v2 user-surface breadth (VERDICT r3 missing #3): networks composites,
+"""v2 user-surface breadth: networks composites,
 numpy image augmentation, pooling/evaluator shims, mq2007 dataset, and the
 acceptance bar — a reference-shaped v2 sentiment-LSTM script that touches
 ONLY paddle_tpu.v2.* end-to-end (reference python/paddle/v2 demo style)."""
@@ -11,7 +11,7 @@ from paddle_tpu import v2 as paddle
 
 class TestV2Networks:
     def test_sentiment_lstm_end_to_end(self):
-        """The VERDICT acceptance script: data -> embedding -> simple_lstm
+        """The acceptance script: data -> embedding -> simple_lstm
         -> pooling -> fc -> classification_cost, trained by the v2 SGD
         event loop on the imdb reader surface, then infer()."""
         from paddle_tpu.dataset import imdb

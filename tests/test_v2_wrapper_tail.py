@@ -1,4 +1,4 @@
-"""Oracle tests for the round-5 v2 wrapper tail (VERDICT r4 #5): every new
+"""Oracle tests for the round-5 v2 wrapper tail: every new
 trainer_config_helpers-parity wrapper runs against a numpy oracle, plus
 the ADVICE r4 fixes (initial_std/mean -> initializer, warn on lr kwargs,
 true vanilla recurrence) and the v2/plot Ploter."""

@@ -1,13 +1,14 @@
 #!/usr/bin/env python
-"""Regression gate between two BENCH_*.json files (ISSUE 16 satellite).
+"""Regression gate between two bench-record JSON files (ISSUE 16
+satellite).
 
-The BENCH_rNN campaign tracks one headline metric per round plus a
+A bench record tracks one headline metric per round plus a
 `parsed` payload of secondary numbers (p50/p99 latency, MFU, goodput,
 shed fraction, bucket hits...). Nothing gated those numbers: a round
 could regress images/sec or p99 and the only trace would be a human
 eyeballing two JSON files. This tool is the gate:
 
-    python tools/bench_diff.py BENCH_r05.json BENCH_r06.json
+    python tools/bench_diff.py old.json new.json
     python tools/bench_diff.py old.json new.json --threshold 0.10
     python tools/bench_diff.py old.json new.json --json
     python tools/bench_diff.py --history BENCH_HISTORY.jsonl
@@ -18,8 +19,7 @@ appends to instead of two hand-picked files: entries are grouped by
 int8 line never gates against its f32 sibling), and within each group
 the NEWEST entry is compared
 against the per-key rolling MEDIAN of all prior entries with the same
-direction-aware thresholds — the standing regression gate the BENCH_r*
-campaign runs after every round. Groups with fewer than two entries are
+direction-aware thresholds. Groups with fewer than two entries are
 skipped (nothing to compare against).
 
 It walks both `parsed` dicts (recursing into sub-dicts like

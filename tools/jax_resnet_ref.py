@@ -1,7 +1,7 @@
 """Framework-independent ceiling probe: hand-rolled pure-JAX ResNet-50
 training step (NHWC, bf16 compute, f32 master weights + momentum), same
 batch/protocol as bench.py. Used to separate framework overhead from the
-chip/XLA ceiling when tuning the flagship bench (VERDICT r2 weak #2)."""
+chip/XLA ceiling when tuning the flagship bench."""
 
 import time
 
