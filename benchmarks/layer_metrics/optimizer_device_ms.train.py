@@ -1,0 +1,14 @@
+"""Device ms a traced step spends in operations lowered from program ops
+of `op_role` optimize (the fused apply and its copies), median over the
+traced steps."""
+
+from benchmarks import program_trace
+
+LAYER = "model step"
+UNIT = "ms"
+MOVES = "train_items_per_s"
+SOURCE = "device_trace"
+
+
+def compute(ev):
+    return program_trace.median_role_ms(ev, "optimize")

@@ -596,6 +596,60 @@ class _CompiledBlock:
         self.last_sig = None
 
 
+_NO_WATCH = contextlib.nullcontext()
+# op_role (backward.py, optimizer.py) -> the named scope that carries it
+# into the HLO; an op without one is the forward pass
+_ROLE_SCOPE = {None: "pd_role.forward", "forward": "pd_role.forward",
+               "backward": "pd_role.backward",
+               "optimize": "pd_role.optimize"}
+
+
+def _step_id(scope):
+    """The id a step's spans share: the scope's PRNG counter as the step
+    starts. Looked up only while someone listens."""
+    if not tracing_mod.active():
+        return None
+    return int(scope.find_var("__rng_counter__") or 0)
+
+
+def _book_build(prog_label, seconds):
+    """executor_build_seconds_total{program,phase}: once per block that
+    compiled, never per step."""
+    family = telemetry.counter(
+        "executor_build_seconds_total",
+        "first run of a compiled block, by phase: trace, lower, compile, "
+        "analysis, execute", labels=("program", "phase"))
+    for phase, secs in seconds.items():
+        family.labels(program=prog_label, phase=phase).inc(max(secs, 0.0))
+
+
+def _build_seconds(events, launch_s, plan_s):
+    """{phase: seconds} of a call that built its block: jax's trace,
+    lower and compile (or cache load) inside the launch, what is left of
+    the launch as `execute`, and the executor's own walk from Program to
+    step function (`plan_s`, before the launch) under `trace`."""
+    seconds = dict.fromkeys(("trace", "lower", "compile"), 0.0)
+    seconds.update(telemetry.build_phase_seconds(events))
+    seconds["execute"] = launch_s - sum(seconds.values())
+    seconds["trace"] += plan_s
+    seconds["analysis"] = 0.0
+    return seconds
+
+
+def _span_build_events(events):
+    """jax's trace / lower / compile events of a watched call as ring
+    spans under the live span (launch), at the times jax measured. Only
+    stretches of a millisecond or more: lowering a ResNet-50 step traces
+    four thousand kernel bodies of 14 us each, which would push every
+    other span out of the ring (the counter counts them all)."""
+    if tracing_mod.enabled():
+        parent = tracing_mod.current_span()
+        if parent.sampled:
+            for phase, start, end in telemetry.merge_build_events(events):
+                if end - start >= 1e-3:
+                    tracing_mod.record_span(phase, start, end, parent=parent)
+
+
 class Executor:
     def __init__(self, place: Optional[Place] = None):
         self.place = place if place is not None else TPUPlace(0)
@@ -610,13 +664,18 @@ class Executor:
             return_numpy: bool = True, use_program_cache: bool = True,
             use_jit: Optional[bool] = None):
         program = program if program is not None else default_main_program()
+        scope = scope if scope is not None else global_scope()
         # hang watchdog: a None fast-path unless sentinel.start() ran
         from . import sentinel as sentinel_mod
         _tok = sentinel_mod.arm_dispatch(telemetry.program_label(program))
         try:
-            return self._run_impl(program, feed, fetch_list, feed_var_name,
-                                  fetch_var_name, scope, return_numpy,
-                                  use_program_cache, use_jit)
+            # `step` covers the whole call; _run_impl tiles it with the
+            # phases prepare / launch / bookkeep / writeback
+            with tracing_mod.span("step", step=_step_id(scope)):
+                return self._run_impl(
+                    program, feed, fetch_list, feed_var_name,
+                    fetch_var_name, scope, return_numpy,
+                    use_program_cache, use_jit)
         except Exception as e:
             # flight-recorder crash hook: a no-op unless the recorder is
             # enabled (inspector.enable_flight_recorder or the
@@ -835,6 +894,17 @@ class Executor:
     def _run_steps_window(self, program, stacked, steps, fetch_list, scope,
                           return_numpy, fetch_mode, use_program_cache,
                           prog_label, place_label):
+        # one `step` span for the K steps of the window, with run()'s
+        # phases; its id is the first step's
+        with tracing_mod.span("step", step=_step_id(scope)):
+            return self._run_window_impl(
+                program, stacked, steps, fetch_list, scope, return_numpy,
+                fetch_mode, use_program_cache, prog_label, place_label)
+
+    def _run_window_impl(self, program, stacked, steps, fetch_list, scope,
+                         return_numpy, fetch_mode, use_program_cache,
+                         prog_label, place_label):
+        tracing_mod.phase("prepare")
         fetch_names = [v.name if isinstance(v, Variable) else str(v)
                        for v in list(fetch_list or [])]
         feed_vals = {n: (v if isinstance(v, jax.Array) else np.asarray(v))
@@ -865,10 +935,13 @@ class Executor:
                dynamics_mod.cache_token(program),
                quant_mod.cache_token(program))
         compiled = self._cache.get(key) if use_program_cache else None
+        plan_s = 0.0
         if compiled is None:
+            plan_t0 = time.perf_counter()
             compiled = self._compile_window(
                 program, state_keys, sorted(feed_vals), fetch_names,
                 persist_out, {}, steps, fetch_mode)
+            plan_s = time.perf_counter() - plan_t0
             if use_program_cache:
                 self._cache[key] = compiled
         from . import profiler as profiler_mod
@@ -887,15 +960,16 @@ class Executor:
         sig = telemetry.signature_of(feed_vals)
         new_sig = sig not in compiled.seen_sigs
         compile_before = telemetry.jax_compile_seconds()
+        build_watch = telemetry.watch_build() if new_sig else _NO_WATCH
+        tracing_mod.phase("launch")
         run_t0 = time.perf_counter()
         try:
-            with jax.default_device(self.device):
-                from . import profiler as profiler_mod
-                with profiler_mod.record("executor_run(window)"):
-                    fetch_vals, new_state = compiled.fn(
-                        feed_vals, state_vals, np.uint32(rng_counter))
-                    if profiler_mod.is_active():
-                        jax.block_until_ready((fetch_vals, new_state))
+            with jax.default_device(self.device), \
+                    build_watch as build_events:
+                fetch_vals, new_state = compiled.fn(
+                    feed_vals, state_vals, np.uint32(rng_counter))
+                if profiler_mod.is_active():
+                    jax.block_until_ready((fetch_vals, new_state))
         except _WindowUnsupported:
             self._cache.pop(key, None)
             raise
@@ -913,6 +987,19 @@ class Executor:
                 raise oom from e
             raise
         run_dt = time.perf_counter() - run_t0
+        if new_sig:
+            _span_build_events(build_events)
+        # window succeeded: counter commit is atomic for all K steps, and
+        # the state goes back before any watcher below runs (its old
+        # buffers were donated)
+        tracing_mod.phase("writeback")
+        dyn_stats = new_state.pop(dynamics_mod.STATE_KEY, None)
+        scope.set_var("__rng_counter__", rng_counter + steps)
+        for n, v in new_state.items():
+            scope.set_var(n, v)
+        tracing_mod.phase("bookkeep")
+        profiler_mod.record_event("executor_run(window)", run_dt,
+                                  start=run_t0)
         compile_s = telemetry.jax_compile_seconds() - compile_before
         cache_status = "miss" if new_sig else "hit"
         if new_sig:
@@ -932,6 +1019,8 @@ class Executor:
                 "compile", program=prog_label, place=place_label,
                 cause=cause, seconds=compile_s, window_steps=steps,
                 signature=[list(s) for s in sig])
+            _book_build(prog_label,
+                        _build_seconds(build_events, run_dt, plan_s))
         else:
             telemetry.counter(
                 "executor_cache_hits_total",
@@ -940,11 +1029,6 @@ class Executor:
                     program=prog_label, place=place_label).inc()
         compiled.last_sig = sig
 
-        # window succeeded: counter commit is atomic for all K steps
-        dyn_stats = new_state.pop(dynamics_mod.STATE_KEY, None)
-        scope.set_var("__rng_counter__", rng_counter + steps)
-        for n, v in new_state.items():
-            scope.set_var(n, v)
         if dyn_stats is not None:
             dynamics_mod.on_window(program, prog_label, dyn_stats,
                                    int(rng_counter), steps)
@@ -981,19 +1065,11 @@ class Executor:
             donated=len(state_vals), feeds=len(feed_vals),
             fetches=len(fetch_names))
         if tracing_mod.enabled():
-            # retroactive window span from the wall time already measured
-            # (perf_counter and monotonic share CLOCK_MONOTONIC on linux;
-            # we re-anchor on monotonic to keep one trace timebase)
-            t_end = time.monotonic()
-            sp = tracing_mod.record_span(
-                "run_steps_window", t_end - run_dt, t_end,
-                attrs={"program": prog_label, "place": place_label,
-                       "steps": steps, "cache": cache_status})
-            if new_sig and compile_s > 0.0:
-                tracing_mod.record_span(
-                    "compile", t_end - run_dt,
-                    min(t_end - run_dt + compile_s, t_end), parent=sp,
-                    attrs={"cause": cause, "seconds": compile_s})
+            step_span = tracing_mod.owner_span()
+            if step_span.sampled:
+                step_span.attrs.update(program=prog_label, place=place_label,
+                                       mode="window", steps=steps,
+                                       cache=cache_status)
 
         hbm_sample = None
         try:
@@ -1014,6 +1090,7 @@ class Executor:
                 "hbm_bytes_in_use": (hbm_sample or {}).get("bytes_in_use"),
                 "hbm_peak_bytes": (hbm_sample or {}).get("peak_bytes"),
             })
+        tracing_mod.phase("writeback")
         return [np.asarray(v) if return_numpy else v for v in fetch_vals]
 
     def static_memory_analysis(self, program=None, feed=None,
@@ -1127,6 +1204,7 @@ class Executor:
     def _run_impl(self, program, feed, fetch_list, feed_var_name,
                   fetch_var_name, scope, return_numpy, use_program_cache,
                   use_jit):
+        tracing_mod.phase("prepare")
         feed = dict(feed or {})
         # program-bound reader pipelines (layers.read_file): when the caller
         # gives no explicit feed for the reader vars, pull the next
@@ -1254,9 +1332,12 @@ class Executor:
                    dynamics_mod.cache_token(program),
                    quant_mod.cache_token(program))
             compiled = self._cache.get(key) if use_program_cache else None
+            plan_s = 0.0
             if compiled is None:
+                plan_t0 = time.perf_counter()
                 compiled = self._compile(program, state_keys, sorted(feed_vals),
                                          fetch_names, persist_out, lod_map)
+                plan_s = time.perf_counter() - plan_t0
                 if use_program_cache:
                     self._cache[key] = compiled
             from . import profiler as profiler_mod
@@ -1272,17 +1353,21 @@ class Executor:
             sig = telemetry.signature_of(feed_vals)
             new_sig = sig not in compiled.seen_sigs
             compile_before = telemetry.jax_compile_seconds()
+            # a signature not seen before builds: jax's own trace / lower /
+            # compile events inside the call are collected (cold path only)
+            build_watch = telemetry.watch_build() if new_sig else _NO_WATCH
+            tracing_mod.phase("launch")
             run_t0 = time.perf_counter()
             try:
-                with jax.default_device(self.device):
-                    with profiler_mod.record("executor_run(jit)"):
-                        fetch_vals, fetch_lens, new_state = compiled.fn(
-                            feed_vals, state_vals, np.uint32(rng_counter))
-                        if profiler_mod.is_active():
-                            # async dispatch returns futures; force execution
-                            # inside the timed scope so the event measures the
-                            # step, not the enqueue (only when profiling)
-                            jax.block_until_ready((fetch_vals, new_state))
+                with jax.default_device(self.device), \
+                        build_watch as build_events:
+                    fetch_vals, fetch_lens, new_state = compiled.fn(
+                        feed_vals, state_vals, np.uint32(rng_counter))
+                    if profiler_mod.is_active():
+                        # async dispatch returns futures; force execution
+                        # inside the timed scope so the event measures the
+                        # step, not the enqueue (only when profiling)
+                        jax.block_until_ready((fetch_vals, new_state))
             except Exception as e:
                 # OOM forensics: a raw RESOURCE_EXHAUSTED becomes a
                 # structured errors.OOMError (breakdown, top live buffers,
@@ -1294,6 +1379,12 @@ class Executor:
                     raise oom from e
                 raise
             run_dt = time.perf_counter() - run_t0
+            if new_sig:
+                _span_build_events(build_events)
+            tracing_mod.phase("bookkeep")
+            # the profiler's host event is the launch, from the one timer
+            profiler_mod.record_event("executor_run(jit)", run_dt,
+                                      start=run_t0)
             # the dynamics stats row leaves new_state immediately: its
             # off-period NaN filler must never reach the check_nan scan or
             # the scope writeback (recorded only after the step commits)
@@ -1321,20 +1412,25 @@ class Executor:
                     "compile", program=prog_label, place=place_label,
                     cause=cause, seconds=compile_s,
                     signature=[list(s) for s in sig])
+                build_s = _build_seconds(build_events, run_dt, plan_s)
                 if cause == "first_compile" and not internal_run:
                     # static memory analysis once per compiled block: an
                     # extra AOT lower/compile from avals (the persistent
                     # compilation cache absorbs the XLA work); advisory —
                     # a failure must never fail the training step
+                    analysis_t0 = time.perf_counter()
                     try:
-                        memory_mod.on_compile(
-                            self, compiled, program, prog_label, place_label,
-                            feed_vals, state_vals, np.uint32(rng_counter),
-                            signature=sig)
+                        with tracing_mod.span("analysis"):
+                            memory_mod.on_compile(
+                                self, compiled, program, prog_label,
+                                place_label, feed_vals, state_vals,
+                                np.uint32(rng_counter), signature=sig)
                     except Exception as mem_e:
                         telemetry.log_event(
                             "memory_analysis_error", program=prog_label,
                             error=f"{type(mem_e).__name__}: {mem_e}")
+                    build_s["analysis"] = time.perf_counter() - analysis_t0
+                _book_build(prog_label, build_s)
                 if cause == "signature_change":
                     last = compiled.last_sig or ()
                     telemetry.counter(
@@ -1383,6 +1479,7 @@ class Executor:
             seed = program.random_seed or 12345
             rng_key = jax.random.fold_in(jax.random.key(seed), rng_counter)
             compile_before = telemetry.jax_compile_seconds()
+            tracing_mod.phase("launch")
             run_t0 = time.perf_counter()
             try:
                 fetch_vals, fetch_lens, new_state = self._run_eager(
@@ -1395,6 +1492,7 @@ class Executor:
                     raise oom from e
                 raise
             run_dt = time.perf_counter() - run_t0
+            tracing_mod.phase("bookkeep")
             compile_s = telemetry.jax_compile_seconds() - compile_before
             mode, donated, cache_status = "eager", 0, "n/a"
             dyn_stats = None  # dynamics rides the traced step only
@@ -1451,16 +1549,10 @@ class Executor:
             execute_s=max(run_dt - compile_s, 0.0), cache=cache_status,
             donated=donated, feeds=len(feed_vals), fetches=n_user_fetch)
         if tracing_mod.enabled():
-            t_end = time.monotonic()
-            sp = tracing_mod.record_span(
-                "step", t_end - run_dt, t_end,
-                attrs={"program": prog_label, "place": place_label,
-                       "mode": mode, "cache": cache_status})
-            if compile_s > 0.0 and cache_status == "miss":
-                tracing_mod.record_span(
-                    "compile", t_end - run_dt,
-                    min(t_end - run_dt + compile_s, t_end), parent=sp,
-                    attrs={"seconds": compile_s})
+            step_span = tracing_mod.owner_span()
+            if step_span.sampled:
+                step_span.attrs.update(program=prog_label, place=place_label,
+                                       mode=mode, cache=cache_status)
 
         hbm_sample = None
         if not internal_run:
@@ -1473,6 +1565,7 @@ class Executor:
             except Exception:
                 hbm_sample = None
 
+        tracing_mod.phase("writeback")
         for n, v in new_state.items():
             if n.endswith(SEQLEN_SUFFIX) or n.endswith(SEQLEN2_SUFFIX):
                 continue
@@ -1487,6 +1580,13 @@ class Executor:
                 scope.set_var(n, LoDTensor(packed, lod))
             else:
                 scope.set_var(n, v)
+        # the state goes back before anything below can wait on the device:
+        # its old buffers were donated, so an interrupt in between would
+        # leave the scope holding dead arrays
+        from . import inspector as inspector_mod
+        flight = not internal_run and inspector_mod.flight_enabled()
+        if extra_fetch or flight:
+            tracing_mod.phase("bookkeep")
         if extra_fetch:
             # pop the telemetry side-fetches (gauges, not user outputs);
             # float() forces the device read — the documented cost of
@@ -1501,22 +1601,22 @@ class Executor:
                     pass
             fetch_vals = fetch_vals[:n_user_fetch]
             fetch_names = fetch_names[:n_user_fetch]
-        if not internal_run:
-            from . import inspector as inspector_mod
-            if inspector_mod.flight_enabled():
-                # flight recorder: one bounded ring record per step (after
-                # the gauge pop above so the global norm is this step's)
-                inspector_mod.record_step(program, prog_label, {
-                    "place": place_label, "mode": mode, "seconds": run_dt,
-                    "compile_s": compile_s, "cache": cache_status,
-                    "feeds": len(feed_vals), "fetches": n_user_fetch,
-                    "rng_counter": int(rng_counter),
-                    "global_norm": telemetry.read_gauge(
-                        "optimizer_global_norm", program=prog_label),
-                    "hbm_bytes_in_use": (hbm_sample or {}).get(
-                        "bytes_in_use"),
-                    "hbm_peak_bytes": (hbm_sample or {}).get("peak_bytes"),
-                })
+        if flight:
+            # flight recorder: one bounded ring record per step (after
+            # the gauge pop above so the global norm is this step's)
+            inspector_mod.record_step(program, prog_label, {
+                "place": place_label, "mode": mode, "seconds": run_dt,
+                "compile_s": compile_s, "cache": cache_status,
+                "feeds": len(feed_vals), "fetches": n_user_fetch,
+                "rng_counter": int(rng_counter),
+                "global_norm": telemetry.read_gauge(
+                    "optimizer_global_norm", program=prog_label),
+                "hbm_bytes_in_use": (hbm_sample or {}).get(
+                    "bytes_in_use"),
+                "hbm_peak_bytes": (hbm_sample or {}).get("peak_bytes"),
+            })
+        if extra_fetch or flight:
+            tracing_mod.phase("writeback")
         # Fetched sequence vars come back in the reference's packed layout
         # ([sum_len, ...] rows): numpy mode returns the packed array, LoDTensor
         # mode additionally carries the offsets.
@@ -1707,11 +1807,16 @@ class Executor:
             ins = newins
         t0 = time.perf_counter() if _BENCHMARK and _EAGER else None
         try:
-            # the scope lands in every emitted HLO instruction's
-            # metadata op_name ("jit(fn)/.../pd.<type>/<prim>") — the hook
-            # the profiler's per-op device table joins timings against
-            # (profiler._print_device_table / xplane.hlo_op_names)
-            with jax.named_scope(f"pd.{op.type}"):
+            # the scopes land in every emitted HLO instruction's metadata
+            # op_name ("jit(fn)/pd_role.<role>/pd.<type>/<prim>") — the hook
+            # device time is booked to program ops by: the outermost
+            # "pd.<type>" (profiler._print_device_table,
+            # xplane.hlo_op_names) and the outermost "pd_role.<op_role>"
+            # (benchmarks/program_trace.py; spelt so that no "pd." rule
+            # sees it). A fused op's members keep the fused op's role.
+            with jax.named_scope(_ROLE_SCOPE.get(op.desc.attrs.get("op_role"),
+                                                 _ROLE_SCOPE[None])), \
+                    jax.named_scope(f"pd.{op.type}"):
                 outs = opdef.lower(ctx, op, ins)
         except (AssertionError, TypeError, ValueError, IndexError) as e:
             # PADDLE_ENFORCE-style context (reference platform/enforce.h +

@@ -323,6 +323,11 @@ def _window_synth(members, type_, group, elide=()):
         for a, v in m.desc.attrs.items():
             attrs[f"{k}:{a}"] = v
     attrs["__fusion_group__"] = group
+    role = members[0].desc.attrs.get("op_role")
+    if role is not None:
+        # the executor's pd_role.<role> scope: a window's device time is
+        # booked to the pass its members belong to
+        attrs["op_role"] = role
     desc = OpDesc(type=type_, inputs=inputs, outputs=outputs, attrs=attrs)
     return _synth_operator(getattr(members[0], "block", None), desc,
                            getattr(members[0], "creation_site", None))

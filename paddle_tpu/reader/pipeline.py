@@ -65,17 +65,22 @@ class DoubleBufferedFeeder:
         self._wctx = None
 
     def _produce(self):
+        from .. import tracing
         try:
             for batch in self.reader():
                 if self._stop.is_set():
                     return
-                feed = self.to_feed(batch)
-                if self.device is not None:
-                    feed = {
-                        k: (jax.device_put(v, self.device)
-                            if isinstance(v, (np.ndarray, np.generic))
-                            else v)
-                        for k, v in feed.items()}
+                # `input_build`: what one batch costs this thread, the
+                # host->device copy included; the wait for a free slot in
+                # the queue is not part of it
+                with tracing.span("input_build"):
+                    feed = self.to_feed(batch)
+                    if self.device is not None:
+                        feed = {
+                            k: (jax.device_put(v, self.device)
+                                if isinstance(v, (np.ndarray, np.generic))
+                                else v)
+                            for k, v in feed.items()}
                 self._queue.put(feed)
         except BaseException as e:          # surface in the consumer
             self._queue.put(e)
@@ -85,7 +90,7 @@ class DoubleBufferedFeeder:
     def __iter__(self):
         import time
 
-        from .. import telemetry
+        from .. import telemetry, tracing
         stall = telemetry.histogram(
             "input_stall_seconds",
             "consumer wait on the prefetch queue (0 when the producer "
@@ -94,9 +99,10 @@ class DoubleBufferedFeeder:
             "input_batches_total", "batches delivered by prefetch feeders")
         self.reset()
         while True:
-            t0 = time.perf_counter()
-            item = self._queue.get()
-            stall.observe(time.perf_counter() - t0)
+            with tracing.span("input_wait"):
+                t0 = time.perf_counter()
+                item = self._queue.get()
+                stall.observe(time.perf_counter() - t0)
             if item is _STOP:
                 self._thread.join()
                 self._thread = None

@@ -34,7 +34,9 @@ __all__ = [
     "snapshot", "prometheus_text", "log_event", "recent_events",
     "enable_step_log", "disable_step_log", "step_log_path", "read_step_log",
     "export_chrome_trace", "default_buckets", "reset", "program_label",
-    "jax_compile_seconds", "signature_of", "read_gauge", "read_series",
+    "jax_compile_seconds", "watch_build", "merge_build_events",
+    "build_phase_seconds",
+    "signature_of", "read_gauge", "read_series",
     "read_histogram", "histogram_quantile",
 ]
 
@@ -646,6 +648,14 @@ def signature_of(feed_vals: Dict[str, Any]) -> Tuple[Tuple[str, str, str], ...]:
 # compile vs execute without AOT-lowering anything.
 _compile_secs = [0.0]
 _compile_listener_installed = [False]
+# While the executor watches a call that may build a block (watch_build),
+# every trace / lower / compile event jax reports inside it, as (phase,
+# start, end) on time.monotonic(). None outside: a steady step pays one
+# comparison, and only when jax compiles something.
+_BUILD_PHASE_OF = {"jaxpr_trace_duration": "trace",
+                   "jaxpr_to_mlir_module_duration": "lower",
+                   "backend_compile_duration": "compile"}
+_build_watch: List[Optional[list]] = [None]
 
 
 def _install_compile_listener():
@@ -656,6 +666,12 @@ def _install_compile_listener():
         import jax.monitoring
 
         def _on_duration(name, secs, **kw):
+            watch = _build_watch[0]
+            if watch is not None:
+                phase = _BUILD_PHASE_OF.get(name.rsplit("/", 1)[-1])
+                if phase is not None:
+                    end = time.monotonic()
+                    watch.append((phase, end - float(secs), end))
             if name.endswith("backend_compile_duration"):
                 _compile_secs[0] += float(secs)
                 counter("jax_backend_compile_seconds_total",
@@ -672,6 +688,48 @@ def jax_compile_seconds() -> float:
     """Monotone accumulator of XLA backend-compile seconds in this process."""
     _install_compile_listener()
     return _compile_secs[0]
+
+
+@contextlib.contextmanager
+def watch_build():
+    """Collect jax's own trace / lower / compile events for the length of
+    the block: yields the list they land in, [(phase, start, end)] on
+    time.monotonic(). `backend_compile` covers a persistent-cache load
+    too. The executor opens this around a call whose signature it has
+    not seen, never around a steady step. Not re-entrant: an inner watch
+    (a nested Executor.run) takes the events, the outer one resumes."""
+    _install_compile_listener()
+    saved, events = _build_watch[0], []
+    _build_watch[0] = events
+    try:
+        yield events
+    finally:
+        _build_watch[0] = saved
+
+
+def merge_build_events(events) -> List[Tuple[str, float, float]]:
+    """watch_build() events with each phase's overlapping intervals
+    joined: a jit traced inside another (jax reports both, the inner
+    inside the outer) is one stretch, counted once."""
+    merged: List[Tuple[str, float, float]] = []
+    for phase in sorted(set(e[0] for e in events)):
+        reach = None
+        for start, end in sorted((s, e) for p, s, e in events if p == phase):
+            if reach is None or start > reach:
+                merged.append((phase, start, end))
+                reach = end
+            elif end > reach:
+                merged[-1] = (phase, merged[-1][1], end)
+                reach = end
+    return merged
+
+
+def build_phase_seconds(events) -> Dict[str, float]:
+    """{phase: seconds} the watch_build() events cover on the clock."""
+    out: Dict[str, float] = {}
+    for phase, start, end in merge_build_events(events):
+        out[phase] = out.get(phase, 0.0) + (end - start)
+    return out
 
 
 _install_compile_listener()
@@ -719,6 +777,10 @@ METRIC_CATALOG = {
     "executor_compile_seconds_total": _m(
         "counter", ("program", "place"),
         "XLA compile wall seconds inside Executor.run"),
+    "executor_build_seconds_total": _m(
+        "counter", ("program", "phase"),
+        "first run of a compiled block, by phase: trace, lower, compile, "
+        "analysis, execute"),
     "executor_cache_hits_total": _m(
         "counter", ("program", "place"),
         "runs served by an already-traced signature"),
@@ -881,8 +943,6 @@ METRIC_CATALOG = {
     "telemetry_quantile_tail_clamped_total": _m(
         "counter", ("name",),
         "quantiles clamped to the last finite bucket edge"),
-    "trace_spans_total": _m("counter", ("name",),
-                            "finished (sampled) trace spans"),
     "trace_spans_dropped_total": _m(
         "counter", (), "spans evicted from the trace ring buffer"),
     "obs_requests_total": _m("counter", ("endpoint",),

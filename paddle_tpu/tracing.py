@@ -2,30 +2,41 @@
 
 The metrics registry (telemetry.py) answers "how is the fleet doing in
 aggregate"; this module answers "where did *this* request's 40 ms go".
-Spans carry a trace id / span id / parent id, wall+monotonic timestamps,
-attrs, and point events, and finished spans land in a bounded process-wide
-ring buffer that `/spans` (obs_server.py) and the exporters read.
+Spans carry a trace id / span id / parent id, the step's id, monotonic
+timestamps, attrs, and point events.
 
 Design points, in the order they matter:
 
-  * **Off by default, cheap when off.** `enabled()` is one attribute
-    read; every instrumentation site in executor/io/serving guards on it.
-    Enable programmatically with `enable()` or via `PADDLE_TPU_TRACE`
-    (``1`` for everything, a float like ``0.1`` for head sampling).
+  * **One helper, two sinks.** `span(name)` brackets live code. Under a
+    profiler session (`jax.profiler.start_trace`, `profiler.profiler()`
+    with a trace directory, the benchmark's traced steps) it is a
+    `jax.profiler.TraceAnnotation("pd.<name>")`: the span sits on
+    `/host:CPU` of the xplane file, on the device trace's clock, beside
+    what the device ran. When `enabled()` it also lands, finished, in a
+    bounded process-wide ring that `/spans` (obs_server.py) and the
+    exporters read. With neither it is a shared no-op: no clock read, no
+    allocation.
+  * **Phases tile their span.** `phase(name)` ends the previous phase of
+    the innermost live span on this thread and starts the next at the
+    same instant; the span's exit ends the last one. The executor's
+    `step` is `prepare`, `launch`, `bookkeep`, `writeback`.
+  * **A step's spans share its id.** `span(name, step=n)` records the
+    executor's `__rng_counter__`; children inherit it.
+  * **Off by default.** Enable the ring programmatically with `enable()`
+    or via `PADDLE_TPU_TRACE` (``1`` for everything, a float like
+    ``0.1`` for head sampling).
   * **Head sampling at the root.** The keep/drop decision is made once,
     when a root span starts, and inherited by every child — a trace is
     either complete or absent, never a partial tree. The sampler is a
     deterministic error-feedback accumulator (no RNG), so a 0.25 rate
     keeps exactly every 4th trace.
-  * **Two span styles.** `span()`/`start_span()` bracket live code with
-    thread-local context propagation (children discover their parent from
-    the stack). `record_span()` creates a span retroactively from
-    timestamps already measured — the executor and batcher time their
-    phases anyway, so tracing adds no second clock read on the hot path.
+  * **Spans from other clocks.** `start_span()` hands out a handle
+    carried across threads; `record_span()` creates a span from
+    timestamps the caller measured anyway (the batcher's phases,
+    checkpoint io, jax's own compile events). Ring only.
   * **Exports.** `export_chrome_trace()` writes Perfetto-loadable
-    ``{"traceEvents": [...]}`` JSON (complete "X" events, µs); JSONL via
-    `export_jsonl()` or a live sink (`PADDLE_TPU_TRACE_JSONL`) mirroring
-    each finished span as one JSON object per line.
+    ``{"traceEvents": [...]}`` JSON of the ring (complete "X" events,
+    µs); `export_jsonl()` one JSON object per line.
 """
 
 from __future__ import annotations
@@ -37,6 +48,8 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
+import jax
+
 from . import telemetry
 
 _DEFAULT_CAPACITY = 4096
@@ -47,9 +60,11 @@ _CAPACITY = _DEFAULT_CAPACITY
 _ENABLED = False
 _SAMPLE = 1.0
 _SAMPLE_ACC = 0.0            # error-feedback accumulator for head sampling
-_JSONL_PATH: Optional[str] = None
 _IDS = itertools.count(1)
-_LOCAL = threading.local()   # .stack — list of live Span objects
+# .stack — list of live sampled Span objects; .ctx — innermost live span()
+_LOCAL = threading.local()
+_Annotation = jax.profiler.TraceAnnotation
+_ANNOTATION_PREFIX = "pd."
 
 # offset from time.monotonic() to wall-clock, so spans recorded from
 # monotonic timestamps can still report a wall "ts"
@@ -61,15 +76,17 @@ class Span:
     manager do it); only ended spans reach the ring buffer."""
 
     __slots__ = ("trace_id", "span_id", "parent_id", "name", "start",
-                 "end_t", "attrs", "events", "sampled")
+                 "end_t", "attrs", "events", "sampled", "step")
 
     def __init__(self, name: str, trace_id: str, span_id: str,
                  parent_id: Optional[str], start: float, sampled: bool,
-                 attrs: Optional[Dict[str, Any]] = None):
+                 attrs: Optional[Dict[str, Any]] = None,
+                 step: Optional[int] = None):
         self.name = name
         self.trace_id = trace_id
         self.span_id = span_id
         self.parent_id = parent_id
+        self.step = step
         self.start = start
         self.end_t: Optional[float] = None
         self.attrs: Dict[str, Any] = dict(attrs) if attrs else {}
@@ -104,6 +121,7 @@ class Span:
             "span_id": self.span_id,
             "parent_id": self.parent_id,
             "name": self.name,
+            "step": self.step,
             "start": self.start,
             "end": end,
             "dur_s": max(end - self.start, 0.0),
@@ -120,7 +138,7 @@ class _NullSpan:
 
     __slots__ = ()
     sampled = False
-    trace_id = span_id = parent_id = None
+    trace_id = span_id = parent_id = step = None
     name = ""
 
     def set_attr(self, key, value):
@@ -181,32 +199,18 @@ def _finish(sp: Span):
                 "trace_spans_dropped_total",
                 "finished spans evicted from the bounded ring buffer").inc(
                     dropped)
-        path = _JSONL_PATH
-    telemetry.counter(
-        "trace_spans_total", "finished (sampled) spans, by span name",
-        labels=("name",)).labels(name=sp.name).inc()
-    if path:
-        try:
-            with open(path, "a") as f:
-                f.write(json.dumps(d) + "\n")
-        except OSError:
-            pass
 
 
 # --- lifecycle ---------------------------------------------------------------
 
-def enable(sample: float = 1.0, capacity: Optional[int] = None,
-           jsonl: Optional[str] = None):
-    """Turn tracing on. `sample` in (0, 1] head-samples root spans;
-    `capacity` bounds the finished-span ring; `jsonl` mirrors finished
-    spans to a file, one JSON object per line."""
-    global _ENABLED, _SAMPLE, _CAPACITY, _JSONL_PATH
+def enable(sample: float = 1.0, capacity: Optional[int] = None):
+    """Turn the ring on. `sample` in (0, 1] head-samples root spans;
+    `capacity` bounds the finished-span ring."""
+    global _ENABLED, _SAMPLE, _CAPACITY
     with _LOCK:
         _SAMPLE = min(max(float(sample), 0.0), 1.0)
         if capacity is not None:
             _CAPACITY = max(int(capacity), 1)
-        if jsonl is not None:
-            _JSONL_PATH = jsonl
     _ENABLED = True
 
 
@@ -219,23 +223,28 @@ def enabled() -> bool:
     return _ENABLED
 
 
+def active() -> bool:
+    """Whether a span would be written anywhere: the ring is on, or a
+    profiler session is collecting annotations."""
+    return _ENABLED or _Annotation.is_enabled()
+
+
 def reset():
     """Drop all recorded spans and restore defaults (tests)."""
-    global _SPANS, _ENABLED, _SAMPLE, _SAMPLE_ACC, _CAPACITY, _JSONL_PATH
+    global _SPANS, _ENABLED, _SAMPLE, _SAMPLE_ACC, _CAPACITY
     with _LOCK:
         _SPANS = []
         _SAMPLE_ACC = 0.0
         _SAMPLE = 1.0
         _CAPACITY = _DEFAULT_CAPACITY
-        _JSONL_PATH = None
     _ENABLED = False
     _LOCAL.stack = []
+    _LOCAL.ctx = None
 
 
 def maybe_enable_from_env():
     """Honor PADDLE_TPU_TRACE: '1'/'true'/'on' → full tracing, a float
-    like '0.1' → head sampling at that rate, '0' → leave off.
-    PADDLE_TPU_TRACE_JSONL names the live JSONL sink."""
+    like '0.1' → head sampling at that rate, '0' → leave off."""
     raw = os.environ.get("PADDLE_TPU_TRACE", "").strip().lower()
     if not raw:
         return
@@ -250,8 +259,7 @@ def maybe_enable_from_env():
         except ValueError:
             return
     if sample and sample > 0.0:
-        enable(sample=sample,
-               jsonl=os.environ.get("PADDLE_TPU_TRACE_JSONL") or None)
+        enable(sample=sample)
 
 
 # --- span creation -----------------------------------------------------------
@@ -263,10 +271,22 @@ def current_span():
     return st[-1] if st else _NULL
 
 
-def start_span(name: str, parent=None, attrs: Optional[Dict] = None):
-    """Start a span without touching the context stack — for handles
+def owner_span():
+    """The ring span of this thread's innermost live `span()`, looking
+    past its phases (or a null span): where code inside a phase puts
+    what it learned about the whole."""
+    ctx = getattr(_LOCAL, "ctx", None)
+    while ctx is not None and ctx.is_phase:
+        ctx = ctx.outer
+    return ctx.sp if ctx is not None else _NULL
+
+
+def start_span(name: str, parent=None, attrs: Optional[Dict] = None,
+               step: Optional[int] = None, start: Optional[float] = None):
+    """Start a ring span without touching the context stack — for handles
     carried across threads (e.g. a serving request whose children are
-    recorded by the batcher worker). Caller must `.end()` it."""
+    recorded by the batcher worker). Caller must `.end()` it. `step`
+    defaults to the parent's."""
     if not _ENABLED:
         return _NULL
     if parent is None or isinstance(parent, _NullSpan):
@@ -283,39 +303,122 @@ def start_span(name: str, parent=None, attrs: Optional[Dict] = None):
     else:
         trace_id = parent.trace_id
         parent_id = parent.span_id
+        if step is None:
+            step = parent.step
     return Span(name, trace_id, _next_id(), parent_id,
-                time.monotonic(), True, attrs)
+                time.monotonic() if start is None else start, True, attrs,
+                step)
 
 
 class _SpanCtx:
-    __slots__ = ("name", "attrs", "sp")
+    """One live `span()` or `phase()` on this thread: the profiler
+    annotation while a session runs, the ring span while enabled."""
 
-    def __init__(self, name, attrs):
+    __slots__ = ("name", "attrs", "step", "is_phase", "sp", "ann", "outer")
+
+    def __init__(self, name, attrs, step, is_phase=False):
         self.name = name
         self.attrs = attrs
+        self.step = step
+        self.is_phase = is_phase
         self.sp = _NULL
+        self.ann = None
+        self.outer = None
 
-    def __enter__(self):
-        self.sp = start_span(self.name, attrs=self.attrs)
-        if self.sp.sampled:
-            _stack().append(self.sp)
+    def open(self, start=None):
+        self.outer = getattr(_LOCAL, "ctx", None)
+        _LOCAL.ctx = self
+        if _Annotation.is_enabled():
+            # the annotation starts when it is made and ends at __exit__
+            name = _ANNOTATION_PREFIX + self.name
+            self.ann = (_Annotation(name) if self.step is None
+                        else _Annotation(name, step=self.step))
+        # a span inside one that was sampled out (or that opened while the
+        # ring was off) is dropped with it: a trace is whole or absent
+        if _ENABLED and (self.outer is None or self.outer.sp.sampled):
+            self.sp = start_span(self.name, attrs=self.attrs,
+                                 step=self.step, start=start)
+            if self.sp.sampled:
+                _stack().append(self.sp)
         return self.sp
 
-    def __exit__(self, exc_type, exc, tb):
-        if self.sp.sampled:
+    def close(self, exc_type=None, exc=None, end=None):
+        # what is still open inside goes first: the last phase, and
+        # whatever an exception left behind
+        inner = getattr(_LOCAL, "ctx", None)
+        chain = []
+        while inner is not None and inner is not self:
+            chain.append(inner)
+            inner = inner.outer
+        if inner is self:
+            for ctx in chain:
+                ctx._end(exc_type, exc, end)
+            _LOCAL.ctx = self.outer
+        self._end(exc_type, exc, end)
+
+    def _end(self, exc_type, exc, end):
+        sp = self.sp
+        if sp.sampled:
             st = _stack()
-            if st and st[-1] is self.sp:
+            if st and st[-1] is sp:
                 st.pop()
+            elif sp in st:
+                st.remove(sp)
             if exc_type is not None:
-                self.sp.set_attr("error", f"{exc_type.__name__}: {exc}")
-        self.sp.end()
+                sp.set_attr("error", f"{exc_type.__name__}: {exc}")
+            sp.end(end)
+        if self.ann is not None:
+            self.ann.__exit__(None, None, None)
+            self.ann = None
+
+    def __enter__(self):
+        return self.open()
+
+    def __exit__(self, exc_type, exc, tb):
+        self.close(exc_type, exc)
         return False
 
 
-def span(name: str, **attrs):
-    """Context manager: start a span as a child of the current thread
-    context, push it, end+pop on exit (recording any exception)."""
-    return _SpanCtx(name, attrs or None)
+class _OffCtx:
+    """What `span()` hands out while nothing listens."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return _NULL
+
+    def __exit__(self, exc_type, exc, tb):
+        return False
+
+
+_OFF = _OffCtx()
+
+
+def span(name: str, step: Optional[int] = None, **attrs):
+    """Context manager: a span over the enclosed code, child of this
+    thread's innermost live span; yields the ring's Span (a null span
+    unless `enabled()` and sampled). `step` is the id the spans of one
+    executor step share (children inherit it). Keep `name` static: it
+    is the key the readers join on."""
+    if not (_ENABLED or _Annotation.is_enabled()):
+        return _OFF
+    return _SpanCtx(name, attrs or None, step)
+
+
+def phase(name: str):
+    """End the previous phase of this thread's innermost live `span()`
+    and start phase `name` at the same instant, as its child; the span's
+    exit ends the last phase. Spans opened inside a phase are its
+    children. Outside any live span, and while nothing listens, this
+    does nothing."""
+    ctx = getattr(_LOCAL, "ctx", None)
+    if ctx is None:
+        return
+    now = time.monotonic() if _ENABLED else None
+    if ctx.is_phase:
+        ctx.close(end=now)
+    if _ENABLED or _Annotation.is_enabled():
+        _SpanCtx(name, None, None, is_phase=True).open(start=now)
 
 
 def capture_context():
@@ -362,23 +465,24 @@ def adopt(ctx):
 def record_span(name: str, start: float, end: float, parent=None,
                 trace_id: Optional[str] = None,
                 attrs: Optional[Dict] = None):
-    """Create an already-finished span from monotonic timestamps measured
-    by the caller — the retroactive style used by code that times its
-    phases anyway (executor steps, batcher phases, checkpoint io).
-    Returns the span (its span_id can parent further retro spans)."""
+    """Create an already-finished ring span from monotonic timestamps
+    the caller measured anyway (batcher phases, checkpoint io, jax's
+    compile events). Returns the span (its span_id can parent further
+    such spans)."""
     if not _ENABLED:
         return _NULL
+    step = None
     if parent is not None:
         if not parent.sampled:
             return _NULL
-        tid, pid = parent.trace_id, parent.span_id
+        tid, pid, step = parent.trace_id, parent.span_id, parent.step
     elif trace_id is not None:
         tid, pid = trace_id, None
     else:
         if not _sample_root():
             return _NULL
         tid, pid = _next_id(), None
-    sp = Span(name, tid, _next_id(), pid, float(start), True, attrs)
+    sp = Span(name, tid, _next_id(), pid, float(start), True, attrs, step)
     sp.end(end=float(end))
     return sp
 
@@ -429,6 +533,8 @@ def export_chrome_trace(path: str,
     for s in spans:
         tid = tids.setdefault(s["trace_id"], len(tids) + 1)
         args = {"span_id": s["span_id"], "trace_id": s["trace_id"]}
+        if s.get("step") is not None:
+            args["step"] = s["step"]
         args.update(s.get("attrs") or {})
         events.append({
             "name": s["name"], "ph": "X", "pid": pid, "tid": tid,

@@ -202,8 +202,11 @@ def instr_like(name: str) -> bool:
     runtime markers ('TfrtCpuExecutable::Execute',
     'ThunkExecutor::Execute (wait...)') and dispatch wrappers
     ('PjitFunction(f)') with the real instruction events — all of which
-    contain '$', ':', '(', or spaces that no instruction name can."""
-    return _INSTR_LIKE.fullmatch(name) is not None
+    contain '$', ':', '(', or spaces that no instruction name can. The
+    program's own spans (tracing.span: 'pd.step', 'pd.launch', ...) sit
+    on the same host plane and are host time, not instructions."""
+    return (_INSTR_LIKE.fullmatch(name) is not None
+            and not name.startswith("pd."))
 
 
 def aggregate_dir(trace_dir) -> Dict[str, int]:
