@@ -7,11 +7,6 @@ counters can't: *before compile*, which ops will miss, and what
 one-line change fixes it. It dry-runs the real gates — never parallel
 re-implementations:
 
-  pallas   — pallas_conv.ineligible over abstract NHWC/OIHW avals built
-             from the desc shapes (bf16 when the program is
-             amp.decorate'd, since mxu_cast runs before the gate); when
-             the first answer is "dtype" we probe again in bf16 so an
-             AMP suggestion doesn't mask a channels problem behind it.
   quant    — quant.gate_for_op over the quantizable ops' desc avals,
              only when the program is decorated O3 (_quant_mode set):
              which matmul/conv ops will count into quant_fallback_total
@@ -34,97 +29,11 @@ from __future__ import annotations
 _PROBE_BATCH = 8  # stand-in for symbolic -1 dims; gates never read it
 
 
-def _conv_hint(reason, ci, co):
-    return {
-        "disabled": "set PADDLE_TPU_PALLAS_CONV=1 to enable the kernels",
-        "rank": "the Pallas kernels only tile 4-D NCHW convs",
-        "groups": "grouped/depthwise convs keep the lax.conv path; use "
-                  "groups=1 for the MXU kernels",
-        "dtype": "run the program under amp.decorate (bf16 on the MXU "
-                 "datapath) — f32 convs never take the Pallas route",
-        "channels": f"pad channels to a multiple of 128 (Ci={ci}, "
-                    f"Co={co}): the MXU tiles lanes in 128s, so e.g. "
-                    f"Ci={-(-max(ci, 1) // 128) * 128} keeps the kernel "
-                    f"eligible",
-        "attrs": "use symmetric 2-element strides/paddings/dilations "
-                 "(the [top, bottom, left, right] padding form is not "
-                 "tiled)",
-        "geometry": "output must stay >= 1x1, padding < effective "
-                    "kernel, and padded width <= 2048 (the VMEM row "
-                    "budget)",
-    }.get(reason, reason)
-
-
 class _Aval:
     def __init__(self, shape, dtype):
         self.shape = tuple(shape)
         self.ndim = len(shape)
         self.dtype = dtype
-
-
-def _check_pallas_convs(pctx):
-    import jax.numpy as jnp
-
-    from ..ops import pallas_conv
-
-    amp = getattr(pctx.program, "_amp_dtype", None)
-    block = pctx.block
-    per_reason = {}  # reason -> detailed diagnostics emitted so far
-    rollup = {}      # reason -> suppressed count
-    for i, op in enumerate(pctx.ops):
-        if op.type != "conv2d":
-            continue
-        xn = (op.desc.input("Input") or [None])[0]
-        wn = (op.desc.input("Filter") or [None])[0]
-        if not (xn and wn and block.desc.has_var(xn)
-                and block.desc.has_var(wn)):
-            continue
-        xv, wv = block.desc.var(xn), block.desc.var(wn)
-        if (xv.shape is None or wv.shape is None
-                or len(xv.shape) != 4 or len(wv.shape) != 4):
-            continue  # the shapes pass already diagnosed rank problems
-        n, c, h, w = (_PROBE_BATCH if d == -1 else d for d in xv.shape)
-        # mxu_cast has run by the time the gate sees the operands
-        dt = jnp.bfloat16 if amp is not None else jnp.float32
-        x = _Aval((n, h, w, c), dt)
-        wt = _Aval(wv.shape, dt)
-        args = (list(op.attr("strides", [1, 1])),
-                list(op.attr("paddings", [0, 0])),
-                list(op.attr("dilations", [1, 1])),
-                int(op.attr("groups", 1) or 1))
-        reason = pallas_conv.ineligible(x, wt, *args)
-        if reason is None:
-            continue
-        ci, co = wv.shape[1], wv.shape[0]
-        hint = _conv_hint(reason, ci, co)
-        if reason == "dtype":
-            # would bf16 alone fix it, or is a deeper miss hiding behind
-            # the AMP suggestion?
-            deeper = pallas_conv.ineligible(
-                _Aval((n, h, w, c), jnp.bfloat16),
-                _Aval(wv.shape, jnp.bfloat16), *args)
-            if deeper is not None:
-                reason = f"dtype, then {deeper}"
-                hint = (f"{_conv_hint('dtype', ci, co)}; even then: "
-                        f"{_conv_hint(deeper, ci, co)}")
-        seen = per_reason.get(reason, 0)
-        if seen >= 4:
-            # a resnet emits one identical miss per conv — summarize the
-            # tail so the first few carry the detail
-            rollup[reason] = rollup.get(reason, 0) + 1
-            continue
-        per_reason[reason] = seen + 1
-        pctx.emit(
-            "warning", "pallas-conv-fallback",
-            f"will take the lax.conv fallback (reason: {reason}) "
-            f"instead of the tiled MXU Pallas kernels — forward and "
-            f"both grad convs all miss, since they share the gate",
-            op_index=i, var=xn, hint=hint)
-    for reason, n in sorted(rollup.items()):
-        pctx.emit("warning", "pallas-conv-fallback",
-                  f"{n} more conv2d op(s) fall back for the same reason "
-                  f"({reason}) — details suppressed after the first "
-                  f"{per_reason[reason]}")
 
 
 def _quant_hint(reason, op_type, k):
@@ -140,9 +49,11 @@ def _quant_hint(reason, op_type, k):
         "shape": f"contraction depth K={k} must be >= 32 and a multiple "
                  f"of 8 to amortize the scale sweeps on the int8 MXU "
                  f"tile; pad the feature dim",
-        "kernel": "the quantized conv rides the Pallas kernel suite — "
-                  "fix the pallas-conv-fallback diagnosis first and this "
-                  "clears too",
+        "kernel": "the int8 conv kernel tiles channels in 128 lanes: it "
+                  "needs Ci and Co multiples of 128, groups=1, "
+                  "2-element strides/paddings/dilations, padding < "
+                  "effective kernel, padded width <= 2048, and no mesh "
+                  "over more than one device",
         "error_bound": "the trace-time error estimate exceeds "
                        "PADDLE_TPU_QUANT_TOL; raise the tolerance to "
                        "accept the quantization noise",
@@ -472,7 +383,6 @@ def _check_planner(pctx):
 
 
 def run(pctx):
-    _check_pallas_convs(pctx)
     _check_quant(pctx)
     _check_shardings(pctx)
     _check_layout(pctx)

@@ -524,11 +524,6 @@ def _conv_bn_act_lower(ctx, op_, ins):
     if g.fold:
         return _fold_lower(ctx, op_, g, env)
     with _muted_observers():
-        if g.conv is not None and _conv_stats_pallas(ctx, g, env):
-            # whole window went through the Pallas conv+stats kernel with
-            # the bn-apply(+act) kernel as epilogue — nothing left to run
-            _freeze(ctx, env, _out_names(op_))
-            return _collect(op_, env)
         if g.conv is not None:
             ctx.executor._exec_op(ctx, g.conv, env)
         reason = _kernel_ineligible(ctx, g, env)
@@ -555,8 +550,8 @@ def _kernel_ineligible(ctx, g: Group, env) -> Optional[str]:
         return "kernel_is_test"
     mesh = getattr(ctx.program, "_mesh", None)
     if mesh is not None and mesh.size > 1:
-        # same rule as pallas_conv.ineligible: a bare Mosaic call cannot
-        # be partitioned, and batch statistics span the whole batch
+        # XLA cannot partition a bare Mosaic call, and batch statistics
+        # span the whole batch
         return "kernel_mesh"
     xname = _first(g.bn.desc.input("X"))
     x = env.get(xname)
@@ -613,96 +608,6 @@ def _bn_act_pallas(ctx, g: Group, env):
         out = _first(act.desc.output("Out"))
         env[out] = yact2.reshape(x.shape)
         ctx.layouts[out] = layout_mod.NHWC
-
-
-def _conv_stats_pallas(ctx, g: Group, env) -> bool:
-    """Whole-window Pallas path: the conv2d_stats kernel emits the conv
-    output AND its per-channel sum/sum-of-squares while each output row
-    is still in VMEM, then bn_apply normalizes (+act) — the window never
-    re-reads the conv output from HBM to compute batch statistics.
-
-    Returns False with NO side effects when ineligible: the caller's
-    member-by-member ladder takes over (its conv member still picks up
-    the Pallas conv kernel through the ordinary lowering, and its
-    fallback reasons keep counting), so this gate needs no counter of
-    its own. Gated to the same predicate as the conv routing plus the
-    bn-apply blocking (M % 8), training-mode bn, the layout convention
-    on (consumers expect the NHWC tags this writes), and no AMP restore
-    (O1 would hand the bn an f32 conv output — the compose ladder's
-    kernel_dtype case)."""
-    from . import pallas_conv
-    from .common import mxu_cast
-    from .nn_ops import _conv_out_dim, _pair
-    conv, bn, act = g.conv, g.bn, g.act
-    if bn.attr("is_test", False) or not ctx.layout_opt:
-        return False
-    if getattr(ctx, "quant_mode", None):
-        # O3: the member-by-member ladder runs instead, so the conv
-        # member reaches its quantized routing (the bf16 conv+stats
-        # kernel would silently skip quantization for fused convs); the
-        # bn+act epilogue still fuses through _bn_act_pallas
-        return False
-    xname = _first(conv.desc.input("Input"))
-    wname = _first(conv.desc.input("Filter"))
-    if env.get(xname) is None or env.get(wname) is None:
-        return False
-    x = jnp.asarray(env[xname])
-    w = jnp.asarray(env[wname])
-    s = _pair(conv.attr("strides", [1, 1]))
-    p = _pair(conv.attr("paddings", [0, 0]))
-    d = _pair(conv.attr("dilations", [1, 1]))
-    groups = conv.attr("groups", 1) or 1
-    (xc, wc), restore = mxu_cast(ctx, x, w)
-    if restore is not None:
-        return False
-    nhwc_in = ctx.layouts.get(xname) == layout_mod.NHWC
-    x_nhwc = xc if nhwc_in else jnp.transpose(xc, (0, 2, 3, 1))
-    if pallas_conv.ineligible(x_nhwc, wc, s, p, d, groups,
-                              getattr(ctx.program, "_mesh", None)) is not None:
-        return False
-    n = x_nhwc.shape[0]
-    co, _, kh, kw = wc.shape
-    oh = _conv_out_dim(x_nhwc.shape[1], kh, p[0], s[0], d[0])
-    ow = _conv_out_dim(x_nhwc.shape[2], kw, p[1], s[1], d[1])
-    m = n * oh * ow
-    if m < 8 or m % 8 != 0:
-        return False
-
-    pallas_conv.count_hit("fused_conv_bn_act")
-    y, csum, csq = pallas_conv.conv2d_stats(x_nhwc, wc, s, p, d)
-    out_name = _first(conv.desc.output("Output"))
-    env[out_name] = y
-    ctx.layouts[out_name] = layout_mod.NHWC
-    # one-pass variance, clamped like the unfused bf16 batch_norm
-    saved_mean = csum / float(m)
-    saved_var = jnp.maximum(csq / float(m) - saved_mean * saved_mean, 0.0)
-
-    scale = jnp.asarray(env[_first(bn.desc.input("Scale"))])
-    bias = jnp.asarray(env[_first(bn.desc.input("Bias"))])
-    mean = jnp.asarray(env[_first(bn.desc.input("Mean"))])
-    var = jnp.asarray(env[_first(bn.desc.input("Variance"))])
-    eps = float(bn.attr("epsilon", 1e-5))
-    momentum = bn.attr("momentum", 0.9)
-    act_fn = None
-    if act is not None:
-        act_fn = functools.partial(_activations[act.type], a=act)
-    ybn2, yact2 = pallas_conv.bn_apply(
-        y.reshape(-1, co), scale.astype(jnp.float32),
-        bias.astype(jnp.float32), saved_mean, saved_var, eps, act_fn)
-
-    env[_first(bn.desc.output("Y"))] = ybn2.reshape(y.shape)
-    ctx.layouts[_first(bn.desc.output("Y"))] = layout_mod.NHWC
-    env[_first(bn.desc.output("MeanOut"))] = \
-        mean * momentum + saved_mean * (1.0 - momentum)
-    env[_first(bn.desc.output("VarianceOut"))] = \
-        var * momentum + saved_var * (1.0 - momentum)
-    env[_first(bn.desc.output("SavedMean"))] = saved_mean
-    env[_first(bn.desc.output("SavedVariance"))] = saved_var
-    if act is not None:
-        aout = _first(act.desc.output("Out"))
-        env[aout] = yact2.reshape(y.shape)
-        ctx.layouts[aout] = layout_mod.NHWC
-    return True
 
 
 def _bn_act_kernel(x_ref, scale_ref, bias_ref, *refs, eps, act, m_total):

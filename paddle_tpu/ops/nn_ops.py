@@ -19,8 +19,7 @@ import numpy as np
 
 from ..framework.desc import OpDesc
 from ..framework.framework import grad_var_name
-from .registry import (NO_GRAD, generic_grad_lower, infer_grad_shapes, op,
-                       register)
+from .registry import NO_GRAD, infer_grad_shapes, op, register
 from .common import (SelectedRowsVal, in_var, mxu_cast, out_var,
                      same_as_input, set_out, to_np_dtype)
 
@@ -274,18 +273,30 @@ def _conv2d_infer(op_, block):
              _conv_out_dim(w, kw, p[1], s[1], d[1])], xv.dtype)
 
 
+def _lax_conv(x, w, s, p, d, groups):
+    """The one conv call every route's arithmetic is defined by: NHWC
+    activation, OIHW filter handed over as HWIO."""
+    return jax.lax.conv_general_dilated(
+        x, jnp.transpose(w, (2, 3, 1, 0)),
+        window_strides=s, padding=[(p[0], p[0]), (p[1], p[1])],
+        rhs_dilation=d, feature_group_count=groups,
+        dimension_numbers=("NHWC", "HWIO", "NHWC"))
+
+
 @op("conv2d", infer_shape=_conv2d_infer)
 def _conv2d(ctx, op_, ins):
     """Computes in NHWC — the TPU-preferred conv layout (channels on the
-    minor axis feed the MXU directly; measured ~2x over NCHW on v5e).
-    Under the trace-time layout convention (ops/layout.py) the NHWC
-    result is kept and tagged so the whole conv/bn/pool stack runs NHWC
-    with one transpose at each end; with the convention off, the
-    user-visible NCHW layout is restored per conv.
+    minor axis feed the MXU directly). Under the trace-time layout
+    convention (ops/layout.py) the NHWC result is kept and tagged so the
+    whole conv/bn/pool stack runs NHWC with one transpose at each end;
+    with the convention off, the user-visible NCHW layout is restored
+    per conv.
 
-    Eligible shapes (pallas_conv.ineligible is the shared gate) route to
-    the hand-tiled Pallas MXU kernel; the rest keep lax.conv with a
-    reason-labelled pallas_fallback_total counter."""
+    Every float conv is XLA's convolution (`_lax_conv`), on one chip as
+    under a mesh: on a v5e it ran ResNet-50 eleven times faster than the
+    row-per-grid-step Pallas kernels of ops/pallas_conv.py (PERF.md §6,
+    PR 25), so nothing selects between them. Only AMP O3 has a second
+    route: the int8 kernel, behind quant.ineligible_conv."""
     from . import layout as layout_mod
     from . import pallas_conv
     from .. import quant
@@ -300,32 +311,21 @@ def _conv2d(ctx, op_, ins):
     if not nhwc_in:
         x = jnp.transpose(x, (0, 2, 3, 1))
     qmode = getattr(ctx, "quant_mode", None)
-    reason = pallas_conv.ineligible(x, w, s, p, d, groups,
-                                    getattr(ctx.program, "_mesh", None))
-    if reason is None:
-        pallas_conv.count_hit(op_.type)
-        qreason = quant.ineligible_conv(x, w, s, p, d, groups, qmode) \
-            if qmode else None
-        if qmode and qreason is None:
+    out = None
+    if qmode:
+        qreason = quant.ineligible_conv(
+            x, w, s, p, d, groups, qmode,
+            mesh=getattr(ctx.program, "_mesh", None))
+        if qreason is None:
+            pallas_conv.count_hit(op_.type)
             quant.count_hit(op_.type)
-            fname = op_.desc.inputs["Filter"][0]
-            out = quant.qconv2d(x, w, s, p, d, qmode,
-                                pre=quant.prequantized(ctx, fname))
+            out = quant.qconv2d(
+                x, w, s, p, d, qmode,
+                pre=quant.prequantized(ctx, op_.desc.inputs["Filter"][0]))
         else:
-            if qmode:
-                quant.count_fallback(op_.type, qreason)
-            out = pallas_conv.conv2d(x, w, s, p, d)
-    else:
-        pallas_conv.count_fallback(op_.type, reason)
-        if qmode:
-            # the quant conv rides the Pallas kernel suite: no kernel,
-            # no quantization (ineligible_conv's "kernel" prerequisite)
-            quant.count_fallback(op_.type, "kernel")
-        out = jax.lax.conv_general_dilated(
-            x, jnp.transpose(w, (2, 3, 1, 0)),
-            window_strides=s, padding=[(p[0], p[0]), (p[1], p[1])],
-            rhs_dilation=d, feature_group_count=groups,
-            dimension_numbers=("NHWC", "HWIO", "NHWC"))
+            quant.count_fallback(op_.type, qreason)
+    if out is None:
+        out = _lax_conv(x, w, s, p, d, groups)
     if restore is not None:
         out = out.astype(restore)
     if ctx.layout_opt:
@@ -342,11 +342,12 @@ def _depthwise_conv2d(ctx, op_, ins):
 
 @op("conv2d_grad", infer_shape=infer_grad_shapes, grad=NO_GRAD)
 def _conv2d_grad(ctx, op_, ins):
-    """Explicit conv backward: eligible shapes take the Pallas grad-input
-    and grad-filter kernels; the rest defer to generic_grad_lower (vjp of
-    the forward lowering), which re-traces the forward under the SAME
-    eligibility predicate — pallas_call is not differentiable, so the
-    gate must agree in both directions (check_pallas_table pins this).
+    """Explicit conv backward: the input and filter gradients are the
+    two transposes of the forward's `_lax_conv` call on the mxu-cast
+    NHWC operands (conv is linear in each operand, so no forward is
+    computed again), whatever route the forward took. Under AMP O3 that is
+    the straight-through estimator: the int8 forward has no transpose
+    rule, so this lowering cannot defer to generic_grad_lower there.
 
     Layout contract (matches the generic path's tag bookkeeping): the
     Output@GRAD cotangent arrives NHWC-tagged when the layout convention
@@ -355,15 +356,11 @@ def _conv2d_grad(ctx, op_, ins):
     tag_outputs re-tags it from the forward var; Filter@GRAD is always
     canonical OIHW."""
     from . import layout as layout_mod
-    from . import pallas_conv
     douts = ins.get("Output@GRAD")
     if not douts or douts[0] is None:
-        # Zero cotangent (output unused by the loss): emit explicit
-        # zeros. Deferring to generic_grad_lower would jax.vjp the
-        # forward lowering, and for Pallas-eligible shapes that re-trace
-        # hits pl.pallas_call — which has no transpose rule — and crashes
-        # at trace time. zeros_like keeps each grad in its forward var's
-        # current layout and dtype, satisfying the contract above.
+        # Zero cotangent (output unused by the loss): explicit zeros,
+        # each in its forward var's current layout and dtype as the
+        # contract above asks, and no conv in the step for them.
         outs = {}
         for slot, names in op_.desc.outputs.items():
             base = slot[: -len("@GRAD")]
@@ -382,34 +379,27 @@ def _conv2d_grad(ctx, op_, ins):
     x_nhwc_in = ctx.layout_of(op_.desc.inputs["Input"][0]) == layout_mod.NHWC
     (xc, wc), _ = mxu_cast(ctx, x, w)
     x_nhwc = xc if x_nhwc_in else jnp.transpose(xc, (0, 2, 3, 1))
-    reason = pallas_conv.ineligible(x_nhwc, wc, s, p, d, groups,
-                                    getattr(ctx.program, "_mesh", None))
-    if reason is not None:
-        pallas_conv.count_fallback(op_.type, reason)
-        # The forward lowering already counted itself when the forward
-        # graph was traced; mute its counters while the vjp re-traces it,
-        # or every grad fallback double-books the op=conv2d series.
-        with pallas_conv.suppress_counters():
-            return generic_grad_lower(ctx, op_, ins)
-    pallas_conv.count_hit(op_.type)
-    dout = jnp.asarray(ins["Output@GRAD"][0])
-    gname = op_.desc.inputs["Output@GRAD"][0]
-    if ctx.layout_of(gname) != layout_mod.NHWC:
+    dout = jnp.asarray(douts[0])
+    if ctx.layout_of(op_.desc.inputs["Output@GRAD"][0]) != layout_mod.NHWC:
         dout = jnp.transpose(dout, (0, 2, 3, 1))
-    dout = dout.astype(jnp.bfloat16)
+    dout = dout.astype(x_nhwc.dtype)   # the conv's own output dtype
     outs = {}
     if "Input@GRAD" in op_.desc.outputs:
-        dx = pallas_conv.conv2d_grad_input(
-            dout, wc, (x_nhwc.shape[1], x_nhwc.shape[2]), s, p, d,
-            out_dtype=x.dtype)
+        dx, = jax.linear_transpose(
+            lambda a: _lax_conv(a, wc, s, p, d, groups), x_nhwc)(dout)
+        dx = dx.astype(x.dtype)
         if not x_nhwc_in:
             dx = jnp.transpose(dx, (0, 3, 1, 2))
         outs["Input@GRAD"] = [dx]
     if "Filter@GRAD" in op_.desc.outputs:
-        dw = pallas_conv.conv2d_grad_filter(
-            x_nhwc, dout, (wc.shape[2], wc.shape[3]), s, p, d,
-            out_dtype=w.dtype)
-        outs["Filter@GRAD"] = [dw]
+        # rounded to the operands' dtype, then cast to the filter's. A
+        # transpose written out with preferred_element_type=f32 gave
+        # bit-equal gradients and updates at the same rate on the v5e
+        # (XLA keeps the excess precision across the cast; PERF.md §6,
+        # PR 25), so the shorter form stays
+        dw, = jax.linear_transpose(
+            lambda b: _lax_conv(x_nhwc, b, s, p, d, groups), wc)(dout)
+        outs["Filter@GRAD"] = [dw.astype(w.dtype)]
     return outs
 
 

@@ -1,8 +1,7 @@
 """Tiled MXU Pallas kernels for conv2d forward / grad-input / grad-filter
-(the kernel phase of the MFU campaign: the scheduling levers are landed
-and the plateau is per kernel; /opt/skills/guides/pallas_guide.md
-patterns, ops/pallas_attention.py and the fusion bn+act kernel as the
-in-repo templates).
+(/opt/skills/guides/pallas_guide.md patterns, ops/pallas_attention.py
+and the fusion bn+act kernel as the in-repo templates). Only the int8
+forward is routed: see "Routing" below before reaching for the rest.
 
 Tiling: NHWC operands, bf16 on the MXU datapath with f32 VMEM
 accumulation (preferred_element_type), channels in 128-lane tiles. The
@@ -36,32 +35,36 @@ largest of {8, 4, 2, 1} that divides OH and fits the VMEM row budget.
 `conv2d_q8` is the forward kernel on int8 operands (quant.py's O3
 routing): int8 x/w tiles, int32 VMEM accumulation, and the per-channel
 dequantization vector applied to the output row while it is still in
-VMEM — the MXU runs int8 dots at twice the bf16 rate, which is where
-the O3 images/sec over O2 comes from.
+VMEM. It walks the same row-per-step grid as the bf16 kernels and has
+not been priced on the chip (PERF.md §7).
 
 `conv2d_stats` is the forward kernel with the Co tile as the *outermost*
-grid dim and per-channel sum/sum-of-squares carried in VMEM scratch: the
-conv->bn->act training window (ops/fusion.py) gets batch statistics for
-free while the output row is still in VMEM, then `bn_apply` normalizes
-(+activation) in one more sweep — the window never re-reads the conv
-output from HBM to compute statistics.
+grid dim and per-channel sum/sum-of-squares carried in VMEM scratch:
+batch statistics while the output row is still in VMEM, then `bn_apply`
+normalizes (+activation) in one more sweep, so a conv->bn->act window
+never re-reads the conv output from HBM to compute statistics.
 
-Eligibility is one shared predicate (`ineligible`) for forward AND
-backward: the generated grad path vjp's the forward lowering
-(registry.generic_grad_lower) and pallas_call is not differentiable, so
-the forward may only take the Pallas route when the grad lowering will
-too. Unsupported combinations fall back to lax.conv with a
-reason-labelled `pallas_fallback_total{op,reason}` counter (mirroring
-fusion_fallback_total), never an error. On CPU (the test mesh) the
-kernels run under the Pallas interpreter — same code path, no Mosaic
+Routing (PR 25). On a v5e these kernels held ResNet-50 bs256 at 1.26 %
+of peak and XLA's convolution ran the same cell eleven times faster
+(PERF.md §6): a grid step here does at most KW dots of [OW <= 112, 128] x
+[128, 128] and pays the pipeline's per-step cost whatever it computes.
+So no bf16 conv lowers to them: `conv2d`, `conv2d_stats`,
+`conv2d_grad_input`, `conv2d_grad_filter` and `bn_apply` stay for their
+parity and described-v5e compile tests until a `simplicity` PR deletes
+them with those tests (PERF.md §7), and ops/nn_ops.py lowers every float
+conv and its backward to lax.conv_general_dilated. Only `conv2d_q8`
+is dispatched (AMP O3, through quant.qconv2d), `ineligible` is its
+tiling gate (quant.ineligible_conv reports a miss as reason "kernel"),
+and KERNELS lists that dispatch for tools/check_registry.py. A conv
+kernel written later earns a route by beating XLA's conv on the chip for
+a shape, and its predicate is then on that shape. On CPU (the test mesh)
+the kernels run under the Pallas interpreter — same code path, no Mosaic
 compile — so parity gates run under JAX_PLATFORMS=cpu.
 """
 
 from __future__ import annotations
 
-import contextlib
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -73,10 +76,13 @@ __all__ = [
     "FALLBACK_REASONS", "KERNELS", "PALLAS_CONV", "bn_apply", "conv2d",
     "conv2d_grad_filter", "conv2d_grad_input", "conv2d_q8",
     "conv2d_stats", "count_fallback", "count_hit", "ineligible",
-    "suppress_counters", "supports",
+    "supports",
 ]
 
-PALLAS_CONV = os.environ.get("PADDLE_TPU_PALLAS_CONV", "1") == "1"
+# Read by nothing: the switch it was is gone with the route it switched.
+# tests/benchmark/test_run_cpu.py (the benchmark's file, not this
+# module's to edit) still setattr's it, which needs the name to exist.
+PALLAS_CONV = True
 
 _LANE = 128
 
@@ -84,8 +90,7 @@ _LANE = 128
 # a reason string produced but not listed here would ship an unlabelled
 # fallback counter).
 FALLBACK_REASONS = frozenset(
-    {"disabled", "mesh", "rank", "groups", "dtype", "channels", "attrs",
-     "geometry"})
+    {"mesh", "rank", "groups", "dtype", "channels", "attrs", "geometry"})
 
 # VMEM width budget: each grid step keeps a [Wp, 128] bf16 input row, an
 # [OW, 128] f32 accumulator and an [OW, 128] output row resident (double
@@ -98,21 +103,17 @@ _MAX_W = 2048
 
 
 def ineligible(x, w, strides, paddings, dilations, groups=1, mesh=None):
-    """None when the Pallas kernels apply, else the fallback reason.
+    """None when the kernels' tiling applies, else the reason.
 
-    `x` is the NHWC operand *post mxu_cast* (AMP O1/O2 convs are bf16 by
-    here; a plain f32 conv reads "dtype"), `w` the OIHW filter. The
-    predicate is shared verbatim by forward and grad routing — see the
-    module docstring for why they must agree — so it also encodes the
-    grad-input geometry: transposed-conv padding stays non-negative iff
-    p <= (K-1)*d per spatial dim. `mesh` is the program's SPMD mesh: XLA
-    cannot partition a Mosaic custom call (it would gather every operand
-    and run the whole conv on each device), and these kernels are not
-    wrapped in shard_map, so a step partitioned over more than one
-    device keeps lax.conv, which GSPMD does partition.
+    `x` is the NHWC operand *post mxu_cast* (AMP convs are bf16 by here;
+    a plain f32 conv reads "dtype"), `w` the OIHW filter. The geometry
+    rules also cover grad-input through the forward kernel:
+    transposed-conv padding stays non-negative iff p <= (K-1)*d per
+    spatial dim. `mesh` is the program's SPMD mesh: XLA cannot partition
+    a Mosaic custom call (it would gather every operand and run the
+    whole conv on each device), and these kernels are not wrapped in
+    shard_map, so a step partitioned over more than one device declines.
     """
-    if not PALLAS_CONV:
-        return "disabled"
     if mesh is not None and mesh.size > 1:
         return "mesh"
     if getattr(x, "ndim", 0) != 4 or getattr(w, "ndim", 0) != 4:
@@ -152,43 +153,21 @@ def supports(x, w, strides, paddings, dilations, groups=1,
                       mesh) is None
 
 
-_SUPPRESS_COUNTERS = False
-
-
-@contextlib.contextmanager
-def suppress_counters():
-    """Silence count_hit/count_fallback on this thread of lowering:
-    generic_grad_lower's vjp re-traces the forward lowering, which would
-    book a second pallas_fallback_total/pallas_kernel_total sample for a
-    forward op that already counted itself when the forward graph was
-    traced — inflating the coverage-trending series."""
-    global _SUPPRESS_COUNTERS
-    prev = _SUPPRESS_COUNTERS
-    _SUPPRESS_COUNTERS = True
-    try:
-        yield
-    finally:
-        _SUPPRESS_COUNTERS = prev
-
-
 def count_fallback(op: str, reason: str):
-    if _SUPPRESS_COUNTERS:
-        return
     from .. import telemetry
     telemetry.counter(
         "pallas_fallback_total",
-        "lowerings that fell back from a Pallas kernel to the XLA path "
-        "(lax.conv, einsum attention), by op and gating reason",
+        "lowerings that declined a Pallas kernel for the XLA path (flash "
+        "attention to einsum attention), by op and gating reason",
         labels=("op", "reason")).labels(op=op, reason=reason).inc()
 
 
 def count_hit(op: str):
-    if _SUPPRESS_COUNTERS:
-        return
     from .. import telemetry
     telemetry.counter(
         "pallas_kernel_total",
-        "conv lowerings served by the Pallas kernel suite, by op",
+        "conv lowerings served by a kernel of this suite (conv2d_q8 "
+        "under AMP O3; no bf16 conv since PR 25), by op",
         labels=("op",)).labels(op=op).inc()
 
 
@@ -470,8 +449,8 @@ def conv2d_q8(x, w, strides, paddings, dilations, dq, out_dtype=None):
     dq f32 [Co] the combined activation*weight dequant scales
     (quant.qconv2d builds them). int32 VMEM accumulation, dequantized to
     `out_dtype` (default bf16) on the output row. Caller must have
-    passed quant.ineligible_conv — which requires the `ineligible` gate
-    here, so the bf16 grad kernels keep agreeing with the route."""
+    passed quant.ineligible_conv, which requires the `ineligible` gate
+    here."""
     ph, pw = paddings
     return _conv_call(x, jnp.transpose(w, (2, 3, 1, 0)), strides,
                       dilations, ((ph, ph), (pw, pw)),
@@ -573,13 +552,13 @@ def bn_apply(x2, scale, bias, mean, var, eps, act_fn):
     return outs[0], None
 
 
-# Dispatch table: which registered op types route through this suite, and
-# with which kernels. check_pallas_table pins it against ops/registry.py
-# and fusion.CONV_OPS — an op listed here but not dispatched (or vice
-# versa) silently loses the kernel, so the lint fails instead.
+# Dispatch table: the registered op types whose lowering can reach a
+# kernel of this suite, and which. check_pallas_table pins it against
+# ops/registry.py and quant.QUANT_OPS: a conv op that quantizes but is
+# not listed (or the reverse) is a route nobody audits, and a `_grad`
+# entry would claim a backward kernel where conv2d_grad only transposes
+# the lax conv.
 KERNELS = {
-    "conv2d": (conv2d, conv2d_stats),
-    "depthwise_conv2d": (conv2d,),        # groups gate: always falls back
-    "conv2d_grad": (conv2d_grad_input, conv2d_grad_filter),
-    "depthwise_conv2d_grad": (conv2d_grad_input, conv2d_grad_filter),
+    "conv2d": (conv2d_q8,),
+    "depthwise_conv2d": (conv2d_q8,),     # groups gate: always declines
 }

@@ -212,7 +212,7 @@ def generic_grad_lower(ctx, op, ins):
     primals = [fwd_ins[s][i] for s, i in diff_paths]
     # the vjp re-traces the forward lowering, which would book a second
     # quant hit/fallback sample for an op that already counted itself on
-    # the forward trace (pallas_conv call sites suppress their own)
+    # the forward trace
     from .. import quant
     with quant.suppress_counters():
         out_vals, vjp_fn = jax.vjp(fwd_fn, primals)

@@ -22,7 +22,7 @@ matmul/conv lowerings route eligible compute through this module:
     would silently produce zero weight gradients;
   * eligibility is a trace-time gate (`ineligible_matmul` /
     `ineligible_conv`) with counted per-reason fallbacks
-    (quant_fallback_total{op,reason}), mirroring pallas_conv's
+    (quant_fallback_total{op,reason}), mirroring attention's
     pallas_fallback_total discipline — including a quantization
     error-bound check against PADDLE_TPU_QUANT_TOL;
   * serving (`ServingEngine(quantize="int8")`) pre-quantizes persistable
@@ -33,7 +33,7 @@ matmul/conv lowerings route eligible compute through this module:
 
 Gate-off story: with PADDLE_TPU_QUANT=0 every gate returns "disabled",
 the lowerings take their plain O2 route, and O3 numerics equal O2
-bitwise — the same contract as PADDLE_TPU_PALLAS_CONV=0.
+bitwise.
 
 Error model for the trace-time bound: symmetric uniform quantization
 adds relative noise of RMS step/sqrt(12) per operand element (int8:
@@ -166,12 +166,14 @@ def ineligible_matmul(x, y, mode="int8"):
 
 
 def ineligible_conv(x, w, strides, paddings, dilations, groups=1,
-                    mode="int8"):
+                    mode="int8", mesh=None):
     """None when the quantized conv applies (NHWC x, OIHW w, both
-    post-mxu_cast), else the reason. The int8 conv runs on the Pallas
-    kernel suite, so pallas_conv.ineligible is a hard prerequisite —
-    the explicit conv2d_grad lowering and the vjp fallback must keep
-    agreeing with the forward route (same contract as the bf16 path)."""
+    post-mxu_cast), else the reason. The int8 conv is the Pallas kernel
+    pallas_conv.conv2d_q8, so that kernel's tiling gate
+    (pallas_conv.ineligible: 128-lane channels, groups 1, the VMEM row
+    budget, no mesh over more than one device) is a hard prerequisite,
+    reason "kernel". The backward never meets this gate: conv2d_grad
+    transposes the bf16 lax conv whatever the forward ran."""
     if not QUANT:
         return "disabled"
     if mode not in _QMAX:
@@ -180,7 +182,7 @@ def ineligible_conv(x, w, strides, paddings, dilations, groups=1,
         return "mode"    # the Pallas quant conv kernel is int8-only
     from .ops import pallas_conv
     if pallas_conv.ineligible(x, w, strides, paddings, dilations,
-                              groups) is not None:
+                              groups, mesh) is not None:
         return "kernel"
     co, ci, kh, kw = w.shape
     if error_estimate(ci * kh * kw, mode) > QUANT_TOL:
@@ -393,9 +395,9 @@ def _make_qconv(strides, paddings, dilations, pre):
 
     def bwd(res, g):
         # straight-through via the bf16 reference conv's vjp. The
-        # explicit conv2d_grad lowering normally shortcuts this with the
-        # Pallas grad kernels; this path exists for direct jax.grad
-        # through the lowering (preflight probes, fused windows).
+        # explicit conv2d_grad lowering does the same without meeting
+        # this rule; it exists for direct jax.grad through the lowering
+        # (preflight probes, fused windows).
         x, w = res
         s, p, d = strides, paddings, dilations
 

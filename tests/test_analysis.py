@@ -166,20 +166,36 @@ class TestPreflightPass:
         report = analyze_program(main, feeds=[], fetches=[])
         assert _by_code(report, "sharding-unknown-axis")
 
-    def test_conv_channel_miss_gets_pallas_hint(self):
-        main = fluid.Program()
-        with fluid.program_guard(main, fluid.Program()):
-            img = fluid.layers.data(name="img", shape=[64, 16, 16])
-            out = fluid.layers.conv2d(input=img, num_filters=128,
-                                      filter_size=3, padding=1)
-        main._amp_dtype = "bfloat16"  # bf16 datapath: dtype gate passes
-        report = analyze_program(main, feeds=["img"], fetches=[out.name])
-        warns = _by_code(report, "pallas-conv-fallback")
-        assert warns, report.format(show_info=True)
+    def test_conv_channel_miss_is_a_quant_matter_only(self):
+        """A 64-channel bf16 conv is XLA's convolution like every other
+        (PR 25): no route is missed and preflight advises nothing. Under
+        O3 the same conv does miss one, the int8 kernel's 128-lane
+        tiling, and the quant pass says what that kernel needs."""
+        def conv_report(**tags):
+            main = fluid.Program()
+            with fluid.program_guard(main, fluid.Program()):
+                img = fluid.layers.data(name="img", shape=[64, 16, 16])
+                out = fluid.layers.conv2d(input=img, num_filters=128,
+                                          filter_size=3, padding=1)
+            main._amp_dtype = "bfloat16"
+            for k, v in tags.items():
+                setattr(main, k, v)
+            return analyze_program(main, feeds=["img"],
+                                   fetches=[out.name])
+
+        report = conv_report()
+        assert not _by_code(report, "pallas-conv-fallback")
+        assert not _by_code(report, "quant-fallback")
+        assert not report.errors and not report.warnings, \
+            report.format(show_info=True)
+
+        report = conv_report(_amp_level="O3", _quant_mode="int8")
+        warns = _by_code(report, "quant-fallback")
+        assert warns and not _by_code(report, "pallas-conv-fallback")
         assert not report.errors  # a fast-path miss is advisory, not fatal
         d = warns[0]
-        assert d.op_index is not None
-        assert "multiple of 128" in (d.hint or "") and "Ci=64" in d.hint
+        assert "reason: kernel" in d.message and d.op_index is not None
+        assert "multiples of 128" in (d.hint or "")
 
     def test_quant_preflight_flags_shallow_matmul(self):
         """ISSUE 20 satellite: planted defect — a K=24 fc under O3

@@ -1,7 +1,7 @@
-"""Pallas conv kernel suite (ops/pallas_conv.py, ISSUE 11): parity
-gates for every kernel, the eligibility gate's reason labels, the
-PADDLE_TPU_PALLAS_CONV=0 escape hatch, and the CPU scan+grad-conv
-warning.
+"""Pallas conv kernel suite (ops/pallas_conv.py, ISSUE 11) and the conv
+route (PR 25): parity gates for every kernel, the tiling gate's reason
+labels, every float conv and its backward as XLA's convolution whatever
+the shape, and the CPU scan+grad-conv warning.
 
 Each kernel ships a parity gate against the lax.conv reference it
 replaces: forward/grad-input/grad-filter vs lax.conv_general_dilated /
@@ -12,6 +12,10 @@ kernels run under Pallas interpret mode, so this whole file is tier-1
 under JAX_PLATFORMS=cpu and re-runs compiled on a real TPU unchanged.
 """
 
+import collections
+import glob
+import os
+import types
 import warnings
 
 import numpy as np
@@ -24,7 +28,8 @@ import paddle_tpu as fluid
 from paddle_tpu import executor as em
 from paddle_tpu import telemetry
 from paddle_tpu.framework import unique_name
-from paddle_tpu.ops import pallas_conv
+from paddle_tpu.ops import layout as layout_mod
+from paddle_tpu.ops import pallas_conv, registry
 
 
 @pytest.fixture(autouse=True)
@@ -32,17 +37,6 @@ def _fresh_telemetry():
     telemetry.reset()
     yield
     telemetry.reset()
-
-
-def _with_pallas(on, fn, *args, **kw):
-    """Run fn under PALLAS_CONV=on. Callers build a FRESH program inside
-    fn — the jit and plan caches key on program identity."""
-    old = pallas_conv.PALLAS_CONV
-    pallas_conv.PALLAS_CONV = on
-    try:
-        return fn(*args, **kw)
-    finally:
-        pallas_conv.PALLAS_CONV = old
 
 
 def _series(name, label=None):
@@ -155,8 +149,6 @@ def test_ineligible_reasons():
     args = ((1, 1), (1, 1), (1, 1))
     assert pallas_conv.ineligible(x, w, *args) is None
     assert pallas_conv.supports(x, w, *args)
-    assert _with_pallas(
-        False, pallas_conv.ineligible, x, w, *args) == "disabled"
     assert pallas_conv.ineligible(x[0], w, *args) == "rank"
     assert pallas_conv.ineligible(x, w, *args, groups=2) == "groups"
     assert pallas_conv.ineligible(
@@ -178,60 +170,121 @@ def test_ineligible_reasons():
     # failing Mosaic compilation at run time
     wide = jax.ShapeDtypeStruct((1, 6, 4096, 128), jnp.bfloat16)
     assert pallas_conv.ineligible(wide, w, *args) == "geometry"
-    for reason in ("disabled", "rank", "groups", "dtype", "channels",
-                   "attrs", "geometry"):
-        assert reason in pallas_conv.FALLBACK_REASONS
+    assert pallas_conv.FALLBACK_REASONS == {
+        "mesh", "rank", "groups", "dtype", "channels", "attrs", "geometry"}
 
 
-def test_zero_cotangent_returns_zeros_without_retrace():
-    """Output@GRAD absent (conv output unused by the loss): the grad
-    lowering must emit explicit zero grads in the forward vars' shapes
-    and dtypes — delegating to the generic vjp would re-trace the
-    Pallas-eligible forward into pl.pallas_call, which has no transpose
-    rule, and crash at trace time."""
+def _grad_op(outputs=("Input@GRAD", "Filter@GRAD"), s=(1, 1), p=(1, 1),
+             d=(1, 1)):
     from paddle_tpu.framework.desc import OpDesc
     from paddle_tpu.framework.framework import Operator
-    from paddle_tpu.ops import registry
 
-    x, wt = _operands(6, 6, 3, 3)   # Pallas-eligible bf16 128-lane shape
     op_ = Operator.__new__(Operator)
     op_.block = None
     op_.desc = OpDesc(
         type="conv2d_grad",
         inputs={"Input": ["x"], "Filter": ["w"], "Output": ["y"],
                 "Output@GRAD": ["y@GRAD"]},
-        outputs={"Input@GRAD": ["x@GRAD"], "Filter@GRAD": ["w@GRAD"]},
-        attrs={"strides": [1, 1], "paddings": [1, 1],
-               "dilations": [1, 1], "groups": 1})
+        outputs={o: [o.replace("Input", "x").replace("Filter", "w")]
+                 for o in outputs},
+        attrs={"strides": list(s), "paddings": list(p),
+               "dilations": list(d), "groups": 1})
+    return op_
+
+
+def test_zero_cotangent_returns_zeros():
+    """Output@GRAD absent (conv output unused by the loss): the grad
+    lowering emits explicit zero grads in the forward vars' shapes and
+    dtypes, and books nothing."""
+    x, wt = _operands(6, 6, 3, 3)
     outs = registry.get("conv2d_grad").lower(
-        None, op_, {"Input": [x], "Filter": [wt], "Output@GRAD": [None]})
+        None, _grad_op(),
+        {"Input": [x], "Filter": [wt], "Output@GRAD": [None]})
     dx, = outs["Input@GRAD"]
     dw, = outs["Filter@GRAD"]
     assert dx.shape == x.shape and dx.dtype == x.dtype
     assert dw.shape == wt.shape and dw.dtype == wt.dtype
     assert not np.asarray(dx, np.float32).any()
     assert not np.asarray(dw, np.float32).any()
-    # a zero grad is not a kernel decision: neither counter moves
     assert _series("pallas_kernel_total") == 0
     assert _series("pallas_fallback_total") == 0
 
 
-def test_suppress_counters_context():
-    with pallas_conv.suppress_counters():
-        pallas_conv.count_hit("conv2d")
-        pallas_conv.count_fallback("conv2d", "dtype")
-    assert _series("pallas_kernel_total") == 0
-    assert _series("pallas_fallback_total") == 0
-    pallas_conv.count_fallback("conv2d", "dtype")
-    assert _series("pallas_fallback_total") == 1
+@pytest.mark.parametrize("nhwc", [True, False], ids=["nhwc", "nchw"])
+@pytest.mark.parametrize("case", CASES)
+def test_explicit_backward_matches_float32_autodiff(case, nhwc):
+    """conv2d_grad under AMP O2 (bf16 operands, f32 master weights) is
+    the two transposes of the lax conv: within bf16 tolerance of
+    jax.grad of the plain float32 conv, in Input's layout and dtype and
+    canonical OIHW for the filter, for every geometry of CASES with the
+    layout convention on (NHWC-tagged Input and cotangent) and off."""
+    h, w, kh, kw, s, p, d = case
+    rng = np.random.default_rng(5)
+    x = jnp.asarray(rng.standard_normal((2, h, w, 128)), jnp.float32)
+    wt = jnp.asarray(rng.standard_normal((128, 128, kh, kw)) * 0.1,
+                     jnp.float32)
+    ref, vjp = jax.vjp(lambda a, b: _ref_fwd(a, b, s, p, d), x, wt)
+    ct = jnp.asarray(rng.standard_normal(ref.shape), jnp.bfloat16)
+    dx_ref, dw_ref = vjp(ct.astype(jnp.float32))
+
+    ctx = types.SimpleNamespace(
+        amp_dtype="bfloat16", amp_level="O2",
+        layout_of=lambda name: layout_mod.NHWC if nhwc else None)
+    to_layout = (lambda a: a) if nhwc else \
+        (lambda a: jnp.transpose(a, (0, 3, 1, 2)))
+    outs = registry.get("conv2d_grad").lower(
+        ctx, _grad_op(s=s, p=p, d=d),
+        {"Input": [to_layout(x)], "Filter": [wt],
+         "Output@GRAD": [to_layout(ct)]})
+    dx, = outs["Input@GRAD"]
+    dw, = outs["Filter@GRAD"]
+    assert dx.dtype == jnp.float32 and dw.dtype == jnp.float32
+    assert dx.shape == to_layout(x).shape and dw.shape == wt.shape
+    for got, want in ((dx, to_layout(dx_ref)), (dw, dw_ref)):
+        err = np.linalg.norm(np.asarray(got - want)) / \
+            np.linalg.norm(np.asarray(want))
+        assert err < 1e-2, err
+    # only the slots the desc asks for are computed
+    only_w = registry.get("conv2d_grad").lower(
+        ctx, _grad_op(outputs=("Filter@GRAD",), s=s, p=p, d=d),
+        {"Input": [to_layout(x)], "Filter": [wt],
+         "Output@GRAD": [to_layout(ct)]})
+    assert list(only_w) == ["Filter@GRAD"]
+    np.testing.assert_array_equal(np.asarray(only_w["Filter@GRAD"][0]),
+                                  np.asarray(dw))
 
 
-# --- through-program: routing, counters, escape hatch ------------------
+def test_no_switch_selects_a_conv_route():
+    """PADDLE_TPU_PALLAS_CONV is read nowhere, and the module attribute
+    the benchmark's CPU test still patches exists and changes nothing."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sources = glob.glob(os.path.join(root, "paddle_tpu", "**", "*.py"),
+                        recursive=True)
+    sources += glob.glob(os.path.join(root, "*.py"))
+    sources += glob.glob(os.path.join(root, "tools", "*.py"))
+    sources += glob.glob(os.path.join(root, "benchmarks", "**", "*.py"),
+                         recursive=True)
+    assert len(sources) > 100
+    readers = [f for f in sources
+               if "PADDLE_TPU_PALLAS_CONV" in open(f).read()]
+    assert not readers, readers
+    x = jnp.zeros((2, 6, 6, 128), jnp.bfloat16)
+    w = jnp.zeros((128, 128, 3, 3), jnp.bfloat16)
+    args = ((1, 1), (1, 1), (1, 1))
+    assert pallas_conv.PALLAS_CONV is True
+    pallas_conv.PALLAS_CONV = False
+    try:
+        assert pallas_conv.ineligible(x, w, *args) is None
+    finally:
+        pallas_conv.PALLAS_CONV = True
 
-def _train_bf16_convnet(steps=3):
-    """AMP O2 conv(C=128)+bn(relu)+pool+fc+SGD: the bf16 NHWC shape the
-    Pallas suite targets — forward via the fused conv->bn->act window,
-    backward via the conv2d_grad dispatch."""
+
+# --- through-program: the route, its census, counters -------------------
+
+def _bf16_convnet(level="O2"):
+    """AMP conv3x3(C=128)+bn(relu)+conv1x1+bn(relu)+pool+fc+SGD: the
+    bf16 NHWC 128-lane shapes the Pallas suite was built for, forward
+    inside fused conv->bn->act windows."""
     unique_name.switch()
     main, startup = fluid.Program(), fluid.Program()
     main.random_seed = startup.random_seed = 11
@@ -242,6 +295,9 @@ def _train_bf16_convnet(steps=3):
         c = fluid.layers.conv2d(input=img, num_filters=128, filter_size=3,
                                 padding=1, bias_attr=False)
         b = fluid.layers.batch_norm(input=c, act="relu")
+        c = fluid.layers.conv2d(input=b, num_filters=128, filter_size=1,
+                                bias_attr=False)
+        b = fluid.layers.batch_norm(input=c, act="relu")
         gp = fluid.layers.pool2d(input=b, global_pooling=True,
                                  pool_type="avg")
         logits = fluid.layers.fc(input=gp, size=5)
@@ -249,83 +305,111 @@ def _train_bf16_convnet(steps=3):
             fluid.layers.softmax_with_cross_entropy(logits, label))
         fluid.optimizer.SGD(learning_rate=0.05).minimize(
             loss, startup_program=startup)
-    fluid.amp.enable(main, level="O2")
-    exe = fluid.Executor(fluid.CPUPlace())
+    fluid.amp.enable(main, level=level)
+    return main, startup, loss
+
+
+def _convnet_feeds(k):
     rng = np.random.default_rng(6)
-    losses = []
+    return [{"img": rng.standard_normal((4, 128, 6, 6)).astype(np.float32),
+             "label": rng.integers(0, 5, (4, 1)).astype(np.int64)}
+            for _ in range(k)]
+
+
+def _train_bf16_convnet(steps=3, level="O2"):
+    main, startup, loss = _bf16_convnet(level)
+    exe = fluid.Executor(fluid.CPUPlace())
+    with em.scope_guard(em.Scope()):
+        exe.run(startup)
+        return [float(np.ravel(exe.run(main, feed=f,
+                                       fetch_list=[loss])[0])[0])
+                for f in _convnet_feeds(steps)]
+
+
+def _step_census(level):
+    """Primitives of the traced train step, pallas_calls by their name."""
+    def walk(jaxpr, acc):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                acc["pallas_call:" + e.params["name"]] += 1
+                continue
+            acc[e.primitive.name] += 1
+            for v in e.params.values():
+                for j in (v if isinstance(v, (list, tuple)) else [v]):
+                    inner = getattr(j, "jaxpr", j)
+                    if hasattr(inner, "eqns"):
+                        walk(inner, acc)
+        return acc
+
+    main, startup, loss = _bf16_convnet(level)
+    exe = fluid.Executor(fluid.CPUPlace())
     scope = em.Scope()
     with em.scope_guard(scope):
         exe.run(startup)
-        for _ in range(steps):
-            xv = rng.standard_normal((4, 128, 6, 6)).astype(np.float32)
-            yv = rng.integers(0, 5, (4, 1)).astype(np.int64)
-            out, = exe.run(main, feed={"img": xv, "label": yv},
-                           fetch_list=[loss])
-            losses.append(float(np.ravel(out)[0]))
-    return losses
+        compiled, feed_vals, state_vals, rng = exe._aot_block(
+            main, _convnet_feeds(1)[0], [loss], scope)
+        return walk(jax.make_jaxpr(compiled.fn)(
+            feed_vals, state_vals, np.uint32(rng)).jaxpr,
+            collections.Counter())
 
 
-def test_amp_o2_training_routes_through_pallas():
-    """Gate ON: the forward conv is consumed by the fused conv->bn->act
-    window (hits count as fused_conv_bn_act, not conv2d) and the
-    backward routes through conv2d_grad; losses match the gate-OFF lax
-    path within bf16 tolerance, and OFF counts per-op `disabled`
-    fallbacks with zero kernel hits."""
-    l_on = _with_pallas(True, _train_bf16_convnet)
-    assert _series("pallas_kernel_total", "op=fused_conv_bn_act") > 0
-    assert _series("pallas_kernel_total", "op=conv2d_grad") > 0
+def test_amp_o2_step_is_xla_convs():
+    """An AMP O2 convnet step holds lax's conv three times per conv
+    (forward, grad-input, grad-filter; the first conv's input is data
+    and takes no gradient) and no pallas_call of the conv suite, at the
+    very shapes its gate passes. Fusion's bn+act kernel still runs, and
+    no conv site books a Pallas counter: a route that is not considered
+    cannot fall back."""
+    census = _step_census("O2")
+    assert census["conv_general_dilated"] == 3 * 2 - 1, census
+    kernels = {k for k in census if k.startswith("pallas_call:")}
+    assert kernels == {"pallas_call:bn_act"}, kernels
+    assert census["pallas_call:bn_act"] == 2
+    assert _series("pallas_kernel_total") == 0
+    assert _series("pallas_fallback_total") == 0
+    _train_bf16_convnet(steps=1)
+    assert _series("pallas_kernel_total") == 0
     assert _series("pallas_fallback_total") == 0
 
-    telemetry.reset()
-    l_off = _with_pallas(False, _train_bf16_convnet)
-    assert _series("pallas_kernel_total") == 0
-    assert _series("pallas_fallback_total", "reason=disabled") > 0
-    np.testing.assert_allclose(l_on, l_off, rtol=0, atol=5e-3)
 
-
-def test_gate_off_is_deterministic_old_path():
-    """PADDLE_TPU_PALLAS_CONV=0 must restore the lax path bit-for-bit:
-    two OFF runs from identical seeds are bitwise equal, and every conv
-    family lowering reports reason=disabled (nothing else gates)."""
-    l0 = _with_pallas(False, _train_bf16_convnet)
-    series = telemetry.read_series("pallas_fallback_total")
-    assert series and all("reason=disabled" in k for k in series), series
-    telemetry.reset()
-    l1 = _with_pallas(False, _train_bf16_convnet)
+def test_two_runs_from_one_seed_are_bit_equal():
+    """Nothing but the program and the seed decides the step: two runs
+    are bitwise equal, the second with the benchmark test's leftover
+    switch flipped, and the loss falls."""
+    l0 = _train_bf16_convnet()
+    pallas_conv.PALLAS_CONV = False
+    try:
+        l1 = _train_bf16_convnet()
+    finally:
+        pallas_conv.PALLAS_CONV = True
     assert l0 == l1
+    assert np.isfinite(l0).all() and l0[-1] < l0[0] + 0.5
 
 
-def test_f32_conv_counts_dtype_fallback():
-    """A plain f32 program never reaches the bf16-only kernels: the
-    fallback counter must say WHY (reason=dtype), and the program still
-    runs to completion on the lax path — unsupported is never an
-    error."""
-    unique_name.switch()
-    main, startup = fluid.Program(), fluid.Program()
-    main.random_seed = startup.random_seed = 9
-    with fluid.program_guard(main, startup):
-        img = fluid.layers.data(name="img", shape=[8, 6, 6],
-                                dtype="float32")
-        c = fluid.layers.conv2d(input=img, num_filters=8, filter_size=3,
-                                padding=1, bias_attr=False)
-        loss = fluid.layers.mean(c)
-    exe = fluid.Executor(fluid.CPUPlace())
-    scope = em.Scope()
-    with em.scope_guard(scope):
-        exe.run(startup)
-        out, = exe.run(main, feed={
-            "img": np.ones((2, 8, 6, 6), np.float32)}, fetch_list=[loss])
-    assert np.isfinite(np.asarray(out)).all()
-    assert _series("pallas_fallback_total", "reason=dtype") > 0
-    assert _series("pallas_kernel_total") == 0
+def test_o3_int8_forward_routes_and_its_backward_runs():
+    """AMP O3: each conv's forward is the int8 kernel (conv2d_q8, behind
+    quant.ineligible_conv), its backward the same two lax transposes as
+    under O2 (the int8 forward has no transpose rule), and training
+    tracks O2."""
+    census = _step_census("O3")
+    assert census["pallas_call:conv2d_q8"] == 2, census
+    assert census["conv_general_dilated"] == 2 * 2 - 1, census
+    assert not [k for k in census if k.startswith("pallas_call:conv2d")
+                and k != "pallas_call:conv2d_q8"], census
+    telemetry.reset()
+    l3 = _train_bf16_convnet(level="O3")
+    # booked per trace of the step, and the step is traced more than once
+    hits = _series("pallas_kernel_total", "op=conv2d")
+    assert hits > 0 and hits % 2 == 0
+    assert _series("quant_kernel_total", "op=conv2d") == hits
+    assert _series("pallas_fallback_total") == 0
+    l2 = _train_bf16_convnet(level="O2")
+    np.testing.assert_allclose(l3, l2, rtol=0, atol=0.1)
 
 
-def test_grad_fallback_counts_forward_once():
-    """conv2d_grad's fallback re-traces the forward lowering inside
-    generic_grad_lower; that re-trace must not book a second
-    pallas_fallback_total{op=conv2d} sample on top of the one the
-    forward trace already counted — the coverage-trending series would
-    read 2x."""
+def test_f32_conv_books_no_pallas_counter():
+    """A plain f32 program is XLA's conv like every other: it runs to
+    completion and no conv site books a hit or a fallback."""
     unique_name.switch()
     main, startup = fluid.Program(), fluid.Program()
     main.random_seed = startup.random_seed = 9
@@ -341,19 +425,17 @@ def test_grad_fallback_counts_forward_once():
     scope = em.Scope()
     with em.scope_guard(scope):
         exe.run(startup)
-        exe.run(main, feed={"img": np.ones((2, 8, 6, 6), np.float32)},
-                fetch_list=[loss])
-    series = telemetry.read_series("pallas_fallback_total")
-    fwd = _series("pallas_fallback_total", "op=conv2d,")
-    bwd = _series("pallas_fallback_total", "op=conv2d_grad,")
-    assert fwd == bwd > 0, series
+        out, = exe.run(main, feed={
+            "img": np.ones((2, 8, 6, 6), np.float32)}, fetch_list=[loss])
+    assert np.isfinite(np.asarray(out)).all()
+    assert _series("pallas_fallback_total") == 0
+    assert _series("pallas_kernel_total") == 0
 
 
-def test_depthwise_conv2d_grad_falls_back_by_groups():
-    """groups != 1 is outside the kernel envelope: the explicit
-    depthwise_conv2d_grad lowering must count reason=groups (or dtype
-    for an f32 trace — whichever gate fires first stays labelled) and
-    delegate to the generic vjp, matching central differences."""
+def test_depthwise_conv2d_grad_matches_central_differences():
+    """groups != 1 takes the same explicit backward (the transposes of
+    the grouped lax conv): central differences agree, nothing is
+    counted."""
     from op_test import OpTest
 
     rng = np.random.default_rng(12)
@@ -366,8 +448,7 @@ def test_depthwise_conv2d_grad_falls_back_by_groups():
     t.outputs = {"Output": np.zeros((1, 2, 2, 2), "float32")}
     t.check_grad(["Input", "Filter"], "Output",
                  max_relative_error=0.02)
-    assert _series("pallas_fallback_total",
-                   "op=depthwise_conv2d_grad") > 0
+    assert _series("pallas_fallback_total") == 0
     assert _series("pallas_kernel_total") == 0
 
 
@@ -395,55 +476,22 @@ def _feeds(k=2):
 
 
 def test_fused_window_parity_under_run_steps(monkeypatch):
-    """run_steps (lax.scan window) over the Pallas-routed bf16 net
-    matches per-step dispatch: the fused conv->bn->act + grad kernels
+    """run_steps (lax.scan window) over the bf16 net matches per-step
+    dispatch: the fused conv->bn->act windows and the conv transposes
     trace identically inside the scan body. Tolerance only for the
     scan's f32 reduction-order drift."""
     monkeypatch.setattr(em, "_WARNED_CPU_SCAN_CONV", True)  # mute here
 
-    def run(windowed):
-        unique_name.switch()
-        main, startup = fluid.Program(), fluid.Program()
-        main.random_seed = startup.random_seed = 11
-        with fluid.program_guard(main, startup):
-            img = fluid.layers.data(name="img", shape=[128, 6, 6],
-                                    dtype="float32")
-            label = fluid.layers.data(name="label", shape=[1],
-                                      dtype="int64")
-            c = fluid.layers.conv2d(input=img, num_filters=128,
-                                    filter_size=3, padding=1,
-                                    bias_attr=False)
-            b = fluid.layers.batch_norm(input=c, act="relu")
-            gp = fluid.layers.pool2d(input=b, global_pooling=True,
-                                     pool_type="avg")
-            logits = fluid.layers.fc(input=gp, size=5)
-            loss = fluid.layers.mean(
-                fluid.layers.softmax_with_cross_entropy(logits, label))
-            fluid.optimizer.SGD(learning_rate=0.05).minimize(
-                loss, startup_program=startup)
-        fluid.amp.enable(main, level="O2")
-        exe = fluid.Executor(fluid.CPUPlace())
-        rng = np.random.default_rng(6)
-        feeds = [{"img": rng.standard_normal((4, 128, 6, 6)).astype(
-                      np.float32),
-                  "label": rng.integers(0, 5, (4, 1)).astype(np.int64)}
-                 for _ in range(2)]
-        scope = em.Scope()
-        with em.scope_guard(scope):
-            exe.run(startup)
-            if windowed:
-                out, = exe.run_steps(main, feed_window=feeds,
-                                     fetch_list=[loss],
-                                     fetch_mode="stack")
-                return [float(v) for v in np.ravel(out)]
-            return [float(np.ravel(exe.run(main, feed=f,
-                                           fetch_list=[loss])[0])[0])
-                    for f in feeds]
-
-    seq = run(False)
-    win = run(True)
+    seq = _train_bf16_convnet(steps=2)
+    main, startup, loss = _bf16_convnet()
+    exe = fluid.Executor(fluid.CPUPlace())
+    with em.scope_guard(em.Scope()):
+        exe.run(startup)
+        out, = exe.run_steps(main, feed_window=_convnet_feeds(2),
+                             fetch_list=[loss], fetch_mode="stack")
+    win = [float(v) for v in np.ravel(out)]
     np.testing.assert_allclose(seq, win, rtol=0, atol=5e-3)
-    assert _series("pallas_kernel_total", "op=fused_conv_bn_act") > 0
+    assert _series("pallas_kernel_total") == 0
 
 
 def test_cpu_scan_grad_conv_warns_once(monkeypatch):

@@ -80,27 +80,33 @@ def test_sparse_lint_catches_missing_entry(monkeypatch):
 
 
 def test_pallas_table_consistent():
-    """ISSUE 11 satellite: pallas_conv.KERNELS must agree with the op
-    registry, fusion.CONV_OPS and its own FALLBACK_REASONS — an orphan
-    kernel or a missing grad twin doesn't raise, the dispatch just
-    silently keeps the lax path (or worse, vjp's a pallas_call)."""
+    """pallas_conv.KERNELS must agree with the op registry,
+    quant.QUANT_OPS and its own FALLBACK_REASONS: since PR 25 the one
+    dispatch is the int8 conv under O3, and an orphan entry or a missing
+    one doesn't raise, the route is just never audited."""
     problems = _load_checker().check_pallas_table()
     assert not problems, "; ".join(f"{w}: {m}" for w, m in problems)
 
 
-def test_pallas_lint_catches_missing_grad(monkeypatch):
-    """Sanity: dropping conv2d_grad from KERNELS trips the shared-gate
-    pairing check, and shrinking FALLBACK_REASONS trips the reason
-    audit."""
+def test_pallas_lint_catches_grad_entry_and_missing_op(monkeypatch):
+    """Sanity: a conv2d_grad entry (no backward kernel is dispatched:
+    conv2d_grad transposes the lax conv), a quantizable conv op dropped
+    from KERNELS, and a shrunk FALLBACK_REASONS each trip the lint."""
     from paddle_tpu.ops import pallas_conv
 
     checker = _load_checker()
     orig = pallas_conv.KERNELS
-    kernels = dict(orig)
-    del kernels["conv2d_grad"]
-    monkeypatch.setattr(pallas_conv, "KERNELS", kernels)
+    monkeypatch.setattr(
+        pallas_conv, "KERNELS",
+        dict(orig, conv2d_grad=(pallas_conv.conv2d_grad_filter,)))
     problems = checker.check_pallas_table()
     assert any("conv2d_grad" in m for _, m in problems), problems
+
+    kernels = dict(orig)
+    del kernels["depthwise_conv2d"]
+    monkeypatch.setattr(pallas_conv, "KERNELS", kernels)
+    problems = checker.check_pallas_table()
+    assert any("depthwise_conv2d" in m for _, m in problems), problems
 
     monkeypatch.setattr(pallas_conv, "KERNELS", orig)
     monkeypatch.setattr(pallas_conv, "FALLBACK_REASONS",

@@ -25,10 +25,12 @@ against the optimizer lowerings, the executor's sparse-aware boundary set
 and the fused-bucket types: a missing entry doesn't raise either — the
 gradient silently densifies and the update goes O(table rows).
 
-The Pallas-table lint (ISSUE 11 satellite) pins pallas_conv.KERNELS the
-same way: orphan kernels, conv window kinds without a dispatch entry,
-forward kernels missing their grad twin (the shared-gate/vjp contract),
-and fallback reasons the gate produces but FALLBACK_REASONS omits.
+The Pallas-table lint (ISSUE 11 satellite; PR 25 left one dispatch, the
+int8 conv under O3) pins pallas_conv.KERNELS the same way: orphan
+kernels, a quantizable conv op without a dispatch entry or the reverse,
+a `_grad` entry (the backward transposes the lax conv; no kernel is
+dispatched for it), and fallback reasons the gate produces but
+FALLBACK_REASONS omits.
 
 The quant-table lint (ISSUE 20 satellite) pins quant.QUANT_OPS the same
 way, both directions: every quantizable op must be registered AND its
@@ -274,60 +276,53 @@ def check_emb_cache():
 
 
 def check_pallas_table():
-    """[(where, message), ...] — pin pallas_conv.KERNELS (ISSUE 11)
-    against ops/registry.py and fusion.CONV_OPS. Three silent failure
-    modes: an orphan kernel (dispatched for an op that isn't registered,
-    or not in the fusion window table — the kernel never runs), a
-    registered conv op missing from KERNELS (it silently keeps the lax
-    path), and a fallback reason produced by the gate but absent from
-    FALLBACK_REASONS (an unlabelled counter series). The forward/grad
-    pairing is load-bearing, not stylistic: the generated grad path
-    vjp's the forward lowering and pallas_call is not differentiable, so
-    every dispatched forward MUST have a dispatched grad (and vice
-    versa) sharing the same gate."""
+    """[(where, message), ...] — pin pallas_conv.KERNELS against
+    ops/registry.py and quant.QUANT_OPS. Since PR 25 the only dispatch
+    left is the int8 conv2d_q8 under AMP O3 (every float conv and its
+    backward is lax.conv_general_dilated), so the silent failure modes
+    are: an orphan entry (an op that isn't registered, or whose lowering
+    never quantizes — the kernel never runs), a quantizable conv op
+    missing from KERNELS (a route nobody audits), a `_grad` entry
+    (conv2d_grad transposes the lax conv; a kernel listed for it is dead
+    code that reads as a route), and a fallback reason produced by the
+    gate but absent from FALLBACK_REASONS (quant reports the miss as
+    "kernel", preflight explains it from this vocabulary)."""
     import inspect
     import re
 
-    from paddle_tpu.ops import fusion, pallas_conv, registry
+    from paddle_tpu import quant
+    from paddle_tpu.ops import pallas_conv, registry
 
     problems = []
     registered = set(registry.registered_ops())
-    fwd_keys = {k for k in pallas_conv.KERNELS if not k.endswith("_grad")}
-    grad_keys = set(pallas_conv.KERNELS) - fwd_keys
+    quant_convs = {k for k, entry in quant.QUANT_OPS.items()
+                   if entry == "qconv2d"}
     for name in sorted(pallas_conv.KERNELS):
-        base = name[:-5] if name.endswith("_grad") else name
-        if base not in registered:
+        if name.endswith("_grad"):
             problems.append((
                 "pallas_conv.KERNELS",
-                f"'{name}' dispatched but '{base}' is not registered in "
+                f"'{name}' lists a backward kernel, but conv2d_grad "
+                f"transposes the lax conv and dispatches none"))
+            continue
+        if name not in registered:
+            problems.append((
+                "pallas_conv.KERNELS",
+                f"'{name}' dispatched but not registered in "
                 f"ops/registry.py — orphan kernel"))
+        if name not in quant_convs:
+            problems.append((
+                "pallas_conv.KERNELS",
+                f"'{name}' is not a qconv2d entry of quant.QUANT_OPS — "
+                f"its lowering never reaches the int8 kernel"))
         for fn in pallas_conv.KERNELS[name]:
             if not callable(fn):
                 problems.append(("pallas_conv.KERNELS",
                                  f"'{name}' lists a non-callable kernel"))
-    for name in sorted(fwd_keys):
-        if name not in fusion.CONV_OPS:
-            problems.append((
-                "pallas_conv.KERNELS",
-                f"forward '{name}' is not a fusion.CONV_OPS window kind — "
-                f"the conv_bn_act window would never see its kernel"))
-        if name + "_grad" not in grad_keys:
-            problems.append((
-                "pallas_conv.KERNELS",
-                f"'{name}' has no '{name}_grad' dispatch — the generic "
-                f"vjp would re-trace a non-differentiable pallas_call"))
-    for name in sorted(fusion.CONV_OPS):
-        if name not in fwd_keys:
-            problems.append((
-                "pallas_conv.KERNELS",
-                f"fusion.CONV_OPS '{name}' has no Pallas dispatch entry — "
-                f"it silently keeps the lax path"))
-    for name in sorted(grad_keys):
-        if name[:-5] not in fwd_keys:
-            problems.append((
-                "pallas_conv.KERNELS",
-                f"grad '{name}' has no forward dispatch — the gate "
-                f"predicate can't be shared"))
+    for name in sorted(quant_convs - set(pallas_conv.KERNELS)):
+        problems.append((
+            "pallas_conv.KERNELS",
+            f"quant.QUANT_OPS '{name}' routes to qconv2d but has no "
+            f"dispatch entry here"))
     # every reason the gate can return must be declared, and vice versa
     src = inspect.getsource(pallas_conv.ineligible)
     produced = set(re.findall(r'return "([a-z_]+)"', src))
