@@ -442,7 +442,7 @@ def multichip_phase(devices, batch, seqlen, d_model, n_head, n_layer, vocab,
     main, startup, loss = _build_lm(seqlen, d_model, n_head, n_layer,
                                     vocab, True)
     mesh = make_mesh((2, 2), ("fsdp", "tp"), devices=list(devices))
-    plan = planner.plan(main, mesh)
+    plan = planner.plan(main, mesh, startup=startup)
     options = overlap.compiler_options(main)
     losses, step_s, exe, scope = _lm_steps(main, startup, loss, feed,
                                            steps, place)

@@ -922,6 +922,7 @@ class Executor:
             if isinstance(v, LoDTensor):
                 v = np.asarray(v.array())   # lod-carrying state fell back
             state_vals[n] = v
+        feed_vals = self._commit_feeds(program, feed_vals, window=True)
         state_vals = self._commit_state(program, state_vals, feed_vals)
         rng_counter = scope.find_var("__rng_counter__") or 0
 
@@ -1123,6 +1124,30 @@ class Executor:
         return _hlo_supplier(compiled.fn, feed_vals, state_vals,
                              np.uint32(rng))().as_text()
 
+    def _commit_feeds(self, program, feed_vals, *, window=False):
+        """On a mesh, move a feed that is committed elsewhere (a
+        DoubleBufferedFeeder puts each batch on one device) to the
+        step's in_sharding for it: jit refuses a committed argument
+        whose sharding is not the one it was given, where it would
+        transfer a numpy feed by itself. A chip-to-chip copy, queued
+        like any other; feeds that already match, numpy feeds and
+        feeds off-mesh pass through."""
+        mesh = getattr(program, "_mesh", None)
+        if mesh is None or mesh.is_multi_process:
+            return feed_vals
+        placed = [n for n, v in feed_vals.items()
+                  if getattr(v, "committed", False)]
+        if not placed:
+            return feed_vals
+        want = self._shardings(program, [], placed, window=window)[0]
+        moved = {n: want[n] for n in placed
+                 if not feed_vals[n].sharding.is_equivalent_to(
+                     want[n], feed_vals[n].ndim)}
+        if not moved:
+            return feed_vals
+        return {**feed_vals, **jax.device_put(
+            {n: feed_vals[n] for n in moved}, moved)}
+
     def _commit_state(self, program, state_vals, feed_vals):
         """Place state where the step's outputs will live, before the
         compiled call: on this executor's device when a feed is committed
@@ -1319,6 +1344,7 @@ class Executor:
 
         state_keys = sorted(state_vals)  # incl. @SEQLEN side channels
         if jit_mode:
+            feed_vals = self._commit_feeds(program, feed_vals)
             state_vals = self._commit_state(program, state_vals,
                                             feed_vals)
             key = (id(program), getattr(program, "_version", 0),

@@ -339,6 +339,17 @@ def aval_of(x):
     return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=sharding)
 
 
+def _shard_nbytes(value) -> int:
+    """Bytes one device holds of `value`: its shard under the array's
+    (or aval's) sharding, all of it without one."""
+    sharding = getattr(value, "sharding", None)
+    shape = getattr(value, "shape", None)
+    if sharding is None or not shape:
+        return nbytes_of(value)
+    return int(np.prod(sharding.shard_shape(tuple(shape)))) \
+        * np.dtype(value.dtype).itemsize
+
+
 def analyze(fn, feed_vals, state_vals, rng_counter=0, *, program="?",
             place="?", signature=None, top_k: int = 8) -> ProgramMemory:
     """AOT-lower the jitted block fn from avals (shapes/dtypes only — the
@@ -365,8 +376,9 @@ def analyze(fn, feed_vals, state_vals, rng_counter=0, *, program="?",
     rec.alias_bytes = int(getattr(stats, "alias_size_in_bytes", 0))
     rec.generated_code_bytes = int(
         getattr(stats, "generated_code_size_in_bytes", 0))
+    # per device, as XLA's alias_size is: a sharded array's shard
     rec.donated_bytes = sum(
-        nbytes_of(v) for v in jax.tree_util.tree_leaves(state_vals))
+        _shard_nbytes(v) for v in jax.tree_util.tree_leaves(state_vals))
     rec.donation_lost_bytes = max(rec.donated_bytes - rec.alias_bytes, 0)
     try:
         rec.peak = hlo_peak_liveness(compiled.as_text(), top_k=top_k)

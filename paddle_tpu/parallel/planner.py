@@ -493,7 +493,7 @@ def _feed_vars(program) -> List[str]:
 
 def plan(program, mesh=None, layout: Optional[SpecLayout] = None,
          feeds: Optional[Sequence[str]] = None,
-         shard_feeds: bool = True) -> Plan:
+         shard_feeds: bool = True, startup=None) -> Plan:
     """Classify every parameter, resolve each role's spec against the
     mesh, and write the result through the existing channels:
     `embedding.shard_table` for embedding roles (sparse path +
@@ -505,9 +505,14 @@ def plan(program, mesh=None, layout: Optional[SpecLayout] = None,
 
     Idempotent per (program, mesh): re-planning overwrites the same
     channels with the same values.
+
+    `startup`: the startup program that creates this program's state.
+    Given one, the plan is laid over it too (`_plan_startup`), so that
+    `exe.run(startup)` creates every parameter and accumulator sharded,
+    where the first step expects it. Without it the whole state sits on
+    one device from start-up until the first step moves it.
     """
-    from . import embedding as embedding_mod
-    from . import tensor_parallel as tp_mod
+    from .. import tracing
 
     if mesh is not None:
         program._mesh = mesh
@@ -516,7 +521,68 @@ def plan(program, mesh=None, layout: Optional[SpecLayout] = None,
     if mesh is None:
         raise ValueError("planner.plan needs a mesh: pass one or tag the "
                          "program (program._mesh = make_mesh(...))")
-    layout = layout or SpecLayout()
+    before = getattr(program, "_sharding_plan", None)
+    # set-up time: the span `pd.plan` on the profiler's clock
+    with tracing.span("plan"):
+        p = _plan(program, mesh, layout or SpecLayout(), feeds, shard_feeds)
+        if startup is not None:
+            _plan_startup(program, startup)
+    _book_plan(program, p, before)
+    return p
+
+
+def _plan_startup(program, startup):
+    """Tag `startup` with the planned program's mesh and, for every var
+    it writes, the spec the planned program resolves for that name: a
+    parameter's own, an optimizer accumulator's parameter's. The
+    executor pins a meshed program's state outputs to these, so the
+    state is born sharded. (gpt2-large on 2 x 2 chips, PR 26: left on one
+    device, 10 GB of parameters and moments filled chip 0 and the next
+    program asked of it could not be loaded.)"""
+    from . import embedding as embedding_mod
+
+    specs = {}
+    for op in startup.global_block().ops:
+        for name in op.desc.output_arg_names():
+            spec = embedding_mod.resolve_state_spec(program, name)
+            if spec is not None:
+                specs[name] = tuple(spec)
+    startup._mesh = program._mesh
+    startup._param_shardings = specs
+    startup._version = getattr(startup, "_version", 0) + 1
+
+
+def _book_plan(program, p: "Plan", before: Optional["Plan"] = None):
+    """What the plan decided, readable after set-up: gauges
+    planner_params{program,role,factor} (how many parameters) and
+    planner_shard_bytes{program,role,factor} (what one chip holds of
+    them). A re-plan zeroes the rows only the plan `before` it had."""
+    from .. import telemetry
+    label = telemetry.program_label(program)
+    counts = telemetry.gauge(
+        "planner_params", "parameters by planned role and shard factor",
+        labels=("program", "role", "factor"))
+    held = telemetry.gauge(
+        "planner_shard_bytes", "bytes one chip holds of the parameters "
+        "of a planned role and shard factor",
+        labels=("program", "role", "factor"))
+    rows: Dict[Tuple[str, int], List[int]] = {
+        (pp.role, pp.factor): [0, 0]
+        for pp in (before.params.values() if before else ())}
+    for pp in p.params.values():
+        row = rows.setdefault((pp.role, pp.factor), [0, 0])
+        row[0] += 1
+        row[1] += pp.per_shard_bytes
+    for (role, factor), (n, nbytes) in rows.items():
+        at = dict(program=label, role=role, factor=str(factor))
+        counts.labels(**at).set(n)
+        held.labels(**at).set(nbytes)
+
+
+def _plan(program, mesh, layout: SpecLayout, feeds, shard_feeds) -> Plan:
+    from . import embedding as embedding_mod
+    from . import tensor_parallel as tp_mod
+
     axis_sizes = dict(getattr(mesh, "shape", None) or {})
     block = program.global_block()
     roles = classify_params(program)

@@ -892,6 +892,11 @@ METRIC_CATALOG = {
     # planner / parallel
     "planner_fallback_total": _m("counter", ("program", "reason"),
                                  "sharding planner bail-outs"),
+    "planner_params": _m("gauge", ("program", "role", "factor"),
+                         "parameters by planned role and shard factor"),
+    "planner_shard_bytes": _m(
+        "gauge", ("program", "role", "factor"),
+        "bytes one chip holds of a planned role's parameters"),
     "overlap_buckets_total": _m("counter", ("program",),
                                 "gradient overlap buckets built"),
     "overlap_fallback_total": _m("counter", ("program", "reason"),
