@@ -25,11 +25,15 @@ trace. The only value-rewriting paths are:
     consumes it;
   * training-mode bn[+act] on bf16 NHWC activations: a single Pallas
     TPU kernel (one-pass E[x^2]-E[x]^2 statistics, matching the unfused
-    bf16 path) normalizes and activates in one VMEM sweep;
-  * optimizer buckets: dense param/grad/moment tensors concatenate into
-    one flat same-dtype buffer per bucket and apply the identical
-    elementwise update once (bitwise equal per element; SelectedRows
-    grads keep their per-param sparse fast path).
+    bf16 path) normalizes and activates in one VMEM sweep.
+
+An optimizer bucket rewrites no value: it is ONE scope and observer
+entry over per-tensor updates, each dense member's own sgd/momentum/adam
+arithmetic in its own shape, dtype, layout and sharding, so every output
+aliases its donated input. No flat buffer is built: inside one
+executable a concatenation saves no launch and costs a relayout of every
+tensor both ways (PERF.md, PR 27). SelectedRows grads keep their sparse
+scatter-apply buckets.
 
 Gradients stay consistent for free: fused windows only ever cover
 forward ops whose `<type>_grad` ops re-trace the UNFUSED forward
@@ -58,7 +62,7 @@ import numpy as np
 from ..framework.desc import OpDesc
 from . import layout as layout_mod
 from . import optimizer_ops
-from .common import SelectedRowsVal, maybe_dense
+from .common import SelectedRowsVal
 from .math_ops import _activations
 from .registry import NO_GRAD, register
 
@@ -334,7 +338,7 @@ def _window_synth(members, type_, group, elide=()):
 
 
 def _bucket_synth(group, members, t, prefix="fused_"):
-    """Fused optimizer op over a same-dtype sub-bucket: slots keep their
+    """Fused optimizer op over a bucket's members: slots keep their
     natural names with one entry per member (uniform across members),
     shared slots (LR, beta pows) collapse to one. prefix="fused_sparse_"
     builds the scatter-apply bucket (members re-executed by
@@ -409,7 +413,6 @@ def execute_group(executor, ctx, group: Group, env, protected=()):
 def _execute_opt_bucket(executor, ctx, group: Group, env):
     from . import sparse_ops
     t = group.members[0].type
-    specs = getattr(ctx.program, "_param_shardings", None) or {}
     tables = getattr(ctx.program, "_sharded_tables", None) or {}
     dense: List[Any] = []
     sparse: List[Any] = []
@@ -417,7 +420,7 @@ def _execute_opt_bucket(executor, ctx, group: Group, env):
         gname = _first(m.desc.input("Grad"))
         pname = _first(m.desc.input("Param"))
         if isinstance(env.get(gname), SelectedRowsVal):
-            # sparse grads never join the dense concat (densifying would
+            # sparse grads never join the dense bucket (densifying would
             # be O(vocab)); when the op has a scatter-apply kernel they
             # get their own per-dtype fused_sparse bucket below. The
             # reasons distinguish "kept sparse on purpose" (dashboards
@@ -431,32 +434,15 @@ def _execute_opt_bucket(executor, ctx, group: Group, env):
             else:
                 _count(ctx, "sparse_grad_unsupported")
                 executor._exec_op(ctx, m, env)
-        elif pname in specs:
-            # explicitly sharded params stay per-param: concatenating
-            # differently-sharded buffers would force GSPMD gathers
-            _count(ctx, "sharded_param")
-            executor._exec_op(ctx, m, env)
         else:
             dense.append(m)
-    # sub-bucket by the trace-time dtypes of every per-param tensor so the
-    # flat concat never promotes (bitwise parity holds per element)
-    per_param = _OPT_SLOTS[t][0]
-    buckets: Dict[Tuple[str, ...], List[Any]] = {}
-    for m in dense:
-        sig = []
-        for s in per_param:
-            if s == "Grad":
-                continue   # grads upcast per-tensor to the param dtype
-            v = env.get(_first(m.desc.input(s)))
-            sig.append(str(getattr(v, "dtype", None)))
-        buckets.setdefault(tuple(sig), []).append(m)
-    for sig in sorted(buckets):
-        ms = buckets[sig]
-        if len(ms) < 2:
-            for m in ms:
-                executor._exec_op(ctx, m, env)
-            continue
-        executor._exec_op(ctx, _bucket_synth(group, ms, t), env)
+    # every dense member joins one bucket whatever its dtype or sharding:
+    # the fused lowering updates each tensor on its own
+    if len(dense) < 2:
+        for m in dense:
+            executor._exec_op(ctx, m, env)
+    else:
+        executor._exec_op(ctx, _bucket_synth(group, dense, t), env)
     # scatter-apply members bucket per param dtype, mirroring the dense
     # buckets: one fused_sparse_<t> unit per dtype (the scatters stay
     # per-table — tables differ in height — but share one scope/observer
@@ -756,72 +742,94 @@ def _fold_lower(ctx, op_, g: Group, env):
 
 
 # --- bucketed optimizer lowerings ---------------------------------------
+# One op over the bucket, one update per member: each tensor keeps its own
+# shape, dtype, layout and sharding, so its outputs alias the donated
+# state element for element and the arithmetic is the per-parameter op's
+# own (bitwise; PADDLE_TPU_FUSION=0 yields the same values).
 
-def _flat_params_grads(ctx, ins):
-    ps = [jnp.asarray(v) for v in ins["Param"]]
-    shapes = [p.shape for p in ps]
-    # per-tensor upcast BEFORE the concat — exactly _param_grad per member
-    gs = [jnp.asarray(maybe_dense(gv)).astype(p.dtype)
-          for p, gv in zip(ps, ins["Grad"])]
-    return _cat(ctx, ps), _cat(ctx, gs), shapes
+def _members(ctx, op_, ins, *slots):
+    """Per member: (param, grad upcast to the param's dtype as
+    `optimizer_ops._param_grad` does, then the named state tensors).
+    Grads are dense here: SelectedRows members never join this bucket.
+
+    Each gradient passes an optimization barrier on its way in, so the
+    update is a pass of its own over the member's tensors. Left free,
+    XLA fuses Adam into the epilogue of the matmul that produced the
+    gradient, where the same six streams cost 3x the standalone pass
+    (gpt2.train-t1024: `mul_grad` +10.4 ms a step against `fused_adam`
+    5.1 ms; ResNet-50's convs read the same either way; PERF.md, PR 27)."""
+    ps = [jnp.asarray(p) for p in ins["Param"]]
+    # the barrier sits before the upcast: what is written out is the
+    # gradient in its own (under AMP, half the) width
+    gs = _grads_where_params_lie(
+        ctx, op_, [jax.lax.optimization_barrier(jnp.asarray(g)).astype(
+            p.dtype) for p, g in zip(ps, ins["Grad"])])
+    for p, g, *state in zip(ps, gs, *(ins[s] for s in slots)):
+        yield (p, g, *(jnp.asarray(v) for v in state))
 
 
-def _cat(ctx, vals):
-    """The bucket's flat buffer. Its members are the parameters a mesh
-    leaves replicated (a sharded one keeps its own op, reason
-    `sharded_param`), so on a mesh the buffer is pinned replicated too:
-    left free, GSPMD splits the 1-D buffer over an axis and builds it
-    from one padded concatenate per member, each a whole buffer and all
-    alive at once (gpt2-large on 2 x 2 chips: 471 members behind the
-    64.3M-element embedding, 37.8 GB a chip, refused by the compiler)."""
-    flats = [jnp.asarray(v).ravel() for v in vals]
-    flat = flats[0] if len(flats) == 1 else jnp.concatenate(flats)
+def _grads_where_params_lie(ctx, op_, gs):
+    """On a mesh, the gradients of the members whose parameter lies
+    replicated are traced through ONE concatenation pinned replicated.
+    It costs nothing on the chip: once the step is partitioned XLA
+    forwards the slices to their operands and the compiled step holds
+    no concatenate. What it does is keep GSPMD's plan for the rest of
+    the step: tied tensor by tensor to their replicated parameters, the
+    vectors' gradients pull the activations around every layer norm and
+    bias off the tp axis (0.85 GB more temporaries per 4 layers of
+    gpt2-large, and at 36 the compiler refuses the step: 2.6K sync
+    flags of 2.0K), and a pin on each gradient does the same. Sharded
+    members need nothing: GSPMD reduce-scatters their gradients onto
+    the parameter's spec (PERF.md, PR 27)."""
     mesh = getattr(ctx.program, "_mesh", None)
-    if mesh is not None and mesh.size > 1:
-        from jax.sharding import NamedSharding, PartitionSpec
-        flat = jax.lax.with_sharding_constraint(
-            flat, NamedSharding(mesh, PartitionSpec()))
-    return flat
-
-
-def _split(flat, shapes):
-    out = []
-    off = 0
-    for s in shapes:
-        n = int(np.prod(s)) if len(s) else 1
-        out.append(flat[off:off + n].reshape(s))
+    if mesh is None or mesh.size == 1:
+        return gs
+    specs = getattr(ctx.program, "_param_shardings", None) or {}
+    whole = [i for i, n in enumerate(op_.desc.input("Param"))
+             if not specs.get(n)]
+    if not whole:
+        return gs
+    from jax.sharding import NamedSharding, PartitionSpec
+    flat = jax.lax.with_sharding_constraint(
+        jnp.concatenate([gs[i].ravel() for i in whole]),
+        NamedSharding(mesh, PartitionSpec()))
+    gs, off = list(gs), 0
+    for i in whole:
+        g, n = gs[i], gs[i].size
+        # (members of two dtypes promote the buffer; the way back is exact)
+        gs[i] = flat[off:off + n].reshape(g.shape).astype(g.dtype)
         off += n
-    return out
+    return gs
+
+
+def _by_slot(t, rows):
+    """One tuple of outputs per member -> {output slot: [per member]}."""
+    return {s: list(col) for s, col in zip(_OPT_SLOTS[t][2], zip(*rows))}
 
 
 def _lower_fused_sgd(ctx, op_, ins):
-    p, grad, shapes = _flat_params_grads(ctx, ins)
-    po = optimizer_ops.sgd_dense(p, grad, optimizer_ops._lr(ins))
-    return {"ParamOut": _split(po, shapes)}
+    lr = optimizer_ops._lr(ins)
+    return {"ParamOut": [optimizer_ops.sgd_dense(p, g, lr)
+                         for p, g in _members(ctx, op_, ins)]}
 
 
 def _lower_fused_momentum(ctx, op_, ins):
-    p, grad, shapes = _flat_params_grads(ctx, ins)
-    v = _cat(ctx, ins["Velocity"])
-    po, vo = optimizer_ops.momentum_dense(
-        p, grad, v, optimizer_ops._lr(ins), op_.attr("mu"),
-        op_.attr("use_nesterov", False))
-    return {"ParamOut": _split(po, shapes),
-            "VelocityOut": _split(vo, shapes)}
+    lr = optimizer_ops._lr(ins)
+    mu, nesterov = op_.attr("mu"), op_.attr("use_nesterov", False)
+    return _by_slot("momentum", [
+        optimizer_ops.momentum_dense(p, g, v, lr, mu, nesterov)
+        for p, g, v in _members(ctx, op_, ins, "Velocity")])
 
 
 def _lower_fused_adam(ctx, op_, ins):
-    p, grad, shapes = _flat_params_grads(ctx, ins)
-    m1 = _cat(ctx, ins["Moment1"])
-    m2 = _cat(ctx, ins["Moment2"])
+    lr = optimizer_ops._lr(ins)
     b1p = jnp.asarray(ins["Beta1Pow"][0]).reshape(())
     b2p = jnp.asarray(ins["Beta2Pow"][0]).reshape(())
-    po, m1o, m2o = optimizer_ops.adam_dense(
-        p, grad, m1, m2, optimizer_ops._lr(ins), op_.attr("beta1", 0.9),
-        op_.attr("beta2", 0.999), op_.attr("epsilon", 1e-8), b1p, b2p)
-    return {"ParamOut": _split(po, shapes),
-            "Moment1Out": _split(m1o, shapes),
-            "Moment2Out": _split(m2o, shapes)}
+    b1, b2 = op_.attr("beta1", 0.9), op_.attr("beta2", 0.999)
+    eps = op_.attr("epsilon", 1e-8)
+    return _by_slot("adam", [
+        optimizer_ops.adam_dense(p, g, m1, m2, lr, b1, b2, eps, b1p, b2p)
+        for p, g, m1, m2 in _members(ctx, op_, ins, "Moment1", "Moment2")])
 
 
 def _sparse_bucket_lower(ctx, op_, ins):

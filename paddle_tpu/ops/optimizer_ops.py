@@ -60,14 +60,15 @@ def _pname(op_):
 
 
 # Dense update math, shared by the per-param lowerings below and the
-# bucketed fused apply (ops/fusion.py). Purely elementwise over
-# (param, grad, accumulators) with scalar hyperparameters, so applying
-# one expression to a concatenation of flattened tensors is bitwise
-# identical to applying it per tensor — the property the fused optimizer
-# parity tests pin down.
+# bucketed fused apply (ops/fusion.py), which calls it once per member
+# tensor — the same expression on the same shapes, so the fused and the
+# per-op traces agree bit for bit (the fused optimizer parity tests).
+# Each output takes its input's dtype (the f32 learning rate would
+# promote a bf16 parameter's update to f32), so it aliases the donated
+# state and the next step sees the dtypes this one was compiled for.
 
 def sgd_dense(p, g, lr):
-    return p - lr * g
+    return (p - lr * g).astype(p.dtype)
 
 
 def momentum_dense(p, g, v, lr, mu, use_nesterov):
@@ -76,7 +77,7 @@ def momentum_dense(p, g, v, lr, mu, use_nesterov):
         p_out = p - lr * (g + mu * v_out)
     else:
         p_out = p - lr * v_out
-    return p_out, v_out
+    return p_out.astype(p.dtype), v_out.astype(v.dtype)
 
 
 def adam_dense(p, g, m1, m2, lr, b1, b2, eps, b1p, b2p):
@@ -84,7 +85,7 @@ def adam_dense(p, g, m1, m2, lr, b1, b2, eps, b1p, b2p):
     m2o = b2 * m2 + (1 - b2) * g * g
     lr_t = lr * jnp.sqrt(1 - b2p) / (1 - b1p)
     po = p - lr_t * m1o / (jnp.sqrt(m2o) + eps)
-    return po, m1o, m2o
+    return po.astype(p.dtype), m1o.astype(m1.dtype), m2o.astype(m2.dtype)
 
 
 @op("sgd", grad=NO_GRAD, infer_shape=_param_out_infer(("Param", "ParamOut")))

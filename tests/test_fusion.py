@@ -2,12 +2,13 @@
 with the unfused per-op trace, per-reason fallback counters, and the
 PADDLE_TPU_FUSION=0 escape hatch.
 
-The fusion pass has three value-rewriting paths (inference BN fold,
-the Pallas bn+act kernel, bucketed optimizer applies); everything else
-composes the registered member lowerings and must therefore be BITWISE
-identical to the unfused trace — these tests pin exactly that: bitwise
-asserts for compose/bucket paths, tolerance asserts only where the
-rewrite legitimately reassociates float math (BN fold).
+The fusion pass has two value-rewriting paths (inference BN fold, the
+Pallas bn+act kernel); everything else composes the registered member
+lowerings, and an optimizer bucket applies its members' own arithmetic
+tensor by tensor, so both must be BITWISE identical to the unfused
+trace — these tests pin exactly that: bitwise asserts for compose/
+bucket paths, tolerance asserts only where the rewrite legitimately
+reassociates float math (BN fold).
 """
 
 import numpy as np
@@ -245,6 +246,71 @@ def test_sparse_grad_keeps_per_param_path():
     l0, s0 = _with_fusion(False, _sparse_emb_net)
     assert l1 == l0
     _assert_state_equal(s1, s0)
+
+
+def _two_fc(opt_factory):
+    unique_name.switch()
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = 31
+    with fluid.program_guard(main, startup):
+        x = fluid.layers.data(name="x", shape=[8], dtype="float32")
+        y = fluid.layers.data(name="y", shape=[1], dtype="float32")
+        h = fluid.layers.fc(input=x, size=16, act="relu")
+        pred = fluid.layers.fc(input=h, size=1)
+        loss = fluid.layers.mean(fluid.layers.square_error_cost(pred, y))
+        opt_factory().minimize(loss, startup_program=startup)
+    rng = np.random.default_rng(3)
+    feed = {"x": rng.standard_normal((4, 8)).astype(np.float32),
+            "y": rng.standard_normal((4, 1)).astype(np.float32)}
+    return main, startup, loss, feed
+
+
+@pytest.mark.parametrize("opt", ["momentum", "adam"])
+def test_bucket_builds_no_flat_buffer(opt):
+    """The bucket is one scope over per-tensor updates: the compiled
+    step holds no concatenate under pd.fused_<t> (the flat buffers cost
+    21-25 % of a chip's step, PERF.md PR 27), and a bf16 member shares
+    the bucket with the f32 ones, each output in its input's dtype."""
+    import jax.numpy as jnp
+
+    factory = {
+        "momentum": lambda: fluid.optimizer.Momentum(learning_rate=0.05,
+                                                     momentum=0.9),
+        "adam": lambda: fluid.optimizer.Adam(learning_rate=0.01),
+    }[opt]
+    main, startup, loss, feed = _two_fc(factory)
+    exe = fluid.Executor(fluid.CPUPlace())
+    scope = em.Scope()
+    seen = []
+
+    def observe(op, ins, outs):
+        if op.type.endswith(opt):   # fused_<opt>, or a member left outside
+            seen.append((op.type, [str(v.dtype) for v in outs["ParamOut"]]))
+
+    with em.scope_guard(scope):
+        exe.run(startup)
+        text = exe.compiled_hlo(main, feed=feed, fetch_list=[loss])
+        fused = [ln for ln in text.split("\n") if f"pd.fused_{opt}" in ln]
+        assert fused
+        assert not [ln for ln in fused if " concatenate(" in ln]
+        # one member and its accumulators in bf16: the trace-time dtypes
+        w = main.global_block().all_parameters()[0].name
+        state = [n for n in scope.local_var_names()
+                 if n == w or n.startswith(w + "_")]
+        assert len(state) == {"momentum": 2, "adam": 3}[opt], state
+        for n in state:
+            scope.set_var(n, jnp.asarray(scope.find_var(n), jnp.bfloat16))
+        em._op_observers.append(observe)
+        try:
+            exe.run(main, feed=feed, fetch_list=[loss])
+        finally:
+            em._op_observers.remove(observe)
+        for n in state:
+            assert scope.find_var(n).dtype == jnp.bfloat16, n
+    # ONE bucket a trace (the step's, and memory.on_compile's second)
+    assert seen and {t for t, _ in seen} == {f"fused_{opt}"}
+    for _, dtypes in seen:
+        assert sorted(dtypes) == ["bfloat16"] + ["float32"] * 3
 
 
 def _run_steps_window(steps=3):
