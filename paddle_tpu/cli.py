@@ -301,9 +301,8 @@ def cmd_telemetry(args):
 def cmd_memory(args):
     """HBM observability console (memory.py): static per-program footprint
     (Compiled.memory_analysis + the peak-liveness walk), live accounting
-    after a real step, donation audit, and the what-if headroom estimate
-    ("will batch B fit?") — on the built-in smoke programs, a --config
-    model, or a crash report's memory section."""
+    after a real step and the donation audit — on the built-in smoke
+    programs, a --config model, or a crash report's memory section."""
     import json
 
     from paddle_tpu import inspector, memory, telemetry
@@ -322,24 +321,18 @@ def cmd_memory(args):
     import paddle_tpu as fluid
     from paddle_tpu import executor as executor_mod
 
-    budget = int(args.budget_gb * (1 << 30)) if args.budget_gb else None
     out = []
 
     def probe(label, main, loss, feed_fn, data_fn):
         exe = fluid.Executor(fluid.TPUPlace(0))
         entry = {"program": label, "batch": args.batch}
-        measure = lambda b: exe.static_memory_analysis(
-            main, feed=feed_fn(b), fetch_list=[loss])
-        rec = measure(args.batch)
+        rec = exe.static_memory_analysis(
+            main, feed=feed_fn(args.batch), fetch_list=[loss])
         entry["static"] = rec.to_dict()
         if data_fn is not None:
             run_b = min(args.batch, 8)
             exe.run(main, feed=data_fn(run_b), fetch_list=[loss])
             entry["live"] = memory.tracker().last
-        if args.what_if:
-            entry["what_if"] = memory.what_if(
-                measure, batches=(max(args.batch // 4, 1), args.batch),
-                budget_bytes=budget)
         out.append(entry)
 
     with executor_mod.scope_guard(executor_mod.Scope()):
@@ -378,7 +371,6 @@ def cmd_memory(args):
         return 0
 
     fmt = memory._fmt_bytes
-    status = 0
     for entry in out:
         s = entry["static"]
         print(f"== {entry['program']} (batch {entry['batch']}) ==")
@@ -406,22 +398,9 @@ def cmd_memory(args):
                   f"(source={live['source']})"
                   + ("".join(f" {k}={fmt(v)}"
                              for k, v in (live.get("classes") or {}).items())))
-        wi = entry.get("what_if")
-        if wi:
-            line = (f"what-if (budget {fmt(wi['budget_bytes'])}): "
-                    f"max_batch={wi['max_batch']}")
-            if "rel_err" in wi:
-                ok = wi["rel_err"] <= 0.15
-                status = status or (0 if ok else 1)
-                line += (f", validated at b={wi['validate_batch']}: "
-                         f"predicted={fmt(wi['predicted_bytes'])} "
-                         f"measured={fmt(wi['measured_bytes'])} "
-                         f"rel_err={wi['rel_err'] * 100:.1f}% "
-                         f"(within 15%: {'yes' if ok else 'NO'})")
-            print(line)
     if args.prometheus:
         print(telemetry.prometheus_text(), end="")
-    return status
+    return 0
 
 
 def cmd_inspect(args):
@@ -1103,8 +1082,7 @@ def main(argv=None):
     p_ins.set_defaults(fn=cmd_inspect)
 
     p_mem = sub.add_parser(
-        "memory", help="HBM footprint: static analysis, live accounting, "
-                       "what-if headroom")
+        "memory", help="HBM footprint: static analysis, live accounting")
     p_mem.add_argument("--smoke", default="fit_a_line,resnet",
                        help="comma list of built-in smoke programs "
                             "(fit_a_line, resnet)")
@@ -1112,13 +1090,6 @@ def main(argv=None):
                        help="measure a --config model instead of the smokes")
     p_mem.add_argument("--batch", type=int, default=32,
                        help="base batch size for the static analysis")
-    p_mem.add_argument("--what-if", action="store_true",
-                       help="fit the headroom model and predict the max "
-                            "batch under --budget-gb (exit 1 if the "
-                            "validated prediction is off by more than 15%%)")
-    p_mem.add_argument("--budget-gb", type=float, default=0,
-                       help="HBM budget in GiB for --what-if (default: "
-                            "device bytes_limit, else 16)")
     p_mem.add_argument("--report", default=None,
                        help="print the memory/OOM section of a crash report "
                             "instead of measuring")

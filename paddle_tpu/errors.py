@@ -77,7 +77,7 @@ class OOMError(MemoryError, RuntimeError):
     to scope/feed vars), `donation_lost_bytes` counts donated state XLA
     failed to alias in place, `analysis` is the block's static
     memory.ProgramMemory view, and `suggestions` are concrete next steps
-    (donate, AMP, remat, what-if batch sizing)."""
+    (donate, AMP, remat, a smaller batch)."""
 
     def __init__(self, message, program=None, breakdown=None,
                  top_buffers=None, donation_lost_bytes=0, analysis=None,

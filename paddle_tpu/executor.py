@@ -23,7 +23,7 @@ import functools
 import os
 import sys
 import time
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -31,8 +31,11 @@ import numpy as np
 
 from . import dynamics as dynamics_mod
 from . import flags as flags_mod
-from . import quant as quant_mod
+from . import inspector as inspector_mod
 from . import memory as memory_mod
+from . import profiler as profiler_mod
+from . import quant as quant_mod
+from . import sentinel as sentinel_mod
 from . import telemetry
 from . import tracing as tracing_mod
 from .framework.desc import VarType
@@ -650,6 +653,72 @@ def _span_build_events(events):
                     tracing_mod.record_span(phase, start, end, parent=parent)
 
 
+class _Launch(NamedTuple):
+    """What `_launch` hands to `_book`: the one timer's readings and what
+    the call turned out to be."""
+    t0: float                   # perf_counter as the call started
+    run_dt: float               # the call, wall seconds
+    compile_s: float            # of them, XLA's backend_compile
+    cache: str                  # "miss" (this signature built), "hit", "n/a"
+    cause: Optional[str] = None     # of a miss: first_compile|signature_change
+    build_s: Optional[Dict[str, float]] = None   # {phase: seconds} of a miss
+    sig: Optional[Tuple] = None     # the feed signature the block ran on
+
+
+def _fetch_names(fetch_list) -> List[str]:
+    return [v.name if isinstance(v, Variable) else str(v)
+            for v in list(fetch_list or [])]
+
+
+def _densify(into, lod_map, name, tensor):
+    """A LoDTensor's dense value; its LoD goes to `lod_map`, and where it
+    has one the value comes back padded, with the lengths filed in `into`
+    under `<name>@SEQLEN` (and `@SEQLEN2` for a nested LoD)."""
+    lod_map[name] = tensor.lod
+    arr = np.asarray(tensor.array())
+    if tensor.lod:
+        arr, lengths, inner = pack_to_padded(arr, tensor.lod)
+        into[name + SEQLEN_SUFFIX] = lengths
+        if inner is not None:
+            into[name + SEQLEN2_SUFFIX] = inner
+    return arr
+
+
+def _rebuild_fetches(fetch_names, fetch_vals, fetch_lens, return_numpy):
+    """return: fetched sequence vars come back in the reference's packed
+    layout ([sum_len, ...] rows): numpy mode returns the packed array,
+    LoDTensor mode additionally carries the offsets."""
+    rebuilt = []
+    for n, v in zip(fetch_names, fetch_vals):
+        lens = fetch_lens.get(n)
+        inner = fetch_lens.get(n + SEQLEN2_SUFFIX)
+        if lens is None and not return_numpy:
+            # keep the fetch on-device: np.asarray would force a
+            # device->host sync per step, which return_numpy=False
+            # callers (benchmarks, pipelined training loops) avoid
+            rebuilt.append(v)
+            continue
+        arr = np.asarray(v)
+        if lens is not None:
+            lens = np.asarray(lens)
+            # ignore spuriously-tagged non-sequence fetches
+            if arr.ndim < 2 or lens.shape[0] != arr.shape[0] or \
+                    (lens.size and lens.max() > arr.shape[1]):
+                lens = None
+        if inner is not None and lens is not None:
+            inner = np.asarray(inner)
+            if arr.ndim < 3 or inner.shape[:2] != arr.shape[:2] or \
+                    (inner.size and inner.max() > arr.shape[2]):
+                inner = None
+        if lens is not None:
+            packed, lod = padded_to_pack(arr, lens, inner)
+            rebuilt.append(np.asarray(packed) if return_numpy
+                           else LoDTensor(packed, lod))
+        else:
+            rebuilt.append(arr if return_numpy else v)
+    return rebuilt
+
+
 class Executor:
     def __init__(self, place: Optional[Place] = None):
         self.place = place if place is not None else TPUPlace(0)
@@ -666,22 +735,19 @@ class Executor:
         program = program if program is not None else default_main_program()
         scope = scope if scope is not None else global_scope()
         # hang watchdog: a None fast-path unless sentinel.start() ran
-        from . import sentinel as sentinel_mod
         _tok = sentinel_mod.arm_dispatch(telemetry.program_label(program))
         try:
-            # `step` covers the whole call; _run_impl tiles it with the
+            # `step` covers the whole call; _dispatch tiles it with the
             # phases prepare / launch / bookkeep / writeback
             with tracing_mod.span("step", step=_step_id(scope)):
                 return self._run_impl(
-                    program, feed, fetch_list, feed_var_name,
-                    fetch_var_name, scope, return_numpy,
+                    program, feed, fetch_list, scope, return_numpy,
                     use_program_cache, use_jit)
         except Exception as e:
             # flight-recorder crash hook: a no-op unless the recorder is
             # enabled (inspector.enable_flight_recorder or the
             # PADDLE_TPU_FLIGHT_RECORDER flag); writes the JSON crash report
             # before the exception propagates
-            from . import inspector as inspector_mod
             inspector_mod.notify_crash(self, program, e)
             raise
         finally:
@@ -720,14 +786,12 @@ class Executor:
         side-fetch gauges (_telemetry_fetch_extra) are skipped on the
         window path: they are a per-step observability feature."""
         program = program if program is not None else default_main_program()
-        from . import sentinel as sentinel_mod
         _tok = sentinel_mod.arm_dispatch(telemetry.program_label(program))
         try:
             return self._run_steps_impl(
                 program, feed_window, reader, steps, fetch_list, scope,
                 return_numpy, fetch_mode, use_program_cache, use_jit)
         except Exception as e:
-            from . import inspector as inspector_mod
             inspector_mod.notify_crash(self, program, e)
             raise
         finally:
@@ -765,8 +829,6 @@ class Executor:
         if steps < 1:
             raise ValueError(f"steps must be >= 1, got {steps}")
 
-        prog_label = telemetry.program_label(program)
-        place_label = f"{type(self.place).__name__}:{self.place.device_id}"
         jit_mode = (not _EAGER) if use_jit is None else use_jit
         check_nan = _CHECK_NAN_INF or flags_mod.get("check_nan_inf")
         reason = lod_reason
@@ -799,10 +861,14 @@ class Executor:
                 # twice would read slot ids as global row ids.
                 win_stacked = (emb_cache.prepare_feed(stacked)
                                if emb_cache is not None else stacked)
-                return self._run_steps_window(
-                    program, win_stacked, steps, fetch_list, scope,
-                    return_numpy, fetch_mode, use_program_cache,
-                    prog_label, place_label)
+                # one `step` span for the K steps of the window, with
+                # run()'s phases; its id is the first step's
+                with tracing_mod.span("step", step=_step_id(scope)):
+                    tracing_mod.phase("prepare")
+                    return self._dispatch(
+                        program, win_stacked, _fetch_names(fetch_list),
+                        scope, return_numpy, use_program_cache, "window",
+                        steps=steps, fetch_mode=fetch_mode)
             except _WindowUnsupported as e:
                 reason = "trace_unsupported"
                 vlog(1, f"run_steps window unsupported, falling back: {e}")
@@ -811,7 +877,8 @@ class Executor:
                 "executor_window_fallback_total",
                 "run_steps calls served by the per-step path",
                 labels=("program", "reason")).labels(
-                    program=prog_label, reason=reason).inc()
+                    program=telemetry.program_label(program),
+                    reason=reason).inc()
         if per_step is None:
             per_step = [{n: v[i] for n, v in stacked.items()}
                         for i in range(steps)]
@@ -891,216 +958,13 @@ class Executor:
         return [np.mean(np.stack([np.asarray(v) for v in col]), axis=0)
                 for col in cols]
 
-    def _run_steps_window(self, program, stacked, steps, fetch_list, scope,
-                          return_numpy, fetch_mode, use_program_cache,
-                          prog_label, place_label):
-        # one `step` span for the K steps of the window, with run()'s
-        # phases; its id is the first step's
-        with tracing_mod.span("step", step=_step_id(scope)):
-            return self._run_window_impl(
-                program, stacked, steps, fetch_list, scope, return_numpy,
-                fetch_mode, use_program_cache, prog_label, place_label)
-
-    def _run_window_impl(self, program, stacked, steps, fetch_list, scope,
-                         return_numpy, fetch_mode, use_program_cache,
-                         prog_label, place_label):
-        tracing_mod.phase("prepare")
-        fetch_names = [v.name if isinstance(v, Variable) else str(v)
-                       for v in list(fetch_list or [])]
-        feed_vals = {n: (v if isinstance(v, jax.Array) else np.asarray(v))
-                     for n, v in stacked.items()}
-        state_names = self._external_inputs(program, set(feed_vals), scope)
-        persist_out = self._persistable_outputs(program)
-        missing = [n for n in state_names if scope.find_var(n) is None]
-        if missing:
-            raise RuntimeError(
-                f"Variables {missing} are read by the program but absent "
-                f"from the scope — run the startup program first.")
-        state_vals = {}
-        for n in state_names:
-            v = scope.find_var(n)
-            if isinstance(v, LoDTensor):
-                v = np.asarray(v.array())   # lod-carrying state fell back
-            state_vals[n] = v
-        feed_vals = self._commit_feeds(program, feed_vals, window=True)
-        state_vals = self._commit_state(program, state_vals, feed_vals)
-        rng_counter = scope.find_var("__rng_counter__") or 0
-
-        state_keys = sorted(state_vals)
-        key = (id(program), getattr(program, "_version", 0),
-               tuple(sorted(feed_vals)), tuple(fetch_names),
-               tuple(state_keys), self.place,
-               getattr(program, "_amp_dtype", None),
-               getattr(program, "_amp_level", "O1"),
-               program.random_seed, "window", steps, fetch_mode,
-               dynamics_mod.cache_token(program),
-               quant_mod.cache_token(program))
-        compiled = self._cache.get(key) if use_program_cache else None
-        plan_s = 0.0
-        if compiled is None:
-            plan_t0 = time.perf_counter()
-            compiled = self._compile_window(
-                program, state_keys, sorted(feed_vals), fetch_names,
-                persist_out, {}, steps, fetch_mode)
-            plan_s = time.perf_counter() - plan_t0
-            if use_program_cache:
-                self._cache[key] = compiled
-        from . import profiler as profiler_mod
-        if profiler_mod.wants_device_table() and \
-                not profiler_mod.has_hlo_supplier(id(compiled.fn)):
-            # run_steps registers its cost analysis too: the fused window
-            # is the production training path, and the MFU campaign needs
-            # attribution exactly there (ISSUE 6 tentpole)
-            profiler_mod.register_hlo_supplier(
-                id(compiled.fn),
-                _hlo_supplier(compiled.fn, feed_vals, state_vals,
-                              np.uint32(rng_counter)),
-                _cost_supplier(self, program, feed_vals, state_vals,
-                               window=True))
-
-        sig = telemetry.signature_of(feed_vals)
-        new_sig = sig not in compiled.seen_sigs
-        compile_before = telemetry.jax_compile_seconds()
-        build_watch = telemetry.watch_build() if new_sig else _NO_WATCH
-        tracing_mod.phase("launch")
-        run_t0 = time.perf_counter()
-        try:
-            with jax.default_device(self.device), \
-                    build_watch as build_events:
-                fetch_vals, new_state = compiled.fn(
-                    feed_vals, state_vals, np.uint32(rng_counter))
-                if profiler_mod.is_active():
-                    jax.block_until_ready((fetch_vals, new_state))
-        except _WindowUnsupported:
-            self._cache.pop(key, None)
-            raise
-        except TypeError as e:
-            if "carry" in str(e):
-                # lax.scan rejected the carry: the program changes a state
-                # aval across steps (shape/dtype drift) — per-step territory
-                self._cache.pop(key, None)
-                raise _WindowUnsupported(str(e)) from e
-            raise
-        except Exception as e:
-            oom = memory_mod.maybe_oom_error(
-                self, program, prog_label, e, feed_vals, state_vals)
-            if oom is not None:
-                raise oom from e
-            raise
-        run_dt = time.perf_counter() - run_t0
-        if new_sig:
-            _span_build_events(build_events)
-        # window succeeded: counter commit is atomic for all K steps, and
-        # the state goes back before any watcher below runs (its old
-        # buffers were donated)
-        tracing_mod.phase("writeback")
-        dyn_stats = new_state.pop(dynamics_mod.STATE_KEY, None)
-        scope.set_var("__rng_counter__", rng_counter + steps)
-        for n, v in new_state.items():
-            scope.set_var(n, v)
-        tracing_mod.phase("bookkeep")
-        profiler_mod.record_event("executor_run(window)", run_dt,
-                                  start=run_t0)
-        compile_s = telemetry.jax_compile_seconds() - compile_before
-        cache_status = "miss" if new_sig else "hit"
-        if new_sig:
-            cause = ("first_compile" if not compiled.seen_sigs
-                     else "signature_change")
-            compiled.seen_sigs.add(sig)
-            telemetry.counter(
-                "executor_compiles_total", "block traces/compiles",
-                labels=("program", "place")).labels(
-                    program=prog_label, place=place_label).inc()
-            telemetry.counter(
-                "executor_compile_seconds_total",
-                "XLA compile wall seconds spent inside Executor.run",
-                labels=("program", "place")).labels(
-                    program=prog_label, place=place_label).inc(compile_s)
-            telemetry.log_event(
-                "compile", program=prog_label, place=place_label,
-                cause=cause, seconds=compile_s, window_steps=steps,
-                signature=[list(s) for s in sig])
-            _book_build(prog_label,
-                        _build_seconds(build_events, run_dt, plan_s))
-        else:
-            telemetry.counter(
-                "executor_cache_hits_total",
-                "runs served by an already-traced signature",
-                labels=("program", "place")).labels(
-                    program=prog_label, place=place_label).inc()
-        compiled.last_sig = sig
-
-        if dyn_stats is not None:
-            dynamics_mod.on_window(program, prog_label, dyn_stats,
-                                   int(rng_counter), steps)
-
-        telemetry.counter(
-            "executor_runs_total", "Executor.run calls",
-            labels=("program", "place", "mode")).labels(
-                program=prog_label, place=place_label, mode="window").inc()
-        telemetry.counter(
-            "executor_steps_total",
-            "training/eval steps executed (a run_steps window counts K)",
-            labels=("program", "place")).labels(
-                program=prog_label, place=place_label).inc(steps)
-        telemetry.histogram(
-            "executor_run_seconds",
-            "Executor.run wall seconds (dispatch-only unless profiling "
-            "forces device sync)", labels=("program", "mode")).labels(
-                program=prog_label, mode="window").observe(run_dt)
-        telemetry.gauge(
-            "executor_last_step_seconds",
-            "wall seconds of the most recent executor step (per-step "
-            "average for run_steps windows) — fleet skew input").set(
-                max(run_dt - compile_s, 0.0) / steps)
-        if self._analysis(program)[3]:
-            telemetry.counter(
-                "optimizer_steps_total",
-                "runs of programs carrying optimizer-role ops",
-                labels=("program",)).labels(program=prog_label).inc(steps)
-        telemetry.log_event(
-            "run_window", program=prog_label, place=place_label,
-            mode="window", steps=steps, seconds=run_dt,
-            per_step_seconds=run_dt / steps, compile_s=compile_s,
-            execute_s=max(run_dt - compile_s, 0.0), cache=cache_status,
-            donated=len(state_vals), feeds=len(feed_vals),
-            fetches=len(fetch_names))
-        if tracing_mod.enabled():
-            step_span = tracing_mod.owner_span()
-            if step_span.sampled:
-                step_span.attrs.update(program=prog_label, place=place_label,
-                                       mode="window", steps=steps,
-                                       cache=cache_status)
-
-        hbm_sample = None
-        try:
-            hbm_sample = memory_mod.on_run(
-                self, program, prog_label, feed_vals, state_vals)
-        except Exception:
-            hbm_sample = None
-        from . import inspector as inspector_mod
-        if inspector_mod.flight_enabled():
-            # ONE flight-recorder entry per window, per-step seconds
-            # derived from the window wall clock
-            inspector_mod.record_step(program, prog_label, {
-                "place": place_label, "mode": "window", "steps": steps,
-                "seconds": run_dt, "per_step_seconds": run_dt / steps,
-                "compile_s": compile_s, "cache": cache_status,
-                "feeds": len(feed_vals), "fetches": len(fetch_names),
-                "rng_counter": int(rng_counter),
-                "hbm_bytes_in_use": (hbm_sample or {}).get("bytes_in_use"),
-                "hbm_peak_bytes": (hbm_sample or {}).get("peak_bytes"),
-            })
-        tracing_mod.phase("writeback")
-        return [np.asarray(v) if return_numpy else v for v in fetch_vals]
-
     def static_memory_analysis(self, program=None, feed=None,
                                fetch_list=None, scope=None, top_k=8):
         """Compile-only memory footprint of `program` under `feed`: the
         block is traced and compiled exactly as run() would (same
         donation, shardings and state gathering) but never executed, so
         no step runs and no real buffers are allocated — feed values may
-        be jax.ShapeDtypeStructs, letting what-if probes ask about batch
+        be jax.ShapeDtypeStructs, so the question can be asked of batch
         sizes that could never fit in host or device memory. Returns the
         memory.ProgramMemory record (also kept in memory.records())."""
         program = program if program is not None else default_main_program()
@@ -1184,51 +1048,19 @@ class Executor:
         """(compiled block, feed_vals, state_vals, rng_counter) gathered
         as run() gathers them, for the compile-only entry points."""
         scope = scope if scope is not None else global_scope()
-        fetch_names = [v.name if isinstance(v, Variable) else str(v)
-                       for v in list(fetch_list or [])]
-        feed_vals, lod_map = {}, {}
-        for name, val in dict(feed or {}).items():
-            if isinstance(val, LoDTensor):
-                lod_map[name] = val.lod
-                arr = np.asarray(val.array())
-                if val.lod:
-                    arr, lengths, inner = pack_to_padded(arr, val.lod)
-                    feed_vals[name + SEQLEN_SUFFIX] = lengths
-                    if inner is not None:
-                        feed_vals[name + SEQLEN2_SUFFIX] = inner
-                feed_vals[name] = arr
-            elif hasattr(val, "shape") and hasattr(val, "dtype"):
-                feed_vals[name] = val   # array or aval, never materialized
-            else:
-                feed_vals[name] = np.asarray(val)
-        state_names = self._external_inputs(program, set(feed_vals), scope)
-        missing = [n for n in state_names if scope.find_var(n) is None]
-        if missing:
-            raise RuntimeError(
-                f"Variables {missing} are read by the program but absent "
-                f"from the scope — run the startup program first.")
-        state_vals = {}
-        for n in state_names:
-            v = scope.find_var(n)
-            if isinstance(v, LoDTensor):
-                lod_map[n] = v.lod
-                arr = np.asarray(v.array())
-                if v.lod:
-                    arr, lengths, inner = pack_to_padded(arr, v.lod)
-                    state_vals[n + SEQLEN_SUFFIX] = lengths
-                    if inner is not None:
-                        state_vals[n + SEQLEN2_SUFFIX] = inner
-                v = arr
-            state_vals[n] = v
+        feed_vals, state_vals, lod_map, rng_counter = self._gather(
+            program, dict(feed or {}), scope)
         compiled = self._compile(
-            program, sorted(state_vals), sorted(feed_vals), fetch_names,
-            self._persistable_outputs(program), lod_map)
-        return (compiled, feed_vals, state_vals,
-                scope.find_var("__rng_counter__") or 0)
+            program, sorted(state_vals), sorted(feed_vals),
+            _fetch_names(fetch_list), self._persistable_outputs(program),
+            lod_map)
+        return compiled, feed_vals, state_vals, rng_counter
 
-    def _run_impl(self, program, feed, fetch_list, feed_var_name,
-                  fetch_var_name, scope, return_numpy, use_program_cache,
-                  use_jit):
+    def _run_impl(self, program, feed, fetch_list, scope, return_numpy,
+                  use_program_cache, use_jit):
+        """run()'s own part of `prepare`: what only a single step has (a
+        reader to pull from, side-fetches, probes, the check_nan_inf
+        scan), worked out and handed to _dispatch."""
         tracing_mod.phase("prepare")
         feed = dict(feed or {})
         # program-bound reader pipelines (layers.read_file): when the caller
@@ -1255,25 +1087,18 @@ class Executor:
         emb_cache = getattr(program, "_emb_cache", None)
         if emb_cache is not None:
             feed = emb_cache.prepare_feed(feed)
-        fetch_list = list(fetch_list or [])
-        scope = scope if scope is not None else global_scope()
-        fetch_names = [v.name if isinstance(v, Variable) else str(v)
-                       for v in fetch_list]
+        fetch_names = _fetch_names(fetch_list)
         jit_mode = (not _EAGER) if use_jit is None else use_jit
-
-        prog_label = telemetry.program_label(program)
-        place_label = f"{type(self.place).__name__}:{self.place.device_id}"
         # telemetry side-fetches (gauge name -> var name), e.g. the global
         # norm the clip pass marked: fetched alongside the user's list (so
         # they share the compiled block) and popped before values return
-        n_user_fetch = len(fetch_names)
-        extra_fetch = []
+        side_fetches = []
         if _TELEMETRY_FETCH:
             marked = getattr(program, "_telemetry_fetch_extra", None)
             if marked:
-                extra_fetch = [(m, n) for m, n in sorted(marked.items())
-                               if n not in fetch_names]
-                fetch_names = fetch_names + [n for _, n in extra_fetch]
+                side_fetches = [(m, n) for m, n in sorted(marked.items())
+                                if n not in fetch_names]
+                fetch_names = fetch_names + [n for _, n in side_fetches]
 
         # inspector probes (inspector.instrument / GradientAudit): their
         # stat vectors are fetched with the user's list, so the probed step
@@ -1292,61 +1117,86 @@ class Executor:
         check_nan = (_CHECK_NAN_INF or flags_mod.get("check_nan_inf")) \
             and not internal_run
 
-        # Normalize feeds. LoDTensor feeds with a LoD become padded dense
-        # arrays plus a `<name>@SEQLEN` lengths input (pack_to_padded) — the
-        # XLA-friendly LoD emulation; plain arrays pass through.
-        feed_vals, lod_map = {}, {}
-        for name, val in feed.items():
-            if isinstance(val, LoDTensor):
-                lod_map[name] = val.lod
-                arr = np.asarray(val.array())
-                if val.lod:
-                    arr, lengths, inner = pack_to_padded(arr, val.lod)
-                    feed_vals[name + SEQLEN_SUFFIX] = lengths
-                    if inner is not None:
-                        feed_vals[name + SEQLEN2_SUFFIX] = inner
-                feed_vals[name] = arr
-            else:
-                feed_vals[name] = np.asarray(val) if not isinstance(
-                    val, jax.Array) else val
+        return self._dispatch(
+            program, feed, fetch_names, scope, return_numpy,
+            use_program_cache, "jit" if jit_mode else "eager",
+            side_fetches=side_fetches, probe_sites=probe_sites,
+            check_nan=check_nan, internal_run=internal_run)
 
-        block = program.global_block()
-        state_names = self._external_inputs(program, set(feed_vals), scope)
-        persist_out = self._persistable_outputs(program)
-
+    def _gather_state(self, program, fed: set, scope, lod_map):
+        """The scope's half of gather -> (state names, state values): what
+        the block reads beyond its feeds. State held as a LoDTensor with a
+        LoD goes in padded, its lengths beside it under `<name>@SEQLEN`,
+        the same convention as a LoD feed."""
+        state_names = self._external_inputs(program, fed, scope)
         missing = [n for n in state_names if scope.find_var(n) is None]
         if missing:
             raise RuntimeError(
-                f"Variables {missing} are read by the program but absent from "
-                f"the scope — run the startup program first.")
-
+                f"Variables {missing} are read by the program but "
+                f"absent from the scope — run the startup program first.")
         state_vals = {}
         for n in state_names:
             v = scope.find_var(n)
-            if isinstance(v, LoDTensor):
-                lod_map[n] = v.lod
-                arr = np.asarray(v.array())
-                if v.lod:
-                    # same padded+SEQLEN convention as LoD feeds
-                    arr, lengths, inner = pack_to_padded(arr, v.lod)
-                    state_vals[n + SEQLEN_SUFFIX] = lengths
-                    if inner is not None:
-                        state_vals[n + SEQLEN2_SUFFIX] = inner
-                v = arr
-            state_vals[n] = v
+            state_vals[n] = _densify(state_vals, lod_map, n, v) \
+                if isinstance(v, LoDTensor) else v
+        return state_names, state_vals
 
-        # the per-step PRNG counter is read here but only committed back to
-        # the scope after the step SUCCEEDS (past the compiled call, the
-        # check_nan_inf scan and the probe checks): a raising run must not
-        # advance the counter, or an OOM/NonFinite retry would replay the
-        # failed step under a different key
-        rng_counter = scope.find_var("__rng_counter__") or 0
+    def _gather(self, program, feed, scope):
+        """gather -> (feed_vals, state_vals, lod_map, rng_counter): the
+        values one dispatch runs on, the same for run(), a run_steps
+        window and the compile-only entry points. LoDTensor feeds with a
+        LoD become padded dense arrays plus a `<name>@SEQLEN` lengths
+        input (pack_to_padded) — the XLA-friendly LoD emulation; device
+        arrays and avals pass through, never materialized.
 
-        state_keys = sorted(state_vals)  # incl. @SEQLEN side channels
-        if jit_mode:
-            feed_vals = self._commit_feeds(program, feed_vals)
-            state_vals = self._commit_state(program, state_vals,
-                                            feed_vals)
+        The per-step PRNG counter is read here and committed only after
+        the step SUCCEEDS (past the compiled call, the check_nan_inf scan
+        and the probe checks): a raising run must not advance it, or an
+        OOM/NonFinite retry would replay the failed step under a
+        different key."""
+        feed_vals, lod_map = {}, {}
+        for name, val in feed.items():
+            if isinstance(val, LoDTensor):
+                val = _densify(feed_vals, lod_map, name, val)
+            elif not isinstance(val, (jax.Array, jax.ShapeDtypeStruct)):
+                val = np.asarray(val)
+            feed_vals[name] = val
+        _names, state_vals = self._gather_state(
+            program, set(feed_vals), scope, lod_map)
+        return (feed_vals, state_vals, lod_map,
+                scope.find_var("__rng_counter__") or 0)
+
+    def _dispatch(self, program, feed, fetch_names, scope, return_numpy,
+                  use_program_cache, mode, *, steps=1, fetch_mode=None,
+                  side_fetches=(), probe_sites=None, check_nan=False,
+                  internal_run=False):
+        """The one way a program runs, inside its `step` span: gather ->
+        lookup -> launch -> validate -> commit -> book -> return. `mode`
+        is "jit" (the block compiled per step), "eager" (op by op, as the
+        reference interprets) or "window" (`steps` steps in one lax.scan,
+        every feed stacked on a leading [K] axis). `fetch_names` is the
+        user's list, then the telemetry `side_fetches` ((gauge, var)
+        pairs), then the probes' stat vars; only the user's come back."""
+        window = mode == "window"
+        prog_label = telemetry.program_label(program)
+        place_label = f"{type(self.place).__name__}:{self.place.device_id}"
+        n_fetch = len(fetch_names) - len(side_fetches) - len(probe_sites or ())
+        feed_vals, state_vals, lod_map, rng_counter = self._gather(
+            program, feed, scope)
+        persist_out = self._persistable_outputs(program)
+
+        # lookup: the block compiled for these names, built on a miss
+        compiled, key, plan_s = None, None, 0.0
+        if mode == "eager":
+            rng_key = jax.random.fold_in(
+                jax.random.key(program.random_seed or 12345), rng_counter)
+            call = lambda: self._run_eager(
+                program, feed_vals, state_vals, fetch_names, persist_out,
+                rng_key, lod_map, check_nan=check_nan)
+        else:
+            state_keys = sorted(state_vals)  # incl. @SEQLEN side channels
+            feed_vals = self._commit_feeds(program, feed_vals, window=window)
+            state_vals = self._commit_state(program, state_vals, feed_vals)
             key = (id(program), getattr(program, "_version", 0),
                    tuple(sorted(feed_vals)), tuple(fetch_names),
                    tuple(state_keys), self.place,
@@ -1355,243 +1205,48 @@ class Executor:
                    # the seed folds into the compiled step (see _compile),
                    # so changing program.random_seed must recompile
                    program.random_seed,
+                   *(("window", steps, fetch_mode) if window else ()),
                    dynamics_mod.cache_token(program),
                    quant_mod.cache_token(program))
             compiled = self._cache.get(key) if use_program_cache else None
-            plan_s = 0.0
             if compiled is None:
                 plan_t0 = time.perf_counter()
-                compiled = self._compile(program, state_keys, sorted(feed_vals),
-                                         fetch_names, persist_out, lod_map)
+                args = (program, state_keys, sorted(feed_vals), fetch_names,
+                        persist_out, lod_map)
+                compiled = (self._compile_window(*args, steps, fetch_mode)
+                            if window else self._compile(*args))
                 plan_s = time.perf_counter() - plan_t0
                 if use_program_cache:
                     self._cache[key] = compiled
-            from . import profiler as profiler_mod
-            if profiler_mod.wants_device_table() and \
-                    not profiler_mod.has_hlo_supplier(id(compiled.fn)):
-                # once per compiled block: building the aval pytree every
-                # step would inflate the host timings being measured
-                profiler_mod.register_hlo_supplier(
-                    id(compiled.fn),
-                    _hlo_supplier(compiled.fn, feed_vals, state_vals,
-                                  np.uint32(rng_counter)),
-                    _cost_supplier(self, program, feed_vals, state_vals))
-            sig = telemetry.signature_of(feed_vals)
-            new_sig = sig not in compiled.seen_sigs
-            compile_before = telemetry.jax_compile_seconds()
-            # a signature not seen before builds: jax's own trace / lower /
-            # compile events inside the call are collected (cold path only)
-            build_watch = telemetry.watch_build() if new_sig else _NO_WATCH
-            tracing_mod.phase("launch")
-            run_t0 = time.perf_counter()
-            try:
-                with jax.default_device(self.device), \
-                        build_watch as build_events:
-                    fetch_vals, fetch_lens, new_state = compiled.fn(
-                        feed_vals, state_vals, np.uint32(rng_counter))
-                    if profiler_mod.is_active():
-                        # async dispatch returns futures; force execution
-                        # inside the timed scope so the event measures the
-                        # step, not the enqueue (only when profiling)
-                        jax.block_until_ready((fetch_vals, new_state))
-            except Exception as e:
-                # OOM forensics: a raw RESOURCE_EXHAUSTED becomes a
-                # structured errors.OOMError (breakdown, top live buffers,
-                # donation losses, suggestions) before the crash-report
-                # hook in run() sees it
-                oom = memory_mod.maybe_oom_error(
-                    self, program, prog_label, e, feed_vals, state_vals)
-                if oom is not None:
-                    raise oom from e
-                raise
-            run_dt = time.perf_counter() - run_t0
-            if new_sig:
-                _span_build_events(build_events)
-            tracing_mod.phase("bookkeep")
-            # the profiler's host event is the launch, from the one timer
-            profiler_mod.record_event("executor_run(jit)", run_dt,
-                                      start=run_t0)
-            # the dynamics stats row leaves new_state immediately: its
-            # off-period NaN filler must never reach the check_nan scan or
-            # the scope writeback (recorded only after the step commits)
-            dyn_stats = new_state.pop(dynamics_mod.STATE_KEY, None)
-            # compile-vs-execute split: XLA's own backend_compile events
-            # (jax.monitoring) accumulated across the call — catches the
-            # jit retraces the executor cache key cannot see
-            compile_s = telemetry.jax_compile_seconds() - compile_before
-            mode, donated = "jit", len(state_vals)
-            cache_status = "miss" if new_sig else "hit"
-            if new_sig:
-                cause = ("first_compile" if not compiled.seen_sigs
-                         else "signature_change")
-                compiled.seen_sigs.add(sig)
-                telemetry.counter(
-                    "executor_compiles_total", "block traces/compiles",
-                    labels=("program", "place")).labels(
-                        program=prog_label, place=place_label).inc()
-                telemetry.counter(
-                    "executor_compile_seconds_total",
-                    "XLA compile wall seconds spent inside Executor.run",
-                    labels=("program", "place")).labels(
-                        program=prog_label, place=place_label).inc(compile_s)
-                telemetry.log_event(
-                    "compile", program=prog_label, place=place_label,
-                    cause=cause, seconds=compile_s,
-                    signature=[list(s) for s in sig])
-                build_s = _build_seconds(build_events, run_dt, plan_s)
-                if cause == "first_compile" and not internal_run:
-                    # static memory analysis once per compiled block: an
-                    # extra AOT lower/compile from avals (the persistent
-                    # compilation cache absorbs the XLA work); advisory —
-                    # a failure must never fail the training step
-                    analysis_t0 = time.perf_counter()
-                    try:
-                        with tracing_mod.span("analysis"):
-                            memory_mod.on_compile(
-                                self, compiled, program, prog_label,
-                                place_label, feed_vals, state_vals,
-                                np.uint32(rng_counter), signature=sig)
-                    except Exception as mem_e:
-                        telemetry.log_event(
-                            "memory_analysis_error", program=prog_label,
-                            error=f"{type(mem_e).__name__}: {mem_e}")
-                    build_s["analysis"] = time.perf_counter() - analysis_t0
-                _book_build(prog_label, build_s)
-                if cause == "signature_change":
-                    last = compiled.last_sig or ()
-                    telemetry.counter(
-                        "executor_cache_misses_total",
-                        "jit retraces caused by a changed feed signature",
-                        labels=("program", "place")).labels(
-                            program=prog_label, place=place_label).inc()
-                    telemetry.log_event(
-                        "cache_miss", program=prog_label, place=place_label,
-                        signature=[list(s) for s in sig],
-                        changed=[list(s) for s in sig if s not in last])
-            else:
-                telemetry.counter(
-                    "executor_cache_hits_total",
-                    "runs served by an already-traced signature",
-                    labels=("program", "place")).labels(
-                        program=prog_label, place=place_label).inc()
-            compiled.last_sig = sig
-            if check_nan:
-                # jit-path equivalent of the reference FLAGS_check_nan_inf
-                # per-op scan (executor.cc:325-333): inside one fused XLA
-                # computation there is no per-op boundary, so the check runs
-                # on every fetch and updated persistable after the step.
-                # Probe stat vectors are exempt: their counts describe OTHER
-                # tensors (record_probes inspects them below), and a stats
-                # l2 that overflowed to inf must not masquerade as a hit.
-                # ONE fused on-device reduction + ONE host sync for the
-                # whole step (_finite_all); the per-tensor np.asarray walk
-                # only runs on the failure path, to name the culprit
-                probe_stat_names = ({s.stat_var for s in probe_sites}
-                                    if probe_sites else ())
-                checked = [
-                    (name, val) for name, val in
-                    list(zip(fetch_names, fetch_vals)) + list(new_state.items())
-                    if name not in probe_stat_names
-                    and jnp.issubdtype(getattr(val, "dtype", None)
-                                       or np.asarray(val).dtype, jnp.inexact)]
-                if checked and not bool(_finite_all([v for _, v in checked])):
-                    for name, val in checked:
-                        arr = np.asarray(val)
-                        if not np.isfinite(arr).all():
-                            self._raise_nonfinite(
-                                program, name, arr, feed, new_state,
-                                rng_counter, scope, prog_label)
-        else:
-            seed = program.random_seed or 12345
-            rng_key = jax.random.fold_in(jax.random.key(seed), rng_counter)
-            compile_before = telemetry.jax_compile_seconds()
-            tracing_mod.phase("launch")
-            run_t0 = time.perf_counter()
-            try:
-                fetch_vals, fetch_lens, new_state = self._run_eager(
-                    program, feed_vals, state_vals, fetch_names, persist_out,
-                    rng_key, lod_map, check_nan=check_nan)
-            except Exception as e:
-                oom = memory_mod.maybe_oom_error(
-                    self, program, prog_label, e, feed_vals, state_vals)
-                if oom is not None:
-                    raise oom from e
-                raise
-            run_dt = time.perf_counter() - run_t0
-            tracing_mod.phase("bookkeep")
-            compile_s = telemetry.jax_compile_seconds() - compile_before
-            mode, donated, cache_status = "eager", 0, "n/a"
-            dyn_stats = None  # dynamics rides the traced step only
+            call = lambda: compiled.fn(feed_vals, state_vals,
+                                       np.uint32(rng_counter))
 
-        if probe_sites:
-            # pop the probe stat vectors (appended after the telemetry
-            # extras) and hand them to the inspector BEFORE state writeback:
-            # a non-finite probe raises here, so a diverged step never
-            # commits its state to the scope
-            n_keep = n_user_fetch + len(extra_fetch)
-            probe_vals = fetch_vals[n_keep:]
-            fetch_vals = fetch_vals[:n_keep]
-            fetch_names = fetch_names[:n_keep]
-            from . import inspector as inspector_mod
-            inspector_mod.record_probes(
-                self, program, scope, probe_sites, probe_vals, feed=feed,
-                new_state=new_state, rng_counter=rng_counter,
-                prog_label=prog_label)
+        out, launch = self._launch(
+            program, compiled, call, key, mode, steps, feed_vals, state_vals,
+            rng_counter, plan_s, prog_label, place_label)
+        # a window has no sequence fetches (_WindowUnsupported)
+        fetch_vals, fetch_lens, new_state = \
+            (out[0], {}, out[1]) if window else out
+        # the dynamics stats row leaves new_state immediately: its
+        # off-period NaN filler must never reach the check_nan scan or
+        # the scope writeback (recorded only after the step commits)
+        dyn_stats = new_state.pop(dynamics_mod.STATE_KEY, None)
 
-        # the step is now known-good: commit the PRNG counter atomically
-        # with (just before) the state write-back below
-        scope.set_var("__rng_counter__", rng_counter + 1)
-        if dyn_stats is not None:
-            dynamics_mod.on_step(program, prog_label, dyn_stats,
-                                 int(rng_counter))
+        # validate: may raise, and then nothing below happens — a diverged
+        # step never commits its state or its counter to the scope
+        n_keep = n_fetch + len(side_fetches)
+        self._validate(
+            program, scope, feed, fetch_names, fetch_vals, new_state,
+            rng_counter, prog_label, probe_sites, n_keep,
+            scan=check_nan and mode == "jit")   # eager scans op by op
 
-        telemetry.counter(
-            "executor_runs_total", "Executor.run calls",
-            labels=("program", "place", "mode")).labels(
-                program=prog_label, place=place_label, mode=mode).inc()
-        telemetry.counter(
-            "executor_steps_total",
-            "training/eval steps executed (a run_steps window counts K)",
-            labels=("program", "place")).labels(
-                program=prog_label, place=place_label).inc()
-        telemetry.histogram(
-            "executor_run_seconds",
-            "Executor.run wall seconds (dispatch-only unless profiling "
-            "forces device sync)", labels=("program", "mode")).labels(
-                program=prog_label, mode=mode).observe(run_dt)
-        telemetry.gauge(
-            "executor_last_step_seconds",
-            "wall seconds of the most recent executor step (per-step "
-            "average for run_steps windows) — fleet skew input").set(
-                max(run_dt - compile_s, 0.0))
-        if self._analysis(program)[3]:
-            telemetry.counter(
-                "optimizer_steps_total",
-                "runs of programs carrying optimizer-role ops",
-                labels=("program",)).labels(program=prog_label).inc()
-        telemetry.log_event(
-            "run", program=prog_label, place=place_label, mode=mode,
-            seconds=run_dt, compile_s=compile_s,
-            execute_s=max(run_dt - compile_s, 0.0), cache=cache_status,
-            donated=donated, feeds=len(feed_vals), fetches=n_user_fetch)
-        if tracing_mod.enabled():
-            step_span = tracing_mod.owner_span()
-            if step_span.sampled:
-                step_span.attrs.update(program=prog_label, place=place_label,
-                                       mode=mode, cache=cache_status)
-
-        hbm_sample = None
-        if not internal_run:
-            # live HBM accounting: one tracker sample per run (gauges +
-            # flight-recorder fields below); byte counts come from avals
-            # only, so the donated state arrays are safe to measure
-            try:
-                hbm_sample = memory_mod.on_run(
-                    self, program, prog_label, feed_vals, state_vals)
-            except Exception:
-                hbm_sample = None
-
+        # commit: the step is known-good. The counter (atomic for all K
+        # steps of a window) and the state go back before any watcher
+        # below runs or anything can wait on the device: the state's old
+        # buffers were donated, so an interrupt in between would leave the
+        # scope holding dead arrays
         tracing_mod.phase("writeback")
+        scope.set_var("__rng_counter__", rng_counter + steps)
         for n, v in new_state.items():
             if n.endswith(SEQLEN_SUFFIX) or n.endswith(SEQLEN2_SUFFIX):
                 continue
@@ -1603,78 +1258,286 @@ class Executor:
                 packed, lod = padded_to_pack(
                     np.asarray(v), np.asarray(new_state[n + SEQLEN_SUFFIX]),
                     None if inner is None else np.asarray(inner))
-                scope.set_var(n, LoDTensor(packed, lod))
+                v = LoDTensor(packed, lod)
+            scope.set_var(n, v)
+
+        tracing_mod.phase("bookkeep")
+        self._book(program, compiled, launch, mode, steps, feed_vals,
+                   state_vals, rng_counter, dyn_stats, n_fetch,
+                   list(zip(side_fetches, fetch_vals[n_fetch:n_keep])),
+                   internal_run, prog_label, place_label)
+        tracing_mod.phase("writeback")
+        return _rebuild_fetches(fetch_names[:n_fetch], fetch_vals[:n_fetch],
+                                fetch_lens, return_numpy)
+
+    def _launch(self, program, compiled, call, key, mode, steps, feed_vals,
+                state_vals, rng_counter, plan_s, prog_label, place_label):
+        """launch -> (what `call` returned, _Launch): the call under the
+        one timer every sink reads, then what even a step that fails
+        validation has done — a signature seen, a block compiled or hit.
+        `compiled` is None in eager mode, which builds nothing."""
+        sig, new_sig, build_watch = None, False, _NO_WATCH
+        if compiled is not None:
+            if profiler_mod.wants_device_table() and \
+                    not profiler_mod.has_hlo_supplier(id(compiled.fn)):
+                # once per compiled block: building the aval pytree every
+                # step would inflate the host timings being measured. The
+                # fused window registers too: it is the production
+                # training path, and the MFU campaign needs attribution
+                # exactly there (ISSUE 6 tentpole)
+                profiler_mod.register_hlo_supplier(
+                    id(compiled.fn),
+                    _hlo_supplier(compiled.fn, feed_vals, state_vals,
+                                  np.uint32(rng_counter)),
+                    _cost_supplier(self, program, feed_vals, state_vals,
+                                   window=mode == "window"))
+            sig = telemetry.signature_of(feed_vals)
+            new_sig = sig not in compiled.seen_sigs
+            if new_sig:
+                # a signature not seen before builds: jax's own trace /
+                # lower / compile events inside the call are collected
+                # (cold path only)
+                build_watch = telemetry.watch_build()
+        compile_before = telemetry.jax_compile_seconds()
+        tracing_mod.phase("launch")
+        run_t0 = time.perf_counter()
+        try:
+            with jax.default_device(self.device), \
+                    build_watch as build_events:
+                out = call()
+                if profiler_mod.is_active():
+                    # async dispatch returns futures; force execution
+                    # inside the timed scope so the event measures the
+                    # step, not the enqueue (only when profiling)
+                    jax.block_until_ready(out)
+        except Exception as e:
+            if mode == "window" and (
+                    isinstance(e, _WindowUnsupported)
+                    or isinstance(e, TypeError) and "carry" in str(e)):
+                # the trace said so itself, or lax.scan rejected the
+                # carry: the program changes a state aval across steps
+                # (shape/dtype drift) — per-step territory
+                self._cache.pop(key, None)
+                if isinstance(e, _WindowUnsupported):
+                    raise
+                raise _WindowUnsupported(str(e)) from e
+            # OOM forensics: a raw RESOURCE_EXHAUSTED becomes a structured
+            # errors.OOMError (breakdown, top live buffers, donation
+            # losses, suggestions) before the crash-report hook sees it
+            oom = memory_mod.maybe_oom_error(
+                self, program, prog_label, e, feed_vals, state_vals)
+            if oom is not None:
+                raise oom from e
+            raise
+        run_dt = time.perf_counter() - run_t0
+        if new_sig:
+            _span_build_events(build_events)
+        tracing_mod.phase("bookkeep")
+        # compile-vs-execute split: XLA's own backend_compile events
+        # (jax.monitoring) accumulated across the call — catches the
+        # jit retraces the executor cache key cannot see
+        compile_s = telemetry.jax_compile_seconds() - compile_before
+        if compiled is None:
+            return out, _Launch(run_t0, run_dt, compile_s, "n/a")
+        cause = build_s = None
+        if new_sig:
+            cause = ("first_compile" if not compiled.seen_sigs
+                     else "signature_change")
+            compiled.seen_sigs.add(sig)
+            telemetry.counter(
+                "executor_compiles_total", "block traces/compiles",
+                labels=("program", "place")).labels(
+                    program=prog_label, place=place_label).inc()
+            telemetry.counter(
+                "executor_compile_seconds_total",
+                "XLA compile wall seconds spent inside Executor.run",
+                labels=("program", "place")).labels(
+                    program=prog_label, place=place_label).inc(compile_s)
+            telemetry.log_event(
+                "compile", program=prog_label, place=place_label,
+                cause=cause, seconds=compile_s,
+                **({"window_steps": steps} if mode == "window" else {}),
+                signature=[list(s) for s in sig])
+            build_s = _build_seconds(build_events, run_dt, plan_s)
+            if cause == "signature_change":
+                last = compiled.last_sig or ()
+                telemetry.counter(
+                    "executor_cache_misses_total",
+                    "jit retraces caused by a changed feed signature",
+                    labels=("program", "place")).labels(
+                        program=prog_label, place=place_label).inc()
+                telemetry.log_event(
+                    "cache_miss", program=prog_label, place=place_label,
+                    signature=[list(s) for s in sig],
+                    changed=[list(s) for s in sig if s not in last])
+        else:
+            telemetry.counter(
+                "executor_cache_hits_total",
+                "runs served by an already-traced signature",
+                labels=("program", "place")).labels(
+                    program=prog_label, place=place_label).inc()
+        compiled.last_sig = sig
+        return out, _Launch(run_t0, run_dt, compile_s,
+                            "miss" if new_sig else "hit", cause, build_s, sig)
+
+    def _validate(self, program, scope, feed, fetch_names, fetch_vals,
+                  new_state, rng_counter, prog_label, probe_sites, n_keep,
+                  scan):
+        """validate: the check_nan_inf scan of a jitted step and the
+        inspector's probes. Either raises a structured NonFiniteError."""
+        if scan:
+            # jit-path equivalent of the reference FLAGS_check_nan_inf
+            # per-op scan (executor.cc:325-333): inside one fused XLA
+            # computation there is no per-op boundary, so the check runs
+            # on every fetch and updated persistable after the step.
+            # Probe stat vectors are exempt: their counts describe OTHER
+            # tensors (record_probes inspects them below), and a stats
+            # l2 that overflowed to inf must not masquerade as a hit.
+            # ONE fused on-device reduction + ONE host sync for the
+            # whole step (_finite_all); the per-tensor np.asarray walk
+            # only runs on the failure path, to name the culprit
+            checked = [
+                (name, val) for name, val in
+                list(zip(fetch_names[:n_keep], fetch_vals))
+                + list(new_state.items())
+                if jnp.issubdtype(getattr(val, "dtype", None)
+                                  or np.asarray(val).dtype, jnp.inexact)]
+            if checked and not bool(_finite_all([v for _, v in checked])):
+                for name, val in checked:
+                    arr = np.asarray(val)
+                    if not np.isfinite(arr).all():
+                        self._raise_nonfinite(
+                            program, name, arr, feed, new_state,
+                            rng_counter, scope, prog_label)
+        if probe_sites:
+            # the probe stat vectors (appended after the telemetry
+            # extras) go to the inspector BEFORE state writeback: a
+            # non-finite probe raises here
+            inspector_mod.record_probes(
+                self, program, scope, probe_sites, fetch_vals[n_keep:],
+                feed=feed, new_state=new_state, rng_counter=rng_counter,
+                prog_label=prog_label)
+
+    def _book(self, program, compiled, launch, mode, steps, feed_vals,
+              state_vals, rng_counter, dyn_stats, n_fetch, side_fetched,
+              internal_run, prog_label, place_label):
+        """book: every watcher and sink of a committed step, each called
+        from this one place. A watcher that waits on the device (dynamics,
+        every 16th step; the side-fetch gauges) finds the scope already
+        whole. Between the modes only the labels, `steps` and the window's
+        two extra fields differ; the static memory analysis belongs to
+        the per-step block alone."""
+        window = mode == "window"
+        run_dt, compile_s = launch.run_dt, launch.compile_s
+        window_fields = {"steps": steps, "per_step_seconds": run_dt / steps} \
+            if window else {}
+        # the profiler's host event is the launch, from the one timer
+        profiler_mod.record_event(f"executor_run({mode})", run_dt,
+                                  start=launch.t0)
+        if launch.build_s is not None:
+            if mode == "jit" and launch.cause == "first_compile" \
+                    and not internal_run:
+                # static memory analysis once per compiled block: an
+                # extra AOT lower/compile from avals (the persistent
+                # compilation cache absorbs the XLA work); advisory —
+                # a failure must never fail the training step
+                analysis_t0 = time.perf_counter()
+                try:
+                    with tracing_mod.span("analysis"):
+                        memory_mod.on_compile(
+                            self, compiled, program, prog_label,
+                            place_label, feed_vals, state_vals,
+                            np.uint32(rng_counter), signature=launch.sig)
+                except Exception as mem_e:
+                    telemetry.log_event(
+                        "memory_analysis_error", program=prog_label,
+                        error=f"{type(mem_e).__name__}: {mem_e}")
+                launch.build_s["analysis"] = \
+                    time.perf_counter() - analysis_t0
+            _book_build(prog_label, launch.build_s)
+        if dyn_stats is not None:
+            if window:
+                dynamics_mod.on_window(program, prog_label, dyn_stats,
+                                       int(rng_counter), steps)
             else:
-                scope.set_var(n, v)
-        # the state goes back before anything below can wait on the device:
-        # its old buffers were donated, so an interrupt in between would
-        # leave the scope holding dead arrays
-        from . import inspector as inspector_mod
-        flight = not internal_run and inspector_mod.flight_enabled()
-        if extra_fetch or flight:
-            tracing_mod.phase("bookkeep")
-        if extra_fetch:
-            # pop the telemetry side-fetches (gauges, not user outputs);
+                dynamics_mod.on_step(program, prog_label, dyn_stats,
+                                     int(rng_counter))
+
+        telemetry.counter(
+            "executor_runs_total", "Executor.run calls",
+            labels=("program", "place", "mode")).labels(
+                program=prog_label, place=place_label, mode=mode).inc()
+        telemetry.counter(
+            "executor_steps_total",
+            "training/eval steps executed (a run_steps window counts K)",
+            labels=("program", "place")).labels(
+                program=prog_label, place=place_label).inc(steps)
+        telemetry.histogram(
+            "executor_run_seconds",
+            "Executor.run wall seconds (dispatch-only unless profiling "
+            "forces device sync)", labels=("program", "mode")).labels(
+                program=prog_label, mode=mode).observe(run_dt)
+        telemetry.gauge(
+            "executor_last_step_seconds",
+            "wall seconds of the most recent executor step (per-step "
+            "average for run_steps windows) — fleet skew input").set(
+                max(run_dt - compile_s, 0.0) / steps)
+        if self._analysis(program)[3]:
+            telemetry.counter(
+                "optimizer_steps_total",
+                "runs of programs carrying optimizer-role ops",
+                labels=("program",)).labels(program=prog_label).inc(steps)
+        telemetry.log_event(
+            "run_window" if window else "run",
+            program=prog_label, place=place_label, mode=mode,
+            seconds=run_dt, compile_s=compile_s,
+            execute_s=max(run_dt - compile_s, 0.0), cache=launch.cache,
+            donated=len(state_vals) if compiled is not None else 0,
+            feeds=len(feed_vals), fetches=n_fetch, **window_fields)
+        if tracing_mod.enabled():
+            step_span = tracing_mod.owner_span()
+            if step_span.sampled:
+                step_span.attrs.update(program=prog_label, place=place_label,
+                                       mode=mode, cache=launch.cache)
+                if window:
+                    step_span.attrs["steps"] = steps
+
+        hbm_sample = None
+        if not internal_run:
+            # live HBM accounting: one tracker sample per run (gauges +
+            # flight-recorder fields below); byte counts come from avals
+            # only, so the donated state arrays are safe to measure
+            try:
+                hbm_sample = memory_mod.on_run(
+                    self, program, prog_label, feed_vals, state_vals)
+            except Exception:
+                hbm_sample = None
+        for (metric, _n), val in side_fetched:
+            # the telemetry side-fetches are gauges, not user outputs;
             # float() forces the device read — the documented cost of
             # _telemetry_fetch_extra (PADDLE_TPU_TELEMETRY_FETCH=0 disables)
-            for (metric, _n), val in zip(extra_fetch,
-                                         fetch_vals[n_user_fetch:]):
-                try:
-                    telemetry.gauge(metric, labels=("program",)).labels(
-                        program=prog_label).set(
-                            float(np.asarray(val).ravel()[0]))
-                except (TypeError, ValueError, IndexError):
-                    pass
-            fetch_vals = fetch_vals[:n_user_fetch]
-            fetch_names = fetch_names[:n_user_fetch]
-        if flight:
-            # flight recorder: one bounded ring record per step (after
-            # the gauge pop above so the global norm is this step's)
-            inspector_mod.record_step(program, prog_label, {
+            try:
+                telemetry.gauge(metric, labels=("program",)).labels(
+                    program=prog_label).set(
+                        float(np.asarray(val).ravel()[0]))
+            except (TypeError, ValueError, IndexError):
+                pass
+        if not internal_run and inspector_mod.flight_enabled():
+            # flight recorder: one bounded ring record per step or window
+            # (after the gauges above so the global norm is this step's; a
+            # window skips the side-fetches and carries no norm)
+            record = {
                 "place": place_label, "mode": mode, "seconds": run_dt,
-                "compile_s": compile_s, "cache": cache_status,
-                "feeds": len(feed_vals), "fetches": n_user_fetch,
+                "compile_s": compile_s, "cache": launch.cache,
+                "feeds": len(feed_vals), "fetches": n_fetch,
                 "rng_counter": int(rng_counter),
-                "global_norm": telemetry.read_gauge(
-                    "optimizer_global_norm", program=prog_label),
-                "hbm_bytes_in_use": (hbm_sample or {}).get(
-                    "bytes_in_use"),
+                "hbm_bytes_in_use": (hbm_sample or {}).get("bytes_in_use"),
                 "hbm_peak_bytes": (hbm_sample or {}).get("peak_bytes"),
-            })
-        if extra_fetch or flight:
-            tracing_mod.phase("writeback")
-        # Fetched sequence vars come back in the reference's packed layout
-        # ([sum_len, ...] rows): numpy mode returns the packed array, LoDTensor
-        # mode additionally carries the offsets.
-        rebuilt = []
-        for n, v in zip(fetch_names, fetch_vals):
-            lens = fetch_lens.get(n)
-            inner = fetch_lens.get(n + SEQLEN2_SUFFIX)
-            if lens is None and not return_numpy:
-                # keep the fetch on-device: np.asarray would force a
-                # device->host sync per step, which return_numpy=False
-                # callers (benchmarks, pipelined training loops) avoid
-                rebuilt.append(v)
-                continue
-            arr = np.asarray(v)
-            if lens is not None:
-                lens = np.asarray(lens)
-                # ignore spuriously-tagged non-sequence fetches
-                if arr.ndim < 2 or lens.shape[0] != arr.shape[0] or \
-                        (lens.size and lens.max() > arr.shape[1]):
-                    lens = None
-            if inner is not None and lens is not None:
-                inner = np.asarray(inner)
-                if arr.ndim < 3 or inner.shape[:2] != arr.shape[:2] or \
-                        (inner.size and inner.max() > arr.shape[2]):
-                    inner = None
-            if lens is not None:
-                packed, lod = padded_to_pack(arr, lens, inner)
-                rebuilt.append(np.asarray(packed) if return_numpy
-                               else LoDTensor(packed, lod))
-            else:
-                rebuilt.append(arr if return_numpy else v)
-        return rebuilt
+                **window_fields}
+            if not window:
+                record["global_norm"] = telemetry.read_gauge(
+                    "optimizer_global_norm", program=prog_label)
+            inspector_mod.record_step(program, prog_label, record)
 
     def _raise_nonfinite(self, program, name, arr, feed, new_state,
                          rng_counter, scope, prog_label):
@@ -1682,7 +1545,6 @@ class Executor:
         offending fetch var and dtype, counts the contamination, and (when
         the nonfinite_attribution flag is on) replays the step with
         bisection probes to name the first offending op."""
-        from . import inspector as inspector_mod
         from .errors import NonFiniteError
         telemetry.counter(
             "nonfinite_detections_total",
@@ -2223,13 +2085,9 @@ class Executor:
         reads has no value in `scope` (startup never ran / load_persistables
         skipped a file)."""
         feed_names = sorted(feed_names)
-        state_names = self._external_inputs(program, set(feed_names), scope)
+        state_names, _vals = self._gather_state(
+            program, set(feed_names), scope, {})
         persist_out = self._persistable_outputs(program)
-        missing = [n for n in state_names if scope.find_var(n) is None]
-        if missing:
-            raise RuntimeError(
-                f"Variables {missing} are read by the program but absent "
-                f"from the scope — run the startup program first.")
         compiled = self._compile(program, state_names, feed_names,
                                  fetch_names, persist_out, lod_map={})
         return compiled, state_names, persist_out
@@ -2294,7 +2152,6 @@ class Executor:
         env.update({k: jnp.asarray(v) for k, v in feed_vals.items()})
         ctx = LoweringContext(self, program, rng_key, lod_map)
         block = program.global_block()
-        from . import profiler as profiler_mod
         for op in block.ops:
             # per-op host events in the interpreter path (reference
             # RecordEvent around each kernel launch, operator.cc:486)

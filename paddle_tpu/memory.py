@@ -22,10 +22,10 @@ space-side sibling of telemetry.py (time) and inspector.py (numerics):
    params / opt-state / feeds / activations by scope metadata, and feeds
    the `hbm_bytes_in_use` / `hbm_peak_bytes` gauges and the inspector
    flight-recorder ring.
-3. **What-if estimation** — `HeadroomModel` fits peak(b) = fixed +
-   per_sample*b from static analyses at two batch sizes, predicts the
-   max batch under an HBM budget, and validates the extrapolation
-   against a fresh analysis at the predicted batch (`what_if`).
+3. **Headroom arithmetic** — `HeadroomModel` fits peak(b) = fixed +
+   per_sample*b from static analyses at two or more batch sizes and
+   gives the max batch under an HBM budget (`default_budget`); the
+   overlap pass and the hot-row cache size themselves with it.
 4. **OOM forensics** — `maybe_oom_error` turns a raw RESOURCE_EXHAUSTED
    (jax XlaRuntimeError) into a structured `errors.OOMError` carrying
    the breakdown, top live buffers, donation losses and concrete
@@ -42,7 +42,7 @@ import math
 import re
 import threading
 import warnings
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -53,7 +53,7 @@ __all__ = [
     "ProgramMemory", "MemoryTracker", "HeadroomModel",
     "analyze", "hlo_peak_liveness", "shape_bytes", "nbytes_of",
     "classify", "tracker", "top_live_buffers", "live_array_bytes",
-    "is_oom", "maybe_oom_error", "what_if", "default_budget",
+    "is_oom", "maybe_oom_error", "default_budget",
     "records", "latest_record", "reset", "memory_report", "bench_summary",
     "crash_section", "build_smoke", "on_compile", "on_run",
     "per_shard_param_bytes",
@@ -82,7 +82,7 @@ flags.define("memory_tracker", True,
              "Executor.run into hbm_* gauges (memory.MemoryTracker; "
              "live-read)")
 flags.define("hbm_budget_bytes", 0,
-             "HBM budget for what-if headroom estimates on backends whose "
+             "HBM budget for headroom estimates on backends whose "
              "memory_stats() reports no bytes_limit (0 = 16 GiB default)")
 
 
@@ -737,8 +737,8 @@ def _build_oom_error(exe, program, prog_label, exc, feed_vals, state_vals):
             f"rematerialize activations or shard the model "
             f"(parallel.shard_all_params_zero)")
     suggestions.append(
-        "reduce the batch size — `python -m paddle_tpu memory --what-if` "
-        "predicts the largest batch that fits")
+        "reduce the batch size — `python -m paddle_tpu memory --batch B` "
+        "reads the static footprint at batch B without running a step")
 
     lines = [f"out of device memory running program '{prog_label}'",
              f"  backend error: {str(exc).splitlines()[0][:300]}"]
@@ -767,7 +767,7 @@ def _build_oom_error(exe, program, prog_label, exc, feed_vals, state_vals):
 
 
 # ---------------------------------------------------------------------------
-# What-if headroom estimation
+# Headroom estimation
 # ---------------------------------------------------------------------------
 
 class HeadroomModel:
@@ -775,8 +775,8 @@ class HeadroomModel:
     static analyses at >= 2 batch sizes. Linear in the batch because every
     per-sample buffer (feeds, activations, logits) scales with b while
     params/opt-state/code do not; XLA padding and fusion keep it only
-    approximately linear — which is why what_if() validates the
-    extrapolation against a fresh analysis at the predicted batch.
+    approximately linear, so a prediction far from the fitted points
+    wants a fresh analysis at the predicted batch.
 
     For sharded programs both inputs are per-device numbers: the static
     analyses XLA returns for an SPMD module are post-partitioning, and
@@ -853,39 +853,6 @@ def default_budget(device=None) -> int:
         return v
     row = chip.peaks(device)
     return row.hbm_bytes if row else 16 * GiB
-
-
-def what_if(measure: Callable[[int], ProgramMemory],
-            batches: Sequence[int] = (8, 32),
-            budget_bytes: Optional[int] = None,
-            validate: bool = True,
-            max_validate_batch: Optional[int] = None) -> Dict[str, Any]:
-    """'Will batch B fit?' — fit a HeadroomModel from static analyses at
-    `batches`, predict the max batch under `budget_bytes`, then validate
-    the model by re-analyzing AT the predicted batch (a fresh XLA
-    compile, independent of the straight-line extrapolation) and
-    reporting the relative error. `measure(b)` must return the
-    ProgramMemory of the program compiled at batch b — e.g. a closure
-    over Executor.static_memory_analysis."""
-    points = []
-    for b in batches:
-        points.append((int(b), measure(int(b)).total_bytes))
-    model = HeadroomModel.fit(points)
-    budget = int(budget_bytes) if budget_bytes else default_budget()
-    bmax = model.max_batch(budget)
-    out: Dict[str, Any] = {"model": model.to_dict(),
-                           "budget_bytes": budget, "max_batch": bmax,
-                           "points": points}
-    if validate and bmax:
-        vb = bmax if max_validate_batch is None else min(
-            bmax, int(max_validate_batch))
-        measured = measure(vb).total_bytes
-        predicted = model.predict(vb)
-        out["validate_batch"] = vb
-        out["predicted_bytes"] = predicted
-        out["measured_bytes"] = measured
-        out["rel_err"] = abs(predicted - measured) / max(measured, 1)
-    return out
 
 
 # ---------------------------------------------------------------------------

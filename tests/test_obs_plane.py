@@ -267,11 +267,16 @@ def _assert_tiled(step, names):
     assert covered == pytest.approx(step["dur_s"], abs=4e-3)
 
 
-PHASES = ["prepare", "launch", "bookkeep", "writeback"]
+# the one dispatch sequence of run() and run_steps(), by the phase each
+# stretch is booked under: gather + lookup, the call, the launch's own
+# accounting + validation, the commit to the scope, the watchers, the
+# fetches' return
+PHASES = ["prepare", "launch", "bookkeep", "writeback", "bookkeep",
+          "writeback"]
 
 
 def test_executor_step_spans(monkeypatch):
-    """`step` covers the whole Executor.run and its four phases tile it.
+    """`step` covers the whole Executor.run and its phases tile it.
     The parent's `step` was made after the fact from the launch's length
     and ended at a clock read past the bookkeeping: it sat late by what
     it left out. Now the run's log_event, the last thing bookkept, falls
@@ -308,12 +313,14 @@ def test_executor_step_spans(monkeypatch):
         # clock reads, close to both
         assert before[i] <= step["start"] <= before[i] + 20e-3
         assert before[i + 1] - 20e-3 <= step["end"] <= before[i + 1]
-        launch, bookkeep = _children(step)[1:3]
-        assert launch["end"] <= booked[i] <= bookkeep["end"]
+        _, launch, _, commit, book, _ = _children(step)
+        # the watchers run after the state went back to the scope
+        assert launch["end"] <= commit["end"] <= book["start"]
+        assert book["start"] <= booked[i] <= book["end"]
     # the first run's launch holds jax's trace, lower and compile, at the
     # times jax measured them; the bookkeeping the analysis. No invented
     # `compile` child at the step's start any more.
-    cold_launch, cold_bookkeep = _children(steps[0])[1:3]
+    _, cold_launch, _, _, cold_bookkeep, _ = _children(steps[0])
     built = [s for s in tracing.recent_spans(trace_id=steps[0]["trace_id"])
              if s["parent_id"] == cold_launch["span_id"]]
     assert {"trace", "lower", "compile"} <= {s["name"] for s in built}
@@ -351,10 +358,10 @@ def test_step_spans_share_the_step_id_in_run_and_run_steps():
         family = tracing.recent_spans(trace_id=step["trace_id"])
         assert len(family) >= 5
         assert {s["step"] for s in family} == {step["step"]}
-    # the window path has run()'s phase names, in its own order (the state
-    # goes back to the scope before the watchers run)
-    _assert_tiled(steps[1], ["prepare", "launch", "writeback", "bookkeep",
-                             "writeback"])
+    # one sequence: a window is tiled as a step is (the state goes back to
+    # the scope before the watchers run)
+    for step in steps:
+        _assert_tiled(step, PHASES)
 
 
 def test_run_steps_fallback_steps_are_plain_steps():
@@ -377,6 +384,101 @@ def test_run_steps_fallback_steps_are_plain_steps():
     assert all(s["attrs"]["mode"] == "eager" for s in steps)
     for step in steps:
         _assert_tiled(step, PHASES)
+
+
+# --- the one dispatch path ---------------------------------------------------
+
+@pytest.mark.parametrize("mode, steps", [("jit", 1), ("eager", 1),
+                                         ("window", 3)])
+def test_one_dispatch_books_once_and_after_the_commit(mode, steps, tmp_path,
+                                                      monkeypatch):
+    """Whatever the mode, one call goes through one book: one profiler
+    host event, one run event, one flight record, one memory sample,
+    `steps` steps counted — and every one of them, the dynamics watcher
+    included, fires with the new state and the PRNG counter already in
+    the scope. Before PR 28 the per-step modes ran their watchers first
+    (dynamics.on_step waits on the device every 16th step) and wrote the
+    donated state back after."""
+    from paddle_tpu import dynamics, inspector, memory, profiler
+    main, startup, loss, feed = _tiny_trainer()
+    scope = executor_mod.Scope()
+    exe = fluid.Executor(fluid.CPUPlace())
+    param = main.global_block().all_parameters()[0].name
+    fired = []
+
+    def spy(module, name, label=None, only=lambda *a, **k: True):
+        real = getattr(module, name)
+
+        def wrapper(*args, **kwargs):
+            if only(*args, **kwargs):
+                fired.append((label or name,
+                              scope.find_var("__rng_counter__"),
+                              np.array(scope.find_var(param))))
+            return real(*args, **kwargs)
+        monkeypatch.setattr(module, name, wrapper)
+
+    def call():
+        if mode == "window":
+            return exe.run_steps(main, feed_window=[feed] * steps,
+                                 fetch_list=[loss])
+        return exe.run(main, feed=feed, fetch_list=[loss],
+                       use_jit=mode == "jit")
+
+    inspector.enable_flight_recorder(str(tmp_path / "crash.json"))
+    try:
+        with executor_mod.scope_guard(scope):
+            exe.run(startup)
+            scope.set_var("__rng_counter__", 15)    # a dynamics sample step
+            call()                                  # cold: builds the block
+            spy(profiler, "record_event")
+            spy(telemetry, "log_event", "run_event",
+                only=lambda kind, **f: kind in ("run", "run_window"))
+            spy(inspector, "record_step")
+            spy(memory, "on_run")
+            spy(dynamics, "on_step", "dynamics")
+            spy(dynamics, "on_window", "dynamics")
+            counter0 = scope.find_var("__rng_counter__")
+            param0 = np.array(scope.find_var(param))
+            steps0 = sum(telemetry.read_series(
+                "executor_steps_total").values())
+            call()
+            steps1 = sum(telemetry.read_series(
+                "executor_steps_total").values())
+            param1 = np.array(scope.find_var(param))
+    finally:
+        inspector.disable_flight_recorder()
+    names = [n for n, _, _ in fired]
+    for once in ("record_event", "run_event", "record_step", "on_run"):
+        assert names.count(once) == 1, names
+    assert names.count("dynamics") == (0 if mode == "eager" else 1), names
+    assert steps1 - steps0 == steps
+    assert not np.array_equal(param0, param1)       # SGD moved it
+    for name, counter, value in fired:
+        assert counter == counter0 + steps, (name, counter)
+        np.testing.assert_array_equal(value, param1, err_msg=name)
+
+
+def test_missing_state_is_one_error_for_every_entry_point():
+    """run(), the compile-only entry points and the serving seam read the
+    scope through one gather: the same scope gives the same error."""
+    main, startup, loss, feed = _tiny_trainer()
+    exe = fluid.Executor(fluid.CPUPlace())
+    scope = executor_mod.Scope()                    # startup never ran
+    raised = []
+    for entry in (
+            lambda: exe.run(main, feed=feed, fetch_list=[loss], scope=scope),
+            lambda: exe.run_steps(main, feed_window=[feed] * 2,
+                                  fetch_list=[loss], scope=scope),
+            lambda: exe._aot_block(main, feed, [loss], scope),
+            lambda: exe.compiled_hlo(main, feed=feed, fetch_list=[loss],
+                                     scope=scope),
+            lambda: exe.prepare_serving(main, sorted(feed), [loss.name],
+                                        scope)):
+        with pytest.raises(RuntimeError, match="run the startup program "
+                                               "first") as err:
+            entry()
+        raised.append(str(err.value))
+    assert len(set(raised)) == 1, raised
 
 
 def test_tracing_off_allocates_no_span(monkeypatch):
