@@ -910,9 +910,11 @@ def main_transformer():
     """Transformer-LM training step (models/transformer.py) with flash
     attention: tokens/sec + MFU. No reference counterpart (2018);
     vs_baseline is the ratio against the same model on the XLA einsum
-    attention path (use_flash=False): flash is meant to win from T=2048
-    up on top of its O(T) memory, and auto-selection keeps the einsum
-    path below that (ops/nn_ops._flash_auto_threshold)."""
+    attention path (use_flash=False): attention alone, the kernels win
+    from T=512 a device up on a v5e, on top of their O(T) memory (end to
+    end T=1024 is what a benchmark cell covers: PERF.md section 6, PR
+    29), and auto-selection keeps the einsum path below that
+    (ops/nn_ops._flash_wins)."""
     import jax
     import paddle_tpu as fluid
     from paddle_tpu import models

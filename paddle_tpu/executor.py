@@ -1443,7 +1443,11 @@ class Executor:
                 # a failure must never fail the training step
                 analysis_t0 = time.perf_counter()
                 try:
-                    with tracing_mod.span("analysis"):
+                    # under the launch's device context: jax keys its
+                    # trace and lowering caches on it, and outside it the
+                    # analysis traced and lowered the whole block again
+                    with tracing_mod.span("analysis"), \
+                            jax.default_device(self.device):
                         memory_mod.on_compile(
                             self, compiled, program, prog_label,
                             place_label, feed_vals, state_vals,

@@ -1087,11 +1087,12 @@ def fused_attention(q, k, v, causal=False,
     (parallel/ring_attention.py) for long-context training; use_flash=True
     runs the Pallas online-softmax VMEM kernel (ops/pallas_attention.py) —
     O(T) memory, scores never hit HBM. The default 'auto' picks per shape:
-    XLA einsum at short T (fuses into neighbors), flash at long T (env
-    PADDLE_TPU_FLASH_AUTO_T, ops/nn_ops._flash_auto_threshold); False
-    forces einsum. (Named fused_attention because reference-parity
-    nets.scaled_dot_product_attention already takes [B, T, D] with
-    num_heads and different semantics.)"""
+    XLA einsum at short T (fuses into neighbors), flash from the per-device
+    sequence length at which the kernels won on the chip
+    (ops/nn_ops._flash_wins); a shape the kernels do not tile keeps einsum
+    with a counted reason; False forces einsum. (Named fused_attention
+    because reference-parity nets.scaled_dot_product_attention already
+    takes [B, T, D] with num_heads and different semantics.)"""
     helper = LayerHelper("fused_attention")
     out = helper.create_tmp_variable(q.dtype)
     # per-row logsumexp residual for the explicit backward (dropout-Mask

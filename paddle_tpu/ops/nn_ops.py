@@ -805,15 +805,28 @@ def _sdpa_grad(fwd, no_grad_set):
         attrs=dict(fwd.attrs))]
 
 
-def _flash_auto_threshold():
-    """Sequence length at which auto-selection flips from the XLA einsum
-    path to the Pallas flash kernel: below it the einsum path fuses
-    better (the custom call is a fusion barrier), at/above it flash is
-    meant to win on top of its O(T) memory. The crossing was placed by a
-    sweep that predates PR 1; it is not measured on today's code
-    (bench.py BENCH_MODE=transformer is the comparison)."""
-    import os
-    return int(os.environ.get("PADDLE_TPU_FLASH_AUTO_T", "2048"))
+# Shortest per-device sequence that 'auto' hands to the flash kernels.
+# Attention alone on a v5e, causal bf16, 12 heads of 64, 16384 tokens a
+# call, forward + backward, ms (tools/flash_sweep.py, PR 29; PERF.md
+# section 6):
+#     T      128    256    512    1024   2048   4096
+#     einsum 0.64   1.81   3.20   6.62   12.25  22.73
+#     flash  2.33   2.04   2.24   3.66   4.17   7.05
+# The kernels' time follows the tokens, einsum's the T x T scores: they
+# cross between 256 and 512. These pairs are attention ALONE, at one
+# (H, D), read before the kernels' last layout (T=1024 reads 2.89 since).
+# End to end only T=1024 is covered: in GPT-2's step the kernels took
+# 28 ms where einsum took 81, and freed 3.3 GB; no benchmark cell runs
+# T=512 or sits on the einsum side of this line.
+_FLASH_AUTO_MIN_T = 512
+
+
+def _flash_wins(q) -> bool:
+    """The 'auto' rule, on the per-device [B, T, H, D] operand the
+    lowering sees: the flash kernels from the sequence length at which
+    they beat einsum attention on the chip, whose [B, H, T, T] scores
+    and probabilities cost HBM traffic and memory that grow with T^2."""
+    return q.shape[1] >= _FLASH_AUTO_MIN_T
 
 
 def _ring_uses_flash(op_, q, mesh):
@@ -873,28 +886,35 @@ def _flash_partition(program, q):
 
 def _sdpa_paths(ctx, op_, q, k, v, count=False):
     """(mode, how): 'ring' under sequence_parallel with an sp mesh (how =
-    the mesh), 'flash' when use_flash (True, or 'auto' at long T) and the
-    gate passes the per-device shape (how = _flash_partition's placement),
-    else 'einsum'. Auto-selection: the default config gets whichever path
-    is faster for its shape, no user flag. `count` books a declined flash
-    request under pallas_fallback_total (the forward op passes it; the
-    grad op recomputes the same static decision in silence)."""
+    the mesh), 'flash' when use_flash is True, or 'auto' and the rule
+    (_flash_wins) gives the per-device shape to the kernels, and their
+    gate passes that shape (how = _flash_partition's placement), else
+    'einsum'. Auto-selection: the default config gets whichever path
+    is faster for its shape, no user flag. `count` books the decision
+    of this lowering, as every Pallas gate does (the forward op passes
+    it; the grad op recomputes the same static decision in silence): a
+    lowering on the kernels under pallas_kernel_total, a declined flash
+    request under pallas_fallback_total with the gate's reason. A step
+    traced again (a new feed shape) lowers its ops again and counts
+    them again, with the decision of that shape."""
     from . import pallas_attention
     mesh = getattr(ctx.program, "_mesh", None)
     if op_.attr("sequence_parallel", False) and mesh is not None and \
             "sp" in mesh.axis_names:
         return "ring", mesh
     uf = op_.attr("use_flash", "auto")
-    if uf == "auto":
-        uf = q.shape[1] >= _flash_auto_threshold()
-    if uf:
-        partition, shard = _flash_partition(ctx.program, q)
-        reason = pallas_attention.ineligible(shard(q), shard(k), shard(v))
+    if not uf:
+        return "einsum", None
+    partition, shard = _flash_partition(ctx.program, q)
+    if uf == "auto" and not _flash_wins(shard(q)):
+        return "einsum", None
+    reason = pallas_attention.ineligible(shard(q), shard(k), shard(v))
+    if count:
         if reason is None:
-            return "flash", partition
-        if count:
+            pallas_attention.count_hit()
+        else:
             pallas_attention.count_fallback(reason)
-    return "einsum", None
+    return ("flash", partition) if reason is None else ("einsum", None)
 
 
 @op("scaled_dot_product_attention", infer_shape=_sdpa_infer,

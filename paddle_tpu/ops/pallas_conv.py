@@ -166,8 +166,11 @@ def count_hit(op: str):
     from .. import telemetry
     telemetry.counter(
         "pallas_kernel_total",
-        "conv lowerings served by a kernel of this suite (conv2d_q8 "
-        "under AMP O3; no bf16 conv since PR 25), by op",
+        "lowerings served by a Pallas kernel, by op: conv2d (conv2d_q8 "
+        "under AMP O3; no bf16 conv since PR 25) and "
+        "scaled_dot_product_attention (the flash kernels, booked by "
+        "ops/nn_ops._sdpa_paths for each lowering of a forward op; the "
+        "grad op books nothing)",
         labels=("op",)).labels(op=op).inc()
 
 

@@ -94,9 +94,10 @@ def test_grads_match_einsum_autodiff(use_flash):
 
 
 class TestAutoSelection:
-    """use_flash defaults to 'auto': einsum below the
-    threshold T (fuses into neighboring HLO), flash at/above it; explicit
-    True/False always wins."""
+    """use_flash defaults to 'auto': the rule (nn_ops._flash_wins) reads
+    the per-device shape the lowering sees, einsum below the sequence
+    length at which the kernels won on the chip, flash from it on;
+    explicit True/False always wins."""
 
     class _Op:
         def __init__(self, attrs):
@@ -110,43 +111,85 @@ class TestAutoSelection:
             _mesh = None
         program = _P()
 
-    def _mode(self, t, attrs, threshold=None, dtype="float32"):
-        import os
+    def _mode(self, t, attrs, heads=4, op_=None):
+        """The path of one [2, t, heads, 64] lowering; with `op_`, as
+        the forward op's lowering, which books its decision."""
         import jax
         import paddle_tpu.ops.nn_ops as nn_ops
-        probe = jax.ShapeDtypeStruct((2, t, 4, 64), dtype)
-        prev = os.environ.get("PADDLE_TPU_FLASH_AUTO_T")
-        if threshold is not None:
-            os.environ["PADDLE_TPU_FLASH_AUTO_T"] = str(threshold)
-        try:
-            mode, _ = nn_ops._sdpa_paths(self._Ctx(), self._Op(attrs),
-                                         probe, probe, probe)
-        finally:
-            if threshold is not None:
-                if prev is None:
-                    del os.environ["PADDLE_TPU_FLASH_AUTO_T"]
-                else:
-                    os.environ["PADDLE_TPU_FLASH_AUTO_T"] = prev
+        probe = jax.ShapeDtypeStruct((2, t, heads, 64), "bfloat16")
+        mode, _ = nn_ops._sdpa_paths(
+            self._Ctx(), op_ or self._Op(attrs), probe, probe, probe,
+            count=op_ is not None)
         return mode
 
-    def test_auto_short_t_takes_einsum(self):
-        assert self._mode(512, {"use_flash": "auto"},
-                          threshold=2048) == "einsum"
+    @pytest.mark.parametrize("t,attrs,heads,mode", [
+        (256, {"use_flash": "auto"}, 4, "einsum"),      # below the rule
+        (512, {"use_flash": "auto"}, 4, "flash"),       # from it on
+        (1024, {"use_flash": "auto"}, 12, "flash"),     # GPT-2's shape
+        (1024, {"use_flash": "auto"}, 10, "flash"),     # GPT-2 large, tp=2
+        (4096, {"use_flash": "auto"}, 4, "flash"),
+        (256, {"use_flash": True}, 4, "flash"),         # explicit wins
+        (8192, {"use_flash": False}, 4, "einsum"),
+        (100, {"use_flash": True}, 4, "einsum"),        # untileable
+        (1024, {"use_flash": "auto"}, 3, "einsum"),     # fills no block
+        (1024, {}, 12, "flash"),                        # default is auto
+    ])
+    def test_rule_reads_shape_and_attr(self, t, attrs, heads, mode):
+        assert self._mode(t, attrs, heads=heads) == mode
 
-    def test_auto_long_t_takes_flash(self):
-        assert self._mode(4096, {"use_flash": "auto"},
-                          threshold=2048) == "flash"
+    def test_rule_reads_the_per_device_shard(self):
+        """Under a mesh the rule and the gate see one device's block:
+        20 heads over tp=2 are 10 a chip (five lane blocks); 6 heads of
+        64 over tp=2 are 3 a chip, which fill no block."""
+        import jax
+        import numpy as np
+        from jax.sharding import Mesh
+        import paddle_tpu.ops.nn_ops as nn_ops
 
-    def test_explicit_true_forces_flash_below_threshold(self):
-        assert self._mode(512, {"use_flash": True},
-                          threshold=2048) == "flash"
+        class Ctx:
+            class program:
+                _mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2),
+                             ("fsdp", "tp"))
+                _sharding_plan = None
 
-    def test_explicit_false_forces_einsum_above_threshold(self):
-        assert self._mode(8192, {"use_flash": False},
-                          threshold=2048) == "einsum"
+        def mode(heads):
+            probe = jax.ShapeDtypeStruct((16, 1024, heads, 64), "bfloat16")
+            return nn_ops._sdpa_paths(Ctx(), self._Op({}), probe, probe,
+                                      probe)
 
-    def test_untileable_shape_falls_back_to_einsum(self):
-        assert self._mode(100, {"use_flash": True}) == "einsum"
+        took, placement = mode(20)
+        assert took == "flash" and placement.mesh is Ctx.program._mesh
+        assert mode(6) == ("einsum", None)
+
+    def test_lowering_books_hit_and_fallback(self):
+        """pallas_kernel_total for a lowering on the kernels (12 heads),
+        pallas_fallback_total with the gate's reason for one that asked
+        and was declined (3 heads of 64), nothing for einsum by rule or
+        by request. The counters count lowerings, as the conv gates'
+        do: a second trace of the same op books again."""
+        from paddle_tpu import telemetry
+        op = "op=scaled_dot_product_attention"
+
+        def read():
+            return (telemetry.read_series("pallas_kernel_total").get(op, 0),
+                    telemetry.read_series("pallas_fallback_total").get(
+                        op + ",reason=heads", 0))
+
+        hits, declined = read()
+        flash_op = self._Op({"use_flash": "auto"})
+        assert self._mode(1024, None, 12, op_=flash_op) == "flash"
+        assert read() == (hits + 1, declined)
+        assert self._mode(1024, None, 12, op_=flash_op) == "flash"
+        assert read() == (hits + 2, declined)
+        declined_op = self._Op({"use_flash": "auto"})
+        assert self._mode(1024, None, 3, op_=declined_op) == "einsum"
+        assert read() == (hits + 2, declined + 1)
+        for attrs, t in (({"use_flash": False}, 1024),
+                         ({"use_flash": "auto"}, 256)):
+            assert self._mode(t, None, 12, op_=self._Op(attrs)) == "einsum"
+        # the grad op's lowering decides again and books nothing
+        assert self._mode(1024, {"use_flash": "auto"}, 12) == "flash"
+        assert read() == (hits + 2, declined + 1)
 
     def test_default_attr_is_auto(self):
         import paddle_tpu as fluid
@@ -155,3 +198,38 @@ class TestAutoSelection:
         sdpa_op, = [op for op in main.global_block().ops
                     if op.type == "scaled_dot_product_attention"]
         assert sdpa_op.attr("use_flash") == "auto"
+
+
+def test_program_books_kernels_and_fallbacks():
+    """Through the executor: a 12-head program lowers onto the kernels
+    (pallas_kernel_total 1, no fallback: its step is traced once, the
+    analysis after the compile included), a program whose T does not tile
+    asks for flash and keeps einsum (pallas_fallback_total{reason=seq})."""
+    from paddle_tpu import telemetry
+    op = "op=scaled_dot_product_attention"
+
+    def run(shape):
+        main, startup = fluid.Program(), fluid.Program()
+        with fluid.program_guard(main, startup):
+            q = fluid.layers.data(name="q", shape=list(shape),
+                                  dtype="float32", append_batch_size=False)
+            out = fluid.layers.fused_attention(q, q, q, causal=True,
+                                               use_flash=True)
+        exe = fluid.Executor(fluid.CPUPlace())
+        x = np.random.default_rng(3).standard_normal(shape).astype(
+            np.float32)
+        with executor_mod.scope_guard(executor_mod.Scope()):
+            exe.run(main, feed={"q": x}, fetch_list=[out])
+
+    def read():
+        return (telemetry.read_series("pallas_kernel_total").get(op, 0),
+                sum(v for k, v in telemetry.read_series(
+                    "pallas_fallback_total").items() if k.startswith(op)),
+                telemetry.read_series("pallas_fallback_total").get(
+                    op + ",reason=seq", 0))
+
+    hits, declined, seq = read()
+    run((1, 128, 12, 64))
+    assert read() == (hits + 1, declined, seq)
+    run((1, 100, 12, 64))
+    assert read() == (hits + 1, declined + 1, seq + 1)

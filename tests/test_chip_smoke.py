@@ -50,8 +50,10 @@ def test_train_phase_tiny(trained):
     record, _ = trained
     assert record["phase"] == "train" and len(record["losses"]) == 3
     assert all(np.isfinite(record["losses"]))
-    # every conv is XLA's: no kernel family counted, none declined
-    assert record["pallas_kernel_total"] == {}
+    # every conv is XLA's: no conv kernel counted, none declined (the
+    # counter is the process's: an attention test that ran earlier in
+    # this worker has booked its own series in it)
+    assert not [k for k in record["pallas_kernel_total"] if "conv" in k]
     assert not [k for k in record["pallas_fallback_total"] if "conv2d" in k]
     json.dumps(record)                     # one JSON line
 
@@ -283,21 +285,21 @@ def test_conv_gate_declines_a_partitioned_step():
 
 
 def test_declined_flash_is_counted_and_takes_einsum():
-    """12 heads cannot be grouped by 8: use_flash=True keeps the einsum
-    path and books pallas_fallback_total{reason="heads"}."""
+    """Three heads of 64 fill no 128-lane block: use_flash=True keeps the
+    einsum path and books pallas_fallback_total{reason="heads"}."""
     import paddle_tpu as fluid
     from paddle_tpu import telemetry
 
     def run(use_flash):
         main, startup = fluid.Program(), fluid.Program()
         with fluid.program_guard(main, startup):
-            q = fluid.layers.data(name="q", shape=[-1, 16, 12, 8],
+            q = fluid.layers.data(name="q", shape=[-1, 16, 3, 64],
                                   append_batch_size=False)
             out = fluid.layers.fused_attention(q, q, q, causal=True,
                                                use_flash=use_flash)
         exe = fluid.Executor(fluid.CPUPlace())
         x = np.random.default_rng(1).standard_normal(
-            (2, 16, 12, 8)).astype(np.float32)
+            (2, 16, 3, 64)).astype(np.float32)
         return exe.run(main, feed={"q": x}, fetch_list=[out])[0]
 
     def declined():
@@ -308,18 +310,19 @@ def test_declined_flash_is_counted_and_takes_einsum():
     einsum = run(False)
     assert declined() == before            # nobody asked for flash
     np.testing.assert_array_equal(run(True), einsum)
-    assert declined() > before             # counted per trace
+    assert declined() == before + 1        # one op, lowered once
 
 
-def test_flash_head_groups_match_one_loop():
-    """16 heads walk the grid in two groups of 8; the answer is the
-    reference's, forward and backward."""
+def test_flash_head_blocks_match_reference():
+    """16 heads of 32 walk the grid in four 128-lane blocks of four
+    heads; the answer is the reference's, forward and backward."""
     from paddle_tpu.parallel.ring_attention import attention_reference
     rng = np.random.default_rng(2)
-    q, k, v = (jnp.asarray(rng.standard_normal((1, 128, 16, 8)),
+    q, k, v = (jnp.asarray(rng.standard_normal((1, 128, 16, 32)),
                            jnp.float32) for _ in range(3))
-    assert pallas_attention._head_block(16) == 8
-    assert pallas_attention._head_block(6) == 6
+    assert pallas_attention._lane_block(16, 32) == (128, 4)
+    assert pallas_attention._lane_block(12, 64) == (128, 2)
+    assert pallas_attention._lane_block(6, 8) == (48, 6)
 
     def loss(fn):
         return lambda q, k, v: jnp.sum(fn(q, k, v) ** 2)

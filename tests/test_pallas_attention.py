@@ -1,7 +1,8 @@
 """Pallas flash-attention kernel (ops/pallas_attention.py): online-softmax
 VMEM kernel vs the XLA reference. On the CPU test platform the kernel runs
 under the Pallas interpreter — the same code Mosaic compiles on TPU
-(measured r3: 1.5x over the XLA reference at T=4096 causal on v5e)."""
+(tests/test_tpu_compile.py compiles it for a described v5e at the
+benchmark cells' shapes; PERF.md section 6, PR 29 has its chip times)."""
 
 import numpy as np
 import pytest
@@ -10,6 +11,7 @@ import jax
 import jax.numpy as jnp
 
 import paddle_tpu as fluid
+from paddle_tpu.ops import pallas_attention
 from paddle_tpu.ops.pallas_attention import flash_attention, supports
 from paddle_tpu.parallel.ring_attention import attention_reference
 
@@ -22,8 +24,11 @@ def _qkv(b, t, h, d):
 
 
 class TestFlashKernel:
+    # 12 and 10 heads of 64 are the benchmark's GPT-2 cells (10 = GPT-2
+    # large's 20 over tp=2): six and five lane blocks of two heads
     @pytest.mark.parametrize("shape", [(2, 64, 2, 32), (1, 128, 4, 64),
-                                       (2, 256, 2, 64)])
+                                       (2, 256, 2, 64), (1, 256, 12, 64),
+                                       (1, 256, 10, 64), (1, 256, 8, 32)])
     @pytest.mark.parametrize("causal", [False, True])
     def test_matches_reference(self, shape, causal):
         q, k, v = _qkv(*shape)
@@ -35,7 +40,9 @@ class TestFlashKernel:
             np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                        rtol=2e-5, atol=2e-6)
 
-    @pytest.mark.parametrize("shape", [(1, 128, 2, 32), (2, 256, 2, 64)])
+    @pytest.mark.parametrize("shape", [(1, 128, 2, 32), (2, 256, 2, 64),
+                                       (1, 256, 12, 64), (1, 256, 10, 64),
+                                       (1, 256, 8, 32)])
     @pytest.mark.parametrize("causal", [False, True])
     def test_gradients_match_reference(self, shape, causal):
         """The Pallas flash backward (dQ/dK/dV kernels recomputing from the
@@ -65,6 +72,15 @@ class TestFlashKernel:
         assert not supports(*_qkv(1, 257, 1, 64))
         q3 = jnp.zeros((2, 64, 32))
         assert not supports(q3, q3, q3)
+        # heads ride the grid in 128-lane blocks: any count that fills
+        # them passes, D must divide 128 or be a multiple of it
+        assert supports(*_qkv(1, 256, 12, 64))
+        assert supports(*_qkv(1, 256, 10, 64))
+        assert supports(*_qkv(1, 256, 12, 32))
+        assert pallas_attention.ineligible(*_qkv(1, 256, 3, 64)) == "heads"
+        assert pallas_attention.ineligible(*_qkv(1, 256, 6, 32)) == "heads"
+        assert pallas_attention.ineligible(*_qkv(1, 256, 4, 96)) == \
+            "head_dim"
 
     def test_sub128_untiled_path_matches(self):
         q, k, v = _qkv(1, 104, 1, 32)       # block = T = 104 (untiled)
@@ -74,19 +90,53 @@ class TestFlashKernel:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-6)
 
-    def test_saved_lse_matches_reference(self):
+    @pytest.mark.parametrize("shape", [(1, 128, 2, 32), (1, 256, 12, 64),
+                                       (1, 256, 10, 64)])
+    @pytest.mark.parametrize("causal", [False, True])
+    def test_saved_lse_matches_reference(self, shape, causal):
         """The forward's saved logsumexp equals log-sum-exp of the scaled
         (masked) scores — the invariant the backward kernels rely on."""
         from paddle_tpu.ops.pallas_attention import _forward
-        q, k, v = _qkv(1, 128, 2, 32)
+        q, k, v = _qkv(*shape)
+        t = shape[1]
         with jax.default_matmul_precision("highest"):
-            _, lse = _forward(q, k, v, True, return_lse=True)
+            _, lse = _forward(q, k, v, causal, return_lse=True)
             s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
-            mask = jnp.tril(jnp.ones((128, 128), bool))
-            s = jnp.where(mask, s, -jnp.inf)
+            if causal:
+                s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
             want = jax.scipy.special.logsumexp(s, axis=-1)
         np.testing.assert_allclose(np.asarray(lse), np.asarray(want),
                                    rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("causal", [False, True])
+    def test_major_tiles_carry_across_the_grid(self, causal):
+        """Past `major` rows the walked side is a grid axis: the carries
+        live in scratch, a causally dead major tile is clamped in the
+        index map and never walked. Cut to 128 rows here (the wrappers
+        take tiles and `major` as static arguments) so that T=512 walks
+        four major tiles of one block each."""
+        q, k, v = _qkv(1, 512, 2, 64)
+        scale = 1.0 / np.sqrt(q.shape[-1])
+
+        def loss(a, b, c):
+            return jnp.sum(attention_reference(a, b, c, causal=causal) ** 2)
+
+        with jax.default_matmul_precision("highest"):
+            got, (lse,) = pallas_attention._fwd_call(
+                q, k, v, 0, 0, scale, causal, normalize=True,
+                tile=(256, 128), major=128)
+            want = attention_reference(q, k, v, causal=causal)
+            do = 2 * got
+            delta = jnp.sum(do * got, axis=-1).transpose(0, 2, 1)
+            g1 = pallas_attention.flash_attention_bwd_block(
+                q, k, v, do, lse, delta, 0, 0, scale, causal,
+                dq_tile=(128, 128), dkv_tile=(256, 128), major=128)
+            g2 = jax.grad(loss, (0, 1, 2))(q, k, v)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-5, atol=2e-6)
+        for a, b in zip(g1, g2):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-3, atol=1e-3)
 
 
 class TestFlashThroughProgram:

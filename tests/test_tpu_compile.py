@@ -2,7 +2,7 @@
 that is described and not attached (on-chip-measurement guide, section 2).
 
 Interpret mode cannot see what Mosaic refuses: a strided value slice, a
-head loop that outgrows VMEM. Every shape the gates pass must compile
+tile that outgrows VMEM. Every shape the gates pass must compile
 here; every shape a gate declines is pinned by its reason instead. This
 is the only file that describes a chip: the topology call lives in a
 module-scoped fixture (never at import, in a skipif or in parametrize),
@@ -159,14 +159,16 @@ def _flash_fwd_bwd(q, k, v):
     return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
 
 
-# T=2048 is the smallest length with the wide (512/1024) tiles, where 16
-# heads in one unrolled loop ran out of VMEM; the gate now walks them in
-# groups of 8, so VMEM no longer grows with H. A tile of heads x D past
-# 1024 lanes is refused too (the forward at 8 x 256), so D=256 passes the
-# gate with 4 heads, the widest it admits.
-@pytest.mark.parametrize("shape", [(1, 1024, 8, 64), (1, 2048, 16, 64),
-                                   (1, 2048, 4, 256)],
-                         ids=["8_heads", "16_heads", "head_dim_256"])
+# The cells' per-device shapes first: GPT-2 (16 sequences, 12 heads) and
+# GPT-2 large on fsdp=2 x tp=2 (8 sequences, 10 of 20 heads a chip). Heads
+# ride a grid axis in blocks of 128 lanes, so the count no longer grows
+# VMEM (16 heads in one unrolled loop were refused at T=2048 before PR
+# 29); D=256 is a lane block of its own; T=4096 walks two major tiles.
+@pytest.mark.parametrize("shape", [(16, 1024, 12, 64), (8, 1024, 10, 64),
+                                   (1, 1024, 8, 64), (1, 2048, 16, 64),
+                                   (1, 2048, 4, 256), (1, 4096, 8, 32)],
+                         ids=["gpt2", "gpt2_large_shard", "8_heads",
+                              "16_heads", "head_dim_256", "two_major_d32"])
 def test_flash_fwd_bwd_compiles(mosaic, one_chip, shape):
     q = jax.ShapeDtypeStruct(shape, BF16)
     assert pallas_attention.ineligible(q, q, q) is None
@@ -175,17 +177,18 @@ def test_flash_fwd_bwd_compiles(mosaic, one_chip, shape):
 
 
 @pytest.mark.parametrize("shape,reason", [
-    ((1, 2048, 12, 64), "heads"),        # > 8 and not a multiple of 8
-    ((1, 2048, 8, 256), "head_dim"),     # 8 x 256 lanes: forward refused
-    ((1, 2048, 16, 128), None),          # two groups of 8 x 128
+    ((1, 2048, 12, 64), None),           # six blocks of two heads
+    ((1, 2048, 3, 64), "heads"),         # 192 lanes fill no 128-lane block
+    ((1, 2048, 8, 96), "head_dim"),      # 96 neither divides 128 nor is
+                                         # a multiple of it
+    ((1, 2048, 8, 256), None),
     ((1, 2048, 2, 512), None),
     ((1, 2050, 8, 64), "seq"),
+    ((2048, 8, 64), "shape"),
 ])
 def test_flash_gate_declines(shape, reason):
     """What the gate declines takes the einsum path with a counted reason
-    (pallas_fallback_total); nothing here reaches the compiler (the
-    shapes passed here, reason None, were compiled for this chip while
-    PR 21 was written, 29 s and 24 s: too long to keep)."""
+    (pallas_fallback_total); nothing here reaches the compiler."""
     q = jax.ShapeDtypeStruct(shape, BF16)
     assert pallas_attention.ineligible(q, q, q) == reason
     assert reason is None or reason in pallas_attention.FALLBACK_REASONS
