@@ -9,6 +9,7 @@ source-compat alias), optimizers-as-ops, save/load, readers and datasets.
 """
 
 from .framework import (Program, Block, Variable, Parameter, program_guard,
+                        name_scope,
                         default_main_program, default_startup_program,
                         switch_main_program, switch_startup_program,
                         unique_name)

@@ -140,6 +140,10 @@ class PassContext:
             self.fetches_explicit = True
         self.fetches = [f if isinstance(f, str) else getattr(f, "name", str(f))
                         for f in fetches]
+        # the executor fetches the telemetry side-fetches with every step
+        self.fetches += sorted(
+            n for n in (getattr(program, "_telemetry_fetch_extra", None)
+                        or {}).values() if n not in self.fetches)
         self.diagnostics: List[Diagnostic] = []
         self._pass_name = ""
 

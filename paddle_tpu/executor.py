@@ -18,6 +18,7 @@ in the global scope across runs; block-local temporaries vanish after the run.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import functools
 import os
@@ -39,7 +40,8 @@ from . import sentinel as sentinel_mod
 from . import telemetry
 from . import tracing as tracing_mod
 from .framework.desc import VarType
-from .framework.framework import Program, Variable, default_main_program
+from .framework.framework import (NAME_SCOPE_ATTR, Program, Variable,
+                                  default_main_program)
 from .ops import registry
 from .ops import sparse_ops as sparse_ops_mod
 
@@ -725,6 +727,8 @@ class Executor:
         self.device = place_device(self.place)
         self._cache: Dict[Tuple, _CompiledBlock] = {}
         self._analysis_cache: Dict[Tuple, Tuple] = {}
+        # telemetry side-fetches dispatched and not yet on the host
+        self._side_pending: collections.deque = collections.deque()
 
     # --- public API ---------------------------------------------------------
     def run(self, program: Optional[Program] = None, feed: Optional[Dict] = None,
@@ -1267,8 +1271,10 @@ class Executor:
                    list(zip(side_fetches, fetch_vals[n_fetch:n_keep])),
                    internal_run, prog_label, place_label)
         tracing_mod.phase("writeback")
-        return _rebuild_fetches(fetch_names[:n_fetch], fetch_vals[:n_fetch],
-                                fetch_lens, return_numpy)
+        fetched = _rebuild_fetches(fetch_names[:n_fetch], fetch_vals[:n_fetch],
+                                   fetch_lens, return_numpy)
+        self._publish_side_fetches()    # on the host by now if the fetches are
+        return fetched
 
     def _launch(self, program, compiled, call, key, mode, steps, feed_vals,
                 state_vals, rng_counter, plan_s, prog_label, place_label):
@@ -1516,16 +1522,11 @@ class Executor:
                     self, program, prog_label, feed_vals, state_vals)
             except Exception:
                 hbm_sample = None
-        for (metric, _n), val in side_fetched:
-            # the telemetry side-fetches are gauges, not user outputs;
-            # float() forces the device read — the documented cost of
-            # _telemetry_fetch_extra (PADDLE_TPU_TELEMETRY_FETCH=0 disables)
-            try:
-                telemetry.gauge(metric, labels=("program",)).labels(
-                    program=prog_label).set(
-                        float(np.asarray(val).ravel()[0]))
-            except (TypeError, ValueError, IndexError):
-                pass
+        # the flight recorder below wants this step's global norm: it waits
+        self._side_pending.extend(
+            (metric, val, prog_label) for (metric, _n), val in side_fetched)
+        self._publish_side_fetches(
+            wait=not internal_run and inspector_mod.flight_enabled())
         if not internal_run and inspector_mod.flight_enabled():
             # flight recorder: one bounded ring record per step or window
             # (after the gauges above so the global norm is this step's; a
@@ -1542,6 +1543,40 @@ class Executor:
                 record["global_norm"] = telemetry.read_gauge(
                     "optimizer_global_norm", program=prog_label)
             inspector_mod.record_step(program, prog_label, record)
+
+    def _publish_side_fetches(self, wait=False):
+        """The telemetry side-fetches (program._telemetry_fetch_extra;
+        PADDLE_TPU_TELEMETRY_FETCH=0 disables) queued by this step and by
+        earlier ones, published without waiting on the device unless
+        `wait`: a value still in flight stays queued until a later call
+        finds it ready, so a pipelined loop keeps its steps in flight; a
+        synchronous one (return_numpy) publishes its own step's values
+        once its fetches are on the host. A metric the catalog lists as a
+        histogram takes a sample a step, any other is a gauge; one that
+        carries the `layer` label takes one series per element of its
+        vector. Each publication is also a `side_fetch` event of the step
+        log (telemetry.recent_events), in step order: a histogram keeps
+        no order, and a reader of a window's tail needs one."""
+        while self._side_pending:
+            metric, val, label = self._side_pending[0]
+            if not (wait or getattr(val, "is_ready", lambda: True)()):
+                break
+            self._side_pending.popleft()
+            try:
+                values = np.asarray(val, np.float64).ravel()
+            except (TypeError, ValueError):
+                continue
+            telemetry.log_event("side_fetch", program=label, metric=metric,
+                                values=values.tolist())
+            spec = telemetry.METRIC_CATALOG.get(metric, {})
+            by_layer = "layer" in spec.get("labels", ())
+            hist = spec.get("kind") == "histogram"
+            family = (telemetry.histogram if hist else telemetry.gauge)(
+                metric, labels=spec.get("labels", ("program",)))
+            for i, v in enumerate(values if by_layer else values[:1]):
+                extra = {"layer": str(i)} if by_layer else {}
+                child = family.labels(program=label, **extra)
+                child.observe(float(v)) if hist else child.set(float(v))
 
     def _raise_nonfinite(self, program, name, arr, feed, new_state,
                          rng_counter, scope, prog_label):
@@ -1576,6 +1611,7 @@ class Executor:
                                  feed))
 
     def close(self):
+        self._publish_side_fetches(wait=True)   # the last steps' values
         self._cache.clear()
         self._analysis_cache.clear()
 
@@ -1705,9 +1741,15 @@ class Executor:
             # "pd.<type>" (profiler._print_device_table,
             # xplane.hlo_op_names) and the outermost "pd_role.<op_role>"
             # (benchmarks/program_trace.py; spelt so that no "pd." rule
-            # sees it). A fused op's members keep the fused op's role.
+            # sees it). A fused op's members keep the fused op's role. An
+            # op built under fluid.name_scope also carries
+            # "pd_scope.<outer.inner>" between the two, spelt likewise.
+            built_under = op.desc.attrs.get(NAME_SCOPE_ATTR)
             with jax.named_scope(_ROLE_SCOPE.get(op.desc.attrs.get("op_role"),
                                                  _ROLE_SCOPE[None])), \
+                    (jax.named_scope("pd_scope." + built_under.strip(
+                        "/").replace("/", ".")) if built_under
+                     else contextlib.nullcontext()), \
                     jax.named_scope(f"pd.{op.type}"):
                 outs = opdef.lower(ctx, op, ins)
         except (AssertionError, TypeError, ValueError, IndexError) as e:

@@ -33,6 +33,7 @@ __all__ = [
     "switch_main_program",
     "switch_startup_program",
     "program_guard",
+    "name_scope",
     "grad_var_name",
     "GRAD_VAR_SUFFIX",
 ]
@@ -338,8 +339,11 @@ class Block:
                 out[k] = [x.name if isinstance(x, Variable) else x for x in v]
             return out
 
+        attrs = dict(attrs or {})
+        if _name_scopes:
+            attrs.setdefault(NAME_SCOPE_ATTR, "/%s/" % "/".join(_name_scopes))
         return OpDesc(type=type, inputs=norm(inputs), outputs=norm(outputs),
-                      attrs=dict(attrs or {}))
+                      attrs=attrs)
 
     def append_op(self, type: str, inputs=None, outputs=None, attrs=None) -> Operator:
         desc = self._make_op(type, inputs, outputs, attrs)
@@ -628,6 +632,25 @@ def switch_startup_program(program: Program) -> Program:
     global _startup_program
     old, _startup_program = _startup_program, program
     return old
+
+
+NAME_SCOPE_ATTR = "op_namescope"
+_name_scopes: List[str] = []
+
+
+@contextlib.contextmanager
+def name_scope(prefix: str):
+    """fluid.name_scope (reference framework.py name_scope): every op
+    appended inside carries the attribute `op_namescope` = "/outer/inner/",
+    and so does its gradient op, which copies its forward op's attributes.
+    The executor lowers it as a named scope `pd_scope.<outer.inner>`
+    between the op's role and its type, so a trace can book a `mul` to
+    the layer that built it (benchmarks/rooflines.py scope_seconds)."""
+    _name_scopes.append(str(prefix).strip("/"))
+    try:
+        yield
+    finally:
+        _name_scopes.pop()
 
 
 @contextlib.contextmanager

@@ -10,7 +10,8 @@ import numpy as np
 __all__ = [
     "Constant", "Uniform", "Normal", "Xavier", "MSRA",
     "ConstantInitializer", "UniformInitializer", "NormalInitializer",
-    "XavierInitializer", "MSRAInitializer", "force_init_on_cpu",
+    "XavierInitializer", "MSRAInitializer", "LogOfUniformInitializer",
+    "SoftplusInverseLogUniformInitializer", "force_init_on_cpu",
     "init_on_cpu",
 ]
 
@@ -117,6 +118,43 @@ class MSRAInitializer(Initializer):
             return UniformInitializer(-limit, limit, self.seed)(var, block)
         std = math.sqrt(2.0 / fi)
         return NormalInitializer(0.0, std, self.seed)(var, block)
+
+
+def _in_place(block, var, op_type, **attrs):
+    return block.append_op(type=op_type, inputs={"X": var},
+                           outputs={"Out": var}, attrs=attrs)
+
+
+class LogOfUniformInitializer(Initializer):
+    """log(u), u ~ U(low, high): Mamba-2's `A_log` (A = -exp(A_log) is
+    then -u; published range 1 to 16)."""
+
+    def __init__(self, low=1.0, high=16.0, seed=0):
+        self.low, self.high, self.seed = low, high, seed
+
+    def __call__(self, var, block):
+        UniformInitializer(self.low, self.high, self.seed)(var, block)
+        return _in_place(block, var, "log")
+
+
+class SoftplusInverseLogUniformInitializer(Initializer):
+    """softplus^-1(dt0) = log(exp(dt0) - 1) with dt0 log-uniform in
+    [low, high] and floored at `floor`: Mamba-2's `dt_bias`, so that the
+    step size softplus(dt_bias) starts at dt0 (published 0.001 to 0.1,
+    floor 1e-4). In float32 exp(dt0) - 1 is off by up to 1e-3 of itself
+    at the floor: noise in an initial value, not in the model."""
+
+    def __init__(self, low=0.001, high=0.1, floor=1e-4, seed=0):
+        self.low, self.high, self.floor, self.seed = low, high, floor, seed
+
+    def __call__(self, var, block):
+        UniformInitializer(math.log(self.low), math.log(self.high),
+                           self.seed)(var, block)
+        _in_place(block, var, "exp")
+        _in_place(block, var, "clip", min=self.floor, max=float("inf"))
+        _in_place(block, var, "exp")
+        _in_place(block, var, "scale", scale=1.0, bias=-1.0)
+        return _in_place(block, var, "log")
 
 
 Constant = ConstantInitializer

@@ -5,12 +5,15 @@ matmul :2428, softmax_with_cross_entropy :3135, one_hot :3254 …)."""
 
 from __future__ import annotations
 
+import functools
 import math
 
 import numpy as np
 
-from ..framework.framework import Variable
-from ..initializer import ConstantInitializer, NormalInitializer
+from ..framework.framework import Variable, name_scope
+from ..initializer import (ConstantInitializer, LogOfUniformInitializer,
+                           NormalInitializer,
+                           SoftplusInverseLogUniformInitializer)
 from ..layer_helper import LayerHelper
 
 __all__ = [
@@ -32,7 +35,7 @@ __all__ = [
     "precision_recall", "positive_negative_pair", "pool3d", "roi_pool",
     "prelu", "crop", "spp", "unpool", "conv3d_transpose",
     "max_pool2d_with_index", "conv_shift", "l1_norm",
-    "fused_attention", "sparse_moe",
+    "fused_attention", "sparse_moe", "rms_norm", "mamba2_mixer", "moe_block",
 
     "hsigmoid", "bilinear_interp", "selective_fc",
 ]
@@ -1082,8 +1085,13 @@ def l1_norm(x, name=None):
 
 def fused_attention(q, k, v, causal=False,
                     sequence_parallel=False, use_flash="auto", name=None):
-    """Fused attention over [B, T, H, D] tensors; sequence_parallel=True
-    runs ring attention over the program mesh's 'sp' axis
+    """Fused attention over [B, T, H, D] tensors. K and V may have fewer
+    heads than Q (grouped-query attention): H_kv must divide H, and query
+    head j reads K/V head j // (H / H_kv). The op repeats K and V to H
+    heads before it chooses a path, so the flash kernels' gate sees equal
+    head counts and books its hit or fallback reason as for full
+    attention, and the grad op sums dK and dV over each group.
+    sequence_parallel=True runs ring attention over the program mesh's 'sp' axis
     (parallel/ring_attention.py) for long-context training; use_flash=True
     runs the Pallas online-softmax VMEM kernel (ops/pallas_attention.py) —
     O(T) memory, scores never hit HBM. The default 'auto' picks per shape:
@@ -1110,9 +1118,11 @@ def fused_attention(q, k, v, causal=False,
 def sparse_moe(x, num_experts, hidden_size, capacity_factor=1.25,
                param_attr=None, name=None):
     """Top-1 gated mixture-of-experts FFN over [N, D] tokens (GShard-style
-    dispatch; see ops/nn_ops.py moe_ffn). Shard the returned layer's W1/W2
-    over an 'ep' mesh axis with parallel.shard_parameter for expert
-    parallelism."""
+    dispatch with a capacity that drops; see ops/nn_ops.py moe_ffn). Shard
+    the returned layer's W1/W2 over an 'ep' mesh axis with
+    parallel.shard_parameter for expert parallelism. For top-k routing
+    that drops no token, a shared expert and a layer that holds a share
+    of the experts, see moe_block."""
     helper = LayerHelper("sparse_moe", param_attr=param_attr)
     d = x.shape[-1]
     # one ParamAttr instance per parameter: create_parameter binds the
@@ -1140,6 +1150,169 @@ def sparse_moe(x, num_experts, hidden_size, capacity_factor=1.25,
                              "W1": [w1], "W2": [w2]},
                      outputs={"Out": [out]},
                      attrs={"capacity_factor": capacity_factor})
+    return out
+
+
+def rms_norm(input, gate=None, groups=1, epsilon=1e-5, param_attr=None,
+             name=None):
+    """weight * x / sqrt(mean(x^2) + epsilon) over the last axis, or over
+    each of `groups` equal slices of it (one weight of the full width
+    either way). With `gate` the input is x * silu(gate) first: Mamba-2's
+    gated norm. Statistics in float32 under AMP."""
+    helper = LayerHelper("rms_norm", param_attr=param_attr, name=name)
+    width = int(input.shape[-1])
+    assert width % groups == 0, (width, groups)
+    scale = helper.create_parameter(
+        attr=helper.param_attr, shape=[width], dtype=input.dtype,
+        default_initializer=ConstantInitializer(1.0))
+    inputs = {"X": [input], "Scale": [scale]}
+    if gate is not None:
+        inputs["Gate"] = [gate]
+    out = helper.create_tmp_variable(input.dtype)
+    helper.append_op(type="rms_norm", inputs=inputs, outputs={"Out": [out]},
+                     attrs={"epsilon": epsilon, "groups": groups})
+    return out
+
+
+def _under_its_name(layer):
+    """Build the layer's ops under fluid.name_scope(<the layer's name>), so
+    that a device trace books every op of it, its `mul`s too, and their
+    gradients to the layer (executor._exec_op's `pd_scope.<name>`)."""
+    @functools.wraps(layer)
+    def build(*args, **kwargs):
+        with name_scope(layer.__name__):
+            return layer(*args, **kwargs)
+    return build
+
+
+def _linear(x, size, scale=0.02, act=None):
+    """A [B, T, in] -> [B, T, size] map with no bias, N(0, scale) weights."""
+    from ..param_attr import ParamAttr
+    return fc(input=x, size=size, num_flatten_dims=2, bias_attr=False, act=act,
+              param_attr=ParamAttr(initializer=NormalInitializer(scale=scale)))
+
+
+@_under_its_name
+def mamba2_mixer(x, num_heads, head_dim, n_groups, state_size, conv_kernel=4,
+                 chunk_size=128, epsilon=1e-5, out_scale=0.02, name=None):
+    """Mamba-2 mixer (Dao & Gu 2024, as nemotron_h's) over x [B, T, D]:
+    [z | xBC | dt] = x W_in of widths d_inner | d_inner + 2 G N | H with
+    d_inner = num_heads * head_dim; xBC through a causal depthwise conv
+    and silu, split into x_s [T, H, P], B and C [T, G, N]; the selective
+    scan (ops/hybrid_ops.py ssd_scan: dt = softplus(dt + dt_bias), A =
+    -exp(A_log), skip D); rms_norm(y, gate=z) in G groups; W_out back to
+    D. A_log, dt_bias and D start as published (log U(1, 16); softplus^-1
+    of a log-uniform step in [0.001, 0.1]; 1). No bias in either map."""
+    helper = LayerHelper("mamba2_mixer", name=name)
+    seqlen, d_model = int(x.shape[1]), int(x.shape[2])
+    d_inner, bc = num_heads * head_dim, n_groups * state_size
+    proj = _linear(x, 2 * d_inner + 2 * bc + num_heads)
+    z, xbc, dt = split(proj, [d_inner, d_inner + 2 * bc, num_heads], dim=2)
+
+    dtype = x.dtype
+    conv_w = helper.create_parameter(
+        attr=None, shape=[d_inner + 2 * bc, conv_kernel], dtype=dtype,
+        default_initializer=NormalInitializer(scale=conv_kernel ** -0.5))
+    conv_b = helper.create_parameter(attr=None, shape=[d_inner + 2 * bc],
+                                     dtype=dtype, is_bias=True)
+    conved = helper.create_tmp_variable(dtype)
+    helper.append_op(type="causal_conv1d",
+                     inputs={"X": [xbc], "Filter": [conv_w],
+                             "Bias": [conv_b]},
+                     outputs={"Out": [conved]}, attrs={})
+    xs, b, c = split(conved, [d_inner, bc, bc], dim=2)
+
+    dt_bias = helper.create_parameter(
+        attr=None, shape=[num_heads], dtype=dtype,
+        default_initializer=SoftplusInverseLogUniformInitializer())
+    a_log = helper.create_parameter(
+        attr=None, shape=[num_heads], dtype=dtype,
+        default_initializer=LogOfUniformInitializer())
+    skip = helper.create_parameter(
+        attr=None, shape=[num_heads], dtype=dtype,
+        default_initializer=ConstantInitializer(1.0))
+    y = helper.create_tmp_variable(dtype)
+    helper.append_op(
+        type="ssd_scan",
+        inputs={"X": [reshape(xs, [-1, seqlen, num_heads, head_dim])],
+                "Dt": [dt], "DtBias": [dt_bias], "ALog": [a_log],
+                "B": [reshape(b, [-1, seqlen, n_groups, state_size])],
+                "C": [reshape(c, [-1, seqlen, n_groups, state_size])],
+                "D": [skip]},
+        outputs={"Out": [y]}, attrs={"chunk_size": chunk_size})
+    y = rms_norm(reshape(y, [-1, seqlen, d_inner]), gate=z, groups=n_groups,
+                 epsilon=epsilon)
+    return _linear(y, d_model, scale=out_scale)
+
+
+@_under_its_name
+def moe_block(x, num_experts, top_k, expert_width, shared_width=0,
+              experts_held=None, expert_offset=0, scaling=1.0,
+              norm_topk_prob=True, out_scale=0.02, stats=None, name=None):
+    """Mixture-of-experts feed-forward over x [B, T, D] with a sigmoid
+    top-k router, squared-ReLU experts and a shared expert:
+
+        s = sigmoid(x W_r) in float32; the top_k of s + b are chosen (b: a
+        selection bias, a buffer that starts at zero and takes no
+        gradient); g_i = scaling * s_i / (sum of the chosen s + 1e-20)
+        out = sum_i g_i f_{e_i}(x) + f_shared(x),  f(x) = relu(x U)^2 V
+
+    The layer is told its share: it holds `experts_held` of `num_experts`
+    from `expert_offset` on (default: all), routes over all of them, and
+    adds only what its own experts give (ops/hybrid_ops.py moe_experts:
+    no token dropped, static shapes, a grouped product over the rows
+    actually routed here); the shared expert is applied to every token.
+    `stats`: a list that receives this layer's (rows routed to held
+    experts, rows combined, busiest held expert over their mean)
+    Variables, each [1]."""
+    from ..param_attr import ParamAttr
+    helper = LayerHelper("moe_block", name=name)
+    seqlen, d_model = int(x.shape[1]), int(x.shape[2])
+    held = num_experts if experts_held is None else experts_held
+    assert 0 <= expert_offset and expert_offset + held <= num_experts
+    dtype = x.dtype
+    tokens = reshape(x, [-1, d_model])
+
+    router_w = helper.create_parameter(
+        attr=None, shape=[d_model, num_experts], dtype=dtype,
+        default_initializer=NormalInitializer(scale=0.02))
+    router_b = helper.create_parameter(
+        attr=ParamAttr(trainable=False), shape=[num_experts], dtype=dtype,
+        default_initializer=ConstantInitializer(0.0))
+    idx = helper.create_tmp_variable("int32", stop_gradient=True)
+    weight = helper.create_tmp_variable("float32")
+    helper.append_op(type="moe_router",
+                     inputs={"X": [tokens], "W": [router_w],
+                             "Bias": [router_b]},
+                     outputs={"TopkIdx": [idx], "TopkWeight": [weight]},
+                     attrs={"top_k": top_k, "scaling": scaling,
+                            "norm_topk_prob": norm_topk_prob})
+
+    up = helper.create_parameter(
+        attr=None, shape=[held, d_model, expert_width], dtype=dtype,
+        default_initializer=NormalInitializer(scale=0.02))
+    down = helper.create_parameter(
+        attr=None, shape=[held, expert_width, d_model], dtype=dtype,
+        default_initializer=NormalInitializer(scale=out_scale))
+    routed = helper.create_tmp_variable(dtype)
+    rows, combined, load = (
+        helper.create_tmp_variable("float32", stop_gradient=True)
+        for _ in range(3))
+    helper.append_op(type="moe_experts",
+                     inputs={"X": [tokens], "TopkIdx": [idx],
+                             "TopkWeight": [weight], "W1": [up],
+                             "W2": [down]},
+                     outputs={"Out": [routed], "RowsRouted": [rows],
+                              "RowsCombined": [combined],
+                              "LoadMaxOverMean": [load]},
+                     attrs={"num_experts": num_experts, "experts_held": held,
+                            "expert_offset": expert_offset, "top_k": top_k})
+    if stats is not None:
+        stats.append((rows, combined, load))
+    out = reshape(routed, [-1, seqlen, d_model])
+    if shared_width:
+        hidden = _linear(x, shared_width, act="relu2")
+        out = elementwise_add(out, _linear(hidden, d_model, scale=out_scale))
     return out
 
 

@@ -9,6 +9,7 @@ glue (loss, optimizer) stays in user code or in `build_classifier`.
 from .alexnet import alexnet
 from .googlenet import googlenet
 from .mnist import mnist_conv, mnist_mlp
+from .nemotron_h import nemotron_h_lm
 from .resnet import resnet_cifar10, resnet_imagenet, resnet50
 from .smallnet import smallnet_mnist_cifar
 from .transformer import transformer_lm
@@ -16,7 +17,7 @@ from .vgg import vgg16, vgg19
 from .common import build_image_classifier
 
 __all__ = [
-    "alexnet", "googlenet", "mnist_conv", "mnist_mlp",
+    "alexnet", "googlenet", "mnist_conv", "mnist_mlp", "nemotron_h_lm",
     "resnet_cifar10", "resnet_imagenet", "resnet50",
     "smallnet_mnist_cifar", "transformer_lm",
     "vgg16", "vgg19", "build_image_classifier",

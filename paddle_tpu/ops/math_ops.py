@@ -223,6 +223,7 @@ _activations = {
         x > a.attr("threshold", 1.0), x, 0.0),
     "gelu": lambda x, a: jax.nn.gelu(x, approximate=False),
     "silu": lambda x, a: jax.nn.silu(x),
+    "relu2": lambda x, a: jnp.square(jax.nn.relu(x)),   # squared ReLU
 }
 
 

@@ -884,6 +884,24 @@ def _flash_partition(program, q):
                            PartitionSpec(batch, heads, None)), shard
 
 
+def _kv_groups(q, k) -> int:
+    """Query heads per K/V head of [B, T, H, D] operands: 1 for full
+    multi-head attention, H / H_kv under grouped-query attention, where
+    query head j reads K/V head j // (H / H_kv)."""
+    hq, hkv = q.shape[2], k.shape[2]
+    assert hq % hkv == 0, f"{hq} query heads over {hkv} K/V heads"
+    return hq // hkv
+
+
+def _repeat_kv(x, groups: int):
+    """K or V of H_kv heads as the query's H: each head `groups` times in
+    a row. Every path (einsum, flash, ring) and the kernels' gate see
+    equal head counts; the grad op sums dK and dV back over each group.
+    The copies cost HBM traffic a kernel that reads H_kv heads would not
+    pay."""
+    return x if groups == 1 else jnp.repeat(x, groups, axis=2)
+
+
 def _sdpa_paths(ctx, op_, q, k, v, count=False):
     """(mode, how): 'ring' under sequence_parallel with an sp mesh (how =
     the mesh), 'flash' when use_flash is True, or 'auto' and the rule
@@ -921,7 +939,9 @@ def _sdpa_paths(ctx, op_, q, k, v, count=False):
     grad=_sdpa_grad)
 def _scaled_dot_product_attention(ctx, op_, ins):
     """Fused softmax attention, Q/K/V [B, T, H, D] (no 2018-reference
-    analogue — the capability the brief requires for long context). With
+    analogue — the capability the brief requires for long context). K and
+    V may have fewer heads than Q (grouped-query attention: a divisor of
+    Q's count; _repeat_kv). With
     sequence_parallel=True and a program mesh carrying an 'sp' axis, the
     computation runs as ring attention (parallel/ring_attention.py):
     sequence shards stay resident per device and K/V rotate over ICI via
@@ -938,6 +958,8 @@ def _scaled_dot_product_attention(ctx, op_, ins):
     v = jnp.asarray(ins["V"][0])
     causal = op_.attr("causal", False)
     (q, k, v), restore = mxu_cast(ctx, q, k, v)
+    groups = _kv_groups(q, k)
+    k, v = _repeat_kv(k, groups), _repeat_kv(v, groups)
     from ..parallel.ring_attention import (attention_reference,
                                            attention_reference_lse,
                                            ring_attention_sharded)
@@ -981,6 +1003,8 @@ def _sdpa_grad_kernel(ctx, op_, ins):
     do = jnp.asarray(ins["Out@GRAD"][0])
     causal = op_.attr("causal", False)
     (q, k, v, do), restore = mxu_cast(ctx, q, k, v, do)
+    groups = _kv_groups(q, k)
+    k, v = _repeat_kv(k, groups), _repeat_kv(v, groups)
     from ..parallel.ring_attention import (attention_reference,
                                            ring_attention_sharded)
     mode, mesh = _sdpa_paths(ctx, op_, q, k, v)
@@ -1023,6 +1047,10 @@ def _sdpa_grad_kernel(ctx, op_, ins):
             lambda a, b, c: attention_reference(a, b, c, causal=causal),
             q, k, v)
         dq, dk, dv = vjp_fn(do.astype(q.dtype))
+    if groups > 1:
+        b, t, h, d = dk.shape
+        dk, dv = (g.astype(jnp.float32).reshape(b, t, h // groups, groups, d)
+                  .sum(3).astype(g.dtype) for g in (dk, dv))
     if restore is not None:
         dq, dk, dv = (dq.astype(restore), dk.astype(restore),
                       dv.astype(restore))

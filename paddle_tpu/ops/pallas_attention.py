@@ -133,7 +133,13 @@ def ineligible(q, k, v):
     sublane-aligned (T % 8 == 0: Mosaic tiles (8, 128) for f32); the
     heads must fill whole 128-lane blocks of the [B, T, H*D] view
     ("heads": 3 heads of 64), and D must divide 128 or be a multiple of
-    it ("head_dim": 96)."""
+    it ("head_dim": 96). The kernels read as many K/V heads as Q heads:
+    the attention op repeats a K/V of fewer heads (grouped-query
+    attention) to the query's count before it asks here
+    (ops/nn_ops._repeat_kv), so such a shape is gated, and its hit or
+    fallback reason booked, as full attention of the query's heads; a
+    K/V that reaches this gate with another head count than Q is
+    "shape"."""
     if q.ndim != 4 or q.shape != k.shape or q.shape != v.shape:
         return "shape"
     _, t, h, d = q.shape

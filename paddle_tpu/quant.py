@@ -62,6 +62,7 @@ __all__ = [
     "ineligible_conv", "ineligible_matmul", "prequantize",
     "prequantized", "qconv2d",
     "qmatmul", "quantize_channelwise", "suppress_counters",
+    "counters_suppressed",
     "weight_qparams",
 ]
 
@@ -260,6 +261,12 @@ def suppress_counters():
         yield
     finally:
         _SUPPRESS_COUNTERS = prev
+
+
+def counters_suppressed() -> bool:
+    """Inside suppress_counters(): a lowering that books its own Pallas
+    gate (ops/hybrid_ops.py) stays silent there too."""
+    return _SUPPRESS_COUNTERS
 
 
 def count_fallback(op: str, reason: str):

@@ -119,7 +119,47 @@ _ELEMWISE_COST = {
     "layer_norm": 5.0, "group_norm": 5.0, "sigmoid": 4.0, "tanh": 4.0,
     "exp": 2.0, "gelu": 8.0, "swish": 5.0, "dropout": 2.0,
     "cross_entropy": 4.0, "softmax_with_cross_entropy": 8.0,
+    "rms_norm": 5.0, "relu2": 2.0,
 }
+
+
+def _scan_flops(ins, outs, attrs):
+    """ssd_scan: the chunked form's four products per token: scores C B^T
+    per group and their masked product with x (chunk wide each), the chunk
+    states and the entering state's read-out (H P N each)."""
+    x, b = _slot_shape(ins, "X"), _slot_shape(ins, "B")
+    if x is None or b is None:
+        return None
+    chunk = int(attrs.get("chunk_size", 128))
+    tokens, hp = _nelems(x[:2]), _nelems(x[2:])
+    return 2.0 * tokens * (chunk * _nelems(b[2:]) + chunk * hp
+                           + 2 * hp * b[3])
+
+
+def _experts_flops(ins, outs, attrs):
+    """moe_experts: the up and down products over the EXPECTED rows
+    routed to the held experts, top_k x held / experts of a token's."""
+    x, w1 = _slot_shape(ins, "X"), _slot_shape(ins, "W1")
+    if x is None or w1 is None:
+        return None
+    held = int(attrs.get("experts_held", w1[0]))
+    share = held / float(attrs.get("num_experts", held))
+    return 4.0 * x[0] * int(attrs.get("top_k", 1)) * share * w1[1] * w1[2]
+
+
+def _router_flops(ins, outs, attrs):
+    x, w = _slot_shape(ins, "X"), _slot_shape(ins, "W")
+    return None if x is None or w is None else 2.0 * x[0] * _nelems(w)
+
+
+def _conv1d_flops(ins, outs, attrs):
+    x, w = _slot_shape(ins, "X"), _slot_shape(ins, "Filter")
+    return None if x is None or w is None else (2.0 * w[1] + 4) * _nelems(x)
+
+
+# ops/hybrid_ops.py: forward flops from the op's concrete shapes
+_HYBRID_COST = {"ssd_scan": _scan_flops, "moe_experts": _experts_flops,
+                "moe_router": _router_flops, "causal_conv1d": _conv1d_flops}
 
 # flops per parameter element for the bucketed fused optimizer applies
 # (ops/fusion.py): sgd = mul+sub; momentum adds the velocity update;
@@ -226,6 +266,10 @@ def op_cost(op_type: str, ins: Dict[str, list], outs: Dict[str, list],
             t = q[-2] if len(q) >= 3 else q[0]
             flops = 4.0 * _nelems(q) * int(t)
         else:
+            flops = float(out_elems)
+    elif base in _HYBRID_COST:
+        flops = _HYBRID_COST[base](ins, outs, attrs)
+        if flops is None:
             flops = float(out_elems)
     elif base.startswith("reduce_") or base in ("mean", "sum"):
         flops = float(in_elems)

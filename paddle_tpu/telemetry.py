@@ -797,6 +797,22 @@ METRIC_CATALOG = {
         "gauge", ("program",),
         "pre-clip gradient global norm (telemetry side-fetch)",
         dynamic=True),
+    "moe_rows_routed": _m(
+        "histogram", ("program", "layer"),
+        "(token, slot) pairs routed to the experts held here, a sample a "
+        "step and expert layer (telemetry side-fetch; models/nemotron_h)",
+        dynamic=True),
+    "moe_rows_combined": _m(
+        "histogram", ("program", "layer"),
+        "rows the held experts' grouped product was given and the "
+        "scatter-add returned; equals moe_rows_routed unless a row is "
+        "lost (telemetry side-fetch)",
+        dynamic=True),
+    "moe_load_max_over_mean": _m(
+        "histogram", ("program", "layer"),
+        "rows of the busiest held expert over the held experts' mean, a "
+        "sample a step and expert layer (telemetry side-fetch)",
+        dynamic=True),
     "jax_backend_compiles_total": _m("counter", (),
                                      "XLA backend compiles observed"),
     "jax_backend_compile_seconds_total": _m(

@@ -1,0 +1,620 @@
+"""The hybrid Mamba-2 / mixture-of-experts / grouped-query-attention ops
+(ops/hybrid_ops.py), their layers and models.nemotron_h_lm, at tiny sizes
+on the CPU: each op through the executor against a plain form written
+here (the scan against the step-by-step recurrence), forward and
+gradient; the share test of the expert layer; the whole tiny model
+against benchmarks/families/nemotron_h.py::reference_loss; and the tables
+that must know every new op."""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as fluid
+from paddle_tpu import executor as executor_mod
+from paddle_tpu.framework.framework import grad_var_name
+from paddle_tpu.layer_helper import LayerHelper
+
+from benchmarks import reference_check, run
+
+DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                    "benchmark", "data")
+NEW_OPS = ("rms_norm", "causal_conv1d", "ssd_scan", "moe_router",
+           "moe_experts", "relu2")
+
+
+def run_op(op_type, inputs, outputs, attrs, wrt=()):
+    """One `op_type` op over data vars through the executor. `inputs`
+    {slot: array}; `outputs` {slot: dtype}. The loss is the sum of the
+    first output times a fixed random cotangent. -> ({slot: array},
+    {slot of wrt: gradient}, cotangent)."""
+    main = fluid.Program()
+    with fluid.program_guard(main, fluid.Program()):
+        return _run_op(main, op_type, inputs, outputs, attrs, wrt)
+
+
+def _run_op(main, op_type, inputs, outputs, attrs, wrt):
+    helper = LayerHelper(op_type)
+    vars_ = {}
+    for slot, value in inputs.items():
+        var = fluid.layers.data(name=slot.lower(), shape=list(value.shape),
+                                dtype=str(value.dtype),
+                                append_batch_size=False)
+        var.stop_gradient = var.desc.stop_gradient = slot not in wrt
+        vars_[slot] = var
+    outs = {slot: helper.create_tmp_variable(dtype)
+            for slot, dtype in outputs.items()}
+    helper.append_op(type=op_type,
+                     inputs={s: [v] for s, v in vars_.items()},
+                     outputs={s: [v] for s, v in outs.items()}, attrs=attrs)
+    first = next(iter(outs))
+    feed = {s.lower(): v for s, v in inputs.items()}
+    fetch = list(outs.values())
+    cot = None
+    if wrt:
+        exe = fluid.Executor(fluid.CPUPlace())
+        with executor_mod.scope_guard(executor_mod.Scope()):
+            shape = np.asarray(exe.run(main, feed=feed, fetch_list=[outs[first]])[0]
+                               ).shape
+        cot = np.random.default_rng(1).standard_normal(shape).astype(
+            np.float32)
+        r = fluid.layers.data(name="cot", shape=list(shape), dtype="float32",
+                              append_batch_size=False)
+        loss = fluid.layers.reduce_sum(
+            fluid.layers.elementwise_mul(outs[first], r))
+        fluid.backward.append_backward(loss)
+        feed["cot"] = cot
+        fetch += [grad_var_name(vars_[s].name) for s in wrt]
+    exe = fluid.Executor(fluid.CPUPlace())
+    with executor_mod.scope_guard(executor_mod.Scope()):
+        got = [np.asarray(v) for v in exe.run(main, feed=feed,
+                                               fetch_list=fetch)]
+    return (dict(zip(outs, got)), dict(zip(wrt, got[len(outs):])), cot)
+
+
+def close(got, want, tol=2e-5):
+    want = np.asarray(want)
+    scale = max(float(np.abs(want).max()), 1e-6)
+    assert float(np.abs(np.asarray(got) - want).max()) <= tol * scale
+
+
+# --- the scan ----------------------------------------------------------------
+
+def recurrence(x, dt_raw, dt_bias, a_log, b, c, skip):
+    """h_t = exp(dt_t A) h_{t-1} + dt_t x_t b_t^T, y_t = h_t c_t + D x_t,
+    one step at a time."""
+    heads, groups = x.shape[2], b.shape[2]
+    bh = jnp.repeat(b, heads // groups, axis=2)
+    ch = jnp.repeat(c, heads // groups, axis=2)
+    dt = jax.nn.softplus(dt_raw + dt_bias)
+    decay = jnp.exp(dt * -jnp.exp(a_log))
+
+    def step(h, inp):
+        x_t, dt_t, a_t, b_t, c_t = inp
+        h = a_t[..., None, None] * h + (dt_t[..., None] * x_t)[..., None] \
+            * b_t[..., None, :]
+        return h, jnp.einsum("bhpn,bhn->bhp", h, c_t)
+
+    h0 = jnp.zeros(x.shape[:1] + x.shape[2:] + b.shape[3:])
+    _, y = jax.lax.scan(step, h0, tuple(
+        v.swapaxes(0, 1) for v in (x, dt, decay, bh, ch)))
+    return y.swapaxes(0, 1) + skip[:, None] * x
+
+
+@pytest.mark.parametrize("seqlen,chunk", [(37, 16), (64, 16), (96, 32),
+                                          (24, 32)])
+def test_ssd_scan_matches_the_recurrence(seqlen, chunk):
+    rng = np.random.default_rng(seqlen)
+    f = lambda *s: rng.standard_normal(s).astype(np.float32)  # noqa: E731
+    ins = {"X": f(2, seqlen, 4, 8), "Dt": f(2, seqlen, 4), "DtBias": f(4),
+           "ALog": f(4), "B": f(2, seqlen, 2, 16), "C": f(2, seqlen, 2, 16),
+           "D": f(4)}
+    wrt = tuple(ins)
+    outs, grads, cot = run_op("ssd_scan", ins, {"Out": "float32"},
+                              {"chunk_size": chunk}, wrt)
+    order = ("X", "Dt", "DtBias", "ALog", "B", "C", "D")
+    want, want_grads = jax.value_and_grad(
+        lambda *a: (recurrence(*a) * cot).sum(), argnums=range(7))(
+            *(jnp.asarray(ins[s]) for s in order))
+    close(outs["Out"], recurrence(*(jnp.asarray(ins[s]) for s in order)))
+    for slot, g in zip(order, want_grads):
+        close(grads[slot], g, tol=1e-4)
+
+
+def test_scan_backward_keeps_no_per_step_state():
+    """The gradient's residuals are per chunk, not per step: nothing of
+    [T, H, P, N] is held."""
+    from paddle_tpu.ops.hybrid_ops import ssd_scan_chunked
+    t, h, p, n = 256, 4, 8, 16
+    args = (jnp.zeros((1, t, h, p)), jnp.ones((1, t, h)), -jnp.ones(h),
+            jnp.zeros((1, t, 2, n)), jnp.zeros((1, t, 2, n)))
+    from jax._src.ad_checkpoint import saved_residuals
+    saved = saved_residuals(
+        lambda *a: jax.checkpoint(
+            lambda *b: ssd_scan_chunked(*b, 32))(*a).sum(), *args)
+    assert max(int(np.prod(aval.shape)) for aval, _ in saved) < t * h * p * n
+
+
+# --- conv, norm --------------------------------------------------------------
+
+def test_causal_conv1d():
+    rng = np.random.default_rng(0)
+    x = rng.standard_normal((2, 19, 6)).astype(np.float32)
+    w = rng.standard_normal((6, 4)).astype(np.float32)
+    b = rng.standard_normal(6).astype(np.float32)
+
+    def plain(x, w, b):
+        padded = jnp.pad(x, ((0, 0), (3, 0), (0, 0)))
+        out = b + sum(padded[:, j:j + 19] * w[:, j] for j in range(4))
+        return jax.nn.silu(out)
+
+    outs, grads, cot = run_op(
+        "causal_conv1d", {"X": x, "Filter": w, "Bias": b},
+        {"Out": "float32"}, {}, ("X", "Filter", "Bias"))
+    close(outs["Out"], plain(x, w, b))
+    # position t reads nothing after t
+    later = x.copy()
+    later[:, 10:] += 1.0
+    np.testing.assert_array_equal(np.asarray(plain(later, w, b))[:, :10],
+                                  np.asarray(plain(x, w, b))[:, :10])
+    want = jax.grad(lambda *a: (plain(*a) * cot).sum(), argnums=(0, 1, 2))(
+        x, w, b)
+    for slot, g in zip(("X", "Filter", "Bias"), want):
+        close(grads[slot], g, tol=1e-4)
+
+
+@pytest.mark.parametrize("groups,gated", [(1, False), (4, True), (2, False)])
+def test_rms_norm_gated_and_grouped(groups, gated):
+    rng = np.random.default_rng(groups)
+    x = rng.standard_normal((3, 5, 16)).astype(np.float32)
+    w = rng.standard_normal(16).astype(np.float32)
+    z = rng.standard_normal((3, 5, 16)).astype(np.float32)
+
+    def plain(x, w, z):
+        h = x * jax.nn.silu(z) if gated else x
+        g = h.reshape(3, 5, groups, -1)
+        g = g / jnp.sqrt((g ** 2).mean(-1, keepdims=True) + 1e-5)
+        return g.reshape(3, 5, 16) * w
+
+    ins = {"X": x, "Scale": w}
+    if gated:
+        ins["Gate"] = z
+    outs, grads, cot = run_op("rms_norm", ins, {"Out": "float32"},
+                              {"epsilon": 1e-5, "groups": groups},
+                              tuple(ins))
+    close(outs["Out"], plain(x, w, z))
+    want = jax.grad(lambda *a: (plain(*a) * cot).sum(), argnums=(0, 1, 2))(
+        x, w, z)
+    for slot, g in zip(("X", "Scale", "Gate"), want):
+        if slot in ins:
+            close(grads[slot], g, tol=1e-4)
+
+
+# --- router ------------------------------------------------------------------
+
+def test_router_choice_normalisation_and_scaling():
+    rng = np.random.default_rng(2)
+    x = rng.standard_normal((40, 12)).astype(np.float32)
+    w = rng.standard_normal((12, 16)).astype(np.float32)
+    bias = np.zeros(16, np.float32)
+    bias[3] = 10.0             # expert 3 is always chosen ...
+    attrs = {"top_k": 3, "scaling": 2.5, "norm_topk_prob": True}
+    outs, grads, cot = run_op(
+        "moe_router", {"X": x, "W": w, "Bias": bias},
+        {"TopkWeight": "float32", "TopkIdx": "int32"}, attrs, ("X", "W"))
+    s = 1.0 / (1.0 + np.exp(-(x @ w)))
+    want_idx = np.argsort(-(s + bias), axis=-1, kind="stable")[:, :3]
+    np.testing.assert_array_equal(np.sort(outs["TopkIdx"], -1),
+                                  np.sort(want_idx, -1))
+    assert (outs["TopkIdx"] == 3).any(-1).all()
+    # ... but weighs in with its score, not its score plus the bias
+    chosen = np.take_along_axis(s, outs["TopkIdx"], -1)
+    close(outs["TopkWeight"], 2.5 * chosen / chosen.sum(-1, keepdims=True))
+    close(outs["TopkWeight"].sum(-1), np.full(40, 2.5))
+
+    def plain(x, w):
+        s = jax.nn.sigmoid(x @ w)
+        c = jnp.take_along_axis(s, jnp.asarray(outs["TopkIdx"]), -1)
+        return 2.5 * c / (c.sum(-1, keepdims=True) + 1e-20)
+
+    want = jax.grad(lambda *a: (plain(*a) * cot).sum(), argnums=(0, 1))(x, w)
+    close(grads["X"], want[0], tol=1e-4)
+    close(grads["W"], want[1], tol=1e-4)
+    unnormed, _, _ = run_op(
+        "moe_router", {"X": x, "W": w, "Bias": bias},
+        {"TopkWeight": "float32", "TopkIdx": "int32"},
+        dict(attrs, norm_topk_prob=False, scaling=1.0))
+    close(unnormed["TopkWeight"],
+          np.take_along_axis(s, unnormed["TopkIdx"], -1))
+
+
+# --- the dropless expert layer -----------------------------------------------
+
+def expert_loop(x, idx, weight, w1, w2, offset=0):
+    """sum over the held experts of (the token's weight for that expert)
+    x relu(x W1[e])^2 W2[e], every token through every held expert."""
+    out = jnp.zeros_like(x)
+    for e in range(w1.shape[0]):
+        mine = (weight * (idx == offset + e)).sum(-1, keepdims=True)
+        out = out + mine * (jnp.maximum(x @ w1[e], 0.0) ** 2 @ w2[e])
+    return out
+
+
+def routed(rng, n, k, experts, favour=None):
+    """(idx [n, k] of distinct experts, weight [n, k]); `favour`: experts
+    that draw most of the tokens."""
+    scores = rng.random((n, experts))
+    if favour is not None:
+        scores[:, favour] += rng.random((n, len(favour))) * 3.0
+    idx = np.argsort(-scores, -1)[:, :k].astype(np.int32)
+    return idx, rng.random((n, k)).astype(np.float32) + 0.1
+
+
+@pytest.mark.parametrize("n,d,f,path", [(64, 128, 128, "gmm"),
+                                        (50, 24, 40, "rows"),
+                                        (64, 24, 40, "width")])
+def test_experts_drop_no_row_under_a_skewed_router(n, d, f, path):
+    """Held experts 4..7 of 16, two of them drawing most tokens: the
+    layer against a loop over the experts, forward and gradient; every
+    routed pair counted; the grouped product on the kernel where it
+    tiles, booked with the reason where not."""
+    from paddle_tpu import telemetry
+    from paddle_tpu.ops import hybrid_ops
+    rng = np.random.default_rng(n + d)
+    k, held, offset = 4, 4, 4
+    x = rng.standard_normal((n, d)).astype(np.float32) * 0.5
+    w1 = rng.standard_normal((held, d, f)).astype(np.float32) * 0.2
+    w2 = rng.standard_normal((held, f, d)).astype(np.float32) * 0.2
+    idx, weight = routed(rng, n, k, 16, favour=[5, 6])
+    reason = hybrid_ops.gmm_ineligible(n * k, d, f)
+    assert (reason or "gmm") == path
+    before = dict(telemetry.read_series("pallas_kernel_total")), \
+        dict(telemetry.read_series("pallas_fallback_total"))
+    ins = {"X": x, "TopkIdx": idx, "TopkWeight": weight, "W1": w1, "W2": w2}
+    outs, grads, cot = run_op(
+        "moe_experts", ins,
+        {"Out": "float32", "RowsRouted": "float32",
+         "RowsCombined": "float32", "LoadMaxOverMean": "float32"},
+        {"num_experts": 16, "experts_held": held, "expert_offset": offset,
+         "top_k": k}, ("X", "TopkWeight", "W1", "W2"))
+    close(outs["Out"], expert_loop(x, idx, weight, w1, w2, offset), tol=1e-4)
+    counts = np.array([(idx == offset + e).sum() for e in range(held)])
+    assert outs["RowsRouted"][0] == outs["RowsCombined"][0] \
+        == counts.sum()                                  # no row lost
+    assert counts.sum() > 0.4 * n * k                    # and it is skewed
+    close(outs["LoadMaxOverMean"], [counts.max() / counts.mean()])
+    want = jax.grad(lambda x, wt, a, b: (expert_loop(
+        x, idx, wt, a, b, offset) * cot).sum(), argnums=(0, 1, 2, 3))(
+            x, weight, w1, w2)
+    for slot, g in zip(("X", "TopkWeight", "W1", "W2"), want):
+        close(grads[slot], g, tol=2e-4)
+    hits = telemetry.read_series("pallas_kernel_total")
+    falls = telemetry.read_series("pallas_fallback_total")
+    key = "op=moe_experts"
+    if reason is None:
+        assert hits[key] > before[0].get(key, 0)
+    else:
+        key = f"op=moe_experts,reason={reason}"
+        assert falls[key] > before[1].get(key, 0)
+
+
+def test_shares_add_up_to_the_uncut_layer():
+    """16 experts in 4 shares of 4: what the four shares give, with the
+    shared expert counted once, is the uncut layer."""
+    rng = np.random.default_rng(7)
+    n, d, f, k = 48, 16, 24, 3
+    x = rng.standard_normal((n, d)).astype(np.float32)
+    w1 = rng.standard_normal((16, d, f)).astype(np.float32) * 0.3
+    w2 = rng.standard_normal((16, f, d)).astype(np.float32) * 0.3
+    s1 = rng.standard_normal((d, 2 * f)).astype(np.float32) * 0.3
+    s2 = rng.standard_normal((2 * f, d)).astype(np.float32) * 0.3
+    idx, weight = routed(rng, n, k, 16)
+    shared = np.maximum(x @ s1, 0.0) ** 2 @ s2
+
+    def share(offset, held):
+        outs, _, _ = run_op(
+            "moe_experts",
+            {"X": x, "TopkIdx": idx, "TopkWeight": weight,
+             "W1": w1[offset:offset + held], "W2": w2[offset:offset + held]},
+            {"Out": "float32", "RowsRouted": "float32",
+             "RowsCombined": "float32", "LoadMaxOverMean": "float32"},
+            {"num_experts": 16, "experts_held": held,
+             "expert_offset": offset, "top_k": k})
+        return outs
+
+    parts = [share(offset, 4) for offset in (0, 4, 8, 12)]
+    whole = share(0, 16)
+    close(sum(p["Out"] for p in parts) + shared, whole["Out"] + shared,
+          tol=1e-5)
+    close(whole["Out"] + shared,
+          expert_loop(x, idx, weight, w1, w2) + shared, tol=1e-4)
+    assert sum(p["RowsRouted"][0] for p in parts) == n * k \
+        == whole["RowsRouted"][0]
+
+
+# --- grouped-query attention -------------------------------------------------
+
+def gqa_plain(q, k, v):
+    t, groups = q.shape[1], q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, groups, 2), jnp.repeat(v, groups, 2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
+
+
+@pytest.mark.parametrize("use_flash", [False, True])
+def test_grouped_query_attention(use_flash):
+    """4 query heads over 2 K/V heads of 32: einsum and the flash kernels
+    (interpreted) against the plain form, forward and gradient; the
+    kernels' gate books the hit as for full attention."""
+    from paddle_tpu import telemetry
+    rng = np.random.default_rng(3)
+    shapes = {"q": (2, 128, 4, 32), "k": (2, 128, 2, 32),
+              "v": (2, 128, 2, 32)}
+    feed = {n: rng.standard_normal(s).astype(np.float32)
+            for n, s in shapes.items()}
+    vars_ = {}
+    for n, s in shapes.items():
+        var = fluid.layers.data(name=n, shape=list(s), dtype="float32",
+                                append_batch_size=False)
+        var.stop_gradient = var.desc.stop_gradient = False
+        vars_[n] = var
+    out = fluid.layers.fused_attention(vars_["q"], vars_["k"], vars_["v"],
+                                       causal=True, use_flash=use_flash)
+    loss = fluid.layers.reduce_sum(fluid.layers.elementwise_mul(out, out))
+    fluid.backward.append_backward(loss)
+    key = "op=scaled_dot_product_attention"
+    before = telemetry.read_series("pallas_kernel_total").get(key, 0)
+    exe = fluid.Executor(fluid.CPUPlace())
+    with executor_mod.scope_guard(executor_mod.Scope()):
+        got = exe.run(feed=feed, fetch_list=[out] + [
+            grad_var_name(vars_[n].name) for n in "qkv"])
+    after = telemetry.read_series("pallas_kernel_total").get(key, 0)
+    assert after - before == (1 if use_flash else 0)
+    args = [jnp.asarray(feed[n]) for n in "qkv"]
+    close(got[0], gqa_plain(*args), tol=1e-4)
+    want = jax.grad(lambda *a: (gqa_plain(*a) ** 2).sum(),
+                    argnums=(0, 1, 2))(*args)
+    for g, w in zip(got[1:], want):
+        assert np.asarray(g).shape == w.shape
+        close(g, w, tol=2e-4)
+
+
+def test_kv_heads_must_divide_query_heads():
+    from paddle_tpu.ops import nn_ops
+    with pytest.raises(AssertionError):
+        nn_ops._kv_groups(jnp.zeros((1, 8, 4, 8)), jnp.zeros((1, 8, 3, 8)))
+
+
+# --- the whole tiny model against the reference ------------------------------
+
+def first_step(amp_level):
+    """reference_check.compare() of the tiny model's first step."""
+    config = run.load_json("configs", "tiny-nemotron-h", DATA)
+    family = run.load_module("families", config["family"])
+    main, startup, loss = family.build(config)
+    if amp_level is None:
+        fluid.amp.disable(main)
+    feed = family.make_batch(config, 2, np.random.default_rng(3))
+    rule = reference_check.rule_of(config)
+    exe = fluid.Executor(fluid.CPUPlace())
+    scope = executor_mod.Scope()
+    with executor_mod.scope_guard(scope):
+        scope.set_var("__rng_counter__", 12345)
+        exe.run(startup)
+        names = [p.name for p in main.global_block().all_parameters()
+                 if p.trainable]
+        params = [scope.find_var(n) for n in names]
+        start = [np.asarray(p) for p in params]
+        ref_loss, ref_grads = reference_check.reference_step(
+            family, config, params, feed)
+        out, = exe.run(main, feed=feed, fetch_list=[loss])
+        slots = reference_check.state_names(main, rule)
+        found = reference_check.compare(
+            rule, config, names, start,
+            [np.asarray(scope.find_var(n)) for n in names],
+            {n: {s: np.asarray(scope.find_var(v))
+                 for s, v in slots[n].items()} for n in names},
+            float(np.ravel(out)[0]), ref_loss, ref_grads,
+            dict.fromkeys(reference_check.HELD))
+    return found, main, names
+
+
+@pytest.mark.parametrize("amp_level,loss_tol,grad_tol", [
+    (None, 1e-6, 1e-5), ("O2", 2e-4, 0.03)])
+def test_tiny_model_against_the_reference(amp_level, loss_tol, grad_tol):
+    """Loss, every gradient, its norm, the tail (the final norm's weight
+    and the head) and one Adam step: float32 to rounding, O2 to bf16."""
+    found, main, names = first_step(amp_level)
+    assert len(names) == 38        # the router's bias is not among them
+    assert found["loss_rel_diff"] <= loss_tol
+    assert found["grad_rel_err"] <= grad_tol
+    assert found["grad_tail_rel_err"] <= grad_tol
+    assert found["grad_norm_rel_diff"] <= grad_tol
+    assert found["update_rel_err"] <= 1e-3
+
+
+def test_published_initialisation_of_the_scan_parameters():
+    config = run.load_json("configs", "tiny-nemotron-h", DATA)
+    family = run.load_module("families", config["family"])
+    main, startup, _ = family.build(config)
+    exe = fluid.Executor(fluid.CPUPlace())
+    scope = executor_mod.Scope()
+    with executor_mod.scope_guard(scope):
+        exe.run(startup)
+        mixer = [np.asarray(scope.find_var(f"mamba2_mixer_0.w_{i}"))
+                 for i in (2, 3, 4)]
+    dt0 = np.log1p(np.exp(mixer[0]))                   # softplus(dt_bias)
+    assert (dt0 >= 0.999e-3).all() and (dt0 <= 0.1001).all()
+    a = np.exp(mixer[1])                               # -A
+    assert (a >= 1.0).all() and (a <= 16.0).all() and a.std() > 0
+    np.testing.assert_array_equal(mixer[2], 1.0)
+
+
+def test_routing_statistics_reach_telemetry_by_layer():
+    """Two expert layers: a sample a step and layer of the rows routed
+    to held experts and of their imbalance, without a fetch by the user."""
+    from paddle_tpu import telemetry
+    config = run.load_json("configs", "tiny-nemotron-h", DATA)
+    family = run.load_module("families", config["family"])
+    main, startup, loss = family.build(config)
+    feed = family.make_batch(config, 2, np.random.default_rng(0))
+    exe = fluid.Executor(fluid.CPUPlace())
+    with executor_mod.scope_guard(executor_mod.Scope()):
+        exe.run(startup)
+        for _ in range(3):
+            exe.run(main, feed=feed, fetch_list=[loss])
+    label = telemetry.program_label(main)
+    tokens = feed["tok"].size
+    for layer in ("0", "1"):
+        rows = telemetry.read_histogram("moe_rows_routed", program=label,
+                                        layer=layer)
+        combined = telemetry.read_histogram("moe_rows_combined",
+                                            program=label, layer=layer)
+        load = telemetry.read_histogram("moe_load_max_over_mean",
+                                        program=label, layer=layer)
+        assert rows["count"] == load["count"] == 3
+        assert combined == rows                # what went in came back
+        # 4 of 16 experts held, 3 chosen of 16: about 3/4 of a row a token
+        assert 0.3 * tokens < rows["sum"] / 3 < 1.5 * tokens
+        assert 1.0 <= load["sum"] / 3 <= 4.0
+
+
+# --- a trace can book every op of a layer to it -----------------------------
+
+def test_name_scope_reaches_the_ops_their_gradients_and_the_hlo():
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = fluid.layers.data(name="x", shape=[8], dtype="float32")
+        with fluid.name_scope("outer"):
+            with fluid.name_scope("inner"):
+                h = fluid.layers.fc(input=x, size=4, bias_attr=False)
+            h = fluid.layers.tanh(h)
+        loss = fluid.layers.mean(h)
+        fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
+    under = {op.type: op.desc.attrs.get("op_namescope")
+             for op in main.global_block().ops}
+    assert under["mul"] == under["mul_grad"] == "/outer/inner/"
+    assert under["tanh"] == under["tanh_grad"] == "/outer/"
+    assert under["mean"] is None and under["sgd"] is None
+    exe = fluid.Executor(fluid.CPUPlace())
+    with executor_mod.scope_guard(executor_mod.Scope()):
+        exe.run(startup)
+        text = exe.compiled_hlo(main, feed={"x": np.ones((2, 8), np.float32)},
+                                fetch_list=[loss])
+    assert "pd_role.forward/pd_scope.outer.inner/pd.mul/" in text
+    assert "pd_role.backward/pd_scope.outer.inner/pd.mul_grad/" in text
+    assert "pd_role.forward/pd.mean/" in text
+
+
+def test_the_two_layers_build_every_op_under_their_name(tiny_program):
+    """What ssm_time_pct.train and moe_time_pct.train read: the mixer's
+    and the shared expert's `mul`s and their gradients too."""
+    main, _ = tiny_program
+    under = {}
+    for op in main.global_block().ops:
+        under.setdefault(op.desc.attrs.get("op_namescope"), []).append(op.type)
+    mixer, experts = under["/mamba2_mixer/"], under["/moe_block/"]
+    for op_type, count in (("mul", 4), ("ssd_scan", 2), ("causal_conv1d", 2),
+                           ("rms_norm", 2)):
+        assert mixer.count(op_type) == mixer.count(op_type + "_grad") == count
+    for op_type, count in (("mul", 4), ("moe_router", 2), ("moe_experts", 2),
+                           ("relu2", 2)):
+        assert experts.count(op_type) == experts.count(op_type + "_grad") \
+            == count
+    assert not {"ssd_scan", "moe_experts", "moe_router"} & set(under[None])
+
+
+# --- no table forgets an op --------------------------------------------------
+
+@pytest.fixture(scope="module")
+def tiny_program():
+    config = run.load_json("configs", "tiny-nemotron-h", DATA)
+    main, _, loss = run.load_module("families", config["family"]).build(config)
+    return main, loss
+
+
+@pytest.fixture(scope="module")
+def o2_dtypes():
+    """{new op type: (dtype of its X, dtype of its first float output)} of
+    the tiny model's forward under AMP O2."""
+    config = run.load_json("configs", "tiny-nemotron-h", DATA)
+    family = run.load_module("families", config["family"])
+    main, startup, _ = family.build(dict(config, amp_level="O2"))
+    names = {}
+    for op in main.global_block().ops:
+        if op.type in NEW_OPS and op.type not in names:
+            slot = "TopkWeight" if op.type == "moe_router" else "Out"
+            names[op.type] = [op.input("X")[0], op.output(slot)[0]]
+    exe = fluid.Executor(fluid.CPUPlace())
+    with executor_mod.scope_guard(executor_mod.Scope()):
+        exe.run(startup)
+        got = exe.run(main, feed=family.make_batch(
+            config, 2, np.random.default_rng(0)),
+            fetch_list=sum(names.values(), []), return_numpy=False)
+    found = [str(v.dtype) for v in got]
+    return dict(zip(names, zip(found[::2], found[1::2])))
+
+
+@pytest.mark.parametrize("op_type", NEW_OPS)
+def test_every_table_knows_the_op(op_type, tiny_program, o2_dtypes):
+    """roofline.op_cost prices it from its shapes (not by the default of
+    one flop an output element), under AMP O2 its output has its
+    input's dtype, bfloat16 after a projection (the router's weights are
+    float32; hybrid_ops' docstring says which arithmetic each op keeps in
+    float32 inside), the static analyzer has a shape rule for it and its gradient, and the
+    tiny model holds it."""
+    from paddle_tpu import roofline
+    from paddle_tpu.analysis import infer
+    main, _ = tiny_program
+    assert op_type in {op.type for op in main.global_block().ops}
+    x_dtype, out_dtype = o2_dtypes[op_type]
+    assert out_dtype == ("float32" if op_type == "moe_router" else x_dtype)
+    assert "bfloat16" in {x for x, _ in o2_dtypes.values()}
+    assert infer.rule_kind(op_type) == "registry"
+    assert infer.rule_kind(op_type + "_grad") == "grad"
+    assert op_type in roofline._HYBRID_COST or \
+        op_type in roofline._ELEMWISE_COST
+
+    def aval(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32)
+
+    ins, outs, attrs = {
+        "rms_norm": ({"X": [aval(6, 8)], "Scale": [aval(8)]},
+                     {"Out": [aval(6, 8)]}, {}),
+        "relu2": ({"X": [aval(6, 8)]}, {"Out": [aval(6, 8)]}, {}),
+        "causal_conv1d": ({"X": [aval(2, 6, 8)], "Filter": [aval(8, 4)],
+                           "Bias": [aval(8)]}, {"Out": [aval(2, 6, 8)]}, {}),
+        "ssd_scan": ({"X": [aval(2, 64, 4, 8)], "B": [aval(2, 64, 2, 16)]},
+                     {"Out": [aval(2, 64, 4, 8)]}, {"chunk_size": 32}),
+        "moe_router": ({"X": [aval(10, 8)], "W": [aval(8, 16)]},
+                       {"TopkWeight": [aval(10, 3)]}, {"top_k": 3}),
+        "moe_experts": ({"X": [aval(10, 8)], "W1": [aval(4, 8, 12)]},
+                        {"Out": [aval(10, 8)]},
+                        {"top_k": 3, "experts_held": 4, "num_experts": 16}),
+    }[op_type]
+    want = {"rms_norm": 5.0 * (48 + 8), "relu2": 2.0 * 48,
+            "causal_conv1d": (2 * 4 + 4) * 96.0,
+            "ssd_scan": 2.0 * 128 * (32 * 32 + 32 * 32 + 2 * 32 * 16),
+            "moe_router": 2.0 * 10 * 128,
+            "moe_experts": 4.0 * 10 * 3 * 0.25 * 8 * 12}[op_type]
+    flops, _ = roofline.op_cost(op_type, ins, outs, attrs)
+    assert flops == pytest.approx(want)
+    assert roofline.op_cost(op_type + "_grad", ins, outs, attrs)[0] == \
+        pytest.approx(2 * want)
+
+
+def test_preflight_of_the_tiny_model_is_clean(tiny_program):
+    """Shapes, dataflow and preflight over the whole train program: no
+    error, no pass that died, no op without a rule, and the side-fetched
+    routing statistics are not dead code."""
+    from paddle_tpu import analysis
+    main, loss = tiny_program
+    report = analysis.analyze_program(main, feeds=["tok", "lab"],
+                                      fetches=[loss.name])
+    bad = [d.format() for d in report.diagnostics
+           if d.severity != "info"]
+    assert not bad, bad

@@ -218,6 +218,38 @@ class TestExecutorTracing:
         assert telemetry.snapshot()["counters"][
             "optimizer_minimize_total"]["optimizer=sgd"] >= 1
 
+    def test_side_fetch_in_flight_is_published_when_the_executor_closes(self):
+        """A side-fetch still on the device stays queued (a pipelined loop
+        keeps its steps in flight); close() waits for it, so the last
+        step's gauge is not lost, and each publication is a `side_fetch`
+        event of the step log, in step order."""
+        class InFlight:
+            def __init__(self, value):
+                self.value = value
+
+            def is_ready(self):
+                return False
+
+            def __array__(self, dtype=None, copy=None):
+                return np.asarray([self.value], dtype)
+
+        exe = fluid.Executor(fluid.CPUPlace())
+        for value in (2.5, 3.5):
+            exe._side_pending.append(
+                ("optimizer_global_norm", InFlight(value), "pipelined"))
+        exe._publish_side_fetches()
+        assert len(exe._side_pending) == 2
+        assert "program=pipelined" not in telemetry.snapshot()[
+            "gauges"].get("optimizer_global_norm", {})
+        exe.close()
+        assert not exe._side_pending
+        assert telemetry.snapshot()["gauges"]["optimizer_global_norm"][
+            "program=pipelined"] == 3.5
+        events = [e for e in telemetry.recent_events(kind="side_fetch")
+                  if e["program"] == "pipelined"]
+        assert [e["values"] for e in events] == [[2.5], [3.5]]
+        assert events[0]["metric"] == "optimizer_global_norm"
+
     def test_feed_conversion_metrics(self):
         main, startup = fluid.Program(), fluid.Program()
         with fluid.program_guard(main, startup):

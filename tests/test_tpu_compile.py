@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from paddle_tpu.ops import fusion, pallas_attention, pallas_conv
+from paddle_tpu.ops import fusion, hybrid_ops, pallas_attention, pallas_conv
 
 BF16 = jnp.bfloat16
 KERNEL = 'custom_call_target="tpu_custom_call"'
@@ -166,14 +166,37 @@ def _flash_fwd_bwd(q, k, v):
 # 29); D=256 is a lane block of its own; T=4096 walks two major tiles.
 @pytest.mark.parametrize("shape", [(16, 1024, 12, 64), (8, 1024, 10, 64),
                                    (1, 1024, 8, 64), (1, 2048, 16, 64),
-                                   (1, 2048, 4, 256), (1, 4096, 8, 32)],
+                                   (1, 2048, 4, 256), (1, 4096, 8, 32),
+                                   (1, 4096, 32, 128)],
                          ids=["gpt2", "gpt2_large_shard", "8_heads",
-                              "16_heads", "head_dim_256", "two_major_d32"])
+                              "16_heads", "head_dim_256", "two_major_d32",
+                              "nemotron_h_after_kv_repeat"])
 def test_flash_fwd_bwd_compiles(mosaic, one_chip, shape):
     q = jax.ShapeDtypeStruct(shape, BF16)
     assert pallas_attention.ineligible(q, q, q) is None
     assert _compile(_flash_fwd_bwd, one_chip, *[(shape, BF16)] * 3) == [
         "flash_dkv", "flash_dq", "flash_fwd"]
+
+
+@pytest.mark.parametrize("dtype", [BF16, jnp.float32],
+                         ids=["bf16", "float32_no_amp"])
+def test_grouped_expert_products_compile(mosaic, one_chip, dtype):
+    """The hybrid cell's expert layer: 4096 tokens x top-6 rows of 2688
+    through 8 held experts of width 1856 and back, at the tiles the sweep
+    chose: the up product forward (the down product's result is not
+    needed for a gradient of its sum), and for each of the two its
+    backward products (gmm on the rows, tgmm on the weights)."""
+    rows, d, f, held = 4096 * 6, 2688, 1856, 8
+    assert hybrid_ops.gmm_ineligible(rows, d, f) is None
+
+    def grads(x, w1, w2, sizes):
+        return jax.grad(lambda *a: hybrid_ops._grouped_products(
+            *a, sizes, True).astype(jnp.float32).sum(), argnums=(0, 1, 2))(
+                x, w1, w2)
+
+    assert _compile(grads, one_chip, ((rows, d), dtype), ((held, d, f), dtype),
+                    ((held, f, d), dtype), ((held,), jnp.int32)) == [
+        "gmm"] * 3 + ["tgmm"] * 2
 
 
 @pytest.mark.parametrize("shape,reason", [
