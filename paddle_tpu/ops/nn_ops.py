@@ -7,6 +7,23 @@ dropout_op.cc, lrn_op.cc, accuracy_op.cc, auc_op.cc, loss ops…). Layout is
 NCHW to match the reference's user-visible semantics; XLA relayouts for the
 MXU internally, so no data_layout_transform pass is needed (reference
 framework/data_layout_transform.cc becomes a compiler concern).
+
+The hard-label softmax_with_cross_entropy carries its own gradient rule
+(`_hard_label_nll`, a jax.custom_vjp inside the lowering, so the generic
+gradient op differentiates through it and XLA merges the re-traced
+forward). Left to autodiff, `log_softmax` + `take_along_axis` on bf16
+logits made the whole GPT-2 step keep a float32 copy of the
+[16384, 50257] logits from the loss to the head's gradient products and
+write the log-probabilities out for a 16384-entry gather: 3.3 GB written
+and read twice, 17 ms of a 169.6 ms step, though head and loss alone
+fuse well (PERF.md section 6, PR 31). The rule reads log-sum-exp and the
+label's logit from the logits as they arrive, in one reduce, and gives
+the gradient in their dtype. Its one `optimization_barrier` pins the
+residual: without it XLA shares the backward's convert with the
+forward's and hoists it into the head's relayout copy, which brings the
+float32 buffer back. The gradient is left unpinned on purpose: XLA
+recomputes it where it is consumed, which measured faster than writing
+it out once (same section).
 """
 
 from __future__ import annotations
@@ -58,25 +75,85 @@ def _swce_infer(op_, block):
         set_out(op_, block, "Loss", list(xv.shape[:-1]) + [1], xv.dtype)
 
 
+def _hit(logits, idx):
+    """Where the last axis' position is the row's label: the one-hot as
+    an iota compare, which a reduce or an elementwise fusion takes in
+    without an array of the logits' size (and which GSPMD partitions
+    where a gather over a split vocabulary would not)."""
+    return jax.lax.broadcasted_iota(
+        jnp.int32, logits.shape, logits.ndim - 1) == idx
+
+
+def _nll_and_lse(logits, idx):
+    """(lse - logits[label], lse), both [..., 1] float32, read from the
+    logits in the dtype they arrive in: each convert sits inside the
+    reduction that consumes it."""
+    l32 = logits.astype(jnp.float32)
+    # the max of bf16 values is exact in bf16
+    m = jnp.max(logits, axis=-1, keepdims=True).astype(jnp.float32)
+    lse = m + jnp.log(jnp.sum(jnp.exp(l32 - m), axis=-1, keepdims=True))
+    picked = jnp.sum(jnp.where(_hit(logits, idx), l32, 0.0), axis=-1,
+                     keepdims=True)
+    return lse - picked, lse
+
+
+@jax.custom_vjp
+def _hard_label_nll(logits, idx):
+    """Hard-label cross-entropy and the rows' log-sum-exp; `idx` is the
+    int32 label with a trailing axis of 1. The rule is the op's own
+    (module docstring): nothing of the logits' size exists in float32,
+    forward or backward."""
+    return _nll_and_lse(logits, idx)
+
+
+def _hard_label_nll_fwd(logits, idx):
+    loss, lse = _nll_and_lse(logits, idx)
+    # barrier: the residual is the logits buffer the head's matmul wrote
+    # (bf16 under AMP). Unpinned, XLA merges the backward's convert with
+    # the forward's and hoists it into the relayout copy, and that one
+    # float32 copy then lives from the loss to the head's gradient matmuls
+    return (loss, lse), (jax.lax.optimization_barrier(logits), idx, lse)
+
+
+def _hard_label_nll_bwd(res, cts):
+    logits, idx, lse = res
+    g_loss, g_lse = cts
+    # d lse / d logits is the softmax, so a cotangent on lse (the Softmax
+    # output's, when something reads it) scales the same exponential
+    p = jnp.exp(logits.astype(jnp.float32) - lse)
+    d = p * (g_loss + g_lse) - jnp.where(_hit(logits, idx), g_loss, 0.0)
+    # no barrier here: XLA recomputes this in the prologues of the head's
+    # two gradient products and of its bias sum, one read of the logits
+    # each; pinned, it is written and read back (2.1 % of GPT-2's step)
+    return d.astype(logits.dtype), None
+
+
+_hard_label_nll.defvjp(_hard_label_nll_fwd, _hard_label_nll_bwd)
+
+
 @op("softmax_with_cross_entropy", infer_shape=_swce_infer,
     non_diff_inputs=("Label",))
 def _softmax_with_cross_entropy(ctx, op_, ins):
     logits = jnp.asarray(ins["Logits"][0])
     label = jnp.asarray(ins["Label"][0])
-    # logsumexp in f32 for stability with bf16 logits (AMP O2); the astype
-    # is inside the trace so its vjp casts the cotangent back to bf16
-    logits = logits.astype(jnp.float32) if logits.dtype != jnp.float32 \
-        else logits
-    logp = jax.nn.log_softmax(logits, axis=-1)
     if op_.attr("soft_label", False):
+        # a soft label weighs the whole row: the dense path. logsumexp in
+        # f32 for stability with bf16 logits (AMP O2); the astype is
+        # inside the trace so its vjp casts the cotangent back to bf16
+        logits = logits.astype(jnp.float32)
+        logp = jax.nn.log_softmax(logits, axis=-1)
         loss = -jnp.sum(label * logp, axis=-1, keepdims=True)
+        softmax = jnp.exp(logp)
     else:
         idx = label.astype(jnp.int32)
         if idx.ndim < logits.ndim:
             idx = idx[..., None]
         elif idx.shape[-1] != 1:
             idx = idx[..., :1]
-        loss = -jnp.take_along_axis(logp, idx, axis=-1)
+        loss, lse = _hard_label_nll(logits, idx)
+        # outside the rule: a step that reads no Softmax drops it; one
+        # that does differentiates it through the logits and through lse
+        softmax = jnp.exp(logits.astype(jnp.float32) - lse)
     # padded sequence logits [B,T,V]: zero the padded positions' losses
     lengths = ctx.seq_len(op_.desc.inputs["Logits"][0])
     if lengths is not None and logits.ndim >= 3:
@@ -84,7 +161,7 @@ def _softmax_with_cross_entropy(ctx, op_, ins):
         mask = (jnp.arange(t)[None, :] <
                 jnp.asarray(lengths)[:, None]).astype(loss.dtype)
         loss = loss * mask.reshape(mask.shape + (1,) * (loss.ndim - 2))
-    return {"Softmax": [jnp.exp(logp)], "Loss": [loss]}
+    return {"Softmax": [softmax], "Loss": [loss]}
 
 
 @op("sigmoid_cross_entropy_with_logits", infer_shape=same_as_input(),

@@ -196,6 +196,10 @@ def generic_grad_lower(ctx, op, ins):
     # MaxIndex); populated during the eager vjp trace below
     out_spec: List = []
 
+    def arrives(s, j):
+        gvals = ins.get(s + "@GRAD", [])
+        return j < len(gvals) and gvals[j] is not None
+
     def fwd_fn(diff_vals):
         local = {s: list(vs) for s, vs in fwd_ins.items()}
         for (slot, i), v in zip(diff_paths, diff_vals):
@@ -205,7 +209,13 @@ def generic_grad_lower(ctx, op, ins):
         out_spec.clear()
         for s in out_slots_order:
             for j, v in enumerate(outs.get(s, [])):
-                flat.append(v)
+                # an output nothing downstream differentiates (the loss
+                # op's Softmax, a norm's saved statistics) leaves the
+                # backward at trace time: fed a zeros cotangent instead,
+                # its whole pullback is emitted and XLA keeps it, because
+                # x * 0 is not 0 for floats (2 % of the GPT-2 step for the
+                # loss's exp(logits) * 0 alone, PERF.md section 6, PR 31)
+                flat.append(v if arrives(s, j) else jax.lax.stop_gradient(v))
                 out_spec.append((s, j))
         return flat
 
@@ -221,12 +231,11 @@ def generic_grad_lower(ctx, op, ins):
     cts = []
     for ov, (s, j) in zip(out_vals, out_spec):
         ov = jnp.asarray(ov)
-        gvals = ins.get(s + "@GRAD", [])
         if not jnp.issubdtype(ov.dtype, jnp.inexact):
             # integer/bool outputs carry no gradient signal
             cts.append(np.zeros(ov.shape, dtype=jax.dtypes.float0))
-        elif j < len(gvals) and gvals[j] is not None:
-            cts.append(jnp.asarray(gvals[j], dtype=ov.dtype))
+        elif arrives(s, j):
+            cts.append(jnp.asarray(ins[s + "@GRAD"][j], dtype=ov.dtype))
         else:
             cts.append(jnp.zeros_like(ov))
     (grads,) = vjp_fn(cts)

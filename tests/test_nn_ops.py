@@ -156,6 +156,188 @@ class TestSoftmaxWithCE(OpTest):
                         no_grad_set={"Label"})
 
 
+# (logits shape, label shape, label dtype, logits dtype, padded, soft,
+# Softmax read downstream): 1031 = 50257 cut to a class count that no
+# 128-lane tile divides
+SWCE_CASES = {
+    "rank2-label_n1-int64": ((6, 1031), (6, 1), "int64", "float32", 0, 0, 0),
+    "rank2-label_n-int32": ((6, 1031), (6,), "int32", "float32", 0, 0, 0),
+    "rank2-130x257": ((130, 257), (130, 1), "int64", "float32", 0, 0, 0),
+    "rank3-label_bt-int64": ((2, 5, 1031), (2, 5), "int64", "float32",
+                             0, 0, 0),
+    "rank3-label_bt1-int32": ((2, 5, 1031), (2, 5, 1), "int32", "float32",
+                              0, 0, 0),
+    "rank2-bf16": ((6, 1031), (6, 1), "int64", "bfloat16", 0, 0, 0),
+    "rank3-bf16": ((2, 5, 1031), (2, 5), "int64", "bfloat16", 0, 0, 0),
+    "rank3-padded": ((3, 8, 1031), (3, 8, 1), "int64", "float32", 1, 0, 0),
+    "rank3-padded-bf16": ((3, 8, 1031), (3, 8, 1), "int64", "bfloat16",
+                          1, 0, 0),
+    "rank2-softmax_read": ((6, 1031), (6, 1), "int64", "float32", 0, 0, 1),
+    "rank3-softmax_read-bf16": ((2, 5, 1031), (2, 5), "int64", "bfloat16",
+                                0, 0, 1),
+    "soft-rank2": ((6, 1031), (6, 1031), "float32", "float32", 0, 1, 0),
+    "soft-rank2-bf16": ((6, 1031), (6, 1031), "float32", "bfloat16",
+                        0, 1, 0),
+    "soft-rank2-softmax_read": ((6, 1031), (6, 1031), "float32", "float32",
+                                0, 1, 1),
+}
+
+
+@pytest.mark.parametrize("case", list(SWCE_CASES))
+def test_softmax_with_cross_entropy_against_dense(case):
+    """Loss, Softmax and the Logits gradient under a non-uniform
+    cotangent, against the dense formula in numpy float64 on the logits
+    as the op receives them. Hard labels take the op's own rule
+    (log-sum-exp and the label's logit, no log-probabilities), soft
+    labels the dense path; both must read the same numbers, also where
+    the objective reads the Softmax output as well."""
+    import ml_dtypes
+    import paddle_tpu as fluid
+    from paddle_tpu.executor import LoDTensor
+
+    shape, lshape, ldtype, dtype, padded, soft, reads = SWCE_CASES[case]
+    rng = np.random.RandomState(31)
+    x = rng.uniform(-4, 4, shape).astype(np.float32)
+    classes = shape[-1]
+    if soft:
+        lab = rng.uniform(0, 1, lshape).astype(np.float32)
+        lab /= lab.sum(-1, keepdims=True)
+    else:
+        lab = rng.randint(0, classes, lshape).astype(ldtype)
+        lab.reshape(-1)[:2] = [0, classes - 1]      # both ends of the row
+    w = rng.uniform(0.5, 2, shape[:-1] + (1,)).astype(np.float32)
+    v = rng.uniform(-1, 1, shape).astype(np.float32) * reads
+    lengths = [8, 2, 5] if padded else None
+
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        # a padded feed declares its features; [batch, time] come with it
+        xv = fluid.layers.data(
+            name="x", shape=[classes] if padded else list(shape),
+            dtype="float32", append_batch_size=bool(padded),
+            lod_level=int(padded), stop_gradient=False)
+        lv = fluid.layers.data(name="lab", shape=list(lshape), dtype=ldtype,
+                               append_batch_size=False)
+        wv = fluid.layers.data(name="w", shape=list(w.shape),
+                               dtype="float32", append_batch_size=False)
+        logits = fluid.layers.cast(xv, dtype) if dtype != "float32" else xv
+        helper = fluid.layer_helper.LayerHelper("softmax_with_cross_entropy")
+        softmax = helper.create_tmp_variable(dtype="float32")
+        loss = helper.create_tmp_variable(dtype="float32")
+        helper.append_op(type="softmax_with_cross_entropy",
+                         inputs={"Logits": [logits], "Label": [lv]},
+                         outputs={"Softmax": [softmax], "Loss": [loss]},
+                         attrs={"soft_label": bool(soft)})
+        total = fluid.layers.reduce_sum(
+            fluid.layers.elementwise_mul(loss, wv))
+        if reads:
+            vv = fluid.layers.data(name="v", shape=list(shape),
+                                   dtype="float32", append_batch_size=False)
+            total = fluid.layers.elementwise_add(
+                total, fluid.layers.reduce_sum(
+                    fluid.layers.elementwise_mul(softmax, vv)))
+        fluid.append_backward(total)
+    feed_x = x
+    if padded:
+        offs = np.concatenate([[0], np.cumsum(lengths)])
+        feed_x = LoDTensor(np.concatenate(
+            [x[i, :n] for i, n in enumerate(lengths)]), [list(offs)])
+    got_loss, got_sm, got_grad, got_total = fluid.Executor(
+        fluid.CPUPlace()).run(
+            main, feed={"x": feed_x, "lab": lab, "w": w, "v": v},
+            fetch_list=[loss.name, softmax.name,
+                        fluid.framework.grad_var_name(logits.name),
+                        total.name])
+
+    mask = np.ones(shape[:-1] + (1,))
+    if padded:
+        mask = (np.arange(shape[1])[None, :] <
+                np.asarray(lengths)[:, None]).astype(np.float64)[..., None]
+        x = x * mask.astype(np.float32)          # the padding is zeros
+    bf16 = dtype == "bfloat16"
+    seen = x.astype(ml_dtypes.bfloat16) if bf16 else x
+    l64 = seen.astype(np.float64)
+    e = np.exp(l64 - l64.max(-1, keepdims=True))
+    sm = e / e.sum(-1, keepdims=True)
+    if soft:
+        target = lab.astype(np.float64)
+    else:
+        target = np.eye(classes)[lab.reshape(shape[:-1])]
+    want_loss = -(target * np.log(sm)).sum(-1, keepdims=True) * mask
+    want_grad = (sm * target.sum(-1, keepdims=True) - target) * (w * mask)
+    want_grad += sm * (v - (sm * v).sum(-1, keepdims=True))
+    # a padded position's loss is zero, so it adds nothing downstream
+    np.testing.assert_allclose(
+        got_total, [(want_loss * w).sum() + (sm * v).sum()], rtol=1e-5)
+    if padded:      # a sequence fetch comes back packed: the valid rows
+        want_loss, sm, want_grad, v = (
+            np.concatenate([a[i, :n] for i, n in enumerate(lengths)])
+            for a in (want_loss, sm, want_grad, v))
+
+    assert got_loss.dtype == np.float32 and got_sm.dtype == np.float32
+    np.testing.assert_allclose(got_loss, want_loss, rtol=2e-6, atol=2e-6)
+    np.testing.assert_allclose(got_sm, sm, rtol=2e-5, atol=1e-9)
+    if bf16:
+        # the gradient comes in the logits' dtype: the dense rule's
+        # float32 value cast once, so within one bf16 ulp (2**-8) of it.
+        # A Softmax read as well reaches the logits as a second bf16
+        # term (sm * v; the part through lse rides the rule's own), and
+        # the two are added in bf16: an ulp of each and of the sum
+        assert got_grad.dtype == ml_dtypes.bfloat16
+        ulps = np.abs(want_grad) + reads * (
+            np.abs(sm * v) + np.abs(want_grad - sm * v))
+        assert (np.abs(got_grad.astype(np.float64) - want_grad)
+                <= 2.0 ** -8 * ulps).all()
+    else:
+        assert got_grad.dtype == np.float32
+        np.testing.assert_allclose(got_grad, want_grad, rtol=2e-5,
+                                   atol=1e-8)
+
+
+def test_output_without_cotangent_leaves_the_backward(monkeypatch):
+    """The generic gradient op differentiates only the outputs a
+    cotangent arrives for. smooth_l1_loss's Diff is read by nothing here;
+    its lowering is swapped for one whose Diff has an infinite slope at
+    the fed 0 and says when its pullback is traced: it never is, and the
+    gradient holds no 0 * inf."""
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu as fluid
+    from paddle_tpu.ops import registry
+
+    pulled = []
+
+    @jax.custom_vjp
+    def watched(x):
+        return jnp.sqrt(x)
+
+    def watched_bwd(x, g):
+        pulled.append(x.shape)
+        return (g * 0.5 / jnp.sqrt(x),)
+
+    watched.defvjp(lambda x: (jnp.sqrt(x), x), watched_bwd)
+
+    def lower(ctx, op_, ins):
+        x = jnp.asarray(ins["X"][0])
+        return {"Out": [jnp.sum(2.0 * x, axis=1, keepdims=True)],
+                "Diff": [watched(x)]}
+
+    monkeypatch.setattr(registry.get("smooth_l1_loss"), "lower", lower)
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = fluid.layers.data(name="x", shape=[4, 3], dtype="float32",
+                              append_batch_size=False, stop_gradient=False)
+        y = fluid.layers.data(name="y", shape=[4, 3], dtype="float32",
+                              append_batch_size=False)
+        fluid.append_backward(fluid.layers.mean(fluid.layers.smooth_l1(x, y)))
+    zeros = np.zeros((4, 3), np.float32)
+    grad, = fluid.Executor(fluid.CPUPlace()).run(
+        main, feed={"x": zeros, "y": zeros},
+        fetch_list=[fluid.framework.grad_var_name("x")])
+    assert pulled == []
+    np.testing.assert_array_equal(grad, np.full((4, 3), 0.5, np.float32))
+
+
 class TestLookupTable(OpTest):
     op_type = "lookup_table"
 

@@ -18,7 +18,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from benchmarks import run
 from paddle_tpu.ops import fusion, hybrid_ops, pallas_attention, pallas_conv
+from tools import describe_step
 
 BF16 = jnp.bfloat16
 KERNEL = 'custom_call_target="tpu_custom_call"'
@@ -197,6 +199,29 @@ def test_grouped_expert_products_compile(mosaic, one_chip, dtype):
     assert _compile(grads, one_chip, ((rows, d), dtype), ((held, d, f), dtype),
                     ((held, f, d), dtype), ((held,), jnp.int32)) == [
         "gmm"] * 3 + ["tgmm"] * 2
+
+
+def test_gpt2_step_holds_no_float32_logits(mosaic, one_chip):
+    """One layer of the `gpt2` cell's train step at its batch of 16: the
+    loss reads the head's bf16 logits where they lie. A float32 array of
+    the logits' size (the convert hoisted into the relayout copy, or the
+    log-probabilities written out for a gather) is 3.3 GB written and
+    read again, 10 ms each on the chip, and only the whole step shows it
+    (head and loss alone fuse well); with either, the temporaries were
+    6.62 GB against 3.73 (PERF.md section 6, PR 31)."""
+    cell = run.load_json("workloads", "gpt2.train-t1024")
+    config = dict(run.load_json("configs", cell["config"]), n_layer=1)
+    compiled = describe_step.compile_step(cell, config, one_chip)
+    text = compiled.as_text()
+    rows = cell["batch"] * config["n_positions"]
+    wide = describe_step.wide_instructions(text, rows * 1024)
+    logits = [shape for elements, shape, _, _ in wide
+              if elements == rows * config["vocab_size"]]
+    assert logits and all(s.startswith("bf16[") for s in logits), wide
+    gathers = [line for line in text.splitlines()
+               if " gather(" in line and "50257" in line]
+    assert not gathers, gathers
+    assert compiled.memory_analysis().temp_size_in_bytes < 4.2e9
 
 
 @pytest.mark.parametrize("shape,reason", [
