@@ -108,12 +108,6 @@ def _is_hbm_overflow(e):
     return memory.is_oom(e) and "vmem" not in str(e)
 
 
-# The pallas_call names of ops/pallas_conv.py. No float conv lowers to
-# them (XLA's convolution ran this step 11 times faster: PERF.md §6, PR
-# 25), so one in the ResNet step, or a pallas_kernel_total hit, is a
-# route that came back without a price.
-_CONV_KERNELS = ("conv2d", "conv2d_stats", "conv2d_grad_filter", "bn_apply",
-                 "conv2d_q8")
 _FLASH_KERNELS = ("flash_fwd", "flash_dq", "flash_dkv")
 
 
@@ -140,20 +134,20 @@ def _step_hlo(exe, prog, feed, fetch, scope):
         return exe.compiled_hlo(prog, feed=feed, fetch_list=[fetch])
 
 
-def _check_no_conv_kernels(hits, calls):
-    """The gates counted no conv kernel and the compiled step holds
-    none: `hits` is pallas_kernel_total as counted, of which only the
-    conv ops' series are judged (the counter is the process's, and an
-    attention lowered before this phase has booked its own series);
-    `calls` the step's Mosaic calls (fusion's bn_act is the only family
-    left in them)."""
+def _check_no_kernels(hits, calls):
+    """The ResNet step is XLA's alone (PERF.md section 6: PR 25 took the
+    convs off the Pallas suite, PR 34 deleted fusion's bn+act kernel):
+    the conv gates counted no kernel and the compiled step holds no
+    Mosaic call. `hits` is pallas_kernel_total as counted, of which only
+    the conv ops' series are judged (the counter is the process's, and
+    an attention lowered before this phase has booked its own series);
+    `calls` the step's Mosaic calls. One that came back came back
+    without a price."""
     convs = {k: n for k, n in hits.items() if k.startswith("op=conv")}
-    held = {k: n for k, n in calls.items()
-            if k.partition("/")[2] in _CONV_KERNELS}
-    if convs or held:
+    if convs or calls:
         raise AssertionError(
-            f"a conv lowered to a Pallas kernel: pallas_kernel_total "
-            f"{convs}, Mosaic calls {held}")
+            f"the train step lowered to a Pallas kernel: "
+            f"pallas_kernel_total {convs}, Mosaic calls {calls}")
 
 
 def _check_flash_kernels(calls, n_layer):
@@ -195,10 +189,9 @@ def train_phase(batch, side, classes, depth=50, steps=3, window=4,
     """ResNet train steps through Executor(TPUPlace(0)), per step and as
     one run_steps window (depth 50 is models.resnet50, the flagship).
     Returns (record, state) — `state` hands the trained program to
-    serve_phase. `compiled` says the Pallas kernels
-    are Mosaic-compiled (the chip), not interpreted (the CPU rehearsal):
-    the step's tpu_custom_calls are then read too, and none may be a
-    conv kernel (_check_no_conv_kernels)."""
+    serve_phase. `compiled` says the step is compiled for the chip, not
+    for the CPU rehearsal: its tpu_custom_calls are then read too, and
+    there may be none (_check_no_kernels)."""
     import jax
     import paddle_tpu as fluid
     from paddle_tpu import executor as executor_mod
@@ -252,7 +245,7 @@ def train_phase(batch, side, classes, depth=50, steps=3, window=4,
     mosaic = {}
     if compiled:
         mosaic = _mosaic_calls(_step_hlo(exe, main, feed, loss, scope))
-    _check_no_conv_kernels(counters["pallas_kernel_total"], mosaic)
+    _check_no_kernels(counters["pallas_kernel_total"], mosaic)
     record = {
         "phase": "train", "model": f"resnet{depth}", "batch": batch,
         "image": [3, side, side], "classes": classes, "amp": "O2",

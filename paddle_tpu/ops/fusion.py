@@ -17,15 +17,14 @@ its anchor preserves the original op order exactly — no dependency
 analysis is needed, and the compose paths below run each member through
 the executor's own `_exec_op` (prepass, registry lowering, SEQLEN and
 layout-tag bookkeeping), so they are bitwise identical to the unfused
-trace. The only value-rewriting paths are:
-
-  * inference-mode conv+bn: BN folds into the conv filter/bias
-    (w' = w * scale/sqrt(var+eps), b' = bias - mean * that) and the
-    conv's own output is elided from the trace when nothing else
-    consumes it;
-  * training-mode bn[+act] on bf16 NHWC activations: a single Pallas
-    TPU kernel (one-pass E[x^2]-E[x]^2 statistics, matching the unfused
-    bf16 path) normalizes and activates in one VMEM sweep.
+trace. The only value-rewriting path is inference-mode conv+bn: BN folds
+into the conv filter/bias (w' = w * scale/sqrt(var+eps), b' = bias -
+mean * that) and the conv's own output is elided from the trace when
+nothing else consumes it. A training-mode conv+bn[+act] window composes
+like every other: between two convolutions XLA sees plain jax.numpy and
+chooses layouts, fusions and what the backward keeps (PERF.md, PR 34:
+the Mosaic bn+act kernel that stood here cost its own sweeps and a
+relayout of every conv output, and went).
 
 An optimizer bucket rewrites no value: it is ONE scope and observer
 entry over per-tensor updates, each dense member's own sgd/momentum/adam
@@ -49,7 +48,6 @@ sub-blocks run per-op as before.
 
 from __future__ import annotations
 
-import functools
 import os
 from contextlib import contextmanager
 from dataclasses import dataclass, field
@@ -57,7 +55,6 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from ..framework.desc import OpDesc
 from . import layout as layout_mod
@@ -75,7 +72,7 @@ FUSION_OPT = os.environ.get("PADDLE_TPU_FUSION", "1") == "1"
 CONV_OPS = frozenset({"conv2d", "depthwise_conv2d"})
 
 # activations fusable as a window tail: unary X->Out, layout-agnostic,
-# and expressible inside the Pallas bn+act kernel (static attrs only)
+# static attrs only
 ACT_OPS = frozenset({
     "relu", "relu6", "leaky_relu", "sigmoid", "tanh", "elu", "swish",
     "brelu", "hard_sigmoid", "soft_relu",
@@ -373,8 +370,7 @@ def _count(ctx, reason: str, amount: int = 1):
     from .. import telemetry
     telemetry.counter(
         "fusion_fallback_total",
-        "ops lowered unfused (or without the fused kernel) by the "
-        "trace-time fusion pass, by reason",
+        "ops lowered unfused by the trace-time fusion pass, by reason",
         labels=("program", "reason")).labels(
         program=telemetry.program_label(ctx.program), reason=reason).inc(
         amount)
@@ -505,178 +501,12 @@ def _compose_lower(ctx, op_, ins):
 # --- conv/bn/act window -------------------------------------------------
 
 def _conv_bn_act_lower(ctx, op_, ins):
+    """Inference folds (only a window with a conv can: `_match_conv_bn_act`);
+    a training-mode window composes its members."""
     g: Group = op_.attr("__fusion_group__")
-    env = ctx.env
     if g.fold:
-        return _fold_lower(ctx, op_, g, env)
-    with _muted_observers():
-        if g.conv is not None:
-            ctx.executor._exec_op(ctx, g.conv, env)
-        reason = _kernel_ineligible(ctx, g, env)
-        if reason is None:
-            _bn_act_pallas(ctx, g, env)
-        else:
-            # compose fallback: still one fused unit for attribution,
-            # but the plain jnp batch_norm (+act) lowerings — bitwise
-            # identical to the unfused trace
-            _count(ctx, reason)
-            ctx.executor._exec_op(ctx, g.bn, env)
-            if g.act is not None:
-                ctx.executor._exec_op(ctx, g.act, env)
-    _freeze(ctx, env, _out_names(op_))
-    return _collect(op_, env)
-
-
-def _kernel_ineligible(ctx, g: Group, env) -> Optional[str]:
-    """None when the Pallas bn+act kernel applies, else a fallback-counter
-    reason. The kernel computes one-pass f32 statistics — exactly the
-    unfused bf16 path — so it is gated to bf16 inputs; f32 inputs keep the
-    two-pass centered variance via the compose fallback."""
-    if g.bn.attr("is_test", False):
-        return "kernel_is_test"
-    mesh = getattr(ctx.program, "_mesh", None)
-    if mesh is not None and mesh.size > 1:
-        # XLA cannot partition a bare Mosaic call, and batch statistics
-        # span the whole batch
-        return "kernel_mesh"
-    xname = _first(g.bn.desc.input("X"))
-    x = env.get(xname)
-    if getattr(x, "ndim", 0) != 4 or \
-            ctx.layouts.get(xname) != layout_mod.NHWC:
-        return "kernel_layout"
-    if getattr(x, "dtype", None) != jnp.bfloat16:
-        return "kernel_dtype"
-    c = x.shape[-1]
-    m = int(np.prod(x.shape[:-1]))
-    if c % 128 != 0 or m < 8 or m % 8 != 0:
-        return "kernel_shape"
-    return None
-
-
-def _bn_act_pallas(ctx, g: Group, env):
-    """Training-mode BN[+act] as one Pallas TPU kernel over the [M, C]
-    view of the NHWC activation (M = N*H*W): a two-phase grid reads each
-    x block twice — phase 0 accumulates per-channel sum/sum-of-squares in
-    VMEM scratch, phase 1 normalizes, applies the activation, and writes
-    the bf16 outputs — so statistics + normalize + activation take two
-    HBM sweeps of x and never materialize f32 intermediates."""
-    bn, act = g.bn, g.act
-    xname = _first(bn.desc.input("X"))
-    x = jnp.asarray(env[xname])
-    scale = jnp.asarray(env[_first(bn.desc.input("Scale"))])
-    bias = jnp.asarray(env[_first(bn.desc.input("Bias"))])
-    mean = jnp.asarray(env[_first(bn.desc.input("Mean"))])
-    var = jnp.asarray(env[_first(bn.desc.input("Variance"))])
-    eps = float(bn.attr("epsilon", 1e-5))
-    momentum = bn.attr("momentum", 0.9)
-    c = x.shape[-1]
-    x2 = x.reshape(-1, c)
-
-    act_fn = None
-    if act is not None:
-        base = _activations[act.type]
-        act_fn = functools.partial(base, a=act)
-    ybn2, yact2, saved_mean, saved_var = _pallas_bn_act(
-        x2, scale.astype(jnp.float32), bias.astype(jnp.float32), eps,
-        act_fn)
-
-    y = ybn2.reshape(x.shape)
-    env[_first(bn.desc.output("Y"))] = y
-    ctx.layouts[_first(bn.desc.output("Y"))] = layout_mod.NHWC
-    # running stats on tiny [C] vectors stay outside the kernel
-    env[_first(bn.desc.output("MeanOut"))] = \
-        mean * momentum + saved_mean * (1.0 - momentum)
-    env[_first(bn.desc.output("VarianceOut"))] = \
-        var * momentum + saved_var * (1.0 - momentum)
-    env[_first(bn.desc.output("SavedMean"))] = saved_mean
-    env[_first(bn.desc.output("SavedVariance"))] = saved_var
-    if act is not None:
-        out = _first(act.desc.output("Out"))
-        env[out] = yact2.reshape(x.shape)
-        ctx.layouts[out] = layout_mod.NHWC
-
-
-def _bn_act_kernel(x_ref, scale_ref, bias_ref, *refs, eps, act, m_total):
-    if act is None:
-        ybn_ref, mean_ref, var_ref, sum_ref, sq_ref = refs
-        yact_ref = None
-    else:
-        ybn_ref, yact_ref, mean_ref, var_ref, sum_ref, sq_ref = refs
-    from jax.experimental import pallas as pl
-    p = pl.program_id(1)
-    m = pl.program_id(2)
-
-    @pl.when(jnp.logical_and(p == 0, m == 0))
-    def _zero():
-        sum_ref[...] = jnp.zeros(sum_ref.shape, jnp.float32)
-        sq_ref[...] = jnp.zeros(sq_ref.shape, jnp.float32)
-
-    @pl.when(p == 0)
-    def _accumulate():
-        xb = x_ref[...].astype(jnp.float32)
-        sum_ref[...] += jnp.sum(xb, axis=0, keepdims=True)
-        sq_ref[...] += jnp.sum(xb * xb, axis=0, keepdims=True)
-
-    @pl.when(p == 1)
-    def _apply():
-        mean = sum_ref[...] / m_total
-        # one-pass variance, clamped like the unfused bf16 batch_norm
-        varv = jnp.maximum(sq_ref[...] / m_total - mean * mean, 0.0)
-
-        @pl.when(m == 0)
-        def _stats():
-            mean_ref[...] = mean
-            var_ref[...] = varv
-
-        inv = jax.lax.rsqrt(varv + eps)
-        xb = x_ref[...].astype(jnp.float32)
-        y = (xb - mean) * (inv * scale_ref[...]) + bias_ref[...]
-        y = y.astype(ybn_ref.dtype)
-        ybn_ref[...] = y
-        if yact_ref is not None:
-            yact_ref[...] = act(y)
-
-
-def _pallas_bn_act(x2, scale, bias, eps, act_fn):
-    """x2: [M, C] bf16 (C % 128 == 0, M % 8 == 0). Returns (ybn, yact,
-    mean, var) with yact None-shaped out when act_fn is None."""
-    from jax.experimental import pallas as pl
-    from .pallas_attention import _compiler_params, _interpret, _scratch
-    m_total, c = x2.shape
-    bc = 128
-    bm = next(b for b in (512, 256, 128, 64, 32, 16, 8) if m_total % b == 0)
-    grid = (c // bc, 2, m_total // bm)
-
-    x_spec = pl.BlockSpec((bm, bc), lambda cc, p, mm: (mm, cc))
-    vec_spec = pl.BlockSpec((1, bc), lambda cc, p, mm: (0, cc))
-    out_specs = [x_spec] + ([x_spec] if act_fn is not None else []) + \
-        [vec_spec, vec_spec]
-    out_shape = [jax.ShapeDtypeStruct((m_total, c), x2.dtype)]
-    if act_fn is not None:
-        out_shape.append(jax.ShapeDtypeStruct((m_total, c), x2.dtype))
-    out_shape += [jax.ShapeDtypeStruct((1, c), jnp.float32),
-                  jax.ShapeDtypeStruct((1, c), jnp.float32)]
-
-    kernel = functools.partial(_bn_act_kernel, eps=eps, act=act_fn,
-                               m_total=float(m_total))
-    outs = pl.pallas_call(
-        kernel,
-        name="bn_act",
-        grid=grid,
-        in_specs=[x_spec, vec_spec, vec_spec],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[_scratch((1, bc)), _scratch((1, bc))],
-        interpret=_interpret(),
-        compiler_params=_compiler_params(
-            ("parallel", "arbitrary", "arbitrary")),
-    )(x2, scale.reshape(1, c), bias.reshape(1, c))
-    if act_fn is not None:
-        ybn, yact, mean, var = outs
-    else:
-        ybn, mean, var = outs
-        yact = None
-    return ybn, yact, mean.reshape(c), var.reshape(c)
+        return _fold_lower(ctx, op_, g, ctx.env)
+    return _compose_lower(ctx, op_, ins)
 
 
 def _fold_lower(ctx, op_, g: Group, env):
@@ -849,7 +679,7 @@ def _sparse_bucket_lower(ctx, op_, ins):
 # --- registration -------------------------------------------------------
 
 register("fused_conv_bn_act", lower=_conv_bn_act_lower, grad=NO_GRAD)
-register("fused_bn_act", lower=_conv_bn_act_lower, grad=NO_GRAD)
+register("fused_bn_act", lower=_compose_lower, grad=NO_GRAD)
 register("fused_fc_act", lower=_compose_lower, grad=NO_GRAD)
 register("fused_chain", lower=_compose_lower, grad=NO_GRAD)
 register("fused_sgd", lower=_lower_fused_sgd, grad=NO_GRAD)

@@ -229,7 +229,7 @@ def _compiler_params(semantics):
     "arbitrary" marks a dim whose scratch accumulators DO carry.
     vmem_limit raised past the 16 MB default (v5e has 128 MB physical
     VMEM; 64 MB leaves headroom for double-buffered DMA). Shared with
-    ops/pallas_conv.py and fusion's bn+act kernel."""
+    ops/pallas_conv.py."""
     if _interpret():
         return None
     from jax.experimental.pallas import tpu as pltpu

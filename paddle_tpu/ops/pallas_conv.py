@@ -1,6 +1,6 @@
 """Tiled MXU Pallas kernels for conv2d forward / grad-input / grad-filter
 (/opt/skills/guides/pallas_guide.md patterns, ops/pallas_attention.py
-and the fusion bn+act kernel as the in-repo templates). Only the int8
+as the in-repo template). Only the int8
 forward is routed: see "Routing" below before reaching for the rest.
 
 Tiling: NHWC operands, bf16 on the MXU datapath with f32 VMEM
@@ -319,9 +319,8 @@ def _wgrad_kernel(x_ref, do_ref, o_ref, acc, *, kw_n, dw, sw, ow, m_n):
 
 def _bn_apply_kernel(x_ref, scale_ref, bias_ref, mean_ref, var_ref, *refs,
                      eps, act):
-    """Normalize + activation given precomputed statistics — phase 1 of
-    the fusion bn+act kernel with the statistics pass replaced by the
-    conv2d_stats epilogue."""
+    """Normalize + activation given precomputed statistics (the
+    conv2d_stats epilogue's)."""
     if act is None:
         (ybn_ref,) = refs
         yact_ref = None
@@ -527,9 +526,8 @@ def conv2d_grad_filter(x, dout, kernel_hw, strides, paddings, dilations,
 
 def bn_apply(x2, scale, bias, mean, var, eps, act_fn):
     """x2 [M, C] bf16 (C % 128 == 0, M % 8 == 0); scale/bias/mean/var f32
-    [C]. Returns (ybn, yact) with yact None when act_fn is — the fusion
-    bn+act kernel's normalize phase, statistics supplied by
-    conv2d_stats."""
+    [C]. Returns (ybn, yact) with yact None when act_fn is: normalize
+    (+ activation), statistics supplied by conv2d_stats."""
     import jax.experimental.pallas as pl
     m_total, c = x2.shape
     bc = _LANE

@@ -2,13 +2,14 @@
 with the unfused per-op trace, per-reason fallback counters, and the
 PADDLE_TPU_FUSION=0 escape hatch.
 
-The fusion pass has two value-rewriting paths (inference BN fold, the
-Pallas bn+act kernel); everything else composes the registered member
-lowerings, and an optimizer bucket applies its members' own arithmetic
-tensor by tensor, so both must be BITWISE identical to the unfused
-trace — these tests pin exactly that: bitwise asserts for compose/
-bucket paths, tolerance asserts only where the rewrite legitimately
-reassociates float math (BN fold).
+The fusion pass has one value-rewriting path (inference BN fold);
+everything else, the training-mode conv+bn+act window included (PR 34:
+its Pallas kernel went, XLA fuses the plain lowerings), composes the
+registered member lowerings, and an optimizer bucket applies its
+members' own arithmetic tensor by tensor, so both must be BITWISE
+identical to the unfused trace — these tests pin exactly that: bitwise
+asserts for compose/bucket paths, tolerance asserts only where the
+rewrite legitimately reassociates float math (BN fold).
 """
 
 import numpy as np
@@ -115,14 +116,16 @@ def test_training_parity_bitwise(opt):
     _assert_state_equal(s1, s0)
 
 
-def test_kernel_gate_counts_f32_fallback():
-    """f32 training bn+act is outside the Pallas kernel's envelope (the
-    kernel mirrors the bf16 one-pass stats); the group must still fuse
-    via compose and count one per-reason fallback per trace."""
-    before = _fallbacks("kernel_dtype")
-    _with_fusion(True, _train_convnet,
-                 lambda: fluid.optimizer.SGD(learning_rate=0.05))
-    assert _fallbacks("kernel_dtype") > before
+def test_f32_window_fuses_without_fallback():
+    """A float32 training conv+bn+act window is a window like any other:
+    it fuses (one fused_conv_bn_act in the plan), books no fallback of
+    any reason and matches the unfused trace bit for bit."""
+    sgd = lambda: fluid.optimizer.SGD(learning_rate=0.05)
+    l1, s1 = _with_fusion(True, _train_convnet, sgd)
+    assert _fallbacks() == 0, telemetry.read_series("fusion_fallback_total")
+    l0, s0 = _with_fusion(False, _train_convnet, sgd)
+    assert l1 == l0
+    _assert_state_equal(s1, s0)
 
 
 def _bn_act_net(steps=2):
@@ -354,38 +357,102 @@ def test_run_steps_window_parity():
     _assert_state_equal(s1, s0)
 
 
-def test_pallas_bn_act_kernel_parity():
-    """The fused bn+act Pallas kernel (interpret mode off-TPU) matches
-    the unfused bf16 one-pass batch_norm math exactly."""
+def _amp_window_net(kind, channels):
+    """An AMP O2 step around one training-mode window on a bf16
+    activation: `conv_bn_act` = conv3x3 -> bn(relu); `bn_act` = conv1x1
+    -> max pool -> bn(relu), whose window holds no conv."""
+    unique_name.switch()
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = 17
+    with fluid.program_guard(main, startup):
+        img = fluid.layers.data(name="img", shape=[channels, 8, 8],
+                                dtype="float32")
+        label = fluid.layers.data(name="label", shape=[1], dtype="int64")
+        if kind == "conv_bn_act":
+            h = fluid.layers.conv2d(input=img, num_filters=channels,
+                                    filter_size=3, padding=1,
+                                    bias_attr=False)
+        else:
+            h = fluid.layers.conv2d(input=img, num_filters=channels,
+                                    filter_size=1, bias_attr=False)
+            h = fluid.layers.pool2d(input=h, pool_size=2, pool_stride=2)
+        h = fluid.layers.batch_norm(input=h, act="relu")
+        gp = fluid.layers.pool2d(input=h, global_pooling=True,
+                                 pool_type="avg")
+        loss = fluid.layers.mean(fluid.layers.softmax_with_cross_entropy(
+            fluid.layers.fc(input=gp, size=5), label))
+        fluid.optimizer.SGD(learning_rate=0.05).minimize(
+            loss, startup_program=startup)
+    fluid.amp.enable(main, level="O2")
+    rng = np.random.default_rng(3)
+    feeds = [{"img": rng.standard_normal((8, channels, 8, 8))
+              .astype(np.float32),
+              "label": rng.integers(0, 5, (8, 1)).astype(np.int64)}
+             for _ in range(2)]
+    return main, startup, loss, feeds
+
+
+def _train_amp_window(kind, channels):
+    """(losses, state, window types planned, pallas_calls in the step's
+    jaxpr) of two steps of `_amp_window_net`."""
     import jax
-    import jax.numpy as jnp
 
-    rng = np.random.default_rng(2)
-    x = jnp.asarray(rng.standard_normal((2, 4, 4, 128)),
-                    dtype=jnp.bfloat16).reshape(-1, 128)
-    scale = jnp.asarray(rng.standard_normal(128), dtype=jnp.float32)
-    bias = jnp.asarray(rng.standard_normal(128), dtype=jnp.float32)
-    eps = 1e-5
+    main, startup, loss, feeds = _amp_window_net(kind, channels)
+    exe = fluid.Executor(fluid.CPUPlace())
+    scope = em.Scope()
+    with em.scope_guard(scope):
+        exe.run(startup)
+        compiled, feed_vals, state_vals, rng = exe._aot_block(
+            main, feeds[0], [loss], scope)
+        # a jaxpr prints its nested jaxprs, so one search sees them all
+        kernels = str(jax.make_jaxpr(compiled.fn)(
+            feed_vals, state_vals, np.uint32(rng))).count("pallas_call")
+        losses = [float(np.ravel(exe.run(main, feed=f,
+                                         fetch_list=[loss])[0])[0])
+                  for f in feeds]
+        state = _state(scope)
+    planned = sorted(g.op.type for g in (fusion_mod.plan(main) or {}).values()
+                     if g.op is not None)
+    return losses, state, planned, kernels
 
-    xf = x.astype(jnp.float32)
-    m_ref = jnp.mean(xf, axis=0)
-    v_ref = jnp.maximum(
-        jnp.mean(jnp.square(xf), axis=0) - jnp.square(m_ref), 0.0)
-    inv = jax.lax.rsqrt(v_ref + eps)
-    y_ref = ((xf - m_ref) * (inv * scale) + bias).astype(x.dtype)
 
-    for act_fn in (None, lambda v, a=None: jnp.maximum(v, 0)):
-        res = fusion_mod._pallas_bn_act(x, scale, bias, eps, act_fn)
-        ybn, mean, var = res[0], res[-2], res[-1]
-        np.testing.assert_array_equal(np.asarray(mean), np.asarray(m_ref))
-        np.testing.assert_array_equal(np.asarray(var), np.asarray(v_ref))
-        np.testing.assert_array_equal(
-            np.asarray(ybn.astype(jnp.float32)),
-            np.asarray(y_ref.astype(jnp.float32)))
-        if act_fn is not None:
-            yact = res[1]
-            np.testing.assert_array_equal(
-                np.asarray(yact), np.asarray(jnp.maximum(ybn, 0)))
+@pytest.mark.parametrize("channels", [64, 128])
+@pytest.mark.parametrize("nhwc", [True, False], ids=["NHWC", "NCHW"])
+@pytest.mark.parametrize("kind", ["conv_bn_act", "bn_act"])
+def test_amp_window_is_plain_jnp_and_bitwise(kind, nhwc, channels,
+                                             monkeypatch):
+    """A bf16 training window lowers to plain jax.numpy whatever its
+    layout and width (128 channels on NHWC is what the deleted bn+act
+    kernel took): the window is planned, the traced step holds no
+    pallas_call, no fallback is booked, and losses and state equal the
+    unfused trace bit for bit."""
+    from paddle_tpu.ops import layout as layout_mod
+    monkeypatch.setattr(layout_mod, "LAYOUT_OPT", nhwc)
+    l1, s1, planned, kernels = _with_fusion(
+        True, _train_amp_window, kind, channels)
+    assert "fused_" + kind in planned, planned
+    assert kernels == 0
+    assert _fallbacks() == 0, telemetry.read_series("fusion_fallback_total")
+    l0, s0, unplanned, _ = _with_fusion(
+        False, _train_amp_window, kind, channels)
+    assert unplanned == []
+    assert l1 == l0 and all(np.isfinite(l1))
+    _assert_state_equal(s1, s0)
+
+
+def test_no_kernel_reason_is_left():
+    """With one branch there is nothing to fall back from: the fusion
+    pass can book no `kernel_*` reason, tools/check_registry.py names
+    none, and ops/fusion.py holds no pallas_call."""
+    import inspect
+    import os
+
+    source = inspect.getsource(fusion_mod)
+    assert "pallas" not in source.lower(), "a kernel is back"
+    assert "kernel_" not in source
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "tools", "check_registry.py")) as f:
+        assert "kernel_" not in f.read()
 
 
 def test_roofline_sees_fused_ops():

@@ -356,15 +356,14 @@ def _step_census(level):
 def test_amp_o2_step_is_xla_convs():
     """An AMP O2 convnet step holds lax's conv three times per conv
     (forward, grad-input, grad-filter; the first conv's input is data
-    and takes no gradient) and no pallas_call of the conv suite, at the
-    very shapes its gate passes. Fusion's bn+act kernel still runs, and
-    no conv site books a Pallas counter: a route that is not considered
-    cannot fall back."""
+    and takes no gradient) and no pallas_call at all, at the very
+    shapes the conv suite's gate passes and the deleted bn+act kernel
+    took (PR 34): the step is XLA's alone. No conv site books a Pallas
+    counter: a route that is not considered cannot fall back."""
     census = _step_census("O2")
     assert census["conv_general_dilated"] == 3 * 2 - 1, census
     kernels = {k for k in census if k.startswith("pallas_call:")}
-    assert kernels == {"pallas_call:bn_act"}, kernels
-    assert census["pallas_call:bn_act"] == 2
+    assert not kernels, kernels
     assert _series("pallas_kernel_total") == 0
     assert _series("pallas_fallback_total") == 0
     _train_bf16_convnet(steps=1)

@@ -19,7 +19,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from benchmarks import run
-from paddle_tpu.ops import fusion, hybrid_ops, pallas_attention, pallas_conv
+from paddle_tpu.ops import hybrid_ops, pallas_attention, pallas_conv
 from tools import describe_step
 
 BF16 = jnp.bfloat16
@@ -58,8 +58,7 @@ def no_persistent_cache():
 @pytest.fixture
 def mosaic(monkeypatch, no_persistent_cache):
     """Kernels lower for Mosaic, not the interpreter. pallas_conv imports
-    `_interpret` by name, so both modules are patched (fusion reads
-    pallas_attention's at call time)."""
+    `_interpret` by name, so both modules are patched."""
     monkeypatch.setattr(pallas_attention, "_interpret", lambda: False)
     monkeypatch.setattr(pallas_conv, "_interpret", lambda: False)
 
@@ -133,14 +132,48 @@ def test_conv_q8_stride2_compiles(mosaic, one_chip):
         ((w[0],), jnp.float32)) == ["conv2d_q8"]
 
 
-def test_bn_act_compiles(mosaic, one_chip):
-    """fusion's bn+act kernel over the [N*H*W, C] view of stage 2."""
-    m, c = 32 * 56 * 56, 256
-    assert _compile(
-        lambda a, sc, b: fusion._pallas_bn_act(a, sc, b, 1e-5,
-                                               jax.nn.relu)[:2],
-        one_chip, ((m, c), BF16), ((c,), jnp.float32),
-        ((c,), jnp.float32)) == ["bn_act"]
+def test_bottleneck_block_is_xla_alone(mosaic, one_chip):
+    """One bottleneck block at stage-2 width (28 x 28, 128 -> 512, batch
+    32) behind a 1 x 1 stem, forward, backward and Momentum under AMP O2:
+    the compiled step holds no Mosaic call, and its entry computation no
+    `reshape` or `copy` as wide as the block's narrowest activation. The
+    bn+act kernel that PR 34 deleted put three of each around every
+    window (a conv's output is laid out {3,0,2,1}; a kernel wants the
+    row-major [N*H*W, C] view, and 28 rows fill no bf16 tile) and kept a
+    `pred` mask per relu for the backward: a quarter of ResNet-50's step
+    on the chip (PERF.md section 6, PR 34)."""
+    import numpy as np
+    import paddle_tpu as fluid
+    from paddle_tpu.framework import unique_name
+    from paddle_tpu.models import resnet
+
+    batch, side, mid = 32, 28, 128
+    unique_name.switch()
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        img = fluid.layers.data(name="img", shape=[3, 2 * side, 2 * side],
+                                dtype="float32")
+        label = fluid.layers.data(name="label", shape=[1], dtype="int64")
+        stem = resnet.conv_bn_layer(img, 4 * mid, 1, 2, 0)
+        block = resnet.bottleneck(stem, 4 * mid, mid, 1)
+        pooled = fluid.layers.pool2d(input=block, global_pooling=True,
+                                     pool_type="avg")
+        loss = fluid.layers.mean(fluid.layers.softmax_with_cross_entropy(
+            fluid.layers.fc(input=pooled, size=10), label))
+        fluid.optimizer.Momentum(learning_rate=0.1, momentum=0.9).minimize(
+            loss, startup_program=startup)
+    fluid.amp.enable(main, level="O2")
+    feed = {"img": np.zeros((batch, 3, 2 * side, 2 * side), np.float32),
+            "label": np.zeros((batch, 1), np.int64)}
+    text = describe_step.compile_program(main, startup, loss, feed,
+                                         one_chip).as_text()
+    assert KERNEL not in text
+    wide = describe_step.wide_instructions(text, batch * side * side * mid - 1)
+    assert len(wide) >= 8, wide     # the activations themselves are seen
+    moved = [row for row in wide
+             if re.match(r"%(reshape|copy)[.\d]*$", row[2])
+             or row[1].startswith("pred[")]
+    assert not moved, moved
 
 
 def test_bn_apply_compiles(mosaic, one_chip):

@@ -47,11 +47,20 @@ def described_chip():
 def compile_step(cell, config, chip):
     """The compiled train step of `cell` under `config`, for the device
     of the sharding `chip`."""
-    import paddle_tpu as fluid
     from benchmarks import run
 
     family = run.load_module("families", config["family"])
     main, startup, loss = family.build(config)
+    feed = family.make_batch(config, cell["batch"], np.random.default_rng(0))
+    return compile_program(main, startup, loss, feed, chip)
+
+
+def compile_program(main, startup, loss, feed, chip):
+    """The train step of `main` as Executor.run would trace it (state
+    donated), compiled for the device of the sharding `chip` from avals
+    alone: the state's from `startup`, the feed's from `feed`."""
+    import paddle_tpu as fluid
+
     exe = fluid.Executor(fluid.CPUPlace())
 
     def step_fn(program, fetch):
@@ -65,7 +74,6 @@ def compile_step(cell, config, chip):
 
     rng = np.uint32(0)
     state = jax.eval_shape(step_fn(startup, []), {}, {}, rng)[2]
-    feed = family.make_batch(config, cell["batch"], np.random.default_rng(0))
     return jax.jit(step_fn(main, [loss.name]), donate_argnums=(1,)).lower(
         on_chip(feed), on_chip(state), on_chip(rng)).compile()
 

@@ -1,8 +1,12 @@
 """Framework-independent ceiling probe: hand-rolled pure-JAX ResNet-50
 training step (NHWC, bf16 compute, f32 master weights + momentum), same
 batch/protocol as bench.py. Used to separate framework overhead from the
-chip/XLA ceiling when tuning the flagship bench."""
+chip/XLA ceiling when tuning the flagship bench.
 
+    python3 tools/jax_resnet_ref.py [batch]    (768; the benchmark's cell: 256)
+"""
+
+import sys
 import time
 
 import jax
@@ -104,7 +108,7 @@ def step(params, mom, x, y):
     return loss, new_p, new_m
 
 
-def main():
+def main(batch=BATCH):
     dev = jax.devices()[0]
     rng = np.random.default_rng(0)
     params = {k: jax.device_put(v, dev)
@@ -112,8 +116,8 @@ def main():
     mom = {k: jax.device_put(np.zeros_like(np.asarray(v)), dev)
            for k, v in params.items()}
     x = jax.device_put(
-        rng.standard_normal((BATCH, 224, 224, 3), dtype=np.float32), dev)
-    y = jax.device_put(rng.integers(0, 1000, (BATCH, 1)).astype(np.int32),
+        rng.standard_normal((batch, 224, 224, 3), dtype=np.float32), dev)
+    y = jax.device_put(rng.integers(0, 1000, (batch, 1)).astype(np.int32),
                        dev)
     for _ in range(WARMUP):
         loss, params, mom = step(params, mom, x, y)
@@ -123,12 +127,13 @@ def main():
         loss, params, mom = step(params, mom, x, y)
     final = float(np.asarray(loss))
     dt = time.perf_counter() - t0
-    img_s = BATCH * STEPS / dt
+    img_s = batch * STEPS / dt
     mfu = img_s * 3 * 4.09e9 / 197e12
-    print(f"pure-jax resnet50: {img_s:.0f} img/s  "
-          f"({dt / STEPS * 1000:.0f} ms/step, mfu {mfu:.3f}, "
-          f"loss {final:.3f})")
+    peak = (dev.memory_stats() or {}).get("peak_bytes_in_use")
+    print(f"pure-jax resnet50 ({dev.device_kind}, batch {batch}): "
+          f"{img_s:.1f} img/s  ({dt / STEPS * 1000:.1f} ms/step, "
+          f"mfu {mfu:.3f}, loss {final:.3f}, peak bytes {peak})")
 
 
 if __name__ == "__main__":
-    main()
+    main(int(sys.argv[1]) if len(sys.argv) > 1 else BATCH)
