@@ -436,7 +436,7 @@ def _perf_fields(probe=None):
             return {}
         out = {"top_ops": roofline.top_ops(report),
                "device_duty_cycle": report.get("device_duty_cycle")}
-        hc = report.get("hlo_counts")
+        hc = report.get("kernel_counts")
         if hc:
             # per-step kernel-count trend: fusion wins show up as fewer
             # HLO instructions/fusions at the same img/s (ISSUE 7)
@@ -867,7 +867,7 @@ def main_attention():
 
     g_flash = make(lambda a, bb, c: flash_attention(a, bb, c, True))
     g_xla = make(lambda a, bb, c: attention_reference(a, bb, c, causal=True))
-    # raw-jax family: no executor suppliers, so attribution degrades to
+    # raw-jax family: no executor, no account, so attribution degrades to
     # duty cycle + unattributed rows — still worth carrying on the line
     _PERF_STEP[0] = lambda: float(
         np.asarray(g_flash(q, k, v)[0]).ravel()[0])

@@ -1,0 +1,18 @@
+"""Milliseconds of a traced step that a chip's core spends in the
+collectives of the `fsdp` mesh axis (the parameters' gathers and the
+gradients' reductions of ZeRO-sharded data parallelism):
+`exposed_collective_ms.train`'s seconds, the same events, of the
+instructions whose replica groups run along `fsdp` alone, from the
+step's account by instruction (`benchmarks/step_account.py`). None where
+the step holds no such collective or the program keeps no account."""
+
+from benchmarks import step_account
+
+LAYER = "collectives"
+UNIT = "ms"
+MOVES = "train_items_per_s"
+SOURCE = "device_trace"
+
+
+def compute(ev):
+    return step_account.axis_ms(ev, "fsdp")

@@ -898,7 +898,7 @@ def cmd_perf(args):
 
     probe = not args.no_probe
     if args.trace_dir:
-        report = roofline.collect_report(args.trace_dir, (), probe=probe)
+        report = roofline.collect_report(args.trace_dir, probe=probe)
     else:
         import paddle_tpu as fluid
         from paddle_tpu import executor as executor_mod, memory
@@ -945,7 +945,7 @@ def cmd_fleet(args):
     if args.trace_dir:
         from paddle_tpu import fleet
         result = {
-            "collectives": fleet.collective_table(args.trace_dir, (),
+            "collectives": fleet.collective_table(args.trace_dir,
                                                   probe=probe),
             "goodput": fleet.goodput_report(),
             "snapshot": None,
@@ -997,16 +997,17 @@ def cmd_fleet(args):
     colls = result.get("collectives")
     if colls and colls.get("rows"):
         print(f"{'Collective':20s} {'Call site':22s} {'MB':>9s} "
-              f"{'busbw GB/s':>11s} {'% link':>7s} {'Exposed(ms)':>12s}")
+              f"{'busbw GB/s':>11s} {'% link':>7s} {'Exposed(ms)':>12s}"
+              f"  Axis")
         for r in colls["rows"]:
             bus = ("{:11.2f}".format(r["busbw_gbps"])
                    if r.get("busbw_gbps") is not None else
                    "          -")
             pct = ("{:6.1%}".format(r["pct_link"])
                    if r.get("pct_link") is not None else "     -")
-            print("[coll] {:13s} {:22s} {:9.2f} {} {} {:12.3f}".format(
+            print("[coll] {:13s} {:22s} {:9.2f} {} {} {:12.3f}  {}".format(
                 r["kind"], r["site"], r["bytes"] / 1e6, bus, pct,
-                r["exposed_ms"]))
+                r["exposed_ms"], r.get("axis") or "-"))
         if colls.get("ici_gbps"):
             print("[coll] link roofline {:.1f} GB/s ({} participants)"
                   .format(colls["ici_gbps"],

@@ -39,6 +39,7 @@ from . import quant as quant_mod
 from . import sentinel as sentinel_mod
 from . import telemetry
 from . import tracing as tracing_mod
+from . import xplane as xplane_mod
 from .framework.desc import VarType
 from .framework.framework import (NAME_SCOPE_ATTR, Program, Variable,
                                   default_main_program)
@@ -506,25 +507,6 @@ def padded_to_pack(padded: np.ndarray, lengths: np.ndarray,
             [outer_offs.tolist(), inner_offs.tolist()])
 
 
-def _hlo_supplier(fn, feed_vals, state_vals, rng_counter):
-    """Zero-arg lazy supplier of the block's AOT-compiled executable for
-    the profiler's per-op device table (.as_text() gives the optimized HLO
-    the attribution joins against, .cost_analysis() the XLA flop count the
-    analytic cost model cross-checks). Captures ONLY avals
-    (shapes/dtypes), never the arrays — state buffers are donated and must
-    not be kept alive. supply() is an AOT lower().compile(): a REAL
-    recompile unless the persistent compilation cache covers it, which is
-    why the profiler caps its supplier registry and only traced sessions
-    pay this — at stop_profiler, never inside the timed region."""
-    avals = jax.tree_util.tree_map(memory_mod.aval_of,
-                                   (feed_vals, state_vals, rng_counter))
-
-    def supply():
-        return fn.lower(*avals).compile()
-
-    return supply
-
-
 # Observers notified as (op, ins, outs) for every op lowered by _exec_op —
 # ins/outs are {slot: [tracer|None]}. Installed only for the duration of an
 # abstract trace (roofline.program_cost runs jax.eval_shape with one) so
@@ -534,18 +516,15 @@ def _hlo_supplier(fn, feed_vals, state_vals, rng_counter):
 _op_observers: List = []
 
 
-def _cost_supplier(executor, program, feed_vals, state_vals, window=False):
+def _cost_supplier(executor, program, feed_avals, state_avals, window=False):
     """Zero-arg lazy supplier of the analytic per-op cost table
-    (roofline.program_cost) for the same compiled block _hlo_supplier
-    describes. Same discipline: captures only avals. window=True strips
-    the leading [K] steps axis off each feed so the table is per-step."""
-    feed_avals = {n: memory_mod.aval_of(v)
-                  for n, v in feed_vals.items()}
+    (roofline.program_cost) for a compiled block: the REQUIRED operations
+    beside the executed ones of its account. Holds only avals.
+    window=True strips the leading [K] steps axis off each feed so the
+    table is per-step."""
     if window:
         feed_avals = {n: jax.ShapeDtypeStruct(a.shape[1:], a.dtype)
                       for n, a in feed_avals.items()}
-    state_avals = {n: memory_mod.aval_of(v)
-                   for n, v in state_vals.items()}
 
     def cost():
         from . import roofline
@@ -599,6 +578,15 @@ class _CompiledBlock:
         # executor-level cache key — names only, no shapes — cannot see)
         self.seen_sigs: set = set()
         self.last_sig = None
+        # the step's account by instruction: (HLO module name,
+        # [xplane.Instr], XLA's FLOP count). Built once, from the text the
+        # static memory analysis reads (memory.on_compile); where that
+        # analysis is off or not this block's (a run_steps window),
+        # lazily from `avals`, the first launch's argument avals (shapes,
+        # dtypes, shardings: never the donated buffers)
+        self.account = None
+        self.avals = None
+        self.cost_fn = None
 
 
 _NO_WATCH = contextlib.nullcontext()
@@ -989,8 +977,53 @@ class Executor:
         program = program if program is not None else default_main_program()
         compiled, feed_vals, state_vals, rng = self._aot_block(
             program, feed, fetch_list, scope)
-        return _hlo_supplier(compiled.fn, feed_vals, state_vals,
-                             np.uint32(rng))().as_text()
+        avals = jax.tree_util.tree_map(
+            memory_mod.aval_of, (feed_vals, state_vals, np.uint32(rng)))
+        return memory_mod.compile_from_avals(compiled.fn, avals).as_text()
+
+    def step_account(self, program=None):
+        """The account by instruction of `program`'s compiled step
+        ([xplane.Instr]: name, opcode, heavy, flops, bytes, the program op
+        instance it was lowered from, a collective's kind, bytes, groups
+        and mesh axis), or None before its first run. Built once a block,
+        where the static memory analysis reads the executable's text; for
+        a block that analysis skipped (the `memory_analysis` flag off, a
+        run_steps window), at the first call here, from the kept avals (a
+        compile the persistent cache serves). The same list is what a
+        trace is joined to (xplane.step_account)."""
+        program = program if program is not None else default_main_program()
+        blocks = [c for c in self._cache.values()
+                  if c.program is program and c.seen_sigs]
+        if not blocks:
+            return None
+        account = self._account_of(program, blocks[-1])
+        return account and account[1]
+
+    def _account_of(self, program, compiled):
+        if compiled.account is None and compiled.avals is not None:
+            try:
+                with jax.default_device(self.device):
+                    module, instrs, xla_flops = memory_mod.account_of(
+                        memory_mod.compile_from_avals(compiled.fn,
+                                                      compiled.avals),
+                        getattr(program, "_mesh", None))
+                self._keep_account(
+                    compiled, telemetry.program_label(program), module,
+                    xplane_mod.compact(instrs), xla_flops)
+            except Exception as e:  # noqa: BLE001 - advisory
+                telemetry.log_event(
+                    "step_account_error",
+                    program=telemetry.program_label(program),
+                    error=f"{type(e).__name__}: {e}")
+        return compiled.account
+
+    def _keep_account(self, compiled, prog_label, module, instrs, xla_flops):
+        """On the block, and under the HLO module name where a reader of
+        a trace in this process finds it."""
+        compiled.account = (module, instrs, xla_flops)
+        xplane_mod.remember_account(
+            module, instrs, program=prog_label, xla_flops=xla_flops,
+            cost=compiled.cost_fn)
 
     def _commit_feeds(self, program, feed_vals, *, window=False):
         """On a mesh, move a feed that is committed elsewhere (a
@@ -1284,19 +1317,23 @@ class Executor:
         `compiled` is None in eager mode, which builds nothing."""
         sig, new_sig, build_watch = None, False, _NO_WATCH
         if compiled is not None:
-            if profiler_mod.wants_device_table() and \
-                    not profiler_mod.has_hlo_supplier(id(compiled.fn)):
-                # once per compiled block: building the aval pytree every
-                # step would inflate the host timings being measured. The
-                # fused window registers too: it is the production
-                # training path, and the MFU campaign needs attribution
-                # exactly there (ISSUE 6 tentpole)
-                profiler_mod.register_hlo_supplier(
-                    id(compiled.fn),
-                    _hlo_supplier(compiled.fn, feed_vals, state_vals,
-                                  np.uint32(rng_counter)),
-                    _cost_supplier(self, program, feed_vals, state_vals,
-                                   window=mode == "window"))
+            if compiled.avals is None:
+                # once per compiled block (building the aval pytree every
+                # step would inflate the host timings being measured):
+                # what a lazy account and the analytic cost table need
+                compiled.avals = jax.tree_util.tree_map(
+                    memory_mod.aval_of,
+                    (feed_vals, state_vals, np.uint32(rng_counter)))
+                compiled.cost_fn = _cost_supplier(
+                    self, program, compiled.avals[0], compiled.avals[1],
+                    window=mode == "window")
+            if compiled.account is None and compiled.seen_sigs \
+                    and profiler_mod.wants_device_table():
+                # a traced session over a block that has run and has no
+                # account yet (a run_steps window, or memory_analysis
+                # off): build it here, before the timed call, so that
+                # stop_profiler compiles nothing
+                self._account_of(program, compiled)
             sig = telemetry.signature_of(feed_vals)
             new_sig = sig not in compiled.seen_sigs
             if new_sig:
@@ -1454,10 +1491,14 @@ class Executor:
                     # analysis traced and lowered the whole block again
                     with tracing_mod.span("analysis"), \
                             jax.default_device(self.device):
-                        memory_mod.on_compile(
+                        rec = memory_mod.on_compile(
                             self, compiled, program, prog_label,
                             place_label, feed_vals, state_vals,
                             np.uint32(rng_counter), signature=launch.sig)
+                        if rec is not None and rec.account is not None:
+                            self._keep_account(
+                                compiled, prog_label, rec.module,
+                                rec.account, rec.xla_flops)
                 except Exception as mem_e:
                     telemetry.log_event(
                         "memory_analysis_error", program=prog_label,
@@ -1738,12 +1779,14 @@ class Executor:
             # the scopes land in every emitted HLO instruction's metadata
             # op_name ("jit(fn)/pd_role.<role>/pd.<type>/<prim>") — the hook
             # device time is booked to program ops by: the outermost
-            # "pd.<type>" (profiler._print_device_table,
-            # xplane.hlo_op_names) and the outermost "pd_role.<op_role>"
+            # "pd.<type>" (xplane.provenance: the account's rows, the
+            # profiler's device table) and the outermost "pd_role.<op_role>"
             # (benchmarks/program_trace.py; spelt so that no "pd." rule
             # sees it). A fused op's members keep the fused op's role. An
             # op built under fluid.name_scope also carries
-            # "pd_scope.<outer.inner>" between the two, spelt likewise.
+            # "pd_scope.<outer.inner>" between the two, spelt likewise;
+            # _trace_block puts the op's position, "pd_at.<n>", around
+            # all three.
             built_under = op.desc.attrs.get(NAME_SCOPE_ATTR)
             with jax.named_scope(_ROLE_SCOPE.get(op.desc.attrs.get("op_role"),
                                                  _ROLE_SCOPE[None])), \
@@ -1852,9 +1895,17 @@ class Executor:
         # their last producing grad op, instead of resolving lazily at
         # the optimizer. Bitwise-neutral; only the sync point moves.
         oplan = overlap_mod.plan(program)
+        # every op (or fused window) of the block is lowered under its
+        # position in the block, "pd_at.<n>": the op INSTANCE in each
+        # emitted instruction's op_name, beside the role, name scope and
+        # type that _exec_op adds (spelt so that no "pd." / "pd_role." /
+        # "pd_scope." rule sees it). Metadata only: the compiled step is
+        # the same. The account's rows group by it (xplane.provenance):
+        # which of 53 conv windows, which layer's mul_grad
         if not groups and oplan is None:
-            for op in block.ops:
-                self._exec_op(ctx, op, env)
+            for i, op in enumerate(block.ops):
+                with jax.named_scope(f"{xplane_mod.AT_SCOPE}{i}"):
+                    self._exec_op(ctx, op, env)
         else:
             protected = set(fetch_names) | set(persist_out)
             ops = block.ops
@@ -1862,12 +1913,14 @@ class Executor:
             i = 0
             while i < len(ops):
                 g = groups.get(i)
-                if g is not None:
-                    fusion_mod.execute_group(self, ctx, g, env, protected)
-                    nxt = g.end
-                else:
-                    self._exec_op(ctx, ops[i], env)
-                    nxt = i + 1
+                with jax.named_scope(f"{xplane_mod.AT_SCOPE}{i}"):
+                    if g is not None:
+                        fusion_mod.execute_group(self, ctx, g, env,
+                                                 protected)
+                        nxt = g.end
+                    else:
+                        self._exec_op(ctx, ops[i], env)
+                        nxt = i + 1
                 if oplan is not None:
                     # anchors inside a fused window flush after the window
                     oplan.flush_range(ctx, env, i, nxt)

@@ -4,12 +4,15 @@ straggler detection, and goodput accounting (ISSUE 8 tentpole).
 Extends the single-host observability stack (telemetry / memory /
 roofline) across the mesh and the fleet, three layers:
 
-1. **Per-collective attribution** — xplane device events classified into
+1. **Per-collective attribution** — the collective rows of the step's
+   account by instruction (xplane.step_account: the trace's `XLA Ops`
+   events joined to the compiled blocks' instructions), classified into
    collective kinds (xplane.COLLECTIVE_KINDS) and joined to framework
    call sites through `pd.coll.<site>` named scopes
    (parallel/_collectives.coll_scope) landing in HLO metadata op_name.
-   Each (kind, site) row carries bytes moved (HLO output shapes), device
-   time, the exposed-vs-overlapped split (xplane.exposed_in_line), and
+   Each (kind, site, mesh axis) row carries bytes moved (HLO output
+   shapes), device time, the exposed-vs-overlapped split
+   (xplane.exposed_in_line), and
    achieved bus bandwidth with the nccl-tests algbw→busbw factors —
    judged against the measured ICI/DCN link roofline
    (roofline.ensure_ici, PADDLE_TPU_ICI_GBPS override) as `% of link`.
@@ -50,31 +53,55 @@ UNATTRIBUTED = "(unattributed)"
 
 # --- per-collective bandwidth attribution -----------------------------------
 
-def collective_table(trace_dir, hlo_texts=(), steps: Optional[int] = None,
-                     probe: bool = True) -> Dict[str, Any]:
-    """Join the trace's collective device events to the compiled modules'
-    collective instructions into per-(kind, site) rows:
+def collective_table(trace_dir, steps: Optional[int] = None,
+                     probe: bool = True, accounts=None,
+                     account=None) -> Dict[str, Any]:
+    """The collective rows of the trace's account by instruction
+    (`xplane.step_account`; `account` hands in one already joined,
+    `accounts` the instruction lists to join with) folded into
+    per-(kind, site, mesh axis) rows:
 
-        {"rows": [{kind, site, count, bytes, time_ms, exposed_ms,
-                   algbw_gbps, busbw_gbps, pct_link, overlap_frac}],
+        {"rows": [{kind, site, axis, group_size, count, bytes, time_ms,
+                   exposed_ms, algbw_gbps, busbw_gbps, pct_link,
+                   overlap_frac}],
          "ici_gbps": float|None, "participants": int|None}
 
-    `bytes` are per traced session (HLO payload × executions ≈ steps);
-    busbw uses the nccl-tests factor for the kind, judged against the
-    link roofline when the ICI probe (or PADDLE_TPU_ICI_GBPS) is
-    available. Events whose instruction has no pd.coll scope pool under
-    "(gspmd)" — the partitioner-inserted collectives (dp grad
-    all-reduce, tensor-parallel gathers) that no framework line emits
-    directly."""
+    `bytes` are per traced session (each instruction's payload × its
+    runs); busbw uses the nccl-tests factor for the kind at the
+    instruction's OWN group size (a tp all-reduce over 2 of 4 chips is
+    not a 4-way one), judged against the link roofline when the ICI
+    probe (or PADDLE_TPU_ICI_GBPS) is available. `axis` is the mesh axis
+    the instruction's replica groups run along ('tp', 'fsdp', 'fsdp+tp'
+    where a group spans both; None without the planner's mesh).
+    Instructions with no pd.coll scope pool under "(gspmd:<op>)" — the
+    partitioner-inserted collectives (dp grad all-reduce,
+    tensor-parallel gathers) that no framework line emits directly,
+    named after the program op they were split from. A collective event
+    no account names keeps its kind and its time."""
     from . import roofline, xplane
 
-    events = xplane.collective_events_dir(trace_dir)
-    instrs: Dict[str, dict] = {}
+    if account is None:
+        account = xplane.step_account(trace_dir, accounts=accounts)
+    by_site: Dict[tuple, Dict[str, float]] = {}
     participants = None
-    for text in hlo_texts:
-        instrs.update(xplane.hlo_collectives(text))
-        if participants is None:
-            participants = xplane.hlo_participants(text)
+    for step in (account or {}).get("steps", ()):
+        for r in step["rows"]:
+            if not r["kind"]:
+                continue
+            site = r["site"]
+            if site is None:
+                site = f"(gspmd:{r['op']})" if r["op"] else "(gspmd)"
+            acc = by_site.setdefault((r["kind"], site, r["axis"]), {
+                "count": 0, "bytes": 0.0, "ms": 0.0, "exposed_ms": 0.0,
+                "bus_bytes": 0.0, "group_size": r["group_size"]})
+            acc["count"] += r["count"]
+            acc["bytes"] += float(r["payload"]) * r["count"]
+            acc["ms"] += r["ms"]
+            acc["exposed_ms"] += r.get("exposed_ms", r["ms"])
+            n = r["group_size"] or 1
+            acc["bus_bytes"] += float(r["payload"]) * r["count"] \
+                * xplane.busbw_factor(r["kind"], n)
+            participants = max(participants or 0, n)
     if participants is None:
         try:
             import jax
@@ -82,44 +109,24 @@ def collective_table(trace_dir, hlo_texts=(), steps: Optional[int] = None,
         except Exception:  # noqa: BLE001 - stdlib-only callers
             participants = None
 
-    # join: event name -> HLO instruction (exact, then base-name match:
-    # the profiler may append suffixes like '%all-reduce.3.clone')
-    by_site: Dict[tuple, Dict[str, float]] = {}
-    for name, ev in events.items():
-        info = instrs.get(name) or instrs.get(name.lstrip("%"))
-        if info is None:
-            base = name.lstrip("%").split(" ")[0]
-            info = instrs.get(base)
-        kind = ev["kind"]
-        site = (info or {}).get("site")
-        if site is None:
-            near = (info or {}).get("near")
-            site = f"(gspmd:{near})" if near else "(gspmd)"
-        nbytes = (info or {}).get("bytes", 0)
-        acc = by_site.setdefault((kind, site), {
-            "count": 0, "bytes": 0.0, "ps": 0, "exposed_ps": 0})
-        acc["count"] += 1
-        acc["bytes"] += float(nbytes) * (steps or 1)
-        acc["ps"] += ev["total_ps"]
-        acc["exposed_ps"] += ev["exposed_ps"]
-
     ici = roofline.ensure_ici(probe) if (by_site or probe) else None
-    n = participants or 1
     rows: List[Dict[str, Any]] = []
-    for (kind, site), acc in sorted(by_site.items(),
-                                    key=lambda kv: -kv[1]["ps"]):
-        secs = acc["ps"] / 1e12
+    for (kind, site, axis), acc in sorted(by_site.items(),
+                                          key=lambda kv: -kv[1]["ms"]):
+        secs = acc["ms"] / 1e3
         algbw = (acc["bytes"] / secs / 1e9) if secs > 0 else None
-        factor = xplane.busbw_factor(kind, n)
-        busbw = algbw * factor if (algbw is not None and factor) else algbw
+        busbw = (acc["bus_bytes"] / secs / 1e9) if secs > 0 else None
+        if busbw == 0.0:
+            busbw = algbw
         pct = (busbw / ici) if (busbw is not None and ici) else None
         rows.append({
-            "kind": kind, "site": site, "count": acc["count"],
-            "bytes": acc["bytes"], "time_ms": acc["ps"] / 1e9,
-            "exposed_ms": acc["exposed_ps"] / 1e9,
+            "kind": kind, "site": site, "axis": axis,
+            "group_size": acc["group_size"], "count": acc["count"],
+            "bytes": acc["bytes"], "time_ms": acc["ms"],
+            "exposed_ms": acc["exposed_ms"],
             "algbw_gbps": algbw, "busbw_gbps": busbw, "pct_link": pct,
-            "overlap_frac": (1.0 - acc["exposed_ps"] / acc["ps"]
-                             if acc["ps"] else None)})
+            "overlap_frac": (1.0 - acc["exposed_ms"] / acc["ms"]
+                             if acc["ms"] else None)})
     return {"rows": rows, "ici_gbps": ici, "participants": participants}
 
 

@@ -9,13 +9,15 @@ space-side sibling of telemetry.py (time) and inspector.py (numerics):
 
 1. **Static analysis** — after a block's first jit compile the executor
    calls `on_compile`, which re-lowers the SAME jitted fn from avals
-   (the `_hlo_supplier` idiom: shapes only, donated buffers never kept
-   alive) and captures `Compiled.memory_analysis()` — argument / output /
-   temp / alias / generated-code bytes — into a `ProgramMemory` record,
-   `memory_*_bytes` gauges and the step-event log. A scheduled-HLO
+   (shapes only, donated buffers never kept alive) and captures
+   `Compiled.memory_analysis()` — argument / output / temp / alias /
+   generated-code bytes — into a `ProgramMemory` record,
+   `memory_*_bytes` gauges and the step-event log. The executable's text
+   is parsed once there (`xplane.hlo_instructions`): a scheduled-HLO
    liveness walk (`hlo_peak_liveness`) attributes the high-water mark to
-   the top-k IR ops through the same `pd.<type>` named-scope metadata the
-   profiler's device table uses (xplane.hlo_op_names).
+   the top-k IR ops through the `pd.<type>` named-scope metadata, and the
+   same list is the step's account by instruction that the executor
+   keeps (`Executor.step_account`).
 2. **Live accounting** — a `MemoryTracker` samples `device.memory_stats()`
    (TPU) or falls back to summing `jax.live_arrays()` (CPU backends
    return None) per Executor.run, classifies state into
@@ -90,34 +92,13 @@ flags.define("hbm_budget_bytes", 0,
 # Shape/byte helpers
 # ---------------------------------------------------------------------------
 
-_DTYPE_BYTES = {
-    "pred": 1, "s2": 1, "s4": 1, "u2": 1, "u4": 1, "s8": 1, "u8": 1,
-    "f8e5m2": 1, "f8e4m3": 1, "f8e4m3fn": 1, "f8e4m3b11fnuz": 1,
-    "f8e5m2fnuz": 1, "f8e4m3fnuz": 1,
-    "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
-    "s32": 4, "u32": 4, "f32": 4, "tf32": 4,
-    "s64": 8, "u64": 8, "f64": 8, "c64": 8,
-    "c128": 16,
-}
-
-_SHAPE_TOKEN = re.compile(r"([a-z][a-z0-9]*)\[([0-9,]*)\]")
-
-
 def shape_bytes(shape_str: str) -> int:
     """Bytes of an HLO shape string — 'f32[128,13]{1,0}' -> 6656; tuple
     shapes '(f32[8], s32[])' sum their elements; unknown element types
-    (token, opaque) count zero."""
-    total = 0
-    for dt, dims in _SHAPE_TOKEN.findall(shape_str):
-        isz = _DTYPE_BYTES.get(dt)
-        if isz is None:
-            continue
-        n = 1
-        for d in dims.split(","):
-            if d:
-                n *= int(d)
-        total += isz * n
-    return total
+    (token, opaque) count zero. The one table of element sizes is
+    xplane's."""
+    from . import xplane
+    return xplane.shape_bytes(shape_str)
 
 
 def nbytes_of(value) -> int:
@@ -150,78 +131,43 @@ def nbytes_of(value) -> int:
 # HLO peak-liveness walk
 # ---------------------------------------------------------------------------
 
-_INSTR = re.compile(
-    r"^\s*(?:ROOT\s+)?%?(?P<name>[\w.\-]+)\s*=\s*"
-    r"(?P<shape>\([^)]*\)|[a-z][a-z0-9]*\[[0-9,]*\](?:\{[^}]*\})?)"
-    r"\s+(?P<op>[\w\-]+)")
 # ops that alias rather than allocate: their "output" is a view/pointer
 _ZERO_COST_OPS = frozenset({"bitcast", "get-tuple-element", "tuple",
                             "bitcast-convert"})
 
 
-def _entry_lines(hlo_text: str) -> List[str]:
-    out: List[str] = []
-    in_entry = False
-    for line in hlo_text.splitlines():
-        s = line.strip()
-        if not in_entry:
-            if s.startswith("ENTRY"):
-                in_entry = True
-            continue
-        if s.startswith("}"):
-            break
-        out.append(line)
-    return out
-
-
-def hlo_peak_liveness(hlo_text: str, top_k: int = 8) -> Optional[Dict]:
+def hlo_peak_liveness(hlo, top_k: int = 8) -> Optional[Dict]:
     """Walk the scheduled entry computation (Compiled.as_text() emits
     is_scheduled=true, so instruction order IS the schedule), assign each
     instruction's output buffer a [def, last-use] live range, and report
     the position and composition of the liveness high-water mark — an
     estimate of XLA buffer assignment, not a reimplementation (fusion
-    internals and layout padding are invisible at this level). Each peak
-    buffer is attributed back to the IR op whose pd.<type> named scope
-    emitted it (xplane.hlo_op_names), so the answer reads 'conv2d output,
-    not %fusion.42'."""
+    internals and layout padding are invisible at this level). `hlo` is
+    the module's text or its parse (`xplane.hlo_instructions`, the one
+    parse: names, shapes and operands come from it). Each peak buffer is
+    attributed back to the IR op whose pd.<type> named scope emitted it,
+    so the answer reads 'conv2d output, not %fusion.42'."""
     from . import xplane
 
-    lines = _entry_lines(hlo_text)
-    names: List[str] = []
-    sizes: Dict[str, int] = {}
-    defpos: Dict[str, int] = {}
-    opcode: Dict[str, str] = {}
-    params: List[str] = []
-    uses_by_pos: List[List[str]] = []
-    for line in lines:
-        m = _INSTR.match(line)
-        if not m:
-            continue
-        name, shape, op = m.group("name"), m.group("shape"), m.group("op")
-        pos = len(names)
-        names.append(name)
-        defpos[name] = pos
-        opcode[name] = op
-        sizes[name] = 0 if op in _ZERO_COST_OPS else shape_bytes(shape)
-        if op == "parameter":
-            params.append(name)
-        rhs = line.split("=", 1)[1]
-        uses_by_pos.append([t for t in re.findall(r"%([\w.\-]+)", rhs)
-                            if t != name])
-    n = len(names)
+    instrs = xplane.hlo_instructions(hlo) if isinstance(hlo, str) else hlo
+    instrs = [i for i in instrs if i.entry]
+    n = len(instrs)
     if n == 0:
         return None
-
-    last_use = {nm: defpos[nm] for nm in names}
-    known = set(names)
-    for pos, uses in enumerate(uses_by_pos):
-        for u in uses:
-            if u in known:
+    names = [i.name for i in instrs]
+    defpos = {nm: pos for pos, nm in enumerate(names)}
+    sizes = {i.name: 0 if i.opcode in _ZERO_COST_OPS
+             else xplane.shape_bytes(i.shape) for i in instrs}
+    last_use = dict(defpos)
+    for pos, i in enumerate(instrs):
+        for u in i.operands:
+            if u in defpos and u != i.name:
                 last_use[u] = max(last_use[u], pos)
     # argument buffers exist for the whole execution (XLA cannot free a
     # caller-owned input) and the ROOT buffer is the output — pin to end
-    for nm in params:
-        last_use[nm] = n - 1
+    for i in instrs:
+        if i.opcode == "parameter":
+            last_use[i.name] = n - 1
     last_use[names[-1]] = n - 1
 
     delta = [0] * (n + 1)
@@ -241,9 +187,9 @@ def hlo_peak_liveness(hlo_text: str, top_k: int = 8) -> Optional[Dict]:
     live = [nm for nm in names
             if sizes[nm] and defpos[nm] <= peak_pos <= last_use[nm]]
     live.sort(key=lambda nm: -sizes[nm])
-    ir_ops = xplane.hlo_op_names(hlo_text)
+    by_name = {i.name: i for i in instrs}
     top = [{"instruction": nm, "bytes": sizes[nm],
-            "op": ir_ops.get(nm, opcode[nm])}
+            "op": xplane.op_label(by_name[nm])}
            for nm in live[:top_k]]
     return {"peak_bytes": peak, "peak_pos": peak_pos,
             "n_instructions": n, "live_at_peak": len(live), "top": top}
@@ -260,7 +206,8 @@ class ProgramMemory:
     __slots__ = ("program", "place", "signature", "argument_bytes",
                  "output_bytes", "temp_bytes", "alias_bytes",
                  "generated_code_bytes", "donated_bytes",
-                 "donation_lost_bytes", "peak")
+                 "donation_lost_bytes", "peak", "account", "module",
+                 "xla_flops")
 
     def __init__(self, program="?", place="?", signature=None):
         self.program = program
@@ -274,6 +221,12 @@ class ProgramMemory:
         self.donated_bytes = 0
         self.donation_lost_bytes = 0
         self.peak: Optional[Dict] = None
+        # the step's account by instruction (xplane.hlo_instructions,
+        # compacted), its HLO module name and XLA's own FLOP count: not
+        # part of to_dict(), the executor keeps them on the compiled block
+        self.account = None
+        self.module = None
+        self.xla_flops = None
 
     @property
     def total_bytes(self) -> int:
@@ -350,23 +303,53 @@ def _shard_nbytes(value) -> int:
         * np.dtype(value.dtype).itemsize
 
 
-def analyze(fn, feed_vals, state_vals, rng_counter=0, *, program="?",
-            place="?", signature=None, top_k: int = 8) -> ProgramMemory:
-    """AOT-lower the jitted block fn from avals (shapes/dtypes only — the
-    _hlo_supplier discipline: donated state buffers must never be kept
-    alive by the capture) and read XLA's CompiledMemoryStats plus the
-    scheduled-HLO liveness walk. A real recompile unless the persistent
+def compile_from_avals(fn, avals):
+    """AOT lower().compile() of a jitted block fn from avals (shapes,
+    dtypes and shardings only: donated state buffers must never be kept
+    alive by a capture). A real recompile unless the persistent
     compilation cache covers it."""
+    with warnings.catch_warnings():
+        # backends without donation support (CPU) warn per compile; the
+        # executor's jit call already surfaced it once — the audit
+        # reports the loss in bytes instead
+        warnings.filterwarnings("ignore", message=".*donated buffers.*")
+        return fn.lower(*avals).compile()
+
+
+def account_of(compiled_exe, mesh=None):
+    """(HLO module name, the step's account by instruction, XLA's FLOP
+    count or None) of a compiled executable: the one parse of its text
+    (xplane.hlo_instructions), compacted to what takes time on the
+    device."""
+    from . import xplane
+    text = compiled_exe.as_text()
+    instrs = xplane.hlo_instructions(text, mesh=mesh)
+    return xplane.module_name(text), instrs, _xla_flops(compiled_exe)
+
+
+def _xla_flops(compiled_exe) -> Optional[float]:
+    try:
+        ca = compiled_exe.cost_analysis()
+        d = ca[0] if isinstance(ca, (list, tuple)) else ca
+        return float(d.get("flops", 0.0))
+    except Exception:  # noqa: BLE001 - backend-dependent
+        return None
+
+
+def analyze(fn, feed_vals, state_vals, rng_counter=0, *, program="?",
+            place="?", signature=None, top_k: int = 8,
+            mesh=None) -> ProgramMemory:
+    """AOT-lower the jitted block fn from avals and read XLA's
+    CompiledMemoryStats, then parse the executable's text ONCE
+    (xplane.hlo_instructions) for both the scheduled-HLO liveness walk
+    and the step's account by instruction (`rec.account`, with the
+    collectives' mesh axes when the planner's `mesh` is given)."""
     import jax
+    from . import xplane
 
     avals = jax.tree_util.tree_map(
         aval_of, (feed_vals, state_vals, np.uint32(rng_counter)))
-    with warnings.catch_warnings():
-        # backends without donation support (CPU) warn per compile; the
-        # executor's jit call already surfaced it once — the audit below
-        # reports the loss in bytes instead
-        warnings.filterwarnings("ignore", message=".*donated buffers.*")
-        compiled = fn.lower(*avals).compile()
+    compiled = compile_from_avals(fn, avals)
     stats = compiled.memory_analysis()
 
     rec = ProgramMemory(program=program, place=place, signature=signature)
@@ -381,7 +364,9 @@ def analyze(fn, feed_vals, state_vals, rng_counter=0, *, program="?",
         _shard_nbytes(v) for v in jax.tree_util.tree_leaves(state_vals))
     rec.donation_lost_bytes = max(rec.donated_bytes - rec.alias_bytes, 0)
     try:
-        rec.peak = hlo_peak_liveness(compiled.as_text(), top_k=top_k)
+        rec.module, instrs, rec.xla_flops = account_of(compiled, mesh)
+        rec.peak = hlo_peak_liveness(instrs, top_k=top_k)
+        rec.account = xplane.compact(instrs)
     except Exception:
         rec.peak = None
     _remember(rec)
@@ -620,7 +605,7 @@ def on_compile(exe, compiled, program, prog_label, place_label,
         return None
     rec = analyze(compiled.fn, feed_vals, state_vals, rng_counter,
                   program=prog_label, place=place_label,
-                  signature=signature)
+                  signature=signature, mesh=getattr(program, "_mesh", None))
     _publish(rec)
     _audit_donation(rec)
     return rec

@@ -381,7 +381,7 @@ def _muted_observers():
     """Member ops run through the executor's full _exec_op for bitwise
     parity, but only the FUSED op should reach the cost observers — the
     device-side HLO attribution keys on the outermost pd.* named scope
-    (xplane.hlo_op_names), so the analytic table must match it."""
+    (xplane.provenance), so the analytic table must match it."""
     from .. import executor as executor_mod
     saved = executor_mod._op_observers
     executor_mod._op_observers = []

@@ -11,7 +11,7 @@ from jax import lax
 def coll_scope(site: str):
     """Named scope tagging a framework collective call site. The scope
     lands in HLO metadata op_name as 'pd.coll.<site>', which
-    xplane.hlo_collectives joins back to device-time events so fleet.py
+    xplane.hlo_instructions reads as the collective's `site`, so fleet.py
     can attribute collective cost to the emitting layer (ring-attention
     rotate, pipeline send, dp grad psum) instead of a bare HLO name."""
     return jax.named_scope(f"pd.coll.{site}")

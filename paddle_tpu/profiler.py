@@ -108,7 +108,6 @@ def start_profiler(state="All", trace_dir: Optional[str] = None):
         "profiler_sessions_total", "profiling sessions started",
         labels=("traced",)).labels(
             traced=str(bool(trace_dir)).lower()).inc()
-    _hlo_suppliers.clear()
     _steps_at_start[0] = sum(
         telemetry.read_series("executor_steps_total").values())
     if trace_dir:
@@ -118,42 +117,15 @@ def start_profiler(state="All", trace_dir: Optional[str] = None):
 
 _start_trace_dir = [None]
 _steps_at_start = [0.0]
-# id(compiled_fn) -> (supplier, cost_fn): supplier is a zero-arg callable
-# returning the AOT-compiled block (or raw optimized-HLO text), cost_fn an
-# optional zero-arg callable returning the analytic per-op cost table
-# (roofline.program_cost). Registered by the executor while a traced
-# profile is active, consumed by the device report at stop.
-_hlo_suppliers: Dict[int, tuple] = {}
 
 
 def wants_device_table() -> bool:
     """True while a traced (trace_dir) profiling session is active — the
-    executor then registers its compiled blocks for HLO attribution."""
+    executor then makes sure every block it launches has its account by
+    instruction (xplane.remember_account; a block compiled with the
+    static memory analysis on already has), so that the device report at
+    stop compiles nothing."""
     return _active and _start_trace_dir[0] is not None
-
-
-_MAX_HLO_SUPPLIERS = 4  # each supply() is a full AOT recompile at stop
-
-
-def has_hlo_supplier(key: int) -> bool:
-    # saturated registry counts as "has": with program caching off every
-    # step builds a fresh compiled fn, and an unbounded registry would
-    # both pin them all alive and recompile each one at stop_profiler
-    return key in _hlo_suppliers or \
-        len(_hlo_suppliers) >= _MAX_HLO_SUPPLIERS
-
-
-def register_hlo_supplier(key: int, supplier, cost_fn=None):
-    if len(_hlo_suppliers) < _MAX_HLO_SUPPLIERS:
-        _hlo_suppliers.setdefault(key, (supplier, cost_fn))
-
-
-def consume_suppliers() -> list:
-    """Drain the registered (supplier, cost_fn) pairs — the device report
-    is built at most once per traced session."""
-    pairs = list(_hlo_suppliers.values())
-    _hlo_suppliers.clear()
-    return pairs
 
 
 def _traced_steps() -> Optional[int]:
@@ -209,34 +181,31 @@ def finish_trace_report(steps: Optional[int] = None, probe: bool = True):
         return None
     from . import roofline
     return roofline.collect_report(
-        trace_dir, consume_suppliers(),
-        steps=steps if steps is not None else _traced_steps(), probe=probe)
+        trace_dir, steps=steps if steps is not None else _traced_steps(),
+        probe=probe)
 
 
 def _print_device_table(trace_dir, sorted_key=None):
     """Per-IR-op device-time attribution for the whole-block jit
-    (reference ParseEvents, platform/profiler.h:137-166): xplane
-    per-instruction timings joined with each compiled module's
-    metadata op_name (which carries the executor's pd.<op_type> named
-    scope), enriched by roofline.py with analytic FLOPs/bytes, achieved
-    TF/s and a compute/memory/unattributed verdict. Unmapped device time
-    is pooled under "(unattributed)" so fractions sum to the true device
-    total. Re-lowers each registered block from avals — served from jax's
-    compilation cache when warm."""
+    (reference ParseEvents, platform/profiler.h:137-166): the trace's
+    instructions joined to the accounts the executor kept of its compiled
+    blocks (xplane.step_account: op instance, FLOPs, bytes, floor),
+    folded by program op and enriched by roofline.py with the analytic
+    FLOPs/bytes, achieved TF/s and a compute/memory/unattributed verdict.
+    Unmapped device time is pooled under "(unattributed)" so fractions
+    sum to the true device total. Nothing is compiled here."""
     from . import roofline
 
-    pairs = consume_suppliers()
     try:
-        report = roofline.collect_report(trace_dir, pairs,
-                                         steps=_traced_steps())
+        report = roofline.collect_report(trace_dir, steps=_traced_steps())
     except Exception as e:  # noqa: BLE001 - truncated/foreign .xplane.pb
         print(f"[device] (trace unreadable: {type(e).__name__}: {e})")
         return
     if report is None or not report.get("rows"):
         return
-    if not report.get("mapped") and not pairs:
-        # nothing was registered (eager run, foreign trace): keep the old
-        # silent behaviour instead of printing an all-unattributed table
+    if not report.get("mapped"):
+        # no account names what ran (eager run, foreign trace): keep the
+        # old silent behaviour instead of an all-unattributed table
         return
     for line in roofline.format_report(report):
         print(line)

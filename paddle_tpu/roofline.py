@@ -11,13 +11,17 @@ Joins three sources into one per-op table:
    input/output avals, and `_op_cost` maps (op_type, shapes) -> (flops,
    bytes). Cross-checked against XLA's own `compiled.cost_analysis()`.
 
-2. **Measured device time** — xplane per-instruction picoseconds
-   (`xplane.aggregate_dir`) joined to IR ops through each compiled
-   block's HLO metadata op_name (the executor's pd.<type> named scope);
-   unmapped device time pools under "(unattributed)" so fractions sum to
-   the true device total. `xplane.timeline_dir` (XLine.timestamp_ns +
-   XEvent.offset_ps) supplies the step-time waterfall: device compute vs
-   infeed vs collectives vs host gap, plus the device duty cycle.
+2. **Measured device time and executed work** — the trace's `XLA Ops`
+   events joined, instruction by instruction, to the account the
+   executor keeps of each compiled block (`xplane.step_account`: op
+   instance, executed FLOPs and bytes, the floor `max(flops / peak,
+   bytes / hbm)`), folded by program op instance; device time no account
+   names pools under "(unattributed)" so fractions sum to the true device
+   total. The analytic model of (1) stays beside it as the REQUIRED
+   FLOPs: what the executed ones exceed it by is recomputation.
+   `xplane.timeline_dir` (XLine.timestamp_ns + XEvent.offset_ps)
+   supplies the step-time waterfall: device compute vs infeed vs
+   collectives vs host gap, plus the device duty cycle.
 
 3. **Two-point measured roofline** — a sustained-matmul TF/s probe and
    an HBM-bandwidth probe (both cached per process; env-overridable via
@@ -44,7 +48,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
-__all__ = ["program_cost", "op_cost", "hlo_counts", "matmul_probe",
+__all__ = ["program_cost", "op_cost", "matmul_probe",
            "hbm_probe", "ici_probe", "ensure_probes", "ensure_ici",
            "nominal_tflops", "collect_report", "format_report", "capture",
            "waterfall", "top_ops", "UNATTRIBUTED"]
@@ -391,27 +395,6 @@ def program_cost(executor, program, feed_avals: Dict[str, Any],
             "total_bytes": sum(d["bytes"] for d in table.values())}
 
 
-# --- HLO instruction / kernel counts ----------------------------------------
-
-# one HLO instruction per "name = <shape> opcode(...)" line; tuple shapes
-# contain no nested parens so the alternation stays regular
-_HLO_INSTR = re.compile(
-    r"^\s*(?:ROOT\s+)?%?[\w.-]+\s*=\s*(?:\([^)]*\)|\S+)\s+([\w-]+)\(",
-    re.M)
-
-
-def hlo_counts(hlo_text: str) -> Dict[str, int]:
-    """{"instructions", "fusions"} for one compiled module's HLO text —
-    the per-step kernel-count proxy the fusion pass is judged by: fewer
-    instructions/fusions at equal math means the trace handed XLA larger
-    windows. Counts every instruction line incl. fused computations'
-    bodies; "fusions" counts the top-level fusion ops (≈ device kernels
-    that aren't library calls)."""
-    ops = _HLO_INSTR.findall(hlo_text or "")
-    return {"instructions": len(ops),
-            "fusions": sum(1 for o in ops if o == "fusion")}
-
-
 # --- two-point measured roofline --------------------------------------------
 
 _PROBES: Dict[str, float] = {}
@@ -670,92 +653,121 @@ def waterfall(trace_dir) -> Optional[Dict[str, Any]]:
 
 # --- the joined report ------------------------------------------------------
 
-def collect_report(trace_dir, suppliers=(), steps: Optional[int] = None,
-                   probe: bool = True) -> Optional[Dict[str, Any]]:
-    """Join measured device time, the analytic cost model, and the
-    two-point roofline into one report dict (see format_report for the
-    printed form). `suppliers` are the profiler's (supply, cost_fn)
-    pairs; `steps` is how many executor steps ran inside the trace (flops
-    scale by it). Never raises on a missing piece — each absent source
-    just blanks its columns."""
-    from . import telemetry, xplane
-
-    mapping: Dict[str, str] = {}
+def _required_costs(infos, notes):
+    """The analytic per-op-type table summed over the compiled blocks a
+    trace ran (their `cost` suppliers, executor._cost_supplier)."""
     cost: Dict[str, Dict[str, float]] = {}
     total_flops = total_bytes = 0.0
-    xla_flops = 0.0
-    have_cost = have_xla = False
-    hlo = {"modules": 0, "instructions": 0, "fusions": 0}
-    texts: List[str] = []
-    notes: List[str] = []
-    for pair in suppliers:
-        supply, cost_fn = pair if isinstance(pair, tuple) else (pair, None)
+    have = False
+    for info in infos:
+        cost_fn = info.get("cost")
+        if cost_fn is None:
+            continue
         try:
-            compiled = supply()
-            text = compiled if isinstance(compiled, str) \
-                else compiled.as_text()
-            texts.append(text)
-            mapping.update(xplane.hlo_op_names(text))
-            counts = hlo_counts(text)
-            hlo["modules"] += 1
-            hlo["instructions"] += counts["instructions"]
-            hlo["fusions"] += counts["fusions"]
-            if not isinstance(compiled, str):
-                try:
-                    ca = compiled.cost_analysis()
-                    d = ca[0] if isinstance(ca, (list, tuple)) else ca
-                    xla_flops += float(d.get("flops", 0.0))
-                    have_xla = True
-                except Exception:  # noqa: BLE001 - backend-dependent
-                    pass
-        except Exception as e:  # noqa: BLE001 - table is best-effort
-            notes.append(f"hlo attribution unavailable: {e}")
-        if cost_fn is not None:
-            try:
-                t = cost_fn()
-                for op_type, d in t["ops"].items():
-                    acc = cost.setdefault(
-                        op_type, {"flops": 0.0, "bytes": 0.0,
-                                  "max_flops": 0.0, "shape": None,
-                                  "peak_factor": None})
-                    pf = d.get("peak_factor")
-                    if pf is not None:
-                        acc["peak_factor"] = pf \
-                            if acc["peak_factor"] is None \
-                            else min(acc["peak_factor"], pf)
-                    acc["flops"] += d["flops"]
-                    acc["bytes"] += d["bytes"]
-                    if d.get("max_flops", 0.0) >= acc["max_flops"]:
-                        acc["max_flops"] = d.get("max_flops", 0.0)
-                        acc["shape"] = d.get("shape")
-                total_flops += t["total_flops"]
-                total_bytes += t["total_bytes"]
-                have_cost = True
-            except Exception as e:  # noqa: BLE001
-                notes.append(
-                    f"cost model unavailable: {type(e).__name__}: {e}")
+            t = cost_fn()
+        except Exception as e:  # noqa: BLE001
+            notes.append(f"cost model unavailable: {type(e).__name__}: {e}")
+            continue
+        for op_type, d in t["ops"].items():
+            acc = cost.setdefault(
+                op_type, {"flops": 0.0, "bytes": 0.0, "max_flops": 0.0,
+                          "shape": None, "peak_factor": None})
+            pf = d.get("peak_factor")
+            if pf is not None:
+                acc["peak_factor"] = pf if acc["peak_factor"] is None \
+                    else min(acc["peak_factor"], pf)
+            acc["flops"] += d["flops"]
+            acc["bytes"] += d["bytes"]
+            if d.get("max_flops", 0.0) >= acc["max_flops"]:
+                acc["max_flops"] = d.get("max_flops", 0.0)
+                acc["shape"] = d.get("shape")
+        total_flops += t["total_flops"]
+        total_bytes += t["total_bytes"]
+        have = True
+    return cost, total_flops, total_bytes, have
 
-    instr_ps = xplane.aggregate_dir(trace_dir)
-    agg = xplane.attribute(instr_ps, mapping, other_label=UNATTRIBUTED)
-    if not agg:
+
+def collect_report(trace_dir, steps: Optional[int] = None,
+                   probe: bool = True, accounts=None
+                   ) -> Optional[Dict[str, Any]]:
+    """Join measured device time, the executed work of each instruction
+    and the two-point roofline into one report dict (see format_report
+    for the printed form), one row a program op INSTANCE (`op`, `at` =
+    its position in the block). `accounts` are (instructions, info) pairs
+    as `xplane.known_accounts()` gives them (the default: what the
+    executor kept in this process, else what an earlier reader saved
+    beside the trace); `info["cost"]` supplies the analytic table, the
+    REQUIRED FLOPs beside the executed ones. `steps` is how many executor
+    steps ran inside the trace (the required totals scale by it). Never
+    raises on a missing piece — each absent source just blanks its
+    columns. Nothing is compiled here."""
+    from . import telemetry, xplane
+
+    notes: List[str] = []
+    pairs = xplane.known_accounts() if accounts is None else list(accounts)
+    acct = xplane.step_account(
+        trace_dir, accounts=[instrs for instrs, _ in pairs]
+        if pairs or accounts is not None else None)
+    if acct is None or not acct["steps"]:
         return None
-    total_ps = sum(agg.values())
+    used = [pairs[i] for i in acct.get("used", ()) if i < len(pairs)]
+    cost, total_flops, total_bytes, have_cost = _required_costs(
+        [info for _, info in used], notes)
+    xla_flops = sum(info.get("xla_flops") or 0.0 for _, info in used)
+    executed_flops = sum(i.flops or 0.0 for instrs, _ in used
+                         for i in instrs if i.entry)
+
     probes = ensure_probes(probe)
     ridge = probes["ridge"]
     sustained = probes["sustained_tflops"]
     nominal = nominal_tflops() or sustained
+    # the floor's peaks: the chip's published ones where the account knows
+    # the chip (xplane.step_account), else the probes'
+    peak = acct["peak_flops"] or (sustained * 1e12 if sustained else None)
+    hbm = acct["hbm_bytes_per_s"] or (
+        probes["hbm_gbps"] * 1e9 if probes["hbm_gbps"] else None)
+
+    agg: Dict[tuple, Dict[str, Any]] = {}
+    total_ps = 0.0
+    for st in acct["steps"]:
+        for r in st["rows"]:
+            ps = r["ms"] * 1e9
+            total_ps += ps
+            key = (r["label"], r["at"]) if r["joined"] or r["op"] \
+                else (UNATTRIBUTED, None)
+            a = agg.setdefault(key, {
+                "ps": 0.0, "work_flops": 0.0, "work_bytes": 0.0,
+                "instrs": {}, "joined": False, "mosaic_ps": 0.0,
+                "scope": r["scope"], "role": r["role"]})
+            a["ps"] += ps
+            if r["joined"]:
+                a["joined"] = True
+                a["work_flops"] += (r["flops"] or 0.0) * r["count"]
+                a["work_bytes"] += (r["bytes"] or 0) * r["count"]
+                a["instrs"][r["name"]] = (r["flops"], r["bytes"], r["shape"])
+                if r["opcode"] == "custom-call" and r["flops"] is None:
+                    a["mosaic_ps"] += ps
+    if not agg:
+        return None
 
     rows = []
-    for name, ps in sorted(agg.items(), key=lambda kv: -kv[1]):
+    for (name, at), a in sorted(agg.items(), key=lambda kv: -kv[1]["ps"]):
+        ps = a["ps"]
         c = cost.get(name)
-        flops = c["flops"] if c else None
-        bytes_ = c["bytes"] if c else None
-        tflops = intensity = None
-        if flops is not None and steps and ps:
-            tflops = flops * steps / (ps / 1e12) / 1e12
-        if flops is not None and bytes_:
-            intensity = flops / bytes_
-        if name == UNATTRIBUTED or c is None:
+        flops = bytes_ = tflops = intensity = None
+        shape = c.get("shape") if c else None
+        if a["joined"]:
+            flops = sum(f or 0.0 for f, _, _ in a["instrs"].values())
+            bytes_ = float(sum(b or 0 for _, b, _ in a["instrs"].values()))
+            if ps:
+                tflops = a["work_flops"] / (ps / 1e12) / 1e12
+            if bytes_:
+                intensity = flops / bytes_
+            if shape is None:
+                shape = xplane._plain(max(
+                    a["instrs"].values(), key=lambda v: v[1] or 0)[2]) \
+                    or None
+        if name == UNATTRIBUTED or not a["joined"]:
             bound = "unattributed"
         elif intensity is not None and ridge is not None:
             bound = "compute" if intensity >= ridge else "memory"
@@ -765,34 +777,34 @@ def collect_report(trace_dir, suppliers=(), steps: Optional[int] = None,
             bound = "compute" if intensity >= 100 else "memory"
         else:
             bound = "unattributed"
-        # per-kernel scoreboard: analytic minimum device time (the larger
-        # of the compute- and bandwidth-floor) vs measured — the achieved
-        # fraction attributes the remaining MFU gap kernel by kernel
+        # per-kernel scoreboard: the least the device could take for the
+        # work this op instance executed (the larger of the compute and
+        # the bandwidth floor) vs measured — the floor share attributes
+        # the remaining MFU gap instance by instance. int8/fp8: an op
+        # type whose every instance routes through the quantized path
+        # computes against the MXU's doubled peak (peak_factor from
+        # program_cost)
         min_ps = efficiency = None
-        if c is not None and steps and ps:
+        factor = (c.get("peak_factor") if c else None) or 1.0
+        if a["joined"] and ps:
             floors = []
-            if flops and sustained:
-                # int8/fp8 roofline: an op whose every instance routes
-                # through the quantized path computes against the MXU's
-                # doubled low-precision peak, so its analytic floor
-                # halves (peak_factor from program_cost, min-combined
-                # across instances — one unquantized instance pins the
-                # whole op type to the bf16 roofline)
-                factor = c.get("peak_factor") or 1.0
-                floors.append(flops * steps / (sustained * factor * 1e12))
-            if bytes_ and probes["hbm_gbps"]:
-                floors.append(bytes_ * steps / (probes["hbm_gbps"] * 1e9))
+            if a["work_flops"] and peak:
+                floors.append(a["work_flops"] / (peak * factor))
+            if a["work_bytes"] and hbm:
+                floors.append(a["work_bytes"] / hbm)
             if floors:
                 min_ps = max(floors) * 1e12
                 if min_ps > 0:
                     efficiency = min_ps / ps
-        rows.append({"op": name, "ps": ps, "frac": ps / total_ps,
+        rows.append({"op": name, "at": at, "role": a["role"],
+                     "scope": a["scope"], "ps": ps, "frac": ps / total_ps,
                      "flops": flops, "bytes": bytes_, "tflops": tflops,
                      "intensity": intensity, "bound": bound,
-                     "shape": c.get("shape") if c else None,
-                     "peak_factor": (c.get("peak_factor") or 1.0)
-                     if c else None,
-                     "min_ps": min_ps, "efficiency": efficiency})
+                     "shape": shape,
+                     "required_flops": c["flops"] if c else None,
+                     "peak_factor": factor if c else None,
+                     "min_ps": min_ps, "efficiency": efficiency,
+                     "mosaic_ps": a["mosaic_ps"]})
 
     wf = None
     try:
@@ -803,8 +815,8 @@ def collect_report(trace_dir, suppliers=(), steps: Optional[int] = None,
     colls = None
     try:
         from . import fleet
-        colls = fleet.collective_table(trace_dir, texts, steps=steps,
-                                       probe=probe)
+        colls = fleet.collective_table(trace_dir, steps=steps, probe=probe,
+                                       account=acct)
     except Exception as e:  # noqa: BLE001
         notes.append(
             f"collective attribution unavailable: {type(e).__name__}: {e}")
@@ -812,7 +824,8 @@ def collect_report(trace_dir, suppliers=(), steps: Optional[int] = None,
     report: Dict[str, Any] = {
         "trace_dir": str(trace_dir), "steps": steps,
         "device_total_ps": total_ps, "rows": rows,
-        "mapped": bool(mapping), "waterfall": wf,
+        "mapped": any(a["joined"] for a in agg.values()),
+        "joined": acct["joined"], "waterfall": wf,
         "collectives": colls,
         "device_duty_cycle": (wf or {}).get("device_duty_cycle"),
         "sustained_tflops": sustained, "hbm_gbps": probes["hbm_gbps"],
@@ -825,11 +838,18 @@ def collect_report(trace_dir, suppliers=(), steps: Optional[int] = None,
             sum(d["flops"] for d in cost.values()
                 if (d.get("peak_factor") or 1.0) > 1.0) / total_flops
             if have_cost and total_flops else None),
-        "hlo_counts": hlo if hlo["modules"] else None,
+        # entry instructions that take time on the device, and the
+        # fusions among them (≈ device kernels that aren't library calls):
+        # the per-step kernel-count proxy the fusion pass is judged by
+        "kernel_counts": {
+            "modules": len(used),
+            "instructions": sum(len(instrs) for instrs, _ in used),
+            "fusions": sum(1 for instrs, _ in used for i in instrs
+                           if i.opcode == "fusion")} if used else None,
         "mfu_nominal": None, "mfu_vs_sustained": None, "notes": notes,
     }
     report["kernel_efficiency"] = [
-        {"op": r["op"], "shape": r["shape"],
+        {"op": r["op"], "at": r["at"], "shape": r["shape"],
          "ms": round(r["ps"] / 1e9, 4),
          "min_ms": round(r["min_ps"] / 1e9, 4),
          "efficiency": round(r["efficiency"], 4)}
@@ -840,13 +860,10 @@ def collect_report(trace_dir, suppliers=(), steps: Optional[int] = None,
     # growing as gates widen — flash-attention custom-calls map to the
     # sdpa op name and stay out of the conv family by construction
     conv_ps = pallas_ps = 0
-    for instr, ps in instr_ps.items():
-        op_name = mapping.get(instr)
-        if op_name is None or "conv" not in op_name:
-            continue
-        conv_ps += ps
-        if instr.split(".")[0] == "custom-call":
-            pallas_ps += ps
+    for r in rows:
+        if "conv" in r["op"] and r["op"] != UNATTRIBUTED:
+            conv_ps += r["ps"]
+            pallas_ps += r["mosaic_ps"]
     report["pallas_kernel_coverage"] = \
         (pallas_ps / conv_ps) if conv_ps else None
     # input-bound verdict: the waterfall blames the host input path when
@@ -862,10 +879,17 @@ def collect_report(trace_dir, suppliers=(), steps: Optional[int] = None,
                 "step time is input-bound: raise the feeder's "
                 "window_prefetch and/or use --steps-per-call auto so "
                 "run_steps windows amortize host dispatch")
-    if have_cost and have_xla and xla_flops > 0:
+    if xla_flops > 0 and (have_cost or executed_flops):
+        # required (the analytic model) beside executed (the account) and
+        # XLA's own count of the compiled step: executed over required is
+        # recomputation, executed against XLA holds the account's
+        # arithmetic to the compiler's
         report["cost_crosscheck"] = {
-            "analytic_flops": total_flops, "xla_flops": xla_flops,
-            "rel_err": abs(total_flops - xla_flops) / xla_flops}
+            "analytic_flops": total_flops if have_cost else None,
+            "executed_flops": executed_flops, "xla_flops": xla_flops,
+            "rel_err": (abs(total_flops - xla_flops) / xla_flops
+                        if have_cost else None),
+            "executed_rel_err": abs(executed_flops - xla_flops) / xla_flops}
     span_ps = (wf or {}).get("span_ps") or 0
     if have_cost and steps and span_ps:
         achieved = total_flops * steps / (span_ps / 1e12) / 1e12
@@ -938,13 +962,18 @@ def format_report(report: Dict[str, Any]) -> List[str]:
     CLI share this). Row format keeps `[device] <op> ...` so existing
     log scrapers (and tests) still find the op in field 2."""
     lines = [f"{'Device op (jit)':40s} {'Total(ms)':>12s} {'Frac':>8s} "
-             f"{'GFLOPs':>9s} {'MB':>9s} {'TF/s':>9s} {'AI':>9s}  Bound"]
+             f"{'GFLOPs':>9s} {'MB':>9s} {'TF/s':>9s} {'AI':>9s}  "
+             f"{'Bound':12s} {'Floor':>6s}  At"]
     for row in report["rows"]:
+        floor = ("{:6.1%}".format(row["efficiency"])
+                 if row.get("efficiency") is not None else "     -")
+        at = "" if row.get("at") is None else f"  @{row['at']}"
         lines.append(
             f"[device] {row['op']:31s} {row['ps'] / 1e9:12.4f} "
             f"{row['frac']:8.1%} {_fmt(row['flops'], 1e9)} "
             f"{_fmt(row['bytes'], 1e6)} {_fmt(row['tflops'])} "
-            f"{_fmt(row['intensity'], 1.0, 1)}  {row['bound']}")
+            f"{_fmt(row['intensity'], 1.0, 1)}  {row['bound']:12s} "
+            f"{floor}{at}")
     wf = report.get("waterfall")
     if wf:
         span = wf["span_ps"]
@@ -963,15 +992,16 @@ def format_report(report: Dict[str, Any]) -> List[str]:
     if colls and colls.get("rows"):
         lines.append(
             f"{'Collective':20s} {'Call site':22s} {'MB':>9s} "
-            f"{'busbw GB/s':>11s} {'% link':>7s} {'Exposed(ms)':>12s}")
+            f"{'busbw GB/s':>11s} {'% link':>7s} {'Exposed(ms)':>12s}"
+            f"  Axis")
         for r in colls["rows"]:
             pct = ("{:6.1%}".format(r["pct_link"])
                    if r.get("pct_link") is not None else "     -")
             lines.append(
-                "[coll] {:13s} {:22s} {:9.2f} {:>11s} {} {:12.3f}".format(
+                "[coll] {:13s} {:22s} {:9.2f} {:>11s} {} {:12.3f}  {}".format(
                     r["kind"], r["site"], r["bytes"] / 1e6,
                     _fmt(r.get("busbw_gbps"), 1.0, 2, 11).strip().rjust(11),
-                    pct, r["exposed_ms"]))
+                    pct, r["exposed_ms"], r.get("axis") or "-"))
         if colls.get("ici_gbps"):
             lines.append(
                 "[coll] link roofline {:.1f} GB/s ({} participants)".format(
@@ -991,8 +1021,9 @@ def format_report(report: Dict[str, Any]) -> List[str]:
             f" {'Achieved':>9s}")
         for r in ke:
             shape = f" [{r['shape']}]" if r.get("shape") else ""
+            op = r["op"] if r.get("at") is None else f"{r['op']}@{r['at']}"
             lines.append(
-                f"[kernel] {r['op']:24s}{shape:14s} {r['ms']:10.4f} "
+                f"[kernel] {op:24s}{shape:14s} {r['ms']:10.4f} "
                 f"{r['min_ms']:10.4f} {r['efficiency']:9.1%}")
     cov = report.get("pallas_kernel_coverage")
     if cov is not None:
@@ -1001,17 +1032,21 @@ def format_report(report: Dict[str, Any]) -> List[str]:
     if report.get("input_bound"):
         lines.append("[verdict] input-bound: " +
                      report.get("input_bound_remedy", ""))
-    hc = report.get("hlo_counts")
+    hc = report.get("kernel_counts")
     if hc:
         lines.append(
             "[hlo] {} instructions | {} fusion kernels | {} modules"
             .format(hc["instructions"], hc["fusions"], hc["modules"]))
     cc = report.get("cost_crosscheck")
     if cc:
-        lines.append(
-            f"[crosscheck] analytic {cc['analytic_flops'] / 1e9:.3f} "
-            f"GFLOPs vs XLA {cc['xla_flops'] / 1e9:.3f} GFLOPs "
-            f"(rel err {cc['rel_err']:.1%})")
+        bits = []
+        if cc.get("analytic_flops") is not None:
+            bits.append(f"analytic {cc['analytic_flops'] / 1e9:.3f} GFLOPs "
+                        f"(rel err {cc['rel_err']:.1%})")
+        bits.append(f"executed {cc['executed_flops'] / 1e9:.3f} GFLOPs "
+                    f"(rel err {cc['executed_rel_err']:.1%})")
+        lines.append("[crosscheck] " + " | ".join(bits)
+                     + f" vs XLA {cc['xla_flops'] / 1e9:.3f} GFLOPs")
     mfu_bits = []
     if report.get("mfu_nominal") is not None:
         mfu_bits.append(f"nominal {report['mfu_nominal']:.3f}")
@@ -1028,11 +1063,12 @@ def format_report(report: Dict[str, Any]) -> List[str]:
 
 def top_ops(report: Dict[str, Any], k: int = 5) -> List[Dict[str, Any]]:
     """Compact per-op summary for bench JSON lines: top-k rows by device
-    time, each {op, ms, frac, gflops, tflops, bound, efficiency}."""
+    time, each {op, at, ms, frac, gflops, tflops, bound, efficiency}."""
     out = []
     for row in report["rows"][:k]:
         out.append({
-            "op": row["op"], "ms": round(row["ps"] / 1e9, 4),
+            "op": row["op"], "at": row.get("at"),
+            "ms": round(row["ps"] / 1e9, 4),
             "frac": round(row["frac"], 4),
             "gflops": (None if row["flops"] is None
                        else round(row["flops"] / 1e9, 3)),

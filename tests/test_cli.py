@@ -101,7 +101,9 @@ class TestPerfCLI:
             timeout=300)
         assert r.returncode == 0, r.stdout + r.stderr[-1500:]
         out = r.stdout
-        assert "(unattributed)" in out
+        # every instruction of the smoke's step is named by its account:
+        # XLA's own count of the step stands beside the executed one
+        assert "[crosscheck]" in out and "executed" in out
         assert "[waterfall]" in out and "[roofline]" in out
         assert "[mfu]" in out
         rows = [ln.split() for ln in out.splitlines()
@@ -109,15 +111,17 @@ class TestPerfCLI:
         data_rows = [t for t in rows
                      if len(t) >= 8 and t[3].endswith("%")]
         assert data_rows, out
-        # every row: op, ms, frac, GFLOPs, MB, TF/s, AI, bound verdict
-        assert all(t[-1] in ("compute", "memory", "unattributed")
+        # every row: op, ms, frac, GFLOPs, MB, TF/s, AI, bound verdict,
+        # floor share, and the op instance (@position in the block)
+        assert all(t[8] in ("compute", "memory", "unattributed")
                    for t in data_rows), data_rows
+        assert any(t[-1].startswith("@") for t in data_rows), data_rows
         # fractions (incl. the unattributed pool) sum to the device total
         total = sum(float(t[3].rstrip("%")) for t in data_rows)
         assert abs(total - 100.0) < 1.0, out
         # at least one attributed row carries real numbers end to end
         attributed = [t for t in data_rows
-                      if t[-1] in ("compute", "memory")]
+                      if t[8] in ("compute", "memory")]
         assert attributed, out
         assert all(t[4] != "-" and t[6] != "-" for t in attributed), out
 
@@ -135,8 +139,8 @@ class TestPerfCLI:
         report = json_mod.loads(r.stdout)
         assert report["rows"] and report["mapped"]
         for row in report["rows"]:
-            assert {"op", "ps", "frac", "flops", "bytes", "tflops",
-                    "bound"} <= set(row)
+            assert {"op", "at", "ps", "frac", "flops", "bytes", "tflops",
+                    "bound", "efficiency"} <= set(row)
         assert report["ridge_intensity"] == 25.0
         assert report.get("device_duty_cycle") is not None
 

@@ -139,33 +139,44 @@ to_apply=%add, metadata={op_name="jit(step)/pd.mean/reduce_sum"}
 
 class TestHloCollectives:
     def test_sites_bytes_and_done_halves(self):
-        out = xplane.hlo_collectives(_HLO)
+        out = {i.name: i for i in xplane.hlo_instructions(_HLO) if i.kind}
         assert set(out) == {"all-reduce.1", "all-gather-start.2",
                             "all-gather-done.2", "all-reduce.9"}
         ar = out["all-reduce.1"]
-        assert ar["kind"] == "all-reduce"
-        assert ar["site"] == "dp_grad"
-        assert ar["bytes"] == 1024 * 1024 * 4
+        assert ar.kind == "all-reduce"
+        assert ar.site == "dp_grad"
+        assert ar.payload == 1024 * 1024 * 4
         # async start carries an (input, output) tuple aliasing ONE
-        # transfer: bytes is the largest component, not the sum
+        # transfer: the payload is the output, not the sum
         ag = out["all-gather-start.2"]
-        assert ag["kind"] == "all-gather"
-        assert ag["site"] == "tp_gather"
-        assert ag["bytes"] == 1024 * 4
+        assert ag.kind == "all-gather"
+        assert ag.site == "tp_gather"
+        assert ag.payload == 1024 * 4
         # the -done half joins time but contributes 0 bytes (no double
         # counting of the pair's payload)
-        assert out["all-gather-done.2"]["bytes"] == 0
+        assert out["all-gather-done.2"].payload == 0
         # GSPMD-inserted collective: no pd.coll scope, but the inherited
         # op_name names the responsible layer
         g = out["all-reduce.9"]
-        assert g["site"] is None
-        assert g["near"] == "mean"
+        assert g.site is None
+        assert g.op == "mean"
 
     def test_participants(self):
-        assert xplane.hlo_participants(_HLO) == 4
-        assert xplane.hlo_participants(
-            "replica_groups={{0,1},{2,3}}, x") == 2
-        assert xplane.hlo_participants("no groups here") is None
+        # each instruction's OWN group size, not the module's first
+        sizes = {i.name: i.group_size
+                 for i in xplane.hlo_instructions(_HLO)
+                 if i.kind and not i.opcode.endswith("-done")}
+        assert len(sizes) == 3 and set(sizes.values()) == {4}
+        (two,) = [i for i in xplane.hlo_instructions(
+            "ENTRY main {\n  %p = f32[8]{0} parameter(0)\n"
+            "  %all-reduce.1 = f32[8]{0} all-reduce(%p), "
+            "replica_groups={{0,1},{2,3}}, to_apply=%add\n}\n") if i.kind]
+        assert two.group_size == 2
+        (none,) = [i for i in xplane.hlo_instructions(
+            "ENTRY main {\n  %p = f32[8]{0} parameter(0)\n"
+            "  %all-reduce.1 = f32[8]{0} all-reduce(%p), to_apply=%add\n"
+            "}\n") if i.kind]
+        assert none.group_size is None
 
 
 # --- exposed-vs-overlapped split ---------------------------------------------
@@ -203,11 +214,11 @@ def pinned_ici(monkeypatch):
 
 
 def _write_trace(tmp_path):
-    # device plane, two lines: the raw XLA-op line (all-reduce.1 4us, of
+    # device plane, two lines: the `XLA Ops` line (all-reduce.1 4us, of
     # which 1us hides under fusion.1) and a derived line repeating the
-    # same event shorter — per-name MAX across lines must pick the raw one
+    # same event shorter — only the core's own line is read
     metas = [_meta(1, "fusion.1"), _meta(2, "all-reduce.1")]
-    raw = _line("xla-ops", 0, [
+    raw = _line("XLA Ops", 0, [
         _event(1, 0, 2_000_000),            # fusion.1: 0..2us
         _event(2, 1_000_000, 4_000_000),    # all-reduce.1: 1..5us
     ])
@@ -217,21 +228,23 @@ def _write_trace(tmp_path):
 
 
 class TestCollectiveEventsDir:
-    def test_max_across_lines_and_exposed(self, tmp_path):
+    def test_ops_line_alone_and_exposed(self, tmp_path):
         _write_trace(tmp_path)
-        evs = xplane.collective_events_dir(str(tmp_path))
-        assert set(evs) == {"all-reduce.1"}
-        rec = evs["all-reduce.1"]
+        (step,) = xplane.device_steps(str(tmp_path))
+        assert [e[0] for e in step["events"]] == ["fusion.1", "all-reduce.1"]
+        account = xplane.step_account(str(tmp_path), accounts=[])
+        (rec,) = [r for r in account["steps"][0]["rows"] if r["kind"]]
         assert rec["kind"] == "all-reduce"
-        assert rec["total_ps"] == 4_000_000          # max, not 4+3
-        assert rec["exposed_ps"] == 3_000_000        # 1us under fusion.1
+        assert rec["ms"] == pytest.approx(0.004)          # once, not 4+3
+        assert rec["exposed_ms"] == pytest.approx(0.003)  # 1us under fusion.1
 
 
 class TestCollectiveTable:
     def test_join_busbw_and_roofline_pct(self, tmp_path, pinned_ici):
         _write_trace(tmp_path)
-        table = fleet.collective_table(str(tmp_path), [_HLO], steps=2,
-                                       probe=False)
+        table = fleet.collective_table(
+            str(tmp_path), accounts=[xplane.hlo_instructions(_HLO)],
+            probe=False)
         assert table["ici_gbps"] == pinned_ici
         assert table["participants"] == 4
         assert len(table["rows"]) == 1
@@ -239,7 +252,8 @@ class TestCollectiveTable:
         assert r["kind"] == "all-reduce"
         assert r["site"] == "dp_grad"
         assert r["count"] == 1
-        assert r["bytes"] == 1024 * 1024 * 4 * 2     # payload x steps
+        assert r["group_size"] == 4 and r["axis"] is None   # no mesh given
+        assert r["bytes"] == 1024 * 1024 * 4         # payload x its one run
         assert r["time_ms"] == pytest.approx(0.004)
         assert r["exposed_ms"] == pytest.approx(0.003)
         assert r["overlap_frac"] == pytest.approx(0.25)
@@ -250,7 +264,8 @@ class TestCollectiveTable:
 
     def test_unjoined_event_pools_under_gspmd(self, tmp_path, pinned_ici):
         _write_trace(tmp_path)
-        table = fleet.collective_table(str(tmp_path), [], probe=False)
+        table = fleet.collective_table(str(tmp_path), accounts=[],
+                                       probe=False)
         (r,) = table["rows"]
         assert r["site"] == "(gspmd)"
         assert r["bytes"] == 0

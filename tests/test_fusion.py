@@ -457,9 +457,10 @@ def test_no_kernel_reason_is_left():
 
 def test_roofline_sees_fused_ops():
     """The analytic cost model prices fused types from their prefixed
-    member slots, and hlo_counts parses instruction/fusion counts."""
+    member slots, and the one parse of compiled text counts
+    instructions and fusions and reads a fusion through its body."""
     import jax
-    from paddle_tpu import roofline
+    from paddle_tpu import roofline, xplane
 
     aval = jax.ShapeDtypeStruct((2, 8, 8, 8), np.float32)
     filt = jax.ShapeDtypeStruct((8, 3, 3, 3), np.float32)
@@ -489,9 +490,12 @@ ENTRY main {
   ROOT t = (f32[8]{0}, f32[8]{0}) tuple(f, x)
 }
 """
-    counts = roofline.hlo_counts(hlo)
-    assert counts["fusions"] == 1
-    assert counts["instructions"] >= 5
+    instrs = xplane.hlo_instructions(hlo)
+    assert [i.name for i in instrs] == ["x", "f", "t"]   # the entry's own
+    (fused,) = [i for i in instrs if i.opcode == "fusion"]
+    assert fused.heavy == "elementwise" and fused.flops == 8.0
+    assert fused.bytes == 2 * 8 * 4
+    assert [i.name for i in xplane.compact(instrs)] == ["f"]
 
 
 def test_plan_window_kinds():

@@ -17,7 +17,7 @@ import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu import executor as em
-from paddle_tpu import fleet, telemetry
+from paddle_tpu import fleet, telemetry, xplane
 from paddle_tpu.framework import unique_name
 from paddle_tpu.parallel import overlap
 
@@ -377,7 +377,7 @@ def _write_mono(tmp_path):
     # one monolithic post-backward all-reduce, nothing left to overlap
     # with: 8us, fully exposed
     metas = [_meta(1, "fusion.1"), _meta(2, "all-reduce.1")]
-    raw = _line("xla-ops", 0, [
+    raw = _line("XLA Ops", 0, [
         _event(1, 0, 2_000_000),               # backward: 0..2us
         _event(2, 2_000_000, 8_000_000),       # all-reduce.1: 2..10us
     ])
@@ -393,7 +393,7 @@ def _write_bucketed(tmp_path):
     # trails the last grad op with only 2us exposed
     metas = [_meta(1, "fusion.1"), _meta(2, "all-reduce.1"),
              _meta(3, "all-reduce.2")]
-    raw = _line("xla-ops", 0, [
+    raw = _line("XLA Ops", 0, [
         _event(1, 0, 6_000_000),               # backward: 0..6us
         _event(2, 1_000_000, 4_000_000),       # bucket0: 1..5us, hidden
         _event(3, 6_000_000, 4_000_000),       # bucket1: 6..10us, exposed
@@ -410,11 +410,12 @@ class TestBucketSitesInFleetReport:
         """The ISSUE 9 acceptance shape: dp-grad collectives appear under
         >= 2 per-bucket sites, and the bucketed schedule's exposed
         fraction beats the monolithic one at equal payload+time."""
-        mono = fleet.collective_table(_write_mono(tmp_path), [_HLO_MONO],
-                                      steps=1, probe=False)
-        buck = fleet.collective_table(_write_bucketed(tmp_path),
-                                      [_HLO_BUCKETED], steps=1,
-                                      probe=False)
+        mono = fleet.collective_table(
+            _write_mono(tmp_path), probe=False,
+            accounts=[xplane.hlo_instructions(_HLO_MONO)])
+        buck = fleet.collective_table(
+            _write_bucketed(tmp_path), probe=False,
+            accounts=[xplane.hlo_instructions(_HLO_BUCKETED)])
         sites = {r["site"] for r in buck["rows"]}
         assert {"dp_grad_bucket0", "dp_grad_bucket1"} <= sites
         es_m = fleet.exposed_summary(mono)

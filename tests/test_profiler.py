@@ -97,8 +97,10 @@ class TestXplaneRoundTrip:
     def test_host_plane_fallback_keeps_only_instructions(self, tmp_path):
         from paddle_tpu import xplane
         self._trace(tmp_path)
-        agg = xplane.aggregate_dir(str(tmp_path))
-        assert agg, "trace produced no aggregatable events"
+        steps = xplane.device_steps(str(tmp_path))
+        assert steps and all(s["host"] for s in steps), \
+            "trace produced no step"
+        agg = {e[0] for s in steps for e in s["events"]}
         # the fallback must admit only instruction-like names: the python
         # line's '$profiler.py:226 trace' event spans the whole session
         # and would otherwise dwarf every real instruction

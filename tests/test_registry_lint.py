@@ -58,6 +58,28 @@ def test_jit_lint_reads_real_source():
     assert "_jit_compile" in src and src.count("jax.jit(") == 1
 
 
+def test_one_parse_of_compiled_text_and_one_reader_of_a_trace():
+    """ISSUE 35: no regex over HLO text and no walk of the xplane wire
+    format outside paddle_tpu/xplane.py; the replaced readers are gone."""
+    assert _load_checker().check_one_parse() == []
+
+
+def test_one_parse_lint_catches_a_second_reader(tmp_path):
+    pkg = tmp_path / "paddle_tpu"
+    pkg.mkdir()
+    (pkg / "xplane.py").write_text("A = 'replica_groups'\n")   # its home
+    (pkg / "fleet.py").write_text(
+        "import re\nG = re.compile(r'replica_groups=')\n"
+        "def f(x):\n    return xplane.aggregate_dir(x)\n")
+    (pkg / "tool.py").write_text("for f in xplane.fields(b''):\n    pass\n")
+    found = _load_checker().check_one_parse(str(pkg))
+    assert {w for w, _ in found} == {"paddle_tpu/fleet.py",
+                                    "paddle_tpu/tool.py"}
+    assert any("removed reader 'aggregate_dir'" in m for _, m in found)
+    assert any("reads compiled text" in m for _, m in found)
+    assert any("wire format" in m for _, m in found)
+
+
 def test_sparse_table_consistent():
     """ISSUE 10 satellite: SPARSE_APPLY_OPS, the optimizer lowerings'
     SelectedRows branches, executor._SPARSE_AWARE_OPS and the

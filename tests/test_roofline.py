@@ -5,6 +5,7 @@ so the tests pin the parser and the report logic together without a
 device."""
 
 import numpy as np
+import pytest
 
 from paddle_tpu import roofline, xplane
 
@@ -139,16 +140,30 @@ class TestSyntheticReport:
     attribution join, the per-row verdicts against the ridge, the
     (unattributed) pool, and the telemetry gauges."""
 
-    HLO = """
-  %fusion.1 = f32[256,256] fusion(f32[256,256] %p0), kind=kOutput, metadata={op_name="jit(step)/pd.matmul/dot_general"}
-  %broadcast.7 = f32[256,256] broadcast(f32[] %c), metadata={op_name="jit(step)/pd.relu/max"}
+    # a module as Compiled.as_text() prints it: the matmul a fusion around
+    # a dot under pd_at.3/pd.matmul, the relu an elementwise maximum
+    HLO = """HloModule jit_step, is_scheduled=true
+
+%fused_computation (param_0: f32[256,256], param_1: f32[256,256]) -> f32[256,256] {
+  %param_0 = f32[256,256]{1,0} parameter(0)
+  %param_1 = f32[256,256]{1,0} parameter(1)
+  ROOT %dot.0 = f32[256,256]{1,0} dot(%param_0, %param_1), lhs_contracting_dims={1}, rhs_contracting_dims={0}
+}
+
+ENTRY %main (p0: f32[256,256], p1: f32[256,256]) -> f32[256,256] {
+  %p0 = f32[256,256]{1,0} parameter(0)
+  %p1 = f32[256,256]{1,0} parameter(1)
+  %fusion.1 = f32[256,256]{1,0} fusion(%p0, %p1), kind=kOutput, calls=%fused_computation, metadata={op_name="jit(step)/pd_at.3/pd_role.forward/pd.matmul/dot_general"}
+  %c = f32[256,256]{1,0} constant(0)
+  ROOT %maximum.7 = f32[256,256]{1,0} maximum(%fusion.1, %c), metadata={op_name="jit(step)/pd_at.4/pd_role.forward/pd.relu/max"}
+}
 """
 
     def _trace(self, tmp_path):
-        # fusion.1 appears on the raw line (40us) AND a derived line
-        # (40us again): dedup must keep 40, not 80. unknown.9 has no HLO
-        # mapping -> "(unattributed)".
-        metas = [_meta(1, "fusion.1"), _meta(2, "broadcast.7"),
+        # fusion.1 appears on the `XLA Ops` line (40us) AND a derived line
+        # (40us again): only the core's own line is read, 40 not 80.
+        # unknown.9 is no instruction of the block -> "(unattributed)".
+        metas = [_meta(1, "fusion.1"), _meta(2, "maximum.7"),
                  _meta(3, "unknown.9")]
         raw = _line("XLA Ops", 1000, [_event(1, 0, 40_000_000),
                                       _event(2, 40_000_000, 10_000_000),
@@ -157,7 +172,7 @@ class TestSyntheticReport:
         _write_xspace(tmp_path / "t.xplane.pb",
                       [_plane("/device:TPU:0", [raw, derived], metas)])
 
-    def _suppliers(self):
+    def _accounts(self):
         n = 256
         cost = {"ops": {
             "matmul": {"flops": 2.0 * n ** 3,
@@ -166,56 +181,74 @@ class TestSyntheticReport:
                      "bytes": 2.0 * n * n * 4, "count": 1}}}
         cost["total_flops"] = sum(d["flops"] for d in cost["ops"].values())
         cost["total_bytes"] = sum(d["bytes"] for d in cost["ops"].values())
-        return [(lambda: self.HLO, lambda: cost)]
+        instrs = xplane.compact(xplane.hlo_instructions(self.HLO))
+        return [(instrs, {"program": "p", "cost": lambda: cost,
+                          "xla_flops": 2.0 * n ** 3 + n * n})]
 
     def test_verdicts_and_unattributed_pool(self, tmp_path, monkeypatch):
         monkeypatch.setattr(roofline, "_PROBES", {})
         monkeypatch.setenv("PADDLE_TPU_SUSTAINED_TFLOPS", "0.5")
         monkeypatch.setenv("PADDLE_TPU_HBM_GBPS", "20")
         self._trace(tmp_path)
-        report = roofline.collect_report(str(tmp_path), self._suppliers(),
-                                         steps=2)
+        report = roofline.collect_report(str(tmp_path), steps=2,
+                                         accounts=self._accounts())
         assert report is not None and report["mapped"]
         rows = {r["op"]: r for r in report["rows"]}
         assert set(rows) == {"matmul", "relu", roofline.UNATTRIBUTED}
-        # dedup: 40us once, not the raw+derived 80us
+        # a row is an op INSTANCE: its position in the block
+        assert rows["matmul"]["at"] == 3 and rows["relu"]["at"] == 4
+        # the ops line alone: 40us once, not the raw+derived 80us
         assert rows["matmul"]["ps"] == 40_000_000
         # matmul intensity 2*256^3/(3*256^2*4) ~ 42.7 >= ridge 25
         assert rows["matmul"]["bound"] == "compute"
-        # relu intensity 256^2/(2*256^2*4) = 0.125 < 25
+        # relu intensity 256^2/(3*256^2*4) < 25 (it reads the constant too)
         assert rows["relu"]["bound"] == "memory"
         assert rows[roofline.UNATTRIBUTED]["bound"] == "unattributed"
         assert rows[roofline.UNATTRIBUTED]["flops"] is None
         assert abs(sum(r["frac"] for r in report["rows"]) - 1.0) < 1e-9
-        # achieved TF/s: flops * steps over the op's device time
+        # executed work from the account: the dot inside the fusion
         mm = rows["matmul"]
-        assert abs(mm["tflops"]
-                   - (mm["flops"] * 2) / (mm["ps"] / 1e12) / 1e12) < 1e-9
+        assert mm["flops"] == 2.0 * 256 ** 3
+        assert mm["required_flops"] == 2.0 * 256 ** 3
+        # achieved TF/s: the flops of its one run over its device time
+        assert abs(mm["tflops"] - mm["flops"] / (mm["ps"] / 1e12) / 1e12) \
+            < 1e-9
+        # floor share: max(flops / 0.5 TF/s, bytes / 20 GB/s) over 40us
+        floor_s = max(mm["flops"] / 0.5e12, mm["bytes"] / 20e9)
+        assert mm["efficiency"] == pytest.approx(floor_s / 40e-6)
+        cc = report["cost_crosscheck"]
+        assert cc["executed_rel_err"] == pytest.approx(0.0)
+        assert report["kernel_counts"] == {"modules": 1, "instructions": 2,
+                                        "fusions": 1}
 
     def test_format_report_and_top_ops(self, tmp_path, monkeypatch):
         monkeypatch.setattr(roofline, "_PROBES", {})
         monkeypatch.setenv("PADDLE_TPU_SUSTAINED_TFLOPS", "0.5")
         monkeypatch.setenv("PADDLE_TPU_HBM_GBPS", "20")
         self._trace(tmp_path)
-        report = roofline.collect_report(str(tmp_path), self._suppliers(),
-                                         steps=2)
+        report = roofline.collect_report(str(tmp_path), steps=2,
+                                         accounts=self._accounts())
         lines = roofline.format_report(report)
         device_rows = [ln for ln in lines if ln.startswith("[device] ")]
         assert device_rows[0].split()[1] == "matmul"
+        assert device_rows[0].split()[-1] == "@3"
         assert any(roofline.UNATTRIBUTED in ln for ln in device_rows)
         assert any(ln.startswith("[roofline]") for ln in lines)
+        assert any(ln.startswith("[crosscheck]") and "executed" in ln
+                   for ln in lines)
         top = roofline.top_ops(report, k=2)
         assert len(top) == 2 and top[0]["op"] == "matmul"
+        assert top[0]["at"] == 3
         assert top[0]["bound"] == "compute"
         assert top[0]["gflops"] == round(2.0 * 256 ** 3 / 1e9, 3)
 
-    def test_foreign_trace_without_suppliers_still_reports(self, tmp_path,
-                                                           monkeypatch):
+    def test_foreign_trace_without_accounts_still_reports(self, tmp_path,
+                                                          monkeypatch):
         monkeypatch.setattr(roofline, "_PROBES", {})
         monkeypatch.setenv("PADDLE_TPU_SUSTAINED_TFLOPS", "0.5")
         monkeypatch.setenv("PADDLE_TPU_HBM_GBPS", "20")
         self._trace(tmp_path)
-        report = roofline.collect_report(str(tmp_path), ())
+        report = roofline.collect_report(str(tmp_path), accounts=[])
         assert report is not None and not report["mapped"]
         assert all(r["bound"] == "unattributed" for r in report["rows"])
 
@@ -260,15 +293,16 @@ class TestWaterfall:
         assert wf["device_duty_cycle"] == 1.0
 
 
-class TestAggregateDedup:
-    def test_device_plane_max_across_lines_then_sum_across_planes(
-            self, tmp_path):
+class TestOpsLineAlone:
+    def test_a_step_per_core_and_the_derived_line_unread(self, tmp_path):
         metas = [_meta(1, "fusion.1")]
         raw = _line("XLA Ops", 0, [_event(1, 0, 10)])
         derived = _line("Steps", 0, [_event(1, 0, 7)])
         p0 = _plane("/device:TPU:0", [raw, derived], metas)
         p1 = _plane("/device:TPU:1", [raw], metas)
         _write_xspace(tmp_path / "t.xplane.pb", [p0, p1])
-        agg = xplane.aggregate_dir(str(tmp_path))
-        # per plane: max(10, 7) = 10; across planes: 10 + 10
-        assert agg == {"fusion.1": 20}
+        account = xplane.step_account(str(tmp_path), accounts=[])
+        # per core the ops line's 10, never 10 + 7; per-core time adds up
+        assert [(s["device"], s["busy_ms"]) for s in account["steps"]] == [
+            ("/device:TPU:0", pytest.approx(10e-9)),
+            ("/device:TPU:1", pytest.approx(10e-9))]

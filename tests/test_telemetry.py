@@ -459,12 +459,12 @@ def _vi(fno, val):
     return _varint(fno << 3) + _varint(val)
 
 
-def _xevent(mid, ps):
-    return _ld(4, _vi(1, mid) + _vi(3, ps))   # XLine.events=4
+def _xevent(mid, ps, off=0):
+    return _ld(4, _vi(1, mid) + _vi(2, off) + _vi(3, ps))   # XLine.events=4
 
 
-def _xline(events):
-    return b"".join(events)
+def _xline(events, name=None):
+    return (_ld(2, name.encode()) if name else b"") + b"".join(events)
 
 
 def _xplane(name, lines, meta):
@@ -484,18 +484,26 @@ class TestXplaneAggregation:
         (d / "host.xplane.pb").write_bytes(b"".join(planes))
         return str(d)
 
-    def test_device_planes_dedup_derived_lines(self, tmp_path):
+    def test_device_planes_read_the_ops_line_alone(self, tmp_path):
         from paddle_tpu import xplane
         meta = {1: "fusion.1", 2: "copy.2"}
-        # raw XLA-op line + a derived step line repeating the instruction:
-        # per-name MAX across lines, not the double-counted sum
-        raw = _xline([_xevent(1, 100), _xevent(2, 30)])
-        derived = _xline([_xevent(1, 100)])
+        # the core's own `XLA Ops` line + a derived step line repeating
+        # the instruction: counted once, never from the derived line
+        raw = _xline([_xevent(1, 100), _xevent(2, 30, off=100)], "XLA Ops")
+        derived = _xline([_xevent(1, 100)], "Steps")
         dev0 = _xplane("/device:TPU:0", [raw, derived], meta)
         dev1 = _xplane("/device:TPU:1", [raw], meta)
         host = _xplane("/host:CPU", [_xline([_xevent(1, 999)])], meta)
-        agg = xplane.aggregate_dir(self._write(tmp_path, [dev0, dev1, host]))
-        assert agg == {"fusion.1": 200, "copy.2": 60}   # summed per core
+        trace = self._write(tmp_path, [dev0, dev1, host])
+        steps = xplane.device_steps(trace)
+        assert [s["device"] for s in steps] == ["/device:TPU:0",
+                                                "/device:TPU:1"]
+        for step in steps:      # a step per core, the host plane unread
+            assert [(e[0], e[2]) for e in step["events"]] == [
+                ("fusion.1", 100), ("copy.2", 30)]
+        account = xplane.step_account(trace, accounts=[])
+        assert sum(r["ms"] for s in account["steps"] for r in s["rows"]
+                   if r["name"] == "fusion.1") == pytest.approx(200e-9)
 
     def test_host_only_trace_falls_back(self, tmp_path):
         from paddle_tpu import xplane
@@ -503,12 +511,14 @@ class TestXplaneAggregation:
         host = _xplane("/host:CPU",
                        [_xline([_xevent(1, 10)]), _xline([_xevent(1, 5)])],
                        meta)
-        agg = xplane.aggregate_dir(self._write(tmp_path, [host]))
-        # host fallback applies the SAME per-name max-across-lines dedup
-        # as device planes (derived lines double-count there too)
-        assert agg == {"op.a": 10}
+        steps = xplane.device_steps(self._write(tmp_path, [host]))
+        # no device plane (the CPU backend): every host line that holds
+        # instruction-like events is a "device" of its own, marked so
+        assert [(s["host"], [(e[0], e[2]) for e in s["events"]])
+                for s in steps] == [(True, [("op.a", 10)]),
+                                    (True, [("op.a", 5)])]
 
-    def test_aggregate_lines_per_line_view(self, tmp_path):
+    def test_plane_events_per_line_view(self, tmp_path):
         from paddle_tpu import xplane
         meta = {1: "op.a"}
         plane = _xplane("/device:TPU:0",
@@ -516,8 +526,9 @@ class TestXplaneAggregation:
                         meta)
         d = self._write(tmp_path, [plane])
         (path,) = [os.path.join(d, f) for f in os.listdir(d)]
-        per = xplane.aggregate_lines(path)["/device:TPU:0"]
-        assert [la.get("op.a") for la in per] == [10, 7]
+        per = xplane.plane_events(path)["/device:TPU:0"]
+        assert [ln["events"] for ln in per] == [[("op.a", 0, 10)],
+                                                [("op.a", 0, 7)]]
 
 
 # --- satellite regression tests ----------------------------------------------

@@ -57,7 +57,10 @@ training-only op (optimizer / `_grad` / fused-optimizer) — a leak here
 means prune kept a training subgraph and serving would mutate weights.
 """
 
+import os
 import sys
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def check_tables():
@@ -118,6 +121,52 @@ def check_collective_kinds():
             problems.append((
                 "xplane._BUSBW_FACTOR",
                 f"factor for unknown kind '{kind}'"))
+    return problems
+
+
+# what only paddle_tpu/xplane.py may hold: the names of an HLO
+# instruction's attributes (a regex over compiled text reaches for them)
+# and the walk of the xplane wire format
+_HLO_TEXT_MARKERS = ("replica_groups", "op_name=", "custom_call_target",
+                     "dim_labels", "lhs_contracting_dims")
+_GONE_READERS = ("aggregate_dir", "collective_events_dir",
+                 "hlo_participants", "hlo_counts", "_hlo_supplier",
+                 "hlo_op_names", "hlo_collectives", "aggregate_lines",
+                 "register_hlo_supplier", "consume_suppliers")
+
+
+def check_one_parse(root=None):
+    """[(where, message), ...] — in the package, one function parses
+    compiled text (xplane.hlo_instructions) and one reads a trace
+    (xplane.device_steps): no other file names an HLO attribute or walks
+    the wire format (`xplane.fields`), and the readers they replaced do
+    not come back."""
+    root = root or os.path.join(REPO, "paddle_tpu")
+    problems = []
+    for dirpath, _, files in os.walk(root):
+        for fname in files:
+            if not fname.endswith(".py"):
+                continue
+            path = os.path.join(dirpath, fname)
+            rel = os.path.relpath(path, os.path.dirname(root))
+            with open(path) as f:
+                text = f.read()
+            for gone in _GONE_READERS:
+                if gone in text:
+                    problems.append((rel, f"names the removed reader "
+                                          f"'{gone}'"))
+            if os.path.basename(path) == "xplane.py" \
+                    and os.path.dirname(path) == root:
+                continue
+            for marker in _HLO_TEXT_MARKERS:
+                if marker in text:
+                    problems.append((
+                        rel, f"reads compiled text for itself ('{marker}'): "
+                             f"xplane.hlo_instructions is the one parse"))
+            if "xplane.fields" in text or "xplane_mod.fields" in text:
+                problems.append((
+                    rel, "walks the xplane wire format: "
+                         "xplane.device_steps is the one reader"))
     return problems
 
 
@@ -978,7 +1027,7 @@ def main():
     coll = check_collective_kinds()
     for where, msg in coll:
         print(f"{where}: {msg}")
-    jit = check_jit_sites()
+    jit = check_jit_sites() + check_one_parse()
     for where, msg in jit:
         print(f"{where}: {msg}")
     sparse = check_sparse_table()

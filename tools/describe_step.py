@@ -2,7 +2,7 @@
 what the chip's compiler makes of it, without the chip.
 
     JAX_PLATFORMS=cpu python3 tools/describe_step.py <cell> [--wider N]
-        [--set key=value ...] [--hlo FILE]
+        [--set key=value ...] [--hlo FILE] [--account [TOP]]
 
 Builds the cell's program from its benchmark files (`benchmarks/` is read,
 never written), traces the step as Executor.run would (state donated, the
@@ -13,14 +13,16 @@ than N elements (default 2**28), widest first, with its `op_name`: the
 buffers that a memory-bound op's traffic is made of. `--set n_layer=1`
 overrides a key of the configuration (a depth, to read one layer fast);
 `--hlo FILE` keeps the optimized HLO, to read who consumes a buffer.
-Nothing runs, so no time comes from here (PERF.md section 3).
+`--account` prints instead the step's account by instruction
+(`paddle_tpu.xplane.hlo_instructions`): FLOPs, bytes and the least a v5e
+could take for each, and their sum, a chipless lower bound of the step
+to set against the ledger's busy time. Nothing runs, so no time comes
+from here (PERF.md section 3).
 """
 
 import argparse
 import json
-import math
 import os
-import re
 import sys
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -29,7 +31,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 import numpy as np
 
-SHAPE = re.compile(r"= \(?(\w+)\[([\d,]+)\](?:\{([\d,]+))?")
 
 
 def described_chip():
@@ -79,23 +80,66 @@ def compile_program(main, startup, loss, feed, chip):
 
 
 def wide_instructions(text, wider):
-    """(elements, dtype[shape], instruction name, op_name) of the entry
-    computation's instructions that write more than `wider` elements."""
-    entry = text[text.index("\nENTRY "):]
+    """(elements, dtype[shape]{layout}, instruction name, op_name) of the
+    entry computation's instructions that write more than `wider`
+    elements: a read of the one parse (`xplane.hlo_instructions`)."""
+    from paddle_tpu import xplane
+
     rows = []
-    for line in entry.splitlines()[1:]:
-        shape = SHAPE.search(line)
-        if shape is None or "parameter(" in line:
+    for instr in xplane.hlo_instructions(text):
+        if not instr.entry or instr.opcode == "parameter":
             continue
-        dtype, dims, layout = shape.groups()
-        elements = math.prod(int(n) for n in dims.split(","))
+        elements, shape = xplane.first_array(instr.shape)
         if elements > wider:
-            op_name = re.search(r'op_name="([^"]*)"', line)
-            rows.append((elements, "%s[%s]" % (dtype, dims)
-                         + ("{%s}" % layout if layout else ""),
-                         line.split("=")[0].strip().removeprefix("ROOT "),
-                         op_name.group(1) if op_name else ""))
+            rows.append((elements, shape, "%" + instr.name, instr.op_name))
     return sorted(rows, reverse=True)
+
+
+def account_rows(text):
+    """[(floor ms, instruction)] of the compiled step for a v5e with no
+    chip: every instruction of the entry computation that takes time,
+    with the least it could take, max(flops / peak, bytes / hbm) from
+    chip.py's table; an async half and a Mosaic call have none here."""
+    from paddle_tpu import chip, xplane
+
+    row = chip.PEAKS["TPU v5 lite"]
+    peak, hbm = row.bf16_tflops * 1e12, row.hbm_gbps * 1e9
+    rows = []
+    for instr in xplane.compact(xplane.hlo_instructions(text)):
+        if not instr.entry:
+            continue
+        floor = xplane.floor_seconds(instr, peak, hbm)
+        rows.append((1e3 * floor[0] if floor else 0.0, instr))
+    return rows
+
+
+def print_account(text, top):
+    from paddle_tpu import xplane
+
+    rows = account_rows(text)
+    by_heavy = {}
+    for floor, i in rows:
+        acc = by_heavy.setdefault(i.heavy, [0, 0.0, 0, 0.0])
+        acc[0] += 1
+        acc[1] += i.flops or 0.0
+        acc[2] += i.bytes
+        acc[3] += floor
+    print("%-22s %6s %12s %12s %10s" % ("heavy", "count", "GFLOP", "MB",
+                                        "floor ms"))
+    for heavy, (n, flops, nbytes, floor) in sorted(
+            by_heavy.items(), key=lambda kv: -kv[1][3]):
+        print("%-22s %6d %12.2f %12.1f %10.3f" % (heavy, n, flops / 1e9,
+                                                  nbytes / 1e6, floor))
+    print("%-22s %6d %12.2f %12.1f %10.3f" % (
+        "sum", len(rows), sum(i.flops or 0.0 for _, i in rows) / 1e9,
+        sum(i.bytes for _, i in rows) / 1e6, sum(f for f, _ in rows)))
+    print("\n%9s %10s %10s  %-12s %-28s %-6s %s" % (
+        "floor ms", "GFLOP", "MB", "heavy", "instruction", "at", "op"))
+    for floor, i in sorted(rows, key=lambda r: -r[0])[:top]:
+        print("%9.4f %10.3f %10.2f  %-12s %-28s %-6s %s/%s %s" % (
+            floor, (i.flops or 0.0) / 1e9, i.bytes / 1e6, i.heavy[:12],
+            i.name[:28], "-" if i.at is None else i.at, i.role, i.op,
+            i.detail or xplane._plain(i.shape)))
 
 
 def main(argv=None):
@@ -105,6 +149,11 @@ def main(argv=None):
     ap.add_argument("--wider", type=int, default=2 ** 28)
     ap.add_argument("--set", action="append", default=[], metavar="key=value")
     ap.add_argument("--hlo", metavar="FILE")
+    ap.add_argument("--account", nargs="?", type=int, const=40, default=None,
+                    metavar="TOP", help="the step's account by instruction "
+                    "for a v5e: FLOPs, bytes and floor ms by what does the "
+                    "work, their sum (a chipless lower bound of the step) "
+                    "and the TOP instructions by floor")
     args = ap.parse_args(argv)
     cell = run.load_json("workloads", args.cell)
     if cell["chips"] != 1:
@@ -126,6 +175,9 @@ def main(argv=None):
         "alias_bytes": mem.alias_size_in_bytes,
         "output_bytes": mem.output_size_in_bytes,
         "mosaic_calls": text.count('custom_call_target="tpu_custom_call"')}))
+    if args.account is not None:
+        print_account(text, args.account)
+        return
     for elements, shape, name, op_name in wide_instructions(text, args.wider):
         print("%14d  %-34s %-40s %s" % (elements, shape, name, op_name))
 

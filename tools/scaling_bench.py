@@ -525,7 +525,7 @@ def _perf_fields(run_one):
             return {}
         out = {"top_ops": roofline.top_ops(report),
                "device_duty_cycle": report.get("device_duty_cycle")}
-        hc = report.get("hlo_counts")
+        hc = report.get("kernel_counts")
         if hc:
             out["hlo_instructions"] = hc["instructions"]
             out["hlo_fusions"] = hc["fusions"]

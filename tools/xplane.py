@@ -6,17 +6,19 @@ Usage: python tools/xplane.py <trace_dir_or_file> [top_n]
        python tools/xplane.py --timeline <trace_dir_or_file> [max_events]
        python tools/xplane.py --collectives <trace_dir> [top_n]
 
-The default view aggregates per-op totals; --timeline prints each line's
-events in execution order (XLine.timestamp_ns anchor + XEvent.offset_ps),
-the raw view behind the profiler's step-time waterfall; --collectives
-prints the collective events only — kind, total ms and exposed ms (time
-not hidden under concurrent compute), summed per kind at the end — the
-stdlib view behind `python -m paddle_tpu fleet`.
+The default view sums the device's own timeline (`xplane.device_steps`:
+the `XLA Ops` line, never the derived lines) per core by instruction and
+by kind; --timeline prints each line's events in execution order
+(XLine.timestamp_ns anchor + XEvent.offset_ps), the raw view behind the
+profiler's step-time waterfall; --collectives prints the collective rows
+of the trace's account (`xplane.step_account`, joined to the account an
+earlier reader saved beside the trace, if any) — kind, mesh axis, total
+and exposed ms, bus bandwidth — summed per kind at the end: the stdlib
+view behind `python -m paddle_tpu fleet`.
 """
 
 from __future__ import annotations
 
-import glob
 import importlib.util
 import os
 import sys
@@ -30,7 +32,7 @@ _spec = importlib.util.spec_from_file_location("_xplane_standalone",
                                                _xp_path)
 _xplane = importlib.util.module_from_spec(_spec)
 _spec.loader.exec_module(_xplane)
-aggregate, category = _xplane.aggregate, _xplane.category
+category = _xplane.category
 
 
 def timeline(target, limit):
@@ -55,23 +57,33 @@ def timeline(target, limit):
 
 
 def collectives(target, limit):
-    evs = _xplane.collective_events_dir(target)
-    if not evs:
+    account = _xplane.step_account(target)
+    rows = {}
+    for step in (account or {}).get("steps", ()):
+        for r in step["rows"]:
+            if r["kind"]:
+                acc = rows.setdefault(r["name"], dict(r, ms=0.0,
+                                                      exposed_ms=0.0))
+                acc["ms"] += r["ms"]
+                acc["exposed_ms"] += r["exposed_ms"]
+    if not rows:
         print("(no collective events)")
         return
     by_kind = {}
-    rows = sorted(evs.items(), key=lambda kv: -kv[1]["total_ps"])
-    print(f"{'total ms':>10s} {'exposed ms':>11s}  kind / event")
-    for name, rec in rows[:limit]:
-        print(f"{rec['total_ps'] / 1e9:10.3f} "
-              f"{rec['exposed_ps'] / 1e9:11.3f}  "
-              f"{rec['kind']:18s} {name[:80]}")
-        agg = by_kind.setdefault(rec["kind"], [0, 0])
-        agg[0] += rec["total_ps"]
-        agg[1] += rec["exposed_ps"]
+    print(f"{'total ms':>10s} {'exposed ms':>11s} {'busbw GB/s':>11s}  "
+          f"kind / axis / event")
+    for name, rec in sorted(rows.items(), key=lambda kv: -kv[1]["ms"])[:limit]:
+        bus = ("%11.2f" % rec["busbw_gbps"]) if rec["busbw_gbps"] else \
+            "          -"
+        print(f"{rec['ms']:10.3f} {rec['exposed_ms']:11.3f} {bus}  "
+              f"{rec['kind']:18s} {rec['axis'] or '-':8s} {name[:70]}")
+    for rec in rows.values():
+        agg = by_kind.setdefault(rec["kind"], [0.0, 0.0])
+        agg[0] += rec["ms"]
+        agg[1] += rec["exposed_ms"]
     for kind, (tot, exp) in sorted(by_kind.items(), key=lambda kv: -kv[1][0]):
-        print(f"[kind] {kind:18s} {tot / 1e9:10.3f} ms total, "
-              f"{exp / 1e9:.3f} ms exposed")
+        print(f"[kind] {kind:18s} {tot:10.3f} ms total, "
+              f"{exp:.3f} ms exposed")
 
 
 def main():
@@ -90,26 +102,24 @@ def main():
     if want_timeline:
         timeline(target, top)
         return
-    if os.path.isdir(target):
-        paths = glob.glob(os.path.join(target, "**", "*.xplane.pb"),
-                          recursive=True)
-    else:
-        paths = [target]
-    for p in paths:
-        print(f"== {p}")
-        for pname, agg in aggregate(p).items():
-            total = sum(agg.values())
-            if not total:
-                continue
-            print(f"-- plane '{pname}': sum {total / 1e9:.2f} ms")
-            cats = {}
-            for name, ps in agg.items():
-                c = category(name)
-                cats[c] = cats.get(c, 0) + ps
-            for c, ps in sorted(cats.items(), key=lambda kv: -kv[1])[:15]:
-                print(f"   [cat] {ps / 1e9:10.2f} ms  {c}")
-            for name, ps in sorted(agg.items(), key=lambda kv: -kv[1])[:top]:
-                print(f"   {ps / 1e9:10.2f} ms  {name[:110]}")
+    per_device = {}
+    for step in _xplane.device_steps(target):
+        agg = per_device.setdefault(step["device"], {})
+        for name, ps in _xplane._self_ps(step["events"]).items():
+            agg[name] = agg.get(name, 0) + ps
+    for pname, agg in per_device.items():
+        total = sum(agg.values())
+        if not total:
+            continue
+        print(f"-- '{pname}': sum {total / 1e9:.2f} ms")
+        cats = {}
+        for name, ps in agg.items():
+            c = category(name)
+            cats[c] = cats.get(c, 0) + ps
+        for c, ps in sorted(cats.items(), key=lambda kv: -kv[1])[:15]:
+            print(f"   [cat] {ps / 1e9:10.2f} ms  {c}")
+        for name, ps in sorted(agg.items(), key=lambda kv: -kv[1])[:top]:
+            print(f"   {ps / 1e9:10.2f} ms  {name[:110]}")
 
 
 if __name__ == "__main__":
