@@ -1269,8 +1269,9 @@ def moe_block(x, num_experts, top_k, expert_width, shared_width=0,
     no token dropped, static shapes, a grouped product over the rows
     actually routed here); the shared expert is applied to every token.
     `stats`: a list that receives this layer's (rows routed to held
-    experts, rows combined, busiest held expert over their mean)
-    Variables, each [1]."""
+    experts, rows combined, busiest held expert over their mean, rows
+    handled: the capacity the step's routed rows were given) Variables,
+    each [1]."""
     from ..param_attr import ParamAttr
     helper = LayerHelper("moe_block", name=name)
     seqlen, d_model = int(x.shape[1]), int(x.shape[2])
@@ -1306,18 +1307,19 @@ def moe_block(x, num_experts, top_k, expert_width, shared_width=0,
         attr=None, shape=[held, expert_width, d_model], dtype=dtype,
         default_initializer=NormalInitializer(scale=out_scale))
     routed = helper.create_tmp_variable(dtype)
-    rows, combined, load = (
+    rows, combined, load, handled = (
         helper.create_tmp_variable("float32", stop_gradient=True)
-        for _ in range(3))
+        for _ in range(4))
     helper.append_op(type="moe_experts",
                      inputs=dict(inputs, W1=[up], W2=[down]),
                      outputs={"Out": [routed], "RowsRouted": [rows],
                               "RowsCombined": [combined],
-                              "LoadMaxOverMean": [load]},
+                              "LoadMaxOverMean": [load],
+                              "RowsHandled": [handled]},
                      attrs={"num_experts": num_experts, "experts_held": held,
                             "expert_offset": expert_offset, "top_k": top_k})
     if stats is not None:
-        stats.append((rows, combined, load))
+        stats.append((rows, combined, load, handled))
     out = reshape(routed, [-1, seqlen, d_model])
     if shared_width and gated:
         out = elementwise_add(out, gated_mlp(x, shared_width,

@@ -20,7 +20,7 @@ def build_image_classifier(model_fn, images, label, class_dim=1000, **kwargs):
 # telemetry side-fetches of a model with expert layers: one series per
 # expert layer (label `layer` = its index among them), a sample a step
 SIDE_METRICS = ("moe_rows_routed", "moe_rows_combined",
-                "moe_load_max_over_mean")
+                "moe_load_max_over_mean", "moe_rows_handled")
 
 
 def side_fetch_marks(program):
@@ -35,8 +35,8 @@ def side_fetch_marks(program):
 
 def mark_routing_stats(program, stats):
     """Side-fetch what layers.moe_block left in `stats`, one (rows
-    routed, rows combined, load) triple an expert layer, as SIDE_METRICS:
-    each a vector over the expert layers."""
+    routed, rows combined, load, rows handled) tuple an expert layer, as
+    SIDE_METRICS: each a vector over the expert layers."""
     for metric, by_layer in zip(SIDE_METRICS, zip(*stats)):
         side_fetch_marks(program)[metric] = layers.concat(
             list(by_layer), axis=0).name

@@ -17,11 +17,17 @@ lowering, whose re-traced forward XLA merges with the original). The
 scan's core is a jax.checkpoint, so its backward keeps the op's inputs
 and the chunk states, and recomputes the [chunk, chunk] decay and score
 blocks instead of holding them per layer; the expert layer's grouped
-products are Pallas calls, which XLA does not merge, so its backward
-runs the forward's two products (three when gated) once more (routed
-rows are top_k * held / experts of a token's, a few per cent of the
-step's arithmetic). The rotation is linear in X and its generic gradient
-is the rotation by the opposite angle.
+products are Pallas calls, whose re-traced forward the chip's compiler
+merges with the original too (16 gmm runs a step of the hybrid cell, not
+24; PERF.md section 6, PR 36). A layer that holds a sixteenth of the
+experts or less handles its rows inside a capacity chosen on the device
+(_capacity_ladder) and carries a rule of its own inside the lowering
+(_handle_routed_rows, a jax.custom_vjp as nn_ops._hard_label_nll is),
+which the generic gradient differentiates through: the backward chooses
+the same branch, runs that branch's forward again inside it (a
+conditional is a wall to the merging) and keeps nothing of a branch's
+size between the two. The rotation is linear in X and its generic
+gradient is the rotation by the opposite angle.
 """
 
 from __future__ import annotations
@@ -270,18 +276,18 @@ def _gmm_tiling(d: int, f: int, dtype):
     return (_GMM_ROWS, min(max(d, f), cap), min(d, f, 512))
 
 
-def _grouped_products(rows, w1, w2, sizes, use_gmm, gate=None):
+def _grouped_products(rows, w1, w2, sizes, kernel, gate=None):
     """relu(rows W1[e])^2 W2[e] for the rows of each group e (`sizes` rows
     each, in order; rows past their sum come back undefined); with `gate`
     (silu(rows Gate[e]) * (rows W1[e])) W2[e], the activation in
-    float32."""
-    if use_gmm:
+    float32. `kernel`: None for lax.ragged_dot, else megablox gmm with
+    `interpret=kernel`."""
+    if kernel is not None:
         from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
-        from .pallas_attention import _interpret
         dot = functools.partial(
             megablox.gmm, group_sizes=sizes, preferred_element_type=rows.dtype,
             tiling=_gmm_tiling(w1.shape[1], w1.shape[2], rows.dtype),
-            interpret=_interpret())
+            interpret=kernel)
     else:
         dot = functools.partial(lax.ragged_dot, group_sizes=sizes)
     with jax.named_scope("moe_up"):
@@ -298,9 +304,113 @@ def _grouped_products(rows, w1, w2, sizes, use_gmm, gate=None):
 
 def _experts_infer(op_, block):
     same_as_input()(op_, block)
-    set_out(op_, block, "RowsRouted", [1], "float32")
-    set_out(op_, block, "RowsCombined", [1], "float32")
-    set_out(op_, block, "LoadMaxOverMean", [1], "float32")
+    for slot in ("RowsRouted", "RowsCombined", "LoadMaxOverMean",
+                 "RowsHandled"):
+        set_out(op_, block, slot, [1], "float32")
+
+
+def _capacity_ladder(pairs: int, held: int, num_experts: int):
+    """The row counts the expert layer may handle, ascending: `pairs` =
+    N x top_k (every pair: nothing is ever dropped) and before it the
+    smallest halving of `pairs` that still holds four times the share a
+    uniform router sends here, pairs * held / num_experts, and tiles as
+    `pairs` does (so gmm_ineligible answers the same for both), if that
+    is at most a quarter of the pairs. A layer that holds more than a
+    sixteenth of the experts has the one rung.
+
+    Two rungs, the factor four and the quarter are what the chip allowed
+    (PERF.md section 6, PR 36): every rung is a branch of the forward and
+    of the gradient to trace, lower and load, 3.4 to 5 s of a cell's
+    set-up each; and the last rung costs a fifth more than the layer
+    without a switch (its gradient runs the forward again inside its
+    branch, where XLA merges the re-traced forward with the original), so
+    the rung before it has to hold a router two or three times off
+    balance and to save more than half when it does."""
+    rung = pairs
+    while (rung % 2 == 0 and rung // 2 * num_experts >= 4 * pairs * held
+           and (rung // 2 % _GMM_ROWS == 0) == (pairs % _GMM_ROWS == 0)):
+        rung //= 2
+    return (rung, pairs) if 4 * rung <= pairs else (pairs,)
+
+
+def _handle_rows(capacity, kernel, order, sizes, x, weight, w1, w2, gate):
+    """The layer over the first `capacity` pairs of `order` (the routed
+    ones, which the caller knows to be no more, then dead ones): their
+    tokens' rows gathered, the grouped products, the weights, the
+    scatter-add back into [N, D]. -> (Out, the live rows it combined)."""
+    head = order[:capacity]
+    token = head // weight.shape[1]
+    live = (jnp.arange(capacity) < sizes.sum())[:, None]
+    rows = jnp.where(live, x[token].astype(w1.dtype), 0)
+    out = _grouped_products(rows, w1, w2, sizes, kernel, gate)
+    # the kernel leaves rows past the routed ones undefined: select before
+    # weighting, so that no gradient is a product with them either
+    out = jnp.where(live, _f32(out), 0) * weight.reshape(-1)[head][:, None]
+    out = jnp.zeros(x.shape, jnp.float32).at[token].add(out)
+    return out.astype(x.dtype), live.sum()
+
+
+# One function object a (capacity, kernel): jax keeps a switch branch's
+# jaxpr by the function traced and its avals, so a model's expert layers,
+# and the forward that the gradient op traces again, trace a rung once
+# (the hybrid cell's step holds 12 such switches of one shape).
+@functools.lru_cache(maxsize=None)
+def _rung(capacity, kernel):
+    return functools.partial(_handle_rows, capacity, kernel)
+
+
+@functools.lru_cache(maxsize=None)
+def _rung_pulled_back(capacity, kernel):
+    def branch(order, sizes, ct, *operands):
+        _, vjp, _ = jax.vjp(functools.partial(
+            _handle_rows, capacity, kernel, order, sizes), *operands,
+            has_aux=True)
+        return vjp(ct)
+    return branch
+
+
+def _cast(mats, dtype):
+    return tuple(None if m is None else m.astype(dtype) for m in mats)
+
+
+def _switch_rows(rungs, kernel, dtype, rung, order, sizes, x, weight, *mats):
+    """_handle_rows at the capacity rungs[rung], one branch a rung; the
+    matrices' casts to `dtype` are operands of the switch, once, not work
+    of each branch."""
+    return lax.switch(rung, [_rung(c, kernel) for c in rungs],
+                      order, sizes, x, weight, *_cast(mats, dtype))
+
+
+# _switch_rows under a rule of the op's own: autodiff through lax.switch
+# keeps every branch's residuals, zero-filled for the branches not taken,
+# a write of [N x top_k, D] a layer whatever was routed. Here the forward
+# keeps its inputs (the layer's own, so nothing new lives from the forward
+# to the backward) and the backward is one switch whose branch takes the
+# gradient of its own forward and pulls back inside it, so no residual
+# leaves a branch (the forward products run again there).
+_handle_routed_rows = jax.custom_vjp(_switch_rows, nondiff_argnums=(0, 1, 2))
+
+
+def _handle_routed_rows_fwd(rungs, kernel, dtype, *args):
+    return _switch_rows(rungs, kernel, dtype, *args), args
+
+
+def _handle_routed_rows_bwd(rungs, kernel, dtype, args, cts):
+    rung, order, sizes, x, weight, *mats = args
+    d_x, d_weight, *d_mats = lax.switch(
+        rung, [_rung_pulled_back(c, kernel) for c in rungs], order, sizes,
+        cts[0], x, weight, *_cast(mats, dtype))
+    # barrier: the matrices' gradients leave the switch in the compute
+    # dtype and wait for the optimizer at the step's end. Unpinned, XLA
+    # moves their casts to the parameters' float32 into every branch, and
+    # five gated layers then hold 1.5 GB of float32 gradients where the
+    # layer without a switch held half (the cast fuses into Adam's update)
+    d_mats = lax.optimization_barrier(d_mats)
+    return (None, None, None, d_x, d_weight) + tuple(
+        None if m is None else d.astype(m.dtype) for d, m in zip(d_mats, mats))
+
+
+_handle_routed_rows.defvjp(_handle_routed_rows_fwd, _handle_routed_rows_bwd)
 
 
 @op("moe_experts", infer_shape=_experts_infer, non_diff_inputs=("TopkIdx",))
@@ -322,23 +432,31 @@ def _moe_experts(ctx, op_, ins):
     shape does not tile, booked with the reason). The rows then return to
     their tokens weighted, by a scatter-add.
 
+    The rows gathered, multiplied and scattered are the first C pairs of
+    that order, C the smallest rung of _capacity_ladder that holds the
+    step's routed pairs, chosen on the device (lax.switch, one branch a
+    rung, in the gradient too); the last rung is all N x top_k, so no
+    step drops a row, and a layer that holds every expert has that rung
+    alone and no conditional.
+
     RowsRouted [1]: the pairs the router sent to held experts, counted
     on its indices; RowsCombined [1]: the rows the grouped product was
     given and the scatter-add returned, counted where they are combined
     (the two differ only if a row is lost between them); LoadMaxOverMean
-    [1]: the busiest held expert's rows over the held experts' mean."""
+    [1]: the busiest held expert's rows over the held experts' mean;
+    RowsHandled [1]: the rung taken."""
     from . import pallas_conv
+    from .pallas_attention import _interpret
     from .. import quant
 
     x = jnp.asarray(ins["X"][0])
     idx = jnp.asarray(ins["TopkIdx"][0])
     weight = _f32(ins["TopkWeight"][0])
     dtype = _compute_dtype(ctx)
-    w1 = jnp.asarray(ins["W1"][0]).astype(dtype)
-    w2 = jnp.asarray(ins["W2"][0]).astype(dtype)
+    w1, w2 = jnp.asarray(ins["W1"][0]), jnp.asarray(ins["W2"][0])
     gate = None
     if ins.get("WGate") and ins["WGate"][0] is not None:
-        gate = jnp.asarray(ins["WGate"][0]).astype(dtype)
+        gate = jnp.asarray(ins["WGate"][0])
     held = op_.attr("experts_held", w1.shape[0])
     n, k = idx.shape
     assert w1.shape[0] == held and k == op_.attr("top_k", k)
@@ -348,8 +466,6 @@ def _moe_experts(ctx, op_, ins):
     order = jnp.argsort(group, stable=True)           # held experts first
     sizes = jnp.bincount(group, length=held + 1)[:held].astype(jnp.int32)
     routed = sizes.sum()
-    live = (jnp.arange(n * k) < routed)[:, None]
-    token = order // k
 
     reason = gmm_ineligible(n * k, x.shape[-1], w1.shape[-1])
     if quant.counters_suppressed():   # the grad op's re-trace books nothing
@@ -358,15 +474,19 @@ def _moe_experts(ctx, op_, ins):
         pallas_conv.count_hit(_GMM_OP)
     else:
         pallas_conv.count_fallback(_GMM_OP, reason)
-    rows = jnp.where(live, x[token].astype(dtype), 0)
-    out = _grouped_products(rows, w1, w2, sizes, reason is None, gate)
-    # the kernel leaves rows past the routed ones undefined: select before
-    # weighting, so that no gradient is a product with them either
-    out = jnp.where(live, _f32(out), 0) * weight.reshape(-1)[order][:, None]
-    out = jnp.zeros(x.shape, jnp.float32).at[token].add(out)
+    kernel = _interpret() if reason is None else None
+    rungs = _capacity_ladder(n * k, held, op_.attr("num_experts", held))
+    rung = (routed > jnp.asarray(rungs[:-1], jnp.int32)).sum()
+    if len(rungs) == 1:    # no conditional and no gradient rule of its own
+        out, combined = _handle_rows(rungs[0], kernel, order, sizes, x,
+                                     weight, *_cast((w1, w2, gate), dtype))
+    else:
+        out, combined = _handle_routed_rows(rungs, kernel, dtype, rung, order,
+                                            sizes, x, weight, w1, w2, gate)
 
     load = sizes.max() / jnp.maximum(routed / held, 1.0)
-    return {"Out": [out.astype(x.dtype)],
+    return {"Out": [out],
             "RowsRouted": [_f32((group < held).sum()).reshape(1)],
-            "RowsCombined": [_f32(live.sum()).reshape(1)],
-            "LoadMaxOverMean": [_f32(load).reshape(1)]}
+            "RowsCombined": [_f32(combined).reshape(1)],
+            "LoadMaxOverMean": [_f32(load).reshape(1)],
+            "RowsHandled": [_f32(jnp.asarray(rungs)[rung]).reshape(1)]}

@@ -813,6 +813,12 @@ METRIC_CATALOG = {
         "rows of the busiest held expert over the held experts' mean, a "
         "sample a step and expert layer (telemetry side-fetch)",
         dynamic=True),
+    "moe_rows_handled": _m(
+        "histogram", ("program", "layer"),
+        "rows the expert layer gathered, multiplied and scattered: the "
+        "smallest capacity of its ladder that holds moe_rows_routed, all "
+        "N x top_k at most (telemetry side-fetch)",
+        dynamic=True),
     "loss_main": _m(
         "gauge", ("program",),
         "next-token cross-entropy of a model with multi-token-prediction "

@@ -213,7 +213,9 @@ def program_text(*programs):
 def test_moe_block_without_gated_is_the_program_it_was():
     """The hybrid cell's layer: its ops, slots, names and attributes,
     forward, backward and startup, hash to what the tree before the gated
-    form gave (tools: the same lines on commit 6312135)."""
+    form gave (tools: the same lines on commit 6312135) with the one
+    output slot `RowsHandled` that PR 36 added to `moe_experts` (and, as
+    a forward output, to its gradient op's inputs)."""
     main, startup = fluid.Program(), fluid.Program()
     with unique_name.guard(), fluid.program_guard(main, startup):
         x = fluid.layers.data(name="x", shape=[2, 8, 16], dtype="float32",
@@ -225,7 +227,7 @@ def test_moe_block_without_gated_is_the_program_it_was():
     text = program_text(main, startup)
     assert "WGate" not in text and "silu" not in text
     assert hashlib.sha256(text.encode()).hexdigest() == \
-        "1760e466e0bbb7dcfcc707443091127a6c7700e6356ebdda7fd020881a6d6c54"
+        "a9ac05b0f0ed0d8c2f596bf59648a8c1fe1a4283683dfcf02816ae87098b84f7"
     # and the same call's gated form has the third matrix
     with unique_name.guard(), fluid.program_guard(fluid.Program(),
                                                   fluid.Program()):
@@ -405,6 +407,7 @@ def test_the_two_losses_and_the_routing_reach_telemetry():
         exe.run(startup)
         for _ in range(2):
             out, = exe.run(main, feed=feed, fetch_list=[loss])
+        exe.close()     # a side-fetch still in flight is published here
     label = telemetry.program_label(main)
     main_loss = telemetry.read_gauge("loss_main", program=label)
     mtp_loss = telemetry.read_gauge("loss_mtp", program=label)
@@ -416,7 +419,10 @@ def test_the_two_losses_and_the_routing_reach_telemetry():
                                         layer=layer)
         combined = telemetry.read_histogram("moe_rows_combined",
                                             program=label, layer=layer)
-        assert rows["count"] == 2 and combined == rows
+        handled = telemetry.read_histogram("moe_rows_handled", program=label,
+                                           layer=layer)
+        assert rows["count"] == handled["count"] == 2 and combined == rows
+        assert handled["sum"] >= rows["sum"]
     assert telemetry.read_histogram("moe_rows_routed", program=label,
                                     layer="3") is None
 

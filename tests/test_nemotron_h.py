@@ -335,6 +335,139 @@ def test_shares_add_up_to_the_uncut_layer():
         == whole["RowsRouted"][0]
 
 
+# --- the capacity ladder -----------------------------------------------------
+
+HELD, OFFSET, EXPERTS = 2, 4, 32
+
+
+def routed_by_hand(rng, n, k, held_pairs):
+    """(idx [n, k], weight [n, k]) with exactly `held_pairs` of the n * k
+    (token, slot) pairs on the held experts OFFSET .. OFFSET + HELD - 1,
+    scattered over tokens and slots, every other pair on an absent one."""
+    absent = np.setdiff1d(np.arange(EXPERTS), OFFSET + np.arange(HELD))
+    flat = rng.choice(absent, n * k)
+    here = rng.permutation(n * k)[:held_pairs]
+    flat[here] = OFFSET + rng.integers(0, HELD, held_pairs)
+    return flat.reshape(n, k).astype(np.int32), \
+        rng.random((n, k)).astype(np.float32) + 0.1
+
+
+def experts_op(rng, n, d, f, k, held_pairs, gated, wrt=()):
+    """One moe_experts op holding HELD of EXPERTS experts over `n` tokens
+    with `held_pairs` pairs routed to them. -> run_op's triple."""
+    ins = {"X": rng.standard_normal((n, d)).astype(np.float32) * 0.5}
+    ins["TopkIdx"], ins["TopkWeight"] = routed_by_hand(rng, n, k, held_pairs)
+    for slot, shape in (("WGate", (HELD, d, f)), ("W1", (HELD, d, f)),
+                        ("W2", (HELD, f, d))):
+        if gated or slot != "WGate":
+            ins[slot] = rng.standard_normal(shape).astype(np.float32) * 0.2
+    return run_op(
+        "moe_experts", ins,
+        dict.fromkeys(("Out", "RowsRouted", "RowsCombined",
+                       "LoadMaxOverMean", "RowsHandled"), "float32"),
+        {"num_experts": EXPERTS, "experts_held": HELD,
+         "expert_offset": OFFSET, "top_k": k},
+        tuple(s for s in wrt if s in ins))
+
+
+def test_the_ladder_is_a_function_of_shapes_and_the_share():
+    from paddle_tpu.ops.hybrid_ops import _capacity_ladder, gmm_ineligible
+    assert _capacity_ladder(24576, 8, 128) == (6144, 24576)   # 4 E = 6144
+    assert _capacity_ladder(24576, 2, 128) == (1536, 24576)   # 4 E = 1536
+    assert _capacity_ladder(16384, 8, 64) == (16384,)         # 4 E = a half
+    assert _capacity_ladder(16384, 4, 64) == (4096, 16384)
+    assert _capacity_ladder(24576, 128, 128) == (24576,)      # all held
+    assert _capacity_ladder(240, 2, 32) == (60, 240)          # ragged_dot
+    assert _capacity_ladder(512, 2, 32) == (128, 512)
+    assert _capacity_ladder(512, 1, 64) == (128, 512)         # 64 would not tile
+    assert _capacity_ladder(250, 1, 128) == (250,)            # 125 has no half
+    for pairs, held, experts in ((24576, 8, 128), (240, 2, 32), (512, 1, 64)):
+        assert len({gmm_ineligible(c, 2688, 1856)
+                    for c in _capacity_ladder(pairs, held, experts)}) == 1
+
+
+# 60 tokens x 4 slots, 2 of 32 experts held: rungs 60, 240 on
+# lax.ragged_dot; 128 x 4 at a lane block's widths: 128, 512 on the kernel
+LADDER_CASES = [(60, 24, 40, r) for r in (0, 59, 60, 61, 240)] \
+    + [(128, 128, 128, r) for r in (100, 129)]
+
+
+@pytest.mark.parametrize("gated", [False, True], ids=["plain", "gated"])
+@pytest.mark.parametrize("n,d,f,held_pairs", LADDER_CASES)
+def test_a_rung_gives_what_the_whole_layer_gives(n, d, f, held_pairs, gated,
+                                                 monkeypatch):
+    """Nothing routed, one under a rung, exactly a rung, one over it and
+    every pair: Out and every gradient equal those of the layer with the
+    full size as its only rung."""
+    from paddle_tpu.ops import hybrid_ops
+    wrt = ("X", "TopkWeight", "WGate", "W1", "W2")
+    seed = 1000 * n + held_pairs
+    outs, grads, _ = experts_op(np.random.default_rng(seed), n, d, f, 4,
+                                held_pairs, gated, wrt)
+    rungs = hybrid_ops._capacity_ladder(n * 4, HELD, EXPERTS)
+    assert len(rungs) > 1
+    assert outs["RowsRouted"][0] == outs["RowsCombined"][0] == held_pairs
+    assert outs["RowsHandled"][0] == min(c for c in rungs if c >= held_pairs)
+    monkeypatch.setattr(hybrid_ops, "_capacity_ladder",
+                        lambda pairs, held, experts: (pairs,))
+    whole, whole_grads, _ = experts_op(np.random.default_rng(seed), n, d, f,
+                                       4, held_pairs, gated, wrt)
+    assert whole["RowsHandled"][0] == n * 4
+    assert whole["RowsCombined"][0] == held_pairs
+    close(outs["Out"], whole["Out"], tol=1e-6)
+    assert set(grads) == set(wrt) - (set() if gated else {"WGate"})
+    for slot in grads:
+        close(grads[slot], whole_grads[slot], tol=1e-6)
+    if not held_pairs:
+        assert not outs["Out"].any() and not grads["W1"].any()
+
+
+class _Attrs:
+    def __init__(self, **attrs):
+        self.attrs = attrs
+
+    def attr(self, name, default=None):
+        return self.attrs.get(name, default)
+
+
+@pytest.mark.parametrize("held,conds", [(EXPERTS, 0), (EXPERTS // 8, 0),
+                                        (2, 1)])
+def test_a_layer_that_holds_every_expert_has_no_conditional(held, conds):
+    """One rung (every expert held, or four times the share more than a
+    quarter of the pairs): the lowering and its gradient hold no `cond`;
+    a sixteenth's forward holds one and its gradient one more (the
+    forward's, which the compiler drops where nothing reads it)."""
+    import types
+    from paddle_tpu.ops import hybrid_ops
+    rng = np.random.default_rng(held)
+    n, d, f, k = 60, 24, 40, 4
+    idx, weight = routed(rng, n, k, EXPERTS)
+    x = rng.standard_normal((n, d)).astype(np.float32)
+    w1 = rng.standard_normal((held, d, f)).astype(np.float32)
+    w2 = rng.standard_normal((held, f, d)).astype(np.float32)
+    op_ = _Attrs(num_experts=EXPERTS, experts_held=held, expert_offset=0,
+                 top_k=k)
+
+    def out(x, weight, w1, w2):
+        return hybrid_ops._moe_experts(
+            types.SimpleNamespace(amp_dtype=None), op_,
+            {"X": [x], "TopkIdx": [idx], "TopkWeight": [weight],
+             "W1": [w1], "W2": [w2]})["Out"][0].sum()
+
+    assert str(jax.make_jaxpr(out)(x, weight, w1, w2)).count("cond[") == conds
+    grad = jax.make_jaxpr(jax.grad(out, argnums=(0, 1, 2, 3)))(
+        x, weight, w1, w2)
+    assert str(grad).count("cond[") == 2 * conds
+
+
+@pytest.mark.parametrize("held_pairs", [0, 1, 59, 60, 61, 239, 240])
+def test_rows_handled_is_the_smallest_rung_that_holds_the_routed(held_pairs):
+    outs, _, _ = experts_op(np.random.default_rng(held_pairs), 60, 24, 40, 4,
+                            held_pairs, gated=False)
+    assert outs["RowsRouted"][0] == outs["RowsCombined"][0] == held_pairs
+    assert outs["RowsHandled"][0] == (60 if held_pairs <= 60 else 240)
+
+
 # --- grouped-query attention -------------------------------------------------
 
 def gqa_plain(q, k, v):
@@ -468,6 +601,7 @@ def test_routing_statistics_reach_telemetry_by_layer():
         exe.run(startup)
         for _ in range(3):
             exe.run(main, feed=feed, fetch_list=[loss])
+        exe.close()     # a side-fetch still in flight is published here
     label = telemetry.program_label(main)
     tokens = feed["tok"].size
     for layer in ("0", "1"):
@@ -477,11 +611,54 @@ def test_routing_statistics_reach_telemetry_by_layer():
                                             program=label, layer=layer)
         load = telemetry.read_histogram("moe_load_max_over_mean",
                                         program=label, layer=layer)
-        assert rows["count"] == load["count"] == 3
+        handled = telemetry.read_histogram("moe_rows_handled", program=label,
+                                           layer=layer)
+        assert rows["count"] == load["count"] == handled["count"] == 3
         assert combined == rows                # what went in came back
+        # a quarter of the experts held: the one rung, every pair (3 slots
+        # a token)
+        assert rows["sum"] <= handled["sum"] == 3 * 3 * tokens
         # 4 of 16 experts held, 3 chosen of 16: about 3/4 of a row a token
         assert 0.3 * tokens < rows["sum"] / 3 < 1.5 * tokens
         assert 1.0 <= load["sum"] / 3 <= 4.0
+
+
+def test_a_model_trains_the_same_with_the_ladder_as_with_one_rung(monkeypatch):
+    """The tiny model as a 1/16 share (4 of 64 experts held: rungs 144 and
+    576 of its 192 tokens x 3 slots), three Adam steps in float32: the
+    losses equal those of the same model with the full size as its only
+    rung, and the steps took the first rung."""
+    from paddle_tpu import telemetry
+    from paddle_tpu.ops import hybrid_ops
+    config = dict(run.load_json("configs", "tiny-nemotron-h", DATA),
+                  n_routed_experts_published=64)
+    family = run.load_module("families", config["family"])
+    feed = family.make_batch(config, 2, np.random.default_rng(0))
+
+    def losses():
+        main, startup, loss = family.build(config)
+        fluid.amp.disable(main)
+        exe = fluid.Executor(fluid.CPUPlace())
+        with executor_mod.scope_guard(executor_mod.Scope()):
+            exe.run(startup)
+            out = [float(np.ravel(exe.run(main, feed=feed,
+                                          fetch_list=[loss])[0])[0])
+                   for _ in range(3)]
+            exe.close()
+        return out, telemetry.read_histogram(
+            "moe_rows_handled", program=telemetry.program_label(main),
+            layer="0")
+
+    assert hybrid_ops._capacity_ladder(feed["tok"].size * 3, 4, 64) == (
+        144, 576)
+    with_ladder, handled = losses()
+    monkeypatch.setattr(hybrid_ops, "_capacity_ladder",
+                        lambda pairs, held, experts: (pairs,))
+    one_rung, whole = losses()
+    assert with_ladder[0] > with_ladder[-1]            # it trains
+    np.testing.assert_allclose(with_ladder, one_rung, rtol=2e-6)
+    assert handled == {"count": 3, "sum": 3 * 144.0}
+    assert whole == {"count": 3, "sum": 3 * 576.0}
 
 
 # --- a trace can book every op of a layer to it -----------------------------

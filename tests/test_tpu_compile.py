@@ -214,25 +214,52 @@ def test_flash_fwd_bwd_compiles(mosaic, one_chip, shape):
         "flash_dkv", "flash_dq", "flash_fwd"]
 
 
-@pytest.mark.parametrize("dtype", [BF16, jnp.float32],
-                         ids=["bf16", "float32_no_amp"])
-def test_grouped_expert_products_compile(mosaic, one_chip, dtype):
+@pytest.mark.parametrize("rows,dtype", [
+    (4096 * 6, BF16), (4096 * 6, jnp.float32), (6144, BF16)],
+    ids=["bf16", "float32_no_amp", "first_rung"])
+def test_grouped_expert_products_compile(mosaic, one_chip, rows, dtype):
     """The hybrid cell's expert layer: 4096 tokens x top-6 rows of 2688
-    through 8 held experts of width 1856 and back, at the tiles the sweep
-    chose: the up product forward (the down product's result is not
-    needed for a gradient of its sum), and for each of the two its
-    backward products (gmm on the rows, tgmm on the weights)."""
-    rows, d, f, held = 4096 * 6, 2688, 1856, 8
+    (and the first rung of its capacity ladder, PR 36) through 8 held
+    experts of width 1856 and back, at the tiles the sweep chose: the up
+    product forward (the down product's result is not needed for a
+    gradient of its sum), and for each of the two its backward products
+    (gmm on the rows, tgmm on the weights)."""
+    d, f, held = 2688, 1856, 8
     assert hybrid_ops.gmm_ineligible(rows, d, f) is None
+    assert rows in hybrid_ops._capacity_ladder(4096 * 6, held, 128)
 
     def grads(x, w1, w2, sizes):
         return jax.grad(lambda *a: hybrid_ops._grouped_products(
-            *a, sizes, True).astype(jnp.float32).sum(), argnums=(0, 1, 2))(
+            *a, sizes, False).astype(jnp.float32).sum(), argnums=(0, 1, 2))(
                 x, w1, w2)
 
     assert _compile(grads, one_chip, ((rows, d), dtype), ((held, d, f), dtype),
                     ((held, f, d), dtype), ((held,), jnp.int32)) == [
         "gmm"] * 3 + ["tgmm"] * 2
+
+
+def test_hybrid_expert_layer_step_holds_one_switch_each_way(mosaic, one_chip):
+    """The hybrid cell's step at its own 4096 tokens and published widths,
+    the depth cut to one mixer and one expert layer: the layer's forward
+    and its gradient are one conditional each, two branches (the rungs
+    6144 and 24576), the forward that the gradient op traces again is
+    dropped, and each gradient branch runs its forward's two products,
+    their two partners on the rows and two on the weights."""
+    cell = run.load_json("workloads", "nemotron3-nano.train-ep16-share")
+    config = dict(run.load_json("configs", cell["config"]),
+                  hybrid_override_pattern="ME", num_hidden_layers=2)
+    text = describe_step.compile_step(cell, config, one_chip).as_text()
+    # (the step's one other conditional is the executor's own, outside
+    # any op: `jit(fn)/cond`)
+    switches = [line for line in text.splitlines()
+                if " conditional(" in line and line.count("%region") == 2
+                and 'op_name="jit(fn)/cond"' not in line]
+    assert len(switches) == 2, switches
+    assert sum("pd.moe_experts/cond" in line for line in switches) == 1
+    kernels = [re.search(r'op_name="[^"]*?(\w+)\)*/pallas_call', line).group(1)
+               for line in text.splitlines() if KERNEL in line]
+    assert {k: kernels.count(k) for k in set(kernels)} == {
+        "gmm": 2 * (2 + 2 + 2), "tgmm": 2 * 2}
 
 
 def test_gpt2_step_holds_no_float32_logits(mosaic, one_chip):
@@ -287,6 +314,8 @@ def test_latent_attention_step_compiles_with_one_cast_of_the_shared_head(
     count = {k: kernels.count(k) for k in set(kernels)}
     assert count.pop("gmm") in (6, 9)
     assert count == {"flash_fwd": 2, "flash_dq": 2, "flash_dkv": 2, "tgmm": 3}
+    # an eighth of the experts held: one rung, no switch (PR 36)
+    assert "pd.moe_experts/cond" not in text
     width = "[%d,%d]" % (config["hidden_size"], config["vocab_size"])
     casts = re.findall(
         r"%([\w.\-]+) = bf16" + re.escape(width) + r"[^=]*? convert\("
