@@ -150,6 +150,28 @@ def attention_kernel_cost(config, tokens=None):
     return flops, 9 * 2.0 * t * s["heads"] * width
 
 
+def expert_layers(config):
+    """Expert layers of a step: every block behind the leading dense
+    ones, and each prediction module's own block."""
+    return (config["num_hidden_layers"] - config["first_k_dense_replace"]
+            + config["num_nextn_predict_layers"])
+
+
+def expert_product_cost(config, rows):
+    """(FLOPs, bytes) of one train step's grouped expert products in ONE
+    expert layer when `rows` (token, slot) pairs were routed to the held
+    experts: the gate, up and down products forward, and for each its two
+    backward products (9 products of rows x d x f), nothing recomputed.
+    Bytes as nemotron_h.expert_product_cost counts them: each product
+    reads its two operands and writes its result once, in bf16, the held
+    experts' weights once a product."""
+    s = _sizes(config)
+    d, f, held = s["d"], s["f"], s["held"]
+    flops = 9 * 2.0 * rows * d * f
+    per_product = 2.0 * (rows * d + rows * f + held * d * f)
+    return flops, 9 * per_product
+
+
 def reference_loss(config, params, feed):
     """L_main + mtp_loss_weight * L_mtp of the forward pass in float32,
     from the layer equations (ISSUE 33, section 1), one sequence at a

@@ -118,6 +118,11 @@ def required_flops_per_item(config):
                   + per["head"])
 
 
+def expert_layers(config):
+    """Expert layers of a step: the pattern's "E"s."""
+    return config["hybrid_override_pattern"].count("E")
+
+
 def expert_product_cost(config, rows):
     """(FLOPs, bytes) of one train step's grouped expert products in ONE
     expert layer when `rows` (token, slot) pairs were routed to the held
@@ -130,6 +135,11 @@ def expert_product_cost(config, rows):
     flops = 6 * 2.0 * rows * d * f
     per_product = 2.0 * (rows * d + rows * f + held * d * f)
     return flops, 6 * per_product
+
+
+def scan_layers(config):
+    """Mamba layers of a step: the pattern's "M"s."""
+    return config["hybrid_override_pattern"].count("M")
 
 
 def scan_cost(config, tokens):

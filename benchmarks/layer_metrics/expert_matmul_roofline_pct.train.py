@@ -1,12 +1,15 @@
 """Share of the v5e roofline the grouped expert products reach: the
-least time for the operations and bytes of one step's products
+least time for the operations and bytes of one step's useful products
 (`family.expert_product_cost`, at the rows the traced steps themselves
-routed to held experts, `rooflines.traced_rows_routed`, times the expert
-layers) over the device time a traced step spends in the Mosaic kernels
-`gmm` and `tgmm`. The time
-includes the forward products the backward runs again (a generic
-gradient re-traces the lowering and XLA does not merge Pallas calls);
-the operations do not, so the share is under 100 by construction."""
+routed to held experts, `rooflines.traced_rows_routed`, times
+`family.expert_layers`) over the device time a traced step spends in the
+Mosaic kernels `gmm` and `tgmm`. The time includes every forward product
+the backward runs again: a generic gradient re-traces the lowering, and
+the chip's compile merges the re-traced kernels with the forward's only
+where no conditional stands between them (PR 36: a ladder's gradient
+branch runs two forward products again). The operations count each
+product once whatever implements it, so the share is under 100 by
+construction and a recompute lowers it."""
 
 from benchmarks import rooflines, run
 
@@ -28,6 +31,6 @@ def compute(ev):
         return None
     family = run.load_module("families", ev["config"]["family"])
     flops, bytes_ = family.expert_product_cost(ev["config"], rows)
-    layers = rooflines.layers_of(ev, "E")
+    layers = family.expert_layers(ev["config"])
     return rooflines.roofline_pct(ev, layers * flops, layers * bytes_,
                                   seconds)

@@ -5,8 +5,9 @@ operations of latent attention, forward and backward: every program op
 two low-rank maps and their norms through the rotation, the assembly of
 the keys and the attention kernels to the output map. The prediction
 module's block is counted too: its scope is nested
-(`pd_scope.mtp_block.latent_attention`), so any scope with
-`latent_attention` among its parts is read. The blocks' pre-norms and
+(`pd_scope.mtp_block.latent_attention`), which
+`rooflines.scope_share_pct` reads as it reads any scope with
+`latent_attention` among its parts. The blocks' pre-norms and
 residual adds are the model's and are not counted."""
 
 from benchmarks import rooflines
@@ -20,12 +21,4 @@ SCOPE = "latent_attention"
 
 
 def compute(ev):
-    steps = rooflines.scoped_steps(ev)
-    if not steps:
-        return None
-    under = sum(secs for step in steps
-                for (_, name), secs in step["by_op"].items()
-                if SCOPE in name.split("."))
-    if not under:
-        return None     # a program without the layer
-    return 100.0 * under / sum(step["busy_s"] for step in steps)
+    return rooflines.scope_share_pct(ev, SCOPE)

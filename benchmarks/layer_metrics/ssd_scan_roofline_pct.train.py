@@ -22,6 +22,6 @@ def compute(ev):
         return None
     family = run.load_module("families", ev["config"]["family"])
     flops, bytes_ = family.scan_cost(ev["config"], ev["items_per_step"])
-    layers = rooflines.layers_of(ev, "M")
+    layers = family.scan_layers(ev["config"])
     return rooflines.roofline_pct(ev, layers * flops, layers * bytes_,
                                   statistics.median(under))

@@ -76,15 +76,17 @@ def scoped_steps(ev):
 
 def scope_share_pct(ev, scope):
     """Share of the device's busy time, over the traced steps, under the
-    operations built in name scope `scope` (or one nested in it), forward
-    and backward; None without a trace or where the trace holds no such
-    scope (a parent program)."""
+    operations built in a name scope with `scope` among its dotted parts
+    (the scope itself, one nested in it, or the same layer built inside
+    another: `mtp_block.moe_block` is a `moe_block`), forward and
+    backward; None without a trace or where the trace holds no such scope
+    (a parent program)."""
     steps = scoped_steps(ev)
     if not steps:
         return None
     under = sum(secs for step in steps
                 for (_, name), secs in step["by_op"].items()
-                if name == scope or name.startswith(scope + "."))
+                if scope in name.split("."))
     if not under:
         return None
     return 100.0 * under / sum(step["busy_s"] for step in steps)
@@ -112,11 +114,6 @@ def roofline_pct(ev, flops, bytes_, seconds):
     least = max(flops / trace_reduce.peak_flops(kind),
                 bytes_ / HBM_BYTES_PER_S[kind])
     return 100.0 * least / seconds
-
-
-def layers_of(ev, kind):
-    """How many layers of `kind` the configuration's pattern holds."""
-    return ev["config"].get("hybrid_override_pattern", "").count(kind)
 
 
 def traced_rows_routed(ev):

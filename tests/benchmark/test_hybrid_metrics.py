@@ -4,9 +4,13 @@ the hybrid cell brought, on a hand-written reduction of a trace
 trace_reduce's `device_ops` rows, a hand-written set of counters and a
 hand-written step log; and the family's arithmetic they price by."""
 
+import os
+
 import pytest
 
 from benchmarks import program_trace, rooflines, run
+
+DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 
 SSM = run.load_module("layer_metrics", "ssm_time_pct.train")
 MOE = run.load_module("layer_metrics", "moe_time_pct.train")
@@ -112,6 +116,17 @@ def test_expert_products_against_the_roofline(evidence):
     least = 4 * max(flops / 197e12, bytes_ / 819e9)
     assert bytes_ / 819e9 > flops / 197e12
     assert GMM.compute(evidence) == pytest.approx(100 * least / 0.010)
+
+
+@pytest.mark.parametrize("config,experts,scans", [
+    (CONFIG, 4, 4),
+    (run.load_json("configs", "tiny-nemotron-h", DATA), 2, 2),
+    ({"hybrid_override_pattern":
+      "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"}, 23, 23)],
+    ids=["the-cell", "tiny", "published"])
+def test_layer_counts_are_the_patterns(config, experts, scans):
+    assert FAMILY.expert_layers(config) == experts
+    assert FAMILY.scan_layers(config) == scans
 
 
 def test_scan_against_the_roofline(evidence):
