@@ -26,8 +26,12 @@ experts or less handles its rows inside a capacity chosen on the device
 which the generic gradient differentiates through: the backward chooses
 the same branch, runs that branch's forward again inside it (a
 conditional is a wall to the merging) and keeps nothing of a branch's
-size between the two. The rotation is linear in X and its generic
-gradient is the rotation by the opposite angle.
+size between the two. Inside a branch, and in the layer without one, the
+two maps between tokens and sorted rows carry rules too (_rows_of_tokens,
+_tokens_of_rows): each is pulled back as a gather through the other's
+index, where autodiff would zero-fill [N, D] and scatter-add. The
+rotation is linear in X and its generic gradient is the rotation by the
+opposite angle.
 """
 
 from __future__ import annotations
@@ -333,21 +337,110 @@ def _capacity_ladder(pairs: int, held: int, num_experts: int):
     return (rung, pairs) if 4 * rung <= pairs else (pairs,)
 
 
-def _handle_rows(capacity, kernel, order, sizes, x, weight, w1, w2, gate):
-    """The layer over the first `capacity` pairs of `order` (the routed
-    ones, which the caller knows to be no more, then dead ones): their
-    tokens' rows gathered, the grouped products, the weights, the
-    scatter-add back into [N, D]. -> (Out, the live rows it combined)."""
+# The two maps between the tokens' rows [N, D] and the C rows the grouped
+# products see. Sorted row p is pair order[p]'s token's row, and a token
+# gets back the sum of its top_k pairs' rows. `order` is a permutation of
+# the N x top_k pairs and `pos` [N, top_k] its inverse, so every sorted
+# row belongs to exactly one pair: each map is the other's transpose, and
+# read through the other index both are gathers, forward and pulled back.
+# Autodiff cannot know that and writes each transpose as a zero-fill of
+# [N, D] and a scatter-add of C rows (1.25-1.34 ms each at 14 % of their
+# bytes on a v5e, ten a step of the latent-attention cell); so each map
+# has a rule of its own.
+
+def _sum_of_pairs(rows, pos, live_rows, weight=None):
+    """[C, D] -> [N, D] float32: sum over a token's top_k pairs of
+    (weight[n, j] *) rows[pos[n, j]], a pair whose place in the order is
+    at or past `live_rows` adding nothing (an absent expert's, or one past
+    the rung: its place may lie past C, so it reads row 0 and is selected
+    away, never multiplied). One gather of N rows a slot, added: the other
+    way, one gather of [N, top_k, D], XLA writes, relays out for the
+    reshape and reads back. The layer alone, forward + gradient on a v5e,
+    a gather a slot | one gather | the scatter-add: 6.11 | 6.82 | 8.45 ms
+    at [4096 x 4, 2048] with every pair handled, 3.15 | 6.27 | 3.74 at
+    [4096 x 6, 2688] with 6144 handled. In a step's trace the forward's
+    gathers, weights and sum are one pass (in the latent-attention cell
+    the epilogue of the shared expert's down product, +0.03 ms); pulled
+    back each slot's gather is a pass of its own and the add one more
+    (0.7 ms a layer there where the fill and scatter-add took 1.5; 0.83
+    against 0.78 at the second shape) (PERF.md section 6, PR 38)."""
+    total = 0
+    for j in range(pos.shape[1]):
+        ok = pos[:, j] < live_rows
+        picked = rows.at[jnp.where(ok, pos[:, j], 0)].get(
+            mode="promise_in_bounds")
+        term = jnp.where(ok[:, None], _f32(picked), 0)
+        total = total + (term if weight is None else term * weight[:, j, None])
+    return total
+
+
+@jax.custom_vjp
+def _rows_of_tokens(x, token, pos, live_rows):
+    """x[token]: [N, D] -> [C, D]. Pulled back, dX[n] is the sum over the
+    token's pairs of their rows' cotangents, in float32."""
+    return x.at[token].get(mode="promise_in_bounds")
+
+
+def _rows_of_tokens_fwd(x, token, pos, live_rows):
+    return _rows_of_tokens(x, token, pos, live_rows), (pos, live_rows)
+
+
+def _rows_of_tokens_bwd(res, ct):
+    return _sum_of_pairs(ct, *res).astype(ct.dtype), None, None, None
+
+
+_rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
+
+
+@jax.custom_vjp
+def _tokens_of_rows(y, weight, head, pos, live_rows):
+    """Out[n] = sum_j weight[n, j] * y[pos[n, j]] over the pairs with a
+    live row: [C, D] in the compute dtype as the kernel leaves it (rows
+    past the live ones undefined) -> [N, D] float32. Pulled back in
+    sorted order, where one gather of the cotangent's rows serves both
+    gradients: dY[p] = weight[pair p] * dOut[token p], dWeight[pair p] =
+    y[p] . dOut[token p], zero past the live rows."""
+    return _sum_of_pairs(y, pos, live_rows, weight)
+
+
+def _tokens_of_rows_fwd(y, weight, head, pos, live_rows):
+    return (_tokens_of_rows(y, weight, head, pos, live_rows),
+            (y, weight, head, pos, live_rows))
+
+
+def _tokens_of_rows_bwd(res, ct):
+    y, weight, head, pos, live_rows = res
+    live = (jnp.arange(y.shape[0]) < live_rows)[:, None]
+    d_rows = jnp.where(live, ct.at[head // weight.shape[1]].get(
+        mode="promise_in_bounds"), 0)
+    by_row = weight.reshape(-1).at[head].get(mode="promise_in_bounds")
+    d_y = (d_rows * by_row[:, None]).astype(y.dtype)
+    d_by_row = (jnp.where(live, _f32(y), 0) * d_rows).sum(-1)
+    ok = pos < live_rows
+    d_weight = jnp.where(ok, d_by_row.at[jnp.where(ok, pos, 0)].get(
+        mode="promise_in_bounds"), 0)
+    return d_y, d_weight, None, None, None
+
+
+_tokens_of_rows.defvjp(_tokens_of_rows_fwd, _tokens_of_rows_bwd)
+
+
+def _handle_rows(capacity, kernel, sort, sizes, x, weight, w1, w2, gate):
+    """The layer over the first `capacity` pairs of the sorted order (the
+    routed ones, which the caller knows to be no more, then dead ones):
+    their tokens' rows gathered, the grouped products, and each token's
+    top_k rows gathered back through the inverse of the sort, weighted
+    and summed in float32. `sort` = (order, its inverse [N, top_k]).
+    -> (Out, the pairs it combined)."""
+    order, pos = sort
     head = order[:capacity]
-    token = head // weight.shape[1]
-    live = (jnp.arange(capacity) < sizes.sum())[:, None]
-    rows = jnp.where(live, x[token].astype(w1.dtype), 0)
+    live_rows = jnp.minimum(sizes.sum(), capacity)
+    live = (jnp.arange(capacity) < live_rows)[:, None]
+    rows = _rows_of_tokens(x, head // weight.shape[1], pos, live_rows)
+    rows = jnp.where(live, rows.astype(w1.dtype), 0)
     out = _grouped_products(rows, w1, w2, sizes, kernel, gate)
-    # the kernel leaves rows past the routed ones undefined: select before
-    # weighting, so that no gradient is a product with them either
-    out = jnp.where(live, _f32(out), 0) * weight.reshape(-1)[head][:, None]
-    out = jnp.zeros(x.shape, jnp.float32).at[token].add(out)
-    return out.astype(x.dtype), live.sum()
+    out = _tokens_of_rows(out, weight, head, pos, live_rows)
+    return out.astype(x.dtype), (pos < live_rows).sum()
 
 
 # One function object a (capacity, kernel): jax keeps a switch branch's
@@ -361,9 +454,9 @@ def _rung(capacity, kernel):
 
 @functools.lru_cache(maxsize=None)
 def _rung_pulled_back(capacity, kernel):
-    def branch(order, sizes, ct, *operands):
+    def branch(sort, sizes, ct, *operands):
         _, vjp, _ = jax.vjp(functools.partial(
-            _handle_rows, capacity, kernel, order, sizes), *operands,
+            _handle_rows, capacity, kernel, sort, sizes), *operands,
             has_aux=True)
         return vjp(ct)
     return branch
@@ -373,12 +466,12 @@ def _cast(mats, dtype):
     return tuple(None if m is None else m.astype(dtype) for m in mats)
 
 
-def _switch_rows(rungs, kernel, dtype, rung, order, sizes, x, weight, *mats):
+def _switch_rows(rungs, kernel, dtype, rung, sort, sizes, x, weight, *mats):
     """_handle_rows at the capacity rungs[rung], one branch a rung; the
     matrices' casts to `dtype` are operands of the switch, once, not work
     of each branch."""
     return lax.switch(rung, [_rung(c, kernel) for c in rungs],
-                      order, sizes, x, weight, *_cast(mats, dtype))
+                      sort, sizes, x, weight, *_cast(mats, dtype))
 
 
 # _switch_rows under a rule of the op's own: autodiff through lax.switch
@@ -396,9 +489,9 @@ def _handle_routed_rows_fwd(rungs, kernel, dtype, *args):
 
 
 def _handle_routed_rows_bwd(rungs, kernel, dtype, args, cts):
-    rung, order, sizes, x, weight, *mats = args
+    rung, sort, sizes, x, weight, *mats = args
     d_x, d_weight, *d_mats = lax.switch(
-        rung, [_rung_pulled_back(c, kernel) for c in rungs], order, sizes,
+        rung, [_rung_pulled_back(c, kernel) for c in rungs], sort, sizes,
         cts[0], x, weight, *_cast(mats, dtype))
     # barrier: the matrices' gradients leave the switch in the compute
     # dtype and wait for the optimizer at the step's end. Unpinned, XLA
@@ -430,21 +523,25 @@ def _moe_experts(ctx, op_, ins):
     over the rows of the held experts alone (Pallas' megablox gmm visits
     only the row tiles its group sizes cover; lax.ragged_dot where the
     shape does not tile, booked with the reason). The rows then return to
-    their tokens weighted, by a scatter-add.
+    their tokens by a second gather, through the inverse of the sort: a
+    token reads the rows of its top_k pairs, weights and adds them in
+    float32. The gradient gathers both ways too (_rows_of_tokens,
+    _tokens_of_rows): no [N, D] is zero-filled and nothing is
+    scatter-added, forward or backward.
 
-    The rows gathered, multiplied and scattered are the first C pairs of
-    that order, C the smallest rung of _capacity_ladder that holds the
+    The rows gathered, multiplied and gathered back are the first C pairs
+    of that order, C the smallest rung of _capacity_ladder that holds the
     step's routed pairs, chosen on the device (lax.switch, one branch a
     rung, in the gradient too); the last rung is all N x top_k, so no
     step drops a row, and a layer that holds every expert has that rung
     alone and no conditional.
 
     RowsRouted [1]: the pairs the router sent to held experts, counted
-    on its indices; RowsCombined [1]: the rows the grouped product was
-    given and the scatter-add returned, counted where they are combined
-    (the two differ only if a row is lost between them); LoadMaxOverMean
-    [1]: the busiest held expert's rows over the held experts' mean;
-    RowsHandled [1]: the rung taken."""
+    on its indices; RowsCombined [1]: the pairs whose rows the grouped
+    product was given and their tokens read back, counted where they are
+    combined (the two differ only if a row is lost between them);
+    LoadMaxOverMean [1]: the busiest held expert's rows over the held
+    experts' mean; RowsHandled [1]: the rung taken."""
     from . import pallas_conv
     from .pallas_attention import _interpret
     from .. import quant
@@ -464,7 +561,8 @@ def _moe_experts(ctx, op_, ins):
     local = idx.reshape(-1) - op_.attr("expert_offset", 0)
     group = jnp.where((local >= 0) & (local < held), local, held)
     order = jnp.argsort(group, stable=True)           # held experts first
-    sizes = jnp.bincount(group, length=held + 1)[:held].astype(jnp.int32)
+    sort = order, jnp.argsort(order).astype(jnp.int32).reshape(n, k)
+    sizes = (group[:, None] == jnp.arange(held)).sum(0, dtype=jnp.int32)
     routed = sizes.sum()
 
     reason = gmm_ineligible(n * k, x.shape[-1], w1.shape[-1])
@@ -478,10 +576,10 @@ def _moe_experts(ctx, op_, ins):
     rungs = _capacity_ladder(n * k, held, op_.attr("num_experts", held))
     rung = (routed > jnp.asarray(rungs[:-1], jnp.int32)).sum()
     if len(rungs) == 1:    # no conditional and no gradient rule of its own
-        out, combined = _handle_rows(rungs[0], kernel, order, sizes, x,
+        out, combined = _handle_rows(rungs[0], kernel, sort, sizes, x,
                                      weight, *_cast((w1, w2, gate), dtype))
     else:
-        out, combined = _handle_routed_rows(rungs, kernel, dtype, rung, order,
+        out, combined = _handle_routed_rows(rungs, kernel, dtype, rung, sort,
                                             sizes, x, weight, w1, w2, gate)
 
     load = sizes.max() / jnp.maximum(routed / held, 1.0)

@@ -145,7 +145,13 @@ def _scan_flops(ins, outs, attrs):
 def _experts_flops(ins, outs, attrs):
     """moe_experts: the up and down products (and the gate product of
     gated experts, given as WGate) over the EXPECTED rows routed to the
-    held experts, top_k x held / experts of a token's."""
+    held experts, top_k x held / experts of a token's. Around them the op
+    moves rows and multiplies nothing: the handled pairs' rows of X
+    gathered into sorted order and N x top_k rows of the down product
+    gathered back to their tokens (the same two gathers in the gradient;
+    no [N, D] is filled and added into since PR 38). Those rows are
+    temporaries, so op_cost's bytes (X, the matrices and Out once) leave
+    them out, as they leave out every op's."""
     x, w1 = _slot_shape(ins, "X"), _slot_shape(ins, "W1")
     if x is None or w1 is None:
         return None

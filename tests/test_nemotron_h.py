@@ -352,22 +352,38 @@ def routed_by_hand(rng, n, k, held_pairs):
         rng.random((n, k)).astype(np.float32) + 0.1
 
 
-def experts_op(rng, n, d, f, k, held_pairs, gated, wrt=()):
-    """One moe_experts op holding HELD of EXPERTS experts over `n` tokens
-    with `held_pairs` pairs routed to them. -> run_op's triple."""
+def experts_inputs(rng, n, d, f, k, held_pairs, gated, held=HELD,
+                   experts=EXPERTS):
+    """moe_experts' inputs for `held` of `experts` experts over `n`
+    tokens: `held_pairs` pairs routed to the held ones by hand (HELD from
+    OFFSET on), or with None a random router's choice of `experts`."""
     ins = {"X": rng.standard_normal((n, d)).astype(np.float32) * 0.5}
-    ins["TopkIdx"], ins["TopkWeight"] = routed_by_hand(rng, n, k, held_pairs)
-    for slot, shape in (("WGate", (HELD, d, f)), ("W1", (HELD, d, f)),
-                        ("W2", (HELD, f, d))):
+    ins["TopkIdx"], ins["TopkWeight"] = routed(rng, n, k, experts) \
+        if held_pairs is None else routed_by_hand(rng, n, k, held_pairs)
+    for slot, shape in (("WGate", (held, d, f)), ("W1", (held, d, f)),
+                        ("W2", (held, f, d))):
         if gated or slot != "WGate":
             ins[slot] = rng.standard_normal(shape).astype(np.float32) * 0.2
+    return ins
+
+
+def run_experts(ins, wrt=(), offset=OFFSET, experts=EXPERTS):
+    """One moe_experts op over `ins`, holding W1's experts from `offset`
+    on. -> run_op's triple."""
     return run_op(
         "moe_experts", ins,
         dict.fromkeys(("Out", "RowsRouted", "RowsCombined",
                        "LoadMaxOverMean", "RowsHandled"), "float32"),
-        {"num_experts": EXPERTS, "experts_held": HELD,
-         "expert_offset": OFFSET, "top_k": k},
+        {"num_experts": experts, "experts_held": ins["W1"].shape[0],
+         "expert_offset": offset, "top_k": ins["TopkIdx"].shape[1]},
         tuple(s for s in wrt if s in ins))
+
+
+def experts_op(rng, n, d, f, k, held_pairs, gated, wrt=()):
+    """One moe_experts op holding HELD of EXPERTS experts over `n` tokens
+    with `held_pairs` pairs routed to them. -> run_op's triple."""
+    return run_experts(experts_inputs(rng, n, d, f, k, held_pairs, gated),
+                       wrt)
 
 
 def test_the_ladder_is_a_function_of_shapes_and_the_share():
@@ -466,6 +482,125 @@ def test_rows_handled_is_the_smallest_rung_that_holds_the_routed(held_pairs):
                             held_pairs, gated=False)
     assert outs["RowsRouted"][0] == outs["RowsCombined"][0] == held_pairs
     assert outs["RowsHandled"][0] == (60 if held_pairs <= 60 else 240)
+
+
+# --- rows back to their tokens ------------------------------------------------
+
+def scatter_add_layer(x, idx, weight, w1, w2, gate, offset):
+    """The layer as the op wrote it before its rows went back by a gather
+    (PR 38): the pairs sorted by held expert, the tokens' rows gathered in
+    that order, one ragged product over the held experts' rows, and the
+    weighted rows added into a zero-filled [N, D] at their tokens. Its
+    gradients are autodiff's: a scatter-add for each gather."""
+    held, k = w1.shape[0], idx.shape[1]
+    local = idx.reshape(-1) - offset
+    group = jnp.where((local >= 0) & (local < held), local, held)
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.bincount(group, length=held + 1)[:held].astype(jnp.int32)
+    token = order // k
+    live = (jnp.arange(order.shape[0]) < sizes.sum())[:, None]
+    rows = jnp.where(live, x[token], 0)
+    up = jax.lax.ragged_dot(rows, w1, sizes)
+    if gate is None:
+        h = jnp.square(jax.nn.relu(up))
+    else:
+        h = jax.nn.silu(jax.lax.ragged_dot(rows, gate, sizes)) * up
+    out = jnp.where(live, jax.lax.ragged_dot(h, w2, sizes), 0) \
+        * weight.reshape(-1)[order][:, None]
+    return jnp.zeros_like(x).at[token].add(out)
+
+
+# name: tokens, d, f, experts, held, offset, pairs routed here (None: a
+# random router's), gated, rungs, why the kernel does not take the product
+GATHER_CASES = {
+    "every_expert_held": (64, 128, 128, 8, 8, 0, None, True, 1, None),
+    "absent_first_and_last": (64, 128, 128, 16, 4, 6, None, False, 1, None),
+    "small_rung_taken": (128, 128, 128, 32, 2, 4, 100, True, 2, None),
+    "full_rung_taken": (128, 128, 128, 32, 2, 4, 300, False, 2, None),
+    "rows_do_not_tile": (50, 24, 40, 16, 4, 6, None, True, 1, "rows"),
+    "rows_do_not_tile_small_rung": (60, 24, 40, 32, 2, 4, 40, False, 2,
+                                    "rows"),
+    "rows_do_not_tile_full_rung": (60, 24, 40, 32, 2, 4, 61, True, 2, "rows"),
+    "nothing_routed_here": (60, 24, 40, 32, 2, 4, 0, True, 2, "rows"),
+}
+
+
+@pytest.mark.parametrize("case", GATHER_CASES)
+def test_rows_return_by_gather_as_by_the_scatter_add(case):
+    """moe_experts' Out and its gradients to X, TopkWeight, W1, W2 and
+    WGate against the scatter-add form written here, and a token none of
+    whose experts is held gets exactly nothing, forward and pulled back."""
+    from paddle_tpu.ops import hybrid_ops
+    n, d, f, experts, held, offset, held_pairs, gated, n_rungs, reason = \
+        GATHER_CASES[case]
+    assert held_pairs is None or (held, offset, experts) == \
+        (HELD, OFFSET, EXPERTS)
+    k = 4
+    ins = experts_inputs(np.random.default_rng(len(case) + n), n, d, f, k,
+                         held_pairs, gated, held, experts)
+    wrt = tuple(s for s in ("X", "TopkWeight", "W1", "W2", "WGate")
+                if s in ins)
+    outs, grads, cot = run_experts(ins, wrt, offset, experts)
+
+    here = (ins["TopkIdx"] >= offset) & (ins["TopkIdx"] < offset + held)
+    rungs = hybrid_ops._capacity_ladder(n * k, held, experts)
+    assert outs["RowsRouted"][0] == outs["RowsCombined"][0] == here.sum()
+    assert outs["RowsHandled"][0] == min(c for c in rungs if c >= here.sum())
+    assert (len(rungs), hybrid_ops.gmm_ineligible(n * k, d, f)) == \
+        (n_rungs, reason)
+    if "rung_taken" in case:
+        assert (outs["RowsHandled"][0] == n * k) == ("full" in case)
+
+    def plain(x, weight, w1, w2, gate=None):
+        return scatter_add_layer(x, ins["TopkIdx"], weight, w1, w2, gate,
+                                 offset)
+
+    operands = [ins[s] for s in wrt]
+    close(outs["Out"], plain(*operands), tol=1e-4)
+    want = jax.grad(lambda *a: (plain(*a) * cot).sum(),
+                    argnums=tuple(range(len(wrt))))(*operands)
+    for slot, g in zip(wrt, want):
+        close(grads[slot], g, tol=2e-4)
+    elsewhere = ~here.any(-1)
+    assert elsewhere.any() == (case != "every_expert_held")
+    assert not outs["Out"][elsewhere].any()
+    assert not grads["X"][elsewhere].any()
+    assert not grads["TopkWeight"][~here].any()
+
+
+@pytest.mark.parametrize("width,held", [(32, 16), (32, 1), (128, 16),
+                                        (128, 1)],
+                         ids=["ragged", "ragged_ladder", "gmm", "gmm_ladder"])
+def test_no_scatter_is_left_in_an_expert_layers_step(width, held):
+    """The compiled train step of one moe_block, every expert held (one
+    rung) and a sixteenth (two, under a conditional): nothing lowered from
+    moe_experts or its gradient is a scatter, but for the few integers of
+    group metadata that megablox's own wrapper writes before its kernel
+    (`jit(gmm)` / `jit(tgmm)` scopes: s32[tiles + groups])."""
+    from paddle_tpu import xplane
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = fluid.layers.data(name="x", shape=[2, 32, width],
+                              dtype="float32", append_batch_size=False)
+        h = fluid.layers.moe_block(x, num_experts=16, top_k=4,
+                                   expert_width=width, shared_width=width,
+                                   experts_held=held)
+        loss = fluid.layers.mean(h)
+        fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
+    exe = fluid.Executor(fluid.CPUPlace())
+    with executor_mod.scope_guard(executor_mod.Scope()):
+        exe.run(startup)
+        text = exe.compiled_hlo(
+            main, feed={"x": np.ones((2, 32, width), np.float32)},
+            fetch_list=[loss])
+    mine = [i for i in xplane.hlo_instructions(text)
+            if i.op in ("moe_experts", "moe_experts_grad")]
+    assert {i.op for i in mine} == {"moe_experts", "moe_experts_grad"}
+    assert {i.heavy for i in mine} >= {"sort", "dot"}
+    left = [(i.name, i.shape, i.op_name) for i in mine
+            if i.heavy == "scatter" and "jit(gmm)" not in i.op_name
+            and "jit(tgmm)" not in i.op_name]
+    assert not left, left
 
 
 # --- grouped-query attention -------------------------------------------------
