@@ -14,12 +14,16 @@ angles, their sines and cosines and the rotation itself are float32.
 
 Gradients: all generic (registry.generic_grad_lower: jax.vjp of the
 lowering, whose re-traced forward XLA merges with the original). The
-scan's core is a jax.checkpoint, so its backward keeps the op's inputs
-and the chunk states, and recomputes the [chunk, chunk] decay and score
-blocks instead of holding them per layer; the expert layer's grouped
-products are Pallas calls, whose re-traced forward the chip's compiler
-merges with the original too (16 gmm runs a step of the hybrid cell, not
-24; PERF.md section 6, PR 36). A layer that holds a sixteenth of the
+scan runs, where its shape tiles (ssd_scan_ineligible), on the two Pallas
+kernels of ops/pallas_scan.py, whose [chunk, chunk] decay and score
+blocks never leave VMEM: a jax.custom_vjp inside the lowering that keeps
+the op's inputs and the state entering each chunk, and recomputes the
+blocks in the gradient's kernel (PERF.md section 6, PR 40); elsewhere
+its core is ssd_scan_chunked under a jax.checkpoint, which keeps the
+op's inputs and recomputes the blocks as XLA arrays. The expert layer's
+grouped products are Pallas calls too; the chip's compiler merges a
+re-traced forward kernel with the original (16 gmm runs a step of the
+hybrid cell, not 24, PERF.md section 6, PR 36; 4 ssd_scan_fwd, not 8). A layer that holds a sixteenth of the
 experts or less handles its rows inside a capacity chosen on the device
 (_capacity_ladder) and carries a rule of its own inside the lowering
 (_handle_routed_rows, a jax.custom_vjp as nn_ops._hard_label_nll is),
@@ -46,7 +50,8 @@ from jax import lax
 from .common import in_var, same_as_input, set_out
 from .registry import op
 
-__all__ = ["GMM_FALLBACK_REASONS", "gmm_ineligible", "ssd_scan_chunked"]
+__all__ = ["GMM_FALLBACK_REASONS", "SSD_SCAN_FALLBACK_REASONS",
+           "gmm_ineligible", "ssd_scan_chunked", "ssd_scan_ineligible"]
 
 
 def _f32(x):
@@ -147,6 +152,13 @@ def ssd_scan_chunked(x, dt, a, b, c, chunk, dtype=jnp.float32):
     b_t^T, y_t = h_t c_t per head, in chunks (Dao & Gu 2024, section 6):
     inside a chunk a masked [chunk, chunk] product, across chunks a
     recurrence over the T/chunk states written as one small product.
+    The statement of the algorithm in plain jax.numpy, and the path for
+    shapes that do not tile (ssd_scan_ineligible: the tiny test models);
+    where they do, ops/pallas_scan.py::ssd_scan_kernels computes the same
+    from the same arguments with the [chunk, chunk] blocks in VMEM. Here
+    the mask and the decayed scores are [B, chunks, G, R, chunk, chunk]
+    arrays that XLA writes and reads back (134 MB each in float32 at the
+    hybrid cell's shape).
     x [B, T, H, P]; dt [B, T, H] (after softplus); a [H] (negative);
     b, c [B, T, G, N], head h reading group h // (H/G). Decays, the
     masks' weights and the states are float32; the four products take
@@ -195,20 +207,79 @@ def ssd_scan_chunked(x, dt, a, b, c, chunk, dtype=jnp.float32):
     return y.reshape(bsz, t + pad, h, p)[:, :t]
 
 
+def _book_kernel_choice(op_type: str, reason):
+    """pallas_kernel_total{op} for a lowering that took its Pallas
+    kernels (`reason` None), pallas_fallback_total{op, reason} for one
+    that kept the XLA path; nothing in the gradient op's re-trace of the
+    forward (registry.generic_grad_lower suppresses the counters)."""
+    from . import pallas_conv
+    from .. import quant
+    if quant.counters_suppressed():
+        return
+    if reason is None:
+        pallas_conv.count_hit(op_type)
+    else:
+        pallas_conv.count_fallback(op_type, reason)
+
+
+_SCAN_OP = "ssd_scan"
+
+# Every reason ssd_scan_ineligible can return.
+SSD_SCAN_FALLBACK_REASONS = frozenset({"chunk", "state", "heads"})
+
+
+def ssd_scan_ineligible(chunk: int, heads_a_group: int, p: int, n: int):
+    """None when the scan kernels (ops/pallas_scan.py) take chunks of
+    `chunk` steps, groups of `heads_a_group` heads of P = `p` and states
+    of N = `n`, else the reason ssd_scan_chunked keeps the scan. Time
+    runs along the kernels' lanes, so a chunk must fill 128-lane blocks
+    (`chunk`), as must the states' N where B and C stand with time on the
+    sublanes (`state`); a head is P sublanes of the group's block, whole
+    packed bf16 rows of 16, and the group's cumulative log-decays are
+    turned as one 128-row tile a step (`heads`)."""
+    if chunk % 128:
+        return "chunk"
+    if n % 128:
+        return "state"
+    if p % 16 or heads_a_group > 128:
+        return "heads"
+    return None
+
+
 @op("ssd_scan", infer_shape=same_as_input())
 def _ssd_scan(ctx, op_, ins):
     """Mamba-2's mixer between its conv and its gated norm. X [B, T, H, P],
     Dt [B, T, H] (raw), DtBias, ALog, D [H], B and C [B, T, G, N]:
     dt = softplus(Dt + DtBias), A = -exp(ALog), the recurrence of
     ssd_scan_chunked with `chunk_size`, plus the skip D * X. Out has X's
-    dtype."""
+    dtype.
+
+    What runs is chosen from the shapes (ssd_scan_ineligible): the two
+    Pallas kernels of ops/pallas_scan.py, forward and gradient, whose
+    [chunk, chunk] blocks never leave VMEM (interpreted off the chip;
+    pallas_kernel_total{op="ssd_scan"}), or ssd_scan_chunked under a
+    jax.checkpoint, booked with the reason (pallas_fallback_total). Both
+    take the same operands at the same precision; softplus, dt * A and
+    the skip are jax.numpy around either, so autodiff carries DtBias,
+    ALog and D."""
+    from .pallas_attention import _interpret
+    from .pallas_scan import ssd_scan_kernels
+
     x = jnp.asarray(ins["X"][0])
+    b, c = jnp.asarray(ins["B"][0]), jnp.asarray(ins["C"][0])
     dt = jax.nn.softplus(_f32(ins["Dt"][0]) + _f32(ins["DtBias"][0]))
     a = -jnp.exp(_f32(ins["ALog"][0]))
-    core = jax.checkpoint(functools.partial(
-        ssd_scan_chunked, chunk=op_.attr("chunk_size", 128),
-        dtype=_compute_dtype(ctx)))
-    y = core(x, dt, a, jnp.asarray(ins["B"][0]), jnp.asarray(ins["C"][0]))
+    chunk = op_.attr("chunk_size", 128)
+    reason = ssd_scan_ineligible(chunk, x.shape[2] // b.shape[2], x.shape[3],
+                                 b.shape[3])
+    _book_kernel_choice(_SCAN_OP, reason)
+    shared = dict(chunk=chunk, dtype=_compute_dtype(ctx))
+    if reason is None:
+        core = functools.partial(ssd_scan_kernels, interpret=_interpret(),
+                                 **shared)
+    else:
+        core = jax.checkpoint(functools.partial(ssd_scan_chunked, **shared))
+    y = core(x, dt, a, b, c)
     y = y + _f32(x) * _f32(ins["D"][0])[:, None]
     return {"Out": [y.astype(x.dtype)]}
 
@@ -542,9 +613,7 @@ def _moe_experts(ctx, op_, ins):
     combined (the two differ only if a row is lost between them);
     LoadMaxOverMean [1]: the busiest held expert's rows over the held
     experts' mean; RowsHandled [1]: the rung taken."""
-    from . import pallas_conv
     from .pallas_attention import _interpret
-    from .. import quant
 
     x = jnp.asarray(ins["X"][0])
     idx = jnp.asarray(ins["TopkIdx"][0])
@@ -566,12 +635,7 @@ def _moe_experts(ctx, op_, ins):
     routed = sizes.sum()
 
     reason = gmm_ineligible(n * k, x.shape[-1], w1.shape[-1])
-    if quant.counters_suppressed():   # the grad op's re-trace books nothing
-        pass
-    elif reason is None:
-        pallas_conv.count_hit(_GMM_OP)
-    else:
-        pallas_conv.count_fallback(_GMM_OP, reason)
+    _book_kernel_choice(_GMM_OP, reason)
     kernel = _interpret() if reason is None else None
     rungs = _capacity_ladder(n * k, held, op_.attr("num_experts", held))
     rung = (routed > jnp.asarray(rungs[:-1], jnp.int32)).sum()

@@ -132,7 +132,12 @@ _ELEMWISE_COST = {
 def _scan_flops(ins, outs, attrs):
     """ssd_scan: the chunked form's four products per token: scores C B^T
     per group and their masked product with x (chunk wide each), the chunk
-    states and the entering state's read-out (H P N each)."""
+    states and the entering state's read-out (H P N each). The useful
+    work, whichever of hybrid_ops.ssd_scan_chunked and the kernels of
+    ops/pallas_scan.py runs it: the kernels issue a head narrower than
+    128 lanes 128 wide behind a lane mask and their gradient computes the
+    forward's products again, which a trace's time holds and this count
+    does not."""
     x, b = _slot_shape(ins, "X"), _slot_shape(ins, "B")
     if x is None or b is None:
         return None

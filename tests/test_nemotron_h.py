@@ -138,6 +138,188 @@ def test_scan_backward_keeps_no_per_step_state():
     assert max(int(np.prod(aval.shape)) for aval, _ in saved) < t * h * p * n
 
 
+# --- the scan on its kernels (ops/pallas_scan.py), interpreted -----------------
+
+SCAN_ORDER = ("X", "Dt", "DtBias", "ALog", "B", "C", "D")
+
+
+def scan_inputs(rng, bsz, seqlen, heads, groups, p=64, n=128, a_log=None):
+    f = lambda *s: rng.standard_normal(s).astype(np.float32)  # noqa: E731
+    # dt = softplus(Dt + DtBias) about 0.05, as the published
+    # initialisation leaves it ([0.001, 0.1]): at dt about 1 a chunk of
+    # 128 steps sums log-decays to -1000 and float32 rounds the mask's
+    # exponents to 1e-4, in either implementation
+    return {"X": f(bsz, seqlen, heads, p), "Dt": f(bsz, seqlen, heads),
+            "DtBias": f(heads) * 0.5 - 3,
+            "ALog": f(heads) if a_log is None else np.float32(a_log),
+            "B": f(bsz, seqlen, groups, n) * 0.3,
+            "C": f(bsz, seqlen, groups, n) * 0.3, "D": f(heads)}
+
+
+def scan_lowering(chunk, amp=None):
+    """The op's lowering as the executor calls it, over SCAN_ORDER."""
+    import types
+    from paddle_tpu.ops import hybrid_ops
+
+    def out(*arrays):
+        return hybrid_ops._ssd_scan(
+            types.SimpleNamespace(amp_dtype=amp), _Attrs(chunk_size=chunk),
+            {s: [a] for s, a in zip(SCAN_ORDER, arrays)})["Out"][0]
+    return out
+
+
+def chunked_form(x, dt_raw, dt_bias, a_log, b, c, skip, chunk=128):
+    from paddle_tpu.ops.hybrid_ops import ssd_scan_chunked
+    y = ssd_scan_chunked(x, jax.nn.softplus(dt_raw + dt_bias),
+                         -jnp.exp(a_log), b, c, chunk)
+    return y + skip[:, None] * x
+
+
+# (batch, T, heads, groups, AMP dtype, ALog or None for random)
+SCAN_KERNEL_CASES = {
+    "one_chunk": (1, 128, 2, 1, None, None),
+    "many_chunks": (2, 384, 2, 1, None, None),
+    "several_groups": (1, 256, 4, 2, None, None),
+    "ragged_tail": (1, 200, 4, 2, None, None),
+    "shorter_than_a_chunk": (1, 72, 2, 1, None, None),
+    "bf16": (1, 256, 4, 2, "bfloat16", None),
+    "bf16_ragged_tail": (2, 200, 2, 1, "bfloat16", None),
+    # a = -e^4: a step keeps e^-3 and a chunk's dead triangle overflows
+    # (exp(350), selected away); a = -e^-9: 512 steps keep 0.997 and the
+    # mask is all ones
+    "both_ends_of_the_exp": (1, 512, 2, 1, None, (4.0, -9.0)),
+}
+
+
+@pytest.mark.parametrize("case", SCAN_KERNEL_CASES)
+def test_scan_kernels_match_the_chunked_form_and_the_recurrence(case):
+    """The kernel path (the shape tiles: P 64, N 128, chunk 128) through
+    the op's lowering: Out and the gradients to all seven inputs against
+    ssd_scan_chunked in float32 and against the step-by-step recurrence;
+    bf16 operands at the tiny model's O2 tolerance."""
+    from paddle_tpu.ops import hybrid_ops
+    bsz, seqlen, heads, groups, amp, a_log = SCAN_KERNEL_CASES[case]
+    assert hybrid_ops.ssd_scan_ineligible(128, heads // groups, 64, 128) \
+        is None
+    rng = np.random.default_rng(seqlen + heads)
+    ins = scan_inputs(rng, bsz, seqlen, heads, groups, a_log=a_log)
+    args = [jnp.asarray(ins[s]) for s in SCAN_ORDER]
+    cot = jnp.asarray(rng.standard_normal(ins["X"].shape), jnp.float32)
+
+    def value_and_grads(fn):
+        out = fn(*args)
+        return out, jax.grad(lambda *a: (fn(*a) * cot).sum(),
+                             argnums=range(7))(*args)
+
+    got, got_grads = value_and_grads(scan_lowering(128, amp))
+    assert got.dtype == jnp.float32
+    # float32: the chunked form's own distance from the recurrence, which
+    # ALog's gradient sets (2.6e-4 in the last case, the kernels' 4e-6)
+    tol = 5e-4 if amp is None else 0.03
+    for form in (chunked_form, recurrence):
+        want, want_grads = value_and_grads(form)
+        close(got, want, tol=tol)
+        for slot, g, w in zip(SCAN_ORDER, got_grads, want_grads):
+            assert g.shape == w.shape, slot
+            close(g, w, tol=tol)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_scan_kernels_give_a_padded_tail_zero_gradients(dtype):
+    """Steps with dt = 0 behind the sequence (log-decay 0, x, B and C
+    zero) whose output nobody reads: every gradient row of the tail is
+    exactly zero, so the padding adds nothing to a gradient."""
+    from paddle_tpu.ops import pallas_scan
+    rng = np.random.default_rng(5)
+    t, live, heads, groups, p, n = 256, 150, 4, 2, 64, 128
+    tail = (np.arange(t) >= live)
+
+    def f(*shape):
+        v = rng.standard_normal((1, t) + shape).astype(np.float32)
+        v[:, tail] = 0
+        return v
+    x, b, c, dy = f(heads, p), f(groups, n), f(groups, n), f(heads, p)
+    dt = np.abs(f(heads))
+    _, vjp = jax.vjp(
+        lambda *a: pallas_scan._scan(*a, 128, True), jnp.asarray(x, dtype),
+        jnp.asarray(dt), jnp.asarray(-1.5 * dt), jnp.asarray(b, dtype),
+        jnp.asarray(c, dtype))
+    for grad in vjp(jnp.asarray(dy)):
+        assert np.abs(np.asarray(grad, np.float32)[:, :live]).max() > 0
+        assert not np.asarray(grad, np.float32)[:, live:].any()
+
+
+def test_the_scan_gate_is_a_function_of_shapes_and_names_its_reasons():
+    from paddle_tpu.ops import hybrid_ops
+    gate = {  # (chunk, heads a group, P, N)
+        (128, 8, 64, 128): None, (256, 8, 64, 128): None,
+        (128, 2, 64, 256): None, (128, 16, 32, 128): None,
+        (128, 1, 64, 128): None, (128, 8, 128, 128): None,
+        (32, 2, 8, 16): "chunk", (64, 8, 64, 128): "chunk",
+        (128, 8, 64, 16): "state", (128, 8, 64, 192): "state",
+        (128, 8, 8, 128): "heads",        # half a packed bf16 row
+        (128, 8, 40, 128): "heads",
+        (128, 160, 64, 128): "heads",     # more rows than the turned tile
+    }
+    for shape, reason in gate.items():
+        assert hybrid_ops.ssd_scan_ineligible(*shape) == reason, shape
+    assert {r for r in gate.values() if r} \
+        == hybrid_ops.SSD_SCAN_FALLBACK_REASONS
+
+
+def _scan_counts():
+    from paddle_tpu import telemetry
+    return (dict(telemetry.read_series("pallas_kernel_total")),
+            dict(telemetry.read_series("pallas_fallback_total")))
+
+
+def test_a_scan_that_does_not_tile_keeps_the_chunked_form_and_says_why():
+    """chunk 64 at widths that would tile: booked as a fallback with the
+    gate's reason, no hit, and the numbers of ssd_scan_chunked."""
+    ins = scan_inputs(np.random.default_rng(9), 1, 128, 2, 1)
+    before = _scan_counts()
+    outs, grads, cot = run_op("ssd_scan", ins, {"Out": "float32"},
+                              {"chunk_size": 64}, SCAN_ORDER)
+    hits, falls = _scan_counts()
+    key = "op=ssd_scan,reason=chunk"
+    assert falls[key] > before[1].get(key, 0)
+    assert hits.get("op=ssd_scan", 0) == before[0].get("op=ssd_scan", 0)
+    args = [jnp.asarray(ins[s]) for s in SCAN_ORDER]
+    close(outs["Out"], chunked_form(*args, chunk=64), tol=1e-6)
+    want = jax.grad(lambda *a: (chunked_form(*a, chunk=64) * cot).sum(),
+                    argnums=range(7))(*args)
+    for slot, g in zip(SCAN_ORDER, want):
+        close(grads[slot], g, tol=1e-5)
+
+
+def test_the_scans_gradient_op_books_no_second_hit():
+    """A program with the gradient op books as many lowerings on the
+    kernels as the same program without it: generic_grad_lower traces the
+    forward again under quant.suppress_counters()."""
+    ins = scan_inputs(np.random.default_rng(11), 1, 128, 2, 1)
+
+    def hits_of(wrt):
+        before = _scan_counts()
+        out = run_op("ssd_scan", ins, {"Out": "float32"},
+                     {"chunk_size": 128}, wrt)
+        after = _scan_counts()
+        assert after[1] == before[1]                 # and no fallback
+        return after[0]["op=ssd_scan"] - before[0].get("op=ssd_scan", 0), out
+
+    forward_only, _ = hits_of(())
+    assert forward_only >= 1
+    # run_op runs the forward program (for Out's shape), then the one
+    # with the gradient ops
+    both, (outs, grads, cot) = hits_of(SCAN_ORDER)
+    assert both == 2 * forward_only
+    args = [jnp.asarray(ins[s]) for s in SCAN_ORDER]
+    close(outs["Out"], recurrence(*args), tol=2e-4)
+    want = jax.grad(lambda *a: (recurrence(*a) * cot).sum(),
+                    argnums=range(7))(*args)
+    for slot, g in zip(SCAN_ORDER, want):
+        close(grads[slot], g, tol=2e-4)
+
+
 # --- conv, norm --------------------------------------------------------------
 
 def test_causal_conv1d():
@@ -917,6 +1099,30 @@ def test_every_table_knows_the_op(op_type, tiny_program, o2_dtypes):
     flops, _ = roofline.op_cost(op_type, ins, outs, attrs)
     assert flops == pytest.approx(want)
     assert roofline.op_cost(op_type + "_grad", ins, outs, attrs)[0] == \
+        pytest.approx(2 * want)
+
+
+@pytest.mark.parametrize("chunk,heads,groups,p,n", [
+    (32, 4, 2, 8, 16), (128, 4, 2, 64, 128), (128, 64, 8, 64, 128)],
+    ids=["chunked_form", "kernels", "kernels_at_the_hybrid_cell"])
+def test_the_scans_price_is_the_algorithms_not_the_implementations(
+        chunk, heads, groups, p, n):
+    """roofline.op_cost prices ssd_scan by the chunked form's four
+    products from the op's shapes, the same whether ssd_scan_chunked or
+    the Pallas kernels run it (what the kernels issue beyond that, masked
+    lanes and the gradient's recompute, is time, not work)."""
+    from paddle_tpu import roofline
+    from paddle_tpu.ops import hybrid_ops
+    tiles = hybrid_ops.ssd_scan_ineligible(chunk, heads // groups, p, n)
+    assert (tiles is None) == (p == 64)
+    tokens = 2 * 256
+    x = jax.ShapeDtypeStruct((2, 256, heads, p), jnp.float32)
+    b = jax.ShapeDtypeStruct((2, 256, groups, n), jnp.float32)
+    want = 2.0 * tokens * (chunk * groups * n + chunk * heads * p
+                           + 2 * heads * p * n)
+    args = ({"X": [x], "B": [b]}, {"Out": [x]}, {"chunk_size": chunk})
+    assert roofline.op_cost("ssd_scan", *args)[0] == pytest.approx(want)
+    assert roofline.op_cost("ssd_scan_grad", *args)[0] == \
         pytest.approx(2 * want)
 
 

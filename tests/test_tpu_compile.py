@@ -238,17 +238,58 @@ def test_grouped_expert_products_compile(mosaic, one_chip, rows, dtype):
         "gmm"] * 3 + ["tgmm"] * 2
 
 
-def test_hybrid_expert_layer_step_holds_one_switch_each_way(mosaic, one_chip):
+def _scan_fwd_bwd(chunk, dtype):
+    from paddle_tpu.ops import pallas_scan
+
+    def grads(x, dt, a, b, c):
+        return jax.grad(lambda *v: pallas_scan.ssd_scan_kernels(
+            *v, chunk, dtype).sum(), argnums=(0, 1, 2, 3, 4))(x, dt, a, b, c)
+    return grads
+
+
+@pytest.mark.parametrize("t,chunk,dtype", [
+    (4096, 128, BF16), (4096, 128, jnp.float32), (2048, 256, BF16)],
+    ids=["hybrid_cell", "float32_no_amp", "chunk_256"])
+def test_scan_kernels_compile(mosaic, one_chip, t, chunk, dtype):
+    """The hybrid cell's scan, [1, 4096, 64 heads x 64] over 8 groups of
+    state 128 in chunks of 128: the forward kernel and the gradient's,
+    each inside the 16 MB of scoped VMEM a Mosaic call has by default
+    (ops/pallas_scan.py asks for no more); without AMP the operands are
+    float32 and the blocks twice the size; a chunk of 256 makes the
+    score and mask blocks [256, 256]."""
+    h, p, g, n = 64, 64, 8, 128
+    assert hybrid_ops.ssd_scan_ineligible(chunk, h // g, p, n) is None
+    assert _compile(_scan_fwd_bwd(chunk, dtype), one_chip,
+                    ((1, t, h, p), dtype), ((1, t, h), jnp.float32),
+                    ((h,), jnp.float32), ((1, t, g, n), dtype),
+                    ((1, t, g, n), dtype)) == ["ssd_scan_bwd", "ssd_scan_fwd"]
+
+
+_HYBRID_ME = {}
+
+
+def _hybrid_mixer_and_expert_step(one_chip):
     """The hybrid cell's step at its own 4096 tokens and published widths,
-    the depth cut to one mixer and one expert layer: the layer's forward
-    and its gradient are one conditional each, two branches (the rungs
-    6144 and 24576), the forward that the gradient op traces again is
-    dropped, and each gradient branch runs its forward's two products,
-    their two partners on the rows and two on the weights."""
-    cell = run.load_json("workloads", "nemotron3-nano.train-ep16-share")
-    config = dict(run.load_json("configs", cell["config"]),
-                  hybrid_override_pattern="ME", num_hidden_layers=2)
-    text = describe_step.compile_step(cell, config, one_chip).as_text()
+    the depth cut to one mixer and one expert layer, compiled once for
+    the tests that read it (call under the `mosaic` fixture)."""
+    if not _HYBRID_ME:
+        cell = run.load_json("workloads", "nemotron3-nano.train-ep16-share")
+        config = dict(run.load_json("configs", cell["config"]),
+                      hybrid_override_pattern="ME", num_hidden_layers=2)
+        _HYBRID_ME["text"] = describe_step.compile_step(
+            cell, config, one_chip).as_text()
+    return _HYBRID_ME["text"]
+
+
+def test_hybrid_expert_layer_step_holds_one_switch_each_way(mosaic, one_chip):
+    """One mixer and one expert layer of the hybrid cell: the expert
+    layer's forward and its gradient are one conditional each, two
+    branches (the rungs 6144 and 24576), the forward that the gradient op
+    traces again is dropped, and each gradient branch runs its forward's
+    two products, their two partners on the rows and two on the weights.
+    The mixer's scan is one forward kernel (the one the gradient op
+    traces again merged with it) and one gradient kernel."""
+    text = _hybrid_mixer_and_expert_step(one_chip)
     # (the step's one other conditional is the executor's own, outside
     # any op: `jit(fn)/cond`)
     switches = [line for line in text.splitlines()
@@ -259,7 +300,24 @@ def test_hybrid_expert_layer_step_holds_one_switch_each_way(mosaic, one_chip):
     kernels = [re.search(r'op_name="[^"]*?(\w+)\)*/pallas_call', line).group(1)
                for line in text.splitlines() if KERNEL in line]
     assert {k: kernels.count(k) for k in set(kernels)} == {
-        "gmm": 2 * (2 + 2 + 2), "tgmm": 2 * 2}
+        "gmm": 2 * (2 + 2 + 2), "tgmm": 2 * 2,
+        "ssd_scan_fwd": 1, "ssd_scan_bwd": 1}
+
+
+def test_hybrid_mixer_step_holds_no_chunk_by_chunk_block(mosaic, one_chip):
+    """The same step: no instruction under the scan or its gradient
+    writes a [chunks, heads, chunk, chunk] array (32 chunks of 128 steps,
+    64 heads in 8 groups: ssd_scan_chunked's mask and decayed scores,
+    134 MB each in float32), in any dtype; the blocks live in the
+    kernels' VMEM. What the two ops do hold is the entering states."""
+    text = _hybrid_mixer_and_expert_step(one_chip)
+    under = [line for line in text.splitlines()
+             if re.search(r"pd\.ssd_scan(_grad)?/", line)]
+    assert any(KERNEL in line for line in under)
+    blocks = [line for line in under
+              if re.search(r"\[(1,)?32,(8,8|64),128,128\]", line)]
+    assert not blocks, blocks[:3]
+    assert any("bf16[1,32,4096,128]" in line for line in under)
 
 
 def test_gpt2_step_holds_no_float32_logits(mosaic, one_chip):
