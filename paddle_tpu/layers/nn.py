@@ -35,7 +35,8 @@ __all__ = [
     "precision_recall", "positive_negative_pair", "pool3d", "roi_pool",
     "prelu", "crop", "spp", "unpool", "conv3d_transpose",
     "max_pool2d_with_index", "conv_shift", "l1_norm",
-    "fused_attention", "sparse_moe", "rms_norm", "mamba2_mixer", "moe_block",
+    "fused_attention", "block_diffusion_attention", "sparse_moe", "rms_norm",
+    "mamba2_mixer", "moe_block",
     "rotary_embedding", "gated_mlp", "latent_attention", "mtp_block",
 
     "hsigmoid", "bilinear_interp", "selective_fc",
@@ -1099,9 +1100,12 @@ def fused_attention(q, k, v, causal=False,
     XLA einsum at short T (fuses into neighbors), flash from the per-device
     sequence length at which the kernels won on the chip
     (ops/nn_ops._flash_wins); a shape the kernels do not tile keeps einsum
-    with a counted reason; False forces einsum. (Named fused_attention
-    because reference-parity nets.scaled_dot_product_attention already
-    takes [B, T, D] with num_heads and different semantics.)"""
+    with a counted reason; False forces einsum. The masks this op knows
+    are none and causal; block-diffusion training's three-part mask at
+    the grain of a block of tokens is block_diffusion_attention's, on the
+    same kernels. (Named fused_attention because reference-parity
+    nets.scaled_dot_product_attention already takes [B, T, D] with
+    num_heads and different semantics.)"""
     helper = LayerHelper("fused_attention")
     out = helper.create_tmp_variable(q.dtype)
     # per-row logsumexp residual for the explicit backward (dropout-Mask
@@ -1196,6 +1200,42 @@ def _linear(x, size, scale=0.02, act=None, name=None):
 
 
 @_under_its_name
+def block_diffusion_attention(q, k, v, block_length, use_flash="auto",
+                              name=None):
+    """Attention of block-diffusion training (arXiv:2503.09573; how the
+    SDAR family's models are trained, arXiv:2510.06303) over q
+    [2B, L, H, D] and k, v [2B, L, H_kv, D] (H_kv divides H, as
+    fused_attention): every sequence is two streams of L positions, its
+    noised copy and its clean one, the B noisy streams first along the
+    batch and their clean streams behind them in the same order. With
+    b(i) = i // block_length:
+
+        a noisy query at i sees the noisy keys j with b(j) = b(i) (its own
+        block, both directions) and the clean keys j with b(j) < b(i);
+        a clean query at i sees the clean keys j with b(j) <= b(i) and no
+        noisy key.
+
+    block_length = 1 makes the clean half plain causal attention. 'auto'
+    takes the flash kernels from the sequence length fused_attention
+    takes them, where the shape tiles and block_length divides 128
+    (ops/pallas_attention.py: the mask lives in the kernels' walk ranges,
+    dead tiles are neither fetched nor walked, nothing of [L, L] reaches
+    HBM), else a masked einsum with the reason counted
+    (pallas_fallback_total{op="block_diffusion_attention"}); False
+    forces the einsum. Its ops lower under
+    `pd_scope.block_diffusion_attention`."""
+    helper = LayerHelper("block_diffusion_attention", name=name)
+    out = helper.create_tmp_variable(q.dtype)
+    lse = helper.create_tmp_variable("float32", stop_gradient=True)
+    helper.append_op(type="block_diffusion_attention",
+                     inputs={"Q": [q], "K": [k], "V": [v]},
+                     outputs={"Out": [out], "LSE": [lse]},
+                     attrs={"block_length": int(block_length),
+                            "use_flash": use_flash})
+    return out
+
+
+@_under_its_name
 def mamba2_mixer(x, num_heads, head_dim, n_groups, state_size, conv_kernel=4,
                  chunk_size=128, epsilon=1e-5, out_scale=0.02, name=None):
     """Mamba-2 mixer (Dao & Gu 2024, as nemotron_h's) over x [B, T, D]:
@@ -1252,15 +1292,19 @@ def mamba2_mixer(x, num_heads, head_dim, n_groups, state_size, conv_kernel=4,
 def moe_block(x, num_experts, top_k, expert_width, shared_width=0,
               experts_held=None, expert_offset=0, scaling=1.0,
               norm_topk_prob=True, out_scale=0.02, stats=None, name=None,
-              gated=False):
-    """Mixture-of-experts feed-forward over x [B, T, D] with a sigmoid
-    top-k router, squared-ReLU experts (`gated`: gated SiLU experts,
+              gated=False, scoring="sigmoid"):
+    """Mixture-of-experts feed-forward over x [B, T, D] with a top-k
+    router, squared-ReLU experts (`gated`: gated SiLU experts,
     f(x) = (silu(x G) * (x U)) V, three matrices an expert, the shared
-    expert likewise) and a shared expert:
+    expert likewise) and, with `shared_width`, a shared expert:
 
-        s = sigmoid(x W_r) in float32; the top_k of s + b are chosen (b: a
-        selection bias, a buffer that starts at zero and takes no
-        gradient); g_i = scaling * s_i / (sum of the chosen s + 1e-20)
+        s = sigmoid(x W_r) in float32 (`scoring` "sigmoid", the default:
+        nemotron_h's and glm4_moe_lite's router) or softmax(x W_r) over
+        all `num_experts` ("softmax": sdar_moe's, the Qwen3-MoE block's);
+        the top_k of s + b (sigmoid) or s * exp(b) (softmax) are chosen
+        (b: a selection bias, a buffer that starts at zero and takes no
+        gradient; models.balance_routers moves it against the load);
+        g_i = scaling * s_i / (sum of the chosen s + 1e-20)
         out = sum_i g_i f_{e_i}(x) + f_shared(x),  f(x) = relu(x U)^2 V
 
     The layer is told its share: it holds `experts_held` of `num_experts`
@@ -1268,6 +1312,8 @@ def moe_block(x, num_experts, top_k, expert_width, shared_width=0,
     adds only what its own experts give (ops/hybrid_ops.py moe_experts:
     no token dropped, static shapes, a grouped product over the rows
     actually routed here); the shared expert is applied to every token.
+    Without one (`shared_width` 0) a token none of whose choices is held
+    here gets exactly zero from the layer.
     `stats`: a list that receives this layer's (rows routed to held
     experts, rows combined, busiest held expert over their mean, rows
     handled: the capacity the step's routed rows were given) Variables,
@@ -1288,12 +1334,15 @@ def moe_block(x, num_experts, top_k, expert_width, shared_width=0,
         default_initializer=ConstantInitializer(0.0))
     idx = helper.create_tmp_variable("int32", stop_gradient=True)
     weight = helper.create_tmp_variable("float32")
+    router_attrs = {"top_k": top_k, "scaling": scaling,
+                    "norm_topk_prob": norm_topk_prob}
+    if scoring != "sigmoid":    # the op's default: a sigmoid router's
+        router_attrs["scoring"] = scoring   # program is the one it was
     helper.append_op(type="moe_router",
                      inputs={"X": [tokens], "W": [router_w],
                              "Bias": [router_b]},
                      outputs={"TopkIdx": [idx], "TopkWeight": [weight]},
-                     attrs={"top_k": top_k, "scaling": scaling,
-                            "norm_topk_prob": norm_topk_prob})
+                     attrs=router_attrs)
 
     inputs = {"X": [tokens], "TopkIdx": [idx], "TopkWeight": [weight]}
     if gated:

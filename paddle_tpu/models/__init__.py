@@ -7,6 +7,7 @@ glue (loss, optimizer) stays in user code or in `build_classifier`.
 """
 
 from .alexnet import alexnet
+from .block_diffusion_moe import block_diffusion_moe_lm
 from .googlenet import googlenet
 from .mla_moe import mla_moe_lm
 from .mnist import mnist_conv, mnist_mlp
@@ -15,12 +16,12 @@ from .resnet import resnet_cifar10, resnet_imagenet, resnet50
 from .smallnet import smallnet_mnist_cifar
 from .transformer import transformer_lm
 from .vgg import vgg16, vgg19
-from .common import build_image_classifier
+from .common import balance_routers, build_image_classifier
 
 __all__ = [
-    "alexnet", "googlenet", "mla_moe_lm", "mnist_conv", "mnist_mlp",
+    "alexnet", "block_diffusion_moe_lm", "googlenet", "mla_moe_lm", "mnist_conv", "mnist_mlp",
     "nemotron_h_lm",
     "resnet_cifar10", "resnet_imagenet", "resnet50",
     "smallnet_mnist_cifar", "transformer_lm",
-    "vgg16", "vgg19", "build_image_classifier",
+    "vgg16", "vgg19", "balance_routers", "build_image_classifier",
 ]

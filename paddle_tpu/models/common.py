@@ -40,3 +40,22 @@ def mark_routing_stats(program, stats):
     for metric, by_layer in zip(SIDE_METRICS, zip(*stats)):
         side_fetch_marks(program)[metric] = layers.concat(
             list(by_layer), axis=0).name
+
+
+def balance_routers(program, rate):
+    """After minimize(): for every moe_router of `program` append the op
+    that moves its selection bias against the load the step's choices
+    made over all its experts (moe_balance_bias: at most `rate` a step;
+    the balancing rule that needs no loss, arXiv:2408.15664), behind the
+    backward's and the optimizer's ops and in their role. Behind the
+    backward because a gradient op traces its router again and has to
+    read the bias the forward read (placed after the router, the rule
+    made the cell's first gradient depend on `rate`: PR 42, chip call E).
+    Returns the appended ops."""
+    block = program.global_block()
+    return [block.append_op(
+        type="moe_balance_bias",
+        inputs={"TopkIdx": op.output("TopkIdx"), "Bias": op.input("Bias")},
+        outputs={"BiasOut": op.input("Bias")},
+        attrs={"rate": rate, "op_role": "optimize"})
+        for op in list(block.ops) if op.type == "moe_router"]

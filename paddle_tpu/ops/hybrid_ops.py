@@ -1,11 +1,13 @@
 """Ops of hybrid state-space / mixture-of-experts language models (no
 2018-reference analogue): RMSNorm with its gated, grouped form, a causal
 depthwise conv over time, the Mamba-2 selective scan in its chunked (SSD)
-form, a sigmoid top-k router and a dropless expert layer that is told
-which experts it holds (squared-ReLU experts, or gated SiLU ones when it
-is given the third matrix), and the rotary position embedding of latent
-attention's decoupled part. models/nemotron_h.py and models/mla_moe.py
-build models from them.
+form, a top-k router (sigmoid or softmax scores), the rule that moves its
+selection bias against the load (moe_balance_bias) and a dropless expert
+layer that is told which experts it holds (squared-ReLU experts, or gated
+SiLU ones when it is given the third matrix), and the rotary position
+embedding (latent attention's decoupled part; every dim of a head in the
+block-diffusion decoder). models/nemotron_h.py, models/mla_moe.py and
+models/block_diffusion_moe.py build models from them.
 
 Precision under AMP: norm statistics, the router, `dt`, `A`, the scan's
 decays and its state stay float32; the scan's four products and the
@@ -48,7 +50,7 @@ import numpy as np
 from jax import lax
 
 from .common import in_var, same_as_input, set_out
-from .registry import op
+from .registry import NO_GRAD, op
 
 __all__ = ["GMM_FALLBACK_REASONS", "SSD_SCAN_FALLBACK_REASONS",
            "gmm_ineligible", "ssd_scan_chunked", "ssd_scan_ineligible"]
@@ -295,22 +297,68 @@ def _router_infer(op_, block):
     set_out(op_, block, "TopkWeight", shape, "float32")
 
 
+_SCORINGS = {"sigmoid": jax.nn.sigmoid,
+             "softmax": functools.partial(jax.nn.softmax, axis=-1)}
+# how the selection bias b enters the choice: added to a sigmoid's score;
+# a softmax's probability is scaled by exp(b) (b added to the logit: the
+# experts' order is the logits' whatever the softmax's temperature), and
+# s * exp(0) is s itself, bit for bit
+_BIASED = {"sigmoid": lambda s, b: s + b,
+           "softmax": lambda s, b: s * jnp.exp(b)}
+
+
 @op("moe_router", infer_shape=_router_infer, non_diff_inputs=("Bias",))
 def _moe_router(ctx, op_, ins):
     """X [N, D], W [D, E], Bias [E] (a buffer: no gradient) -> TopkIdx
-    [N, k] int32 and TopkWeight [N, k] float32. Scores s = sigmoid(X W) in
-    float32 at full precision; the k experts with the largest s + Bias are
-    chosen; their weights are `scaling` * s_i / (sum of the chosen s +
-    1e-20) when `norm_topk_prob`, else `scaling` * s_i."""
-    s = jax.nn.sigmoid(jnp.matmul(_f32(ins["X"][0]), _f32(ins["W"][0]),
-                                  precision=lax.Precision.HIGHEST))
-    _, idx = lax.top_k(s + lax.stop_gradient(_f32(ins["Bias"][0])),
-                       op_.attr("top_k", 1))
+    [N, k] int32 and TopkWeight [N, k] float32. Scores s = sigmoid(X W)
+    (`scoring` "sigmoid", the default: nemotron_h's and glm4_moe_lite's
+    routers) or softmax(X W) over all E experts ("softmax": sdar_moe's),
+    in float32 at full precision; the k experts with the largest s + Bias
+    (sigmoid) or s * exp(Bias) (softmax) are chosen (the bias moves the
+    choice only); their weights are `scaling` * s_i / (sum of the chosen
+    s + 1e-20) when `norm_topk_prob`, else `scaling` * s_i."""
+    scoring = op_.attr("scoring", "sigmoid")
+    assert scoring in _SCORINGS, scoring
+    s = _SCORINGS[scoring](jnp.matmul(_f32(ins["X"][0]), _f32(ins["W"][0]),
+                                      precision=lax.Precision.HIGHEST))
+    _, idx = lax.top_k(
+        _BIASED[scoring](s, lax.stop_gradient(_f32(ins["Bias"][0]))),
+        op_.attr("top_k", 1))
     w = jnp.take_along_axis(s, idx, axis=-1)
     if op_.attr("norm_topk_prob", True):
         w = w / (w.sum(-1, keepdims=True) + 1e-20)
     return {"TopkIdx": [idx.astype(jnp.int32)],
             "TopkWeight": [w * op_.attr("scaling", 1.0)]}
+
+
+def _balance_infer(op_, block):
+    bias = in_var(op_, block, "Bias")
+    if bias is not None and bias.shape is not None:
+        set_out(op_, block, "BiasOut", list(bias.shape), bias.dtype)
+
+
+@op("moe_balance_bias", infer_shape=_balance_infer, grad=NO_GRAD)
+def _moe_balance_bias(ctx, op_, ins):
+    """The balancing rule that needs no loss (arXiv:2408.15664, in its
+    form proportional to the error, held to one `rate` a step): TopkIdx
+    [N, k] (the step's choices over all E experts, held here or not) and
+    Bias [E] -> BiasOut [E] = Bias + `rate` * clip((mean - count_e) /
+    mean, -1, 1), count_e the (token, slot) pairs that chose expert e
+    and mean = N k / E: an expert chosen too often is chosen less at the
+    next step, and one at its share is left alone. BiasOut is Bias' own
+    variable: the executor writes it back with the rest of the state,
+    and no gradient passes; the op belongs behind the backward
+    (models.balance_routers), whose router gradient reads Bias again.
+    The counts are a compare-and-sum, as
+    moe_experts' sizes are (a scatter-add of N k ones costs 0.2 ms a
+    layer on a v5e: PR 38)."""
+    idx, bias = ins["TopkIdx"][0], ins["Bias"][0]
+    experts = bias.shape[0]
+    count = (idx.reshape(-1, 1) == jnp.arange(experts)).sum(
+        0, dtype=jnp.float32)
+    mean = idx.size / experts
+    step = op_.attr("rate", 0.0) * jnp.clip((mean - count) / mean, -1.0, 1.0)
+    return {"BiasOut": [(_f32(bias) + step).astype(bias.dtype)]}
 
 
 # --- dropless expert layer ---------------------------------------------------

@@ -29,6 +29,7 @@ it out once (same section).
 from __future__ import annotations
 
 import collections
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -866,13 +867,14 @@ def _sdpa_grad(fwd, no_grad_set):
     pattern; reference batch_norm saves statistics the same way). The
     generic vjp maker would re-trace the forward INSIDE the grad op — for
     HLO einsums XLA CSEs the duplicate, but pallas custom calls are not
-    CSE'd, so use_flash would pay the flash forward twice per step."""
+    CSE'd, so use_flash would pay the flash forward twice per step.
+    Both attention ops' (`<forward type>_grad`)."""
     wanted = [s for s in ("Q", "K", "V")
               if fwd.input(s)[0] not in no_grad_set]
     if not wanted:
         return []
     return [OpDesc(
-        type="scaled_dot_product_attention_grad",
+        type=fwd.type + "_grad",
         inputs={"Q": fwd.input("Q"), "K": fwd.input("K"),
                 "V": fwd.input("V"), "Out": fwd.output("Out"),
                 "LSE": fwd.output("LSE"),
@@ -977,6 +979,16 @@ def _repeat_kv(x, groups: int):
     The copies cost HBM traffic a kernel that reads H_kv heads would not
     pay."""
     return x if groups == 1 else jnp.repeat(x, groups, axis=2)
+
+
+def _sum_kv_groups(g, groups: int):
+    """_repeat_kv pulled back: the gradient of a repeated K or V summed
+    over each group of `groups` heads, in float32."""
+    if groups == 1:
+        return g
+    b, t, h, d = g.shape
+    return g.astype(jnp.float32).reshape(b, t, h // groups, groups, d) \
+        .sum(3).astype(g.dtype)
 
 
 def _sdpa_paths(ctx, op_, q, k, v, count=False):
@@ -1124,10 +1136,7 @@ def _sdpa_grad_kernel(ctx, op_, ins):
             lambda a, b, c: attention_reference(a, b, c, causal=causal),
             q, k, v)
         dq, dk, dv = vjp_fn(do.astype(q.dtype))
-    if groups > 1:
-        b, t, h, d = dk.shape
-        dk, dv = (g.astype(jnp.float32).reshape(b, t, h // groups, groups, d)
-                  .sum(3).astype(g.dtype) for g in (dk, dv))
+    dk, dv = _sum_kv_groups(dk, groups), _sum_kv_groups(dv, groups)
     if restore is not None:
         dq, dk, dv = (dq.astype(restore), dk.astype(restore),
                       dv.astype(restore))
@@ -1136,6 +1145,233 @@ def _sdpa_grad_kernel(ctx, op_, ins):
         if name in op_.desc.outputs:
             outs[name] = [g]
     return outs
+
+
+# --- block-diffusion attention -----------------------------------------------
+
+_BD_OP = "block_diffusion_attention"
+
+
+def _bd_masks(length: int, block: int):
+    """The three parts of block-diffusion training's mask as [L, L] bools
+    over (query position, key position), from the positions' blocks
+    b(i) = i // block: a noisy query sees the clean keys of `earlier`
+    blocks and the noisy keys of its `own`; a clean query sees the clean
+    keys `upto` its own block (block-causal). No clean query sees a noisy
+    key."""
+    bid = jnp.arange(length) // block
+    return {"earlier": bid[:, None] > bid[None, :],
+            "own": bid[:, None] == bid[None, :],
+            "upto": bid[:, None] >= bid[None, :]}
+
+
+def _bd_einsum(q, k, v, block: int):
+    """(Out [2B, L, H, D], LSE [2B, H, L]) of block-diffusion attention
+    as masked einsum attention: the noisy half against [clean keys of
+    earlier blocks ; noisy keys of its own block], the clean half
+    block-causal against the clean keys. Scores [B, H, L, 2L] and
+    [B, H, L, L] reach HBM: the path of shapes the kernels do not tile."""
+    half, length = q.shape[0] // 2, q.shape[1]
+    scale = 1.0 / (q.shape[-1] ** 0.5)
+    mask = _bd_masks(length, block)
+
+    def scores(qs, ks, keep):
+        logits = jnp.einsum("bqhd,bkhd->bhqk", qs, ks).astype(
+            jnp.float32) * scale
+        return jnp.where(keep[None, None], logits, -jnp.inf)
+
+    (qn, qc), (kn, kc), (vn, vc) = ((x[:half], x[half:]) for x in (q, k, v))
+    noisy = jnp.concatenate([scores(qn, kc, mask["earlier"]),
+                             scores(qn, kn, mask["own"])], axis=-1)
+    clean = scores(qc, kc, mask["upto"])
+    out = jnp.concatenate([
+        jnp.einsum("bhqk,bkhd->bqhd",
+                   jax.nn.softmax(noisy, -1).astype(v.dtype),
+                   jnp.concatenate([vc, vn], axis=1)),
+        jnp.einsum("bhqk,bkhd->bqhd",
+                   jax.nn.softmax(clean, -1).astype(v.dtype), vc)])
+    lse = jnp.concatenate([jax.scipy.special.logsumexp(noisy, -1),
+                           jax.scipy.special.logsumexp(clean, -1)])
+    return out, lse
+
+
+def _own_rows(x, block: int):
+    """x [B, L, H, D] -> [block, B, L, H, D] float32, a broadcast: entry j
+    holds, at every position, row j of the position's own block of
+    `block` positions. The own-block part of block-diffusion attention is
+    written over it as `block` elementwise passes at the operands' own
+    [B, L, H, D] shape (multiply and reduce over D, or weigh and add),
+    which XLA fuses without ever building it: L x block pairs a head as
+    einsums over [.., block, block] put a dim of `block` = 4 on the lanes
+    and pad it 32 times."""
+    b, t, h, d = x.shape
+    blocks = x.astype(jnp.float32).reshape(b, t // block, 1, block, h, d)
+    rows = jnp.broadcast_to(blocks, (b, t // block, block, block, h, d))
+    return jnp.moveaxis(rows, 3, 0).reshape(block, b, t, h, d)
+
+
+def _own_block_scores(qn, kn, block: int):
+    """Scaled scores of every noisy query against the `block` noisy keys
+    of its own block, [block, B, L, H] float32."""
+    scale = 1.0 / qn.shape[-1] ** 0.5
+    return jnp.sum(qn.astype(jnp.float32) * _own_rows(kn, block),
+                   axis=-1) * scale
+
+
+def _to_own_rows(weight, x, block: int):
+    """_own_rows pulled back: [B, L, H, D] whose row j of every block is
+    the sum over the block's positions q of weight[j] at q times x at q;
+    weight [block, B, L, H] float32, x [B, L, H, D]."""
+    b, t, h, d = x.shape
+    n = t // block
+    terms = weight.reshape(block, b, n, block, h, 1) \
+        * x.astype(jnp.float32).reshape(1, b, n, block, h, d)
+    return jnp.moveaxis(terms.sum(3), 0, 2).reshape(b, t, h, d)
+
+
+def _bd_flash(q, k, v, block: int):
+    """(Out, LSE) of block-diffusion attention on the flash kernels, all
+    three parts from the one block-causal geometry of
+    ops/pallas_attention.py: the clean half is a block-causal forward;
+    the noisy half's view of the clean keys is the same geometry with
+    the query's position moved back by one block (q_off = -block:
+    strictly earlier blocks), returned unnormalized as (acc, l, m); its
+    own block is L x block pairs a head, elementwise (_own_rows), merged
+    with that partial by their row statistics as ring attention merges
+    its steps. Dead tiles are neither fetched nor walked, so the score
+    work is L^2 + O(L x tile) pairs a head, where the [2L, 2L] square has
+    4 L^2; nothing of [L, L] reaches HBM."""
+    from . import pallas_attention
+    half = q.shape[0] // 2
+    (qn, qc), (kn, kc), (vn, vc) = ((x[:half], x[half:]) for x in (q, k, v))
+    scale = 1.0 / (q.shape[-1] ** 0.5)
+    out_c, lse_c = pallas_attention._forward(qc, kc, vc, True,
+                                             return_lse=True, block=block)
+    acc1, l1, m1 = pallas_attention.flash_attention_block(
+        qn, kc, vc, -block, 0, scale, True, block=block)
+    l1, m1 = l1.transpose(0, 2, 1), m1.transpose(0, 2, 1)    # [B, L, H]
+    s = _own_block_scores(qn, kn, block)                  # [block, B, L, H]
+    m2 = s.max(0)
+    m = jnp.maximum(m1, m2)
+    p = jnp.exp(s - m)                  # already on the merged maximum
+    a1 = jnp.exp(m1 - m)
+    l = l1 * a1 + p.sum(0)
+    acc2 = jnp.sum(p[..., None] * _own_rows(vn, block), axis=0)
+    out_n = (acc1 * a1[..., None] + acc2) / l[..., None]
+    lse_n = (m + jnp.log(l)).transpose(0, 2, 1)
+    return (jnp.concatenate([out_n.astype(q.dtype), out_c]),
+            jnp.concatenate([lse_n, lse_c]))
+
+
+def _bd_flash_grad(q, k, v, o, lse, do, block: int):
+    """(dQ, dK, dV) of _bd_flash from the saved (Out, LSE): the flash
+    backward on each of the two kernel parts against the merged LSE, as
+    the ring's backward runs it a shard, and the own-block part's few
+    pairs elementwise; the clean keys' gradient is the sum of what the
+    two halves sent them."""
+    from . import pallas_attention
+    half = q.shape[0] // 2
+    scale = 1.0 / (q.shape[-1] ** 0.5)
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    parts = ((x[:half], x[half:])
+             for x in (q, k, v, do, lse, delta.transpose(0, 2, 1)))
+    (qn, qc), (kn, kc), (vn, vc), (don, doc), (lse_n, lse_c), (dl_n, dl_c) \
+        = parts
+    bwd = functools.partial(pallas_attention.flash_attention_bwd_block,
+                            k_off=0, scale=scale, causal=True, block=block)
+    dq_c, dk_c, dv_c = bwd(qc, kc, vc, doc, lse_c, dl_c, q_off=0)
+    dq_n, dk_c2, dv_c2 = bwd(qn, kc, vc, don, lse_n, dl_n, q_off=-block)
+
+    p = jnp.exp(_own_block_scores(qn, kn, block)
+                - lse_n.transpose(0, 2, 1))               # [block, B, L, H]
+    dp = jnp.sum(don.astype(jnp.float32) * _own_rows(vn, block), axis=-1)
+    ds = p * (dp - delta[:half]) * scale
+    dq_own = jnp.sum(ds[..., None] * _own_rows(kn, block), axis=0)
+    dk_n, dv_n = _to_own_rows(ds, qn, block), _to_own_rows(p, don, block)
+
+    def add(x, y):
+        return (x.astype(jnp.float32) + y.astype(jnp.float32)).astype(q.dtype)
+
+    return (jnp.concatenate([add(dq_n, dq_own), dq_c]),
+            jnp.concatenate([dk_n.astype(q.dtype), add(dk_c, dk_c2)]),
+            jnp.concatenate([dv_n.astype(q.dtype), add(dv_c, dv_c2)]))
+
+
+def _bd_takes_flash(ctx, op_, q, count=False) -> bool:
+    """Whether this lowering runs on the flash kernels: use_flash True,
+    or 'auto' and the rule of scaled_dot_product_attention
+    (_flash_wins) on one stream's [B, L, H, D], and the kernels' gate
+    passes that shape with the block length. The op carries no shard_map
+    of its own: a program planned over a mesh of several devices passes
+    use_flash=False. `count` books the decision under
+    op="block_diffusion_attention", a hit or the reason, as _sdpa_paths
+    does: the forward op passes it."""
+    from . import pallas_attention
+    uf = op_.attr("use_flash", "auto")
+    one = jax.ShapeDtypeStruct((q.shape[0] // 2,) + q.shape[1:], q.dtype)
+    if not uf or (uf == "auto" and not _flash_wins(one)):
+        return False
+    reason = pallas_attention.ineligible(one, one, one,
+                                         block=op_.attr("block_length", 1))
+    if count and reason is None:
+        pallas_attention.count_hit(_BD_OP)
+    elif count:
+        pallas_attention.count_fallback(reason, _BD_OP)
+    return reason is None
+
+
+@op(_BD_OP, infer_shape=_sdpa_infer, grad=_sdpa_grad)
+def _block_diffusion_attention(ctx, op_, ins):
+    """Attention of block-diffusion training (arXiv:2503.09573): every
+    sequence runs as two streams of L positions, a noised copy and the
+    clean one, Q/K/V [2B, L, H, D] with the B noisy streams first and
+    their clean streams behind them (K and V may have fewer heads:
+    grouped-query attention, as scaled_dot_product_attention). With
+    b(i) = i // block_length, a noisy query at i sees the noisy keys of
+    its own block (both directions) and the clean keys of earlier blocks;
+    a clean query sees the clean keys of blocks up to its own. One
+    algorithm, two implementations chosen from the shapes
+    (_bd_takes_flash): _bd_flash on the kernels, _bd_einsum elsewhere.
+    Emits LSE [2B, H, L] float32 for the explicit backward."""
+    q, k, v = (jnp.asarray(ins[s][0]) for s in ("Q", "K", "V"))
+    block = op_.attr("block_length", 1)
+    assert q.shape[0] % 2 == 0 and q.shape[1] % block == 0, (q.shape, block)
+    (q, k, v), restore = mxu_cast(ctx, q, k, v)
+    groups = _kv_groups(q, k)
+    k, v = _repeat_kv(k, groups), _repeat_kv(v, groups)
+    run = _bd_flash if _bd_takes_flash(ctx, op_, q, count=True) \
+        else _bd_einsum
+    out, lse = run(q, k, v, block)
+    if restore is not None:
+        out = out.astype(restore)
+    return {"Out": [out], "LSE": [lse]}
+
+
+@op(_BD_OP + "_grad", grad=NO_GRAD, non_diff_inputs=("LSE",))
+def _block_diffusion_attention_grad(ctx, op_, ins):
+    """dQ/dK/dV of block_diffusion_attention: on the kernels from the
+    saved (Out, LSE), no forward run again (_bd_flash_grad); the einsum
+    path differentiates its forward under jax.vjp."""
+    q, k, v, do = (jnp.asarray(ins[s][0]) for s in ("Q", "K", "V",
+                                                    "Out@GRAD"))
+    block = op_.attr("block_length", 1)
+    (q, k, v, do), restore = mxu_cast(ctx, q, k, v, do)
+    groups = _kv_groups(q, k)
+    k, v = _repeat_kv(k, groups), _repeat_kv(v, groups)
+    if _bd_takes_flash(ctx, op_, q):
+        dq, dk, dv = _bd_flash_grad(
+            q, k, v, jnp.asarray(ins["Out"][0]).astype(q.dtype),
+            jnp.asarray(ins["LSE"][0]), do.astype(q.dtype), block)
+    else:
+        _, vjp_fn = jax.vjp(lambda a, b, c: _bd_einsum(a, b, c, block)[0],
+                            q, k, v)
+        dq, dk, dv = vjp_fn(do.astype(q.dtype))
+    dk, dv = _sum_kv_groups(dk, groups), _sum_kv_groups(dv, groups)
+    if restore is not None:
+        dq, dk, dv = (g.astype(restore) for g in (dq, dk, dv))
+    return {name: [g] for name, g in (("Q@GRAD", dq), ("K@GRAD", dk),
+                                      ("V@GRAD", dv))
+            if name in op_.desc.outputs}
 
 
 # --- mixture of experts ------------------------------------------------------

@@ -12,8 +12,17 @@ kernels that recompute the score blocks in VMEM from (Q, K, LSE):
 `flash_dq` (Q/dO tile resident, K/V walked) and `flash_dkv` (K/V tile
 resident, Q/dO walked). All three kernels take global (q_off, k_off)
 position offsets, so the same code serves the single-device path
-(offsets 0) and the per-shard blocks of the ring composition
-(parallel/ring_attention.py).
+(offsets 0), the per-shard blocks of the ring composition
+(parallel/ring_attention.py) and the parts of block-diffusion attention
+(ops/nn_ops._bd_flash).
+
+Masks: none, or causal at the grain of a block of `block` positions:
+keep where floor(q_pos / block) >= floor(k_pos / block). block = 1 is
+the plain causal mask (every cell but one); block > 1 lets a query see
+its whole block of positions, both directions, and every block before it
+(block-diffusion training's clean stream), and with q_off = -block only
+the blocks strictly before its own (its noisy stream's view of the clean
+keys). `block` divides 128, so every K tile starts on a block boundary.
 
 Orientation: every kernel computes the TRANSPOSED block S^T = K Q^T
 [rows of K, rows of Q]. The softmax statistics (running max and sum,
@@ -36,8 +45,11 @@ lane blocks: 12 and 10 heads of 64 are admitted, 3 heads of 64 are not
 ("heads"), D=96 is not ("head_dim"). On the chip the kernels have run
 at D=64 (12 and 10 heads, T=1024: PR 29), D=128 (32 heads, T=4096: PR
 30) and D=256, one head a 256-lane block (20 heads, T=4096, latent
-attention's expanded heads: PR 33); D=32 and D=512 have compiled for a
-described v5e (tests/test_tpu_compile.py) and run interpreted only. Inside a block the heads are told
+attention's expanded heads: PR 33); under the block mask at D=128 (32
+heads, both streams of one 4096-token sequence in blocks of 4: PR 42);
+D=32 and D=512 have compiled for a described v5e
+(tests/test_tpu_compile.py) and run interpreted only. Inside a block the
+heads are told
 apart by a lane mask on the RESIDENT operand: Q (or K, V, dO) with the
 other heads' lanes zeroed contracts over 128 lanes to exactly one
 head's scores, at the MXU cost of the D-wide contraction (a 128-deep
@@ -46,9 +58,9 @@ systolic pass either way) and with no lane slicing or shuffling; dkv's
 mask. The walked side is a VMEM-resident "major" tile (the whole
 sequence up to `_MAJOR` rows, else a grid axis with the carries in
 scratch) stepped through by an in-kernel loop whose bounds come from the
-causal geometry: blocks past the diagonal are never visited, blocks
-below it skip the mask, and a causally dead major tile is clamped in the
-`index_map`, so its DMA is not issued either. Row statistics (LSE, the
+mask's geometry (_reach, _first_seer): blocks past the (block) diagonal
+are never visited, blocks below it skip the mask, and a dead major tile
+is clamped in the `index_map`, so its DMA is not issued either. Row statistics (LSE, the
 ring's m and l, the backward's delta) travel lane-dense as
 [B, H, T/rows, 1, rows].
 
@@ -83,7 +95,7 @@ _NEG = -1e30
 _LANES = 128
 
 # Every reason `ineligible` can return.
-FALLBACK_REASONS = frozenset({"shape", "seq", "heads", "head_dim"})
+FALLBACK_REASONS = frozenset({"shape", "seq", "heads", "head_dim", "block"})
 
 # Rows of the walked operand that stay in VMEM at once. Up to here the
 # whole sequence is one tile and the walk is the in-kernel loop alone;
@@ -140,7 +152,7 @@ def _seq_ok(t: int) -> bool:
     return t >= 8 and t % 8 == 0 and (t <= 128 or t % 128 == 0)
 
 
-def ineligible(q, k, v):
+def ineligible(q, k, v, block: int = 1):
     """None when the flash kernels apply to [B, T, H, D] operands, else
     the reason the caller keeps the einsum path: T must tile and be
     sublane-aligned (T % 8 == 0: Mosaic tiles (8, 128) for f32); the
@@ -152,12 +164,16 @@ def ineligible(q, k, v):
     (ops/nn_ops._repeat_kv), so such a shape is gated, and its hit or
     fallback reason booked, as full attention of the query's heads; a
     K/V that reaches this gate with another head count than Q is
-    "shape"."""
+    "shape". A mask at the grain of `block` positions needs every K tile
+    to start on a block boundary ("block": `block` must divide 128 and
+    T)."""
     if q.ndim != 4 or q.shape != k.shape or q.shape != v.shape:
         return "shape"
     _, t, h, d = q.shape
     if not _seq_ok(t):
         return "seq"
+    if _LANES % block or t % block:
+        return "block"
     return _heads_ineligible(h, d)
 
 
@@ -172,20 +188,20 @@ def block_supports(q, k) -> bool:
             and _heads_ineligible(q.shape[2], q.shape[3]) is None)
 
 
-def count_fallback(reason: str):
-    """pallas_fallback_total{op="scaled_dot_product_attention", reason}:
+def count_fallback(reason: str, op: str = _OP):
+    """pallas_fallback_total{op, reason} (op: the program op that asked,
+    "scaled_dot_product_attention" or "block_diffusion_attention"):
     flash was asked for (use_flash True, or 'auto' on a shape the rule
     gives to the kernels) and the gate kept the einsum path."""
     from . import pallas_conv
-    pallas_conv.count_fallback(_OP, reason)
+    pallas_conv.count_fallback(op, reason)
 
 
-def count_hit():
-    """pallas_kernel_total{op="scaled_dot_product_attention"}: one per
-    lowering of a forward op that took the flash kernels (a step of 12
-    layers traced once reads 12)."""
+def count_hit(op: str = _OP):
+    """pallas_kernel_total{op}: one per lowering of a forward op that
+    took the flash kernels (a step of 12 layers traced once reads 12)."""
     from . import pallas_conv
-    pallas_conv.count_hit(_OP)
+    pallas_conv.count_hit(op)
 
 
 def _fit(t: int, want: int) -> int:
@@ -293,13 +309,13 @@ def _clip(x, hi: int):
     return jnp.clip(x, 0, hi)
 
 
-def _walk(full, masked, block):
-    """Run block(j, masked) over the (lo, hi) range of walked blocks that
+def _walk(full, masked, visit):
+    """Run visit(j, masked) over the (lo, hi) range of walked blocks that
     need no mask and over the range the diagonal crosses (None: no causal
     mask)."""
     def run(bounds, mask):
         def body(j, carry):
-            block(j, mask)
+            visit(j, mask)
             return carry
         lax.fori_loop(*bounds, body, 0)
     run(full, False)
@@ -307,41 +323,73 @@ def _walk(full, masked, block):
         run(masked, True)
 
 
-def _kv_ranges(q_first, k_base, bq: int, bk: int, per: int, causal: bool):
+# The mask's geometry. A causal mask at the grain of `block` positions
+# keeps the pair where floor(q_pos / block) >= floor(k_pos / block): a
+# query sees every key of its own block of positions, both directions,
+# and of the blocks before it. block = 1 is the plain causal mask, and
+# every expression below is then the one the kernels had before the
+# grain was a parameter (the same kernels, instruction for instruction).
+# The walked ranges and the index maps need only the two ends of that
+# relation; the predicate inside a crossed tile needs the key's offset in
+# its block, which is its row's offset in the tile when every K tile
+# starts on a block boundary: `block` divides 128, so it divides every
+# tile, and k_off is a multiple of it (`ineligible` holds the first, the
+# callers the second; q_off is free, which is how block-diffusion
+# training asks for STRICTLY earlier blocks: q_off = -block).
+
+def _reach(q, block: int):
+    """The last key position a query at position q sees."""
+    return q if block == 1 else _floordiv(q, block) * block + (block - 1)
+
+
+def _first_seer(k, block: int):
+    """The first query position that sees the key at position k."""
+    return k if block == 1 else _floordiv(k, block) * block
+
+
+def _kv_ranges(q_first, k_base, bq: int, bk: int, per: int, causal: bool,
+               block: int = 1):
     """_walk's ranges over the `per` K/V blocks of a major tile that
     starts at position k_base, for a resident Q tile of bq rows at
     q_first: the blocks wholly at or below the diagonal, then those it
     crosses; blocks past it are in neither."""
     if not causal:
         return (0, per), None
-    n_full = _clip(_floordiv(q_first - k_base + 1, bk), per)
-    n_live = _clip(_floordiv(q_first + bq - 1 - k_base, bk) + 1, per)
+    n_full = _clip(_floordiv(_reach(q_first, block) - k_base + 1, bk), per)
+    n_live = _clip(
+        _floordiv(_reach(q_first + bq - 1, block) - k_base, bk) + 1, per)
     return (0, n_full), (n_full, n_live)
 
 
-def _kv_major_index(bq: int, mk: int, n_maj: int, causal: bool):
+def _kv_major_index(bq: int, mk: int, n_maj: int, causal: bool,
+                    block: int = 1):
     """index_map half of _kv_ranges: the K/V major tile to fetch for Q
     tile i at grid step kk; past the last live one the index stays put,
     so no DMA is issued for a tile the loop will not walk."""
     def index(i, kk, offs):
         if not causal:
             return kk
-        last = _floordiv(offs[0] + (i + 1) * bq - 1 - offs[1], mk)
+        last = _floordiv(
+            _reach(offs[0] + (i + 1) * bq - 1, block) - offs[1], mk)
         return jnp.minimum(kk, _clip(last, n_maj - 1))
     return index
 
 
-def _q_minus_k(bk: int, bq: int):
+def _q_minus_k(bk: int, bq: int, block: int = 1):
     """Column minus row of a transposed [bk, bq] block: position of the
-    Q row less that of the K row, up to the blocks' offsets. The causal
-    mask keeps where it is >= k_first - q_first."""
-    return (lax.broadcasted_iota(jnp.int32, (bk, bq), 1)
-            - lax.broadcasted_iota(jnp.int32, (bk, bq), 0))
+    Q row less that of the first key of the K row's block of `block`
+    positions (the K row itself at block = 1), up to the tiles' offsets.
+    The mask keeps where it is >= k_first - q_first."""
+    row = lax.broadcasted_iota(jnp.int32, (bk, bq), 0)
+    if block > 1:
+        row = row - row % block
+    return lax.broadcasted_iota(jnp.int32, (bk, bq), 1) - row
 
 
 def _fwd_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, *rest, bq: int,
                 bk: int, mk: int, n_maj: int, d: int, hpb: int,
-                scale: float, causal: bool, normalize: bool):
+                scale: float, causal: bool, normalize: bool,
+                block: int = 1):
     """Grid (B, lane blocks, Tq/bq, Tk/mk): Q tile [bq, L] resident, the
     K/V major tile [mk, L] in VMEM, walked in blocks of bk rows by the
     loop; (acc, m, l) carry in scratch across major tiles. Works on the
@@ -373,9 +421,9 @@ def _fwd_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, *rest, bq: int,
         qt = qt * jnp.asarray(scale, qt.dtype)
     qs = [_only(mask, qt) for mask in masks]
     if causal:
-        diff = _q_minus_k(bk, bq)
+        diff = _q_minus_k(bk, bq, block)
 
-    def block(j, masked):
+    def walked(j, masked):
         start = pl.multiple_of(j * bk, bk)
         kb = k_ref[0, pl.ds(start, bk), :]
         vt = _transpose(v_ref[0, pl.ds(start, bk), :])      # [L, bk]
@@ -395,7 +443,8 @@ def _fwd_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, *rest, bq: int,
             acc_sc[rows, :] = acc_sc[rows, :] * alpha + _dot(
                 vt[rows, :], pt.astype(vt.dtype), _NN)
 
-    _walk(*_kv_ranges(q_first, k_base, bq, bk, per, causal), block)
+    _walk(*_kv_ranges(q_first, k_base, bq, bk, per, causal, block),
+          walked)
 
     @pl.when(kk == n_maj - 1)
     def _finalize():
@@ -482,9 +531,9 @@ def _specs(lanes, hpb, b_res, b_walk, m_walk, walk_index):
 # shapes to one function and calls it per layer (36 pallas_calls lowered
 # one by one cost GPT-2's first step 5 s a lowering, PERF.md section 6).
 @functools.partial(jax.jit, static_argnames=("scale", "causal", "normalize",
-                                             "tile", "major"))
+                                             "tile", "major", "block"))
 def _fwd_call(q, k, v, q_off, k_off, scale, causal, normalize, tile=_TILE,
-              major=_MAJOR):
+              major=_MAJOR, block=1):
     """Returns (out [B,Tq,H,D], stats): stats = (lse,) when normalizing,
     else (m, l); each [B, H, Tq] f32."""
     b, tq, h, d = q.shape
@@ -495,13 +544,14 @@ def _fwd_call(q, k, v, q_off, k_off, scale, causal, normalize, tile=_TILE,
     n_maj = tk // mk
 
     res, walk, res_stat, _ = _specs(
-        lanes, hpb, bq, bk, mk, _kv_major_index(bq, mk, n_maj, causal))
+        lanes, hpb, bq, bk, mk,
+        _kv_major_index(bq, mk, n_maj, causal, block))
     struct = _vma_struct(q)
     n_stat = 1 if normalize else 2
     out, *stats = _call(
         functools.partial(_fwd_kernel, bq=bq, bk=bk, mk=mk, n_maj=n_maj,
                           d=d, hpb=hpb, scale=float(scale), causal=causal,
-                          normalize=normalize),
+                          normalize=normalize, block=block),
         "flash_fwd", (b, h * d // lanes, tq // bq, n_maj),
         [res, walk, walk], [res] + [res_stat] * n_stat,
         [struct((b, tq, h * d), q.dtype if normalize else jnp.float32)]
@@ -511,25 +561,31 @@ def _fwd_call(q, k, v, q_off, k_off, scale, causal, normalize, tile=_TILE,
     return out.reshape(b, tq, h, d), [s.reshape(b, h, tq) for s in stats]
 
 
-def _forward(q, k, v, causal, return_lse=False):
+def _forward(q, k, v, causal, return_lse=False, block=1):
     scale = 1.0 / (q.shape[-1] ** 0.5)
-    out, (lse,) = _fwd_call(q, k, v, 0, 0, scale, causal, normalize=True)
+    out, (lse,) = _fwd_call(q, k, v, 0, 0, scale, causal, normalize=True,
+                            block=block)
     return (out, lse) if return_lse else out
 
 
-def flash_attention_block(q, k, v, q_off, k_off, scale, causal):
-    """Per-shard flash block for ring attention: q [B,Tq,H,D] resident,
-    k/v [B,Tk,H,D] visiting, global offsets as traced scalars. Returns
-    (acc [B,Tq,H,D] unnormalized, l [B,H,Tq], m [B,H,Tq]) in f32 carries,
-    matching parallel.ring_attention._block_attn's online-softmax form."""
+def flash_attention_block(q, k, v, q_off, k_off, scale, causal, block=1):
+    """One part of an attention whose keys come in parts: q [B,Tq,H,D]
+    resident, k/v [B,Tk,H,D] one part of the keys, global offsets as
+    traced scalars (ring attention's visiting shard; block-diffusion
+    attention's clean keys under q_off = -block). Returns (acc
+    [B,Tq,H,D] unnormalized, l [B,H,Tq], m [B,H,Tq]) in f32 carries,
+    matching parallel.ring_attention._block_attn's online-softmax form.
+    A row that sees no key of the part has m = -1e30 and an (acc, l) to
+    be weighted exp(m - m_merged) = 0 by the merge."""
     acc, (m, l) = _fwd_call(q, k, v, q_off, k_off, scale, causal,
-                            normalize=False)
+                            normalize=False, block=block)
     return acc, l, m
 
 
 def _dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
                dq_ref, dq_sc, *, bq: int, bk: int, mk: int, n_maj: int,
-               d: int, hpb: int, scale: float, causal: bool):
+               d: int, hpb: int, scale: float, causal: bool,
+               block: int = 1):
     """Grid and walk of the forward: Q/dO tile resident, K/V walked, dQ^T
     carried in scratch. Recomputes P^T = exp(S^T - LSE) per block;
     dS^T = P^T * (V dO^T - delta); dQ^T = (sum_k K^T dS^T) * scale."""
@@ -554,9 +610,9 @@ def _dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
     qs = [_only(mask, qt) for mask in masks]
     dos = [_only(mask, dot_) for mask in masks]
     if causal:
-        diff = _q_minus_k(bk, bq)
+        diff = _q_minus_k(bk, bq, block)
 
-    def block(j, masked):
+    def walked(j, masked):
         start = pl.multiple_of(j * bk, bk)
         kb = k_ref[0, pl.ds(start, bk), :]
         vb = v_ref[0, pl.ds(start, bk), :]
@@ -573,7 +629,8 @@ def _dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
             rows = slice(hh * d, (hh + 1) * d)
             dq_sc[rows, :] = dq_sc[rows, :] + _dot(kt[rows, :], dst, _NN)
 
-    _walk(*_kv_ranges(q_first, k_base, bq, bk, per, causal), block)
+    _walk(*_kv_ranges(q_first, k_base, bq, bk, per, causal, block),
+          walked)
 
     @pl.when(kk == n_maj - 1)
     def _finalize():
@@ -583,7 +640,7 @@ def _dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
 def _dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
                 dk_ref, dv_ref, dk_sc, dv_sc, *, bq: int, bk: int,
                 mq: int, n_maj: int, d: int, hpb: int, scale: float,
-                causal: bool):
+                causal: bool, block: int = 1):
     """Grid (B, lane blocks, Tk/bk, Tq/mq): K/V tile resident, the
     Q/dO/LSE/delta major tile walked in blocks of bq rows, dK/dV carried
     in scratch. Works on the transposed blocks S^T = K Q^T [bk, bq], so
@@ -611,9 +668,9 @@ def _dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
     ks = [_only(mask, kt) for mask in masks]
     vs = [_only(mask, vt) for mask in masks]
     if causal:
-        diff = _q_minus_k(bk, bq)
+        diff = _q_minus_k(bk, bq, block)
 
-    def block(j, masked):
+    def walked(j, masked):
         start = pl.multiple_of(j * bq, bq)
         qb = q_ref[0, pl.ds(start, bq), :]
         dob = do_ref[0, pl.ds(start, bq), :]
@@ -634,12 +691,14 @@ def _dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
 
     if causal:
         # Q blocks the diagonal crosses, then those wholly below it
-        live0 = _clip(_floordiv(k_first - q_base, bq), per)
-        full0 = _clip(-_floordiv(q_base - (k_first + bk - 1), bq), per)
+        live0 = _clip(
+            _floordiv(_first_seer(k_first, block) - q_base, bq), per)
+        full0 = _clip(-_floordiv(
+            q_base - _first_seer(k_first + bk - 1, block), bq), per)
         full0 = jnp.maximum(full0, live0)
-        _walk((full0, per), (live0, full0), block)
+        _walk((full0, per), (live0, full0), walked)
     else:
-        _walk((0, per), None, block)
+        _walk((0, per), None, walked)
 
     @pl.when(kk == n_maj - 1)
     def _finalize():
@@ -648,10 +707,10 @@ def _dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "causal", "dq_tile",
-                                             "dkv_tile", "major"))
+                                             "dkv_tile", "major", "block"))
 def flash_attention_bwd_block(q, k, v, do, lse, delta, q_off, k_off, scale,
                               causal, dq_tile=_TILE, dkv_tile=_TILE,
-                              major=_MAJOR):
+                              major=_MAJOR, block=1):
     """Flash backward for one (Q shard, K/V shard) pair with global position
     offsets: q/do [B,Tq,H,D], k/v [B,Tk,H,D], lse/delta [B,H,Tq] (scaled-
     score logsumexp from the forward; delta = rowsum(dO*O)). Returns
@@ -673,7 +732,8 @@ def flash_attention_bwd_block(q, k, v, do, lse, delta, q_off, k_off, scale,
     offs = _offsets(q_off, k_off)
     struct = _vma_struct(q)
     q2, k2, v2, do2 = _flat(q), _flat(k), _flat(v), _flat(do)
-    statics = dict(d=d, hpb=hpb, scale=float(scale), causal=causal)
+    statics = dict(d=d, hpb=hpb, scale=float(scale), causal=causal,
+                   block=block)
 
     def stat(x, rows):
         return x.reshape(b, h, tq // rows, 1, rows)
@@ -683,7 +743,8 @@ def flash_attention_bwd_block(q, k, v, do, lse, delta, q_off, k_off, scale,
     n_maj = tk // mk
 
     res, walk, res_stat, _ = _specs(
-        lanes, hpb, bq, bk, mk, _kv_major_index(bq, mk, n_maj, causal))
+        lanes, hpb, bq, bk, mk,
+        _kv_major_index(bq, mk, n_maj, causal, block))
     dq = _call(
         functools.partial(_dq_kernel, bq=bq, bk=bk, mk=mk, n_maj=n_maj,
                           **statics),
@@ -699,7 +760,8 @@ def flash_attention_bwd_block(q, k, v, do, lse, delta, q_off, k_off, scale,
     def q_index(i, kk, offs):
         if not causal:
             return kk
-        first = _floordiv(offs[1] + i * bk - offs[0], mq)
+        first = _floordiv(
+            _first_seer(offs[1] + i * bk, block) - offs[0], mq)
         return jnp.maximum(kk, _clip(first, n_maj - 1))
 
     res, walk, _, walk_stat = _specs(lanes, hpb, bk, bq, mq, q_index)

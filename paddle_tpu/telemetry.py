@@ -829,6 +829,17 @@ METRIC_CATALOG = {
         "the prediction modules' cross-entropy before its weight, the "
         "last step's (telemetry side-fetch; models/mla_moe)",
         dynamic=True),
+    "loss_diffusion": _m(
+        "gauge", ("program",),
+        "position-weighted masked-diffusion cross-entropy of a model "
+        "trained by diffusion over blocks, the last step's (telemetry "
+        "side-fetch; models/block_diffusion_moe)",
+        dynamic=True),
+    "masked_share": _m(
+        "gauge", ("program",),
+        "share of the last step's data tokens the input pipeline masked "
+        "(telemetry side-fetch; models/block_diffusion_moe)",
+        dynamic=True),
     "jax_backend_compiles_total": _m("counter", (),
                                      "XLA backend compiles observed"),
     "jax_backend_compile_seconds_total": _m(

@@ -214,6 +214,27 @@ def test_flash_fwd_bwd_compiles(mosaic, one_chip, shape):
         "flash_dkv", "flash_dq", "flash_fwd"]
 
 
+def _block_diffusion_fwd_bwd(q, k, v):
+    from paddle_tpu.ops import nn_ops
+
+    out, lse = nn_ops._bd_flash(q, k, v, 4)
+    return nn_ops._bd_flash_grad(q, k, v, out, lse, out, 4)
+
+
+def test_flash_kernels_compile_under_the_block_mask(mosaic, one_chip):
+    """The block-diffusion cell's attention op at its shape, both streams
+    of one 4096-token sequence, 32 heads of 128 after the K/V repeat, in
+    blocks of 4: each kernel twice (the clean half block-causal, the
+    noisy half against the clean keys of strictly earlier blocks)."""
+    shape = (2, 4096, 32, 128)
+    one = jax.ShapeDtypeStruct((1,) + shape[1:], BF16)
+    assert pallas_attention.ineligible(one, one, one, block=4) is None
+    assert _compile(_block_diffusion_fwd_bwd, one_chip,
+                    *[(shape, BF16)] * 3) == [
+        "flash_dkv", "flash_dkv", "flash_dq", "flash_dq", "flash_fwd",
+        "flash_fwd"]
+
+
 @pytest.mark.parametrize("rows,dtype", [
     (4096 * 6, BF16), (4096 * 6, jnp.float32), (6144, BF16)],
     ids=["bf16", "float32_no_amp", "first_rung"])
@@ -395,6 +416,48 @@ def test_latent_attention_cell_fits_the_chip_at_4096_tokens(mosaic, one_chip):
     mem = compiled.memory_analysis()
     assert compiled.as_text().count(KERNEL) == 78
     assert mem.alias_size_in_bytes > 8.4e9
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 15.75e9
+
+
+BD_CELL = "sdar-30b-a3b.train-bd4-t4096-ep16-share"
+
+
+def test_block_diffusion_step_compiles_with_no_square_of_scores(
+        mosaic, one_chip):
+    """The block-diffusion cell's step at its own 4096-token sequence and
+    published widths, the depth cut to one block (the six take eight
+    minutes here; tools/describe_step.py sized them: 7.53 GB of
+    temporaries + 5.03 GB of aliased state): the attention op on the
+    flash kernels, two runs of each (the clean half and the noisy half's
+    view of the clean keys), the experts on gmm / tgmm behind the ladder's
+    switch (8 of 128 held: two rungs), and under the attention's scope no
+    array larger than the op's own operands: nothing of [., L, L] or
+    [., 2L, 2L] reaches HBM."""
+    from paddle_tpu import xplane
+    cell = run.load_json("workloads", BD_CELL)
+    config = dict(run.load_json("configs", cell["config"]),
+                  num_hidden_layers=1)
+    length = config["sequence_length"]
+    compiled = describe_step.compile_step(cell, config, one_chip)
+    text = compiled.as_text()
+    kernels = [re.search(r'op_name="[^"]*?(\w+)\)*/pallas_call', line).group(1)
+               for line in text.splitlines() if KERNEL in line]
+    flash = {k: kernels.count(k) for k in set(kernels) if "flash" in k}
+    assert flash == {"flash_fwd": 2, "flash_dq": 2, "flash_dkv": 2}
+    assert "gmm" in kernels and "tgmm" in kernels
+    assert "pd.moe_experts/cond" in text
+    scoped = [i for i in xplane.hlo_instructions(text)
+              if "block_diffusion_attention" in (i.op_name or "")]
+    assert len(scoped) > 20
+    # (H x D = 4096 = L here, so an operand's flat view [1, L, H D] has
+    # two dims of L: the test is on size. One head's [L, L] scores of all
+    # 32 heads are 16 times the op's own [2, L, H, D] operand, the two
+    # streams' square 64 times.)
+    operand = 2 * length * config["num_attention_heads"] * config["head_dim"]
+    for instr in scoped:
+        assert xplane.first_array(instr.shape)[0] <= operand, (
+            instr.name, instr.shape)
+    mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 15.75e9
 
 
