@@ -7,14 +7,21 @@ and output in VMEM scratch, so the [T, T] score matrix never reaches
 HBM. The only residual saved for the backward is the per-row logsumexp
 (m + log l).
 
-Backward: the standard flash backward (FlashAttention-2 style), two
-kernels that recompute the score blocks in VMEM from (Q, K, LSE):
-`flash_dq` (Q/dO tile resident, K/V walked) and `flash_dkv` (K/V tile
-resident, Q/dO walked). All three kernels take global (q_off, k_off)
-position offsets, so the same code serves the single-device path
-(offsets 0), the per-shard blocks of the ring composition
-(parallel/ring_attention.py) and the parts of block-diffusion attention
-(ops/nn_ops._bd_flash).
+Backward: the flash backward (FlashAttention-2 style), recomputing the
+score blocks in VMEM from (Q, K, LSE), as ONE kernel wherever it fits
+(PR 43): `flash_dkv`, the kernel that holds a K/V tile resident and
+walks Q/dO, also accumulates dQ, in float32 VMEM scratch over the whole
+Q sequence of the call, from the dS^T block it already has for dK; S and
+dP are then computed once a block and not twice. Past the Q length whose
+accumulator fits the scoped VMEM (`_split_reason`: 16384 rows at 128
+lanes in bf16; shapes alone, no knob) the backward is two kernels that
+each recompute the blocks: `flash_dq` (Q/dO tile resident, K/V walked)
+and `flash_dkv` without the accumulator. `flash_backward_total{form,
+reason}` books the form of every lowering (`count_backward`). All the
+kernels take global (q_off, k_off) position offsets, so the same code
+serves the single-device path (offsets 0), the per-shard blocks of the
+ring composition (parallel/ring_attention.py) and the parts of
+block-diffusion attention (ops/nn_ops._bd_flash).
 
 Masks: none, or causal at the grain of a block of `block` positions:
 keep where floor(q_pos / block) >= floor(k_pos / block). block = 1 is
@@ -88,8 +95,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-__all__ = ["FALLBACK_REASONS", "count_fallback", "count_hit",
-           "flash_attention", "ineligible", "supports"]
+__all__ = ["BACKWARD_SPLIT_REASONS", "FALLBACK_REASONS", "count_backward",
+           "count_fallback", "count_hit", "flash_attention", "ineligible",
+           "supports"]
 
 _NEG = -1e30
 _LANES = 128
@@ -122,6 +130,14 @@ _MAJOR = 2048
 # T=4096 was measured with, until a PR prices both shapes (section 7).
 # The wrappers take the tiles (and `major`) as static arguments, so the
 # sweep and the tests vary them per call.
+# The fused backward (PR 43) was swept at 256..1024 rows a side at three
+# shapes and keeps the pair; ms a call, dq + dkv -> fused, each at
+# (512, 512): (16, 1024, 12, 64) 1.05 + 1.38 -> 1.64 of 1.64-2.00;
+# (1, 4096, 32, 128) 2.31 + 2.61 -> 3.23 of 3.23-4.74 (3.23 at
+# (1024, 1024) too); (1, 4096, 20, 256) 2.69 + 3.37 -> 4.14 of 4.14-4.56;
+# (1, 4096, 32, 128) under block = 4: 2.33 + 2.63 -> 3.24 at q_off 0,
+# 2.18 + 2.60 -> 3.24 at q_off -4. Fused is 1.19-1.24 x dkv everywhere:
+# no shape where the two calls win, so the rule is VMEM's alone.
 _TILE = (512, 512)
 
 _OP = "scaled_dot_product_attention"
@@ -239,6 +255,9 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+_VMEM_LIMIT = 64 * 1024 * 1024
+
+
 def _compiler_params(semantics):
     """Declare grid-dimension semantics so Mosaic can overlap tile DMA
     with compute: "parallel" dims carry nothing across iterations;
@@ -250,7 +269,7 @@ def _compiler_params(semantics):
         return None
     from jax.experimental.pallas import tpu as pltpu
     return pltpu.CompilerParams(dimension_semantics=tuple(semantics),
-                                vmem_limit_bytes=64 * 1024 * 1024)
+                                vmem_limit_bytes=_VMEM_LIMIT)
 
 
 def _scratch(shape):
@@ -260,8 +279,11 @@ def _scratch(shape):
 
 # Grid (batch, lane block, resident tile, major tile): the first three
 # carry nothing across iterations; the major tiles of the walked side
-# carry the scratch accumulators.
+# carry the scratch accumulators. The fused backward also carries dQ
+# across the resident K tiles (a v5e has one TensorCore: nothing
+# parallel is lost).
 _SEM = ("parallel", "parallel", "parallel", "arbitrary")
+_SEM_FUSED = ("parallel", "parallel", "arbitrary", "arbitrary")
 
 
 def _dot(a, b, dims):
@@ -486,7 +508,7 @@ def _flat(x):
 
 
 def _call(kernel, name, grid, in_specs, out_specs, out_shape, scratch,
-          *operands):
+          *operands, semantics=_SEM):
     """One pallas_call of the family: the offsets ride as the scalar
     prefetch, so the index maps can clamp a causally dead tile."""
     import jax.experimental.pallas as pl
@@ -498,7 +520,7 @@ def _call(kernel, name, grid, in_specs, out_specs, out_shape, scratch,
             out_specs=out_specs,
             scratch_shapes=[_scratch(s) for s in scratch]),
         out_shape=out_shape, interpret=_interpret(),
-        compiler_params=_compiler_params(_SEM))(*operands)
+        compiler_params=_compiler_params(semantics))(*operands)
 
 
 def _specs(lanes, hpb, b_res, b_walk, m_walk, walk_index):
@@ -638,16 +660,32 @@ def _dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
 
 
 def _dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
-                dk_ref, dv_ref, dk_sc, dv_sc, *, bq: int, bk: int,
-                mq: int, n_maj: int, d: int, hpb: int, scale: float,
-                causal: bool, block: int = 1):
+                dk_ref, dv_ref, *rest, bq: int, bk: int, mq: int,
+                n_maj: int, d: int, hpb: int, scale: float, causal: bool,
+                block: int = 1, n_k: int = 0):
     """Grid (B, lane blocks, Tk/bk, Tq/mq): K/V tile resident, the
     Q/dO/LSE/delta major tile walked in blocks of bq rows, dK/dV carried
     in scratch. Works on the transposed blocks S^T = K Q^T [bk, bq], so
     LSE and delta are [1, bq] rows broadcast along sublanes:
-    dV = sum_q P^T dO; dK = (sum_q dS^T Q) * scale."""
+    dV = sum_q P^T dO; dK = (sum_q dS^T Q) * scale.
+
+    n_k > 0 (the K tiles of the call) is the FUSED backward, the whole
+    gradient in this one call: `rest` then leads with dQ's output block,
+    the whole [Tq, L] of a (batch, lane block), and ends with its float32
+    accumulator [Tq/bq, L, bq], one transposed tile a Q block, which
+    lives across the K tiles as well (that grid axis is "arbitrary"
+    then). The dS^T block dK is made from is what dQ needs too:
+    dQ^T[:, q block] += K^T dS^T, one head's D sublanes at a time as
+    `_dq_kernel` adds it, the resident K turned once a grid step. Q
+    blocks this K tile never sees add nothing, which leaves their rows
+    right; the accumulator is zeroed at the first K tile and scaled,
+    turned back and stored at the last."""
     import jax.experimental.pallas as pl
 
+    if n_k:
+        dq_ref, dk_sc, dv_sc, dq_sc = rest
+    else:
+        dk_sc, dv_sc = rest
     i = pl.program_id(2)    # k tile
     kk = pl.program_id(3)   # q major tile
     k_first = off_ref[1] + i * bk
@@ -662,6 +700,12 @@ def _dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
 
     masks = _head_masks(bk, k_ref.shape[-1], d, hpb)
     kt = k_ref[0]
+    if n_k:
+        @pl.when((i == 0) & (kk == 0))
+        def _init_dq():
+            dq_sc[...] = jnp.zeros_like(dq_sc)
+
+        k_t = _transpose(kt)                                 # [L, bk]
     if fold:
         kt = kt * jnp.asarray(scale, kt.dtype)
     vt = v_ref[0]
@@ -686,6 +730,11 @@ def _dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
             dpt = _dot(vs[hh], dob, _NT)
             dst = (pt * (dpt - dl_ref[0, hh, j])).astype(qb.dtype)
             dks.append(_dot(dst, qb, _NN))
+            if n_k:
+                rows = slice(hh * d, (hh + 1) * d)
+                at = kk * per + j
+                dq_sc[at, rows, :] = dq_sc[at, rows, :] + _dot(
+                    k_t[rows, :], dst, _NN)
         dv_sc[...] = dv_sc[...] + _by_head(masks, dvs)
         dk_sc[...] = dk_sc[...] + _by_head(masks, dks)
 
@@ -705,9 +754,60 @@ def _dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
         dk_ref[0] = (dk_sc[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
 
+    if n_k:
+        @pl.when((i == n_k - 1) & (kk == n_maj - 1))
+        def _finalize_dq():
+            def store(at, carry):
+                rows = pl.ds(pl.multiple_of(at * bq, bq), bq)
+                dq_ref[0, rows, :] = _transpose(
+                    dq_sc[at] * scale).astype(dq_ref.dtype)
+                return carry
+            lax.fori_loop(0, n_maj * per, store, 0)
 
-@functools.partial(jax.jit, static_argnames=("scale", "causal", "dq_tile",
-                                             "dkv_tile", "major", "block"))
+
+# Every ground `_split_reason` can give for the two-call backward.
+BACKWARD_SPLIT_REASONS = frozenset({"vmem"})
+
+
+def _split_reason(tq: int, tk: int, lanes: int, itemsize: int, dkv_tile,
+                  major: int):
+    """None where the backward runs fused, else why it keeps two calls.
+    From what the lowering sees alone (Tq, the lane block, the dtype,
+    the tiles): "vmem" when dQ's float32 accumulator over the whole Q
+    sequence of the call and its output block, beside what `flash_dkv`
+    holds anyway, pass half of the scoped VMEM the family asks for
+    (`_compiler_params`); the other half is the compiler's (the
+    [bk, bq] float32 blocks in flight count here at six). At 128 lanes
+    in bf16 that is a Tq past 16384; the cells' 1024 and 4096 take 1 and
+    4-8 MB."""
+    bk, bq = _fit(tk, dkv_tile[0]), _fit(tq, dkv_tile[1])
+    mq = _major(tq, bq, major)
+    dq = tq * lanes * (4 + 2 * itemsize)      # scratch; output, two buffers
+    walked = 2 * 2 * mq * lanes * itemsize    # Q and dO, two buffers each
+    # K, V, dK, dV, two buffers each; dK's and dV's scratch
+    resident = bk * lanes * (4 * 2 * itemsize + 2 * 4)
+    blocks = 6 * bk * bq * 4
+    if dq + walked + resident + blocks > _VMEM_LIMIT // 2:
+        return "vmem"
+    return None
+
+
+def count_backward(reason):
+    """flash_backward_total{form, reason}: one per lowering of a flash
+    backward (`flash_attention_bwd_block`: an attention gradient op's,
+    each part of block-diffusion attention's, a ring step's), form
+    "fused" (reason "") or "split" with the ground of
+    BACKWARD_SPLIT_REASONS."""
+    from .. import telemetry
+    telemetry.counter(
+        "flash_backward_total",
+        "lowerings of a flash attention backward, by form: fused (one "
+        "K/V-resident kernel that also accumulates dQ) or split (flash_dq "
+        "+ flash_dkv) with the shape ground that kept the two calls",
+        labels=("form", "reason")).labels(
+            form="split" if reason else "fused", reason=reason or "").inc()
+
+
 def flash_attention_bwd_block(q, k, v, do, lse, delta, q_off, k_off, scale,
                               causal, dq_tile=_TILE, dkv_tile=_TILE,
                               major=_MAJOR, block=1):
@@ -717,12 +817,35 @@ def flash_attention_bwd_block(q, k, v, do, lse, delta, q_off, k_off, scale,
     (dq, dk, dv) in the inputs' dtypes. Offsets (0, 0) with Tq == Tk == T
     is exactly the single-device flash backward; the ring backward calls it
     per visiting shard (parallel/ring_attention.py). `dq_tile` and
-    `dkv_tile` are each kernel's (resident rows, walked block rows)."""
+    `dkv_tile` are each kernel's (resident rows, walked block rows).
+
+    One call or two, by shape (`_split_reason`, booked by
+    `count_backward`): fused, `flash_dkv` alone also accumulates dQ
+    (at `dkv_tile`; `dq_tile` is then unread); split, `flash_dq` and
+    `flash_dkv` each compute the score blocks."""
+    assert block_supports(q, k), (
+        f"flash_attention_bwd_block needs tileable shapes (tq={q.shape[1]}, "
+        f"tk={k.shape[1]}, h={q.shape[2]}, d={q.shape[3]}); gate callers "
+        f"with block_supports()")
+    lanes, _ = _lane_block(*q.shape[2:])
+    reason = _split_reason(q.shape[1], k.shape[1], lanes,
+                           jnp.dtype(q.dtype).itemsize, dkv_tile, major)
+    count_backward(reason)
+    return _bwd_call(q, k, v, do, lse, delta, q_off, k_off, float(scale),
+                     causal, dq_tile, dkv_tile, major, block,
+                     fused=reason is None)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "causal", "dq_tile",
+                                             "dkv_tile", "major", "block",
+                                             "fused"))
+def _bwd_call(q, k, v, do, lse, delta, q_off, k_off, scale, causal,
+              dq_tile=_TILE, dkv_tile=_TILE, major=_MAJOR, block=1,
+              fused=True):
+    """flash_attention_bwd_block's kernels in the form it chose (the
+    sweep and the tests ask for either)."""
     b, tq, h, d = q.shape
     tk = k.shape[1]
-    assert block_supports(q, k), (
-        f"flash_attention_bwd_block needs tileable shapes (tq={tq}, "
-        f"tk={tk}, h={h}, d={d}); gate callers with block_supports()")
     lanes, hpb = _lane_block(h, d)
     # rows no shard ever validated carry lse = -inf (possible only for
     # non-causal corner cases); push them to +big so exp(s - lse) == 0 and
@@ -738,20 +861,21 @@ def flash_attention_bwd_block(q, k, v, do, lse, delta, q_off, k_off, scale,
     def stat(x, rows):
         return x.reshape(b, h, tq // rows, 1, rows)
 
-    bq, bk = _fit(tq, dq_tile[0]), _fit(tk, dq_tile[1])
-    mk = _major(tk, bk, major)
-    n_maj = tk // mk
+    if not fused:
+        bq, bk = _fit(tq, dq_tile[0]), _fit(tk, dq_tile[1])
+        mk = _major(tk, bk, major)
+        n_maj = tk // mk
 
-    res, walk, res_stat, _ = _specs(
-        lanes, hpb, bq, bk, mk,
-        _kv_major_index(bq, mk, n_maj, causal, block))
-    dq = _call(
-        functools.partial(_dq_kernel, bq=bq, bk=bk, mk=mk, n_maj=n_maj,
-                          **statics),
-        "flash_dq", (b, h * d // lanes, tq // bq, n_maj),
-        [res, walk, walk, res, res_stat, res_stat], res,
-        struct((b, tq, h * d), q.dtype), [(lanes, bq)],
-        offs, q2, k2, v2, do2, stat(lse, bq), stat(delta, bq))
+        res, walk, res_stat, _ = _specs(
+            lanes, hpb, bq, bk, mk,
+            _kv_major_index(bq, mk, n_maj, causal, block))
+        dq = _call(
+            functools.partial(_dq_kernel, bq=bq, bk=bk, mk=mk, n_maj=n_maj,
+                              **statics),
+            "flash_dq", (b, h * d // lanes, tq // bq, n_maj),
+            [res, walk, walk, res, res_stat, res_stat], res,
+            struct((b, tq, h * d), q.dtype), [(lanes, bq)],
+            offs, q2, k2, v2, do2, stat(lse, bq), stat(delta, bq))
 
     bk, bq = _fit(tk, dkv_tile[0]), _fit(tq, dkv_tile[1])
     mq = _major(tq, bq, major)
@@ -765,14 +889,30 @@ def flash_attention_bwd_block(q, k, v, do, lse, delta, q_off, k_off, scale,
         return jnp.maximum(kk, _clip(first, n_maj - 1))
 
     res, walk, _, walk_stat = _specs(lanes, hpb, bk, bq, mq, q_index)
-    dk, dv = _call(
+    out_specs = [res, res]
+    out_shape = [struct((b, tk, h * d), k.dtype),
+                 struct((b, tk, h * d), v.dtype)]
+    scratch = [(bk, lanes), (bk, lanes)]
+    if fused:
+        import jax.experimental.pallas as pl
+        # dQ's block is the whole Q sequence of a (batch, lane block): its
+        # index holds still over the K tiles and the major tiles, so it
+        # is written back once, after the last of them
+        out_specs.append(pl.BlockSpec(
+            (1, tq, lanes), lambda bb, g, i, kk, offs: (bb, 0, g)))
+        out_shape.append(struct((b, tq, h * d), q.dtype))
+        scratch.append((tq // bq, lanes, bq))
+    outs = _call(
         functools.partial(_dkv_kernel, bq=bq, bk=bk, mq=mq, n_maj=n_maj,
-                          **statics),
+                          n_k=tk // bk if fused else 0, **statics),
         "flash_dkv", (b, h * d // lanes, tk // bk, n_maj),
-        [walk, res, res, walk, walk_stat, walk_stat], [res, res],
-        [struct((b, tk, h * d), k.dtype), struct((b, tk, h * d), v.dtype)],
-        [(bk, lanes), (bk, lanes)],
-        offs, q2, k2, v2, do2, stat(lse, bq), stat(delta, bq))
+        [walk, res, res, walk, walk_stat, walk_stat], out_specs, out_shape,
+        scratch, offs, q2, k2, v2, do2, stat(lse, bq), stat(delta, bq),
+        semantics=_SEM_FUSED if fused else _SEM)
+    if fused:
+        dk, dv, dq = outs
+    else:
+        dk, dv = outs
 
     return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape))
 

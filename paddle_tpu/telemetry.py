@@ -862,6 +862,9 @@ METRIC_CATALOG = {
                               "pallas kernel launches"),
     "pallas_fallback_total": _m("counter", ("op", "reason"),
                                 "pallas kernels that fell back to XLA"),
+    "flash_backward_total": _m("counter", ("form", "reason"),
+                               "flash attention backward lowerings, fused "
+                               "(one kernel) or split by a shape ground"),
     "quant_kernel_total": _m("counter", ("op",),
                              "ops routed through int8/fp8 quantization"),
     "quant_fallback_total": _m("counter", ("op", "reason"),

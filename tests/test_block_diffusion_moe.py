@@ -197,11 +197,18 @@ def test_kernels_against_the_einsum_path_and_the_dense_mask(block):
     from paddle_tpu import telemetry
     q, k, v = operands(block, (2, 256, 2, 128), kv_heads=1)
     hits = dict(telemetry.read_series("pallas_kernel_total"))
+    backward = dict(telemetry.read_series("flash_backward_total"))
     key = "op=block_diffusion_attention"
     outs, grads, cot = attention_op(q, k, v, block, use_flash=True)
     assert dict(telemetry.read_series("pallas_kernel_total"))[key] == \
         hits.get(key, 0) + 2   # run_op lowers the forward twice (alone for
-    # its shape, then with the backward); the grad op books nothing
+    # its shape, then with the backward); the grad op books nothing there,
+    # and each of its two kernel parts one fused backward (PR 43)
+    booked = dict(telemetry.read_series("flash_backward_total"))
+    assert booked["form=fused,reason="] == \
+        backward.get("form=fused,reason=", 0) + 2
+    assert {k: n for k, n in booked.items() if "split" in k} == \
+        {k: n for k, n in backward.items() if "split" in k}
     plain, plain_grads, _ = attention_op(q, k, v, block, use_flash=False)
     close(outs["Out"], plain["Out"], tol=1e-5)
     close(outs["LSE"], plain["LSE"], tol=1e-5)
@@ -210,14 +217,16 @@ def test_kernels_against_the_einsum_path_and_the_dense_mask(block):
     against_dense(outs, grads, cot, q, k, v, block, tol=2e-5)
 
 
+@pytest.mark.parametrize("fused", [True, False], ids=["fused", "split"])
 @pytest.mark.parametrize("q_off", [0, -8], ids=["upto", "earlier"])
-def test_walk_ranges_over_several_tiles_and_major_tiles(q_off):
+def test_walk_ranges_over_several_tiles_and_major_tiles(q_off, fused):
     """The block-causal geometry over 4 x 4 tiles of 128 rows in two
     major tiles of 256, at block 8, with the query's position moved back
     by one block (strictly earlier blocks) and not: the three kernels
-    against a dense mask on positions. Rows that see no key (the first
-    block under q_off = -block) are left out of the forward's comparison
-    and carry a zero cotangent."""
+    against a dense mask on positions, the backward as one call (dQ
+    accumulated over the four K tiles beside dK and dV) and as two. Rows
+    that see no key (the first block under q_off = -block) are left out
+    of the forward's comparison and carry a zero cotangent."""
     block, t, tiles = 8, 512, dict(tile=(128, 128), major=256)
     rng = np.random.default_rng(5)
     q, k, v, do = (jnp.asarray(rng.standard_normal((1, t, 1, 128)),
@@ -238,9 +247,10 @@ def test_walk_ranges_over_several_tiles_and_major_tiles(q_off):
         q, k, v, q_off, 0, scale, True, normalize=True, block=block, **tiles)
     close(out[:, seen], want[:, seen], tol=1e-5)
     delta = jnp.sum(do * out, axis=-1).transpose(0, 2, 1)
-    grads = pallas_attention.flash_attention_bwd_block(
+    grads = pallas_attention._bwd_call(
         q, k, v, do, lse, delta, q_off, 0, scale, True, dq_tile=tiles["tile"],
-        dkv_tile=tiles["tile"], major=tiles["major"], block=block)
+        dkv_tile=tiles["tile"], major=tiles["major"], block=block,
+        fused=fused)
     for g, g_ref in zip(grads, vjp(do)):
         close(g, g_ref, tol=2e-5)
 

@@ -377,11 +377,16 @@ def test_smoke_refuses_a_kernel_in_the_train_step(hits, held, match):
         chip_smoke._check_no_kernels(hits, calls)
 
 
-def test_smoke_wants_three_flash_kernels_per_layer():
+def test_smoke_wants_two_flash_kernels_per_layer():
+    """The forward and the fused backward (PR 43); a flash_dq call is the
+    split backward, which the smoke's sequence length never takes."""
     calls = {"scaled_dot_product_attention/flash_fwd": 4,
-             "scaled_dot_product_attention_grad/flash_dq": 4,
              "scaled_dot_product_attention_grad/flash_dkv": 4}
     chip_smoke._check_flash_kernels(calls, 4)
+    with pytest.raises(AssertionError, match="4 flash_dq"):
+        chip_smoke._check_flash_kernels(
+            dict(calls, **{"scaled_dot_product_attention_grad/flash_dq": 4}),
+            4)
     calls.pop("scaled_dot_product_attention_grad/flash_dkv")
     with pytest.raises(AssertionError, match="0 flash_dkv"):
         chip_smoke._check_flash_kernels(calls, 4)
