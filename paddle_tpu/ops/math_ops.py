@@ -227,15 +227,74 @@ _activations = {
 }
 
 
-def _make_act(fn):
+@jax.custom_vjp
+def _pinned(v):
+    """v behind an optimization barrier: every reader reads the array and
+    none can take its evaluation into an operand. The cotangent passes
+    freely (a barrier's own transpose would pin it too, and the gradient
+    product would write it out for a pass of its own: +7.6 % where this
+    form reads +11.4 %, PERF.md section 6, PR 44)."""
+    return jax.lax.optimization_barrier(v)
+
+
+_pinned.defvjp(lambda v: (_pinned(v), None), lambda _, g: (g,))
+
+
+def _erf_gelu(x, a):
+    """jax.nn.gelu(x, approximate=False) term for term, its erfc pinned:
+    the polynomial is evaluated once a layer and direction, in the dtype
+    the op's arithmetic has it in, and 0.5 * x * erfc (two multiplies) is
+    what a reader recomputes; the gradient reads the same array."""
+    if not jnp.issubdtype(x.dtype, jnp.inexact):
+        return jax.nn.gelu(x, approximate=False)
+    sqrt_half = np.sqrt(0.5).astype(x.dtype)
+    return jnp.array(0.5 * x * _pinned(jax.lax.erfc(-x * sqrt_half)),
+                     dtype=x.dtype)
+
+
+# The activations dear enough to be evaluated once and kept, each as the
+# same arithmetic with its dear term pinned: those whose evaluation costs
+# more vector time an element than a product that takes it into an
+# operand costs MXU time. Erf gelu is 144 float32 instructions an element
+# on a v5e (no bf16 VPU), all but a few of them erfc's polynomial; left
+# free, XLA clones it into the operand of every product that reads gelu's
+# output and into the epilogue of the one that makes its gradient, and
+# each of those runs at the vector unit's pace, 38-42 % of the MXU's
+# (gpt2.train-t1024). What is kept is erfc and not gelu's output: that is
+# what XLA keeps by itself under GSPMD's plan (gpt2-large.train-fsdp2-tp2,
+# whose compiled step this leaves as it was), one array a layer beside
+# the pre-activation where the output would be a second. A dozen vector
+# operations an element (relu, relu2, silu, swish, tanh) recomputed in an
+# operand are cheaper than 2 B written and read: not here.
+KEPT_ACTS = {"gelu": _erf_gelu}
+
+
+def _count_kept(name):
+    """activation_kept_total{act}: one a lowering of a forward op (the
+    gradient op's re-trace is silent, as for the kernels' counters)."""
+    from .. import quant, telemetry
+    if not quant.counters_suppressed():
+        telemetry.counter(
+            "activation_kept_total",
+            "lowerings of an activation whose dear term is pinned behind "
+            "an optimization barrier, so it is evaluated once and not "
+            "inside the products that read it",
+            labels=("act",)).labels(act=name).inc()
+
+
+def _make_act(name, fn):
     def lower(ctx, op_, ins):
         x = jnp.asarray(ins["X"][0])
+        kept = KEPT_ACTS.get(name)
+        if kept is not None:
+            _count_kept(name)
+            return {"Out": [kept(x, op_)]}
         return {"Out": [fn(x, op_)]}
     return lower
 
 
 for _name, _fn in _activations.items():
-    register(_name, lower=_make_act(_fn), infer_shape=same_as_input())
+    register(_name, lower=_make_act(_name, _fn), infer_shape=same_as_input())
 
 
 # --- reductions -------------------------------------------------------------

@@ -865,6 +865,9 @@ METRIC_CATALOG = {
     "flash_backward_total": _m("counter", ("form", "reason"),
                                "flash attention backward lowerings, fused "
                                "(one kernel) or split by a shape ground"),
+    "activation_kept_total": _m("counter", ("act",),
+                                "lowerings of an activation evaluated once "
+                                "and kept (ops/math_ops.py KEPT_ACTS)"),
     "quant_kernel_total": _m("counter", ("op",),
                              "ops routed through int8/fp8 quantization"),
     "quant_fallback_total": _m("counter", ("op", "reason"),

@@ -363,6 +363,21 @@ def test_hybrid_mixer_step_holds_no_chunk_by_chunk_block(mosaic, one_chip):
     assert any("bf16[1,32,4096,128]" in line for line in under)
 
 
+_GPT2_LAYER = {}
+
+
+def _gpt2_one_layer_step(one_chip):
+    """One layer of the `gpt2` cell's train step at its batch of 16,
+    compiled once for the tests that read it (call under the `mosaic`
+    fixture): (cell, config, compiled)."""
+    if not _GPT2_LAYER:
+        cell = run.load_json("workloads", "gpt2.train-t1024")
+        config = dict(run.load_json("configs", cell["config"]), n_layer=1)
+        _GPT2_LAYER["step"] = (
+            cell, config, describe_step.compile_step(cell, config, one_chip))
+    return _GPT2_LAYER["step"]
+
+
 def test_gpt2_step_holds_no_float32_logits(mosaic, one_chip):
     """One layer of the `gpt2` cell's train step at its batch of 16: the
     loss reads the head's bf16 logits where they lie. A float32 array of
@@ -371,9 +386,7 @@ def test_gpt2_step_holds_no_float32_logits(mosaic, one_chip):
     read again, 10 ms each on the chip, and only the whole step shows it
     (head and loss alone fuse well); with either, the temporaries were
     6.62 GB against 3.73 (PERF.md section 6, PR 31)."""
-    cell = run.load_json("workloads", "gpt2.train-t1024")
-    config = dict(run.load_json("configs", cell["config"]), n_layer=1)
-    compiled = describe_step.compile_step(cell, config, one_chip)
+    cell, config, compiled = _gpt2_one_layer_step(one_chip)
     text = compiled.as_text()
     rows = cell["batch"] * config["n_positions"]
     wide = describe_step.wide_instructions(text, rows * 1024)
@@ -384,6 +397,43 @@ def test_gpt2_step_holds_no_float32_logits(mosaic, one_chip):
                if " gather(" in line and "50257" in line]
     assert not gathers, gathers
     assert compiled.memory_analysis().temp_size_in_bytes < 4.2e9
+
+
+def test_gpt2_step_evaluates_gelu_outside_every_products_operand(
+        mosaic, one_chip):
+    """The same step: erf gelu is 144 float32 instructions an element on
+    a v5e, and a product that takes them into an operand runs at the
+    vector unit's pace (38-42 % of the MXU's for the down projection and
+    its grad-weight product, PERF.md section 6, PR 44). The op's erfc is
+    pinned (`math_ops.KEPT_ACTS`), so no fused computation that is an
+    operand inside a fusion holding a convolution carries it, the
+    polynomial lives in at most two computations a layer (one: the up
+    projection's epilogue; the parent held it in three more: both of
+    those operands and the grad-input product's epilogue), and the kept
+    array (100.7 MB in bf16, beside the pre-activation) costs under
+    0.25 GB of temporaries a layer over the parent's 3.73."""
+    _, _, compiled = _gpt2_one_layer_step(one_chip)
+    text = compiled.as_text()
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%([\w.\-]+) \(", line)
+        if head and line.rstrip().endswith("{"):
+            name = head.group(2)
+            comps[name] = (bool(head.group(1)), [])
+        elif name is not None:
+            comps[name][1].append(line)
+    erfc = {n for n, (entry, lines) in comps.items()
+            if not entry and any("pd.gelu/erfc" in line for line in lines)}
+    assert 1 <= len(erfc) <= 2, sorted(erfc)
+    products = [lines for _, lines in comps.values()
+                if any(" convolution(" in line for line in lines)]
+    assert products
+    operands = {m.group(1) for lines in products for line in lines
+                for m in [re.search(r" fusion\(.*calls=%([\w.\-]+)", line)]
+                if m}
+    assert operands
+    assert not operands & erfc, sorted(operands & erfc)
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.73e9 + 0.25e9
 
 
 MLA_CELL = "glm-4.7-flash.train-mla-mtp-ep8-share"
