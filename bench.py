@@ -447,15 +447,10 @@ def _perf_fields(probe=None):
         out["bound"] = (attributed[0]["bound"] if attributed
                         else "unattributed")
         # per-kernel scoreboard (ISSUE 11): measured vs roofline-minimum
-        # device time per op+shape, plus how much of the conv-family time
-        # the Pallas kernels served — the evidence columns the kernel
-        # phase of the MFU campaign is judged by
+        # device time per op+shape
         ke = report.get("kernel_efficiency")
         if ke:
             out["kernel_efficiency"] = ke[:5]
-        if report.get("pallas_kernel_coverage") is not None:
-            out["pallas_kernel_coverage"] = round(
-                report["pallas_kernel_coverage"], 4)
         if report.get("input_bound") is not None:
             out["input_bound"] = report["input_bound"]
             if report.get("input_bound_remedy"):

@@ -49,11 +49,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from . import kernel_choice
 from .common import in_var, same_as_input, set_out
 from .registry import NO_GRAD, op
 
-__all__ = ["GMM_FALLBACK_REASONS", "SSD_SCAN_FALLBACK_REASONS",
-           "gmm_ineligible", "ssd_scan_chunked", "ssd_scan_ineligible"]
+__all__ = ["gmm_ineligible", "ssd_scan_chunked", "ssd_scan_ineligible"]
 
 
 def _f32(x):
@@ -209,31 +209,14 @@ def ssd_scan_chunked(x, dt, a, b, c, chunk, dtype=jnp.float32):
     return y.reshape(bsz, t + pad, h, p)[:, :t]
 
 
-def _book_kernel_choice(op_type: str, reason):
-    """pallas_kernel_total{op} for a lowering that took its Pallas
-    kernels (`reason` None), pallas_fallback_total{op, reason} for one
-    that kept the XLA path; nothing in the gradient op's re-trace of the
-    forward (registry.generic_grad_lower suppresses the counters)."""
-    from . import pallas_conv
-    from .. import quant
-    if quant.counters_suppressed():
-        return
-    if reason is None:
-        pallas_conv.count_hit(op_type)
-    else:
-        pallas_conv.count_fallback(op_type, reason)
-
-
 _SCAN_OP = "ssd_scan"
-
-# Every reason ssd_scan_ineligible can return.
-SSD_SCAN_FALLBACK_REASONS = frozenset({"chunk", "state", "heads"})
 
 
 def ssd_scan_ineligible(chunk: int, heads_a_group: int, p: int, n: int):
     """None when the scan kernels (ops/pallas_scan.py) take chunks of
     `chunk` steps, groups of `heads_a_group` heads of P = `p` and states
-    of N = `n`, else the reason ssd_scan_chunked keeps the scan. Time
+    of N = `n`, else the reason ssd_scan_chunked keeps the scan
+    (kernel_choice.REASONS["ssd_scan"]). Time
     runs along the kernels' lanes, so a chunk must fill 128-lane blocks
     (`chunk`), as must the states' N where B and C stand with time on the
     sublanes (`state`); a head is P sublanes of the group's block, whole
@@ -274,7 +257,7 @@ def _ssd_scan(ctx, op_, ins):
     chunk = op_.attr("chunk_size", 128)
     reason = ssd_scan_ineligible(chunk, x.shape[2] // b.shape[2], x.shape[3],
                                  b.shape[3])
-    _book_kernel_choice(_SCAN_OP, reason)
+    kernel_choice.book(_SCAN_OP, reason)
     shared = dict(chunk=chunk, dtype=_compute_dtype(ctx))
     if reason is None:
         core = functools.partial(ssd_scan_kernels, interpret=_interpret(),
@@ -366,15 +349,13 @@ def _moe_balance_bias(ctx, op_, ins):
 _GMM_OP = "moe_experts"
 _GMM_ROWS = 128
 
-# Every reason gmm_ineligible can return.
-GMM_FALLBACK_REASONS = frozenset({"rows", "width"})
-
 
 def gmm_ineligible(rows: int, d: int, f: int):
     """None when Pallas' grouped matmul (megablox gmm) takes [rows, d] x
     [held, d, f] and back, else the reason lax.ragged_dot keeps the
-    product: `rows` = tokens x top_k must be a multiple of the row tile,
-    d and f at least one lane block wide."""
+    product (kernel_choice.REASONS["moe_experts"]): `rows` = tokens x
+    top_k must be a multiple of the row tile, d and f at least one lane
+    block wide."""
     if rows % _GMM_ROWS:
         return "rows"
     if d < 128 or f < 128:
@@ -683,7 +664,7 @@ def _moe_experts(ctx, op_, ins):
     routed = sizes.sum()
 
     reason = gmm_ineligible(n * k, x.shape[-1], w1.shape[-1])
-    _book_kernel_choice(_GMM_OP, reason)
+    kernel_choice.book(_GMM_OP, reason)
     kernel = _interpret() if reason is None else None
     rungs = _capacity_ladder(n * k, held, op_.attr("num_experts", held))
     rung = (routed > jnp.asarray(rungs[:-1], jnp.int32)).sum()

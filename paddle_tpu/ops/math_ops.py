@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import kernel_choice
 from .registry import NO_GRAD, op, register
 from .common import (SelectedRowsVal, maybe_dense, broadcast_y_to_x, in_var, matmul_shape, mxu_cast, out_var,
                      same_as_input, set_out)
@@ -272,8 +273,8 @@ KEPT_ACTS = {"gelu": _erf_gelu}
 def _count_kept(name):
     """activation_kept_total{act}: one a lowering of a forward op (the
     gradient op's re-trace is silent, as for the kernels' counters)."""
-    from .. import quant, telemetry
-    if not quant.counters_suppressed():
+    from .. import telemetry
+    if not kernel_choice.in_retrace():
         telemetry.counter(
             "activation_kept_total",
             "lowerings of an activation whose dear term is pinned behind "

@@ -37,6 +37,7 @@ import numpy as np
 
 from ..framework.desc import OpDesc
 from ..framework.framework import grad_var_name
+from . import kernel_choice
 from .registry import NO_GRAD, infer_grad_shapes, op, register
 from .common import (SelectedRowsVal, in_var, mxu_cast, out_var,
                      same_as_input, set_out, to_np_dtype)
@@ -371,12 +372,11 @@ def _conv2d(ctx, op_, ins):
     per conv.
 
     Every float conv is XLA's convolution (`_lax_conv`), on one chip as
-    under a mesh: on a v5e it ran ResNet-50 eleven times faster than the
-    row-per-grid-step Pallas kernels of ops/pallas_conv.py (PERF.md §6,
-    PR 25), so nothing selects between them. Only AMP O3 has a second
-    route: the int8 kernel, behind quant.ineligible_conv."""
+    under a mesh: on a v5e it ran ResNet-50 eleven times faster than
+    row-per-grid-step Pallas kernels did (PERF.md §6, PR 25; deleted at
+    PR 45). Only AMP O3 has a second route: the int8 kernel
+    pallas_conv.conv2d_q8, behind quant.ineligible_conv."""
     from . import layout as layout_mod
-    from . import pallas_conv
     from .. import quant
     x = jnp.asarray(ins["Input"][0])
     w = jnp.asarray(ins["Filter"][0])
@@ -395,7 +395,8 @@ def _conv2d(ctx, op_, ins):
             x, w, s, p, d, groups, qmode,
             mesh=getattr(ctx.program, "_mesh", None))
         if qreason is None:
-            pallas_conv.count_hit(op_.type)
+            # booked under the kernel's op, whichever conv op lowers
+            kernel_choice.book("conv2d", None)
             quant.count_hit(op_.type)
             out = quant.qconv2d(
                 x, w, s, p, d, qmode,
@@ -1017,10 +1018,7 @@ def _sdpa_paths(ctx, op_, q, k, v, count=False):
         return "einsum", None
     reason = pallas_attention.ineligible(shard(q), shard(k), shard(v))
     if count:
-        if reason is None:
-            pallas_attention.count_hit()
-        else:
-            pallas_attention.count_fallback(reason)
+        kernel_choice.book("scaled_dot_product_attention", reason)
     return ("flash", partition) if reason is None else ("einsum", None)
 
 
@@ -1313,10 +1311,8 @@ def _bd_takes_flash(ctx, op_, q, count=False) -> bool:
         return False
     reason = pallas_attention.ineligible(one, one, one,
                                          block=op_.attr("block_length", 1))
-    if count and reason is None:
-        pallas_attention.count_hit(_BD_OP)
-    elif count:
-        pallas_attention.count_fallback(reason, _BD_OP)
+    if count:
+        kernel_choice.book(_BD_OP, reason)
     return reason is None
 
 

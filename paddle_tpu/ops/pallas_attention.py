@@ -82,8 +82,8 @@ On CPU (the test mesh) the kernels run under the Pallas interpreter
 (interpret=True): same code path, no Mosaic compile. Shapes must tile: T
 divisible by 128, or T itself when at most 128 (sublane-aligned,
 T % 8 == 0); callers fall back to attention_reference otherwise
-(ops/nn_ops.py wiring) and book the reason (`count_fallback`), as they
-book a lowering that took the kernels (`count_hit`).
+(ops/nn_ops.py wiring) and book the gate's answer, the kernels or the
+reason (`kernel_choice.book`).
 """
 
 from __future__ import annotations
@@ -95,15 +95,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-__all__ = ["BACKWARD_SPLIT_REASONS", "FALLBACK_REASONS", "count_backward",
-           "count_fallback", "count_hit", "flash_attention", "ineligible",
-           "supports"]
+__all__ = ["BACKWARD_SPLIT_REASONS", "count_backward", "flash_attention",
+           "ineligible", "supports"]
 
 _NEG = -1e30
 _LANES = 128
-
-# Every reason `ineligible` can return.
-FALLBACK_REASONS = frozenset({"shape", "seq", "heads", "head_dim", "block"})
 
 # Rows of the walked operand that stay in VMEM at once. Up to here the
 # whole sequence is one tile and the walk is the in-kernel loop alone;
@@ -140,9 +136,6 @@ _MAJOR = 2048
 # no shape where the two calls win, so the rule is VMEM's alone.
 _TILE = (512, 512)
 
-_OP = "scaled_dot_product_attention"
-
-
 def _lane_block(h: int, d: int):
     """(lanes, heads) of one grid step's block of the [.., H*D] view, or
     the reason no block fits."""
@@ -170,7 +163,8 @@ def _seq_ok(t: int) -> bool:
 
 def ineligible(q, k, v, block: int = 1):
     """None when the flash kernels apply to [B, T, H, D] operands, else
-    the reason the caller keeps the einsum path: T must tile and be
+    the reason the caller keeps the einsum path (kernel_choice.REASONS,
+    one set for both attention ops): T must tile and be
     sublane-aligned (T % 8 == 0: Mosaic tiles (8, 128) for f32); the
     heads must fill whole 128-lane blocks of the [B, T, H*D] view
     ("heads": 3 heads of 64), and D must divide 128 or be a multiple of
@@ -202,22 +196,6 @@ def block_supports(q, k) -> bool:
     differ in length; each must tile."""
     return (q.ndim == 4 and _seq_ok(q.shape[1]) and _seq_ok(k.shape[1])
             and _heads_ineligible(q.shape[2], q.shape[3]) is None)
-
-
-def count_fallback(reason: str, op: str = _OP):
-    """pallas_fallback_total{op, reason} (op: the program op that asked,
-    "scaled_dot_product_attention" or "block_diffusion_attention"):
-    flash was asked for (use_flash True, or 'auto' on a shape the rule
-    gives to the kernels) and the gate kept the einsum path."""
-    from . import pallas_conv
-    pallas_conv.count_fallback(op, reason)
-
-
-def count_hit(op: str = _OP):
-    """pallas_kernel_total{op}: one per lowering of a forward op that
-    took the flash kernels (a step of 12 layers traced once reads 12)."""
-    from . import pallas_conv
-    pallas_conv.count_hit(op)
 
 
 def _fit(t: int, want: int) -> int:

@@ -28,6 +28,7 @@ import numpy as np
 
 from ..framework.desc import OpDesc
 from ..framework.framework import grad_var_name
+from . import kernel_choice
 
 # sentinel: op has no gradient (metrics, int ops, assignment of constants…)
 NO_GRAD = "no_grad"
@@ -220,11 +221,9 @@ def generic_grad_lower(ctx, op, ins):
         return flat
 
     primals = [fwd_ins[s][i] for s, i in diff_paths]
-    # the vjp re-traces the forward lowering, which would book a second
-    # quant hit/fallback sample for an op that already counted itself on
-    # the forward trace
-    from .. import quant
-    with quant.suppress_counters():
+    # the vjp re-traces the forward lowering, which counted itself on
+    # the forward trace: nothing is booked a second time
+    with kernel_choice.retrace():
         out_vals, vjp_fn = jax.vjp(fwd_fn, primals)
 
     # Cotangents matched to fwd_fn's actual flat output.

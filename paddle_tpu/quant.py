@@ -47,7 +47,6 @@ force the "error_bound" fallback) fall back to bf16.
 
 from __future__ import annotations
 
-import contextlib
 import math
 import os
 
@@ -61,8 +60,7 @@ __all__ = [
     "count_hit", "error_estimate", "fp8_supported", "gate_for_op",
     "ineligible_conv", "ineligible_matmul", "prequantize",
     "prequantized", "qconv2d",
-    "qmatmul", "quantize_channelwise", "suppress_counters",
-    "counters_suppressed",
+    "qmatmul", "quantize_channelwise",
     "weight_qparams",
 ]
 
@@ -245,34 +243,14 @@ def gate_for_op(op_type, ins, attrs, mode, nhwc=False):
 
 # --- counters -----------------------------------------------------------
 
-_SUPPRESS_COUNTERS = False
-
-
-@contextlib.contextmanager
-def suppress_counters():
-    """Silence count_hit/count_fallback on this thread of lowering:
-    generic_grad_lower's vjp re-traces forward lowerings, which would
-    book a second quant_fallback_total/quant_kernel_total sample for an
-    op that already counted itself on the forward trace."""
-    global _SUPPRESS_COUNTERS
-    prev = _SUPPRESS_COUNTERS
-    _SUPPRESS_COUNTERS = True
-    try:
-        yield
-    finally:
-        _SUPPRESS_COUNTERS = prev
-
-
-def counters_suppressed() -> bool:
-    """Inside suppress_counters(): a lowering that books its own Pallas
-    gate (ops/hybrid_ops.py) stays silent there too."""
-    return _SUPPRESS_COUNTERS
-
+# Both are silent in a gradient op's re-trace of a forward lowering
+# (ops/kernel_choice.py): the forward op counted itself on its own trace.
 
 def count_fallback(op: str, reason: str):
-    if _SUPPRESS_COUNTERS:
-        return
     from . import telemetry
+    from .ops import kernel_choice
+    if kernel_choice.in_retrace():
+        return
     telemetry.counter(
         "quant_fallback_total",
         "O3 lowerings that fell back from the quantized path to bf16, "
@@ -281,9 +259,10 @@ def count_fallback(op: str, reason: str):
 
 
 def count_hit(op: str):
-    if _SUPPRESS_COUNTERS:
-        return
     from . import telemetry
+    from .ops import kernel_choice
+    if kernel_choice.in_retrace():
+        return
     telemetry.counter(
         "quant_kernel_total",
         "lowerings served by the quantized (int8/fp8) path, by op",

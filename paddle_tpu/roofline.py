@@ -748,7 +748,7 @@ def collect_report(trace_dir, steps: Optional[int] = None,
                 else (UNATTRIBUTED, None)
             a = agg.setdefault(key, {
                 "ps": 0.0, "work_flops": 0.0, "work_bytes": 0.0,
-                "instrs": {}, "joined": False, "mosaic_ps": 0.0,
+                "instrs": {}, "joined": False,
                 "scope": r["scope"], "role": r["role"]})
             a["ps"] += ps
             if r["joined"]:
@@ -756,8 +756,6 @@ def collect_report(trace_dir, steps: Optional[int] = None,
                 a["work_flops"] += (r["flops"] or 0.0) * r["count"]
                 a["work_bytes"] += (r["bytes"] or 0) * r["count"]
                 a["instrs"][r["name"]] = (r["flops"], r["bytes"], r["shape"])
-                if r["opcode"] == "custom-call" and r["flops"] is None:
-                    a["mosaic_ps"] += ps
     if not agg:
         return None
 
@@ -814,8 +812,7 @@ def collect_report(trace_dir, steps: Optional[int] = None,
                      "shape": shape,
                      "required_flops": c["flops"] if c else None,
                      "peak_factor": factor if c else None,
-                     "min_ps": min_ps, "efficiency": efficiency,
-                     "mosaic_ps": a["mosaic_ps"]})
+                     "min_ps": min_ps, "efficiency": efficiency})
 
     wf = None
     try:
@@ -865,18 +862,6 @@ def collect_report(trace_dir, steps: Optional[int] = None,
          "min_ms": round(r["min_ps"] / 1e9, 4),
          "efficiency": round(r["efficiency"], 4)}
         for r in rows if r["efficiency"] is not None]
-    # fraction of device conv-family seconds served by Pallas kernels
-    # (pallas lowers to custom-call instructions; lax convs to
-    # convolution/fusion ones), so the bench trajectory shows coverage
-    # growing as gates widen — flash-attention custom-calls map to the
-    # sdpa op name and stay out of the conv family by construction
-    conv_ps = pallas_ps = 0
-    for r in rows:
-        if "conv" in r["op"] and r["op"] != UNATTRIBUTED:
-            conv_ps += r["ps"]
-            pallas_ps += r["mosaic_ps"]
-    report["pallas_kernel_coverage"] = \
-        (pallas_ps / conv_ps) if conv_ps else None
     # input-bound verdict: the waterfall blames the host input path when
     # the device idles more than it computes and infeed+host-gap dominate
     duty = report["device_duty_cycle"]
@@ -926,12 +911,6 @@ def collect_report(trace_dir, steps: Optional[int] = None,
                 labels=("op", "shape")).labels(
                 op=row["op"], shape=row["shape"] or "?").set(
                 row["efficiency"])
-    if report["pallas_kernel_coverage"] is not None:
-        telemetry.gauge(
-            "pallas_kernel_coverage",
-            "fraction of device conv-family seconds served by Pallas "
-            "kernels in the latest traced session").set(
-            report["pallas_kernel_coverage"])
     for gname in ("mfu_nominal", "mfu_vs_sustained", "device_duty_cycle"):
         if report.get(gname) is not None:
             telemetry.gauge(
@@ -1036,10 +1015,6 @@ def format_report(report: Dict[str, Any]) -> List[str]:
             lines.append(
                 f"[kernel] {op:24s}{shape:14s} {r['ms']:10.4f} "
                 f"{r['min_ms']:10.4f} {r['efficiency']:9.1%}")
-    cov = report.get("pallas_kernel_coverage")
-    if cov is not None:
-        lines.append(f"[kernel] pallas conv coverage {cov:.1%} of device "
-                     f"conv-family time")
     if report.get("input_bound"):
         lines.append("[verdict] input-bound: " +
                      report.get("input_bound_remedy", ""))

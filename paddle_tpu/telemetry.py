@@ -872,8 +872,6 @@ METRIC_CATALOG = {
                              "ops routed through int8/fp8 quantization"),
     "quant_fallback_total": _m("counter", ("op", "reason"),
                                "quantizable ops kept at full precision"),
-    "pallas_kernel_coverage": _m("gauge", (),
-                                 "fraction of eligible ops on pallas"),
     "kernel_efficiency": _m("gauge", ("op", "shape"),
                             "measured/roofline kernel efficiency"),
     "device_op_seconds_total": _m("counter", ("op",),

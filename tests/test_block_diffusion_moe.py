@@ -20,7 +20,7 @@ import pytest
 import paddle_tpu as fluid
 from paddle_tpu import executor as executor_mod
 from paddle_tpu.framework.framework import grad_var_name
-from paddle_tpu.ops import nn_ops, pallas_attention
+from paddle_tpu.ops import kernel_choice, nn_ops, pallas_attention
 
 from benchmarks import run
 from test_nemotron_h import close, first_step, run_op
@@ -287,7 +287,8 @@ def test_what_may_leak_where():
 def test_the_gate_knows_the_block_length(shape, block, reason):
     q = jax.ShapeDtypeStruct(shape, jnp.float32)
     assert pallas_attention.ineligible(q, q, q, block=block) == reason
-    assert reason is None or reason in pallas_attention.FALLBACK_REASONS
+    assert reason is None or \
+        reason in kernel_choice.REASONS["block_diffusion_attention"]
 
 
 def test_a_declined_shape_is_booked_with_its_reason():

@@ -23,7 +23,7 @@ sys.path.insert(0, REPO)
 import bench  # noqa: E402
 import chip_smoke  # noqa: E402
 from paddle_tpu import chip, executor as executor_mod, memory, roofline  # noqa: E402
-from paddle_tpu.ops import pallas_attention, pallas_conv  # noqa: E402
+from paddle_tpu.ops import kernel_choice, pallas_attention, pallas_conv  # noqa: E402
 
 
 # --- chip_smoke.py ----------------------------------------------------------
@@ -223,12 +223,12 @@ def test_harness_uses_the_same_cache():
 
 # --- kernels and gates agree ------------------------------------------------
 
-def _lax_conv(x, w, s, p, d):
+def _lax_conv_i32(x, w, s, p, d):
     return jax.lax.conv_general_dilated(
         x, jnp.transpose(w, (2, 3, 1, 0)), window_strides=s,
         padding=[(p[0], p[0]), (p[1], p[1])], rhs_dilation=d,
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
-        preferred_element_type=jnp.float32)
+        preferred_element_type=jnp.int32)
 
 
 # (H, W, K, stride, padding, dilation): the strides Mosaic refused as
@@ -241,23 +241,23 @@ def _lax_conv(x, w, s, p, d):
     (7, 7, 3, 1, 1, 1),      # stride 1: the identity case
 ])
 def test_strided_conv_gate_and_kernels_agree(h, w_, k, s, p, d):
+    """The gate passes each stride's geometry and the kernel that
+    survives (the int8 conv of AMP O3) reads the right taps of the
+    de-interleaved row: equal to XLA's integer conv on the same int8
+    operands under the same dequantization scales."""
     rng = np.random.default_rng(0)
-    x = jnp.asarray(rng.standard_normal((2, h, w_, 128)), jnp.bfloat16)
-    w = jnp.asarray(rng.standard_normal((128, 128, k, k)) * 0.05,
-                    jnp.bfloat16)
     args = ((s, s), (p, p), (d, d))
-    assert pallas_conv.ineligible(x, w, *args) is None
-    f32 = jnp.float32
-    want, vjp = jax.vjp(lambda w_: _lax_conv(x.astype(f32), w_, *args),
-                        w.astype(f32))
-    got = pallas_conv.conv2d(x, w, *args, out_dtype=f32)
-    np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
-    # grad-filter takes the same taps
-    dout = jnp.asarray(rng.standard_normal(want.shape), jnp.bfloat16)
-    gw = pallas_conv.conv2d_grad_filter(x, dout, (k, k), *args,
-                                        out_dtype=f32)
-    np.testing.assert_allclose(gw, vjp(dout.astype(f32))[0],
-                               rtol=2e-2, atol=2e-1)
+    # the gate is asked before quantization, with the bf16 operands
+    assert pallas_conv.ineligible(
+        jax.ShapeDtypeStruct((2, h, w_, 128), jnp.bfloat16),
+        jax.ShapeDtypeStruct((128, 128, k, k), jnp.bfloat16), *args) is None
+    x = jnp.asarray(rng.integers(-127, 128, (2, h, w_, 128)), jnp.int8)
+    w = jnp.asarray(rng.integers(-127, 128, (128, 128, k, k)), jnp.int8)
+    dq = jnp.asarray(rng.uniform(0.5, 2.0, 128) * 1e-4, jnp.float32)
+    want = _lax_conv_i32(x, w, *args).astype(jnp.float32) * dq
+    got = pallas_conv.conv2d_q8(x, w, *args, dq, out_dtype=jnp.float32)
+    assert got.shape == want.shape
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=0)
 
 
 def test_deinterleave_is_identity_at_stride_one():
@@ -281,7 +281,7 @@ def test_conv_gate_declines_a_partitioned_step():
     one = make_mesh((1,), ("dp",), devices=jax.devices()[:1])
     assert pallas_conv.ineligible(x, w, *args, mesh=many) == "mesh"
     assert pallas_conv.ineligible(x, w, *args, mesh=one) is None
-    assert "mesh" in pallas_conv.FALLBACK_REASONS
+    assert "mesh" in kernel_choice.REASONS["conv2d"]
 
 
 def test_declined_flash_is_counted_and_takes_einsum():

@@ -250,7 +250,7 @@ def test_scan_kernels_give_a_padded_tail_zero_gradients(dtype):
 
 
 def test_the_scan_gate_is_a_function_of_shapes_and_names_its_reasons():
-    from paddle_tpu.ops import hybrid_ops
+    from paddle_tpu.ops import hybrid_ops, kernel_choice
     gate = {  # (chunk, heads a group, P, N)
         (128, 8, 64, 128): None, (256, 8, 64, 128): None,
         (128, 2, 64, 256): None, (128, 16, 32, 128): None,
@@ -264,7 +264,7 @@ def test_the_scan_gate_is_a_function_of_shapes_and_names_its_reasons():
     for shape, reason in gate.items():
         assert hybrid_ops.ssd_scan_ineligible(*shape) == reason, shape
     assert {r for r in gate.values() if r} \
-        == hybrid_ops.SSD_SCAN_FALLBACK_REASONS
+        == kernel_choice.REASONS["ssd_scan"]
 
 
 def _scan_counts():
@@ -294,8 +294,9 @@ def test_a_scan_that_does_not_tile_keeps_the_chunked_form_and_says_why():
 
 def test_the_scans_gradient_op_books_no_second_hit():
     """A program with the gradient op books as many lowerings on the
-    kernels as the same program without it: generic_grad_lower traces the
-    forward again under quant.suppress_counters()."""
+    kernels as the same program without it (generic_grad_lower traces
+    the forward again inside kernel_choice.retrace()), and the kernels'
+    gradient through the executor is the recurrence's."""
     ins = scan_inputs(np.random.default_rng(11), 1, 128, 2, 1)
 
     def hits_of(wrt):

@@ -1,15 +1,14 @@
-"""Pallas conv kernel suite (ops/pallas_conv.py, ISSUE 11) and the conv
-route (PR 25): parity gates for every kernel, the tiling gate's reason
-labels, every float conv and its backward as XLA's convolution whatever
-the shape, and the CPU scan+grad-conv warning.
+"""The int8 conv kernel (ops/pallas_conv.py: the one conv kernel since PR
+45) and the conv route (PR 25): the kernel's parity, the tiling gate's
+reason labels, every float conv and its backward as XLA's convolution
+whatever the shape, and the CPU scan+grad-conv warning.
 
-Each kernel ships a parity gate against the lax.conv reference it
-replaces: forward/grad-input/grad-filter vs lax.conv_general_dilated /
-jax.vjp on the same bf16-rounded operands (tolerance covers only f32
-accumulation-order drift, observed relative error <=3e-4), conv2d_stats
-vs conv2d bitwise, bn_apply vs the normalize formula bitwise. On CPU the
-kernels run under Pallas interpret mode, so this whole file is tier-1
-under JAX_PLATFORMS=cpu and re-runs compiled on a real TPU unchanged.
+conv2d_q8 is held to an integer lax.conv_general_dilated on the same
+int8 operands with the same dequantization scales: int32 accumulation
+is exact in any order, so the two differ by the float32 product's last
+bit at most. On CPU the kernel runs under Pallas interpret mode, so this
+whole file is tier-1 under JAX_PLATFORMS=cpu and re-runs compiled on a
+real TPU unchanged.
 """
 
 import collections
@@ -29,7 +28,7 @@ from paddle_tpu import executor as em
 from paddle_tpu import telemetry
 from paddle_tpu.framework import unique_name
 from paddle_tpu.ops import layout as layout_mod
-from paddle_tpu.ops import pallas_conv, registry
+from paddle_tpu.ops import kernel_choice, pallas_conv, registry
 
 
 @pytest.fixture(autouse=True)
@@ -48,7 +47,7 @@ def _series(name, label=None):
 
 # --- direct-kernel parity ----------------------------------------------
 
-# (H, W, KH, KW, strides, paddings, dilations) — C fixed at one 128 lane
+# (H, W, KH, KW, strides, paddings, dilations), C fixed at one 128 lane
 # tile. Covers stride, asymmetric spatial dims, 1x1, dilation+padding,
 # and mixed per-dim stride/padding.
 CASES = [
@@ -69,76 +68,48 @@ def _operands(h, w, kh, kw, n=2, c=128, seed=0):
 
 
 def _ref_fwd(x, wt, s, p, d):
-    """f32 lax.conv on the same bf16-rounded operands: the kernels only
-    reassociate the f32 accumulation, so this is the exact target."""
+    """f32 lax.conv on the same bf16-rounded operands: the explicit
+    backward's target."""
     return jax.lax.conv_general_dilated(
         x.astype(jnp.float32), wt.astype(jnp.float32),
         window_strides=s, padding=[(p[0], p[0]), (p[1], p[1])],
         rhs_dilation=d, dimension_numbers=("NHWC", "OIHW", "NHWC"))
 
 
+def q8_operands(h, w, kh, kw, n=2, c=128, seed=0):
+    """int8 x [N, H, W, C], int8 w [C, C, KH, KW] over the full range and
+    a per-channel dequantization vector of uneven scales."""
+    rng = np.random.default_rng(seed)
+    x = jnp.asarray(rng.integers(-127, 128, (n, h, w, c)), jnp.int8)
+    wt = jnp.asarray(rng.integers(-127, 128, (c, c, kh, kw)), jnp.int8)
+    dq = jnp.asarray(rng.uniform(0.5, 2.0, c) * 1e-4, jnp.float32)
+    return x, wt, dq
+
+
+def q8_reference(x, wt, dq, s, p, d):
+    """The plain form of conv2d_q8: XLA's conv on the int8 operands,
+    accumulated in int32, times the same scales in float32."""
+    acc = jax.lax.conv_general_dilated(
+        x, wt, window_strides=s, padding=[(p[0], p[0]), (p[1], p[1])],
+        rhs_dilation=d, dimension_numbers=("NHWC", "OIHW", "NHWC"),
+        preferred_element_type=jnp.int32)
+    return acc.astype(jnp.float32) * dq
+
+
 @pytest.mark.parametrize("case", CASES)
 def test_forward_parity(case):
+    """The gate passes the geometry (asked, as the O3 route asks, with
+    the bf16 operands before they are quantized) and the kernel equals
+    the integer reference: stride, uneven spatial dims, 1x1, dilation
+    with padding, per-dim stride and padding."""
     h, w, kh, kw, s, p, d = case
-    x, wt = _operands(h, w, kh, kw)
-    assert pallas_conv.supports(x, wt, s, p, d)
-    y = pallas_conv.conv2d(x, wt, s, p, d, out_dtype=jnp.float32)
-    ref = _ref_fwd(x, wt, s, p, d)
+    assert pallas_conv.ineligible(*_operands(h, w, kh, kw), s, p, d) is None
+    x, wt, dq = q8_operands(h, w, kh, kw)
+    y = pallas_conv.conv2d_q8(x, wt, s, p, d, dq, out_dtype=jnp.float32)
+    ref = q8_reference(x, wt, dq, s, p, d)
+    assert y.shape == ref.shape and np.abs(np.asarray(ref)).max() > 1
     np.testing.assert_allclose(np.asarray(y), np.asarray(ref),
-                               rtol=2e-2, atol=2e-2)
-
-
-@pytest.mark.parametrize("case", CASES)
-def test_grad_parity(case):
-    h, w, kh, kw, s, p, d = case
-    x, wt = _operands(h, w, kh, kw, seed=1)
-    ref, vjp = jax.vjp(lambda a, b: _ref_fwd(a, b, s, p, d), x, wt)
-    ct = jnp.asarray(
-        np.random.default_rng(2).standard_normal(ref.shape), jnp.bfloat16)
-    dx_ref, dw_ref = vjp(ct.astype(jnp.float32))
-    dx = pallas_conv.conv2d_grad_input(ct, wt, (h, w), s, p, d,
-                                       out_dtype=jnp.float32)
-    dw = pallas_conv.conv2d_grad_filter(x, ct, (kh, kw), s, p, d,
-                                        out_dtype=jnp.float32)
-    np.testing.assert_allclose(np.asarray(dx), np.asarray(dx_ref),
-                               rtol=3e-2, atol=3e-2)
-    np.testing.assert_allclose(np.asarray(dw), np.asarray(dw_ref),
-                               rtol=3e-2, atol=3e-2)
-
-
-def test_stats_kernel_matches_plain_conv():
-    """conv2d_stats' output tile is the SAME accumulation as conv2d —
-    bitwise — and its channel sums match the rounded output."""
-    h, w, kh, kw, s, p, d = CASES[1]
-    x, wt = _operands(h, w, kh, kw, seed=3)
-    y = pallas_conv.conv2d(x, wt, s, p, d)
-    ys, csum, csq = pallas_conv.conv2d_stats(x, wt, s, p, d)
-    np.testing.assert_array_equal(np.asarray(ys, np.float32),
-                                  np.asarray(y, np.float32))
-    yf = np.asarray(ys, np.float32).reshape(-1, 128)
-    np.testing.assert_allclose(np.asarray(csum), yf.sum(0),
-                               rtol=1e-3, atol=1e-3)
-    np.testing.assert_allclose(np.asarray(csq), (yf * yf).sum(0),
-                               rtol=1e-3, atol=1e-3)
-
-
-def test_bn_apply_matches_formula():
-    rng = np.random.default_rng(4)
-    x2 = jnp.asarray(rng.standard_normal((16, 128)), jnp.bfloat16)
-    scale = jnp.asarray(rng.standard_normal(128), jnp.float32)
-    bias = jnp.asarray(rng.standard_normal(128), jnp.float32)
-    mean = jnp.asarray(rng.standard_normal(128), jnp.float32)
-    var = jnp.asarray(rng.random(128) + 0.5, jnp.float32)
-    eps = 1e-5
-    ybn, yact = pallas_conv.bn_apply(x2, scale, bias, mean, var, eps,
-                                     jax.nn.relu)
-    ref = ((x2.astype(jnp.float32) - mean) * jax.lax.rsqrt(var + eps)
-           * scale + bias).astype(jnp.bfloat16)
-    np.testing.assert_array_equal(np.asarray(ybn, np.float32),
-                                  np.asarray(ref, np.float32))
-    np.testing.assert_array_equal(
-        np.asarray(yact, np.float32),
-        np.asarray(jax.nn.relu(ref), np.float32))
+                               rtol=1e-6, atol=0)
 
 
 # --- the eligibility gate ----------------------------------------------
@@ -148,14 +119,14 @@ def test_ineligible_reasons():
     w = jnp.zeros((128, 128, 3, 3), jnp.bfloat16)
     args = ((1, 1), (1, 1), (1, 1))
     assert pallas_conv.ineligible(x, w, *args) is None
-    assert pallas_conv.supports(x, w, *args)
     assert pallas_conv.ineligible(x[0], w, *args) == "rank"
     assert pallas_conv.ineligible(x, w, *args, groups=2) == "groups"
     assert pallas_conv.ineligible(
         x.astype(jnp.float32), w, *args) == "dtype"
     assert pallas_conv.ineligible(
         x[..., :120], w[:, :120], *args) == "channels"
-    # padding beyond (K-1)*d breaks the grad-input transposed-conv pads
+    # padding beyond (K-1)*d: the deleted grad-input kernel's bound,
+    # kept so that the route is what it was
     assert pallas_conv.ineligible(
         x, w, (1, 1), (5, 5), (1, 1)) == "geometry"
     # output collapses to zero rows
@@ -170,7 +141,7 @@ def test_ineligible_reasons():
     # failing Mosaic compilation at run time
     wide = jax.ShapeDtypeStruct((1, 6, 4096, 128), jnp.bfloat16)
     assert pallas_conv.ineligible(wide, w, *args) == "geometry"
-    assert pallas_conv.FALLBACK_REASONS == {
+    assert kernel_choice.REASONS["conv2d"] == {
         "mesh", "rank", "groups", "dtype", "channels", "attrs", "geometry"}
 
 

@@ -102,10 +102,13 @@ def test_sparse_lint_catches_missing_entry(monkeypatch):
 
 
 def test_pallas_table_consistent():
-    """pallas_conv.KERNELS must agree with the op registry,
-    quant.QUANT_OPS and its own FALLBACK_REASONS: since PR 25 the one
-    dispatch is the int8 conv under O3, and an orphan entry or a missing
-    one doesn't raise, the route is just never audited."""
+    """pallas_conv.KERNELS must agree with the op registry and
+    quant.QUANT_OPS (since PR 25 the one conv dispatch is the int8 conv
+    under O3, and an orphan entry or a missing one doesn't raise, the
+    route is just never audited), kernel_choice.REASONS with the source
+    of every op's gate, and kernel_choice must be the only creator of
+    the two counters (tests/test_kernel_choice.py takes the table op by
+    op)."""
     problems = _load_checker().check_pallas_table()
     assert not problems, "; ".join(f"{w}: {m}" for w, m in problems)
 
@@ -113,14 +116,15 @@ def test_pallas_table_consistent():
 def test_pallas_lint_catches_grad_entry_and_missing_op(monkeypatch):
     """Sanity: a conv2d_grad entry (no backward kernel is dispatched:
     conv2d_grad transposes the lax conv), a quantizable conv op dropped
-    from KERNELS, and a shrunk FALLBACK_REASONS each trip the lint."""
-    from paddle_tpu.ops import pallas_conv
+    from KERNELS, and a shrunk table of the conv gate's reasons each
+    trip the lint."""
+    from paddle_tpu.ops import kernel_choice, pallas_conv
 
     checker = _load_checker()
     orig = pallas_conv.KERNELS
     monkeypatch.setattr(
         pallas_conv, "KERNELS",
-        dict(orig, conv2d_grad=(pallas_conv.conv2d_grad_filter,)))
+        dict(orig, conv2d_grad=(pallas_conv.conv2d_q8,)))
     problems = checker.check_pallas_table()
     assert any("conv2d_grad" in m for _, m in problems), problems
 
@@ -131,8 +135,8 @@ def test_pallas_lint_catches_grad_entry_and_missing_op(monkeypatch):
     assert any("depthwise_conv2d" in m for _, m in problems), problems
 
     monkeypatch.setattr(pallas_conv, "KERNELS", orig)
-    monkeypatch.setattr(pallas_conv, "FALLBACK_REASONS",
-                        pallas_conv.FALLBACK_REASONS - {"geometry"})
+    monkeypatch.setitem(kernel_choice.REASONS, "conv2d",
+                        kernel_choice.REASONS["conv2d"] - {"geometry"})
     problems = checker.check_pallas_table()
     assert any("geometry" in m for _, m in problems), problems
 

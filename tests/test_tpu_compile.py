@@ -19,7 +19,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from benchmarks import run
-from paddle_tpu.ops import hybrid_ops, pallas_attention, pallas_conv
+from paddle_tpu.ops import (hybrid_ops, kernel_choice, pallas_attention,
+                            pallas_conv)
 from tools import describe_step
 
 BF16 = jnp.bfloat16
@@ -86,41 +87,17 @@ CONV = [
 CONV_IDS = [c[0] for c in CONV]
 
 
-def _geometry(x, w, s, p):
-    oh = (x[1] + 2 * p - w[2]) // s + 1
-    return (x[0], oh, oh, w[0])
-
-
 @pytest.mark.parametrize("name,x,w,s,p", CONV, ids=CONV_IDS)
 def test_conv_forward_compiles(mosaic, one_chip, name, x, w, s, p):
+    """Every shape the gate passes (asked with the bf16 operands, as the
+    O3 route asks before it quantizes) compiles as the int8 kernel."""
     args = ((s, s), (p, p), (1, 1))
     assert pallas_conv.ineligible(jax.ShapeDtypeStruct(x, BF16),
                                   jax.ShapeDtypeStruct(w, BF16),
                                   *args) is None
-    assert _compile(lambda a, b: pallas_conv.conv2d(a, b, *args),
-                    one_chip, (x, BF16), (w, BF16)) == ["conv2d"]
-
-
-@pytest.mark.parametrize("name,x,w,s,p", CONV, ids=CONV_IDS)
-def test_conv_grads_compile(mosaic, one_chip, name, x, w, s, p):
-    """grad-input and grad-filter behind the same gate as the forward."""
-    args = ((s, s), (p, p), (1, 1))
-    dout = _geometry(x, w, s, p)
-
-    def grads(a, d, b):
-        return (pallas_conv.conv2d_grad_input(d, b, a.shape[1:3], *args),
-                pallas_conv.conv2d_grad_filter(a, d, b.shape[2:], *args))
-
-    # grad-input is the forward kernel on the flipped filter
-    assert _compile(grads, one_chip, (x, BF16), (dout, BF16),
-                    (w, BF16)) == ["conv2d", "conv2d_grad_filter"]
-
-
-def test_conv_stats_stride2_compiles(mosaic, one_chip):
-    _, x, w, s, p = CONV[2]
     assert _compile(
-        lambda a, b: pallas_conv.conv2d_stats(a, b, (s, s), (p, p), (1, 1)),
-        one_chip, (x, BF16), (w, BF16)) == ["conv2d_stats"]
+        lambda a, b, dq: pallas_conv.conv2d_q8(a, b, *args, dq), one_chip,
+        (x, jnp.int8), (w, jnp.int8), ((w[0],), jnp.float32)) == ["conv2d_q8"]
 
 
 def test_conv_q8_stride2_compiles(mosaic, one_chip):
@@ -174,17 +151,6 @@ def test_bottleneck_block_is_xla_alone(mosaic, one_chip):
              if re.match(r"%(reshape|copy)[.\d]*$", row[2])
              or row[1].startswith("pred[")]
     assert not moved, moved
-
-
-def test_bn_apply_compiles(mosaic, one_chip):
-    """The normalize(+act) half of the fused conv+bn+act window, whose
-    statistics come from conv2d_stats: the same stage-2 view."""
-    m, c = 32 * 56 * 56, 256
-    vec = ((c,), jnp.float32)
-    assert _compile(
-        lambda a, sc, b, mu, var: pallas_conv.bn_apply(
-            a, sc, b, mu, var, 1e-5, jax.nn.relu),
-        one_chip, ((m, c), BF16), vec, vec, vec, vec) == ["bn_apply"]
 
 
 def _flash_fwd_bwd(q, k, v):
@@ -555,6 +521,7 @@ def test_flash_gate_declines(shape, reason):
     (pallas_fallback_total); nothing here reaches the compiler."""
     q = jax.ShapeDtypeStruct(shape, BF16)
     assert pallas_attention.ineligible(q, q, q) == reason
-    assert reason is None or reason in pallas_attention.FALLBACK_REASONS
+    assert reason is None or \
+        reason in kernel_choice.REASONS["scaled_dot_product_attention"]
     # the ring path's per-shard check declines the same shapes
     assert pallas_attention.block_supports(q, q) == (reason is None)

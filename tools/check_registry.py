@@ -25,12 +25,15 @@ against the optimizer lowerings, the executor's sparse-aware boundary set
 and the fused-bucket types: a missing entry doesn't raise either — the
 gradient silently densifies and the update goes O(table rows).
 
-The Pallas-table lint (ISSUE 11 satellite; PR 25 left one dispatch, the
-int8 conv under O3) pins pallas_conv.KERNELS the same way: orphan
+The Pallas-table lint (ISSUE 11 satellite; PR 25 left one conv dispatch,
+the int8 conv under O3) pins pallas_conv.KERNELS the same way: orphan
 kernels, a quantizable conv op without a dispatch entry or the reverse,
 a `_grad` entry (the backward transposes the lax conv; no kernel is
-dispatched for it), and fallback reasons the gate produces but
-FALLBACK_REASONS omits.
+dispatched for it). Since PR 45 it also pins kernel_choice.REASONS, the
+one table of the reasons a lowering may give for declining a Pallas
+kernel, against the source of each op's gate, both ways, and
+kernel_choice as the only creator of pallas_kernel_total and
+pallas_fallback_total.
 
 The quant-table lint (ISSUE 20 satellite) pins quant.QUANT_OPS the same
 way, both directions: every quantizable op must be registered AND its
@@ -61,6 +64,8 @@ import os
 import sys
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:        # `python tools/check_registry.py` as written
+    sys.path.insert(0, REPO)
 
 
 def check_tables():
@@ -324,23 +329,44 @@ def check_emb_cache():
     return problems
 
 
+def _package_sources():
+    """{path relative to the repo: text} of every module of paddle_tpu/."""
+    sources = {}
+    for dirpath, _dirs, files in os.walk(os.path.join(REPO, "paddle_tpu")):
+        for fname in sorted(files):
+            if fname.endswith(".py"):
+                path = os.path.join(dirpath, fname)
+                with open(path) as f:
+                    sources[os.path.relpath(path, REPO)] = f.read()
+    return sources
+
+
 def check_pallas_table():
-    """[(where, message), ...] — pin pallas_conv.KERNELS against
-    ops/registry.py and quant.QUANT_OPS. Since PR 25 the only dispatch
-    left is the int8 conv2d_q8 under AMP O3 (every float conv and its
-    backward is lax.conv_general_dilated), so the silent failure modes
-    are: an orphan entry (an op that isn't registered, or whose lowering
-    never quantizes — the kernel never runs), a quantizable conv op
-    missing from KERNELS (a route nobody audits), a `_grad` entry
-    (conv2d_grad transposes the lax conv; a kernel listed for it is dead
-    code that reads as a route), and a fallback reason produced by the
-    gate but absent from FALLBACK_REASONS (quant reports the miss as
-    "kernel", preflight explains it from this vocabulary)."""
+    """[(where, message), ...] — pin the kernel layer's two tables.
+
+    kernel_choice.REASONS (op -> the reasons its gate may give for the
+    XLA path), for every op in it: the reasons the sources of its gate
+    (kernel_choice.GATES) can return equal the declared set, both ways.
+    A produced reason that is not declared raises at the first lowering
+    that meets it; a declared reason nothing produces is a dead counter
+    label. And kernel_choice is the only module of paddle_tpu/ that
+    creates pallas_kernel_total or pallas_fallback_total: a second
+    creator is a second place that decides what a hit is.
+
+    pallas_conv.KERNELS against ops/registry.py and quant.QUANT_OPS.
+    The only conv dispatch is the int8 conv2d_q8 under AMP O3 (every
+    float conv and its backward is lax.conv_general_dilated), so the
+    silent failure modes are: an orphan entry (an op that isn't
+    registered, or whose lowering never quantizes: the kernel never
+    runs), a quantizable conv op missing from KERNELS (a route nobody
+    audits) and a `_grad` entry (conv2d_grad transposes the lax conv; a
+    kernel listed for it is dead code that reads as a route)."""
+    import importlib
     import inspect
     import re
 
     from paddle_tpu import quant
-    from paddle_tpu.ops import pallas_conv, registry
+    from paddle_tpu.ops import kernel_choice, pallas_conv, registry
 
     problems = []
     registered = set(registry.registered_ops())
@@ -372,19 +398,51 @@ def check_pallas_table():
             "pallas_conv.KERNELS",
             f"quant.QUANT_OPS '{name}' routes to qconv2d but has no "
             f"dispatch entry here"))
-    # every reason the gate can return must be declared, and vice versa
-    src = inspect.getsource(pallas_conv.ineligible)
-    produced = set(re.findall(r'return "([a-z_]+)"', src))
-    for reason in sorted(produced - pallas_conv.FALLBACK_REASONS):
-        problems.append((
-            "pallas_conv.FALLBACK_REASONS",
-            f"gate returns '{reason}' but it is not declared — an "
-            f"unlabelled pallas_fallback_total series"))
-    for reason in sorted(pallas_conv.FALLBACK_REASONS - produced):
-        problems.append((
-            "pallas_conv.FALLBACK_REASONS",
-            f"declared reason '{reason}' is never produced by the gate — "
-            f"dead counter label"))
+
+    # every reason a gate can return must be declared, and vice versa
+    for name in sorted(set(kernel_choice.REASONS) | set(kernel_choice.GATES)):
+        where = f"kernel_choice.REASONS['{name}']"
+        if name not in registered:
+            problems.append((where, "op is not registered in "
+                                    "ops/registry.py"))
+        if name not in kernel_choice.GATES or \
+                name not in kernel_choice.REASONS:
+            problems.append((
+                where, "REASONS and GATES must both hold the op: a table "
+                       "entry whose gate the lint cannot read is unpinned"))
+            continue
+        produced = set()
+        for dotted in kernel_choice.GATES[name]:
+            module, fn = dotted.split(".")
+            gate = getattr(importlib.import_module(
+                f"paddle_tpu.ops.{module}"), fn, None)
+            if gate is None:
+                problems.append((where, f"gate '{dotted}' does not exist"))
+                continue
+            produced |= set(re.findall(r'return "([a-z_]+)"',
+                                       inspect.getsource(gate)))
+        declared = kernel_choice.REASONS[name]
+        for reason in sorted(produced - declared):
+            problems.append((
+                where,
+                f"gate returns '{reason}' but it is not declared — "
+                f"kernel_choice.book raises on it at lowering"))
+        for reason in sorted(declared - produced):
+            problems.append((
+                where,
+                f"declared reason '{reason}' is never produced by the "
+                f"gate — dead counter label"))
+
+    creates = re.compile(
+        r'\b(?:counter|gauge|histogram)\(\s*'
+        r'["\'](pallas_kernel_total|pallas_fallback_total)["\']')
+    for rel, text in sorted(_package_sources().items()):
+        if rel == os.path.join("paddle_tpu", "ops", "kernel_choice.py"):
+            continue
+        for metric in sorted(set(creates.findall(text))):
+            problems.append((
+                rel, f"creates {metric}: only ops/kernel_choice.py may, "
+                     f"through book()"))
     return problems
 
 
@@ -526,12 +584,8 @@ def check_serving_programs():
     model export) and none may be training-only: an optimizer/grad op
     leaking into a pruned program means prune kept a training subgraph
     alive and every serve call would silently mutate the weights."""
-    import os
-
     problems = []
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    if repo not in sys.path:
-        sys.path.insert(0, repo)
+    repo = REPO
     import importlib.util
 
     from paddle_tpu import io as io_mod
@@ -689,7 +743,6 @@ def check_metric_names():
     so a reader asking for labels the emitter doesn't write is exactly
     the silent-drift bug this lint exists to catch."""
     import ast
-    import os
 
     from paddle_tpu import telemetry
 
@@ -712,63 +765,54 @@ def check_metric_names():
             return tuple(out)
         return None
 
-    root = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "paddle_tpu")
     emitters = {}   # name -> list of (kind, labels-or-None, where)
     readers = []    # (fn, name, label-names-or-None, where)
     read_kinds = {"read_gauge": ("gauge",),
                   "read_histogram": ("histogram",),
                   "histogram_quantile": ("histogram",),
                   "read_series": ("counter", "gauge")}
-    for dirpath, _dirs, files in os.walk(root):
-        for fname in sorted(files):
-            if not fname.endswith(".py"):
+    for rel, text in sorted(_package_sources().items()):
+        try:
+            tree = ast.parse(text)
+        except SyntaxError as e:
+            problems.append((rel, f"unparseable: {e}"))
+            continue
+        for node in ast.walk(tree):
+            if not isinstance(node, ast.Call):
                 continue
-            path = os.path.join(dirpath, fname)
-            rel = os.path.relpath(path, os.path.dirname(root))
-            with open(path) as f:
-                try:
-                    tree = ast.parse(f.read())
-                except SyntaxError as e:
-                    problems.append((rel, f"unparseable: {e}"))
-                    continue
-            for node in ast.walk(tree):
-                if not isinstance(node, ast.Call):
-                    continue
-                fn = node.func
-                attr = (fn.attr if isinstance(fn, ast.Attribute)
-                        else fn.id if isinstance(fn, ast.Name) else None)
-                if attr is None or not node.args:
-                    continue
-                first = node.args[0]
-                name = (first.value
-                        if isinstance(first, ast.Constant)
-                        and isinstance(first.value, str) else None)
-                where = f"{rel}:{node.lineno}"
-                if attr in ("counter", "gauge", "histogram"):
-                    if name is None:
-                        continue  # dynamic name: catalog covers it
-                    labels_node = None
-                    for kw in node.keywords:
-                        if kw.arg == "labels":
-                            labels_node = kw.value
-                    if labels_node is None and len(node.args) >= 3:
-                        labels_node = node.args[2]
-                    emitters.setdefault(name, []).append(
-                        (attr, _literal_labels(labels_node), where))
-                elif attr in read_kinds and name is not None:
-                    # keyword args on the read helpers ARE label names;
-                    # a **dynamic expansion (arg=None) is unverifiable
-                    labelnames = []
-                    for kw in node.keywords:
-                        if kw.arg is None:
-                            labelnames = None
-                            break
-                        labelnames.append(kw.arg)
-                    readers.append((attr, name,
-                                    None if labelnames is None
-                                    else tuple(labelnames), where))
+            fn = node.func
+            attr = (fn.attr if isinstance(fn, ast.Attribute)
+                    else fn.id if isinstance(fn, ast.Name) else None)
+            if attr is None or not node.args:
+                continue
+            first = node.args[0]
+            name = (first.value
+                    if isinstance(first, ast.Constant)
+                    and isinstance(first.value, str) else None)
+            where = f"{rel}:{node.lineno}"
+            if attr in ("counter", "gauge", "histogram"):
+                if name is None:
+                    continue  # dynamic name: catalog covers it
+                labels_node = None
+                for kw in node.keywords:
+                    if kw.arg == "labels":
+                        labels_node = kw.value
+                if labels_node is None and len(node.args) >= 3:
+                    labels_node = node.args[2]
+                emitters.setdefault(name, []).append(
+                    (attr, _literal_labels(labels_node), where))
+            elif attr in read_kinds and name is not None:
+                # keyword args on the read helpers ARE label names;
+                # a **dynamic expansion (arg=None) is unverifiable
+                labelnames = []
+                for kw in node.keywords:
+                    if kw.arg is None:
+                        labelnames = None
+                        break
+                    labelnames.append(kw.arg)
+                readers.append((attr, name,
+                                None if labelnames is None
+                                else tuple(labelnames), where))
 
     # direction 1: every literal emitter must match the catalog
     for name in sorted(emitters):

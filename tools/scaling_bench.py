@@ -533,14 +533,11 @@ def _perf_fields(run_one):
                       if r["bound"] != "unattributed"]
         out["bound"] = (attributed[0]["bound"] if attributed
                         else "unattributed")
-        # per-kernel scoreboard + Pallas conv coverage + input-bound
-        # verdict (ISSUE 11), same columns as bench.py
+        # per-kernel scoreboard + input-bound verdict (ISSUE 11), same
+        # columns as bench.py
         ke = report.get("kernel_efficiency")
         if ke:
             out["kernel_efficiency"] = ke[:5]
-        if report.get("pallas_kernel_coverage") is not None:
-            out["pallas_kernel_coverage"] = round(
-                report["pallas_kernel_coverage"], 4)
         if report.get("input_bound") is not None:
             out["input_bound"] = report["input_bound"]
             if report.get("input_bound_remedy"):
