@@ -351,6 +351,9 @@ def lm_phase(batch, seqlen, d_model, n_head, n_layer, vocab, steps=3,
         "flash": out["flash"], "einsum": out["einsum"],
         "first_loss_rel_diff": abs(a - b) / abs(b), "rtol": rtol,
         "flash_declined": declined, "tpu_custom_calls": mosaic,
+        # the process's own: empty unless a model under a sliding window
+        # was lowered in it
+        **_counters("attention_window_total"),
         **compiles.record(),
     }
 

@@ -1086,7 +1086,8 @@ def l1_norm(x, name=None):
 
 
 def fused_attention(q, k, v, causal=False,
-                    sequence_parallel=False, use_flash="auto", name=None):
+                    sequence_parallel=False, use_flash="auto", name=None,
+                    window=0):
     """Fused attention over [B, T, H, D] tensors. K and V may have fewer
     heads than Q (grouped-query attention): H_kv must divide H, and query
     head j reads K/V head j // (H / H_kv). The op repeats K and V to H
@@ -1101,7 +1102,11 @@ def fused_attention(q, k, v, causal=False,
     sequence length at which the kernels won on the chip
     (ops/nn_ops._flash_wins); a shape the kernels do not tile keeps einsum
     with a counted reason; False forces einsum. The masks this op knows
-    are none and causal; block-diffusion training's three-part mask at
+    are none, causal, and causal under a sliding `window`: a query sees
+    the last `window` keys up to and with its own (0: every earlier key);
+    the kernels neither fetch nor walk the tiles wholly before the window
+    and the einsum path masks alike; ring attention takes no window and
+    says so. Block-diffusion training's three-part mask at
     the grain of a block of tokens is block_diffusion_attention's, on the
     same kernels. (Named fused_attention because reference-parity
     nets.scaled_dot_product_attention already takes [B, T, D] with
@@ -1111,12 +1116,13 @@ def fused_attention(q, k, v, causal=False,
     # per-row logsumexp residual for the explicit backward (dropout-Mask
     # pattern); stop_gradient — it carries no cotangent of its own
     lse = helper.create_tmp_variable("float32", stop_gradient=True)
+    attrs = {"causal": causal, "sequence_parallel": sequence_parallel,
+             "use_flash": use_flash}
+    if window:      # written only when set: every other program is the
+        attrs["window"] = int(window)   # one it was
     helper.append_op(type="scaled_dot_product_attention",
                      inputs={"Q": [q], "K": [k], "V": [v]},
-                     outputs={"Out": [out], "LSE": [lse]},
-                     attrs={"causal": causal,
-                            "sequence_parallel": sequence_parallel,
-                            "use_flash": use_flash})
+                     outputs={"Out": [out], "LSE": [lse]}, attrs=attrs)
     return out
 
 
@@ -1292,11 +1298,17 @@ def mamba2_mixer(x, num_heads, head_dim, n_groups, state_size, conv_kernel=4,
 def moe_block(x, num_experts, top_k, expert_width, shared_width=0,
               experts_held=None, expert_offset=0, scaling=1.0,
               norm_topk_prob=True, out_scale=0.02, stats=None, name=None,
-              gated=False, scoring="sigmoid"):
+              gated=False, scoring="sigmoid", router_input=None,
+              gate_act="silu"):
     """Mixture-of-experts feed-forward over x [B, T, D] with a top-k
     router, squared-ReLU experts (`gated`: gated SiLU experts,
     f(x) = (silu(x G) * (x U)) V, three matrices an expert, the shared
-    expert likewise) and, with `shared_width`, a shared expert:
+    expert likewise; `gate_act` "relu": ReGLU routed experts,
+    relu(x G) * (x U)) and, with `shared_width`, a shared expert.
+    `router_input` (a Variable of x's shape; default x): what the router
+    reads where that is not what the experts read (SmallThinker's router
+    reads the attention's normed input, its experts the attention's
+    normed output); s below is then computed from it:
 
         s = sigmoid(x W_r) in float32 (`scoring` "sigmoid", the default:
         nemotron_h's and glm4_moe_lite's router) or softmax(x W_r) over
@@ -1323,8 +1335,13 @@ def moe_block(x, num_experts, top_k, expert_width, shared_width=0,
     seqlen, d_model = int(x.shape[1]), int(x.shape[2])
     held = num_experts if experts_held is None else experts_held
     assert 0 <= expert_offset and expert_offset + held <= num_experts
+    assert gate_act in ("silu", "relu") and (gated or gate_act == "silu")
+    assert not (shared_width and gate_act != "silu"), \
+        "the shared expert is gated_mlp's silu form"
     dtype = x.dtype
     tokens = reshape(x, [-1, d_model])
+    routed_by = tokens if router_input is None \
+        else reshape(router_input, [-1, d_model])
 
     router_w = helper.create_parameter(
         attr=None, shape=[d_model, num_experts], dtype=dtype,
@@ -1339,7 +1356,7 @@ def moe_block(x, num_experts, top_k, expert_width, shared_width=0,
     if scoring != "sigmoid":    # the op's default: a sigmoid router's
         router_attrs["scoring"] = scoring   # program is the one it was
     helper.append_op(type="moe_router",
-                     inputs={"X": [tokens], "W": [router_w],
+                     inputs={"X": [routed_by], "W": [router_w],
                              "Bias": [router_b]},
                      outputs={"TopkIdx": [idx], "TopkWeight": [weight]},
                      attrs=router_attrs)
@@ -1359,14 +1376,17 @@ def moe_block(x, num_experts, top_k, expert_width, shared_width=0,
     rows, combined, load, handled = (
         helper.create_tmp_variable("float32", stop_gradient=True)
         for _ in range(4))
+    expert_attrs = {"num_experts": num_experts, "experts_held": held,
+                    "expert_offset": expert_offset, "top_k": top_k}
+    if gate_act != "silu":      # the op's default: a silu gate's program
+        expert_attrs["gate_act"] = gate_act     # is the one it was
     helper.append_op(type="moe_experts",
                      inputs=dict(inputs, W1=[up], W2=[down]),
                      outputs={"Out": [routed], "RowsRouted": [rows],
                               "RowsCombined": [combined],
                               "LoadMaxOverMean": [load],
                               "RowsHandled": [handled]},
-                     attrs={"num_experts": num_experts, "experts_held": held,
-                            "expert_offset": expert_offset, "top_k": top_k})
+                     attrs=expert_attrs)
     if stats is not None:
         stats.append((rows, combined, load, handled))
     out = reshape(routed, [-1, seqlen, d_model])

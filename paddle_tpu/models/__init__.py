@@ -16,6 +16,7 @@ from .resnet import resnet_cifar10, resnet_imagenet, resnet50
 from .smallnet import smallnet_mnist_cifar
 from .transformer import transformer_lm
 from .vgg import vgg16, vgg19
+from .window_moe import window_moe_lm
 from .common import balance_routers, build_image_classifier
 
 __all__ = [
@@ -23,5 +24,6 @@ __all__ = [
     "nemotron_h_lm",
     "resnet_cifar10", "resnet_imagenet", "resnet50",
     "smallnet_mnist_cifar", "transformer_lm",
-    "vgg16", "vgg19", "balance_routers", "build_image_classifier",
+    "vgg16", "vgg19", "window_moe_lm", "balance_routers",
+    "build_image_classifier",
 ]

@@ -380,12 +380,17 @@ def _gmm_tiling(d: int, f: int, dtype):
     return (_GMM_ROWS, min(max(d, f), cap), min(d, f, 512))
 
 
-def _grouped_products(rows, w1, w2, sizes, kernel, gate=None):
+# the gated form's activations, by the op's `gate_act`
+_GATE_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def _grouped_products(rows, w1, w2, sizes, kernel, gate=None,
+                      gate_act="silu"):
     """relu(rows W1[e])^2 W2[e] for the rows of each group e (`sizes` rows
     each, in order; rows past their sum come back undefined); with `gate`
-    (silu(rows Gate[e]) * (rows W1[e])) W2[e], the activation in
-    float32. `kernel`: None for lax.ragged_dot, else megablox gmm with
-    `interpret=kernel`."""
+    (act(rows Gate[e]) * (rows W1[e])) W2[e], act = `gate_act` (silu, or
+    relu: ReGLU), the activation in float32. `kernel`: None for
+    lax.ragged_dot, else megablox gmm with `interpret=kernel`."""
     if kernel is not None:
         from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
         dot = functools.partial(
@@ -401,7 +406,7 @@ def _grouped_products(rows, w1, w2, sizes, kernel, gate=None):
     else:
         with jax.named_scope("moe_gate"):
             g = dot(rows, gate)
-        h = (jax.nn.silu(_f32(g)) * _f32(h)).astype(h.dtype)
+        h = (_GATE_ACTS[gate_act](_f32(g)) * _f32(h)).astype(h.dtype)
     with jax.named_scope("moe_down"):
         return dot(h, w2)
 
@@ -525,7 +530,8 @@ def _tokens_of_rows_bwd(res, ct):
 _tokens_of_rows.defvjp(_tokens_of_rows_fwd, _tokens_of_rows_bwd)
 
 
-def _handle_rows(capacity, kernel, sort, sizes, x, weight, w1, w2, gate):
+def _handle_rows(capacity, kernel, gate_act, sort, sizes, x, weight, w1, w2,
+                 gate):
     """The layer over the first `capacity` pairs of the sorted order (the
     routed ones, which the caller knows to be no more, then dead ones):
     their tokens' rows gathered, the grouped products, and each token's
@@ -538,25 +544,25 @@ def _handle_rows(capacity, kernel, sort, sizes, x, weight, w1, w2, gate):
     live = (jnp.arange(capacity) < live_rows)[:, None]
     rows = _rows_of_tokens(x, head // weight.shape[1], pos, live_rows)
     rows = jnp.where(live, rows.astype(w1.dtype), 0)
-    out = _grouped_products(rows, w1, w2, sizes, kernel, gate)
+    out = _grouped_products(rows, w1, w2, sizes, kernel, gate, gate_act)
     out = _tokens_of_rows(out, weight, head, pos, live_rows)
     return out.astype(x.dtype), (pos < live_rows).sum()
 
 
-# One function object a (capacity, kernel): jax keeps a switch branch's
+# One function object a (capacity, kernel, gate_act): jax keeps a switch branch's
 # jaxpr by the function traced and its avals, so a model's expert layers,
 # and the forward that the gradient op traces again, trace a rung once
 # (the hybrid cell's step holds 12 such switches of one shape).
 @functools.lru_cache(maxsize=None)
-def _rung(capacity, kernel):
-    return functools.partial(_handle_rows, capacity, kernel)
+def _rung(capacity, kernel, gate_act):
+    return functools.partial(_handle_rows, capacity, kernel, gate_act)
 
 
 @functools.lru_cache(maxsize=None)
-def _rung_pulled_back(capacity, kernel):
+def _rung_pulled_back(capacity, kernel, gate_act):
     def branch(sort, sizes, ct, *operands):
         _, vjp, _ = jax.vjp(functools.partial(
-            _handle_rows, capacity, kernel, sort, sizes), *operands,
+            _handle_rows, capacity, kernel, gate_act, sort, sizes), *operands,
             has_aux=True)
         return vjp(ct)
     return branch
@@ -566,11 +572,12 @@ def _cast(mats, dtype):
     return tuple(None if m is None else m.astype(dtype) for m in mats)
 
 
-def _switch_rows(rungs, kernel, dtype, rung, sort, sizes, x, weight, *mats):
+def _switch_rows(rungs, kernel, gate_act, dtype, rung, sort, sizes, x, weight,
+                 *mats):
     """_handle_rows at the capacity rungs[rung], one branch a rung; the
     matrices' casts to `dtype` are operands of the switch, once, not work
     of each branch."""
-    return lax.switch(rung, [_rung(c, kernel) for c in rungs],
+    return lax.switch(rung, [_rung(c, kernel, gate_act) for c in rungs],
                       sort, sizes, x, weight, *_cast(mats, dtype))
 
 
@@ -581,17 +588,19 @@ def _switch_rows(rungs, kernel, dtype, rung, sort, sizes, x, weight, *mats):
 # to the backward) and the backward is one switch whose branch takes the
 # gradient of its own forward and pulls back inside it, so no residual
 # leaves a branch (the forward products run again there).
-_handle_routed_rows = jax.custom_vjp(_switch_rows, nondiff_argnums=(0, 1, 2))
+_handle_routed_rows = jax.custom_vjp(_switch_rows,
+                                     nondiff_argnums=(0, 1, 2, 3))
 
 
-def _handle_routed_rows_fwd(rungs, kernel, dtype, *args):
-    return _switch_rows(rungs, kernel, dtype, *args), args
+def _handle_routed_rows_fwd(rungs, kernel, gate_act, dtype, *args):
+    return _switch_rows(rungs, kernel, gate_act, dtype, *args), args
 
 
-def _handle_routed_rows_bwd(rungs, kernel, dtype, args, cts):
+def _handle_routed_rows_bwd(rungs, kernel, gate_act, dtype, args, cts):
     rung, sort, sizes, x, weight, *mats = args
     d_x, d_weight, *d_mats = lax.switch(
-        rung, [_rung_pulled_back(c, kernel) for c in rungs], sort, sizes,
+        rung, [_rung_pulled_back(c, kernel, gate_act) for c in rungs], sort,
+        sizes,
         cts[0], x, weight, *_cast(mats, dtype))
     # barrier: the matrices' gradients leave the switch in the compute
     # dtype and wait for the optimizer at the step's end. Unpinned, XLA
@@ -613,7 +622,8 @@ def _moe_experts(ctx, op_, ins):
     Out[n] = sum over the chosen (i of top_k) with TopkIdx[n, i] held of
     TopkWeight[n, i] * f_e(X[n]), f_e(x) = relu(x W1[e])^2 W2[e], or with
     the optional third matrix WGate [held, D, F] the gated form f_e(x) =
-    (silu(x WGate[e]) * (x W1[e])) W2[e]. What the experts held elsewhere
+    (act(x WGate[e]) * (x W1[e])) W2[e], act the attr `gate_act` ("silu",
+    the default, or "relu"). What the experts held elsewhere
     would add is left out; with experts_held = num_experts this is the
     whole routed layer.
 
@@ -652,6 +662,7 @@ def _moe_experts(ctx, op_, ins):
     gate = None
     if ins.get("WGate") and ins["WGate"][0] is not None:
         gate = jnp.asarray(ins["WGate"][0])
+    gate_act = op_.attr("gate_act", "silu")
     held = op_.attr("experts_held", w1.shape[0])
     n, k = idx.shape
     assert w1.shape[0] == held and k == op_.attr("top_k", k)
@@ -669,11 +680,12 @@ def _moe_experts(ctx, op_, ins):
     rungs = _capacity_ladder(n * k, held, op_.attr("num_experts", held))
     rung = (routed > jnp.asarray(rungs[:-1], jnp.int32)).sum()
     if len(rungs) == 1:    # no conditional and no gradient rule of its own
-        out, combined = _handle_rows(rungs[0], kernel, sort, sizes, x,
-                                     weight, *_cast((w1, w2, gate), dtype))
+        out, combined = _handle_rows(rungs[0], kernel, gate_act, sort, sizes,
+                                     x, weight, *_cast((w1, w2, gate), dtype))
     else:
-        out, combined = _handle_routed_rows(rungs, kernel, dtype, rung, sort,
-                                            sizes, x, weight, w1, w2, gate)
+        out, combined = _handle_routed_rows(rungs, kernel, gate_act, dtype,
+                                            rung, sort, sizes, x, weight, w1,
+                                            w2, gate)
 
     load = sizes.max() / jnp.maximum(routed / held, 1.0)
     return {"Out": [out],

@@ -992,9 +992,34 @@ def _sum_kv_groups(g, groups: int):
         .sum(3).astype(g.dtype)
 
 
+def _window(op_) -> int:
+    """The op's `window` (absent: 0, none): a causal query sees the last
+    `window` keys up to and with its own. Every path takes it or says
+    that it does not."""
+    window = int(op_.attr("window", 0))
+    if window < 0 or (window and not op_.attr("causal", False)):
+        raise ValueError(
+            f"{op_.type}: window={window} needs causal=True and a "
+            f"positive number of keys")
+    return window
+
+
+def _count_window(window: int):
+    """attention_window_total{window}: one per lowering of a forward
+    attention op that carries a window."""
+    if window and not kernel_choice.in_retrace():
+        from .. import telemetry
+        telemetry.counter(
+            "attention_window_total",
+            "lowerings of a forward attention op under a sliding window, "
+            "by the window's keys",
+            labels=("window",)).labels(window=str(window)).inc()
+
+
 def _sdpa_paths(ctx, op_, q, k, v, count=False):
     """(mode, how): 'ring' under sequence_parallel with an sp mesh (how =
-    the mesh), 'flash' when use_flash is True, or 'auto' and the rule
+    the mesh; a window is refused there by name: the ring's shards know
+    none), 'flash' when use_flash is True, or 'auto' and the rule
     (_flash_wins) gives the per-device shape to the kernels, and their
     gate passes that shape (how = _flash_partition's placement), else
     'einsum'. Auto-selection: the default config gets whichever path
@@ -1009,6 +1034,11 @@ def _sdpa_paths(ctx, op_, q, k, v, count=False):
     mesh = getattr(ctx.program, "_mesh", None)
     if op_.attr("sequence_parallel", False) and mesh is not None and \
             "sp" in mesh.axis_names:
+        window = _window(op_)
+        if window:
+            raise NotImplementedError(
+                f"{op_.type}: window={window} under sequence_parallel: "
+                f"ring attention takes no window")
         return "ring", mesh
     uf = op_.attr("use_flash", "auto")
     if not uf:
@@ -1043,7 +1073,7 @@ def _scaled_dot_product_attention(ctx, op_, ins):
     q = jnp.asarray(ins["Q"][0])
     k = jnp.asarray(ins["K"][0])
     v = jnp.asarray(ins["V"][0])
-    causal = op_.attr("causal", False)
+    causal, window = op_.attr("causal", False), _window(op_)
     (q, k, v), restore = mxu_cast(ctx, q, k, v)
     groups = _kv_groups(q, k)
     k, v = _repeat_kv(k, groups), _repeat_kv(v, groups)
@@ -1051,6 +1081,7 @@ def _scaled_dot_product_attention(ctx, op_, ins):
                                            attention_reference_lse,
                                            ring_attention_sharded)
     mode, mesh = _sdpa_paths(ctx, op_, q, k, v, count=True)
+    _count_window(window)
     if mode == "ring":
         out, lse = ring_attention_sharded(
             q, k, v, mesh, axis="sp", causal=causal,
@@ -1062,7 +1093,7 @@ def _scaled_dot_product_attention(ctx, op_, ins):
 
         def fwd(q, k, v):
             return pallas_attention._forward(q, k, v, causal,
-                                             return_lse=True)
+                                             return_lse=True, window=window)
 
         if mesh is not None:        # a _FlashPlacement
             fwd = jax.shard_map(
@@ -1070,8 +1101,8 @@ def _scaled_dot_product_attention(ctx, op_, ins):
                 out_specs=(mesh.qkv, mesh.lse), check_vma=False)
         out, lse = fwd(q, k, v)
     else:
-        out = attention_reference(q, k, v, causal=causal)
-        lse = attention_reference_lse(q, k, causal=causal)
+        out = attention_reference(q, k, v, causal=causal, window=window)
+        lse = attention_reference_lse(q, k, causal=causal, window=window)
     if restore is not None:
         out = out.astype(restore)
     return {"Out": [out], "LSE": [lse]}
@@ -1088,7 +1119,7 @@ def _sdpa_grad_kernel(ctx, op_, ins):
     k = jnp.asarray(ins["K"][0])
     v = jnp.asarray(ins["V"][0])
     do = jnp.asarray(ins["Out@GRAD"][0])
-    causal = op_.attr("causal", False)
+    causal, window = op_.attr("causal", False), _window(op_)
     (q, k, v, do), restore = mxu_cast(ctx, q, k, v, do)
     groups = _kv_groups(q, k)
     k, v = _repeat_kv(k, groups), _repeat_kv(v, groups)
@@ -1105,7 +1136,7 @@ def _sdpa_grad_kernel(ctx, op_, ins):
             delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                             axis=-1).transpose(0, 2, 1)
             return pallas_attention.flash_attention_bwd_block(
-                q, k, v, do, lse, delta, 0, 0, scale, causal)
+                q, k, v, do, lse, delta, 0, 0, scale, causal, window=window)
 
         if mesh is not None:        # a _FlashPlacement
             bwd = jax.shard_map(
@@ -1131,7 +1162,8 @@ def _sdpa_grad_kernel(ctx, op_, ins):
             dq, dk, dv = vjp_fn(do.astype(q.dtype))
     else:
         _, vjp_fn = jax.vjp(
-            lambda a, b, c: attention_reference(a, b, c, causal=causal),
+            lambda a, b, c: attention_reference(a, b, c, causal=causal,
+                                                window=window),
             q, k, v)
         dq, dk, dv = vjp_fn(do.astype(q.dtype))
     dk, dv = _sum_kv_groups(dk, groups), _sum_kv_groups(dv, groups)

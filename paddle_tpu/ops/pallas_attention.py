@@ -30,6 +30,11 @@ its whole block of positions, both directions, and every block before it
 (block-diffusion training's clean stream), and with q_off = -block only
 the blocks strictly before its own (its noisy stream's view of the clean
 keys). `block` divides 128, so every K tile starts on a block boundary.
+At block = 1 the mask may also have a far edge, a sliding `window`
+(PR 46): a query sees the `window` keys up to and with its own. It lives
+where the diagonal lives, in the walk ranges, the `index_map` clamps and
+the predicates of the tiles an edge crosses: a tile wholly before the
+window is neither fetched nor walked, forward or backward.
 
 Orientation: every kernel computes the TRANSPOSED block S^T = K Q^T
 [rows of K, rows of Q]. The softmax statistics (running max and sum,
@@ -53,7 +58,9 @@ lane blocks: 12 and 10 heads of 64 are admitted, 3 heads of 64 are not
 at D=64 (12 and 10 heads, T=1024: PR 29), D=128 (32 heads, T=4096: PR
 30) and D=256, one head a 256-lane block (20 heads, T=4096, latent
 attention's expanded heads: PR 33); under the block mask at D=128 (32
-heads, both streams of one 4096-token sequence in blocks of 4: PR 42);
+heads, both streams of one 4096-token sequence in blocks of 4: PR 42)
+and under a window of 4096 keys at D=128 (28 heads, one 8192-token
+sequence in four major tiles: PR 46);
 D=32 and D=512 have compiled for a described v5e
 (tests/test_tpu_compile.py) and run interpreted only. Inside a block the
 heads are told
@@ -309,18 +316,50 @@ def _clip(x, hi: int):
     return jnp.clip(x, 0, hi)
 
 
-def _walk(full, masked, visit):
-    """Run visit(j, masked) over the (lo, hi) range of walked blocks that
-    need no mask and over the range the diagonal crosses (None: no causal
-    mask)."""
-    def run(bounds, mask):
+# What a walked block's predicate holds it to (bits): the causal diagonal,
+# the window's far edge, or, where a window is shorter than two tiles and
+# one block may cross both, both.
+_OPEN, _DIAG, _EDGE = 0, 1, 2
+
+
+def _walk(full, masked, edge, visit, both=False):
+    """Run visit(j, kind) over the (lo, hi) range of walked blocks that
+    need no mask (kind _OPEN), over the range the diagonal crosses (_DIAG,
+    with `both` also held to the window: _DIAG | _EDGE; None: no causal
+    mask) and over the range the window's far edge crosses (_EDGE; None:
+    no window)."""
+    def run(bounds, kind):
         def body(j, carry):
-            visit(j, mask)
+            visit(j, kind)
             return carry
         lax.fori_loop(*bounds, body, 0)
-    run(full, False)
+    run(full, _OPEN)
     if masked is not None:
-        run(masked, True)
+        run(masked, _DIAG | _EDGE if both else _DIAG)
+    if edge is not None:
+        run(edge, _EDGE)
+
+
+def _crosses_both(window: int, bq: int, bk: int) -> bool:
+    """Whether a block the diagonal crosses may cross the window's far
+    edge too. Such a block holds a pair with q - k < 0 and all its pairs
+    have q - k <= bq + bk - 2, so from a window of bq + bk keys on it lies
+    wholly inside the window and the diagonal's predicate is enough (the
+    cell's 4096 keys over tiles of 512); a block the far edge crosses and
+    the diagonal does not is never past the diagonal."""
+    return 0 < window < bq + bk
+
+
+def _held(st, diff, first, kind: int, window: int):
+    """The transposed score block with the pairs its predicate drops at
+    _NEG: `diff` = _q_minus_k, `first` = the walked K block's first
+    position less the Q block's; the diagonal keeps q >= k, the window's
+    edge q - k < window."""
+    if kind & _DIAG:
+        st = jnp.where(diff >= first, st, _NEG)
+    if kind & _EDGE:
+        st = jnp.where(diff < first + window, st, _NEG)
+    return st
 
 
 # The mask's geometry. A causal mask at the grain of `block` positions
@@ -347,31 +386,94 @@ def _first_seer(k, block: int):
     return k if block == 1 else _floordiv(k, block) * block
 
 
+# A window is the relation's other end (block = 1 only): a query at q sees
+# the `window` keys q - window < k <= q, its own among them, so a key at k
+# is seen by the queries k <= q < k + window. Every range below then has a
+# lower end beside its upper one: the tiles wholly before the window are
+# neither fetched nor walked, those its far edge crosses carry the second
+# predicate, and with window = 0 (none) every expression is the one of
+# before. A window that is no multiple of a tile only makes the edge
+# tile's predicate partial.
+
+def _ceildiv(x, b: int):
+    return -_floordiv(-x, b)
+
+
 def _kv_ranges(q_first, k_base, bq: int, bk: int, per: int, causal: bool,
-               block: int = 1):
+               block: int = 1, window: int = 0):
     """_walk's ranges over the `per` K/V blocks of a major tile that
     starts at position k_base, for a resident Q tile of bq rows at
     q_first: the blocks wholly at or below the diagonal, then those it
-    crosses; blocks past it are in neither."""
+    crosses; blocks past it are in neither. Under a window the open range
+    starts at the first block every row of the tile still sees, and the
+    blocks before it that the first row sees are the third range."""
     if not causal:
-        return (0, per), None
+        return (0, per), None, None
     n_full = _clip(_floordiv(_reach(q_first, block) - k_base + 1, bk), per)
     n_live = _clip(
         _floordiv(_reach(q_first + bq - 1, block) - k_base, bk) + 1, per)
-    return (0, n_full), (n_full, n_live)
+    if not window:
+        return (0, n_full), (n_full, n_live), None
+    first_live = _clip(_floordiv(q_first - window + 1 - k_base, bk), per)
+    first_open = _clip(_ceildiv(q_first + bq - window - k_base, bk), per)
+    # a block both edges cross is walked once, with the diagonal's
+    first_open = jnp.clip(first_open, first_live, n_full)
+    return (first_open, n_full), (n_full, n_live), (first_live, first_open)
 
 
 def _kv_major_index(bq: int, mk: int, n_maj: int, causal: bool,
-                    block: int = 1):
+                    block: int = 1, window: int = 0):
     """index_map half of _kv_ranges: the K/V major tile to fetch for Q
-    tile i at grid step kk; past the last live one the index stays put,
-    so no DMA is issued for a tile the loop will not walk."""
+    tile i at grid step kk; past the last live one (and, under a window,
+    before the first) the index stays put, so no DMA is issued for a tile
+    the loop will not walk."""
     def index(i, kk, offs):
         if not causal:
             return kk
+        last = _clip(_floordiv(
+            _reach(offs[0] + (i + 1) * bq - 1, block) - offs[1], mk),
+            n_maj - 1)
+        if not window:
+            return jnp.minimum(kk, last)
+        first = _floordiv(offs[0] + i * bq - window + 1 - offs[1], mk)
+        return jnp.clip(kk, _clip(first, n_maj - 1), last)
+    return index
+
+
+def _q_ranges(k_first, q_base, bq: int, bk: int, per: int, block: int = 1,
+              window: int = 0):
+    """_kv_ranges from the other side: _walk's ranges over the `per` Q
+    blocks of a major tile that starts at position q_base, for a resident
+    K tile of bk rows at k_first: the Q blocks wholly below the diagonal,
+    those it crosses and, under a window, those the far edge crosses (the
+    last row of the tile is seen up to k_first + bk + window - 2)."""
+    live0 = _clip(_floordiv(_first_seer(k_first, block) - q_base, bq), per)
+    full0 = _clip(-_floordiv(
+        q_base - _first_seer(k_first + bk - 1, block), bq), per)
+    full0 = jnp.maximum(full0, live0)
+    if not window:
+        return (full0, per), (live0, full0), None
+    last_open = _clip(_floordiv(k_first + window - q_base, bq), per)
+    last_live = _clip(
+        _floordiv(k_first + bk + window - 2 - q_base, bq) + 1, per)
+    last_open = jnp.clip(last_open, full0, last_live)
+    return (full0, last_open), (live0, full0), (last_open, last_live)
+
+
+def _q_major_index(bq: int, bk: int, mq: int, n_maj: int, causal: bool,
+                   block: int = 1, window: int = 0):
+    """index_map half of _q_ranges: the Q major tile to fetch for K tile i
+    at grid step kk, clamped to the live ones."""
+    def index(i, kk, offs):
+        if not causal:
+            return kk
+        first = _clip(_floordiv(
+            _first_seer(offs[1] + i * bk, block) - offs[0], mq), n_maj - 1)
+        if not window:
+            return jnp.maximum(kk, first)
         last = _floordiv(
-            _reach(offs[0] + (i + 1) * bq - 1, block) - offs[1], mk)
-        return jnp.minimum(kk, _clip(last, n_maj - 1))
+            offs[1] + (i + 1) * bk + window - 2 - offs[0], mq)
+        return jnp.clip(kk, first, _clip(last, n_maj - 1))
     return index
 
 
@@ -389,7 +491,7 @@ def _q_minus_k(bk: int, bq: int, block: int = 1):
 def _fwd_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, *rest, bq: int,
                 bk: int, mk: int, n_maj: int, d: int, hpb: int,
                 scale: float, causal: bool, normalize: bool,
-                block: int = 1):
+                block: int = 1, window: int = 0):
     """Grid (B, lane blocks, Tq/bq, Tk/mk): Q tile [bq, L] resident, the
     K/V major tile [mk, L] in VMEM, walked in blocks of bk rows by the
     loop; (acc, m, l) carry in scratch across major tiles. Works on the
@@ -420,8 +522,7 @@ def _fwd_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, *rest, bq: int,
     if fold:
         qt = qt * jnp.asarray(scale, qt.dtype)
     qs = [_only(mask, qt) for mask in masks]
-    if causal:
-        diff = _q_minus_k(bk, bq, block)
+    diff = _q_minus_k(bk, bq, block) if causal else None
 
     def walked(j, masked):
         start = pl.multiple_of(j * bk, bk)
@@ -431,8 +532,7 @@ def _fwd_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, *rest, bq: int,
             st = _dot(kb, qs[hh], _NT)
             if not fold:
                 st = st * scale
-            if masked:
-                st = jnp.where(diff >= k_base + start - q_first, st, _NEG)
+            st = _held(st, diff, k_base + start - q_first, masked, window)
             m_prev = m_sc[hh]
             m_new = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
@@ -443,8 +543,8 @@ def _fwd_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, *rest, bq: int,
             acc_sc[rows, :] = acc_sc[rows, :] * alpha + _dot(
                 vt[rows, :], pt.astype(vt.dtype), _NN)
 
-    _walk(*_kv_ranges(q_first, k_base, bq, bk, per, causal, block),
-          walked)
+    _walk(*_kv_ranges(q_first, k_base, bq, bk, per, causal, block, window),
+          walked, both=_crosses_both(window, bq, bk))
 
     @pl.when(kk == n_maj - 1)
     def _finalize():
@@ -530,12 +630,22 @@ def _specs(lanes, hpb, b_res, b_walk, m_walk, walk_index):
 # traces and lowers each kernel once: jax lowers an inner jit of the same
 # shapes to one function and calls it per layer (36 pallas_calls lowered
 # one by one cost GPT-2's first step 5 s a lowering, PERF.md section 6).
+def _check_window(window: int, causal: bool, block: int):
+    """A window is a causal mask's far edge, at the grain of a position."""
+    if window < 0 or (window and (not causal or block != 1)):
+        raise ValueError(
+            f"a window of {window} keys needs a causal mask at block 1 "
+            f"(causal={causal}, block={block})")
+
+
 @functools.partial(jax.jit, static_argnames=("scale", "causal", "normalize",
-                                             "tile", "major", "block"))
+                                             "tile", "major", "block",
+                                             "window"))
 def _fwd_call(q, k, v, q_off, k_off, scale, causal, normalize, tile=_TILE,
-              major=_MAJOR, block=1):
+              major=_MAJOR, block=1, window=0):
     """Returns (out [B,Tq,H,D], stats): stats = (lse,) when normalizing,
     else (m, l); each [B, H, Tq] f32."""
+    _check_window(window, causal, block)
     b, tq, h, d = q.shape
     tk = k.shape[1]
     lanes, hpb = _lane_block(h, d)
@@ -545,13 +655,13 @@ def _fwd_call(q, k, v, q_off, k_off, scale, causal, normalize, tile=_TILE,
 
     res, walk, res_stat, _ = _specs(
         lanes, hpb, bq, bk, mk,
-        _kv_major_index(bq, mk, n_maj, causal, block))
+        _kv_major_index(bq, mk, n_maj, causal, block, window))
     struct = _vma_struct(q)
     n_stat = 1 if normalize else 2
     out, *stats = _call(
         functools.partial(_fwd_kernel, bq=bq, bk=bk, mk=mk, n_maj=n_maj,
                           d=d, hpb=hpb, scale=float(scale), causal=causal,
-                          normalize=normalize, block=block),
+                          normalize=normalize, block=block, window=window),
         "flash_fwd", (b, h * d // lanes, tq // bq, n_maj),
         [res, walk, walk], [res] + [res_stat] * n_stat,
         [struct((b, tq, h * d), q.dtype if normalize else jnp.float32)]
@@ -561,10 +671,10 @@ def _fwd_call(q, k, v, q_off, k_off, scale, causal, normalize, tile=_TILE,
     return out.reshape(b, tq, h, d), [s.reshape(b, h, tq) for s in stats]
 
 
-def _forward(q, k, v, causal, return_lse=False, block=1):
+def _forward(q, k, v, causal, return_lse=False, block=1, window=0):
     scale = 1.0 / (q.shape[-1] ** 0.5)
     out, (lse,) = _fwd_call(q, k, v, 0, 0, scale, causal, normalize=True,
-                            block=block)
+                            block=block, window=window)
     return (out, lse) if return_lse else out
 
 
@@ -585,7 +695,7 @@ def flash_attention_block(q, k, v, q_off, k_off, scale, causal, block=1):
 def _dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
                dq_ref, dq_sc, *, bq: int, bk: int, mk: int, n_maj: int,
                d: int, hpb: int, scale: float, causal: bool,
-               block: int = 1):
+               block: int = 1, window: int = 0):
     """Grid and walk of the forward: Q/dO tile resident, K/V walked, dQ^T
     carried in scratch. Recomputes P^T = exp(S^T - LSE) per block;
     dS^T = P^T * (V dO^T - delta); dQ^T = (sum_k K^T dS^T) * scale."""
@@ -609,8 +719,7 @@ def _dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
     dot_ = do_ref[0]
     qs = [_only(mask, qt) for mask in masks]
     dos = [_only(mask, dot_) for mask in masks]
-    if causal:
-        diff = _q_minus_k(bk, bq, block)
+    diff = _q_minus_k(bk, bq, block) if causal else None
 
     def walked(j, masked):
         start = pl.multiple_of(j * bk, bk)
@@ -621,16 +730,15 @@ def _dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
             st = _dot(kb, qs[hh], _NT)
             if not fold:
                 st = st * scale
-            if masked:
-                st = jnp.where(diff >= k_base + start - q_first, st, _NEG)
+            st = _held(st, diff, k_base + start - q_first, masked, window)
             pt = jnp.exp(st - lse_ref[0, hh, 0])
             dpt = _dot(vb, dos[hh], _NT)
             dst = (pt * (dpt - dl_ref[0, hh, 0])).astype(kb.dtype)
             rows = slice(hh * d, (hh + 1) * d)
             dq_sc[rows, :] = dq_sc[rows, :] + _dot(kt[rows, :], dst, _NN)
 
-    _walk(*_kv_ranges(q_first, k_base, bq, bk, per, causal, block),
-          walked)
+    _walk(*_kv_ranges(q_first, k_base, bq, bk, per, causal, block, window),
+          walked, both=_crosses_both(window, bq, bk))
 
     @pl.when(kk == n_maj - 1)
     def _finalize():
@@ -640,7 +748,7 @@ def _dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
 def _dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
                 dk_ref, dv_ref, *rest, bq: int, bk: int, mq: int,
                 n_maj: int, d: int, hpb: int, scale: float, causal: bool,
-                block: int = 1, n_k: int = 0):
+                block: int = 1, n_k: int = 0, window: int = 0):
     """Grid (B, lane blocks, Tk/bk, Tq/mq): K/V tile resident, the
     Q/dO/LSE/delta major tile walked in blocks of bq rows, dK/dV carried
     in scratch. Works on the transposed blocks S^T = K Q^T [bk, bq], so
@@ -689,8 +797,7 @@ def _dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
     vt = v_ref[0]
     ks = [_only(mask, kt) for mask in masks]
     vs = [_only(mask, vt) for mask in masks]
-    if causal:
-        diff = _q_minus_k(bk, bq, block)
+    diff = _q_minus_k(bk, bq, block) if causal else None
 
     def walked(j, masked):
         start = pl.multiple_of(j * bq, bq)
@@ -701,8 +808,7 @@ def _dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
             st = _dot(ks[hh], qb, _NT)
             if not fold:
                 st = st * scale
-            if masked:
-                st = jnp.where(diff >= k_first - q_base - start, st, _NEG)
+            st = _held(st, diff, k_first - q_base - start, masked, window)
             pt = jnp.exp(st - lse_ref[0, hh, j])
             dvs.append(_dot(pt.astype(dob.dtype), dob, _NN))
             dpt = _dot(vs[hh], dob, _NT)
@@ -717,15 +823,10 @@ def _dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
         dk_sc[...] = dk_sc[...] + _by_head(masks, dks)
 
     if causal:
-        # Q blocks the diagonal crosses, then those wholly below it
-        live0 = _clip(
-            _floordiv(_first_seer(k_first, block) - q_base, bq), per)
-        full0 = _clip(-_floordiv(
-            q_base - _first_seer(k_first + bk - 1, block), bq), per)
-        full0 = jnp.maximum(full0, live0)
-        _walk((full0, per), (live0, full0), walked)
+        _walk(*_q_ranges(k_first, q_base, bq, bk, per, block, window),
+              walked, both=_crosses_both(window, bq, bk))
     else:
-        _walk((0, per), None, walked)
+        _walk((0, per), None, None, walked)
 
     @pl.when(kk == n_maj - 1)
     def _finalize():
@@ -788,7 +889,7 @@ def count_backward(reason):
 
 def flash_attention_bwd_block(q, k, v, do, lse, delta, q_off, k_off, scale,
                               causal, dq_tile=_TILE, dkv_tile=_TILE,
-                              major=_MAJOR, block=1):
+                              major=_MAJOR, block=1, window=0):
     """Flash backward for one (Q shard, K/V shard) pair with global position
     offsets: q/do [B,Tq,H,D], k/v [B,Tk,H,D], lse/delta [B,H,Tq] (scaled-
     score logsumexp from the forward; delta = rowsum(dO*O)). Returns
@@ -811,17 +912,18 @@ def flash_attention_bwd_block(q, k, v, do, lse, delta, q_off, k_off, scale,
     count_backward(reason)
     return _bwd_call(q, k, v, do, lse, delta, q_off, k_off, float(scale),
                      causal, dq_tile, dkv_tile, major, block,
-                     fused=reason is None)
+                     fused=reason is None, window=window)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "causal", "dq_tile",
                                              "dkv_tile", "major", "block",
-                                             "fused"))
+                                             "fused", "window"))
 def _bwd_call(q, k, v, do, lse, delta, q_off, k_off, scale, causal,
               dq_tile=_TILE, dkv_tile=_TILE, major=_MAJOR, block=1,
-              fused=True):
+              fused=True, window=0):
     """flash_attention_bwd_block's kernels in the form it chose (the
     sweep and the tests ask for either)."""
+    _check_window(window, causal, block)
     b, tq, h, d = q.shape
     tk = k.shape[1]
     lanes, hpb = _lane_block(h, d)
@@ -834,7 +936,7 @@ def _bwd_call(q, k, v, do, lse, delta, q_off, k_off, scale, causal,
     struct = _vma_struct(q)
     q2, k2, v2, do2 = _flat(q), _flat(k), _flat(v), _flat(do)
     statics = dict(d=d, hpb=hpb, scale=float(scale), causal=causal,
-                   block=block)
+                   block=block, window=window)
 
     def stat(x, rows):
         return x.reshape(b, h, tq // rows, 1, rows)
@@ -846,7 +948,7 @@ def _bwd_call(q, k, v, do, lse, delta, q_off, k_off, scale, causal,
 
         res, walk, res_stat, _ = _specs(
             lanes, hpb, bq, bk, mk,
-            _kv_major_index(bq, mk, n_maj, causal, block))
+            _kv_major_index(bq, mk, n_maj, causal, block, window))
         dq = _call(
             functools.partial(_dq_kernel, bq=bq, bk=bk, mk=mk, n_maj=n_maj,
                               **statics),
@@ -859,14 +961,9 @@ def _bwd_call(q, k, v, do, lse, delta, q_off, k_off, scale, causal,
     mq = _major(tq, bq, major)
     n_maj = tq // mq
 
-    def q_index(i, kk, offs):
-        if not causal:
-            return kk
-        first = _floordiv(
-            _first_seer(offs[1] + i * bk, block) - offs[0], mq)
-        return jnp.maximum(kk, _clip(first, n_maj - 1))
-
-    res, walk, _, walk_stat = _specs(lanes, hpb, bk, bq, mq, q_index)
+    res, walk, _, walk_stat = _specs(
+        lanes, hpb, bk, bq, mq,
+        _q_major_index(bq, bk, mq, n_maj, causal, block, window))
     out_specs = [res, res]
     out_shape = [struct((b, tk, h * d), k.dtype),
                  struct((b, tk, h * d), v.dtype)]

@@ -27,36 +27,42 @@ __all__ = ["attention_reference", "ring_attention", "ring_attention_sharded",
            "ring_attention_bwd_sharded", "flash_ring_eligible"]
 
 
-def _scaled_masked_logits(q, k, causal, scale):
+def _scaled_masked_logits(q, k, causal, scale, window=0):
     """The one definition of the attention scores [B, H, Tq, Tk]:
     attention_reference and attention_reference_lse MUST build logits
     through this single helper — the einsum-path backward's correctness
     (LSE consistent with the probs) and the XLA-CSE performance story
-    both depend on the two being the identical computation."""
+    both depend on the two being the identical computation. `window`
+    (causal only; 0 = none): a query sees the last `window` keys up to
+    its own."""
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / jnp.sqrt(d).astype(q.dtype)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     if causal:
         tq, tk = logits.shape[-2], logits.shape[-1]
         mask = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)
+        if window:
+            mask &= ~jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq - window)
         logits = jnp.where(mask, logits, -jnp.inf)
     return logits
 
 
-def attention_reference(q, k, v, causal: bool = False, scale=None):
+def attention_reference(q, k, v, causal: bool = False, scale=None,
+                        window: int = 0):
     """Plain softmax attention, q/k/v [B, T, H, D] -> [B, T, H, D]."""
-    probs = jax.nn.softmax(_scaled_masked_logits(q, k, causal, scale),
-                           axis=-1)
+    probs = jax.nn.softmax(
+        _scaled_masked_logits(q, k, causal, scale, window), axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def attention_reference_lse(q, k, causal: bool = False, scale=None):
+def attention_reference_lse(q, k, causal: bool = False, scale=None,
+                            window: int = 0):
     """Per-row logsumexp of the scaled (masked) scores [B, H, T] in f32 —
     the LSE residual the flash kernels save; here derived from the same
     logits XLA CSEs with attention_reference's einsum."""
     return jax.scipy.special.logsumexp(
-        _scaled_masked_logits(q, k, causal, scale).astype(jnp.float32),
-        axis=-1)
+        _scaled_masked_logits(q, k, causal, scale, window).astype(
+            jnp.float32), axis=-1)
 
 
 def _block_attn(q, k, v, scale, mask):

@@ -819,6 +819,11 @@ METRIC_CATALOG = {
         "smallest capacity of its ladder that holds moe_rows_routed, all "
         "N x top_k at most (telemetry side-fetch)",
         dynamic=True),
+    "loss": _m(
+        "gauge", ("program",),
+        "mean next-token cross-entropy, the last step's (telemetry "
+        "side-fetch; models/window_moe)",
+        dynamic=True),
     "loss_main": _m(
         "gauge", ("program",),
         "next-token cross-entropy of a model with multi-token-prediction "
@@ -865,6 +870,9 @@ METRIC_CATALOG = {
     "flash_backward_total": _m("counter", ("form", "reason"),
                                "flash attention backward lowerings, fused "
                                "(one kernel) or split by a shape ground"),
+    "attention_window_total": _m("counter", ("window",),
+                                 "forward attention lowerings under a "
+                                 "sliding window, by its keys"),
     "activation_kept_total": _m("counter", ("act",),
                                 "lowerings of an activation evaluated once "
                                 "and kept (ops/math_ops.py KEPT_ACTS)"),

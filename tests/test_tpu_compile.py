@@ -11,6 +11,7 @@ out of the interpreter with monkeypatch, not through an option of the
 program.
 """
 
+import functools
 import re
 
 import jax
@@ -221,6 +222,32 @@ def test_flash_kernels_compile_under_the_block_mask(mosaic, one_chip):
     assert _compile(_block_diffusion_fwd_bwd, one_chip,
                     *[(shape, BF16)] * 3) == [
         "flash_dkv", "flash_dkv", "flash_fwd", "flash_fwd"]
+
+
+def _window_fwd_bwd(window, q, k, v):
+    out, lse = pallas_attention._forward(q, k, v, True, return_lse=True,
+                                         window=window)
+    delta = jnp.sum(out.astype(jnp.float32) ** 2, -1).transpose(0, 2, 1)
+    return pallas_attention.flash_attention_bwd_block(
+        q, k, v, out, lse, delta, 0, 0, 128 ** -0.5, True, window=window)
+
+
+@pytest.mark.parametrize("window", [4096, 4000, 600],
+                         ids=["the_cells", "no_multiple_of_a_tile",
+                              "shorter_than_two_tiles"])
+def test_flash_kernels_compile_under_a_window(mosaic, one_chip, window):
+    """The sliding-window cell's attention op at its shape, one
+    8192-token sequence, 28 heads of 128 after the K/V repeat, four major
+    tiles of 2048 rows: the forward and the fused backward (dQ's
+    accumulator over 8192 rows is 4 MB a lane block) whose walk ranges
+    and index maps take the window's far edge; a window that makes the
+    edge tile's predicate partial, and one so short that a block crosses
+    both edges."""
+    shape = (1, 8192, 28, 128)
+    one = jax.ShapeDtypeStruct(shape, BF16)
+    assert pallas_attention.ineligible(one, one, one) is None
+    assert _compile(functools.partial(_window_fwd_bwd, window), one_chip,
+                    *[(shape, BF16)] * 3) == ["flash_dkv", "flash_fwd"]
 
 
 @pytest.mark.parametrize("rows,dtype", [
@@ -502,6 +529,49 @@ def test_block_diffusion_step_compiles_with_no_square_of_scores(
     for instr in scoped:
         assert xplane.first_array(instr.shape)[0] <= operand, (
             instr.name, instr.shape)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 15.75e9
+
+
+SWA_CELL = "smallthinker-21b-a3b.train-swa-t8192-ep8-share"
+
+
+def test_window_step_compiles_with_both_kinds_of_layer(mosaic, one_chip):
+    """The sliding-window cell's step at its own 8192-token sequence and
+    published widths, the depth cut to the first two blocks, one of each
+    kind (the four take four minutes here; tools/describe_step.py sized
+    them: 7.44 GB of temporaries + 4.45 GB of aliased state): each
+    attention op on the flash kernels with the fused backward, the
+    experts on gmm / tgmm with no switch (8 of 64 held: one rung), the
+    rotations under the windowed layer's scope alone, and under either
+    attention scope no array larger than the op's own operands: nothing
+    of [., T, T] reaches HBM."""
+    from paddle_tpu import xplane
+    cell = run.load_json("workloads", SWA_CELL)
+    config = dict(run.load_json("configs", cell["config"]),
+                  num_hidden_layers=2)
+    compiled = describe_step.compile_step(cell, config, one_chip)
+    text = compiled.as_text()
+    kernels = [re.search(r'op_name="[^"]*?(\w+)\)*/pallas_call', line).group(1)
+               for line in text.splitlines() if KERNEL in line]
+    flash = {k: kernels.count(k) for k in set(kernels) if "flash" in k}
+    assert flash == {"flash_fwd": 2, "flash_dkv": 2}
+    assert "gmm" in kernels and "tgmm" in kernels
+    assert "pd.moe_experts/cond" not in text
+    scoped = {kind: [i for i in xplane.hlo_instructions(text)
+                     if "pd_scope." + kind in (i.op_name or "")]
+              for kind in ("window_attention", "global_attention")}
+    assert any("rotary_embedding" in i.op_name
+               for i in scoped["window_attention"])
+    assert not any("rotary_embedding" in i.op_name
+                   for i in scoped["global_attention"])
+    operand = (config["sequence_length"] * config["num_attention_heads"]
+               * config["head_dim"])
+    for kind, instrs in scoped.items():
+        assert len(instrs) > 4, kind
+        for instr in instrs:
+            assert xplane.first_array(instr.shape)[0] <= operand, (
+                instr.name, instr.shape)
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 15.75e9
 

@@ -3,6 +3,7 @@ sweep, and jax's bundled Pallas flash attention as an outside yardstick.
 
     chiprun -- python3 tools/flash_sweep.py [B T H D] [--parent DIR]
         [--rows 256,512] [--major 4096] [--block 4 [--q-off -4]]
+        [--window 4096]
 
 Times the forward and forward+backward (jax.vjp on a random cotangent)
 of causal bf16 attention at one [B, T, H, D]; `_TILE` and the selection
@@ -15,7 +16,9 @@ walked side that stay in VMEM at once (pallas_attention._MAJOR) for the
 per-kernel sweep. The backward is timed in both forms: `fused` (one
 K/V-resident call that also accumulates dQ) beside `dq` and `dkv`, the
 split form's two calls, each alone. `--block N` masks at the grain of N
-positions and `--q-off` shifts the queries (block-diffusion attention's
+positions, `--window N` gives the causal mask a far edge of N keys (the
+sliding-window cell's layers: 1 8192 28 128 --window 4096) and `--q-off`
+shifts the queries (block-diffusion attention's
 two kernel parts: 0 and -N); einsum, the bundled kernel and the parent
 are then left out, the kernels alone are timed. `--parent DIR` also
 times the kernels of another checkout (its ops/pallas_attention.py) on
@@ -87,8 +90,9 @@ def main():
     ap.add_argument("--major", type=int, default=pa._MAJOR)
     ap.add_argument("--block", type=int, default=1)
     ap.add_argument("--q-off", type=int, default=0)
+    ap.add_argument("--window", type=int, default=0)
     ns = ap.parse_args()
-    plain = ns.block == 1 and ns.q_off == 0
+    plain = ns.block == 1 and ns.q_off == 0 and not ns.window
     b, t, h, d = ns.shape
     os.makedirs(os.path.dirname(OUT), exist_ok=True)
     log = open(OUT, "a")
@@ -102,7 +106,7 @@ def main():
     base = dict(shape=[b, t, h, d], device=jax.devices()[0].device_kind,
                 major=ns.major)
     if not plain:
-        base.update(block=ns.block, q_off=ns.q_off)
+        base.update(block=ns.block, q_off=ns.q_off, window=ns.window)
 
     def einsum(q, k, v):
         return attention_reference(q, k, v, causal=True)
@@ -125,6 +129,9 @@ def main():
         out = got[0]
         lse = jax.jit(
             lambda q, k, v: pa._forward(q, k, v, True, True)[1])(q, k, v)
+    elif ns.window:
+        out, lse = jax.jit(lambda q, k, v: pa._forward(
+            q, k, v, True, True, window=ns.window))(q, k, v)
     else:
         # one part of block-diffusion attention: the raw (acc, l, m); a
         # row that sees no key (the first block under q_off = -block)
@@ -138,7 +145,7 @@ def main():
     # each kernel alone over (resident rows, walked block rows)
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1).transpose(0, 2, 1)
-    mask = dict(major=ns.major, block=ns.block)
+    mask = dict(major=ns.major, block=ns.block, window=ns.window)
 
     def bwd(fused, **tiles):
         return lambda *a: pa._bwd_call(*a, ns.q_off, 0, scale, True,
