@@ -35,7 +35,10 @@ conditional is a wall to the merging) and keeps nothing of a branch's
 size between the two. Inside a branch, and in the layer without one, the
 two maps between tokens and sorted rows carry rules too (_rows_of_tokens,
 _tokens_of_rows): each is pulled back as a gather through the other's
-index, where autodiff would zero-fill [N, D] and scatter-add. The
+index, where autodiff would zero-fill [N, D] and scatter-add. The map
+back to the tokens, forward of the one and pulled back of the other
+(_sum_of_pairs), runs on the kernel of ops/pallas_pair_sum.py wherever
+its gate takes the shape: one pass over the live rows (PR 47). The
 rotation is linear in X and its generic gradient is the rotation by the
 opposite angle.
 """
@@ -49,7 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from . import kernel_choice
+from . import kernel_choice, pallas_pair_sum
 from .common import in_var, same_as_input, set_out
 from .registry import NO_GRAD, op
 
@@ -463,12 +466,15 @@ def _sum_of_pairs(rows, pos, live_rows, weight=None):
     reshape and reads back. The layer alone, forward + gradient on a v5e,
     a gather a slot | one gather | the scatter-add: 6.11 | 6.82 | 8.45 ms
     at [4096 x 4, 2048] with every pair handled, 3.15 | 6.27 | 3.74 at
-    [4096 x 6, 2688] with 6144 handled. In a step's trace the forward's
-    gathers, weights and sum are one pass (in the latent-attention cell
-    the epilogue of the shared expert's down product, +0.03 ms); pulled
-    back each slot's gather is a pass of its own and the add one more
-    (0.7 ms a layer there where the fill and scatter-add took 1.5; 0.83
-    against 0.78 at the second shape) (PERF.md section 6, PR 38)."""
+    [4096 x 6, 2688] with 6144 handled (PERF.md section 6, PR 38). In a
+    step's trace the forward's gathers, weights and sum are one pass only
+    behind a shared expert, whose down product takes them into its
+    epilogue (+0.03 ms in the latent-attention cell); without one, and
+    pulled back everywhere, each slot's gather is a pass of its own that
+    writes [N, D] in float32 and the add reads all top_k of them (537 MB
+    at [8192, 2048], top 8). Since PR 47 this is the path of the shapes
+    ops/pallas_pair_sum.py declines, and the statement its kernel is
+    tested against (_pairs_summed chooses)."""
     total = 0
     for j in range(pos.shape[1]):
         ok = pos[:, j] < live_rows
@@ -479,37 +485,66 @@ def _sum_of_pairs(rows, pos, live_rows, weight=None):
     return total
 
 
-@jax.custom_vjp
-def _rows_of_tokens(x, token, pos, live_rows):
-    """x[token]: [N, D] -> [C, D]. Pulled back, dX[n] is the sum over the
-    token's pairs of their rows' cotangents, in float32."""
-    return x.at[token].get(mode="promise_in_bounds")
+_PAIR_SUM_OP = "pair_sum"
 
 
-def _rows_of_tokens_fwd(x, token, pos, live_rows):
-    return _rows_of_tokens(x, token, pos, live_rows), (pos, live_rows)
+def _pairs_summed(rows, pos, windows, live_rows, weight=None,
+                  out_dtype=jnp.float32):
+    """_sum_of_pairs as `out_dtype`: on the kernel of
+    ops/pallas_pair_sum.py where the op's gate took it (`windows` is then
+    the sort's pair_windows), as written above where it declined (None).
+    Both callers below take the one path: XLA fuses the gathers into no
+    consumer when pulled back, and forward only behind a shared expert's
+    down product, which a lowering cannot see."""
+    if windows is None:
+        return _sum_of_pairs(rows, pos, live_rows, weight).astype(out_dtype)
+    from .pallas_attention import _interpret
+    return pallas_pair_sum.pair_sum(rows, pos, live_rows, windows, weight,
+                                    out_dtype=out_dtype,
+                                    interpret=_interpret())
 
 
-def _rows_of_tokens_bwd(res, ct):
-    return _sum_of_pairs(ct, *res).astype(ct.dtype), None, None, None
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _rows_of_tokens(x, token, pos, windows, live_rows, dtype):
+    """x[token] as `dtype`, zero from `live_rows` on: [N, D] -> [C, D].
+    Pulled back, dX[n] is the sum over the token's live pairs of their
+    rows' cotangents, in float32. The cast and the select are inside the
+    rule so that the cotangent arrives as the grouped product's gradient
+    leaves it, in the compute dtype and unselected: _pairs_summed reads
+    no row from `live_rows` on, and outside the rule their transposes
+    were a pass over [C, D] that wrote it out in float32 ahead of the
+    gathers (0.31 ms a layer at [16384, 2048]: PERF.md section 6, PR 47)."""
+    live = (jnp.arange(token.shape[0]) < live_rows)[:, None]
+    rows = x.at[token].get(mode="promise_in_bounds")
+    return jnp.where(live, rows.astype(dtype), 0)
+
+
+def _rows_of_tokens_fwd(x, token, pos, windows, live_rows, dtype):
+    return (_rows_of_tokens(x, token, pos, windows, live_rows, dtype),
+            (pos, windows, live_rows, x[:0]))
+
+
+def _rows_of_tokens_bwd(dtype, res, ct):
+    *sort, like = res
+    return (_pairs_summed(ct, *sort, out_dtype=like.dtype),) + (None,) * 4
 
 
 _rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
 
 
 @jax.custom_vjp
-def _tokens_of_rows(y, weight, head, pos, live_rows):
+def _tokens_of_rows(y, weight, head, pos, windows, live_rows):
     """Out[n] = sum_j weight[n, j] * y[pos[n, j]] over the pairs with a
     live row: [C, D] in the compute dtype as the kernel leaves it (rows
     past the live ones undefined) -> [N, D] float32. Pulled back in
     sorted order, where one gather of the cotangent's rows serves both
     gradients: dY[p] = weight[pair p] * dOut[token p], dWeight[pair p] =
     y[p] . dOut[token p], zero past the live rows."""
-    return _sum_of_pairs(y, pos, live_rows, weight)
+    return _pairs_summed(y, pos, windows, live_rows, weight)
 
 
-def _tokens_of_rows_fwd(y, weight, head, pos, live_rows):
-    return (_tokens_of_rows(y, weight, head, pos, live_rows),
+def _tokens_of_rows_fwd(y, weight, head, pos, windows, live_rows):
+    return (_tokens_of_rows(y, weight, head, pos, windows, live_rows),
             (y, weight, head, pos, live_rows))
 
 
@@ -524,7 +559,7 @@ def _tokens_of_rows_bwd(res, ct):
     ok = pos < live_rows
     d_weight = jnp.where(ok, d_by_row.at[jnp.where(ok, pos, 0)].get(
         mode="promise_in_bounds"), 0)
-    return d_y, d_weight, None, None, None
+    return d_y, d_weight, None, None, None, None
 
 
 _tokens_of_rows.defvjp(_tokens_of_rows_fwd, _tokens_of_rows_bwd)
@@ -536,16 +571,16 @@ def _handle_rows(capacity, kernel, gate_act, sort, sizes, x, weight, w1, w2,
     routed ones, which the caller knows to be no more, then dead ones):
     their tokens' rows gathered, the grouped products, and each token's
     top_k rows gathered back through the inverse of the sort, weighted
-    and summed in float32. `sort` = (order, its inverse [N, top_k]).
+    and summed in float32. `sort` = (order, its inverse [N, top_k], the
+    kernel's pair_windows or None: _pairs_summed).
     -> (Out, the pairs it combined)."""
-    order, pos = sort
+    order, pos, windows = sort
     head = order[:capacity]
     live_rows = jnp.minimum(sizes.sum(), capacity)
-    live = (jnp.arange(capacity) < live_rows)[:, None]
-    rows = _rows_of_tokens(x, head // weight.shape[1], pos, live_rows)
-    rows = jnp.where(live, rows.astype(w1.dtype), 0)
+    rows = _rows_of_tokens(x, head // weight.shape[1], pos, windows,
+                           live_rows, w1.dtype)
     out = _grouped_products(rows, w1, w2, sizes, kernel, gate, gate_act)
-    out = _tokens_of_rows(out, weight, head, pos, live_rows)
+    out = _tokens_of_rows(out, weight, head, pos, windows, live_rows)
     return out.astype(x.dtype), (pos < live_rows).sum()
 
 
@@ -670,7 +705,6 @@ def _moe_experts(ctx, op_, ins):
     local = idx.reshape(-1) - op_.attr("expert_offset", 0)
     group = jnp.where((local >= 0) & (local < held), local, held)
     order = jnp.argsort(group, stable=True)           # held experts first
-    sort = order, jnp.argsort(order).astype(jnp.int32).reshape(n, k)
     sizes = (group[:, None] == jnp.arange(held)).sum(0, dtype=jnp.int32)
     routed = sizes.sum()
 
@@ -679,6 +713,12 @@ def _moe_experts(ctx, op_, ins):
     kernel = _interpret() if reason is None else None
     rungs = _capacity_ladder(n * k, held, op_.attr("num_experts", held))
     rung = (routed > jnp.asarray(rungs[:-1], jnp.int32)).sum()
+    # the token side's kernel, if it takes every rung (_pairs_summed)
+    declined = next(filter(None, (pallas_pair_sum.ineligible(
+        n, c, x.shape[-1], held) for c in rungs)), None)
+    kernel_choice.book(_PAIR_SUM_OP, declined)
+    sort = (order, jnp.argsort(order).astype(jnp.int32).reshape(n, k),
+            None if declined else pallas_pair_sum.pair_windows(group, held, k))
     if len(rungs) == 1:    # no conditional and no gradient rule of its own
         out, combined = _handle_rows(rungs[0], kernel, gate_act, sort, sizes,
                                      x, weight, *_cast((w1, w2, gate), dtype))

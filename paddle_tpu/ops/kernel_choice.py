@@ -17,7 +17,7 @@ import contextlib
 
 from .. import telemetry
 
-__all__ = ["GATES", "REASONS", "book", "in_retrace", "retrace"]
+__all__ = ["GATES", "REASONS", "WITHIN", "book", "in_retrace", "retrace"]
 
 _ATTENTION = frozenset({"shape", "seq", "heads", "head_dim", "block"})
 
@@ -32,6 +32,8 @@ REASONS = {
     "block_diffusion_attention": _ATTENTION,
     "ssd_scan": frozenset({"chunk", "state", "heads"}),
     "moe_experts": frozenset({"rows", "width"}),
+    # moe_experts' second choice, the token side's kernel (WITHIN)
+    "pair_sum": frozenset({"width", "tokens", "rows", "experts"}),
 }
 
 # op -> the functions of paddle_tpu.ops whose `return "<reason>"` lines
@@ -44,7 +46,12 @@ GATES = {
     "block_diffusion_attention": _ATTENTION_GATE,
     "ssd_scan": ("hybrid_ops.ssd_scan_ineligible",),
     "moe_experts": ("hybrid_ops.gmm_ineligible",),
+    "pair_sum": ("pallas_pair_sum.ineligible",),
 }
+
+# a kernel that is one of several choices of an op's lowering is booked
+# under its own name: kernel -> the registered op whose lowering books it
+WITHIN = {"pair_sum": "moe_experts"}
 
 _RETRACE = False
 

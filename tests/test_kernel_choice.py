@@ -18,11 +18,20 @@ from test_nemotron_h import SCAN_ORDER, routed, scan_inputs
 from test_registry_lint import _load_checker
 
 OPS = ("conv2d", "scaled_dot_product_attention", "block_diffusion_attention",
-       "ssd_scan", "moe_experts")
+       "ssd_scan", "moe_experts", "pair_sum")
 
 
 def test_the_table_holds_the_five_ops_with_a_kernel():
+    """Five registered ops, and `pair_sum`: moe_experts' second choice,
+    a kernel booked under its own name WITHIN that op (PR 47)."""
     assert tuple(kernel_choice.REASONS) == OPS == tuple(kernel_choice.GATES)
+    assert kernel_choice.WITHIN == {"pair_sum": "moe_experts"}
+
+
+def test_the_lint_catches_a_kernel_within_no_registered_op(monkeypatch):
+    assert not _problems("pair_sum")
+    monkeypatch.setitem(kernel_choice.WITHIN, "pair_sum", "moe_expert")
+    assert any("not registered" in m for _, m in _problems("pair_sum"))
 
 
 # --- the lint ---------------------------------------------------------------
@@ -202,7 +211,10 @@ def _mul_o3():
 # but for conv2d, whose explicit gradient op asks no gate
 BOOKED = {
     "ssd_scan": (_scan, {"pallas_kernel_total": {"op=ssd_scan": 1}}),
-    "moe_experts": (_experts, {"pallas_kernel_total": {"op=moe_experts": 1}}),
+    # its 64 tokens are no whole tile of the token side's kernel
+    "moe_experts": (_experts, {
+        "pallas_kernel_total": {"op=moe_experts": 1},
+        "pallas_fallback_total": {"op=pair_sum,reason=tokens": 1}}),
     "gelu_kept": (_gelu, {"activation_kept_total": {"act=gelu": 1}}),
     "conv2d_o3": (_conv_o3, {"pallas_kernel_total": {"op=conv2d": 1},
                              "quant_kernel_total": {"op=conv2d": 1}}),

@@ -274,6 +274,43 @@ def test_grouped_expert_products_compile(mosaic, one_chip, rows, dtype):
         "gmm"] * 3 + ["tgmm"] * 2
 
 
+@pytest.mark.parametrize("under_switch", [False, True],
+                         ids=["alone", "in_a_switch_branch"])
+@pytest.mark.parametrize("n,k,d,c", [(8192, 8, 2048, 16384),
+                                     (8192, 6, 2560, 49152)],
+                         ids=["block_diffusion_cell", "sliding_window_cell"])
+def test_pair_sum_compiles(mosaic, one_chip, n, k, d, c, under_switch):
+    """The token side of the two cells without a shared expert (PR 47):
+    8192 positions x top 8 of 2048 over the 16384-row rung, and 8192
+    tokens x top 6 of 2560 over all 49152 pairs, 8 experts held: the
+    forward's weighted map from the grouped product's bf16 rows to
+    float32 and the gradient's from the bf16 cotangent to bf16, as
+    hybrid_ops hands them to the kernel, alone and as the work of a
+    lax.switch branch (the ladder's)."""
+    from paddle_tpu.ops import pallas_pair_sum
+    held = 8
+    assert pallas_pair_sum.ineligible(n, c, d, held) is None
+    tiles = n // pallas_pair_sum._TILE
+
+    def maps(rows, ct, pos, windows, live, weight):
+        return (hybrid_ops._pairs_summed(rows, pos, windows, live, weight),
+                hybrid_ops._pairs_summed(ct, pos, windows, live,
+                                         out_dtype=ct.dtype))
+
+    def switched(rung, *operands):
+        return jax.lax.switch(rung, [maps, lambda *a: maps(*a)[::-1][::-1]],
+                              *operands)
+
+    shapes = (((c, d), BF16), ((c, d), BF16), ((n, k), jnp.int32),
+              ((2, tiles, held), jnp.int32), ((), jnp.int32),
+              ((n, k), jnp.float32))
+    if under_switch:
+        assert _compile(switched, one_chip, ((), jnp.int32), *shapes) == [
+            "pair_sum"] * 4
+    else:
+        assert _compile(maps, one_chip, *shapes) == ["pair_sum"] * 2
+
+
 def _scan_fwd_bwd(chunk, dtype):
     from paddle_tpu.ops import pallas_scan
 
@@ -322,7 +359,9 @@ def test_hybrid_expert_layer_step_holds_one_switch_each_way(mosaic, one_chip):
     layer's forward and its gradient are one conditional each, two
     branches (the rungs 6144 and 24576), the forward that the gradient op
     traces again is dropped, and each gradient branch runs its forward's
-    two products, their two partners on the rows and two on the weights.
+    two products, their two partners on the rows and two on the weights;
+    the token side's kernel runs once in each of the four branches (PR 47:
+    the forward's weighted map, the gradient's pulled-back one).
     The mixer's scan is one forward kernel (the one the gradient op
     traces again merged with it) and one gradient kernel."""
     text = _hybrid_mixer_and_expert_step(one_chip)
@@ -336,7 +375,7 @@ def test_hybrid_expert_layer_step_holds_one_switch_each_way(mosaic, one_chip):
     kernels = [re.search(r'op_name="[^"]*?(\w+)\)*/pallas_call', line).group(1)
                for line in text.splitlines() if KERNEL in line]
     assert {k: kernels.count(k) for k in set(kernels)} == {
-        "gmm": 2 * (2 + 2 + 2), "tgmm": 2 * 2,
+        "gmm": 2 * (2 + 2 + 2), "tgmm": 2 * 2, "pair_sum": 2 * 2,
         "ssd_scan_fwd": 1, "ssd_scan_bwd": 1}
 
 
@@ -457,7 +496,8 @@ def test_latent_attention_step_compiles_with_one_cast_of_the_shared_head(
     # kept by tools/describe_step.py's bare environment and by the chip
     count = {k: kernels.count(k) for k in set(kernels)}
     assert count.pop("gmm") in (6, 9)
-    assert count == {"flash_fwd": 2, "flash_dkv": 2, "tgmm": 3}
+    # and the token side's kernel, forward and pulled back (PR 47)
+    assert count == {"flash_fwd": 2, "flash_dkv": 2, "tgmm": 3, "pair_sum": 2}
     # an eighth of the experts held: one rung, no switch (PR 36)
     assert "pd.moe_experts/cond" not in text
     width = "[%d,%d]" % (config["hidden_size"], config["vocab_size"])
@@ -478,14 +518,15 @@ def test_latent_attention_cell_fits_the_chip_at_4096_tokens(mosaic, one_chip):
     PR 43 fused the six attention ops' two backward kernels) and 57 under
     this harness's XLA_FLAGS, which merge the 15 forward products the
     expert layers' generic gradient traces again with the originals (as
-    the one-block test above allows); 6.15 GB of temporaries + 8.48 GB
-    of aliased state."""
+    the one-block test above allows), and since PR 47 the five expert
+    layers' `pair_sum`, forward and pulled back; 6.15 GB of temporaries +
+    8.48 GB of aliased state."""
     cell = run.load_json("workloads", MLA_CELL)
     config = run.load_json("configs", cell["config"])
     compiled = describe_step.compile_step(cell, config, one_chip)
     mem = compiled.memory_analysis()
     text = compiled.as_text()
-    assert text.count(KERNEL) in (57, 72)
+    assert text.count(KERNEL) in (57 + 10, 72 + 10)
     assert text.count("flash_fwd") and "flash_dq" not in text
     assert mem.alias_size_in_bytes > 8.4e9
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 15.75e9
@@ -517,6 +558,8 @@ def test_block_diffusion_step_compiles_with_no_square_of_scores(
     flash = {k: kernels.count(k) for k in set(kernels) if "flash" in k}
     assert flash == {"flash_fwd": 2, "flash_dkv": 2}
     assert "gmm" in kernels and "tgmm" in kernels
+    # the token side, forward and pulled back, in both rungs (PR 47)
+    assert kernels.count("pair_sum") == 2 * 2
     assert "pd.moe_experts/cond" in text
     scoped = [i for i in xplane.hlo_instructions(text)
               if "block_diffusion_attention" in (i.op_name or "")]
@@ -557,6 +600,8 @@ def test_window_step_compiles_with_both_kinds_of_layer(mosaic, one_chip):
     flash = {k: kernels.count(k) for k in set(kernels) if "flash" in k}
     assert flash == {"flash_fwd": 2, "flash_dkv": 2}
     assert "gmm" in kernels and "tgmm" in kernels
+    # the token side, forward and pulled back, in both layers (PR 47)
+    assert kernels.count("pair_sum") == 2 * 2
     assert "pd.moe_experts/cond" not in text
     scoped = {kind: [i for i in xplane.hlo_instructions(text)
                      if "pd_scope." + kind in (i.op_name or "")]

@@ -345,7 +345,8 @@ def check_pallas_table():
     """[(where, message), ...] — pin the kernel layer's two tables.
 
     kernel_choice.REASONS (op -> the reasons its gate may give for the
-    XLA path), for every op in it: the reasons the sources of its gate
+    XLA path), for every op in it (a registered op, or a kernel that
+    kernel_choice.WITHIN places in one): the reasons the sources of its gate
     (kernel_choice.GATES) can return equal the declared set, both ways.
     A produced reason that is not declared raises at the first lowering
     that meets it; a declared reason nothing produces is a dead counter
@@ -402,9 +403,10 @@ def check_pallas_table():
     # every reason a gate can return must be declared, and vice versa
     for name in sorted(set(kernel_choice.REASONS) | set(kernel_choice.GATES)):
         where = f"kernel_choice.REASONS['{name}']"
-        if name not in registered:
+        if kernel_choice.WITHIN.get(name, name) not in registered:
             problems.append((where, "op is not registered in "
-                                    "ops/registry.py"))
+                                    "ops/registry.py, nor a kernel WITHIN "
+                                    "one that is"))
         if name not in kernel_choice.GATES or \
                 name not in kernel_choice.REASONS:
             problems.append((
