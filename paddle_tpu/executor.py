@@ -45,6 +45,7 @@ from .framework.framework import (NAME_SCOPE_ATTR, Program, Variable,
                                   default_main_program)
 from .ops import registry
 from .ops import sparse_ops as sparse_ops_mod
+from .ops.sibling_products import OpenProducts
 
 __all__ = [
     "CPUPlace", "TPUPlace", "CUDAPlace", "place_device",
@@ -410,6 +411,12 @@ SEQLEN2_SUFFIX = "@SEQLEN2"   # inner lengths [B, S] of nested (level-2) LoD
 _SPARSE_AWARE_OPS = frozenset(
     {"sum"} | set(sparse_ops_mod.SPARSE_APPLY_OPS)
     | {"fused_sparse_" + t for t in sparse_ops_mod.SPARSE_APPLY_OPS})
+
+
+# ops that take an OpenProducts value as it is: `sum` folds it, and a
+# fused chain (the one window kind that can hold a `sum`) runs its
+# members through _exec_op, where each meets this same boundary
+_OPEN_PRODUCT_AWARE_OPS = frozenset({"sum", "fused_chain"})
 
 
 def _bucket_len(n: int) -> int:
@@ -1753,6 +1760,15 @@ class Executor:
             propagate_tag = layout_mod.prepass(ctx.layouts, op, op.type, env)
         ins = {slot: [env.get(n) for n in names]
                for slot, names in op.desc.inputs.items()}
+        if op.type not in _OPEN_PRODUCT_AWARE_OPS:
+            # the gradient of one activation through sibling products,
+            # left open by their gradient ops and folded by the program's
+            # `sum` (ops/sibling_products.py), becomes an array where the
+            # first other op reads it: one contraction, reduced once
+            for slot, names in op.desc.inputs.items():
+                for k, n in enumerate(names):
+                    if isinstance(ins[slot][k], OpenProducts):
+                        ins[slot][k] = env[n] = ins[slot][k].close()
         if op.type not in _SPARSE_AWARE_OPS:
             # SelectedRows grads (sparse embedding path) densify at the
             # boundary of any op without a sparse kernel — the analogue of

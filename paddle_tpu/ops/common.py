@@ -43,6 +43,9 @@ def maybe_dense(v, count_as: Optional[str] = None):
             from . import sparse_ops
             sparse_ops.count_densify(count_as, "densified_at_" + count_as)
         return v.to_dense()
+    from .sibling_products import OpenProducts
+    if isinstance(v, OpenProducts):
+        return v.close()
     return v
 
 

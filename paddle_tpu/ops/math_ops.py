@@ -16,8 +16,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import kernel_choice
-from .registry import NO_GRAD, op, register
+from ..framework.desc import OpDesc
+from ..framework.framework import Operator
+from . import kernel_choice, sibling_products
+from .registry import (NO_GRAD, generic_grad_lower, infer_grad_shapes, op,
+                       register)
 from .common import (SelectedRowsVal, maybe_dense, broadcast_y_to_x, in_var, matmul_shape, mxu_cast, out_var,
                      same_as_input, set_out)
 
@@ -67,6 +70,34 @@ def _mul(ctx, op_, ins):
         out2d = out2d.astype(restore)
     out_shape = x.shape[:xn] + y.shape[yn:]
     return {"Out": [out2d.reshape(out_shape)]}
+
+
+def _mul_grad(ctx, op_, ins):
+    """The generic vjp of `mul`, but for a product that
+    `sibling_products.members` admits: its weight's gradient as ever, and
+    in place of `dX` the pair (dOut, Y) left open, for the program's
+    `sum` to fold with its siblings' and the first reader to contract
+    once."""
+    if not sibling_products.is_member(ctx, op_, ins):
+        return generic_grad_lower(ctx, op_, ins)
+    outs = {}
+    rest = {s: ns for s, ns in op_.desc.outputs.items() if s != "X@GRAD"}
+    if rest:
+        view = Operator.__new__(Operator)
+        view.block = getattr(op_, "block", None)
+        view.desc = OpDesc(type=op_.type, inputs=dict(op_.desc.inputs),
+                           outputs=rest, attrs=dict(op_.desc.attrs))
+        outs = generic_grad_lower(ctx, view, ins)
+    x, y = jnp.asarray(ins["X"][0]), jnp.asarray(ins["Y"][0])
+    (xf, yf), _ = mxu_cast(ctx, _flat2(x, op_.attr("x_num_col_dims", 1)),
+                           _flat2(y, 1))
+    outs["X@GRAD"] = [sibling_products.open_pair(
+        ctx, x, yf, ins["Out@GRAD"][0], jnp.result_type(xf, yf))]
+    return outs
+
+
+register("mul_grad", lower=_mul_grad, infer_shape=infer_grad_shapes,
+         grad=NO_GRAD)
 
 
 def _matmul_infer(op_, block):
@@ -383,6 +414,8 @@ def _sum(ctx, op_, ins):
     dense+sparse mixes): all-sparse inputs concatenate rows/values (rows may
     repeat, like the reference's unmerged SelectedRows), a mix densifies."""
     raw = [x for x in ins["X"] if x is not None]
+    if any(isinstance(x, sibling_products.OpenProducts) for x in raw):
+        return {"Out": [sibling_products.fold(raw)]}
     if raw and all(isinstance(x, SelectedRowsVal) for x in raw):
         if len(raw) == 1:
             return {"Out": [raw[0]]}

@@ -86,7 +86,9 @@ def expected_collectives(program) -> Dict[str, str]:
             continue
         if ndim >= 2 and spec[-1]:
             out[name] = ("column-parallel ({0}): activation all-gather on "
-                         "use, grad reduce-scatter".format(spec[-1]))
+                         "use, grad reduce-scatter; siblings of one "
+                         "activation reduce their input gradient once"
+                         .format(spec[-1]))
         elif ndim >= 2 and spec[0]:
             out[name] = ("row-parallel ({0}): output all-reduce"
                          .format(spec[0]))

@@ -876,6 +876,10 @@ METRIC_CATALOG = {
     "activation_kept_total": _m("counter", ("act",),
                                 "lowerings of an activation evaluated once "
                                 "and kept (ops/math_ops.py KEPT_ACTS)"),
+    "sibling_products_merged_total": _m(
+        "counter", ("program", "direction"),
+        "groups of sibling products of one activation traced as one "
+        "contraction (ops/sibling_products.py)"),
     "quant_kernel_total": _m("counter", ("op",),
                              "ops routed through int8/fp8 quantization"),
     "quant_fallback_total": _m("counter", ("op", "reason"),

@@ -507,3 +507,58 @@ class TestExecutorIntegration:
         _with_overlap(True, _train, _build_fc, 8, 1)
         series = telemetry.read_series("overlap_buckets_total")
         assert sum(series.values()) >= 2        # >= 2 buckets flushed
+
+    def test_merged_sibling_gradients_leave_the_buckets_where_they_were(
+            self, monkeypatch):
+        """ISSUE 48: on dp x tp the input gradients of q, k and v are
+        traced as one contraction (ops/sibling_products.py). The plan
+        reads the ProgramDesc, which the merge does not touch: the same
+        buckets at the same anchors with the matcher's table emptied, each
+        of the three weights' gradients still written by its own mul_grad
+        (and left to GSPMD as `tp_sharded`), and the trace flushes every
+        bucket it flushed before."""
+        import jax
+
+        import chip_smoke
+        from paddle_tpu.ops import sibling_products
+        from paddle_tpu.parallel import planner
+        from paddle_tpu.parallel.mesh import make_mesh
+
+        if len(jax.devices()) < 4:
+            pytest.skip("needs four (virtual) devices")
+        monkeypatch.setenv("PADDLE_TPU_OVERLAP_BUCKET_MB", "0.001")
+
+        def traced():
+            telemetry.reset()
+            main, startup, loss = chip_smoke._build_lm(
+                seqlen=32, d_model=32, n_head=2, n_layer=2, vocab=101,
+                use_flash=False)
+            planner.plan(main, make_mesh((2, 2), ("dp", "tp"),
+                                         devices=jax.devices()[:4]))
+            plan = overlap.plan(main)
+            exe = fluid.Executor(fluid.CPUPlace())
+            with em.scope_guard(em.Scope()):
+                exe.run(startup)
+                exe.run(main, feed=chip_smoke._lm_feed(4, 32, 101),
+                        fetch_list=[loss])
+            ops = main.global_block().ops
+            writers = {n: op.type for op in ops
+                       for n in op.desc.output_arg_names()
+                       if n.endswith(".w_0@GRAD")}
+            return ([(b.params, b.grads, b.anchor, b.spec)
+                     for b in plan.buckets], writers,
+                    sum(telemetry.read_series(
+                        "overlap_buckets_total").values()),
+                    _fallbacks("tp_sharded"),
+                    sum(telemetry.read_series(
+                        "sibling_products_merged_total").values()))
+
+        with_rule = _with_overlap(True, traced)
+        monkeypatch.setattr(sibling_products, "SIBLING_OPS", frozenset())
+        without = _with_overlap(True, traced)
+        assert with_rule[4] == 2 and without[4] == 0
+        assert with_rule[:4] == without[:4]
+        buckets, writers, flushed, tp_sharded, _ = with_rule
+        assert buckets and flushed >= len(buckets) and tp_sharded >= 6
+        assert {writers["fc_%d.w_0@GRAD" % i] for i in range(3)} == {
+            "mul_grad"}

@@ -16,10 +16,12 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, SingleDeviceSharding
 
 from benchmarks import run
+from paddle_tpu import xplane
 from paddle_tpu.ops import (hybrid_ops, kernel_choice, pallas_attention,
                             pallas_conv)
 from tools import describe_step
@@ -466,6 +468,43 @@ def test_gpt2_step_evaluates_gelu_outside_every_products_operand(
     assert operands
     assert not operands & erfc, sorted(operands & erfc)
     assert compiled.memory_analysis().temp_size_in_bytes < 3.73e9 + 0.25e9
+
+
+def test_planned_gpt2_large_layer_reduces_qkv_input_gradient_once(
+        mosaic, topo):
+    """One layer of `gpt2-large.train-fsdp2-tp2`, planned fsdp=2 x tp=2
+    over the described chips and compiled as the executor compiles a
+    planned step: the input gradients of q, k and v are one contraction
+    (`ops/sibling_products.py`), so the tp axis reduces 5 arrays of a
+    whole `bf16[8192, 1280]` activation a step and chip, none of them in
+    a tuple (Megatron's 4 a layer and the head's input gradient; the
+    parent reduced 7, three of them in one tuple, 147.6 MB where this
+    reads 105.6), and the cotangents' stack is an operand of the product,
+    not an array of its own (PERF.md section 6, PR 48)."""
+    from paddle_tpu import telemetry
+
+    def merged():
+        return sum(telemetry.read_series(
+            "sibling_products_merged_total").values())
+
+    cell = run.load_json("workloads", "gpt2-large.train-fsdp2-tp2")
+    config = dict(run.load_json("configs", cell["config"]), n_layer=1)
+    before = merged()
+    sizes = tuple(cell["mesh"].values())
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(sizes), tuple(cell["mesh"]))
+    compiled = describe_step.compile_step(cell, config, mesh)
+    assert merged() - before == 1
+    text = compiled.as_text()
+    whole = re.compile(r"bf16\[(8192,1280|8,1024,1280)\]")
+    carried = [len(whole.findall(i.shape))
+               for i in xplane.hlo_instructions(text, mesh=cell["mesh"])
+               if i.kind == "all-reduce" and i.axis == "tp"]
+    assert sum(carried) == 5 and max(carried) == 1, carried
+    rows = describe_step.collective_rows(text, cell["mesh"])
+    assert rows[("tp", "all-reduce")][1] < 110e6
+    entry = text[text.index("ENTRY "):]
+    assert "bf16[3,8192,640]" in text and \
+        not re.search(r"= bf16\[3,8192,640\]", entry)
 
 
 MLA_CELL = "glm-4.7-flash.train-mla-mtp-ep8-share"

@@ -1,5 +1,6 @@
-"""A one-chip benchmark cell's train step, compiled for a described v5e:
-what the chip's compiler makes of it, without the chip.
+"""A benchmark cell's train step, compiled for a described v5e (one chip,
+or the cell's planner mesh over a described 2 x 2): what the chip's
+compiler makes of it, without the chip.
 
     JAX_PLATFORMS=cpu python3 tools/describe_step.py <cell> [--wider N]
         [--set key=value ...] [--hlo FILE] [--account [TOP]]
@@ -16,8 +17,14 @@ overrides a key of the configuration (a depth, to read one layer fast);
 `--account` prints instead the step's account by instruction
 (`paddle_tpu.xplane.hlo_instructions`): FLOPs, bytes and the least a v5e
 could take for each, and their sum, a chipless lower bound of the step
-to set against the ledger's busy time. Nothing runs, so no time comes
-from here (PERF.md section 3).
+to set against the ledger's busy time. A cell with a `mesh` (`gpt2-large.
+train-fsdp2-tp2`; 36 layers take five minutes here, `--set n_layer=2`
+half a minute) is planned over described chips as its traffic kind
+plans it, its numbers are one chip's, and its collectives are printed by
+mesh axis and kind with runs and MB: the chipless half of
+`benchmarks/step_account.py`'s table, which a change to what GSPMD
+reduces is read from before a four-chip call. Nothing runs, so no time
+comes from here (PERF.md section 3).
 """
 
 import argparse
@@ -33,34 +40,59 @@ import numpy as np
 
 
 
-def described_chip():
-    """Chip 0 of a described v5e:2x2, and the kernels steered out of the
-    interpreter: the process sees the CPU and would take it."""
+def _described_devices():
+    """The chips of a described v5e:2x2, and the kernels steered out of
+    the interpreter: the process sees the CPU and would take it."""
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
     from paddle_tpu.ops import pallas_attention, pallas_conv
 
     pallas_attention._interpret = pallas_conv._interpret = lambda: False
-    return SingleDeviceSharding(topologies.get_topology_desc(
-        platform="tpu", topology_name="v5e:2x2").devices[0])
+    return topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices
 
 
-def compile_step(cell, config, chip):
-    """The compiled train step of `cell` under `config`, for the device
-    of the sharding `chip`."""
+def described_chip():
+    """Chip 0 of a described v5e:2x2 as a sharding."""
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(_described_devices()[0])
+
+
+def described_mesh(axes):
+    """The planner mesh of a cell (`{"fsdp": 2, "tp": 2}`, in mesh order)
+    over the chips of a described v5e:2x2, kernels steered as for
+    `described_chip`."""
+    from jax.sharding import Mesh
+
+    sizes = tuple(axes.values())
+    return Mesh(np.array(_described_devices()[:int(np.prod(sizes))]).reshape(
+        sizes), tuple(axes))
+
+
+def compile_step(cell, config, where):
+    """The compiled train step of `cell` under `config`, for `where`: the
+    sharding of one described chip, or for a cell with a `mesh` the
+    described mesh the program is planned over first, as
+    `benchmarks/traffic/train_steps_planned.py` plans it."""
     from benchmarks import run
 
     family = run.load_module("families", config["family"])
     main, startup, loss = family.build(config)
     feed = family.make_batch(config, cell["batch"], np.random.default_rng(0))
-    return compile_program(main, startup, loss, feed, chip)
+    if cell.get("mesh"):
+        from paddle_tpu.parallel import planner
+        planner.plan(main, where, startup=startup)
+    return compile_program(main, startup, loss, feed, where)
 
 
-def compile_program(main, startup, loss, feed, chip):
+def compile_program(main, startup, loss, feed, where):
     """The train step of `main` as Executor.run would trace it (state
-    donated), compiled for the device of the sharding `chip` from avals
-    alone: the state's from `startup`, the feed's from `feed`."""
+    donated), compiled from avals alone, the state's from `startup`, the
+    feed's from `feed`: for the device of the sharding `where`, or, where
+    `main` carries a mesh, with the shardings and compiler options the
+    executor gives a planned step."""
     import paddle_tpu as fluid
+    from paddle_tpu.parallel import overlap
 
     exe = fluid.Executor(fluid.CPUPlace())
 
@@ -68,15 +100,39 @@ def compile_program(main, startup, loss, feed, chip):
         return exe._make_step_fn(program, fetch,
                                  exe._persistable_outputs(program), {})
 
-    def on_chip(tree):
-        return jax.tree_util.tree_map(
-            lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=chip),
-            tree)
-
     rng = np.uint32(0)
     state = jax.eval_shape(step_fn(startup, []), {}, {}, rng)[2]
-    return jax.jit(step_fn(main, [loss.name]), donate_argnums=(1,)).lower(
-        on_chip(feed), on_chip(state), on_chip(rng)).compile()
+    options = {}
+    if getattr(main, "_mesh", None) is None:
+        feed_at, state_at, rng_at = ({n: where for n in feed},
+                                     {n: where for n in state}, where)
+    else:
+        feed_at, state_at, rng_at = exe._shardings(main, list(state),
+                                                   list(feed))
+        options = {"compiler_options": dict(overlap.TPU_OVERLAP_OPTIONS)}
+
+    def avals(tree, at):
+        return {n: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=at[n])
+                for n, v in tree.items()}
+
+    return jax.jit(step_fn(main, [loss.name]), donate_argnums=(1,),
+                   **options).lower(
+        avals(feed, feed_at), avals(state, state_at),
+        jax.ShapeDtypeStruct((), np.uint32, sharding=rng_at)).compile()
+
+
+def collective_rows(text, axes):
+    """{(mesh axis, kind): [instructions, payload bytes]} of a planned
+    step's compiled text, a chip."""
+    from paddle_tpu import xplane
+
+    rows = {}
+    for instr in xplane.hlo_instructions(text, mesh=dict(axes)):
+        if instr.kind:
+            row = rows.setdefault((instr.axis, instr.kind), [0, 0])
+            row[0] += 1
+            row[1] += instr.payload
+    return rows
 
 
 def wide_instructions(text, wider):
@@ -156,13 +212,12 @@ def main(argv=None):
                     "and the TOP instructions by floor")
     args = ap.parse_args(argv)
     cell = run.load_json("workloads", args.cell)
-    if cell["chips"] != 1:
-        sys.exit("%s runs on %d chips: a planned step needs its mesh "
-                 "(.claude/skills/verify, recipe 4)" % (args.cell, cell["chips"]))
     config = run.load_json("configs", cell["config"])
     config.update((k, json.loads(v)) for k, v in
                   (item.split("=", 1) for item in args.set))
-    compiled = compile_step(cell, config, described_chip())
+    compiled = compile_step(
+        cell, config, described_mesh(cell["mesh"]) if cell.get("mesh")
+        else described_chip())
     text = compiled.as_text()
     if args.hlo:
         with open(args.hlo, "w") as f:
@@ -175,6 +230,13 @@ def main(argv=None):
         "alias_bytes": mem.alias_size_in_bytes,
         "output_bytes": mem.output_size_in_bytes,
         "mosaic_calls": text.count('custom_call_target="tpu_custom_call"')}))
+    if cell.get("mesh"):
+        print("%-10s %-20s %6s %10s" % ("axis", "kind", "runs", "MB"))
+        for (axis, kind), (runs, payload) in sorted(
+                collective_rows(text, cell["mesh"]).items(),
+                key=lambda kv: -kv[1][1]):
+            print("%-10s %-20s %6d %10.2f" % (axis or "-", kind, runs,
+                                              payload / 1e6))
     if args.account is not None:
         print_account(text, args.account)
         return
