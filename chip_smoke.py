@@ -10,6 +10,12 @@ width of the models the repo benchmarks, with random weights from a seed:
   lm      transformer LM train step (d_model 512 / 8 heads / 4 layers /
           T=2048, AMP O2) with use_flash=True, and the same step with
           use_flash=False on the same seed: first-step losses agree.
+  recompute
+          a two-layer granite_hybrid_lm (one group of 64 heads of 64,
+          state 128, chunk 128, T=2048, AMP O2) whose first layer's
+          forward is replayed in the backward, and the same step without
+          checkpoints on the same seed: first-step losses agree, the scan
+          runs on its kernels, the replay holds one more forward kernel.
   serve   save_inference_model of that ResNet-50, ServingEngine on the
           saved dir (max batch 8), requests in two batch buckets, answers
           equal to Executor inference on the same inputs.
@@ -262,25 +268,34 @@ def train_phase(batch, side, classes, depth=50, steps=3, window=4,
     return record, (exe, scope, main, predict)
 
 
-def _build_lm(seqlen, d_model, n_head, n_layer, vocab, use_flash):
+def _build_train(seqlen, seed, loss_of):
+    """(main, startup, loss) of an AMP O2 Adam train step of the language
+    model `loss_of(tok, lab)` builds over [B, seqlen] ids: its loss and
+    the checkpoints its backward keeps (None: none)."""
     import paddle_tpu as fluid
-    from paddle_tpu import models
     from paddle_tpu.framework import unique_name
 
     main, startup = fluid.Program(), fluid.Program()
-    main.random_seed = startup.random_seed = SEED + 2
+    main.random_seed = startup.random_seed = seed
     with unique_name.guard(), fluid.program_guard(main, startup):
         tok = fluid.layers.data(name="tok", shape=[-1, seqlen],
                                 dtype="int64", append_batch_size=False)
         lab = fluid.layers.data(name="lab", shape=[-1, seqlen],
                                 dtype="int64", append_batch_size=False)
-        loss = models.transformer_lm(
-            tok, lab, vocab_size=vocab, d_model=d_model, n_head=n_head,
-            n_layer=n_layer, use_flash=use_flash)
+        loss, checkpoints = loss_of(tok, lab)
         opt = fluid.amp.decorate(fluid.optimizer.Adam(learning_rate=1e-4),
                                  level="O2")
-        opt.minimize(loss, startup_program=startup)
+        opt.minimize(loss, startup_program=startup, checkpoints=checkpoints)
     return main, startup, loss
+
+
+def _build_lm(seqlen, d_model, n_head, n_layer, vocab, use_flash):
+    from paddle_tpu import models
+
+    return _build_train(
+        seqlen, SEED + 2, lambda tok, lab: (models.transformer_lm(
+            tok, lab, vocab_size=vocab, d_model=d_model, n_head=n_head,
+            n_layer=n_layer, use_flash=use_flash), None))
 
 
 def _lm_feed(batch, seqlen, vocab):
@@ -355,6 +370,77 @@ def lm_phase(batch, seqlen, d_model, n_head, n_layer, vocab, steps=3,
         # was lowered in it
         **_counters("attention_window_total"),
         **compiles.record(),
+    }
+
+
+def _build_hybrid(seqlen, d_model, heads, head_dim, state, width, vocab,
+                  recompute):
+    from paddle_tpu import models
+
+    return _build_train(
+        seqlen, SEED + 3, lambda tok, lab: models.granite_hybrid_lm(
+            tok, lab, vocab_size=vocab, hidden_size=d_model,
+            layer_types=["mamba", "mamba"], mamba_n_heads=heads,
+            mamba_d_head=head_dim, mamba_n_groups=1, mamba_d_state=state,
+            num_attention_heads=4, num_key_value_heads=2,
+            shared_intermediate_size=width, embedding_multiplier=12,
+            residual_multiplier=0.22, logits_scaling=8,
+            mamba_chunk_size=128, recompute=recompute))
+
+
+def recompute_phase(seqlen, d_model, heads, head_dim, state, width, vocab,
+                    steps=3, rtol=1e-2, compiled=True):
+    """Two Mamba-2 layers of ONE group of `heads` heads with the residual
+    stream kept at the layers' inputs, so that the first layer's forward
+    ops run a second time in the backward
+    (backward.append_backward(checkpoints=)), and the same step without
+    checkpoints on the same seed: the first-step losses agree within
+    `rtol`, no scan falls back, and the compiled step with checkpoints
+    holds one forward scan kernel more (the replay's: XLA merged it with
+    neither the first forward nor dropped it) and the same gradient
+    kernels."""
+    from paddle_tpu import backward
+
+    feed = _lm_feed(1, seqlen, vocab)
+    compiles = _Compiles()
+    before = _counters("pallas_fallback_total")["pallas_fallback_total"]
+    out, mosaic = {}, {}
+    for name, recompute in (("replayed", True), ("kept", False)):
+        main, startup, loss = _build_hybrid(seqlen, d_model, heads, head_dim,
+                                            state, width, vocab, recompute)
+        assert bool(backward.replayed_ops(main)) == recompute
+        losses, step_s, exe, scope = _lm_steps(main, startup, loss, feed,
+                                               steps)
+        out[name] = {"losses": losses, "step_s": step_s}
+        if compiled:
+            mosaic[name] = _mosaic_calls(_step_hlo(exe, main, feed, loss,
+                                                   scope))
+    declined = {k: v - before.get(k, 0) for k, v in _counters(
+        "pallas_fallback_total")["pallas_fallback_total"].items()
+        if "ssd_scan" in k and v > before.get(k, 0)}
+    if declined:
+        raise AssertionError(f"the scan fell back: {declined}")
+    a, b = out["replayed"]["losses"][0], out["kept"]["losses"][0]
+    if abs(a - b) > rtol * abs(b):
+        raise AssertionError(
+            f"first-step loss: with checkpoints {a} vs without {b} differ "
+            f"by more than rtol {rtol}")
+    if compiled:
+        want = dict(mosaic["kept"])
+        want["ssd_scan/ssd_scan_fwd"] = want.get("ssd_scan/ssd_scan_fwd",
+                                                 0) + 1
+        if mosaic["replayed"] != want:
+            raise AssertionError(
+                f"the replayed layer's scan: Mosaic calls "
+                f"{mosaic['replayed']} with checkpoints, {mosaic['kept']} "
+                f"without; expected one ssd_scan_fwd more")
+    return {
+        "phase": "recompute", "model": "granite_hybrid_lm", "seqlen": seqlen,
+        "d_model": d_model, "heads_in_one_group": heads, "amp": "O2",
+        "steps": steps, "replayed": out["replayed"], "kept": out["kept"],
+        "first_loss_rel_diff": abs(a - b) / abs(b), "rtol": rtol,
+        "tpu_custom_calls": mosaic,
+        **_counters("recompute_segments_total"), **compiles.record(),
     }
 
 
@@ -546,6 +632,8 @@ def main(argv=None):
                 batch //= 2
         _emit(record)
         _emit(lm_phase(**lm))
+        _emit(recompute_phase(seqlen=2048, d_model=512, heads=64, head_dim=64,
+                              state=128, width=1024, vocab=8192))
         _emit(serve_phase(state, side=224))
     _emit({"ok": True, "device": device})
     return 0

@@ -10,19 +10,38 @@ registry's grad makers (generic vjp-backed by default, registry.py).
 Fan-in accumulation: when several consumers contribute to one variable's
 gradient, later contributions are renamed and summed eagerly (pairwise `sum`
 ops), which is semantically the reference's @RENAME@ + sum_op insertion.
+
+Recomputation by segments (`checkpoints=`): the forward ops between two
+checkpoint variables are a segment. Before a segment's grad ops are
+emitted, the forward ops they read are appended again, in the backward's
+role and marked `recompute_segment` = the segment's index, reading the
+segment's inputs behind one `recompute_barrier` op and writing
+`<name>@RECOMPUTE`; the segment's grad ops read those. What the forward
+made between two checkpoints is then dead at the segment's end, and what
+lives across the forward/backward boundary is the checkpoints. The ops
+after the last checkpoint are not replayed: the backward starts there.
+Without `checkpoints` nothing of this runs and the program is the one it
+was.
 """
 
 from __future__ import annotations
 
 import os
-from typing import Dict, List, Optional, Sequence, Set, Tuple
+from typing import (Dict, List, NamedTuple, Optional, Sequence, Set,
+                    Tuple)
 
-from .framework.desc import OpDesc
+from .framework.desc import BlockRef, BlocksRef, OpDesc
 from .framework.framework import (Block, Parameter, Program, Variable,
                                   grad_var_name)
 from .ops import registry
 
-__all__ = ["append_backward", "calc_gradient"]
+__all__ = ["append_backward", "calc_gradient", "replayed_ops",
+           "RECOMPUTE_ATTR"]
+
+RECOMPUTE_SUFFIX = "@RECOMPUTE"
+# on a replayed forward op: the index of its segment (executor._exec_op
+# lowers it under `pd_recompute.<index>`, xplane.recompute_of reads it back)
+RECOMPUTE_ATTR = "recompute_segment"
 
 
 def _collect_no_grad(block: Block, extra: Optional[Set[str]]) -> Set[str]:
@@ -119,13 +138,173 @@ def _check_silent_zero_grad(block: Block, fwd_op, no_grad: Set[str],
             f"accept zero gradients.")
 
 
+# forward ops whose second run would not give what the first gave
+_RANDOM_OPS = frozenset({
+    "uniform_random", "gaussian_random", "uniform_random_batch_size_like",
+    "gaussian_random_batch_size_like", "sampling_id", "random_crop"})
+
+
+def _replay_refusal(block: Block, op) -> Optional[str]:
+    """Why `op` may not run a second time in the backward, or None."""
+    if any(isinstance(v, (BlockRef, BlocksRef)) for v in op.desc.attrs.values()):
+        return "it runs a sub-block (recomputation covers the root block only)"
+    if op.type in _RANDOM_OPS or (
+            op.type == "dropout" and op.attr("dropout_prob", 0.0)
+            and not op.attr("is_test", False)):
+        return "it draws random numbers: a replay would draw others"
+    for n in op.output_arg_names:
+        v = block.var_recursive(n) if block.has_var_recursive(n) else None
+        if v is not None and v.persistable:
+            return f"it writes the persistable variable {n!r}"
+        if v is not None and v.lod_level:
+            return f"its output {n!r} carries a LoD"
+        if n in op.input_arg_names:
+            return f"it overwrites its input {n!r}"
+    return None
+
+
+def replayed_ops(program: Program) -> Dict[int, List[str]]:
+    """{segment: the types of the forward ops replayed in it, in order}
+    of the root block, read from the ops' RECOMPUTE_ATTR (the barrier is
+    not one of them)."""
+    found: Dict[int, List[str]] = {}
+    for op in program.global_block().ops:
+        seg = op.desc.attrs.get(RECOMPUTE_ATTR)
+        if seg is not None:
+            found.setdefault(seg, [])
+            if op.type != "recompute_barrier":
+                found[seg].append(op.type)
+    return found
+
+
+class _Segment(NamedTuple):
+    """One recomputed segment: `index` among the segments, `ops` the
+    block positions of its forward ops (all on the loss path), `ends` the
+    checkpoints its last op writes."""
+    index: int
+    ops: List[int]
+    ends: List[str]
+
+
+def _segments(block: Block, rel: List[int], checkpoints) -> List[_Segment]:
+    """The forward ops on the loss path cut at the ops that write a
+    checkpoint; what follows the last checkpoint is no segment."""
+    names = {c.name if isinstance(c, Variable) else str(c)
+             for c in checkpoints}
+    writer = {n: i for i in rel for n in block.ops[i].output_arg_names}
+    unknown = sorted(n for n in names if not block.has_var_recursive(n))
+    if unknown:
+        raise ValueError(f"checkpoints {unknown} are not variables of the "
+                         f"program")
+    cuts = sorted({writer[n] for n in names if n in writer})
+    segments, start = [], -1
+    for cut in cuts:
+        ops = [i for i in rel if start < i <= cut]
+        ends = [n for n in block.ops[cut].output_arg_names if n in names]
+        segments.append(_Segment(len(segments), ops, ends))
+        start = cut
+    return segments
+
+
+def _append_replay(block: Block, seg: _Segment, kept: Set[str],
+                   no_grad: Set[str],
+                   produced_count: Dict[str, int]) -> Dict[str, str]:
+    """Append segment `seg`'s barrier and replayed forward ops and return
+    {forward name: the name the segment's grad ops read in its place}.
+    `kept`: every checkpoint's name. Only the forward ops whose outputs a
+    grad op of the segment reads (or a replayed op between them and the
+    segment's inputs) run again; an op that would differ the second time
+    is refused by name."""
+    written = {n for i in seg.ops for n in block.ops[i].output_arg_names
+               if n not in kept}
+    # what the segment's grad ops read of the forward pass: a generic
+    # grad op (registry.generic_grad_lower) traces its forward op again
+    # from the inputs and takes the outputs' names alone
+    needed = set()
+    for i in seg.ops:
+        fwd = block.ops[i].desc
+        generic = registry.get(fwd.type).grad is None
+        for g in registry.make_grad_op_descs(fwd, no_grad):
+            read = fwd.input_arg_names() if generic else g.input_arg_names()
+            needed |= {n for n in read if n in written}
+    replayed = []
+    for i in reversed(seg.ops):
+        op = block.ops[i]
+        if needed & set(op.output_arg_names):
+            replayed.append(i)
+            needed |= {n for n in op.input_arg_names if n in written}
+    replayed.reverse()
+    if not replayed:
+        return {}
+    for i in replayed:
+        why = _replay_refusal(block, block.ops[i])
+        if why:
+            raise ValueError(
+                f"recomputation segment {seg.index}: operator "
+                f"'{block.ops[i].type}' (outputs "
+                f"{block.ops[i].output_arg_names}) cannot be replayed: {why}. "
+                f"Move a checkpoint so that it falls outside every segment.")
+
+    def mirror(name, new):
+        fv = block.var_recursive(name)
+        block.create_var(name=new, shape=fv.desc.shape, dtype=fv.dtype,
+                         lod_level=fv.lod_level, stop_gradient=True)
+        return new
+
+    # the segment's inputs: read by a replayed op, written by none of them,
+    # and not state (a parameter is read where it lies)
+    inner = {n for i in replayed for n in block.ops[i].output_arg_names}
+    entering = []
+    for i in replayed:
+        for n in block.ops[i].input_arg_names:
+            if n in inner or n in entering or not block.has_var_recursive(n) \
+                    or block.var_recursive(n).persistable:
+                continue
+            entering.append(n)
+    rename = {n: mirror(n, f"{n}{RECOMPUTE_SUFFIX}.{seg.index}")
+              for n in entering}
+    # the cotangents that enter the segment: the replay waits for them
+    # behind the barrier, and XLA cannot merge it with the first forward
+    cotangents = [grad_var_name(n) for n in seg.ends
+                  if produced_count.get(grad_var_name(n))]
+    attrs = {"op_role": "backward", RECOMPUTE_ATTR: seg.index}
+    block.append_op(
+        type="recompute_barrier",
+        inputs={"X": entering, "Dep": cotangents},
+        outputs={"Out": [rename[n] for n in entering], "DepOut": cotangents},
+        attrs=dict(attrs))
+    for i in replayed:
+        fwd = block.ops[i].desc
+        for n in fwd.output_arg_names():
+            if n not in kept:
+                rename[n] = mirror(n, n + RECOMPUTE_SUFFIX)
+        block.append_op(
+            type=fwd.type,
+            inputs={s: [rename.get(n, n) for n in names]
+                    for s, names in fwd.inputs.items()},
+            outputs={s: [rename.get(n, n) for n in names]
+                     for s, names in fwd.outputs.items()},
+            attrs={**fwd.attrs, **attrs})
+    return rename
+
+
 def append_backward(loss: Variable, parameter_list: Optional[Sequence] = None,
                     no_grad_set: Optional[Set[str]] = None,
-                    callbacks=None) -> List[Tuple[Parameter, Variable]]:
+                    callbacks=None,
+                    checkpoints: Optional[Sequence] = None
+                    ) -> List[Tuple[Parameter, Variable]]:
     """Append grad ops for `loss` and return [(param, grad_var)].
 
     Only root-block autodiff is supported directly; control-flow ops carry
     their own sub-block grad logic via custom grad makers.
+
+    `checkpoints`: variables (or names) of the forward pass to keep; the
+    forward ops between two of them are recomputed in the backward (module
+    docstring). None or an empty list: nothing is. Root block only, as
+    the autodiff itself: an op that runs a sub-block, draws random numbers,
+    writes state or carries a LoD is refused by name where a segment would
+    replay it. replayed_ops(program) reads what each segment replays back
+    from the IR.
     """
     program = loss.block.program
     block = program.global_block()
@@ -155,12 +334,24 @@ def append_backward(loss: Variable, parameter_list: Optional[Sequence] = None,
     produced_count: Dict[str, int] = {loss_g: 1}
     grad_to_var: Dict[str, str] = {loss_g: loss.name}
 
+    # recomputation: a segment is replayed when the walk reaches its last op
+    segments = _segments(block, rel, checkpoints) if checkpoints else []
+    segment_ending_at = {seg.ops[-1]: seg for seg in segments if seg.ops}
+    kept = {n for seg in segments for n in seg.ends}
+    replayed_as: Dict[str, str] = {}
+
     for i in reversed(rel):
         fwd_op = block.ops[i]
+        if i in segment_ending_at:      # the segments tile what lies
+            replayed_as = _append_replay(   # before the last checkpoint
+                block, segment_ending_at[i], kept, no_grad, produced_count)
         gdescs = registry.make_grad_op_descs(fwd_op.desc, no_grad)
         if not gdescs:
             _check_silent_zero_grad(block, fwd_op, no_grad, produced_count)
         for g in gdescs:
+            if replayed_as:
+                g.inputs = {s: [replayed_as.get(n, n) for n in names]
+                            for s, names in g.inputs.items()}
             # Rename duplicate grad writes, then accumulate with sum ops.
             # Exception: a grad op that CONSUMES n@GRAD and produces n@GRAD
             # mirrors a forward op that read-and-overwrote n (while loop

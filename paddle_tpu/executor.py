@@ -40,6 +40,7 @@ from . import sentinel as sentinel_mod
 from . import telemetry
 from . import tracing as tracing_mod
 from . import xplane as xplane_mod
+from .backward import RECOMPUTE_ATTR, replayed_ops
 from .framework.desc import VarType
 from .framework.framework import (NAME_SCOPE_ATTR, Program, Variable,
                                   default_main_program)
@@ -1398,6 +1399,22 @@ class Executor:
                 "executor_compiles_total", "block traces/compiles",
                 labels=("program", "place")).labels(
                     program=prog_label, place=place_label).inc()
+            replayed = replayed_ops(program)
+            if replayed:
+                telemetry.counter(
+                    "recompute_segments_total",
+                    "segments of forward ops a compiled block replays in "
+                    "its backward (append_backward(checkpoints=)), a compile",
+                    labels=("program",)).labels(program=prog_label).inc(
+                        len(replayed))
+                by_type = telemetry.counter(
+                    "recompute_ops_total",
+                    "forward ops replayed in the backward, a compile, by "
+                    "op type", labels=("program", "type"))
+                for types in replayed.values():
+                    for op_type in types:
+                        by_type.labels(program=prog_label,
+                                       type=op_type).inc()
             telemetry.counter(
                 "executor_compile_seconds_total",
                 "XLA compile wall seconds spent inside Executor.run",
@@ -1802,10 +1819,17 @@ class Executor:
             # op built under fluid.name_scope also carries
             # "pd_scope.<outer.inner>" between the two, spelt likewise;
             # _trace_block puts the op's position, "pd_at.<n>", around
-            # all three.
+            # all three. A forward op replayed in the backward
+            # (backward.RECOMPUTE_ATTR) carries "pd_recompute.<segment>"
+            # inside its role (xplane.recompute_of).
             built_under = op.desc.attrs.get(NAME_SCOPE_ATTR)
+            replayed_in = op.desc.attrs.get(RECOMPUTE_ATTR)
             with jax.named_scope(_ROLE_SCOPE.get(op.desc.attrs.get("op_role"),
                                                  _ROLE_SCOPE[None])), \
+                    (jax.named_scope(f"{xplane_mod.RECOMPUTE_SCOPE}"
+                                     f"{replayed_in}")
+                     if replayed_in is not None
+                     else contextlib.nullcontext()), \
                     (jax.named_scope("pd_scope." + built_under.strip(
                         "/").replace("/", ".")) if built_under
                      else contextlib.nullcontext()), \

@@ -9,6 +9,7 @@ glue (loss, optimizer) stays in user code or in `build_classifier`.
 from .alexnet import alexnet
 from .block_diffusion_moe import block_diffusion_moe_lm
 from .googlenet import googlenet
+from .granite_hybrid import granite_hybrid_lm
 from .mla_moe import mla_moe_lm
 from .mnist import mnist_conv, mnist_mlp
 from .nemotron_h import nemotron_h_lm
@@ -20,7 +21,7 @@ from .window_moe import window_moe_lm
 from .common import balance_routers, build_image_classifier
 
 __all__ = [
-    "alexnet", "block_diffusion_moe_lm", "googlenet", "mla_moe_lm", "mnist_conv", "mnist_mlp",
+    "alexnet", "block_diffusion_moe_lm", "googlenet", "granite_hybrid_lm", "mla_moe_lm", "mnist_conv", "mnist_mlp",
     "nemotron_h_lm",
     "resnet_cifar10", "resnet_imagenet", "resnet50",
     "smallnet_mnist_cifar", "transformer_lm",

@@ -222,14 +222,15 @@ def ssd_scan_ineligible(chunk: int, heads_a_group: int, p: int, n: int):
     (kernel_choice.REASONS["ssd_scan"]). Time
     runs along the kernels' lanes, so a chunk must fill 128-lane blocks
     (`chunk`), as must the states' N where B and C stand with time on the
-    sublanes (`state`); a head is P sublanes of the group's block, whole
-    packed bf16 rows of 16, and the group's cumulative log-decays are
-    turned as one 128-row tile a step (`heads`)."""
+    sublanes (`state`); a head is P sublanes of its block, whole packed
+    bf16 rows of 16 (`heads`). Any number of heads a group tiles: a step
+    owns pallas_scan.heads_a_step of them at the op's chunk."""
+    del heads_a_group
     if chunk % 128:
         return "chunk"
     if n % 128:
         return "state"
-    if p % 16 or heads_a_group > 128:
+    if p % 16:
         return "heads"
     return None
 

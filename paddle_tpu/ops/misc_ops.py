@@ -1,5 +1,5 @@
 """Misc + LoD-array ops: assign_value, fill, minus, modified_huber_loss,
-l1_norm, average_accumulates, print, save/load(_combine),
+l1_norm, average_accumulates, print, recompute_barrier, save/load(_combine),
 lod_tensor_to_array / array_to_lod_tensor, split/merge_lod_tensor,
 reorder_lod_tensor_by_rank.
 
@@ -133,6 +133,29 @@ def _print(ctx, op_, ins):
 # --- ModelAverage accumulators ------------------------------------------------
 
 _K_MAX_ACC = 16384   # reference average_accumulates_op.h kMaxNumAccumulates
+
+
+def _recompute_barrier_infer(op_, block):
+    for src, dst in (("X", "Out"), ("Dep", "DepOut")):
+        for name, out in zip(op_.input(src), op_.output(dst)):
+            if name != out and block.has_var_recursive(name):
+                like = block.var_recursive(name).desc
+                desc = block.var_recursive(out).desc
+                desc.shape, desc.dtype = like.shape, like.dtype
+
+
+@op("recompute_barrier", infer_shape=_recompute_barrier_infer, grad=NO_GRAD)
+def _recompute_barrier(ctx, op_, ins):
+    """What stands between a replayed segment and the first forward pass
+    (backward.append_backward(checkpoints=)): Out[i] = X[i], the
+    segment's inputs, and DepOut = Dep, the cotangents entering the
+    segment (written back under their own names), all behind one
+    optimization barrier. The replayed ops read Out, so XLA can neither
+    merge them with the forward ops that computed the same from X nor
+    start them before the cotangents are there."""
+    xs, deps = list(ins.get("X", [])), list(ins.get("Dep", []))
+    out = jax.lax.optimization_barrier(tuple(xs + deps))
+    return {"Out": list(out[:len(xs)]), "DepOut": list(out[len(xs):])}
 
 
 @op("average_accumulates", grad=NO_GRAD,

@@ -13,8 +13,17 @@ owns one (batch, group, chunk): the group's scores are one
 scores are built from it on the VPU and the EUP and consumed by the MXU
 without leaving VMEM.
 
-Grid (batch, group, chunk), the chunk axis sequential: the running state
-of the group's R heads, [R x P, N] float32 (256 KB at R 8, P 64, N 128),
+Grid (batch, head block, chunk), the chunk axis sequential. A head block
+is the R heads of a group that one step owns (heads_a_step): the whole
+group where it has at most 8 heads (the hybrid cell's 8 groups of 8: the
+grid is (batch, group, chunk) as it was), else a divisor of it, so one
+group of 64 heads (granite-4.0-h-micro) is 2 head blocks of 32 at chunk
+128 that read the same B and C; the recurrence is a head's own, so a
+block's state needs no other's, and what the heads of a group share, dB
+and dC, leaves the gradient's kernel as one share a head block
+([B, blocks x N, T]) that jax.numpy adds up behind it (at 8192 tokens 2
+shares of 2 MB each way, against 64 MB of x a pass). The running state
+of a block's R heads, [R x P, N] float32 (256 KB at R 8, P 64, N 128),
 is VMEM scratch, so the recurrence across chunks is in the kernel. The
 forward walks the chunks upward and writes the state ENTERING each
 chunk, in the compute dtype, beside y: the gradient's one residual
@@ -33,7 +42,7 @@ broadcast; the sums over a head's P that the log-decays' gradient needs
 run down the sublanes and arrive lane-dense; and the per-head products
 stream P rows through the MXU, y_r^T = x_r^T W_r^T. For the masks the
 kernel turns one [128, chunk] tile of cum a step on the XLU to have it
-as columns too (why R <= 128). This is also how the hybrid cell's step
+as columns too (why R <= 128: heads_a_step stays under it). This is also how the hybrid cell's step
 holds its activations: at one sequence a step XLA lays [1, T, C] out
 with T minor, so the swapaxes around the kernels are bitcasts there. A
 first version with time on the sublanes ([chunk, R x P] blocks, two
@@ -92,7 +101,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-__all__ = ["ssd_scan_kernels"]
+__all__ = ["heads_a_step", "ssd_scan_kernels"]
 
 _LANES = 128
 _F32 = jnp.float32
@@ -248,30 +257,66 @@ def _call(kernel, name, grid, in_specs, out_specs, out_shape, scratch,
         compiler_params=params)(*operands)
 
 
-def _grid(x, b, chunk, r, p, up: bool):
+# The most positions x heads one grid step owns, chunk x heads of a group.
+# A step's work and its VMEM grow with both: 64 heads of one group
+# (granite-4.0-h-micro) at the published chunk of 256 would be x, y, dy
+# and dx blocks of [4096, 256], 2-4 MB each and double-buffered, a 2 MB
+# state and 64 unrolled masks a step, and Mosaic refuses it for VMEM; so
+# does 32 heads at 256. On the chip (tools/scan_sweep.py at
+# [1, 8192, 64, 64], G 1, N 128, bf16; my chip run, PR 49), forward +
+# gradient in ms by chunk and heads a step: 128 x 8 2.80, x 16 2.43,
+# x 32 2.30; 256 x 8 2.74, x 16 2.60 (ssd_scan_chunked 11.10): more heads
+# a step are fewer steps, and B, C and the scores are fetched and formed
+# once a step. A group of at most 8 heads is one step whatever the chunk
+# (the hybrid cell's 8 groups of 8: the shape the kernels were written
+# and measured at).
+_STEP_ROWS = 4096
+_WHOLE_GROUP = 8
+
+
+def heads_a_step(heads_a_group: int, chunk: int = 128,
+                 itemsize: int = 2) -> int:
+    """The heads of a group that one grid step owns: all of a group of at
+    most _WHOLE_GROUP, else its largest divisor r with chunk x r within
+    _STEP_ROWS of bf16 operands, half that of float32 ones (a head
+    block; module docstring): 32 of 64 at chunk 128, 16 at chunk 256;
+    without AMP 16 and 8 (Mosaic refuses 16 float32 heads at 256)."""
+    if heads_a_group <= _WHOLE_GROUP:
+        return heads_a_group
+    most = max(_WHOLE_GROUP, _STEP_ROWS * 2 // (chunk * itemsize))
+    return max(k for k in range(1, min(heads_a_group, most) + 1)
+               if heads_a_group % k == 0)
+
+
+def _grid(x, b, chunk, r, p, groups, up: bool):
     """(grid, N, BlockSpecs) over x [B, H x P, T] and b [B, T, G x N]:
-    grid (batch, group, chunk step), the step walking the chunks upward,
-    or downward for the gradient."""
+    grid (batch, head block, chunk step), the step walking the chunks
+    upward, or downward for the gradient. A head block is `r` heads of
+    one of the `groups` groups and reads that group's B and C."""
     import jax.experimental.pallas as pl
     bsz, hp, t = x.shape
-    g, chunks = hp // (r * p), t // chunk
-    n, l, rp = b.shape[2] // g, chunk, r * p
+    blocks, chunks = hp // (r * p), t // chunk
+    n, l, rp = b.shape[2] // groups, chunk, r * p
+    per = blocks // groups                     # head blocks a group
 
     def z(s):
         return s if up else chunks - 1 - s
 
-    return (bsz, g, chunks), n, dict(
+    return (bsz, blocks, chunks), n, dict(
         cum=pl.BlockSpec((1, 1, r, l), lambda i, g, s: (i, g, 0, z(s))),
         x=pl.BlockSpec((1, rp, l), lambda i, g, s: (i, g, z(s))),
-        b=pl.BlockSpec((1, l, n), lambda i, g, s: (i, z(s), g)),
-        bt=pl.BlockSpec((1, n, l), lambda i, g, s: (i, g, z(s))),
+        b=pl.BlockSpec((1, l, n), lambda i, g, s: (i, z(s), g // per)),
+        bt=pl.BlockSpec((1, n, l), lambda i, g, s: (i, g // per, z(s))),
+        # a head block's share of dB and dC, [B, blocks x N, T]
+        dbt=pl.BlockSpec((1, n, l), lambda i, g, s: (i, g, z(s))),
         h=pl.BlockSpec((1, 1, rp, n), lambda i, g, s: (i, z(s), g, 0)),
         tot=pl.BlockSpec((1, 1, 1, r, n), lambda i, g, s: (i, z(s), g, 0, 0)))
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "r", "p", "interpret"))
-def _forward(cum, dt, x, b, ct, *, chunk, r, p, interpret):
-    grid, n, sp = _grid(x, b, chunk, r, p, True)
+@functools.partial(jax.jit, static_argnames=("chunk", "r", "p", "groups",
+                                             "interpret"))
+def _forward(cum, dt, x, b, ct, *, chunk, r, p, groups, interpret):
+    grid, n, sp = _grid(x, b, chunk, r, p, groups, True)
     return _call(
         functools.partial(_fwd_kernel, r=r, p=p), "ssd_scan_fwd", grid,
         [sp["cum"], sp["cum"], sp["x"], sp["b"], sp["bt"]],
@@ -281,22 +326,31 @@ def _forward(cum, dt, x, b, ct, *, chunk, r, p, interpret):
         (r * p, n), interpret, cum, dt, x, b, ct)
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "r", "p", "interpret"))
-def _backward(cum, dt, x, dy, b, bt, c, ct, entering, *, chunk, r, p,
+@functools.partial(jax.jit, static_argnames=("chunk", "r", "p", "groups",
+                                             "interpret"))
+def _backward(cum, dt, x, dy, b, bt, c, ct, entering, *, chunk, r, p, groups,
               interpret):
-    grid, n, sp = _grid(x, b, chunk, r, p, False)
-    return _call(
+    grid, n, sp = _grid(x, b, chunk, r, p, groups, False)
+    shares = (grid[0], grid[1] * n, x.shape[2])
+    out = _call(
         functools.partial(_bwd_kernel, r=r, p=p), "ssd_scan_bwd", grid,
         [sp["cum"], sp["cum"], sp["x"], sp["x"], sp["b"], sp["bt"], sp["b"],
          sp["bt"], sp["h"]],
-        [sp["x"], sp["cum"], sp["cum"], sp["bt"], sp["bt"], sp["tot"]],
+        [sp["x"], sp["cum"], sp["cum"], sp["dbt"], sp["dbt"], sp["tot"]],
         [jax.ShapeDtypeStruct(x.shape, x.dtype),
          jax.ShapeDtypeStruct(cum.shape, _F32),
          jax.ShapeDtypeStruct(cum.shape, _F32),
-         jax.ShapeDtypeStruct(bt.shape, b.dtype),
-         jax.ShapeDtypeStruct(ct.shape, c.dtype),
+         jax.ShapeDtypeStruct(shares, b.dtype),
+         jax.ShapeDtypeStruct(shares, c.dtype),
          jax.ShapeDtypeStruct((grid[0], grid[2], grid[1], r, n), _F32)],
         (r * p, n), interpret, cum, dt, x, dy, b, bt, c, ct, entering)
+    if grid[1] == groups:
+        return out
+    # B and C are their group's: the head blocks' shares of dB and dC add up
+    d_x, d_cum, d_dt, d_b, d_c, d_tot = out
+    d_b, d_c = (v.astype(_F32).reshape(grid[0], groups, -1, n, v.shape[2])
+                .sum(2).reshape(bt.shape).astype(v.dtype) for v in (d_b, d_c))
+    return d_x, d_cum, d_dt, d_b, d_c, d_tot
 
 
 def _head_rows(v, g):
@@ -325,28 +379,29 @@ def _flat(v):
     return rows, rows.swapaxes(1, 2)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _scan(x, dt, da, b, c, chunk, interpret):
-    return _scan_fwd(x, dt, da, b, c, chunk, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _scan(x, dt, da, b, c, chunk, r, interpret):
+    return _scan_fwd(x, dt, da, b, c, chunk, r, interpret)[0]
 
 
-def _scan_fwd(x, dt, da, b, c, chunk, interpret):
+def _scan_fwd(x, dt, da, b, c, chunk, r, interpret):
     bsz, t, h, p = x.shape
-    g = b.shape[2]
+    blocks = h // r                            # head blocks, group-major
     y, entering = _forward(
-        _cum_rows(da, chunk, g), _head_rows(dt, g), _flat(x)[1], _flat(b)[0],
-        _flat(c)[1], chunk=chunk, r=h // g, p=p, interpret=interpret)
+        _cum_rows(da, chunk, blocks), _head_rows(dt, blocks), _flat(x)[1],
+        _flat(b)[0], _flat(c)[1], chunk=chunk, r=r, p=p, groups=b.shape[2],
+        interpret=interpret)
     return y.swapaxes(1, 2).reshape(x.shape), (x, dt, da, b, c, entering)
 
 
-def _scan_bwd(chunk, interpret, res, dy):
+def _scan_bwd(chunk, r, interpret, res, dy):
     x, dt, da, b, c, entering = res
     bsz, t, h, p = x.shape
-    g = b.shape[2]
+    blocks = h // r
     d_x, d_cum, d_dt, d_b, d_c, d_tot = _backward(
-        _cum_rows(da, chunk, g), _head_rows(dt, g), _flat(x)[1],
+        _cum_rows(da, chunk, blocks), _head_rows(dt, blocks), _flat(x)[1],
         _flat(dy.astype(b.dtype))[1], *_flat(b), *_flat(c), entering,
-        chunk=chunk, r=h // g, p=p, interpret=interpret)
+        chunk=chunk, r=r, p=p, groups=b.shape[2], interpret=interpret)
     # the state a chunk leaves is the next one's entering state: its
     # dh . h, summed over the head, lands on the chunk's last position;
     # then the cumulative sum's transpose inside each chunk
@@ -364,19 +419,25 @@ _scan.defvjp(_scan_fwd, _scan_bwd)
 
 
 def ssd_scan_kernels(x, dt, a, b, c, chunk, dtype=jnp.float32,
-                     interpret=False):
+                     interpret=False, heads=None):
     """ssd_scan_chunked's recurrence, arguments and precision on the two
     kernels, for shapes hybrid_ops.ssd_scan_ineligible admits. dt * a and
     the padding of T to a multiple of `chunk` (dt = 0: such a step
     neither decays nor feeds the state) stay jax.numpy, so autodiff
     carries them; the kernels and their rule see x, dt, the log-decays,
-    B and C, and multiply x by dt where they read it."""
+    B and C, and multiply x by dt where they read it. `heads`: the heads
+    of a group one grid step owns (default heads_a_step of the group's;
+    tools/scan_sweep.py passes others)."""
     t = x.shape[1]
     pad = (-t) % chunk
     if pad:
         x, dt, b, c = (jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2))
                        for v in (x, dt, b, c))
     dt = dt.astype(_F32)
+    per_group = x.shape[2] // b.shape[2]
+    heads = heads or heads_a_step(per_group, chunk,
+                                  jnp.dtype(dtype).itemsize)
+    assert per_group % heads == 0, (per_group, heads)
     y = _scan(x, dt, dt * a.astype(_F32), b.astype(dtype), c.astype(dtype),
-              chunk, interpret)
+              chunk, heads, interpret)
     return y[:, :t]

@@ -117,11 +117,14 @@ class Optimizer:
         return optimize_ops
 
     def minimize(self, loss, startup_program=None, parameter_list=None,
-                 no_grad_set=None) -> Tuple[List, List]:
+                 no_grad_set=None, checkpoints=None) -> Tuple[List, List]:
         """append_backward + regularization + clip + optimizer ops
-        (reference optimizer.py Optimizer.minimize)."""
+        (reference optimizer.py Optimizer.minimize). `checkpoints`: the
+        forward variables to keep; what lies between two of them is
+        recomputed in the backward (backward.append_backward)."""
         params_grads = append_backward(loss, parameter_list, no_grad_set,
-                                       [error_clip_callback])
+                                       [error_clip_callback],
+                                       checkpoints=checkpoints)
         params_grads = sorted(params_grads, key=lambda pg: pg[0].name)
         params_grads = append_gradient_clip_ops(params_grads)
         params_grads = append_regularization_ops(params_grads,

@@ -749,7 +749,8 @@ def collect_report(trace_dir, steps: Optional[int] = None,
             a = agg.setdefault(key, {
                 "ps": 0.0, "work_flops": 0.0, "work_bytes": 0.0,
                 "instrs": {}, "joined": False,
-                "scope": r["scope"], "role": r["role"]})
+                "scope": r["scope"], "role": r["role"],
+                "recompute": r.get("recompute")})
             a["ps"] += ps
             if r["joined"]:
                 a["joined"] = True
@@ -806,7 +807,8 @@ def collect_report(trace_dir, steps: Optional[int] = None,
                 if min_ps > 0:
                     efficiency = min_ps / ps
         rows.append({"op": name, "at": at, "role": a["role"],
-                     "scope": a["scope"], "ps": ps, "frac": ps / total_ps,
+                     "scope": a["scope"], "recompute": a["recompute"],
+                     "ps": ps, "frac": ps / total_ps,
                      "flops": flops, "bytes": bytes_, "tflops": tflops,
                      "intensity": intensity, "bound": bound,
                      "shape": shape,
@@ -958,6 +960,8 @@ def format_report(report: Dict[str, Any]) -> List[str]:
         floor = ("{:6.1%}".format(row["efficiency"])
                  if row.get("efficiency") is not None else "     -")
         at = "" if row.get("at") is None else f"  @{row['at']}"
+        if row.get("recompute") is not None:   # a replayed forward op
+            at += f"  replay {row['recompute']}"
         lines.append(
             f"[device] {row['op']:31s} {row['ps'] / 1e9:12.4f} "
             f"{row['frac']:8.1%} {_fmt(row['flops'], 1e9)} "

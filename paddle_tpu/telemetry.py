@@ -789,6 +789,12 @@ METRIC_CATALOG = {
     "executor_window_fallback_total": _m(
         "counter", ("program", "reason"),
         "run_steps windows that fell back to per-step execution"),
+    "recompute_segments_total": _m(
+        "counter", ("program",),
+        "segments of forward ops a compiled block replays in its backward"),
+    "recompute_ops_total": _m(
+        "counter", ("program", "type"),
+        "forward ops replayed in the backward, a compile, by op type"),
     "optimizer_steps_total": _m("counter", ("program",),
                                 "runs of optimizer-carrying programs"),
     "optimizer_minimize_total": _m("counter", ("optimizer",),

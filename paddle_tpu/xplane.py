@@ -564,7 +564,9 @@ class Instr(NamedTuple):
     with a float32 product at `highest` precision counted as the 6 bf16
     passes it runs as (the trace's own `flops` stat; the floor's); `at` is the program
     op's position in the block it was lowered from; `kind`, `payload`,
-    `group_size`, `groups`, `axis`, `site` are set for a collective."""
+    `group_size`, `groups`, `axis`, `site` are set for a collective;
+    `recompute` is the segment of a forward op replayed in the backward
+    (backward.append_backward(checkpoints=)), None for every other."""
     name: str
     opcode: str
     heavy: str
@@ -586,6 +588,7 @@ class Instr(NamedTuple):
     groups: Optional[str] = None
     axis: Optional[str] = None
     site: Optional[str] = None
+    recompute: Optional[int] = None
 
 
 _ROLE = re.compile(r"pd_role\.([A-Za-z0-9_]+)")
@@ -597,6 +600,16 @@ _AT = re.compile(r"pd_at\.([0-9]+)")
 # parallel/), wherever it sits under the program op's scope
 _COLL = re.compile(r"(?<![A-Za-z0-9_])pd\.coll\.([A-Za-z0-9_.\-]+)")
 AT_SCOPE = "pd_at."
+# executor._exec_op, around a replayed forward op, inside its role
+RECOMPUTE_SCOPE = "pd_recompute."
+_RECOMPUTE = re.compile(r"pd_recompute\.([0-9]+)")
+
+
+def recompute_of(op_name: str) -> Optional[int]:
+    """The recomputation segment an HLO op_name's program op replays a
+    forward op of (`pd_recompute.<n>`), None for any other op."""
+    found = _RECOMPUTE.search(op_name or "")
+    return int(found.group(1)) if found else None
 
 
 def provenance(op_name: str):
@@ -1369,7 +1382,8 @@ def hlo_instructions(text: str, mesh=None) -> List[Instr]:
             by_name[raw.name] = Instr(
                 raw.name, raw.opcode, heavy, flops, mxu_flops, nbytes,
                 raw.shape, detail, op_name, role, scope, op,
-                at, is_entry, raw.operands, **coll)
+                at, is_entry, raw.operands,
+                recompute=recompute_of(op_name), **coll)
             out.append(by_name[raw.name])
     return out
 
@@ -1481,20 +1495,21 @@ def _peaks(device_kind):
 
 _ROW_FIELDS = ("name", "opcode", "heavy", "flops", "bytes", "shape", "detail",
                "role", "scope", "op", "at", "kind", "payload", "group_size",
-               "axis", "site")
+               "axis", "site", "recompute")
 
 
 def _unjoined_row(name, stats):
     """The row of an event no account names: its name, what the trace's
     own op_name (`tf_op`) says of its provenance, and no cost."""
-    role, scope, op, at = provenance(
-        str(stats.get("tf_op") or stats.get("op_name") or ""))
+    op_name = str(stats.get("tf_op") or stats.get("op_name") or "")
+    role, scope, op, at = provenance(op_name)
     kind = collective_kind(name)
     row = dict.fromkeys(_ROW_FIELDS)
     row.update(name=name, opcode=category(name),
                heavy="collective" if kind else category(name), shape="",
                detail="", role=role, scope=scope, op=op, at=at, kind=kind,
-               payload=0, label=op or category(name), joined=False)
+               payload=0, recompute=recompute_of(op_name),
+               label=op or category(name), joined=False)
     return row
 
 

@@ -81,6 +81,17 @@ def test_lm_phase_tiny():
     assert record["flash_declined"] == {}
 
 
+def test_recompute_phase_tiny():
+    """One group of 16 heads on the scan kernels, interpreted: with and
+    without checkpoints the first loss is the same, and the compile with
+    checkpoints booked its segments."""
+    record = chip_smoke.recompute_phase(
+        seqlen=256, d_model=64, heads=16, head_dim=16, state=128, width=96,
+        vocab=128, steps=2, compiled=False)
+    assert record["first_loss_rel_diff"] < 1e-3
+    assert sum(record["recompute_segments_total"].values()) >= 2
+
+
 def test_multichip_phase_on_virtual_devices():
     """The guide's second rehearsal: the 4-chip phase on four of the
     harness's virtual CPU devices — planner mesh, flash under shard_map,

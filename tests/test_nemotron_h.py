@@ -241,7 +241,8 @@ def test_scan_kernels_give_a_padded_tail_zero_gradients(dtype):
     x, b, c, dy = f(heads, p), f(groups, n), f(groups, n), f(heads, p)
     dt = np.abs(f(heads))
     _, vjp = jax.vjp(
-        lambda *a: pallas_scan._scan(*a, 128, True), jnp.asarray(x, dtype),
+        lambda *a: pallas_scan._scan(*a, 128, heads // groups, True),
+        jnp.asarray(x, dtype),
         jnp.asarray(dt), jnp.asarray(-1.5 * dt), jnp.asarray(b, dtype),
         jnp.asarray(c, dtype))
     for grad in vjp(jnp.asarray(dy)):
@@ -259,7 +260,8 @@ def test_the_scan_gate_is_a_function_of_shapes_and_names_its_reasons():
         (128, 8, 64, 16): "state", (128, 8, 64, 192): "state",
         (128, 8, 8, 128): "heads",        # half a packed bf16 row
         (128, 8, 40, 128): "heads",
-        (128, 160, 64, 128): "heads",     # more rows than the turned tile
+        (128, 160, 64, 128): None,        # 20 head blocks of 8 (PR 49)
+        (256, 64, 64, 128): None,         # one group of 64: 8 head blocks
     }
     for shape, reason in gate.items():
         assert hybrid_ops.ssd_scan_ineligible(*shape) == reason, shape

@@ -340,6 +340,26 @@ def test_scan_kernels_compile(mosaic, one_chip, t, chunk, dtype):
                     ((1, t, g, n), dtype)) == ["ssd_scan_bwd", "ssd_scan_fwd"]
 
 
+@pytest.mark.parametrize("chunk", [256, 128])
+def test_scan_kernels_compile_at_one_group_of_64_heads(mosaic, one_chip,
+                                                       chunk):
+    """The granite cell's scan, [1, 8192, 64 heads x 64] over ONE group of
+    state 128: a step that owned the group's 64 heads would want x, y, dy
+    and dx blocks of [4096, chunk] and a 2 MB state, and Mosaic refuses
+    it for VMEM (PR 49); as 4 head blocks of 16, or 2 of 32 at chunk 128,
+    that read the same B and C
+    both kernels compile at the published chunk of 256 and at the 128 the
+    cell is lowered with, no fallback."""
+    from paddle_tpu.ops import pallas_scan
+    t, h, p, g, n = 8192, 64, 64, 1, 128
+    assert hybrid_ops.ssd_scan_ineligible(chunk, h // g, p, n) is None
+    assert pallas_scan.heads_a_step(h // g, chunk) == {256: 16, 128: 32}[chunk]
+    assert _compile(_scan_fwd_bwd(chunk, BF16), one_chip,
+                    ((1, t, h, p), BF16), ((1, t, h), jnp.float32),
+                    ((h,), jnp.float32), ((1, t, g, n), BF16),
+                    ((1, t, g, n), BF16)) == ["ssd_scan_bwd", "ssd_scan_fwd"]
+
+
 _HYBRID_ME = {}
 
 
@@ -658,6 +678,51 @@ def test_window_step_compiles_with_both_kinds_of_layer(mosaic, one_chip):
                 instr.name, instr.shape)
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 15.75e9
+
+
+GRANITE_CELL = "granite-4.0-h-micro.train-ssm-recompute"
+
+
+def test_granite_step_holds_fewer_temporaries_with_checkpoints(mosaic,
+                                                               one_chip):
+    """The granite cell's step at its own 8192-token sequence and published
+    widths, the depth cut to two Mamba layers and the attention layer (the
+    ten take five minutes here; tools/describe_step.py sized them: 7.08e9
+    B of temporaries with the checkpoints, 7.64e9 without, beside 9.27e9
+    of aliased state): with the residual stream kept at the layers'
+    inputs the two Mamba layers' forward ops run again in the backward,
+    their scan kernels with them (XLA merges the forward the gradient op
+    traces again with the replayed one, not with the first: the barrier
+    stands between), and the compiler's own memory analysis holds
+    measurably fewer temporary bytes than the same step without
+    checkpoints: the replay survived the compiler. No scan falls back."""
+    from paddle_tpu import telemetry, xplane
+    cell = run.load_json("workloads", GRANITE_CELL)
+    config = dict(run.load_json("configs", cell["config"]),
+                  num_hidden_layers=3,
+                  layer_types=["mamba", "mamba", "attention"])
+    before = dict(telemetry.read_series("pallas_fallback_total"))
+    temps, kernels, replayed = {}, {}, {}
+    for recompute in (True, False):
+        compiled = describe_step.compile_step(
+            cell, dict(config, recompute=recompute), one_chip)
+        text = compiled.as_text()
+        temps[recompute] = compiled.memory_analysis().temp_size_in_bytes
+        names = [re.search(r'op_name="[^"]*?(\w+)\)*/pallas_call',
+                           line).group(1)
+                 for line in text.splitlines() if KERNEL in line]
+        kernels[recompute] = {k: names.count(k) for k in set(names)}
+        replayed[recompute] = {i.recompute for i in
+                               xplane.hlo_instructions(text)
+                               if i.recompute is not None}
+    assert dict(telemetry.read_series("pallas_fallback_total")) == before
+    assert kernels[False] == {"ssd_scan_fwd": 2, "ssd_scan_bwd": 2,
+                              "flash_fwd": 1, "flash_dkv": 1}
+    assert kernels[True] == dict(kernels[False], ssd_scan_fwd=4)
+    # (segment 0 replays the embedding's lookup alone: XLA drops it)
+    assert replayed[False] == set() and {1, 2} <= replayed[True] <= {0, 1, 2}
+    # 2.78e9 against 3.57e9 when this was written
+    assert temps[True] < 0.9 * temps[False], temps
 
 
 @pytest.mark.parametrize("shape,reason", [

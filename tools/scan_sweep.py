@@ -3,6 +3,7 @@ under a jax.checkpoint, as the op runs it where a shape does not tile)
 beside the Pallas kernels of ops/pallas_scan.py.
 
     chiprun -- python3 tools/scan_sweep.py [B T H P G N chunk] [--dtype bfloat16]
+                                           [--chunks 128,256] [--heads 8,16]
 
 Times the forward and forward + gradient (jax.vjp on a random cotangent)
 of the scan's core, (x, dt, a, B, C) -> y, at one shape, each kernel
@@ -15,7 +16,13 @@ real transposes that the hybrid cell's step does not pay (XLA lays its
 [1, T, C] activations out with T minor). The table in
 ops/pallas_scan.py's docstring is written from it (PERF.md section 6,
 PR 40: [1, 4096, 64, 64], G 8, N 128, chunk 128, the hybrid cell's
-shape). One JSON line per reading goes to chiprun_out/scan_sweep.jsonl.
+shape). `--chunks` and `--heads` time the kernels at other chunks than
+the shape's and at other heads a grid step than
+pallas_scan.heads_a_step gives (one group of 64 heads, PR 49:
+`1 8192 64 64 1 128 256 --chunks 128,256 --heads 8,16`; Mosaic refuses
+32 heads at chunk 256 for VMEM and the run ends there); the result
+does not depend on either. One JSON line per reading goes to
+chiprun_out/scan_sweep.jsonl.
 """
 
 import argparse
@@ -65,6 +72,8 @@ def main():
     ap.add_argument("shape", nargs="*", type=int,
                     default=[1, 4096, 64, 64, 8, 128, 128])
     ap.add_argument("--dtype", default="bfloat16")
+    ap.add_argument("--chunks", default="")
+    ap.add_argument("--heads", default="")
     ns = ap.parse_args()
     bsz, t, h, p, g, n, chunk = ns.shape
     dtype = jnp.dtype(ns.dtype)
@@ -89,37 +98,49 @@ def main():
     base = dict(shape=ns.shape, dtype=str(dtype),
                 device=jax.devices()[0].device_kind)
 
-    chunked = jax.checkpoint(functools.partial(
-        ssd_scan_chunked, chunk=chunk, dtype=dtype))
-    kernels = functools.partial(pallas_scan.ssd_scan_kernels, chunk=chunk,
-                                dtype=dtype, interpret=_interpret())
+    def ints(text, default):
+        return [int(v) for v in text.split(",")] if text else [default]
+
     exact = jax.jit(fwd_bwd(mixer_view(functools.partial(
         ssd_scan_chunked, chunk=chunk, dtype=jnp.float32), h, g)))(
             *(v.astype(jnp.float32) for v in args), dy)
-    for path, scan in (("chunked", mixer_view(chunked, h, g)),
-                       ("kernels", mixer_view(kernels, h, g))):
+
+    def read(path, scan, **form):
         got = jax.jit(fwd_bwd(scan))(*args, dy)
-        report(log, **base, path=path, fwd_ms=bench(scan, *args),
+        report(log, **base, **form, path=path, fwd_ms=bench(scan, *args),
                fwd_bwd_ms=bench(fwd_bwd(scan), *args, dy),
                rel_err_vs_float32=dict(zip(
                    ("y", "dx", "ddt", "da", "dB", "dC"),
                    rel_err(got, exact))))
 
-    # each kernel alone, on the operands the rule hands it (time last)
-    cum = pallas_scan._cum_rows(dt * a, chunk, g)
-    rows = pallas_scan._head_rows(dt, g)
+    read("chunked", mixer_view(jax.checkpoint(functools.partial(
+        ssd_scan_chunked, chunk=chunk, dtype=dtype)), h, g), chunk=chunk)
     turned = [jnp.asarray(v.swapaxes(1, 2))
               for v in (x, dy.astype(dtype), b, c)]
-    static = dict(chunk=chunk, r=h // g, p=p, interpret=_interpret())
-    forward = functools.partial(pallas_scan._forward, **static)
-    operands = (cum, rows, turned[0], b, turned[3])
-    _, entering = forward(*operands)
-    report(log, **base, path="kernels", kernel="ssd_scan_fwd",
-           ms=bench(forward, *operands))
-    report(log, **base, path="kernels", kernel="ssd_scan_bwd",
-           ms=bench(functools.partial(pallas_scan._backward, **static),
-                    cum, rows, turned[0], turned[1], b, turned[2], c,
-                    turned[3], entering))
+    for chunk_ in ints(ns.chunks, chunk):
+        for heads in ints(ns.heads,
+                          pallas_scan.heads_a_step(h // g, chunk_)):
+            form = dict(chunk=chunk_, heads_a_step=heads)
+            read("kernels", mixer_view(functools.partial(
+                pallas_scan.ssd_scan_kernels, chunk=chunk_, dtype=dtype,
+                interpret=_interpret(), heads=heads), h, g), **form)
+            # each kernel alone, on the operands the rule hands it (time
+            # last)
+            cum = pallas_scan._cum_rows(dt * a, chunk_, h // heads)
+            rows = pallas_scan._head_rows(dt, h // heads)
+            static = dict(chunk=chunk_, r=heads, p=p, groups=g,
+                          interpret=_interpret())
+            forward = functools.partial(pallas_scan._forward, **static)
+            operands = (cum, rows, turned[0], b, turned[3])
+            _, entering = forward(*operands)
+            report(log, **base, **form, path="kernels",
+                   kernel="ssd_scan_fwd", ms=bench(forward, *operands))
+            report(log, **base, **form, path="kernels",
+                   kernel="ssd_scan_bwd",
+                   ms=bench(functools.partial(pallas_scan._backward,
+                                              **static),
+                            cum, rows, turned[0], turned[1], b, turned[2],
+                            c, turned[3], entering))
 
 
 if __name__ == "__main__":
