@@ -16,7 +16,22 @@ extra syncs.
 Three layers:
 
 1. **On-device** — per-series {weight l2/rms/max-abs, grad l2/rms/zero-frac,
-   update ratio sqrt(sum dW^2)/(||W||+eps), optimizer-moment rms}. Series are
+   update ratio sqrt(sum dW^2)/(||W||+eps), optimizer-moment rms}. dW is
+   **the step the optimizer's rule took, as a function of what the update
+   left behind** (`optimizer_ops.dense_step`: sgd `lr * g` on the rule's
+   own `Grad`, momentum `lr * v_new`, adam `lr_t * m1_new / (sqrt(m2_new)
+   + eps)` with the beta powers the rule read), not `w_new - w_old`: the
+   state is donated and every rule writes into its parameter's buffer, so
+   an old value with a reader behind the update is copied out ahead of it
+   on EVERY step (a cond's operands exist whichever branch runs), 8 bytes
+   of traffic a float32 parameter. The reduction reads no old value of a
+   parameter's size. `_discover_rules` finds each parameter's rule when
+   the plan is built; a parameter without one (adagrad, rmsprop, ftrl, the
+   proximal rules, two writers), one whose gradient arrives as SelectedRows
+   and one kept narrower than float32 (a step can round away whole there,
+   and frozen-param has to see what the rounding left) keep the difference
+   and their copy, and `dynamics_update_norm_total{program, source="step"
+   | "difference"}` counts both kinds, parameters a compile. Series are
    per-parameter on small programs and collapse to planner roles
    (parallel.planner.classify_params: embedding/attn_qkv/ffn_up/...) past
    MAX_PARAM_SERIES, bounding cardinality on billion-param programs. Fields
@@ -182,15 +197,31 @@ def cache_token(program) -> Optional[Tuple[bool, int]]:
 # Trace-time plan
 # ---------------------------------------------------------------------------
 
-class _ParamEntry:
-    __slots__ = ("name", "grad", "sparse_grad", "moments", "role")
+class _StepRule:
+    """The dense rule that alone writes a parameter, and the names of what
+    its step (`optimizer_ops.dense_step`) is made of: the rule's own `Grad`
+    input (None where the step reads none), the new state tensors, and
+    the scalars as (name, whether the state of before the step tells the
+    value the rule read: ops behind the rule wrote it, none ahead)."""
+    __slots__ = ("op", "grad", "new", "scalars")
 
-    def __init__(self, name, grad, sparse_grad, moments, role):
+    def __init__(self, op, grad, new, scalars):
+        self.op = op
+        self.grad = grad
+        self.new = new
+        self.scalars = scalars
+
+
+class _ParamEntry:
+    __slots__ = ("name", "grad", "sparse_grad", "moments", "role", "rule")
+
+    def __init__(self, name, grad, sparse_grad, moments, role, rule=None):
         self.name = name
         self.grad = grad
         self.sparse_grad = sparse_grad
         self.moments = moments
         self.role = role
+        self.rule = rule
 
 
 class _Group:
@@ -251,6 +282,55 @@ def _discover_moments(block, param_shapes) -> Dict[str, List[str]]:
     return moments
 
 
+def _discover_rules(block, param_shapes) -> Dict[str, _StepRule]:
+    """Per parameter, the optimizer op whose step stands for its update:
+    a rule with a step sibling (`optimizer_ops.step_reads`) that is the
+    one writer of the parameter and of its new moments, whose gradient
+    nothing rewrites behind it and whose scalars are still told when the
+    block ends: by the trace where nothing wrote them behind the rule,
+    else by the state of before the step where nothing wrote them ahead
+    of it (Adam's beta powers, advanced behind every adam op). Any other
+    parameter keeps the difference of its values."""
+    from .ops import optimizer_ops
+    writers: Dict[str, List[int]] = {}
+    for i, op in enumerate(block.ops):
+        for n in op.output_arg_names:
+            writers.setdefault(n, []).append(i)
+
+    def first(names):
+        return names[0] if names else None
+
+    def told(name, old):
+        """Whether the end of the block still tells the scalar the rule at
+        `i` read: in the trace, or (`old`) in the state of before."""
+        if name is None:
+            return False
+        return not old or (
+            block.desc.has_var(name) and block.desc.var(name).persistable
+            and all(w > i for w in writers[name]))
+
+    rules: Dict[str, _StepRule] = {}
+    for i, op in enumerate(block.ops):
+        pname = first(op.desc.inputs.get("Param"))
+        reads = optimizer_ops.step_reads(op) if pname in param_shapes \
+            else None
+        if reads is None or writers.get(pname) != [i]:
+            continue
+        new_slots, reads_grad, scalar_slots = reads
+        new = tuple(first(op.desc.output(s)) for s in new_slots)
+        grad = first(op.desc.input("Grad")) if reads_grad else None
+        scalars = tuple((n, any(w >= i for w in writers.get(n, ())))
+                        for n in (first(op.desc.input(s))
+                                  for s in scalar_slots))
+        if reads_grad and (grad is None
+                           or any(w >= i for w in writers.get(grad, ()))):
+            continue
+        if all(writers.get(n) == [i] for n in new) \
+                and all(told(n, old) for n, old in scalars):
+            rules[pname] = _StepRule(op, grad, new, scalars)
+    return rules
+
+
 def _build_plan(program) -> Optional[DynamicsPlan]:
     block = program.global_block()
     params = [p for p in block.all_parameters()
@@ -280,10 +360,12 @@ def _build_plan(program) -> Optional[DynamicsPlan]:
 
     param_shapes = {name: shape for name, _, _, shape in entries}
     moments = _discover_moments(block, param_shapes)
+    rules = _discover_rules(block, param_shapes)
     roles = _param_roles(program, list(param_shapes))
 
     pents = [_ParamEntry(name, grad, sparse,
-                         tuple(moments.get(name, ())), roles[name])
+                         tuple(moments.get(name, ())), roles[name],
+                         rules.get(name))
              for name, grad, sparse, _ in entries]
 
     if len(pents) <= MAX_PARAM_SERIES:
@@ -296,7 +378,15 @@ def _build_plan(program) -> Optional[DynamicsPlan]:
                   for role, es in sorted(by_role.items())]
     groups.sort(key=lambda grp: grp.name)
 
-    grab = sorted({e.grad for e in pents if e.grad is not None})
+    # what the reduction reads of the trace beside the state: the raw
+    # gradients, and of a rule's step its own gradient and the scalars
+    # written ahead of it
+    grab = {e.grad for e in pents if e.grad is not None}
+    for r in rules.values():
+        grab.update(n for n, old in r.scalars if not old)
+        if r.grad is not None:
+            grab.add(r.grad)
+    grab = sorted(grab)
     return DynamicsPlan(groups, tuple(grab), period(), len(pents))
 
 
@@ -321,8 +411,50 @@ def plan(program) -> Optional[DynamicsPlan]:
 # On-device fused reduction (traced inside the executor's step fn)
 # ---------------------------------------------------------------------------
 
-def _group_row(grp: _Group, old_state, new_state, grabs):
+def _resolve_step(ent: _ParamEntry, old_state, new_state, grabs):
+    """(gradient, new tensors, scalars) for `optimizer_ops.dense_step` where
+    the rule's step gives this parameter's update, decided where the step
+    is traced; None where the difference of its values has to. It has to
+    for a parameter no dense rule with a step alone updates (the plan's
+    finding), for one whose gradient arrived as SelectedRows (the rule
+    took its scatter-apply, and every statistic reduces over the touched
+    rows), and for one kept narrower than float32: there a step can round
+    away whole, and frozen-param has to see what the rounding left."""
     import jax.numpy as jnp
+    rule = ent.rule
+    if rule is None or jnp.result_type(new_state[ent.name]).itemsize < 4:
+        return None
+    g = grabs.get(rule.grad)
+    if any(getattr(v, "rows", None) is not None
+           for v in (g, grabs.get(ent.grad))):
+        return None
+    new = [new_state.get(n) for n in rule.new]
+    scalars = [(old_state if old else grabs).get(n)
+               for n, old in rule.scalars]
+    if any(v is None for v in new + scalars) \
+            or (rule.grad is not None and g is None):
+        return None
+    return g, new, scalars
+
+
+def _count_sources(prog_label: str, steps: Dict[str, Any]):
+    family = telemetry.counter(
+        "dynamics_update_norm_total",
+        "parameters of a traced step's dynamics table, a compile, by "
+        "where the update ratio's numerator comes from: the optimizer "
+        "rule's own step, or the difference of the parameter's values "
+        "(which keeps a copy of the old one behind the update)",
+        labels=("program", "source"))
+    n_step = sum(1 for v in steps.values() if v is not None)
+    # both series exist after a compile: a reader tells 0 from not booked
+    family.labels(program=prog_label, source="step").inc(n_step)
+    family.labels(program=prog_label, source="difference").inc(
+        len(steps) - n_step)
+
+
+def _group_row(grp: _Group, steps, old_state, new_state, grabs):
+    import jax.numpy as jnp
+    from .ops import optimizer_ops
     f32 = jnp.float32
     zero = jnp.zeros((), f32)
     w_sumsq, w_max, w_n = zero, zero, 0.0
@@ -332,10 +464,9 @@ def _group_row(grp: _Group, old_state, new_state, grabs):
     has_grad = has_update = has_moment = False
 
     for ent in grp.params:
-        w_old = old_state.get(ent.name)
-        if w_old is None:
+        if ent.name not in steps:
             continue
-        w_new = new_state.get(ent.name, w_old)
+        w_new = new_state[ent.name]
         gval = grabs.get(ent.grad) if ent.grad is not None else None
         # sparse-grad params: EVERY statistic (weight, update, moment)
         # reduces over the rows this step touched — a full-table pass
@@ -347,16 +478,30 @@ def _group_row(grp: _Group, old_state, new_state, grabs):
         rows = getattr(gval, "rows", None)
         if rows is not None:
             wf = jnp.take(jnp.asarray(w_new), rows, axis=0).astype(f32)
-            of = jnp.take(jnp.asarray(w_old), rows, axis=0).astype(f32)
         else:
             wf = jnp.asarray(w_new).astype(f32)
-            of = jnp.asarray(w_old).astype(f32)
         w_sumsq = w_sumsq + jnp.sum(jnp.square(wf))
         w_max = jnp.maximum(w_max, jnp.max(jnp.abs(wf)))
         w_n += float(wf.size)
-        if ent.name in new_state:
-            has_update = True
-            d_sumsq = d_sumsq + jnp.sum(jnp.square(wf - of))
+        # the update's norm: of the rule's own step, as a function of what
+        # the update left behind, wherever _resolve_step found one; nothing
+        # of such a parameter's size is read from before the update
+        has_update = True
+        reads = steps[ent.name]
+        if reads is not None:
+            g, new, scalars = reads
+            if g is not None:
+                # the rule's upcast (optimizer_ops._param_grad)
+                g = jnp.asarray(g).astype(jnp.result_type(w_new))
+            df = optimizer_ops.dense_step(
+                ent.rule.op, g, [jnp.asarray(v) for v in new],
+                scalars).astype(f32)
+        else:
+            w_old = jnp.asarray(old_state[ent.name])
+            if rows is not None:
+                w_old = jnp.take(w_old, rows, axis=0)
+            df = wf - w_old.astype(f32)
+        d_sumsq = d_sumsq + jnp.sum(jnp.square(df))
         if gval is not None:
             # SelectedRows grads reduce over the touched rows only — no
             # densify (the sparse_densify_fallback counters stay at 0)
@@ -366,7 +511,7 @@ def _group_row(grp: _Group, old_state, new_state, grabs):
             g_nonzero = g_nonzero + jnp.sum((gf != 0).astype(f32))
             g_n += float(gf.size)
         for mname in ent.moments:
-            mval = new_state.get(mname, old_state.get(mname))
+            mval = new_state.get(mname)
             if mval is None:
                 continue
             mval = jnp.asarray(mval)
@@ -393,18 +538,32 @@ def _group_row(grp: _Group, old_state, new_state, grabs):
 
 
 def sampled_stats(dyn_plan: Optional[DynamicsPlan], old_state, new_state,
-                  grabs, rng_counter):
+                  grabs, rng_counter, prog_label: str = ""):
     """[len(groups), len(STAT_FIELDS)] float32, or None when no plan. Off
     period-boundary steps return a NaN filler (never read host-side — the
-    executor knows the counter — but it must be popped before check_nan)."""
+    executor knows the counter — but it must be popped before check_nan).
+
+    `old_state` is donated and every rule writes its outputs into its
+    inputs' buffers, so whatever of it the sampled branch reads, XLA
+    copies out ahead of the update on every step, sampled or not (a
+    cond's operands exist whichever branch runs). So the branch is not
+    given it: it closes over what `_resolve_step` resolved, scalars of
+    `old_state` among them, and over `old`, the old value of each
+    parameter that has to fall to the difference."""
     if dyn_plan is None:
         return None
     import jax
     import jax.numpy as jnp
     shape = (len(dyn_plan.groups), len(STAT_FIELDS))
 
+    steps = {ent.name: _resolve_step(ent, old_state, new_state, grabs)
+             for grp in dyn_plan.groups for ent in grp.params
+             if ent.name in old_state and ent.name in new_state}
+    _count_sources(prog_label, steps)
+    old = {n: old_state[n] for n, reads in steps.items() if reads is None}
+
     def _take(_):
-        return jnp.stack([_group_row(grp, old_state, new_state, grabs)
+        return jnp.stack([_group_row(grp, steps, old, new_state, grabs)
                           for grp in dyn_plan.groups])
 
     def _skip(_):

@@ -2045,7 +2045,8 @@ class Executor:
             # scalars; pinning them replicated below would be a no-op
             # anyway, but the weights/grads must be the trace's own)
             dyn_stats = dynamics_mod.sampled_stats(
-                dyn_plan, state_vals, new_state, grabs, rng_counter)
+                dyn_plan, state_vals, new_state, grabs, rng_counter,
+                telemetry.program_label(program))
             if mesh is not None:
                 # pin state outputs to the same shardings the next run's
                 # in_shardings expect (annotated params keep their spec,

@@ -67,25 +67,70 @@ def _pname(op_):
 # promote a bf16 parameter's update to f32), so it aliases the donated
 # state and the next step sees the dtypes this one was compiled for.
 
+# Beside each rule stands the step it takes (`p_out = p - step`), written
+# over what the update leaves behind: the gradient the rule was given, the
+# new moments, the scalars the rule read. The rule subtracts it, and the
+# dynamics table (dynamics.py, layer 1) takes the update's norm from it,
+# so nothing reads a parameter's old value behind its update.
+
+def sgd_step(g, lr):
+    return lr * g
+
+
 def sgd_dense(p, g, lr):
-    return (p - lr * g).astype(p.dtype)
+    return (p - sgd_step(g, lr)).astype(p.dtype)
+
+
+def momentum_step(g, v_new, lr, mu, use_nesterov):
+    if use_nesterov:
+        return lr * (g + mu * v_new)
+    return lr * v_new
 
 
 def momentum_dense(p, g, v, lr, mu, use_nesterov):
     v_out = mu * v + g
-    if use_nesterov:
-        p_out = p - lr * (g + mu * v_out)
-    else:
-        p_out = p - lr * v_out
+    p_out = p - momentum_step(g, v_out, lr, mu, use_nesterov)
     return p_out.astype(p.dtype), v_out.astype(v.dtype)
+
+
+def adam_step(m1_new, m2_new, lr, eps, b1p, b2p):
+    lr_t = lr * jnp.sqrt(1 - b2p) / (1 - b1p)
+    return lr_t * m1_new / (jnp.sqrt(m2_new) + eps)
 
 
 def adam_dense(p, g, m1, m2, lr, b1, b2, eps, b1p, b2p):
     m1o = b1 * m1 + (1 - b1) * g
     m2o = b2 * m2 + (1 - b2) * g * g
-    lr_t = lr * jnp.sqrt(1 - b2p) / (1 - b1p)
-    po = p - lr_t * m1o / (jnp.sqrt(m2o) + eps)
+    po = p - adam_step(m1o, m2o, lr, eps, b1p, b2p)
     return po.astype(p.dtype), m1o.astype(m1.dtype), m2o.astype(m2.dtype)
+
+
+def step_reads(op_):
+    """What `dense_step` reads for the rule `op_`: (output slots whose new
+    values it takes, whether it takes the rule's `Grad`, the scalar input
+    slots in the order `dense_step` wants them); None for a rule with no
+    step of its own above."""
+    if op_.type == "sgd":
+        return (), True, ("LearningRate",)
+    if op_.type == "momentum":
+        return (("VelocityOut",), bool(op_.attr("use_nesterov", False)),
+                ("LearningRate",))
+    if op_.type == "adam":
+        return (("Moment1Out", "Moment2Out"), False,
+                ("LearningRate", "Beta1Pow", "Beta2Pow"))
+    return None
+
+
+def dense_step(op_, g, new, scalars):
+    """The step the dense rule `op_` took, from the values `step_reads`
+    names: `g` (None where not read), `new` and `scalars` in its order."""
+    lr, *pows = (jnp.asarray(s).reshape(()) for s in scalars)
+    if op_.type == "sgd":
+        return sgd_step(g, lr)
+    if op_.type == "momentum":
+        return momentum_step(g, new[0], lr, op_.attr("mu"),
+                             op_.attr("use_nesterov", False))
+    return adam_step(new[0], new[1], lr, op_.attr("epsilon", 1e-8), *pows)
 
 
 @op("sgd", grad=NO_GRAD, infer_shape=_param_out_infer(("Param", "ParamOut")))

@@ -1041,4 +1041,9 @@ METRIC_CATALOG = {
         "gauge", ("program",), "series with any non-ok dynamics verdict"),
     "dynamics_samples_total": _m(
         "counter", ("program",), "dynamics samples recorded"),
+    "dynamics_update_norm_total": _m(
+        "counter", ("program", "source"),
+        "parameters of a traced step's dynamics table, a compile, by the "
+        "update ratio's numerator: the rule's own step, or the difference "
+        "of the parameter's values (an old copy kept behind the update)"),
 }

@@ -36,7 +36,8 @@ def _fresh_dynamics_state():
     dynamics.reset()
 
 
-def _build_program(seed=7):
+def _build_program(seed=7, minimize=None):
+    """`minimize(loss, startup)` appends the optimizer (Momentum if None)."""
     main, startup = fluid.Program(), fluid.Program()
     main.random_seed = seed
     startup.random_seed = seed
@@ -48,9 +49,12 @@ def _build_program(seed=7):
             pred = fluid.layers.fc(input=h, size=1)
             loss = fluid.layers.mean(
                 fluid.layers.square_error_cost(input=pred, label=y))
-            fluid.optimizer.Momentum(
-                learning_rate=0.01, momentum=0.9).minimize(
-                    loss, startup_program=startup)
+            if minimize is None:
+                fluid.optimizer.Momentum(
+                    learning_rate=0.01, momentum=0.9).minimize(
+                        loss, startup_program=startup)
+            else:
+                minimize(loss, startup)
     return main, startup, loss
 
 
@@ -131,6 +135,155 @@ def test_run_steps_window_samples_period_boundaries():
     assert len(progs) == 1
     for series in next(iter(progs.values()))["series"].values():
         assert series["samples"] == 2
+
+
+# -- where the update ratio's numerator comes from ----------------------------
+
+_RULES = {
+    "sgd": lambda lr: fluid.optimizer.SGD(learning_rate=lr),
+    "momentum": lambda lr: fluid.optimizer.Momentum(
+        learning_rate=lr, momentum=0.9),
+    "nesterov": lambda lr: fluid.optimizer.Momentum(
+        learning_rate=lr, momentum=0.9, use_nesterov=True),
+    "adam": lambda lr: fluid.optimizer.Adam(learning_rate=lr),
+    # the rule's Grad is not the raw gradient, and its LearningRate is
+    # written by ops of the block ahead of it
+    "sgd_clip_decay_schedule": lambda lr: fluid.optimizer.SGD(
+        learning_rate=fluid.layers.exponential_decay(lr, 2, 0.5),
+        regularization=fluid.regularizer.L2Decay(0.1)),
+    # no step sibling: the difference of the parameter's values stays
+    "adagrad": lambda lr: fluid.optimizer.Adagrad(learning_rate=lr),
+}
+
+
+def _update_norm_sources():
+    out = {"step": 0, "difference": 0}
+    for key, v in telemetry.read_series("dynamics_update_norm_total").items():
+        out[dict(kv.split("=") for kv in key.split(","))["source"]] += int(v)
+    return out
+
+
+def _observed_steps(rule, window, steps=3):
+    """`steps` steps under `rule` sampling every one: the weights before
+    the first and after each ([steps + 1] dicts), each step's table
+    ([steps] of {series: {field: value}}) and the update-norm counter."""
+    telemetry.reset()
+    dynamics.reset()
+    def minimize(loss, startup):
+        if rule == "sgd_clip_decay_schedule":
+            fluid.clip.set_gradient_clip(
+                fluid.clip.GradientClipByGlobalNorm(clip_norm=0.05))
+        _RULES[rule](0.05).minimize(loss, startup_program=startup)
+
+    main, startup, loss = _build_program(seed=11, minimize=minimize)
+    names = _param_names(main)
+    feeds = _batches(steps, seed=3)
+    scope = executor_mod.Scope()
+
+    def weights():
+        return {n: np.array(scope.find_var(n), np.float64) for n in names}
+
+    with dynamics.override(True, 1), executor_mod.scope_guard(scope):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        ws = [weights()]
+        if window:
+            # the window's steps are bitwise the per-step path's
+            # (tests/test_run_steps.py): the weights between its steps
+            # come from a second scope run step by step
+            beside = executor_mod.Scope()
+            for n in scope.local_var_names():
+                beside.set_var(n, np.array(scope.find_var(n)))
+            exe.run_steps(main, feed_window=feeds, fetch_list=[loss.name])
+            with dynamics.override(False):
+                for feed in feeds:
+                    exe.run(main, feed=feed, fetch_list=[loss.name],
+                            scope=beside)
+                    ws.append({n: np.array(beside.find_var(n), np.float64)
+                               for n in names})
+            for n in names:
+                assert np.array_equal(ws[-1][n], weights()[n])
+        else:
+            for feed in feeds:
+                exe.run(main, feed=feed, fetch_list=[loss.name])
+                ws.append(weights())
+    (prog,) = dynamics.payload()["programs"].values()
+    tables = [{name: s["recent"][i] for name, s in prog["series"].items()}
+              for i in range(steps)]
+    return ws, tables, _update_norm_sources()
+
+
+@pytest.mark.parametrize("window", [False, True], ids=["run", "run_steps"])
+@pytest.mark.parametrize("fused", [False, True], ids=["single", "bucket"])
+@pytest.mark.parametrize("rule", sorted(_RULES))
+def test_update_ratio_from_the_rules_own_step(rule, fused, window,
+                                              monkeypatch):
+    """The sampled update ratio is ||w1 - w0|| / ||w1|| of the fetched
+    weights whichever source its numerator has; a rule with a step sibling
+    (single op or fused bucket, per step or in a window) reads no old
+    value and books `source="step"`, any other keeps the difference; the
+    other seven fields are bitwise the difference-based table's."""
+    from paddle_tpu.ops import fusion
+    monkeypatch.setattr(fusion, "FUSION_OPT", fused)
+    ws, tables, sources = _observed_steps(rule, window)
+    compiles = 1  # run, or run_steps' window: one trace of the step each
+    want = "difference" if rule == "adagrad" else "step"
+    other = "step" if want == "difference" else "difference"
+    assert sources == {want: 4 * compiles, other: 0}
+    for i, table in enumerate(tables):
+        assert sorted(table) == sorted(ws[0])
+        for name, vals in table.items():
+            w0, w1 = ws[i][name], ws[i + 1][name]
+            ratio = np.linalg.norm(w1 - w0) / np.linalg.norm(w1)
+            assert ratio > 0
+            assert vals["update_ratio"] == pytest.approx(ratio, rel=1e-3)
+
+    # the table as the difference of values gave it: no rule is found
+    monkeypatch.setattr(dynamics, "_discover_rules", lambda *a: {})
+    _, by_difference, sources = _observed_steps(rule, window)
+    assert sources == {"step": 0, "difference": 4 * compiles}
+    for table, old in zip(tables, by_difference):
+        for name, vals in table.items():
+            for field in dynamics.STAT_FIELDS:
+                if field != "update_ratio":
+                    assert vals[field] == old[name][field], (name, field)
+            assert vals["update_ratio"] == pytest.approx(
+                old[name]["update_ratio"], rel=1e-3)
+
+
+def test_parameter_narrower_than_float32_keeps_the_difference():
+    """In bfloat16 a step can round away whole: the table has to report
+    what the rounding left (frozen-param reads it), so such a parameter
+    keeps `w_new - w_old` though its rule has a step, and is counted."""
+    import jax.numpy as jnp
+    main, _, _ = _build_program()
+    with dynamics.override(True, 1):
+        plan = dynamics.plan(main)
+    rng = np.random.RandomState(5)
+    old, new, grabs = {}, {}, {}
+    for grp in plan.groups:
+        (ent,) = grp.params
+        assert ent.rule is not None and ent.rule.op.type == "momentum"
+        shape = main.global_block().var(ent.name).shape
+        w = jnp.asarray(rng.randn(*shape), jnp.bfloat16)
+        # a step of 1e-4 of the weight: under half a bfloat16 ulp
+        v = (w.astype(jnp.float32) * 1e-2).astype(jnp.bfloat16)
+        old[ent.name], new[ent.name] = w, (w - 0.01 * v).astype(jnp.bfloat16)
+        new[ent.rule.new[0]] = v
+        grabs[ent.grad] = jnp.ones(shape, jnp.bfloat16)
+        for name, _ in ent.rule.scalars:
+            grabs[name] = old[name] = jnp.full((1,), 0.01, jnp.float32)
+    table = np.asarray(dynamics.sampled_stats(plan, old, new, grabs, 0, "pX"))
+    assert _update_norm_sources() == {"step": 0, "difference": 4}
+    ratio = table[:, dynamics.STAT_FIELDS.index("update_ratio")]
+    assert np.all(ratio == 0.0), ratio
+    # the same values in float32 take the rule's step, which is not zero
+    as_f32 = lambda d: {n: v.astype(jnp.float32) for n, v in d.items()}
+    table = np.asarray(dynamics.sampled_stats(
+        plan, as_f32(old), as_f32(new), as_f32(grabs), 0, "pX"))
+    assert _update_norm_sources() == {"step": 4, "difference": 4}
+    ratio = table[:, dynamics.STAT_FIELDS.index("update_ratio")]
+    assert np.all((ratio > 5e-5) & (ratio < 2e-4)), ratio
 
 
 # -- verdict layer on synthetic series --------------------------------------

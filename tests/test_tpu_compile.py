@@ -490,6 +490,36 @@ def test_gpt2_step_evaluates_gelu_outside_every_products_operand(
     assert compiled.memory_analysis().temp_size_in_bytes < 3.73e9 + 0.25e9
 
 
+def test_gpt2_step_keeps_no_parameter_of_before_its_update(mosaic, one_chip):
+    """The same step, the observatory on as every cell runs it: the
+    sampled table takes an update's norm from the rule's own step
+    (`dynamics._resolve_step`), so nothing reads a parameter's old value
+    behind the update that overwrites its donated buffer, and the entry
+    computation holds no `copy` without an `op_name` in a parameter's
+    shape (the parent copied every float32 master ahead of its update,
+    sampled step or not: 308.8 MB each for the table and the head, PERF.md
+    section 6, PR 50); its temporaries are within 1 % of the step compiled
+    with the observatory off."""
+    from paddle_tpu import dynamics
+
+    cell, config, compiled = _gpt2_one_layer_step(one_chip)
+    main, _, _ = run.load_module("families", config["family"]).build(config)
+    shapes = {tuple(sorted(p.shape))
+              for p in main.global_block().all_parameters()}
+    kept = [i.name + " " + i.shape
+            for i in xplane.hlo_instructions(compiled.as_text())
+            if i.entry and i.opcode == "copy" and not i.op_name
+            for dims in [re.match(r"f32\[([\d,]+)\]", i.shape)]
+            if dims and tuple(sorted(map(int, dims.group(1).split(","))))
+            in shapes]
+    assert not kept, kept
+    with dynamics.override(False):
+        bare = describe_step.compile_step(cell, config, one_chip)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert abs(temp - bare.memory_analysis().temp_size_in_bytes) \
+        < 0.01 * temp
+
+
 def test_planned_gpt2_large_layer_reduces_qkv_input_gradient_once(
         mosaic, topo):
     """One layer of `gpt2-large.train-fsdp2-tp2`, planned fsdp=2 x tp=2
