@@ -9,9 +9,14 @@ on-device reduction appended to the traced step**: `plan()` resolves each
 trainable parameter's grad var and optimizer moments at trace time,
 `sampled_stats()` emits a single [groups, fields] float32 array inside the
 jit (gated by `lax.cond` on the step counter so off-period steps pay one
-predicate, not the reduction), and the executor ships it back in the normal
-fetch round-trip — the same transfer that already carries fetches, so no
-extra syncs.
+predicate, not the reduction), and the host reads it **from a step already
+finished**: the executor hands a sampled step's row to `on_step` still in
+flight, it waits in the observatory's one pending queue, and the first
+later step (or read) that finds it ready records it, in step order. No
+step waits on the device for its own sample, so a pipelined loop keeps
+its steps in flight; a verdict fires at most as many dispatches late as
+the loop keeps in flight. `drain(wait=True)` (the read side below,
+`Executor.close()`) reads what is pending at once, and books the wait.
 
 Three layers:
 
@@ -622,6 +627,11 @@ class _Observatory:
         self.lock = threading.RLock()
         self.programs: Dict[str, Dict[str, _Series]] = {}
         self.activations: Dict[str, Dict[str, Dict[str, Any]]] = {}
+        # sampled rows dispatched and not yet on the host, oldest first:
+        # (program label, plan, stats, [(row of a window's stack or None,
+        # the counter that step ran with)])
+        self.pending: collections.deque = collections.deque()
+        self._turn = threading.Lock()   # held by the one thread draining
         self.samples = 0
         self._log_fh = None
         self._log_path: Optional[str] = None
@@ -686,8 +696,46 @@ class _Observatory:
 
     # -- sample intake ------------------------------------------------------
 
+    def drain(self, wait: bool = False):
+        """Record the pending samples whose rows are on the host by now,
+        oldest first, and stop at the first still in flight: unless
+        `wait`, which blocks on each in turn and books the wait under
+        site `dynamics`. One thread records at a time, which keeps the
+        step order; the turn is no lock of the table's, so a record's
+        file write and a reader's wait on the device are held under
+        nothing the training thread needs: its own drain never waits for
+        the turn, the thread that has it takes those rows too."""
+        if not self._turn.acquire(blocking=wait):
+            return
+        try:
+            while self.pending:
+                head = self.pending[0]
+                how = "ready"
+                if not telemetry.is_ready(head[2]):
+                    if not wait:
+                        return
+                    try:
+                        telemetry.host_wait(head[2], head[0], "dynamics")
+                    except Exception:
+                        pass    # the step failed on the device: dropped
+                    how = "forced"
+                self.pending.popleft()
+                self._publish(head, how)
+        finally:
+            self._turn.release()
+
+    def _publish(self, entry, how: str):
+        prog_label, dyn_plan, stats, picks = entry
+        try:
+            arr = np.asarray(stats)
+            for row, step in picks:
+                self.record(prog_label, step, dyn_plan,
+                            arr if row is None else arr[row], how)
+        except Exception:
+            pass
+
     def record(self, prog_label: str, step: int, dyn_plan: DynamicsPlan,
-               row_arr: np.ndarray):
+               row_arr: np.ndarray, how: str = "ready"):
         arr = np.asarray(row_arr, np.float64)
         log_recs = []
         with self.lock:
@@ -725,7 +773,7 @@ class _Observatory:
                     **{k: (v if v is None or math.isfinite(v) else str(v))
                        for k, v in vals.items()}})
             self.samples += 1
-            self._emit_program_gauges(prog_label, series_map)
+            self._emit_program_gauges(prog_label, series_map, how)
         # JSONL export happens outside the observatory lock (file IO can
         # block); each record is one buffered write, so lines from
         # concurrent recorders interleave whole, never torn
@@ -756,7 +804,7 @@ class _Observatory:
                 labels=("program", "series")).labels(
                     program=prog_label, series=series).set(w)
 
-    def _emit_program_gauges(self, prog_label, series_map):
+    def _emit_program_gauges(self, prog_label, series_map, how):
         dead = sum(1 for s in series_map.values()
                    if s.code == "dead-layer")
         frozen = sum(1 for s in series_map.values()
@@ -778,8 +826,11 @@ class _Observatory:
             labels=("program",)).labels(program=prog_label).set(unhealthy)
         telemetry.counter(
             "dynamics_samples_total",
-            "dynamics samples recorded by the observatory",
-            labels=("program",)).labels(program=prog_label).inc()
+            "dynamics samples recorded by the observatory, by how the row "
+            "reached the host: found ready by a later step, or forced by "
+            "a reader's drain",
+            labels=("program", "how")).labels(
+                program=prog_label, how=how).inc()
 
     # -- activation saturation (fed from inspector probes) ------------------
 
@@ -869,33 +920,40 @@ _OBS = _Observatory()
 # ---------------------------------------------------------------------------
 
 def on_step(program, prog_label: str, stats, rng_counter: int):
-    """Record the per-step stats array if this step was a sample (the
-    executor passes the pre-increment counter the traced cond saw)."""
+    """Queue the step's stats row if this step was a sample (the executor
+    passes the pre-increment counter the traced cond saw; any other row
+    is dropped here), then record what earlier samples have reached the
+    host. `stats` is an output of the step just dispatched and is never
+    waited on: no later step takes it as an input, so it is never
+    donated and stays readable until a drain reads it."""
     dyn_plan = plan(program)
-    if dyn_plan is None or stats is None:
-        return
-    if int(rng_counter) % dyn_plan.period != 0:
-        return
-    try:
-        _OBS.record(prog_label, int(rng_counter), dyn_plan,
-                    np.asarray(stats))
-    except Exception:
-        pass
+    if dyn_plan is not None and stats is not None \
+            and int(rng_counter) % dyn_plan.period == 0:
+        _OBS.pending.append(
+            (prog_label, dyn_plan, stats, [(None, int(rng_counter))]))
+    drain()
 
 
 def on_window(program, prog_label: str, stats, base_counter: int,
               steps: int):
-    """Record the period-boundary rows out of a run_steps window's stacked
-    [K, groups, fields] stats (step i ran with counter base_counter+i)."""
+    """The same for a run_steps window: the period-boundary rows of its
+    stacked [K, groups, fields] stats (step i ran with counter
+    base_counter+i) are one pending entry."""
     dyn_plan = plan(program)
-    if dyn_plan is None or stats is None:
-        return
+    if dyn_plan is not None and stats is not None:
+        picks = [(i, int(base_counter) + i) for i in range(int(steps))
+                 if (int(base_counter) + i) % dyn_plan.period == 0]
+        if picks:
+            _OBS.pending.append((prog_label, dyn_plan, stats, picks))
+    drain()
+
+
+def drain(wait: bool = False):
+    """Record the pending samples that are on the host; with `wait`, all
+    of them, blocking on those still in flight (a reader asked, or the
+    executor is closing). Never raises."""
     try:
-        arr = np.asarray(stats)
-        for i in range(int(steps)):
-            c = int(base_counter) + i
-            if c % dyn_plan.period == 0:
-                _OBS.record(prog_label, c, dyn_plan, arr[i])
+        _OBS.drain(wait)
     except Exception:
         pass
 
@@ -915,16 +973,22 @@ def observe_probes(prog_label: str, stats):
 # ---------------------------------------------------------------------------
 
 def payload(recent: int = 32) -> Dict[str, Any]:
-    """The /dynamics endpoint + `dynamics --json` body."""
+    """The /dynamics endpoint + `dynamics --json` body. A reader asked:
+    what is pending is read first, whatever it waits."""
+    drain(wait=True)
     return _OBS.payload(recent=recent)
 
 
 def verdicts() -> List[Dict[str, Any]]:
+    drain(wait=True)
     return _OBS.verdicts()
 
 
 def crash_section() -> Optional[Dict[str, Any]]:
-    """Compact last-snapshot for inspector crash/hang reports."""
+    """Compact last-snapshot for inspector crash/hang reports. It takes
+    what is on the host and waits for nothing: a hang report is written
+    because the device does not answer."""
+    drain()
     return _OBS.crash_section()
 
 

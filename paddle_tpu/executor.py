@@ -1226,9 +1226,10 @@ class Executor:
         prog_label = telemetry.program_label(program)
         place_label = f"{type(self.place).__name__}:{self.place.device_id}"
         n_fetch = len(fetch_names) - len(side_fetches) - len(probe_sites or ())
-        feed_vals, state_vals, lod_map, rng_counter = self._gather(
-            program, feed, scope)
-        persist_out = self._persistable_outputs(program)
+        with tracing_mod.span("sink.gather"):
+            feed_vals, state_vals, lod_map, rng_counter = self._gather(
+                program, feed, scope)
+            persist_out = self._persistable_outputs(program)
 
         # lookup: the block compiled for these names, built on a miss
         compiled, key, plan_s = None, None, 0.0
@@ -1239,21 +1240,27 @@ class Executor:
                 program, feed_vals, state_vals, fetch_names, persist_out,
                 rng_key, lod_map, check_nan=check_nan)
         else:
-            state_keys = sorted(state_vals)  # incl. @SEQLEN side channels
-            feed_vals = self._commit_feeds(program, feed_vals, window=window)
-            state_vals = self._commit_state(program, state_vals, feed_vals)
-            key = (id(program), getattr(program, "_version", 0),
-                   tuple(sorted(feed_vals)), tuple(fetch_names),
-                   tuple(state_keys), self.place,
-                   getattr(program, "_amp_dtype", None),
-                   getattr(program, "_amp_level", "O1"),
-                   # the seed folds into the compiled step (see _compile),
-                   # so changing program.random_seed must recompile
-                   program.random_seed,
-                   *(("window", steps, fetch_mode) if window else ()),
-                   dynamics_mod.cache_token(program),
-                   quant_mod.cache_token(program))
-            compiled = self._cache.get(key) if use_program_cache else None
+            # every argument checked against where the block wants it, and
+            # the key of the block compiled for these names
+            with tracing_mod.span("sink.validate"):
+                state_keys = sorted(state_vals)  # incl. @SEQLEN side channels
+                feed_vals = self._commit_feeds(program, feed_vals,
+                                               window=window)
+                state_vals = self._commit_state(program, state_vals,
+                                                feed_vals)
+                key = (id(program), getattr(program, "_version", 0),
+                       tuple(sorted(feed_vals)), tuple(fetch_names),
+                       tuple(state_keys), self.place,
+                       getattr(program, "_amp_dtype", None),
+                       getattr(program, "_amp_level", "O1"),
+                       # the seed folds into the compiled step (see
+                       # _compile), so changing program.random_seed must
+                       # recompile
+                       program.random_seed,
+                       *(("window", steps, fetch_mode) if window else ()),
+                       dynamics_mod.cache_token(program),
+                       quant_mod.cache_token(program))
+                compiled = self._cache.get(key) if use_program_cache else None
             if compiled is None:
                 plan_t0 = time.perf_counter()
                 args = (program, state_keys, sorted(feed_vals), fetch_names,
@@ -1300,8 +1307,11 @@ class Executor:
                 # next run re-packs it with its lengths intact (incl. the
                 # inner lengths of nested lod_level=2 state)
                 inner = new_state.get(n + SEQLEN2_SUFFIX)
+                v, lengths, inner = telemetry.host_wait(
+                    (v, new_state[n + SEQLEN_SUFFIX], inner), prog_label,
+                    "lod_writeback")    # the repack is host work
                 packed, lod = padded_to_pack(
-                    np.asarray(v), np.asarray(new_state[n + SEQLEN_SUFFIX]),
+                    np.asarray(v), np.asarray(lengths),
                     None if inner is None else np.asarray(inner))
                 v = LoDTensor(packed, lod)
             scope.set_var(n, v)
@@ -1314,7 +1324,12 @@ class Executor:
         tracing_mod.phase("writeback")
         fetched = _rebuild_fetches(fetch_names[:n_fetch], fetch_vals[:n_fetch],
                                    fetch_lens, return_numpy)
-        self._publish_side_fetches()    # on the host by now if the fetches are
+        # on the host by now if the fetches are: a synchronous run records
+        # its own step's values, a pipelined one waits for nothing
+        with tracing_mod.span("sink.side_fetch"):
+            self._publish_side_fetches()
+        with tracing_mod.span("sink.dynamics"):
+            dynamics_mod.drain()
         return fetched
 
     def _launch(self, program, compiled, call, key, mode, steps, feed_vals,
@@ -1342,8 +1357,9 @@ class Executor:
                 # off): build it here, before the timed call, so that
                 # stop_profiler compiles nothing
                 self._account_of(program, compiled)
-            sig = telemetry.signature_of(feed_vals)
-            new_sig = sig not in compiled.seen_sigs
+            with tracing_mod.span("sink.signature"):
+                sig = telemetry.signature_of(feed_vals)
+                new_sig = sig not in compiled.seen_sigs
             if new_sig:
                 # a signature not seen before builds: jax's own trace /
                 # lower / compile events inside the call are collected
@@ -1360,7 +1376,7 @@ class Executor:
                     # async dispatch returns futures; force execution
                     # inside the timed scope so the event measures the
                     # step, not the enqueue (only when profiling)
-                    jax.block_until_ready(out)
+                    telemetry.host_wait(out, prog_label, "profiler_sync")
         except Exception as e:
             if mode == "window" and (
                     isinstance(e, _WindowUnsupported)
@@ -1463,37 +1479,46 @@ class Executor:
             # ONE fused on-device reduction + ONE host sync for the
             # whole step (_finite_all); the per-tensor np.asarray walk
             # only runs on the failure path, to name the culprit
-            checked = [
-                (name, val) for name, val in
-                list(zip(fetch_names[:n_keep], fetch_vals))
-                + list(new_state.items())
-                if jnp.issubdtype(getattr(val, "dtype", None)
-                                  or np.asarray(val).dtype, jnp.inexact)]
-            if checked and not bool(_finite_all([v for _, v in checked])):
-                for name, val in checked:
-                    arr = np.asarray(val)
-                    if not np.isfinite(arr).all():
-                        self._raise_nonfinite(
-                            program, name, arr, feed, new_state,
-                            rng_counter, scope, prog_label)
+            with tracing_mod.span("sink.check_nan_inf"):
+                checked = [
+                    (name, val) for name, val in
+                    list(zip(fetch_names[:n_keep], fetch_vals))
+                    + list(new_state.items())
+                    if jnp.issubdtype(getattr(val, "dtype", None)
+                                      or np.asarray(val).dtype, jnp.inexact)]
+                if checked and not bool(telemetry.host_wait(
+                        _finite_all([v for _, v in checked]), prog_label,
+                        "check_nan_inf")):
+                    for name, val in checked:
+                        arr = np.asarray(val)
+                        if not np.isfinite(arr).all():
+                            self._raise_nonfinite(
+                                program, name, arr, feed, new_state,
+                                rng_counter, scope, prog_label)
         if probe_sites:
             # the probe stat vectors (appended after the telemetry
             # extras) go to the inspector BEFORE state writeback: a
             # non-finite probe raises here
-            inspector_mod.record_probes(
-                self, program, scope, probe_sites, fetch_vals[n_keep:],
-                feed=feed, new_state=new_state, rng_counter=rng_counter,
-                prog_label=prog_label)
+            with tracing_mod.span("sink.probes"):
+                inspector_mod.record_probes(
+                    self, program, scope, probe_sites, fetch_vals[n_keep:],
+                    feed=feed, new_state=new_state, rng_counter=rng_counter,
+                    prog_label=prog_label)
 
     def _book(self, program, compiled, launch, mode, steps, feed_vals,
               state_vals, rng_counter, dyn_stats, n_fetch, side_fetched,
               internal_run, prog_label, place_label):
         """book: every watcher and sink of a committed step, each called
-        from this one place. A watcher that waits on the device (dynamics,
-        every 16th step; the side-fetch gauges) finds the scope already
-        whole. Between the modes only the labels, `steps` and the window's
-        two extra fields differ; the static memory analysis belongs to
-        the per-step block alone."""
+        from this one place under a span of its own (`sink.<name>`, inside
+        the `bookkeep` phase), so a trace says which of them held the
+        host. None of them waits on the device by default: the dynamics
+        row and the side-fetch gauges are queued in flight and published
+        by a later step that finds them ready; the one that does wait
+        (the flight recorder wants this step's norm) books it through
+        telemetry.host_wait and finds the scope already whole. Between
+        the modes only the labels, `steps` and the window's two extra
+        fields differ; the static memory analysis belongs to the per-step
+        block alone."""
         window = mode == "window"
         run_dt, compile_s = launch.run_dt, launch.compile_s
         window_fields = {"steps": steps, "per_step_seconds": run_dt / steps} \
@@ -1502,75 +1527,54 @@ class Executor:
         profiler_mod.record_event(f"executor_run({mode})", run_dt,
                                   start=launch.t0)
         if launch.build_s is not None:
-            if mode == "jit" and launch.cause == "first_compile" \
-                    and not internal_run:
-                # static memory analysis once per compiled block: an
-                # extra AOT lower/compile from avals (the persistent
-                # compilation cache absorbs the XLA work); advisory —
-                # a failure must never fail the training step
-                analysis_t0 = time.perf_counter()
-                try:
-                    # under the launch's device context: jax keys its
-                    # trace and lowering caches on it, and outside it the
-                    # analysis traced and lowered the whole block again
-                    with tracing_mod.span("analysis"), \
-                            jax.default_device(self.device):
-                        rec = memory_mod.on_compile(
-                            self, compiled, program, prog_label,
-                            place_label, feed_vals, state_vals,
-                            np.uint32(rng_counter), signature=launch.sig)
-                        if rec is not None and rec.account is not None:
-                            self._keep_account(
-                                compiled, prog_label, rec.module,
-                                rec.account, rec.xla_flops)
-                except Exception as mem_e:
-                    telemetry.log_event(
-                        "memory_analysis_error", program=prog_label,
-                        error=f"{type(mem_e).__name__}: {mem_e}")
-                launch.build_s["analysis"] = \
-                    time.perf_counter() - analysis_t0
-            _book_build(prog_label, launch.build_s)
+            with tracing_mod.span("sink.build"):
+                self._book_first_run(program, compiled, launch, mode,
+                                     feed_vals, state_vals, rng_counter,
+                                     internal_run, prog_label, place_label)
         if dyn_stats is not None:
-            if window:
-                dynamics_mod.on_window(program, prog_label, dyn_stats,
-                                       int(rng_counter), steps)
-            else:
-                dynamics_mod.on_step(program, prog_label, dyn_stats,
-                                     int(rng_counter))
+            with tracing_mod.span("sink.dynamics"):
+                if window:
+                    dynamics_mod.on_window(program, prog_label, dyn_stats,
+                                           int(rng_counter), steps)
+                else:
+                    dynamics_mod.on_step(program, prog_label, dyn_stats,
+                                         int(rng_counter))
 
-        telemetry.counter(
-            "executor_runs_total", "Executor.run calls",
-            labels=("program", "place", "mode")).labels(
-                program=prog_label, place=place_label, mode=mode).inc()
-        telemetry.counter(
-            "executor_steps_total",
-            "training/eval steps executed (a run_steps window counts K)",
-            labels=("program", "place")).labels(
-                program=prog_label, place=place_label).inc(steps)
-        telemetry.histogram(
-            "executor_run_seconds",
-            "Executor.run wall seconds (dispatch-only unless profiling "
-            "forces device sync)", labels=("program", "mode")).labels(
-                program=prog_label, mode=mode).observe(run_dt)
-        telemetry.gauge(
-            "executor_last_step_seconds",
-            "wall seconds of the most recent executor step (per-step "
-            "average for run_steps windows) — fleet skew input").set(
-                max(run_dt - compile_s, 0.0) / steps)
-        if self._analysis(program)[3]:
+        with tracing_mod.span("sink.counters"):
             telemetry.counter(
-                "optimizer_steps_total",
-                "runs of programs carrying optimizer-role ops",
-                labels=("program",)).labels(program=prog_label).inc(steps)
-        telemetry.log_event(
-            "run_window" if window else "run",
-            program=prog_label, place=place_label, mode=mode,
-            seconds=run_dt, compile_s=compile_s,
-            execute_s=max(run_dt - compile_s, 0.0), cache=launch.cache,
-            donated=len(state_vals) if compiled is not None else 0,
-            feeds=len(feed_vals), fetches=n_fetch, **window_fields)
+                "executor_runs_total", "Executor.run calls",
+                labels=("program", "place", "mode")).labels(
+                    program=prog_label, place=place_label, mode=mode).inc()
+            telemetry.counter(
+                "executor_steps_total",
+                "training/eval steps executed (a run_steps window counts K)",
+                labels=("program", "place")).labels(
+                    program=prog_label, place=place_label).inc(steps)
+            telemetry.histogram(
+                "executor_run_seconds",
+                "Executor.run wall seconds (dispatch-only unless profiling "
+                "forces device sync)", labels=("program", "mode")).labels(
+                    program=prog_label, mode=mode).observe(run_dt)
+            telemetry.gauge(
+                "executor_last_step_seconds",
+                "wall seconds of the most recent executor step (per-step "
+                "average for run_steps windows) — fleet skew input").set(
+                    max(run_dt - compile_s, 0.0) / steps)
+            if self._analysis(program)[3]:
+                telemetry.counter(
+                    "optimizer_steps_total",
+                    "runs of programs carrying optimizer-role ops",
+                    labels=("program",)).labels(
+                        program=prog_label).inc(steps)
+            telemetry.log_event(
+                "run_window" if window else "run",
+                program=prog_label, place=place_label, mode=mode,
+                seconds=run_dt, compile_s=compile_s,
+                execute_s=max(run_dt - compile_s, 0.0), cache=launch.cache,
+                donated=len(state_vals) if compiled is not None else 0,
+                feeds=len(feed_vals), fetches=n_fetch, **window_fields)
         if tracing_mod.enabled():
-            step_span = tracing_mod.owner_span()
+            step_span = tracing_mod.owner_span()    # no sink span is open
             if step_span.sampled:
                 step_span.attrs.update(program=prog_label, place=place_label,
                                        mode=mode, cache=launch.cache)
@@ -1582,41 +1586,84 @@ class Executor:
             # live HBM accounting: one tracker sample per run (gauges +
             # flight-recorder fields below); byte counts come from avals
             # only, so the donated state arrays are safe to measure
-            try:
-                hbm_sample = memory_mod.on_run(
-                    self, program, prog_label, feed_vals, state_vals)
-            except Exception:
-                hbm_sample = None
-        # the flight recorder below wants this step's global norm: it waits
-        self._side_pending.extend(
-            (metric, val, prog_label) for (metric, _n), val in side_fetched)
-        self._publish_side_fetches(
-            wait=not internal_run and inspector_mod.flight_enabled())
-        if not internal_run and inspector_mod.flight_enabled():
+            with tracing_mod.span("sink.memory"):
+                try:
+                    hbm_sample = memory_mod.on_run(
+                        self, program, prog_label, feed_vals, state_vals)
+                except Exception:
+                    hbm_sample = None
+        flight = not internal_run and inspector_mod.flight_enabled()
+        with tracing_mod.span("sink.side_fetch"):
+            # the flight recorder below wants this step's global norm: it
+            # waits, and the wait is booked
+            self._side_pending.extend(
+                (metric, val, prog_label)
+                for (metric, _n), val in side_fetched)
+            self._publish_side_fetches(wait=flight)
+        if flight:
             # flight recorder: one bounded ring record per step or window
             # (after the gauges above so the global norm is this step's; a
             # window skips the side-fetches and carries no norm)
-            record = {
-                "place": place_label, "mode": mode, "seconds": run_dt,
-                "compile_s": compile_s, "cache": launch.cache,
-                "feeds": len(feed_vals), "fetches": n_fetch,
-                "rng_counter": int(rng_counter),
-                "hbm_bytes_in_use": (hbm_sample or {}).get("bytes_in_use"),
-                "hbm_peak_bytes": (hbm_sample or {}).get("peak_bytes"),
-                **window_fields}
-            if not window:
-                record["global_norm"] = telemetry.read_gauge(
-                    "optimizer_global_norm", program=prog_label)
-            inspector_mod.record_step(program, prog_label, record)
+            with tracing_mod.span("sink.flight"):
+                record = {
+                    "place": place_label, "mode": mode, "seconds": run_dt,
+                    "compile_s": compile_s, "cache": launch.cache,
+                    "feeds": len(feed_vals), "fetches": n_fetch,
+                    "rng_counter": int(rng_counter),
+                    "hbm_bytes_in_use":
+                        (hbm_sample or {}).get("bytes_in_use"),
+                    "hbm_peak_bytes": (hbm_sample or {}).get("peak_bytes"),
+                    **window_fields}
+                if not window:
+                    record["global_norm"] = telemetry.read_gauge(
+                        "optimizer_global_norm", program=prog_label)
+                inspector_mod.record_step(program, prog_label, record)
+
+    def _book_first_run(self, program, compiled, launch, mode, feed_vals,
+                        state_vals, rng_counter, internal_run, prog_label,
+                        place_label):
+        """What a run that built its block books besides: the static
+        memory analysis (once a per-step block) and the build's seconds
+        by phase."""
+        if mode == "jit" and launch.cause == "first_compile" \
+                and not internal_run:
+            # static memory analysis once per compiled block: an
+            # extra AOT lower/compile from avals (the persistent
+            # compilation cache absorbs the XLA work); advisory —
+            # a failure must never fail the training step
+            analysis_t0 = time.perf_counter()
+            try:
+                # under the launch's device context: jax keys its
+                # trace and lowering caches on it, and outside it the
+                # analysis traced and lowered the whole block again
+                with tracing_mod.span("analysis"), \
+                        jax.default_device(self.device):
+                    rec = memory_mod.on_compile(
+                        self, compiled, program, prog_label,
+                        place_label, feed_vals, state_vals,
+                        np.uint32(rng_counter), signature=launch.sig)
+                    if rec is not None and rec.account is not None:
+                        self._keep_account(
+                            compiled, prog_label, rec.module,
+                            rec.account, rec.xla_flops)
+            except Exception as mem_e:
+                telemetry.log_event(
+                    "memory_analysis_error", program=prog_label,
+                    error=f"{type(mem_e).__name__}: {mem_e}")
+            launch.build_s["analysis"] = \
+                time.perf_counter() - analysis_t0
+        _book_build(prog_label, launch.build_s)
 
     def _publish_side_fetches(self, wait=False):
         """The telemetry side-fetches (program._telemetry_fetch_extra;
         PADDLE_TPU_TELEMETRY_FETCH=0 disables) queued by this step and by
         earlier ones, published without waiting on the device unless
-        `wait`: a value still in flight stays queued until a later call
-        finds it ready, so a pipelined loop keeps its steps in flight; a
-        synchronous one (return_numpy) publishes its own step's values
-        once its fetches are on the host. A metric the catalog lists as a
+        `wait` (booked under site `side_fetch`): a value still in flight
+        stays queued until a later call finds it ready, so a pipelined
+        loop keeps its steps in flight; a synchronous one (return_numpy)
+        publishes its own step's values once its fetches are on the host.
+        The dynamics rows follow the same rule in the observatory's own
+        queue (dynamics.drain). A metric the catalog lists as a
         histogram takes a sample a step, any other is a gauge; one that
         carries the `layer` label takes one series per element of its
         vector. Each publication is also a `side_fetch` event of the step
@@ -1624,10 +1671,12 @@ class Executor:
         no order, and a reader of a window's tail needs one."""
         while self._side_pending:
             metric, val, label = self._side_pending[0]
-            if not (wait or getattr(val, "is_ready", lambda: True)()):
+            if not (wait or telemetry.is_ready(val)):
                 break
             self._side_pending.popleft()
             try:
+                if wait:
+                    telemetry.host_wait(val, label, "side_fetch")
                 values = np.asarray(val, np.float64).ravel()
             except (TypeError, ValueError):
                 continue
@@ -1677,6 +1726,7 @@ class Executor:
 
     def close(self):
         self._publish_side_fetches(wait=True)   # the last steps' values
+        dynamics_mod.drain(wait=True)           # and their dynamics rows
         self._cache.clear()
         self._analysis_cache.clear()
 

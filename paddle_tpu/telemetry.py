@@ -36,7 +36,7 @@ __all__ = [
     "export_chrome_trace", "default_buckets", "reset", "program_label",
     "jax_compile_seconds", "watch_build", "merge_build_events",
     "build_phase_seconds",
-    "signature_of", "read_gauge", "read_series",
+    "signature_of", "is_ready", "host_wait", "read_gauge", "read_series",
     "read_histogram", "histogram_quantile",
 ]
 
@@ -641,6 +641,37 @@ def signature_of(feed_vals: Dict[str, Any]) -> Tuple[Tuple[str, str, str], ...]:
     return tuple(sig)
 
 
+def is_ready(value) -> bool:
+    """Whether reading `value` on the host would not wait on the device:
+    a jax array says so itself, anything else is on the host already."""
+    ready = getattr(value, "is_ready", None)
+    return ready is None or ready()
+
+
+def host_wait(value, program: str, site: str):
+    """Block the host until `value`, a device array or a pytree of them,
+    is computed, and hand it back: the one way the executor waits on the
+    device for a value the caller did not ask for. The wait is booked
+    where it is made, as seconds of the blocking call alone in
+    executor_host_wait_seconds{program, site}, and only when a leaf is
+    not ready on arrival: a value already computed costs nothing and
+    books nothing, so a steady pipelined loop reads 0 under every site."""
+    import jax
+    if all(is_ready(leaf) for leaf in jax.tree_util.tree_leaves(value)):
+        return value
+    t0 = time.perf_counter()
+    jax.block_until_ready(value)
+    histogram(
+        "executor_host_wait_seconds",
+        "seconds the host blocked on a device value the caller did not "
+        "ask for, by the site that waited: dynamics (a forced drain of "
+        "the pending samples), side_fetch (the flight recorder's norm), "
+        "check_nan_inf, profiler_sync, lod_writeback",
+        labels=("program", "site")).labels(
+            program=program, site=site).observe(time.perf_counter() - t0)
+    return value
+
+
 # Accumulated backend-compile seconds, fed by jax.monitoring: XLA fires
 # '/jax/core/compile/backend_compile_duration' for every real compilation
 # (including jit retraces the executor-level cache can't see). Reading the
@@ -772,6 +803,11 @@ METRIC_CATALOG = {
                                "Executor.run wall seconds"),
     "executor_last_step_seconds": _m("gauge", (),
                                      "wall seconds of the latest step"),
+    "executor_host_wait_seconds": _m(
+        "histogram", ("program", "site"),
+        "seconds the host blocked on a device value the caller did not "
+        "ask for, by site (telemetry.host_wait); nothing in a steady "
+        "pipelined loop"),
     "executor_compiles_total": _m("counter", ("program", "place"),
                                   "block traces/compiles"),
     "executor_compile_seconds_total": _m(
@@ -1040,7 +1076,9 @@ METRIC_CATALOG = {
     "dynamics_unhealthy_series": _m(
         "gauge", ("program",), "series with any non-ok dynamics verdict"),
     "dynamics_samples_total": _m(
-        "counter", ("program",), "dynamics samples recorded"),
+        "counter", ("program", "how"),
+        "dynamics samples recorded, by how the row reached the host: "
+        "found ready by a later step, or forced by a reader's drain"),
     "dynamics_update_norm_total": _m(
         "counter", ("program", "source"),
         "parameters of a traced step's dynamics table, a compile, by the "

@@ -440,3 +440,253 @@ def test_dynamics_endpoint_serves_payload():
     assert series["verdict"] == "dead-layer"
     assert len(series["recent"]) == 4
     assert [v["code"] for v in body["verdicts"]] == ["dead-layer"]
+
+
+# -- no watcher waits on the device (PR 51) -----------------------------------
+
+
+def _recorded_steps():
+    """Steps of the samples in the table, in the order they were recorded,
+    read without a drain (dynamics.payload would force one)."""
+    obs = dynamics._OBS
+    (series_map,) = obs.programs.values() if obs.programs else ({},)
+    rings = [[step for step, _ in s.ring] for s in series_map.values()]
+    assert all(r == rings[0] for r in rings)
+    return rings[0] if rings else []
+
+
+def _by_label(series, label, count=int):
+    """{value of `label`: sum of count(child)} over a family's series."""
+    out = {}
+    for labels, child in series.items():
+        key = dict(kv.split("=") for kv in labels.split(","))[label]
+        out[key] = out.get(key, 0) + count(child)
+    return out
+
+
+def _host_waits():
+    """{site: samples booked} of executor_host_wait_seconds."""
+    return _by_label(telemetry.snapshot()["histograms"].get(
+        "executor_host_wait_seconds", {}), "site", lambda h: h["count"])
+
+
+def _samples_by_how():
+    return _by_label(telemetry.read_series("dynamics_samples_total"), "how")
+
+
+def test_pipelined_loop_reads_its_samples_from_steps_already_finished():
+    """return_numpy=False with two steps in flight, 40 steps, period 16:
+    no step waits for its own sample, so nothing is booked under site
+    `dynamics`; each sample is in the table at most two dispatches after
+    its own, in step order; Executor.close() leaves none pending."""
+    in_flight_max, losses = 2, []
+    main, startup, loss = _build_program()
+    scope = executor_mod.Scope()
+    in_flight, late = [], {}
+    with dynamics.override(True, 16), executor_mod.scope_guard(scope):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        scope.set_var("__rng_counter__", 9)     # steps 9..48: 16, 32, 48
+        for i, feed in enumerate(_batches(40)):
+            out, = exe.run(main, feed=feed, fetch_list=[loss],
+                           return_numpy=False)
+            in_flight.append(out)
+            if len(in_flight) >= in_flight_max:
+                losses.append(float(np.asarray(in_flight.pop(0)).ravel()[0]))
+            for step in _recorded_steps():
+                late.setdefault(step, (9 + i) - step)
+        assert _host_waits().get("dynamics", 0) == 0
+        assert "forced" not in _samples_by_how()
+        # 48 is the last dispatch: on the host or not, close() has it
+        assert set(late) >= {16, 32}
+        assert all(0 <= n <= in_flight_max for n in late.values()), late
+        exe.close()
+    assert _recorded_steps() == [16, 32, 48]
+    assert not dynamics._OBS.pending
+    assert sum(_samples_by_how().values()) == 3
+    assert np.isfinite(losses).all()
+
+
+def test_a_reader_drains_what_is_pending_and_books_it_forced(monkeypatch):
+    """A row still in flight stays queued whatever the steps behind it
+    do; dynamics.payload() waits for it, books the wait under site
+    `dynamics` and the sample as how="forced"."""
+    monkeypatch.setattr(telemetry, "is_ready", lambda value: False)
+    main, startup, loss = _build_program()
+    scope = executor_mod.Scope()
+    with dynamics.override(True, 4), executor_mod.scope_guard(scope):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        scope.set_var("__rng_counter__", 4)
+        for feed in _batches(6):                # 4..9: 4 and 8 sample
+            exe.run(main, feed=feed, fetch_list=[loss], return_numpy=False)
+    assert len(dynamics._OBS.pending) == 2 and not _recorded_steps()
+    assert not _host_waits()
+    body = dynamics.payload()
+    assert body["samples_recorded"] == 2
+    assert _recorded_steps() == [4, 8]
+    assert _samples_by_how() == {"forced": 2}
+    assert _host_waits() == {"dynamics": 2}
+
+
+def test_a_synchronous_loop_records_each_sample_in_its_own_step():
+    """return_numpy=True: once the fetches are on the host so is the
+    step's row, and the run that made it records it, unforced."""
+    main, startup, loss = _build_program()
+    scope = executor_mod.Scope()
+    seen = []
+    with dynamics.override(True, 2), executor_mod.scope_guard(scope):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        scope.set_var("__rng_counter__", 2)
+        for feed in _batches(6):                # 2..7: 2, 4, 6 sample
+            exe.run(main, feed=feed, fetch_list=[loss])
+            seen.append(list(_recorded_steps()))
+    assert seen == [[2], [2], [2, 4], [2, 4], [2, 4, 6], [2, 4, 6]]
+    assert _samples_by_how() == {"ready": 3}
+    assert not _host_waits()
+
+
+def _wait_dynamics(forced, tmp_path, monkeypatch):
+    main, startup, loss = _build_program()
+    with dynamics.override(True, 1):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        exe.run(main, feed=_batches(1)[0], fetch_list=[loss],
+                return_numpy=False)
+        if forced:                              # a reader asks
+            dynamics.drain(wait=True)
+
+
+def _wait_side_fetch(forced, tmp_path, monkeypatch):
+    from paddle_tpu import inspector
+
+    def clipped(loss, startup):
+        fluid.clip.set_gradient_clip(
+            fluid.clip.GradientClipByGlobalNorm(clip_norm=1.0))
+        fluid.optimizer.SGD(learning_rate=0.01).minimize(
+            loss, startup_program=startup)
+
+    main, startup, loss = _build_program(minimize=clipped)
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup)
+    if forced:                                  # it wants this step's norm
+        inspector.enable_flight_recorder(str(tmp_path / "crash.json"))
+    try:
+        exe.run(main, feed=_batches(1)[0], fetch_list=[loss],
+                return_numpy=False)
+    finally:
+        inspector.disable_flight_recorder()
+
+
+def _wait_check_nan_inf(forced, tmp_path, monkeypatch):
+    monkeypatch.setattr(executor_mod, "_CHECK_NAN_INF", forced)
+    main, startup, loss = _build_program()
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup)
+    exe.run(main, feed=_batches(1)[0], fetch_list=[loss], return_numpy=False)
+
+
+def _wait_profiler_sync(forced, tmp_path, monkeypatch):
+    from paddle_tpu import profiler
+    main, startup, loss = _build_program()
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup)
+    if forced:
+        profiler.start_profiler()
+    try:
+        exe.run(main, feed=_batches(1)[0], fetch_list=[loss],
+                return_numpy=False)
+    finally:
+        if forced:
+            profiler.stop_profiler()
+            profiler.reset_profiler()
+
+
+def _wait_lod_writeback(forced, tmp_path, monkeypatch):
+    """Sequence state goes back to the scope packed: host work."""
+    main, startup = fluid.Program(), fluid.Program()
+    with unique_name.guard(), fluid.program_guard(main, startup):
+        x = fluid.layers.data(name="x", shape=[2], dtype="float32",
+                              lod_level=1)
+        kept = main.global_block().create_var(
+            name="kept_rows", shape=[-1, 2], dtype="float32",
+            persistable=True, lod_level=1 if forced else 0)
+        fluid.layers.assign(fluid.layers.scale(x, scale=2.0), output=kept)
+    rows = fluid.LoDTensor(
+        np.arange(10, dtype=np.float32).reshape(5, 2), [[0, 2, 5]])
+    scope = executor_mod.Scope()
+    fluid.Executor(fluid.CPUPlace()).run(
+        main, feed={"x": rows}, fetch_list=[], scope=scope)
+    assert isinstance(scope.find_var("kept_rows"),
+                      fluid.LoDTensor) == forced
+
+
+@pytest.mark.parametrize("forced", [True, False], ids=["forced", "default"])
+@pytest.mark.parametrize("site", ["dynamics", "side_fetch", "check_nan_inf",
+                                  "profiler_sync", "lod_writeback"])
+def test_each_wait_on_the_device_is_booked_at_its_site(
+        site, forced, tmp_path, monkeypatch):
+    """Every place the executor blocks on a device value the caller did
+    not ask for books the wait under its own site when its flag takes it
+    there with the value still in flight, and nothing otherwise. The
+    CPU finishes these steps before the host looks, so every value is
+    made to read as in flight."""
+    monkeypatch.setattr(telemetry, "is_ready", lambda value: False)
+    scope = executor_mod.Scope()
+    with executor_mod.scope_guard(scope):
+        globals()["_wait_" + site](forced, tmp_path, monkeypatch)
+    booked = _host_waits()
+    assert set(booked) == ({site} if forced else set()), booked
+    assert all(n >= 1 for n in booked.values())
+
+
+def test_readers_draining_beside_the_training_thread_lose_nothing(
+        monkeypatch):
+    """The pending queue is shared by the training thread (on_step) and
+    any reader (the obs server's /dynamics): with more readers than
+    cores forcing drains while steps are dispatched, every sample is
+    recorded once, in step order. Each row reads as in flight the first
+    time it is asked, so the ready and the forced paths race."""
+    import sys
+    import threading
+    asked = set()
+
+    def first_time_in_flight(value):
+        if id(value) in asked:
+            return True
+        asked.add(id(value))
+        return False
+
+    monkeypatch.setattr(telemetry, "is_ready", first_time_in_flight)
+    main, startup, loss = _build_program()
+    scope = executor_mod.Scope()
+    stop = threading.Event()
+
+    def reader():
+        while not stop.is_set():
+            dynamics.drain(wait=True)
+
+    readers = [threading.Thread(target=reader, daemon=True)
+               for _ in range((os.cpu_count() or 4) + 2)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        with dynamics.override(True, 1), executor_mod.scope_guard(scope):
+            exe = fluid.Executor(fluid.CPUPlace())
+            exe.run(startup)
+            scope.set_var("__rng_counter__", 100)
+            for t in readers:
+                t.start()
+            for feed in _batches(60):
+                exe.run(main, feed=feed, fetch_list=[loss],
+                        return_numpy=False)
+            exe.close()
+    finally:
+        stop.set()
+        for t in readers:
+            t.join(timeout=30)
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in readers)
+    assert _recorded_steps() == list(range(100, 160))
+    assert sum(_samples_by_how().values()) == 60

@@ -327,12 +327,62 @@ def test_executor_step_spans(monkeypatch):
     for s in built:
         assert cold_launch["start"] <= s["start"]
         assert s["end"] <= cold_launch["end"] + 1e-3
+    # ... under the sink that books a first run (PR 51: every sink of
+    # the bookkeeping has a span of its own)
+    (build,) = [
+        s for s in tracing.recent_spans(name="sink.build")
+        if s["parent_id"] == cold_bookkeep["span_id"]]
     (analysis,) = [
         s for s in tracing.recent_spans(name="analysis")
-        if s["parent_id"] == cold_bookkeep["span_id"]]
+        if s["parent_id"] == build["span_id"]]
     assert analysis["dur_s"] > 0
     assert all(not [k for k in _children(s) if k["name"] == "compile"]
                for s in steps)
+
+
+def test_each_sink_has_a_span_inside_the_phase_that_holds_it(tmp_path):
+    """PR 51: a step's host time is split by sink. The three stretches of
+    `prepare` and every call site of `_book` are `sink.<name>` spans,
+    children of their phase; the flight recorder's only while it records,
+    the first run's only on a run that built. Nothing listening, `span`
+    hands out the one shared no-op."""
+    from paddle_tpu import inspector
+    assert tracing.span("sink.gather") is tracing.span("sink.memory")
+    tracing.enable()
+    main, startup, loss, feed = _tiny_trainer()
+    scope = executor_mod.Scope()
+    exe = fluid.Executor(fluid.CPUPlace())
+    with executor_mod.scope_guard(scope):
+        exe.run(startup)
+        exe.run(main, feed=feed, fetch_list=[loss])         # builds
+        exe.run(main, feed=feed, fetch_list=[loss])
+        inspector.enable_flight_recorder(str(tmp_path / "crash.json"))
+        try:
+            exe.run(main, feed=feed, fetch_list=[loss])
+        finally:
+            inspector.disable_flight_recorder()
+    cold, warm, recorded = [
+        s for s in tracing.recent_spans(name="step")
+        if s["attrs"].get("program") == telemetry.program_label(main)]
+
+    def sinks(step):
+        spans = tracing.recent_spans(trace_id=step["trace_id"])
+        phase = {s["span_id"]: s["name"] for s in _children(step)}
+        return [(phase[s["parent_id"]], s["name"])
+                for s in sorted(spans, key=lambda s: s["start"])
+                if s["name"].startswith("sink.")]
+
+    steady = [("prepare", "sink.gather"), ("prepare", "sink.validate"),
+              ("prepare", "sink.signature"), ("bookkeep", "sink.dynamics"),
+              ("bookkeep", "sink.counters"), ("bookkeep", "sink.memory"),
+              ("bookkeep", "sink.side_fetch"),
+              ("writeback", "sink.side_fetch"),
+              ("writeback", "sink.dynamics")]
+    assert sinks(warm) == steady
+    assert sinks(cold) == steady[:3] + [("bookkeep", "sink.build")] \
+        + steady[3:]
+    assert sinks(recorded) == steady[:7] + [("bookkeep", "sink.flight")] \
+        + steady[7:]
 
 
 def test_step_spans_share_the_step_id_in_run_and_run_steps():
