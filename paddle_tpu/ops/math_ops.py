@@ -10,6 +10,7 @@ like the reference's math functors (operators/math/math_function.*).
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -46,6 +47,47 @@ def _mul_infer(op_, block):
             list(xv.shape[:xn]) + list(yv.shape[yn:]), xv.dtype)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _cotangent_at(v, sharding):
+    """v, and its cotangent constrained to `sharding` where it arrives:
+    every product of the gradient reads that one array."""
+    return v
+
+
+_cotangent_at.defvjp(
+    lambda v, sharding: (v, None),
+    lambda sharding, _, g: (jax.lax.with_sharding_constraint(g, sharding),))
+
+
+def _gather_once(ctx, op_, x):
+    """`tensor_parallel.gather_once` for this product in this trace:
+    nothing for a weight that is no parameter of the program, on the
+    quantised route, or run eagerly (there is no partitioner). Books
+    tp_gather_pinned_total{program, side}, one a product and side, on
+    the forward trace."""
+    program = getattr(ctx, "program", None)
+    if getattr(program, "_mesh", None) is None or \
+            getattr(ctx, "quant_mode", None) or \
+            not isinstance(x, jax.core.Tracer) or \
+            op_.attr("y_num_col_dims", 1) != 1:
+        return frozenset(), None
+    from ..parallel import tensor_parallel
+    sides, whole = tensor_parallel.gather_once(
+        program, op_.desc.input("Y")[0], x.shape[0])
+    if sides and not kernel_choice.in_retrace():
+        from .. import telemetry
+        pinned = telemetry.counter(
+            "tp_gather_pinned_total",
+            "products whose activation operand (column-parallel) or "
+            "output cotangent (row-parallel) is constrained so that it "
+            "crosses the model axis once (tensor_parallel.gather_once)",
+            labels=("program", "side"))
+        for side in sides:
+            pinned.labels(program=telemetry.program_label(program),
+                          side=side).inc()
+    return sides, whole
+
+
 @op("mul", infer_shape=_mul_infer)
 def _mul(ctx, op_, ins):
     x = jnp.asarray(ins["X"][0])
@@ -53,6 +95,16 @@ def _mul(ctx, op_, ins):
     xn = op_.attr("x_num_col_dims", 1)
     yn = op_.attr("y_num_col_dims", 1)
     (xf, yf), restore = mxu_cast(ctx, _flat2(x, xn), _flat2(y, yn))
+    # both constraints in the op's own shape: on the flattened value a
+    # reshape stands between the pinned array and its neighbours, and XLA
+    # then fuses neither way across it (PERF.md section 6, PR 52)
+    once, whole = _gather_once(ctx, op_, x)
+    if "operand" in once:
+        # pinned as [rows, d] the product stays 2-D and gelu leaves the up
+        # projection's epilogue for a pass of its own: 10.7 ms more busy
+        # time a step of gpt2-large.train-fsdp2-tp2
+        xf = jax.lax.with_sharding_constraint(
+            xf.reshape(x.shape), whole(x.ndim)).reshape(xf.shape)
     qmode = getattr(ctx, "quant_mode", None)
     if qmode:
         from .. import quant
@@ -66,9 +118,14 @@ def _mul(ctx, op_, ins):
             out2d = jnp.matmul(xf, yf)
     else:
         out2d = jnp.matmul(xf, yf)
+    out_shape = x.shape[:xn] + y.shape[yn:]
+    if "cotangent" in once:
+        # pinned as [rows, n] the grad-weight product no longer takes the
+        # activation before it into its operand, and XLA keeps gelu's
+        # output for it: +42 MB a layer
+        out2d = _cotangent_at(out2d.reshape(out_shape), whole(len(out_shape)))
     if restore is not None:
         out2d = out2d.astype(restore)
-    out_shape = x.shape[:xn] + y.shape[yn:]
     return {"Out": [out2d.reshape(out_shape)]}
 
 

@@ -152,15 +152,9 @@ def _match(program) -> FrozenSet[int]:
     from ..parallel import planner
     from ..parallel.overlap import _spec_axes
 
-    mesh = getattr(program, "_mesh", None)
     specs = getattr(program, "_param_shardings", None)
-    if mesh is None or not specs or not SIBLING_OPS:
-        return frozenset()
-    plan = getattr(program, "_sharding_plan", None)
-    sizes = dict(mesh.shape)
-    live = {a for a in (plan.model_axes if plan else planner.model_axes())
-            if int(sizes.get(a, 1)) > 1}
-    if not live:
+    live = planner.live_model_axes(program)
+    if not live or not specs or not SIBLING_OPS:
         return frozenset()
     block = program.global_block()
     by_activation: Dict[Any, list] = {}

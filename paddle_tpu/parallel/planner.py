@@ -226,6 +226,20 @@ def model_axes(layout: Optional[SpecLayout] = None) -> frozenset:
     return frozenset({layout.tensor_axis, "mp"})
 
 
+def live_model_axes(program) -> frozenset:
+    """The model axes of the program's plan that its mesh has at size > 1:
+    what the rules about what crosses the model axis gate on
+    (`ops/sibling_products.py`, `tensor_parallel.gather_once`). Empty
+    without a mesh."""
+    mesh = getattr(program, "_mesh", None)
+    if mesh is None:
+        return frozenset()
+    plan = getattr(program, "_sharding_plan", None)
+    sizes = dict(mesh.shape)
+    return frozenset(a for a in model_axes(plan.layout if plan else None)
+                     if int(sizes.get(a, 1)) > 1)
+
+
 # --------------------------------------------------------------------------
 # Role classification: walk the ProgramDesc
 # --------------------------------------------------------------------------

@@ -23,7 +23,13 @@ from __future__ import annotations
 from typing import Dict, Optional, Sequence, Tuple
 
 __all__ = ["shard_parameter", "param_shardings", "shard_fc_params",
-           "shard_all_params_zero", "expected_collectives"]
+           "shard_all_params_zero", "expected_collectives", "gather_once",
+           "GATHER_ONCE_SIDES"]
+
+# What a product under a planned model axis may have constrained, so that
+# the value crosses the axis once (`gather_once`). A test empties this to
+# trace the step as if the rule were not there.
+GATHER_ONCE_SIDES = frozenset({"operand", "cotangent"})
 
 
 def _specs(program) -> Dict[str, Tuple]:
@@ -70,6 +76,55 @@ def shard_fc_params(program, axis: str = "mp", min_dim: int = 2):
     return program
 
 
+def gather_once(program, weight: str, batch: int):
+    """(sides, whole) for the product of an activation whose leading
+    dimension is `batch` with the 2-D parameter `weight`: the gate of the
+    one-gather-a-value rule, read from the program and its mesh alone.
+
+    GSPMD's own propagation leaves the residual stream sharded along d
+    over the model axis and gathers it once a CONSUMER: q, k, v and the
+    up projection each gathered the same normed activation, each gradient
+    product its operand again (gpt2-large.train-fsdp2-tp2: 8 gathers of a
+    whole activation a layer where Megatron's algebra needs 4, PERF.md
+    section 6, PR 52). So where the weight's spec carries an axis of
+    `planner.model_axes()` that the mesh has at size > 1, `math_ops._mul`
+    constrains to `whole(ndim)` (the leading dimension over the plan's
+    batch axes, no axis on any other)
+
+    - "operand": the activation as the product reads it, after
+      `mxu_cast`, where the axis is on the weight's output dimension
+      (column-parallel): the gather moves the product's dtype, and
+      siblings and the gradient ops' re-trace constrain the same value,
+      which XLA keeps as one;
+    - "cotangent": the product's output cotangent where it enters the
+      product's gradient, where the axis is on the contraction dimension
+      (row-parallel): `dX = dY . W^T` and `dW = X^T . dY` read one dY.
+
+    No mesh, no spec, a model axis of size 1, a batch its axes do not
+    divide: `sides` is empty and the step is traced as it always was."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from . import planner
+    from .overlap import _spec_axes
+
+    spec = (getattr(program, "_param_shardings", None) or {}).get(weight)
+    live = planner.live_model_axes(program)
+    if not live or not spec or len(spec) != 2:
+        return frozenset(), None
+    sides = GATHER_ONCE_SIDES & {
+        side for side, ent in zip(("cotangent", "operand"), spec)
+        if live & set(_spec_axes((ent,)))}
+    mesh, plan = program._mesh, getattr(program, "_sharding_plan", None)
+    rows = (plan.layout if plan else planner.SpecLayout()).batch_spec(mesh)
+    shards = 1
+    for a in _spec_axes(rows):
+        shards *= int(mesh.shape[a])
+    if not sides or batch % shards:
+        return frozenset(), None
+    return sides, lambda ndim: NamedSharding(
+        mesh, PartitionSpec(*rows, *(None,) * (ndim - 1)))
+
+
 def expected_collectives(program) -> Dict[str, str]:
     """{param_name: predicted GSPMD collective pattern} for every annotated
     parameter — the Megatron algebra in words. Tensor-parallel collectives
@@ -85,13 +140,14 @@ def expected_collectives(program) -> Dict[str, str]:
         if not axes:
             continue
         if ndim >= 2 and spec[-1]:
-            out[name] = ("column-parallel ({0}): activation all-gather on "
-                         "use, grad reduce-scatter; siblings of one "
-                         "activation reduce their input gradient once"
-                         .format(spec[-1]))
+            out[name] = ("column-parallel ({0}): activation gathered once "
+                         "for all readers of it, grad reduce-scatter; "
+                         "siblings of one activation reduce their input "
+                         "gradient once".format(spec[-1]))
         elif ndim >= 2 and spec[0]:
-            out[name] = ("row-parallel ({0}): output all-reduce"
-                         .format(spec[0]))
+            out[name] = ("row-parallel ({0}): output all-reduce; "
+                         "cotangent gathered once for both gradient "
+                         "products".format(spec[0]))
         elif ndim == 1:
             out[name] = ("sharded bias ({0}): gathers with its layer"
                          .format(axes[0]))

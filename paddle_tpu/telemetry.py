@@ -922,6 +922,10 @@ METRIC_CATALOG = {
         "counter", ("program", "direction"),
         "groups of sibling products of one activation traced as one "
         "contraction (ops/sibling_products.py)"),
+    "tp_gather_pinned_total": _m(
+        "counter", ("program", "side"),
+        "products whose operand or output cotangent is constrained to "
+        "cross the model axis once (tensor_parallel.gather_once)"),
     "quant_kernel_total": _m("counter", ("op",),
                              "ops routed through int8/fp8 quantization"),
     "quant_fallback_total": _m("counter", ("op", "reason"),

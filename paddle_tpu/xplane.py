@@ -564,7 +564,11 @@ class Instr(NamedTuple):
     with a float32 product at `highest` precision counted as the 6 bf16
     passes it runs as (the trace's own `flops` stat; the floor's); `at` is the program
     op's position in the block it was lowered from; `kind`, `payload`,
-    `group_size`, `groups`, `axis`, `site` are set for a collective;
+    `group_size`, `groups`, `axis`, `site`, `channel` (its `channel_id`:
+    an asynchronous collective is one instruction in each computation
+    that holds a piece of it, all under one id) and `moved` (the shape
+    the collective itself writes, where `shape` is its fusion's) are set
+    for a collective;
     `recompute` is the segment of a forward op replayed in the backward
     (backward.append_backward(checkpoints=)), None for every other."""
     name: str
@@ -589,6 +593,8 @@ class Instr(NamedTuple):
     axis: Optional[str] = None
     site: Optional[str] = None
     recompute: Optional[int] = None
+    channel: Optional[int] = None
+    moved: str = ""
 
 
 _ROLE = re.compile(r"pd_role\.([A-Za-z0-9_]+)")
@@ -654,6 +660,7 @@ _GROUPS = re.compile(
     r"replica_groups=(\{\{[0-9,{} ]*\}\}|\{\}|\[[0-9,]+\]<=\[[0-9,]+\]"
     r"(?:T\([0-9,]+\))?)")
 _PAIRS = re.compile(r"source_target_pairs=\{([0-9,{} ]*)\}")
+_CHANNEL = re.compile(r"\bchannel_id=(\d+)")
 
 MOSAIC_TARGET = "tpu_custom_call"
 # a custom call that joins the pieces of an async slice under one name:
@@ -1360,8 +1367,11 @@ def hlo_instructions(text: str, mesh=None) -> List[Instr]:
                                        p.group(1) if p else None)
                 site = _COLL.search(op_name)
                 site = site.group(1) if site else None
+                channel = _CHANNEL.search(moved.attrs)
                 coll = {
                     "kind": kind,
+                    "channel": int(channel.group(1)) if channel else None,
+                    "moved": _plain(moved.shape),
                     "payload": 0 if moved.opcode.endswith("-done")
                     or raw.name.startswith("async-collective-done")
                     else (_async_moved(moved.shape)
@@ -1378,7 +1388,8 @@ def hlo_instructions(text: str, mesh=None) -> List[Instr]:
                 start = by_name.get(raw.operands[0])
                 if start is not None and start.kind == coll["kind"]:
                     coll.update(group_size=start.group_size,
-                                groups=start.groups, axis=start.axis)
+                                groups=start.groups, axis=start.axis,
+                                channel=start.channel)
             by_name[raw.name] = Instr(
                 raw.name, raw.opcode, heavy, flops, mxu_flops, nbytes,
                 raw.shape, detail, op_name, role, scope, op,

@@ -520,41 +520,95 @@ def test_gpt2_step_keeps_no_parameter_of_before_its_update(mosaic, one_chip):
         < 0.01 * temp
 
 
-def test_planned_gpt2_large_layer_reduces_qkv_input_gradient_once(
-        mosaic, topo):
+PLANNED_CELL = "gpt2-large.train-fsdp2-tp2"
+_PLANNED_LAYER = {}
+
+
+def _planned_layer_step(topo):
     """One layer of `gpt2-large.train-fsdp2-tp2`, planned fsdp=2 x tp=2
     over the described chips and compiled as the executor compiles a
-    planned step: the input gradients of q, k and v are one contraction
-    (`ops/sibling_products.py`), so the tp axis reduces 5 arrays of a
-    whole `bf16[8192, 1280]` activation a step and chip, none of them in
-    a tuple (Megatron's 4 a layer and the head's input gradient; the
-    parent reduced 7, three of them in one tuple, 147.6 MB where this
-    reads 105.6), and the cotangents' stack is an operand of the product,
-    not an array of its own (PERF.md section 6, PR 48)."""
+    planned step, once for the tests that read it (call under the
+    `mosaic` fixture): (cell, compiled text, what the trace added to
+    each telemetry series)."""
     from paddle_tpu import telemetry
 
-    def merged():
-        return sum(telemetry.read_series(
-            "sibling_products_merged_total").values())
+    def series():
+        return {name + "{" + labels + "}": v
+                for name in ("sibling_products_merged_total",
+                             "tp_gather_pinned_total")
+                for labels, v in telemetry.read_series(name).items()}
 
-    cell = run.load_json("workloads", "gpt2-large.train-fsdp2-tp2")
-    config = dict(run.load_json("configs", cell["config"]), n_layer=1)
-    before = merged()
-    sizes = tuple(cell["mesh"].values())
-    mesh = Mesh(np.array(topo.devices[:4]).reshape(sizes), tuple(cell["mesh"]))
-    compiled = describe_step.compile_step(cell, config, mesh)
-    assert merged() - before == 1
-    text = compiled.as_text()
+    if not _PLANNED_LAYER:
+        cell = run.load_json("workloads", PLANNED_CELL)
+        config = dict(run.load_json("configs", cell["config"]), n_layer=1)
+        sizes = tuple(cell["mesh"].values())
+        mesh = Mesh(np.array(topo.devices[:4]).reshape(sizes),
+                    tuple(cell["mesh"]))
+        before = series()
+        text = describe_step.compile_step(cell, config, mesh).as_text()
+        _PLANNED_LAYER["step"] = (cell, text, {
+            k: v - before.get(k, 0) for k, v in series().items()})
+    return _PLANNED_LAYER["step"]
+
+
+def _counted(added, name, label=""):
+    return sum(v for k, v in added.items()
+               if k.startswith(name) and label in k)
+
+
+def test_planned_gpt2_large_layer_reduces_qkv_input_gradient_once(
+        mosaic, topo):
+    """One layer of the planned cell: the input gradients of q, k and v
+    are one contraction (`ops/sibling_products.py`), so the tp axis
+    reduces 5 arrays of a whole `bf16[8192, 1280]` activation a step and
+    chip, none of them in a tuple (Megatron's 4 a layer and the head's
+    input gradient; the parent reduced 7, three of them in one tuple,
+    147.6 MB where this reads 105.6), and the cotangents' stack is an
+    operand of the product, not an array of its own (PERF.md section 6,
+    PR 48)."""
+    cell, text, added = _planned_layer_step(topo)
+    assert _counted(added, "sibling_products_merged_total") == 1
     whole = re.compile(r"bf16\[(8192,1280|8,1024,1280)\]")
     carried = [len(whole.findall(i.shape))
                for i in xplane.hlo_instructions(text, mesh=cell["mesh"])
                if i.kind == "all-reduce" and i.axis == "tp"]
     assert sum(carried) == 5 and max(carried) == 1, carried
-    rows = describe_step.collective_rows(text, cell["mesh"])
-    assert rows[("tp", "all-reduce")][1] < 110e6
+    rows = describe_step.collective_rows(text, cell["mesh"], 8192)
+    assert sum(row[2] for (axis, kind, _, _), row in rows.items()
+               if (axis, kind) == ("tp", "all-reduce")) < 110e6
     entry = text[text.index("ENTRY "):]
     assert "bf16[3,8192,640]" in text and \
         not re.search(r"= bf16\[3,8192,640\]", entry)
+
+
+def test_planned_gpt2_large_layer_gathers_each_value_once(mosaic, topo):
+    """The same step: what a column-parallel product reads and what a
+    row-parallel product's gradient reads crosses the tp axis once
+    (`tensor_parallel.gather_once`). The rule constrained 4 operands (q,
+    k, v, up) and 2 cotangents (out, down); the tp axis gathers a whole
+    `[8192, 1280]` activation in at most 2 + 2 collectives a layer and
+    the head's one each way (counted by `channel_id`: an asynchronous
+    gather is an instruction in every computation that holds a piece of
+    it), every one in bf16 (a pin on the norm's output gathers float32,
+    PERF.md section 7), nothing crosses it as an all-to-all (what a pin
+    of T over tp gives), and the all-reduces are PR 48's five. The
+    operand is pinned in the op's own shape, so the up projection is the
+    3-D product it was and gelu's erfc still rides in its epilogue
+    (pinned as `[8192, 1280]` it took a pass of its own, 10.7 ms more
+    busy time a step on the chip, PERF.md section 6, PR 52)."""
+    cell, text, added = _planned_layer_step(topo)
+    assert _counted(added, "tp_gather_pinned_total", "side=operand") == 4
+    assert _counted(added, "tp_gather_pinned_total", "side=cotangent") == 2
+    rows = describe_step.collective_rows(text, cell["mesh"], 8192)
+    gathers = {dtype: row for (axis, kind, what, dtype), row in rows.items()
+               if (axis, kind, what) == ("tp", "all-gather", "activation")}
+    assert set(gathers) == {"bf16"}, rows
+    assert gathers["bf16"][1] <= 2 + 2 + 2, rows
+    assert not [key for key in rows if key[:2] == ("tp", "all-to-all")], rows
+    assert rows[("tp", "all-reduce", "activation", "bf16")][:2] == [5, 5]
+    erfc = [comp for comp in re.split(r"\n(?=%|ENTRY )", text)
+            if "pd.gelu/erfc" in comp and not comp.startswith("ENTRY")]
+    assert erfc and all(" convolution(" in comp for comp in erfc), len(erfc)
 
 
 MLA_CELL = "glm-4.7-flash.train-mla-mtp-ep8-share"

@@ -21,15 +21,18 @@ to set against the ledger's busy time. A cell with a `mesh` (`gpt2-large.
 train-fsdp2-tp2`; 36 layers take five minutes here, `--set n_layer=2`
 half a minute) is planned over described chips as its traffic kind
 plans it, its numbers are one chip's, and its collectives are printed by
-mesh axis and kind with runs and MB: the chipless half of
+mesh axis and kind, whole activations apart from the rest (weights,
+optimizer state) and by dtype, with instructions, collectives (distinct
+`channel_id`s) and MB: the chipless half of
 `benchmarks/step_account.py`'s table, which a change to what GSPMD
-reduces is read from before a four-chip call. Nothing runs, so no time
+reduces or gathers is read from before a four-chip call. Nothing runs, so no time
 comes from here (PERF.md section 3).
 """
 
 import argparse
 import json
 import os
+import re
 import sys
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -121,18 +124,42 @@ def compile_program(main, startup, loss, feed, where):
         jax.ShapeDtypeStruct((), np.uint32, sharding=rng_at)).compile()
 
 
-def collective_rows(text, axes):
-    """{(mesh axis, kind): [instructions, payload bytes]} of a planned
-    step's compiled text, a chip."""
+def tokens_a_chip(compiled):
+    """Rows of a whole activation on one chip: the elements one chip
+    holds of the step's first feed (the token ids, [batch, T] split over
+    the plan's batch axes)."""
+    feeds, shardings = compiled.args_info[0][0], compiled.input_shardings[0][0]
+    name = min(feeds)
+    return int(np.prod(shardings[name].shard_shape(feeds[name].shape)))
+
+
+def collective_rows(text, axes, rows=None):
+    """{(mesh axis, kind, what, dtype): [instructions, collectives, payload
+    bytes]} of a planned step's compiled text, a chip. An asynchronous
+    collective is one instruction in each computation that holds a piece
+    of it, so the collectives are counted by `channel_id`. `what` is
+    "activation" where the collective writes a whole activation (an array
+    of every one of the chip's `rows` tokens: `[rows, n]` or `[batch, T,
+    n]`), "rest" for everything else (weights, optimizer state, heads'
+    pieces); `dtype` is the first array's."""
     from paddle_tpu import xplane
 
-    rows = {}
+    found = {}
     for instr in xplane.hlo_instructions(text, mesh=dict(axes)):
-        if instr.kind:
-            row = rows.setdefault((instr.axis, instr.kind), [0, 0])
-            row[0] += 1
-            row[1] += instr.payload
-    return rows
+        if not instr.kind:
+            continue
+        dtype, dims = re.search(r"(\w+)\[([\d,]*)\]", instr.moved).groups()
+        lead = [int(d) for d in dims.split(",") if d][:-1]
+        what = "activation" if rows and lead and np.prod(lead) == rows \
+            else "rest"
+        row = found.setdefault((instr.axis, instr.kind, what, dtype),
+                               [0, set(), 0])
+        row[0] += 1
+        row[1].add(instr.channel if instr.channel is not None
+                   else instr.name)
+        row[2] += instr.payload
+    return {key: [n, len(channels), payload]
+            for key, (n, channels, payload) in found.items()}
 
 
 def wide_instructions(text, wider):
@@ -231,12 +258,14 @@ def main(argv=None):
         "output_bytes": mem.output_size_in_bytes,
         "mosaic_calls": text.count('custom_call_target="tpu_custom_call"')}))
     if cell.get("mesh"):
-        print("%-10s %-20s %6s %10s" % ("axis", "kind", "runs", "MB"))
-        for (axis, kind), (runs, payload) in sorted(
-                collective_rows(text, cell["mesh"]).items(),
-                key=lambda kv: -kv[1][1]):
-            print("%-10s %-20s %6d %10.2f" % (axis or "-", kind, runs,
-                                              payload / 1e6))
+        print("%-10s %-20s %-10s %-6s %6s %6s %10s" % (
+            "axis", "kind", "what", "dtype", "instrs", "colls", "MB"))
+        for (axis, kind, what, dtype), (runs, colls, payload) in sorted(
+                collective_rows(text, cell["mesh"],
+                                tokens_a_chip(compiled)).items(),
+                key=lambda kv: -kv[1][2]):
+            print("%-10s %-20s %-10s %-6s %6d %6d %10.2f" % (
+                axis or "-", kind, what, dtype, runs, colls, payload / 1e6))
     if args.account is not None:
         print_account(text, args.account)
         return
