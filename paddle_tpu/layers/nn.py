@@ -1106,7 +1106,12 @@ def fused_attention(q, k, v, causal=False,
     the last `window` keys up to and with its own (0: every earlier key);
     the kernels neither fetch nor walk the tiles wholly before the window
     and the einsum path masks alike; ring attention takes no window and
-    says so. Block-diffusion training's three-part mask at
+    says so. A window shorter than two tiles (512 keys under tiles of 512
+    rows: Laguna-XS.2's) leaves a layer no open tile: every walked tile
+    is masked by the diagonal, the window's far edge or both. Layers of
+    one model may differ in H (48 and 64 query heads over 8 K/V heads in
+    models/gated_window_moe.py): each op repeats K and V by its own
+    ratio. Block-diffusion training's three-part mask at
     the grain of a block of tokens is block_diffusion_attention's, on the
     same kernels. (Named fused_attention because reference-parity
     nets.scaled_dot_product_attention already takes [B, T, D] with
@@ -1400,15 +1405,41 @@ def moe_block(x, num_experts, top_k, expert_width, shared_width=0,
 
 
 @_under_its_name
-def rotary_embedding(x, theta=10000.0, rotary_dims=None, name=None):
+def rotary_embedding(x, theta=10000.0, rotary_dims=None, name=None,
+                     scaling=None):
     """Rotary position embedding over x [B, T, H, D]: the last
     `rotary_dims` of every head (default all D) are rotated by the
     position t along axis 1, the pair (i, i + r/2) of those r dims by the
     angle t * theta^(-2i/r); the dims before them pass through. Angles
-    and the rotation are float32 under AMP (ops/hybrid_ops.py)."""
-    return _simple("rotary_embedding", x, name=name,
-                   attrs={"theta": float(theta),
-                          "rotary_dims": int(rotary_dims or x.shape[-1])})
+    and the rotation are float32 under AMP (ops/hybrid_ops.py).
+
+    `scaling`: a published `rope_parameters` / `rope_scaling` group.
+    `rope_type` "yarn" (arXiv:2309.00071) blends each pair's frequency
+    between theta^(-2i/r) and that over `factor`, by how often the pair
+    turns over `original_max_position_embeddings` (`beta_fast`, default
+    32, and `beta_slow`, default 1, bound the blend), and multiplies the
+    cosines and sines by `attention_factor` (default 0.1 ln(factor) + 1):
+    the rotated dims of queries and keys come out scaled by it, the
+    others do not. None, or `rope_type` "default": the plain angles. The
+    op's new attributes are written only when set, so a program without
+    `scaling` is the one it was."""
+    attrs = {"theta": float(theta),
+             "rotary_dims": int(rotary_dims or x.shape[-1])}
+    kind = (scaling or {}).get("rope_type", "default")
+    if kind == "yarn":
+        factor = float(scaling["factor"])
+        attrs.update(
+            yarn_factor=factor,
+            yarn_original_positions=float(
+                scaling["original_max_position_embeddings"]),
+            yarn_beta_fast=float(scaling.get("beta_fast") or 32),
+            yarn_beta_slow=float(scaling.get("beta_slow") or 1),
+            attention_factor=float(scaling.get("attention_factor")
+                                   or 0.1 * math.log(factor) + 1.0))
+    elif kind != "default":
+        raise ValueError(f"rotary_embedding knows rope_type 'default' and "
+                         f"'yarn', not {kind!r}")
+    return _simple("rotary_embedding", x, name=name, attrs=attrs)
 
 
 @_under_its_name

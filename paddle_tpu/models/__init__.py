@@ -8,6 +8,7 @@ glue (loss, optimizer) stays in user code or in `build_classifier`.
 
 from .alexnet import alexnet
 from .block_diffusion_moe import block_diffusion_moe_lm
+from .gated_window_moe import gated_window_moe_lm
 from .googlenet import googlenet
 from .granite_hybrid import granite_hybrid_lm
 from .mla_moe import mla_moe_lm
@@ -21,7 +22,8 @@ from .window_moe import window_moe_lm
 from .common import balance_routers, build_image_classifier
 
 __all__ = [
-    "alexnet", "block_diffusion_moe_lm", "googlenet", "granite_hybrid_lm", "mla_moe_lm", "mnist_conv", "mnist_mlp",
+    "alexnet", "block_diffusion_moe_lm", "gated_window_moe_lm", "googlenet",
+    "granite_hybrid_lm", "mla_moe_lm", "mnist_conv", "mnist_mlp",
     "nemotron_h_lm",
     "resnet_cifar10", "resnet_imagenet", "resnet50",
     "smallnet_mnist_cifar", "transformer_lm",

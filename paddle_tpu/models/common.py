@@ -51,11 +51,15 @@ def balance_routers(program, rate):
     backward because a gradient op traces its router again and has to
     read the bias the forward read (placed after the router, the rule
     made the cell's first gradient depend on `rate`: PR 42, chip call E).
-    Returns the appended ops."""
+    A router that the backward replays (`minimize(checkpoints=)`) is the
+    same router a second time and gets no second rule. Returns the
+    appended ops."""
+    from ..backward import RECOMPUTE_ATTR
     block = program.global_block()
     return [block.append_op(
         type="moe_balance_bias",
         inputs={"TopkIdx": op.output("TopkIdx"), "Bias": op.input("Bias")},
         outputs={"BiasOut": op.input("Bias")},
         attrs={"rate": rate, "op_role": "optimize"})
-        for op in list(block.ops) if op.type == "moe_router"]
+        for op in list(block.ops) if op.type == "moe_router"
+        and RECOMPUTE_ATTR not in op.desc.attrs]

@@ -6,8 +6,10 @@ selection bias against the load (moe_balance_bias) and a dropless expert
 layer that is told which experts it holds (squared-ReLU experts, or gated
 SiLU ones when it is given the third matrix), and the rotary position
 embedding (latent attention's decoupled part; every dim of a head in the
-block-diffusion decoder). models/nemotron_h.py, models/mla_moe.py and
-models/block_diffusion_moe.py build models from them.
+block-diffusion decoder; YaRN's blended frequencies and factor over half
+a head in the gated window decoder's full layers). models/nemotron_h.py,
+models/mla_moe.py, models/block_diffusion_moe.py, models/window_moe.py
+and models/gated_window_moe.py build models from them.
 
 Precision under AMP: norm statistics, the router, `dt`, `A`, the scan's
 decays and its state stay float32; the scan's four products and the
@@ -92,12 +94,40 @@ def _rms_norm(ctx, op_, ins):
 
 # --- rotary position embedding -----------------------------------------------
 
-def _rotary_angles(positions, dims: int, theta: float):
-    """[positions, dims / 2] float32 angles t * theta^(-2 i / dims)."""
+def _yarn_frequencies(inv, dims: int, theta: float, yarn):
+    """YaRN's per-pair frequencies (arXiv:2309.00071, as transformers'
+    _compute_yarn_parameters): pair j keeps theta^(-2j/dims) below `low`
+    (it turns more than beta_fast times over the original context),
+    takes it divided by `factor` from `high` on (fewer than beta_slow
+    turns), and a linear blend between; low and high are the floor and
+    the ceiling of dims * ln(original / (2 pi beta)) / (2 ln theta).
+    `yarn`: (factor, original positions, beta_fast, beta_slow)."""
+    factor, original, beta_fast, beta_slow = yarn
+
+    def pair_that_turns(times):
+        return dims * np.log(original / (times * 2 * np.pi)) \
+            / (2 * np.log(theta))
+
+    low = max(np.floor(pair_that_turns(beta_fast)), 0)
+    high = min(np.ceil(pair_that_turns(beta_slow)), dims - 1)
+    ramp = np.clip((np.arange(dims // 2) - low)
+                   / (high - low if high != low else 0.001), 0.0, 1.0)
+    return inv * (1 - ramp) + inv / factor * ramp
+
+
+def _rotary_angles(positions, dims: int, theta: float, yarn=None):
+    """[positions, dims / 2] float32 angles t * theta^(-2 i / dims), the
+    frequencies blended by _yarn_frequencies where `yarn` is given."""
     inv = np.float64(theta) ** (-np.arange(0, dims, 2, dtype=np.float64)
                                 / dims)
+    if yarn is not None:
+        inv = _yarn_frequencies(inv, dims, float(theta), yarn)
     return jnp.arange(positions, dtype=jnp.float32)[:, None] \
         * jnp.asarray(inv, jnp.float32)
+
+
+_YARN_ATTRS = ("yarn_factor", "yarn_original_positions", "yarn_beta_fast",
+               "yarn_beta_slow")
 
 
 @op("rotary_embedding", infer_shape=same_as_input())
@@ -105,15 +135,24 @@ def _rotary_embedding(ctx, op_, ins):
     """X [B, T, H, D]: the last `rotary_dims` of every head (default all
     D) are rotated by the position t of axis 1, the pair (i, i + r/2) of
     those r dims by the angle t * theta^(-2i/r) (the rotate-half
-    pairing); the leading D - r dims pass through. Angles, sines and
-    cosines and the rotation are float32 whatever X is; Out has X's
-    dtype."""
+    pairing); the leading D - r dims pass through. With `yarn_factor`
+    (and the three attributes beside it) the per-pair frequencies are
+    YaRN's blend (_yarn_frequencies); `attention_factor` multiplies the
+    cosines and sines, so the rotated dims come out scaled by it and the
+    others do not. Angles, sines and cosines and the rotation are float32
+    whatever X is; Out has X's dtype."""
     x = jnp.asarray(ins["X"][0])
     d = x.shape[-1]
     r = op_.attr("rotary_dims", 0) or d
     assert r % 2 == 0 and r <= d, (r, d)
-    angle = _rotary_angles(x.shape[1], r, op_.attr("theta", 10000.0))
+    yarn = None
+    if op_.attr("yarn_factor", 0.0):
+        yarn = tuple(float(op_.attr(name)) for name in _YARN_ATTRS)
+    angle = _rotary_angles(x.shape[1], r, op_.attr("theta", 10000.0), yarn)
     cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    factor = op_.attr("attention_factor", 1.0)
+    if factor != 1.0:   # traced only when set: every other step's HLO,
+        cos, sin = factor * cos, factor * sin   # and cache key, as it was
     h = _f32(x)
     keep, first, second = h[..., :d - r], h[..., d - r:d - r // 2], \
         h[..., d - r // 2:]

@@ -60,7 +60,13 @@ at D=64 (12 and 10 heads, T=1024: PR 29), D=128 (32 heads, T=4096: PR
 attention's expanded heads: PR 33); under the block mask at D=128 (32
 heads, both streams of one 4096-token sequence in blocks of 4: PR 42)
 and under a window of 4096 keys at D=128 (28 heads, one 8192-token
-sequence in four major tiles: PR 46);
+sequence in four major tiles: PR 46); under a window of 512 keys at
+D=128 beside causal layers of another head count (64 and 48 heads, K/V
+of 8 heads repeated 8 and 6 times ahead of the kernels, one 8192-token
+sequence: PR 53), where the window is one tile of 512 rows, below
+bq + bk, so `_crosses_both` is live and every walked tile of a windowed
+layer is masked: the diagonal's tile by both edges and the tile before
+it by the far edge, none open;
 D=32 and D=512 have compiled for a described v5e
 (tests/test_tpu_compile.py) and run interpreted only. Inside a block the
 heads are told

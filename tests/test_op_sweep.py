@@ -501,6 +501,10 @@ class TestLrn(OpTest):
         self.attrs = {"n": n, "k": k, "alpha": alpha, "beta": beta}
         self.outputs = {"Out": out}
         self.check_output(rtol=1e-4)
+        # lrn_grad is executed here and nowhere else: a test that only
+        # BUILDS alexnet's backward registers the lazy grad op in its
+        # worker, and tests/test_zz_op_coverage.py then asks for a run
+        self.check_grad(["X"], "Out", max_relative_error=0.02)
 
 
 class TestNormOp(OpTest):
