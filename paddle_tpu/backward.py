@@ -18,10 +18,14 @@ role and marked `recompute_segment` = the segment's index, reading the
 segment's inputs behind one `recompute_barrier` op and writing
 `<name>@RECOMPUTE`; the segment's grad ops read those. What the forward
 made between two checkpoints is then dead at the segment's end, and what
-lives across the forward/backward boundary is the checkpoints. The ops
-after the last checkpoint are not replayed: the backward starts there.
-Without `checkpoints` nothing of this runs and the program is the one it
-was.
+lives across the forward/backward boundary is the checkpoints and, of an
+op whose registry entry declares them (`OpDef.kept_in_replay`: the two
+attention ops' Out and LSE, dear to compute again and one activation to
+hold), the outputs it keeps: such an op stands in its replayed segment
+all the same, reads the first forward's values of them through the
+barrier under `Kept<slot>` inputs and runs nothing. The ops after the
+last checkpoint are not replayed: the backward starts there. Without
+`checkpoints` nothing of this runs and the program is the one it was.
 """
 
 from __future__ import annotations
@@ -163,16 +167,22 @@ def _replay_refusal(block: Block, op) -> Optional[str]:
     return None
 
 
-def replayed_ops(program: Program) -> Dict[int, List[str]]:
+def replayed_ops(program: Program, handed_on: Optional[bool] = None
+                 ) -> Dict[int, List[str]]:
     """{segment: the types of the forward ops replayed in it, in order}
     of the root block, read from the ops' RECOMPUTE_ATTR (the barrier is
-    not one of them)."""
+    not one of them). `handed_on`: True, only the ops that stand in their
+    segment and are handed outputs the first forward kept (they read
+    registry.KEPT_SLOT inputs and run nothing); False, only the ops that
+    run again; None, both."""
     found: Dict[int, List[str]] = {}
     for op in program.global_block().ops:
         seg = op.desc.attrs.get(RECOMPUTE_ATTR)
         if seg is not None:
             found.setdefault(seg, [])
-            if op.type != "recompute_barrier":
+            handed = any(s.startswith(registry.KEPT_SLOT)
+                         for s in op.desc.inputs)
+            if op.type != "recompute_barrier" and handed_on in (None, handed):
                 found[seg].append(op.type)
     return found
 
@@ -261,8 +271,20 @@ def _append_replay(block: Block, seg: _Segment, kept: Set[str],
                     or block.var_recursive(n).persistable:
                 continue
             entering.append(n)
-    rename = {n: mirror(n, f"{n}{RECOMPUTE_SUFFIX}.{seg.index}")
-              for n in entering}
+
+    def behind_the_barrier(n):
+        return mirror(n, f"{n}{RECOMPUTE_SUFFIX}.{seg.index}")
+
+    rename = {n: behind_the_barrier(n) for n in entering}
+    # what the first forward keeps for a replayed op that declares it
+    # (registry.OpDef.kept_in_replay) enters the segment as its inputs
+    # do, behind the barrier: XLA then neither relays nor converts it
+    # for the backward's readers before the backward is there (ahead of
+    # the barrier it kept a second and a float32 copy of every kept Out
+    # alive across the whole step)
+    handed = {n: behind_the_barrier(n) for i in replayed
+              for s in registry.get(block.ops[i].type).kept_in_replay
+              for n in block.ops[i].desc.output(s)}
     # the cotangents that enter the segment: the replay waits for them
     # behind the barrier, and XLA cannot merge it with the first forward
     cotangents = [grad_var_name(n) for n in seg.ends
@@ -270,18 +292,26 @@ def _append_replay(block: Block, seg: _Segment, kept: Set[str],
     attrs = {"op_role": "backward", RECOMPUTE_ATTR: seg.index}
     block.append_op(
         type="recompute_barrier",
-        inputs={"X": entering, "Dep": cotangents},
-        outputs={"Out": [rename[n] for n in entering], "DepOut": cotangents},
+        inputs={"X": entering + list(handed), "Dep": cotangents},
+        outputs={"Out": [rename[n] for n in entering] + list(handed.values()),
+                 "DepOut": cotangents},
         attrs=dict(attrs))
     for i in replayed:
         fwd = block.ops[i].desc
         for n in fwd.output_arg_names():
             if n not in kept:
                 rename[n] = mirror(n, n + RECOMPUTE_SUFFIX)
+        inputs = {s: [rename.get(n, n) for n in names]
+                  for s, names in fwd.inputs.items()}
+        # an op that declares kept outputs stands in its place and is
+        # handed them: its lowering returns them and runs nothing
+        # (registry.handed_on)
+        for s in registry.get(fwd.type).kept_in_replay:
+            inputs[registry.KEPT_SLOT + s] = [handed[n]
+                                              for n in fwd.output(s)]
         block.append_op(
             type=fwd.type,
-            inputs={s: [rename.get(n, n) for n in names]
-                    for s, names in fwd.inputs.items()},
+            inputs=inputs,
             outputs={s: [rename.get(n, n) for n in names]
                      for s, names in fwd.outputs.items()},
             attrs={**fwd.attrs, **attrs})

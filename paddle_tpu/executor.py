@@ -1423,11 +1423,14 @@ class Executor:
                     "its backward (append_backward(checkpoints=)), a compile",
                     labels=("program",)).labels(program=prog_label).inc(
                         len(replayed))
+                # what runs again: an op handed the outputs its first
+                # run kept books itself where it is lowered
+                # (registry.handed_on: recompute_kept_total, _bytes)
                 by_type = telemetry.counter(
                     "recompute_ops_total",
-                    "forward ops replayed in the backward, a compile, by "
-                    "op type", labels=("program", "type"))
-                for types in replayed.values():
+                    "forward ops that run again in the backward, a "
+                    "compile, by op type", labels=("program", "type"))
+                for types in replayed_ops(program, handed_on=False).values():
                     for op_type in types:
                         by_type.labels(program=prog_label,
                                        type=op_type).inc()

@@ -38,7 +38,8 @@ import numpy as np
 from ..framework.desc import OpDesc
 from ..framework.framework import grad_var_name
 from . import kernel_choice
-from .registry import NO_GRAD, infer_grad_shapes, op, register
+from .registry import (NO_GRAD, handed_on, infer_grad_shapes, op,
+                       register)
 from .common import (SelectedRowsVal, in_var, mxu_cast, out_var,
                      same_as_input, set_out, to_np_dtype)
 
@@ -885,6 +886,15 @@ def _sdpa_grad(fwd, no_grad_set):
         attrs=dict(fwd.attrs))]
 
 
+# What both attention ops keep across a replayed segment
+# (registry.OpDef.kept_in_replay, which says why these two and no other):
+# the two outputs their explicit grad op reads. Between two checkpoints
+# they are dead after the forward, so the replay would run the forward
+# kernels a second time for them, behind a barrier that keeps XLA from
+# merging the two runs: the cost that grad op was written to avoid.
+_SDPA_KEPT = ("Out", "LSE")
+
+
 # Shortest per-device sequence that 'auto' hands to the flash kernels.
 # Attention alone on a v5e, causal bf16, 12 heads of 64, 16384 tokens a
 # call, forward + backward, ms (tools/flash_sweep.py, PR 29; PERF.md
@@ -1053,7 +1063,7 @@ def _sdpa_paths(ctx, op_, q, k, v, count=False):
 
 
 @op("scaled_dot_product_attention", infer_shape=_sdpa_infer,
-    grad=_sdpa_grad)
+    grad=_sdpa_grad, kept_in_replay=_SDPA_KEPT)
 def _scaled_dot_product_attention(ctx, op_, ins):
     """Fused softmax attention, Q/K/V [B, T, H, D] (no 2018-reference
     analogue — the capability the brief requires for long context). K and
@@ -1069,7 +1079,11 @@ def _scaled_dot_product_attention(ctx, op_, ins):
     path derives it from the same logits XLA already CSEs; the ring path
     emits the real ring-merged LSE so its explicit backward can run the
     blockwise ring gradient directly, without re-executing the forward
-    (Pallas custom calls are not CSE'd — ADVICE r4)."""
+    (Pallas custom calls are not CSE'd — ADVICE r4). Replayed in a
+    recomputed segment it is handed both and runs nothing (_SDPA_KEPT)."""
+    kept = handed_on(ctx, op_, ins)
+    if kept is not None:
+        return kept
     q = jnp.asarray(ins["Q"][0])
     k = jnp.asarray(ins["K"][0])
     v = jnp.asarray(ins["V"][0])
@@ -1348,7 +1362,8 @@ def _bd_takes_flash(ctx, op_, q, count=False) -> bool:
     return reason is None
 
 
-@op(_BD_OP, infer_shape=_sdpa_infer, grad=_sdpa_grad)
+@op(_BD_OP, infer_shape=_sdpa_infer, grad=_sdpa_grad,
+    kept_in_replay=_SDPA_KEPT)
 def _block_diffusion_attention(ctx, op_, ins):
     """Attention of block-diffusion training (arXiv:2503.09573): every
     sequence runs as two streams of L positions, a noised copy and the
@@ -1360,7 +1375,11 @@ def _block_diffusion_attention(ctx, op_, ins):
     a clean query sees the clean keys of blocks up to its own. One
     algorithm, two implementations chosen from the shapes
     (_bd_takes_flash): _bd_flash on the kernels, _bd_einsum elsewhere.
-    Emits LSE [2B, H, L] float32 for the explicit backward."""
+    Emits LSE [2B, H, L] float32 for the explicit backward; replayed in
+    a recomputed segment it is handed both and runs nothing (_SDPA_KEPT)."""
+    kept = handed_on(ctx, op_, ins)
+    if kept is not None:
+        return kept
     q, k, v = (jnp.asarray(ins[s][0]) for s in ("Q", "K", "V"))
     block = op_.attr("block_length", 1)
     assert q.shape[0] % 2 == 0 and q.shape[1] % block == 0, (q.shape, block)

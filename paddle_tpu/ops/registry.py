@@ -43,28 +43,73 @@ class OpDef:
     no_kernel: bool = False                   # executor-level op (feed/fetch/while…)
     # forward input slots the generic grad should NOT differentiate (indices etc.)
     non_diff_inputs: Sequence[str] = field(default_factory=tuple)
+    # output slots whose first value outlives the forward under
+    # `append_backward(checkpoints=)`: a replayed segment hands them to the
+    # op again under KEPT_SLOT + slot and the lowering returns them
+    # (handed_on) where another op would run a second time. For an op
+    # whose cost to compute again grows faster than what it leaves: the
+    # two attention ops, whose work grows with the (query, key) pairs and
+    # whose Out and LSE grow with the tokens. A `mul` or a norm is the
+    # opposite (cheap to run again, as wide as its input to hold) and is
+    # what recomputation is there not to keep. Python-side only: no
+    # forward op's desc carries it.
+    kept_in_replay: Sequence[str] = field(default_factory=tuple)
 
 
 _REGISTRY: Dict[str, OpDef] = {}
 
 
 def register(type: str, *, lower=None, infer_shape=None, grad=None,
-             no_kernel=False, non_diff_inputs=()) -> OpDef:
+             no_kernel=False, non_diff_inputs=(), kept_in_replay=()) -> OpDef:
     assert type not in _REGISTRY, f"op '{type}' registered twice"
     d = OpDef(type=type, lower=lower, infer_shape=infer_shape, grad=grad,
-              no_kernel=no_kernel, non_diff_inputs=tuple(non_diff_inputs))
+              no_kernel=no_kernel, non_diff_inputs=tuple(non_diff_inputs),
+              kept_in_replay=tuple(kept_in_replay))
     _REGISTRY[type] = d
     return d
 
 
 def op(type: str, *, infer_shape=None, grad=None, no_kernel=False,
-       non_diff_inputs=()):
+       non_diff_inputs=(), kept_in_replay=()):
     """Decorator form: @op("relu") def _(ctx, op, ins): ..."""
     def deco(fn):
         register(type, lower=fn, infer_shape=infer_shape, grad=grad,
-                 no_kernel=no_kernel, non_diff_inputs=non_diff_inputs)
+                 no_kernel=no_kernel, non_diff_inputs=non_diff_inputs,
+                 kept_in_replay=kept_in_replay)
         return fn
     return deco
+
+
+# a replayed op reads the first forward's value of a kept output slot
+# under this prefix and the slot's name (backward._append_replay)
+KEPT_SLOT = "Kept"
+
+
+def handed_on(ctx, op_, ins) -> Optional[Dict[str, list]]:
+    """{output slot: its values} of a replayed op that was handed its own
+    result (OpDef.kept_in_replay), None for every other: the first line
+    of such an op's lowering, ahead of whatever books or counts a
+    lowering, since nothing is lowered there. Books the hand-over, as
+    every lowering books what it chose: recompute_kept_total{program,
+    type} and the values' bytes as traced, recompute_kept_bytes{program}."""
+    slots = get(op_.type).kept_in_replay
+    if not slots or KEPT_SLOT + slots[0] not in ins:
+        return None
+    kept = {slot: list(ins[KEPT_SLOT + slot]) for slot in slots}
+    from .. import memory, telemetry
+    program = telemetry.program_label(ctx.program)
+    telemetry.counter(
+        "recompute_kept_total",
+        "replayed forward ops handed the outputs their first run kept, a "
+        "lowering, by op type", labels=("program", "type")).labels(
+            program=program, type=op_.type).inc()
+    telemetry.counter(
+        "recompute_kept_bytes",
+        "bytes of the outputs kept across the forward for a replayed op, "
+        "a lowering", labels=("program",)).labels(program=program).inc(
+            sum(memory.nbytes_of(v) for values in kept.values()
+                for v in values))
+    return kept
 
 
 def get(type: str) -> OpDef:

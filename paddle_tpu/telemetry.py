@@ -830,7 +830,16 @@ METRIC_CATALOG = {
         "segments of forward ops a compiled block replays in its backward"),
     "recompute_ops_total": _m(
         "counter", ("program", "type"),
-        "forward ops replayed in the backward, a compile, by op type"),
+        "forward ops that run again in the backward, a compile, by op "
+        "type"),
+    "recompute_kept_total": _m(
+        "counter", ("program", "type"),
+        "replayed forward ops handed the outputs their first run kept "
+        "(registry.OpDef.kept_in_replay), a lowering, by op type"),
+    "recompute_kept_bytes": _m(
+        "counter", ("program",),
+        "bytes of the outputs kept across the forward for a replayed op, "
+        "a lowering"),
     "optimizer_steps_total": _m("counter", ("program",),
                                 "runs of optimizer-carrying programs"),
     "optimizer_minimize_total": _m("counter", ("optimizer",),

@@ -161,6 +161,17 @@ def test_the_model_is_built_from_the_published_lists():
     assert sorted(replayed) == [1, 2, 3, 4]     # the last layer is not
     assert all(types.count("scaled_dot_product_attention") == 1
                for types in replayed.values())
+    # each stands in its segment and is handed the Out and LSE its first
+    # run kept, behind the segment's barrier; nothing else is handed on
+    assert backward.replayed_ops(main, handed_on=True) == {
+        seg: ["scaled_dot_product_attention"] for seg in replayed}
+    again = [op for op in ops if op.type == "scaled_dot_product_attention"
+             and backward.RECOMPUTE_ATTR in op.desc.attrs]
+    assert [(op.input("KeptOut"), op.input("KeptLSE"))
+            for op in reversed(again)] == [
+        ([f"{first.output('Out')[0]}@RECOMPUTE.{seg}"],
+         [f"{first.output('LSE')[0]}@RECOMPUTE.{seg}"])
+        for seg, first in zip(sorted(replayed), attention)]
     assert [types.count("moe_router") for _, types in
             sorted(replayed.items())] == [0, 1, 1, 1]
     rules = [op for op in ops if op.type == "moe_balance_bias"]
@@ -177,6 +188,13 @@ def test_the_model_is_built_from_the_published_lists():
     label = telemetry.program_label(main)
     assert telemetry.read_gauge(model.LOSS_METRIC, program=label) == \
         pytest.approx(float(np.ravel(out)[0]), rel=1e-6)
+    series = f"program={label},type=scaled_dot_product_attention"
+    assert telemetry.read_series("recompute_kept_total")[series] == 4
+    assert series not in telemetry.read_series("recompute_ops_total")
+    # float32 here: Out [2, T, H_l, hd] and LSE [2, H_l, T] of four layers
+    t, hd = config["sequence_length"], config["head_dim"]
+    assert telemetry.read_series("recompute_kept_bytes")[
+        f"program={label}"] == 4 * 2 * t * (6 + 3 * 8) * (hd + 1)
     for layer in ("0", "3"):
         rows = telemetry.read_histogram("moe_rows_routed", program=label,
                                         layer=layer)

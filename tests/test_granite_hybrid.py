@@ -191,12 +191,31 @@ def test_a_segment_that_would_differ_is_refused_by_name():
 
 
 def test_counters_of_a_compile_with_checkpoints(step):
+    """recompute_ops_total counts what runs again: replayed_ops less the
+    attention op, which is handed the outputs its first run kept and is
+    counted under recompute_kept_total."""
+    import collections
     from paddle_tpu import telemetry
     replayed = backward.replayed_ops(step.main)
-    segments = dict(telemetry.read_series("recompute_segments_total"))
-    ops = dict(telemetry.read_series("recompute_ops_total"))
-    assert sum(segments.values()) >= len(replayed)
-    assert sum(v for k, v in ops.items() if "type=ssd_scan" in k) >= 1
+    label = f"program={telemetry.program_label(step.main)}"
+    assert telemetry.read_series("recompute_segments_total")[label] == \
+        len(replayed)
+    ran_again = collections.Counter(
+        t for types in backward.replayed_ops(
+            step.main, handed_on=False).values() for t in types)
+    handed = collections.Counter(
+        t for types in backward.replayed_ops(
+            step.main, handed_on=True).values() for t in types)
+    assert handed == {"scaled_dot_product_attention": 1}
+    assert ran_again + handed == collections.Counter(
+        t for types in replayed.values() for t in types)
+    ops = {k.split("type=")[1]: v for k, v in
+           telemetry.read_series("recompute_ops_total").items()
+           if k.startswith(label + ",")}
+    assert ops == dict(ran_again) and ops["ssd_scan"] >= 1
+    assert telemetry.read_series("recompute_kept_total")[
+        f"{label},type=scaled_dot_product_attention"] == 1
+    assert telemetry.read_series("recompute_kept_bytes")[label] > 0
 
 
 # sha256 of main.to_json() + startup.to_json() of every model of
