@@ -36,7 +36,7 @@ __all__ = [
     "prelu", "crop", "spp", "unpool", "conv3d_transpose",
     "max_pool2d_with_index", "conv_shift", "l1_norm",
     "fused_attention", "block_diffusion_attention", "sparse_moe", "rms_norm",
-    "mamba2_mixer", "moe_block",
+    "mamba2_mixer", "kda_mixer", "moe_block",
     "rotary_embedding", "gated_mlp", "latent_attention", "mtp_block",
 
     "hsigmoid", "bilinear_interp", "selective_fc",
@@ -1300,6 +1300,69 @@ def mamba2_mixer(x, num_heads, head_dim, n_groups, state_size, conv_kernel=4,
 
 
 @_under_its_name
+def kda_mixer(x, num_heads, head_dim, conv_kernel=4, gate_rank=None,
+              chunk_size=64, epsilon=1e-5, l2_epsilon=1e-6, out_scale=0.02,
+              name=None):
+    """Kimi Delta Attention (arXiv:2510.26692) over x [B, T, D], H =
+    `num_heads` heads of K = V = `head_dim`:
+
+        q~, k~, v = silu(conv(x W_q)), silu(conv(x W_k)), silu(conv(x W_v))
+        gate = (x W_f1) W_f2;  beta = x W_b          [T, H, K] and [T, H], raw
+        o = kda_scan(q~, k~, v, gate, A_log, dt_bias, beta)
+        y = concat_h(rms_norm(o_h; w) * sigmoid((x W_g1) W_g2)_h) W_o
+
+    A map, a causal depthwise convolution of `conv_kernel` taps (no bias:
+    causal_conv1d without its Bias) and silu for each of q, k and v; the
+    decay gate and the output gate through low-rank maps of `gate_rank`
+    (default the head's width); the op (ops/hybrid_ops.py kda_scan: the
+    L2 norm of q~ and k~ a head, g = -exp(A_log) softplus(gate + dt_bias)
+    a channel, beta's sigmoid, the gated delta rule in chunks of
+    `chunk_size`, all in float32 around bf16 products); one norm weight
+    [K] for all heads. A_log [H] and dt_bias [H K] start as Mamba-2's
+    (log U(1, 16); softplus^-1 of a log-uniform step in [0.001, 0.1]). No
+    bias in any map. Parameters in the order created: W_q, W_k, W_v, the
+    three filters, W_f1, W_f2, W_b, A_log, dt_bias, w, W_g1, W_g2, W_o."""
+    helper = LayerHelper("kda_mixer", name=name)
+    seqlen, d_model = int(x.shape[1]), int(x.shape[2])
+    width, rank = num_heads * head_dim, gate_rank or head_dim
+    dtype = x.dtype
+
+    def by_head(t):
+        return reshape(t, [-1, seqlen, num_heads, head_dim])
+
+    def short_conv(t):
+        taps = helper.create_parameter(
+            attr=None, shape=[width, conv_kernel], dtype=dtype,
+            default_initializer=NormalInitializer(scale=conv_kernel ** -0.5))
+        out = helper.create_tmp_variable(dtype)
+        helper.append_op(type="causal_conv1d",
+                         inputs={"X": [t], "Filter": [taps]},
+                         outputs={"Out": [out]}, attrs={})
+        return by_head(out)
+
+    q, k, v = map(short_conv, [_linear(x, width) for _ in range(3)])
+    gate = by_head(_linear(_linear(x, rank), width))
+    beta = _linear(x, num_heads)
+    a_log = helper.create_parameter(
+        attr=None, shape=[num_heads], dtype=dtype,
+        default_initializer=LogOfUniformInitializer())
+    dt_bias = helper.create_parameter(
+        attr=None, shape=[width], dtype=dtype,
+        default_initializer=SoftplusInverseLogUniformInitializer())
+    o = helper.create_tmp_variable(dtype)
+    helper.append_op(
+        type="kda_scan",
+        inputs={"Q": [q], "K": [k], "V": [v], "Gate": [gate],
+                "ALog": [a_log], "DtBias": [dt_bias], "Beta": [beta]},
+        outputs={"Out": [o]},
+        attrs={"chunk_size": int(chunk_size), "epsilon": float(l2_epsilon)})
+    o = elementwise_mul(
+        rms_norm(o, epsilon=epsilon),
+        by_head(_linear(_linear(x, rank), width, act="sigmoid")))
+    return _linear(reshape(o, [-1, seqlen, width]), d_model, scale=out_scale)
+
+
+@_under_its_name
 def moe_block(x, num_experts, top_k, expert_width, shared_width=0,
               experts_held=None, expert_offset=0, scaling=1.0,
               norm_topk_prob=True, out_scale=0.02, stats=None, name=None,
@@ -1454,7 +1517,7 @@ def gated_mlp(x, width, out_scale=0.02):
 def latent_attention(x, num_heads, q_lora_rank, kv_lora_rank,
                      qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
                      rope_theta=10000.0, epsilon=1e-5, out_scale=0.02,
-                     use_flash="auto"):
+                     use_flash="auto", rotate=True):
     """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434 section
     2.1) over x [B, T, D], in its expanded (training) form:
 
@@ -1468,24 +1531,56 @@ def latent_attention(x, num_heads, q_lora_rank, kv_lora_rank,
     `num_heads` query heads, and is broadcast to them here before the
     attention op, so q, k, v reach it as [B, T, H, nope + rope] and
     [B, T, H, v_head_dim] and 'auto' takes the flash kernels where they
-    tile. No bias in any map."""
+    tile. No bias in any map.
+
+    `q_lora_rank` None (Kimi-Linear's `q_lora_rank: null`): the queries
+    are one direct map, [q_nope_j | q_rope_j] = (x W_q)_j, with no latent
+    and no norm. `rotate` False (its `mla_use_nope`): no rotation; the
+    one shared key head is carried to every query head unturned, and the
+    layer has no positions. Where the keys' width differs from the
+    values' (its 192 beside 128, which the flash kernels' gate answers
+    `shape`), q, k and v reach the op with zero lanes up to the next
+    multiple of 128 (256), q scaled by sqrt(lanes / (nope + rope)) for
+    the op's 1 / sqrt(lanes), and the output's leading v_head_dim lanes
+    are taken: scores and outputs are exact, the kernels walk 1.6 times
+    the live products. Every departure is built only when asked for: a
+    program with a latent query, a rotation and equal widths is the one
+    it was."""
     from .tensor import concat
     seqlen, d_model = int(x.shape[1]), int(x.shape[2])
     qk_dim = qk_nope_head_dim + qk_rope_head_dim
-    c_q = rms_norm(_linear(x, q_lora_rank), epsilon=epsilon)
-    q = reshape(_linear(c_q, num_heads * qk_dim),
+    q_in = x if q_lora_rank is None \
+        else rms_norm(_linear(x, q_lora_rank), epsilon=epsilon)
+    q = reshape(_linear(q_in, num_heads * qk_dim),
                 [-1, seqlen, num_heads, qk_dim])
-    q = rotary_embedding(q, theta=rope_theta, rotary_dims=qk_rope_head_dim)
+    if rotate:
+        q = rotary_embedding(q, theta=rope_theta,
+                             rotary_dims=qk_rope_head_dim)
     c_kv, k_rope = split(_linear(x, kv_lora_rank + qk_rope_head_dim),
                          [kv_lora_rank, qk_rope_head_dim], dim=2)
-    k_rope = rotary_embedding(
-        reshape(k_rope, [-1, seqlen, 1, qk_rope_head_dim]), theta=rope_theta)
+    k_rope = reshape(k_rope, [-1, seqlen, 1, qk_rope_head_dim])
+    if rotate:
+        k_rope = rotary_embedding(k_rope, theta=rope_theta)
     kv = reshape(_linear(rms_norm(c_kv, epsilon=epsilon),
                          num_heads * (qk_nope_head_dim + v_head_dim)),
                  [-1, seqlen, num_heads, qk_nope_head_dim + v_head_dim])
     k_nope, v = split(kv, [qk_nope_head_dim, v_head_dim], dim=3)
     k = concat([k_nope, expand(k_rope, [1, 1, num_heads, 1])], axis=3)
+    lanes = max(qk_dim, v_head_dim)
+    if qk_dim != v_head_dim:
+        lanes = -(-lanes // 128) * 128 if lanes > 128 else lanes
+
+        def widened(t, width):
+            return t if width == lanes else pad(
+                t, [0, 0, 0, 0, 0, 0, 0, lanes - width])
+
+        if lanes != qk_dim:
+            q = scale(q, scale=(lanes / qk_dim) ** 0.5)
+        q, k, v = widened(q, qk_dim), widened(k, qk_dim), \
+            widened(v, v_head_dim)
     attn = fused_attention(q, k, v, causal=True, use_flash=use_flash)
+    if lanes != v_head_dim:
+        attn = split(attn, [v_head_dim, lanes - v_head_dim], dim=3)[0]
     return _linear(reshape(attn, [-1, seqlen, num_heads * v_head_dim]),
                    d_model, scale=out_scale)
 

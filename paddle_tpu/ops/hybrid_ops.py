@@ -58,7 +58,8 @@ from . import kernel_choice, pallas_pair_sum
 from .common import in_var, same_as_input, set_out
 from .registry import NO_GRAD, op
 
-__all__ = ["gmm_ineligible", "ssd_scan_chunked", "ssd_scan_ineligible"]
+__all__ = ["gmm_ineligible", "kda_chunked", "ssd_scan_chunked",
+           "ssd_scan_ineligible"]
 
 
 def _f32(x):
@@ -165,7 +166,8 @@ def _rotary_embedding(ctx, op_, ins):
 
 @op("causal_conv1d", infer_shape=same_as_input())
 def _causal_conv1d(ctx, op_, ins):
-    """X [B, T, C], Filter [C, K], Bias [C]: Out[t] = silu(Bias + sum_j
+    """X [B, T, C], Filter [C, K], Bias [C] (may be absent: Kimi Delta
+    Attention's short convolutions have none): Out[t] = silu(Bias + sum_j
     Filter[:, j] * X[t - (K-1) + j]) with zeros before t = 0 (a depthwise
     conv1d, left pad K-1, then Mamba's activation). K shifted
     multiply-adds on the VPU; float32 inside, X's dtype out."""
@@ -173,7 +175,8 @@ def _causal_conv1d(ctx, op_, ins):
     w = _f32(ins["Filter"][0])
     k, t = w.shape[1], x.shape[1]
     padded = jnp.pad(_f32(x), ((0, 0), (k - 1, 0), (0, 0)))
-    out = _f32(ins["Bias"][0])
+    bias = ins.get("Bias")
+    out = _f32(bias[0]) if bias and bias[0] is not None else 0.0
     for j in range(k):
         out = out + padded[:, j:j + t] * w[:, j]
     return {"Out": [jax.nn.silu(out).astype(x.dtype)]}
@@ -310,6 +313,232 @@ def _ssd_scan(ctx, op_, ins):
     y = core(x, dt, a, b, c)
     y = y + _f32(x) * _f32(ins["D"][0])[:, None]
     return {"Out": [y.astype(x.dtype)]}
+
+
+# --- Kimi Delta Attention: the gated delta rule, a decay a channel, chunked ---
+
+# rows of one sub-block of a chunk. Every exponent of kda_chunked is a
+# difference of summed log-decays referred to a sub-block's first row, so
+# the largest it takes is what ONE sub-block's tokens decay by: 16 tokens
+# of the published strongest initial decay (-1.6 a token) are e^25.6,
+# where float32 ends at e^88.7.
+_KDA_SUB = 16
+
+
+@jax.custom_vjp
+def _unit_lower_inverse(a):
+    """(I + a)^-1 for a [..., C, C] strictly lower triangular, C a power
+    of two, in float32 at full precision: block forward substitution by
+    doubling. X holds the inverse of every diagonal block of b rows (b =
+    1: the identity); with a's entries under the first and beside the
+    second of each pair of neighbouring blocks, a_b (the pair's A21, all
+    else zero), X - X a_b X is the same of 2 b rows: [[M11, 0], [-M22 A21
+    M11, M22]] a pair. log2(C) levels of two batched [C, C] products
+    each, every one exact forward substitution: no power of `a` is formed
+    (the Neumann product (I - a)(I + a^2)(I + a^4)... forms them, and
+    cancels catastrophically where keys repeat), no loop over rows
+    reaches the compiled step, and every operand keeps [C, C] as its
+    minor dims (a [b, b] block a pair pads sixty-four-fold in a v5e's
+    tiles at b = 2). Its gradient is the inverse's own, da = -M^T dM M^T
+    below the diagonal: two products and M alone kept, where autodiff
+    through the levels keeps two [C, C] arrays a level."""
+    c = a.shape[-1]
+    assert c & (c - 1) == 0, f"a chunk of {c} rows is no power of two"
+    at = np.arange(c)
+    inverse = jnp.broadcast_to(jnp.eye(c, dtype=jnp.float32), a.shape)
+    b = 1
+    while b < c:
+        # rows in the second block of a pair, columns in the first
+        under = ((at[:, None] // b) % 2 == 1) & (
+            at[:, None] // b - at[None, :] // b == 1)
+        step = jnp.matmul(jnp.where(under, a, 0.0), inverse,
+                          precision=lax.Precision.HIGHEST)
+        inverse = inverse - jnp.matmul(inverse, step,
+                                       precision=lax.Precision.HIGHEST)
+        b *= 2
+    return inverse
+
+
+def _unit_lower_inverse_fwd(a):
+    inverse = _unit_lower_inverse(a)
+    return inverse, inverse
+
+
+def _unit_lower_inverse_bwd(inverse, d_inverse):
+    turned = jnp.swapaxes(inverse, -1, -2)
+    d_a = -jnp.matmul(
+        jnp.matmul(turned, d_inverse, precision=lax.Precision.HIGHEST),
+        turned, precision=lax.Precision.HIGHEST)
+    at = np.arange(inverse.shape[-1])
+    return (jnp.where(at[:, None] > at[None, :], d_a, 0.0),)
+
+
+_unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
+
+
+def kda_chunked(q, k, v, g, beta, chunk, dtype=jnp.float32):
+    """The gated delta rule with a decay a channel (Kimi Delta Attention,
+    arXiv:2510.26692), a head at a time:
+
+        S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T
+        o_t = S_t^T q_t
+
+    in chunks of `chunk` tokens (the WY form): one lax.scan over the
+    chunks with the state [B, H, K, V] as the carry, each chunk
+    (_kda_chunk, which has the algebra) a jax.checkpoint. The gradient
+    keeps a state a chunk and computes a chunk's [C, C] system, inverse
+    and decayed copies again ahead of their gradient, so nothing of a
+    chunk outlives it. tools/kda_sweep.py on a v5e at the Kimi-Linear
+    cell's [1, 8192, 32, 128, 128], forward | forward + gradient ms (PR
+    55): 8.9 | 25.5 at chunks of 64 (9.1 | 27.0 at 32); held over more
+    than its own chunk the gradient's working set leaves the chip: 9.6 |
+    27.4 with four chunks of 64 kept at once, 10.0 | 38.0 with sixteen,
+    16.4 | 53.2 with sixty-four.
+
+    q, k [B, T, H, K] (unit rows: the caller's L2 norm), v [B, T, H, V],
+    g [B, T, H, K] (<= 0) and beta [B, T, H] in float32. T need not be a
+    multiple of `chunk`: the tail is padded with beta = 0 and g = 0,
+    which neither writes nor decays."""
+    bsz, t, h, kd = q.shape
+    pad = (-t) % chunk
+    if pad:
+        q, k, v, g, beta = (
+            jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+            for x in (q, k, v, g, beta))
+
+    def chunks(x):
+        return jnp.moveaxis(
+            x.reshape((bsz, (t + pad) // chunk, chunk) + x.shape[2:]), 1, 0)
+
+    @jax.checkpoint
+    def one_chunk(state, chunk_):
+        return _kda_chunk(state, *chunk_, dtype)
+
+    _, out = lax.scan(one_chunk,
+                      jnp.zeros((bsz, h, kd, v.shape[-1]), jnp.float32),
+                      tuple(map(chunks, (q, k, v, g, beta))))
+    return jnp.moveaxis(out, 0, 1).reshape(bsz, t + pad, h, -1)[:, :t]
+
+
+def _kda_chunk(state, q, k, v, g, beta, dtype):
+    """(the state left, o [B, C, H, V]) of one chunk of C tokens entered
+    with `state` [B, H, K, V] in float32. With G_r the summed g of the
+    chunk's rows up to and with r, A_rs = beta_r sum_c k_rc k_sc exp(G_rc
+    - G_sc) below the diagonal, M = (I + A)^-1 (_unit_lower_inverse), w =
+    M (beta k exp(G)) and u = M (beta v): u' = u - w S, o = (q exp(G)) S
+    + tril(q k^T decayed) u', S <- Diag(exp(G_C)) S + (k exp(G_C - G))^T
+    u'.
+
+    G, A, the inverse and the state are float32; the products take
+    `dtype` operands and accumulate in float32. Every exponent is a
+    DIFFERENCE referred to the first row of a sub-block of _KDA_SUB rows
+    (a row's own sub-block for the row side, the row's sub-block for
+    every key it may see), at most 0 across sub-blocks and at most one
+    sub-block's decay inside one; exp(-G) alone, which passes float32 at
+    a chunk's summed decay of -88.7, is never taken."""
+    bsz, chunk, h, kd = q.shape
+    sub = _KDA_SUB if chunk % _KDA_SUB == 0 else chunk
+    n = chunk // sub
+    # [B, C, H, ...] -> [B, H, C, ...]: a head's chunk is a matrix, and
+    # every product below is batched over b and h
+    q, k, v, g, beta = (jnp.moveaxis(_f32(x), 2, 1)
+                        for x in (q, k, v, g, beta))
+    cum = jnp.cumsum(g, axis=2)                         # [B, H, l, K]
+    # the summed decay ahead of each sub-block's first row: [B, H, n, K]
+    ahead = jnp.concatenate(
+        [jnp.zeros_like(cum[:, :, :1]), cum[:, :, sub - 1:-1:sub]], axis=2)
+    own = jnp.repeat(ahead, sub, axis=2)                # a row's own sub-block
+    shrunk, decayed = jnp.exp(cum - own), jnp.exp(cum)
+
+    def by_sub(x):
+        return x.astype(dtype).reshape(bsz, h, n, sub, kd)
+
+    # q's and k's rows of a sub-block, one under the other: [.., n, 2 sub, K]
+    rows = jnp.concatenate([by_sub(q * shrunk), by_sub(k * shrunk)], axis=3)
+    # the keys as row sub-block i reads them: those up to its own last row
+    at = jnp.arange(chunk)
+    seen = at[None, :] < (jnp.arange(n)[:, None] + 1) * sub     # [n, l]
+    rise = ahead[:, :, :, None] - cum[:, :, None]       # [B, H, n, l, K]
+    keys = (k[:, :, None] * jnp.exp(
+        jnp.where(seen[:, :, None], rise, -jnp.inf))).astype(dtype)
+
+    def dot(spec, *operands):
+        return jnp.einsum(spec, *operands,
+                          preferred_element_type=jnp.float32)
+
+    scores = dot("bhnik,bhnsk->bhnis", rows, keys)
+    qk, kk = (scores[:, :, :, part].reshape(bsz, h, chunk, chunk)
+              for part in (slice(0, sub), slice(sub, None)))
+    below = at[:, None] > at[None, :]
+    qk = jnp.where(below | (at[:, None] == at[None, :]), qk, 0.0)
+    a = jnp.where(below, kk, 0.0) * beta[..., None]     # [B, H, l, s]
+    inverse = _unit_lower_inverse(a).astype(dtype)
+    fed = beta[..., None]
+    w = dot("bhls,bhsk->bhlk", inverse,
+            (k * decayed * fed).astype(dtype)).astype(dtype)
+    u = dot("bhls,bhsv->bhlv", inverse, (v * fed).astype(dtype))
+    entering = state.astype(dtype)
+    fresh = (u - dot("bhlk,bhkv->bhlv", w, entering)).astype(dtype)
+    last = cum[:, :, -1]                                # [B, H, K]
+    to_end = (k * jnp.exp(last[:, :, None] - cum)).astype(dtype)
+    left = state * jnp.exp(last)[..., None] + dot("bhlk,bhlv->bhkv", to_end,
+                                                  fresh)
+    out = dot("bhlk,bhkv->bhlv", (q * decayed).astype(dtype), entering) \
+        + dot("bhls,bhsv->bhlv", qk.astype(dtype), fresh)
+    return left, jnp.moveaxis(out, 1, 2)
+
+
+def _kda_infer(op_, block):
+    v = in_var(op_, block, "V")
+    if v is not None and v.shape is not None:
+        set_out(op_, block, "Out", list(v.shape), v.dtype)
+
+
+@op("kda_scan", infer_shape=_kda_infer)
+def _kda_scan(ctx, op_, ins):
+    """Kimi Delta Attention between its short convolutions and its gated
+    norm. Q, K [B, T, H, K] and V [B, T, H, V] (behind conv and silu),
+    Gate [B, T, H, K] (the low-rank map's output, raw), ALog [H], DtBias
+    [H * K], Beta [B, T, H] (raw):
+
+        q, k <- q / sqrt(sum q^2 + `epsilon`), k likewise    (a head's rows)
+        g = -exp(ALog) * softplus(Gate + DtBias)             a channel, <= 0
+        beta = sigmoid(Beta)
+        Out = kda_chunked(q, k, V, g, beta, `chunk_size`) / sqrt(K)
+
+    The norm, g and beta are float32 whatever the inputs are, and
+    jax.numpy around the chunked core, so autodiff carries ALog and
+    DtBias; Out has V's dtype. One path, XLA's: the chunked form
+    (kda_chunked) and autodiff's gradient of it (each chunk computed again
+    ahead of its pull-back; the triangular inverse's gradient its own);
+    kda_scan_total{chunk, path} books each forward lowering, `chunked` a
+    first forward's and `chunked_replay` that of an op a recomputed
+    segment runs again (a gradient's re-trace books nothing)."""
+    q, k = (_f32(ins[slot][0]) for slot in ("Q", "K"))
+    v = jnp.asarray(ins["V"][0])
+    eps = op_.attr("epsilon", 1e-6)
+    q, k = (x * lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + eps)
+            for x in (q, k))
+    heads, width = q.shape[2], q.shape[3]
+    bias = _f32(ins["DtBias"][0]).reshape(heads, width)
+    g = -jnp.exp(_f32(ins["ALog"][0]))[:, None] \
+        * jax.nn.softplus(_f32(ins["Gate"][0]) + bias)
+    beta = jax.nn.sigmoid(_f32(ins["Beta"][0]))
+    chunk = op_.attr("chunk_size", 64)
+    if not kernel_choice.in_retrace():
+        from .. import telemetry
+        from ..backward import RECOMPUTE_ATTR
+        telemetry.counter(
+            "kda_scan_total",
+            "lowerings of a forward kda_scan op, by its chunk length and "
+            "the path taken (`chunked`: XLA's, the one there is; "
+            "`chunked_replay`: the same, run again by a recomputed segment)",
+            labels=("chunk", "path")).labels(
+                chunk=str(chunk),
+                path="chunked_replay" if RECOMPUTE_ATTR in op_.desc.attrs
+                else "chunked").inc()
+    out = kda_chunked(q, k, v, g, beta, chunk, dtype=_compute_dtype(ctx))
+    return {"Out": [(out * width ** -0.5).astype(v.dtype)]}
 
 
 # --- router ------------------------------------------------------------------

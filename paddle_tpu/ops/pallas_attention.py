@@ -66,7 +66,11 @@ of 8 heads repeated 8 and 6 times ahead of the kernels, one 8192-token
 sequence: PR 53), where the window is one tile of 512 rows, below
 bq + bk, so `_crosses_both` is live and every walked tile of a windowed
 layer is masked: the diagonal's tile by both edges and the tile before
-it by the far edge, none open;
+it by the far edge, none open; and at D=256 causal, 32 heads over one
+8192-token sequence in four major tiles, where 64 of q's and k's lanes
+and 128 of v's are zeros (PR 55: latent attention at keys 192 wide
+beside values 128 wide, which this gate answers `shape` and
+layers.latent_attention widens to one 256-lane block a head);
 D=32 and D=512 have compiled for a described v5e
 (tests/test_tpu_compile.py) and run interpreted only. Inside a block the
 heads are told

@@ -147,6 +147,18 @@ def _scan_flops(ins, outs, attrs):
                            + 2 * hp * b[3])
 
 
+def _kda_flops(ins, outs, attrs):
+    """kda_scan: the recurrence's three products of a head's [K, V] state
+    a token (k^T S, the rank-one correction, q^T S). The useful work,
+    which no chunk length moves: the chunked form's within-chunk products
+    and its triangular inverse are how hybrid_ops.kda_chunked reaches it,
+    which a trace's time holds and this count does not."""
+    k, v = _slot_shape(ins, "K"), _slot_shape(ins, "V")
+    if k is None or v is None:
+        return None
+    return 3 * 2.0 * _nelems(k) * v[3]
+
+
 def _experts_flops(ins, outs, attrs):
     """moe_experts: the up and down products (and the gate product of
     gated experts, given as WGate) over the EXPECTED rows routed to the
@@ -179,7 +191,8 @@ def _conv1d_flops(ins, outs, attrs):
 
 # ops/hybrid_ops.py: forward flops from the op's concrete shapes
 _HYBRID_COST = {"ssd_scan": _scan_flops, "moe_experts": _experts_flops,
-                "moe_router": _router_flops, "causal_conv1d": _conv1d_flops}
+                "moe_router": _router_flops, "causal_conv1d": _conv1d_flops,
+                "kda_scan": _kda_flops}
 
 # flops per parameter element for the bucketed fused optimizer applies
 # (ops/fusion.py): sgd = mul+sub; momentum adds the velocity update;

@@ -924,6 +924,9 @@ METRIC_CATALOG = {
     "attention_window_total": _m("counter", ("window",),
                                  "forward attention lowerings under a "
                                  "sliding window, by its keys"),
+    "kda_scan_total": _m("counter", ("chunk", "path"),
+                         "forward kda_scan lowerings, by the delta rule's "
+                         "chunk length and the path taken"),
     "activation_kept_total": _m("counter", ("act",),
                                 "lowerings of an activation evaluated once "
                                 "and kept (ops/math_ops.py KEPT_ACTS)"),

@@ -1145,36 +1145,74 @@ class _Module:
                 r = by_name.get(r.operands[0])
             return r
 
+        def updated_in_place(r):
+            """(the operand a dynamic-update-slice updates in place, the
+            update's bytes) for a result that is one, behind bitcasts."""
+            r = behind_bitcasts(r)
+            if r is None or r.opcode != "dynamic-update-slice" \
+                    or len(r.operands) < 2:
+                return None
+            base = behind_bitcasts(by_name.get(r.operands[0]))
+            if base is None or base.opcode != "parameter":
+                return None
+            return base.name, _hbm(shapes.get(r.operands[1], ""))
+
         root = next((r for r in raws if r.root), raws[-1] if raws else None)
-        in_place = None
-        if root is not None and root.opcode == "tuple":
-            made = [by_name.get(o) for o in root.operands]
-            out = sum(_hbm(r.shape) for r in made if r is not None
-                      and r.opcode != "parameter"
-                      and collective_kind(r.opcode) is None)
-        root = behind_bitcasts(root)
-        if root is not None and root.opcode == "dynamic-update-slice" \
-                and len(root.operands) > 1:
-            base = behind_bitcasts(by_name.get(root.operands[0]))
-            if base is not None and base.opcode == "parameter":
-                in_place = base.name
-                out = _hbm(shapes.get(root.operands[1], ""))
+        # each result by itself: one of several may be an update in place
+        # (a scan's stacked output written beside a product)
+        tupled = root is not None and root.opcode == "tuple"
+        results = [by_name.get(o) for o in root.operands] if tupled \
+            else [root]
+        updates = [updated_in_place(r) for r in results]
+        in_place = {u[0] for u in updates if u}
+        if tupled or in_place:
+            out = sum(u[1] if u else _hbm(r.shape)
+                      for r, u in zip(results, updates)
+                      if u or (r is not None and r.opcode != "parameter"
+                               and collective_kind(r.opcode) is None))
         for index, pname in numbered.items():
             if index >= len(reads):
                 continue
             uses = users.get(pname, ())
-            if pname == in_place and len(uses) == 1:
+            if pname in in_place and len(uses) == 1:
                 reads[index] = 0
             elif uses and all(u.opcode == "tuple"
                               or collective_kind(u.opcode) is not None
                               for u in uses):
                 reads[index] = 0
-            elif uses and all(u.opcode in ("slice", "dynamic-slice", "gather")
-                              and u.operands and u.operands[0] == pname
-                              for u in uses):
-                reads[index] = min(
-                    reads[index], sum(_hbm(u.shape) for u in uses))
+            else:
+                sliced = self._sliced_bytes(cname, pname)
+                if sliced is not None:
+                    reads[index] = min(reads[index], sliced)
         return reads, out
+
+    def _sliced_bytes(self, cname, pname):
+        """Bytes the fused computation `cname` reads of its parameter
+        `pname` where all it does with it is slice it, itself or in the
+        fusions nested in it (a loop body's product whose operand fusion
+        picks one step's row of a scan's stacked input); None where
+        anything reads it whole."""
+        raws = self.comps[cname]
+        uses = [r for r in raws if pname in r.operands]
+        total = 0
+        for use in uses:
+            if use.opcode in ("slice", "dynamic-slice", "gather") \
+                    and use.operands[0] == pname:
+                total += _hbm(use.shape)
+                continue
+            inner = self.called(use) if use.opcode == "fusion" else ()
+            if len(inner) != 1:
+                return None
+            by_index = {r.operands[0]: r.name for r in self.comps[inner[0]]
+                        if r.opcode == "parameter" and r.operands}
+            for at, operand in enumerate(use.operands):
+                if operand != pname:
+                    continue
+                nested = self._sliced_bytes(inner[0], by_index.get(str(at)))
+                if nested is None:
+                    return None
+                total += nested
+        return total if uses else None
 
 
 def _parse_groups(text: Optional[str], pairs: Optional[str]):

@@ -507,6 +507,43 @@ class TestLrn(OpTest):
         self.check_grad(["X"], "Out", max_relative_error=0.02)
 
 
+class TestKdaScan(OpTest):
+    op_type = "kda_scan"
+
+    def test(self):
+        """The gated delta rule with a decay a channel against its
+        recurrence token by token in numpy, 12 tokens in chunks of 4
+        (tests/test_kda_moe.py holds the chunked form to the recurrence
+        at the published decays; kda_scan_grad is executed here too)."""
+        bsz, t, h, k, v = 1, 12, 2, 4, 3
+        q, key, gate = (RNG.randn(bsz, t, h, k).astype("float32")
+                        for _ in range(3))
+        val = RNG.randn(bsz, t, h, v).astype("float32")
+        a_log = np.log(RNG.uniform(1, 4, h)).astype("float32")
+        dt_bias = RNG.randn(h * k).astype("float32")
+        beta = RNG.randn(bsz, t, h).astype("float32")
+        eps = 1e-6
+        qn, kn = (x / np.sqrt((x * x).sum(-1, keepdims=True) + eps)
+                  for x in (q, key))
+        g = -np.exp(a_log)[:, None] * np.log1p(np.exp(
+            gate + dt_bias.reshape(h, k)))
+        out = np.zeros_like(val)
+        state = np.zeros((bsz, h, k, v))
+        for i in range(t):
+            state = state * np.exp(g[:, i])[..., None]
+            seen = np.einsum("bhk,bhkv->bhv", kn[:, i], state)
+            state = state + _sigmoid(beta[:, i])[..., None, None] \
+                * kn[:, i][..., None] * (val[:, i] - seen)[..., None, :]
+            out[:, i] = np.einsum("bhk,bhkv->bhv", qn[:, i], state) / 2.0
+        self.inputs = {"Q": q, "K": key, "V": val, "Gate": gate,
+                       "ALog": a_log, "DtBias": dt_bias, "Beta": beta}
+        self.attrs = {"chunk_size": 4, "epsilon": eps}
+        self.outputs = {"Out": out.astype("float32")}
+        self.check_output(rtol=1e-4, atol=1e-5)
+        self.check_grad(["Q", "K", "V", "Gate", "Beta"], "Out",
+                        max_relative_error=0.02)
+
+
 class TestNormOp(OpTest):
     op_type = "norm"
 

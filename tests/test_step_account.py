@@ -124,6 +124,80 @@ def test_float32_product_at_highest_precision_runs_six_passes():
     assert half.mxu_flops == half.flops     # bf16 operands: one pass
 
 
+def test_a_scans_stacked_output_beside_a_product_costs_the_update():
+    """A loop body's fusion that gives a product AND writes one step's
+    row of the scan's stacked output in place (a tuple root, one result a
+    dynamic-update-slice of an operand): the stacked array costs the
+    update, not its 32 rows read and written (PR 55: the delta rule's
+    walk over the chunks read 108 % of its roofline with the whole array
+    charged to every step)."""
+    fused = """\
+%fused (p0: x, p1: x, p2: x, p3: x) -> x {
+  %p0 = bf16[32,64,128]{2,1,0} parameter(0)
+  %p1 = s32[] parameter(1)
+  %p2 = bf16[64,128]{1,0} parameter(2)
+  %p3 = bf16[128,128]{1,0} parameter(3)
+  %zero = s32[] constant(0)
+  %dot.1 = f32[64,128]{1,0} dot(%p2, %p3), lhs_contracting_dims={1}, rhs_contracting_dims={0}
+  %half = bf16[64,128]{1,0} convert(%dot.1)
+  %row = bf16[1,64,128]{2,1,0} bitcast(%half)
+  %dus = bf16[32,64,128]{2,1,0} dynamic-update-slice(%p0, %row, %p1, %zero, %zero)
+  ROOT %both = (bf16[32,64,128]{2,1,0}, f32[64,128]{1,0}) tuple(%dus, %dot.1)
+}
+"""
+    text = _entry("""\
+  %stack = bf16[32,64,128]{2,1,0} parameter(0)
+  %i = s32[] parameter(1)
+  %a = bf16[64,128]{1,0} parameter(2)
+  %b = bf16[128,128]{1,0} parameter(3)
+  ROOT %fusion.1 = (bf16[32,64,128]{2,1,0}, f32[64,128]{1,0}) fusion(%stack, %i, %a, %b), kind=kOutput, calls=%fused""", fused)
+    got = _only(text, "fusion.1")
+    assert got.heavy == "dot"
+    row, product = 2 * 64 * 128, 4 * 64 * 128
+    assert got.bytes == 4 + 2 * 64 * 128 + 2 * 128 * 128 + row + product
+
+
+def test_an_operand_sliced_inside_a_nested_fusion_costs_the_slice():
+    """A loop body's product whose operand fusion picks one step's row of
+    a scan's stacked input: the fusion reads the row, wherever inside it
+    the slice is taken; an operand a nested fusion reads whole is
+    charged whole."""
+    fused = """\
+%pick (q0: x, q1: x) -> x {
+  %q0 = bf16[32,128,128]{2,1,0} parameter(0)
+  %q1 = s32[] parameter(1)
+  %zero = s32[] constant(0)
+  %ds = bf16[1,128,128]{2,1,0} dynamic-slice(%q0, %q1, %zero, %zero), dynamic_slice_sizes={1,128,128}
+  ROOT %one = bf16[128,128]{1,0} bitcast(%ds)
+}
+
+%whole (r0: x) -> x {
+  %r0 = bf16[32,128,128]{2,1,0} parameter(0)
+  %c = bf16[] constant(0)
+  ROOT %sum = bf16[128,128]{1,0} reduce(%r0, %c), dimensions={0}, to_apply=%region
+}
+
+%fused (p0: x, p1: x, p2: x) -> x {
+  %p0 = bf16[32,128,128]{2,1,0} parameter(0)
+  %p1 = s32[] parameter(1)
+  %p2 = bf16[64,128]{1,0} parameter(2)
+  %row = bf16[128,128]{1,0} fusion(%p0, %p1), kind=kLoop, calls=%PICK
+  ROOT %dot.1 = f32[64,128]{1,0} dot(%p2, %row), lhs_contracting_dims={1}, rhs_contracting_dims={0}
+}
+"""
+    body = """\
+  %stack = bf16[32,128,128]{2,1,0} parameter(0)
+  %i = s32[] parameter(1)
+  %a = bf16[64,128]{1,0} parameter(2)
+  ROOT %fusion.1 = f32[64,128]{1,0} fusion(%stack, %i, %a), kind=kOutput, calls=%fused"""
+    rest = 4 + 2 * 64 * 128 + 4 * 64 * 128
+    got = _only(_entry(body, fused.replace("%PICK", "%pick")), "fusion.1")
+    assert got.bytes == 2 * 128 * 128 + rest
+    got = _only(_entry(body, fused.replace("%PICK", "%whole").replace(
+        "fusion(%p0, %p1), kind", "fusion(%p0), kind")), "fusion.1")
+    assert got.bytes == 32 * 2 * 128 * 128 + rest
+
+
 def test_mosaic_call_elementwise_reduce_and_copies():
     text = _entry("""\
   %p = bf16[4,128,256]{2,1,0:T(8,128)(2,1)} parameter(0)

@@ -764,6 +764,62 @@ def test_window_step_compiles_with_both_kinds_of_layer(mosaic, one_chip):
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 15.75e9
 
 
+KDA_CELL = "kimi-linear.train-kda-t8192-ep32-share"
+
+
+def test_delta_rule_step_compiles_with_both_kinds_of_mixer(mosaic, one_chip):
+    """The Kimi-Linear cell's step at its own 8192-token sequence and
+    published widths, the depth cut to two layers, one of each kind (a
+    KDA mixer before the dense feed-forward, latent attention before an
+    expert layer; the five take five minutes here; tools/describe_step.py
+    sized them, PR 55: 7.91e9 B of temporaries + 7.23e9 B of aliased
+    state): latent attention at keys 192 wide beside values 128 wide on
+    the flash kernels at one 256-lane block a head with the fused
+    backward; the experts on gmm / tgmm under the ladder's one switch
+    each way (8 of 256 held: two rungs); the delta rule chunked, its
+    loops over chunks and none over tokens: every loop under the op
+    carries the [H, K, V] state, and nothing of [., T, T] reaches HBM
+    under either mixer."""
+    from paddle_tpu import xplane
+    cell = run.load_json("workloads", KDA_CELL)
+    config = run.load_json("configs", cell["config"])
+    config = dict(config, num_hidden_layers=2, linear_attn_config=dict(
+        config["linear_attn_config"], kda_layers=[1], full_attn_layers=[2]))
+    compiled = describe_step.compile_step(cell, config, one_chip)
+    text = compiled.as_text()
+    kernels = [re.search(r'op_name="[^"]*?(\w+)\)*/pallas_call', line).group(1)
+               for line in text.splitlines() if KERNEL in line]
+    flash = {k: kernels.count(k) for k in set(kernels) if "flash" in k}
+    assert flash == {"flash_fwd": 1, "flash_dkv": 1}
+    assert "gmm" in kernels and "tgmm" in kernels
+    # the token side, forward and pulled back, in each of the two rungs
+    assert kernels.count("pair_sum") == 2 * 2
+    assert "pd.moe_experts/cond" in text
+    tokens = config["sequence_length"]
+    heads = config["linear_attn_config"]["num_heads"]
+    width = config["linear_attn_config"]["head_dim"]
+    loops = [i for i in xplane.hlo_instructions(text)
+             if i.opcode == "while" and "pd.kda_scan" in (i.op_name or "")]
+    # a pass is ONE loop over the 128 chunks: the forward's, the replayed forward's where
+    # the compiler has not merged it with the gradient's, and the
+    # gradient's two (forward keeping a state a chunk, then backwards,
+    # each chunk computed again ahead of its pull-back)
+    assert len(loops) in (1 + 2, 1 + 1 + 2), [i.op_name for i in loops]
+    for loop in loops:      # every loop carries a [H, K, V] state
+        assert f"{heads},{width},{width}" in loop.shape.replace(" ", ""), \
+            loop.shape
+    widest = tokens * config["num_attention_heads"] * 256
+    for kind in ("kda_mixer", "latent_attention"):
+        instrs = [i for i in xplane.hlo_instructions(text)
+                  if "pd_scope." + kind in (i.op_name or "")]
+        assert len(instrs) > 4, kind
+        for instr in instrs:
+            assert xplane.first_array(instr.shape)[0] <= 4 * widest, (
+                instr.name, instr.shape)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 15.75e9
+
+
 GRANITE_CELL = "granite-4.0-h-micro.train-ssm-recompute"
 
 
