@@ -27,7 +27,13 @@ its core is ssd_scan_chunked under a jax.checkpoint, which keeps the
 op's inputs and recomputes the blocks as XLA arrays. The expert layer's
 grouped products are Pallas calls too; the chip's compiler merges a
 re-traced forward kernel with the original (16 gmm runs a step of the
-hybrid cell, not 24, PERF.md section 6, PR 36; 4 ssd_scan_fwd, not 8). A layer that holds a sixteenth of the
+hybrid cell, not 24, PERF.md section 6, PR 36; 4 ssd_scan_fwd, not 8). The
+delta rule (kda_scan) likewise: where a head is a lane block
+(kda_scan_ineligible) the kernels of ops/pallas_kda.py under one
+jax.custom_vjp that keeps the op's inputs alone (the gradient runs the
+forward kernel again for the state entering each chunk and each chunk's
+inverse, then the backward kernel; PERF.md section 6, PR 56), elsewhere
+kda_chunked and autodiff's gradient of it. A layer that holds a sixteenth of the
 experts or less handles its rows inside a capacity chosen on the device
 (_capacity_ladder) and carries a rule of its own inside the lowering
 (_handle_routed_rows, a jax.custom_vjp as nn_ops._hard_label_nll is),
@@ -393,7 +399,9 @@ def kda_chunked(q, k, v, g, beta, chunk, dtype=jnp.float32):
     55): 8.9 | 25.5 at chunks of 64 (9.1 | 27.0 at 32); held over more
     than its own chunk the gradient's working set leaves the chip: 9.6 |
     27.4 with four chunks of 64 kept at once, 10.0 | 38.0 with sixteen,
-    16.4 | 53.2 with sixty-four.
+    16.4 | 53.2 with sixty-four. The whole op around it (kda_scan_chunked
+    from bf16 operands) 10.35 | 27.79 at chunks of 64, where the kernels
+    of ops/pallas_kda.py read 5.93 | 10.80 (PR 56).
 
     q, k [B, T, H, K] (unit rows: the caller's L2 norm), v [B, T, H, V],
     g [B, T, H, K] (<= 0) and beta [B, T, H] in float32. T need not be a
@@ -494,6 +502,42 @@ def _kda_infer(op_, block):
         set_out(op_, block, "Out", list(v.shape), v.dtype)
 
 
+_KDA_OP = "kda_scan"
+
+
+def kda_scan_ineligible(chunk: int, k: int, v: int):
+    """None when the delta rule's kernels (ops/pallas_kda.py) take chunks
+    of `chunk` tokens and heads of K = `k` and V = `v` channels, else the
+    reason kda_chunked keeps the op (kernel_choice.REASONS["kda_scan"]).
+    A head is one lane block of its projection's [T, H x K] rows: K must
+    be 128 (Mosaic aborts on a row of a [., 256] array, as it did for
+    the scan kernels' N: PR 40) and V whole lane blocks (`width`); the
+    chunk's system is solved by doubling from sub-blocks of _KDA_SUB
+    rows, a power of two of them (`chunk`). Any number of heads and any
+    T tile: a step owns pallas_kda.heads_a_step of the heads, and a tail
+    is padded."""
+    if k != 128 or v % 128:
+        return "width"
+    if chunk % _KDA_SUB or chunk & (chunk - 1):
+        return "chunk"
+    return None
+
+
+def kda_scan_chunked(q, k, v, gate, a_log, dt_bias, beta, chunk, eps,
+                     dtype=jnp.float32):
+    """The op kda_scan in jax.numpy around kda_chunked (the op's
+    docstring has the equations): the norm, g and beta in float32
+    whatever the inputs are, so autodiff carries a_log and dt_bias."""
+    q, k = (x * lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + eps)
+            for x in (_f32(q), _f32(k)))
+    heads, width = q.shape[2], q.shape[3]
+    g = -jnp.exp(_f32(a_log))[:, None] * jax.nn.softplus(
+        _f32(gate) + _f32(dt_bias).reshape(heads, width))
+    out = kda_chunked(q, k, v, g, jax.nn.sigmoid(_f32(beta)), chunk,
+                      dtype=dtype)
+    return (out * width ** -0.5).astype(v.dtype)
+
+
 @op("kda_scan", infer_shape=_kda_infer)
 def _kda_scan(ctx, op_, ins):
     """Kimi Delta Attention between its short convolutions and its gated
@@ -506,39 +550,50 @@ def _kda_scan(ctx, op_, ins):
         beta = sigmoid(Beta)
         Out = kda_chunked(q, k, V, g, beta, `chunk_size`) / sqrt(K)
 
-    The norm, g and beta are float32 whatever the inputs are, and
-    jax.numpy around the chunked core, so autodiff carries ALog and
-    DtBias; Out has V's dtype. One path, XLA's: the chunked form
-    (kda_chunked) and autodiff's gradient of it (each chunk computed again
-    ahead of its pull-back; the triangular inverse's gradient its own);
-    kda_scan_total{chunk, path} books each forward lowering, `chunked` a
-    first forward's and `chunked_replay` that of an op a recomputed
+    The norm, g and beta are float32 whatever the inputs are; Out has V's
+    dtype. What runs is chosen from the shapes (kda_scan_ineligible): the
+    Pallas kernels of ops/pallas_kda.py, forward and gradient, which read
+    Q, K, V and Gate as [B, T, H x K] in the dtype they arrive in, form
+    the norms, g and each chunk's system in VMEM and keep a head's state
+    there across the walk (interpreted off the chip;
+    pallas_kernel_total{op="kda_scan"}), or kda_scan_chunked, XLA's (the
+    chunked form and autodiff's gradient of it, each chunk computed again
+    ahead of its pull-back; the triangular inverse's gradient its own),
+    booked with the reason (pallas_fallback_total). Both take the same
+    operands at the same precision. kda_scan_total{chunk, path} books
+    each forward lowering: `kernel` or `chunked` a first forward's,
+    `kernel_replay` or `chunked_replay` that of an op a recomputed
     segment runs again (a gradient's re-trace books nothing)."""
-    q, k = (_f32(ins[slot][0]) for slot in ("Q", "K"))
-    v = jnp.asarray(ins["V"][0])
-    eps = op_.attr("epsilon", 1e-6)
-    q, k = (x * lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + eps)
-            for x in (q, k))
-    heads, width = q.shape[2], q.shape[3]
-    bias = _f32(ins["DtBias"][0]).reshape(heads, width)
-    g = -jnp.exp(_f32(ins["ALog"][0]))[:, None] \
-        * jax.nn.softplus(_f32(ins["Gate"][0]) + bias)
-    beta = jax.nn.sigmoid(_f32(ins["Beta"][0]))
+    from .pallas_attention import _interpret
+    from .pallas_kda import kda_scan_kernels
+
+    operands = [jnp.asarray(ins[slot][0]) for slot in (
+        "Q", "K", "V", "Gate", "ALog", "DtBias", "Beta")]
     chunk = op_.attr("chunk_size", 64)
+    reason = kda_scan_ineligible(chunk, operands[0].shape[3],
+                                 operands[2].shape[3])
+    kernel_choice.book(_KDA_OP, reason)
     if not kernel_choice.in_retrace():
         from .. import telemetry
         from ..backward import RECOMPUTE_ATTR
+        path = "kernel" if reason is None else "chunked"
+        if RECOMPUTE_ATTR in op_.desc.attrs:
+            path += "_replay"
         telemetry.counter(
             "kda_scan_total",
             "lowerings of a forward kda_scan op, by its chunk length and "
-            "the path taken (`chunked`: XLA's, the one there is; "
-            "`chunked_replay`: the same, run again by a recomputed segment)",
-            labels=("chunk", "path")).labels(
-                chunk=str(chunk),
-                path="chunked_replay" if RECOMPUTE_ATTR in op_.desc.attrs
-                else "chunked").inc()
-    out = kda_chunked(q, k, v, g, beta, chunk, dtype=_compute_dtype(ctx))
-    return {"Out": [(out * width ** -0.5).astype(v.dtype)]}
+            "the path taken (`kernel`: ops/pallas_kda.py's; `chunked`: "
+            "XLA's; `kernel_replay`, `chunked_replay`: the same, run "
+            "again by a recomputed segment)",
+            labels=("chunk", "path")).labels(chunk=str(chunk),
+                                             path=path).inc()
+    shared = dict(chunk=chunk, eps=op_.attr("epsilon", 1e-6),
+                  dtype=_compute_dtype(ctx))
+    if reason is None:
+        out = kda_scan_kernels(*operands, interpret=_interpret(), **shared)
+    else:
+        out = kda_scan_chunked(*operands, **shared)
+    return {"Out": [out]}
 
 
 # --- router ------------------------------------------------------------------

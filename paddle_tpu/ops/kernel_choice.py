@@ -31,6 +31,10 @@ REASONS = {
     "scaled_dot_product_attention": _ATTENTION,
     "block_diffusion_attention": _ATTENTION,
     "ssd_scan": frozenset({"chunk", "state", "heads"}),
+    # the delta rule (ops/pallas_kda.py, forward and gradient): a head
+    # one lane block of K and whole ones of V, the chunk a power of two
+    # of 16-row sub-blocks; else hybrid_ops.kda_scan_chunked keeps the op
+    "kda_scan": frozenset({"width", "chunk"}),
     "moe_experts": frozenset({"rows", "width"}),
     # moe_experts' second choice, the token side's kernel (WITHIN)
     "pair_sum": frozenset({"width", "tokens", "rows", "experts"}),
@@ -45,6 +49,7 @@ GATES = {
     "scaled_dot_product_attention": _ATTENTION_GATE,
     "block_diffusion_attention": _ATTENTION_GATE,
     "ssd_scan": ("hybrid_ops.ssd_scan_ineligible",),
+    "kda_scan": ("hybrid_ops.kda_scan_ineligible",),
     "moe_experts": ("hybrid_ops.gmm_ineligible",),
     "pair_sum": ("pallas_pair_sum.ineligible",),
 }

@@ -26,7 +26,7 @@ from paddle_tpu import executor as executor_mod
 from paddle_tpu import telemetry
 from paddle_tpu.framework import unique_name
 from paddle_tpu.framework.framework import NAME_SCOPE_ATTR, grad_var_name
-from paddle_tpu.ops import hybrid_ops
+from paddle_tpu.ops import hybrid_ops, pallas_kda
 
 from benchmarks import run
 from test_nemotron_h import close, first_step, run_op
@@ -96,17 +96,35 @@ def scan_inputs(strong, bsz=2, seqlen=200, heads=3, width=16, value=8):
         "Beta": normal(bsz, seqlen, heads)}
 
 
+# the shapes that take each path of the op (hybrid_ops.kda_scan_ineligible):
+# heads of 16 and 8 channels XLA's chunked form, a lane block each the
+# kernels of ops/pallas_kda.py, interpreted here
+PATHS = {"chunked": dict(), "kernel": dict(bsz=1, heads=2, width=128,
+                                           value=128)}
+
+
+def booked_paths():
+    return dict(telemetry.read_series("kda_scan_total"))
+
+
+@pytest.mark.parametrize("path", PATHS)
 @pytest.mark.parametrize("strong", [False, True], ids=["mild", "strong"])
 @pytest.mark.parametrize("chunk", [32, 64])
-def test_chunked_delta_rule_matches_the_recurrence(chunk, strong):
+def test_chunked_delta_rule_matches_the_recurrence(chunk, strong, path):
     """Out to 2e-5 and the gradient of every one of the op's seven
     inputs to 1e-4 of its largest entry, at 200 tokens (several chunks
     and a padded tail), against jax.grad of the recurrence; at the strong
-    decays a chunk of 64 sums to -102 and exp(-G) alone would be inf."""
-    ins = scan_inputs(strong)
+    decays a chunk of 64 sums to -102 and exp(-G) alone would be inf.
+    By `path`: XLA's chunked form, and the kernels with their hand-written
+    gradient (the float32 state, sums, exponents and inverse are what
+    2e-5 holds: a state or a summed decay in bf16 reads 2.5e-3)."""
+    ins = scan_inputs(strong, **PATHS[path])
     eps = 1e-6
+    before = booked_paths()
     got, grads, cot = run_op("kda_scan", ins, {"Out": "float32"},
                              {"chunk_size": chunk, "epsilon": eps}, SLOTS)
+    label = f"chunk={chunk},path={path}"
+    assert booked_paths().get(label, 0) > before.get(label, 0)
     args = [jnp.asarray(ins[s]) for s in SLOTS]
     want, g = recurrence(*args, eps)
     if strong:      # a chunk of 64 tokens' summed log-decay, a channel
@@ -120,6 +138,53 @@ def test_chunked_delta_rule_matches_the_recurrence(chunk, strong):
         assert np.isfinite(grads[slot]).all(), slot
         assert float(jnp.abs(g_ref).max()) > 0, slot
         close(grads[slot], g_ref, tol=1e-4)
+
+
+def kernels(q, k, v, gate, a_log, dt_bias, beta, chunk, dtype=jnp.float32,
+            eps=1e-6, **how):
+    return pallas_kda.kda_scan_kernels(q, k, v, gate, a_log, dt_bias, beta,
+                                       chunk, eps, dtype=dtype,
+                                       interpret=True, **how)
+
+
+def with_gradients(fn, args, cot=None):
+    out, vjp = jax.vjp(fn, *args)
+    return (out,) + vjp(jnp.ones_like(out) if cot is None else cot)
+
+
+@pytest.mark.parametrize("strong", [False, True], ids=["mild", "strong"])
+def test_the_kernels_are_the_chunked_form(strong):
+    """The op on the kernels against the op on kda_chunked (jax.vjp of
+    XLA's form) on the same float32 inputs, 200 tokens in chunks of 64
+    with a padded tail, two heads in one grid step and a head a step: Out
+    and the gradients of q, k, v, the gate and beta to 1e-5 of the
+    largest entry, A_log's and dt_bias', which sum a cotangent over every
+    token, to 1e-4."""
+    ins = scan_inputs(strong, **PATHS["kernel"])
+    args = [jnp.asarray(ins[s]) for s in SLOTS]
+    cot = jnp.asarray(np.random.default_rng(1).standard_normal(
+        ins["V"].shape), jnp.float32)
+    want = with_gradients(lambda *a: hybrid_ops.kda_scan_chunked(
+        *a, 64, 1e-6), args, cot)
+    for heads in (2, 1):
+        got = with_gradients(lambda *a: kernels(*a, 64, heads=heads), args,
+                             cot)
+        for name, g, w in zip(("Out",) + SLOTS, got, want):
+            assert float(jnp.abs(w).max()) > 0, name
+            close(g, w, tol=1e-4 if name in ("ALog", "DtBias") else 1e-5)
+
+
+def test_the_kernels_carry_the_state():
+    """200 tokens through the kernels in chunks of 32 (seven grid steps
+    with the state in scratch, the tail padded) and as one chunk of 256
+    that carries nothing: the same output and the same seven gradients
+    to float32's rounding."""
+    ins = scan_inputs(False, **PATHS["kernel"])
+    args = [jnp.asarray(ins[s]) for s in SLOTS]
+    for got, want in zip(
+            with_gradients(lambda *a: kernels(*a, 32), args),
+            with_gradients(lambda *a: kernels(*a, 256), args)):
+        close(got, want, tol=1e-5)
 
 
 def test_chunks_carry_the_state():
@@ -191,6 +256,74 @@ def test_the_state_and_the_decays_stay_float32_under_bf16_operands():
     assert seen == {"exp": 5, "cumsum": 1, "full": 10, "bf16": 7}, seen
 
 
+def test_the_kernels_keep_float32_where_the_chunked_form_does():
+    """The same, read from the kernels' own traced programs (the
+    pallas_calls of a forward and its gradient: the forward kernel, whose
+    entering states and inverses the rule keeps, and the backward kernel)
+    with bf16 operands: each call's scratch is the float32 [R, V, K]
+    state or its cotangent; every exponent, logarithm and reciprocal root
+    is taken of a float32; the running sum is a float32 product at full
+    precision with the triangle of ones; the inverse's levels and its
+    pull-back (-M^T dM M^T) are float32 x float32 at full precision;
+    every other product takes bf16 operands and every product's result
+    is float32; and the entering states and inverses cross from the forward
+    kernel to the backward kernel in float32."""
+    ins = scan_inputs(False, seqlen=64, **PATHS["kernel"])
+    args = [jnp.asarray(ins[s], jnp.bfloat16 if s in ("Q", "K", "V", "Gate",
+                                                      "Beta") else None)
+            for s in SLOTS]
+    traced = jax.make_jaxpr(lambda *a: with_gradients(
+        lambda *b: kernels(*b, 32, dtype=jnp.bfloat16), a))(*args).jaxpr
+    calls = [e for e in _equations(traced) if e.primitive.name == "pallas_call"]
+    assert [e.params["name"] for e in calls] == ["kda_scan_fwd",
+                                                 "kda_scan_bwd"]
+    # the two heads' systems of a chunk of 32 rows are one pack: the
+    # running sum and the inverse's two products a level above the
+    # second (the first two are written out on the VPU, in float32); in
+    # the backward the running sum, its transpose and the inverse's
+    # pull-back
+    full = {"kda_scan_fwd": 1 + 2 * 3, "kda_scan_bwd": 1 + 1 + 2}
+    for call in calls:
+        name, body = call.params["name"], call.params["jaxpr"]
+        scratch = body.invars[-1].aval
+        assert (scratch.shape, scratch.dtype) == ((2, 128, 128), jnp.float32)
+        seen = {"full": 0, "bf16": 0, "exp": 0}
+        for eqn in _equations(body):
+            prim = eqn.primitive.name
+            if prim in ("exp", "log1p", "rsqrt", "logistic", "reduce_sum"):
+                assert eqn.invars[0].aval.dtype == jnp.float32, eqn
+                seen["exp"] += prim == "exp"
+            elif prim == "dot_general":
+                assert eqn.outvars[0].aval.dtype == jnp.float32, eqn
+                kinds = {v.aval.dtype for v in eqn.invars}
+                if kinds == {jnp.dtype("float32")}:
+                    assert "HIGHEST" in str(eqn.params["precision"]), eqn
+                    seen["full"] += 1
+                else:
+                    assert kinds == {jnp.dtype("bfloat16")}, eqn
+                    seen["bf16"] += 1
+        assert seen["full"] == full[name], (name, seen)
+        assert seen["bf16"] >= 7 and seen["exp"] >= 5, (name, seen)
+    kept = [(v.aval.shape, v.aval.dtype) for v in calls[0].outvars[1:]]
+    assert kept == [((1, 2, 2, 128, 128), jnp.float32),
+                    ((1, 2, 1, 32, 64), jnp.float32)]
+    assert [(v.aval.shape, v.aval.dtype) for v in calls[1].invars[-2:]] == kept
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_nothing_overflows_in_the_kernels_at_the_strongest_decay(dtype):
+    """The kernels at chunks of 64 whose summed log-decay is -102 on
+    every channel: every value and every gradient finite in float32 and
+    with bf16 operands, and the bf16 result within 3 % of the float32
+    one's largest entry."""
+    ins = scan_inputs(True, seqlen=256, **PATHS["kernel"])
+    args = [jnp.asarray(ins[s]) for s in SLOTS]
+    got = with_gradients(lambda *a: kernels(*a, 64, dtype=jnp.dtype(dtype)),
+                         args)
+    assert all(bool(jnp.isfinite(x).all()) for x in got)
+    close(got[0], kernels(*args, 64), tol=3e-2)
+
+
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_nothing_overflows_at_the_strongest_published_decay(dtype):
     """kda_chunked itself at chunks of 64 whose summed log-decay is -102
@@ -228,6 +361,40 @@ def test_the_triangular_inverse_where_every_key_is_the_same():
         want = np.linalg.inv(np.eye(64) + a.astype(np.float64))
         got = hybrid_ops._unit_lower_inverse(jnp.asarray(a))
         assert float(np.abs(np.asarray(got) - want).max()) \
+            <= 1e-5 * max(1.0, float(np.abs(want).max()))
+
+
+def kernels_inverse(a, interpret=True):
+    """pallas_kda._inverse on [pack, C, C] strictly lower blocks, side by
+    side in one [C, pack C] array as the kernels hold them."""
+    from jax.experimental import pallas as pl
+    pack, c, _ = a.shape
+    packed = jnp.concatenate(list(jnp.asarray(a, jnp.float32)), axis=1)
+
+    def body(a_ref, out_ref):
+        _, _, row, col = pallas_kda._triangles(c, pack)
+        out_ref[...] = pallas_kda._inverse(
+            a_ref[...], row, col, pallas_kda._head_of_lane(packed.shape, c))
+
+    out = pl.pallas_call(body, out_shape=jax.ShapeDtypeStruct(
+        packed.shape, jnp.float32), interpret=interpret)(packed)
+    return np.asarray(out).reshape(c, pack, c).transpose(1, 0, 2)
+
+
+@pytest.mark.parametrize("pack,c,spread", [(2, 64, 1.0), (2, 32, 1.0),
+                                           (1, 128, 0.25)])
+def test_the_kernels_inverse_where_every_key_is_the_same(pack, c, spread):
+    """The same two systems through the kernels' own inverse (two heads
+    side by side in one array, the first level not formed, the second on
+    the VPU, the rest two full-precision products a level), against
+    numpy's inverse at 1e-5 (128 rows of N(0, 1) are a system no float32
+    solves: its inverse reaches 1e14; a quarter of that spread there)."""
+    rng = np.random.default_rng(2)
+    lower = np.tril(np.ones((c, c), np.float32), -1)
+    for a in (lower * (spread * rng.standard_normal((pack, c, c))).astype(
+            np.float32), 0.9 * np.broadcast_to(lower, (pack, c, c))):
+        want = np.linalg.inv(np.eye(c) + a.astype(np.float64))
+        assert float(np.abs(kernels_inverse(a) - want).max()) \
             <= 1e-5 * max(1.0, float(np.abs(want).max()))
 
 

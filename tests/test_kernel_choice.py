@@ -18,12 +18,13 @@ from test_nemotron_h import SCAN_ORDER, routed, scan_inputs
 from test_registry_lint import _load_checker
 
 OPS = ("conv2d", "scaled_dot_product_attention", "block_diffusion_attention",
-       "ssd_scan", "moe_experts", "pair_sum")
+       "ssd_scan", "kda_scan", "moe_experts", "pair_sum")
 
 
-def test_the_table_holds_the_five_ops_with_a_kernel():
-    """Five registered ops, and `pair_sum`: moe_experts' second choice,
-    a kernel booked under its own name WITHIN that op (PR 47)."""
+def test_the_table_holds_the_six_ops_with_a_kernel():
+    """Six registered ops (`kda_scan` since PR 56), and `pair_sum`:
+    moe_experts' second choice, a kernel booked under its own name WITHIN
+    that op (PR 47)."""
     assert tuple(kernel_choice.REASONS) == OPS == tuple(kernel_choice.GATES)
     assert kernel_choice.WITHIN == {"pair_sum": "moe_experts"}
 
@@ -161,6 +162,18 @@ def _scan():
                 wrt=SCAN_ORDER)
 
 
+def _delta_rule(width, value):
+    """kda_scan at heads of `width` and `value` channels: a lane block
+    each is the kernels', anything else XLA's chunked form."""
+    from test_kda_moe import SLOTS, scan_inputs as kda_inputs
+    return lambda: dict(
+        op_type="kda_scan",
+        inputs=kda_inputs(False, bsz=1, seqlen=64, heads=2, width=width,
+                          value=value),
+        outputs={"Out": "float32"},
+        attrs={"chunk_size": 32, "epsilon": 1e-6}, wrt=SLOTS)
+
+
 def _experts():
     rng = np.random.default_rng(3)
     n, d, f, k, held = 64, 128, 128, 4, 4
@@ -211,6 +224,10 @@ def _mul_o3():
 # but for conv2d, whose explicit gradient op asks no gate
 BOOKED = {
     "ssd_scan": (_scan, {"pallas_kernel_total": {"op=ssd_scan": 1}}),
+    "kda_scan": (_delta_rule(128, 128),
+                 {"pallas_kernel_total": {"op=kda_scan": 1}}),
+    "kda_scan_narrow": (_delta_rule(16, 8), {
+        "pallas_fallback_total": {"op=kda_scan,reason=width": 1}}),
     # its 64 tokens are no whole tile of the token side's kernel
     "moe_experts": (_experts, {
         "pallas_kernel_total": {"op=moe_experts": 1},
@@ -237,3 +254,20 @@ def test_a_gradients_retrace_of_the_forward_books_nothing(case):
     ops = _one_op(backward=True, **build())
     assert build()["op_type"] + "_grad" in ops
     assert _grown(before) == booked
+
+
+@pytest.mark.parametrize("chunk,k,v,reason", [
+    (64, 128, 128, None), (32, 128, 256, None), (256, 128, 128, None),
+    (64, 16, 16, "width"), (64, 128, 64, "width"), (64, 256, 128, "width"),
+    (48, 128, 128, "chunk"), (8, 128, 128, "chunk"), (96, 128, 128, "chunk")])
+def test_the_delta_rules_gate_reads_widths_and_the_chunk(chunk, k, v, reason):
+    """A head one lane block of K and whole blocks of V, and a chunk that
+    doubles up from sub-blocks of sixteen rows; heads and tokens of any
+    number tile (a step owns a divisor of the heads, fewer the longer
+    the chunk and the wider the operands; a tail is padded)."""
+    from paddle_tpu.ops import hybrid_ops, pallas_kda
+    assert hybrid_ops.kda_scan_ineligible(chunk, k, v) == reason
+    assert [pallas_kda.heads_a_step(h) for h in (1, 2, 3, 6, 32)] \
+        == [1, 2, 3, 6, 8]
+    assert [pallas_kda.heads_a_step(32, *how) for how in (
+        (64, 4), (128, 2), (256, 2), (256, 4))] == [4, 4, 2, 1]

@@ -360,6 +360,37 @@ def test_scan_kernels_compile_at_one_group_of_64_heads(mosaic, one_chip,
                     ((1, t, g, n), BF16)) == ["ssd_scan_bwd", "ssd_scan_fwd"]
 
 
+@pytest.mark.parametrize("chunk,dtype,heads", [
+    (64, BF16, 8), (64, jnp.float32, 4), (128, BF16, 4), (32, BF16, 8)],
+    ids=["kimi_cell", "float32_no_amp", "chunk_128", "chunk_32"])
+def test_delta_rule_kernels_compile(mosaic, one_chip, chunk, dtype, heads):
+    """The Kimi-Linear cell's delta rule, [1, 8192, 32 heads x 128] in
+    chunks of 64 with bf16 operands: the forward kernel and the
+    gradient's, each inside the 16 MB of scoped VMEM a Mosaic call has by
+    default at the heads a step pallas_kda.heads_a_step gives (sixteen
+    heads a step compile and are refused for VMEM when the gradient's
+    kernel is loaded on the chip: PR 56); without AMP the operands are
+    float32 and a step owns half the heads; a chunk of 128 is one head a
+    pack (its [128, 128] system fills a lane block alone), one of 32 two
+    heads in half a lane block."""
+    from paddle_tpu.ops import pallas_kda
+    t, h, k, v = 8192, 32, 128, 128
+    assert hybrid_ops.kda_scan_ineligible(chunk, k, v) is None
+    assert pallas_kda.heads_a_step(h, chunk, jnp.dtype(dtype).itemsize) \
+        == heads
+
+    def grads(*operands):
+        return jax.grad(lambda *a: pallas_kda.kda_scan_kernels(
+            *a, chunk, 1e-6, dtype=dtype).astype(jnp.float32).sum(),
+            argnums=tuple(range(7)))(*operands)
+
+    assert _compile(grads, one_chip, ((1, t, h, k), dtype),
+                    ((1, t, h, k), dtype), ((1, t, h, v), dtype),
+                    ((1, t, h, k), dtype), ((h,), jnp.float32),
+                    ((h * k,), jnp.float32), ((1, t, h), dtype)) \
+        == ["kda_scan_bwd", "kda_scan_fwd"]
+
+
 _HYBRID_ME = {}
 
 
@@ -776,10 +807,15 @@ def test_delta_rule_step_compiles_with_both_kinds_of_mixer(mosaic, one_chip):
     state): latent attention at keys 192 wide beside values 128 wide on
     the flash kernels at one 256-lane block a head with the fused
     backward; the experts on gmm / tgmm under the ladder's one switch
-    each way (8 of 256 held: two rungs); the delta rule chunked, its
-    loops over chunks and none over tokens: every loop under the op
-    carries the [H, K, V] state, and nothing of [., T, T] reaches HBM
-    under either mixer."""
+    each way (8 of 256 held: two rungs); the delta rule on the kernels of
+    ops/pallas_kda.py (PR 56): under the op and its gradient the forward
+    kernel twice (the first forward's; and the replayed one's, which is
+    the very call the gradient op's re-trace makes for the entering
+    states, so the compiler runs one for both: a third would be 5.7 ms a
+    layer and step on the chip) and the backward kernel once, NO loop, and nothing of [., T, T] under either mixer;
+    under the KDA mixer a float32 array of the projections' [T, H K]
+    reaches HBM from the short convolutions and the gated norm alone,
+    none from the op or its gradient."""
     from paddle_tpu import xplane
     cell = run.load_json("workloads", KDA_CELL)
     config = run.load_json("configs", cell["config"])
@@ -795,27 +831,30 @@ def test_delta_rule_step_compiles_with_both_kinds_of_mixer(mosaic, one_chip):
     # the token side, forward and pulled back, in each of the two rungs
     assert kernels.count("pair_sum") == 2 * 2
     assert "pd.moe_experts/cond" in text
+    delta = {k: kernels.count(k) for k in set(kernels) if "kda" in k}
+    assert delta == {"kda_scan_fwd": 2, "kda_scan_bwd": 1}, delta
     tokens = config["sequence_length"]
-    heads = config["linear_attn_config"]["num_heads"]
-    width = config["linear_attn_config"]["head_dim"]
-    loops = [i for i in xplane.hlo_instructions(text)
-             if i.opcode == "while" and "pd.kda_scan" in (i.op_name or "")]
-    # a pass is ONE loop over the 128 chunks: the forward's, the replayed forward's where
-    # the compiler has not merged it with the gradient's, and the
-    # gradient's two (forward keeping a state a chunk, then backwards,
-    # each chunk computed again ahead of its pull-back)
-    assert len(loops) in (1 + 2, 1 + 1 + 2), [i.op_name for i in loops]
-    for loop in loops:      # every loop carries a [H, K, V] state
-        assert f"{heads},{width},{width}" in loop.shape.replace(" ", ""), \
-            loop.shape
+    instrs = list(xplane.hlo_instructions(text))
+    under_op = [i for i in instrs if "pd.kda_scan" in (i.op_name or "")]
+    assert under_op and not [i.name for i in under_op if i.opcode == "while"]
     widest = tokens * config["num_attention_heads"] * 256
     for kind in ("kda_mixer", "latent_attention"):
-        instrs = [i for i in xplane.hlo_instructions(text)
-                  if "pd_scope." + kind in (i.op_name or "")]
-        assert len(instrs) > 4, kind
-        for instr in instrs:
+        scoped = [i for i in instrs if "pd_scope." + kind in (i.op_name or "")]
+        assert len(scoped) > 4, kind
+        for instr in scoped:
             assert xplane.first_array(instr.shape)[0] <= 4 * widest, (
                 instr.name, instr.shape)
+    lin = config["linear_attn_config"]
+    wide = f"f32[1,{tokens},{lin['num_heads'] * lin['head_dim']}]"
+    heads = f"f32[1,{tokens},{lin['num_heads']},{lin['head_dim']}]"
+    float32 = {re.search(r"pd\.(\w+)", i.op_name.split("kda_mixer")[1]).group(1)
+               for i in instrs if "pd_scope.kda_mixer" in (i.op_name or "")
+               and any(x in i.shape.replace(" ", "") for x in (wide, heads))}
+    # the short convolutions take their map's result in float32 and work
+    # there, as their gradient and the gated norm do; the delta rule
+    # reads and writes bf16
+    assert float32 <= {"mul", "causal_conv1d", "causal_conv1d_grad",
+                       "rms_norm", "rms_norm_grad"}, float32
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 15.75e9
 

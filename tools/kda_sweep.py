@@ -1,21 +1,32 @@
-"""The gated delta rule alone on the chip: hybrid_ops.kda_chunked over
-chunk lengths, which is what a configuration's `kda_chunk_size` is
-written from.
+"""The op kda_scan alone on the chip, from the operands the mixer hands it
+to its output: XLA's chunked form (hybrid_ops.kda_scan_chunked) over chunk
+lengths, which is what a configuration's `kda_chunk_size` is written
+from, beside the kernels of ops/pallas_kda.py over the heads one grid
+step owns, which is what pallas_kda._HEADS is written from.
 
     chiprun -- python3 tools/kda_sweep.py [B T H K V] [--chunks 32,64,128]
+                        [--path chunked,kernel] [--heads-a-step 2,4,8]
                         [--dtype bfloat16] [--errors 1]
 
 Times the forward and forward + gradient (jax.vjp on a random cotangent)
-of (q, k, v, g, beta) -> o at one shape (default the Kimi-Linear cell's,
-[1, 8192, 32, 128, 128]) for each chunk length, with unit q and k and
-decays drawn as the mixer's initial A_log and dt_bias give them, and
-with `--errors 1` the largest error of the output and of the five
-gradients against the same function with float32 operands at chunk 64,
-over the largest entry. Sixteen calls are chained in one executable (a
-call alone is host dispatch on that machine, as tools/pair_sum_sweep.py
-found). One JSON line per reading goes to chiprun_out/kda_sweep.jsonl;
-chipless (`JAX_PLATFORMS=cpu`) give a tiny shape: `1 256 2 16 16
---chunks 16,32`.
+of (q, k, v, gate, A_log, dt_bias, beta) -> o at one shape (default the
+Kimi-Linear cell's, [1, 8192, 32, 128, 128]) for each path, chunk length
+and (the kernels) heads a step, with q, k, v, the gate and beta in
+`--dtype` as the projections write them, and decays drawn as the mixer's
+initial A_log and dt_bias give them; with `--errors 1` the largest error
+of the output and of the seven gradients against XLA's form with float32
+operands at chunk 64, over the largest entry. Sixteen calls are chained
+in one executable (a call alone is host dispatch on that machine, as
+tools/pair_sum_sweep.py found). One JSON line per reading goes to
+chiprun_out/kda_sweep.jsonl; chipless (`JAX_PLATFORMS=cpu`) give a tiny
+shape, which the kernels take interpreted where the gate admits it: `1
+256 2 16 16 --chunks 16,32 --path chunked`, `1 256 2 128 128 --chunks 64`.
+
+On a v5e at the default shape, bf16, forward | forward + gradient ms (my
+chip runs, PR 56): XLA's form 10.35 | 27.79 at chunk 64 (its core alone,
+kda_chunked on float32 unit operands, read 8.87 | 25.43 in PR 55); the
+kernels 6.11 | 11.13 at 4 heads a step and 5.93 | 10.80 at 8; 16 are
+refused for VMEM. The errors are in ops/pallas_kda.py's docstring.
 """
 
 import argparse
@@ -28,44 +39,45 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from paddle_tpu.ops.hybrid_ops import kda_chunked
+from paddle_tpu.ops import hybrid_ops, pallas_attention, pallas_kda
 from tools.flash_sweep import bench, report
 
 OUT = "chiprun_out/kda_sweep.jsonl"
 CHAINED = 16
+SLOTS = ("q", "k", "v", "gate", "a_log", "dt_bias", "beta")
 
 
-def inputs(bsz, t, h, k, v, seed=0):
-    """Unit q and k, v ~ N(0, 1), g = -A softplus(N(0, 0.5) + dt_bias)
-    with A in U(1, 16) a head and the step log-uniform in [0.001, 0.1] a
-    channel, beta in (0, 1), and a cotangent for o."""
+def inputs(bsz, t, h, k, v, dtype, seed=0):
+    """The op's seven operands and a cotangent for o: q, k, v ~ N(0, 1),
+    the gate ~ N(0, 0.5) and beta ~ N(0, 1) in `dtype`; A in U(1, 16) a
+    head and dt_bias the softplus' inverse of a step log-uniform in
+    [0.001, 0.1] a channel, float32."""
     rng = np.random.default_rng(seed)
 
-    def normal(*shape):
-        return rng.standard_normal(shape).astype(np.float32)
+    def normal(*shape, scale=1.0):
+        return jnp.asarray(scale * rng.standard_normal(shape), dtype)
 
-    q, key = (x / np.linalg.norm(x, axis=-1, keepdims=True)
-              for x in (normal(bsz, t, h, k), normal(bsz, t, h, k)))
-    step = np.exp(rng.uniform(np.log(0.001), np.log(0.1), (h, k)))
-    raw = 0.5 * normal(bsz, t, h, k) + np.log(np.expm1(step))
-    g = -rng.uniform(1, 16, (h, 1)) * np.logaddexp(0.0, raw)
-    beta = 1 / (1 + np.exp(-normal(bsz, t, h)))
-    return tuple(jnp.asarray(x, jnp.float32) for x in (
-        q, key, normal(bsz, t, h, v), g, beta, normal(bsz, t, h, v)))
+    step = np.exp(rng.uniform(np.log(0.001), np.log(0.1), h * k))
+    return (normal(bsz, t, h, k), normal(bsz, t, h, k), normal(bsz, t, h, v),
+            normal(bsz, t, h, k, scale=0.5),
+            jnp.asarray(np.log(rng.uniform(1, 16, h)), jnp.float32),
+            jnp.asarray(np.log(np.expm1(step)), jnp.float32),
+            normal(bsz, t, h), normal(bsz, t, h, v))
 
 
 def chained(fn, with_gradient):
     """CHAINED calls of `fn` in one executable, each reading the last
     one's output through v so that none is dropped or merged."""
-    def run(q, k, v, g, beta, do):
+    def run(q, k, v, gate, a_log, dt_bias, beta, do):
         def once(v_, _):
             if with_gradient:
-                out, vjp = jax.vjp(fn, q, k, v_, g, beta)
+                out, vjp = jax.vjp(fn, q, k, v_, gate, a_log, dt_bias, beta)
                 grads = vjp(do)
-                return v_ + 1e-6 * grads[2], out.sum() + sum(
-                    x.sum() for x in grads)
-            out = fn(q, k, v_, g, beta)
-            return v_ + 1e-6 * out, out.sum()
+                return v_ + (1e-6 * grads[2]).astype(v_.dtype), sum(
+                    x.astype(jnp.float32).sum() for x in (out,) + grads)
+            out = fn(q, k, v_, gate, a_log, dt_bias, beta)
+            return v_ + (1e-6 * out).astype(v_.dtype), \
+                out.astype(jnp.float32).sum()
         return jax.lax.scan(once, v, None, length=CHAINED)
     return run
 
@@ -74,34 +86,58 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("shape", nargs="*", type=int,
                     default=[1, 8192, 32, 128, 128])
-    ap.add_argument("--chunks", default="32,64,128")
+    ap.add_argument("--chunks", default="64")
+    ap.add_argument("--path", default="chunked,kernel")
+    ap.add_argument("--heads-a-step", default="2,4,8")
     ap.add_argument("--dtype", default="bfloat16")
     ap.add_argument("--errors", type=int, default=1)
     args = ap.parse_args()
     dtype = jnp.dtype(args.dtype)
-    args_ = inputs(*args.shape)
+    args_ = inputs(*args.shape, dtype)
+    eps = 1e-6
     os.makedirs(os.path.dirname(OUT), exist_ok=True)
 
-    def both(fn):
-        out, vjp = jax.vjp(fn, *args_[:5])
-        return (out,) + vjp(args_[5])
+    def both(fn, operands):
+        out, vjp = jax.vjp(fn, *operands[:7])
+        return (out,) + vjp(operands[7].astype(out.dtype))
 
+    forms = []
+    for chunk in map(int, args.chunks.split(",")):
+        for path in args.path.split(","):
+            if path == "chunked":
+                forms.append((dict(chunk=chunk, path=path), lambda *a, c=chunk:
+                              hybrid_ops.kda_scan_chunked(*a, c, eps, dtype)))
+                continue
+            reason = hybrid_ops.kda_scan_ineligible(chunk, *args.shape[3:])
+            if reason is not None:
+                print(f"chunk {chunk}: the gate declines ({reason})")
+                continue
+            for r in map(int, args.heads_a_step.split(",")):
+                if args.shape[2] % r:
+                    continue
+                forms.append((
+                    dict(chunk=chunk, path=path, heads_a_step=r),
+                    lambda *a, c=chunk, r=r: pallas_kda.kda_scan_kernels(
+                        *a, c, eps, dtype=dtype, heads=r,
+                        interpret=pallas_attention._interpret())))
     with open(OUT, "a") as log:
-        want = jax.jit(lambda: both(
-            lambda *a: kda_chunked(*a, 64)))() if args.errors else None
-        for chunk in map(int, args.chunks.split(",")):
-            fn = lambda *a, c=chunk: kda_chunked(*a, c, dtype=dtype)
-            row = dict(shape=args.shape, chunk=chunk, dtype=args.dtype,
-                       device=jax.devices()[0].device_kind)
+        want = None
+        if args.errors:
+            full = tuple(x.astype(jnp.float32) for x in args_)
+            want = jax.jit(lambda: both(
+                lambda *a: hybrid_ops.kda_scan_chunked(*a, 64, eps), full))()
+        for labels, fn in forms:
+            row = dict(shape=args.shape, dtype=args.dtype,
+                       device=jax.devices()[0].device_kind, **labels)
             for name, with_gradient in (("fwd_ms", False),
                                         ("fwd_bwd_ms", True)):
                 row[name] = bench(chained(fn, with_gradient), *args_,
                                   iters=3) / CHAINED
             if want is not None:
-                got = jax.jit(lambda f=fn: both(f))()
-                row["rel_err"] = [
-                    float(jnp.abs(a - b).max() / jnp.abs(b).max())
-                    for a, b in zip(got, want)]
+                got = jax.jit(lambda f=fn: both(f, args_))()
+                row["rel_err"] = dict(zip(("out",) + SLOTS, (
+                    float(jnp.abs(a.astype(jnp.float32) - b).max()
+                          / jnp.abs(b).max()) for a, b in zip(got, want))))
             report(log, **row)
 
 
