@@ -186,7 +186,7 @@ def analyze_program(program, feeds: Optional[Sequence[str]] = None,
     presumed feedable and the fetch set falls back to the loss names
     recorded by append_backward. Never raises: a pass that dies on an
     analyzer bug degrades to a single `analyzer-internal` warning so the
-    crash-report and bench integrations stay harmless.
+    crash-report integration stays harmless.
     """
     ctx = PassContext(program, feeds, fetches)
     for name, fn in _passes():

@@ -136,8 +136,7 @@ def run(pctx):
                 f"fetches persistable '{n}', which is also updated "
                 f"in-program: its pre-update buffer is donated to XLA, so "
                 f"the fetch costs an extra device copy and under "
-                f"PADDLE_TPU_STEPS_PER_CALL>1 only the last window value "
-                f"is visible", var=n,
+                f"run_steps only the last window value is visible", var=n,
                 hint="fetch a non-persistable snapshot (assign the value "
                      "to a fresh var) or read the param from the scope "
                      "after run()")

@@ -210,7 +210,7 @@ def _check_layout(pctx):
                 "info", "layout-barrier",
                 f"consumes NHWC-tagged '{hit[0]}' but is neither "
                 f"layout-aware nor layout-agnostic: under "
-                f"PADDLE_TPU_LAYOUT_OPT the value transposes back to "
+                f"PADDLE_TPU_NHWC the value transposes back to "
                 f"NCHW here", op_index=i, var=hit[0])
 
 

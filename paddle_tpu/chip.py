@@ -67,7 +67,7 @@ def enable_compile_cache() -> Optional[str]:
     which case jax already reads it and no directory is set in code
     (returns None). The path is part of the cache key's stability: it is
     fixed, never a temporary, pid- or time-derived directory. Every entry
-    point that compiles (chip_smoke.py, bench.py, the CLI, the test
+    point that compiles (chip_smoke.py, the benchmark, the CLI, the test
     harness) calls this once before its first compile.
 
     Either way, MLIR locations keep only the frame that emitted the op,

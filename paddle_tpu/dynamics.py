@@ -55,7 +55,7 @@ Three layers:
    `python -m paddle_tpu dynamics` CLI, and a crash-report section.
 
 Knobs: PADDLE_TPU_DYNAMICS=0 disables; PADDLE_TPU_DYNAMICS_PERIOD (default
-16) sets the sampling period; both read per-plan so tests/bench can flip
+16) sets the sampling period; both read per-plan so tests can flip
 them via override(). The eager fallback path does not sample — dynamics
 rides the traced step only.
 """
@@ -168,8 +168,8 @@ def period() -> int:
 
 class override:
     """Context manager forcing the observatory on/off (and optionally the
-    period) regardless of the environment — the bench A/B arms and the
-    parity test use this rather than mutating os.environ."""
+    period) regardless of the environment — the tests use this rather
+    than mutating os.environ."""
 
     def __init__(self, enabled: Optional[bool], period: Optional[int] = None):
         self._enabled = enabled

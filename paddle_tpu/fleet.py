@@ -32,9 +32,8 @@ roofline) across the mesh and the fleet, three layers:
    Published as `goodput_fraction` + `goodput_seconds{bucket}`.
 
 Consumers: `python -m paddle_tpu fleet` (CLI), `perf`'s report
-(roofline.collect_report embeds `collectives`), profiler.stop_profiler
-(fleet summary line when multi-process) and the bench harnesses
-(`busbw`, `fleet_skew`, `goodput` JSON fields).
+(roofline.collect_report embeds `collectives`) and
+profiler.stop_profiler (fleet summary line when multi-process).
 """
 
 from __future__ import annotations
@@ -44,9 +43,8 @@ import shutil
 import tempfile
 from typing import Any, Dict, List, Optional
 
-__all__ = ["collective_table", "busbw_by_kind", "exposed_summary",
-           "local_snapshot", "fleet_snapshot", "goodput_report",
-           "format_goodput", "format_fleet", "capture"]
+__all__ = ["collective_table", "local_snapshot", "fleet_snapshot",
+           "goodput_report", "format_goodput", "format_fleet", "capture"]
 
 UNATTRIBUTED = "(unattributed)"
 
@@ -128,42 +126,6 @@ def collective_table(trace_dir, steps: Optional[int] = None,
             "overlap_frac": (1.0 - acc["exposed_ms"] / acc["ms"]
                              if acc["ms"] else None)})
     return {"rows": rows, "ici_gbps": ici, "participants": participants}
-
-
-def busbw_by_kind(table: Optional[Dict[str, Any]]) -> Dict[str, float]:
-    """{kind: busbw_gbps} folded over a collective_table's rows (time-
-    weighted across sites) — the compact per-kind form bench JSON lines
-    carry."""
-    if not table or not table.get("rows"):
-        return {}
-    acc: Dict[str, Dict[str, float]] = {}
-    for r in table["rows"]:
-        if r.get("busbw_gbps") is None:
-            continue
-        a = acc.setdefault(r["kind"], {"bw_ms": 0.0, "ms": 0.0})
-        a["bw_ms"] += r["busbw_gbps"] * r["time_ms"]
-        a["ms"] += r["time_ms"]
-    return {k: round(a["bw_ms"] / a["ms"], 3)
-            for k, a in acc.items() if a["ms"] > 0}
-
-
-def exposed_summary(table: Optional[Dict[str, Any]]) \
-        -> Optional[Dict[str, float]]:
-    """`exposed_collective_seconds` + `overlap_fraction` folded over a
-    collective_table's rows (ISSUE 9 satellite: the overlap win as one
-    tracked number on every bench/scaling JSON line). Exposed time is
-    collective device time covered by no concurrent compute
-    (xplane.exposed_in_line); overlap_fraction = 1 - exposed/total over
-    ALL collective time, so a fully hidden sync reads 1.0 and the
-    monolithic end-of-trace sync reads ~0. None when the trace shows no
-    collectives at all (single-device runs)."""
-    rows = (table or {}).get("rows") or []
-    total_ms = sum(r.get("time_ms") or 0.0 for r in rows)
-    if total_ms <= 0:
-        return None
-    exposed_ms = sum(r.get("exposed_ms") or 0.0 for r in rows)
-    return {"exposed_collective_seconds": round(exposed_ms / 1e3, 6),
-            "overlap_fraction": round(1.0 - exposed_ms / total_ms, 4)}
 
 
 # --- cross-host skew / straggler detection ----------------------------------

@@ -87,7 +87,7 @@ def save_vars(executor: Executor, dirname: str, main_program: Optional[Program]
 def _record_checkpoint(op: str, dirname: str, nbytes: int, n_vars: int,
                        seconds: Optional[float] = None):
     """Checkpoint size telemetry: one gauge series per direction plus a
-    step-event record, so bench/telemetry logs show how much state each
+    step-event record, so telemetry logs show how much state each
     save/load moved (ISSUE: memory observability covers disk-bound state
     too, not just HBM). `seconds` is the wall duration of the transfer —
     the goodput ledger prices checkpoint badput from it when the run

@@ -56,7 +56,7 @@ __all__ = [
     "analyze", "hlo_peak_liveness", "shape_bytes", "nbytes_of",
     "classify", "tracker", "top_live_buffers", "live_array_bytes",
     "is_oom", "maybe_oom_error", "default_budget",
-    "records", "latest_record", "reset", "memory_report", "bench_summary",
+    "records", "latest_record", "reset", "memory_report",
     "crash_section", "build_smoke", "on_compile", "on_run",
     "per_shard_param_bytes",
 ]
@@ -521,7 +521,7 @@ class MemoryTracker:
     allocator's truth (bytes_in_use / peak_bytes_in_use / bytes_limit);
     CPU backends return None and the tracker falls back to summing
     jax.live_arrays(). Feeds the hbm_* gauges and keeps a process-lifetime
-    peak for bench/OOM reports."""
+    peak for OOM reports."""
 
     def __init__(self):
         self.peak_bytes = 0
@@ -741,7 +741,7 @@ def _build_oom_error(exe, program, prog_label, exc, feed_vals, state_vals):
     for s in suggestions:
         lines.append(f"  suggestion: {s}")
     # keep the status name in the message so callers matching the raw
-    # XlaRuntimeError text (retry loops, bench transient markers) still do
+    # XlaRuntimeError text (retry loops) still do
     lines.append("  (RESOURCE_EXHAUSTED)")
     return OOMError("\n".join(lines), program=prog_label,
                     breakdown=breakdown, top_buffers=top,
@@ -849,22 +849,6 @@ def memory_report() -> Dict[str, Any]:
     return {"programs": [r.to_dict() for r in records()],
             "tracker": dict(_TRACKER.last),
             "peak_bytes": _TRACKER.peak_bytes}
-
-
-def bench_summary() -> Optional[Dict[str, Any]]:
-    """peak_hbm_bytes (+ hbm_utilization when a capacity is known) for the
-    bench JSON record; None when nothing was measured."""
-    peak = _TRACKER.peak_bytes
-    limit = int(_TRACKER.last.get("limit_bytes") or 0) if _TRACKER.last else 0
-    if not peak:
-        peak = max((r.total_bytes for r in records()), default=0)
-    if not peak:
-        return None
-    out: Dict[str, Any] = {"peak_hbm_bytes": int(peak),
-                           "hbm_utilization": None}
-    if limit:
-        out["hbm_utilization"] = round(peak / limit, 4)
-    return out
 
 
 def crash_section() -> Dict[str, Any]:

@@ -3,7 +3,7 @@
 Reference: benchmark/paddle/image/resnet.py (v2 config DSL) and
 tests/book/test_image_classification.py resnet_cifar10. Rebuilt on the fluid
 layers DSL: conv+bn blocks map to single XLA fusions; all matmuls/convs land
-on the MXU. The flagship bench model (bench.py) is resnet50.
+on the MXU. resnet50 is the benchmark's `resnet50.train-bs256` cell.
 """
 
 from __future__ import annotations
@@ -78,7 +78,7 @@ def resnet_imagenet(input, class_dim=1000, depth=50, is_test=False):
 
 
 def resnet50(input, class_dim=1000, is_test=False):
-    """The flagship/bench model (BASELINE.json north star)."""
+    """The benchmark's ResNet-50 (BASELINE.json north star)."""
     return resnet_imagenet(input, class_dim=class_dim, depth=50,
                            is_test=is_test)
 

@@ -5,7 +5,8 @@ RNNs, SURVEY.md §2.5 last row) — this is the repo's north-star long-context
 config: pre-LN GPT-style blocks whose attention lowers to the Pallas flash
 kernels (ops/pallas_attention.py) with use_flash=True, and to ring
 attention over an 'sp' mesh axis with sequence_parallel=True
-(parallel/ring_attention.py). Benchmark: BENCH_MODE=transformer.
+(parallel/ring_attention.py). Benchmark: the `gpt2*` cells of
+BENCHMARK.json.
 """
 
 from __future__ import annotations

@@ -41,12 +41,12 @@ def per_shard_param_bytes(program, scope=None):
     `Executor.static_memory_analysis`, whose memory_analysis() of an SPMD
     program is already per-shard (XLA partitions the module before buffer
     assignment) — this splits the same number into replicated-vs-sharded
-    so sweeps (tools/scaling_bench) can see WHY the footprint scales.
+    so a reader can see WHY the footprint scales.
     Returns {devices, replicated_bytes, sharded_bytes_per_device,
     per_device_bytes, by_axes, params}. `by_axes` partitions the
     per-device bytes by the axis-name set each param shards over —
     "replicated", "fsdp", "fsdp+tp", ... — the breakdown the planner's
-    byte validation and the SCALE_MODEL=lm bench lines report."""
+    byte validation reports."""
     from .. import executor as executor_mod
     from .. import memory as memory_mod
 

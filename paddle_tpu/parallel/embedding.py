@@ -20,8 +20,7 @@ the annotation side:
     sharding, so a 1M×64 adam table's moments shard with it instead of
     replicating.
   * `per_shard_table_bytes` / `state_shard_factor` — per-device HBM
-    accounting for tables + their optimizer state (memory.py breakdown,
-    bench evidence columns).
+    accounting for tables + their optimizer state (memory.py breakdown).
 
 Shard-axis selection: `PADDLE_TPU_EMB_SHARD_AXIS` (default "fsdp") names
 the mesh axis (comma-separated for multi-axis) used when a caller does
@@ -203,8 +202,7 @@ def per_shard_table_bytes(program, scope=None) -> Dict:
     """Per-device HBM for each sharded table and its optimizer state:
     {tables: {name: {rows, dim, bytes, per_shard_bytes, opt_state_bytes,
     opt_state_per_shard_bytes, factor}}, total_bytes,
-    total_per_shard_bytes}. The bench `embedding` family emits these as
-    evidence columns (acceptance: per-shard ≈ total/devices at 8
+    total_per_shard_bytes} (acceptance: per-shard ≈ total/devices at 8
     devices). Bytes come from live scope vars when materialized, else
     from the block's static shapes."""
     from .. import executor as executor_mod

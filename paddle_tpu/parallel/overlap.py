@@ -511,7 +511,7 @@ def choose_steps_per_call(python_overhead_ms: Optional[float] = None,
                           budget_bytes: Optional[int] = None,
                           target_overhead_frac: float = 0.02,
                           lo: int = 1, hi: int = 64) -> int:
-    """Pick the run_steps window K (`--steps-per-call auto`).
+    """Pick the run_steps window K.
 
     Amortization: with K steps per dispatch the per-step Python cost is
     overhead/K, so K = ceil(overhead / (frac * step_time)) caps host

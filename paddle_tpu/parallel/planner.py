@@ -55,7 +55,7 @@ and tools/check_registry.py's `check_planner_roles` lint (every role
 producible, every rule op registered, embedding.py in agreement).
 
 Env knobs: `PADDLE_TPU_MESH="dp=2,fsdp=2,tp=2"` sizes the mesh for
-`mesh_from_env()` (examples, scaling_bench SCALE_MODEL=lm).
+`mesh_from_env()` (examples/fluid/train_transformer_fsdp_tp.py).
 """
 
 from __future__ import annotations
@@ -670,8 +670,8 @@ def _plan(program, mesh, layout: SpecLayout, feeds, shard_feeds) -> Plan:
 def validate_plan_bytes(program, scope=None, tol: float = 0.01
                         ) -> Dict[str, Dict]:
     """Cross-check the plan's predicted per-shard bytes against
-    parallel.per_shard_param_bytes (the accounting the bench columns and
-    memory.classify ride). Returns {param: {predicted, accounted}} for
+    parallel.per_shard_param_bytes (the accounting memory.classify
+    rides). Returns {param: {predicted, accounted}} for
     every parameter BOTH sides measured; raises AssertionError on any
     relative mismatch > tol — a hard failure, because divergence means
     the planner and the executor disagree about per-device HBM."""

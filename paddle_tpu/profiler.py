@@ -171,9 +171,9 @@ def stop_profiler(sorted_key=None, profile_path=None):
 
 def finish_trace_report(steps: Optional[int] = None, probe: bool = True):
     """Silent counterpart of stop_profiler for programmatic capture
-    (bench.py, roofline.capture): stop the traced session and return the
-    roofline report dict without printing anything — bench stdout must
-    stay one-JSON-line-per-config. Returns None when no trace was active."""
+    (roofline.capture, fleet.capture): stop the traced session and return
+    the roofline report dict without printing anything. Returns None when
+    no trace was active."""
     global _active
     _active = False
     trace_dir = _end_trace()

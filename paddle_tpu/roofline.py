@@ -32,9 +32,8 @@ Joins three sources into one per-op table:
 
 The report also publishes continuous `mfu_nominal`, `mfu_vs_sustained`
 and `device_duty_cycle` gauges through telemetry.py. Consumers:
-`profiler.stop_profiler` (printed table), `python -m paddle_tpu perf`
-(CLI), and `bench.py`/`tools/scaling_bench.py` (`top_ops`, `bound`,
-`device_duty_cycle` JSON fields) via `capture()`.
+`profiler.stop_profiler` (printed table) and `python -m paddle_tpu perf`
+(CLI) via `capture()`.
 """
 
 from __future__ import annotations
@@ -51,7 +50,7 @@ import numpy as np
 __all__ = ["program_cost", "op_cost", "matmul_probe",
            "hbm_probe", "ici_probe", "ensure_probes", "ensure_ici",
            "nominal_tflops", "collect_report", "format_report", "capture",
-           "waterfall", "top_ops", "UNATTRIBUTED"]
+           "waterfall", "UNATTRIBUTED"]
 
 UNATTRIBUTED = "(unattributed)"
 
@@ -436,8 +435,8 @@ def matmul_probe(n: Optional[int] = None, iters: Optional[int] = None,
                  repeats: int = 3) -> float:
     """Sustained matmul TF/s: a jitted lax.scan chain of data-dependent
     [n,n] matmuls (nothing elidable), best of `repeats`, scalar readback
-    as the fence. Same methodology as bench.py's sustained probe, sized
-    down automatically on CPU so tier-1 CI stays fast."""
+    as the fence. Sized down automatically on CPU so tier-1 CI stays
+    fast."""
     import jax
     import jax.numpy as jnp
 
@@ -557,7 +556,7 @@ def ensure_probes(probe: bool = True) -> Dict[str, Optional[float]]:
     """{"sustained_tflops","hbm_gbps","ridge"} — measured once per process
     and cached; PADDLE_TPU_SUSTAINED_TFLOPS / PADDLE_TPU_HBM_GBPS env
     overrides skip the measurement entirely (hermetic CI, or reusing the
-    numbers a previous bench measured on the same host)."""
+    numbers a previous run measured on the same host)."""
     if "sustained_tflops" not in _PROBES:
         env = os.environ.get("PADDLE_TPU_SUSTAINED_TFLOPS")
         if env:
@@ -888,8 +887,8 @@ def collect_report(trace_dir, steps: Optional[int] = None,
         if report["input_bound"]:
             report["input_bound_remedy"] = (
                 "step time is input-bound: raise the feeder's "
-                "window_prefetch and/or use --steps-per-call auto so "
-                "run_steps windows amortize host dispatch")
+                "window_prefetch and/or drive the loop through "
+                "Executor.run_steps so a window amortizes host dispatch")
     if xla_flops > 0 and (have_cost or executed_flops):
         # required (the analytic model) beside executed (the account) and
         # XLA's own count of the compiled step: executed over required is
@@ -1064,31 +1063,11 @@ def format_report(report: Dict[str, Any]) -> List[str]:
     return lines
 
 
-def top_ops(report: Dict[str, Any], k: int = 5) -> List[Dict[str, Any]]:
-    """Compact per-op summary for bench JSON lines: top-k rows by device
-    time, each {op, at, ms, frac, gflops, tflops, bound, efficiency}."""
-    out = []
-    for row in report["rows"][:k]:
-        out.append({
-            "op": row["op"], "at": row.get("at"),
-            "ms": round(row["ps"] / 1e9, 4),
-            "frac": round(row["frac"], 4),
-            "gflops": (None if row["flops"] is None
-                       else round(row["flops"] / 1e9, 3)),
-            "tflops": (None if row["tflops"] is None
-                       else round(row["tflops"], 3)),
-            "bound": row["bound"],
-            "efficiency": (None if row.get("efficiency") is None
-                           else round(row["efficiency"], 4))})
-    return out
-
-
 def capture(run, steps: int = 3, probe: bool = True) \
         -> Optional[Dict[str, Any]]:
     """Run `run()` `steps` times inside a silent traced profiling session
     and return the roofline report (None on any failure). Nothing is
-    printed — bench.py's stdout contract (one JSON line per config) stays
-    intact. The temp trace dir is deleted afterwards."""
+    printed. The temp trace dir is deleted afterwards."""
     from . import profiler as profiler_mod
 
     tmp = tempfile.mkdtemp(prefix="pd_roofline_")

@@ -12,8 +12,7 @@ Three layers, composed bottom-up:
     max-latency timer; bounded depth with deadline-aware load shedding
     (`ServingOverloadError`), per-request latency histograms.
   * harness.py — concurrent-client load generator reporting
-    p50/p99/qps/bucket-hits/goodput; backs `BENCH_MODE=serving` and
-    `python -m paddle_tpu serve`.
+    p50/p99/qps/bucket-hits/goodput; backs `python -m paddle_tpu serve`.
   * slo.py — per-model availability/latency objectives with fast/slow
     window burn-rate evaluation, fed one outcome per request by the
     batcher and scraped via `slo_burn_rate{model,window}` / `/healthz`.

@@ -7,9 +7,8 @@ distribution from the engine's AOT cache, shed fraction, and goodput.
 `overload_report` runs the canonical two-phase experiment — a normal
 phase at N clients, then a 2x overload phase against a bounded queue —
 showing the load-shedding policy holding accepted-request latency while
-goodput (not availability) absorbs the excess. bench.py's
-BENCH_MODE=serving and the `serve` CLI subcommand are thin wrappers over
-these functions, so the JSON they emit comes from one implementation.
+goodput (not availability) absorbs the excess. The `serve` CLI
+subcommand is a thin wrapper over these functions.
 """
 
 from __future__ import annotations

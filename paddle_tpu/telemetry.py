@@ -284,7 +284,7 @@ def read_series(name: str) -> Dict[str, float]:
     (label_key is the registry's serialized 'k=v,k=v' form; the unlabeled
     series maps from ''). Same read-only contract as read_gauge: never
     creates the family or any child. Empty when the family is absent or a
-    histogram. Used by the memory CLI/bench to fold per-device hbm_*
+    histogram. Used by the memory CLI to fold per-device hbm_*
     gauges without knowing the device labels in advance."""
     with _REG._lock:
         fam = _REG._families.get(name)

@@ -3,8 +3,7 @@ topology (tests/test_tpu_compile.py does), so they count wherever the
 suite runs. chip_smoke.py's phases run here at tiny sizes with the Pallas
 kernels interpreted — the guide's first rehearsal — and the pieces that
 keep a CPU from passing for a chip are pinned: the device check, the
-accelerator place, the peak table, the one compile cache, bench.py's
-single attempt."""
+accelerator place, the peak table, the one compile cache."""
 
 import json
 import os
@@ -20,7 +19,6 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-import bench  # noqa: E402
 import chip_smoke  # noqa: E402
 from paddle_tpu import chip, executor as executor_mod, memory, roofline  # noqa: E402
 from paddle_tpu.ops import kernel_choice, pallas_attention, pallas_conv  # noqa: E402
@@ -175,10 +173,8 @@ def test_peak_table_knows_v5e_and_nothing_else():
 
 
 def test_peak_overrides_are_gone(monkeypatch):
-    monkeypatch.setenv("BENCH_PEAK_TFLOPS", "1")
     monkeypatch.setenv("PADDLE_TPU_PEAK_TFLOPS", "1")
     assert roofline.nominal_tflops() is None
-    assert bench._peak_tflops() is None and bench._mfu(1e12) is None
 
 
 def test_default_budget_assumes_no_hbm_size():
@@ -401,49 +397,3 @@ def test_smoke_wants_two_flash_kernels_per_layer():
     calls.pop("scaled_dot_product_attention_grad/flash_dkv")
     with pytest.raises(AssertionError, match="0 flash_dkv"):
         chip_smoke._check_flash_kernels(calls, 4)
-
-
-# --- bench.py: one attempt, the device named --------------------------------
-
-def test_bench_timed_loop_makes_one_attempt():
-    calls = []
-
-    def step():
-        calls.append(1)
-        raise RuntimeError("RESOURCE_EXHAUSTED: Ran out of memory in "
-                           "memory space vmem")
-
-    with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
-        bench._timed_loop(step, 2, 4)
-    assert len(calls) == 1
-
-
-def test_bench_failure_line_names_device_and_exits_1(monkeypatch, capsys):
-    attempts = []
-
-    def lost():
-        raise RuntimeError("UNAVAILABLE: the device is gone")
-
-    def boom(mode):
-        attempts.append(mode)
-        # a device lost in the attempt cannot be asked what it is: the
-        # line names what main() saw before the family ran
-        monkeypatch.setattr(chip, "describe", lost)
-        raise RuntimeError("INTERNAL: Mosaic failed to compile TPU kernel")
-
-    monkeypatch.setattr(bench, "_dispatch", boom)
-    monkeypatch.setattr(bench, "_DEVICE", None)
-    monkeypatch.setenv("BENCH_MODE", "resnet")
-    monkeypatch.setenv("BENCH_HISTORY", "off")
-    assert bench.main() == 1
-    assert attempts == ["resnet"]              # no rebuild, no retry
-    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert line["value"] is None and "Mosaic" in line["errors"][0]
-    assert (line["platform"], line["device_kind"]) == ("cpu", "cpu")
-    assert line["device_count"] == len(jax.devices())
-
-
-def test_bench_has_no_retry_layer():
-    for name in ("_is_transient", "_retrying", "BenchError", "RETRIES",
-                 "_TRANSIENT_MARKERS", "PEAK_TFLOPS"):
-        assert not hasattr(bench, name), name

@@ -145,6 +145,29 @@ class TestPerfCLI:
         assert report.get("device_duty_cycle") is not None
 
 
+def test_serve_smoke_prints_a_line_a_phase_and_a_summary(tmp_path):
+    """`serve --smoke`: one JSON line for the normal phase, one for the
+    overload phase at twice the clients, then the engine/batcher
+    summary."""
+    import json
+    r = run_cli(["serve", "--smoke", "--clients", "2", "--requests", "3"],
+                str(tmp_path))
+    assert r.returncode == 0, r.stdout + r.stderr[-1500:]
+    normal, overload, summary = [json.loads(ln)
+                                 for ln in r.stdout.splitlines()
+                                 if ln.startswith("{")]
+    for phase, line, clients in (("normal", normal, 2),
+                                 ("overload", overload, 4)):
+        assert (line["phase"], line["clients"]) == (phase, clients)
+        assert {"p50_ms", "p99_ms", "qps", "shed_fraction", "bucket_hits",
+                "goodput_fraction"} <= set(line), line
+        assert 0.0 < line["p50_ms"] <= line["p99_ms"]
+        assert line["requests"] == 3 * clients and line["errors"] == 0
+    assert summary["model"] == "smoke"
+    assert summary["batcher"]["submitted"] == 18
+    assert sum(summary["engine"]["bucket_runs"].values()) >= 1
+
+
 class TestCheckgrad:
     def test_checkgrad_passes(self, tmp_path):
         cfg = tmp_path / "conf.py"

@@ -16,6 +16,7 @@ import http.client
 import json
 import math
 import os
+import time
 
 import numpy as np
 import pytest
@@ -529,9 +530,27 @@ def test_a_reader_drains_what_is_pending_and_books_it_forced(monkeypatch):
     assert _host_waits() == {"dynamics": 2}
 
 
+def _sleep_until_pending_rows_are_ready(seconds=10.0):
+    """The test's own bounded wait: it sleeps, asks nothing of the device
+    and books nothing. Fails if a queued row still reads as in flight
+    after `seconds`."""
+    deadline = time.monotonic() + seconds
+    while not all(telemetry.is_ready(entry[2])
+                  for entry in dynamics._OBS.pending):
+        assert time.monotonic() < deadline, "a row never became ready"
+        time.sleep(0.001)
+
+
 def test_a_synchronous_loop_records_each_sample_in_its_own_step():
-    """return_numpy=True: once the fetches are on the host so is the
-    step's row, and the run that made it records it, unforced."""
+    """return_numpy=True: the step's row is an output of the execution
+    whose loss is on the host by now, and the first unforced drain that
+    finds it ready records it, in step order, with no wait booked. As a
+    rule that is the run's own last drain. jax's CPU client may report the
+    row ready a thread hand-off behind the loss (0.3 ms when the host
+    sleeps, one interpreter switch interval when it keeps running Python,
+    seen for a step loaded from the compile cache), and these six runs
+    take less than that: so the test sleeps until the row reads as ready
+    and makes the drain the next step would start with."""
     main, startup, loss = _build_program()
     scope = executor_mod.Scope()
     seen = []
@@ -541,6 +560,8 @@ def test_a_synchronous_loop_records_each_sample_in_its_own_step():
         scope.set_var("__rng_counter__", 2)
         for feed in _batches(6):                # 2..7: 2, 4, 6 sample
             exe.run(main, feed=feed, fetch_list=[loss])
+            _sleep_until_pending_rows_are_ready()
+            dynamics.drain()                    # takes what is ready
             seen.append(list(_recorded_steps()))
     assert seen == [[2], [2], [2, 4], [2, 4], [2, 4, 6], [2, 4, 6]]
     assert _samples_by_how() == {"ready": 3}

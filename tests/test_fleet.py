@@ -272,22 +272,6 @@ class TestCollectiveTable:
         assert r["algbw_gbps"] == 0.0   # time joined, payload unknown
 
 
-class TestBusbwByKind:
-    def test_time_weighted_fold(self):
-        table = {"rows": [
-            {"kind": "all-reduce", "busbw_gbps": 10.0, "time_ms": 1.0},
-            {"kind": "all-reduce", "busbw_gbps": 20.0, "time_ms": 3.0},
-            {"kind": "all-gather", "busbw_gbps": 5.0, "time_ms": 2.0},
-            {"kind": "send/recv", "busbw_gbps": None, "time_ms": 9.0},
-        ]}
-        out = fleet.busbw_by_kind(table)
-        assert out == {"all-reduce": 17.5, "all-gather": 5.0}
-
-    def test_empty(self):
-        assert fleet.busbw_by_kind(None) == {}
-        assert fleet.busbw_by_kind({"rows": []}) == {}
-
-
 # --- goodput ledger -----------------------------------------------------------
 
 class TestGoodput:

@@ -1,8 +1,7 @@
 """Roofline attribution (ISSUE 6): analytic op costs, probe/ridge math,
-synthetic-xplane report joins, waterfall bucketing, and the bench-facing
-top_ops summary. The synthetic traces hand-encode the XSpace wire format
-so the tests pin the parser and the report logic together without a
-device."""
+synthetic-xplane report joins and waterfall bucketing. The synthetic
+traces hand-encode the XSpace wire format so the tests pin the parser and
+the report logic together without a device."""
 
 import numpy as np
 import pytest
@@ -221,7 +220,7 @@ ENTRY %main (p0: f32[256,256], p1: f32[256,256]) -> f32[256,256] {
         assert report["kernel_counts"] == {"modules": 1, "instructions": 2,
                                         "fusions": 1}
 
-    def test_format_report_and_top_ops(self, tmp_path, monkeypatch):
+    def test_format_report(self, tmp_path, monkeypatch):
         monkeypatch.setattr(roofline, "_PROBES", {})
         monkeypatch.setenv("PADDLE_TPU_SUSTAINED_TFLOPS", "0.5")
         monkeypatch.setenv("PADDLE_TPU_HBM_GBPS", "20")
@@ -236,11 +235,10 @@ ENTRY %main (p0: f32[256,256], p1: f32[256,256]) -> f32[256,256] {
         assert any(ln.startswith("[roofline]") for ln in lines)
         assert any(ln.startswith("[crosscheck]") and "executed" in ln
                    for ln in lines)
-        top = roofline.top_ops(report, k=2)
-        assert len(top) == 2 and top[0]["op"] == "matmul"
-        assert top[0]["at"] == 3
-        assert top[0]["bound"] == "compute"
-        assert top[0]["gflops"] == round(2.0 * 256 ** 3 / 1e9, 3)
+        top = report["rows"][0]
+        assert (top["op"], top["at"], top["bound"]) == ("matmul", 3,
+                                                        "compute")
+        assert top["flops"] == 2.0 * 256 ** 3
 
     def test_foreign_trace_without_accounts_still_reports(self, tmp_path,
                                                           monkeypatch):

@@ -11,7 +11,6 @@ against the engine's python counters AND the telemetry series, so the
 observability surface can't silently drift from the behavior.
 """
 
-import os
 import time
 
 import numpy as np
@@ -435,29 +434,6 @@ def test_concurrent_client_smoke_latency_histograms():
         assert h and h["count"] >= 12
     eng.close()
 
-
-def test_bench_serving_mode_json_line():
-    """BENCH_MODE=serving emits one JSON line with the required keys
-    (satellite). Subprocess so bench's module-level env reads are fresh;
-    roofline/perf probes off to keep it seconds, not minutes."""
-    import json
-    import subprocess
-    import sys
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ, JAX_PLATFORMS="cpu", BENCH_MODE="serving",
-               BENCH_ROOFLINE="0", BENCH_PERF="0", BENCH_SERVE_CLIENTS="2",
-               BENCH_SERVE_REQUESTS="3", BENCH_HISTORY="0", PYTHONPATH=repo)
-    r = subprocess.run([sys.executable, os.path.join(repo, "bench.py")],
-                       capture_output=True, text=True, env=env,
-                       timeout=420, cwd=repo)
-    assert r.returncode == 0, r.stdout + r.stderr[-2000:]
-    line = json.loads(r.stdout.strip().splitlines()[-1])
-    for key in ("p50_ms", "p99_ms", "qps", "shed_fraction", "bucket_hits",
-                "goodput_fraction", "overload"):
-        assert key in line, (key, line)
-    assert line["densify_fallbacks"] == 0
-    assert 0.0 < line["p50_ms"] <= line["p99_ms"]
 
 def test_overload_report_slo_and_latency_bound():
     """Overload acceptance (ISSUE 16): injected overload drives the SLO

@@ -1,7 +1,7 @@
 """Framework-independent ceiling probe: hand-rolled pure-JAX ResNet-50
-training step (NHWC, bf16 compute, f32 master weights + momentum), same
-batch/protocol as bench.py. Used to separate framework overhead from the
-chip/XLA ceiling when tuning the flagship bench.
+training step (NHWC, bf16 compute, f32 master weights + momentum), the
+model of the benchmark's `resnet50.train-bs256` cell. Used to separate
+framework overhead from the chip/XLA ceiling when tuning that cell.
 
     python3 tools/jax_resnet_ref.py [batch]    (768; the benchmark's cell: 256)
 """
