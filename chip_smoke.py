@@ -52,11 +52,18 @@ def _emit(record):
     print(json.dumps(record), flush=True)
 
 
-def _counters(*names):
-    """{family: {labels: value}} of the gate counters, as counted so far."""
+def _counters(*names, since=None):
+    """{family: {labels: value}} of the gate counters, as counted so far,
+    or with `since` (an earlier reading) what they grew by since: the
+    counters are the process's, and another program lowered before this
+    phase has booked its own."""
     from paddle_tpu import telemetry
-    return {n: {k: int(v) for k, v in
-                sorted(telemetry.read_series(n).items())} for n in names}
+    now = {n: {k: int(v) for k, v in
+               sorted(telemetry.read_series(n).items())} for n in names}
+    if since is None:
+        return now
+    return {n: {k: v - since[n].get(k, 0) for k, v in series.items()
+                if v > since[n].get(k, 0)} for n, series in now.items()}
 
 
 # jax's own persistent-compilation-cache events, counted since
@@ -147,10 +154,9 @@ def _check_no_kernels(hits, calls):
     """The ResNet step is XLA's alone (PERF.md section 6: PR 25 took the
     convs off the Pallas suite, PR 34 deleted fusion's bn+act kernel):
     the conv gates counted no kernel and the compiled step holds no
-    Mosaic call. `hits` is pallas_kernel_total as counted, of which only
-    the conv ops' series are judged (the counter is the process's, and
-    an attention lowered before this phase has booked its own series);
-    `calls` the step's Mosaic calls. One that came back came back
+    Mosaic call. `hits` is what pallas_kernel_total grew by in the phase,
+    of which only the conv ops' series are judged; `calls` the step's
+    Mosaic calls. One that came back came back
     without a price."""
     convs = {k: n for k, n in hits.items() if k.startswith("op=conv")}
     if convs or calls:
@@ -219,6 +225,9 @@ def train_phase(batch, side, classes, depth=50, steps=3, window=4,
 
     probe = "fc_0.w_0"
     compiles = _Compiles()
+    gates = ("pallas_kernel_total", "pallas_fallback_total",
+             "fusion_fallback_total")
+    counted = _counters(*gates)
     with executor_mod.scope_guard(scope):
         exe.run(startup)
         before = np.asarray(scope.find_var(probe)).copy()
@@ -230,8 +239,7 @@ def train_phase(batch, side, classes, depth=50, steps=3, window=4,
                 main, feed=feed, fetch_list=[loss])[0]))
             losses.append(_finite_scalar(out, f"train step {i}"))
             step_s.append(dt)
-        counters = _counters("pallas_kernel_total", "pallas_fallback_total",
-                             "fusion_fallback_total")
+        counters = _counters(*gates, since=counted)
         # shapes are all the compile-only check below needs: the batch
         # leaves the device before the window four times its size arrives
         feed = {k: jax.ShapeDtypeStruct(v.shape, v.dtype,

@@ -1448,12 +1448,16 @@ def moe_block(x, num_experts, top_k, expert_width, shared_width=0,
                     "expert_offset": expert_offset, "top_k": top_k}
     if gate_act != "silu":      # the op's default: a silu gate's program
         expert_attrs["gate_act"] = gate_act     # is the one it was
+    # what the op keeps for its gradient op: the up product's rows, and
+    # the gate's
+    kept = {slot: [helper.create_tmp_variable(dtype, stop_gradient=True)]
+            for slot in (("Up", "GateUp") if gated else ("Up",))}
     helper.append_op(type="moe_experts",
                      inputs=dict(inputs, W1=[up], W2=[down]),
                      outputs={"Out": [routed], "RowsRouted": [rows],
                               "RowsCombined": [combined],
                               "LoadMaxOverMean": [load],
-                              "RowsHandled": [handled]},
+                              "RowsHandled": [handled], **kept},
                      attrs=expert_attrs)
     if stats is not None:
         stats.append((rows, combined, load, handled))

@@ -16,50 +16,54 @@ decays and its state stay float32; the scan's four products and the
 expert products take bf16 operands with float32 accumulation; the rotary
 angles, their sines and cosines and the rotation itself are float32.
 
-Gradients: all generic (registry.generic_grad_lower: jax.vjp of the
-lowering, whose re-traced forward XLA merges with the original). The
-scan runs, where its shape tiles (ssd_scan_ineligible), on the two Pallas
-kernels of ops/pallas_scan.py, whose [chunk, chunk] decay and score
-blocks never leave VMEM: a jax.custom_vjp inside the lowering that keeps
-the op's inputs and the state entering each chunk, and recomputes the
-blocks in the gradient's kernel (PERF.md section 6, PR 40); elsewhere
-its core is ssd_scan_chunked under a jax.checkpoint, which keeps the
-op's inputs and recomputes the blocks as XLA arrays. The expert layer's
-grouped products are Pallas calls too; the chip's compiler merges a
-re-traced forward kernel with the original (16 gmm runs a step of the
-hybrid cell, not 24, PERF.md section 6, PR 36; 4 ssd_scan_fwd, not 8). The
-delta rule (kda_scan) likewise: where a head is a lane block
-(kda_scan_ineligible) the kernels of ops/pallas_kda.py under one
-jax.custom_vjp that keeps the op's inputs alone (the gradient runs the
-forward kernel again for the state entering each chunk and each chunk's
-inverse, then the backward kernel; PERF.md section 6, PR 56), elsewhere
-kda_chunked and autodiff's gradient of it. A layer that holds a sixteenth of the
-experts or less handles its rows inside a capacity chosen on the device
-(_capacity_ladder) and carries a rule of its own inside the lowering
-(_handle_routed_rows, a jax.custom_vjp as nn_ops._hard_label_nll is),
-which the generic gradient differentiates through: the backward chooses
-the same branch, runs that branch's forward again inside it (a
-conditional is a wall to the merging) and keeps nothing of a branch's
-size between the two. Inside a branch, and in the layer without one, the
-two maps between tokens and sorted rows carry rules too (_rows_of_tokens,
-_tokens_of_rows): each is pulled back as a gather through the other's
-index, where autodiff would zero-fill [N, D] and scatter-add. The map
-back to the tokens, forward of the one and pulled back of the other
-(_sum_of_pairs), runs on the kernel of ops/pallas_pair_sum.py wherever
-its gate takes the shape: one pass over the live rows (PR 47). The
-rotation is linear in X and its generic gradient is the rotation by the
-opposite angle.
+Gradients: generic but the expert layer's (registry.generic_grad_lower:
+jax.vjp of the lowering, whose re-traced forward XLA merges with the
+original). The scan runs, where its shape tiles (ssd_scan_ineligible),
+on the two Pallas kernels of ops/pallas_scan.py, whose [chunk, chunk]
+decay and score blocks never leave VMEM: a jax.custom_vjp inside the
+lowering that keeps the op's inputs and the state entering each chunk,
+and recomputes the blocks in the gradient's kernel (PERF.md section 6,
+PR 40); elsewhere its core is ssd_scan_chunked under a jax.checkpoint,
+which keeps the op's inputs and recomputes the blocks as XLA arrays; the
+chip's compiler merges a re-traced forward kernel with the original (4
+ssd_scan_fwd a step of the hybrid cell, not 8). The delta rule (kda_scan)
+likewise: where a head is a lane block (kda_scan_ineligible) the kernels
+of ops/pallas_kda.py under one jax.custom_vjp that keeps the op's inputs
+alone (the gradient runs the forward kernel again for the state entering
+each chunk and each chunk's inverse, then the backward kernel; PERF.md
+section 6, PR 56), elsewhere kda_chunked and autodiff's gradient of it.
+The expert layer is the
+exception to the generic rule: moe_experts writes the up product's rows
+(and the gate's) as outputs and an explicit gradient op reads them
+(_experts_grad, as nn_ops._sdpa_grad reads LSE), so the gradient runs the
+pulled-back products alone. A layer that holds an eighth of the experts
+or less handles its rows inside a capacity chosen on the device
+(_capacity_ladder): one lax.switch in the op and one in its gradient op,
+which takes the same rung from the same routed count; a conditional is a
+wall to XLA's merging, which is why nothing is traced twice across it
+(a re-traced forward inside the gradient's branch ran its products a
+second time: PERF.md section 6, PR 36 and PR 58). Inside a branch, and
+in the layer without one, the two maps between tokens and sorted rows
+are gathers both ways, forward and pulled back (_rows_of_tokens,
+_pairs_summed: autodiff would zero-fill [N, D] and scatter-add). The map
+back to the tokens, forward and pulled back (_sum_of_pairs), runs on the
+kernel of ops/pallas_pair_sum.py wherever its gate takes the shape: one
+pass over the live rows (PR 47). The rotation is linear in X and its
+generic gradient is the rotation by the opposite angle.
 """
 
 from __future__ import annotations
 
 import functools
+import types
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from ..framework.desc import OpDesc
+from ..framework.framework import grad_var_name
 from . import kernel_choice, pallas_pair_sum
 from .common import in_var, same_as_input, set_out
 from .registry import NO_GRAD, op
@@ -711,31 +715,59 @@ def _gmm_tiling(d: int, f: int, dtype):
 _GATE_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
+def _grouped_dots(sizes, kernel, d, f, dtype):
+    """The three grouped products over rows sorted by group, `sizes` rows
+    each, in order -> (dot, dot_t, dot_w): dot(a, w)[p] = a[p] w[e of p]
+    and dot_t(a, w)[p] = a[p] w[e of p]^T (rows past the sizes' sum come
+    back undefined from both), dot_w(a, b)[e] = the sum over e's rows of
+    a[p]^T b[p] (rows past the sum add nothing). `kernel`: None for
+    lax.ragged_dot and its transposes, else megablox gmm and tgmm with
+    `interpret=kernel` at _gmm_tiling(d, f, dtype), results in `dtype`."""
+    if kernel is None:
+        dot = functools.partial(lax.ragged_dot, group_sizes=sizes)
+        by_group = lax.RaggedDotDimensionNumbers(
+            dot_dimension_numbers=(((0,), (0,)), ((), ())),
+            lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+        return (dot, lambda a, w: dot(a, w.swapaxes(1, 2)),
+                lambda a, b: lax.ragged_dot_general(a, b, sizes, by_group))
+    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm
+    tiling = _gmm_tiling(d, f, dtype)
+    dot = functools.partial(
+        megablox.gmm, group_sizes=sizes, preferred_element_type=dtype,
+        tiling=tiling, interpret=kernel)
+    return (dot, functools.partial(dot, transpose_rhs=True),
+            lambda a, b: tgmm(a.swapaxes(0, 1), b, sizes, dtype, tiling,
+                              None, sizes.shape[0], interpret=kernel))
+
+
+def _hidden(up, gate_up=None, gate_act="silu"):
+    """The experts' hidden rows, float32, from the up product's rows and
+    the gate product's: relu(up)^2, or act(gate_up) * up, act = `gate_act`
+    (silu, or relu: ReGLU)."""
+    if gate_up is None:
+        return jnp.square(jax.nn.relu(_f32(up)))
+    return _GATE_ACTS[gate_act](_f32(gate_up)) * _f32(up)
+
+
 def _grouped_products(rows, w1, w2, sizes, kernel, gate=None,
                       gate_act="silu"):
     """relu(rows W1[e])^2 W2[e] for the rows of each group e (`sizes` rows
     each, in order; rows past their sum come back undefined); with `gate`
-    (act(rows Gate[e]) * (rows W1[e])) W2[e], act = `gate_act` (silu, or
-    relu: ReGLU), the activation in float32. `kernel`: None for
-    lax.ragged_dot, else megablox gmm with `interpret=kernel`."""
-    if kernel is not None:
-        from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
-        dot = functools.partial(
-            megablox.gmm, group_sizes=sizes, preferred_element_type=rows.dtype,
-            tiling=_gmm_tiling(w1.shape[1], w1.shape[2], rows.dtype),
-            interpret=kernel)
-    else:
-        dot = functools.partial(lax.ragged_dot, group_sizes=sizes)
+    (act(rows Gate[e]) * (rows W1[e])) W2[e], the activation in float32
+    (_hidden). `kernel` as _grouped_dots takes it. -> (the down product,
+    (the up product,) or (the up product, the gate's): what the gradient
+    reads in place of running either again)."""
+    dot, _, _ = _grouped_dots(sizes, kernel, w1.shape[1], w1.shape[2],
+                              rows.dtype)
     with jax.named_scope("moe_up"):
-        h = dot(rows, w1)
-    if gate is None:
-        h = jnp.square(jax.nn.relu(h))
-    else:
+        kept = (dot(rows, w1),)
+    if gate is not None:
         with jax.named_scope("moe_gate"):
-            g = dot(rows, gate)
-        h = (_GATE_ACTS[gate_act](_f32(g)) * _f32(h)).astype(h.dtype)
+            kept += (dot(rows, gate),)
     with jax.named_scope("moe_down"):
-        return dot(h, w2)
+        return dot(_hidden(*kept, gate_act=gate_act).astype(rows.dtype),
+                   w2), kept
 
 
 def _experts_infer(op_, block):
@@ -743,27 +775,42 @@ def _experts_infer(op_, block):
     for slot in ("RowsRouted", "RowsCombined", "LoadMaxOverMean",
                  "RowsHandled"):
         set_out(op_, block, slot, [1], "float32")
+    xv, wv, idx = (in_var(op_, block, s) for s in ("X", "W1", "TopkIdx"))
+    if None in (xv, wv, idx) or None in (xv.shape, wv.shape, idx.shape):
+        return
+    pairs = xv.shape[0] * idx.shape[1] if xv.shape[0] >= 0 else -1
+    for slot in _KEPT:      # the compute dtype is the lowering's to know
+        set_out(op_, block, slot, [pairs, wv.shape[2]], xv.dtype)
 
 
 def _capacity_ladder(pairs: int, held: int, num_experts: int):
     """The row counts the expert layer may handle, ascending: `pairs` =
-    N x top_k (every pair: nothing is ever dropped) and before it the
-    smallest halving of `pairs` that still holds four times the share a
-    uniform router sends here, pairs * held / num_experts, and tiles as
-    `pairs` does (so gmm_ineligible answers the same for both), if that
-    is at most a quarter of the pairs. A layer that holds more than a
-    sixteenth of the experts has the one rung.
+    N x top_k (every pair: nothing is ever dropped) and before it, if it
+    is at most a quarter of the pairs, the smallest halving of `pairs`
+    that tiles as `pairs` does (so gmm_ineligible answers the same for
+    both) and still holds four times the share a uniform router sends
+    here, pairs * held / num_experts, or, where four times the share is
+    more than a quarter of the pairs, twice the share. A sixteenth of the
+    experts or less has room for four times off balance, an eighth for
+    twice (pairs / 4), and a layer that holds more than an eighth has the
+    one rung and no conditional.
 
-    Two rungs, the factor four and the quarter are what the chip allowed
-    (PERF.md section 6, PR 36): every rung is a branch of the forward and
-    of the gradient to trace, lower and load, 3.4 to 5 s of a cell's
-    set-up each; and the last rung costs a fifth more than the layer
-    without a switch (its gradient runs the forward again inside its
-    branch, where XLA merges the re-traced forward with the original), so
-    the rung before it has to hold a router two or three times off
-    balance and to save more than half when it does."""
+    Two rungs and the quarter are what the chip allowed (PERF.md section
+    6, PR 36): every rung is a branch of the forward and of the gradient
+    to trace, lower and load, seconds of a cell's set-up each, so the
+    rung before the last has to save more than half of the rows' handling
+    when it is taken. The factor: four where it fits (PR 36's routers ran
+    two or three times off balance in their first steps), two for an
+    eighth since the gradient's branch reads the forward's products and
+    runs none again (PERF.md section 6, PR 58: the last rung behind a
+    switch cost a fifth more than the layer without one while it did, and
+    a layer whose router overflows the smaller rung at every step paid
+    that for nothing); routers that train under a balancing rule stay
+    within a fifth of uniform, and one that does not takes the last rung
+    at the price of the layer without a ladder."""
+    factor = 4 if 16 * held <= num_experts else 2
     rung = pairs
-    while (rung % 2 == 0 and rung // 2 * num_experts >= 4 * pairs * held
+    while (rung % 2 == 0 and rung // 2 * num_experts >= factor * pairs * held
            and (rung // 2 % _GMM_ROWS == 0) == (pairs % _GMM_ROWS == 0)):
         rung //= 2
     return (rung, pairs) if 4 * rung <= pairs else (pairs,)
@@ -777,8 +824,8 @@ def _capacity_ladder(pairs: int, held: int, num_experts: int):
 # read through the other index both are gathers, forward and pulled back.
 # Autodiff cannot know that and writes each transpose as a zero-fill of
 # [N, D] and a scatter-add of C rows (1.25-1.34 ms each at 14 % of their
-# bytes on a v5e, ten a step of the latent-attention cell); so each map
-# has a rule of its own.
+# bytes on a v5e, ten a step of the latent-attention cell, PR 38); the
+# op's gradient (_pull_rows_back) is written by hand with both.
 
 def _sum_of_pairs(rows, pos, live_rows, weight=None):
     """[C, D] -> [N, D] float32: sum over a token's top_k pairs of
@@ -817,9 +864,11 @@ def _pairs_summed(rows, pos, windows, live_rows, weight=None,
     """_sum_of_pairs as `out_dtype`: on the kernel of
     ops/pallas_pair_sum.py where the op's gate took it (`windows` is then
     the sort's pair_windows), as written above where it declined (None).
-    Both callers below take the one path: XLA fuses the gathers into no
-    consumer when pulled back, and forward only behind a shared expert's
-    down product, which a lowering cannot see."""
+    Forward (weighted, the down product's rows back to their tokens) and
+    pulled back (the rows' cotangents summed into dX) take the one path:
+    XLA fuses the gathers into no consumer when pulled back, and forward
+    only behind a shared expert's down product, which a lowering cannot
+    see."""
     if windows is None:
         return _sum_of_pairs(rows, pos, live_rows, weight).astype(out_dtype)
     from .pallas_attention import _interpret
@@ -828,153 +877,196 @@ def _pairs_summed(rows, pos, windows, live_rows, weight=None,
                                     interpret=_interpret())
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _rows_of_tokens(x, token, pos, windows, live_rows, dtype):
-    """x[token] as `dtype`, zero from `live_rows` on: [N, D] -> [C, D].
-    Pulled back, dX[n] is the sum over the token's live pairs of their
-    rows' cotangents, in float32. The cast and the select are inside the
-    rule so that the cotangent arrives as the grouped product's gradient
-    leaves it, in the compute dtype and unselected: _pairs_summed reads
-    no row from `live_rows` on, and outside the rule their transposes
-    were a pass over [C, D] that wrote it out in float32 ahead of the
-    gathers (0.31 ms a layer at [16384, 2048]: PERF.md section 6, PR 47)."""
+def _rows_of_tokens(x, token, live_rows, dtype):
+    """x[token] as `dtype`, zero from `live_rows` on: [N, D] -> [C, D],
+    the cast and the select in the gather's own pass."""
     live = (jnp.arange(token.shape[0]) < live_rows)[:, None]
-    rows = x.at[token].get(mode="promise_in_bounds")
-    return jnp.where(live, rows.astype(dtype), 0)
+    return jnp.where(live, x.at[token].get(
+        mode="promise_in_bounds").astype(dtype), 0)
 
 
-def _rows_of_tokens_fwd(x, token, pos, windows, live_rows, dtype):
-    return (_rows_of_tokens(x, token, pos, windows, live_rows, dtype),
-            (pos, windows, live_rows, x[:0]))
-
-
-def _rows_of_tokens_bwd(dtype, res, ct):
-    *sort, like = res
-    return (_pairs_summed(ct, *sort, out_dtype=like.dtype),) + (None,) * 4
-
-
-_rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
-
-
-@jax.custom_vjp
-def _tokens_of_rows(y, weight, head, pos, windows, live_rows):
-    """Out[n] = sum_j weight[n, j] * y[pos[n, j]] over the pairs with a
-    live row: [C, D] in the compute dtype as the kernel leaves it (rows
-    past the live ones undefined) -> [N, D] float32. Pulled back in
-    sorted order, where one gather of the cotangent's rows serves both
-    gradients: dY[p] = weight[pair p] * dOut[token p], dWeight[pair p] =
-    y[p] . dOut[token p], zero past the live rows."""
-    return _pairs_summed(y, pos, windows, live_rows, weight)
-
-
-def _tokens_of_rows_fwd(y, weight, head, pos, windows, live_rows):
-    return (_tokens_of_rows(y, weight, head, pos, windows, live_rows),
-            (y, weight, head, pos, live_rows))
-
-
-def _tokens_of_rows_bwd(res, ct):
-    y, weight, head, pos, live_rows = res
-    live = (jnp.arange(y.shape[0]) < live_rows)[:, None]
-    d_rows = jnp.where(live, ct.at[head // weight.shape[1]].get(
-        mode="promise_in_bounds"), 0)
-    by_row = weight.reshape(-1).at[head].get(mode="promise_in_bounds")
-    d_y = (d_rows * by_row[:, None]).astype(y.dtype)
-    d_by_row = (jnp.where(live, _f32(y), 0) * d_rows).sum(-1)
-    ok = pos < live_rows
-    d_weight = jnp.where(ok, d_by_row.at[jnp.where(ok, pos, 0)].get(
-        mode="promise_in_bounds"), 0)
-    return d_y, d_weight, None, None, None, None
-
-
-_tokens_of_rows.defvjp(_tokens_of_rows_fwd, _tokens_of_rows_bwd)
+# the output slots that carry _grouped_products' kept results from the op
+# to its gradient op, in that order (the second only when gated)
+_KEPT = ("Up", "GateUp")
 
 
 def _handle_rows(capacity, kernel, gate_act, sort, sizes, x, weight, w1, w2,
-                 gate):
+                 gate=None):
     """The layer over the first `capacity` pairs of the sorted order (the
     routed ones, which the caller knows to be no more, then dead ones):
     their tokens' rows gathered, the grouped products, and each token's
     top_k rows gathered back through the inverse of the sort, weighted
     and summed in float32. `sort` = (order, its inverse [N, top_k], the
     kernel's pair_windows or None: _pairs_summed).
-    -> (Out, the pairs it combined)."""
+    -> (Out, the pairs it combined, _grouped_products' kept results as
+    [N x top_k, F], the rung's rows first: a switch's branches return one
+    shape (_over_all_pairs)."""
     order, pos, windows = sort
     head = order[:capacity]
     live_rows = jnp.minimum(sizes.sum(), capacity)
-    rows = _rows_of_tokens(x, head // weight.shape[1], pos, windows,
-                           live_rows, w1.dtype)
-    out = _grouped_products(rows, w1, w2, sizes, kernel, gate, gate_act)
-    out = _tokens_of_rows(out, weight, head, pos, windows, live_rows)
-    return out.astype(x.dtype), (pos < live_rows).sum()
+    rows = _rows_of_tokens(x, head // weight.shape[1], live_rows, w1.dtype)
+    out, kept = _grouped_products(rows, w1, w2, sizes, kernel, gate, gate_act)
+    out = _pairs_summed(out, pos, windows, live_rows, weight)
+    return (out.astype(x.dtype), (pos < live_rows).sum(),
+            tuple(_over_all_pairs(a, order.shape[0], kernel) for a in kept))
 
 
-# One function object a (capacity, kernel, gate_act): jax keeps a switch branch's
-# jaxpr by the function traced and its avals, so a model's expert layers,
-# and the forward that the gradient op traces again, trace a rung once
-# (the hybrid cell's step holds 12 such switches of one shape).
+def _over_all_pairs(rows, pairs, kernel):
+    """[C, F] -> [pairs, F]: `rows` first, the rest never read (the
+    gradient op slices the rung's rows back out). Beside the kernels the
+    rest is not written either: the rows are put at the head of a buffer
+    that a Pallas call with an empty body leaves as the allocator gave it,
+    a pass over C rows; a fill with zeros is a write of pairs - C rows of
+    each kept product a layer and step (2.8 ms a step in the Kimi-Linear
+    cell, whose small rung is an eighth of the pairs: PERF.md section 6,
+    PR 58). Without the kernels (`kernel` None) the rest is zero."""
+    if rows.shape[0] == pairs:
+        return rows
+    if kernel is None:
+        return jnp.pad(rows, ((0, pairs - rows.shape[0]), (0, 0)))
+    from jax.experimental import pallas as pl
+    unwritten = pl.pallas_call(
+        lambda out: None, name="unwritten_rows", interpret=kernel,
+        out_shape=jax.ShapeDtypeStruct((pairs,) + rows.shape[1:], rows.dtype),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY))()
+    return lax.dynamic_update_slice(unwritten, rows, (0, 0))
+
+
+def _pull_rows_back(capacity, kernel, gate_act, sort, sizes, ct, kept, x,
+                    weight, w1, w2, gate=None):
+    """_handle_rows pulled back over the same `capacity` pairs, from Out's
+    cotangent `ct` [N, D] and the forward's `kept` products: no grouped
+    product of the forward runs again. In sorted order one gather of the
+    cotangent's rows serves every gradient: with g[p] = ct[token p] and
+    t = g W2[e]^T (the down product pulled back per unit of weight),
+    dWeight[pair p] = hidden[p] . t[p] (what y[p] . g[p] is, without y),
+    dW2[e] = (weight * hidden)^T g over e's rows, and weight * t goes back
+    through the activation (in float32) to the up product's rows and the
+    gate's, whose transposes give dW1, dWGate and the rows' cotangent;
+    dX[n] sums that over the token's live pairs (_pairs_summed). The
+    tokens' rows are gathered again (a pass of the rung's size; kept, they
+    would be [N x top_k, D] a layer). Rows from `live_rows` on hold what
+    the kernels left there and reach no result: the products over groups
+    skip them and both maps back select them away.
+    -> (dX, dWeight, dW1, dW2[, dWGate]), the matrices' in their dtype."""
+    order, pos, windows = sort
+    head = order[:capacity]
+    token = head // weight.shape[1]
+    live_rows = jnp.minimum(sizes.sum(), capacity)
+    _, dot_t, dot_w = _grouped_dots(sizes, kernel, w1.shape[1], w1.shape[2],
+                                    w1.dtype)
+    g = _rows_of_tokens(ct, token, live_rows, w1.dtype)
+    by_row = weight.reshape(-1).at[head].get(
+        mode="promise_in_bounds")[:, None]
+    hidden, back = jax.vjp(functools.partial(_hidden, gate_act=gate_act),
+                           *(a[:capacity] for a in kept))
+    with jax.named_scope("moe_down"):
+        t = _f32(dot_t(g, w2))
+        d_w2 = dot_w((hidden * by_row).astype(w1.dtype), g)
+    d_by_row = (hidden * t).sum(-1)
+    d_kept = back(t * by_row)
+    rows = _rows_of_tokens(x, token, live_rows, w1.dtype)
+    with jax.named_scope("moe_up"):
+        d_rows, d_w1 = dot_t(d_kept[0], w1), dot_w(rows, d_kept[0])
+    d_mats = (d_w1, d_w2)
+    if gate is not None:
+        with jax.named_scope("moe_gate"):
+            d_rows = d_rows + dot_t(d_kept[1], gate)
+            d_mats += (dot_w(rows, d_kept[1]),)
+    ok = pos < live_rows
+    d_weight = jnp.where(ok, d_by_row.at[jnp.where(ok, pos, 0)].get(
+        mode="promise_in_bounds"), 0)
+    return (_pairs_summed(d_rows, pos, windows, live_rows,
+                          out_dtype=x.dtype), d_weight) + d_mats
+
+
+# One function object a (function, capacity, kernel, gate_act): jax keeps
+# a switch branch's jaxpr by the function traced and its avals, so a
+# model's expert layers trace a rung once (the hybrid cell's step holds 12
+# such switches of one shape).
 @functools.lru_cache(maxsize=None)
-def _rung(capacity, kernel, gate_act):
-    return functools.partial(_handle_rows, capacity, kernel, gate_act)
+def _rung(handle, capacity, kernel, gate_act):
+    return functools.partial(handle, capacity, kernel, gate_act)
 
 
-@functools.lru_cache(maxsize=None)
-def _rung_pulled_back(capacity, kernel, gate_act):
-    def branch(sort, sizes, ct, *operands):
-        _, vjp, _ = jax.vjp(functools.partial(
-            _handle_rows, capacity, kernel, gate_act, sort, sizes), *operands,
-            has_aux=True)
-        return vjp(ct)
-    return branch
+def _on_the_rung(handle, r, *operands):
+    """handle (_handle_rows or _pull_rows_back) at the capacity the step's
+    routed pairs fit, r.rungs[r.rung]: one lax.switch branch a rung, and
+    no conditional where the ladder has one. The matrices' casts to the
+    compute dtype are operands of the switch, once, not work of each
+    branch."""
+    branches = [_rung(handle, c, r.kernel, r.gate_act) for c in r.rungs]
+    operands = (r.sort, r.sizes) + operands + (r.x, r.weight) + tuple(
+        m.astype(r.dtype) for m in r.mats)
+    if len(branches) == 1:
+        return branches[0](*operands)
+    return lax.switch(r.rung, branches, *operands)
 
 
-def _cast(mats, dtype):
-    return tuple(None if m is None else m.astype(dtype) for m in mats)
+def _routed(ctx, op_, ins):
+    """What moe_experts and its gradient op both make of the op's inputs
+    (XLA merges the two where no replay's barrier stands between them):
+    the pairs sorted by held expert, the ladder and the step's rung, and
+    which kernels take the shapes."""
+    from .pallas_attention import _interpret
+
+    x = jnp.asarray(ins["X"][0])
+    idx = jnp.asarray(ins["TopkIdx"][0])
+    mats = [jnp.asarray(ins[slot][0]) for slot in ("W1", "W2", "WGate")
+            if ins.get(slot) and ins[slot][0] is not None]
+    held = op_.attr("experts_held", mats[0].shape[0])
+    n, k = idx.shape
+    assert mats[0].shape[0] == held and k == op_.attr("top_k", k)
+
+    local = idx.reshape(-1) - op_.attr("expert_offset", 0)
+    group = jnp.where((local >= 0) & (local < held), local, held)
+    order = jnp.argsort(group, stable=True)           # held experts first
+    sizes = (group[:, None] == jnp.arange(held)).sum(0, dtype=jnp.int32)
+
+    reason = gmm_ineligible(n * k, x.shape[-1], mats[0].shape[-1])
+    rungs = _capacity_ladder(n * k, held, op_.attr("num_experts", held))
+    # the token side's kernel, if it takes every rung (_pairs_summed)
+    declined = next(filter(None, (pallas_pair_sum.ineligible(
+        n, c, x.shape[-1], held) for c in rungs)), None)
+    return types.SimpleNamespace(
+        x=x, weight=_f32(ins["TopkWeight"][0]), mats=mats,
+        dtype=_compute_dtype(ctx), gate_act=op_.attr("gate_act", "silu"),
+        held=held, group=group, sizes=sizes, reason=reason, declined=declined,
+        kernel=_interpret() if reason is None else None, rungs=rungs,
+        rung=(sizes.sum() > jnp.asarray(rungs[:-1], jnp.int32)).sum(),
+        sort=(order, jnp.argsort(order).astype(jnp.int32).reshape(n, k),
+              None if declined else pallas_pair_sum.pair_windows(
+                  group, held, k)))
 
 
-def _switch_rows(rungs, kernel, gate_act, dtype, rung, sort, sizes, x, weight,
-                 *mats):
-    """_handle_rows at the capacity rungs[rung], one branch a rung; the
-    matrices' casts to `dtype` are operands of the switch, once, not work
-    of each branch."""
-    return lax.switch(rung, [_rung(c, kernel, gate_act) for c in rungs],
-                      sort, sizes, x, weight, *_cast(mats, dtype))
+def _experts_grad(fwd, no_grad_set):
+    """moe_experts_grad reads the op's inputs, Out's cotangent and the
+    products the forward kept (_KEPT), as nn_ops._sdpa_grad reads LSE:
+    the generic maker's op would trace the forward again inside the
+    gradient op, and behind a conditional XLA merges none of it with the
+    original."""
+    wanted = [s for s in ("X", "TopkWeight", "W1", "W2", "WGate")
+              if fwd.inputs.get(s) and fwd.input(s)[0] not in no_grad_set]
+    if not wanted:
+        return []
+    kept = _KEPT if fwd.inputs.get("WGate") else _KEPT[:1]
+    missing = [s for s in kept if not fwd.outputs.get(s)]
+    if missing:
+        raise ValueError(
+            f"moe_experts (Out {fwd.output('Out')}): its gradient reads the "
+            f"output slots {missing}, which the op was built without")
+    return [OpDesc(
+        type=fwd.type + "_grad",
+        inputs={**{s: list(names) for s, names in fwd.inputs.items()},
+                **{s: fwd.output(s) for s in kept},
+                "Out@GRAD": [grad_var_name(fwd.output("Out")[0])]},
+        outputs={s + "@GRAD": [grad_var_name(fwd.input(s)[0])]
+                 for s in wanted},
+        attrs=dict(fwd.attrs))]
 
 
-# _switch_rows under a rule of the op's own: autodiff through lax.switch
-# keeps every branch's residuals, zero-filled for the branches not taken,
-# a write of [N x top_k, D] a layer whatever was routed. Here the forward
-# keeps its inputs (the layer's own, so nothing new lives from the forward
-# to the backward) and the backward is one switch whose branch takes the
-# gradient of its own forward and pulls back inside it, so no residual
-# leaves a branch (the forward products run again there).
-_handle_routed_rows = jax.custom_vjp(_switch_rows,
-                                     nondiff_argnums=(0, 1, 2, 3))
-
-
-def _handle_routed_rows_fwd(rungs, kernel, gate_act, dtype, *args):
-    return _switch_rows(rungs, kernel, gate_act, dtype, *args), args
-
-
-def _handle_routed_rows_bwd(rungs, kernel, gate_act, dtype, args, cts):
-    rung, sort, sizes, x, weight, *mats = args
-    d_x, d_weight, *d_mats = lax.switch(
-        rung, [_rung_pulled_back(c, kernel, gate_act) for c in rungs], sort,
-        sizes,
-        cts[0], x, weight, *_cast(mats, dtype))
-    # barrier: the matrices' gradients leave the switch in the compute
-    # dtype and wait for the optimizer at the step's end. Unpinned, XLA
-    # moves their casts to the parameters' float32 into every branch, and
-    # five gated layers then hold 1.5 GB of float32 gradients where the
-    # layer without a switch held half (the cast fuses into Adam's update)
-    d_mats = lax.optimization_barrier(d_mats)
-    return (None, None, None, d_x, d_weight) + tuple(
-        None if m is None else d.astype(m.dtype) for d, m in zip(d_mats, mats))
-
-
-_handle_routed_rows.defvjp(_handle_routed_rows_fwd, _handle_routed_rows_bwd)
-
-
-@op("moe_experts", infer_shape=_experts_infer, non_diff_inputs=("TopkIdx",))
+@op("moe_experts", infer_shape=_experts_infer, grad=_experts_grad,
+    non_diff_inputs=("TopkIdx",))
 def _moe_experts(ctx, op_, ins):
     """The routed experts' part of a mixture-of-experts layer, for the
     `experts_held` experts from `expert_offset` on of `num_experts`:
@@ -994,66 +1086,62 @@ def _moe_experts(ctx, op_, ins):
     shape does not tile, booked with the reason). The rows then return to
     their tokens by a second gather, through the inverse of the sort: a
     token reads the rows of its top_k pairs, weights and adds them in
-    float32. The gradient gathers both ways too (_rows_of_tokens,
-    _tokens_of_rows): no [N, D] is zero-filled and nothing is
-    scatter-added, forward or backward.
+    float32. The gradient op gathers both ways too (_pull_rows_back): no
+    [N, D] is zero-filled and nothing is scatter-added, forward or
+    backward.
 
     The rows gathered, multiplied and gathered back are the first C pairs
     of that order, C the smallest rung of _capacity_ladder that holds the
     step's routed pairs, chosen on the device (lax.switch, one branch a
-    rung, in the gradient too); the last rung is all N x top_k, so no
-    step drops a row, and a layer that holds every expert has that rung
-    alone and no conditional.
+    rung, in the gradient op too); the last rung is all N x top_k, so no
+    step drops a row, and a layer that holds more than an eighth of the
+    experts has that rung alone and no conditional.
 
+    Up, and GateUp of the gated form [N x top_k, F] in the compute dtype:
+    the up product's rows and the gate's in sorted order, undefined past
+    the rung taken, which the gradient op reads where it would run the
+    forward's products again (dear to compute, F wide to hold; a replayed
+    segment's forward hands them on, and the first forward's are dead).
     RowsRouted [1]: the pairs the router sent to held experts, counted
     on its indices; RowsCombined [1]: the pairs whose rows the grouped
     product was given and their tokens read back, counted where they are
     combined (the two differ only if a row is lost between them);
     LoadMaxOverMean [1]: the busiest held expert's rows over the held
     experts' mean; RowsHandled [1]: the rung taken."""
-    from .pallas_attention import _interpret
-
-    x = jnp.asarray(ins["X"][0])
-    idx = jnp.asarray(ins["TopkIdx"][0])
-    weight = _f32(ins["TopkWeight"][0])
-    dtype = _compute_dtype(ctx)
-    w1, w2 = jnp.asarray(ins["W1"][0]), jnp.asarray(ins["W2"][0])
-    gate = None
-    if ins.get("WGate") and ins["WGate"][0] is not None:
-        gate = jnp.asarray(ins["WGate"][0])
-    gate_act = op_.attr("gate_act", "silu")
-    held = op_.attr("experts_held", w1.shape[0])
-    n, k = idx.shape
-    assert w1.shape[0] == held and k == op_.attr("top_k", k)
-
-    local = idx.reshape(-1) - op_.attr("expert_offset", 0)
-    group = jnp.where((local >= 0) & (local < held), local, held)
-    order = jnp.argsort(group, stable=True)           # held experts first
-    sizes = (group[:, None] == jnp.arange(held)).sum(0, dtype=jnp.int32)
-    routed = sizes.sum()
-
-    reason = gmm_ineligible(n * k, x.shape[-1], w1.shape[-1])
-    kernel_choice.book(_GMM_OP, reason)
-    kernel = _interpret() if reason is None else None
-    rungs = _capacity_ladder(n * k, held, op_.attr("num_experts", held))
-    rung = (routed > jnp.asarray(rungs[:-1], jnp.int32)).sum()
-    # the token side's kernel, if it takes every rung (_pairs_summed)
-    declined = next(filter(None, (pallas_pair_sum.ineligible(
-        n, c, x.shape[-1], held) for c in rungs)), None)
-    kernel_choice.book(_PAIR_SUM_OP, declined)
-    sort = (order, jnp.argsort(order).astype(jnp.int32).reshape(n, k),
-            None if declined else pallas_pair_sum.pair_windows(group, held, k))
-    if len(rungs) == 1:    # no conditional and no gradient rule of its own
-        out, combined = _handle_rows(rungs[0], kernel, gate_act, sort, sizes,
-                                     x, weight, *_cast((w1, w2, gate), dtype))
-    else:
-        out, combined = _handle_routed_rows(rungs, kernel, gate_act, dtype,
-                                            rung, sort, sizes, x, weight, w1,
-                                            w2, gate)
-
-    load = sizes.max() / jnp.maximum(routed / held, 1.0)
+    r = _routed(ctx, op_, ins)
+    kernel_choice.book(_GMM_OP, r.reason)
+    kernel_choice.book(_PAIR_SUM_OP, r.declined)
+    out, combined, kept = _on_the_rung(_handle_rows, r)
+    routed = r.sizes.sum()
+    load = r.sizes.max() / jnp.maximum(routed / r.held, 1.0)
     return {"Out": [out],
-            "RowsRouted": [_f32((group < held).sum()).reshape(1)],
+            **{slot: [a] for slot, a in zip(_KEPT, kept)},
+            "RowsRouted": [_f32((r.group < r.held).sum()).reshape(1)],
             "RowsCombined": [_f32(combined).reshape(1)],
             "LoadMaxOverMean": [_f32(load).reshape(1)],
-            "RowsHandled": [_f32(jnp.asarray(rungs)[rung]).reshape(1)]}
+            "RowsHandled": [_f32(jnp.asarray(r.rungs)[r.rung]).reshape(1)]}
+
+
+@op("moe_experts_grad", grad=NO_GRAD)
+def _moe_experts_grad(ctx, op_, ins):
+    """dX, dTopkWeight and the matrices' gradients from Out's cotangent
+    and the forward's kept products, on the rung the forward took (the
+    same routed count picks it): _pull_rows_back, which runs the pulled-
+    back grouped products and no forward one."""
+    r = _routed(ctx, op_, ins)
+    kept = tuple(jnp.asarray(ins[slot][0])      # one a matrix but W2
+                 for slot in _KEPT[:len(r.mats) - 1])
+    d_x, d_weight, *d_mats = _on_the_rung(
+        _pull_rows_back, r, jnp.asarray(ins["Out@GRAD"][0]), kept)
+    # barrier: the matrices' gradients leave the switch in the compute
+    # dtype and wait for the optimizer at the step's end. Unpinned, XLA
+    # moves their casts to the parameters' float32 into every branch, and
+    # five gated layers then hold 1.5 GB of float32 gradients where the
+    # layer without a switch held half
+    d_mats = lax.optimization_barrier(d_mats)
+    grads = {"X": d_x, "TopkWeight": d_weight.astype(
+        jnp.asarray(ins["TopkWeight"][0]).dtype)}
+    for slot, m, d in zip(("W1", "W2", "WGate"), r.mats, d_mats):
+        grads[slot] = d.astype(m.dtype)
+    return {slot + "@GRAD": [g] for slot, g in grads.items()
+            if slot + "@GRAD" in op_.desc.outputs}

@@ -710,20 +710,26 @@ def test_shares_add_up_to_the_uncut_layer():
     assert np.asarray(weight).sum(-1) == pytest.approx(scaling, rel=1e-5)
 
 
-def test_the_ladder_has_two_rungs_at_a_thirty_second():
+@pytest.mark.parametrize("held,small", [(8, 8192), (16, 16384), (32, 16384)],
+                         ids=["a_thirty_second", "a_sixteenth", "an_eighth"])
+def test_the_ladder_has_two_rungs_at_a_thirty_second(held, small):
     """8 held of 256 at 8192 tokens and top 8: the capacity ladder gives
     8192 | 65536 pairs, and a uniform router's 2048 rows take the small
-    one."""
-    ladder = hybrid_ops._capacity_ladder(8192 * 8, 8, 256)
-    assert list(ladder) == [8192, 65536]
+    one; a share of 16 has four times its 4096 rows, and one of 32 (the
+    gated window cell's eighth) twice its 8192: a quarter of the pairs."""
+    ladder = hybrid_ops._capacity_ladder(8192 * 8, held, 256)
+    assert list(ladder) == [small, 65536]
+    assert small >= 2 * 8192 * 8 * held // 256
 
 
 # --- 5. what asks for none of it ---------------------------------------------
 
 PARENT_PROGRAMS = {
     # sha256 of main.to_json() | startup.to_json() at PR 54's tree
-    "glm-4.7-flash": ("229a908b9f6b2a16", "57465f9570324186"),
-    "nemotron3-nano-30b-a3b": ("46a0a0ae673c6e3c", "2cd691daa316a1c1"),
+    # (the programs with expert layers: at PR 58's, whose moe_experts ops
+    # write Up / GateUp for an explicit gradient op)
+    "glm-4.7-flash": ("c6c56c119b5b8e5c", "57465f9570324186"),
+    "nemotron3-nano-30b-a3b": ("b3acdca94fc207b9", "2cd691daa316a1c1"),
     "granite-4.0-h-micro": ("34df072b045df201", "aef2a12bd424d1b5"),
 }
 

@@ -184,7 +184,8 @@ def _experts():
         inputs={"X": f32(n, d) * 0.5, "TopkIdx": idx, "TopkWeight": weight,
                 "W1": f32(held, d, f) * 0.2, "W2": f32(held, f, d) * 0.2},
         outputs={"Out": "float32", "RowsRouted": "float32",
-                 "RowsCombined": "float32", "LoadMaxOverMean": "float32"},
+                 "RowsCombined": "float32", "LoadMaxOverMean": "float32",
+                 "Up": "float32"},
         attrs={"num_experts": 16, "experts_held": held, "expert_offset": 4,
                "top_k": k},
         wrt=("X", "TopkWeight", "W1", "W2"))
@@ -221,7 +222,8 @@ def _mul_o3():
 
 # the lowerings that book themselves, and all that one forward lowering
 # books: generic gradients (jax.vjp traces the forward lowering again)
-# but for conv2d, whose explicit gradient op asks no gate
+# but for conv2d's and moe_experts', whose explicit gradient ops book
+# nothing (the experts' takes the forward's choice in silence)
 BOOKED = {
     "ssd_scan": (_scan, {"pallas_kernel_total": {"op=ssd_scan": 1}}),
     "kda_scan": (_delta_rule(128, 128),

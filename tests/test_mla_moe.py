@@ -176,7 +176,8 @@ def test_gated_shares_add_up_to_the_uncut_layer():
              "W1": up[offset:offset + held],
              "W2": down[offset:offset + held]},
             {"Out": "float32", "RowsRouted": "float32",
-             "RowsCombined": "float32", "LoadMaxOverMean": "float32"},
+             "RowsCombined": "float32", "LoadMaxOverMean": "float32",
+             "Up": "float32", "GateUp": "float32"},
             {"num_experts": 8, "experts_held": held,
              "expert_offset": offset, "top_k": k}, wrt)
 
@@ -213,9 +214,10 @@ def program_text(*programs):
 def test_moe_block_without_gated_is_the_program_it_was():
     """The hybrid cell's layer: its ops, slots, names and attributes,
     forward, backward and startup, hash to what the tree before the gated
-    form gave (tools: the same lines on commit 6312135) with the one
-    output slot `RowsHandled` that PR 36 added to `moe_experts` (and, as
-    a forward output, to its gradient op's inputs)."""
+    form gave (tools: the same lines on commit 6312135) with the output
+    slot `RowsHandled` that PR 36 added to `moe_experts` and the slot `Up`
+    that PR 58 added for its gradient op, which since then is the op's
+    own (the inputs, Up and Out's cotangent) and not the generic one."""
     main, startup = fluid.Program(), fluid.Program()
     with unique_name.guard(), fluid.program_guard(main, startup):
         x = fluid.layers.data(name="x", shape=[2, 8, 16], dtype="float32",
@@ -227,7 +229,7 @@ def test_moe_block_without_gated_is_the_program_it_was():
     text = program_text(main, startup)
     assert "WGate" not in text and "silu" not in text
     assert hashlib.sha256(text.encode()).hexdigest() == \
-        "a9ac05b0f0ed0d8c2f596bf59648a8c1fe1a4283683dfcf02816ae87098b84f7"
+        "90bbd9d550472d245975d9750e31e151731af045309abf4018343c6e855f4904"
     # and the same call's gated form has the third matrix
     with unique_name.guard(), fluid.program_guard(fluid.Program(),
                                                   fluid.Program()):

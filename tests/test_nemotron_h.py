@@ -462,7 +462,8 @@ def test_experts_drop_no_row_under_a_skewed_router(n, d, f, path):
     outs, grads, cot = run_op(
         "moe_experts", ins,
         {"Out": "float32", "RowsRouted": "float32",
-         "RowsCombined": "float32", "LoadMaxOverMean": "float32"},
+         "RowsCombined": "float32", "LoadMaxOverMean": "float32",
+         "Up": "float32"},
         {"num_experts": 16, "experts_held": held, "expert_offset": offset,
          "top_k": k}, ("X", "TopkWeight", "W1", "W2"))
     close(outs["Out"], expert_loop(x, idx, weight, w1, w2, offset), tol=1e-4)
@@ -525,14 +526,14 @@ def test_shares_add_up_to_the_uncut_layer():
 HELD, OFFSET, EXPERTS = 2, 4, 32
 
 
-def routed_by_hand(rng, n, k, held_pairs):
+def routed_by_hand(rng, n, k, held_pairs, held=HELD):
     """(idx [n, k], weight [n, k]) with exactly `held_pairs` of the n * k
-    (token, slot) pairs on the held experts OFFSET .. OFFSET + HELD - 1,
+    (token, slot) pairs on the held experts OFFSET .. OFFSET + held - 1,
     scattered over tokens and slots, every other pair on an absent one."""
-    absent = np.setdiff1d(np.arange(EXPERTS), OFFSET + np.arange(HELD))
+    absent = np.setdiff1d(np.arange(EXPERTS), OFFSET + np.arange(held))
     flat = rng.choice(absent, n * k)
     here = rng.permutation(n * k)[:held_pairs]
-    flat[here] = OFFSET + rng.integers(0, HELD, held_pairs)
+    flat[here] = OFFSET + rng.integers(0, held, held_pairs)
     return flat.reshape(n, k).astype(np.int32), \
         rng.random((n, k)).astype(np.float32) + 0.1
 
@@ -540,11 +541,12 @@ def routed_by_hand(rng, n, k, held_pairs):
 def experts_inputs(rng, n, d, f, k, held_pairs, gated, held=HELD,
                    experts=EXPERTS):
     """moe_experts' inputs for `held` of `experts` experts over `n`
-    tokens: `held_pairs` pairs routed to the held ones by hand (HELD from
-    OFFSET on), or with None a random router's choice of `experts`."""
+    tokens: `held_pairs` pairs routed to the held ones by hand (`held`
+    of EXPERTS from OFFSET on), or with None a random router's choice of
+    `experts`."""
     ins = {"X": rng.standard_normal((n, d)).astype(np.float32) * 0.5}
     ins["TopkIdx"], ins["TopkWeight"] = routed(rng, n, k, experts) \
-        if held_pairs is None else routed_by_hand(rng, n, k, held_pairs)
+        if held_pairs is None else routed_by_hand(rng, n, k, held_pairs, held)
     for slot, shape in (("WGate", (held, d, f)), ("W1", (held, d, f)),
                         ("W2", (held, f, d))):
         if gated or slot != "WGate":
@@ -558,61 +560,84 @@ def run_experts(ins, wrt=(), offset=OFFSET, experts=EXPERTS):
     return run_op(
         "moe_experts", ins,
         dict.fromkeys(("Out", "RowsRouted", "RowsCombined",
-                       "LoadMaxOverMean", "RowsHandled"), "float32"),
+                       "LoadMaxOverMean", "RowsHandled", "Up")
+                      + (("GateUp",) if "WGate" in ins else ()), "float32"),
         {"num_experts": experts, "experts_held": ins["W1"].shape[0],
          "expert_offset": offset, "top_k": ins["TopkIdx"].shape[1]},
         tuple(s for s in wrt if s in ins))
 
 
-def experts_op(rng, n, d, f, k, held_pairs, gated, wrt=()):
-    """One moe_experts op holding HELD of EXPERTS experts over `n` tokens
-    with `held_pairs` pairs routed to them. -> run_op's triple."""
-    return run_experts(experts_inputs(rng, n, d, f, k, held_pairs, gated),
-                       wrt)
+def experts_op(rng, n, d, f, k, held_pairs, gated, wrt=(), held=HELD):
+    """One moe_experts op holding `held` of EXPERTS experts over `n`
+    tokens with `held_pairs` pairs routed to them. -> run_op's triple."""
+    return run_experts(experts_inputs(rng, n, d, f, k, held_pairs, gated,
+                                      held), wrt)
 
 
-def test_the_ladder_is_a_function_of_shapes_and_the_share():
+LADDERS = {
+    # four times the uniform share where that is at most a quarter of the
+    # pairs: a sixteenth of the experts or less
+    "hybrid_cell": ((24576, 8, 128), (6144, 24576)),
+    "a_sixty_fourth": ((24576, 2, 128), (1536, 24576)),
+    "a_sixteenth": ((16384, 4, 64), (4096, 16384)),
+    "block_diffusion_cell": ((65536, 8, 128), (16384, 65536)),
+    "delta_rule_cell": ((65536, 8, 256), (8192, 65536)),
+    # twice the share for an eighth: a quarter of the pairs
+    "latent_cell": ((16384, 8, 64), (4096, 16384)),
+    "gated_window_cell": ((65536, 32, 256), (16384, 65536)),
+    "sliding_window_cell": ((49152, 8, 64), (12288, 49152)),
+    "an_eighth_on_ragged_dot": ((240, 4, 32), (60, 240)),
+    # more than an eighth: the one rung
+    "a_quarter": ((16384, 16, 64), (16384,)),
+    "over_an_eighth": ((240, 5, 32), (240,)),
+    "all_held": ((24576, 128, 128), (24576,)),
+    # the rung tiles as the pairs do
+    "ragged_dot": ((240, 2, 32), (60, 240)),
+    "kernel": ((512, 2, 32), (128, 512)),
+    "64_would_not_tile": ((512, 1, 64), (128, 512)),
+    "125_has_no_half": ((250, 1, 128), (250,)),
+}
+
+
+@pytest.mark.parametrize("case", LADDERS)
+def test_the_ladder_is_a_function_of_shapes_and_the_share(case):
     from paddle_tpu.ops.hybrid_ops import _capacity_ladder, gmm_ineligible
-    assert _capacity_ladder(24576, 8, 128) == (6144, 24576)   # 4 E = 6144
-    assert _capacity_ladder(24576, 2, 128) == (1536, 24576)   # 4 E = 1536
-    assert _capacity_ladder(16384, 8, 64) == (16384,)         # 4 E = a half
-    assert _capacity_ladder(16384, 4, 64) == (4096, 16384)
-    assert _capacity_ladder(24576, 128, 128) == (24576,)      # all held
-    assert _capacity_ladder(240, 2, 32) == (60, 240)          # ragged_dot
-    assert _capacity_ladder(512, 2, 32) == (128, 512)
-    assert _capacity_ladder(512, 1, 64) == (128, 512)         # 64 would not tile
-    assert _capacity_ladder(250, 1, 128) == (250,)            # 125 has no half
-    for pairs, held, experts in ((24576, 8, 128), (240, 2, 32), (512, 1, 64)):
-        assert len({gmm_ineligible(c, 2688, 1856)
-                    for c in _capacity_ladder(pairs, held, experts)}) == 1
+    (pairs, held, experts), rungs = LADDERS[case]
+    assert _capacity_ladder(pairs, held, experts) == rungs
+    assert rungs[-1] == pairs and len(rungs) <= 2   # no pair is dropped
+    assert all(4 * c <= pairs for c in rungs[:-1])
+    assert len({gmm_ineligible(c, 2688, 1856) for c in rungs}) == 1
 
 
-# 60 tokens x 4 slots, 2 of 32 experts held: rungs 60, 240 on
-# lax.ragged_dot; 128 x 4 at a lane block's widths: 128, 512 on the kernel
+# 60 tokens x 4 slots, 2 of 32 experts held (a sixteenth) or 4 (an
+# eighth): rungs 60, 240 on lax.ragged_dot; 128 x 4 at a lane block's
+# widths: 128, 512 on the kernel
 LADDER_CASES = [(60, 24, 40, r) for r in (0, 59, 60, 61, 240)] \
     + [(128, 128, 128, r) for r in (100, 129)]
 
 
+@pytest.mark.parametrize("held", [HELD, 4], ids=["a_sixteenth", "an_eighth"])
 @pytest.mark.parametrize("gated", [False, True], ids=["plain", "gated"])
 @pytest.mark.parametrize("n,d,f,held_pairs", LADDER_CASES)
 def test_a_rung_gives_what_the_whole_layer_gives(n, d, f, held_pairs, gated,
-                                                 monkeypatch):
-    """Nothing routed, one under a rung, exactly a rung, one over it and
-    every pair: Out and every gradient equal those of the layer with the
-    full size as its only rung."""
+                                                 held, monkeypatch):
+    """Nothing routed, one under a rung, exactly a rung, one over it (the
+    full rung taken by overflow) and every pair: Out and every gradient
+    equal those of the layer with the full size as its only rung and no
+    switch, and no routed row is lost."""
     from paddle_tpu.ops import hybrid_ops
     wrt = ("X", "TopkWeight", "WGate", "W1", "W2")
     seed = 1000 * n + held_pairs
     outs, grads, _ = experts_op(np.random.default_rng(seed), n, d, f, 4,
-                                held_pairs, gated, wrt)
-    rungs = hybrid_ops._capacity_ladder(n * 4, HELD, EXPERTS)
-    assert len(rungs) > 1
+                                held_pairs, gated, wrt, held)
+    rungs = hybrid_ops._capacity_ladder(n * 4, held, EXPERTS)
+    assert rungs == (n, 4 * n)
     assert outs["RowsRouted"][0] == outs["RowsCombined"][0] == held_pairs
     assert outs["RowsHandled"][0] == min(c for c in rungs if c >= held_pairs)
     monkeypatch.setattr(hybrid_ops, "_capacity_ladder",
                         lambda pairs, held, experts: (pairs,))
     whole, whole_grads, _ = experts_op(np.random.default_rng(seed), n, d, f,
-                                       4, held_pairs, gated, wrt)
+                                       4, held_pairs, gated, wrt, held)
     assert whole["RowsHandled"][0] == n * 4
     assert whole["RowsCombined"][0] == held_pairs
     close(outs["Out"], whole["Out"], tol=1e-6)
@@ -631,13 +656,13 @@ class _Attrs:
         return self.attrs.get(name, default)
 
 
-@pytest.mark.parametrize("held,conds", [(EXPERTS, 0), (EXPERTS // 8, 0),
-                                        (2, 1)])
+@pytest.mark.parametrize("held,conds", [(EXPERTS, 0), (EXPERTS // 4, 0),
+                                        (EXPERTS // 8, 1), (2, 1)])
 def test_a_layer_that_holds_every_expert_has_no_conditional(held, conds):
-    """One rung (every expert held, or four times the share more than a
-    quarter of the pairs): the lowering and its gradient hold no `cond`;
-    a sixteenth's forward holds one and its gradient one more (the
-    forward's, which the compiler drops where nothing reads it)."""
+    """One rung (every expert held, or more than an eighth): the lowering
+    and the gradient op's hold no `cond`; an eighth's or a sixteenth's
+    forward holds one and its gradient op one, its own: it reads the
+    forward's kept products and traces no forward."""
     import types
     from paddle_tpu.ops import hybrid_ops
     rng = np.random.default_rng(held)
@@ -648,17 +673,33 @@ def test_a_layer_that_holds_every_expert_has_no_conditional(held, conds):
     w2 = rng.standard_normal((held, f, d)).astype(np.float32)
     op_ = _Attrs(num_experts=EXPERTS, experts_held=held, expert_offset=0,
                  top_k=k)
+    op_.desc = types.SimpleNamespace(outputs=dict.fromkeys(
+        s + "@GRAD" for s in ("X", "TopkWeight", "W1", "W2")))
+    ctx = types.SimpleNamespace(amp_dtype=None)
 
-    def out(x, weight, w1, w2):
-        return hybrid_ops._moe_experts(
-            types.SimpleNamespace(amp_dtype=None), op_,
-            {"X": [x], "TopkIdx": [idx], "TopkWeight": [weight],
-             "W1": [w1], "W2": [w2]})["Out"][0].sum()
+    def ins(x, weight, w1, w2):
+        return {"X": [x], "TopkIdx": [idx], "TopkWeight": [weight],
+                "W1": [w1], "W2": [w2]}
 
-    assert str(jax.make_jaxpr(out)(x, weight, w1, w2)).count("cond[") == conds
-    grad = jax.make_jaxpr(jax.grad(out, argnums=(0, 1, 2, 3)))(
-        x, weight, w1, w2)
-    assert str(grad).count("cond[") == 2 * conds
+    def out(*operands):
+        return hybrid_ops._moe_experts(ctx, op_, ins(*operands))
+
+    def grads(up, ct, *operands):
+        return hybrid_ops._moe_experts_grad(
+            ctx, op_, dict(ins(*operands), **{"Up": [up], "Out@GRAD": [ct]}))
+
+    forward = jax.make_jaxpr(out)(x, weight, w1, w2)
+    assert str(forward).count("cond[") == conds
+    outs = out(x, weight, w1, w2)
+    assert outs["Up"][0].shape == (n * k, f) and "GateUp" not in outs
+    pulled = jax.make_jaxpr(grads)(outs["Up"][0], outs["Out"][0], x, weight,
+                                   w1, w2)
+    assert str(pulled).count("cond[") == conds
+    # a rung: the forward's two products; in the gradient op the two
+    # pulled back to the rows and the two to the matrices, and no
+    # forward one
+    assert str(forward).count("ragged_dot_general[") == 2 * (conds + 1)
+    assert str(pulled).count("ragged_dot_general[") == 4 * (conds + 1)
 
 
 @pytest.mark.parametrize("held_pairs", [0, 1, 59, 60, 61, 239, 240])
@@ -707,6 +748,14 @@ GATHER_CASES = {
                                     "rows"),
     "rows_do_not_tile_full_rung": (60, 24, 40, 32, 2, 4, 61, True, 2, "rows"),
     "nothing_routed_here": (60, 24, 40, 32, 2, 4, 0, True, 2, "rows"),
+    # an eighth held: twice the uniform share is the small rung
+    "an_eighth_small_rung_taken": (128, 128, 128, 32, 4, 4, 120, True, 2,
+                                   None),
+    "an_eighth_full_rung_taken": (128, 128, 128, 32, 4, 4, 129, False, 2,
+                                  None),
+    "an_eighth_rows_do_not_tile_full_rung": (60, 24, 40, 32, 4, 4, 100, True,
+                                             2, "rows"),
+    "a_quarter_has_one_rung": (60, 24, 40, 32, 8, 4, 100, True, 1, "rows"),
 }
 
 
@@ -718,8 +767,7 @@ def test_rows_return_by_gather_as_by_the_scatter_add(case):
     from paddle_tpu.ops import hybrid_ops
     n, d, f, experts, held, offset, held_pairs, gated, n_rungs, reason = \
         GATHER_CASES[case]
-    assert held_pairs is None or (held, offset, experts) == \
-        (HELD, OFFSET, EXPERTS)
+    assert held_pairs is None or (offset, experts) == (OFFSET, EXPERTS)
     k = 4
     ins = experts_inputs(np.random.default_rng(len(case) + n), n, d, f, k,
                          held_pairs, gated, held, experts)
@@ -943,15 +991,18 @@ def test_routing_statistics_reach_telemetry_by_layer():
         assert 1.0 <= load["sum"] / 3 <= 4.0
 
 
-def test_a_model_trains_the_same_with_the_ladder_as_with_one_rung(monkeypatch):
-    """The tiny model as a 1/16 share (4 of 64 experts held: rungs 144 and
-    576 of its 192 tokens x 3 slots), three Adam steps in float32: the
-    losses equal those of the same model with the full size as its only
-    rung, and the steps took the first rung."""
+@pytest.mark.parametrize("experts", [64, 32],
+                         ids=["a_sixteenth", "an_eighth"])
+def test_a_model_trains_the_same_with_the_ladder_as_with_one_rung(
+        experts, monkeypatch):
+    """The tiny model as a 1/16 share or an eighth (4 of 64 or of 32
+    experts held: rungs 144 and 576 of its 192 tokens x 3 slots), three
+    Adam steps in float32: the losses equal those of the same model with
+    the full size as its only rung, and the steps took the first rung."""
     from paddle_tpu import telemetry
     from paddle_tpu.ops import hybrid_ops
     config = dict(run.load_json("configs", "tiny-nemotron-h", DATA),
-                  n_routed_experts_published=64)
+                  n_routed_experts_published=experts)
     family = run.load_module("families", config["family"])
     feed = family.make_batch(config, 2, np.random.default_rng(0))
 
@@ -969,7 +1020,7 @@ def test_a_model_trains_the_same_with_the_ladder_as_with_one_rung(monkeypatch):
             "moe_rows_handled", program=telemetry.program_label(main),
             layer="0")
 
-    assert hybrid_ops._capacity_ladder(feed["tok"].size * 3, 4, 64) == (
+    assert hybrid_ops._capacity_ladder(feed["tok"].size * 3, 4, experts) == (
         144, 576)
     with_ladder, handled = losses()
     monkeypatch.setattr(hybrid_ops, "_capacity_ladder",

@@ -143,21 +143,24 @@ def _booked():
                          "op=pair_sum,reason=tokens", 0)])
 
 
+@pytest.mark.parametrize("held", [HELD, 4], ids=["a_sixteenth", "an_eighth"])
 @pytest.mark.parametrize("gated", [False, True], ids=["relu2", "gated"])
 @pytest.mark.parametrize("held_pairs", [100, 600], ids=["rung0", "rung1"])
-def test_the_layers_gradient_through_both_rungs(held_pairs, gated,
+def test_the_layers_gradient_through_both_rungs(held_pairs, gated, held,
                                                 monkeypatch):
-    """moe_experts over a two-rung ladder (256 | 1024 pairs) with the
-    kernel on both of the token side's maps, forward and pulled back,
-    against the same op with the gate declined: Out and every gradient to
-    1e-6 in float32, the choice booked once a forward lowering of the op
-    (run_op lowers it in two programs) and never by a gradient's."""
+    """moe_experts over a two-rung ladder (256 | 1024 pairs: four times
+    a sixteenth's share, twice an eighth's) with the kernel on both of
+    the token side's maps, forward and pulled back, the small rung and
+    the full one taken by overflow, against the same op with the gate
+    declined: Out and every gradient to 1e-6 in float32, the choice
+    booked once a forward lowering of the op (run_op lowers it in two
+    programs) and never by the gradient op's."""
     n, d, f, k = 256, 128, 128, 4
     wrt = ("X", "TopkWeight", "WGate", "W1", "W2")
-    assert hybrid_ops._capacity_ladder(n * k, HELD, EXPERTS) == (256, 1024)
+    assert hybrid_ops._capacity_ladder(n * k, held, EXPERTS) == (256, 1024)
     before = _booked()
     outs, grads, _ = experts_op(np.random.default_rng(held_pairs), n, d, f, k,
-                                held_pairs, gated, wrt)
+                                held_pairs, gated, wrt, held)
     lowered, took, declined = _booked() - before
     assert lowered == took > 0 == declined
     assert outs["RowsRouted"][0] == outs["RowsCombined"][0] == held_pairs
@@ -167,7 +170,7 @@ def test_the_layers_gradient_through_both_rungs(held_pairs, gated,
                         lambda *shape: "tokens")
     before = _booked()
     plain, plain_grads, _ = experts_op(np.random.default_rng(held_pairs), n,
-                                       d, f, k, held_pairs, gated, wrt)
+                                       d, f, k, held_pairs, gated, wrt, held)
     lowered, took, declined = _booked() - before
     assert lowered == declined > 0 == took
     close(outs["Out"], plain["Out"], tol=1e-6)

@@ -268,8 +268,8 @@ def test_grouped_expert_products_compile(mosaic, one_chip, rows, dtype):
 
     def grads(x, w1, w2, sizes):
         return jax.grad(lambda *a: hybrid_ops._grouped_products(
-            *a, sizes, False).astype(jnp.float32).sum(), argnums=(0, 1, 2))(
-                x, w1, w2)
+            *a, sizes, False)[0].astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(x, w1, w2)
 
     assert _compile(grads, one_chip, ((rows, d), dtype), ((held, d, f), dtype),
                     ((held, f, d), dtype), ((held,), jnp.int32)) == [
@@ -409,14 +409,15 @@ def _hybrid_mixer_and_expert_step(one_chip):
 
 def test_hybrid_expert_layer_step_holds_one_switch_each_way(mosaic, one_chip):
     """One mixer and one expert layer of the hybrid cell: the expert
-    layer's forward and its gradient are one conditional each, two
-    branches (the rungs 6144 and 24576), the forward that the gradient op
-    traces again is dropped, and each gradient branch runs its forward's
-    two products, their two partners on the rows and two on the weights;
-    the token side's kernel runs once in each of the four branches (PR 47:
-    the forward's weighted map, the gradient's pulled-back one).
-    The mixer's scan is one forward kernel (the one the gradient op
-    traces again merged with it) and one gradient kernel."""
+    layer's forward and its gradient op are one conditional each, two
+    branches (the rungs 6144 and 24576); a forward branch runs the two
+    grouped products and a gradient branch their two partners on the rows
+    and two on the weights and NO forward product (PR 58: it reads the up
+    product's rows the forward kept); the token side's kernel runs once in
+    each of the four branches (PR 47: the forward's weighted map, the
+    gradient's pulled-back one). The mixer's scan is one forward kernel
+    (the one the gradient op traces again merged with it) and one gradient
+    kernel."""
     text = _hybrid_mixer_and_expert_step(one_chip)
     # (the step's one other conditional is the executor's own, outside
     # any op: `jit(fn)/cond`)
@@ -425,10 +426,14 @@ def test_hybrid_expert_layer_step_holds_one_switch_each_way(mosaic, one_chip):
                 and 'op_name="jit(fn)/cond"' not in line]
     assert len(switches) == 2, switches
     assert sum("pd.moe_experts/cond" in line for line in switches) == 1
+    assert sum("pd.moe_experts_grad/cond" in line for line in switches) == 1
     kernels = [re.search(r'op_name="[^"]*?(\w+)\)*/pallas_call', line).group(1)
                for line in text.splitlines() if KERNEL in line]
     assert {k: kernels.count(k) for k in set(kernels)} == {
-        "gmm": 2 * (2 + 2 + 2), "tgmm": 2 * 2, "pair_sum": 2 * 2,
+        "gmm": 2 * (2 + 2), "tgmm": 2 * 2, "pair_sum": 2 * 2,
+        # the small rung's kept product at the head of a buffer nothing
+        # fills (hybrid_ops._over_all_pairs)
+        "unwritten_rows": 1,
         "ssd_scan_fwd": 1, "ssd_scan_bwd": 1}
 
 
@@ -663,17 +668,23 @@ def test_latent_attention_step_compiles_with_one_cast_of_the_shared_head(
     entry = text[text.index("\nENTRY "):]
     kernels = [re.search(r'op_name="[^"]*?(\w+)\)*/pallas_call', line).group(1)
                for line in text.splitlines() if KERNEL in line]
-    # the module's expert layer: three grouped products forward and each
-    # one's two backward products (gmm for the rows', tgmm for the
-    # weights'); the three forward ones the generic gradient traces again
-    # are merged with the originals under this harness's XLA_FLAGS and
-    # kept by tools/describe_step.py's bare environment and by the chip
+    # the module's expert layer, an eighth of the experts held: two rungs
+    # (4096 | 16384, PR 58) under one switch in the op and one in its
+    # gradient op. A forward branch runs the three grouped products, a
+    # gradient branch each one's two partners (gmm for the rows', tgmm for
+    # the weights') and no forward product: it reads the forward's Up and
+    # GateUp. The token side's kernel, forward and pulled back, a rung.
     count = {k: kernels.count(k) for k in set(kernels)}
-    assert count.pop("gmm") in (6, 9)
-    # and the token side's kernel, forward and pulled back (PR 47)
-    assert count == {"flash_fwd": 2, "flash_dkv": 2, "tgmm": 3, "pair_sum": 2}
-    # an eighth of the experts held: one rung, no switch (PR 36)
-    assert "pd.moe_experts/cond" not in text
+    assert count == {"flash_fwd": 2, "flash_dkv": 2, "gmm": 2 * (3 + 3),
+                     "tgmm": 2 * 3, "pair_sum": 2 * 2,
+                     # the small rung's two kept products, each at the head
+                     # of a buffer nothing fills (_over_all_pairs)
+                     "unwritten_rows": 2}
+    assert "pd.moe_experts/cond" in text
+    assert "pd.moe_experts_grad/cond" in text
+    under_grad = [line for line in text.splitlines() if KERNEL in line
+                  and "pd.moe_experts_grad/" in line]
+    assert len(under_grad) == 2 * (3 + 3 + 1)
     width = "[%d,%d]" % (config["hidden_size"], config["vocab_size"])
     casts = re.findall(
         r"%([\w.\-]+) = bf16" + re.escape(width) + r"[^=]*? convert\("
@@ -687,20 +698,24 @@ def test_latent_attention_step_compiles_with_one_cast_of_the_shared_head(
 
 @pytest.mark.slow
 def test_latent_attention_cell_fits_the_chip_at_4096_tokens(mosaic, one_chip):
-    """The sizing rule's compile (PERF.md section 4): the whole step, 72
-    Mosaic calls in tools/describe_step.py's bare environment (78 before
-    PR 43 fused the six attention ops' two backward kernels) and 57 under
-    this harness's XLA_FLAGS, which merge the 15 forward products the
-    expert layers' generic gradient traces again with the originals (as
-    the one-block test above allows), and since PR 47 the five expert
-    layers' `pair_sum`, forward and pulled back; 6.15 GB of temporaries +
-    8.48 GB of aliased state."""
+    """The sizing rule's compile (PERF.md section 4): the whole step. Its
+    Mosaic calls: the six attention ops' flash_fwd and fused flash_dkv
+    (PR 43), and since PR 58 the five expert layers' two rungs each, a
+    forward branch its three grouped products and a gradient branch their
+    three partners on the rows and three on the weights (no forward
+    product: before PR 58 the generic gradient traced 15 again, which
+    this harness's XLA_FLAGS merged with the originals and
+    tools/describe_step.py's bare environment and the chip did not),
+    a rung's `pair_sum` forward and pulled back (PR 47) and the small
+    rung's two empty-bodied calls (_over_all_pairs): 12 + 5 x (2 x (6 + 3
+    + 2) + 2) in either environment; 6.15 GB of temporaries + 8.48 GB of
+    aliased state before the ladder."""
     cell = run.load_json("workloads", MLA_CELL)
     config = run.load_json("configs", cell["config"])
     compiled = describe_step.compile_step(cell, config, one_chip)
     mem = compiled.memory_analysis()
     text = compiled.as_text()
-    assert text.count(KERNEL) in (57 + 10, 72 + 10)
+    assert text.count(KERNEL) == 12 + 5 * (2 * (6 + 3 + 2) + 2)
     assert text.count("flash_fwd") and "flash_dq" not in text
     assert mem.alias_size_in_bytes > 8.4e9
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 15.75e9
@@ -759,7 +774,9 @@ def test_window_step_compiles_with_both_kinds_of_layer(mosaic, one_chip):
     kind (the four take four minutes here; tools/describe_step.py sized
     them: 7.44 GB of temporaries + 4.45 GB of aliased state): each
     attention op on the flash kernels with the fused backward, the
-    experts on gmm / tgmm with no switch (8 of 64 held: one rung), the
+    experts on gmm / tgmm behind the ladder's switch (8 of 64 held, an
+    eighth: 12288 | 49152, PR 58), three products a forward branch and
+    their six partners a gradient branch, none of them a forward's, the
     rotations under the windowed layer's scope alone, and under either
     attention scope no array larger than the op's own operands: nothing
     of [., T, T] reaches HBM."""
@@ -773,10 +790,13 @@ def test_window_step_compiles_with_both_kinds_of_layer(mosaic, one_chip):
                for line in text.splitlines() if KERNEL in line]
     flash = {k: kernels.count(k) for k in set(kernels) if "flash" in k}
     assert flash == {"flash_fwd": 2, "flash_dkv": 2}
-    assert "gmm" in kernels and "tgmm" in kernels
-    # the token side, forward and pulled back, in both layers (PR 47)
-    assert kernels.count("pair_sum") == 2 * 2
-    assert "pd.moe_experts/cond" not in text
+    # two layers of two rungs: the forward's three products, the gradient
+    # op's three on the rows and three on the weights
+    assert kernels.count("gmm") == 2 * 2 * (3 + 3)
+    assert kernels.count("tgmm") == 2 * 2 * 3
+    # the token side, forward and pulled back, in both layers' rungs
+    assert kernels.count("pair_sum") == 2 * 2 * 2
+    assert "pd.moe_experts/cond" in text
     scoped = {kind: [i for i in xplane.hlo_instructions(text)
                      if "pd_scope." + kind in (i.op_name or "")]
               for kind in ("window_attention", "global_attention")}
@@ -793,6 +813,53 @@ def test_window_step_compiles_with_both_kinds_of_layer(mosaic, one_chip):
                 instr.name, instr.shape)
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 15.75e9
+
+
+LAGUNA_CELL = "laguna-xs.2.train-gated-swa512-ep8-share"
+
+
+def test_gated_window_step_keeps_no_product_of_a_forward_that_is_replayed(
+        mosaic, one_chip):
+    """The gated window cell's step at its own 8192-token sequence and
+    published widths, the depth cut to the dense layer and two expert
+    layers under a checkpoint a layer, the first expert layer replayed
+    and the last not (the five take two minutes here): 32 of 256 experts
+    held, an eighth, so each expert op and its gradient op are one switch
+    over 16384 | 65536 pairs (PR 58). What a gradient op reads of its
+    forward is Up and GateUp, [65536, 512] bf16 each: the replayed layer's
+    FIRST forward leaves its conditional with Out alone (nothing reads
+    the pair: the compiler drops it and the small rung's fill), its
+    replayed forward with the pair alone (nothing reads that Out: the
+    down product goes, two grouped products a branch where three), and
+    the last layer's forward, which nothing replays, with all three. A
+    gradient branch runs its three products on the rows and three on the
+    weights, no forward's."""
+    cell = run.load_json("workloads", LAGUNA_CELL)
+    config = dict(run.load_json("configs", cell["config"]),
+                  num_hidden_layers=3)
+    text = describe_step.compile_step(cell, config, one_chip).as_text()
+    kernels = [(i.op, i.recompute is not None,
+                re.search(r"(\w+)\)*/pallas_call", i.op_name).group(1))
+               for i in xplane.hlo_instructions(text)
+               if i.opcode == "custom-call" and "pallas_call" in i.op_name
+               and (i.op or "").startswith("moe_experts")]
+    assert {k: kernels.count(k) for k in set(kernels)} == {
+        ("moe_experts", False, "gmm"): 2 * 2 * 3,
+        ("moe_experts", False, "pair_sum"): 2 * 2,
+        # the two kept products of the small rung, where they are read:
+        # the last layer's forward and the replay (_over_all_pairs)
+        ("moe_experts", False, "unwritten_rows"): 2,
+        ("moe_experts", True, "unwritten_rows"): 2,
+        ("moe_experts", True, "gmm"): 2 * 2,
+        ("moe_experts_grad", False, "gmm"): 2 * 2 * 3,
+        ("moe_experts_grad", False, "tgmm"): 2 * 2 * 3,
+        ("moe_experts_grad", False, "pair_sum"): 2 * 2}
+    kept = "bf16[65536,512]"
+    switches = sorted(
+        (i.at, i.recompute is not None, i.shape.count(kept))
+        for i in xplane.hlo_instructions(text)
+        if i.opcode == "conditional" and i.op == "moe_experts")
+    assert [s[1:] for s in switches] == [(False, 0), (False, 2), (True, 2)]
 
 
 KDA_CELL = "kimi-linear.train-kda-t8192-ep32-share"

@@ -445,7 +445,8 @@ def experts_op(x, idx, weight, gate, up, down, offset=0, held=None,
         {"X": x, "TopkIdx": idx, "TopkWeight": weight, "WGate": gate,
          "W1": up, "W2": down},
         dict.fromkeys(("Out", "RowsRouted", "RowsCombined",
-                       "LoadMaxOverMean", "RowsHandled"), "float32"),
+                       "LoadMaxOverMean", "RowsHandled", "Up", "GateUp"),
+                      "float32"),
         dict(attrs, num_experts=num_experts, experts_held=held,
              expert_offset=offset, top_k=idx.shape[1]), wrt)
 
@@ -488,9 +489,11 @@ def test_the_relu_gate_against_the_formula_and_shut_it_passes_nothing():
 
 PARENT_PROGRAMS = {
     # sha256 of main.to_json() | startup.to_json() at PR 45's tree
-    "glm-4.7-flash": ("229a908b9f6b2a16", "57465f9570324186"),
-    "sdar-30b-a3b-chat": ("927f45a7acac9318", "d87f315e59443b2d"),
-    "nemotron3-nano-30b-a3b": ("46a0a0ae673c6e3c", "2cd691daa316a1c1"),
+    # (the programs with expert layers: at PR 58's, whose moe_experts ops
+    # write Up / GateUp for an explicit gradient op)
+    "glm-4.7-flash": ("c6c56c119b5b8e5c", "57465f9570324186"),
+    "sdar-30b-a3b-chat": ("ae3fbd7051acd413", "d87f315e59443b2d"),
+    "nemotron3-nano-30b-a3b": ("b3acdca94fc207b9", "2cd691daa316a1c1"),
     "gpt2": ("32530ba784525f48", "6cae3670f852b823"),
 }
 

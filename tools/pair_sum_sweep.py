@@ -7,8 +7,8 @@ ops/pallas_pair_sum.py over its tiles and windows.
 
 At each cell's shape (N, top_k, D, C, live pairs) it times the two maps
 the layer runs: `forward`, weighted, the grouped product's bf16 rows ->
-float32 (_tokens_of_rows), and `pulled_back`, unweighted, the bf16
-cotangent's rows -> float32 -> bf16 (_rows_of_tokens_bwd), and prints ms
+float32 (_handle_rows), and `pulled_back`, unweighted, the bf16
+cotangent's rows -> float32 -> bf16 (_pull_rows_back), and prints ms
 (on the device: sixteen calls chained in one executable) and GB/s a
 form, the bytes being the live rows read once and [N, D] written once;
 and the largest difference from the XLA form. The routing
