@@ -1668,10 +1668,11 @@ class Executor:
         The dynamics rows follow the same rule in the observatory's own
         queue (dynamics.drain). A metric the catalog lists as a
         histogram takes a sample a step, any other is a gauge; one that
-        carries the `layer` label takes one series per element of its
-        vector. Each publication is also a `side_fetch` event of the step
-        log (telemetry.recent_events), in step order: a histogram keeps
-        no order, and a reader of a window's tail needs one."""
+        carries a label beside `program` (`layer`, `exit`) takes one
+        series per element of its vector. Each publication is also a
+        `side_fetch` event of the step log (telemetry.recent_events), in
+        step order: a histogram keeps no order, and a reader of a
+        window's tail needs one."""
         while self._side_pending:
             metric, val, label = self._side_pending[0]
             if not (wait or telemetry.is_ready(val)):
@@ -1686,12 +1687,13 @@ class Executor:
             telemetry.log_event("side_fetch", program=label, metric=metric,
                                 values=values.tolist())
             spec = telemetry.METRIC_CATALOG.get(metric, {})
-            by_layer = "layer" in spec.get("labels", ())
+            labels = spec.get("labels", ("program",))
+            by_element = [n for n in labels if n != "program"]
             hist = spec.get("kind") == "histogram"
             family = (telemetry.histogram if hist else telemetry.gauge)(
-                metric, labels=spec.get("labels", ("program",)))
-            for i, v in enumerate(values if by_layer else values[:1]):
-                extra = {"layer": str(i)} if by_layer else {}
+                metric, labels=labels)
+            for i, v in enumerate(values if by_element else values[:1]):
+                extra = {n: str(i) for n in by_element}
                 child = family.labels(program=label, **extra)
                 child.observe(float(v)) if hist else child.set(float(v))
 

@@ -1510,11 +1510,16 @@ def rotary_embedding(x, theta=10000.0, rotary_dims=None, name=None,
 
 
 @_under_its_name
-def gated_mlp(x, width, out_scale=0.02):
+def gated_mlp(x, width, out_scale=0.02, name=None):
     """Gated (SwiGLU) feed-forward over x [B, T, D]:
-    (silu(x W_g) * (x W_u)) W_d, no bias."""
-    hidden = elementwise_mul(_linear(x, width, act="silu"), _linear(x, width))
-    return _linear(hidden, int(x.shape[-1]), scale=out_scale)
+    (silu(x W_g) * (x W_u)) W_d, no bias. `name` names the three weights
+    `<name>.gate`, `<name>.up` and `<name>.down`, so that a second
+    feed-forward can read the same ones."""
+    gate, up, down = (name and f"{name}.{part}"
+                      for part in ("gate", "up", "down"))
+    hidden = elementwise_mul(_linear(x, width, act="silu", name=gate),
+                             _linear(x, width, name=up))
+    return _linear(hidden, int(x.shape[-1]), scale=out_scale, name=down)
 
 
 @_under_its_name

@@ -12,6 +12,7 @@ from .gated_window_moe import gated_window_moe_lm
 from .googlenet import googlenet
 from .granite_hybrid import granite_hybrid_lm
 from .kda_moe import kda_moe_lm
+from .looped_lm import looped_lm
 from .mla_moe import mla_moe_lm
 from .mnist import mnist_conv, mnist_mlp
 from .nemotron_h import nemotron_h_lm
@@ -24,8 +25,8 @@ from .common import balance_routers, build_image_classifier
 
 __all__ = [
     "alexnet", "block_diffusion_moe_lm", "gated_window_moe_lm", "googlenet",
-    "granite_hybrid_lm", "kda_moe_lm", "mla_moe_lm", "mnist_conv",
-    "mnist_mlp",
+    "granite_hybrid_lm", "kda_moe_lm", "looped_lm", "mla_moe_lm",
+    "mnist_conv", "mnist_mlp",
     "nemotron_h_lm",
     "resnet_cifar10", "resnet_imagenet", "resnet50",
     "smallnet_mnist_cifar", "transformer_lm",

@@ -896,6 +896,16 @@ METRIC_CATALOG = {
         "share of the last step's data tokens the input pipeline masked "
         "(telemetry side-fetch; models/block_diffusion_moe)",
         dynamic=True),
+    "loop_exit_loss": _m(
+        "histogram", ("program", "exit"),
+        "mean next-token cross-entropy of one exit of a looped decoder, a "
+        "sample a step and exit (telemetry side-fetch; models/looped_lm)",
+        dynamic=True),
+    "loop_exit_mass": _m(
+        "histogram", ("program", "exit"),
+        "mean mass the exits' gate gives one exit of a looped decoder; "
+        "the exits' sum to 1 (telemetry side-fetch; models/looped_lm)",
+        dynamic=True),
     "jax_backend_compiles_total": _m("counter", (),
                                      "XLA backend compiles observed"),
     "jax_backend_compile_seconds_total": _m(

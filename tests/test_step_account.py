@@ -464,7 +464,8 @@ def _tiny(cell, tmp_path, monkeypatch):
     return os.path.join(str(tmp_path), cell)
 
 
-@pytest.mark.parametrize("cell", ["tiny-gpt2.train", "tiny-resnet18.train"])
+@pytest.mark.parametrize("cell", ["tiny-gpt2.train", "tiny-resnet18.train",
+                                  "tiny-ouro.train"])
 def test_account_agrees_with_xla_and_rows_sum_to_busy(cell, tmp_path,
                                                       monkeypatch,
                                                       no_persistent_cache):
@@ -489,6 +490,31 @@ def test_account_agrees_with_xla_and_rows_sum_to_busy(cell, tmp_path,
         assert sum(r["ms"] for r in step["rows"]) == pytest.approx(
             step["busy_ms"])
     assert os.path.exists(os.path.join(trace_dir, xplane.ACCOUNT_FILE))
+
+
+def test_a_looped_steps_account_tells_passes_replays_and_exits_apart(
+        tmp_path, monkeypatch, no_persistent_cache):
+    """One layer's weights are read by three passes and their replays
+    (tiny-ouro: 2 layers x 3 passes): the account places each product at
+    its own op position, marks the replayed ones with their segment, and
+    books the exits' and the attention's instructions under their
+    scopes, forward and backward."""
+    _tiny("tiny-ouro.train", tmp_path, monkeypatch)
+    (instrs,) = [instrs for instrs, _ in xplane.known_accounts()
+                 if any(i.role == "backward" for i in instrs)]
+    products = [i for i in instrs if i.op == "mul" and i.flops]
+    forward = {i.at for i in products if i.recompute is None}
+    replayed = {i.at for i in products if i.recompute is not None}
+    # q, k, v, o, gate, up, down a layer application and a head an exit;
+    # five of the six applications and two of the three exits replayed
+    # (the compiler drops a replayed product whose result no gradient
+    # reads)
+    assert len(forward) == 6 * 7 + 3 and 5 * 5 <= len(replayed) <= 5 * 7 + 2
+    assert {i.recompute for i in products} == {None, 1, 2, 3, 4, 5}
+    for scope in ("loop_exit", "loop_attention"):
+        roles = {i.role for i in instrs if i.scope
+                 and scope in i.scope.split(".")}
+        assert roles == {"forward", "backward"}, (scope, roles)
 
 
 def _small_program():

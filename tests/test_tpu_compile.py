@@ -990,3 +990,56 @@ def test_flash_gate_declines(shape, reason):
         reason in kernel_choice.REASONS["scaled_dot_product_attention"]
     # the ring path's per-shard check declines the same shapes
     assert pallas_attention.block_supports(q, q) == (reason is None)
+
+
+OURO_CELL = "ouro-2.6b.train-loop4-t4096-pp6-stage"
+
+
+def test_looped_step_reads_one_set_of_weights_four_times(mosaic, one_chip):
+    """The Ouro cell's step at its own 4096-token sequence and published
+    widths, the depth cut to ONE layer run four times (the eight take
+    five minutes here; tools/describe_step.py sized them, PR 59:
+    6.74e9 B of temporaries + 7.35e9 B of aliased state, 64 Mosaic
+    calls): four flash forward calls and four fused backward calls for
+    four applications of which three are replayed (a replayed attention
+    op is handed its first output and row statistics and runs no
+    kernel); each float32 master weight is converted to bf16 ONCE for its
+    four readers and their three replays (the weights are read where
+    they lie, not through a segment's barrier, so the compiler shares
+    the copy); each weight's gradient leaves the backward as ONE float32
+    array (the fan-in's `sum` ops are fused with the four products'
+    converts); an exit's logits reach HBM in bf16 alone."""
+    cell = run.load_json("workloads", OURO_CELL)
+    config = dict(run.load_json("configs", cell["config"]),
+                  num_hidden_layers=1)
+    compiled = describe_step.compile_step(cell, config, one_chip)
+    text = compiled.as_text()
+    kernels = [re.search(r'op_name="[^"]*?(\w+)\)*/pallas_call', line).group(1)
+               for line in text.splitlines() if KERNEL in line]
+    assert {k: kernels.count(k) for k in set(kernels)} == {
+        "flash_fwd": 4, "flash_dkv": 4}
+    instrs = [i for i in xplane.hlo_instructions(text) if i.entry]
+
+    def writes(dtype, *dims):
+        shape = "%s[%s]" % (dtype, ",".join(map(str, dims)))
+        return [i for i in instrs if i.opcode in ("fusion", "convert")
+                and i.shape.replace(" ", "").lstrip("(").startswith(shape)]
+
+    d, f, v, t = (config["hidden_size"], config["intermediate_size"],
+                  config["vocab_size"], config["sequence_length"])
+    # the forward's converts: gate and up, down, the head: one each
+    for dims, count in (((d, f), 2), ((f, d), 1), ((d, v), 1)):
+        converts = [i for i in writes("bf16", *dims) if i.op == "mul"
+                    and i.opcode == "convert"]
+        assert len(converts) == count, (dims, converts)
+    # the backward's float32 gradients: one array a weight
+    for dims, count in (((d, f), 2), ((f, d), 1), ((d, v), 1)):
+        grads = [i for i in writes("f32", *dims) if i.op == "mul_grad"]
+        assert len(grads) == count, (dims, [g.name for g in grads])
+    logits = {dtype: [i for i in instrs if i.opcode != "parameter"
+                      and re.match(r"\(?%s\[(1,)?%d,%d\]" % (dtype, t, v),
+                                   i.shape.replace(" ", ""))]
+              for dtype in ("f32", "bf16")}
+    assert logits["bf16"] and not logits["f32"]
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 15.75e9
