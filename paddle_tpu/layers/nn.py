@@ -1252,7 +1252,9 @@ def mamba2_mixer(x, num_heads, head_dim, n_groups, state_size, conv_kernel=4,
     """Mamba-2 mixer (Dao & Gu 2024, as nemotron_h's) over x [B, T, D]:
     [z | xBC | dt] = x W_in of widths d_inner | d_inner + 2 G N | H with
     d_inner = num_heads * head_dim; xBC through a causal depthwise conv
-    and silu, split into x_s [T, H, P], B and C [T, G, N]; the selective
+    and silu (built `time_on_lanes`: the scan's kernels read time along
+    the lanes, and the conv's take their blocks the same way),
+    split into x_s [T, H, P], B and C [T, G, N]; the selective
     scan (ops/hybrid_ops.py ssd_scan: dt = softplus(dt + dt_bias), A =
     -exp(A_log), skip D); rms_norm(y, gate=z) in G groups; W_out back to
     D. A_log, dt_bias and D start as published (log U(1, 16); softplus^-1
@@ -1273,7 +1275,8 @@ def mamba2_mixer(x, num_heads, head_dim, n_groups, state_size, conv_kernel=4,
     helper.append_op(type="causal_conv1d",
                      inputs={"X": [xbc], "Filter": [conv_w],
                              "Bias": [conv_b]},
-                     outputs={"Out": [conved]}, attrs={})
+                     # the scan behind it reads time along the lanes
+                     outputs={"Out": [conved]}, attrs={"time_on_lanes": True})
     xs, b, c = split(conved, [d_inner, bc, bc], dim=2)
 
     dt_bias = helper.create_parameter(

@@ -16,9 +16,15 @@ decays and its state stay float32; the scan's four products and the
 expert products take bf16 operands with float32 accumulation; the rotary
 angles, their sines and cosines and the rotation itself are float32.
 
-Gradients: generic but the expert layer's (registry.generic_grad_lower:
-jax.vjp of the lowering, whose re-traced forward XLA merges with the
-original). The scan runs, where its shape tiles (ssd_scan_ineligible),
+Gradients: generic but the expert layer's and the short convolution's
+(registry.generic_grad_lower: jax.vjp of the lowering, whose re-traced
+forward XLA merges with the original). The convolution and its explicit
+gradient op run, where time and channels are whole 128-wide blocks
+(pallas_conv1d.ineligible), on the two kernels of ops/pallas_conv1d.py,
+which read X (and Out's cotangent) once in the dtype they arrive in and
+write once, the gradient's computing the pre-activation again from the
+op's inputs alone (PERF.md section 6, PR 60); elsewhere
+causal_conv1d_reference and autodiff's gradient of it. The scan runs, where its shape tiles (ssd_scan_ineligible),
 on the two Pallas kernels of ops/pallas_scan.py, whose [chunk, chunk]
 decay and score blocks never leave VMEM: a jax.custom_vjp inside the
 lowering that keeps the op's inputs and the state entering each chunk,
@@ -68,8 +74,8 @@ from . import kernel_choice, pallas_pair_sum
 from .common import in_var, same_as_input, set_out
 from .registry import NO_GRAD, op
 
-__all__ = ["gmm_ineligible", "kda_chunked", "ssd_scan_chunked",
-           "ssd_scan_ineligible"]
+__all__ = ["causal_conv1d_reference", "gmm_ineligible", "kda_chunked",
+           "ssd_scan_chunked", "ssd_scan_ineligible"]
 
 
 def _f32(x):
@@ -174,22 +180,106 @@ def _rotary_embedding(ctx, op_, ins):
 
 # --- causal depthwise conv over time -----------------------------------------
 
-@op("causal_conv1d", infer_shape=same_as_input())
+def causal_conv1d_reference(x, w, bias=None):
+    """The op causal_conv1d in plain jax.numpy: K shifted multiply-adds
+    over a zero-padded float32 copy of X and Mamba's activation; float32
+    inside, X's dtype out. The statement the kernels of
+    ops/pallas_conv1d.py are held to, and the path (with autodiff's
+    gradient of it) for shapes their gate declines."""
+    w = _f32(w)
+    k, t = w.shape[1], x.shape[1]
+    padded = jnp.pad(_f32(x), ((0, 0), (k - 1, 0), (0, 0)))
+    out = _f32(bias) if bias is not None else 0.0
+    for j in range(k):
+        out = out + padded[:, j:j + t] * w[:, j]
+    return jax.nn.silu(out).astype(x.dtype)
+
+
+_CONV1D_OP = "causal_conv1d"
+
+
+def _conv1d_operands(op_, ins):
+    """(X, Filter, Bias or None, the kernels' keywords or None where
+    their gate declines, the gate's reason) of the op or its gradient
+    op."""
+    from . import pallas_conv1d
+    from .pallas_attention import _interpret
+
+    x, w = jnp.asarray(ins["X"][0]), jnp.asarray(ins["Filter"][0])
+    bias = ins.get("Bias")
+    bias = jnp.asarray(bias[0]) if bias and bias[0] is not None else None
+    reason = pallas_conv1d.ineligible(x.shape[1], x.shape[2], w.shape[1],
+                                      x.dtype)
+    kernel = None if reason else dict(
+        lanes=bool(op_.attr("time_on_lanes", False)), interpret=_interpret())
+    return x, w, bias, kernel, reason
+
+
+def _conv1d_grad(fwd, no_grad_set):
+    """causal_conv1d_grad reads the op's inputs and Out's cotangent and
+    nothing the forward made (the kernel computes the pre-activation
+    again): the generic maker's op would trace the forward inside the
+    gradient op and keep its float32 copies for the pull-back."""
+    wanted = [s for s in ("X", "Filter", "Bias")
+              if fwd.inputs.get(s) and fwd.input(s)[0] not in no_grad_set]
+    if not wanted:
+        return []
+    return [OpDesc(
+        type=fwd.type + "_grad",
+        inputs={**{s: list(names) for s, names in fwd.inputs.items()},
+                "Out@GRAD": [grad_var_name(fwd.output("Out")[0])]},
+        outputs={s + "@GRAD": [grad_var_name(fwd.input(s)[0])]
+                 for s in wanted},
+        attrs=dict(fwd.attrs))]
+
+
+@op("causal_conv1d", infer_shape=same_as_input(), grad=_conv1d_grad)
 def _causal_conv1d(ctx, op_, ins):
     """X [B, T, C], Filter [C, K], Bias [C] (may be absent: Kimi Delta
     Attention's short convolutions have none): Out[t] = silu(Bias + sum_j
     Filter[:, j] * X[t - (K-1) + j]) with zeros before t = 0 (a depthwise
     conv1d, left pad K-1, then Mamba's activation). K shifted
-    multiply-adds on the VPU; float32 inside, X's dtype out."""
-    x = jnp.asarray(ins["X"][0])
-    w = _f32(ins["Filter"][0])
-    k, t = w.shape[1], x.shape[1]
-    padded = jnp.pad(_f32(x), ((0, 0), (k - 1, 0), (0, 0)))
-    bias = ins.get("Bias")
-    out = _f32(bias[0]) if bias and bias[0] is not None else 0.0
-    for j in range(k):
-        out = out + padded[:, j:j + t] * w[:, j]
-    return {"Out": [jax.nn.silu(out).astype(x.dtype)]}
+    multiply-adds on the VPU; float32 inside, X's dtype out.
+
+    What runs is chosen from the shapes (pallas_conv1d.ineligible): the
+    forward kernel of ops/pallas_conv1d.py, which reads X once in the
+    dtype it has and writes Out once (interpreted off the chip;
+    pallas_kernel_total{op="causal_conv1d"}), or
+    causal_conv1d_reference, XLA's, booked with the reason
+    (pallas_fallback_total). Every forward lowering books, a replayed
+    segment's too (12 + 9 in the Kimi-Linear cell); the gradient op books
+    nothing. `time_on_lanes` (an attribute the layer writes: mamba2_mixer,
+    whose scan reads time along the lanes) turns the kernel's blocks, not
+    the result."""
+    from . import pallas_conv1d
+
+    x, w, bias, kernel, reason = _conv1d_operands(op_, ins)
+    kernel_choice.book(_CONV1D_OP, reason)
+    if kernel is None:
+        return {"Out": [causal_conv1d_reference(x, w, bias)]}
+    return {"Out": [pallas_conv1d.causal_conv1d_fwd(x, w, bias, **kernel)]}
+
+
+@op("causal_conv1d_grad", grad=NO_GRAD)
+def _causal_conv1d_grad(ctx, op_, ins):
+    """dX (X's dtype), dFilter and dBias (their parameters' dtypes) from
+    X, Filter, Bias and Out's cotangent: the gradient's kernel of
+    ops/pallas_conv1d.py where the forward took its kernel (the same
+    gate), else autodiff's gradient of causal_conv1d_reference."""
+    from . import pallas_conv1d
+
+    x, w, bias, kernel, _ = _conv1d_operands(op_, ins)
+    d_out = jnp.asarray(ins["Out@GRAD"][0])
+    if kernel is None:
+        primals = (x, w) if bias is None else (x, w, bias)
+        grads = jax.vjp(causal_conv1d_reference, *primals)[1](
+            d_out.astype(x.dtype))
+    else:
+        grads = pallas_conv1d.causal_conv1d_bwd(x, w, bias, d_out, **kernel)
+    return {slot + "@GRAD": [g.astype(like.dtype)]
+            for slot, g, like in zip(("X", "Filter", "Bias"), grads,
+                                     (x, w, bias))
+            if slot + "@GRAD" in op_.desc.outputs}
 
 
 # --- Mamba-2 selective scan, chunked (SSD) -----------------------------------

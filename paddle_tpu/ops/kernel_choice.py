@@ -35,6 +35,11 @@ REASONS = {
     # one lane block of K and whole ones of V, the chunk a power of two
     # of 16-row sub-blocks; else hybrid_ops.kda_scan_chunked keeps the op
     "kda_scan": frozenset({"width", "chunk"}),
+    # the mixers' short convolution (ops/pallas_conv1d.py, forward and
+    # gradient): whole 128-wide blocks of time and of channels, bf16 or
+    # float32, the taps' reach inside a tile; else
+    # hybrid_ops.causal_conv1d_reference keeps the op
+    "causal_conv1d": frozenset({"dtype", "taps", "time", "channels"}),
     "moe_experts": frozenset({"rows", "width"}),
     # moe_experts' second choice, the token side's kernel (WITHIN)
     "pair_sum": frozenset({"width", "tokens", "rows", "experts"}),
@@ -50,6 +55,7 @@ GATES = {
     "block_diffusion_attention": _ATTENTION_GATE,
     "ssd_scan": ("hybrid_ops.ssd_scan_ineligible",),
     "kda_scan": ("hybrid_ops.kda_scan_ineligible",),
+    "causal_conv1d": ("pallas_conv1d.ineligible",),
     "moe_experts": ("hybrid_ops.gmm_ineligible",),
     "pair_sum": ("pallas_pair_sum.ineligible",),
 }

@@ -223,7 +223,8 @@ def test_counters_of_a_compile_with_checkpoints(step):
 # (c26b2bf) built them: append_backward emits what it emitted, op for op
 # and name for name, so the accepted cells' compile cache keys hold
 # (the four presets with expert layers: as PR 58 builds them, whose
-# moe_experts ops write Up / GateUp for an explicit gradient op)
+# moe_experts ops write Up / GateUp for an explicit gradient op; the
+# hybrid preset as PR 60 builds it: causal_conv1d's explicit gradient op)
 PARENT_PROGRAMS = {
     "alexnet":
         "749347dae9d46259e5085d6cd6b8129e79f7488ac336064944ae999749fb20e9",
@@ -246,7 +247,7 @@ PARENT_PROGRAMS = {
     "tiny-gpt2":
         "5fd176abaa4479ded067ac1cee7922fd9247f2a913e92ecba88ca58391eefb66",
     "tiny-nemotron-h":
-        "fc691b7f8238517f31ba0a7972c6945d445b4f86d7e496dd87ba32b83558c596",
+        "530a4521428c6e73de3e9e41db9f7264798457c1ec1a37061bf05ed5c44fe001",
     "tiny-glm-moe-lite":
         "8c60a3136a68e77e3579afe2192aada0bc17dfa22f2825ad6204b6c2cd2ec0e2",
     "tiny-sdar-moe":

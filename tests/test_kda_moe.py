@@ -727,10 +727,12 @@ def test_the_ladder_has_two_rungs_at_a_thirty_second(held, small):
 PARENT_PROGRAMS = {
     # sha256 of main.to_json() | startup.to_json() at PR 54's tree
     # (the programs with expert layers: at PR 58's, whose moe_experts ops
-    # write Up / GateUp for an explicit gradient op)
+    # write Up / GateUp for an explicit gradient op; the two with Mamba
+    # mixers at PR 60's, whose causal_conv1d ops carry `time_on_lanes`
+    # and an explicit gradient op)
     "glm-4.7-flash": ("c6c56c119b5b8e5c", "57465f9570324186"),
-    "nemotron3-nano-30b-a3b": ("b3acdca94fc207b9", "2cd691daa316a1c1"),
-    "granite-4.0-h-micro": ("34df072b045df201", "aef2a12bd424d1b5"),
+    "nemotron3-nano-30b-a3b": ("6ac48d33c64fc359", "2cd691daa316a1c1"),
+    "granite-4.0-h-micro": ("cbaa1f07490aec02", "aef2a12bd424d1b5"),
 }
 
 

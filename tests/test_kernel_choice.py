@@ -18,11 +18,12 @@ from test_nemotron_h import SCAN_ORDER, routed, scan_inputs
 from test_registry_lint import _load_checker
 
 OPS = ("conv2d", "scaled_dot_product_attention", "block_diffusion_attention",
-       "ssd_scan", "kda_scan", "moe_experts", "pair_sum")
+       "ssd_scan", "kda_scan", "causal_conv1d", "moe_experts", "pair_sum")
 
 
-def test_the_table_holds_the_six_ops_with_a_kernel():
-    """Six registered ops (`kda_scan` since PR 56), and `pair_sum`:
+def test_the_table_holds_the_seven_ops_with_a_kernel():
+    """Seven registered ops (`kda_scan` since PR 56, `causal_conv1d` since
+    PR 60), and `pair_sum`:
     moe_experts' second choice, a kernel booked under its own name WITHIN
     that op (PR 47)."""
     assert tuple(kernel_choice.REASONS) == OPS == tuple(kernel_choice.GATES)

@@ -447,17 +447,19 @@ def test_the_gate_and_the_mix_stay_float32_under_bf16_activations():
 # (main, startup) of each accepted language configuration's programs as
 # the parent commit (PR 58) built them, by the first 16 hex digits of
 # sha256 over Program.to_json(): layers.gated_mlp's `name` names nothing
-# unless asked
+# unless asked (the three that build causal_conv1d as PR 60 builds them:
+# an explicit gradient op, and `time_on_lanes` under mamba2_mixer; their
+# startup programs are the parent's)
 PARENT_PROGRAMS = {
     "gpt2": ("32530ba784525f48", "6cae3670f852b823"),
     "gpt2-large": ("90b85e99fedb4110", "0abfed69b2161959"),
-    "nemotron3-nano-30b-a3b": ("b3acdca94fc207b9", "2cd691daa316a1c1"),
+    "nemotron3-nano-30b-a3b": ("6ac48d33c64fc359", "2cd691daa316a1c1"),
     "glm-4.7-flash": ("c6c56c119b5b8e5c", "57465f9570324186"),
     "sdar-30b-a3b-chat": ("ae3fbd7051acd413", "d87f315e59443b2d"),
     "smallthinker-21b-a3b-instruct": ("dc6827508c9e3acb", "33caad39b72bf4c9"),
-    "granite-4.0-h-micro": ("34df072b045df201", "aef2a12bd424d1b5"),
+    "granite-4.0-h-micro": ("cbaa1f07490aec02", "aef2a12bd424d1b5"),
     "laguna-xs.2": ("d9e2dffcce8f0f43", "2bfd552d47366495"),
-    "kimi-linear-48b-a3b-instruct": ("39ef0886d04ad408", "f56ab18f9f796b0c"),
+    "kimi-linear-48b-a3b-instruct": ("2e7f57cbc1436779", "f56ab18f9f796b0c"),
 }
 
 

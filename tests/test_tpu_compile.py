@@ -67,6 +67,16 @@ def mosaic(monkeypatch, no_persistent_cache):
     monkeypatch.setattr(pallas_conv, "_interpret", lambda: False)
 
 
+def _float32_under_the_conv(text):
+    """The instructions under pd.causal_conv1d or its gradient op that
+    write a float32 array of a whole [T, C] activation (2**24 elements
+    and more: the cells' are 2.5e7 to 3.6e7)."""
+    return [(i.name, i.shape) for i in xplane.hlo_instructions(text)
+            if re.search(r"pd\.causal_conv1d(_grad)?/", i.op_name or "")
+            and any(np.prod([int(d) for d in dims.split(",")]) >= 2 ** 24
+                    for dims in re.findall(r"f32\[([\d,]+)\]", i.shape))]
+
+
 def _compile(fn, one_chip, *shapes):
     """Names of the Mosaic calls in `fn` compiled for the described chip:
     each pallas_call's `name`, which the optimized HLO keeps in op_name
@@ -391,6 +401,35 @@ def test_delta_rule_kernels_compile(mosaic, one_chip, chunk, dtype, heads):
         == ["kda_scan_bwd", "kda_scan_fwd"]
 
 
+@pytest.mark.parametrize("t,c,bias,lanes", [
+    (8192, 4096, False, False), (8192, 4352, True, True),
+    (4096, 6144, True, True), (8192, 4352, True, False)],
+    ids=["kimi_cell", "granite_cell", "hybrid_cell", "rows_at_4352"])
+@pytest.mark.parametrize("dtype", [BF16, jnp.float32],
+                         ids=["bf16", "float32_no_amp"])
+def test_short_conv_kernels_compile(mosaic, one_chip, dtype, t, c, bias,
+                                    lanes):
+    """The three cells' short convolutions, [1, T, C] under four taps:
+    the forward kernel and the gradient's of ops/pallas_conv1d.py at the
+    blocks and chunks its table gives, inside the 16 MB of scoped VMEM a
+    Mosaic call has by default; time on the sublanes as kda_mixer builds
+    it (no Bias), on the lanes as mamba2_mixer does; C = 4352 = 34 lane
+    blocks takes blocks of 256 channels; without AMP the operands are
+    float32."""
+    from paddle_tpu.ops import pallas_conv1d
+    assert pallas_conv1d.ineligible(t, c, 4, dtype) is None
+
+    def both(x, w, b, d_out):
+        b = b if bias else None
+        return (pallas_conv1d.causal_conv1d_fwd(x, w, b, lanes=lanes),
+                pallas_conv1d.causal_conv1d_bwd(x, w, b, d_out, lanes=lanes))
+
+    assert _compile(both, one_chip, ((1, t, c), dtype),
+                    ((c, 4), jnp.float32), ((c,), jnp.float32),
+                    ((1, t, c), dtype)) \
+        == ["causal_conv1d_bwd", "causal_conv1d_fwd"]
+
+
 _HYBRID_ME = {}
 
 
@@ -434,7 +473,9 @@ def test_hybrid_expert_layer_step_holds_one_switch_each_way(mosaic, one_chip):
         # the small rung's kept product at the head of a buffer nothing
         # fills (hybrid_ops._over_all_pairs)
         "unwritten_rows": 1,
-        "ssd_scan_fwd": 1, "ssd_scan_bwd": 1}
+        "ssd_scan_fwd": 1, "ssd_scan_bwd": 1,
+        "causal_conv1d_fwd": 1, "causal_conv1d_bwd": 1}
+    assert not _float32_under_the_conv(text)
 
 
 def test_hybrid_mixer_step_holds_no_chunk_by_chunk_block(mosaic, one_chip):
@@ -880,9 +921,13 @@ def test_delta_rule_step_compiles_with_both_kinds_of_mixer(mosaic, one_chip):
     the very call the gradient op's re-trace makes for the entering
     states, so the compiler runs one for both: a third would be 5.7 ms a
     layer and step on the chip) and the backward kernel once, NO loop, and nothing of [., T, T] under either mixer;
+    the three short convolutions on the kernels of ops/pallas_conv1d.py
+    (PR 60): three forward calls, three replayed ones and three of the
+    explicit gradient op's, which traces no forward;
     under the KDA mixer a float32 array of the projections' [T, H K]
-    reaches HBM from the short convolutions and the gated norm alone,
-    none from the op or its gradient."""
+    reaches HBM from the gated norm alone (and the maps' float32 weight
+    gradients' side), none from the convolutions, the op or their
+    gradients."""
     from paddle_tpu import xplane
     cell = run.load_json("workloads", KDA_CELL)
     config = run.load_json("configs", cell["config"])
@@ -900,6 +945,8 @@ def test_delta_rule_step_compiles_with_both_kinds_of_mixer(mosaic, one_chip):
     assert "pd.moe_experts/cond" in text
     delta = {k: kernels.count(k) for k in set(kernels) if "kda" in k}
     assert delta == {"kda_scan_fwd": 2, "kda_scan_bwd": 1}, delta
+    conv = {k: kernels.count(k) for k in set(kernels) if "conv1d" in k}
+    assert conv == {"causal_conv1d_fwd": 3 + 3, "causal_conv1d_bwd": 3}, conv
     tokens = config["sequence_length"]
     instrs = list(xplane.hlo_instructions(text))
     under_op = [i for i in instrs if "pd.kda_scan" in (i.op_name or "")]
@@ -917,11 +964,9 @@ def test_delta_rule_step_compiles_with_both_kinds_of_mixer(mosaic, one_chip):
     float32 = {re.search(r"pd\.(\w+)", i.op_name.split("kda_mixer")[1]).group(1)
                for i in instrs if "pd_scope.kda_mixer" in (i.op_name or "")
                and any(x in i.shape.replace(" ", "") for x in (wide, heads))}
-    # the short convolutions take their map's result in float32 and work
-    # there, as their gradient and the gated norm do; the delta rule
-    # reads and writes bf16
-    assert float32 <= {"mul", "causal_conv1d", "causal_conv1d_grad",
-                       "rms_norm", "rms_norm_grad"}, float32
+    # the gated norm works in float32; the short convolutions and the
+    # delta rule read and write bf16 and widen in VMEM
+    assert float32 <= {"mul", "rms_norm", "rms_norm_grad"}, float32
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 15.75e9
 
@@ -941,7 +986,11 @@ def test_granite_step_holds_fewer_temporaries_with_checkpoints(mosaic,
     traces again with the replayed one, not with the first: the barrier
     stands between), and the compiler's own memory analysis holds
     measurably fewer temporary bytes than the same step without
-    checkpoints: the replay survived the compiler. No scan falls back."""
+    checkpoints: the replay survived the compiler. The short convolutions
+    run on their kernels in the replayed segments too (PR 60: a forward
+    call a layer, one more replayed, one call of the explicit gradient
+    op), with time along the lanes as the scan's, and write no float32
+    [T, C] array. No scan and no convolution falls back."""
     from paddle_tpu import telemetry, xplane
     cell = run.load_json("workloads", GRANITE_CELL)
     config = dict(run.load_json("configs", cell["config"]),
@@ -953,6 +1002,7 @@ def test_granite_step_holds_fewer_temporaries_with_checkpoints(mosaic,
         compiled = describe_step.compile_step(
             cell, dict(config, recompute=recompute), one_chip)
         text = compiled.as_text()
+        assert not _float32_under_the_conv(text)
         temps[recompute] = compiled.memory_analysis().temp_size_in_bytes
         names = [re.search(r'op_name="[^"]*?(\w+)\)*/pallas_call',
                            line).group(1)
@@ -963,8 +1013,10 @@ def test_granite_step_holds_fewer_temporaries_with_checkpoints(mosaic,
                                if i.recompute is not None}
     assert dict(telemetry.read_series("pallas_fallback_total")) == before
     assert kernels[False] == {"ssd_scan_fwd": 2, "ssd_scan_bwd": 2,
+                              "causal_conv1d_fwd": 2, "causal_conv1d_bwd": 2,
                               "flash_fwd": 1, "flash_dkv": 1}
-    assert kernels[True] == dict(kernels[False], ssd_scan_fwd=4)
+    assert kernels[True] == dict(kernels[False], ssd_scan_fwd=4,
+                                 causal_conv1d_fwd=4)
     # (segment 0 replays the embedding's lookup alone: XLA drops it)
     assert replayed[False] == set() and {1, 2} <= replayed[True] <= {0, 1, 2}
     # 2.78e9 against 3.57e9 when this was written

@@ -490,10 +490,12 @@ def test_the_relu_gate_against_the_formula_and_shut_it_passes_nothing():
 PARENT_PROGRAMS = {
     # sha256 of main.to_json() | startup.to_json() at PR 45's tree
     # (the programs with expert layers: at PR 58's, whose moe_experts ops
-    # write Up / GateUp for an explicit gradient op)
+    # write Up / GateUp for an explicit gradient op; the hybrid program
+    # at PR 60's, whose causal_conv1d ops carry `time_on_lanes` and an
+    # explicit gradient op)
     "glm-4.7-flash": ("c6c56c119b5b8e5c", "57465f9570324186"),
     "sdar-30b-a3b-chat": ("ae3fbd7051acd413", "d87f315e59443b2d"),
-    "nemotron3-nano-30b-a3b": ("b3acdca94fc207b9", "2cd691daa316a1c1"),
+    "nemotron3-nano-30b-a3b": ("6ac48d33c64fc359", "2cd691daa316a1c1"),
     "gpt2": ("32530ba784525f48", "6cae3670f852b823"),
 }
 
