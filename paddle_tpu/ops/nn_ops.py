@@ -946,7 +946,8 @@ def _flash_partition(program, q):
     (placement, shard): placement None off-mesh, else the mesh with the
     specs of a [B, T, H, D] operand and of a [B, H, T] row statistic
     (LSE); shard(x) is the aval of x's per-device block, which is what
-    the kernels' gate must pass."""
+    the kernels' gate must pass, or None for an x (a K or V of fewer
+    heads than Q) whose heads the tensor axis does not divide."""
     mesh = getattr(program, "_mesh", None)
     if mesh is None or mesh.size == 1 or q.ndim != 4:
         return None, lambda x: x
@@ -968,6 +969,8 @@ def _flash_partition(program, q):
 
     def shard(x):
         b, t, h, d = x.shape
+        if h % nh:
+            return None
         return jax.ShapeDtypeStruct((b // nb, t, h // nh, d), x.dtype)
 
     return _FlashPlacement(mesh, PartitionSpec(batch, None, heads, None),
@@ -985,10 +988,10 @@ def _kv_groups(q, k) -> int:
 
 def _repeat_kv(x, groups: int):
     """K or V of H_kv heads as the query's H: each head `groups` times in
-    a row. Every path (einsum, flash, ring) and the kernels' gate see
-    equal head counts; the grad op sums dK and dV back over each group.
-    The copies cost HBM traffic a kernel that reads H_kv heads would not
-    pay."""
+    a row, for the paths that need equal head counts (`_repeated_kv`'s
+    grounds); their grad ops sum dK and dV back over each group. The
+    flash kernels read H_kv heads where one head is a lane block and pay
+    none of it."""
     return x if groups == 1 else jnp.repeat(x, groups, axis=2)
 
 
@@ -1000,6 +1003,44 @@ def _sum_kv_groups(g, groups: int):
     b, t, h, d = g.shape
     return g.astype(jnp.float32).reshape(b, t, h // groups, groups, d) \
         .sum(3).astype(g.dtype)
+
+
+def _repeated_kv(q, k, mode, shard=lambda x: x):
+    """None where this lowering hands K and V on at their own head count
+    (equal counts, or the flash kernels read the K/V head of a query head
+    themselves: `pallas_attention.reads_kv_heads`), else the ground for
+    the repeat: "path" (`mode` is einsum or ring), "mesh" (the tensor
+    axis of a planned program does not divide H_kv: `shard` gives None)
+    or "lanes" (a lane block of several heads: D = 64)."""
+    from . import pallas_attention
+    if _kv_groups(q, k) == 1:
+        return None
+    if mode != "flash":
+        return "path"
+    if shard(k) is None:
+        return "mesh"
+    return None if pallas_attention.reads_kv_heads(shard(q), shard(k)) \
+        else "lanes"
+
+
+def _count_kv_groups(op_type: str, groups: int, ground):
+    """attention_kv_groups_total{op, groups, form, ground}: one per
+    lowering of a forward attention op under grouped-query attention,
+    form "kernel" (K and V read at their own heads, ground "") or
+    "repeated" with `_repeated_kv`'s ground."""
+    if groups > 1 and not kernel_choice.in_retrace():
+        from .. import telemetry
+        telemetry.counter(
+            "attention_kv_groups_total",
+            "lowerings of a forward attention op whose K and V have fewer "
+            "heads than Q, by query heads a K/V head and by form: kernel "
+            "(the flash kernels read K/V at their own head count) or "
+            "repeated (K/V widened to Q's heads ahead of the path, dK/dV "
+            "summed behind it) with the ground: lanes, path or mesh",
+            labels=("op", "groups", "form", "ground")).labels(
+                op=op_type, groups=str(groups),
+                form="repeated" if ground else "kernel",
+                ground=ground or "").inc()
 
 
 def _window(op_) -> int:
@@ -1027,19 +1068,22 @@ def _count_window(window: int):
 
 
 def _sdpa_paths(ctx, op_, q, k, v, count=False):
-    """(mode, how): 'ring' under sequence_parallel with an sp mesh (how =
-    the mesh; a window is refused there by name: the ring's shards know
-    none), 'flash' when use_flash is True, or 'auto' and the rule
-    (_flash_wins) gives the per-device shape to the kernels, and their
-    gate passes that shape (how = _flash_partition's placement), else
-    'einsum'. Auto-selection: the default config gets whichever path
-    is faster for its shape, no user flag. `count` books the decision
-    of this lowering, as every Pallas gate does (the forward op passes
-    it; the grad op recomputes the same static decision in silence): a
-    lowering on the kernels under pallas_kernel_total, a declined flash
-    request under pallas_fallback_total with the gate's reason. A step
-    traced again (a new feed shape) lowers its ops again and counts
-    them again, with the decision of that shape."""
+    """(mode, how, repeated): 'ring' under sequence_parallel with an sp
+    mesh (how = the mesh; a window is refused there by name: the ring's
+    shards know none), 'flash' when use_flash is True, or 'auto' and the
+    rule (_flash_wins) gives the per-device shape to the kernels, and
+    their gate passes that shape (how = _flash_partition's placement),
+    else 'einsum'. `repeated` is `_repeated_kv`'s ground for widening a K
+    and V of fewer heads to the query's count ahead of the path, None
+    where they go on as they came; the gate is asked about the operands
+    the kernels would get. Auto-selection: the default config gets
+    whichever path is faster for its shape, no user flag. `count` books
+    the decision of this lowering, as every Pallas gate does (the forward
+    op passes it; the grad op recomputes the same static decision in
+    silence): a lowering on the kernels under pallas_kernel_total, a
+    declined flash request under pallas_fallback_total with the gate's
+    reason. A step traced again (a new feed shape) lowers its ops again
+    and counts them again, with the decision of that shape."""
     from . import pallas_attention
     mesh = getattr(ctx.program, "_mesh", None)
     if op_.attr("sequence_parallel", False) and mesh is not None and \
@@ -1049,17 +1093,26 @@ def _sdpa_paths(ctx, op_, q, k, v, count=False):
             raise NotImplementedError(
                 f"{op_.type}: window={window} under sequence_parallel: "
                 f"ring attention takes no window")
-        return "ring", mesh
+        return "ring", mesh, _repeated_kv(q, k, "ring")
+    einsum = "einsum", None, _repeated_kv(q, k, "einsum")
     uf = op_.attr("use_flash", "auto")
     if not uf:
-        return "einsum", None
+        return einsum
     partition, shard = _flash_partition(ctx.program, q)
     if uf == "auto" and not _flash_wins(shard(q)):
-        return "einsum", None
-    reason = pallas_attention.ineligible(shard(q), shard(k), shard(v))
+        return einsum
+    repeated = _repeated_kv(q, k, "flash", shard)
+
+    def got(x):
+        if repeated:
+            x = jax.ShapeDtypeStruct(
+                x.shape[:2] + q.shape[2:3] + x.shape[3:], x.dtype)
+        return shard(x)
+
+    reason = pallas_attention.ineligible(shard(q), got(k), got(v))
     if count:
         kernel_choice.book("scaled_dot_product_attention", reason)
-    return ("flash", partition) if reason is None else ("einsum", None)
+    return ("flash", partition, repeated) if reason is None else einsum
 
 
 @op("scaled_dot_product_attention", infer_shape=_sdpa_infer,
@@ -1068,7 +1121,9 @@ def _scaled_dot_product_attention(ctx, op_, ins):
     """Fused softmax attention, Q/K/V [B, T, H, D] (no 2018-reference
     analogue — the capability the brief requires for long context). K and
     V may have fewer heads than Q (grouped-query attention: a divisor of
-    Q's count; _repeat_kv). With
+    Q's count; the flash kernels read them at their own count where one
+    head is a lane block, every other path has them repeated:
+    _repeated_kv). With
     sequence_parallel=True and a program mesh carrying an 'sp' axis, the
     computation runs as ring attention (parallel/ring_attention.py):
     sequence shards stay resident per device and K/V rotate over ICI via
@@ -1090,12 +1145,14 @@ def _scaled_dot_product_attention(ctx, op_, ins):
     causal, window = op_.attr("causal", False), _window(op_)
     (q, k, v), restore = mxu_cast(ctx, q, k, v)
     groups = _kv_groups(q, k)
-    k, v = _repeat_kv(k, groups), _repeat_kv(v, groups)
     from ..parallel.ring_attention import (attention_reference,
                                            attention_reference_lse,
                                            ring_attention_sharded)
-    mode, mesh = _sdpa_paths(ctx, op_, q, k, v, count=True)
+    mode, mesh, repeated = _sdpa_paths(ctx, op_, q, k, v, count=True)
     _count_window(window)
+    _count_kv_groups(op_.type, groups, repeated)
+    if repeated:
+        k, v = _repeat_kv(k, groups), _repeat_kv(v, groups)
     if mode == "ring":
         out, lse = ring_attention_sharded(
             q, k, v, mesh, axis="sp", causal=causal,
@@ -1136,10 +1193,11 @@ def _sdpa_grad_kernel(ctx, op_, ins):
     causal, window = op_.attr("causal", False), _window(op_)
     (q, k, v, do), restore = mxu_cast(ctx, q, k, v, do)
     groups = _kv_groups(q, k)
-    k, v = _repeat_kv(k, groups), _repeat_kv(v, groups)
     from ..parallel.ring_attention import (attention_reference,
                                            ring_attention_sharded)
-    mode, mesh = _sdpa_paths(ctx, op_, q, k, v)
+    mode, mesh, repeated = _sdpa_paths(ctx, op_, q, k, v)
+    if repeated:
+        k, v = _repeat_kv(k, groups), _repeat_kv(v, groups)
     if mode == "flash":
         from . import pallas_attention
         o = jnp.asarray(ins["Out"][0]).astype(q.dtype)
@@ -1180,7 +1238,8 @@ def _sdpa_grad_kernel(ctx, op_, ins):
                                                 window=window),
             q, k, v)
         dq, dk, dv = vjp_fn(do.astype(q.dtype))
-    dk, dv = _sum_kv_groups(dk, groups), _sum_kv_groups(dv, groups)
+    if repeated:
+        dk, dv = _sum_kv_groups(dk, groups), _sum_kv_groups(dv, groups)
     if restore is not None:
         dq, dk, dv = (dq.astype(restore), dk.astype(restore),
                       dv.astype(restore))
@@ -1262,15 +1321,19 @@ def _own_block_scores(qn, kn, block: int):
                    axis=-1) * scale
 
 
-def _to_own_rows(weight, x, block: int):
-    """_own_rows pulled back: [B, L, H, D] whose row j of every block is
-    the sum over the block's positions q of weight[j] at q times x at q;
-    weight [block, B, L, H] float32, x [B, L, H, D]."""
+def _to_own_rows(weight, x, block: int, groups: int = 1):
+    """_own_rows pulled back: [B, L, H / groups, D] float32 whose row j of
+    every block is the sum over the block's positions q, and over each
+    group of `groups` heads (_repeat_kv pulled back in the same
+    reduction), of weight[j] at q times x at q; weight [block, B, L, H]
+    float32, x [B, L, H, D]."""
     b, t, h, d = x.shape
     n = t // block
-    terms = weight.reshape(block, b, n, block, h, 1) \
-        * x.astype(jnp.float32).reshape(1, b, n, block, h, d)
-    return jnp.moveaxis(terms.sum(3), 0, 2).reshape(b, t, h, d)
+    terms = weight.reshape(block, b, n, block, h // groups, groups, 1) \
+        * x.astype(jnp.float32).reshape(1, b, n, block, h // groups, groups,
+                                        d)
+    return jnp.moveaxis(terms.sum((3, 5)), 0, 2).reshape(
+        b, t, h // groups, d)
 
 
 def _bd_flash(q, k, v, block: int):
@@ -1282,12 +1345,15 @@ def _bd_flash(q, k, v, block: int):
     strictly earlier blocks), returned unnormalized as (acc, l, m); its
     own block is L x block pairs a head, elementwise (_own_rows), merged
     with that partial by their row statistics as ring attention merges
-    its steps. Dead tiles are neither fetched nor walked, so the score
-    work is L^2 + O(L x tile) pairs a head, where the [2L, 2L] square has
-    4 L^2; nothing of [L, L] reaches HBM."""
+    its steps. K and V may have fewer heads than Q: the kernels take the
+    clean ones as they came, the own-block passes a repeat of the noisy
+    ones that XLA fuses into them. Dead tiles are neither fetched nor
+    walked, so the score work is L^2 + O(L x tile) pairs a head, where
+    the [2L, 2L] square has 4 L^2; nothing of [L, L] reaches HBM."""
     from . import pallas_attention
-    half = q.shape[0] // 2
+    half, groups = q.shape[0] // 2, _kv_groups(q, k)
     (qn, qc), (kn, kc), (vn, vc) = ((x[:half], x[half:]) for x in (q, k, v))
+    kn, vn = _repeat_kv(kn, groups), _repeat_kv(vn, groups)
     scale = 1.0 / (q.shape[-1] ** 0.5)
     out_c, lse_c = pallas_attention._forward(qc, kc, vc, True,
                                              return_lse=True, block=block)
@@ -1314,13 +1380,14 @@ def _bd_flash_grad(q, k, v, o, lse, do, block: int):
     pairs elementwise; the clean keys' gradient is the sum of what the
     two halves sent them."""
     from . import pallas_attention
-    half = q.shape[0] // 2
+    half, groups = q.shape[0] // 2, _kv_groups(q, k)
     scale = 1.0 / (q.shape[-1] ** 0.5)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     parts = ((x[:half], x[half:])
              for x in (q, k, v, do, lse, delta.transpose(0, 2, 1)))
     (qn, qc), (kn, kc), (vn, vc), (don, doc), (lse_n, lse_c), (dl_n, dl_c) \
         = parts
+    kn, vn = _repeat_kv(kn, groups), _repeat_kv(vn, groups)
     bwd = functools.partial(pallas_attention.flash_attention_bwd_block,
                             k_off=0, scale=scale, causal=True, block=block)
     dq_c, dk_c, dv_c = bwd(qc, kc, vc, doc, lse_c, dl_c, q_off=0)
@@ -1331,7 +1398,8 @@ def _bd_flash_grad(q, k, v, o, lse, do, block: int):
     dp = jnp.sum(don.astype(jnp.float32) * _own_rows(vn, block), axis=-1)
     ds = p * (dp - delta[:half]) * scale
     dq_own = jnp.sum(ds[..., None] * _own_rows(kn, block), axis=0)
-    dk_n, dv_n = _to_own_rows(ds, qn, block), _to_own_rows(p, don, block)
+    dk_n = _to_own_rows(ds, qn, block, groups)
+    dv_n = _to_own_rows(p, don, block, groups)
 
     def add(x, y):
         return (x.astype(jnp.float32) + y.astype(jnp.float32)).astype(q.dtype)
@@ -1341,25 +1409,32 @@ def _bd_flash_grad(q, k, v, o, lse, do, block: int):
             jnp.concatenate([dv_n.astype(q.dtype), add(dv_c, dv_c2)]))
 
 
-def _bd_takes_flash(ctx, op_, q, count=False) -> bool:
-    """Whether this lowering runs on the flash kernels: use_flash True,
-    or 'auto' and the rule of scaled_dot_product_attention
-    (_flash_wins) on one stream's [B, L, H, D], and the kernels' gate
-    passes that shape with the block length. The op carries no shard_map
-    of its own: a program planned over a mesh of several devices passes
-    use_flash=False. `count` books the decision under
-    op="block_diffusion_attention", a hit or the reason, as _sdpa_paths
-    does: the forward op passes it."""
+def _bd_takes_flash(ctx, op_, q, k, count=False):
+    """(flash, repeated): whether this lowering runs on the flash
+    kernels: use_flash True, or 'auto' and the rule of
+    scaled_dot_product_attention (_flash_wins) on one stream's
+    [B, L, H, D], and the kernels' gate passes that shape with the block
+    length; and `_repeated_kv`'s ground for widening K and V to the
+    query's heads first, None where they go on as they came. The op
+    carries no shard_map of its own: a program planned over a mesh of
+    several devices passes use_flash=False. `count` books the decision
+    under op="block_diffusion_attention", a hit or the reason, as
+    _sdpa_paths does: the forward op passes it."""
     from . import pallas_attention
     uf = op_.attr("use_flash", "auto")
     one = jax.ShapeDtypeStruct((q.shape[0] // 2,) + q.shape[1:], q.dtype)
     if not uf or (uf == "auto" and not _flash_wins(one)):
-        return False
-    reason = pallas_attention.ineligible(one, one, one,
+        return False, _repeated_kv(q, k, "einsum")
+    repeated = _repeated_kv(q, k, "flash")
+    kv = one if repeated else jax.ShapeDtypeStruct(
+        one.shape[:2] + k.shape[2:], k.dtype)
+    reason = pallas_attention.ineligible(one, kv, kv,
                                          block=op_.attr("block_length", 1))
     if count:
         kernel_choice.book(_BD_OP, reason)
-    return reason is None
+    if reason is not None:
+        return False, _repeated_kv(q, k, "einsum")
+    return True, repeated
 
 
 @op(_BD_OP, infer_shape=_sdpa_infer, grad=_sdpa_grad,
@@ -1369,7 +1444,8 @@ def _block_diffusion_attention(ctx, op_, ins):
     sequence runs as two streams of L positions, a noised copy and the
     clean one, Q/K/V [2B, L, H, D] with the B noisy streams first and
     their clean streams behind them (K and V may have fewer heads:
-    grouped-query attention, as scaled_dot_product_attention). With
+    grouped-query attention, as scaled_dot_product_attention, repeated
+    or not by the same rule). With
     b(i) = i // block_length, a noisy query at i sees the noisy keys of
     its own block (both directions) and the clean keys of earlier blocks;
     a clean query sees the clean keys of blocks up to its own. One
@@ -1385,10 +1461,11 @@ def _block_diffusion_attention(ctx, op_, ins):
     assert q.shape[0] % 2 == 0 and q.shape[1] % block == 0, (q.shape, block)
     (q, k, v), restore = mxu_cast(ctx, q, k, v)
     groups = _kv_groups(q, k)
-    k, v = _repeat_kv(k, groups), _repeat_kv(v, groups)
-    run = _bd_flash if _bd_takes_flash(ctx, op_, q, count=True) \
-        else _bd_einsum
-    out, lse = run(q, k, v, block)
+    flash, repeated = _bd_takes_flash(ctx, op_, q, k, count=True)
+    _count_kv_groups(op_.type, groups, repeated)
+    if repeated:
+        k, v = _repeat_kv(k, groups), _repeat_kv(v, groups)
+    out, lse = (_bd_flash if flash else _bd_einsum)(q, k, v, block)
     if restore is not None:
         out = out.astype(restore)
     return {"Out": [out], "LSE": [lse]}
@@ -1404,8 +1481,10 @@ def _block_diffusion_attention_grad(ctx, op_, ins):
     block = op_.attr("block_length", 1)
     (q, k, v, do), restore = mxu_cast(ctx, q, k, v, do)
     groups = _kv_groups(q, k)
-    k, v = _repeat_kv(k, groups), _repeat_kv(v, groups)
-    if _bd_takes_flash(ctx, op_, q):
+    flash, repeated = _bd_takes_flash(ctx, op_, q, k)
+    if repeated:
+        k, v = _repeat_kv(k, groups), _repeat_kv(v, groups)
+    if flash:
         dq, dk, dv = _bd_flash_grad(
             q, k, v, jnp.asarray(ins["Out"][0]).astype(q.dtype),
             jnp.asarray(ins["LSE"][0]), do.astype(q.dtype), block)
@@ -1413,7 +1492,8 @@ def _block_diffusion_attention_grad(ctx, op_, ins):
         _, vjp_fn = jax.vjp(lambda a, b, c: _bd_einsum(a, b, c, block)[0],
                             q, k, v)
         dq, dk, dv = vjp_fn(do.astype(q.dtype))
-    dk, dv = _sum_kv_groups(dk, groups), _sum_kv_groups(dv, groups)
+    if repeated:
+        dk, dv = _sum_kv_groups(dk, groups), _sum_kv_groups(dv, groups)
     if restore is not None:
         dq, dk, dv = (g.astype(restore) for g in (dq, dk, dv))
     return {name: [g] for name, g in (("Q@GRAD", dq), ("K@GRAD", dk),

@@ -61,9 +61,9 @@ attention's expanded heads: PR 33); under the block mask at D=128 (32
 heads, both streams of one 4096-token sequence in blocks of 4: PR 42)
 and under a window of 4096 keys at D=128 (28 heads, one 8192-token
 sequence in four major tiles: PR 46); under a window of 512 keys at
-D=128 beside causal layers of another head count (64 and 48 heads, K/V
-of 8 heads repeated 8 and 6 times ahead of the kernels, one 8192-token
-sequence: PR 53), where the window is one tile of 512 rows, below
+D=128 beside causal layers of another head count (64 and 48 heads over
+K/V of 8 heads, one 8192-token sequence: PR 53; the K/V read at their 8
+heads since PR 61, below), where the window is one tile of 512 rows, below
 bq + bk, so `_crosses_both` is live and every walked tile of a windowed
 layer is masked: the diagonal's tile by both edges and the tile before
 it by the far edge, none open; and at D=256 causal, 32 heads over one
@@ -88,6 +88,22 @@ is clamped in the `index_map`, so its DMA is not issued either. Row statistics (
 ring's m and l, the backward's delta) travel lane-dense as
 [B, H, T/rows, 1, rows].
 
+Grouped-query attention (PR 61): K and V may come as [B, T, H_kv, D]
+with H = groups x H_kv query heads, wherever a lane block is one head
+(`reads_kv_heads`: D a multiple of 128). Query head g reads K/V head
+g // groups: the K/V BlockSpecs of `flash_fwd`, `flash_dq` and
+`flash_dkv` index that lane block of the [B, T, H_kv*D] view, and the
+kernel bodies know nothing of it. dK and dV of a K/V head are the sum
+over its query heads: the fused `flash_dkv` then runs on the grid
+(B, H_kv, groups, Tk/bk, Tq/mq), a member of the group being one query
+head with its own dQ accumulator as before, and adds the members' shares
+in float32 scratch over the whole K sequence of the call ([Tk/bk, bk, L]
+each), rounded and stored once by the last member. With groups == 1 the
+specs, the grids and the bodies are the ones of before, instruction for
+instruction (tests/test_pallas_attention.py holds the jaxprs). Where a
+lane block holds several heads (D = 64) the attention op repeats K and V
+ahead of the kernels as before (ops/nn_ops._repeated_kv).
+
 Precision: dots take the input dtype (bf16 rides the MXU's half-precision
 datapath) with f32 ACCUMULATION via preferred_element_type; softmax
 statistics and scaling run in f32; P/dS are cast back to the input dtype
@@ -105,6 +121,7 @@ reason (`kernel_choice.book`).
 
 from __future__ import annotations
 
+import collections
 import functools
 import math
 
@@ -185,16 +202,23 @@ def ineligible(q, k, v, block: int = 1):
     sublane-aligned (T % 8 == 0: Mosaic tiles (8, 128) for f32); the
     heads must fill whole 128-lane blocks of the [B, T, H*D] view
     ("heads": 3 heads of 64), and D must divide 128 or be a multiple of
-    it ("head_dim": 96). The kernels read as many K/V heads as Q heads:
-    the attention op repeats a K/V of fewer heads (grouped-query
-    attention) to the query's count before it asks here
-    (ops/nn_ops._repeat_kv), so such a shape is gated, and its hit or
-    fallback reason booked, as full attention of the query's heads; a
-    K/V that reaches this gate with another head count than Q is
+    it ("head_dim": 96). K and V may have fewer heads than Q
+    (grouped-query attention: H_kv divides H, query head j reads K/V
+    head j // (H / H_kv)) where a lane block is ONE head
+    (`reads_kv_heads`: D a multiple of 128): the kernels then read K and
+    V at their own head count through the index maps, and nothing is
+    repeated. Where a lane block holds several heads (D = 64: the query
+    heads of a block would read a K/V head that sits at other lanes) the
+    attention op repeats K and V to the query's count before it asks
+    here (ops/nn_ops._repeat_kv) and the shape is gated as full
+    attention; a K/V of another head count that reaches this gate
+    there, or one whose count does not divide H, is
     "shape". A mask at the grain of `block` positions needs every K tile
     to start on a block boundary ("block": `block` must divide 128 and
     T)."""
-    if q.ndim != 4 or q.shape != k.shape or q.shape != v.shape:
+    if q.ndim != 4 or k.shape != v.shape or not (
+            q.shape == k.shape
+            or (q.shape[:2] == k.shape[:2] and reads_kv_heads(q, k))):
         return "shape"
     _, t, h, d = q.shape
     if not _seq_ok(t):
@@ -202,6 +226,16 @@ def ineligible(q, k, v, block: int = 1):
     if _LANES % block or t % block:
         return "block"
     return _heads_ineligible(h, d)
+
+
+def reads_kv_heads(q, k) -> bool:
+    """Whether the kernels read a K (and a V like it) of fewer heads than
+    Q at its own head count: [.., H_kv, D] beside Q's [.., H, D], H_kv
+    dividing H, one head a lane block."""
+    if k.ndim != 4 or q.shape[3] != k.shape[3]:
+        return False
+    h, hkv, d = q.shape[2], k.shape[2], q.shape[3]
+    return bool(hkv) and h % hkv == 0 and _lane_block(h, d)[1] == 1
 
 
 def supports(q, k, v) -> bool:
@@ -212,7 +246,8 @@ def block_supports(q, k) -> bool:
     """The ring path's per-shard gate: Q and the visiting K/V shard may
     differ in length; each must tile."""
     return (q.ndim == 4 and _seq_ok(q.shape[1]) and _seq_ok(k.shape[1])
-            and _heads_ineligible(q.shape[2], q.shape[3]) is None)
+            and _heads_ineligible(q.shape[2], q.shape[3]) is None
+            and (q.shape[2] == k.shape[2] or reads_kv_heads(q, k)))
 
 
 def _fit(t: int, want: int) -> int:
@@ -279,6 +314,7 @@ def _scratch(shape):
 # parallel is lost).
 _SEM = ("parallel", "parallel", "parallel", "arbitrary")
 _SEM_FUSED = ("parallel", "parallel", "arbitrary", "arbitrary")
+_SEM_MEMBERS = ("parallel", "parallel", "arbitrary", "arbitrary", "arbitrary")
 
 
 def _dot(a, b, dims):
@@ -611,29 +647,63 @@ def _call(kernel, name, grid, in_specs, out_specs, out_shape, scratch,
         compiler_params=_compiler_params(semantics))(*operands)
 
 
-def _specs(lanes, hpb, b_res, b_walk, m_walk, walk_index):
+_Specs = collections.namedtuple(
+    "_Specs", "res walk res_stat walk_stat res_kv walk_kv")
+
+
+def _specs(lanes, hpb, b_res, b_walk, m_walk, walk_index, groups=1,
+           members=False):
     """BlockSpecs of the family's grid (batch, lane block, resident tile,
     major tile): an operand tile and a row-statistic tile, on the
     resident and on the walked side. walk_index(i, kk, offs) is the major
-    tile to fetch (clamped where the causal mask kills one)."""
+    tile to fetch (clamped where the causal mask kills one).
+
+    `groups` query heads read one K/V head (a lane block is one head
+    then): `res_kv` / `walk_kv` are the tile of an operand of the K/V
+    heads' count, lane block g // groups where the query's operands take
+    g; with groups == 1 they are `res` / `walk` themselves. `members`
+    splits the head axis in two, (K/V head, member of its group): the
+    grid of the backward that sums a group's dK and dV (`_bwd_call`)."""
     import jax.experimental.pallas as pl
 
-    def res(bb, g, i, kk, offs):
+    def on(index):
+        """index(bb, g, g_kv, i, kk, offs) as an index map of the grid."""
+        if members:
+            return lambda bb, hk, m, i, kk, offs: index(
+                bb, hk * groups + m, hk, i, kk, offs)
+        if groups == 1:
+            return lambda bb, g, i, kk, offs: index(bb, g, g, i, kk, offs)
+        return lambda bb, g, i, kk, offs: index(
+            bb, g, lax.div(g, jnp.int32(groups)), i, kk, offs)
+
+    def res(bb, g, g_kv, i, kk, offs):
         return bb, i, g
 
-    def walk(bb, g, i, kk, offs):
+    def walk(bb, g, g_kv, i, kk, offs):
         return bb, walk_index(i, kk, offs), g
 
-    def res_stat(bb, g, i, kk, offs):
+    def res_kv(bb, g, g_kv, i, kk, offs):
+        return bb, i, g_kv
+
+    def walk_kv(bb, g, g_kv, i, kk, offs):
+        return bb, walk_index(i, kk, offs), g_kv
+
+    def res_stat(bb, g, g_kv, i, kk, offs):
         return bb, g, i, 0, 0
 
-    def walk_stat(bb, g, i, kk, offs):
+    def walk_stat(bb, g, g_kv, i, kk, offs):
         return bb, g, walk_index(i, kk, offs), 0, 0
 
-    return (pl.BlockSpec((1, b_res, lanes), res),
-            pl.BlockSpec((1, m_walk, lanes), walk),
-            pl.BlockSpec((1, hpb, 1, 1, b_res), res_stat),
-            pl.BlockSpec((1, hpb, m_walk // b_walk, 1, b_walk), walk_stat))
+    def tile(rows, index):
+        return pl.BlockSpec((1, rows, lanes), on(index))
+
+    res_tile, walk_tile = tile(b_res, res), tile(m_walk, walk)
+    return _Specs(
+        res_tile, walk_tile,
+        pl.BlockSpec((1, hpb, 1, 1, b_res), on(res_stat)),
+        pl.BlockSpec((1, hpb, m_walk // b_walk, 1, b_walk), on(walk_stat)),
+        tile(b_res, res_kv) if groups > 1 else res_tile,
+        tile(m_walk, walk_kv) if groups > 1 else walk_tile)
 
 
 # The wrappers are jitted so that a step with one attention per layer
@@ -654,7 +724,9 @@ def _check_window(window: int, causal: bool, block: int):
 def _fwd_call(q, k, v, q_off, k_off, scale, causal, normalize, tile=_TILE,
               major=_MAJOR, block=1, window=0):
     """Returns (out [B,Tq,H,D], stats): stats = (lse,) when normalizing,
-    else (m, l); each [B, H, Tq] f32."""
+    else (m, l); each [B, H, Tq] f32. k and v are [B,Tk,H_kv,D]: the
+    walked tiles of query lane block g are those of K/V lane block
+    g // (H / H_kv), and the kernel's body knows nothing of it."""
     _check_window(window, causal, block)
     b, tq, h, d = q.shape
     tk = k.shape[1]
@@ -663,9 +735,9 @@ def _fwd_call(q, k, v, q_off, k_off, scale, causal, normalize, tile=_TILE,
     mk = _major(tk, bk, major)
     n_maj = tk // mk
 
-    res, walk, res_stat, _ = _specs(
-        lanes, hpb, bq, bk, mk,
-        _kv_major_index(bq, mk, n_maj, causal, block, window))
+    sp = _specs(lanes, hpb, bq, bk, mk,
+                _kv_major_index(bq, mk, n_maj, causal, block, window),
+                groups=h // k.shape[2])
     struct = _vma_struct(q)
     n_stat = 1 if normalize else 2
     out, *stats = _call(
@@ -673,7 +745,7 @@ def _fwd_call(q, k, v, q_off, k_off, scale, causal, normalize, tile=_TILE,
                           d=d, hpb=hpb, scale=float(scale), causal=causal,
                           normalize=normalize, block=block, window=window),
         "flash_fwd", (b, h * d // lanes, tq // bq, n_maj),
-        [res, walk, walk], [res] + [res_stat] * n_stat,
+        [sp.res, sp.walk_kv, sp.walk_kv], [sp.res] + [sp.res_stat] * n_stat,
         [struct((b, tq, h * d), q.dtype if normalize else jnp.float32)]
         + [struct((b, h, tq // bq, 1, bq), jnp.float32)] * n_stat,
         [(lanes, bq), (hpb, 1, bq), (hpb, 1, bq)],
@@ -758,7 +830,8 @@ def _dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
 def _dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
                 dk_ref, dv_ref, *rest, bq: int, bk: int, mq: int,
                 n_maj: int, d: int, hpb: int, scale: float, causal: bool,
-                block: int = 1, n_k: int = 0, window: int = 0):
+                block: int = 1, n_k: int = 0, window: int = 0,
+                groups: int = 0):
     """Grid (B, lane blocks, Tk/bk, Tq/mq): K/V tile resident, the
     Q/dO/LSE/delta major tile walked in blocks of bq rows, dK/dV carried
     in scratch. Works on the transposed blocks S^T = K Q^T [bk, bq], so
@@ -775,21 +848,37 @@ def _dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
     `_dq_kernel` adds it, the resident K turned once a grid step. Q
     blocks this K tile never sees add nothing, which leaves their rows
     right; the accumulator is zeroed at the first K tile and scaled,
-    turned back and stored at the last."""
+    turned back and stored at the last.
+
+    groups > 0 is the fused backward of `groups` query heads a K/V head
+    (PR 61), on the grid (B, K/V heads, members of a group, Tk/bk,
+    Tq/mq): a member is one query head, walked as above with its own dQ,
+    against the K/V head they all read. dK and dV are the sum over the
+    members, so their float32 scratch is [Tk/bk, bk, L], a tile a K tile,
+    and lives across the members: zeroed by the first, rounded and stored
+    once by the last, where the equal-heads form rounds each head's
+    share and leaves the sum to XLA."""
     import jax.experimental.pallas as pl
 
     if n_k:
         dq_ref, dk_sc, dv_sc, dq_sc = rest
     else:
         dk_sc, dv_sc = rest
-    i = pl.program_id(2)    # k tile
-    kk = pl.program_id(3)   # q major tile
+    axis = 3 if groups else 2
+    i = pl.program_id(axis)         # k tile
+    kk = pl.program_id(axis + 1)    # q major tile
     k_first = off_ref[1] + i * bk
     q_base = off_ref[0] + kk * mq
     per = mq // bq
     fold = _folds(scale)
+    if groups:
+        member = pl.program_id(2)
+        dk_sc, dv_sc = dk_sc.at[i], dv_sc.at[i]
 
-    @pl.when(kk == 0)
+    def of_member(when, which):
+        return when & (member == which) if groups else when
+
+    @pl.when(of_member(kk == 0, 0))
     def _init():
         dk_sc[...] = jnp.zeros_like(dk_sc)
         dv_sc[...] = jnp.zeros_like(dv_sc)
@@ -838,7 +927,7 @@ def _dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
     else:
         _walk((0, per), None, None, walked)
 
-    @pl.when(kk == n_maj - 1)
+    @pl.when(of_member(kk == n_maj - 1, groups - 1))
     def _finalize():
         dk_ref[0] = (dk_sc[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
@@ -859,7 +948,7 @@ BACKWARD_SPLIT_REASONS = frozenset({"vmem"})
 
 
 def _split_reason(tq: int, tk: int, lanes: int, itemsize: int, dkv_tile,
-                  major: int):
+                  major: int, groups: int = 1):
     """None where the backward runs fused, else why it keeps two calls.
     From what the lowering sees alone (Tq, the lane block, the dtype,
     the tiles): "vmem" when dQ's float32 accumulator over the whole Q
@@ -868,13 +957,18 @@ def _split_reason(tq: int, tk: int, lanes: int, itemsize: int, dkv_tile,
     (`_compiler_params`); the other half is the compiler's (the
     [bk, bq] float32 blocks in flight count here at six). At 128 lanes
     in bf16 that is a Tq past 16384; the cells' 1024 and 4096 take 1 and
-    4-8 MB."""
+    4-8 MB. With `groups` > 1 query heads a K/V head the fused call also
+    holds dK's and dV's float32 sums over the whole K sequence (their
+    output blocks stay one tile: `_bwd_call`): 8192 rows at 128 lanes in
+    bf16 are 8.4 MB of dQ and 8.4 MB of sums in 27 MB, and the two calls
+    start past 8192 rows."""
     bk, bq = _fit(tk, dkv_tile[0]), _fit(tq, dkv_tile[1])
     mq = _major(tq, bq, major)
     dq = tq * lanes * (4 + 2 * itemsize)      # scratch; output, two buffers
     walked = 2 * 2 * mq * lanes * itemsize    # Q and dO, two buffers each
     # K, V, dK, dV, two buffers each; dK's and dV's scratch
-    resident = bk * lanes * (4 * 2 * itemsize + 2 * 4)
+    resident = bk * lanes * 4 * 2 * itemsize \
+        + (tk if groups > 1 else bk) * lanes * 2 * 4
     blocks = 6 * bk * bq * 4
     if dq + walked + resident + blocks > _VMEM_LIMIT // 2:
         return "vmem"
@@ -918,7 +1012,8 @@ def flash_attention_bwd_block(q, k, v, do, lse, delta, q_off, k_off, scale,
         f"with block_supports()")
     lanes, _ = _lane_block(*q.shape[2:])
     reason = _split_reason(q.shape[1], k.shape[1], lanes,
-                           jnp.dtype(q.dtype).itemsize, dkv_tile, major)
+                           jnp.dtype(q.dtype).itemsize, dkv_tile, major,
+                           groups=q.shape[2] // k.shape[2])
     count_backward(reason)
     return _bwd_call(q, k, v, do, lse, delta, q_off, k_off, float(scale),
                      causal, dq_tile, dkv_tile, major, block,
@@ -927,15 +1022,23 @@ def flash_attention_bwd_block(q, k, v, do, lse, delta, q_off, k_off, scale,
 
 @functools.partial(jax.jit, static_argnames=("scale", "causal", "dq_tile",
                                              "dkv_tile", "major", "block",
-                                             "fused", "window"))
+                                             "fused", "window", "summed"))
 def _bwd_call(q, k, v, do, lse, delta, q_off, k_off, scale, causal,
               dq_tile=_TILE, dkv_tile=_TILE, major=_MAJOR, block=1,
-              fused=True, window=0):
+              fused=True, window=0, summed=True):
     """flash_attention_bwd_block's kernels in the form it chose (the
-    sweep and the tests ask for either)."""
+    sweep and the tests ask for either). k and v are [B,Tk,H_kv,D] and
+    so are the dK and dV returned: every kernel reads the K/V head of its
+    query head through the index maps. Where H_kv < H the fused form sums
+    a group's dK and dV inside `flash_dkv`, on a grid whose head axis is
+    (K/V head, member of its group) (`_dkv_kernel`, groups > 0); the
+    split form, and the fused one under `summed=False` (the sweep's and
+    the tests' yardstick), write them a query head and sum them here."""
     _check_window(window, causal, block)
     b, tq, h, d = q.shape
-    tk = k.shape[1]
+    tk, h_kv = k.shape[1:3]
+    groups = h // h_kv
+    members = fused and summed and groups > 1
     lanes, hpb = _lane_block(h, d)
     # rows no shard ever validated carry lse = -inf (possible only for
     # non-causal corner cases); push them to +big so exp(s - lse) == 0 and
@@ -956,48 +1059,69 @@ def _bwd_call(q, k, v, do, lse, delta, q_off, k_off, scale, causal,
         mk = _major(tk, bk, major)
         n_maj = tk // mk
 
-        res, walk, res_stat, _ = _specs(
-            lanes, hpb, bq, bk, mk,
-            _kv_major_index(bq, mk, n_maj, causal, block, window))
+        sp = _specs(lanes, hpb, bq, bk, mk,
+                    _kv_major_index(bq, mk, n_maj, causal, block, window),
+                    groups=groups)
         dq = _call(
             functools.partial(_dq_kernel, bq=bq, bk=bk, mk=mk, n_maj=n_maj,
                               **statics),
             "flash_dq", (b, h * d // lanes, tq // bq, n_maj),
-            [res, walk, walk, res, res_stat, res_stat], res,
+            [sp.res, sp.walk_kv, sp.walk_kv, sp.res, sp.res_stat,
+             sp.res_stat], sp.res,
             struct((b, tq, h * d), q.dtype), [(lanes, bq)],
             offs, q2, k2, v2, do2, stat(lse, bq), stat(delta, bq))
 
     bk, bq = _fit(tk, dkv_tile[0]), _fit(tq, dkv_tile[1])
     mq = _major(tq, bq, major)
     n_maj = tq // mq
+    n_k = tk // bk
 
-    res, walk, _, walk_stat = _specs(
-        lanes, hpb, bk, bq, mq,
-        _q_major_index(bq, bk, mq, n_maj, causal, block, window))
-    out_specs = [res, res]
-    out_shape = [struct((b, tk, h * d), k.dtype),
-                 struct((b, tk, h * d), v.dtype)]
-    scratch = [(bk, lanes), (bk, lanes)]
+    sp = _specs(lanes, hpb, bk, bq, mq,
+                _q_major_index(bq, bk, mq, n_maj, causal, block, window),
+                groups=groups, members=members)
+    import jax.experimental.pallas as pl
+    if members:
+        # a group's dK and dV leave once, from its last member: until
+        # then the block's index holds still and nothing is written back
+        kv_out = pl.BlockSpec(
+            (1, bk, lanes), lambda bb, hk, m, i, kk, offs: (
+                bb, jnp.where(m == groups - 1, i, 0), hk))
+        grid, semantics = (b, h_kv, groups, n_k, n_maj), _SEM_MEMBERS
+        scratch = [(n_k, bk, lanes), (n_k, bk, lanes)]
+        dq_index = lambda bb, hk, m, i, kk, offs: (bb, 0, hk * groups + m)
+    else:
+        kv_out = sp.res
+        grid = (b, h * d // lanes, n_k, n_maj)
+        semantics = _SEM_FUSED if fused else _SEM
+        scratch = [(bk, lanes), (bk, lanes)]
+        dq_index = lambda bb, g, i, kk, offs: (bb, 0, g)
+    heads_out = h_kv if members else h
+    out_specs = [kv_out, kv_out]
+    out_shape = [struct((b, tk, heads_out * d), k.dtype),
+                 struct((b, tk, heads_out * d), v.dtype)]
     if fused:
-        import jax.experimental.pallas as pl
         # dQ's block is the whole Q sequence of a (batch, lane block): its
         # index holds still over the K tiles and the major tiles, so it
         # is written back once, after the last of them
-        out_specs.append(pl.BlockSpec(
-            (1, tq, lanes), lambda bb, g, i, kk, offs: (bb, 0, g)))
+        out_specs.append(pl.BlockSpec((1, tq, lanes), dq_index))
         out_shape.append(struct((b, tq, h * d), q.dtype))
         scratch.append((tq // bq, lanes, bq))
     outs = _call(
         functools.partial(_dkv_kernel, bq=bq, bk=bk, mq=mq, n_maj=n_maj,
-                          n_k=tk // bk if fused else 0, **statics),
-        "flash_dkv", (b, h * d // lanes, tk // bk, n_maj),
-        [walk, res, res, walk, walk_stat, walk_stat], out_specs, out_shape,
+                          n_k=n_k if fused else 0,
+                          groups=groups if members else 0, **statics),
+        "flash_dkv", grid,
+        [sp.walk, sp.res_kv, sp.res_kv, sp.walk, sp.walk_stat,
+         sp.walk_stat], out_specs, out_shape,
         scratch, offs, q2, k2, v2, do2, stat(lse, bq), stat(delta, bq),
-        semantics=_SEM_FUSED if fused else _SEM)
+        semantics=semantics)
     if fused:
         dk, dv, dq = outs
     else:
         dk, dv = outs
+    if heads_out != h_kv:
+        dk, dv = (x.astype(jnp.float32).reshape(b, tk, h_kv, groups, d)
+                  .sum(3).astype(x.dtype) for x in (dk, dv))
 
     return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape))
 

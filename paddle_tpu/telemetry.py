@@ -934,6 +934,11 @@ METRIC_CATALOG = {
     "attention_window_total": _m("counter", ("window",),
                                  "forward attention lowerings under a "
                                  "sliding window, by its keys"),
+    "attention_kv_groups_total": _m(
+        "counter", ("op", "groups", "form", "ground"),
+        "forward attention lowerings under grouped-query attention, by "
+        "form: kernel (K/V read at their own head count) or repeated, "
+        "with the ground"),
     "kda_scan_total": _m("counter", ("chunk", "path"),
                          "forward kda_scan lowerings, by the delta rule's "
                          "chunk length and the path taken"),

@@ -117,7 +117,7 @@ class TestAutoSelection:
         import jax
         import paddle_tpu.ops.nn_ops as nn_ops
         probe = jax.ShapeDtypeStruct((2, t, heads, 64), "bfloat16")
-        mode, _ = nn_ops._sdpa_paths(
+        mode, _, _ = nn_ops._sdpa_paths(
             self._Ctx(), op_ or self._Op(attrs), probe, probe, probe,
             count=op_ is not None)
         return mode
@@ -157,9 +157,9 @@ class TestAutoSelection:
             return nn_ops._sdpa_paths(Ctx(), self._Op({}), probe, probe,
                                       probe)
 
-        took, placement = mode(20)
+        took, placement, _ = mode(20)
         assert took == "flash" and placement.mesh is Ctx.program._mesh
-        assert mode(6) == ("einsum", None)
+        assert mode(6) == ("einsum", None, None)
 
     def test_lowering_books_hit_and_fallback(self):
         """pallas_kernel_total for a lowering on the kernels (12 heads),

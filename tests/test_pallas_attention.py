@@ -139,18 +139,22 @@ class TestFlashKernel:
                                        rtol=1e-3, atol=1e-3)
 
 
-def _dense_part(q, k, v, do, q_off, k_off, causal, block):
+def _dense_part(q, k, v, do, q_off, k_off, causal, block, window=0):
     """One part of an attention, dense in float32: the gradients of
     softmax(mask(QK^T / sqrt(D))) V over this part's keys alone, its
     LSE (-inf on a row that sees no key of the part) and delta, as the
     ring's backward and block-diffusion attention's hand them to the
-    kernels. keep: floor(q_pos / block) >= floor(k_pos / block)."""
+    kernels. keep: floor(q_pos / block) >= floor(k_pos / block), and
+    under a window q_pos - k_pos < window."""
     f32 = [x.astype(jnp.float32) for x in (q, k, v, do)]
     scale = 1.0 / np.sqrt(q.shape[-1])
     keep = np.ones((q.shape[1], k.shape[1]), bool)
     if causal:
-        keep = ((np.arange(q.shape[1])[:, None] + q_off) // block
-                >= (np.arange(k.shape[1])[None, :] + k_off) // block)
+        q_pos = np.arange(q.shape[1])[:, None] + q_off
+        k_pos = np.arange(k.shape[1])[None, :] + k_off
+        keep = q_pos // block >= k_pos // block
+        if window:
+            keep &= q_pos - k_pos < window
     seen = jnp.asarray(keep.any(-1))
     f32[3] = f32[3] * seen[None, :, None, None]
 
@@ -208,6 +212,198 @@ def _backwards_booked():
     from paddle_tpu import telemetry
     booked = dict(telemetry.read_series("flash_backward_total"))
     return (booked.pop("form=fused,reason=", 0), booked)
+
+
+def _repeat(x, groups):
+    return jnp.repeat(x, groups, axis=2)
+
+
+def _sum_groups(x, groups):
+    b, t, h, d = x.shape
+    return x.astype(jnp.float32).reshape(b, t, h // groups, groups, d).sum(3)
+
+
+# (id, (B, T, H, D), K/V heads, block, q_off, window): the grouped shapes
+# of ISSUE 61 under the masks of the three cells that run them (causal,
+# a window, block-diffusion attention's two kernel parts)
+GROUPED = [
+    pytest.param(shape, kv, block, q_off, window, dtype, id=(
+        f"{shape[2]}over{kv}-t{shape[1]}-{name}-{dtype.__name__}"))
+    for (shape, kv), dtype in (
+        (((1, 256, 8, 128), 2), jnp.float32),
+        (((1, 512, 6, 128), 2), jnp.bfloat16))
+    for name, block, q_off, window in (
+        ("causal", 1, 0, 0), ("window128", 1, 0, 128),
+        ("block4", 4, 0, 0), ("block4_earlier", 4, -4, 0))]
+
+
+class TestKVHeads:
+    """K and V of fewer heads than Q, read at their own head count (PR
+    61): the kernels' K/V index maps take lane block g // groups, and the
+    fused `flash_dkv` sums a group's dK and dV in float32 scratch on the
+    grid (B, H_kv, members, Tk/bk, Tq/mq). Against the equal-heads
+    kernels on repeated K/V (the form of before) and a dense float32
+    gradient; two blocks a major tile, two major tiles at T = 512."""
+
+    TILES = dict(tile=(128, 128), major=256)
+
+    @pytest.mark.parametrize("shape,kv,block,q_off,window,dtype", GROUPED)
+    def test_forward_is_the_repeated_one_bit_for_bit(self, shape, kv, block,
+                                                     q_off, window, dtype):
+        rng = np.random.default_rng(sum(shape) + kv + block)
+        b, t, h, d = shape
+        q = jnp.asarray(rng.standard_normal(shape), dtype)
+        k, v = (jnp.asarray(rng.standard_normal((b, t, kv, d)), dtype)
+                for _ in range(2))
+        assert pallas_attention.ineligible(q, k, v, block=block) is None
+        for normalize in (True, False) if not window else (True,):
+            with jax.default_matmul_precision("highest"):
+                run = lambda k, v: pallas_attention._fwd_call(
+                    q, k, v, q_off, 0, d ** -0.5, True, normalize=normalize,
+                    block=block, window=window, **self.TILES)
+                got, want = run(k, v), run(_repeat(k, h // kv),
+                                           _repeat(v, h // kv))
+            for a, b_ in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+                np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                              np.asarray(b_, np.float32))
+
+    @pytest.mark.parametrize("shape,kv,block,q_off,window,dtype", GROUPED)
+    def test_backward_sums_a_group_in_float32(self, shape, kv, block, q_off,
+                                              window, dtype):
+        """dQ is the repeated form's to the bit in every form; dK and dV
+        written a query head and summed behind the call are the repeated
+        form's to the bit, and summed inside the fused call they are no
+        further (root mean square) from the dense float32 gradient than
+        those."""
+        rng = np.random.default_rng(sum(shape) + kv + block + 1)
+        b, t, h, d = shape
+        groups = h // kv
+        q, do = (jnp.asarray(rng.standard_normal(shape), dtype)
+                 for _ in range(2))
+        k, v = (jnp.asarray(rng.standard_normal((b, t, kv, d)), dtype)
+                for _ in range(2))
+        kr, vr = _repeat(k, groups), _repeat(v, groups)
+        tiles = dict(dq_tile=(128, 128), dkv_tile=(128, 128), major=256,
+                     block=block, window=window)
+        with jax.default_matmul_precision("highest"):
+            do, lse, delta, (_, dk_ref, dv_ref) = _dense_part(
+                q, kr, vr, do, q_off, 0, True, block, window)
+            rest = (do, lse, delta, q_off, 0, d ** -0.5, True)
+            dq_r, dk_r, dv_r = pallas_attention._bwd_call(
+                q, kr, vr, *rest, fused=True, **tiles)
+            forms = {name: pallas_attention._bwd_call(q, k, v, *rest, **form,
+                                                      **tiles)
+                     for name, form in (
+                         ("summed", dict(fused=True)),
+                         ("per_head", dict(fused=True, summed=False)),
+                         ("split", dict(fused=False)))}
+        want = [_sum_groups(x, groups) for x in (dk_ref, dv_ref)]
+        repeated = [_sum_groups(x, groups).astype(dtype).astype(jnp.float32)
+                    for x in (dk_r, dv_r)]
+        for name, (dq, dk, dv) in forms.items():
+            assert dk.shape == k.shape and dv.shape == v.shape
+            if name != "split":     # the split form's dQ sums another way
+                np.testing.assert_array_equal(np.asarray(dq, np.float32),
+                                              np.asarray(dq_r, np.float32))
+            for got, rep, ref in zip((dk, dv), repeated, want):
+                got = np.asarray(got, np.float32)
+                if name != "summed":
+                    np.testing.assert_array_equal(got, np.asarray(rep))
+                # root mean square: the largest error is one rounding
+                # of the largest value either way
+                far, before = (float(jnp.sqrt(jnp.mean((x - ref) ** 2)))
+                               for x in (got, rep))
+                assert far <= before * 1.001 + 1e-6, (name, far, before)
+
+    def test_the_gate_takes_a_group_only_at_one_head_a_lane_block(self):
+        def reason(h, kv, d):
+            q = jax.ShapeDtypeStruct((1, 256, h, d), jnp.bfloat16)
+            k = jax.ShapeDtypeStruct((1, 256, kv, d), jnp.bfloat16)
+            return pallas_attention.ineligible(q, k, k)
+
+        assert reason(8, 2, 128) is None and reason(6, 2, 256) is None
+        assert reason(8, 3, 128) == "shape"     # 3 does not divide 8
+        assert reason(4, 2, 64) == "shape"      # two heads a lane block
+        assert reason(2, 1, 32) == "shape"      # one block of two heads
+        assert reason(3, 3, 64) == "heads"      # equal counts: as before
+
+    @pytest.mark.parametrize("tq,groups,reason", [
+        (8192, 8, None), (8192, 6, None), (8192, 7, None), (4096, 8, None),
+        (16384, 1, None), (16384, 2, "vmem")])
+    def test_the_rule_counts_the_sums_of_a_group(self, tq, groups, reason):
+        """The cells' grouped calls (8192 rows: Laguna's 8 and 6 query
+        heads a K/V head, the sliding window's 7; 4096: block diffusion's
+        8) run fused with dK's and dV's float32 sums over the whole K
+        sequence counted; past 8192 rows a group keeps two calls."""
+        assert pallas_attention._split_reason(
+            tq, tq, 128, 2, pallas_attention._TILE, pallas_attention._MAJOR,
+            groups=groups) == reason
+
+
+# sha256 (16 hex digits) of str(jax.make_jaxpr(call)) for the kernels'
+# calls at equal head counts, taken from the tree BEFORE PR 61
+# (ops/pallas_attention.py of commit 1853025): grid, BlockSpecs with their
+# index maps, scratch and kernel body are in that string. (call, shape,
+# dtype, static arguments)
+PARENTS_CALLS = [
+    ("fwd", (1, 256, 12, 64), "float32", {}, "0d8319c853a45065"),
+    ("fwd", (1, 1024, 2, 128), "bfloat16", {}, "c8ecd8c84e1f5b46"),
+    ("fwd", (1, 512, 2, 128), "bfloat16", dict(block=4, normalize=False),
+     "cfa5d35e35ae872c"),
+    ("fwd", (1, 1024, 2, 128), "bfloat16", dict(window=256, major=512),
+     "7966706ff094cb47"),
+    ("fwd", (1, 512, 1, 256), "bfloat16", {}, "f48e4dec5755ae3f"),
+    ("bwd", (1, 256, 12, 64), "float32", dict(fused=True), "aba3668d5c66787e"),
+    ("bwd", (1, 1024, 2, 128), "bfloat16", dict(fused=True), "8425e0ce358f44f8"),
+    ("bwd", (1, 1024, 2, 128), "bfloat16", dict(fused=False), "3a91e4f0b99b6c90"),
+    ("bwd", (1, 512, 2, 128), "bfloat16", dict(fused=True, block=4), "71a55f4189e80c80"),
+    ("bwd", (1, 1024, 2, 128), "bfloat16",
+     dict(fused=True, window=256, major=512), "1a89a86a1ae0f8ac"),
+    ("bwd", (1, 512, 1, 256), "bfloat16", dict(fused=True), "bc613535c5255d9a"),
+]
+
+
+def call_digest(module, call, shape, dtype, statics):
+    """The digest PARENTS_CALLS holds, of `module`'s kernels."""
+    import hashlib
+    b, t, h, d = shape
+    x = jax.ShapeDtypeStruct(shape, dtype)
+    stat = jax.ShapeDtypeStruct((b, h, t), jnp.float32)
+    if call == "fwd":
+        statics = dict(dict(normalize=True), **statics)
+        jaxpr = jax.make_jaxpr(lambda q, k, v: module._fwd_call(
+            q, k, v, 0, 0, d ** -0.5, True, **statics))(x, x, x)
+    else:
+        jaxpr = jax.make_jaxpr(lambda q, k, v, do, lse, dl: module._bwd_call(
+            q, k, v, do, lse, dl, 0, 0, d ** -0.5, True, **statics))(
+                x, x, x, x, stat, stat)
+    text = [str(jaxpr)]
+
+    def index_maps(jaxpr):
+        """A pallas_call prints its BlockSpecs without their index maps."""
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                text.extend(str(m.index_map_jaxpr) for m in
+                            eqn.params["grid_mapping"].block_mappings)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                index_maps(sub)
+
+    index_maps(jaxpr.jaxpr)
+    assert len(text) > 1
+    return hashlib.sha256("\n".join(text).encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("call,shape,dtype,statics,digest", PARENTS_CALLS,
+                         ids=[f"{c[0]}-{'x'.join(map(str, c[1]))}-" + "-".join(
+                             f"{k}{v}" for k, v in c[3].items())
+                             for c in PARENTS_CALLS])
+def test_equal_head_counts_lower_to_the_calls_of_before(call, shape, dtype,
+                                                        statics, digest):
+    """With groups == 1 every kernel call is the one of before PR 61:
+    GPT-2's, GPT-2 large's, the latent cells' and Ouro's steps compile
+    to what they compiled to."""
+    assert call_digest(pallas_attention, call, shape, dtype,
+                       statics) == digest
 
 
 class TestFusedBackward:
@@ -334,6 +530,71 @@ class TestFlashThroughProgram:
             outs[flash] = np.asarray(r)
         np.testing.assert_allclose(outs[True], outs[False],
                                    rtol=2e-5, atol=2e-6)
+
+
+class TestGroupedThroughProgram:
+    """The attention op under grouped-query attention (PR 61): K and V
+    reach the flash kernels at their own head count where one head is a
+    lane block, repeated elsewhere, and `attention_kv_groups_total` books
+    which."""
+
+    @staticmethod
+    def _grads(heads, kv_heads, d, use_flash, feed):
+        from paddle_tpu import executor as executor_mod
+        from paddle_tpu.framework.framework import grad_var_name
+        main, startup = fluid.Program(), fluid.Program()
+        with fluid.program_guard(main, startup):
+            q, k, v = (fluid.layers.data(
+                name=n, shape=[1, 256, h, d], dtype="float32",
+                append_batch_size=False)
+                for n, h in (("q", heads), ("k", kv_heads), ("v", kv_heads)))
+            for var in (q, k, v):
+                var.stop_gradient = False
+                var.desc.stop_gradient = False
+            out = fluid.layers.fused_attention(q, k, v, causal=True,
+                                               use_flash=use_flash)
+            loss = fluid.layers.mean(fluid.layers.elementwise_mul(out, out))
+            fluid.backward.append_backward(loss)
+        exe = fluid.Executor(fluid.CPUPlace())
+        with executor_mod.scope_guard(executor_mod.Scope()):
+            with jax.default_matmul_precision("highest"):
+                return exe.run(main, feed=feed, fetch_list=[out] + [
+                    grad_var_name(n) for n in ("q", "k", "v")])
+
+    @pytest.mark.parametrize("heads,kv_heads,d,series", [
+        (8, 2, 128, "groups=4,form=kernel,ground="),
+        (4, 2, 64, "groups=2,form=repeated,ground=lanes")],
+        ids=["one_head_a_lane_block", "two_heads_a_lane_block"])
+    def test_the_op_books_its_form_and_matches_einsum(self, heads, kv_heads,
+                                                      d, series):
+        from paddle_tpu import telemetry
+        from paddle_tpu.ops import kernel_choice, nn_ops
+
+        def booked():
+            return dict(telemetry.read_series("attention_kv_groups_total"))
+
+        rng = np.random.default_rng(heads)
+        feed = {n: rng.standard_normal((1, 256, h, d)).astype(np.float32)
+                for n, h in (("q", heads), ("k", kv_heads), ("v", kv_heads))}
+        series = "op=scaled_dot_product_attention," + series
+        path = f"op=scaled_dot_product_attention," \
+            f"groups={heads // kv_heads},form=repeated,ground=path"
+        before = booked()
+        flash = self._grads(heads, kv_heads, d, True, feed)
+        # one for the forward op's lowering, none for the gradient op's
+        after = booked()
+        assert after.get(series, 0) == before.get(series, 0) + 1
+        assert sum(after.values()) == sum(before.values()) + 1
+        einsum = self._grads(heads, kv_heads, d, False, feed)
+        assert booked().get(path, 0) == after.get(path, 0) + 1
+        for got, want in zip(flash, einsum):
+            assert got.shape == want.shape
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       rtol=1e-3, atol=1e-5)
+        # a generic gradient's re-trace of a forward lowering is silent
+        with kernel_choice.retrace():
+            nn_ops._count_kv_groups("scaled_dot_product_attention", 4, None)
+        assert booked() == dict(after, **{path: after.get(path, 0) + 1})
 
 
 class TestFlashRingComposition:

@@ -225,7 +225,7 @@ def _block_diffusion_fwd_bwd(q, k, v):
 
 def test_flash_kernels_compile_under_the_block_mask(mosaic, one_chip):
     """The block-diffusion cell's attention op at its shape, both streams
-    of one 4096-token sequence, 32 heads of 128 after the K/V repeat, in
+    of one 4096-token sequence, 32 heads of 128 at equal head counts, in
     blocks of 4: each kernel twice (the clean half block-causal, the
     noisy half against the clean keys of strictly earlier blocks)."""
     shape = (2, 4096, 32, 128)
@@ -236,20 +236,27 @@ def test_flash_kernels_compile_under_the_block_mask(mosaic, one_chip):
         "flash_dkv", "flash_dkv", "flash_fwd", "flash_fwd"]
 
 
-def _window_fwd_bwd(window, q, k, v):
-    out, lse = pallas_attention._forward(q, k, v, True, return_lse=True,
-                                         window=window)
+def _masked_fwd_bwd(window, block, q_off, q, k, v):
+    scale = q.shape[-1] ** -0.5
+    if block == 1:
+        out, lse = pallas_attention._forward(q, k, v, True, return_lse=True,
+                                             window=window)
+    else:       # one part of block-diffusion attention, merged by the op
+        out, _, lse = pallas_attention.flash_attention_block(
+            q, k, v, q_off, 0, scale, True, block=block)
+        out = out.astype(q.dtype)
     delta = jnp.sum(out.astype(jnp.float32) ** 2, -1).transpose(0, 2, 1)
     return pallas_attention.flash_attention_bwd_block(
-        q, k, v, out, lse, delta, 0, 0, 128 ** -0.5, True, window=window)
+        q, k, v, out, lse, delta, q_off, 0, scale, True, block=block,
+        window=window)
 
 
 @pytest.mark.parametrize("window", [4096, 4000, 600],
                          ids=["the_cells", "no_multiple_of_a_tile",
                               "shorter_than_two_tiles"])
 def test_flash_kernels_compile_under_a_window(mosaic, one_chip, window):
-    """The sliding-window cell's attention op at its shape, one
-    8192-token sequence, 28 heads of 128 after the K/V repeat, four major
+    """The sliding-window cell's attention op at its shape at equal head
+    counts, one 8192-token sequence, 28 heads of 128, four major
     tiles of 2048 rows: the forward and the fused backward (dQ's
     accumulator over 8192 rows is 4 MB a lane block) whose walk ranges
     and index maps take the window's far edge; a window that makes the
@@ -258,8 +265,58 @@ def test_flash_kernels_compile_under_a_window(mosaic, one_chip, window):
     shape = (1, 8192, 28, 128)
     one = jax.ShapeDtypeStruct(shape, BF16)
     assert pallas_attention.ineligible(one, one, one) is None
-    assert _compile(functools.partial(_window_fwd_bwd, window), one_chip,
-                    *[(shape, BF16)] * 3) == ["flash_dkv", "flash_fwd"]
+    assert _compile(functools.partial(_masked_fwd_bwd, window, 1, 0),
+                    one_chip, *[(shape, BF16)] * 3) == ["flash_dkv",
+                                                         "flash_fwd"]
+
+
+@pytest.mark.parametrize("t,heads,kv_heads,window,block,q_off", [
+    (8192, 64, 8, 512, 1, 0), (8192, 48, 8, 0, 1, 0),
+    (8192, 28, 4, 4096, 1, 0), (4096, 32, 4, 0, 4, 0),
+    (4096, 32, 4, 0, 4, -4)],
+    ids=["laguna_window", "laguna_full", "sliding_window",
+         "block_diffusion_clean", "block_diffusion_earlier"])
+def test_flash_kernels_read_kv_at_their_own_heads(mosaic, one_chip, t, heads,
+                                                  kv_heads, window, block,
+                                                  q_off):
+    """The three grouped-query cells' attention calls at their shapes
+    (PR 61): K, V, dK and dV of the K/V heads' count beside a Q of 6 to 8
+    times as many heads of 128, the forward and the backward as ONE fused
+    `flash_dkv` on the grid (B, K/V heads, members, K tiles, Q major
+    tiles) with dQ's accumulator and a group's float32 dK / dV sums over
+    8192 rows in VMEM: the rule sends none of them to the two calls."""
+    from paddle_tpu import telemetry
+    q, kv = (1, t, heads, 128), (1, t, kv_heads, 128)
+    assert pallas_attention.ineligible(
+        *(jax.ShapeDtypeStruct(s, BF16) for s in (q, kv, kv)),
+        block=block) is None
+    before = dict(telemetry.read_series("flash_backward_total"))
+    assert _compile(
+        functools.partial(_masked_fwd_bwd, window, block, q_off), one_chip,
+        (q, BF16), (kv, BF16), (kv, BF16)) == ["flash_dkv", "flash_fwd"]
+    fused = "form=fused,reason="
+    assert dict(telemetry.read_series("flash_backward_total")) == dict(
+        before, **{fused: before.get(fused, 0) + 1})
+
+
+def _flash_calls(text):
+    """(kernel, operand shapes, result shape) of each flash call in an
+    optimized HLO: the operands from the call's layout constraints."""
+    calls = []
+    for line in text.splitlines():
+        name = re.search(r"(flash_\w+)\)*/pallas_call", line)
+        if KERNEL in line and name:
+            operands = re.search(
+                r"operand_layout_constraints=\{(.*?\})\}", line).group(1)
+            calls.append((name.group(1),
+                          re.findall(r"\w+\[[\d,]*\]", operands),
+                          line.split(" custom-call(")[0]))
+    return calls
+
+
+def _kv_groups_booked():
+    from paddle_tpu import telemetry
+    return dict(telemetry.read_series("attention_kv_groups_total"))
 
 
 @pytest.mark.parametrize("rows,dtype", [
@@ -878,7 +935,9 @@ def test_gated_window_step_keeps_no_product_of_a_forward_that_is_replayed(
     cell = run.load_json("workloads", LAGUNA_CELL)
     config = dict(run.load_json("configs", cell["config"]),
                   num_hidden_layers=3)
+    booked = _kv_groups_booked()
     text = describe_step.compile_step(cell, config, one_chip).as_text()
+    _no_kv_repeat_around_the_flash_calls(text, booked)
     kernels = [(i.op, i.recompute is not None,
                 re.search(r"(\w+)\)*/pallas_call", i.op_name).group(1))
                for i in xplane.hlo_instructions(text)
@@ -901,6 +960,37 @@ def test_gated_window_step_keeps_no_product_of_a_forward_that_is_replayed(
         for i in xplane.hlo_instructions(text)
         if i.opcode == "conditional" and i.op == "moe_experts")
     assert [s[1:] for s in switches] == [(False, 0), (False, 2), (True, 2)]
+
+
+def _no_kv_repeat_around_the_flash_calls(text, booked):
+    """The same step's attention ops (PR 61), a causal layer of 48 query
+    heads and two windowed ones of 64 over 8 K/V heads of 128: every
+    flash call takes K and V as `bf16[1, 8192, 1024]`, the gradient
+    calls give dK and dV so, and under the two ops nothing of
+    `bf16[8192, 8, groups, 128]` is broadcast and nothing reduced to
+    `bf16[8192, 8, 128]`: no K or V at the query's width (`bf16[1, 8192,
+    8192]` or `[1, 8192, 6144]`) is made ahead of a Mosaic call and no
+    group sum runs behind one."""
+    calls = _flash_calls(text)
+    assert sorted(c[0] for c in calls) == ["flash_dkv"] * 3 + ["flash_fwd"] * 3
+    kv = "bf16[1,8192,1024]"
+    for kernel, operands, result in calls:
+        assert operands.count(kv) == 2, (kernel, operands)
+        assert result.count(kv) == (2 if kernel == "flash_dkv" else 0)
+    ops = ("scaled_dot_product_attention", "scaled_dot_product_attention_grad")
+    around = [i for i in xplane.hlo_instructions(text) if i.op in ops]
+    assert len(around) > 20
+    for i in around:
+        assert not (i.opcode == "broadcast" and i.shape.startswith("bf16[")
+                    ), (i.name, i.shape)
+        assert not (i.opcode == "reduce"
+                    and i.shape.startswith("bf16[8192,8,128]")), i.name
+    now = _kv_groups_booked()
+    added = {k: v - booked.get(k, 0) for k, v in now.items()
+             if v != booked.get(k, 0)}
+    op = "op=scaled_dot_product_attention,groups="
+    assert added == {op + "6,form=kernel,ground=": 1,
+                     op + "8,form=kernel,ground=": 2}
 
 
 KDA_CELL = "kimi-linear.train-kda-t8192-ep32-share"
@@ -997,12 +1087,18 @@ def test_granite_step_holds_fewer_temporaries_with_checkpoints(mosaic,
                   num_hidden_layers=3,
                   layer_types=["mamba", "mamba", "attention"])
     before = dict(telemetry.read_series("pallas_fallback_total"))
+    booked = _kv_groups_booked()
     temps, kernels, replayed = {}, {}, {}
     for recompute in (True, False):
         compiled = describe_step.compile_step(
             cell, dict(config, recompute=recompute), one_chip)
         text = compiled.as_text()
         assert not _float32_under_the_conv(text)
+        # 32 query heads over 8 K/V heads of 64, two heads a lane block:
+        # the kernels get K and V repeated to the query's width (PR 61)
+        for kernel, operands, _ in _flash_calls(text):
+            assert operands.count("bf16[1,8192,2048]") >= 3, (kernel,
+                                                              operands)
         temps[recompute] = compiled.memory_analysis().temp_size_in_bytes
         names = [re.search(r'op_name="[^"]*?(\w+)\)*/pallas_call',
                            line).group(1)
@@ -1012,6 +1108,10 @@ def test_granite_step_holds_fewer_temporaries_with_checkpoints(mosaic,
                                xplane.hlo_instructions(text)
                                if i.recompute is not None}
     assert dict(telemetry.read_series("pallas_fallback_total")) == before
+    lanes = "op=scaled_dot_product_attention,groups=4,form=repeated," \
+        "ground=lanes"
+    assert _kv_groups_booked() == dict(booked, **{
+        lanes: booked.get(lanes, 0) + 2})
     assert kernels[False] == {"ssd_scan_fwd": 2, "ssd_scan_bwd": 2,
                               "causal_conv1d_fwd": 2, "causal_conv1d_bwd": 2,
                               "flash_fwd": 1, "flash_dkv": 1}

@@ -24,9 +24,22 @@ are then left out, the kernels alone are timed. `--parent DIR` also
 times the kernels of another checkout (its ops/pallas_attention.py) on
 the head counts it admits. One JSON line per reading goes to
 chiprun_out/flash_sweep.jsonl.
+
+`--kv-heads N` (PR 61) gives K and V N heads under Q's H and times the
+three forms of grouped-query attention at the tiles the kernels have,
+in place of the tile sweep: `repeated` (the form of before: K/V widened
+to H heads, the equal-heads kernels, dK/dV summed over each group in
+float32, all inside the timed jit), `kernel` (K/V read at their own
+heads, a group's dK/dV summed inside the fused `flash_dkv`) and
+`kernel_per_head` (K/V read at their own heads, dK/dV written a query
+head and summed behind the call): the forward, the backward from the
+saved (Out, LSE) as the gradient op runs it, both in one jit, and
+`flash_dkv` alone (no repeat and no sum around it), with each form's
+largest error against dense float32 attention, a query head at a time.
 """
 
 import argparse
+import functools
 import importlib.util
 import itertools
 import json
@@ -40,6 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from paddle_tpu.ops import nn_ops
 from paddle_tpu.ops import pallas_attention as pa
 from paddle_tpu.parallel.ring_attention import attention_reference
 
@@ -79,6 +93,108 @@ def max_err(got, want):
                for g, w in zip(got, want))
 
 
+def dense_head(scale, block, q_off, window):
+    """Dense float32 attention of ONE query head under the sweep's mask,
+    [T, D] operands: (out, dq, dk, dv). A row that sees no key (the first
+    block under q_off = -block) gives zeros and sends nothing back."""
+    @jax.jit
+    def run(q, k, v, do):
+        q, k, v, do = (x.astype(jnp.float32) for x in (q, k, v, do))
+        qp = jnp.arange(q.shape[0])[:, None] + q_off
+        kp = jnp.arange(k.shape[0])[None, :]
+        keep = qp // block >= kp // block
+        if window:
+            keep &= qp - kp < window
+        seen = keep.any(-1, keepdims=True)
+
+        def attend(q, k, v):
+            p = jax.nn.softmax(jnp.where(keep, q @ k.T * scale, -1e30), -1)
+            return jnp.where(seen, p @ v, 0.0)
+
+        out, vjp = jax.vjp(attend, q, k, v)
+        return (out,) + vjp(do)
+    return run
+
+
+def grouped(ns, log, base, operands):
+    """The three forms of grouped-query attention at one shape (the
+    module docstring's `--kv-heads`)."""
+    b, t, h, d = ns.shape
+    groups = h // ns.kv_heads
+    scale = 1.0 / d ** 0.5
+    q, do = operands(h)[:2]
+    k, v = operands(ns.kv_heads)[:2]
+    mask = dict(major=ns.major, block=ns.block, window=ns.window)
+
+    def forward(q, k, v):
+        """(Out, LSE) as the op's forward (block 1) or as one part of
+        block-diffusion attention, whose raw (acc, m, l) the op merges."""
+        out, stats = pa._fwd_call(q, k, v, ns.q_off, 0, scale, True,
+                                  normalize=ns.block == 1, tile=pa._TILE,
+                                  **mask)
+        if ns.block == 1:
+            return out, stats[0]
+        m, l = stats
+        l, seen = jnp.maximum(l, 1e-30), m > -1e29
+        out = jnp.where(seen.transpose(0, 2, 1)[..., None],
+                        out / l.transpose(0, 2, 1)[..., None], 0.0)
+        return out.astype(q.dtype), jnp.where(seen, m + jnp.log(l), 1e30)
+
+    def backward(q, k, v, do, out, lse, **form):
+        delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                        axis=-1).transpose(0, 2, 1)
+        return pa._bwd_call(q, k, v, do, lse, delta, ns.q_off, 0, scale,
+                            True, **form, **mask)
+
+    # the repeated form is the attention op's own, with its helpers
+    widen = functools.partial(nn_ops._repeat_kv, groups=groups)
+    summed = functools.partial(nn_ops._sum_kv_groups, groups=groups)
+
+    def repeated_fwd(q, k, v):
+        return forward(q, widen(k), widen(v))
+
+    def repeated_bwd(q, k, v, do, out, lse):
+        dq, dk, dv = backward(q, widen(k), widen(v), do, out, lse)
+        return dq, summed(dk), summed(dv)
+
+    forms = {
+        "repeated": (repeated_fwd, repeated_bwd,
+                     lambda *a: backward(*a)[1:], (widen(k), widen(v))),
+        "kernel": (forward, backward, lambda *a: backward(*a)[1:], (k, v)),
+        "kernel_per_head": (
+            forward, functools.partial(backward, summed=False),
+            lambda *a: backward(*a, summed=False)[1:], (k, v)),
+    }
+    dense = dense_head(scale, ns.block, ns.q_off, ns.window)
+    want = [jnp.stack([jnp.stack(x, 1) for x in zip(*(
+        dense(q[i, :, j], k[i, :, j // groups], v[i, :, j // groups],
+              do[i, :, j]) for j in range(h)))]) for i in range(b)]
+    want = [jnp.stack([w[n] for w in want]) for n in range(4)]
+    want[2:] = [x.reshape(b, t, ns.kv_heads, groups, d).sum(3)
+                for x in want[2:]]
+    out, lse = jax.jit(forward)(q, k, v)
+    reason = pa._split_reason(t, t, pa._lane_block(h, d)[0],
+                              q.dtype.itemsize, pa._TILE, ns.major,
+                              groups=groups)
+    for name in filter(None, ns.forms.split(",")):
+        fwd, bwd, dkv, kv = forms[name]
+
+        def both(q, k, v, do):
+            out, lse = fwd(q, k, v)
+            return (out,) + tuple(bwd(q, k, v, do, out, lse))
+
+        got = jax.jit(both)(q, k, v, do)
+        report(log, **base, kv_heads=ns.kv_heads, path="flash", form=name,
+               backward="split" if reason else "fused",
+               fwd_ms=bench(lambda *a: fwd(*a)[0], q, k, v),
+               bwd_ms=bench(bwd, q, k, v, do, out, lse),
+               fwd_bwd_ms=bench(both, q, k, v, do),
+               dkv_alone_ms=bench(dkv, q, *kv, do, out, lse),
+               max_abs_err_vs_dense=dict(zip(
+                   ("out", "dq", "dk", "dv"),
+                   (max_err([g], [w]) for g, w in zip(got, want)))))
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("shape", nargs="*", type=int, default=[16, 1024, 12, 64])
@@ -91,6 +207,9 @@ def main():
     ap.add_argument("--block", type=int, default=1)
     ap.add_argument("--q-off", type=int, default=0)
     ap.add_argument("--window", type=int, default=0)
+    ap.add_argument("--kv-heads", type=int, default=0)
+    ap.add_argument("--forms", default="repeated,kernel,kernel_per_head",
+                    help="the forms --kv-heads times")
     ns = ap.parse_args()
     plain = ns.block == 1 and ns.q_off == 0 and not ns.window
     b, t, h, d = ns.shape
@@ -107,6 +226,8 @@ def main():
                 major=ns.major)
     if not plain:
         base.update(block=ns.block, q_off=ns.q_off, window=ns.window)
+    if ns.kv_heads:
+        return grouped(ns, log, base, operands)
 
     def einsum(q, k, v):
         return attention_reference(q, k, v, causal=True)
