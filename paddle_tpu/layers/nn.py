@@ -36,7 +36,7 @@ __all__ = [
     "prelu", "crop", "spp", "unpool", "conv3d_transpose",
     "max_pool2d_with_index", "conv_shift", "l1_norm",
     "fused_attention", "block_diffusion_attention", "sparse_moe", "rms_norm",
-    "mamba2_mixer", "kda_mixer", "moe_block",
+    "mamba2_mixer", "kda_mixer", "short_conv_mixer", "moe_block",
     "rotary_embedding", "gated_mlp", "latent_attention", "mtp_block",
 
     "hsigmoid", "bilinear_interp", "selective_fc",
@@ -1366,11 +1366,51 @@ def kda_mixer(x, num_heads, head_dim, conv_kernel=4, gate_rank=None,
 
 
 @_under_its_name
+def short_conv_mixer(x, conv_kernel=3, out_scale=0.02, name=None):
+    """LFM2's gated short convolution (the `conv` layers of lfm2 /
+    lfm2_moe) over x [B, T, D]:
+
+        Bg, Cg, xs = x W_B, x W_C, x W_x        three maps [D, D]: the
+                                                thirds of the published
+                                                in_proj [D, 3 D], in its
+                                                order (B, C, x)
+        z[t] = sum_j w[:, j] * (Bg * xs)[t - (K-1) + j]     depthwise,
+                                zeros before t = 0, no bias, no activation
+        y = (Cg * z) W_out
+
+    One causal_conv1d op with both gates and `activation` "identity":
+    the gate ahead, the taps and the gate behind run in one kernel that
+    reads the three maps' rows once and writes once
+    (ops/pallas_conv1d.py), and its gradient op another. The thirds are
+    three maps, not slices of one projection, so that each reaches the
+    kernel as the array its product wrote (a slice of [T, 3 D] handed to
+    a kernel is a copy); the values, the parameters' count and the
+    published order are the one projection's. No bias in any map; taps
+    N(0, K^-1/2) as the other mixers'. Parameters in the order created:
+    W_B, W_C, W_x, the taps [D, K], W_out."""
+    helper = LayerHelper("short_conv_mixer", name=name)
+    d_model = int(x.shape[2])
+    dtype = x.dtype
+    gate_ahead, gate_behind, xs = (_linear(x, d_model) for _ in range(3))
+    taps = helper.create_parameter(
+        attr=None, shape=[d_model, conv_kernel], dtype=dtype,
+        default_initializer=NormalInitializer(scale=conv_kernel ** -0.5))
+    out = helper.create_tmp_variable(dtype)
+    helper.append_op(type="causal_conv1d",
+                     inputs={"X": [xs], "Filter": [taps],
+                             "PreGate": [gate_ahead],
+                             "PostGate": [gate_behind]},
+                     outputs={"Out": [out]},
+                     attrs={"activation": "identity"})
+    return _linear(out, d_model, scale=out_scale)
+
+
+@_under_its_name
 def moe_block(x, num_experts, top_k, expert_width, shared_width=0,
               experts_held=None, expert_offset=0, scaling=1.0,
               norm_topk_prob=True, out_scale=0.02, stats=None, name=None,
               gated=False, scoring="sigmoid", router_input=None,
-              gate_act="silu"):
+              gate_act="silu", norm_epsilon=1e-20):
     """Mixture-of-experts feed-forward over x [B, T, D] with a top-k
     router, squared-ReLU experts (`gated`: gated SiLU experts,
     f(x) = (silu(x G) * (x U)) V, three matrices an expert, the shared
@@ -1387,7 +1427,7 @@ def moe_block(x, num_experts, top_k, expert_width, shared_width=0,
         the top_k of s + b (sigmoid) or s * exp(b) (softmax) are chosen
         (b: a selection bias, a buffer that starts at zero and takes no
         gradient; models.balance_routers moves it against the load);
-        g_i = scaling * s_i / (sum of the chosen s + 1e-20)
+        g_i = scaling * s_i / (sum of the chosen s + `norm_epsilon`)
         out = sum_i g_i f_{e_i}(x) + f_shared(x),  f(x) = relu(x U)^2 V
 
     The layer is told its share: it holds `experts_held` of `num_experts`
@@ -1426,6 +1466,8 @@ def moe_block(x, num_experts, top_k, expert_width, shared_width=0,
                     "norm_topk_prob": norm_topk_prob}
     if scoring != "sigmoid":    # the op's default: a sigmoid router's
         router_attrs["scoring"] = scoring   # program is the one it was
+    if norm_epsilon != 1e-20:   # likewise (lfm2_moe's routers: 1e-6)
+        router_attrs["norm_epsilon"] = float(norm_epsilon)
     helper.append_op(type="moe_router",
                      inputs={"X": [routed_by], "W": [router_w],
                              "Bias": [router_b]},

@@ -8,6 +8,7 @@ glue (loss, optimizer) stays in user code or in `build_classifier`.
 
 from .alexnet import alexnet
 from .block_diffusion_moe import block_diffusion_moe_lm
+from .conv_moe import conv_moe_lm
 from .gated_window_moe import gated_window_moe_lm
 from .googlenet import googlenet
 from .granite_hybrid import granite_hybrid_lm
@@ -24,7 +25,8 @@ from .window_moe import window_moe_lm
 from .common import balance_routers, build_image_classifier
 
 __all__ = [
-    "alexnet", "block_diffusion_moe_lm", "gated_window_moe_lm", "googlenet",
+    "alexnet", "block_diffusion_moe_lm", "conv_moe_lm",
+    "gated_window_moe_lm", "googlenet",
     "granite_hybrid_lm", "kda_moe_lm", "looped_lm", "mla_moe_lm",
     "mnist_conv", "mnist_mlp",
     "nemotron_h_lm",

@@ -180,39 +180,60 @@ def _rotary_embedding(ctx, op_, ins):
 
 # --- causal depthwise conv over time -----------------------------------------
 
-def causal_conv1d_reference(x, w, bias=None):
-    """The op causal_conv1d in plain jax.numpy: K shifted multiply-adds
-    over a zero-padded float32 copy of X and Mamba's activation; float32
-    inside, X's dtype out. The statement the kernels of
-    ops/pallas_conv1d.py are held to, and the path (with autodiff's
-    gradient of it) for shapes their gate declines."""
+def causal_conv1d_reference(x, w, bias=None, pre_gate=None, post_gate=None,
+                            activation="silu"):
+    """The op causal_conv1d in plain jax.numpy, its whole form:
+
+        U = PreGate * X                                 (no gate: X)
+        pre[t] = Bias + sum_j Filter[:, j] * U[t - (K-1) + j]
+        Out = PostGate * act(pre)                       (no gate: act(pre))
+
+    K shifted multiply-adds over a zero-padded float32 copy of U, then
+    `activation` ("silu", Mamba's, or "identity"); float32 inside, X's
+    dtype out. The statement the kernels of ops/pallas_conv1d.py are held
+    to, and the path (with autodiff's gradient of it) for shapes their
+    gate declines."""
+    assert activation in ("silu", "identity"), activation
     w = _f32(w)
     k, t = w.shape[1], x.shape[1]
-    padded = jnp.pad(_f32(x), ((0, 0), (k - 1, 0), (0, 0)))
+    u = _f32(x) if pre_gate is None else _f32(x) * _f32(pre_gate)
+    padded = jnp.pad(u, ((0, 0), (k - 1, 0), (0, 0)))
     out = _f32(bias) if bias is not None else 0.0
     for j in range(k):
         out = out + padded[:, j:j + t] * w[:, j]
-    return jax.nn.silu(out).astype(x.dtype)
+    if activation == "silu":
+        out = jax.nn.silu(out)
+    if post_gate is not None:
+        out = out * _f32(post_gate)
+    return out.astype(x.dtype)
 
 
 _CONV1D_OP = "causal_conv1d"
+# the op's tensor inputs behind X, and the reference's / the kernels'
+# keyword each arrives under
+_CONV1D_SLOTS = {"Filter": "w", "Bias": "bias", "PreGate": "pre_gate",
+                 "PostGate": "post_gate"}
 
 
 def _conv1d_operands(op_, ins):
-    """(X, Filter, Bias or None, the kernels' keywords or None where
-    their gate declines, the gate's reason) of the op or its gradient
-    op."""
+    """(X, {keyword: Filter, Bias, the gates: those that are there, and
+    `activation` where it is not the default}, the kernels' keywords or
+    None where their gate declines, the gate's reason) of the op or its
+    gradient op."""
     from . import pallas_conv1d
     from .pallas_attention import _interpret
 
-    x, w = jnp.asarray(ins["X"][0]), jnp.asarray(ins["Filter"][0])
-    bias = ins.get("Bias")
-    bias = jnp.asarray(bias[0]) if bias and bias[0] is not None else None
-    reason = pallas_conv1d.ineligible(x.shape[1], x.shape[2], w.shape[1],
-                                      x.dtype)
+    x = jnp.asarray(ins["X"][0])
+    form = {key: jnp.asarray(ins[slot][0])
+            for slot, key in _CONV1D_SLOTS.items()
+            if ins.get(slot) and ins[slot][0] is not None}
+    if op_.attr("activation", "silu") != "silu":
+        form["activation"] = op_.attr("activation")
+    reason = pallas_conv1d.ineligible(x.shape[1], x.shape[2],
+                                      form["w"].shape[1], x.dtype)
     kernel = None if reason else dict(
         lanes=bool(op_.attr("time_on_lanes", False)), interpret=_interpret())
-    return x, w, bias, kernel, reason
+    return x, form, kernel, reason
 
 
 def _conv1d_grad(fwd, no_grad_set):
@@ -220,7 +241,7 @@ def _conv1d_grad(fwd, no_grad_set):
     nothing the forward made (the kernel computes the pre-activation
     again): the generic maker's op would trace the forward inside the
     gradient op and keep its float32 copies for the pull-back."""
-    wanted = [s for s in ("X", "Filter", "Bias")
+    wanted = [s for s in ("X",) + tuple(_CONV1D_SLOTS)
               if fwd.inputs.get(s) and fwd.input(s)[0] not in no_grad_set]
     if not wanted:
         return []
@@ -236,15 +257,23 @@ def _conv1d_grad(fwd, no_grad_set):
 @op("causal_conv1d", infer_shape=same_as_input(), grad=_conv1d_grad)
 def _causal_conv1d(ctx, op_, ins):
     """X [B, T, C], Filter [C, K], Bias [C] (may be absent: Kimi Delta
-    Attention's short convolutions have none): Out[t] = silu(Bias + sum_j
-    Filter[:, j] * X[t - (K-1) + j]) with zeros before t = 0 (a depthwise
-    conv1d, left pad K-1, then Mamba's activation). K shifted
-    multiply-adds on the VPU; float32 inside, X's dtype out.
+    Attention's short convolutions have none), and two optional gates of
+    X's shape, PreGate ahead of the taps and PostGate behind them (LFM2's
+    operator, C * conv(B * x), with `activation` "identity"):
+
+        Out[t] = PostGate[t] * act(Bias + sum_j Filter[:, j]
+                                   * (PreGate * X)[t - (K-1) + j])
+
+    with zeros before t = 0 (a depthwise conv1d, left pad K-1, then
+    `activation`: "silu", Mamba's and the default, or "identity"). K
+    shifted multiply-adds on the VPU; float32 inside, X's dtype out.
+    Without gates and attribute the program and the lowering are what
+    they were.
 
     What runs is chosen from the shapes (pallas_conv1d.ineligible): the
-    forward kernel of ops/pallas_conv1d.py, which reads X once in the
-    dtype it has and writes Out once (interpreted off the chip;
-    pallas_kernel_total{op="causal_conv1d"}), or
+    forward kernel of ops/pallas_conv1d.py, which reads X and the gates
+    once in the dtype they have and writes Out once (interpreted off the
+    chip; pallas_kernel_total{op="causal_conv1d"}), or
     causal_conv1d_reference, XLA's, booked with the reason
     (pallas_fallback_total). Every forward lowering books, a replayed
     segment's too (12 + 9 in the Kimi-Linear cell); the gradient op books
@@ -253,32 +282,43 @@ def _causal_conv1d(ctx, op_, ins):
     the result."""
     from . import pallas_conv1d
 
-    x, w, bias, kernel, reason = _conv1d_operands(op_, ins)
+    x, form, kernel, reason = _conv1d_operands(op_, ins)
     kernel_choice.book(_CONV1D_OP, reason)
     if kernel is None:
-        return {"Out": [causal_conv1d_reference(x, w, bias)]}
-    return {"Out": [pallas_conv1d.causal_conv1d_fwd(x, w, bias, **kernel)]}
+        return {"Out": [causal_conv1d_reference(x, **form)]}
+    return {"Out": [pallas_conv1d.causal_conv1d_fwd(
+        x, form.pop("w"), form.pop("bias", None), **form, **kernel)]}
 
 
 @op("causal_conv1d_grad", grad=NO_GRAD)
 def _causal_conv1d_grad(ctx, op_, ins):
-    """dX (X's dtype), dFilter and dBias (their parameters' dtypes) from
-    X, Filter, Bias and Out's cotangent: the gradient's kernel of
-    ops/pallas_conv1d.py where the forward took its kernel (the same
-    gate), else autodiff's gradient of causal_conv1d_reference."""
+    """dX and the gates' gradients (X's dtype), dFilter and dBias (their
+    parameters' dtypes) from the op's inputs and Out's cotangent: the
+    gradient's kernel of ops/pallas_conv1d.py where the forward took its
+    kernel (the same gate), else autodiff's gradient of
+    causal_conv1d_reference."""
     from . import pallas_conv1d
 
-    x, w, bias, kernel, _ = _conv1d_operands(op_, ins)
+    x, form, kernel, _ = _conv1d_operands(op_, ins)
     d_out = jnp.asarray(ins["Out@GRAD"][0])
+    primals = {"X": x, **{slot: form[key]
+                          for slot, key in _CONV1D_SLOTS.items()
+                          if key in form}}
     if kernel is None:
-        primals = (x, w) if bias is None else (x, w, bias)
-        grads = jax.vjp(causal_conv1d_reference, *primals)[1](
-            d_out.astype(x.dtype))
+        def statement(tensors):
+            return causal_conv1d_reference(
+                tensors["X"], activation=form.get("activation", "silu"),
+                **{_CONV1D_SLOTS[s]: v for s, v in tensors.items()
+                   if s != "X"})
+        grads, = jax.vjp(statement, primals)[1](d_out.astype(x.dtype))
     else:
-        grads = pallas_conv1d.causal_conv1d_bwd(x, w, bias, d_out, **kernel)
-    return {slot + "@GRAD": [g.astype(like.dtype)]
-            for slot, g, like in zip(("X", "Filter", "Bias"), grads,
-                                     (x, w, bias))
+        grads = dict(zip(
+            ("X", "Filter", "Bias", "PreGate", "PostGate"),
+            pallas_conv1d.causal_conv1d_bwd(
+                x, form.pop("w"), form.pop("bias", None), d_out, **form,
+                **kernel)))
+    return {slot + "@GRAD": [grads[slot].astype(like.dtype)]
+            for slot, like in primals.items()
             if slot + "@GRAD" in op_.desc.outputs}
 
 
@@ -720,7 +760,8 @@ def _moe_router(ctx, op_, ins):
     in float32 at full precision; the k experts with the largest s + Bias
     (sigmoid) or s * exp(Bias) (softmax) are chosen (the bias moves the
     choice only); their weights are `scaling` * s_i / (sum of the chosen
-    s + 1e-20) when `norm_topk_prob`, else `scaling` * s_i."""
+    s + `norm_epsilon`, default 1e-20; lfm2_moe publishes 1e-6) when
+    `norm_topk_prob`, else `scaling` * s_i."""
     scoring = op_.attr("scoring", "sigmoid")
     assert scoring in _SCORINGS, scoring
     s = _SCORINGS[scoring](jnp.matmul(_f32(ins["X"][0]), _f32(ins["W"][0]),
@@ -730,7 +771,7 @@ def _moe_router(ctx, op_, ins):
         op_.attr("top_k", 1))
     w = jnp.take_along_axis(s, idx, axis=-1)
     if op_.attr("norm_topk_prob", True):
-        w = w / (w.sum(-1, keepdims=True) + 1e-20)
+        w = w / (w.sum(-1, keepdims=True) + op_.attr("norm_epsilon", 1e-20))
     return {"TopkIdx": [idx.astype(jnp.int32)],
             "TopkWeight": [w * op_.attr("scaling", 1.0)]}
 

@@ -191,16 +191,23 @@ def _delayed(x, before, k, axis):
         for j in range(k)]
 
 
-def _chunk(x_ref, win, r, ct, unit, c0, co, before_block):
-    """Chunk `r` of a group of channels in float32, and the `unit` steps
-    before it: the block's own, or ahead of its first chunk
-    `before_block` (the previous time block's tail)."""
+def _chunk(x_ref, p_ref, win, r, ct, unit, c0, co, before_block):
+    """Chunk `r` of a group of channels in float32: (its first step, X,
+    the gate ahead or None, U = PreGate * X: what the taps read, and U
+    of the `unit` steps before the chunk: the block's own, or ahead of
+    its first chunk `before_block`, the previous time block's tail)."""
     import jax.experimental.pallas as pl
     t0 = pl.multiple_of(r * ct, ct)
-    x = x_ref[win(t0, ct, c0, co)].astype(_F32)
-    before = x_ref[win(pl.multiple_of(jnp.maximum(t0 - unit, 0), unit),
-                       unit, c0, co)].astype(_F32)
-    return t0, x, jnp.where(r == 0, before_block, before)
+    at = win(t0, ct, c0, co)
+    x = x_ref[at].astype(_F32)
+    earlier = win(pl.multiple_of(jnp.maximum(t0 - unit, 0), unit), unit, c0,
+                  co)
+    before = x_ref[earlier].astype(_F32)
+    p, u = None, x
+    if p_ref is not None:
+        p = p_ref[at].astype(_F32)
+        u, before = x * p, before * p_ref[earlier].astype(_F32)
+    return t0, x, p, u, jnp.where(r == 0, before_block, before)
 
 
 def _pre(delayed, taps, bias):
@@ -212,10 +219,15 @@ def _pre(delayed, taps, bias):
     return pre
 
 
-def _fwd_kernel(x_ref, w_ref, o_ref, halo_ref, *wide, axis, k, bias, chunk,
-                unit):
+def _fwd_kernel(*refs, axis, k, bias, chunk, unit, pre=False, post=False,
+                act="silu"):
     import jax.experimental.pallas as pl
 
+    it = iter(refs)
+    x_ref = next(it)
+    p_ref = next(it) if pre else None
+    q_ref = next(it) if post else None
+    w_ref, o_ref, halo_ref, *wide = it
     tt, cc = x_ref.shape[axis], x_ref.shape[1 - axis]
     ct, co = chunk
     win = functools.partial(_window, axis)
@@ -232,15 +244,21 @@ def _fwd_kernel(x_ref, w_ref, o_ref, halo_ref, *wide, axis, k, bias, chunk,
         taps, b = _taps_of(taps_ref, axis, k, bias, c0, co, ct)
 
         def one(r, _):
-            t0, x, before = _chunk(x_ref, win, r, ct, unit, c0, co,
-                                   halo_ref[win(0, unit, c0, co)])
-            pre = _pre(_delayed(x, before, k, axis), taps, b)
-            o_ref[win(t0, ct, c0, co)] = jax.nn.silu(pre).astype(o_ref.dtype)
+            t0, _, _, u, before = _chunk(x_ref, p_ref, win, r, ct, unit, c0,
+                                         co, halo_ref[win(0, unit, c0, co)])
+            out = _pre(_delayed(u, before, k, axis), taps, b)
+            if act == "silu":
+                out = jax.nn.silu(out)
+            if q_ref is not None:
+                out = out * q_ref[win(t0, ct, c0, co)].astype(_F32)
+            o_ref[win(t0, ct, c0, co)] = out.astype(o_ref.dtype)
 
         lax.fori_loop(0, tt // ct, one, None)
 
     lax.fori_loop(0, cc // co, group, None)
-    halo_ref[...] = x_ref[win(tt - unit, unit, 0, cc)].astype(_F32)
+    tail = win(tt - unit, unit, 0, cc)
+    halo_ref[...] = x_ref[tail].astype(_F32) if p_ref is None \
+        else x_ref[tail].astype(_F32) * p_ref[tail].astype(_F32)
 
 
 def _folded(p, axis, fold):
@@ -253,11 +271,23 @@ def _folded(p, axis, fold):
     return total
 
 
-def _bwd_kernel(x_ref, prev_ref, do_ref, w_ref, dx_ref, dw_ref, next_ref,
-                *wide, axis, k, bias, chunk, unit, fold):
+def _bwd_kernel(*refs, axis, k, bias, chunk, unit, fold, pre=False,
+                post=False, act="silu"):
+    """Operands: X and the `unit` steps of X before the block, the gate
+    ahead and its steps before likewise, the gate behind, Out's
+    cotangent, the taps; results: dX, the gates' gradients, the taps'
+    partial sums; scratch as _scratch gives it."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    it = iter(refs)
+    x_ref, prev_ref = next(it), next(it)
+    p_ref, prev_p_ref = (next(it), next(it)) if pre else (None, None)
+    q_ref = next(it) if post else None
+    do_ref, w_ref, dx_ref = next(it), next(it), next(it)
+    dp_ref = next(it) if pre else None
+    dq_ref = next(it) if post else None
+    dw_ref, next_ref, *wide = it
     tt, cc = x_ref.shape[axis], x_ref.shape[1 - axis]
     ct, co = chunk
     chunks = tt // ct
@@ -276,18 +306,29 @@ def _bwd_kernel(x_ref, prev_ref, do_ref, w_ref, dx_ref, dw_ref, next_ref,
     def group(g, _):
         c0 = pl.multiple_of(g * co, co)
         taps, b = _taps_of(taps_ref, axis, k, bias, c0, co, ct)
-        before_block = jnp.where(
-            first_block, 0.0, prev_ref[win(0, unit, c0, co)].astype(_F32))
+        before_block = prev_ref[win(0, unit, c0, co)].astype(_F32)
+        if prev_p_ref is not None:
+            before_block = before_block * prev_p_ref[
+                win(0, unit, c0, co)].astype(_F32)
+        before_block = jnp.where(first_block, 0.0, before_block)
 
         def one(q, carry):
             after, sums = carry
-            t0, x, before = _chunk(x_ref, win, chunks - 1 - q, ct, unit, c0,
-                                   co, before_block)
-            delayed = _delayed(x, before, k, axis)
-            pre = _pre(delayed, taps, b)
-            s = jax.nn.sigmoid(pre)
-            dpre = do_ref[win(t0, ct, c0, co)].astype(_F32) * (
-                s * (1.0 + pre * (1.0 - s)))
+            t0, x, p, u, before = _chunk(x_ref, p_ref, win, chunks - 1 - q,
+                                         ct, unit, c0, co, before_block)
+            at = win(t0, ct, c0, co)
+            delayed = _delayed(u, before, k, axis)
+            if act == "silu" or q_ref is not None:
+                pre = _pre(delayed, taps, b)
+            if act == "silu":
+                s = jax.nn.sigmoid(pre)
+            dpre = do_ref[at].astype(_F32)
+            if q_ref is not None:   # Out = PostGate * act(pre)
+                dq_ref[at] = (dpre * (pre * s if act == "silu" else pre)
+                              ).astype(dq_ref.dtype)
+                dpre = dpre * q_ref[at].astype(_F32)
+            if act == "silu":
+                dpre = dpre * (s * (1.0 + pre * (1.0 - s)))
             # dX[t] = sum_j tap_j * dpre[t + (K-1) - j]: the chunk ahead
             # of the `after` steps, rolled on by a unit less the reach (a
             # roll back by the reach, past the array's end, was three
@@ -296,8 +337,12 @@ def _bwd_kernel(x_ref, prev_ref, do_ref, w_ref, dx_ref, dw_ref, next_ref,
             ahead = [dpre if j == k - 1 else lax.slice_in_dim(
                 pltpu.roll(ext, unit - (k - 1 - j), axis), unit, unit + ct,
                 axis=axis) for j in range(k)]
-            dx_ref[win(t0, ct, c0, co)] = _pre(ahead, taps, None).astype(
-                dx_ref.dtype)
+            d_u = _pre(ahead, taps, None)
+            if p_ref is None:
+                dx_ref[at] = d_u.astype(dx_ref.dtype)
+            else:                   # U = PreGate * X
+                dx_ref[at] = (d_u * p).astype(dx_ref.dtype)
+                dp_ref[at] = (d_u * x).astype(dp_ref.dtype)
             terms = [dpre * xs for xs in delayed] + [dpre] * bool(bias)
             sums = tuple(acc + _folded(p, axis, fold)
                          for acc, p in zip(sums, terms))
@@ -336,10 +381,21 @@ def _scratch(axis, unit, cc, rows):
     return [carried, pltpu.VMEM((cc, rows * 128), _F32)] if axis else [carried]
 
 
+def _form(pre, post, act):
+    """The kernels' keywords of the gated form; none for the plain one,
+    whose calls are traced as they were."""
+    assert act in ("silu", "identity"), act
+    return dict(pre=pre, post=post, act=act) \
+        if pre or post or act != "silu" else {}
+
+
 @functools.lru_cache(maxsize=None)
-def _fwd_call(axis, bsz, t, c, k, bias, dtype, tile, chunk, interpret):
+def _fwd_call(axis, bsz, t, c, k, bias, dtype, tile, chunk, interpret,
+              pre=False, post=False, act="silu"):
     """One traced forward a shape: a model's layers, a replayed segment's
-    forwards and q, k and v lower the same jitted function."""
+    forwards and q, k and v lower the same jitted function. `pre`,
+    `post`: whether the gates are operands, behind X in that order, each
+    in X's blocks; a call with a gate goes by a name of its own."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -351,10 +407,10 @@ def _fwd_call(axis, bsz, t, c, k, bias, dtype, tile, chunk, interpret):
                         lambda b, j, i: _oriented(axis, 0, j))
     call = pl.pallas_call(
         functools.partial(_fwd_kernel, axis=axis, k=k, bias=bias,
-                          chunk=chunk, unit=unit),
-        name="causal_conv1d_fwd",
+                          chunk=chunk, unit=unit, **_form(pre, post, act)),
+        name=("gated_conv1d" if pre or post else "causal_conv1d") + "_fwd",
         grid=(bsz, c // cc, t // tt),
-        in_specs=[block, taps], out_specs=block,
+        in_specs=[block] * (1 + pre + post) + [taps], out_specs=block,
         out_shape=jax.ShapeDtypeStruct((bsz,) + _oriented(axis, t, c), dtype),
         scratch_shapes=_scratch(axis, unit, cc, rows),
         interpret=interpret,
@@ -364,7 +420,8 @@ def _fwd_call(axis, bsz, t, c, k, bias, dtype, tile, chunk, interpret):
 
 
 @functools.lru_cache(maxsize=None)
-def _bwd_call(axis, bsz, t, c, k, bias, dtype, tile, chunk, interpret):
+def _bwd_call(axis, bsz, t, c, k, bias, dtype, tile, chunk, interpret,
+              pre=False, post=False, act="silu"):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -388,12 +445,14 @@ def _bwd_call(axis, bsz, t, c, k, bias, dtype, tile, chunk, interpret):
                         lambda b, j, i: (b,) + _oriented(axis, 0, j))
     call = pl.pallas_call(
         functools.partial(_bwd_kernel, axis=axis, k=k, bias=bias,
-                          chunk=chunk, unit=unit, fold=fold),
-        name="causal_conv1d_bwd",
+                          chunk=chunk, unit=unit, fold=fold,
+                          **_form(pre, post, act)),
+        name=("gated_conv1d" if pre or post else "causal_conv1d") + "_bwd",
         grid=(bsz, c // cc, blocks),
-        in_specs=[block, prev, block, taps], out_specs=[block, sums],
-        out_shape=[
-            jax.ShapeDtypeStruct((bsz,) + _oriented(axis, t, c), dtype),
+        in_specs=[block, prev] * (1 + pre) + [block] * (1 + post) + [taps],
+        out_specs=[block] * (1 + pre + post) + [sums],
+        out_shape=[jax.ShapeDtypeStruct((bsz,) + _oriented(axis, t, c),
+                                        dtype)] * (1 + pre + post) + [
             jax.ShapeDtypeStruct((bsz,) + _oriented(axis, rows * fold, c),
                                  _F32)],
         scratch_shapes=_scratch(axis, unit, cc, rows),
@@ -413,38 +472,63 @@ def _packed_taps(w, bias, axis):
     return taps.T if axis == 0 else taps
 
 
-def causal_conv1d_fwd(x, w, bias=None, *, lanes=False, tile=None, chunk=None,
+def _gates(pre_gate, post_gate, activation, like):
+    """The gated form's operands (those that are there, in the kernels'
+    order) and the calls' keywords."""
+    gates = [g for g in (pre_gate, post_gate) if g is not None]
+    assert all(g.shape == like.shape and g.dtype == like.dtype
+               for g in gates), [g.shape for g in gates]
+    return gates, (pre_gate is not None, post_gate is not None, activation)
+
+
+def causal_conv1d_fwd(x, w, bias=None, *, pre_gate=None, post_gate=None,
+                      activation="silu", lanes=False, tile=None, chunk=None,
                       interpret=False):
-    """silu(Bias + the causal depthwise conv of X [B, T, C] under Filter
-    [C, K]) in X's dtype, on the forward kernel; `lanes`: time along the
-    lanes (the kernel sees [B, C, T])."""
+    """PostGate * act(Bias + the causal depthwise conv of PreGate * X
+    [B, T, C] under Filter [C, K]) in X's dtype, on the forward kernel;
+    the gates are optional and X's shape and dtype, `activation` "silu"
+    or "identity"; `lanes`: time along the lanes (the kernel sees
+    [B, C, T])."""
     bsz, t, c = x.shape
     axis = int(lanes)
     assert ineligible(t, c, w.shape[1], x.dtype) is None
+    gates, form = _gates(pre_gate, post_gate, activation, x)
     call = _fwd_call(axis, bsz, t, c, w.shape[1], bias is not None,
-                     jnp.dtype(x.dtype), tile, chunk, interpret)
+                     jnp.dtype(x.dtype), tile, chunk, interpret, *form)
+    taps = _packed_taps(w, bias, axis)
     if lanes:
-        return jnp.swapaxes(call(jnp.swapaxes(x, 1, 2),
-                                 _packed_taps(w, bias, axis)), 1, 2)
-    return call(x, _packed_taps(w, bias, axis))
+        return jnp.swapaxes(call(*(jnp.swapaxes(a, 1, 2)
+                                   for a in [x] + gates), taps), 1, 2)
+    return call(x, *gates, taps)
 
 
-def causal_conv1d_bwd(x, w, bias, d_out, *, lanes=False, tile=None,
-                      chunk=None, interpret=False):
+def causal_conv1d_bwd(x, w, bias, d_out, *, pre_gate=None, post_gate=None,
+                      activation="silu", lanes=False, tile=None, chunk=None,
+                      interpret=False):
     """(dX in X's dtype, dFilter [C, K] float32, dBias [C] float32 or
-    None) of causal_conv1d_fwd from X, Filter, Bias and Out's cotangent,
-    on the gradient's kernel: pre and its sigmoid are computed again."""
+    None, dPreGate and dPostGate in X's dtype or None) of
+    causal_conv1d_fwd from its operands and Out's cotangent, on the
+    gradient's kernel: U = PreGate * X, the pre-activation and its
+    sigmoid are computed again."""
     bsz, t, c = x.shape
     axis, k = int(lanes), w.shape[1]
     assert ineligible(t, c, k, x.dtype) is None and d_out.shape == x.shape
+    gates, form = _gates(pre_gate, post_gate, activation, x)
     call = _bwd_call(axis, bsz, t, c, k, bias is not None,
-                     jnp.dtype(x.dtype), tile, chunk, interpret)
+                     jnp.dtype(x.dtype), tile, chunk, interpret, *form)
     taps = _packed_taps(w, bias, axis)
     if lanes:
         x, d_out = jnp.swapaxes(x, 1, 2), jnp.swapaxes(d_out, 1, 2)
-    d_x, sums = call(x, x, d_out, taps)
+        gates = [jnp.swapaxes(g, 1, 2) for g in gates]
+    # X and the gate ahead twice: the block, and the steps before it
+    *grads, sums = call(x, x, *gates[:1] * 2 * form[0], *gates[form[0]:],
+                        d_out, taps)
     if lanes:
-        d_x, sums = jnp.swapaxes(d_x, 1, 2), jnp.swapaxes(sums, 1, 2)
+        grads = [jnp.swapaxes(g, 1, 2) for g in grads]
+        sums = jnp.swapaxes(sums, 1, 2)
     # [B, rows x fold, C] -> [rows, C]: the partials folded
     sums = sums.reshape(bsz, k + (bias is not None), -1, c).sum((0, 2))
-    return d_x, sums[:k].T, (sums[k] if bias is not None else None)
+    grads = iter(grads)
+    return (next(grads), sums[:k].T, (sums[k] if bias is not None else None),
+            next(grads) if form[0] else None,
+            next(grads) if form[1] else None)

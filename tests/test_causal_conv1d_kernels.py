@@ -1,13 +1,17 @@
 """The short convolution's kernels (ops/pallas_conv1d.py, PR 60),
 interpreted, against the jax.numpy statement
 (hybrid_ops.causal_conv1d_reference): Out, dX, dFilter and dBias in both
-orientations over three time blocks, so both halos are crossed; an
-impulse across a block's edge each way; what the gate declines, booked
+orientations over three time blocks, so both halos are crossed, the plain
+form and the gated one (PR 62: a gate ahead of the taps, a gate behind
+them, silu or no activation; LFM2's operator is both gates, three taps,
+no bias, no activation); an impulse across a block's edge each way; the
+plain form's calls held to the jaxpr of before the gates; what the gate declines, booked
 and equal to the statement; the op and its explicit gradient op through
 the executor; and the eight accepted programs that build no
 causal_conv1d, which serialise as the parent's.
 """
 
+import functools
 import hashlib
 
 import jax
@@ -31,22 +35,37 @@ def forms(lanes):
                 chunk=(128, 16) if lanes else (64, 128), interpret=True)
 
 
-def operands(bsz, k, bias, dtype, seed=0):
+def operands(bsz, k, bias, dtype, seed=0, gates=""):
+    """(X, Filter, Bias or None, Out's cotangent, {the gated form's
+    keywords}); `gates`: which of "pre" and "post" are there."""
     rng = np.random.default_rng(seed)
-    return (jnp.asarray(rng.standard_normal((bsz, T, C)), dtype),
-            jnp.asarray(rng.standard_normal((C, k)) * k ** -0.5, jnp.float32),
-            jnp.asarray(rng.standard_normal(C), jnp.float32) if bias
-            else None,
-            jnp.asarray(rng.standard_normal((bsz, T, C)), dtype))
+
+    def rows():
+        return jnp.asarray(rng.standard_normal((bsz, T, C)), dtype)
+
+    x, w, b, d_out = (
+        rows(),
+        jnp.asarray(rng.standard_normal((C, k)) * k ** -0.5, jnp.float32),
+        jnp.asarray(rng.standard_normal(C), jnp.float32) if bias else None,
+        rows())
+    return x, w, b, d_out, {side + "_gate": rows()
+                            for side in ("pre", "post") if side in gates}
 
 
-@jax.jit
-def statement(x, w, b, d_out):
-    """(Out, dX, dFilter, dBias or None) of the statement, compiled as a
-    program's step compiles it."""
-    primals = (x, w) if b is None else (x, w, b)
-    out, pull = jax.vjp(hybrid_ops.causal_conv1d_reference, *primals)
-    return (out,) + tuple(pull(d_out)) + (None,) * (b is None)
+@functools.partial(jax.jit, static_argnames="activation")
+def statement(x, w, b, d_out, pre_gate=None, post_gate=None,
+              activation="silu"):
+    """(Out, dX, dFilter, dBias, dPreGate, dPostGate: None where the
+    operand is absent) of the statement, compiled as a program's step
+    compiles it."""
+    there = {key: v for key, v in dict(
+        x=x, w=w, bias=b, pre_gate=pre_gate, post_gate=post_gate).items()
+        if v is not None}
+    out, pull = jax.vjp(lambda kw: hybrid_ops.causal_conv1d_reference(
+        activation=activation, **kw), there)
+    grads, = pull(d_out)
+    return (out,) + tuple(grads.get(key) for key in (
+        "x", "w", "bias", "pre_gate", "post_gate"))
 
 
 def largest(a):
@@ -58,35 +77,57 @@ def distance(got, want):
                          - want.astype(jnp.float32)).max())
 
 
+# (taps, Bias, gates, activation): the plain form's four, then the gated
+# form: LFM2's operator (both gates, three taps, no bias, no activation),
+# the same under silu, and each gate alone
+FORMS = [(4, True, "", "silu"), (4, False, "", "silu"),
+         (2, True, "", "silu"), (2, False, "", "silu"),
+         (3, False, "pre+post", "identity"), (3, False, "pre+post", "silu"),
+         (4, True, "pre", "silu"), (2, False, "post", "identity")]
+
+
 @pytest.mark.parametrize("bsz", [1, 2])
-@pytest.mark.parametrize("k,bias", [(4, True), (4, False), (2, True),
-                                    (2, False)])
+@pytest.mark.parametrize(
+    "k,bias,gates,activation", FORMS,
+    ids=[f"{k}-{bias}" + (f"-{gates}-{act}" if gates else "")
+         for k, bias, gates, act in FORMS])
 @pytest.mark.parametrize("dtype", [jnp.float32, BF16],
                          ids=["float32", "bf16"])
 @pytest.mark.parametrize("lanes", [False, True],
                          ids=["time_on_sublanes", "time_on_lanes"])
-def test_kernels_equal_the_statement(lanes, dtype, k, bias, bsz):
+def test_kernels_equal_the_statement(lanes, dtype, k, bias, gates,
+                                     activation, bsz):
     """Out equals the statement bit for bit (the same float32 operations
-    in the same order, rounded once); the gradient recomputes the
-    pre-activation and writes silu's derivative as s (1 + pre (1 - s)),
-    so float32 holds to 1e-6 of the largest entry and a bf16 dX to one
-    rounding of the statement's own bf16 result on the same operands;
-    dFilter and dBias are float32 sums over B x T in another order."""
-    x, w, b, d_out = operands(bsz, k, bias, dtype)
-    want = statement(x, w, b, d_out)
-    out = pallas_conv1d.causal_conv1d_fwd(x, w, b, **forms(lanes))
+    in the same order, rounded once), gated or not; the gradient
+    recomputes U = PreGate * X and the pre-activation and writes silu's
+    derivative as s (1 + pre (1 - s)), so float32 holds to 1e-6 of the
+    largest entry and a bf16 dX (and a gate's gradient, which leaves in
+    X's dtype too) to one rounding of the statement's own bf16 result on
+    the same operands; dFilter and dBias are float32 sums over B x T in
+    another order."""
+    x, w, b, d_out, gated = operands(bsz, k, bias, dtype, gates=gates)
+    gated["activation"] = activation
+    want = statement(x, w, b, d_out, **gated)
+    out = pallas_conv1d.causal_conv1d_fwd(x, w, b, **gated, **forms(lanes))
     assert out.dtype == x.dtype
     np.testing.assert_array_equal(np.asarray(out, np.float32),
                                   np.asarray(want[0], np.float32))
-    d_x, d_w, d_b = pallas_conv1d.causal_conv1d_bwd(x, w, b, d_out,
-                                                    **forms(lanes))
-    assert d_x.dtype == x.dtype and d_w.dtype == jnp.float32
+    d_x, d_w, d_b, d_pre, d_post = pallas_conv1d.causal_conv1d_bwd(
+        x, w, b, d_out, **gated, **forms(lanes))
+    assert d_w.dtype == jnp.float32
     # one rounding of a bf16 result: 2^-8 of it
     ulp = 2.0 ** -8 if dtype == BF16 else 0.0
-    assert distance(d_x, want[1]) <= (1e-6 + ulp) * largest(want[1])
-    if dtype == BF16:       # and nearly every entry is the very same
-        same = np.asarray(d_x, np.float32) == np.asarray(want[1], np.float32)
-        assert same.mean() > 0.999
+    for got, ref, there in ((d_x, want[1], True),
+                            (d_pre, want[4], "pre" in gates),
+                            (d_post, want[5], "post" in gates)):
+        assert (got is not None) == there
+        if not there:
+            continue
+        assert got.dtype == x.dtype
+        assert distance(got, ref) <= (1e-6 + ulp) * largest(ref)
+        if dtype == BF16:       # and nearly every entry is the very same
+            same = np.asarray(got, np.float32) == np.asarray(ref, np.float32)
+            assert same.mean() > 0.999
     assert distance(d_w, want[2]) <= 1e-6 * largest(want[2])
     assert (d_b is None) == (not bias)
     if bias:
@@ -115,7 +156,7 @@ def test_an_impulse_crosses_a_time_blocks_edge_both_ways(lanes, k):
     assert not out[0, ~hit].any()
     # silu'(0) = 1/2 wherever X is zero: dpre = dOut / 2 there
     d_out = jnp.zeros((1, T, C), jnp.float32).at[0, edge, :].set(2.0)
-    d_x, d_w, _ = pallas_conv1d.causal_conv1d_bwd(
+    d_x, d_w, *_ = pallas_conv1d.causal_conv1d_bwd(
         jnp.zeros_like(x), w, None, d_out, **forms(lanes))
     back = np.zeros(T, bool)
     back[edge - (k - 1):edge + 1] = True
@@ -170,22 +211,32 @@ def test_a_declined_shape_keeps_the_statement_and_is_booked(reason):
         assert distance(jnp.asarray(grads[slot]), g) <= tol * largest(g)
 
 
-@pytest.mark.parametrize("bias", [True, False], ids=["bias", "no_bias"])
+@pytest.mark.parametrize("bias,gates,activation", [
+    (True, "", "silu"), (False, "", "silu"),
+    (False, "PreGate+PostGate", "identity"), (True, "PostGate", "silu")],
+    ids=["bias", "no_bias", "gated_as_lfm2", "a_gate_behind"])
 @pytest.mark.parametrize("lanes", [False, True],
                          ids=["time_on_sublanes", "time_on_lanes"])
-def test_the_op_and_its_gradient_op_run_the_kernels(lanes, bias):
+def test_the_op_and_its_gradient_op_run_the_kernels(lanes, bias, gates,
+                                                    activation):
     """Through the executor at a shape the gate takes: the forward books
     pallas_kernel_total{op="causal_conv1d"} once and no fallback, the
-    explicit gradient op (causal_conv1d_grad: X, Filter, Bias and Out's
+    explicit gradient op (causal_conv1d_grad: the op's inputs and Out's
     cotangent in, nothing the forward made) books nothing, and Out and
-    the three gradients are the statement's; `time_on_lanes`, the
-    attribute mamba2_mixer writes, turns the blocks and not the result."""
+    every input's gradient, the gates' among them, are the statement's;
+    `time_on_lanes`, the attribute mamba2_mixer writes, turns the blocks
+    and not the result. The gated form is booked as the plain one."""
     rng = np.random.default_rng(5)
     inputs = {"X": rng.standard_normal((2, 256, 128)).astype(np.float32),
               "Filter": (0.5 * rng.standard_normal((128, 4))).astype(
                   np.float32)}
     if bias:
         inputs["Bias"] = rng.standard_normal(128).astype(np.float32)
+    for slot in filter(None, gates.split("+")):
+        inputs[slot] = rng.standard_normal((2, 256, 128)).astype(np.float32)
+    attrs = {"time_on_lanes": lanes}
+    if activation != "silu":
+        attrs["activation"] = activation
 
     def hits():
         return sum(value for key, value in dict(
@@ -194,13 +245,17 @@ def test_the_op_and_its_gradient_op_run_the_kernels(lanes, bias):
 
     kernels, declined = hits(), fallbacks()
     outs, grads, cot = run_op("causal_conv1d", inputs, {"Out": "float32"},
-                              {"time_on_lanes": lanes}, tuple(inputs))
+                              attrs, tuple(inputs))
     # (run_op runs the forward alone once for the cotangent's shape)
     assert hits() == kernels + 2 and fallbacks() == declined
     want = statement(*(jnp.asarray(inputs.get(s)) if s in inputs else None
-                       for s in ("X", "Filter", "Bias")), jnp.asarray(cot))
+                       for s in ("X", "Filter", "Bias")), jnp.asarray(cot),
+                     *(jnp.asarray(inputs.get(s)) if s in inputs else None
+                       for s in ("PreGate", "PostGate")),
+                     activation=activation)
     np.testing.assert_array_equal(outs["Out"], np.asarray(want[0]))
-    for slot, g in zip(("X", "Filter", "Bias"), want[1:]):
+    for slot, g in zip(("X", "Filter", "Bias", "PreGate", "PostGate"),
+                       want[1:]):
         if slot in inputs:
             assert grads[slot].dtype == np.float32
             assert distance(jnp.asarray(grads[slot]), g) <= 1e-6 * largest(g)
@@ -228,6 +283,94 @@ def test_the_gradient_op_reads_nothing_the_forward_made():
                                          "X@GRAD"]
     assert grad.attr("time_on_lanes") is True
     assert not registry.get("causal_conv1d").kept_in_replay
+
+
+def test_the_gated_layers_gradient_op_reads_nothing_the_forward_made():
+    """layers.short_conv_mixer builds ONE causal_conv1d with both gates
+    and no activation; its gradient op reads the op's four inputs and
+    Out's cotangent and returns a gradient for each of the four."""
+    from paddle_tpu import layers
+    import paddle_tpu as fluid
+    from paddle_tpu.ops import registry
+
+    main = fluid.Program()
+    with fluid.program_guard(main, fluid.Program()):
+        x = layers.data(name="x", shape=[1, 256, 128], dtype="float32",
+                        append_batch_size=False)
+        fluid.backward.append_backward(layers.reduce_sum(
+            layers.short_conv_mixer(x)))
+    ops = [op for op in main.global_block().ops
+           if op.type.startswith("causal_conv1d")]
+    assert [op.type for op in ops] == ["causal_conv1d", "causal_conv1d_grad"]
+    op, grad = ops
+    assert sorted(op.desc.inputs) == ["Filter", "PostGate", "PreGate", "X"]
+    assert op.desc.attrs["activation"] == "identity"
+    assert "time_on_lanes" not in op.desc.attrs
+    assert sorted(grad.desc.inputs) == ["Filter", "Out@GRAD", "PostGate",
+                                        "PreGate", "X"]
+    assert sorted(grad.desc.outputs) == ["Filter@GRAD", "PostGate@GRAD",
+                                         "PreGate@GRAD", "X@GRAD"]
+    assert grad.attr("activation") == "identity"
+    assert not registry.get("causal_conv1d").kept_in_replay
+
+
+def call_digest(call, axis, shape, k, bias, form=()):
+    """The first 16 hex digits of sha256 over the jaxpr of one of the
+    kernels' calls and its BlockSpecs' index maps (a pallas_call prints
+    its specs without them)."""
+    bsz, t, c = shape
+    fn = getattr(pallas_conv1d, call)(axis, bsz, t, c, k, bias,
+                                      jnp.dtype(BF16), None, None, False,
+                                      *form)
+    x = jax.ShapeDtypeStruct((bsz, t, c) if axis == 0 else (bsz, c, t), BF16)
+    rows = k + bias
+    taps = jax.ShapeDtypeStruct((rows, c) if axis == 0 else (c, rows),
+                                jnp.float32)
+    pre, post = (form + (False, False))[:2]
+    args = (x,) * (1 + pre + post) if call == "_fwd_call" \
+        else (x,) * (2 + 2 * pre + post + 1)
+    jaxpr = jax.make_jaxpr(fn)(*args, taps)
+    text = [str(jaxpr)]
+
+    def index_maps(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                text.extend(str(m.index_map_jaxpr) for m in
+                            eqn.params["grid_mapping"].block_mappings)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                index_maps(sub)
+
+    index_maps(jaxpr.jaxpr)
+    assert len(text) > 1
+    return hashlib.sha256("\n".join(text).encode()).hexdigest()[:16]
+
+
+# (call, time axis, [B, T, C], taps, Bias) of the three accepted cells'
+# kernel calls and their digests at the parent commit (PR 61: `git
+# archive 214c520`, the same function run there)
+PARENTS_CALLS = [
+    ("_fwd_call", 0, (1, 8192, 4096), 4, False, "acb297e9c2124c6c"),
+    ("_fwd_call", 1, (1, 8192, 4352), 4, True, "435f2f814939f2ae"),
+    ("_fwd_call", 1, (1, 4096, 6144), 4, True, "1a9a446e478d11cf"),
+    ("_bwd_call", 0, (1, 8192, 4096), 4, False, "1361ff5549c57782"),
+    ("_bwd_call", 1, (1, 8192, 4352), 4, True, "7521ad21471c928e"),
+    ("_bwd_call", 1, (1, 4096, 6144), 4, True, "a3b429f736f4697f"),
+]
+
+
+@pytest.mark.parametrize("call,axis,shape,k,bias,digest", PARENTS_CALLS,
+                         ids=[f"{c[0]}-{'x'.join(map(str, c[2]))}"
+                              for c in PARENTS_CALLS])
+def test_the_plain_forms_calls_are_the_calls_of_before(call, axis, shape, k,
+                                                       bias, digest):
+    """Without a gate and under silu every kernel call is traced to the
+    jaxpr of before PR 62, index maps included: the Kimi-Linear, granite
+    and hybrid cells' steps lower what they lowered (their programs are
+    held to the parent's by tests/test_conv_moe.py). A gated call is
+    another kernel under another name."""
+    assert call_digest(call, axis, shape, k, bias) == digest
+    assert call_digest(call, axis, shape, k, bias,
+                       (True, True, "identity")) != digest
 
 
 # (main, startup) of the eight accepted configurations that build no

@@ -192,6 +192,25 @@ def _experts():
         wrt=("X", "TopkWeight", "W1", "W2"))
 
 
+def _short_conv(t, gated):
+    """causal_conv1d over [1, t, 128] under three taps: the plain form,
+    or LFM2's (a gate ahead, a gate behind, no activation); whole 128-wide
+    blocks of time are the kernels', anything else the statement's."""
+    def build():
+        rng = np.random.default_rng(8)
+        rows = lambda: rng.standard_normal((1, t, 128)).astype(  # noqa: E731
+            np.float32)
+        inputs = {"X": rows(), "Filter": (0.5 * rng.standard_normal(
+            (128, 3))).astype(np.float32)}
+        if gated:
+            inputs.update(PreGate=rows(), PostGate=rows())
+        return dict(op_type="causal_conv1d", inputs=inputs,
+                    outputs={"Out": "float32"},
+                    attrs={"activation": "identity"} if gated else {},
+                    wrt=tuple(inputs))
+    return build
+
+
 def _gelu():
     x = np.random.default_rng(5).standard_normal((2, 8, 128))
     return dict(op_type="gelu", inputs={"X": x.astype(np.float32)},
@@ -223,8 +242,8 @@ def _mul_o3():
 
 # the lowerings that book themselves, and all that one forward lowering
 # books: generic gradients (jax.vjp traces the forward lowering again)
-# but for conv2d's and moe_experts', whose explicit gradient ops book
-# nothing (the experts' takes the forward's choice in silence)
+# but for conv2d's, moe_experts' and causal_conv1d's, whose explicit
+# gradient ops book nothing (they take the forward's choice in silence)
 BOOKED = {
     "ssd_scan": (_scan, {"pallas_kernel_total": {"op=ssd_scan": 1}}),
     "kda_scan": (_delta_rule(128, 128),
@@ -235,6 +254,13 @@ BOOKED = {
     "moe_experts": (_experts, {
         "pallas_kernel_total": {"op=moe_experts": 1},
         "pallas_fallback_total": {"op=pair_sum,reason=tokens": 1}}),
+    # the gated form (PR 62) is booked as the plain one, hit or reason
+    "causal_conv1d": (_short_conv(128, False),
+                      {"pallas_kernel_total": {"op=causal_conv1d": 1}}),
+    "causal_conv1d_gated": (_short_conv(256, True),
+                            {"pallas_kernel_total": {"op=causal_conv1d": 1}}),
+    "causal_conv1d_gated_declined": (_short_conv(96, True), {
+        "pallas_fallback_total": {"op=causal_conv1d,reason=time": 1}}),
     "gelu_kept": (_gelu, {"activation_kept_total": {"act=gelu": 1}}),
     "conv2d_o3": (_conv_o3, {"pallas_kernel_total": {"op=conv2d": 1},
                              "quant_kernel_total": {"op=conv2d": 1}}),

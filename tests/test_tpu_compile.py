@@ -487,6 +487,34 @@ def test_short_conv_kernels_compile(mosaic, one_chip, dtype, t, c, bias,
         == ["causal_conv1d_bwd", "causal_conv1d_fwd"]
 
 
+@pytest.mark.parametrize("dtype", [BF16, jnp.float32],
+                         ids=["bf16", "float32_no_amp"])
+@pytest.mark.parametrize("lanes", [False, True],
+                         ids=["time_on_sublanes", "time_on_lanes"])
+def test_gated_short_conv_kernels_compile(mosaic, one_chip, dtype, lanes):
+    """LFM2's operator at the new cell's [1, 16384, 2048] under three
+    taps, no bias, no activation (PR 62): the gated forward kernel (X and
+    two gates in, Out out) and the gated gradient's (X and the gate ahead
+    twice each, the gate behind, the cotangent; three gradients and the
+    taps' sums out) at the plain form's blocks and chunks, inside the
+    16 MB of scoped VMEM a Mosaic call has by default, under names of
+    their own; rows as short_conv_mixer builds it, and the other
+    orientation."""
+    from paddle_tpu.ops import pallas_conv1d
+    t, c = 16384, 2048
+    assert pallas_conv1d.ineligible(t, c, 3, dtype) is None
+
+    def both(x, pre, post, w, d_out):
+        form = dict(pre_gate=pre, post_gate=post, activation="identity",
+                    lanes=lanes)
+        return (pallas_conv1d.causal_conv1d_fwd(x, w, None, **form),
+                pallas_conv1d.causal_conv1d_bwd(x, w, None, d_out, **form))
+
+    rows = ((1, t, c), dtype)
+    assert _compile(both, one_chip, rows, rows, rows, ((c, 3), jnp.float32),
+                    rows) == ["gated_conv1d_bwd", "gated_conv1d_fwd"]
+
+
 _HYBRID_ME = {}
 
 
@@ -1057,6 +1085,76 @@ def test_delta_rule_step_compiles_with_both_kinds_of_mixer(mosaic, one_chip):
     # the gated norm works in float32; the short convolutions and the
     # delta rule read and write bf16 and widen in VMEM
     assert float32 <= {"mul", "rms_norm", "rms_norm_grad"}, float32
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 15.75e9
+
+
+LFM2_CELL = "lfm2-24b-a2b.train-shortconv-ep8-share"
+
+
+def test_shortconv_step_keeps_no_float32_rows_around_the_conv(mosaic,
+                                                              one_chip):
+    """The LFM2 cell's step at its own 16,384-token sequence and published
+    widths, the layers held cut to published layers 0, 2 and 3 (conv +
+    dense, attention + experts, conv + experts; the five take four minutes
+    here and tools/describe_step.py sized them, PR 62: 5.19e9 B of
+    temporaries + 5.63e9 B of aliased state) under a checkpoint a layer:
+    the gated short convolution on the gated kernels of
+    ops/pallas_conv1d.py, two forward calls, one replayed (the last
+    segment is not) and two of the explicit gradient op's, which traces no
+    forward; every operand and result of them a bf16 [1, 16384, 2048]
+    array that a product wrote or reads, with no float32 array of a whole
+    [T, 2048] or [T, 6144] activation and no copy under the op or its
+    gradient op; attention at 32 query heads over 8 of 64 with K and V
+    widened to the query heads (two heads share a lane block: `lanes`), on
+    the flash kernels with the FUSED backward at 16,384 rows, the longest
+    `_split_reason` lets through at 128 lanes, booked once a lowering; the
+    experts on gmm / tgmm under the ladder's one switch each way."""
+    from paddle_tpu import telemetry
+    cell = run.load_json("workloads", LFM2_CELL)
+    config = dict(run.load_json("configs", cell["config"]),
+                  layers_held=[0, 2, 3], num_hidden_layers=3)
+    tokens = config["sequence_length"]
+    assert tokens == 16384 and pallas_attention._split_reason(
+        tokens, tokens, 128, 2, pallas_attention._TILE,
+        pallas_attention._MAJOR) is None
+    assert pallas_attention._split_reason(
+        2 * tokens, 2 * tokens, 128, 2, pallas_attention._TILE,
+        pallas_attention._MAJOR) == "vmem"
+    booked = {name: dict(telemetry.read_series(name)) for name in (
+        "flash_backward_total", "attention_kv_groups_total")}
+    compiled = describe_step.compile_step(cell, config, one_chip)
+    text = compiled.as_text()
+    kernels = [re.search(r'op_name="[^"]*?(\w+)\)*/pallas_call', line).group(1)
+               for line in text.splitlines() if KERNEL in line]
+    conv = {k: kernels.count(k) for k in set(kernels) if "conv1d" in k}
+    assert conv == {"gated_conv1d_fwd": 2 + 1, "gated_conv1d_bwd": 2}, conv
+    flash = {k: kernels.count(k) for k in set(kernels) if "flash" in k}
+    assert flash == {"flash_fwd": 1, "flash_dkv": 1}, flash
+    assert "gmm" in kernels and "tgmm" in kernels
+    assert "pd.moe_experts/cond" in text
+    added = {name: {k: v - booked[name].get(k, 0) for k, v in dict(
+        telemetry.read_series(name)).items() if v != booked[name].get(k, 0)}
+        for name in booked}
+    assert added == {
+        "flash_backward_total": {"form=fused,reason=": 1},
+        "attention_kv_groups_total": {
+            "op=scaled_dot_product_attention,groups=4,form=repeated,"
+            "ground=lanes": 1}}, added
+    rows = f"bf16[1,{tokens},{config['hidden_size']}]"
+    for line in text.splitlines():
+        if KERNEL in line and "conv1d" in line:
+            operands = re.findall(r"\w+\[[\d,]*\]", re.search(
+                r"operand_layout_constraints=\{(.*?\})\}", line).group(1))
+            gated_bwd = "gated_conv1d_bwd" in line
+            assert operands.count(rows) == (6 if gated_bwd else 3), operands
+            assert line.split(" custom-call(")[0].count(rows) == (
+                3 if gated_bwd else 1)
+    assert not _float32_under_the_conv(text)
+    under = [i for i in xplane.hlo_instructions(text)
+             if re.search(r"pd\.causal_conv1d(_grad)?/", i.op_name or "")]
+    assert len(under) >= 5
+    assert not [i.name for i in under if i.opcode in ("copy", "transpose")]
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 15.75e9
 

@@ -11,7 +11,10 @@ what pallas_conv1d._TILES is written from.
 Times the forward and forward + gradient (X, Filter, Bias from a random
 cotangent) at the three cells' shapes ([1, 8192, 4096] K 4 no bias;
 [1, 8192, 4352] and [1, 4096, 6144] K 4 with bias) with X and the
-cotangent in `--dtype` as the projections write them. A form is
+cotangent in `--dtype` as the projections write them; `--cells lfm2` is
+the gated form at LFM2's [1, 16384, 2048], K 3, no bias, no activation,
+a gate ahead of the taps and a gate behind them (three operands read,
+their three gradients written). A form is
 `time x channels / time x channels` of a block and of a chunk; with time
 on the lanes the kernels are handed [B, C, T] (the swapaxes around them
 cancel against the sweep's own, as they are bitcasts in a Mamba cell's
@@ -23,7 +26,7 @@ is host dispatch on that machine, as tools/pair_sum_sweep.py found). One
 JSON line per reading goes to chiprun_out/conv1d_sweep.jsonl; chipless
 (`JAX_PLATFORMS=cpu`) give a tiny shape, which the kernels take
 interpreted: `--shape 1 384 256 4 1 --forms 128x128/64x128 --lane-forms
-128x128/128x16`.
+128x128/128x16` (`--gated identity`: that shape in the gated form).
 """
 
 import argparse
@@ -43,7 +46,9 @@ OUT = "chiprun_out/conv1d_sweep.jsonl"
 CHAINED = 16
 # B, T, C, K, Bias
 CELLS = {"kimi": (1, 8192, 4096, 4, 0), "granite": (1, 8192, 4352, 4, 1),
-         "hybrid": (1, 4096, 6144, 4, 1)}
+         "hybrid": (1, 4096, 6144, 4, 1), "lfm2": (1, 16384, 2048, 3, 0)}
+# the cells whose op is the gated form: its activation (both gates)
+GATED = {"lfm2": "identity"}
 
 
 def inputs(bsz, t, c, k, bias, dtype, seed=0):
@@ -56,16 +61,37 @@ def inputs(bsz, t, c, k, bias, dtype, seed=0):
 
 
 def both_of(fwd, bwd):
-    """(Out, dX, dFilter[, dBias]) of one call."""
+    """(Out, dX, dFilter[, dBias][, dPreGate, dPostGate]) of one call."""
     def run(x, w, b, do):
         return (fwd(x, w, b),) + tuple(
             g for g in bwd(x, w, b, do) if g is not None)
     return run
 
 
-def statement_bwd(x, w, b, do):
-    primals = (x, w) if b is None else (x, w, b)
-    return jax.vjp(hybrid_ops.causal_conv1d_reference, *primals)[1](do)
+def gated_form(shape, activation, dtype, seed=1):
+    """The gated form's keywords at `shape`: both gates, seeded."""
+    rng = np.random.default_rng(seed)
+    return dict(activation=activation, **{
+        side: jnp.asarray(rng.standard_normal(shape[:3]), dtype)
+        for side in ("pre_gate", "post_gate")})
+
+
+def statement_of(form):
+    """(forward, gradient) of the statement under the gated form's
+    keywords (none: the plain op), the gradients in the kernels' order."""
+    gates = {k: v for k, v in form.items() if k != "activation"}
+    rest = {k: v for k, v in form.items() if k == "activation"}
+
+    def fwd(x, w, b):
+        return hybrid_ops.causal_conv1d_reference(x, w, b, **form)
+
+    def bwd(x, w, b, do):
+        primals = dict(x=x, w=w, **gates, **({} if b is None else {"bias": b}))
+        grads, = jax.vjp(lambda kw: hybrid_ops.causal_conv1d_reference(
+            **kw, **rest), primals)[1](do)
+        return tuple(grads.get(key) for key in (
+            "x", "w", "bias", "pre_gate", "post_gate"))
+    return fwd, bwd
 
 
 def chained(fwd, bwd, lanes):
@@ -102,6 +128,8 @@ def main():
     ap.add_argument("--lane-forms", default="512x512/512x16")
     ap.add_argument("--dtype", default="bfloat16")
     ap.add_argument("--errors", type=int, default=1)
+    ap.add_argument("--gated", default=None, metavar="ACTIVATION",
+                    help="--shape in the gated form: silu or identity")
     args = ap.parse_args()
     dtype = jnp.dtype(args.dtype)
     interpret = pallas_attention._interpret()
@@ -111,12 +139,14 @@ def main():
     with open(OUT, "a") as log:
         for cell, shape in shapes.items():
             operands = inputs(*shape, dtype)
-            forms = [(dict(path="statement"), False,
-                      hybrid_ops.causal_conv1d_reference, statement_bwd)]
+            activation = GATED.get(cell, args.gated)
+            form = gated_form(shape, activation, dtype) if activation else {}
+            statement = statement_of(form)
+            forms = [(dict(path="statement"), False) + statement]
             for lanes, given in ((False, args.forms), (True, args.lane_forms)):
                 for tile, chunk in parsed(given):
                     kw = dict(lanes=lanes, tile=tile, chunk=chunk,
-                              interpret=interpret)
+                              interpret=interpret, **form)
                     forms.append((
                         dict(path="kernel", time_on="lanes" if lanes else
                              "sublanes", tile=tile, chunk=chunk), lanes,
@@ -124,7 +154,7 @@ def main():
                         pallas_conv1d.causal_conv1d_fwd(x, w, b, **kw),
                         lambda x, w, b, do, kw=kw:
                         pallas_conv1d.causal_conv1d_bwd(x, w, b, do, **kw)))
-            want = jax.jit(both_of(forms[0][2], statement_bwd))(*operands) \
+            want = jax.jit(both_of(*statement))(*operands) \
                 if args.errors else None
             for labels, lanes, fwd, bwd in forms:
                 row = dict(cell=cell, shape=shape, dtype=args.dtype,
@@ -137,7 +167,8 @@ def main():
                     if want is not None and labels["path"] == "kernel":
                         got = jax.jit(both_of(fwd, bwd))(*operands)
                         row["rel_err"] = dict(zip(
-                            ("out", "dx", "dfilter", "dbias"),
+                            ["out", "dx", "dfilter"] + ["dbias"] * shape[4]
+                            + ["dpre", "dpost"] * bool(form),
                             (float(jnp.abs(a.astype(jnp.float32)
                                            - b.astype(jnp.float32)).max()
                                    / jnp.abs(b.astype(jnp.float32)).max())
