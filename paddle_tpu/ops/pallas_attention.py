@@ -36,6 +36,25 @@ where the diagonal lives, in the walk ranges, the `index_map` clamps and
 the predicates of the tiles an edge crosses: a tile wholly before the
 window is neither fetched nor walked, forward or backward.
 
+An edge is walked at a finer grain than the tile (PR 63, `_patterns`):
+the tiles no edge crosses keep their [512, 512] blocks, and a tile an
+edge crosses is computed in [grain, grain] sub-tiles. A sub-tile wholly
+outside the mask is not computed (no product, no exp: it contributed
+exact zeros), one wholly inside it runs with no predicate, and only
+those an edge really crosses carry one. Which sub-tile is which is worked
+out ahead of the lowering, from the relations the walk ranges use, for
+each offset between a walked and a resident tile at which an edge can
+cross (one or two values where the call's offsets are plain ints, as the
+attention ops' are: 0 and 0, or q_off = -block); the kernel picks a
+crossed tile's pattern by that offset and runs it as ONE block of
+straight-line code, the resident tile's parts side by side (a sub-tile
+walked as a loop trip of its own cost 1.7 times a tile's pairs, 3.4 times
+at 128 rows: PERF.md section 6, PR 63). A call whose offsets are traced
+values (a ring shard's) walks its tiles whole, as before.
+`flash_edge_subtiles_total{kernel, mask, grain, state}` books a
+lowering's count of each (`edge_subtiles`), and a call with no mask
+builds none of it.
+
 Orientation: every kernel computes the TRANSPOSED block S^T = K Q^T
 [rows of K, rows of Q]. The softmax statistics (running max and sum,
 LSE, the backward's delta) are then [1, rows of Q] rows: lane-dense,
@@ -169,6 +188,58 @@ _MAJOR = 2048
 # 2.18 + 2.60 -> 3.24 at q_off -4. Fused is 1.19-1.24 x dkv everywhere:
 # no shape where the two calls win, so the rule is VMEM's alone.
 _TILE = (512, 512)
+
+# Rows a side of the sub-tiles in which a tile that an edge of the mask
+# crosses is computed (`_patterns`): never under 128, the lanes of S^T,
+# where the row statistics live. From the sweep on a v5e (PR 63, my chip
+# runs, call 4: tools/flash_sweep.py --rows 512 --grain 512,256,128, bf16,
+# sixteen calls chained; a grain of 512 is the whole-tile walk of before),
+# ms a call, whole tiles -> grain 256 | grain 128, and the pairs computed
+# over the pairs kept, whole | 256 | 128:
+#   shape, mask                   flash_fwd              flash_dkv (fused)      pairs
+#   1 8192 8 128, window 512      0.608 -> 0.461 | 0.445  0.979 -> 0.838 | 0.836  2.00 | 1.50 | 1.25
+#   16 1024 12 64, causal         0.907 -> 0.744 | 0.723  1.519 -> 1.391 | 1.401  1.50 | 1.25 | 1.12
+#   1 4096 16 128, causal         0.862 -> 0.803 | 0.795  1.350 -> 1.301 | 1.310  1.12 | 1.06 | 1.03
+#   1 8192 48 128, causal         9.804 -> 9.431 | 9.387  14.13 -> 13.86 | 13.90  1.06 | 1.03 | 1.02
+#   1 4096 20 256, causal         1.916 -> 1.846 | 1.836  3.376 -> 3.242 | 3.235  1.12 | 1.06 | 1.03
+#   1 8192 28 128, window 4096    5.614 -> 5.212 | 5.176  8.121 -> 7.702 | 7.708  1.12 | 1.06 | 1.03
+#   1 4096 32 128, block 4        1.719 -> 1.614 | 1.585  2.629 -> 2.528 | 2.549  1.12 | 1.06 | 1.03
+#   the same, q_off -4            1.716 -> 1.614 | 1.584  2.628 -> 2.529 | 2.547  1.13 | 1.06 | 1.03
+# No shape where the whole tile wins, two heads a lane block (D = 64) and
+# one head of 256 lanes among them; the forward reads 128 under 256
+# everywhere, the backward the two within 0.7 % either way: one grain.
+# What the sweep threw out on the way (calls 1 to 3; PERF.md section 6):
+# a sub-tile as a loop trip of its own, its bounds from the ranges at the
+# finer grain (1.3 to 2.1 times the whole tile's time: a trip's chain of
+# product, max, exp, product overlaps with no other); the score product a
+# part of the queries at a time (Q^T is the MXU's stationary side, a lane
+# group an MXU: a part costs what the whole tile does, so the forward and
+# `flash_dq` form a tile's scores ONCE and cut the vector work and the
+# second product alone); and every pattern of a call in each crossed range
+# with a whole-tile body behind them (+12 % on the windowed shapes: a
+# kernel pays for the bodies it holds, run or not).
+_GRAIN = 128
+
+
+def _static(q_off, k_off):
+    """(q_off, k_off) where a call's offsets are plain ints (the attention
+    ops': 0 and 0, or -block and 0), None where one is a traced value (a
+    ring shard's): the `at` of the wrappers."""
+    if isinstance(q_off, int) and isinstance(k_off, int):
+        return q_off, k_off
+    return None
+
+
+def _sub_tiles(side, b_res, b_walk, causal, grain, block, window, at):
+    """(grain, patterns) of a kernel whose crossed tiles are walked in
+    sub-tiles (`_patterns`), (0, None) where they are walked whole: no
+    mask, offsets that are traced values, tiles of unequal sides, or
+    tiles no larger than the grain."""
+    grain = grain or _GRAIN
+    if not causal or at is None or b_res != b_walk or grain >= b_res:
+        return 0, None
+    return grain, _patterns(side, b_res, grain, block, window, at)
+
 
 def _lane_block(h: int, d: int):
     """(lanes, heads) of one grid step's block of the [.., H*D] view, or
@@ -354,12 +425,30 @@ def _by_head(masks, vals):
     return out
 
 
+# The geometry below runs on traced int32 scalars inside a kernel and an
+# index map, and on plain Python ints where a call's walk is counted ahead
+# of its lowering (`edge_subtiles`): one set of relations for both.
+
 def _floordiv(x, b: int):
+    if isinstance(x, int):
+        return x // b
     return jnp.floor_divide(x, jnp.int32(b))
 
 
+def _between(x, lo, hi):
+    if all(isinstance(a, int) for a in (x, lo, hi)):
+        return min(max(x, lo), hi)
+    return jnp.clip(x, lo, hi)
+
+
 def _clip(x, hi: int):
-    return jnp.clip(x, 0, hi)
+    return _between(x, 0, hi)
+
+
+def _max(x, y):
+    if isinstance(x, int) and isinstance(y, int):
+        return max(x, y)
+    return jnp.maximum(x, y)
 
 
 # What a walked block's predicate holds it to (bits): the causal diagonal,
@@ -368,22 +457,101 @@ def _clip(x, hi: int):
 _OPEN, _DIAG, _EDGE = 0, 1, 2
 
 
-def _walk(full, masked, edge, visit, both=False):
+def _walk(full, masked, edge, visit, both=False, crossed=None):
     """Run visit(j, kind) over the (lo, hi) range of walked blocks that
     need no mask (kind _OPEN), over the range the diagonal crosses (_DIAG,
     with `both` also held to the window: _DIAG | _EDGE; None: no causal
     mask) and over the range the window's far edge crosses (_EDGE; None:
-    no window)."""
-    def run(bounds, kind):
+    no window). `crossed`(j, kind), where given, takes visit's place on
+    the two ranges an edge crosses (the sub-tiles of `_patterns`)."""
+    def run(bounds, kind, visit):
         def body(j, carry):
             visit(j, kind)
             return carry
         lax.fori_loop(*bounds, body, 0)
-    run(full, _OPEN)
+    run(full, _OPEN, visit)
     if masked is not None:
-        run(masked, _DIAG | _EDGE if both else _DIAG)
+        run(masked, _DIAG | _EDGE if both else _DIAG, crossed or visit)
     if edge is not None:
-        run(edge, _EDGE)
+        run(edge, _EDGE, crossed or visit)
+
+
+def _patterns(side: str, b: int, grain: int, block: int, window: int, at):
+    """The sub-tiles of every tile an edge of the mask can cross (PR 63):
+    {d: (far, cells)}, d the walked tile's first position less the
+    resident tile's, `far` whether such a tile lies in the range the
+    window's far edge crosses (else in the diagonal's: `_walk`), cells a
+    list of (part, rows, strips): against the rows `part` (a slice of
+    `grain` rows) of the resident tile, the rows `rows` (a slice) of the
+    walked tile, in strips of `grain` rows each with its
+    (kind, first): the predicate that strip's sub-tile runs under and, for
+    `_held`, its K rows' first position less its Q rows'. A sub-tile
+    wholly outside the mask is in no cell (not computed), one wholly
+    inside it has kind _OPEN (no predicate), and one an edge crosses the
+    edge's kind.
+
+    `side` "kv" is the Q-resident walk of flash_fwd and flash_dq, "q"
+    flash_dkv's K-resident one; both sides' tiles are [b, b]. `at` = the
+    call's (q_off, k_off) as plain ints: with them a tile's d is one of a
+    few values, the same for every resident tile (0 alone where the tiles
+    are aligned on the diagonal; -window beside it under a window of whole
+    tiles), so each value's cells are worked out here, ahead of the
+    lowering, from the relations the walk ranges use (`_kv_ranges`,
+    `_q_ranges` on plain ints at the finer grain), and the kernel picks a
+    crossed tile's by its d. Every d a crossed tile of such a call can
+    have is here: d is (walk_off - res_off) mod b plus whole tiles, and an
+    edge crosses no tile further than a window and a block away (a
+    lowering's count, `_tiles_walked`, looks every one of them up).
+    Offsets that are traced values (a ring shard's) give no patterns:
+    their tiles are walked whole."""
+    q_off, k_off = at
+    res_off, walk_off = (k_off, q_off) if side == "q" else (q_off, k_off)
+    first = res_off + (b << 12)     # a resident tile far from position 0
+    n = b // grain
+    diag = _DIAG | _EDGE if _crosses_both(window, grain, grain) else _DIAG
+    reach = (window + block) // b + 2
+
+    def ranges(d, part, rows, per):
+        if side == "q":
+            return _q_ranges(first + part, first + d, rows, rows, per,
+                             block, window)
+        return _kv_ranges(first + part, first + d, rows, rows, per, True,
+                          block, window)
+
+    out = {}
+    for d in range((walk_off - res_off) % b - reach * b, reach * b, b):
+        _, masked, edge = ranges(d, 0, b, 1)
+        if masked != (0, 1) and edge != (0, 1):
+            continue            # open, or dead: no edge crosses it
+        cells = []
+        for part in range(0, b, grain):
+            opened, crossed, far = ranges(d, part, grain, n)
+            kinds = {}
+            for bounds, kind in ((opened, _OPEN), (crossed, diag),
+                                 (far or (0, 0), _EDGE)):
+                kinds.update((strip, kind) for strip in range(*bounds))
+            if not kinds:
+                continue
+            lo, hi = min(kinds), max(kinds) + 1
+            sign = -1 if side == "q" else 1
+            cells.append((slice(part, part + grain),
+                          slice(lo * grain, hi * grain),
+                          [(kinds[strip], sign * (d + strip * grain - part))
+                           for strip in range(lo, hi)]))
+        out[d] = (edge == (0, 1), cells)
+    return out
+
+
+def _held_strips(st, axis: int, strips, diff, window: int):
+    """The score block with each of its strips along `axis` (the walked
+    side of `_patterns`' cell) held to its own predicate."""
+    size = st.shape[axis] // len(strips)
+    if all(kind == _OPEN for kind, _ in strips):
+        return st
+    return jnp.concatenate([
+        _held(lax.slice_in_dim(st, n * size, (n + 1) * size, axis=axis),
+              diff, first, kind, window)
+        for n, (kind, first) in enumerate(strips)], axis)
 
 
 def _crosses_both(window: int, bq: int, bk: int) -> bool:
@@ -406,6 +574,39 @@ def _held(st, diff, first, kind: int, window: int):
     if kind & _EDGE:
         st = jnp.where(diff < first + window, st, _NEG)
     return st
+
+
+def _rows(x, part):
+    """The rows `part` (a slice) of a value; None: all of it."""
+    return x if part is None else x[part]
+
+
+def _pattern_cells(pattern, axis: int, grain: int, block: int, window: int):
+    """A pattern's cells as the kernels' `cells` take them: each with the
+    predicate of its strips (along `axis` of the score block) as a
+    function of the block."""
+    sub = _q_minus_k(grain, grain, block)
+    return [(part, rows, lambda st, _, strips=strips: _held_strips(
+        st, axis, strips, sub, window)) for part, rows, strips in pattern]
+
+
+def _crossed(patterns, offset, cells_visit):
+    """_walk's `crossed`: the tile at walked block j is computed in the
+    cells of its pattern (`_patterns`), picked by `offset`(j), the tile's
+    d, among those of the range it is walked in: where the tiles are
+    aligned a range has one pattern and nothing is picked."""
+    if not patterns:
+        return None
+
+    def visit(j, kind):
+        import jax.experimental.pallas as pl
+        mine = {d: cells for d, (far, cells) in patterns.items()
+                if far == (kind == _EDGE)}
+        if len(mine) == 1:
+            return cells_visit(j, *mine.values())
+        for d, cells in mine.items():
+            pl.when(offset(j) == d)(functools.partial(cells_visit, j, cells))
+    return visit
 
 
 # The mask's geometry. A causal mask at the grain of `block` positions
@@ -463,7 +664,7 @@ def _kv_ranges(q_first, k_base, bq: int, bk: int, per: int, causal: bool,
     first_live = _clip(_floordiv(q_first - window + 1 - k_base, bk), per)
     first_open = _clip(_ceildiv(q_first + bq - window - k_base, bk), per)
     # a block both edges cross is walked once, with the diagonal's
-    first_open = jnp.clip(first_open, first_live, n_full)
+    first_open = _between(first_open, first_live, n_full)
     return (first_open, n_full), (n_full, n_live), (first_live, first_open)
 
 
@@ -496,13 +697,13 @@ def _q_ranges(k_first, q_base, bq: int, bk: int, per: int, block: int = 1,
     live0 = _clip(_floordiv(_first_seer(k_first, block) - q_base, bq), per)
     full0 = _clip(-_floordiv(
         q_base - _first_seer(k_first + bk - 1, block), bq), per)
-    full0 = jnp.maximum(full0, live0)
+    full0 = _max(full0, live0)
     if not window:
         return (full0, per), (live0, full0), None
     last_open = _clip(_floordiv(k_first + window - q_base, bq), per)
     last_live = _clip(
         _floordiv(k_first + bk + window - 2 - q_base, bq) + 1, per)
-    last_open = jnp.clip(last_open, full0, last_live)
+    last_open = _between(last_open, full0, last_live)
     return (full0, last_open), (live0, full0), (last_open, last_live)
 
 
@@ -537,7 +738,8 @@ def _q_minus_k(bk: int, bq: int, block: int = 1):
 def _fwd_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, *rest, bq: int,
                 bk: int, mk: int, n_maj: int, d: int, hpb: int,
                 scale: float, causal: bool, normalize: bool,
-                block: int = 1, window: int = 0):
+                block: int = 1, window: int = 0, grain: int = 0,
+                patterns=None):
     """Grid (B, lane blocks, Tq/bq, Tk/mk): Q tile [bq, L] resident, the
     K/V major tile [mk, L] in VMEM, walked in blocks of bk rows by the
     loop; (acc, m, l) carry in scratch across major tiles. Works on the
@@ -570,27 +772,54 @@ def _fwd_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, *rest, bq: int,
     qs = [_only(mask, qt) for mask in masks]
     diff = _q_minus_k(bk, bq, block) if causal else None
 
-    def walked(j, masked):
+    def cells(j, cells):
+        """The block at j of the major tile, cell by cell: against the
+        queries `part` of the tile (None: all), the keys `rows` of the
+        block (None: all), in strips each under its own predicate. The
+        statistics and the output a cell moves are its part's lanes."""
         start = pl.multiple_of(j * bk, bk)
         kb = k_ref[0, pl.ds(start, bk), :]
         vt = _transpose(v_ref[0, pl.ds(start, bk), :])      # [L, bk]
-        for hh in range(hpb):
-            st = _dot(kb, qs[hh], _NT)
-            if not fold:
-                st = st * scale
-            st = _held(st, diff, k_base + start - q_first, masked, window)
-            m_prev = m_sc[hh]
-            m_new = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            pt = jnp.exp(st - m_new)
-            l_sc[hh] = l_sc[hh] * alpha + jnp.sum(pt, axis=0, keepdims=True)
-            m_sc[hh] = m_new
-            rows = slice(hh * d, (hh + 1) * d)
-            acc_sc[rows, :] = acc_sc[rows, :] * alpha + _dot(
-                vt[rows, :], pt.astype(vt.dtype), _NN)
+        whole = {}
+        for part, rows, strips in cells:
+            cols = slice(None) if part is None else part
+            keys = slice(None) if rows is None else rows
+            for hh in range(hpb):
+                stat = (hh,) if part is None else (hh, slice(None), part)
+                # the scores are one product a tile and head whatever its
+                # cells: Q^T is the MXU's stationary side, a lane group an
+                # MXU, so a part of the queries costs what all of them do
+                if hh not in whole:
+                    whole[hh] = _dot(kb, qs[hh], _NT)
+                    if not fold:
+                        whole[hh] = whole[hh] * scale
+                st = whole[hh] if part is None else whole[hh][keys, part]
+                st = strips(st, start)
+                m_prev = m_sc[stat]
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(st, axis=0, keepdims=True))
+                alpha = jnp.exp(m_prev - m_new)
+                pt = jnp.exp(st - m_new)
+                l_sc[stat] = l_sc[stat] * alpha + jnp.sum(pt, axis=0,
+                                                          keepdims=True)
+                m_sc[stat] = m_new
+                lanes = slice(hh * d, (hh + 1) * d)
+                acc_sc[lanes, cols] = acc_sc[lanes, cols] * alpha + _dot(
+                    vt[lanes, keys], pt.astype(vt.dtype), _NN)
+
+    def offset(j):
+        return k_base + j * bk - q_first
+
+    def walked(j, kind):
+        cells(j, [(None, None, lambda st, start: _held(
+            st, diff, k_base + start - q_first, kind, window))])
+
+    def sub_tiles(j, pattern):
+        cells(j, _pattern_cells(pattern, 0, grain, block, window))
 
     _walk(*_kv_ranges(q_first, k_base, bq, bk, per, causal, block, window),
-          walked, both=_crosses_both(window, bq, bk))
+          walked, both=_crosses_both(window, bq, bk),
+          crossed=_crossed(patterns, offset, sub_tiles))
 
     @pl.when(kk == n_maj - 1)
     def _finalize():
@@ -720,13 +949,16 @@ def _check_window(window: int, causal: bool, block: int):
 
 @functools.partial(jax.jit, static_argnames=("scale", "causal", "normalize",
                                              "tile", "major", "block",
-                                             "window"))
+                                             "window", "grain", "at"))
 def _fwd_call(q, k, v, q_off, k_off, scale, causal, normalize, tile=_TILE,
-              major=_MAJOR, block=1, window=0):
+              major=_MAJOR, block=1, window=0, grain=None, at=None):
     """Returns (out [B,Tq,H,D], stats): stats = (lse,) when normalizing,
     else (m, l); each [B, H, Tq] f32. k and v are [B,Tk,H_kv,D]: the
     walked tiles of query lane block g are those of K/V lane block
-    g // (H / H_kv), and the kernel's body knows nothing of it."""
+    g // (H / H_kv), and the kernel's body knows nothing of it. `at` =
+    (q_off, k_off) where the caller knows them as plain ints (`_static`):
+    the tiles an edge crosses are then walked in sub-tiles of `grain`
+    rows a side (`_sub_tiles`; None: `_GRAIN`)."""
     _check_window(window, causal, block)
     b, tq, h, d = q.shape
     tk = k.shape[1]
@@ -734,6 +966,8 @@ def _fwd_call(q, k, v, q_off, k_off, scale, causal, normalize, tile=_TILE,
     bq, bk = _fit(tq, tile[0]), _fit(tk, tile[1])
     mk = _major(tk, bk, major)
     n_maj = tk // mk
+    grain, patterns = _sub_tiles("kv", bq, bk, causal, grain, block,
+                                 window, at)
 
     sp = _specs(lanes, hpb, bq, bk, mk,
                 _kv_major_index(bq, mk, n_maj, causal, block, window),
@@ -743,7 +977,8 @@ def _fwd_call(q, k, v, q_off, k_off, scale, causal, normalize, tile=_TILE,
     out, *stats = _call(
         functools.partial(_fwd_kernel, bq=bq, bk=bk, mk=mk, n_maj=n_maj,
                           d=d, hpb=hpb, scale=float(scale), causal=causal,
-                          normalize=normalize, block=block, window=window),
+                          normalize=normalize, block=block, window=window,
+                          grain=grain, patterns=patterns),
         "flash_fwd", (b, h * d // lanes, tq // bq, n_maj),
         [sp.res, sp.walk_kv, sp.walk_kv], [sp.res] + [sp.res_stat] * n_stat,
         [struct((b, tq, h * d), q.dtype if normalize else jnp.float32)]
@@ -755,8 +990,11 @@ def _fwd_call(q, k, v, q_off, k_off, scale, causal, normalize, tile=_TILE,
 
 def _forward(q, k, v, causal, return_lse=False, block=1, window=0):
     scale = 1.0 / (q.shape[-1] ** 0.5)
+    if causal:
+        count_edge_subtiles("flash_fwd", q, k, _TILE, _MAJOR, block, window,
+                            (0, 0))
     out, (lse,) = _fwd_call(q, k, v, 0, 0, scale, causal, normalize=True,
-                            block=block, window=window)
+                            block=block, window=window, at=(0, 0))
     return (out, lse) if return_lse else out
 
 
@@ -769,15 +1007,19 @@ def flash_attention_block(q, k, v, q_off, k_off, scale, causal, block=1):
     matching parallel.ring_attention._block_attn's online-softmax form.
     A row that sees no key of the part has m = -1e30 and an (acc, l) to
     be weighted exp(m - m_merged) = 0 by the merge."""
+    at = _static(q_off, k_off)
+    if causal:
+        count_edge_subtiles("flash_fwd", q, k, _TILE, _MAJOR, block, 0, at)
     acc, (m, l) = _fwd_call(q, k, v, q_off, k_off, scale, causal,
-                            normalize=False, block=block)
+                            normalize=False, block=block, at=at)
     return acc, l, m
 
 
 def _dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
                dq_ref, dq_sc, *, bq: int, bk: int, mk: int, n_maj: int,
                d: int, hpb: int, scale: float, causal: bool,
-               block: int = 1, window: int = 0):
+               block: int = 1, window: int = 0, grain: int = 0,
+               patterns=None):
     """Grid and walk of the forward: Q/dO tile resident, K/V walked, dQ^T
     carried in scratch. Recomputes P^T = exp(S^T - LSE) per block;
     dS^T = P^T * (V dO^T - delta); dQ^T = (sum_k K^T dS^T) * scale."""
@@ -803,24 +1045,45 @@ def _dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
     dos = [_only(mask, dot_) for mask in masks]
     diff = _q_minus_k(bk, bq, block) if causal else None
 
-    def walked(j, masked):
+    def cells(j, cells):
+        """`_fwd_kernel`'s: the block at j, cell by cell."""
         start = pl.multiple_of(j * bk, bk)
         kb = k_ref[0, pl.ds(start, bk), :]
         vb = v_ref[0, pl.ds(start, bk), :]
         kt = _transpose(kb)                                  # [L, bk]
-        for hh in range(hpb):
-            st = _dot(kb, qs[hh], _NT)
-            if not fold:
-                st = st * scale
-            st = _held(st, diff, k_base + start - q_first, masked, window)
-            pt = jnp.exp(st - lse_ref[0, hh, 0])
-            dpt = _dot(vb, dos[hh], _NT)
-            dst = (pt * (dpt - dl_ref[0, hh, 0])).astype(kb.dtype)
-            rows = slice(hh * d, (hh + 1) * d)
-            dq_sc[rows, :] = dq_sc[rows, :] + _dot(kt[rows, :], dst, _NN)
+        scores, dps = {}, {}    # one product a tile and head: `_fwd_kernel`
+        for part, rows, strips in cells:
+            cols = slice(None) if part is None else part
+            keys = slice(None) if rows is None else rows
+            for hh in range(hpb):
+                stat = (0, hh, 0) if part is None else (
+                    0, hh, 0, slice(None), part)
+                if hh not in scores:
+                    st = _dot(kb, qs[hh], _NT)
+                    scores[hh] = st if fold else st * scale
+                st = scores[hh] if part is None else scores[hh][keys, part]
+                pt = jnp.exp(strips(st, start) - lse_ref[stat])
+                if hh not in dps:
+                    dps[hh] = _dot(vb, dos[hh], _NT)
+                dpt = dps[hh] if part is None else dps[hh][keys, part]
+                dst = (pt * (dpt - dl_ref[stat])).astype(kb.dtype)
+                lanes = slice(hh * d, (hh + 1) * d)
+                dq_sc[lanes, cols] = dq_sc[lanes, cols] + _dot(
+                    kt[lanes, keys], dst, _NN)
+
+    def offset(j):
+        return k_base + j * bk - q_first
+
+    def walked(j, kind):
+        cells(j, [(None, None, lambda st, start: _held(
+            st, diff, k_base + start - q_first, kind, window))])
+
+    def sub_tiles(j, pattern):
+        cells(j, _pattern_cells(pattern, 0, grain, block, window))
 
     _walk(*_kv_ranges(q_first, k_base, bq, bk, per, causal, block, window),
-          walked, both=_crosses_both(window, bq, bk))
+          walked, both=_crosses_both(window, bq, bk),
+          crossed=_crossed(patterns, offset, sub_tiles))
 
     @pl.when(kk == n_maj - 1)
     def _finalize():
@@ -831,7 +1094,7 @@ def _dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
                 dk_ref, dv_ref, *rest, bq: int, bk: int, mq: int,
                 n_maj: int, d: int, hpb: int, scale: float, causal: bool,
                 block: int = 1, n_k: int = 0, window: int = 0,
-                groups: int = 0):
+                groups: int = 0, grain: int = 0, patterns=None):
     """Grid (B, lane blocks, Tk/bk, Tq/mq): K/V tile resident, the
     Q/dO/LSE/delta major tile walked in blocks of bq rows, dK/dV carried
     in scratch. Works on the transposed blocks S^T = K Q^T [bk, bq], so
@@ -857,7 +1120,12 @@ def _dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
     members, so their float32 scratch is [Tk/bk, bk, L], a tile a K tile,
     and lives across the members: zeroed by the first, rounded and stored
     once by the last, where the equal-heads form rounds each head's
-    share and leaves the sum to XLA."""
+    share and leaves the sum to XLA.
+
+    `patterns` (PR 63, `_patterns` on the "q" side) gives a Q block that
+    an edge of the mask crosses as cells of `grain` rows of K against the
+    queries that see any of them: each cell its own five products, on the
+    part of dK and dV and the lanes of dQ it touches."""
     import jax.experimental.pallas as pl
 
     if n_k:
@@ -897,33 +1165,58 @@ def _dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
     ks = [_only(mask, kt) for mask in masks]
     vs = [_only(mask, vt) for mask in masks]
     diff = _q_minus_k(bk, bq, block) if causal else None
+    # a part's rows of the head masks (Mosaic slices no mask)
+    part_masks = patterns and _head_masks(grain, k_ref.shape[-1], d, hpb)
 
-    def walked(j, masked):
+    def cells(j, cells):
+        """The Q block at j of the major tile, cell by cell: against the
+        keys `part` of the resident tile (None: all), the queries `rows`
+        of the block (None: all), in strips each under its own predicate.
+        The dK and dV a cell adds to are its part's rows, the statistics
+        it reads and the dQ it adds to its queries' lanes."""
         start = pl.multiple_of(j * bq, bq)
         qb = q_ref[0, pl.ds(start, bq), :]
         dob = do_ref[0, pl.ds(start, bq), :]
-        dvs, dks = [], []
-        for hh in range(hpb):
-            st = _dot(ks[hh], qb, _NT)
-            if not fold:
-                st = st * scale
-            st = _held(st, diff, k_first - q_base - start, masked, window)
-            pt = jnp.exp(st - lse_ref[0, hh, j])
-            dvs.append(_dot(pt.astype(dob.dtype), dob, _NN))
-            dpt = _dot(vs[hh], dob, _NT)
-            dst = (pt * (dpt - dl_ref[0, hh, j])).astype(qb.dtype)
-            dks.append(_dot(dst, qb, _NN))
-            if n_k:
-                rows = slice(hh * d, (hh + 1) * d)
-                at = kk * per + j
-                dq_sc[at, rows, :] = dq_sc[at, rows, :] + _dot(
-                    k_t[rows, :], dst, _NN)
-        dv_sc[...] = dv_sc[...] + _by_head(masks, dvs)
-        dk_sc[...] = dk_sc[...] + _by_head(masks, dks)
+        for part, rows, strips in cells:
+            cols = slice(None) if rows is None else rows
+            stat = (j,) if rows is None else (j, slice(None), cols)
+            qc, doc = _rows(qb, rows), _rows(dob, rows)
+            dvs, dks = [], []
+            for hh in range(hpb):
+                st = _dot(_rows(ks[hh], part), qc, _NT)
+                if not fold:
+                    st = st * scale
+                st = strips(st, start)
+                pt = jnp.exp(st - lse_ref[(0, hh) + stat])
+                dvs.append(_dot(pt.astype(doc.dtype), doc, _NN))
+                dpt = _dot(_rows(vs[hh], part), doc, _NT)
+                dst = (pt * (dpt - dl_ref[(0, hh) + stat])).astype(qc.dtype)
+                dks.append(_dot(dst, qc, _NN))
+                if n_k:
+                    lanes = slice(hh * d, (hh + 1) * d)
+                    keys = slice(None) if part is None else part
+                    at = kk * per + j
+                    dq_sc[at, lanes, cols] = dq_sc[at, lanes, cols] + _dot(
+                        k_t[lanes, keys], dst, _NN)
+            own = ... if part is None else (part, slice(None))
+            by_head = masks if part is None else part_masks
+            dv_sc[own] = dv_sc[own] + _by_head(by_head, dvs)
+            dk_sc[own] = dk_sc[own] + _by_head(by_head, dks)
+
+    def offset(j):
+        return q_base + j * bq - k_first
+
+    def walked(j, kind):
+        cells(j, [(None, None, lambda st, start: _held(
+            st, diff, k_first - q_base - start, kind, window))])
+
+    def sub_tiles(j, pattern):
+        cells(j, _pattern_cells(pattern, 1, grain, block, window))
 
     if causal:
         _walk(*_q_ranges(k_first, q_base, bq, bk, per, block, window),
-              walked, both=_crosses_both(window, bq, bk))
+              walked, both=_crosses_both(window, bq, bk),
+              crossed=_crossed(patterns, offset, sub_tiles))
     else:
         _walk((0, per), None, None, walked)
 
@@ -941,6 +1234,120 @@ def _dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
                     dq_sc[at] * scale).astype(dq_ref.dtype)
                 return carry
             lax.fori_loop(0, n_maj * per, store, 0)
+
+
+def _tiles_walked(kernel: str, tq: int, tk: int, tile, grain: int,
+                  major: int, block: int, window: int, at):
+    """Every block a masked call's walk computes, from the kernel's own
+    geometry on plain ints: (q0, q1, k0, k1, kind, sub), rows [q0, q1) of
+    the call's Q against rows [k0, k1) of its K under the predicate
+    `kind`; `sub` says the block is a sub-tile of a tile an edge crosses
+    (`_patterns`, PR 63) and not a whole tile. `kernel` is the Q-resident
+    walk of flash_fwd and flash_dq, or flash_dkv's K-resident one; `at` =
+    (q_off, k_off), or None for offsets the lowering cannot see (counted
+    at 0, tiles whole)."""
+    side = "q" if kernel == "flash_dkv" else "kv"
+    q_off, k_off = at or (0, 0)
+    if side == "q":
+        b_res, b_walk = _fit(tk, tile[0]), _fit(tq, tile[1])
+        t_res, t_walk, res_off, walk_off = tk, tq, k_off, q_off
+    else:
+        b_res, b_walk = _fit(tq, tile[0]), _fit(tk, tile[1])
+        t_res, t_walk, res_off, walk_off = tq, tk, q_off, k_off
+    m_walk = _major(t_walk, b_walk, major)
+    grain, patterns = _sub_tiles(side, b_res, b_walk, True, grain, block,
+                                 window, at)
+    both = _crosses_both(window, b_res, b_walk)
+
+    def pair(res, walk, kind, sub):
+        return (walk + res if side == "q" else res + walk) + (kind, sub)
+
+    for i in range(t_res // b_res):
+        for kk in range(t_walk // m_walk):
+            first, base = res_off + i * b_res, walk_off + kk * m_walk
+            per = m_walk // b_walk
+            full, masked, edge = (
+                _q_ranges(first, base, b_walk, b_res, per, block, window)
+                if side == "q" else
+                _kv_ranges(first, base, b_res, b_walk, per, True, block,
+                           window))
+            for bounds, kind in ((full, _OPEN),
+                                 (masked, _DIAG | _EDGE if both else _DIAG),
+                                 (edge or (0, 0), _EDGE)):
+                for j in range(*bounds):
+                    r0, w0 = i * b_res, kk * m_walk + j * b_walk
+                    if kind == _OPEN or not patterns:
+                        yield pair((r0, r0 + b_res), (w0, w0 + b_walk), kind,
+                                   False)
+                        continue
+                    # a crossed tile's d is one of the patterns'
+                    for part, rows, strips in patterns[
+                            base + j * b_walk - first][1]:
+                        for n, (kind_n, _) in enumerate(strips):
+                            lo = w0 + rows.start + n * grain
+                            yield pair((r0 + part.start, r0 + part.stop),
+                                       (lo, lo + grain), kind_n, True)
+
+
+def edge_subtiles(kernel: str, tq: int, tk: int, tile=_TILE, grain=None,
+                  major: int = _MAJOR, block: int = 1, window: int = 0,
+                  at=(0, 0)):
+    """What a causal call of `kernel` (flash_fwd | flash_dq | flash_dkv)
+    walks, a lane block of one batch row, counted ahead of its lowering
+    from the geometry the kernel itself runs on (`_tiles_walked`):
+
+    - `grain`, "K rows x Q rows" of the blocks that the tiles an edge of
+      the mask crosses are computed in (the tile's where they are walked
+      whole: `_sub_tiles`);
+    - `dead`, `open`, `held`: such blocks by state: not computed, computed
+      with no predicate, computed under one;
+    - `walked_pairs`, the (query, key) pairs of every block computed;
+      `tile_pairs`, what whole tiles would walk (the walk of before PR
+      63); `live_pairs`, those the mask keeps."""
+    import numpy as np
+    swap = kernel == "flash_dkv"
+    b_q, b_k = (_fit(tq, tile[1 if swap else 0]),
+                _fit(tk, tile[0 if swap else 1]))
+    count = dict(dead=0, open=0, held=0)
+    walked, crossed, g_q, g_k = 0, set(), b_q, b_k
+    for q0, q1, k0, k1, kind, sub in _tiles_walked(
+            kernel, tq, tk, tile, grain, major, block, window, at):
+        walked += (q1 - q0) * (k1 - k0)
+        if sub or kind != _OPEN:
+            count["held" if kind != _OPEN else "open"] += 1
+            g_q, g_k = q1 - q0, k1 - k0
+            crossed.add((q0 // b_q, k0 // b_k))
+    count["dead"] = len(crossed) * (b_q // g_q) * (b_k // g_k) \
+        - count["open"] - count["held"]
+    q_off, k_off = at or (0, 0)
+    q = q_off + np.arange(tq, dtype=np.int64)
+    last = np.minimum(q // block * block + block - 1, k_off + tk - 1)
+    first = np.maximum(q - window + 1 if window else k_off, k_off)
+    return dict(
+        count, grain=f"{g_k}x{g_q}", walked_pairs=walked,
+        tile_pairs=walked + count["dead"] * g_q * g_k,
+        live_pairs=int(np.maximum(last - first + 1, 0).sum()))
+
+
+def count_edge_subtiles(kernel: str, q, k, tile, major: int, block: int,
+                        window: int, at):
+    """flash_edge_subtiles_total{kernel, mask, grain, state}: once a
+    lowering of a masked flash call, the blocks of `edge_subtiles` a lane
+    block of one batch row. `at` = `_static`'s: offsets that are traced
+    values (a ring shard's) count as 0, their tiles walked whole."""
+    from .. import telemetry
+    got = edge_subtiles(kernel, q.shape[1], k.shape[1], tile, None, major,
+                        block, window, at)
+    series = telemetry.counter(
+        "flash_edge_subtiles_total",
+        "blocks in which a masked flash call visits the tiles an edge of "
+        "its mask crosses, a lane block and batch row, by state: dead "
+        "(not computed), open (no predicate) or held (under one)",
+        labels=("kernel", "mask", "grain", "state"))
+    mask = "window" if window else "block" if block > 1 else "causal"
+    for state in ("dead", "open", "held"):
+        series.labels(kernel=kernel, mask=mask, grain=got["grain"],
+                      state=state).inc(got[state])
 
 
 # Every ground `_split_reason` can give for the two-call backward.
@@ -1015,17 +1422,23 @@ def flash_attention_bwd_block(q, k, v, do, lse, delta, q_off, k_off, scale,
                            jnp.dtype(q.dtype).itemsize, dkv_tile, major,
                            groups=q.shape[2] // k.shape[2])
     count_backward(reason)
+    at = _static(q_off, k_off)
+    if causal:
+        for kernel, tile in (("flash_dq", dq_tile),) * bool(reason) + (
+                ("flash_dkv", dkv_tile),):
+            count_edge_subtiles(kernel, q, k, tile, major, block, window, at)
     return _bwd_call(q, k, v, do, lse, delta, q_off, k_off, float(scale),
                      causal, dq_tile, dkv_tile, major, block,
-                     fused=reason is None, window=window)
+                     fused=reason is None, window=window, at=at)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "causal", "dq_tile",
                                              "dkv_tile", "major", "block",
-                                             "fused", "window", "summed"))
+                                             "fused", "window", "summed",
+                                             "grain", "at"))
 def _bwd_call(q, k, v, do, lse, delta, q_off, k_off, scale, causal,
               dq_tile=_TILE, dkv_tile=_TILE, major=_MAJOR, block=1,
-              fused=True, window=0, summed=True):
+              fused=True, window=0, summed=True, grain=None, at=None):
     """flash_attention_bwd_block's kernels in the form it chose (the
     sweep and the tests ask for either). k and v are [B,Tk,H_kv,D] and
     so are the dK and dV returned: every kernel reads the K/V head of its
@@ -1051,6 +1464,10 @@ def _bwd_call(q, k, v, do, lse, delta, q_off, k_off, scale, causal,
     statics = dict(d=d, hpb=hpb, scale=float(scale), causal=causal,
                    block=block, window=window)
 
+    def sub_tiles(side, b_res, b_walk):
+        return dict(zip(("grain", "patterns"), _sub_tiles(
+            side, b_res, b_walk, causal, grain, block, window, at)))
+
     def stat(x, rows):
         return x.reshape(b, h, tq // rows, 1, rows)
 
@@ -1064,7 +1481,7 @@ def _bwd_call(q, k, v, do, lse, delta, q_off, k_off, scale, causal,
                     groups=groups)
         dq = _call(
             functools.partial(_dq_kernel, bq=bq, bk=bk, mk=mk, n_maj=n_maj,
-                              **statics),
+                              **sub_tiles("kv", bq, bk), **statics),
             "flash_dq", (b, h * d // lanes, tq // bq, n_maj),
             [sp.res, sp.walk_kv, sp.walk_kv, sp.res, sp.res_stat,
              sp.res_stat], sp.res,
@@ -1109,7 +1526,8 @@ def _bwd_call(q, k, v, do, lse, delta, q_off, k_off, scale, causal,
     outs = _call(
         functools.partial(_dkv_kernel, bq=bq, bk=bk, mq=mq, n_maj=n_maj,
                           n_k=n_k if fused else 0,
-                          groups=groups if members else 0, **statics),
+                          groups=groups if members else 0,
+                          **sub_tiles("q", bk, bq), **statics),
         "flash_dkv", grid,
         [sp.walk, sp.res_kv, sp.res_kv, sp.walk, sp.walk_stat,
          sp.walk_stat], out_specs, out_shape,

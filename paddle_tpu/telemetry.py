@@ -931,6 +931,11 @@ METRIC_CATALOG = {
     "flash_backward_total": _m("counter", ("form", "reason"),
                                "flash attention backward lowerings, fused "
                                "(one kernel) or split by a shape ground"),
+    "flash_edge_subtiles_total": _m(
+        "counter", ("kernel", "mask", "grain", "state"),
+        "blocks in which a masked flash call visits the tiles an edge of "
+        "its mask crosses, by state: dead (not computed), open (no "
+        "predicate) or held (under one); once a lowering"),
     "attention_window_total": _m("counter", ("window",),
                                  "forward attention lowerings under a "
                                  "sliding window, by its keys"),

@@ -340,11 +340,189 @@ class TestKVHeads:
             groups=groups) == reason
 
 
+# (id, block, window, q_off, k_off, Tq, Tk): the masks whose edges the
+# kernels walk in sub-tiles (PR 63), at the tiles they have (512 rows, so
+# the sequences are two tiles and more): the diagonal alone; a window of
+# one tile (no tile is open), of eight, and one that is no multiple of
+# the grain; block-diffusion attention's two kernel parts; a ring shard
+# whose keys start at a multiple of 128 that is no multiple of the tile
+EDGES = [("causal", 1, 0, 0, 0, 1024, 1024),
+         ("window512", 1, 512, 0, 0, 1536, 1536),
+         ("window4096", 1, 4096, 0, 0, 4608, 4608),
+         ("window384", 1, 384, 0, 0, 1536, 1536),
+         ("block4", 4, 0, 0, 0, 1024, 1024),
+         ("block4_earlier", 4, 0, -4, 0, 1024, 1024),
+         ("ring_shard", 1, 0, 640, 384, 512, 1024)]
+# (id, H, K/V heads, D)
+EDGE_HEADS = [("two_heads_a_lane_block", 2, 2, 64), ("groups8", 8, 1, 128),
+              ("one_head_of_256_lanes", 1, 1, 256)]
+
+
+def _kept(tq, tk, q_off, k_off, block, window):
+    """The mask on positions, [Tq, Tk]."""
+    q_pos = np.arange(tq)[:, None] + q_off
+    k_pos = np.arange(tk)[None, :] + k_off
+    keep = q_pos // block >= k_pos // block
+    return keep & (q_pos - k_pos < window) if window else keep
+
+
+def _dense_by_head(q, k, v, do, keep):
+    """Dense float32 attention under `keep`, one [Tq, Tk] square of a
+    query head at a time against K/V head j // groups: (out, lse, (dq, dk,
+    dv)), dK and dV summed over each group. A row that sees no key gives
+    an output of 0 and an LSE of -inf and sends nothing back."""
+    scale = q.shape[-1] ** -0.5
+    groups = q.shape[2] // k.shape[2]
+    seen = jnp.asarray(keep.any(-1))[:, None]
+
+    @jax.jit
+    def head(q, k, v, do):
+        def attend(q, k, v):
+            s = q @ k.T * scale
+            out = jax.nn.softmax(jnp.where(keep, s, -1e30), -1) @ v
+            return jnp.where(seen, out, 0.0), jax.scipy.special.logsumexp(
+                jnp.where(keep, s, -jnp.inf), axis=-1)
+        out, vjp, lse = jax.vjp(attend, q, k, v, has_aux=True)
+        return (out, lse) + vjp(do * seen)
+
+    heads = [head(q[0, :, j], k[0, :, j // groups], v[0, :, j // groups],
+                  do[0, :, j]) for j in range(q.shape[2])]
+    out, lse, dq, dk, dv = (jnp.stack(x, 1) for x in zip(*heads))
+    dk, dv = (x.reshape(x.shape[0], -1, groups, x.shape[-1]).sum(2)
+              for x in (dk, dv))
+    return out[None], lse.T[None], (dq[None], dk[None], dv[None])
+
+
+@pytest.mark.parametrize("name,h,kv,d", EDGE_HEADS,
+                         ids=[c[0] for c in EDGE_HEADS])
+@pytest.mark.parametrize("mask,block,window,q_off,k_off,tq,tk", EDGES,
+                         ids=[c[0] for c in EDGES])
+def test_edges_walked_in_subtiles_match_dense(mask, block, window, q_off,
+                                              k_off, tq, tk, name, h, kv, d):
+    """All three kernels and both backward forms at the tiles and the
+    grain they have, where a crossed tile's dead sub-tiles are skipped and
+    its open ones carry no predicate: outputs, LSE and the three
+    gradients against dense float32 attention on positions."""
+    rng = np.random.default_rng(tq + window + block + d)
+    q, do = (jnp.asarray(rng.standard_normal((1, tq, h, d)), jnp.float32)
+             for _ in range(2))
+    k, v = (jnp.asarray(rng.standard_normal((1, tk, kv, d)), jnp.float32)
+            for _ in range(2))
+    for kernel in ("flash_fwd", "flash_dkv"):
+        walk = pallas_attention.edge_subtiles(
+            kernel, tq, tk, block=block, window=window, at=(q_off, k_off))
+        assert walk["dead"] > 0 and walk["held"] > 0, walk
+    keep = _kept(tq, tk, q_off, k_off, block, window)
+    seen = keep.any(-1)
+    with jax.default_matmul_precision("highest"):
+        want, want_lse, want_grads = _dense_by_head(q, k, v, do, keep)
+        out, stats = pallas_attention._fwd_call(
+            q, k, v, q_off, k_off, d ** -0.5, True, normalize=seen.all(),
+            block=block, window=window, at=(q_off, k_off))
+        if seen.all():
+            lse, = stats
+        else:       # one part of an attention: the raw (acc, m, l)
+            m, l = stats
+            out = out / l.transpose(0, 2, 1)[..., None]
+            lse = m + jnp.log(l)
+        np.testing.assert_allclose(np.asarray(out)[:, seen],
+                                   np.asarray(want)[:, seen],
+                                   rtol=2e-5, atol=2e-5)
+        np.testing.assert_allclose(np.asarray(lse)[..., seen],
+                                   np.asarray(want_lse)[..., seen],
+                                   rtol=2e-5, atol=2e-5)
+        delta = jnp.sum(do * want, axis=-1).transpose(0, 2, 1)
+        for fused in (True, False):
+            grads = pallas_attention._bwd_call(
+                q, k, v, do * seen[None, :, None, None], want_lse, delta,
+                q_off, k_off, d ** -0.5, True, block=block, window=window,
+                fused=fused, at=(q_off, k_off))
+            for got, ref in zip(grads, want_grads):
+                assert got.shape == ref.shape
+                # under one key dQ and dK are zero: held to the
+                # cotangent's size
+                assert float(jnp.abs(got - ref).max()) <= 1e-4 * max(
+                    1.0, float(jnp.abs(ref).max())), (fused, got.shape)
+
+
+# (block, window, q_off, k_off, Tq, Tk, tile): the masks of EDGES on
+# smaller sequences, windows that fall inside one sub-tile and across
+# three, a shard at odd multiples of 128, and tiles of unequal sides
+WALKS = [(1, 0, 0, 0, 1024, 1024, (512, 512)),
+         (1, 512, 0, 0, 1536, 1536, (512, 512)),
+         (1, 384, 0, 0, 1536, 1536, (512, 512)),
+         (1, 100, 0, 0, 1024, 1024, (512, 512)),
+         (1, 700, 0, 0, 2048, 2048, (512, 512)),
+         (1, 1536, 0, 0, 2560, 2560, (512, 512)),
+         (4, 0, 0, 0, 1024, 1024, (512, 512)),
+         (4, 0, -4, 0, 1024, 1024, (512, 512)),
+         (128, 0, -128, 0, 1024, 1024, (512, 512)),
+         (1, 0, 640, 384, 512, 1024, (512, 512)),
+         (1, 0, 128, 896, 1024, 512, (512, 512)),
+         (1, 640, 0, 0, 2048, 2048, (512, 256)),
+         (1, 0, 0, 0, 1024, 1024, (256, 512))]
+
+
+@pytest.mark.parametrize("grain", [128, 256])
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_dkv"])
+@pytest.mark.parametrize("block,window,q_off,k_off,tq,tk,tile", WALKS, ids=[
+    "-".join(map(str, w[:6])) + "-" + "x".join(map(str, w[6]))
+    for w in WALKS])
+def test_the_classifier_by_brute_force(block, window, q_off, k_off, tq, tk,
+                                       tile, kernel, grain):
+    """Every pair the mask keeps lies in exactly one block the walk
+    visits; a block visited with no predicate holds kept pairs alone; a
+    held block's predicate, applied to its pairs, is the mask (the edge
+    it does not test drops none of them); every visited block holds a
+    kept pair (the dead ones are in no range); and `edge_subtiles` counts
+    those blocks. Two major tiles, so that a range is cut by one."""
+    from paddle_tpu.ops.pallas_attention import _DIAG, _EDGE, _OPEN
+    major = 1024
+    keep = _kept(tq, tk, q_off, k_off, block, window)
+    q_pos = np.arange(tq)[:, None] + q_off
+    k_pos = np.arange(tk)[None, :] + k_off
+    diag = q_pos // block >= k_pos // block
+    edge = q_pos - k_pos < window if window else np.ones_like(keep)
+    visits = np.zeros(keep.shape, np.int32)
+    count = dict(open=0, held=0)
+    for q0, q1, k0, k1, kind, sub in pallas_attention._tiles_walked(
+            kernel, tq, tk, tile, grain, major, block, window,
+            (q_off, k_off)):
+        at = np.s_[q0:q1, k0:k1]
+        visits[at] += 1
+        assert keep[at].any(), (q0, k0, kind)
+        if kind == _OPEN:
+            assert keep[at].all(), (q0, k0)
+        held = np.ones_like(keep[at])
+        if kind & _DIAG:
+            held &= diag[at]
+        if kind & _EDGE:
+            held &= edge[at]
+        np.testing.assert_array_equal(held, keep[at])
+        if sub or kind != _OPEN:
+            count["held" if kind != _OPEN else "open"] += 1
+    assert visits.max() == 1 and (visits[keep] == 1).all()
+    got = pallas_attention.edge_subtiles(
+        kernel, tq, tk, tile, grain, major, block, window, (q_off, k_off))
+    assert {k: got[k] for k in count} == count
+    assert got["walked_pairs"] == int(visits.sum())
+    assert got["live_pairs"] == int(keep.sum())
+    # a crossed tile's blocks are dead, open or held, and whole tiles
+    # walk all of them
+    assert got["tile_pairs"] >= got["walked_pairs"] >= got["live_pairs"]
+    bq, bk = (int(x) for x in got["grain"].split("x")[::-1])
+    assert (got["tile_pairs"] - got["walked_pairs"]) == got["dead"] * bq * bk
+
+
 # sha256 (16 hex digits) of str(jax.make_jaxpr(call)) for the kernels'
 # calls at equal head counts, taken from the tree BEFORE PR 61
 # (ops/pallas_attention.py of commit 1853025): grid, BlockSpecs with their
 # index maps, scratch and kernel body are in that string. (call, shape,
-# dtype, static arguments)
+# dtype, static arguments). The causal calls are asked for without `at`,
+# as a ring shard's are (offsets the lowering cannot see): whole tiles,
+# the walk of before PR 63. The calls with no mask (`causal=False`) were
+# taken from the tree before PR 63 (commit 80fa22e), and are asked for
+# with the offsets known: no mask, so no sub-tile.
 PARENTS_CALLS = [
     ("fwd", (1, 256, 12, 64), "float32", {}, "0d8319c853a45065"),
     ("fwd", (1, 1024, 2, 128), "bfloat16", {}, "c8ecd8c84e1f5b46"),
@@ -360,6 +538,44 @@ PARENTS_CALLS = [
     ("bwd", (1, 1024, 2, 128), "bfloat16",
      dict(fused=True, window=256, major=512), "1a89a86a1ae0f8ac"),
     ("bwd", (1, 512, 1, 256), "bfloat16", dict(fused=True), "bc613535c5255d9a"),
+    ("fwd", (1, 1024, 12, 64), "bfloat16", dict(causal=False, at=(0, 0)),
+     "0a1e848c0afabdd6"),
+    ("fwd", (1, 1024, 2, 128), "bfloat16", dict(causal=False, at=(0, 0)),
+     "e36cca13af12a037"),
+    ("fwd", (1, 4096, 2, 128), "bfloat16",
+     dict(causal=False, normalize=False, at=(0, 0)), "183e9690613fbea9"),
+    ("fwd", (1, 512, 1, 256), "bfloat16", dict(causal=False, at=(0, 0)),
+     "18f0263fd5cd72cf"),
+    ("bwd", (1, 1024, 12, 64), "bfloat16",
+     dict(causal=False, fused=True, at=(0, 0)), "e31f3390f750e596"),
+    ("bwd", (1, 1024, 2, 128), "bfloat16",
+     dict(causal=False, fused=True, at=(0, 0)), "815c41a394a78152"),
+    ("bwd", (1, 1024, 2, 128), "bfloat16",
+     dict(causal=False, fused=False, at=(0, 0)), "675c33f4cc12ba28"),
+    ("bwd", (1, 4096, 2, 128), "bfloat16",
+     dict(causal=False, fused=True, at=(0, 0)), "d4b8ea3d50c5c05c"),
+    ("bwd", (1, 512, 1, 256), "bfloat16",
+     dict(causal=False, fused=True, at=(0, 0)), "798983b32c7ecf15"),
+]
+# The causal calls as the attention ops make them since PR 63, the
+# offsets told as plain ints: re-taken, so that a change to the sub-tiled
+# walk shows as one. (1, 256, 12, 64): a tile no larger than the grain is
+# the call of before.
+SUBTILED_CALLS = [
+    ("fwd", (1, 1024, 2, 128), "bfloat16", {}, "19bfbd00190d11f0"),
+    ("fwd", (1, 512, 2, 128), "bfloat16",
+     dict(block=4, normalize=False, at=(-4, 0)), "6bfe03cd6229a5d0"),
+    ("fwd", (1, 1024, 2, 128), "bfloat16", dict(window=256, major=512),
+     "d611cc237e4fd127"),
+    ("fwd", (1, 512, 1, 256), "bfloat16", {}, "ed567a75bc1a8403"),
+    ("bwd", (1, 1024, 2, 128), "bfloat16", dict(fused=True), "725dd67c59364a5d"),
+    ("bwd", (1, 1024, 2, 128), "bfloat16", dict(fused=False), "1674eb5d91392e69"),
+    ("bwd", (1, 512, 2, 128), "bfloat16", dict(fused=True, block=4), "5b4ef9a549504f79"),
+    ("bwd", (1, 1024, 2, 128), "bfloat16",
+     dict(fused=True, window=256, major=512), "82bbf0f402e1122e"),
+    ("bwd", (1, 512, 1, 256), "bfloat16", dict(fused=True), "2e3a2b1e21c2c4ac"),
+    ("fwd", (1, 1024, 12, 64), "bfloat16", {}, "faa943bf7ceecc66"),
+    ("bwd", (1, 1024, 12, 64), "bfloat16", dict(fused=True), "0039c71179522978"),
 ]
 
 
@@ -369,13 +585,15 @@ def call_digest(module, call, shape, dtype, statics):
     b, t, h, d = shape
     x = jax.ShapeDtypeStruct(shape, dtype)
     stat = jax.ShapeDtypeStruct((b, h, t), jnp.float32)
+    statics = dict(statics)
+    causal = statics.pop("causal", True)
     if call == "fwd":
         statics = dict(dict(normalize=True), **statics)
         jaxpr = jax.make_jaxpr(lambda q, k, v: module._fwd_call(
-            q, k, v, 0, 0, d ** -0.5, True, **statics))(x, x, x)
+            q, k, v, 0, 0, d ** -0.5, causal, **statics))(x, x, x)
     else:
         jaxpr = jax.make_jaxpr(lambda q, k, v, do, lse, dl: module._bwd_call(
-            q, k, v, do, lse, dl, 0, 0, d ** -0.5, True, **statics))(
+            q, k, v, do, lse, dl, 0, 0, d ** -0.5, causal, **statics))(
                 x, x, x, x, stat, stat)
     text = [str(jaxpr)]
 
@@ -393,17 +611,70 @@ def call_digest(module, call, shape, dtype, statics):
     return hashlib.sha256("\n".join(text).encode()).hexdigest()[:16]
 
 
+def _call_ids(calls):
+    return [f"{c[0]}-{'x'.join(map(str, c[1]))}-" + "-".join(
+        f"{k}{v}" for k, v in c[3].items()) for c in calls]
+
+
 @pytest.mark.parametrize("call,shape,dtype,statics,digest", PARENTS_CALLS,
-                         ids=[f"{c[0]}-{'x'.join(map(str, c[1]))}-" + "-".join(
-                             f"{k}{v}" for k, v in c[3].items())
-                             for c in PARENTS_CALLS])
+                         ids=_call_ids(PARENTS_CALLS))
 def test_equal_head_counts_lower_to_the_calls_of_before(call, shape, dtype,
                                                         statics, digest):
-    """With groups == 1 every kernel call is the one of before PR 61:
-    GPT-2's, GPT-2 large's, the latent cells' and Ouro's steps compile
-    to what they compiled to."""
+    """With groups == 1 every kernel call is the one of before PR 61, and
+    since PR 63 wherever no sub-tile is built: a masked call whose offsets
+    are traced values, and a call with no mask whatever it is told."""
     assert call_digest(pallas_attention, call, shape, dtype,
                        statics) == digest
+
+
+@pytest.mark.parametrize("call,shape,dtype,statics,digest", SUBTILED_CALLS,
+                         ids=_call_ids(SUBTILED_CALLS))
+def test_masked_calls_walk_their_edges_in_subtiles(call, shape, dtype,
+                                                   statics, digest):
+    told = dict(dict(at=(0, 0)), **statics)
+    got = call_digest(pallas_attention, call, shape, dtype, told)
+    assert got == digest
+    untold = {k: v for k, v in told.items() if k != "at"}
+    assert got != call_digest(pallas_attention, call, shape, dtype, untold)
+
+
+def test_a_masked_lowering_books_its_subtiles_and_an_unmasked_one_nothing():
+    """flash_edge_subtiles_total{kernel, mask, grain, state}: once a
+    lowering, the blocks of `edge_subtiles` a lane block of one batch
+    row; a traced offset (a ring shard's) counts as 0."""
+    from paddle_tpu import telemetry
+
+    def booked():
+        return dict(telemetry.read_series("flash_edge_subtiles_total"))
+
+    def added(before):
+        return {k: v - before.get(k, 0) for k, v in booked().items()
+                if v != before.get(k, 0)}
+
+    x = jax.ShapeDtypeStruct((1, 1024, 2, 128), jnp.bfloat16)
+    stat = jax.ShapeDtypeStruct((1, 2, 1024), jnp.float32)
+    before = booked()
+    jax.eval_shape(lambda q: pallas_attention._forward(q, q, q, False), x)
+    jax.eval_shape(lambda q, s: pallas_attention.flash_attention_bwd_block(
+        q, q, q, q, s, s, 0, 0, 0.125, False), x, stat)
+    assert added(before) == {}
+    jax.eval_shape(lambda q: pallas_attention._forward(q, q, q, True,
+                                                       window=512), x)
+    want = pallas_attention.edge_subtiles("flash_fwd", 1024, 1024,
+                                          window=512)
+    assert want["dead"] > 0
+    assert added(before) == {
+        f"kernel=flash_fwd,mask=window,grain={want['grain']},state={state}":
+        want[state] for state in ("dead", "open", "held")}
+    before = booked()
+    jax.eval_shape(lambda q, s, off: pallas_attention.flash_attention_bwd_block(
+        q, q, q, q, s, s, off, 0, 0.125, True, block=4), x, stat,
+        jax.ShapeDtypeStruct((), jnp.int32))
+    want = pallas_attention.edge_subtiles("flash_dkv", 1024, 1024, block=4,
+                                          at=None)
+    assert want["dead"] == want["open"] == 0 and want["grain"] == "512x512"
+    assert added(before) == {
+        f"kernel=flash_dkv,mask=block,grain=512x512,state=held": want["held"]}
 
 
 class TestFusedBackward:

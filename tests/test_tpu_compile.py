@@ -166,6 +166,27 @@ def test_bottleneck_block_is_xla_alone(mosaic, one_chip):
     assert not moved, moved
 
 
+def _dead_subtiles_booked():
+    """flash_edge_subtiles_total's dead sub-tiles so far, by kernel: what
+    a lowering's walk leaves uncomputed of the tiles an edge crosses."""
+    from paddle_tpu import telemetry
+    dead = {}
+    for series, n in telemetry.read_series(
+            "flash_edge_subtiles_total").items():
+        labels = dict(pair.split("=") for pair in series.split(","))
+        if labels["state"] == "dead":
+            dead[labels["kernel"]] = dead.get(labels["kernel"], 0) + n
+    return dead
+
+
+def _walks_in_subtiles(before):
+    """Both kernels of a forward and a fused backward lowered since
+    `before` skip dead sub-tiles (PR 63)."""
+    now = _dead_subtiles_booked()
+    return all(now.get(kernel, 0) > before.get(kernel, 0)
+               for kernel in ("flash_fwd", "flash_dkv"))
+
+
 def _flash_fwd_bwd(q, k, v):
     def loss(q, k, v):
         out = pallas_attention.flash_attention(q, k, v, True)
@@ -187,10 +208,15 @@ def _flash_fwd_bwd(q, k, v):
                               "nemotron_h_after_kv_repeat",
                               "latent_attention_expanded"])
 def test_flash_fwd_bwd_compiles(mosaic, one_chip, shape):
+    """Causal, so since PR 63 the diagonal's tiles are walked in
+    sub-tiles: GPT-2's two heads a lane block and the latent cell's head
+    of 256 lanes among them."""
     q = jax.ShapeDtypeStruct(shape, BF16)
     assert pallas_attention.ineligible(q, q, q) is None
+    before = _dead_subtiles_booked()
     assert _compile(_flash_fwd_bwd, one_chip, *[(shape, BF16)] * 3) == [
         "flash_dkv", "flash_fwd"]
+    assert _walks_in_subtiles(before)
 
 
 def _flash_bwd(q, k, v, do, lse, delta):
@@ -291,12 +317,16 @@ def test_flash_kernels_read_kv_at_their_own_heads(mosaic, one_chip, t, heads,
         *(jax.ShapeDtypeStruct(s, BF16) for s in (q, kv, kv)),
         block=block) is None
     before = dict(telemetry.read_series("flash_backward_total"))
+    dead = _dead_subtiles_booked()
     assert _compile(
         functools.partial(_masked_fwd_bwd, window, block, q_off), one_chip,
         (q, BF16), (kv, BF16), (kv, BF16)) == ["flash_dkv", "flash_fwd"]
     fused = "form=fused,reason="
     assert dict(telemetry.read_series("flash_backward_total")) == dict(
         before, **{fused: before.get(fused, 0) + 1})
+    # Laguna's window of one tile, where no tile is open, and the noisy
+    # stream's q_off = -block among them: the edges walked in sub-tiles
+    assert _walks_in_subtiles(dead)
 
 
 def _flash_calls(text):

@@ -2,8 +2,8 @@
 sweep, and jax's bundled Pallas flash attention as an outside yardstick.
 
     chiprun -- python3 tools/flash_sweep.py [B T H D] [--parent DIR]
-        [--rows 256,512] [--major 4096] [--block 4 [--q-off -4]]
-        [--window 4096]
+        [--rows 256,512] [--grain 128,256,512] [--major 4096]
+        [--block 4 [--q-off -4]] [--window 4096]
 
 Times the forward and forward+backward (jax.vjp on a random cotangent)
 of causal bf16 attention at one [B, T, H, D]; `_TILE` and the selection
@@ -11,9 +11,17 @@ rule in ops/nn_ops.py::_flash_wins are written from this table (PERF.md
 section 6, PR 29: [16, 1024, 12, 64]; PR 33: [1, 4096, 20, 256], the
 head size of latent attention's expanded heads; PR 43: those two,
 [1, 4096, 32, 128] and the last under `--block 4` at both offsets).
-`--rows` limits the tile rows swept, `--major` sets the rows of the
+`--rows` limits the tile rows swept, `--grain` gives the rows a side of
+the sub-tiles a crossed tile is visited in (pallas_attention._GRAIN is
+written from it, PR 63: a grain of 512 is the whole-tile walk of before;
+each reading of a masked kernel carries `walked_over_live`, the pairs it
+computes over the pairs the mask keeps, from
+pallas_attention.edge_subtiles), `--major` sets the rows of the
 walked side that stay in VMEM at once (pallas_attention._MAJOR) for the
-per-kernel sweep. The backward is timed in both forms: `fused` (one
+per-kernel sweep. A kernel alone is timed as sixteen calls chained in one
+executable (each call's offset waits on the one before; the kernels are
+told the offsets as plain ints beside it, as the attention ops tell
+them). The backward is timed in both forms: `fused` (one
 K/V-resident call that also accumulates dQ) beside `dq` and `dkv`, the
 split form's two calls, each alone. `--block N` masks at the grain of N
 positions, `--window N` gives the causal mask a far edge of N keys (the
@@ -59,6 +67,7 @@ from paddle_tpu.parallel.ring_attention import attention_reference
 
 ROWS = (128, 256, 512, 1024)
 OUT = "chiprun_out/flash_sweep.jsonl"
+CHAIN = 16
 
 
 def bench(fn_, *args, iters=20, reps=3):
@@ -72,6 +81,27 @@ def bench(fn_, *args, iters=20, reps=3):
         jax.block_until_ready(out)
         best = min(best, (time.perf_counter() - t0) / iters)
     return best * 1e3
+
+
+def chained(fn, q_off):
+    """ms a call of fn(q_off, *args), `CHAIN` calls in one executable:
+    each call's query offset is the given one plus a zero that hangs on
+    the call before."""
+    def run(*args):
+        def body(_, seen):
+            out = fn(q_off + (seen > 1e30).astype(jnp.int32), *args)
+            return jax.tree.leaves(out)[0].ravel()[0].astype(jnp.float32)
+        return jax.lax.fori_loop(0, CHAIN, body, jnp.float32(0))
+    return lambda *args: bench(run, *args, iters=4) / CHAIN
+
+
+def walked_over_live(kernel, ns, tile, grain):
+    """Pairs a masked kernel computes over pairs the mask keeps."""
+    t = ns.shape[1]
+    walk = pa.edge_subtiles(
+        {"fwd": "flash_fwd", "dq": "flash_dq"}.get(kernel, "flash_dkv"), t,
+        t, tile, grain, ns.major, ns.block, ns.window, (ns.q_off, 0))
+    return round(walk["walked_pairs"] / walk["live_pairs"], 4)
 
 
 def fwd_bwd(attn):
@@ -124,12 +154,13 @@ def grouped(ns, log, base, operands):
     scale = 1.0 / d ** 0.5
     q, do = operands(h)[:2]
     k, v = operands(ns.kv_heads)[:2]
-    mask = dict(major=ns.major, block=ns.block, window=ns.window)
+    mask = dict(major=ns.major, block=ns.block, window=ns.window,
+                grain=ns.grains[0], at=(ns.q_off, 0))
 
-    def forward(q, k, v):
+    def forward(q, k, v, q_off=ns.q_off):
         """(Out, LSE) as the op's forward (block 1) or as one part of
         block-diffusion attention, whose raw (acc, m, l) the op merges."""
-        out, stats = pa._fwd_call(q, k, v, ns.q_off, 0, scale, True,
+        out, stats = pa._fwd_call(q, k, v, q_off, 0, scale, True,
                                   normalize=ns.block == 1, tile=pa._TILE,
                                   **mask)
         if ns.block == 1:
@@ -140,30 +171,32 @@ def grouped(ns, log, base, operands):
                         out / l.transpose(0, 2, 1)[..., None], 0.0)
         return out.astype(q.dtype), jnp.where(seen, m + jnp.log(l), 1e30)
 
-    def backward(q, k, v, do, out, lse, **form):
+    def backward(q, k, v, do, out, lse, q_off=ns.q_off, **form):
         delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                         axis=-1).transpose(0, 2, 1)
-        return pa._bwd_call(q, k, v, do, lse, delta, ns.q_off, 0, scale,
+        return pa._bwd_call(q, k, v, do, lse, delta, q_off, 0, scale,
                             True, **form, **mask)
 
     # the repeated form is the attention op's own, with its helpers
     widen = functools.partial(nn_ops._repeat_kv, groups=groups)
     summed = functools.partial(nn_ops._sum_kv_groups, groups=groups)
 
-    def repeated_fwd(q, k, v):
-        return forward(q, widen(k), widen(v))
+    def repeated_fwd(q, k, v, **at):
+        return forward(q, widen(k), widen(v), **at)
 
-    def repeated_bwd(q, k, v, do, out, lse):
-        dq, dk, dv = backward(q, widen(k), widen(v), do, out, lse)
+    def repeated_bwd(q, k, v, do, out, lse, **at):
+        dq, dk, dv = backward(q, widen(k), widen(v), do, out, lse, **at)
         return dq, summed(dk), summed(dv)
 
     forms = {
         "repeated": (repeated_fwd, repeated_bwd,
-                     lambda *a: backward(*a)[1:], (widen(k), widen(v))),
-        "kernel": (forward, backward, lambda *a: backward(*a)[1:], (k, v)),
+                     lambda *a, **at: backward(*a, **at)[1:],
+                     (widen(k), widen(v))),
+        "kernel": (forward, backward,
+                   lambda *a, **at: backward(*a, **at)[1:], (k, v)),
         "kernel_per_head": (
             forward, functools.partial(backward, summed=False),
-            lambda *a: backward(*a, summed=False)[1:], (k, v)),
+            lambda *a, **at: backward(*a, summed=False, **at)[1:], (k, v)),
     }
     dense = dense_head(scale, ns.block, ns.q_off, ns.window)
     want = [jnp.stack([jnp.stack(x, 1) for x in zip(*(
@@ -179,17 +212,22 @@ def grouped(ns, log, base, operands):
     for name in filter(None, ns.forms.split(",")):
         fwd, bwd, dkv, kv = forms[name]
 
-        def both(q, k, v, do):
-            out, lse = fwd(q, k, v)
-            return (out,) + tuple(bwd(q, k, v, do, out, lse))
+        def both(q_off, q, k, v, do):
+            out, lse = fwd(q, k, v, q_off=q_off)
+            return (out,) + tuple(bwd(q, k, v, do, out, lse, q_off=q_off))
 
-        got = jax.jit(both)(q, k, v, do)
+        def at(fn):
+            return lambda q_off, *a: fn(*a, q_off=q_off)
+
+        got = jax.jit(functools.partial(both, ns.q_off))(q, k, v, do)
         report(log, **base, kv_heads=ns.kv_heads, path="flash", form=name,
-               backward="split" if reason else "fused",
-               fwd_ms=bench(lambda *a: fwd(*a)[0], q, k, v),
-               bwd_ms=bench(bwd, q, k, v, do, out, lse),
-               fwd_bwd_ms=bench(both, q, k, v, do),
-               dkv_alone_ms=bench(dkv, q, *kv, do, out, lse),
+               backward="split" if reason else "fused", grain=ns.grains[0],
+               walked_over_live=walked_over_live("fwd", ns, pa._TILE,
+                                                 ns.grains[0]),
+               fwd_ms=chained(at(fwd), ns.q_off)(q, k, v),
+               bwd_ms=chained(at(bwd), ns.q_off)(q, k, v, do, out, lse),
+               fwd_bwd_ms=chained(both, ns.q_off)(q, k, v, do),
+               dkv_alone_ms=chained(at(dkv), ns.q_off)(q, *kv, do, out, lse),
                max_abs_err_vs_dense=dict(zip(
                    ("out", "dq", "dk", "dv"),
                    (max_err([g], [w]) for g, w in zip(got, want)))))
@@ -202,7 +240,13 @@ def main():
     ap.add_argument("--kernels", default="fwd,dq,dkv,fused",
                     help="kernels to sweep tiles of ('' for none)")
     ap.add_argument("--bundled", type=int, default=1)
+    ap.add_argument("--einsum", type=int, default=1,
+                    help="0: leave out einsum, whose [B, H, T, T] float32 "
+                         "scores pass the chip at 48 heads of 8192 rows")
     ap.add_argument("--rows", default=",".join(map(str, ROWS)))
+    ap.add_argument("--grain", default=str(pa._GRAIN),
+                    help="rows a side of a crossed tile's sub-tiles, "
+                         "each swept (--kv-heads: the first alone)")
     ap.add_argument("--major", type=int, default=pa._MAJOR)
     ap.add_argument("--block", type=int, default=1)
     ap.add_argument("--q-off", type=int, default=0)
@@ -211,6 +255,7 @@ def main():
     ap.add_argument("--forms", default="repeated,kernel,kernel_per_head",
                     help="the forms --kv-heads times")
     ns = ap.parse_args()
+    ns.grains = [int(g) for g in ns.grain.split(",")]
     plain = ns.block == 1 and ns.q_off == 0 and not ns.window
     b, t, h, d = ns.shape
     os.makedirs(os.path.dirname(OUT), exist_ok=True)
@@ -237,16 +282,17 @@ def main():
 
     scale = 1.0 / d ** 0.5
     if plain:
-        want = jax.jit(fwd_bwd(einsum))(q, k, v, do)
-        report(log, **base, path="einsum", fwd_ms=bench(einsum, q, k, v),
-               fwd_bwd_ms=bench(fwd_bwd(einsum), q, k, v, do))
-
         # the kernels at the tiles pallas_attention has
         got = jax.jit(fwd_bwd(flash))(q, k, v, do)
-        report(log, **base, path="flash", tiles=list(pa._TILE),
-               fwd_ms=bench(flash, q, k, v),
-               fwd_bwd_ms=bench(fwd_bwd(flash), q, k, v, do),
-               max_abs_err_vs_einsum=max_err(got, want))
+        row = dict(fwd_ms=bench(flash, q, k, v),
+                   fwd_bwd_ms=bench(fwd_bwd(flash), q, k, v, do))
+        if ns.einsum:
+            report(log, **base, path="einsum",
+                   fwd_ms=bench(einsum, q, k, v),
+                   fwd_bwd_ms=bench(fwd_bwd(einsum), q, k, v, do))
+            row.update(max_abs_err_vs_einsum=max_err(
+                got, jax.jit(fwd_bwd(einsum))(q, k, v, do)))
+        report(log, **base, path="flash", tiles=list(pa._TILE), **row)
         out = got[0]
         lse = jax.jit(
             lambda q, k, v: pa._forward(q, k, v, True, True)[1])(q, k, v)
@@ -266,46 +312,58 @@ def main():
     # each kernel alone over (resident rows, walked block rows)
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1).transpose(0, 2, 1)
-    mask = dict(major=ns.major, block=ns.block, window=ns.window)
+    mask = dict(major=ns.major, block=ns.block, window=ns.window,
+                at=(ns.q_off, 0))
 
     def bwd(fused, **tiles):
-        return lambda *a: pa._bwd_call(*a, ns.q_off, 0, scale, True,
-                                       fused=fused, **tiles, **mask)
+        return lambda q_off, *a: pa._bwd_call(*a, q_off, 0, scale, True,
+                                              fused=fused, **tiles, **mask)
 
-    # the wrappers take the tiles as static arguments: one jit entry a
-    # tile, nothing to clear between them
+    # the wrappers take the tiles and the grain as static arguments: one
+    # jit entry a form, nothing to clear between them
     kernels = {
-        "fwd": (lambda tile: lambda q, k, v: pa._fwd_call(
-            q, k, v, ns.q_off, 0, scale, True, normalize=plain, tile=tile,
+        "fwd": (lambda **form: lambda q_off, q, k, v: pa._fwd_call(
+            q, k, v, q_off, 0, scale, True, normalize=plain, **form,
             **mask)[0],
-            (q, k, v)),
+            "tile", (q, k, v)),
         # the split form's two calls, each alone (XLA drops the other),
         # and the fused form's one
-        "dq": (lambda tile: lambda *a: bwd(False, dq_tile=tile)(*a)[0],
-               (q, k, v, do, lse, delta)),
-        "dkv": (lambda tile: lambda *a: bwd(False, dkv_tile=tile)(*a)[1:],
-                (q, k, v, do, lse, delta)),
-        "fused": (lambda tile: bwd(True, dkv_tile=tile),
-                  (q, k, v, do, lse, delta)),
+        "dq": (lambda **form: lambda *a: bwd(False, **form)(*a)[0],
+               "dq_tile", (q, k, v, do, lse, delta)),
+        "dkv": (lambda **form: lambda *a: bwd(False, **form)(*a)[1:],
+                "dkv_tile", (q, k, v, do, lse, delta)),
+        "fused": (lambda **form: bwd(True, **form),
+                  "dkv_tile", (q, k, v, do, lse, delta)),
     }
-    split = jax.jit(bwd(False))(q, k, v, do, lse, delta)
+    split = jax.jit(functools.partial(bwd(False), ns.q_off))(
+        q, k, v, do, lse, delta)
     report(log, **base, path="flash", tiles=list(pa._TILE),
            fused_max_abs_err_vs_split=max_err(
-               jax.jit(bwd(True))(q, k, v, do, lse, delta), split),
+               jax.jit(functools.partial(bwd(True), ns.q_off))(
+                   q, k, v, do, lse, delta), split),
            split_reason=pa._split_reason(
                t, t, pa._lane_block(h, d)[0], q.dtype.itemsize, pa._TILE,
                ns.major))
     rows = [r for r in map(int, ns.rows.split(",")) if t % r == 0]
     for kernel in filter(None, ns.kernels.split(",")):
-        at, args = kernels[kernel]
-        for tile in itertools.product(rows, rows):
+        at, tiled, args = kernels[kernel]
+        timed = set()
+        for tile, grain in itertools.product(
+                itertools.product(rows, rows), ns.grains):
+            grain = min(grain, max(tile))       # past it: the whole tile
+            if (tile, grain) in timed:
+                continue
+            timed.add((tile, grain))
             try:
-                ms = bench(at(tile), *args)
+                ms = chained(at(grain=grain, **{tiled: tile}),
+                             ns.q_off)(*args)
             except Exception as e:      # a tile Mosaic refuses
                 ms = None
                 print(kernel, tile, "refused:", str(e)[:200], flush=True)
             report(log, **base, path="flash", kernel=kernel,
-                   tile=list(tile), ms=ms)
+                   tile=list(tile), grain=grain, ms=ms,
+                   walked_over_live=walked_over_live(kernel, ns, tile,
+                                                     grain))
 
     # jax's bundled kernel, head-major operands (its layout), its default
     # tiles and two larger sets
