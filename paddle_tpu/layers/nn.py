@@ -36,7 +36,8 @@ __all__ = [
     "prelu", "crop", "spp", "unpool", "conv3d_transpose",
     "max_pool2d_with_index", "conv_shift", "l1_norm",
     "fused_attention", "block_diffusion_attention", "sparse_moe", "rms_norm",
-    "mamba2_mixer", "kda_mixer", "short_conv_mixer", "moe_block",
+    "mamba2_mixer", "kda_mixer", "gdn_mixer", "short_conv_mixer",
+    "moe_block",
     "rotary_embedding", "gated_mlp", "latent_attention", "mtp_block",
 
     "hsigmoid", "bilinear_interp", "selective_fc",
@@ -1170,23 +1171,33 @@ def sparse_moe(x, num_experts, hidden_size, capacity_factor=1.25,
 
 
 def rms_norm(input, gate=None, groups=1, epsilon=1e-5, param_attr=None,
-             name=None):
+             name=None, unit_offset=False, gate_behind=False):
     """weight * x / sqrt(mean(x^2) + epsilon) over the last axis, or over
     each of `groups` equal slices of it (one weight of the full width
     either way). With `gate` the input is x * silu(gate) first: Mamba-2's
-    gated norm. Statistics in float32 under AMP."""
+    gated norm; with `gate_behind` the gate comes behind the norm, weight
+    * norm(x) * silu(gate): Gated DeltaNet's. `unit_offset`: the weight is
+    1 + w with w from zeros (qwen3_next's norms). Statistics in float32
+    under AMP. The two attributes are written only when set, so a call
+    without them builds the op it built."""
     helper = LayerHelper("rms_norm", param_attr=param_attr, name=name)
     width = int(input.shape[-1])
     assert width % groups == 0, (width, groups)
+    assert gate is not None or not gate_behind
     scale = helper.create_parameter(
         attr=helper.param_attr, shape=[width], dtype=input.dtype,
-        default_initializer=ConstantInitializer(1.0))
+        default_initializer=ConstantInitializer(0.0 if unit_offset else 1.0))
     inputs = {"X": [input], "Scale": [scale]}
     if gate is not None:
         inputs["Gate"] = [gate]
+    attrs = {"epsilon": epsilon, "groups": groups}
+    if unit_offset:
+        attrs["unit_offset"] = True
+    if gate_behind:
+        attrs["gate_behind"] = True
     out = helper.create_tmp_variable(input.dtype)
     helper.append_op(type="rms_norm", inputs=inputs, outputs={"Out": [out]},
-                     attrs={"epsilon": epsilon, "groups": groups})
+                     attrs=attrs)
     return out
 
 
@@ -1302,6 +1313,20 @@ def mamba2_mixer(x, num_heads, head_dim, n_groups, state_size, conv_kernel=4,
     return _linear(y, d_model, scale=out_scale)
 
 
+def _silu_conv(helper, t, conv_kernel):
+    """silu of a causal depthwise convolution of `conv_kernel` taps over t
+    [B, T, C], no bias: one causal_conv1d op, taps N(0, K^-1/2) created
+    by the layer's `helper`."""
+    taps = helper.create_parameter(
+        attr=None, shape=[int(t.shape[-1]), conv_kernel], dtype=t.dtype,
+        default_initializer=NormalInitializer(scale=conv_kernel ** -0.5))
+    out = helper.create_tmp_variable(t.dtype)
+    helper.append_op(type="causal_conv1d",
+                     inputs={"X": [t], "Filter": [taps]},
+                     outputs={"Out": [out]}, attrs={})
+    return out
+
+
 @_under_its_name
 def kda_mixer(x, num_heads, head_dim, conv_kernel=4, gate_rank=None,
               chunk_size=64, epsilon=1e-5, l2_epsilon=1e-6, out_scale=0.02,
@@ -1334,14 +1359,7 @@ def kda_mixer(x, num_heads, head_dim, conv_kernel=4, gate_rank=None,
         return reshape(t, [-1, seqlen, num_heads, head_dim])
 
     def short_conv(t):
-        taps = helper.create_parameter(
-            attr=None, shape=[width, conv_kernel], dtype=dtype,
-            default_initializer=NormalInitializer(scale=conv_kernel ** -0.5))
-        out = helper.create_tmp_variable(dtype)
-        helper.append_op(type="causal_conv1d",
-                         inputs={"X": [t], "Filter": [taps]},
-                         outputs={"Out": [out]}, attrs={})
-        return by_head(out)
+        return by_head(_silu_conv(helper, t, conv_kernel))
 
     q, k, v = map(short_conv, [_linear(x, width) for _ in range(3)])
     gate = by_head(_linear(_linear(x, rank), width))
@@ -1363,6 +1381,69 @@ def kda_mixer(x, num_heads, head_dim, conv_kernel=4, gate_rank=None,
         rms_norm(o, epsilon=epsilon),
         by_head(_linear(_linear(x, rank), width, act="sigmoid")))
     return _linear(reshape(o, [-1, seqlen, width]), d_model, scale=out_scale)
+
+
+@_under_its_name
+def gdn_mixer(x, num_key_heads, num_value_heads, key_dim, value_dim,
+              conv_kernel=4, chunk_size=64, epsilon=1e-6, l2_epsilon=1e-6,
+              out_scale=0.02, name=None):
+    """Gated DeltaNet (arXiv:2412.06464, as qwen3_next sizes it) over x
+    [B, T, D]: Hk = `num_key_heads` heads of K = `key_dim` under Hv =
+    `num_value_heads` heads of V = `value_dim`, value head j reading key
+    head j // (Hv / Hk):
+
+        q~, k~, v = silu(conv(x W_q)), silu(conv(x W_k)), silu(conv(x W_v))
+        z = x W_z [T, Hv, V];  b, alpha = x W_b, x W_alpha     [T, Hv], raw
+        o = kda_scan(q~, k~, v, alpha, A_log, dt_bias, b)      a decay a head
+        y = concat_j(w * o_j / sqrt(mean o_j^2 + `epsilon`) * silu(z_j)) W_o
+
+    The op (ops/hybrid_ops.py kda_scan in its head-decay form: the L2
+    norm of q~ and k~ a head, q over sqrt(K), g = -exp(A_log)
+    softplus(alpha + dt_bias) and beta = sigmoid(b) a value head, the
+    gated delta rule in chunks of `chunk_size`) reads q~ and k~ at Hk
+    heads: nothing is repeated or broadcast ahead of it. The published
+    in_proj_qkvz [D, 2 Hk K + 2 Hv V] (grouped by key head) and
+    in_proj_ba [D, 2 Hv] are held as the six maps their columns are, and
+    the one depthwise convolution over the 2 Hk K + Hv V channels of [q |
+    k | v] as the three its taps are (causal_conv1d without its Bias), so
+    that each reaches its kernel as the array its product wrote: a
+    permutation of columns, the same values and the same count. The norm
+    a head is rms_norm with the gate behind it and one plain weight [V].
+    A_log [Hv] and dt_bias [Hv] start as Mamba-2's. No bias in any map.
+    Parameters in the order created: W_q, W_k, W_v, the three filters,
+    W_z, W_b, W_alpha, A_log, dt_bias, w, W_o."""
+    helper = LayerHelper("gdn_mixer", name=name)
+    seqlen, d_model = int(x.shape[1]), int(x.shape[2])
+    heads = (num_key_heads, num_key_heads, num_value_heads)
+    dims = (key_dim, key_dim, value_dim)
+    dtype = x.dtype
+
+    def short_conv(t, n, width):
+        return reshape(_silu_conv(helper, t, conv_kernel),
+                       [-1, seqlen, n, width])
+
+    q, k, v = map(short_conv,
+                  [_linear(x, n * w) for n, w in zip(heads, dims)], heads,
+                  dims)
+    z = reshape(_linear(x, num_value_heads * value_dim),
+                [-1, seqlen, num_value_heads, value_dim])
+    beta, alpha = (_linear(x, num_value_heads) for _ in range(2))
+    a_log = helper.create_parameter(
+        attr=None, shape=[num_value_heads], dtype=dtype,
+        default_initializer=LogOfUniformInitializer())
+    dt_bias = helper.create_parameter(
+        attr=None, shape=[num_value_heads], dtype=dtype,
+        default_initializer=SoftplusInverseLogUniformInitializer())
+    o = helper.create_tmp_variable(dtype)
+    helper.append_op(
+        type="kda_scan",
+        inputs={"Q": [q], "K": [k], "V": [v], "Gate": [alpha],
+                "ALog": [a_log], "DtBias": [dt_bias], "Beta": [beta]},
+        outputs={"Out": [o]},
+        attrs={"chunk_size": int(chunk_size), "epsilon": float(l2_epsilon)})
+    o = rms_norm(o, gate=z, epsilon=epsilon, gate_behind=True)
+    return _linear(reshape(o, [-1, seqlen, num_value_heads * value_dim]),
+                   d_model, scale=out_scale)
 
 
 @_under_its_name
@@ -1410,7 +1491,7 @@ def moe_block(x, num_experts, top_k, expert_width, shared_width=0,
               experts_held=None, expert_offset=0, scaling=1.0,
               norm_topk_prob=True, out_scale=0.02, stats=None, name=None,
               gated=False, scoring="sigmoid", router_input=None,
-              gate_act="silu", norm_epsilon=1e-20):
+              gate_act="silu", norm_epsilon=1e-20, shared_gate=False):
     """Mixture-of-experts feed-forward over x [B, T, D] with a top-k
     router, squared-ReLU experts (`gated`: gated SiLU experts,
     f(x) = (silu(x G) * (x U)) V, three matrices an expert, the shared
@@ -1436,7 +1517,10 @@ def moe_block(x, num_experts, top_k, expert_width, shared_width=0,
     no token dropped, static shapes, a grouped product over the rows
     actually routed here); the shared expert is applied to every token.
     Without one (`shared_width` 0) a token none of whose choices is held
-    here gets exactly zero from the layer.
+    here gets exactly zero from the layer. `shared_gate`: the shared
+    expert's output is times sigmoid(x w_sg), one scalar a token (w_sg
+    [D, 1], created behind the shared expert's three maps;
+    qwen2_moe's and qwen3_next's shared expert).
     `stats`: a list that receives this layer's (rows routed to held
     experts, rows combined, busiest held expert over their mean, rows
     handled: the capacity the step's routed rows were given) Variables,
@@ -1507,9 +1591,13 @@ def moe_block(x, num_experts, top_k, expert_width, shared_width=0,
     if stats is not None:
         stats.append((rows, combined, load, handled))
     out = reshape(routed, [-1, seqlen, d_model])
+    assert not shared_gate or (shared_width and gated)
     if shared_width and gated:
-        out = elementwise_add(out, gated_mlp(x, shared_width,
-                                             out_scale=out_scale))
+        shared = gated_mlp(x, shared_width, out_scale=out_scale)
+        if shared_gate:     # [B, T, D] times [B, T]: a scalar a token
+            shared = elementwise_mul(shared, reshape(
+                _linear(x, 1, act="sigmoid"), [-1, seqlen]), axis=0)
+        out = elementwise_add(out, shared)
     elif shared_width:
         hidden = _linear(x, shared_width, act="relu2")
         out = elementwise_add(out, _linear(hidden, d_model, scale=out_scale))
@@ -1518,11 +1606,14 @@ def moe_block(x, num_experts, top_k, expert_width, shared_width=0,
 
 @_under_its_name
 def rotary_embedding(x, theta=10000.0, rotary_dims=None, name=None,
-                     scaling=None):
+                     scaling=None, rotate_first=False):
     """Rotary position embedding over x [B, T, H, D]: the last
     `rotary_dims` of every head (default all D) are rotated by the
     position t along axis 1, the pair (i, i + r/2) of those r dims by the
-    angle t * theta^(-2i/r); the dims before them pass through. Angles
+    angle t * theta^(-2i/r); the dims before them pass through. With
+    `rotate_first` the FIRST `rotary_dims` are the rotated ones and the
+    dims behind them pass through (qwen3_next: 64 of 256; the attribute
+    is written only when set). Angles
     and the rotation are float32 under AMP (ops/hybrid_ops.py).
 
     `scaling`: a published `rope_parameters` / `rope_scaling` group.
@@ -1537,6 +1628,8 @@ def rotary_embedding(x, theta=10000.0, rotary_dims=None, name=None,
     `scaling` is the one it was."""
     attrs = {"theta": float(theta),
              "rotary_dims": int(rotary_dims or x.shape[-1])}
+    if rotate_first:
+        attrs["rotate_first"] = True
     kind = (scaling or {}).get("rope_type", "default")
     if kind == "yarn":
         factor = float(scaling["factor"])

@@ -10,6 +10,7 @@ from .alexnet import alexnet
 from .block_diffusion_moe import block_diffusion_moe_lm
 from .conv_moe import conv_moe_lm
 from .gated_window_moe import gated_window_moe_lm
+from .gdn_moe import gdn_moe_lm
 from .googlenet import googlenet
 from .granite_hybrid import granite_hybrid_lm
 from .kda_moe import kda_moe_lm
@@ -26,7 +27,7 @@ from .common import balance_routers, build_image_classifier
 
 __all__ = [
     "alexnet", "block_diffusion_moe_lm", "conv_moe_lm",
-    "gated_window_moe_lm", "googlenet",
+    "gated_window_moe_lm", "gdn_moe_lm", "googlenet",
     "granite_hybrid_lm", "kda_moe_lm", "looped_lm", "mla_moe_lm",
     "mnist_conv", "mnist_mlp",
     "nemotron_h_lm",

@@ -1,5 +1,6 @@
 """Ops of hybrid state-space / mixture-of-experts language models (no
-2018-reference analogue): RMSNorm with its gated, grouped form, a causal
+2018-reference analogue): RMSNorm with its gated, grouped forms (the
+gate ahead of the norm or behind it, a weight of 1 + w), a causal
 depthwise conv over time, the Mamba-2 selective scan in its chunked (SSD)
 form, a top-k router (sigmoid or softmax scores), the rule that moves its
 selection bias against the load (moe_balance_bias) and a dropless expert
@@ -7,9 +8,10 @@ layer that is told which experts it holds (squared-ReLU experts, or gated
 SiLU ones when it is given the third matrix), and the rotary position
 embedding (latent attention's decoupled part; every dim of a head in the
 block-diffusion decoder; YaRN's blended frequencies and factor over half
-a head in the gated window decoder's full layers). models/nemotron_h.py,
-models/mla_moe.py, models/block_diffusion_moe.py, models/window_moe.py
-and models/gated_window_moe.py build models from them.
+a head in the gated window decoder's full layers; the first quarter of a
+head in the Gated DeltaNet decoder's). models/nemotron_h.py,
+models/mla_moe.py, models/block_diffusion_moe.py, models/window_moe.py,
+models/gated_window_moe.py and models/gdn_moe.py build models from them.
 
 Precision under AMP: norm statistics, the router, `dt`, `A`, the scan's
 decays and its state stay float32; the scan's four products and the
@@ -96,16 +98,28 @@ def _rms_norm(ctx, op_, ins):
     """Out = Scale * n(X), n(x) = x / sqrt(mean(x^2) + epsilon) over the
     last axis, or over each of `groups` equal slices of it. With Gate the
     input is X * silu(Gate) first (gate before norm, as nemotron_h's
-    MambaRMSNormGated). Statistics in float32; Out has X's dtype."""
+    MambaRMSNormGated), or with `gate_behind` the result is times
+    silu(Gate): Scale * n(X) * silu(Gate), the norm ahead of the gate
+    (qwen3_next's gated norm a head). `unit_offset`: the weight is 1 +
+    Scale (Scale from zeros; qwen3_next's and Gemma's form). Statistics
+    in float32; Out has X's dtype."""
     x = jnp.asarray(ins["X"][0])
     h = _f32(x)
-    if ins.get("Gate") and ins["Gate"][0] is not None:
-        h = h * jax.nn.silu(_f32(ins["Gate"][0]))
+    gate = ins["Gate"][0] if ins.get("Gate") else None
+    behind = op_.attr("gate_behind", False)
+    if gate is not None and not behind:
+        h = h * jax.nn.silu(_f32(gate))
     groups = op_.attr("groups", 1)
     g = h.reshape(h.shape[:-1] + (groups, h.shape[-1] // groups))
     g = g * lax.rsqrt(jnp.mean(jnp.square(g), axis=-1, keepdims=True)
                       + op_.attr("epsilon", 1e-5))
-    out = g.reshape(h.shape) * _f32(ins["Scale"][0])
+    out = g.reshape(h.shape)    # (traced in the order it always was)
+    scale = _f32(ins["Scale"][0])
+    if op_.attr("unit_offset", False):
+        scale = 1.0 + scale
+    out = out * scale
+    if gate is not None and behind:
+        out = out * jax.nn.silu(_f32(gate))
     return {"Out": [out.astype(x.dtype)]}
 
 
@@ -152,7 +166,9 @@ def _rotary_embedding(ctx, op_, ins):
     """X [B, T, H, D]: the last `rotary_dims` of every head (default all
     D) are rotated by the position t of axis 1, the pair (i, i + r/2) of
     those r dims by the angle t * theta^(-2i/r) (the rotate-half
-    pairing); the leading D - r dims pass through. With `yarn_factor`
+    pairing); the leading D - r dims pass through. With `rotate_first`
+    the FIRST r dims are the rotated ones and the trailing D - r pass
+    through (qwen3_next's partial rotary). With `yarn_factor`
     (and the three attributes beside it) the per-pair frequencies are
     YaRN's blend (_yarn_frequencies); `attention_factor` multiplies the
     cosines and sines, so the rotated dims come out scaled by it and the
@@ -171,6 +187,11 @@ def _rotary_embedding(ctx, op_, ins):
     if factor != 1.0:   # traced only when set: every other step's HLO,
         cos, sin = factor * cos, factor * sin   # and cache key, as it was
     h = _f32(x)
+    if op_.attr("rotate_first", False):
+        first, second, keep = h[..., :r // 2], h[..., r // 2:r], h[..., r:]
+        out = jnp.concatenate([first * cos - second * sin,
+                               second * cos + first * sin, keep], axis=-1)
+        return {"Out": [out.astype(x.dtype)]}
     keep, first, second = h[..., :d - r], h[..., d - r:d - r // 2], \
         h[..., d - r // 2:]
     out = jnp.concatenate([keep, first * cos - second * sin,
@@ -455,7 +476,8 @@ def _ssd_scan(ctx, op_, ins):
     return {"Out": [y.astype(x.dtype)]}
 
 
-# --- Kimi Delta Attention: the gated delta rule, a decay a channel, chunked ---
+# --- the gated delta rule, chunked: Kimi Delta Attention's decay a channel, ---
+# --- and Gated DeltaNet's a head with key heads under groups of value heads ---
 
 # rows of one sub-block of a chunk. Every exponent of kda_chunked is a
 # difference of summed log-decays referred to a sub-block's first row, so
@@ -639,10 +661,13 @@ def _kda_infer(op_, block):
 _KDA_OP = "kda_scan"
 
 
-def kda_scan_ineligible(chunk: int, k: int, v: int):
+def kda_scan_ineligible(chunk: int, k: int, v: int, ratio: int = 1,
+                        per_head: bool = True):
     """None when the delta rule's kernels (ops/pallas_kda.py) take chunks
-    of `chunk` tokens and heads of K = `k` and V = `v` channels, else the
-    reason kda_chunked keeps the op (kernel_choice.REASONS["kda_scan"]).
+    of `chunk` tokens and heads of K = `k` and V = `v` channels, `ratio`
+    value heads reading one key head under a decay a head (`per_head`) or
+    a channel, else the reason kda_chunked keeps the op
+    (kernel_choice.REASONS["kda_scan"]).
     A head is one lane block of its projection's [T, H x K] rows: K must
     be 128 (Mosaic aborts on a row of a [., 256] array, as it did for
     the scan kernels' N: PR 40) and V whole lane blocks (`width`); the
@@ -654,6 +679,12 @@ def kda_scan_ineligible(chunk: int, k: int, v: int):
         return "width"
     if chunk % _KDA_SUB or chunk & (chunk - 1):
         return "chunk"
+    # a key head's dq and dk are summed inside one grid step, which owns
+    # at most _HEADS heads; the channel form's kernels (Kimi-Linear's,
+    # held to what they were) read q, k and the gate through one block
+    from .pallas_kda import _HEADS
+    if ratio > 1 and (ratio > _HEADS or not per_head):
+        return "group"
     return None
 
 
@@ -661,12 +692,21 @@ def kda_scan_chunked(q, k, v, gate, a_log, dt_bias, beta, chunk, eps,
                      dtype=jnp.float32):
     """The op kda_scan in jax.numpy around kda_chunked (the op's
     docstring has the equations): the norm, g and beta in float32
-    whatever the inputs are, so autodiff carries a_log and dt_bias."""
+    whatever the inputs are, so autodiff carries a_log and dt_bias. A
+    decay a head is broadcast over the head's channels and a key head
+    repeated to its value heads here: the statement, and the path of
+    shapes the kernels do not take, not the cell's."""
     q, k = (x * lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + eps)
             for x in (_f32(q), _f32(k)))
-    heads, width = q.shape[2], q.shape[3]
-    g = -jnp.exp(_f32(a_log))[:, None] * jax.nn.softplus(
-        _f32(gate) + _f32(dt_bias).reshape(heads, width))
+    heads, width = v.shape[2], q.shape[3]
+    if heads != q.shape[2]:     # value head j reads key head j // ratio
+        q, k = (jnp.repeat(x, heads // x.shape[2], axis=2) for x in (q, k))
+    if gate.ndim == 3:          # a decay a head, over its channels
+        g = jnp.broadcast_to((-jnp.exp(_f32(a_log)) * jax.nn.softplus(
+            _f32(gate) + _f32(dt_bias)))[..., None], q.shape)
+    else:
+        g = -jnp.exp(_f32(a_log))[:, None] * jax.nn.softplus(
+            _f32(gate) + _f32(dt_bias).reshape(heads, width))
     out = kda_chunked(q, k, v, g, jax.nn.sigmoid(_f32(beta)), chunk,
                       dtype=dtype)
     return (out * width ** -0.5).astype(v.dtype)
@@ -697,15 +737,28 @@ def _kda_scan(ctx, op_, ins):
     operands at the same precision. kda_scan_total{chunk, path} books
     each forward lowering: `kernel` or `chunked` a first forward's,
     `kernel_replay` or `chunked_replay` that of an op a recomputed
-    segment runs again (a gradient's re-trace books nothing)."""
+    segment runs again (a gradient's re-trace books nothing).
+
+    The Gated DeltaNet form (arXiv:2412.06464), told by the shapes alone:
+    Gate [B, T, H] and DtBias [H], a decay a HEAD, g = -exp(ALog) *
+    softplus(Gate + DtBias) read by all of a head's channels; and Q, K
+    [B, T, H / r, K] under V's H heads, value head j reading key head j
+    // r. The kernels then take one exponent a row and head where the
+    channel form takes K, read Q and K at their own head count and sum a
+    key head's dQ and dK over its group inside the backward walk (other
+    calls, `gdn_scan_fwd` / `gdn_scan_bwd`: the channel form's are what
+    they were); kda_scan_head_decay_total{path, groups} books such a
+    lowering beside kda_scan_total."""
     from .pallas_attention import _interpret
     from .pallas_kda import kda_scan_kernels
 
     operands = [jnp.asarray(ins[slot][0]) for slot in (
         "Q", "K", "V", "Gate", "ALog", "DtBias", "Beta")]
     chunk = op_.attr("chunk_size", 64)
+    per_head = operands[3].ndim == 3
+    ratio = operands[2].shape[2] // operands[0].shape[2]
     reason = kda_scan_ineligible(chunk, operands[0].shape[3],
-                                 operands[2].shape[3])
+                                 operands[2].shape[3], ratio, per_head)
     kernel_choice.book(_KDA_OP, reason)
     if not kernel_choice.in_retrace():
         from .. import telemetry
@@ -721,6 +774,14 @@ def _kda_scan(ctx, op_, ins):
             "again by a recomputed segment)",
             labels=("chunk", "path")).labels(chunk=str(chunk),
                                              path=path).inc()
+        if per_head:
+            telemetry.counter(
+                "kda_scan_head_decay_total",
+                "of kda_scan_total's lowerings, those with a decay a head "
+                "(Gate [B, T, H]), by path and by the value heads that "
+                "read one key head",
+                labels=("path", "groups")).labels(
+                    path=path, groups=str(ratio)).inc()
     shared = dict(chunk=chunk, eps=op_.attr("epsilon", 1e-6),
                   dtype=_compute_dtype(ctx))
     if reason is None:

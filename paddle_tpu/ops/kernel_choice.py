@@ -33,8 +33,10 @@ REASONS = {
     "ssd_scan": frozenset({"chunk", "state", "heads"}),
     # the delta rule (ops/pallas_kda.py, forward and gradient): a head
     # one lane block of K and whole ones of V, the chunk a power of two
-    # of 16-row sub-blocks; else hybrid_ops.kda_scan_chunked keeps the op
-    "kda_scan": frozenset({"width", "chunk"}),
+    # of 16-row sub-blocks, a group of value heads under one key head no
+    # wider than a grid step (and only under a decay a head); else
+    # hybrid_ops.kda_scan_chunked keeps the op
+    "kda_scan": frozenset({"width", "chunk", "group"}),
     # the mixers' short convolution (ops/pallas_conv1d.py, forward and
     # gradient): whole 128-wide blocks of time and of channels, bf16 or
     # float32, the taps' reach inside a tile; else

@@ -78,6 +78,22 @@ Mosaic's fp32 contraction) and every accumulation are float32; every
 other product takes operands in the compute dtype (bf16 under AMP).
 Every exponent is a difference referred to a sub-block of _SUB rows.
 
+A decay a HEAD and key heads under groups of value heads (Gated DeltaNet,
+arXiv:2412.06464; PR 64) share the kernels' bodies under other names
+(`gdn_scan_fwd` / `gdn_scan_bwd`; the channel form's calls are what they
+were, jaxpr for jaxpr): g [B, T, H] and its running sum inside each chunk
+are formed in jax.numpy inside the rule (`_running_sums`) and reach the
+kernels as beta does, [B, blocks, T, R]; the exponents are taken once a
+row of all R heads and parked in VMEM scratch, a column a head
+(`_decays_a_head`); q and k are read at their own head count, a step's R
+/ ratio key heads, their unit rows formed once a key head; and a key
+head's dq and dk are summed over its group ahead of the norm's pull-back.
+On a v5e at [1, 16384, 16 | 32 heads, 128, 128], chunk 64, bf16, 8 heads
+a step (tools/kda_sweep.py --decay head --key-heads 16; my chip run, PR
+64), forward | forward + gradient ms: these kernels 12.38 | 21.01; the
+channel form's behind a gate broadcast to [B, T, H, K] and q, k repeated
+to 32 heads, widening and pull-back included, 15.11 | 27.25.
+
 On CPU the kernels run under the Pallas interpreter (`interpret`).
 
 The op alone on a v5e (tools/kda_sweep.py, my chip runs, PR 56), ms a
@@ -120,12 +136,17 @@ _HEADS = 8
 _STEP_ROWS = 512
 
 
-def heads_a_step(heads: int, chunk: int = 64, itemsize: int = 2) -> int:
+def heads_a_step(heads: int, chunk: int = 64, itemsize: int = 2,
+                 ratio: int = 1) -> int:
     """The heads one grid step owns: the largest divisor of `heads` up to
     _HEADS with chunk x heads within _STEP_ROWS of bf16 operands, half
-    that of float32 ones (8 at the published chunk of 64 under AMP)."""
-    most = max(1, min(_HEADS, _STEP_ROWS * 2 // (chunk * itemsize)))
-    return max(r for r in range(1, min(heads, most) + 1) if heads % r == 0)
+    that of float32 ones (8 at the published chunk of 64 under AMP).
+    Where `ratio` value heads read one key head a step owns whole
+    groups (one at least, whatever the rows: the gate admits groups of
+    up to _HEADS), so a key head's dq and dk are summed inside it."""
+    most = max(ratio, min(_HEADS, _STEP_ROWS * 2 // (chunk * itemsize)))
+    return max(r for r in range(1, min(heads, most) + 1)
+               if heads % r == 0 and r % ratio == 0)
 
 
 def _dot(a, b):
@@ -277,6 +298,49 @@ def _decays(gate, a_row, bias_row, lower):
                 to_end=jnp.exp(last - cum), last=jnp.exp(last))
 
 
+class _Plane:
+    """Plane i of a [n, C, R] scratch, indexed as the array it holds: [rows,
+    lanes] is a load from the ref (Mosaic takes no view, `.at`, of a ref
+    whose lanes are not whole blocks)."""
+
+    def __init__(self, ref, i):
+        self.ref, self.i = ref, i
+
+    def __getitem__(self, at):
+        return self.ref[(self.i,) + at]
+
+
+def _decays_a_head(cum, e_sc, last_sc, kd):
+    """_decays where the decay is a head's and not a channel's. `cum` [C,
+    R]: the running sum of g down the chunk, a column a head (formed in
+    jax.numpy ahead of the call: g is [B, T, H]). Every exponent is taken
+    ONCE A ROW of all R heads, R lanes of a vector register where the
+    channel form fills 128 a head, and parked in VMEM scratch, whose
+    columns the heads read back as [C, 1] and broadcast over their lanes
+    as they do beta's (a ref gives a column at a lane offset; a loaded
+    value does not). exp(G_last) is parked as [V, R]: a column of it
+    multiplies the transposed state's rows."""
+    c, r = cum.shape
+    ahead = [jnp.zeros((1, r), _F32)] + [
+        cum[i * _SUB - 1:i * _SUB] for i in range(1, c // _SUB)]
+    own = jnp.concatenate(
+        [jnp.broadcast_to(x, (_SUB, r)) for x in ahead], axis=0)
+    last = cum[c - 1:c]
+    at = lax.broadcasted_iota(jnp.int32, cum.shape, 0)
+    e_sc[0] = jnp.exp(cum - own)
+    e_sc[1] = jnp.exp(cum)
+    e_sc[2] = jnp.exp(last - cum)
+    for i, row in enumerate(ahead):
+        seen = at < (i + 1) * _SUB
+        e_sc[3 + i] = jnp.where(
+            seen, jnp.exp(jnp.where(seen, row - cum, 0.0)), 0.0)
+    last_sc[...] = jnp.broadcast_to(jnp.exp(last), last_sc.shape)
+    return dict(at=lax.broadcasted_iota(jnp.int32, (c, kd), 0),
+                rises=[_Plane(e_sc, 3 + i) for i in range(len(ahead))],
+                shrunk=_Plane(e_sc, 0), decayed=_Plane(e_sc, 1),
+                to_end=_Plane(e_sc, 2), last=last_sc)
+
+
 def _unit(x, eps):
     """A head's rows over their L2 norm, and the factor."""
     x = x.astype(_F32)
@@ -293,14 +357,14 @@ def _pack_forward(heads, d, tri, *, c, dtype):
     lower, below, _, _ = tri
     pack = len(heads)
     for h in heads:
-        shrunk = d["shrunk"][:, h["wide"]]
+        shrunk = d["shrunk"][:, h["own"]]
         h["qs"], h["ks"] = ((h[x] * shrunk).astype(dtype) for x in "qk")
     rows, keys, qk, kk = [], [], [], []
     for i, rise in enumerate(d["rises"]):
         own = slice(i * _SUB, (i + 1) * _SUB)
         rows.append(_cat([jnp.concatenate([h["qs"][own], h["ks"][own]], axis=0)
                           for h in heads], axis=1))     # [2 sub, pack K]
-        keys.append([(h["k"] * rise[:, h["wide"]]).astype(dtype)
+        keys.append([(h["k"] * rise[:, h["own"]]).astype(dtype)
                      for h in heads])
         scores = _dot_nt(rows[i], _diag_of_heads(keys[i]))  # [2 sub, pack C]
         qk.append(scores[:_SUB])
@@ -310,7 +374,7 @@ def _pack_forward(heads, d, tri, *, c, dtype):
     for i in range(1, pack):
         beta = jnp.where(head == i, heads[i]["beta"], beta)
     fed = [jnp.concatenate(
-        [(h["k"] * d["decayed"][:, h["wide"]] * h["beta"]).astype(dtype),
+        [(h["k"] * d["decayed"][:, h["own"]] * h["beta"]).astype(dtype),
          (h["v"] * h["beta"]).astype(dtype)], axis=1) for h in heads]
     return dict(rows=rows, keys=keys, fed=fed, head=head,
                 beta=jnp.broadcast_to(beta, (c, pack * c)),
@@ -318,22 +382,37 @@ def _pack_forward(heads, d, tri, *, c, dtype):
                 kk=jnp.where(below, jnp.concatenate(kk, axis=0), 0.0))
 
 
-def _heads_of(refs, j, *, kd, vd, eps):
+def _heads_of(refs, j, units, *, kd, vd, eps, ratio, per_head):
+    """Value head j of a step: `wide` its key head's lanes of q and k (j
+    // ratio: the unit rows are formed once a key head, in `units`),
+    `tall` its own of v and o, `own` its lanes of _decays' arrays (its K
+    channels, or its one column where the decay is a head's)."""
     q_ref, k_ref, v_ref, beta_ref = refs
-    wide, tall = slice(j * kd, (j + 1) * kd), slice(j * vd, (j + 1) * vd)
-    q, rq = _unit(q_ref[0, :, wide], eps)
-    k, rk = _unit(k_ref[0, :, wide], eps)
-    return dict(j=j, wide=wide, tall=tall, q=q, k=k, rq=rq, rk=rk,
+    i = j // ratio
+    wide, tall = slice(i * kd, (i + 1) * kd), slice(j * vd, (j + 1) * vd)
+    if i not in units:
+        units[i] = _unit(q_ref[0, :, wide], eps) \
+            + _unit(k_ref[0, :, wide], eps)
+    q, rq, k, rk = units[i]
+    own = slice(j, j + 1) if per_head else slice(j * kd, (j + 1) * kd)
+    return dict(j=j, wide=wide, tall=tall, own=own, q=q, k=k, rq=rq, rk=rk,
                 v=v_ref[0, :, tall].astype(_F32),
                 beta=beta_ref[0, 0, :, j:j + 1])        # [C, 1] float32
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, gate_ref, beta_ref, a_ref, bias_ref,
-                o_ref, enter_ref, inverse_ref, s_sc,
-                *, r, pack, kd, vd, eps, scale, dtype):
+def _fwd_kernel(q_ref, k_ref, v_ref, gate_ref, beta_ref, *rest,
+                r, pack, kd, vd, eps, scale, dtype, ratio=1, per_head=False):
     """One chunk of a head block: o, and for the gradient the state
-    entering the chunk and the chunk's inverse a pack."""
+    entering the chunk and the chunk's inverse a pack. `rest`: a's and
+    dt_bias' rows, the three results and the state's scratch; where the
+    decay is a head's (`per_head`: `gate_ref` then holds the running sums
+    G [1, 1, C, R]) no rows, and _decays_a_head's two scratches behind
+    the state's."""
     import jax.experimental.pallas as pl
+    if per_head:
+        o_ref, enter_ref, inverse_ref, s_sc, e_sc, last_sc = rest
+    else:
+        a_ref, bias_ref, o_ref, enter_ref, inverse_ref, s_sc = rest
 
     @pl.when(pl.program_id(2) == 0)
     def _start():
@@ -341,18 +420,23 @@ def _fwd_kernel(q_ref, k_ref, v_ref, gate_ref, beta_ref, a_ref, bias_ref,
 
     c = q_ref.shape[1]
     tri = _triangles(c, pack)
-    d = _decays(gate_ref[0], a_ref[...], bias_ref[...],
-                _triangles(c, 1)[0].astype(_F32))
+    if per_head:
+        d = _decays_a_head(gate_ref[0, 0], e_sc, last_sc, kd)
+    else:
+        d = _decays(gate_ref[0], a_ref[...], bias_ref[...],
+                    _triangles(c, 1)[0].astype(_F32))
+    units = {}
     for g in range(r // pack):
         heads = [_heads_of((q_ref, k_ref, v_ref, beta_ref), g * pack + i,
-                           kd=kd, vd=vd, eps=eps) for i in range(pack)]
+                           units, kd=kd, vd=vd, eps=eps, ratio=ratio,
+                           per_head=per_head) for i in range(pack)]
         f = _pack_forward(heads, d, tri, c=c, dtype=dtype)
         inverse = _inverse(f["kk"] * f["beta"], tri[2], tri[3], f["head"])
         inverse_ref[0, 0, g] = inverse
         wu = _dot(inverse.astype(dtype), _diag_of_heads(f["fed"]))
         fresh, carried = [], []
         for i, h in enumerate(heads):
-            j, wide = h["j"], h["wide"]
+            j, wide = h["j"], h["own"]
             state = s_sc[j]                             # [V, K] float32
             enter_ref[0, 0, j] = state
             entering = state.astype(dtype)
@@ -370,37 +454,51 @@ def _fwd_kernel(q_ref, k_ref, v_ref, gate_ref, beta_ref, a_ref, bias_ref,
             ).astype(o_ref.dtype)
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, gate_ref, beta_ref, a_ref, bias_ref,
-                do_ref, enter_ref, inverse_ref, dq_ref, dk_ref, dv_ref,
-                dgate_ref, dbeta_ref, da_ref, dbias_ref, ds_sc,
-                *, r, pack, kd, vd, eps, scale, dtype):
+def _bwd_kernel(q_ref, k_ref, v_ref, gate_ref, beta_ref, *rest,
+                r, pack, kd, vd, eps, scale, dtype, ratio=1, per_head=False):
+    """One chunk of a head block, walked downward. `rest` as
+    _fwd_kernel's: where the decay is a head's the kernel hands back the
+    running sums' cotangent [1, 1, C, R], a head's channels summed, where
+    the channel form pulls it back to the gate, a and dt_bias itself."""
     import jax.experimental.pallas as pl
+    if per_head:
+        (do_ref, enter_ref, inverse_ref, dq_ref, dk_ref, dv_ref, dcum_ref,
+         dbeta_ref, ds_sc, e_sc, last_sc) = rest
+    else:
+        (a_ref, bias_ref, do_ref, enter_ref, inverse_ref, dq_ref, dk_ref,
+         dv_ref, dgate_ref, dbeta_ref, da_ref, dbias_ref, ds_sc) = rest
 
     @pl.when(pl.program_id(2) == 0)
     def _start():
         ds_sc[...] = jnp.zeros_like(ds_sc)
-        da_ref[...] = jnp.zeros_like(da_ref)
-        dbias_ref[...] = jnp.zeros_like(dbias_ref)
+        if not per_head:
+            da_ref[...] = jnp.zeros_like(da_ref)
+            dbias_ref[...] = jnp.zeros_like(dbias_ref)
 
     c = q_ref.shape[1]
     n = c // _SUB
     tri = _triangles(c, pack)
     lower, below = tri[:2]
-    running = _triangles(c, 1)[0].astype(_F32)
-    a_row = a_ref[...]
-    d = _decays(gate_ref[0], a_row, bias_ref[...], running)
+    if per_head:
+        d = _decays_a_head(gate_ref[0, 0], e_sc, last_sc, kd)
+    else:
+        running = _triangles(c, 1)[0].astype(_F32)
+        a_row = a_ref[...]
+        d = _decays(gate_ref[0], a_row, bias_ref[...], running)
     turned = ((0,), (0,)), ((), ())
     both = kd + vd
     d_cum, d_beta = [None] * r, [None] * r
+    units, group = {}, None     # a key head's dq and dk over its group
     for g in range(r // pack):
         heads = [_heads_of((q_ref, k_ref, v_ref, beta_ref), g * pack + i,
-                           kd=kd, vd=vd, eps=eps) for i in range(pack)]
+                           units, kd=kd, vd=vd, eps=eps, ratio=ratio,
+                           per_head=per_head) for i in range(pack)]
         f = _pack_forward(heads, d, tri, c=c, dtype=dtype)
         inverse = inverse_ref[0, 0, g]                  # [C, pack C] float32
         inv = inverse.astype(dtype)
         wu = _dot(inv, _diag_of_heads(f["fed"]))
         for i, h in enumerate(heads):
-            wide = h["wide"]
+            wide = h["own"]
             h["state"] = enter_ref[0, 0, h["j"]]        # [V, K] float32
             h["entering"] = h["state"].astype(dtype)
             h["w"] = wu[:, i * both:i * both + kd].astype(dtype)
@@ -429,7 +527,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, gate_ref, beta_ref, a_ref, bias_ref,
             d_wu.append(jnp.concatenate(
                 [-_dot(d_fresh, h["entering"]), d_fresh.astype(_F32)],
                 axis=1).astype(dtype))                  # [C, K + V]
-            ds_sc[h["j"]] = h["d_left"] * d["last"][:, h["wide"]] \
+            ds_sc[h["j"]] = h["d_left"] * d["last"][:, h["own"]] \
                 + _dot_tn(h["d_out"], h["qd"]) - _dot_tn(d_fresh, h["w"])
         d_wu = _cat(d_wu, axis=1)
         d_fed = _dot_tn(inv, d_wu)                      # [pack C, pack (K+V)]
@@ -453,7 +551,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, gate_ref, beta_ref, a_ref, bias_ref,
             fed_k = h["d_kb"] * h["decayed"] * h["beta"]
             h["d_q"] = h["d_qd"] * h["decayed"]
             h["d_k"] = h["d_te"] * h["to_end"] + fed_k
-            last = jnp.sum(h["d_left"] * h["state"] * d["last"][:, h["wide"]],
+            last = jnp.sum(h["d_left"] * h["state"] * d["last"][:, h["own"]],
                            axis=0, keepdims=True) + jnp.sum(
                 h["d_te"] * h["to_end"] * h["k"], axis=0, keepdims=True)
             h["d_cum"] = h["d_q"] * h["q"] \
@@ -468,12 +566,12 @@ def _bwd_kernel(q_ref, k_ref, v_ref, gate_ref, beta_ref, a_ref, bias_ref,
             d_row = _dot(d_scores, _diag_of_heads(f["keys"][s]))
             d_key = _dot_tn(d_scores, f["rows"][s])     # [pack C, pack K]
             for i, h in enumerate(heads):
-                shrunk = d["shrunk"][own, h["wide"]]
+                shrunk = d["shrunk"][own, h["own"]]
                 mine = d_row[:, i * kd:(i + 1) * kd]
                 mine = jnp.concatenate(
                     [mine[:_SUB] * shrunk, mine[_SUB:] * shrunk], axis=0)
                 h["d_rows"].append(mine)
-                of_key = _block(d_key, i, c, kd) * rise[:, h["wide"]]
+                of_key = _block(d_key, i, c, kd) * rise[:, h["own"]]
                 h["d_k"] = h["d_k"] + of_key
                 h["d_cum"] = h["d_cum"] - of_key * h["k"]
                 if s:       # the first sub-block is referred to 0
@@ -489,11 +587,16 @@ def _bwd_kernel(q_ref, k_ref, v_ref, gate_ref, beta_ref, a_ref, bias_ref,
             d_ks = jnp.concatenate([x[_SUB:] for x in h["d_rows"]], axis=0)
             d_q, d_k = h["d_q"] + d_qs, h["d_k"] + d_ks
             d_cum[h["j"]] = h["d_cum"] + d_qs * h["q"] + d_ks * h["k"]
-            # the unit rows: x = x~ r, d x~ = r (dx - x (dx . x))
-            dq_ref[0, :, h["wide"]] = (h["rq"] * (d_q - h["q"] * jnp.sum(
-                d_q * h["q"], -1, keepdims=True))).astype(dq_ref.dtype)
-            dk_ref[0, :, h["wide"]] = (h["rk"] * (d_k - h["k"] * jnp.sum(
-                d_k * h["k"], -1, keepdims=True))).astype(dk_ref.dtype)
+            if ratio > 1:   # a key head's dq and dk, summed over its group
+                if h["j"] % ratio:  # ahead of the norm's pull-back (linear)
+                    d_q, d_k = d_q + group[0], d_k + group[1]
+                group = (d_q, d_k)
+            if (h["j"] + 1) % ratio == 0:   # the group's last member writes
+                # the unit rows: x = x~ r, d x~ = r (dx - x (dx . x))
+                dq_ref[0, :, h["wide"]] = (h["rq"] * (d_q - h["q"] * jnp.sum(
+                    d_q * h["q"], -1, keepdims=True))).astype(dq_ref.dtype)
+                dk_ref[0, :, h["wide"]] = (h["rk"] * (d_k - h["k"] * jnp.sum(
+                    d_k * h["k"], -1, keepdims=True))).astype(dk_ref.dtype)
             dv_ref[0, :, h["tall"]] = (h["d_vb"] * h["beta"]).astype(
                 dv_ref.dtype)
             d_beta[h["j"]] = (
@@ -501,6 +604,10 @@ def _bwd_kernel(q_ref, k_ref, v_ref, gate_ref, beta_ref, a_ref, bias_ref,
                 + jnp.sum(h["d_vb"] * h["v"], -1, keepdims=True)
                 + _sum_a_head(of_a, f["head"], i))
     dbeta_ref[0, 0] = _cat(d_beta, axis=1)
+    if per_head:    # the running sums' own: a head's channels read one G
+        dcum_ref[0, 0] = _cat([jnp.sum(x, -1, keepdims=True) for x in d_cum],
+                              axis=1)
+        return
     # the running sum, g = a softplus(z), z = gate + dt_bias
     d_g = _full(running, _cat(d_cum, axis=1), turned)
     d_z = d_g * a_row * jax.nn.sigmoid(d["z"])
@@ -515,10 +622,11 @@ def _packed(chunk, r):
     return 2 if 2 * chunk <= _LANES and r % 2 == 0 else 1
 
 
-def _specs(bsz, t, heads, kd, vd, chunk, r, up: bool):
+def _specs(bsz, t, heads, kd, vd, chunk, r, up: bool, ratio: int = 1):
     """(grid, heads a pack, BlockSpecs): grid (batch, head block, chunk
     step), the step walking the chunks upward, or downward for the
-    gradient."""
+    gradient. `keys`: q's and k's block where `ratio` value heads read
+    one key head, the step's r / ratio key heads."""
     import jax.experimental.pallas as pl
     chunks = t // chunk
     pack = _packed(chunk, r)
@@ -529,6 +637,8 @@ def _specs(bsz, t, heads, kd, vd, chunk, r, up: bool):
     return (bsz, heads // r, chunks), pack, dict(
         wide=pl.BlockSpec((1, chunk, r * kd), lambda b, h, s: (b, z(s), h)),
         tall=pl.BlockSpec((1, chunk, r * vd), lambda b, h, s: (b, z(s), h)),
+        keys=pl.BlockSpec((1, chunk, r // ratio * kd),
+                          lambda b, h, s: (b, z(s), h)),
         beta=pl.BlockSpec((1, 1, chunk, r), lambda b, h, s: (b, h, z(s), 0)),
         row=pl.BlockSpec((1, r * kd), lambda b, h, s: (0, h)),
         # a (batch, head block)'s share of a row's gradient, summed over
@@ -542,13 +652,16 @@ def _specs(bsz, t, heads, kd, vd, chunk, r, up: bool):
 
 def _call(kernel, name, grid, in_specs, out_specs, out_shape, scratch,
           interpret, *operands):
+    """`scratch`: the float32 VMEM scratch's shape, or a list of them."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     params = None if interpret else pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
+    shapes = scratch if isinstance(scratch, list) else [scratch]
     return pl.pallas_call(
         kernel, name=name, grid=grid, in_specs=in_specs, out_specs=out_specs,
-        out_shape=out_shape, scratch_shapes=[pltpu.VMEM(scratch, _F32)],
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM(shape, _F32) for shape in shapes],
         interpret=interpret, compiler_params=params)(*operands)
 
 
@@ -643,11 +756,130 @@ def _scan_bwd(static, kept, d_out):
 _scan.defvjp(_scan_fwd, _scan_bwd)
 
 
+# --- a decay a head, key heads under groups of value heads -------------------
+
+_STATIC_A_HEAD = _STATIC + ("ratio",)
+
+
+def _scratch_a_head(r, chunk, vd, kd):
+    """The state's scratch and _decays_a_head's two."""
+    return [(r, vd, kd), (3 + chunk // _SUB, chunk, r), (vd, r)]
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC_A_HEAD)
+def _forward_a_head(q, k, v, cum, beta, *, heads, chunk, r, eps, dtype,
+                    interpret, ratio):
+    """_forward where the decay is a head's and `ratio` value heads read
+    one key head: q, k [B, T, H / ratio x K] as their projections wrote
+    them (a step's block is its r / ratio key heads: nothing is repeated
+    ahead of the call), `cum` [B, blocks, T, R] the running sums of g
+    inside each chunk, laid out as beta is. Another kernel under another
+    name: the channel form's call is the one it was."""
+    bsz, t = q.shape[:2]
+    kd, vd = q.shape[2] * ratio // heads, v.shape[2] // heads
+    grid, pack, sp = _specs(bsz, t, heads, kd, vd, chunk, r, True, ratio)
+    return _call(
+        functools.partial(_fwd_kernel, r=r, pack=pack, kd=kd, vd=vd, eps=eps,
+                          scale=kd ** -0.5, dtype=dtype, ratio=ratio,
+                          per_head=True),
+        "gdn_scan_fwd", grid,
+        [sp["keys"], sp["keys"], sp["tall"], sp["beta"], sp["beta"]],
+        [sp["tall"], sp["state"], sp["inverse"]],
+        [jax.ShapeDtypeStruct(v.shape, v.dtype),
+         jax.ShapeDtypeStruct((bsz, grid[2], heads, vd, kd), _F32),
+         jax.ShapeDtypeStruct(
+             (bsz, grid[2], heads // pack, chunk, pack * chunk), _F32)],
+        _scratch_a_head(r, chunk, vd, kd), interpret, q, k, v, cum, beta)
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC_A_HEAD)
+def _backward_a_head(q, k, v, cum, beta, d_out, entering, inverse, *, heads,
+                     chunk, r, eps, dtype, interpret, ratio):
+    """The gradients of q and k (a key head's summed over its group
+    inside the kernel), v, the running sums and beta."""
+    bsz, t = q.shape[:2]
+    kd, vd = q.shape[2] * ratio // heads, v.shape[2] // heads
+    grid, pack, sp = _specs(bsz, t, heads, kd, vd, chunk, r, False, ratio)
+    return _call(
+        functools.partial(_bwd_kernel, r=r, pack=pack, kd=kd, vd=vd, eps=eps,
+                          scale=kd ** -0.5, dtype=dtype, ratio=ratio,
+                          per_head=True),
+        "gdn_scan_bwd", grid,
+        [sp["keys"], sp["keys"], sp["tall"], sp["beta"], sp["beta"],
+         sp["tall"], sp["state"], sp["inverse"]],
+        [sp["keys"], sp["keys"], sp["tall"], sp["beta"], sp["beta"]],
+        [jax.ShapeDtypeStruct(q.shape, q.dtype),
+         jax.ShapeDtypeStruct(k.shape, k.dtype),
+         jax.ShapeDtypeStruct(v.shape, v.dtype),
+         jax.ShapeDtypeStruct(cum.shape, _F32),
+         jax.ShapeDtypeStruct(beta.shape, _F32)],
+        _scratch_a_head(r, chunk, vd, kd), interpret, q, k, v, cum, beta,
+        d_out, entering, inverse)
+
+
+def _running_sums(gate, a_log, dt_bias, *, live, chunk, r):
+    """gate [B, T, H] raw -> the running sums inside each chunk of g =
+    -exp(a_log) softplus(gate + dt_bias), float32 [B, blocks, T, R] (a
+    head's a column of its block, as beta's are): 4 B a head and token.
+    Rows from `live` on are padding and decay nothing."""
+    bsz, t, h = gate.shape
+    g = -jnp.exp(a_log.astype(_F32)) * jax.nn.softplus(
+        gate.astype(_F32) + dt_bias.astype(_F32))
+    if live < t:
+        g = g * (jnp.arange(t) < live)[None, :, None]
+    cum = jnp.cumsum(g.reshape(bsz, -1, chunk, h), axis=2)
+    return cum.reshape(bsz, t, h // r, r).transpose(0, 2, 1, 3)
+
+
+def _split(static):
+    """(what _running_sums reads, what the kernels' calls read) of the
+    rule's static arguments."""
+    static = dict(static)
+    sums = dict(live=static.pop("live"), chunk=static["chunk"],
+                r=static["r"])
+    return sums, static
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def _scan_a_head(q, k, v, gate, beta, a_log, dt_bias, static):
+    return _scan_a_head_fwd(q, k, v, gate, beta, a_log, dt_bias, static)[0]
+
+
+def _scan_a_head_fwd(q, k, v, gate, beta, a_log, dt_bias, static):
+    """As _scan_fwd: all three results in use, so a replayed forward op's
+    call and the gradient op's re-traced one are one to the compiler. The
+    running sums are formed INSIDE the rule for the same reason: traced
+    under the gradient op's jax.vjp they were other instructions than
+    the forward op's (the softplus and the cumsum linearised), the two
+    calls' operands differed and the chip ran the forward kernel a third
+    time a layer (PR 64: 9 runs a step of 10.3 ms where 6 were meant)."""
+    sums, static_ = _split(static)
+    cum = _running_sums(gate, a_log, dt_bias, **sums)
+    out, entering, inverse = _forward_a_head(q, k, v, cum, beta, **static_)
+    return out, (q, k, v, gate, beta, a_log, dt_bias, entering, inverse)
+
+
+def _scan_a_head_bwd(static, kept, d_out):
+    q, k, v, gate, beta, a_log, dt_bias, entering, inverse = kept
+    sums, static = _split(static)
+    cum, pull = jax.vjp(functools.partial(_running_sums, **sums), gate,
+                        a_log, dt_bias)
+    d_q, d_k, d_v, d_cum, d_beta = _backward_a_head(
+        q, k, v, cum, beta, d_out, entering, inverse, **static)
+    d_gate, d_a, d_bias = pull(d_cum)
+    return d_q, d_k, d_v, d_gate, d_beta, d_a, d_bias
+
+
+_scan_a_head.defvjp(_scan_a_head_fwd, _scan_a_head_bwd)
+
+
 def kda_scan_kernels(q, k, v, gate, a_log, dt_bias, beta, chunk, eps,
                      dtype=jnp.float32, interpret=False, heads=None):
     """The op kda_scan (hybrid_ops._kda_scan's equations, arguments as
     its slots hold them: q, k, gate [B, T, H, K] and v [B, T, H, V] raw,
-    a_log [H], dt_bias [H x K], beta [B, T, H] raw) on the kernels, for
+    a_log [H], dt_bias [H x K], beta [B, T, H] raw; or, a decay a head,
+    gate [B, T, H], dt_bias [H] and q, k [B, T, H / ratio, K]: value
+    head j reads key head j // ratio) on the kernels, for
     shapes hybrid_ops.kda_scan_ineligible admits; the result [B, T, H, V]
     in v's dtype. beta's sigmoid and the padding of T to
     a multiple of `chunk` (beta = 0: such a row writes nothing, it comes
@@ -655,9 +887,10 @@ def kda_scan_kernels(q, k, v, gate, a_log, dt_bias, beta, chunk, eps,
     last chunk leaves, which nobody reads) stay jax.numpy, so autodiff
     carries them. `heads`: the heads one grid step owns (default
     heads_a_step; tools/kda_sweep.py passes others)."""
-    bsz, t, h, _ = q.shape
-    r = heads or heads_a_step(h, chunk, jnp.dtype(dtype).itemsize)
-    assert h % r == 0, (h, r)
+    bsz, t, h, _ = v.shape
+    ratio = h // q.shape[2]
+    r = heads or heads_a_step(h, chunk, jnp.dtype(dtype).itemsize, ratio)
+    assert h % r == 0 and r % ratio == 0, (h, r, ratio)
     pad = (-t) % chunk
     beta = jax.nn.sigmoid(beta.astype(_F32))
     flat = [x.reshape(bsz, t, -1) for x in (q, k, v, gate)]
@@ -668,5 +901,10 @@ def kda_scan_kernels(q, k, v, gate, a_log, dt_bias, beta, chunk, eps,
     beta = beta.reshape(bsz, t + pad, h // r, r).transpose(0, 2, 1, 3)
     static = (("heads", h), ("chunk", chunk), ("r", r), ("eps", float(eps)),
               ("dtype", jnp.dtype(dtype)), ("interpret", bool(interpret)))
-    out = _scan(*flat, beta, a_log, dt_bias, static)
+    if gate.ndim == 4:
+        assert ratio == 1, "a decay a channel reads its own key head"
+        out = _scan(*flat, beta, a_log, dt_bias, static)
+    else:
+        out = _scan_a_head(*flat, beta, a_log, dt_bias,
+                           static + (("ratio", ratio), ("live", t)))
     return out[:, :t].reshape(v.shape)

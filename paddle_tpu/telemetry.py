@@ -947,6 +947,10 @@ METRIC_CATALOG = {
     "kda_scan_total": _m("counter", ("chunk", "path"),
                          "forward kda_scan lowerings, by the delta rule's "
                          "chunk length and the path taken"),
+    "kda_scan_head_decay_total": _m(
+        "counter", ("path", "groups"),
+        "of kda_scan_total's lowerings, those with a decay a head, by path "
+        "and the value heads that read one key head"),
     "activation_kept_total": _m("counter", ("act",),
                                 "lowerings of an activation evaluated once "
                                 "and kept (ops/math_ops.py KEPT_ACTS)"),

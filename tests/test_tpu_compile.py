@@ -488,6 +488,38 @@ def test_delta_rule_kernels_compile(mosaic, one_chip, chunk, dtype, heads):
         == ["kda_scan_bwd", "kda_scan_fwd"]
 
 
+@pytest.mark.parametrize("key_heads,dtype,heads", [
+    (16, BF16, 8), (32, BF16, 8), (16, jnp.float32, 4)],
+    ids=["qwen3_next_cell", "equal_heads", "float32_no_amp"])
+def test_head_decay_delta_rule_kernels_compile(mosaic, one_chip, key_heads,
+                                               dtype, heads):
+    """The Qwen3-Next cell's delta rule, [1, 16384, 16 key | 32 value
+    heads x 128] in chunks of 64 under a decay a head: the forward kernel
+    and the gradient's (`gdn_scan_fwd` / `gdn_scan_bwd`: the channel
+    form's keep their names) at the heads a step
+    pallas_kda.heads_a_step gives, whole groups of value heads; q, k and
+    the gate reach the kernels at their own sizes (no repeat, no
+    broadcast: the calls' operands are [1, T, 16 x 128] and [1, 4, T,
+    8])."""
+    from paddle_tpu.ops import pallas_kda
+    t, h, k, v, chunk = 16384, 32, 128, 128, 64
+    ratio = h // key_heads
+    assert hybrid_ops.kda_scan_ineligible(chunk, k, v, ratio, True) is None
+    assert pallas_kda.heads_a_step(h, chunk, jnp.dtype(dtype).itemsize,
+                                   ratio) == heads
+
+    def grads(*operands):
+        return jax.grad(lambda *a: pallas_kda.kda_scan_kernels(
+            *a, chunk, 1e-6, dtype=dtype).astype(jnp.float32).sum(),
+            argnums=tuple(range(7)))(*operands)
+
+    assert _compile(grads, one_chip, ((1, t, key_heads, k), dtype),
+                    ((1, t, key_heads, k), dtype), ((1, t, h, v), dtype),
+                    ((1, t, h), dtype), ((h,), jnp.float32),
+                    ((h,), jnp.float32), ((1, t, h), dtype)) \
+        == ["gdn_scan_bwd", "gdn_scan_fwd"]
+
+
 @pytest.mark.parametrize("t,c,bias,lanes", [
     (8192, 4096, False, False), (8192, 4352, True, True),
     (4096, 6144, True, True), (8192, 4352, True, False)],
@@ -1185,6 +1217,99 @@ def test_shortconv_step_keeps_no_float32_rows_around_the_conv(mosaic,
              if re.search(r"pd\.causal_conv1d(_grad)?/", i.op_name or "")]
     assert len(under) >= 5
     assert not [i.name for i in under if i.opcode in ("copy", "transpose")]
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 15.75e9
+
+
+GDN_CELL = "qwen3-next-80b-a3b.train-gdn-t16k-ep16-share"
+
+
+def test_gated_delta_net_step_reads_q_k_and_the_gate_as_they_are(mosaic,
+                                                                 one_chip):
+    """The Qwen3-Next cell's step at its own 16,384-token sequence and
+    published widths, the layers held cut to published layers 2 and 3 (a
+    Gated DeltaNet layer and the gated attention layer, each with its
+    expert layer; the four take five minutes here and
+    tools/describe_step.py sized them, PR 64: 7.47e9 B of temporaries +
+    7.51e9 B of aliased state) under a checkpoint a layer: the delta rule
+    on the head-decay kernels of ops/pallas_kda.py, the forward kernel
+    twice (the first forward's, and the replayed one's, which is the call
+    the gradient op's re-trace makes) and the backward kernel once, the
+    channel form's kernels nowhere; q and k reach them as the bf16 [1, T,
+    16 x 128] arrays their convolutions wrote and the gate as float32 [1,
+    4, T, 8] running sums: NOTHING of [T, 32 x 128] is broadcast or
+    repeated ahead of a call and no dq or dk is reduced behind one;
+    attention at 16 query heads over 2 of 256 on the flash kernels with K
+    and V at their own 2 heads (`form=kernel`) and the backward in two
+    calls, dq and dkv (`form=split`, `reason=vmem`: at 16,384 rows of 256
+    lanes the fused call's dQ accumulator does not fit); 32 of 512
+    experts held on gmm / tgmm under the ladder's two rungs."""
+    from paddle_tpu import telemetry
+    cell = run.load_json("workloads", GDN_CELL)
+    config = dict(run.load_json("configs", cell["config"]),
+                  layers_held=[2, 3], num_hidden_layers=2)
+    tokens = config["sequence_length"]
+    assert tokens == 16384
+    booked = {name: dict(telemetry.read_series(name)) for name in (
+        "flash_backward_total", "attention_kv_groups_total",
+        "kda_scan_head_decay_total")}
+    compiled = describe_step.compile_step(cell, config, one_chip)
+    text = compiled.as_text()
+    kernels = [re.search(r'op_name="[^"]*?(\w+)\)*/pallas_call', line).group(1)
+               for line in text.splitlines() if KERNEL in line]
+    delta = {k: kernels.count(k) for k in set(kernels)
+             if "kda" in k or "gdn" in k}
+    assert delta == {"gdn_scan_fwd": 2, "gdn_scan_bwd": 1}, delta
+    conv = {k: kernels.count(k) for k in set(kernels) if "conv1d" in k}
+    assert conv == {"causal_conv1d_fwd": 3 + 3, "causal_conv1d_bwd": 3}, conv
+    flash = {k: kernels.count(k) for k in set(kernels) if "flash" in k}
+    # 16,384 rows of 256 lanes: the fused backward's dQ accumulator does
+    # not fit VMEM (`_split_reason`), so dq and dkv are two calls
+    assert pallas_attention._split_reason(
+        tokens, tokens, 256, 2, pallas_attention._TILE,
+        pallas_attention._MAJOR) == "vmem"
+    assert flash == {"flash_fwd": 1, "flash_dq": 1, "flash_dkv": 1}, flash
+    assert "gmm" in kernels and "tgmm" in kernels
+    assert "pd.moe_experts/cond" in text
+    added = {name: {k: v - booked[name].get(k, 0) for k, v in dict(
+        telemetry.read_series(name)).items() if v != booked[name].get(k, 0)}
+        for name in booked}
+    assert added == {
+        "flash_backward_total": {"form=split,reason=vmem": 1},
+        "attention_kv_groups_total": {
+            "op=scaled_dot_product_attention,groups=8,form=kernel,"
+            "ground=": 1},
+        "kda_scan_head_decay_total": {"path=kernel,groups=2": 1,
+                                      "path=kernel_replay,groups=2": 1}}, \
+        added
+    keys, values = f"bf16[1,{tokens},2048]", f"bf16[1,{tokens},4096]"
+    sums = f"f32[1,4,{tokens},8]"
+    for line in text.splitlines():
+        if KERNEL in line and "gdn_scan" in line:
+            operands = re.findall(r"\w+\[[\d,]*\]", re.search(
+                r"operand_layout_constraints=\{(.*?\})\}", line).group(1))
+            backward = "gdn_scan_bwd" in line
+            assert operands.count(keys) == 2, operands      # q and k
+            assert operands.count(values) == 1 + backward   # v (and do)
+            assert operands.count(sums) == 2                # G and beta
+            results = line.split(" custom-call(")[0]
+            assert results.count(keys) == (2 if backward else 0)
+    under = [i for i in xplane.hlo_instructions(text)
+             if re.search(r"pd\.kda_scan(_grad)?/", i.op_name or "")]
+    assert under and not [i.name for i in under if i.opcode == "while"]
+    # nothing as wide as [T, 32 heads x 128] under the op or its gradient
+    # (a broadcast gate, repeated keys or their pulled-back sums would be)
+    # but the kernels' own v, o, do, dv and entering states
+    wide = tokens * 32 * 128
+
+    def widest(i, dtype):
+        return max([int(np.prod([int(d) for d in dims.split(",")]))
+                    for dims in re.findall(dtype + r"\[([\d,]+)\]", i.shape)]
+                   or [0])
+
+    theirs = ("custom-call", "get-tuple-element")   # the kernels' results
+    assert not [(i.name, i.shape) for i in under if i.opcode not in theirs
+                and max(widest(i, "f32"), widest(i, "bf16")) >= wide]
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 15.75e9
 

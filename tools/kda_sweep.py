@@ -7,6 +7,8 @@ step owns, which is what pallas_kda._HEADS is written from.
     chiprun -- python3 tools/kda_sweep.py [B T H K V] [--chunks 32,64,128]
                         [--path chunked,kernel] [--heads-a-step 2,4,8]
                         [--dtype bfloat16] [--errors 1]
+                        [--decay head --key-heads 16
+                         --path chunked,kernel,broadcast]
 
 Times the forward and forward + gradient (jax.vjp on a random cotangent)
 of (q, k, v, gate, A_log, dt_bias, beta) -> o at one shape (default the
@@ -21,6 +23,17 @@ tools/pair_sum_sweep.py found). One JSON line per reading goes to
 chiprun_out/kda_sweep.jsonl; chipless (`JAX_PLATFORMS=cpu`) give a tiny
 shape, which the kernels take interpreted where the gate admits it: `1
 256 2 16 16 --chunks 16,32 --path chunked`, `1 256 2 128 128 --chunks 64`.
+
+`--decay head` gives the Gated DeltaNet form: the gate [B, T, H] and
+dt_bias [H], and with `--key-heads N` q and k at N heads under v's H (the
+Qwen3-Next cell: `1 16384 32 128 128 --decay head --key-heads 16`). The
+path `kernel` is then the kernels' own form (`gdn_scan_fwd` / `_bwd`: one
+exponent a row and head, q and k at their own head count, a key head's dq
+and dk summed in the walk) and `broadcast` the channel form's kernels
+behind a gate broadcast to [B, T, H, K] and q, k repeated to H heads, the
+widening and its pull-back inside the timed call: what the op would run
+had it kept one form (ISSUE 64 keeps the broadcast only if the direct
+form is no faster).
 
 On a v5e at the default shape, bf16, forward | forward + gradient ms (my
 chip runs, PR 56): XLA's form 10.35 | 27.79 at chunk 64 (its core alone,
@@ -47,19 +60,22 @@ CHAINED = 16
 SLOTS = ("q", "k", "v", "gate", "a_log", "dt_bias", "beta")
 
 
-def inputs(bsz, t, h, k, v, dtype, seed=0):
+def inputs(bsz, t, h, k, v, dtype, seed=0, key_heads=None, per_head=False):
     """The op's seven operands and a cotangent for o: q, k, v ~ N(0, 1),
     the gate ~ N(0, 0.5) and beta ~ N(0, 1) in `dtype`; A in U(1, 16) a
     head and dt_bias the softplus' inverse of a step log-uniform in
-    [0.001, 0.1] a channel, float32."""
+    [0.001, 0.1] a channel, float32. `key_heads`: q's and k's heads
+    (default h); `per_head`: the gate and dt_bias a head, not a channel."""
     rng = np.random.default_rng(seed)
+    hk = key_heads or h
+    decays = (h,) if per_head else (h, k)
 
     def normal(*shape, scale=1.0):
         return jnp.asarray(scale * rng.standard_normal(shape), dtype)
 
-    step = np.exp(rng.uniform(np.log(0.001), np.log(0.1), h * k))
-    return (normal(bsz, t, h, k), normal(bsz, t, h, k), normal(bsz, t, h, v),
-            normal(bsz, t, h, k, scale=0.5),
+    step = np.exp(rng.uniform(np.log(0.001), np.log(0.1), int(np.prod(decays))))
+    return (normal(bsz, t, hk, k), normal(bsz, t, hk, k), normal(bsz, t, h, v),
+            normal(bsz, t, *decays, scale=0.5),
             jnp.asarray(np.log(rng.uniform(1, 16, h)), jnp.float32),
             jnp.asarray(np.log(np.expm1(step)), jnp.float32),
             normal(bsz, t, h), normal(bsz, t, h, v))
@@ -91,10 +107,24 @@ def main():
     ap.add_argument("--heads-a-step", default="2,4,8")
     ap.add_argument("--dtype", default="bfloat16")
     ap.add_argument("--errors", type=int, default=1)
+    ap.add_argument("--decay", default="channel", choices=("channel", "head"))
+    ap.add_argument("--key-heads", type=int, default=0)
     args = ap.parse_args()
     dtype = jnp.dtype(args.dtype)
-    args_ = inputs(*args.shape, dtype)
+    per_head = args.decay == "head"
+    heads, width = args.shape[2], args.shape[3]
+    ratio = heads // (args.key_heads or heads)
+    args_ = inputs(*args.shape, dtype, key_heads=args.key_heads,
+                   per_head=per_head)
     eps = 1e-6
+
+    def widened(fn):
+        """`fn` on the channel form's operands, made here from a head's."""
+        def run(q, k, v, gate, a_log, dt_bias, beta):
+            q, k = (jnp.repeat(x, ratio, axis=2) for x in (q, k))
+            gate = jnp.broadcast_to(gate[..., None], v.shape[:3] + (width,))
+            return fn(q, k, v, gate, a_log, jnp.repeat(dt_bias, width), beta)
+        return run
     os.makedirs(os.path.dirname(OUT), exist_ok=True)
 
     def both(fn, operands):
@@ -108,18 +138,23 @@ def main():
                 forms.append((dict(chunk=chunk, path=path), lambda *a, c=chunk:
                               hybrid_ops.kda_scan_chunked(*a, c, eps, dtype)))
                 continue
-            reason = hybrid_ops.kda_scan_ineligible(chunk, *args.shape[3:])
-            if reason is not None:
-                print(f"chunk {chunk}: the gate declines ({reason})")
+            direct = path == "kernel"
+            reason = hybrid_ops.kda_scan_ineligible(
+                chunk, *args.shape[3:], ratio if direct else 1,
+                per_head and direct)
+            if reason is not None or (path == "broadcast") > per_head:
+                print(f"chunk {chunk}, {path}: the gate declines ({reason})")
                 continue
             for r in map(int, args.heads_a_step.split(",")):
-                if args.shape[2] % r:
+                if heads % r or (direct and r % ratio):
                     continue
-                forms.append((
-                    dict(chunk=chunk, path=path, heads_a_step=r),
-                    lambda *a, c=chunk, r=r: pallas_kda.kda_scan_kernels(
+
+                def kernels(*a, c=chunk, r=r):
+                    return pallas_kda.kda_scan_kernels(
                         *a, c, eps, dtype=dtype, heads=r,
-                        interpret=pallas_attention._interpret())))
+                        interpret=pallas_attention._interpret())
+                forms.append((dict(chunk=chunk, path=path, heads_a_step=r),
+                              kernels if direct else widened(kernels)))
     with open(OUT, "a") as log:
         want = None
         if args.errors:
@@ -127,8 +162,8 @@ def main():
             want = jax.jit(lambda: both(
                 lambda *a: hybrid_ops.kda_scan_chunked(*a, 64, eps), full))()
         for labels, fn in forms:
-            row = dict(shape=args.shape, dtype=args.dtype,
-                       device=jax.devices()[0].device_kind, **labels)
+            row = dict(shape=args.shape, dtype=args.dtype, decay=args.decay,
+                       key_heads=args.key_heads or heads, device=jax.devices()[0].device_kind, **labels)
             for name, with_gradient in (("fwd_ms", False),
                                         ("fwd_bwd_ms", True)):
                 row[name] = bench(chained(fn, with_gradient), *args_,
