@@ -21,9 +21,11 @@ made between two checkpoints is then dead at the segment's end, and what
 lives across the forward/backward boundary is the checkpoints and, of an
 op whose registry entry declares them (`OpDef.kept_in_replay`: the two
 attention ops' Out and LSE, dear to compute again and one activation to
-hold), the outputs it keeps: such an op stands in its replayed segment
-all the same, reads the first forward's values of them through the
-barrier under `Kept<slot>` inputs and runs nothing. The ops after the
+hold; the delta rule's chunk inverses), the outputs it keeps: such an op
+stands in its replayed segment all the same, reads the first forward's
+values of them through the barrier under `Kept<slot>` inputs and runs
+nothing, or only what it does not keep (kda_scan's Out and entering
+states, from the kept inverses). The ops after the
 last checkpoint are not replayed: the backward starts there. Without
 `checkpoints` nothing of this runs and the program is the one it was.
 """
@@ -173,17 +175,22 @@ def replayed_ops(program: Program, handed_on: Optional[bool] = None
     of the root block, read from the ops' RECOMPUTE_ATTR (the barrier is
     not one of them). `handed_on`: True, only the ops that stand in their
     segment and are handed outputs the first forward kept (they read
-    registry.KEPT_SLOT inputs and run nothing); False, only the ops that
-    run again; None, both."""
+    registry.KEPT_SLOT inputs); False, only the ops that run again, among
+    them an op handed some of its outputs and not all (kda_scan, handed
+    its Inverse); None, all."""
     found: Dict[int, List[str]] = {}
     for op in program.global_block().ops:
         seg = op.desc.attrs.get(RECOMPUTE_ATTR)
-        if seg is not None:
-            found.setdefault(seg, [])
-            handed = any(s.startswith(registry.KEPT_SLOT)
-                         for s in op.desc.inputs)
-            if op.type != "recompute_barrier" and handed_on in (None, handed):
-                found[seg].append(op.type)
+        if seg is None:
+            continue
+        found.setdefault(seg, [])
+        if op.type == "recompute_barrier":
+            continue
+        kept = {s[len(registry.KEPT_SLOT):] for s in op.desc.inputs
+                if s.startswith(registry.KEPT_SLOT)}
+        runs = not kept or kept < set(op.desc.outputs)
+        if handed_on is None or (bool(kept) if handed_on else runs):
+            found[seg].append(op.type)
     return found
 
 
@@ -233,8 +240,8 @@ def _append_replay(block: Block, seg: _Segment, kept: Set[str],
     needed = set()
     for i in seg.ops:
         fwd = block.ops[i].desc
-        generic = registry.get(fwd.type).grad is None
         for g in registry.make_grad_op_descs(fwd, no_grad):
+            generic = "__fwd_type__" in g.attrs
             read = fwd.input_arg_names() if generic else g.input_arg_names()
             needed |= {n for n in read if n in written}
     replayed = []
@@ -304,11 +311,12 @@ def _append_replay(block: Block, seg: _Segment, kept: Set[str],
         inputs = {s: [rename.get(n, n) for n in names]
                   for s, names in fwd.inputs.items()}
         # an op that declares kept outputs stands in its place and is
-        # handed them: its lowering returns them and runs nothing
-        # (registry.handed_on)
+        # handed them: its lowering returns them and runs nothing, or
+        # what it does not keep alone (registry.handed_on)
         for s in registry.get(fwd.type).kept_in_replay:
-            inputs[registry.KEPT_SLOT + s] = [handed[n]
-                                              for n in fwd.output(s)]
+            if fwd.output(s):
+                inputs[registry.KEPT_SLOT + s] = [handed[n]
+                                                  for n in fwd.output(s)]
         block.append_op(
             type=fwd.type,
             inputs=inputs,

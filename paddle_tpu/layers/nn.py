@@ -1327,6 +1327,26 @@ def _silu_conv(helper, t, conv_kernel):
     return out
 
 
+def _kda_scan(helper, inputs, chunk_size, l2_epsilon):
+    """Append the delta rule's op over `inputs` and return its Out. Where
+    the kernels take the shapes the op is built with the float32 outputs
+    they write for its gradient op (hybrid_ops.kda_scan_outputs): each
+    chunk's inverse, which outlives the forward where a checkpointed
+    segment replays the op, and the state entering each chunk."""
+    from ..ops.hybrid_ops import kda_scan_outputs
+    q, v, gate = (inputs[s][0] for s in ("Q", "V", "Gate"))
+    out = helper.create_tmp_variable(v.dtype)
+    kept = kda_scan_outputs(
+        int(chunk_size), int(q.shape[3]), int(v.shape[3]),
+        int(v.shape[2]) // int(q.shape[2]), len(gate.shape) == 3)
+    helper.append_op(
+        type="kda_scan", inputs=inputs,
+        outputs={"Out": [out],
+                 **{s: [helper.create_tmp_variable("float32")] for s in kept}},
+        attrs={"chunk_size": int(chunk_size), "epsilon": float(l2_epsilon)})
+    return out
+
+
 @_under_its_name
 def kda_mixer(x, num_heads, head_dim, conv_kernel=4, gate_rank=None,
               chunk_size=64, epsilon=1e-5, l2_epsilon=1e-6, out_scale=0.02,
@@ -1370,13 +1390,9 @@ def kda_mixer(x, num_heads, head_dim, conv_kernel=4, gate_rank=None,
     dt_bias = helper.create_parameter(
         attr=None, shape=[width], dtype=dtype,
         default_initializer=SoftplusInverseLogUniformInitializer())
-    o = helper.create_tmp_variable(dtype)
-    helper.append_op(
-        type="kda_scan",
-        inputs={"Q": [q], "K": [k], "V": [v], "Gate": [gate],
-                "ALog": [a_log], "DtBias": [dt_bias], "Beta": [beta]},
-        outputs={"Out": [o]},
-        attrs={"chunk_size": int(chunk_size), "epsilon": float(l2_epsilon)})
+    o = _kda_scan(helper, {"Q": [q], "K": [k], "V": [v], "Gate": [gate],
+                           "ALog": [a_log], "DtBias": [dt_bias],
+                           "Beta": [beta]}, chunk_size, l2_epsilon)
     o = elementwise_mul(
         rms_norm(o, epsilon=epsilon),
         by_head(_linear(_linear(x, rank), width, act="sigmoid")))
@@ -1434,13 +1450,9 @@ def gdn_mixer(x, num_key_heads, num_value_heads, key_dim, value_dim,
     dt_bias = helper.create_parameter(
         attr=None, shape=[num_value_heads], dtype=dtype,
         default_initializer=SoftplusInverseLogUniformInitializer())
-    o = helper.create_tmp_variable(dtype)
-    helper.append_op(
-        type="kda_scan",
-        inputs={"Q": [q], "K": [k], "V": [v], "Gate": [alpha],
-                "ALog": [a_log], "DtBias": [dt_bias], "Beta": [beta]},
-        outputs={"Out": [o]},
-        attrs={"chunk_size": int(chunk_size), "epsilon": float(l2_epsilon)})
+    o = _kda_scan(helper, {"Q": [q], "K": [k], "V": [v], "Gate": [alpha],
+                           "ALog": [a_log], "DtBias": [dt_bias],
+                           "Beta": [beta]}, chunk_size, l2_epsilon)
     o = rms_norm(o, gate=z, epsilon=epsilon, gate_behind=True)
     return _linear(reshape(o, [-1, seqlen, num_value_heads * value_dim]),
                    d_model, scale=out_scale)

@@ -18,7 +18,8 @@ decays and its state stay float32; the scan's four products and the
 expert products take bf16 operands with float32 accumulation; the rotary
 angles, their sines and cosines and the rotation itself are float32.
 
-Gradients: generic but the expert layer's and the short convolution's
+Gradients: generic but the expert layer's, the short convolution's and,
+on the kernels, the delta rule's
 (registry.generic_grad_lower: jax.vjp of the lowering, whose re-traced
 forward XLA merges with the original). The convolution and its explicit
 gradient op run, where time and channels are whole 128-wide blocks
@@ -34,14 +35,18 @@ and recomputes the blocks in the gradient's kernel (PERF.md section 6,
 PR 40); elsewhere its core is ssd_scan_chunked under a jax.checkpoint,
 which keeps the op's inputs and recomputes the blocks as XLA arrays; the
 chip's compiler merges a re-traced forward kernel with the original (4
-ssd_scan_fwd a step of the hybrid cell, not 8). The delta rule (kda_scan)
-likewise: where a head is a lane block (kda_scan_ineligible) the kernels
-of ops/pallas_kda.py under one jax.custom_vjp that keeps the op's inputs
-alone (the gradient runs the forward kernel again for the state entering
-each chunk and each chunk's inverse, then the backward kernel; PERF.md
-section 6, PR 56), elsewhere kda_chunked and autodiff's gradient of it.
-The expert layer is the
-exception to the generic rule: moe_experts writes the up product's rows
+ssd_scan_fwd a step of the hybrid cell, not 8). The delta rule (kda_scan),
+where a head is a lane block (kda_scan_ineligible), is the second
+exception: the kernels of ops/pallas_kda.py write the state entering each
+chunk and each chunk's inverse as the op's outputs Entering and Inverse,
+and an explicit gradient op reads them and runs the backward kernel alone
+(_kda_grad). Under checkpoints the INVERSES outlive the forward
+(kept_in_replay): a replayed op is handed them and runs the forward
+kernel that reads them and forms none, writing Out and the entering
+states again, which are four times the inverses' bytes and are not kept
+(PERF.md section 6, PR 56 and PR 65); elsewhere kda_chunked and
+autodiff's gradient of it, the generic rule. The expert layer is the
+first exception: moe_experts writes the up product's rows
 (and the gate's) as outputs and an explicit gradient op reads them
 (_experts_grad, as nn_ops._sdpa_grad reads LSE), so the gradient runs the
 pulled-back products alone. A layer that holds an eighth of the experts
@@ -74,7 +79,8 @@ from ..framework.desc import OpDesc
 from ..framework.framework import grad_var_name
 from . import kernel_choice, pallas_pair_sum
 from .common import in_var, same_as_input, set_out
-from .registry import NO_GRAD, op
+from .registry import (NO_GRAD, generic_grad_lower, generic_grad_op_descs,
+                       handed_on, op)
 
 __all__ = ["causal_conv1d_reference", "gmm_ineligible", "kda_chunked",
            "ssd_scan_chunked", "ssd_scan_ineligible"]
@@ -653,12 +659,29 @@ def _kda_chunk(state, q, k, v, g, beta, dtype):
 
 
 def _kda_infer(op_, block):
-    v = in_var(op_, block, "V")
-    if v is not None and v.shape is not None:
-        set_out(op_, block, "Out", list(v.shape), v.dtype)
+    v, q = in_var(op_, block, "V"), in_var(op_, block, "Q")
+    if v is None or v.shape is None:
+        return
+    set_out(op_, block, "Out", list(v.shape), v.dtype)
+    if q is None or q.shape is None:
+        return
+    chunk = op_.attr("chunk_size", 64)
+    b, t, h, width = v.shape
+    chunks = -(-t // chunk) if t >= 0 else -1
+    set_out(op_, block, "Entering", [b, chunks, h, width, q.shape[3]],
+            "float32")
+    # how many heads' blocks share a pack is the lowering's to know (the
+    # heads a step owns follow the compute dtype)
+    set_out(op_, block, "Inverse", [b, chunks, -1, chunk, -1], "float32")
 
 
 _KDA_OP = "kda_scan"
+# the two outputs the kernels write beside Out and the gradient op reads
+# (pallas_kda.kda_scan_forward's, in its order): the state entering each
+# chunk, which a replayed op writes again, and each chunk's inverse, kept
+# across a replayed segment (the op's registry entry). An op built
+# without them (XLA's path: kda_scan_outputs) has the generic gradient.
+_KDA_KEPT = ("Entering", "Inverse")
 
 
 def kda_scan_ineligible(chunk: int, k: int, v: int, ratio: int = 1,
@@ -688,6 +711,16 @@ def kda_scan_ineligible(chunk: int, k: int, v: int, ratio: int = 1,
     return None
 
 
+def kda_scan_outputs(chunk: int, k: int, v: int, ratio: int = 1,
+                     per_head: bool = True):
+    """The output slots a kda_scan op of these shapes is built with beside
+    Out (layers.kda_mixer, layers.gdn_mixer): _KDA_KEPT where the kernels
+    take the shapes (kda_scan_ineligible's arguments), none on XLA's
+    path."""
+    return () if kda_scan_ineligible(chunk, k, v, ratio, per_head) \
+        else _KDA_KEPT
+
+
 def kda_scan_chunked(q, k, v, gate, a_log, dt_bias, beta, chunk, eps,
                      dtype=jnp.float32):
     """The op kda_scan in jax.numpy around kda_chunked (the op's
@@ -712,7 +745,43 @@ def kda_scan_chunked(q, k, v, gate, a_log, dt_bias, beta, chunk, eps,
     return (out * width ** -0.5).astype(v.dtype)
 
 
-@op("kda_scan", infer_shape=_kda_infer)
+def _kda_operands(op_, ins):
+    """(the op's seven operands in the kernels' order, its chunk length,
+    whether the decay is a head's, the value heads a key head, the reason
+    the kernels decline the shapes or None)."""
+    operands = [jnp.asarray(ins[slot][0]) for slot in (
+        "Q", "K", "V", "Gate", "ALog", "DtBias", "Beta")]
+    chunk = op_.attr("chunk_size", 64)
+    per_head = operands[3].ndim == 3
+    ratio = operands[2].shape[2] // operands[0].shape[2]
+    return operands, chunk, per_head, ratio, kda_scan_ineligible(
+        chunk, operands[0].shape[3], operands[2].shape[3], ratio, per_head)
+
+
+def _kda_grad(fwd, no_grad_set):
+    """kda_scan_grad reads the op's inputs, Out's cotangent and the two
+    float32 outputs the forward kernel wrote (_KDA_KEPT), as
+    nn_ops._sdpa_grad reads LSE, and runs the backward kernel alone: the
+    generic maker's op traces the forward kernel again for them. An op
+    built without them (XLA's path) has the generic maker's."""
+    if not all(fwd.outputs.get(s) for s in _KDA_KEPT):
+        return generic_grad_op_descs(fwd, no_grad_set)
+    wanted = [s for s, names in fwd.inputs.items()
+              if names[0] not in no_grad_set]
+    if not wanted:
+        return []
+    return [OpDesc(
+        type=fwd.type + "_grad",
+        inputs={**{s: list(names) for s, names in fwd.inputs.items()},
+                **{s: fwd.output(s) for s in _KDA_KEPT},
+                "Out@GRAD": [grad_var_name(fwd.output("Out")[0])]},
+        outputs={s + "@GRAD": [grad_var_name(fwd.input(s)[0])]
+                 for s in wanted},
+        attrs=dict(fwd.attrs))]
+
+
+@op("kda_scan", infer_shape=_kda_infer, grad=_kda_grad,
+    kept_in_replay=("Inverse",))
 def _kda_scan(ctx, op_, ins):
     """Kimi Delta Attention between its short convolutions and its gated
     norm. Q, K [B, T, H, K] and V [B, T, H, V] (behind conv and silu),
@@ -734,10 +803,27 @@ def _kda_scan(ctx, op_, ins):
     chunked form and autodiff's gradient of it, each chunk computed again
     ahead of its pull-back; the triangular inverse's gradient its own),
     booked with the reason (pallas_fallback_total). Both take the same
-    operands at the same precision. kda_scan_total{chunk, path} books
-    each forward lowering: `kernel` or `chunked` a first forward's,
-    `kernel_replay` or `chunked_replay` that of an op a recomputed
-    segment runs again (a gradient's re-trace books nothing).
+    operands at the same precision.
+
+    Inverse [B, chunks, H / pack, C, pack C] and Entering [B, chunks, H,
+    V, K], float32, of an op built with them (kda_scan_outputs: the
+    kernels' shapes): each chunk's inverse and the state entering it, as
+    the forward kernel writes them and kda_scan_grad's backward kernel
+    reads them. Inverse is kept across a replayed segment (the registry
+    entry's kept_in_replay): the replayed op is handed it as KeptInverse,
+    and its forward kernel reads it where it would form it again from the
+    same K, Beta and decays, and writes Out and Entering (the inverse is
+    two fifths of the kernel to form and 16 KB a head and chunk to hold;
+    the entering states, 64 KB a head and chunk, are not kept). An op
+    built without the two (XLA's path, a raw op) has the generic
+    gradient, which on the kernels' shapes runs them under
+    pallas_kda.kda_scan_kernels' custom_vjp.
+
+    kda_scan_total{chunk, path} books each forward lowering: `kernel` or
+    `chunked` a first forward's; of an op a recomputed segment runs
+    again, `kernel_given_inverse` where it was handed the inverses,
+    `kernel_replay` or `chunked_replay` where it forms all again (a
+    gradient's re-trace books nothing).
 
     The Gated DeltaNet form (arXiv:2412.06464), told by the shapes alone:
     Gate [B, T, H] and DtBias [H], a decay a HEAD, g = -exp(ALog) *
@@ -750,28 +836,33 @@ def _kda_scan(ctx, op_, ins):
     they were); kda_scan_head_decay_total{path, groups} books such a
     lowering beside kda_scan_total."""
     from .pallas_attention import _interpret
-    from .pallas_kda import kda_scan_kernels
+    from .pallas_kda import kda_scan_forward, kda_scan_kernels
 
-    operands = [jnp.asarray(ins[slot][0]) for slot in (
-        "Q", "K", "V", "Gate", "ALog", "DtBias", "Beta")]
-    chunk = op_.attr("chunk_size", 64)
-    per_head = operands[3].ndim == 3
-    ratio = operands[2].shape[2] // operands[0].shape[2]
-    reason = kda_scan_ineligible(chunk, operands[0].shape[3],
-                                 operands[2].shape[3], ratio, per_head)
+    kept = handed_on(ctx, op_, ins)
+    operands, chunk, per_head, ratio, reason = _kda_operands(op_, ins)
+    keeps = all(s in op_.desc.outputs for s in _KDA_KEPT)
+    if keeps and reason:
+        raise ValueError(
+            f"kda_scan (Out {op_.desc.output('Out')}) was built with the "
+            f"outputs {_KDA_KEPT}, which the kernels alone write, at shapes "
+            f"they decline ({reason}): build it with kda_scan_outputs'")
     kernel_choice.book(_KDA_OP, reason)
     if not kernel_choice.in_retrace():
         from .. import telemetry
         from ..backward import RECOMPUTE_ATTR
         path = "kernel" if reason is None else "chunked"
-        if RECOMPUTE_ATTR in op_.desc.attrs:
+        if kept is not None:
+            path += "_given_inverse"
+        elif RECOMPUTE_ATTR in op_.desc.attrs:
             path += "_replay"
         telemetry.counter(
             "kda_scan_total",
             "lowerings of a forward kda_scan op, by its chunk length and "
             "the path taken (`kernel`: ops/pallas_kda.py's; `chunked`: "
             "XLA's; `kernel_replay`, `chunked_replay`: the same, run "
-            "again by a recomputed segment)",
+            "again by a recomputed segment; `kernel_given_inverse`: the "
+            "kernels' forward run again from the chunk inverses the first "
+            "run kept)",
             labels=("chunk", "path")).labels(chunk=str(chunk),
                                              path=path).inc()
         if per_head:
@@ -784,11 +875,39 @@ def _kda_scan(ctx, op_, ins):
                     path=path, groups=str(ratio)).inc()
     shared = dict(chunk=chunk, eps=op_.attr("epsilon", 1e-6),
                   dtype=_compute_dtype(ctx))
+    if keeps:
+        out, entering, inverse = kda_scan_forward(
+            *operands, interpret=_interpret(), **shared,
+            inverse=kept and jnp.asarray(kept["Inverse"][0]))
+        return {"Out": [out], "Entering": [entering], "Inverse": [inverse]}
     if reason is None:
         out = kda_scan_kernels(*operands, interpret=_interpret(), **shared)
     else:
         out = kda_scan_chunked(*operands, **shared)
     return {"Out": [out]}
+
+
+@op("kda_scan_grad", grad=NO_GRAD)
+def _kda_scan_grad(ctx, op_, ins):
+    """The gradients of kda_scan's seven inputs from Out's cotangent, the
+    inverses and the entering states the forward kernel wrote: the
+    backward kernel alone (pallas_kda.kda_scan_backward), no forward one.
+    The desc of an op built without the two is the generic maker's
+    (_kda_grad) and is lowered as such."""
+    if "__fwd_type__" in op_.desc.attrs:
+        return generic_grad_lower(ctx, op_, ins)
+    from .pallas_attention import _interpret
+    from .pallas_kda import kda_scan_backward
+
+    operands, chunk, _, _, _ = _kda_operands(op_, ins)
+    grads = kda_scan_backward(
+        *operands, *(jnp.asarray(ins[s][0]) for s in _KDA_KEPT),
+        jnp.asarray(ins["Out@GRAD"][0]), chunk=chunk,
+        eps=op_.attr("epsilon", 1e-6), dtype=_compute_dtype(ctx),
+        interpret=_interpret())
+    slots = ("Q", "K", "V", "Gate", "ALog", "DtBias", "Beta")
+    return {slot + "@GRAD": [g] for slot, g in zip(slots, grads)
+            if slot + "@GRAD" in op_.desc.outputs}
 
 
 # --- router ------------------------------------------------------------------

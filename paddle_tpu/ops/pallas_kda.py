@@ -43,16 +43,31 @@ so a product serves two heads and no vector register is half empty; the
 first level, whose products are by the identity, not formed; the second,
 whose operands hold one entry a row, written out on the VPU (`_inverse`).
 
-The gradient under one jax.custom_vjp. The forward kernel writes, beside
-o, the state ENTERING each chunk (float32 [B, chunks, H, V, K], which is
-what the scan kept as its carries) and each chunk's inverse M (float32,
-16 KB a head and chunk against the eight full-precision products that
-form it); a forward op drops the two, and the rule's forward keeps them
-for the backward kernel. A program's gradient op traces the forward again
-(registry.generic_grad_lower), so the two live inside the gradient op
-alone and the op's INPUTS are all that outlives the forward; that
-re-traced call is the state pass, and it is the very call a replayed
-forward op makes, so the compiler runs one for both. The backward kernel
+The gradient. The forward kernel writes, beside o, the state ENTERING
+each chunk (float32 [B, chunks, H, V, K], which is what the scan kept as
+its carries) and each chunk's inverse M (float32, 16 KB a head and chunk
+against the eight full-precision products that form it), and the backward
+kernel reads both. In a program they are the op's outputs Entering and
+Inverse (hybrid_ops._kda_scan calls kda_scan_forward) and its gradient op
+runs the backward kernel alone (kda_scan_backward): one forward kernel a
+layer and step. WHAT OUTLIVES THE FORWARD where a checkpointed segment
+replays the op (PR 65): the op's inputs' checkpoint and the INVERSES
+(registry: kept_in_replay); the replayed op is handed them and runs the
+forward kernel that READS them in place of _inverse (`given`: another
+call, `kda_scan_fwd_given` / `gdn_scan_fwd_given`, nothing else of the
+body differs) and writes o and the entering states again. The inverse
+hangs on k, beta and the decays alone and is the dear part of a chunk to
+form; the entering states are four times its bytes (537 against 134 MB a
+layer at 16,384 tokens and 32 heads) and are not kept. On a v5e the
+given-inverse call reads 2.05 ms where the plain one reads 5.68 at [1,
+8192, 32, 128, 128], 5.13 against 11.97 under a decay a head at 16,384
+tokens (tools/kda_sweep.py --path forward,given_inverse; my chip run, PR
+65), and 1.52 | 3.40 ms inside the two cells' steps. kda_scan_kernels
+is the same pair under one jax.custom_vjp, for callers outside a program
+(the tests, tools/kda_sweep.py, a raw op built without the two outputs):
+its rule's forward keeps the two for the backward kernel, and a gradient
+that re-traces it (registry.generic_grad_lower) runs the forward kernel a
+second time as its state pass. The backward kernel
 walks the chunks downward with the state's cotangent in the same
 scratch; each chunk's system and decayed copies are formed again in VMEM
 ahead of their pull-back. With do the output's cotangent and dS' that of
@@ -117,7 +132,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-__all__ = ["heads_a_step", "kda_scan_kernels"]
+__all__ = ["heads_a_step", "kda_scan_backward", "kda_scan_forward",
+           "kda_scan_kernels"]
 
 _LANES = 128
 _F32 = jnp.float32
@@ -401,18 +417,25 @@ def _heads_of(refs, j, units, *, kd, vd, eps, ratio, per_head):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, gate_ref, beta_ref, *rest,
-                r, pack, kd, vd, eps, scale, dtype, ratio=1, per_head=False):
+                r, pack, kd, vd, eps, scale, dtype, ratio=1, per_head=False,
+                given=False):
     """One chunk of a head block: o, and for the gradient the state
     entering the chunk and the chunk's inverse a pack. `rest`: a's and
     dt_bias' rows, the three results and the state's scratch; where the
     decay is a head's (`per_head`: `gate_ref` then holds the running sums
     G [1, 1, C, R]) no rows, and _decays_a_head's two scratches behind
-    the state's."""
+    the state's. `given`: the inverses are the last operand, those an
+    earlier run of this kernel wrote over the same k, beta and decays,
+    and not a result: none is formed."""
     import jax.experimental.pallas as pl
-    if per_head:
-        o_ref, enter_ref, inverse_ref, s_sc, e_sc, last_sc = rest
+    if not per_head:
+        a_ref, bias_ref, *rest = rest
+    if given:
+        inverse_ref, o_ref, enter_ref, s_sc, *rest = rest
     else:
-        a_ref, bias_ref, o_ref, enter_ref, inverse_ref, s_sc = rest
+        o_ref, enter_ref, inverse_ref, s_sc, *rest = rest
+    if per_head:
+        e_sc, last_sc = rest
 
     @pl.when(pl.program_id(2) == 0)
     def _start():
@@ -431,8 +454,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, gate_ref, beta_ref, *rest,
                            units, kd=kd, vd=vd, eps=eps, ratio=ratio,
                            per_head=per_head) for i in range(pack)]
         f = _pack_forward(heads, d, tri, c=c, dtype=dtype)
-        inverse = _inverse(f["kk"] * f["beta"], tri[2], tri[3], f["head"])
-        inverse_ref[0, 0, g] = inverse
+        if given:
+            inverse = inverse_ref[0, 0, g]              # [C, pack C] float32
+        else:
+            inverse = _inverse(f["kk"] * f["beta"], tri[2], tri[3], f["head"])
+            inverse_ref[0, 0, g] = inverse
         wu = _dot(inverse.astype(dtype), _diag_of_heads(f["fed"]))
         fresh, carried = [], []
         for i, h in enumerate(heads):
@@ -674,31 +700,52 @@ def _rows(a_log, dt_bias, kd):
             dt_bias.astype(_F32).reshape(1, -1))
 
 
+def _forward_call(kernel, name, grid, sp, in_specs, result, scratch,
+                  interpret, operands, inverse):
+    """A forward kernel's call -> (o, the entering states, the inverses).
+    `result`: (o's, the entering states', the inverses') ShapeDtypeStructs.
+    Handed `inverse`, the inverses an earlier call wrote over the same k,
+    beta and decays, the kernel reads them where it would form them
+    (`given`: another kernel under `name`_given) and they are returned as
+    they came."""
+    if inverse is None:
+        return _call(kernel, name, grid, in_specs,
+                     [sp["tall"], sp["state"], sp["inverse"]], list(result),
+                     scratch, interpret, *operands)
+    out, entering = _call(
+        functools.partial(kernel, given=True), name + "_given", grid,
+        in_specs + [sp["inverse"]], [sp["tall"], sp["state"]],
+        list(result[:2]), scratch, interpret, *operands, inverse)
+    return out, entering, inverse
+
+
+def _results(v, bsz, chunks, heads, vd, kd, chunk, pack):
+    return (jax.ShapeDtypeStruct(v.shape, v.dtype),
+            jax.ShapeDtypeStruct((bsz, chunks, heads, vd, kd), _F32),
+            jax.ShapeDtypeStruct(
+                (bsz, chunks, heads // pack, chunk, pack * chunk), _F32))
+
+
 @functools.partial(jax.jit, static_argnames=_STATIC)
-def _forward(q, k, v, gate, beta, a_log, dt_bias, *, heads, chunk, r, eps,
-             dtype, interpret):
+def _forward(q, k, v, gate, beta, a_log, dt_bias, inverse=None, *, heads,
+             chunk, r, eps, dtype, interpret):
     """(o [B, T, H x V] in v's dtype, the state entering each chunk [B,
     chunks, H, V, K], each chunk's inverse [B, chunks, H / pack, C, pack
     C]), the two the gradient reads in float32. One call for the forward
-    and for the gradient's state pass, over the op's own operands: where
-    the compiler finds both, a replayed forward beside the gradient that
-    follows it, it runs one."""
+    and for the custom_vjp's state pass, over the op's own operands: where
+    the compiler finds both it runs one. `inverse`: _forward_call's."""
     bsz, t = q.shape[:2]
     kd, vd = q.shape[2] // heads, v.shape[2] // heads
     grid, pack, sp = _specs(bsz, t, heads, kd, vd, chunk, r, True)
-    return _call(
+    return _forward_call(
         functools.partial(_fwd_kernel, r=r, pack=pack, kd=kd, vd=vd, eps=eps,
                           scale=kd ** -0.5, dtype=dtype),
-        "kda_scan_fwd", grid,
+        "kda_scan_fwd", grid, sp,
         [sp["wide"], sp["wide"], sp["tall"], sp["wide"], sp["beta"],
          sp["row"], sp["row"]],
-        [sp["tall"], sp["state"], sp["inverse"]],
-        [jax.ShapeDtypeStruct(v.shape, v.dtype),
-         jax.ShapeDtypeStruct((bsz, grid[2], heads, vd, kd), _F32),
-         jax.ShapeDtypeStruct(
-             (bsz, grid[2], heads // pack, chunk, pack * chunk), _F32)],
-        (r, vd, kd), interpret, q, k, v, gate, beta,
-        *_rows(a_log, dt_bias, kd))
+        _results(v, bsz, grid[2], heads, vd, kd, chunk, pack),
+        (r, vd, kd), interpret,
+        (q, k, v, gate, beta, *_rows(a_log, dt_bias, kd)), inverse)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
@@ -767,8 +814,8 @@ def _scratch_a_head(r, chunk, vd, kd):
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC_A_HEAD)
-def _forward_a_head(q, k, v, cum, beta, *, heads, chunk, r, eps, dtype,
-                    interpret, ratio):
+def _forward_a_head(q, k, v, cum, beta, inverse=None, *, heads, chunk, r,
+                    eps, dtype, interpret, ratio):
     """_forward where the decay is a head's and `ratio` value heads read
     one key head: q, k [B, T, H / ratio x K] as their projections wrote
     them (a step's block is its r / ratio key heads: nothing is repeated
@@ -778,18 +825,15 @@ def _forward_a_head(q, k, v, cum, beta, *, heads, chunk, r, eps, dtype,
     bsz, t = q.shape[:2]
     kd, vd = q.shape[2] * ratio // heads, v.shape[2] // heads
     grid, pack, sp = _specs(bsz, t, heads, kd, vd, chunk, r, True, ratio)
-    return _call(
+    return _forward_call(
         functools.partial(_fwd_kernel, r=r, pack=pack, kd=kd, vd=vd, eps=eps,
                           scale=kd ** -0.5, dtype=dtype, ratio=ratio,
                           per_head=True),
-        "gdn_scan_fwd", grid,
+        "gdn_scan_fwd", grid, sp,
         [sp["keys"], sp["keys"], sp["tall"], sp["beta"], sp["beta"]],
-        [sp["tall"], sp["state"], sp["inverse"]],
-        [jax.ShapeDtypeStruct(v.shape, v.dtype),
-         jax.ShapeDtypeStruct((bsz, grid[2], heads, vd, kd), _F32),
-         jax.ShapeDtypeStruct(
-             (bsz, grid[2], heads // pack, chunk, pack * chunk), _F32)],
-        _scratch_a_head(r, chunk, vd, kd), interpret, q, k, v, cum, beta)
+        _results(v, bsz, grid[2], heads, vd, kd, chunk, pack),
+        _scratch_a_head(r, chunk, vd, kd), interpret, (q, k, v, cum, beta),
+        inverse)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC_A_HEAD)
@@ -873,6 +917,39 @@ def _scan_a_head_bwd(static, kept, d_out):
 _scan_a_head.defvjp(_scan_a_head_fwd, _scan_a_head_bwd)
 
 
+def _laid_out(q, k, v, gate, beta, *, chunk, r):
+    """The op's q, k, v and gate as [B, T', H x K] rows and its raw beta
+    as sigmoid(beta) [B, blocks, T', R], a head's a column of its block,
+    T' = T padded to whole chunks (beta = 0 there: such a row writes
+    nothing, it comes behind every row that is read, and what it decays is
+    the state the last chunk leaves, which nobody reads). jax.numpy, so
+    autodiff carries it."""
+    bsz, t, h, _ = v.shape
+    pad = (-t) % chunk
+    beta = jax.nn.sigmoid(beta.astype(_F32))
+    flat = [x.reshape(bsz, t, -1) for x in (q, k, v, gate)]
+    if pad:
+        flat = [jnp.pad(x, ((0, 0), (0, pad), (0, 0))) for x in flat]
+        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
+    return (*flat,
+            beta.reshape(bsz, t + pad, h // r, r).transpose(0, 2, 1, 3))
+
+
+def _static(q, v, gate, chunk, eps, dtype, interpret, heads):
+    """(the heads a step owns, whether the decay is a head's, the rule's
+    static arguments as _scan / _scan_a_head take them)."""
+    t, h = v.shape[1:3]
+    ratio = h // q.shape[2]
+    r = heads or heads_a_step(h, chunk, jnp.dtype(dtype).itemsize, ratio)
+    assert h % r == 0 and r % ratio == 0, (h, r, ratio)
+    static = (("heads", h), ("chunk", chunk), ("r", r), ("eps", float(eps)),
+              ("dtype", jnp.dtype(dtype)), ("interpret", bool(interpret)))
+    if gate.ndim == 4:
+        assert ratio == 1, "a decay a channel reads its own key head"
+        return r, False, static
+    return r, True, static + (("ratio", ratio), ("live", t))
+
+
 def kda_scan_kernels(q, k, v, gate, a_log, dt_bias, beta, chunk, eps,
                      dtype=jnp.float32, interpret=False, heads=None):
     """The op kda_scan (hybrid_ops._kda_scan's equations, arguments as
@@ -881,30 +958,60 @@ def kda_scan_kernels(q, k, v, gate, a_log, dt_bias, beta, chunk, eps,
     gate [B, T, H], dt_bias [H] and q, k [B, T, H / ratio, K]: value
     head j reads key head j // ratio) on the kernels, for
     shapes hybrid_ops.kda_scan_ineligible admits; the result [B, T, H, V]
-    in v's dtype. beta's sigmoid and the padding of T to
-    a multiple of `chunk` (beta = 0: such a row writes nothing, it comes
-    behind every row that is read, and what it decays is the state the
-    last chunk leaves, which nobody reads) stay jax.numpy, so autodiff
-    carries them. `heads`: the heads one grid step owns (default
-    heads_a_step; tools/kda_sweep.py passes others)."""
-    bsz, t, h, _ = v.shape
-    ratio = h // q.shape[2]
-    r = heads or heads_a_step(h, chunk, jnp.dtype(dtype).itemsize, ratio)
-    assert h % r == 0 and r % ratio == 0, (h, r, ratio)
-    pad = (-t) % chunk
-    beta = jax.nn.sigmoid(beta.astype(_F32))
-    flat = [x.reshape(bsz, t, -1) for x in (q, k, v, gate)]
-    if pad:
-        flat = [jnp.pad(x, ((0, 0), (0, pad), (0, 0))) for x in flat]
-        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
-    # [B, T, H] -> [B, blocks, T, R]: a head's beta a column of its block
-    beta = beta.reshape(bsz, t + pad, h // r, r).transpose(0, 2, 1, 3)
-    static = (("heads", h), ("chunk", chunk), ("r", r), ("eps", float(eps)),
-              ("dtype", jnp.dtype(dtype)), ("interpret", bool(interpret)))
-    if gate.ndim == 4:
-        assert ratio == 1, "a decay a channel reads its own key head"
-        out = _scan(*flat, beta, a_log, dt_bias, static)
+    in v's dtype, differentiable (one jax.custom_vjp whose gradient runs
+    the forward kernel again for its state pass: for callers outside a
+    program, whose op keeps the inverses and has a gradient op of its
+    own, kda_scan_forward and kda_scan_backward). `heads`: the heads one
+    grid step owns (default heads_a_step; tools/kda_sweep.py passes
+    others)."""
+    r, per_head, static = _static(q, v, gate, chunk, eps, dtype, interpret,
+                                  heads)
+    laid = _laid_out(q, k, v, gate, beta, chunk=chunk, r=r)
+    out = (_scan_a_head if per_head else _scan)(*laid, a_log, dt_bias,
+                                                 static)
+    return out[:, :v.shape[1]].reshape(v.shape)
+
+
+def kda_scan_forward(q, k, v, gate, a_log, dt_bias, beta, chunk, eps,
+                     dtype=jnp.float32, interpret=False, heads=None,
+                     inverse=None):
+    """kda_scan_kernels' forward alone -> (the result [B, T, H, V], the
+    state entering each chunk [B, chunks, H, V, K], each chunk's inverse
+    [B, chunks, H / pack, C, pack C]), the two float32, which
+    kda_scan_backward reads. `inverse`: the inverses an earlier call
+    wrote from the same k, gate, a_log, dt_bias and beta; the kernel then
+    reads them and forms none (they are the dear part of a chunk: eight
+    full-precision products a pack of heads), and they come back as they
+    came."""
+    r, per_head, static = _static(q, v, gate, chunk, eps, dtype, interpret,
+                                  heads)
+    *rows, gate, beta = _laid_out(q, k, v, gate, beta, chunk=chunk, r=r)
+    if per_head:
+        sums, static = _split(static)
+        out, entering, inverse = _forward_a_head(
+            *rows, _running_sums(gate, a_log, dt_bias, **sums), beta, inverse,
+            **static)
     else:
-        out = _scan_a_head(*flat, beta, a_log, dt_bias,
-                           static + (("ratio", ratio), ("live", t)))
-    return out[:, :t].reshape(v.shape)
+        out, entering, inverse = _forward(*rows, gate, beta, a_log, dt_bias,
+                                          inverse, **dict(static))
+    return out[:, :v.shape[1]].reshape(v.shape), entering, inverse
+
+
+def kda_scan_backward(q, k, v, gate, a_log, dt_bias, beta, entering, inverse,
+                      d_out, chunk, eps, dtype=jnp.float32, interpret=False,
+                      heads=None):
+    """The gradients of kda_scan_forward's first seven arguments, in
+    their order, from its two float32 results and its first's cotangent:
+    the backward kernel alone, and the pull-back of the jax.numpy around
+    it (_laid_out; the running sums of a decay a head)."""
+    r, per_head, static = _static(q, v, gate, chunk, eps, dtype, interpret,
+                                  heads)
+    laid, pull = jax.vjp(functools.partial(_laid_out, chunk=chunk, r=r),
+                         q, k, v, gate, beta)
+    bsz, t = v.shape[:2]
+    d_out = jnp.pad(d_out.astype(v.dtype).reshape(bsz, t, -1),
+                    ((0, 0), (0, laid[0].shape[1] - t), (0, 0)))
+    *d_laid, d_a, d_bias = (_scan_a_head_bwd if per_head else _scan_bwd)(
+        static, (*laid, a_log, dt_bias, entering, inverse), d_out)
+    d_q, d_k, d_v, d_gate, d_beta = pull(tuple(d_laid))
+    return d_q, d_k, d_v, d_gate, d_a, d_bias, d_beta

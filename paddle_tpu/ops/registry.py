@@ -49,10 +49,13 @@ class OpDef:
     # (handed_on) where another op would run a second time. For an op
     # whose cost to compute again grows faster than what it leaves: the
     # two attention ops, whose work grows with the (query, key) pairs and
-    # whose Out and LSE grow with the tokens. A `mul` or a norm is the
-    # opposite (cheap to run again, as wide as its input to hold) and is
-    # what recomputation is there not to keep. Python-side only: no
-    # forward op's desc carries it.
+    # whose Out and LSE grow with the tokens; and the delta rule's
+    # Inverse, eight full-precision products a chunk and pack of heads to
+    # form and 16 KB a head to hold, where the op's other outputs are run
+    # again from it. A `mul` or a norm is the opposite (cheap to run
+    # again, as wide as its input to hold) and is what recomputation is
+    # there not to keep. Python-side only: no forward op's desc carries
+    # it, and a slot an op was built without is not kept.
     kept_in_replay: Sequence[str] = field(default_factory=tuple)
 
 
@@ -89,9 +92,12 @@ def handed_on(ctx, op_, ins) -> Optional[Dict[str, list]]:
     """{output slot: its values} of a replayed op that was handed its own
     result (OpDef.kept_in_replay), None for every other: the first line
     of such an op's lowering, ahead of whatever books or counts a
-    lowering, since nothing is lowered there. Books the hand-over, as
-    every lowering books what it chose: recompute_kept_total{program,
-    type} and the values' bytes as traced, recompute_kept_bytes{program}."""
+    lowering. An op that keeps all its outputs (the attention ops)
+    returns them and runs nothing; one that keeps some (kda_scan's
+    Inverse) returns them beside the others, which it runs again from
+    them. Books the hand-over, as every lowering books what it chose:
+    recompute_kept_total{program, type} and the values' bytes as traced,
+    recompute_kept_bytes{program}."""
     slots = get(op_.type).kept_in_replay
     if not slots or KEPT_SLOT + slots[0] not in ins:
         return None
@@ -164,6 +170,14 @@ def make_grad_op_descs(fwd: OpDesc, no_grad_set: set) -> List[OpDesc]:
         return []
     if callable(opdef.grad):
         return opdef.grad(fwd, no_grad_set)
+    return generic_grad_op_descs(fwd, no_grad_set)
+
+
+def generic_grad_op_descs(fwd: OpDesc, no_grad_set: set) -> List[OpDesc]:
+    """The default maker's `<type>_grad` desc, which generic_grad_lower
+    runs (`__fwd_type__` marks it): also what a custom maker returns for
+    the ops it has nothing of its own for."""
+    opdef = get(fwd.type)
     assert opdef.lower is not None, (
         f"op '{fwd.type}' has no lowering and no custom grad maker")
     inputs: Dict[str, List[str]] = {}

@@ -835,7 +835,9 @@ METRIC_CATALOG = {
     "recompute_kept_total": _m(
         "counter", ("program", "type"),
         "replayed forward ops handed the outputs their first run kept "
-        "(registry.OpDef.kept_in_replay), a lowering, by op type"),
+        "(registry.OpDef.kept_in_replay: all of them, or kda_scan's chunk "
+        "inverses, from which it runs the rest again), a lowering, by op "
+        "type"),
     "recompute_kept_bytes": _m(
         "counter", ("program",),
         "bytes of the outputs kept across the forward for a replayed op, "
@@ -946,7 +948,10 @@ METRIC_CATALOG = {
         "with the ground"),
     "kda_scan_total": _m("counter", ("chunk", "path"),
                          "forward kda_scan lowerings, by the delta rule's "
-                         "chunk length and the path taken"),
+                         "chunk length and the path taken (a replayed op's: "
+                         "`kernel_given_inverse` where it was handed the "
+                         "chunk inverses its first run kept, else "
+                         "`kernel_replay` / `chunked_replay`)"),
     "kda_scan_head_decay_total": _m(
         "counter", ("path", "groups"),
         "of kda_scan_total's lowerings, those with a decay a head, by path "

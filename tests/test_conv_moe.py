@@ -427,9 +427,10 @@ def test_shares_add_up_to_the_uncut_layer():
 # causal_conv1d, as the parent commit (PR 61) built them: the first 16 hex
 # digits of sha256 over Program.to_json(). No gate, no attribute: their
 # cells' programs are the parent's (the eight programs without the op are
-# held by tests/test_causal_conv1d_kernels.py).
+# held by tests/test_causal_conv1d_kernels.py; Kimi-Linear's main program
+# as PR 65 builds its kda_scan ops, with Inverse and Entering beside Out).
 PARENT_PROGRAMS = {
-    "kimi-linear-48b-a3b-instruct": ("2e7f57cbc1436779", "f56ab18f9f796b0c"),
+    "kimi-linear-48b-a3b-instruct": ("2c1c4f892d9bd452", "f56ab18f9f796b0c"),
     "granite-4.0-h-micro": ("cbaa1f07490aec02", "aef2a12bd424d1b5"),
     "nemotron3-nano-30b-a3b": ("6ac48d33c64fc359", "2cd691daa316a1c1"),
 }

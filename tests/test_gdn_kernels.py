@@ -296,13 +296,15 @@ def test_the_channel_forms_calls_are_the_parents(call, shape, chunk, r,
 # (main, startup) of the eleven accepted language configurations and the
 # ResNet as the parent commit (PR 63) built them: the first 16 hex digits
 # of sha256 over Program.to_json(). rms_norm, rotary_embedding, moe_block
-# and kda_mixer without the new arguments build the ops of before.
+# and kda_mixer without the new arguments build the ops of before
+# (Kimi-Linear's main program as PR 65 builds it: its four kda_scan ops
+# write Inverse and Entering beside Out, hybrid_ops.kda_scan_outputs).
 PARENT_PROGRAMS = {
     "glm-4.7-flash": ("c6c56c119b5b8e5c", "57465f9570324186"),
     "gpt2-large": ("90b85e99fedb4110", "0abfed69b2161959"),
     "gpt2": ("32530ba784525f48", "6cae3670f852b823"),
     "granite-4.0-h-micro": ("cbaa1f07490aec02", "aef2a12bd424d1b5"),
-    "kimi-linear-48b-a3b-instruct": ("2e7f57cbc1436779", "f56ab18f9f796b0c"),
+    "kimi-linear-48b-a3b-instruct": ("2c1c4f892d9bd452", "f56ab18f9f796b0c"),
     "laguna-xs.2": ("d9e2dffcce8f0f43", "2bfd552d47366495"),
     "lfm2-24b-a2b": ("d27c6e5344c2f8dc", "816e981734df8260"),
     "nemotron3-nano-30b-a3b": ("6ac48d33c64fc359", "2cd691daa316a1c1"),

@@ -449,7 +449,8 @@ def test_the_gate_and_the_mix_stay_float32_under_bf16_activations():
 # sha256 over Program.to_json(): layers.gated_mlp's `name` names nothing
 # unless asked (the three that build causal_conv1d as PR 60 builds them:
 # an explicit gradient op, and `time_on_lanes` under mamba2_mixer; their
-# startup programs are the parent's)
+# startup programs are the parent's; Kimi-Linear's kda_scan ops as PR 65
+# builds them, with Inverse and Entering beside Out)
 PARENT_PROGRAMS = {
     "gpt2": ("32530ba784525f48", "6cae3670f852b823"),
     "gpt2-large": ("90b85e99fedb4110", "0abfed69b2161959"),
@@ -459,7 +460,7 @@ PARENT_PROGRAMS = {
     "smallthinker-21b-a3b-instruct": ("dc6827508c9e3acb", "33caad39b72bf4c9"),
     "granite-4.0-h-micro": ("cbaa1f07490aec02", "aef2a12bd424d1b5"),
     "laguna-xs.2": ("d9e2dffcce8f0f43", "2bfd552d47366495"),
-    "kimi-linear-48b-a3b-instruct": ("2e7f57cbc1436779", "f56ab18f9f796b0c"),
+    "kimi-linear-48b-a3b-instruct": ("2c1c4f892d9bd452", "f56ab18f9f796b0c"),
 }
 
 

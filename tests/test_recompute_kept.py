@@ -262,7 +262,7 @@ def test_an_op_without_the_declaration_is_replayed_as_before(monkeypatch):
     assert registry.get("mul").kept_in_replay == ()
     assert [t for t in registry.registered_ops()
             if registry.get(t).kept_in_replay] == sorted(
-                [BLOCK_DIFFUSION, SDPA])
+                [BLOCK_DIFFUSION, "kda_scan", SDPA])
 
 
 @pytest.mark.parametrize("kind", ["two_kv_heads", "block_diffusion"])

@@ -520,6 +520,39 @@ def test_head_decay_delta_rule_kernels_compile(mosaic, one_chip, key_heads,
         == ["gdn_scan_bwd", "gdn_scan_fwd"]
 
 
+@pytest.mark.parametrize("t,key_heads,per_head,dtype,name", [
+    (8192, 32, False, BF16, "kda_scan"), (16384, 16, True, BF16, "gdn_scan"),
+    (8192, 32, False, jnp.float32, "kda_scan")],
+    ids=["kimi_cell", "qwen3_next_cell", "float32_no_amp"])
+def test_given_inverse_delta_rule_kernels_compile(mosaic, one_chip, t,
+                                                  key_heads, per_head, dtype,
+                                                  name):
+    """The forward kernel a replayed kda_scan op runs at the two cells'
+    shapes (PR 65): handed the float32 inverses of an earlier run as its
+    last operand, it is one Mosaic call under the forward's name +
+    `_given` inside the default scoped VMEM, beside the plain forward and
+    the backward alone: the three calls a checkpointed layer's step
+    holds."""
+    from paddle_tpu.ops import pallas_kda
+    h, k, v, chunk = 32, 128, 128, 64
+    gate = ((1, t, h), dtype) if per_head else ((1, t, h, k), dtype)
+    bias = ((h,), jnp.float32) if per_head else ((h * k,), jnp.float32)
+    shapes = (((1, t, key_heads, k), dtype), ((1, t, key_heads, k), dtype),
+              ((1, t, h, v), dtype), gate, ((h,), jnp.float32), bias,
+              ((1, t, h), dtype))
+
+    def layer(*a):
+        out, entering, inverse = pallas_kda.kda_scan_forward(
+            *a, chunk, 1e-6, dtype=dtype)
+        again, entering, inverse = pallas_kda.kda_scan_forward(
+            *a, chunk, 1e-6, dtype=dtype, inverse=inverse)
+        return pallas_kda.kda_scan_backward(
+            *a, entering, inverse, out + again, chunk, 1e-6, dtype=dtype)
+
+    assert _compile(layer, one_chip, *shapes) == [
+        name + "_bwd", name + "_fwd", name + "_fwd_given"]
+
+
 @pytest.mark.parametrize("t,c,bias,lanes", [
     (8192, 4096, False, False), (8192, 4352, True, True),
     (4096, 6144, True, True), (8192, 4352, True, False)],
@@ -1097,10 +1130,11 @@ def test_delta_rule_step_compiles_with_both_kinds_of_mixer(mosaic, one_chip):
     backward; the experts on gmm / tgmm under the ladder's one switch
     each way (8 of 256 held: two rungs); the delta rule on the kernels of
     ops/pallas_kda.py (PR 56): under the op and its gradient the forward
-    kernel twice (the first forward's; and the replayed one's, which is
-    the very call the gradient op's re-trace makes for the entering
-    states, so the compiler runs one for both: a third would be 5.7 ms a
-    layer and step on the chip) and the backward kernel once, NO loop, and nothing of [., T, T] under either mixer;
+    kernel once (the first forward's, whose inverses are kept), the
+    given-inverse forward kernel once (the replayed op's, which reads them
+    and writes Out and the entering states: PR 65; the gradient op traces
+    no forward) and the backward kernel once, NO loop, and nothing of [.,
+    T, T] under either mixer;
     the three short convolutions on the kernels of ops/pallas_conv1d.py
     (PR 60): three forward calls, three replayed ones and three of the
     explicit gradient op's, which traces no forward;
@@ -1124,7 +1158,8 @@ def test_delta_rule_step_compiles_with_both_kinds_of_mixer(mosaic, one_chip):
     assert kernels.count("pair_sum") == 2 * 2
     assert "pd.moe_experts/cond" in text
     delta = {k: kernels.count(k) for k in set(kernels) if "kda" in k}
-    assert delta == {"kda_scan_fwd": 2, "kda_scan_bwd": 1}, delta
+    assert delta == {"kda_scan_fwd": 1, "kda_scan_fwd_given": 1,
+                     "kda_scan_bwd": 1}, delta
     conv = {k: kernels.count(k) for k in set(kernels) if "conv1d" in k}
     assert conv == {"causal_conv1d_fwd": 3 + 3, "causal_conv1d_bwd": 3}, conv
     tokens = config["sequence_length"]
@@ -1233,8 +1268,9 @@ def test_gated_delta_net_step_reads_q_k_and_the_gate_as_they_are(mosaic,
     tools/describe_step.py sized them, PR 64: 7.47e9 B of temporaries +
     7.51e9 B of aliased state) under a checkpoint a layer: the delta rule
     on the head-decay kernels of ops/pallas_kda.py, the forward kernel
-    twice (the first forward's, and the replayed one's, which is the call
-    the gradient op's re-trace makes) and the backward kernel once, the
+    once (the first forward's, whose inverses are kept), the given-inverse
+    one once (the replayed op's: PR 65; its last operand the float32 [1,
+    256, 16, 64, 128] inverses) and the backward kernel once, the
     channel form's kernels nowhere; q and k reach them as the bf16 [1, T,
     16 x 128] arrays their convolutions wrote and the gate as float32 [1,
     4, T, 8] running sums: NOTHING of [T, 32 x 128] is broadcast or
@@ -1259,7 +1295,8 @@ def test_gated_delta_net_step_reads_q_k_and_the_gate_as_they_are(mosaic,
                for line in text.splitlines() if KERNEL in line]
     delta = {k: kernels.count(k) for k in set(kernels)
              if "kda" in k or "gdn" in k}
-    assert delta == {"gdn_scan_fwd": 2, "gdn_scan_bwd": 1}, delta
+    assert delta == {"gdn_scan_fwd": 1, "gdn_scan_fwd_given": 1,
+                     "gdn_scan_bwd": 1}, delta
     conv = {k: kernels.count(k) for k in set(kernels) if "conv1d" in k}
     assert conv == {"causal_conv1d_fwd": 3 + 3, "causal_conv1d_bwd": 3}, conv
     flash = {k: kernels.count(k) for k in set(kernels) if "flash" in k}
@@ -1279,11 +1316,12 @@ def test_gated_delta_net_step_reads_q_k_and_the_gate_as_they_are(mosaic,
         "attention_kv_groups_total": {
             "op=scaled_dot_product_attention,groups=8,form=kernel,"
             "ground=": 1},
-        "kda_scan_head_decay_total": {"path=kernel,groups=2": 1,
-                                      "path=kernel_replay,groups=2": 1}}, \
-        added
+        "kda_scan_head_decay_total": {
+            "path=kernel,groups=2": 1,
+            "path=kernel_given_inverse,groups=2": 1}}, added
     keys, values = f"bf16[1,{tokens},2048]", f"bf16[1,{tokens},4096]"
     sums = f"f32[1,4,{tokens},8]"
+    inverses = f"f32[1,{tokens // 64},16,64,128]"
     for line in text.splitlines():
         if KERNEL in line and "gdn_scan" in line:
             operands = re.findall(r"\w+\[[\d,]*\]", re.search(
@@ -1294,6 +1332,12 @@ def test_gated_delta_net_step_reads_q_k_and_the_gate_as_they_are(mosaic,
             assert operands.count(sums) == 2                # G and beta
             results = line.split(" custom-call(")[0]
             assert results.count(keys) == (2 if backward else 0)
+            # read by the given-inverse forward and the backward, written
+            # by the first forward alone
+            assert operands.count(inverses) == (
+                backward or "gdn_scan_fwd_given" in line), operands
+            assert results.count(inverses) == (
+                not backward and "gdn_scan_fwd_given" not in line)
     under = [i for i in xplane.hlo_instructions(text)
              if re.search(r"pd\.kda_scan(_grad)?/", i.op_name or "")]
     assert under and not [i.name for i in under if i.opcode == "while"]

@@ -5,7 +5,8 @@ from, beside the kernels of ops/pallas_kda.py over the heads one grid
 step owns, which is what pallas_kda._HEADS is written from.
 
     chiprun -- python3 tools/kda_sweep.py [B T H K V] [--chunks 32,64,128]
-                        [--path chunked,kernel] [--heads-a-step 2,4,8]
+                        [--path chunked,kernel,given_inverse]
+                        [--heads-a-step 2,4,8]
                         [--dtype bfloat16] [--errors 1]
                         [--decay head --key-heads 16
                          --path chunked,kernel,broadcast]
@@ -35,6 +36,19 @@ widening and its pull-back inside the timed call: what the op would run
 had it kept one form (ISSUE 64 keeps the broadcast only if the direct
 form is no faster).
 
+The path `given_inverse` is the forward kernel a replayed op runs
+(pallas_kda.kda_scan_forward handed `inverse=`): the forward alone, the
+chunk inverses formed by a plain forward outside the timed call and read
+where the plain kernel forms them (they hang on k, beta and the decays,
+not on v, which the chain moves); beside it `forward` is the plain
+forward through the same function, all three results written. No
+gradient column: what the two differ by is what keeping the inverses
+across a replayed segment saves a layer and step. On a v5e, bf16, chunk
+64, 8 heads a step, `forward` | `given_inverse` ms (my chip run, PR 65,
+call 1; the outputs equal to the last bit): the Kimi-Linear shape 5.68 |
+2.05, the Qwen3-Next shape (`--decay head --key-heads 16`) 11.97 | 5.13;
+inside the cells' steps the kernels read 5.16 | 1.52 and 10.24 | 3.40.
+
 On a v5e at the default shape, bf16, forward | forward + gradient ms (my
 chip runs, PR 56): XLA's form 10.35 | 27.79 at chunk 64 (its core alone,
 kda_chunked on float32 unit operands, read 8.87 | 25.43 in PR 55); the
@@ -57,6 +71,8 @@ from tools.flash_sweep import bench, report
 
 OUT = "chiprun_out/kda_sweep.jsonl"
 CHAINED = 16
+# the paths that time pallas_kda.kda_scan_forward, which has no gradient
+FORWARD_ALONE = ("forward", "given_inverse")
 SLOTS = ("q", "k", "v", "gate", "a_log", "dt_bias", "beta")
 
 
@@ -83,15 +99,17 @@ def inputs(bsz, t, h, k, v, dtype, seed=0, key_heads=None, per_head=False):
 
 def chained(fn, with_gradient):
     """CHAINED calls of `fn` in one executable, each reading the last
-    one's output through v so that none is dropped or merged."""
-    def run(q, k, v, gate, a_log, dt_bias, beta, do):
+    one's output through v so that none is dropped or merged. `more`:
+    operands behind the op's seven that `fn` takes and the chain leaves
+    as they are (the inverses handed to the given-inverse forward)."""
+    def run(q, k, v, gate, a_log, dt_bias, beta, do, *more):
         def once(v_, _):
             if with_gradient:
                 out, vjp = jax.vjp(fn, q, k, v_, gate, a_log, dt_bias, beta)
                 grads = vjp(do)
                 return v_ + (1e-6 * grads[2]).astype(v_.dtype), sum(
                     x.astype(jnp.float32).sum() for x in (out,) + grads)
-            out = fn(q, k, v_, gate, a_log, dt_bias, beta)
+            out = fn(q, k, v_, gate, a_log, dt_bias, beta, *more)
             return v_ + (1e-6 * out).astype(v_.dtype), \
                 out.astype(jnp.float32).sum()
         return jax.lax.scan(once, v, None, length=CHAINED)
@@ -138,7 +156,7 @@ def main():
                 forms.append((dict(chunk=chunk, path=path), lambda *a, c=chunk:
                               hybrid_ops.kda_scan_chunked(*a, c, eps, dtype)))
                 continue
-            direct = path == "kernel"
+            direct = path != "broadcast"
             reason = hybrid_ops.kda_scan_ineligible(
                 chunk, *args.shape[3:], ratio if direct else 1,
                 per_head and direct)
@@ -153,7 +171,18 @@ def main():
                     return pallas_kda.kda_scan_kernels(
                         *a, c, eps, dtype=dtype, heads=r,
                         interpret=pallas_attention._interpret())
+                def forward(*a, c=chunk, r=r):
+                    """The plain forward, or handed the inverses behind
+                    the op's seven operands the given-inverse one; all it
+                    writes held behind a barrier, so that no result is
+                    pruned."""
+                    return jax.lax.optimization_barrier(
+                        pallas_kda.kda_scan_forward(
+                            *a[:7], c, eps, dtype=dtype, heads=r,
+                            interpret=pallas_attention._interpret(),
+                            inverse=a[7] if a[7:] else None))[0]
                 forms.append((dict(chunk=chunk, path=path, heads_a_step=r),
+                              forward if path in FORWARD_ALONE else
                               kernels if direct else widened(kernels)))
     with open(OUT, "a") as log:
         want = None
@@ -164,11 +193,26 @@ def main():
         for labels, fn in forms:
             row = dict(shape=args.shape, dtype=args.dtype, decay=args.decay,
                        key_heads=args.key_heads or heads, device=jax.devices()[0].device_kind, **labels)
-            for name, with_gradient in (("fwd_ms", False),
-                                        ("fwd_bwd_ms", True)):
-                row[name] = bench(chained(fn, with_gradient), *args_,
+            more = ()
+            if labels["path"] == "given_inverse":
+                plain, _, inverse = jax.jit(
+                    lambda *a: pallas_kda.kda_scan_forward(
+                        *a, labels["chunk"], eps, dtype=dtype,
+                        heads=labels["heads_a_step"],
+                        interpret=pallas_attention._interpret()))(*args_[:7])
+                more = (inverse,)
+                row["equals_forward"] = bool(jnp.array_equal(
+                    plain, jax.jit(fn)(*args_[:7], inverse)))
+            alone = labels["path"] in FORWARD_ALONE
+            for name, with_gradient in [("fwd_ms", False)] + [
+                    ("fwd_bwd_ms", True)] * (not alone):
+                row[name] = bench(chained(fn, with_gradient), *args_, *more,
                                   iters=3) / CHAINED
-            if want is not None:
+            if want is not None and alone:
+                got = jax.jit(fn)(*args_[:7], *more).astype(jnp.float32)
+                row["rel_err"] = dict(out=float(
+                    jnp.abs(got - want[0]).max() / jnp.abs(want[0]).max()))
+            elif want is not None:
                 got = jax.jit(lambda f=fn: both(f, args_))()
                 row["rel_err"] = dict(zip(("out",) + SLOTS, (
                     float(jnp.abs(a.astype(jnp.float32) - b).max()
