@@ -1364,7 +1364,7 @@ class Executor:
                 # a signature not seen before builds: jax's own trace /
                 # lower / compile events inside the call are collected
                 # (cold path only)
-                build_watch = telemetry.watch_build()
+                build_watch = telemetry.watch_build(prog_label)
         compile_before = telemetry.jax_compile_seconds()
         tracing_mod.phase("launch")
         run_t0 = time.perf_counter()
@@ -1640,7 +1640,10 @@ class Executor:
                 # trace and lowering caches on it, and outside it the
                 # analysis traced and lowered the whole block again
                 with tracing_mod.span("analysis"), \
-                        jax.default_device(self.device):
+                        jax.default_device(self.device), \
+                        telemetry.watch_build(prog_label):
+                    # (watched for the program's name alone: the cache's
+                    # answer for the analysis' own compile is the block's)
                     rec = memory_mod.on_compile(
                         self, compiled, program, prog_label,
                         place_label, feed_vals, state_vals,

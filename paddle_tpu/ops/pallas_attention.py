@@ -148,6 +148,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from . import kernel_cost
+
 __all__ = ["BACKWARD_SPLIT_REASONS", "count_backward", "flash_attention",
            "ineligible", "supports"]
 
@@ -445,10 +447,13 @@ def _clip(x, hi: int):
     return _between(x, 0, hi)
 
 
-def _max(x, y):
+_max = kernel_cost.maximum
+
+
+def _min(x, y):
     if isinstance(x, int) and isinstance(y, int):
-        return max(x, y)
-    return jnp.maximum(x, y)
+        return min(x, y)
+    return jnp.minimum(x, y)
 
 
 # What a walked block's predicate holds it to (bits): the causal diagonal,
@@ -681,9 +686,9 @@ def _kv_major_index(bq: int, mk: int, n_maj: int, causal: bool,
             _reach(offs[0] + (i + 1) * bq - 1, block) - offs[1], mk),
             n_maj - 1)
         if not window:
-            return jnp.minimum(kk, last)
+            return _min(kk, last)
         first = _floordiv(offs[0] + i * bq - window + 1 - offs[1], mk)
-        return jnp.clip(kk, _clip(first, n_maj - 1), last)
+        return _between(kk, _clip(first, n_maj - 1), last)
     return index
 
 
@@ -717,10 +722,10 @@ def _q_major_index(bq: int, bk: int, mq: int, n_maj: int, causal: bool,
         first = _clip(_floordiv(
             _first_seer(offs[1] + i * bk, block) - offs[0], mq), n_maj - 1)
         if not window:
-            return jnp.maximum(kk, first)
+            return _max(kk, first)
         last = _floordiv(
             offs[1] + (i + 1) * bk + window - 2 - offs[0], mq)
-        return jnp.clip(kk, first, _clip(last, n_maj - 1))
+        return _between(kk, first, _clip(last, n_maj - 1))
     return index
 
 
@@ -861,9 +866,11 @@ def _flat(x):
 
 
 def _call(kernel, name, grid, in_specs, out_specs, out_shape, scratch,
-          *operands, semantics=_SEM):
+          *operands, semantics=_SEM, work):
     """One pallas_call of the family: the offsets ride as the scalar
-    prefetch, so the index maps can clamp a causally dead tile."""
+    prefetch, so the index maps can clamp a causally dead tile. `work`:
+    `_work`'s count of what the kernel computes, which the call declares
+    (`_declared`)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     return pl.pallas_call(
@@ -873,7 +880,62 @@ def _call(kernel, name, grid, in_specs, out_specs, out_shape, scratch,
             out_specs=out_specs,
             scratch_shapes=[_scratch(s) for s in scratch]),
         out_shape=out_shape, interpret=_interpret(),
+        cost_estimate=_declared(grid, in_specs, operands[1:], out_shape,
+                                **work),
         compiler_params=_compiler_params(semantics))(*operands)
+
+
+def _work(kernel: str, fused: bool, heads: int, lanes: int, d: int, tq: int,
+          tk: int, tile, grain, major: int, causal: bool, block: int,
+          window: int, at):
+    """What one call of `kernel` computes, as `_declared` takes it: the
+    (Q tile, K tile) pairs of tiles whose score product is formed and the
+    (query, key) pairs computed, a head, from the walk the kernel itself
+    runs (`_tiles_walked`: live and edge sub-tiles of the mask; offsets
+    that are traced values count at 0 with their tiles whole, as they are
+    walked)."""
+    swap = kernel == "flash_dkv"
+    b_q, b_k = (_fit(tq, tile[1 if swap else 0]),
+                _fit(tk, tile[0 if swap else 1]))
+    if causal:
+        tiles, pairs = set(), 0
+        for q0, q1, k0, k1, _, _ in _tiles_walked(
+                kernel, tq, tk, tile, grain, major, block, window, at):
+            tiles.add((q0 // b_q, k0 // b_k))
+            pairs += (q1 - q0) * (k1 - k0)
+        tiles = len(tiles)
+    else:
+        tiles, pairs = (tq // b_q) * (tk // b_k), tq * tk
+    return dict(kernel=kernel, fused=fused, heads=heads, lanes=lanes, d=d,
+                tile_pairs=tiles * b_q * b_k, pairs=pairs,
+                offsets=at or (0, 0))
+
+
+def _declared(grid, in_specs, operands, out_shape, *, kernel, fused, heads,
+              lanes, d, tile_pairs, pairs, offsets):
+    """The call's cost_estimate (ops/kernel_cost.py): the work as
+    implemented. MXU FLOPs a head: `flash_fwd` and `flash_dq` form a
+    tile's scores (and dq its dP) ONCE whatever its sub-tiles, a product
+    over all the lane block's lanes (the other heads' and a head's zero
+    padding among them), and the second product over the computed pairs
+    alone, D rows deep; `flash_dkv` forms its four products over the lane
+    block a computed pair, and dQ's D rows deep where fused. One exp a
+    computed score. Bytes: every operand block times the grid steps that
+    fetch it (K and V once a Q tile and live major tile, not once a
+    call; a clamped index fetches nothing), and each result once: dQ is
+    accumulated in VMEM and leaves once."""
+    if kernel == "flash_fwd":
+        flops = 2 * lanes * tile_pairs + 2 * d * pairs
+    elif kernel == "flash_dq":
+        flops = 4 * lanes * tile_pairs + 2 * d * pairs
+    else:
+        flops = (8 * lanes + 2 * d * fused) * pairs
+    many = grid[0] * heads
+    out = out_shape if isinstance(out_shape, (list, tuple)) else [out_shape]
+    return kernel_cost.estimate(
+        flops * many, pairs * many,
+        kernel_cost.fetched_bytes(grid, in_specs, operands, offsets)
+        + kernel_cost.array_bytes(*out))
 
 
 _Specs = collections.namedtuple(
@@ -903,7 +965,7 @@ def _specs(lanes, hpb, b_res, b_walk, m_walk, walk_index, groups=1,
         if groups == 1:
             return lambda bb, g, i, kk, offs: index(bb, g, g, i, kk, offs)
         return lambda bb, g, i, kk, offs: index(
-            bb, g, lax.div(g, jnp.int32(groups)), i, kk, offs)
+            bb, g, kernel_cost.div(g, groups), i, kk, offs)
 
     def res(bb, g, g_kv, i, kk, offs):
         return bb, i, g
@@ -966,6 +1028,8 @@ def _fwd_call(q, k, v, q_off, k_off, scale, causal, normalize, tile=_TILE,
     bq, bk = _fit(tq, tile[0]), _fit(tk, tile[1])
     mk = _major(tk, bk, major)
     n_maj = tk // mk
+    work = _work("flash_fwd", False, h, lanes, d, tq, tk, tile, grain, major,
+                 causal, block, window, at)
     grain, patterns = _sub_tiles("kv", bq, bk, causal, grain, block,
                                  window, at)
 
@@ -984,7 +1048,7 @@ def _fwd_call(q, k, v, q_off, k_off, scale, causal, normalize, tile=_TILE,
         [struct((b, tq, h * d), q.dtype if normalize else jnp.float32)]
         + [struct((b, h, tq // bq, 1, bq), jnp.float32)] * n_stat,
         [(lanes, bq), (hpb, 1, bq), (hpb, 1, bq)],
-        _offsets(q_off, k_off), _flat(q), _flat(k), _flat(v))
+        _offsets(q_off, k_off), _flat(q), _flat(k), _flat(v), work=work)
     return out.reshape(b, tq, h, d), [s.reshape(b, h, tq) for s in stats]
 
 
@@ -1468,6 +1532,10 @@ def _bwd_call(q, k, v, do, lse, delta, q_off, k_off, scale, causal,
         return dict(zip(("grain", "patterns"), _sub_tiles(
             side, b_res, b_walk, causal, grain, block, window, at)))
 
+    def work(kernel, tile):
+        return _work(kernel, fused, h, lanes, d, tq, tk, tile, grain, major,
+                     causal, block, window, at)
+
     def stat(x, rows):
         return x.reshape(b, h, tq // rows, 1, rows)
 
@@ -1486,7 +1554,8 @@ def _bwd_call(q, k, v, do, lse, delta, q_off, k_off, scale, causal,
             [sp.res, sp.walk_kv, sp.walk_kv, sp.res, sp.res_stat,
              sp.res_stat], sp.res,
             struct((b, tq, h * d), q.dtype), [(lanes, bq)],
-            offs, q2, k2, v2, do2, stat(lse, bq), stat(delta, bq))
+            offs, q2, k2, v2, do2, stat(lse, bq), stat(delta, bq),
+            work=work("flash_dq", dq_tile))
 
     bk, bq = _fit(tk, dkv_tile[0]), _fit(tq, dkv_tile[1])
     mq = _major(tq, bq, major)
@@ -1532,7 +1601,7 @@ def _bwd_call(q, k, v, do, lse, delta, q_off, k_off, scale, causal,
         [sp.walk, sp.res_kv, sp.res_kv, sp.walk, sp.walk_stat,
          sp.walk_stat], out_specs, out_shape,
         scratch, offs, q2, k2, v2, do2, stat(lse, bq), stat(delta, bq),
-        semantics=semantics)
+        semantics=semantics, work=work("flash_dkv", dkv_tile))
     if fused:
         dk, dv, dq = outs
     else:

@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from . import kernel_cost
 from .pallas_attention import _compiler_params, _interpret
 
 __all__ = ["KERNELS", "PALLAS_CONV", "conv2d_q8", "ineligible"]
@@ -225,10 +226,23 @@ def _conv_call(x, w_hwio, strides, paddings, dilations, dq, out_dtype):
                           lambda nn, hh, cc, ss: (nn, hh, 0, cc))
     kernel = functools.partial(_fwd_kernel, kw_n=kw_n, dw=dw, sw=sw,
                                ow=ow, n_s=n_s, bh=bh)
+    out_shape = jax.ShapeDtypeStruct((n, oh, ow, co), out_dtype)
+    # The work as implemented (ops/kernel_cost.py): KW products [OW, 128]
+    # by [128, 128] a grid step, int8 on the MXU's doubled rate, so
+    # counted at half a bf16 pass each (a floor against the bf16 peak
+    # must not pass what runs); an input row and a filter tap times the
+    # steps that fetch them (the tap held over an H block), the result
+    # once.
+    steps = grid[0] * grid[1] * grid[2] * grid[3]
+    cost = kernel_cost.estimate(
+        steps * kw_n * ow * _LANE * _LANE, 0,
+        kernel_cost.fetched_bytes(grid, [x_spec, w_spec, dq_spec],
+                                  (xp, w_hwio, dq))
+        + kernel_cost.array_bytes(out_shape))
     return pl.pallas_call(
-        kernel, name="conv2d_q8",
+        kernel, name="conv2d_q8", cost_estimate=cost,
         grid=grid, in_specs=[x_spec, w_spec, dq_spec], out_specs=o_spec,
-        out_shape=jax.ShapeDtypeStruct((n, oh, ow, co), out_dtype),
+        out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((bh, ow, _LANE), jnp.int32)],
         interpret=_interpret(),
         compiler_params=_compiler_params(

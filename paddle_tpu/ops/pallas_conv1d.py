@@ -98,10 +98,13 @@ fit the registers at, and a block of one chunk a group.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from . import kernel_cost
 
 __all__ = ["causal_conv1d_bwd", "causal_conv1d_fwd", "ineligible"]
 
@@ -389,6 +392,31 @@ def _form(pre, post, act):
         if pre or post or act != "silu" else {}
 
 
+def _declared(grid, in_specs, out_shape, dtype, k, bias, pre, post, act,
+              backward):
+    """A call's cost_estimate (ops/kernel_cost.py). The kernels issue no
+    product: their arithmetic is the vector unit's, counted here as the
+    statement counts it (a tap a multiply and an add an element, silu
+    four and its derivative five, a gate one, and in the gradient the
+    taps three times: the pre-activation again, dX and the taps' sums),
+    which no floor is made of at these shapes: the bytes are. Bytes: X,
+    the gates and Out's cotangent once a block, the `unit` steps before
+    a block beside them in the gradient, the taps once a channel block,
+    and each result once. One transcendental an element under silu."""
+    elements = math.prod(out_shape[0].shape)
+    silu = act == "silu"
+    if backward:
+        a_element = 3 * 2 * k + bias + pre * 3 + post * 2 + silu * 8
+    else:
+        a_element = 2 * k - (not bias) + pre + post + silu * 4
+    operands = [jax.ShapeDtypeStruct((), dtype)] * (len(in_specs) - 1) \
+        + [jax.ShapeDtypeStruct((), _F32)]
+    return kernel_cost.estimate(
+        elements * a_element, elements * silu,
+        kernel_cost.fetched_bytes(grid, in_specs, operands)
+        + kernel_cost.array_bytes(*out_shape))
+
+
 @functools.lru_cache(maxsize=None)
 def _fwd_call(axis, bsz, t, c, k, bias, dtype, tile, chunk, interpret,
               pre=False, post=False, act="silu"):
@@ -405,15 +433,17 @@ def _fwd_call(axis, bsz, t, c, k, bias, dtype, tile, chunk, interpret,
                          lambda b, j, i: (b,) + _oriented(axis, i, j))
     taps = pl.BlockSpec(_oriented(axis, rows, cc),
                         lambda b, j, i: _oriented(axis, 0, j))
+    grid, in_specs = (bsz, c // cc, t // tt), [block] * (1 + pre + post) + [taps]
+    out_shape = jax.ShapeDtypeStruct((bsz,) + _oriented(axis, t, c), dtype)
     call = pl.pallas_call(
         functools.partial(_fwd_kernel, axis=axis, k=k, bias=bias,
                           chunk=chunk, unit=unit, **_form(pre, post, act)),
         name=("gated_conv1d" if pre or post else "causal_conv1d") + "_fwd",
-        grid=(bsz, c // cc, t // tt),
-        in_specs=[block] * (1 + pre + post) + [taps], out_specs=block,
-        out_shape=jax.ShapeDtypeStruct((bsz,) + _oriented(axis, t, c), dtype),
+        grid=grid, in_specs=in_specs, out_specs=block, out_shape=out_shape,
         scratch_shapes=_scratch(axis, unit, cc, rows),
         interpret=interpret,
+        cost_estimate=_declared(grid, in_specs, [out_shape], dtype, k, bias,
+                                pre, post, act, False),
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")))
     return jax.jit(call)
@@ -438,25 +468,27 @@ def _bwd_call(axis, bsz, t, c, k, bias, dtype, tile, chunk, interpret,
     prev = pl.BlockSpec(
         (None,) + _oriented(axis, unit, cc),
         lambda b, j, i: (b,) + _oriented(
-            axis, jnp.maximum(down(i) * (tt // unit) - 1, 0), j))
+            axis, kernel_cost.maximum(down(i) * (tt // unit) - 1, 0), j))
     taps = pl.BlockSpec(_oriented(axis, rows, cc),
                         lambda b, j, i: _oriented(axis, 0, j))
     sums = pl.BlockSpec((None,) + _oriented(axis, rows * fold, cc),
                         lambda b, j, i: (b,) + _oriented(axis, 0, j))
+    grid = (bsz, c // cc, blocks)
+    in_specs = [block, prev] * (1 + pre) + [block] * (1 + post) + [taps]
+    out_shape = [jax.ShapeDtypeStruct((bsz,) + _oriented(axis, t, c),
+                                      dtype)] * (1 + pre + post) + [
+        jax.ShapeDtypeStruct((bsz,) + _oriented(axis, rows * fold, c), _F32)]
     call = pl.pallas_call(
         functools.partial(_bwd_kernel, axis=axis, k=k, bias=bias,
                           chunk=chunk, unit=unit, fold=fold,
                           **_form(pre, post, act)),
         name=("gated_conv1d" if pre or post else "causal_conv1d") + "_bwd",
-        grid=(bsz, c // cc, blocks),
-        in_specs=[block, prev] * (1 + pre) + [block] * (1 + post) + [taps],
-        out_specs=[block] * (1 + pre + post) + [sums],
-        out_shape=[jax.ShapeDtypeStruct((bsz,) + _oriented(axis, t, c),
-                                        dtype)] * (1 + pre + post) + [
-            jax.ShapeDtypeStruct((bsz,) + _oriented(axis, rows * fold, c),
-                                 _F32)],
+        grid=grid, in_specs=in_specs,
+        out_specs=[block] * (1 + pre + post) + [sums], out_shape=out_shape,
         scratch_shapes=_scratch(axis, unit, cc, rows),
         interpret=interpret,
+        cost_estimate=_declared(grid, in_specs, out_shape, dtype, k, bias,
+                                pre, post, act, True),
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")))
     return jax.jit(call)

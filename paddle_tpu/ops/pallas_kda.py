@@ -132,6 +132,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from . import kernel_cost
+
 __all__ = ["heads_a_step", "kda_scan_backward", "kda_scan_forward",
            "kda_scan_kernels"]
 
@@ -676,9 +678,57 @@ def _specs(bsz, t, heads, kd, vd, chunk, r, up: bool, ratio: int = 1):
                              lambda b, h, s: (b, z(s), h, 0, 0)))
 
 
+def _declared(grid, in_specs, operands, out_shape, *, r, pack, kd, vd,
+              per_head, backward=False, given=False):
+    """A call's cost_estimate (ops/kernel_cost.py), the work as
+    implemented: the MXU FLOPs of every product of `_fwd_kernel` /
+    `_bwd_kernel`, a float32 product at Precision.HIGHEST (`_full`: the
+    inverse's, the running sum's) at the 6 bf16 passes it runs as, every
+    other at one (Mosaic rounds float32 operands at default precision).
+    A chunk of a pack of p heads, C rows, K and V wide: the sub-blocks'
+    scores 4 C^2 p^2 K; the inverse, two full-precision products of [C,
+    pC] by [pC, pC] a level from the third on (none where the inverses
+    are `given`, or in the gradient, which reads them); [w u] = M [kb vb]
+    2 C pC p(K+V); three state products a head forward, seven in the
+    gradient; the within-chunk output; and in the gradient the pull-backs
+    of each, dA's two full-precision products and the scores' 8 C^2 p^2
+    K. A decay a channel adds the running sum, [C, C] by [C, R K] at
+    full precision (and its transpose in the gradient). The decays'
+    multiplies and every other pass on the vector unit are time and not
+    floor: left out. Transcendentals: the exponents `_decays` /
+    `_decays_a_head` take. Bytes: each block times the steps that fetch
+    it, the entering states and the inverses among them where read, and
+    each result once, those two among them where written."""
+    c, p, both = in_specs[0].block_shape[1], pack, kd + vd
+    full = kernel_cost.passes(_F32, highest=True)
+    square = 2 * c * (p * c) ** 2                       # [C, pC] by [pC, pC]
+    scores, wu, state = 4 * c * c * p * p * kd, 2 * c * p * c * p * both, \
+        2 * c * kd * vd
+    within = 2 * c * p * c * p * vd
+    if backward:
+        a_pack = scores + wu + 7 * p * state + 2 * within + 2 * wu \
+            + 2 * full * square + 2 * scores
+    else:
+        levels = 0 if given else max(c.bit_length() - 3, 0)
+        a_pack = scores + levels * 2 * full * square + wu + 3 * p * state \
+            + within
+    sub = c // _SUB
+    if per_head:
+        running, exps = 0, (3 + sub) * c * r
+    else:
+        running = (1 + backward) * full * 2 * c * c * r * kd
+        exps = (5 + backward + sub) * c * r * kd
+    steps = grid[0] * grid[1] * grid[2]
+    return kernel_cost.estimate(
+        steps * (r // p * a_pack + running), steps * exps,
+        kernel_cost.fetched_bytes(grid, in_specs, operands)
+        + kernel_cost.array_bytes(*out_shape))
+
+
 def _call(kernel, name, grid, in_specs, out_specs, out_shape, scratch,
-          interpret, *operands):
-    """`scratch`: the float32 VMEM scratch's shape, or a list of them."""
+          interpret, *operands, work):
+    """`scratch`: the float32 VMEM scratch's shape, or a list of them;
+    `work`: what `_declared` needs of the kernel's statics."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     params = None if interpret else pltpu.CompilerParams(
@@ -688,7 +738,9 @@ def _call(kernel, name, grid, in_specs, out_specs, out_shape, scratch,
         kernel, name=name, grid=grid, in_specs=in_specs, out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM(shape, _F32) for shape in shapes],
-        interpret=interpret, compiler_params=params)(*operands)
+        interpret=interpret, compiler_params=params,
+        cost_estimate=_declared(grid, in_specs, operands, out_shape,
+                                **work))(*operands)
 
 
 _STATIC = ("heads", "chunk", "r", "eps", "dtype", "interpret")
@@ -708,14 +760,17 @@ def _forward_call(kernel, name, grid, sp, in_specs, result, scratch,
     beta and decays, the kernel reads them where it would form them
     (`given`: another kernel under `name`_given) and they are returned as
     they came."""
+    work = {x: kernel.keywords.get(x, False)
+            for x in ("r", "pack", "kd", "vd", "per_head")}
     if inverse is None:
         return _call(kernel, name, grid, in_specs,
                      [sp["tall"], sp["state"], sp["inverse"]], list(result),
-                     scratch, interpret, *operands)
+                     scratch, interpret, *operands, work=work)
     out, entering = _call(
         functools.partial(kernel, given=True), name + "_given", grid,
         in_specs + [sp["inverse"]], [sp["tall"], sp["state"]],
-        list(result[:2]), scratch, interpret, *operands, inverse)
+        list(result[:2]), scratch, interpret, *operands, inverse,
+        work=dict(work, given=True))
     return out, entering, inverse
 
 
@@ -773,7 +828,9 @@ def _backward(q, k, v, gate, beta, a_log, dt_bias, d_out, entering, inverse,
          jax.ShapeDtypeStruct(gate.shape, gate.dtype),
          jax.ShapeDtypeStruct(beta.shape, _F32), share, share],
         (r, vd, kd), interpret, q, k, v, gate, beta,
-        *_rows(a_log, dt_bias, kd), d_out, entering, inverse)
+        *_rows(a_log, dt_bias, kd), d_out, entering, inverse,
+        work=dict(r=r, pack=pack, kd=kd, vd=vd, per_head=False,
+                  backward=True))
     d_a = d_a.sum((0, 1)).reshape(heads, kd).sum(1)
     return (*grads, (-jnp.exp(a_log.astype(_F32)) * d_a).astype(a_log.dtype),
             d_bias.sum((0, 1)).reshape(dt_bias.shape).astype(dt_bias.dtype))
@@ -858,7 +915,9 @@ def _backward_a_head(q, k, v, cum, beta, d_out, entering, inverse, *, heads,
          jax.ShapeDtypeStruct(cum.shape, _F32),
          jax.ShapeDtypeStruct(beta.shape, _F32)],
         _scratch_a_head(r, chunk, vd, kd), interpret, q, k, v, cum, beta,
-        d_out, entering, inverse)
+        d_out, entering, inverse,
+        work=dict(r=r, pack=pack, kd=kd, vd=vd, per_head=True,
+                  backward=True))
 
 
 def _running_sums(gate, a_log, dt_bias, *, live, chunk, r):

@@ -85,6 +85,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from . import kernel_cost
+
 __all__ = ["ineligible", "pair_sum", "pair_windows"]
 
 # tokens a grid step, rows of one expert's window a fetch (the table above)
@@ -261,8 +263,26 @@ def _call(c, n, k, d, held, dtype, weighted, out_dtype, tile, window,
     kernel = functools.partial(
         _kernel, held=held, window=window, weighted=weighted,
         exact_f32=jnp.dtype(dtype).itemsize == 4)
+    # What the call declares (ops/kernel_cost.py) is what EVERY run of it
+    # does, since the rounds a tile takes are the routing's and no trace
+    # knows them: one round a tile (the first always runs), its placing
+    # product [tile, lanes] by [lanes, d] at the passes its form costs (a
+    # float32 one 6, a weighted bf16 one three products), the tables, the
+    # positions and the weights read once and the result written once.
+    # The rows themselves, fetched by the kernel's own copies a window at
+    # a time, and every round past a tile's first are work the
+    # declaration leaves out: its floor stands under what runs.
+    exact = kernel_cost.passes(dtype, highest=True)
+    rounds = exact if exact > 1 else 3 if weighted else 1
+    index = jax.ShapeDtypeStruct((n, k), jnp.int32)
+    cost = kernel_cost.estimate(
+        n // tile * rounds * 2 * tile * lanes * d, 0,
+        kernel_cost.array_bytes(
+            jax.ShapeDtypeStruct((n // tile, 3, lanes), jnp.int32),
+            *[index] * (1 + weighted),
+            jax.ShapeDtypeStruct((n, d), out_dtype)))
     call = pl.pallas_call(
-        kernel, name="pair_sum",
+        kernel, name="pair_sum", cost_estimate=cost,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(n // tile,), in_specs=in_specs,
             out_specs=pl.BlockSpec((tile, d), lambda i, *_: (i, 0)),

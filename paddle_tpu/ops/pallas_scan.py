@@ -101,6 +101,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from . import kernel_cost
+
 __all__ = ["heads_a_step", "ssd_scan_kernels"]
 
 _LANES = 128
@@ -244,6 +246,35 @@ def _bwd_kernel(cum_ref, dt_ref, x_ref, dy_ref, b_ref, bt_ref, c_ref, ct_ref,
                   + _dot(ct, d_scores.astype(dtype))).astype(dbt_ref.dtype)
 
 
+def _declared(grid, in_specs, operands, out_shape, scratch, backward):
+    """A call's cost_estimate (ops/kernel_cost.py), the work as
+    implemented, a grid step of R heads of P rows, a chunk of L positions
+    and a state N wide: forward, the group's scores 2 L^2 N once, the
+    entering state's read-out and the state's update 2 RP N L each, and a
+    head's masked product 2 P L^2; the gradient, both orientations of the
+    scores, Y again, dX and dW a head (three times 2 P L^2), the
+    read-outs of h and dh, dh's update, and dB's and dC's two products
+    each. One pass each: the operands arrive in the compute dtype. A head
+    narrower than 128 lanes is still P rows of a product L deep here, so
+    nothing is padded in this orientation. Transcendentals: a head's
+    [L, L] mask (two in the gradient) and its rows' factors. Bytes: each
+    block times the steps that fetch it (B and C once a head block and
+    chunk), each result once."""
+    (rp, n), l = scratch, in_specs[0].block_shape[-1]
+    r = in_specs[0].block_shape[2]
+    if backward:
+        flops = 2 * 2 * l * l * n + 3 * 2 * rp * l * l + 5 * 2 * rp * n * l \
+            + 2 * 2 * n * l * l
+    else:
+        flops = 2 * l * l * n + 2 * rp * l * l + 2 * 2 * rp * n * l
+    exps = (1 + backward) * r * l * l + 2 * r * l + r * _LANES
+    steps = grid[0] * grid[1] * grid[2]
+    return kernel_cost.estimate(
+        steps * flops, steps * exps,
+        kernel_cost.fetched_bytes(grid, in_specs, operands)
+        + kernel_cost.array_bytes(*out_shape))
+
+
 def _call(kernel, name, grid, in_specs, out_specs, out_shape, scratch,
           interpret, *operands):
     import jax.experimental.pallas as pl
@@ -254,7 +285,9 @@ def _call(kernel, name, grid, in_specs, out_specs, out_shape, scratch,
         kernel, name=name, grid=grid, in_specs=in_specs, out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM(scratch, _F32)], interpret=interpret,
-        compiler_params=params)(*operands)
+        compiler_params=params,
+        cost_estimate=_declared(grid, in_specs, operands, out_shape, scratch,
+                                name.endswith("_bwd")))(*operands)
 
 
 # The most positions x heads one grid step owns, chunk x heads of a group.

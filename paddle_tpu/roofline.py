@@ -18,7 +18,15 @@ Joins three sources into one per-op table:
    bytes / hbm)`), folded by program op instance; device time no account
    names pools under "(unattributed)" so fractions sum to the true device
    total. The analytic model of (1) stays beside it as the REQUIRED
-   FLOPs: what the executed ones exceed it by is recomputation.
+   FLOPs: what the executed ones exceed it by is recomputation, and for
+   an op that runs on Pallas kernels (kda_scan, the attention ops,
+   moe_experts, ssd_scan, causal_conv1d, each with its gradient) the work
+   the implementation adds. There the executed side is what each Mosaic
+   call declares of itself (`Instr.declared_*`, ops/kernel_cost.py: the
+   work as implemented) plus the account's count of the op's XLA
+   instructions; the report's kernels table sets each kernel's declared
+   floor beside its device time. executed / required says how much work
+   an implementation adds, declared floor / time how fast it does it.
    `xplane.timeline_dir` (XLine.timestamp_ns + XEvent.offset_ps)
    supplies the step-time waterfall: device compute vs infeed vs
    collectives vs host gap, plus the device duty cycle.
@@ -188,10 +196,55 @@ def _conv1d_flops(ins, outs, attrs):
     return None if x is None or w is None else (2.0 * w[1] + 4) * _nelems(x)
 
 
+def _sdpa_flops(ins, outs, attrs):
+    """scaled_dot_product_attention over Q [B, T, H, D] and K [B, Tk, H_kv,
+    D]: the two products, scores and weighted sum, over the (query, key)
+    pairs the mask keeps (`causal`, and under it a `window` of keys), 4 D
+    a pair and query head. The useful work, whichever of the einsum and
+    the flash kernels of ops/pallas_attention.py runs it: masked pairs a
+    tile computes all the same, lanes of padding and the backward's
+    recomputed scores are time and not this count. A replayed op that was
+    handed its first run's outputs (registry.handed_on) requires none."""
+    q, k = _slot_shape(ins, "Q"), _slot_shape(ins, "K")
+    if q is None or k is None or len(q) != 4 or len(k) != 4:
+        return None
+    if _slot_shape(ins, "KeptOut") is not None:
+        return 0.0      # replayed and handed Out and LSE: it runs nothing
+    tq, tk = q[1], k[1]
+    pairs = tq * tk
+    if attrs.get("causal", False):
+        window = int(attrs.get("window", 0) or 0)
+        seen = np.minimum(np.arange(tq, dtype=np.int64) + 1 + (tk - tq), tk)
+        if window:
+            seen = np.minimum(seen, window)
+        pairs = int(np.maximum(seen, 0).sum())
+    return 4.0 * q[0] * q[2] * q[3] * pairs
+
+
+def _bd_attention_flops(ins, outs, attrs):
+    """block_diffusion_attention over Q [2 B, L, H, D], the noised
+    streams then the clean ones: a clean query sees the clean keys of
+    its block and of every block before it, a noised one its own noised
+    block and the clean blocks strictly before, so each of the two sees
+    (q // block + 1) x block keys; 4 D a pair and head, as `_sdpa_flops`,
+    and nothing where a replay was handed the outputs."""
+    q = _slot_shape(ins, "Q")
+    if q is None or len(q) != 4:
+        return None
+    if _slot_shape(ins, "KeptOut") is not None:
+        return 0.0
+    block = int(attrs.get("block_length", 1) or 1)
+    pairs = int(((np.arange(q[1], dtype=np.int64) // block + 1)
+                 * block).sum())
+    return 4.0 * q[0] * q[2] * q[3] * pairs
+
+
 # ops/hybrid_ops.py: forward flops from the op's concrete shapes
 _HYBRID_COST = {"ssd_scan": _scan_flops, "moe_experts": _experts_flops,
                 "moe_router": _router_flops, "causal_conv1d": _conv1d_flops,
-                "kda_scan": _kda_flops}
+                "kda_scan": _kda_flops,
+                "scaled_dot_product_attention": _sdpa_flops,
+                "block_diffusion_attention": _bd_attention_flops}
 
 # flops per parameter element for the bucketed fused optimizer applies
 # (ops/fusion.py): sgd = mul+sub; momentum adds the velocity update;
@@ -290,6 +343,10 @@ def op_cost(op_type: str, ins: Dict[str, list], outs: Dict[str, list],
             flops = 2.0 * _nelems(out_shape) * int(k)
         else:
             flops = float(out_elems)
+    elif base in _HYBRID_COST:
+        flops = _HYBRID_COST[base](ins, outs, attrs)
+        if flops is None:
+            flops = float(out_elems)
     elif "attention" in base:
         # scores + weighted sum: 2 * (2 * B*H*T^2*D) = 4*T*q_elems
         q = (_slot_shape(ins, "Q") or _slot_shape(ins, "Query")
@@ -298,10 +355,6 @@ def op_cost(op_type: str, ins: Dict[str, list], outs: Dict[str, list],
             t = q[-2] if len(q) >= 3 else q[0]
             flops = 4.0 * _nelems(q) * int(t)
         else:
-            flops = float(out_elems)
-    elif base in _HYBRID_COST:
-        flops = _HYBRID_COST[base](ins, outs, attrs)
-        if flops is None:
             flops = float(out_elems)
     elif base.startswith("reduce_") or base in ("mean", "sum"):
         flops = float(in_elems)
@@ -710,6 +763,93 @@ def _required_costs(infos, notes):
     return cost, total_flops, total_bytes, have
 
 
+def _build_table(snapshot) -> Dict[str, Dict[str, Any]]:
+    """{program: {"phases": {phase: seconds}, "cache": {"hit", "miss"}}}
+    of the blocks this process built: the first run's seconds by phase
+    (executor_build_seconds_total) and, beside them, how many executables
+    jax's persistent cache loaded and how many it had to compile
+    (executor_compile_cache_total): a `compile` phase of a hit is a load.
+    Empty read away from the process that ran the program."""
+    out: Dict[str, Dict[str, Any]] = {}
+    for name, field, key in (("executor_build_seconds_total", "phases",
+                              "phase"),
+                             ("executor_compile_cache_total", "cache",
+                              "result")):
+        for labels, value in (snapshot["counters"].get(name) or {}).items():
+            said = dict(pair.split("=", 1) for pair in labels.split(","))
+            row = out.setdefault(said.get("program", "-"),
+                                 {"phases": {}, "cache": {}})
+            row[field][said.get(key, "-")] = value
+    return out
+
+
+def _kernel_tables(steps, cost):
+    """(kernels, kernel-backed ops) of the main module's traced `steps`,
+    each a step and chip (the mean over them).
+
+    kernels: one row a Mosaic kernel that declared its work, by name
+    (`heavy`): `calls`, `ms`, `floor_ms` (the declared floor: max(declared
+    FLOPs / peak, declared bytes / HBM bandwidth) a call, summed),
+    `share` = floor over time, `bound`, `declared_by` ("kernel": the
+    package's own, the work as implemented; "jax": megablox gmm / tgmm,
+    every row of the buffer, an upper bound), `gflop`, `mb`.
+
+    kernel-backed ops: one row a program op type some declared kernel
+    runs under (`kda_scan`, `kda_scan_grad`, ...): `ms` under the op,
+    `executed_flops` = its Mosaic calls' declared FLOPs (`declared_flops`)
+    + the account's FLOPs of its XLA instructions, `required_flops` from
+    the analytic table (`op_cost`; None read away from the process that
+    traced unless it left the table beside the trace), `added` = executed
+    over required: the work the implementation adds."""
+    if not steps:
+        return [], []
+    n = float(len(steps))
+    kernels: Dict[str, Dict[str, Any]] = {}
+    ops: Dict[str, Dict[str, Any]] = {}
+    backed = {r["label"] for st in steps for r in st["rows"]
+              if r["joined"] and r.get("declared_by")}
+    for st in steps:
+        for r in st["rows"]:
+            if not r["joined"]:
+                continue
+            declared = r.get("declared_by")
+            if declared:
+                k = kernels.setdefault(r["heavy"], {
+                    "kernel": r["heavy"], "calls": 0.0, "ms": 0.0,
+                    "floor_ms": None, "gflop": 0.0, "mb": 0.0,
+                    "by_flops": 0.0, "declared_by": declared})
+                k["calls"] += r["count"] / n
+                k["ms"] += r["ms"] / n
+                k["gflop"] += (r["declared_flops"] or 0.0) * r["count"] / n / 1e9
+                k["mb"] += (r["declared_bytes"] or 0) * r["count"] / n / 1e6
+                if r.get("kernel_floor_ms") is not None:
+                    k["floor_ms"] = (k["floor_ms"] or 0.0) \
+                        + r["kernel_floor_ms"] / n
+                    if r["kernel_bound"] == "flops":
+                        k["by_flops"] += r["kernel_floor_ms"] / n
+            if r["label"] in backed:
+                o = ops.setdefault(r["label"], {
+                    "op": r["label"], "ms": 0.0, "declared_flops": 0.0,
+                    "xla_flops": 0.0})
+                o["ms"] += r["ms"] / n
+                o["declared_flops" if declared else "xla_flops"] += (
+                    (r["declared_flops"] if declared else r["flops"])
+                    or 0.0) * r["count"] / n
+    for k in kernels.values():
+        floor, by_flops = k["floor_ms"], k.pop("by_flops")
+        k["share"] = floor / k["ms"] if floor is not None and k["ms"] else None
+        # the kernel's bound is the one most of its floor is made of
+        k["bound"] = None if floor is None else \
+            "flops" if 2 * by_flops >= floor else "bytes"
+    for o in ops.values():
+        o["executed_flops"] = o["declared_flops"] + o["xla_flops"]
+        required = (cost.get(o["op"]) or {}).get("flops")
+        o["required_flops"] = required
+        o["added"] = o["executed_flops"] / required if required else None
+    return tuple(sorted(table.values(), key=lambda d: -d["ms"])
+                 for table in (kernels, ops))
+
+
 def collect_report(trace_dir, steps: Optional[int] = None,
                    probe: bool = True, accounts=None
                    ) -> Optional[Dict[str, Any]]:
@@ -736,6 +876,12 @@ def collect_report(trace_dir, steps: Optional[int] = None,
     used = [pairs[i] for i in acct.get("used", ()) if i < len(pairs)]
     cost, total_flops, total_bytes, have_cost = _required_costs(
         [info for _, info in used], notes)
+    if have_cost:
+        xplane.save_required(trace_dir, cost)
+    else:
+        # read away from the process that traced: what it left beside the
+        # trace (per-op REQUIRED flops; no totals, so no MFU from here)
+        cost = xplane.saved_required(trace_dir) or cost
     xla_flops = sum(info.get("xla_flops") or 0.0 for _, info in used)
     executed_flops = sum(i.flops or 0.0 for instrs, _ in used
                          for i in instrs if i.entry)
@@ -766,9 +912,15 @@ def collect_report(trace_dir, steps: Optional[int] = None,
             a["ps"] += ps
             if r["joined"]:
                 a["joined"] = True
-                a["work_flops"] += (r["flops"] or 0.0) * r["count"]
-                a["work_bytes"] += (r["bytes"] or 0) * r["count"]
-                a["instrs"][r["name"]] = (r["flops"], r["bytes"], r["shape"])
+                # a Mosaic call's work is what it declares of itself (its
+                # FLOPs as implemented, the bytes its pipeline moves): the
+                # account's own count stops at the call
+                flops, nbytes = (r["flops"], r["bytes"]) \
+                    if r.get("declared_by") is None \
+                    else (r["declared_flops"], r["declared_bytes"])
+                a["work_flops"] += (flops or 0.0) * r["count"]
+                a["work_bytes"] += (nbytes or 0) * r["count"]
+                a["instrs"][r["name"]] = (flops, nbytes, r["shape"])
     if not agg:
         return None
 
@@ -869,7 +1021,10 @@ def collect_report(trace_dir, steps: Optional[int] = None,
             "fusions": sum(1 for instrs, _ in used for i in instrs
                            if i.opcode == "fusion")} if used else None,
         "mfu_nominal": None, "mfu_vs_sustained": None, "notes": notes,
+        "build": _build_table(telemetry.snapshot()),
     }
+    report["kernels"], report["kernel_ops"] = _kernel_tables(
+        xplane.main_steps(acct), cost)
     report["kernel_efficiency"] = [
         {"op": r["op"], "at": r["at"], "shape": r["shape"],
          "ms": round(r["ps"] / 1e9, 4),
@@ -1031,9 +1186,48 @@ def format_report(report: Dict[str, Any]) -> List[str]:
             lines.append(
                 f"[kernel] {op:24s}{shape:14s} {r['ms']:10.4f} "
                 f"{r['min_ms']:10.4f} {r['efficiency']:9.1%}")
+    ops = report.get("kernel_ops")
+    if ops:
+        lines.append(
+            f"{'Kernel-backed op (a step)':34s} {'ms':>9s} {'Required':>10s} "
+            f"{'Executed':>10s} {'Added':>7s}  GFLOP: Mosaic declared + XLA")
+        for o in ops:
+            added = "      -" if o["added"] is None \
+                else "{:6.2f}x".format(o["added"])
+            lines.append(
+                f"[work] {o['op']:27s} {o['ms']:9.3f} "
+                f"{_fmt(o['required_flops'], 1e9, 2, 10)} "
+                f"{_fmt(o['executed_flops'], 1e9, 2, 10)} {added}  "
+                f"{o['declared_flops'] / 1e9:.2f} + "
+                f"{o['xla_flops'] / 1e9:.2f}")
+    kernels = report.get("kernels")
+    if kernels:
+        lines.append(
+            f"{'Mosaic kernel (a step)':34s} {'Calls':>6s} {'ms':>9s} "
+            f"{'Floor ms':>9s} {'Share':>7s} {'Bound':6s} {'By':7s} "
+            f"{'GFLOP':>9s} {'MB':>9s}")
+        for k in kernels:
+            share = "      -" if k["share"] is None \
+                else "{:7.1%}".format(k["share"])
+            lines.append(
+                f"[mosaic] {k['kernel']:25s} {k['calls']:6.1f} "
+                f"{k['ms']:9.3f} {_fmt(k['floor_ms'], 1.0, 3, 9)} {share} "
+                f"{k['bound'] or '-':6s} {k['declared_by']:7s} "
+                f"{k['gflop']:9.2f} {k['mb']:9.1f}")
     if report.get("input_bound"):
         lines.append("[verdict] input-bound: " +
                      report.get("input_bound_remedy", ""))
+    for program, row in sorted((report.get("build") or {}).items()):
+        phases = " | ".join(
+            f"{phase} {row['phases'][phase]:.2f} s" for phase in
+            ("trace", "lower", "compile", "analysis", "execute")
+            if phase in row["phases"])
+        cache = row["cache"]
+        if cache:
+            phases += (" | " if phases else "") + (
+                "cache: {:.0f} loaded, {:.0f} compiled".format(
+                    cache.get("hit", 0), cache.get("miss", 0)))
+        lines.append(f"[build] {program}: {phases}")
     hc = report.get("kernel_counts")
     if hc:
         lines.append(

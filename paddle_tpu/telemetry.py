@@ -687,6 +687,11 @@ _BUILD_PHASE_OF = {"jaxpr_trace_duration": "trace",
                    "jaxpr_to_mlir_module_duration": "lower",
                    "backend_compile_duration": "compile"}
 _build_watch: List[Optional[list]] = [None]
+# the program whose block the watched call may build: the label of the
+# persistent cache's hits and misses inside it ("-" outside any watch:
+# a jit of the caller's own)
+_build_program: List[Optional[str]] = [None]
+_CACHE_RESULT_OF = {"cache_hits": "hit", "cache_misses": "miss"}
 
 
 def _install_compile_listener():
@@ -710,7 +715,26 @@ def _install_compile_listener():
                 counter("jax_backend_compiles_total",
                         "XLA backend compilations").inc()
 
+        def _on_event(name, **kw):
+            # jax's persistent compilation cache says of every executable
+            # it was asked for whether it loaded it or had to compile:
+            # what setup_compile_s cannot tell (a load and a compile are
+            # both `backend_compile` seconds)
+            if "/compilation_cache/" in name:
+                result = _CACHE_RESULT_OF.get(name.rsplit("/", 1)[-1])
+                if result is not None:
+                    counter(
+                        "executor_compile_cache_total",
+                        "executables jax's persistent compilation cache "
+                        "was asked for, by the program whose block was "
+                        "being built (- outside one) and whether it was "
+                        "loaded (hit) or compiled (miss)",
+                        labels=("program", "result")).labels(
+                            program=_build_program[0] or "-",
+                            result=result).inc()
+
         jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        jax.monitoring.register_event_listener(_on_event)
     except Exception:   # jax absent/too old: compile split degrades to 0
         pass
 
@@ -722,20 +746,22 @@ def jax_compile_seconds() -> float:
 
 
 @contextlib.contextmanager
-def watch_build():
+def watch_build(program: Optional[str] = None):
     """Collect jax's own trace / lower / compile events for the length of
     the block: yields the list they land in, [(phase, start, end)] on
     time.monotonic(). `backend_compile` covers a persistent-cache load
-    too. The executor opens this around a call whose signature it has
-    not seen, never around a steady step. Not re-entrant: an inner watch
-    (a nested Executor.run) takes the events, the outer one resumes."""
+    too: which of the two it was is booked beside it, under `program`, as
+    executor_compile_cache_total{program, result}. The executor opens
+    this around a call whose signature it has not seen, never around a
+    steady step. Not re-entrant: an inner watch (a nested Executor.run)
+    takes the events, the outer one resumes."""
     _install_compile_listener()
-    saved, events = _build_watch[0], []
-    _build_watch[0] = events
+    saved, events = (_build_watch[0], _build_program[0]), []
+    _build_watch[0], _build_program[0] = events, program
     try:
         yield events
     finally:
-        _build_watch[0] = saved
+        _build_watch[0], _build_program[0] = saved
 
 
 def merge_build_events(events) -> List[Tuple[str, float, float]]:
@@ -813,6 +839,10 @@ METRIC_CATALOG = {
     "executor_compile_seconds_total": _m(
         "counter", ("program", "place"),
         "XLA compile wall seconds inside Executor.run"),
+    "executor_compile_cache_total": _m(
+        "counter", ("program", "result"),
+        "executables jax's persistent compilation cache was asked for, by "
+        "program and hit (loaded) or miss (compiled)"),
     "executor_build_seconds_total": _m(
         "counter", ("program", "phase"),
         "first run of a compiled block, by phase: trace, lower, compile, "

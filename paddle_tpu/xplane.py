@@ -560,7 +560,21 @@ def _plain(shape_text: str) -> str:
 
 class Instr(NamedTuple):
     """One instruction of a compiled step. `flops` is None for a Mosaic
-    call (its cost is the kernel family's to give); `mxu_flops` is `flops`
+    call: XLA's count stops at the call. What the call does stands in
+    `declared_flops`, `declared_transcendentals` and `declared_bytes`,
+    the `cost_estimate` the call itself carries in its `backend_config`
+    (ops/kernel_cost.py: the work as implemented, MXU FLOPs at the passes
+    they cost, the bytes its pipeline moves; the bytes scaled by the
+    share of the call's operands and results that are in HBM, where XLA
+    holds one of them in VMEM: `S(1)` in its layout), with `declared_by`
+    "kernel" for one of the package's and "jax" for megablox `gmm` /
+    `tgmm`, whose estimate counts every row of the buffer and is an upper
+    bound; all four None for every other instruction, for a call that
+    declared nothing and in an account an older cache served (compiled
+    text from before PR 66 carries no estimate: nothing fails, the
+    kernels' floor is just not there). `flops` stays None for a Mosaic
+    call whatever it declares: the benchmark's readers tell one by it.
+    `mxu_flops` is `flops`
     with a float32 product at `highest` precision counted as the 6 bf16
     passes it runs as (the trace's own `flops` stat; the floor's); `at` is the program
     op's position in the block it was lowered from; `kind`, `payload`,
@@ -595,6 +609,10 @@ class Instr(NamedTuple):
     recompute: Optional[int] = None
     channel: Optional[int] = None
     moved: str = ""
+    declared_flops: Optional[float] = None
+    declared_transcendentals: Optional[float] = None
+    declared_bytes: Optional[int] = None
+    declared_by: Optional[str] = None
 
 
 _ROLE = re.compile(r"pd_role\.([A-Za-z0-9_]+)")
@@ -663,6 +681,15 @@ _PAIRS = re.compile(r"source_target_pairs=\{([0-9,{} ]*)\}")
 _CHANNEL = re.compile(r"\bchannel_id=(\d+)")
 
 MOSAIC_TARGET = "tpu_custom_call"
+_MOSAIC_CALL = 'custom_call_target="%s"' % MOSAIC_TARGET
+# what a Mosaic call declares of itself: an object behind the serialized
+# body in its backend_config, the numbers as strings
+_COST_KEY = '"cost_estimate":{'
+_DECLARED = re.compile(r", cost_estimate=\{([^{}]*)\}")
+_DECLARED_FIELD = re.compile(r'"(\w+)":"?(\d+)"?')
+# jax's own kernels under the package's ops (megablox, hybrid_ops): their
+# estimate is jax's, every row of the buffer
+JAX_KERNELS = frozenset({"gmm", "tgmm"})
 # a custom call that joins the pieces of an async slice under one name:
 # it moves nothing
 _ALIASING_TARGET = 'custom_call_target="ConcatBitcast"'
@@ -789,7 +816,16 @@ def _computations(text):
         # Mosaic kernel's body) is most of a line and nothing here reads it
         cut = line.find(", backend_config=")
         if cut >= 0:
-            line = line[:cut]
+            declared = ""
+            if _MOSAIC_CALL in line[:cut]:
+                # the call's own statement of its work follows the body:
+                # one search from the line's end, never over the body
+                at = line.rfind(_COST_KEY)
+                end = line.find("}", at)
+                if at > cut and end > at:
+                    declared = ", cost_estimate=" \
+                        + line[at + len(_COST_KEY) - 1:end + 1]
+            line = line[:cut] + declared
         m = _FAST.match(line)
         if m:
             current.append(_Raw(bool(m.group(1)), m.group(2), m.group(3),
@@ -815,13 +851,29 @@ def floor_seconds(instr, peak, hbm):
     """(seconds, "flops" | "bytes") the chip needs at least for one run of
     an instruction: max(MXU FLOPs / peak, HBM bytes / bandwidth), and
     which of the two it is. None for an async half, for control flow
-    (its body's instructions carry it) and for a Mosaic call (the account
-    knows neither its FLOPs nor how much of its operands it reads)."""
+    (its body's instructions carry it) and for a Mosaic call, whose
+    floor is `kernel_floor_seconds`': XLA's count stops at the call, and
+    the benchmark's readers tell a call by this None."""
     if is_async(instr.opcode) or instr.heavy == "control" \
             or instr.flops is None:
         return None
-    work, moved = (instr.mxu_flops or 0.0) / peak, instr.bytes / hbm
+    return _floor((instr.mxu_flops or 0.0) / peak, instr.bytes / hbm)
+
+
+def _floor(work, moved):
     return max(work, moved), "bytes" if moved >= work else "flops"
+
+
+def kernel_floor_seconds(instr, peak, hbm):
+    """`floor_seconds` of a Mosaic call from what the call declares of
+    itself (`Instr.declared_*`): max(declared FLOPs / peak, declared
+    bytes / bandwidth) and which of the two. None for every other
+    instruction and for a call that declared nothing (compiled before
+    PR 66, or served by a cache from then)."""
+    if instr.declared_by is None:
+        return None
+    return _floor((instr.declared_flops or 0.0) / peak,
+                  (instr.declared_bytes or 0) / hbm)
 
 
 def _valid_positions(size, stride, pad_lo, lhs_dil, rhs_dil, in_size,
@@ -1428,6 +1480,24 @@ def hlo_instructions(text: str, mesh=None) -> List[Instr]:
                     coll.update(group_size=start.group_size,
                                 groups=start.groups, axis=start.axis,
                                 channel=start.channel)
+            declared = _DECLARED.search(raw.attrs) \
+                if raw.opcode == "custom-call" else None
+            if declared is not None:
+                said = dict(_DECLARED_FIELD.findall(declared.group(1)))
+                # XLA may hold an operand or a result of the call in VMEM
+                # (`S(1)` in its layout: a copy brought it there and paid
+                # for it, as for any instruction's): the call's pipeline
+                # then reads it at no HBM cost, so the declared bytes
+                # count by the share of the call's arrays that are in HBM
+                whole = sum(shape_bytes(shapes.get(o, ""))
+                            for o in raw.operands) + shape_bytes(raw.shape)
+                coll.update(
+                    declared_flops=float(said.get("flops", 0)),
+                    declared_transcendentals=float(
+                        said.get("transcendentals", 0)),
+                    declared_bytes=int(said.get("bytes_accessed", 0))
+                    * nbytes // max(whole, 1),
+                    declared_by="jax" if heavy in JAX_KERNELS else "kernel")
             by_name[raw.name] = Instr(
                 raw.name, raw.opcode, heavy, flops, mxu_flops, nbytes,
                 raw.shape, detail, op_name, role, scope, op,
@@ -1520,6 +1590,35 @@ def _save_accounts(trace_dir, accounts, device_kind):
                                  for i in acct] for acct in accounts]}, f)
 
 
+def save_required(trace_dir, cost) -> None:
+    """Leave the analytic per-op-type table (`roofline.program_cost`'s
+    REQUIRED FLOPs and bytes, which only the process that ran the program
+    can form) in the account file beside the trace, once, so that a
+    report read from the directory alone sets required beside executed."""
+    path = os.path.join(trace_dir, ACCOUNT_FILE)
+    try:
+        with open(path) as f:
+            saved = json.load(f)
+        if "required" in saved:
+            return
+        saved["required"] = {
+            op: {k: d[k] for k in ("flops", "bytes") if k in d}
+            for op, d in cost.items()}
+        with open(path, "w") as f:
+            json.dump(saved, f)
+    except (OSError, ValueError):
+        pass
+
+
+def saved_required(trace_dir) -> Optional[Dict[str, Dict[str, float]]]:
+    """`save_required`'s table, or None."""
+    try:
+        with open(os.path.join(trace_dir, ACCOUNT_FILE)) as f:
+            return json.load(f).get("required")
+    except (OSError, ValueError):
+        return None
+
+
 def _peaks(device_kind):
     """(FLOP/s, HBM bytes/s, kind) from chip.py's table: of `device_kind`
     when given (a trace read away from its chip), else of this process's
@@ -1544,7 +1643,8 @@ def _peaks(device_kind):
 
 _ROW_FIELDS = ("name", "opcode", "heavy", "flops", "bytes", "shape", "detail",
                "role", "scope", "op", "at", "kind", "payload", "group_size",
-               "axis", "site", "recompute")
+               "axis", "site", "recompute", "declared_flops",
+               "declared_transcendentals", "declared_bytes", "declared_by")
 
 
 def _unjoined_row(name, stats):
@@ -1581,8 +1681,14 @@ def step_account(trace_dir, accounts=None) -> Optional[Dict[str, Any]]:
     max(flops / peak, bytes / HBM bandwidth) with `bound` = which of the
     two it is, "flops" or "bytes" (None without peaks: the CPU; for an
     async half, whose transfer runs under other instructions; for control
-    flow, whose body's rows carry it; for a Mosaic call, whose FLOPs the
-    account does not know and whose operands it may read in part) and `busbw_gbps` for a collective (payload over its time, times
+    flow, whose body's rows carry it; for a Mosaic call, at which XLA's
+    count stops: `flops` and `floor_ms` stay None on its row, which is how
+    the benchmark's readers tell one), for a Mosaic call the four
+    `declared_*` fields of its `Instr` with `kernel_floor_ms` =
+    max(declared FLOPs / peak, declared bytes / HBM bandwidth) x `count`
+    and `kernel_bound` (None on every other row, without peaks, and
+    where the call declared nothing: an account from before PR 66) and
+    `busbw_gbps` for a collective (payload over its time, times
     the nccl-tests factor at the instruction's own group size). An event
     no account names keeps its name, the trace's op_name (`tf_op`) for
     its provenance, and no cost.
@@ -1657,6 +1763,12 @@ def step_account(trace_dir, accounts=None) -> Optional[Dict[str, Any]]:
             if floor:
                 row["floor_ms"] = 1e3 * counts[name] * floor[0]
                 row["bound"] = floor[1]
+            row["kernel_floor_ms"] = row["kernel_bound"] = None
+            floor = instr is not None and peak and hbm \
+                and kernel_floor_seconds(instr, peak, hbm)
+            if floor:
+                row["kernel_floor_ms"] = 1e3 * counts[name] * floor[0]
+                row["kernel_bound"] = floor[1]
             if row["kind"]:
                 # the part no concurrent non-collective event on the line
                 # covers; on a chip's `XLA Ops` line, all of it

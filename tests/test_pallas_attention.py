@@ -5,6 +5,7 @@ under the Pallas interpreter — the same code Mosaic compiles on TPU
 benchmark cells' shapes; PERF.md section 6, PR 29 has its chip times)."""
 
 import numpy as np
+import re
 import pytest
 
 import jax
@@ -595,7 +596,10 @@ def call_digest(module, call, shape, dtype, statics):
         jaxpr = jax.make_jaxpr(lambda q, k, v, do, lse, dl: module._bwd_call(
             q, k, v, do, lse, dl, 0, 0, d ** -0.5, causal, **statics))(
                 x, x, x, x, stat, stat)
-    text = [str(jaxpr)]
+    # the parent's calls declared no work (PR 66): the digests hold the
+    # rest of the call, the declaration set aside
+    text = [re.sub(r"cost_estimate=CostEstimate\([^)]*\)",
+                   "cost_estimate=None", str(jaxpr))]
 
     def index_maps(jaxpr):
         """A pallas_call prints its BlockSpecs without their index maps."""

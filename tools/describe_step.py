@@ -17,7 +17,9 @@ overrides a key of the configuration (a depth, to read one layer fast);
 `--account` prints instead the step's account by instruction
 (`paddle_tpu.xplane.hlo_instructions`): FLOPs, bytes and the least a v5e
 could take for each, and their sum, a chipless lower bound of the step
-to set against the ledger's busy time. A cell with a `mesh` (`gpt2-large.
+to set against the ledger's busy time, and under it the Mosaic kernels
+by name with what each call declares of itself (FLOPs as implemented,
+bytes, the floor they make) and the calls that declared nothing. A cell with a `mesh` (`gpt2-large.
 train-fsdp2-tp2`; 36 layers take five minutes here, `--set n_layer=2`
 half a minute) is planned over described chips as its traffic kind
 plans it, its numbers are one chip's, and its collectives are printed by
@@ -182,7 +184,8 @@ def account_rows(text):
     """[(floor ms, instruction)] of the compiled step for a v5e with no
     chip: every instruction of the entry computation that takes time,
     with the least it could take, max(flops / peak, bytes / hbm) from
-    chip.py's table; an async half and a Mosaic call have none here."""
+    chip.py's table; an async half has none, and a Mosaic call's is in
+    `kernel_rows`, from what the call declares of itself."""
     from paddle_tpu import chip, xplane
 
     row = chip.PEAKS["TPU v5 lite"]
@@ -194,6 +197,51 @@ def account_rows(text):
         floor = xplane.floor_seconds(instr, peak, hbm)
         rows.append((1e3 * floor[0] if floor else 0.0, instr))
     return rows
+
+
+def kernel_rows(text):
+    """{kernel name: [calls, declared FLOPs, declared bytes, floor ms,
+    declared_by]} of the compiled step's Mosaic calls for a v5e, from
+    what each call declares of itself (`Instr.declared_*`), and the calls
+    that declared nothing."""
+    from paddle_tpu import chip, xplane
+
+    row = chip.PEAKS["TPU v5 lite"]
+    peak, hbm = row.bf16_tflops * 1e12, row.hbm_gbps * 1e9
+    kernels, silent = {}, []
+    for instr in xplane.hlo_instructions(text):
+        if instr.opcode != "custom-call" or instr.flops is not None:
+            continue
+        floor = xplane.kernel_floor_seconds(instr, peak, hbm)
+        if floor is None:
+            silent.append(instr.heavy)
+            continue
+        acc = kernels.setdefault(instr.heavy,
+                                 [0, 0.0, 0, 0.0, instr.declared_by])
+        acc[0] += 1
+        acc[1] += instr.declared_flops
+        acc[2] += instr.declared_bytes
+        acc[3] += 1e3 * floor[0]
+    return kernels, silent
+
+
+def print_kernels(text):
+    """The kernels table of `--account`: an instruction inside a loop or
+    a switch's branch counts once, as the account's own rows do."""
+    kernels, silent = kernel_rows(text)
+    print("\n%-22s %6s %12s %12s %10s  %s" % (
+        "Mosaic kernel", "calls", "GFLOP", "MB", "floor ms", "declared by"))
+    for name, (n, flops, nbytes, floor, by) in sorted(
+            kernels.items(), key=lambda kv: -kv[1][3]):
+        print("%-22s %6d %12.2f %12.1f %10.3f  %s" % (
+            name, n, flops / 1e9, nbytes / 1e6, floor, by))
+    print("%-22s %6d %12.2f %12.1f %10.3f  (calls that declared nothing: "
+          "%d%s)" % (
+              "sum", sum(k[0] for k in kernels.values()),
+              sum(k[1] for k in kernels.values()) / 1e9,
+              sum(k[2] for k in kernels.values()) / 1e6,
+              sum(k[3] for k in kernels.values()), len(silent),
+              " " + ",".join(sorted(set(silent))) if silent else ""))
 
 
 def print_account(text, top):
@@ -216,6 +264,7 @@ def print_account(text, top):
     print("%-22s %6d %12.2f %12.1f %10.3f" % (
         "sum", len(rows), sum(i.flops or 0.0 for _, i in rows) / 1e9,
         sum(i.bytes for _, i in rows) / 1e6, sum(f for f, _ in rows)))
+    print_kernels(text)
     print("\n%9s %10s %10s  %-12s %-28s %-6s %s" % (
         "floor ms", "GFLOP", "MB", "heavy", "instruction", "at", "op"))
     for floor, i in sorted(rows, key=lambda r: -r[0])[:top]:
