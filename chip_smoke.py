@@ -404,7 +404,9 @@ def recompute_phase(seqlen, d_model, heads, head_dim, state, width, vocab,
     (backward.append_backward(checkpoints=)), and the same step without
     checkpoints on the same seed: the first-step losses agree within
     `rtol`, no scan falls back, and the compiled step with checkpoints
-    holds one forward scan kernel more (the replay's: XLA merged it with
+    holds one forward scan kernel more for each scan the executor chose
+    to replay (recompute.py: none where the device has room to keep the
+    segment; where it replays, XLA merged the replay's kernel with
     neither the first forward nor dropped it) and the same gradient
     kernels."""
     from paddle_tpu import backward
@@ -412,7 +414,7 @@ def recompute_phase(seqlen, d_model, heads, head_dim, state, width, vocab,
     feed = _lm_feed(1, seqlen, vocab)
     compiles = _Compiles()
     before = _counters("pallas_fallback_total")["pallas_fallback_total"]
-    out, mosaic = {}, {}
+    out, mosaic, scans_again = {}, {}, 0
     for name, recompute in (("replayed", True), ("kept", False)):
         main, startup, loss = _build_hybrid(seqlen, d_model, heads, head_dim,
                                             state, width, vocab, recompute)
@@ -420,6 +422,12 @@ def recompute_phase(seqlen, d_model, heads, head_dim, state, width, vocab,
         losses, step_s, exe, scope = _lm_steps(main, startup, loss, feed,
                                                steps)
         out[name] = {"losses": losses, "step_s": step_s}
+        if recompute:
+            decided = exe.recompute_plan(main).decisions
+            scans_again = sum(
+                types.count("ssd_scan") for segment, types in
+                backward.replayed_ops(main).items()
+                if not decided[segment].kept)
         if compiled:
             mosaic[name] = _mosaic_calls(_step_hlo(exe, main, feed, loss,
                                                    scope))
@@ -436,12 +444,12 @@ def recompute_phase(seqlen, d_model, heads, head_dim, state, width, vocab,
     if compiled:
         want = dict(mosaic["kept"])
         want["ssd_scan/ssd_scan_fwd"] = want.get("ssd_scan/ssd_scan_fwd",
-                                                 0) + 1
+                                                 0) + scans_again
         if mosaic["replayed"] != want:
             raise AssertionError(
                 f"the replayed layer's scan: Mosaic calls "
                 f"{mosaic['replayed']} with checkpoints, {mosaic['kept']} "
-                f"without; expected one ssd_scan_fwd more")
+                f"without; expected {scans_again} ssd_scan_fwd more")
     return {
         "phase": "recompute", "model": "granite_hybrid_lm", "seqlen": seqlen,
         "d_model": d_model, "heads_in_one_group": heads, "amp": "O2",

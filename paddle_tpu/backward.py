@@ -28,6 +28,12 @@ nothing, or only what it does not keep (kda_scan's Out and entering
 states, from the kept inverses). The ops after the
 last checkpoint are not replayed: the backward starts there. Without
 `checkpoints` nothing of this runs and the program is the one it was.
+
+A checkpoint is where the backward MAY cut, not an order: the program
+spells every segment's replay, and the executor, which knows the device,
+lowers a segment's barrier and replayed ops only if keeping the segment's
+forward values would not fit (recompute.py; a device that reports no
+memory limit, the CPU, replays every segment as spelt).
 """
 
 from __future__ import annotations
@@ -173,7 +179,9 @@ def replayed_ops(program: Program, handed_on: Optional[bool] = None
                  ) -> Dict[int, List[str]]:
     """{segment: the types of the forward ops replayed in it, in order}
     of the root block, read from the ops' RECOMPUTE_ATTR (the barrier is
-    not one of them). `handed_on`: True, only the ops that stand in their
+    not one of them): what MAY be replayed. Which of the segments a
+    device replays is the executor's decision at its trace
+    (Executor.recompute_plan, recompute.py). `handed_on`: True, only the ops that stand in their
     segment and are handed outputs the first forward kept (they read
     registry.KEPT_SLOT inputs); False, only the ops that run again, among
     them an op handed some of its outputs and not all (kda_scan, handed
@@ -336,9 +344,12 @@ def append_backward(loss: Variable, parameter_list: Optional[Sequence] = None,
     Only root-block autodiff is supported directly; control-flow ops carry
     their own sub-block grad logic via custom grad makers.
 
-    `checkpoints`: variables (or names) of the forward pass to keep; the
-    forward ops between two of them are recomputed in the backward (module
-    docstring). None or an empty list: nothing is. Root block only, as
+    `checkpoints`: variables (or names) of the forward pass at which the
+    backward may cut; the forward ops between two of them are spelt again
+    in the backward (module docstring), and run again wherever the
+    executor finds that keeping their values would not fit the device
+    (recompute.py: on a device that reports no memory limit, always).
+    None or an empty list: nothing is. Root block only, as
     the autodiff itself: an op that runs a sub-block, draws random numbers,
     writes state or carries a LoD is refused by name where a segment would
     replay it. replayed_ops(program) reads what each segment replays back

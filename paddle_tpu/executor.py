@@ -24,6 +24,7 @@ import functools
 import os
 import sys
 import time
+import warnings
 from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
@@ -36,11 +37,12 @@ from . import inspector as inspector_mod
 from . import memory as memory_mod
 from . import profiler as profiler_mod
 from . import quant as quant_mod
+from . import recompute as recompute_mod
 from . import sentinel as sentinel_mod
 from . import telemetry
 from . import tracing as tracing_mod
 from . import xplane as xplane_mod
-from .backward import RECOMPUTE_ATTR, replayed_ops
+from .backward import RECOMPUTE_ATTR
 from .framework.desc import VarType
 from .framework.framework import (NAME_SCOPE_ATTR, Program, Variable,
                                   default_main_program)
@@ -723,6 +725,12 @@ class Executor:
         self.device = place_device(self.place)
         self._cache: Dict[Tuple, _CompiledBlock] = {}
         self._analysis_cache: Dict[Tuple, Tuple] = {}
+        # (id(program), version) -> what the last trace decided of the
+        # program's recomputation segments (recompute.Plan), and the
+        # programs whose compile ran out of memory with segments kept:
+        # those are traced again with every segment replayed
+        self._replay_plans: Dict[Tuple, recompute_mod.Plan] = {}
+        self._replay_all: set = set()
         # telemetry side-fetches dispatched and not yet on the host
         self._side_pending: collections.deque = collections.deque()
 
@@ -1261,21 +1269,32 @@ class Executor:
                        dynamics_mod.cache_token(program),
                        quant_mod.cache_token(program))
                 compiled = self._cache.get(key) if use_program_cache else None
-            if compiled is None:
-                plan_t0 = time.perf_counter()
+            def build():
                 args = (program, state_keys, sorted(feed_vals), fetch_names,
                         persist_out, lod_map)
-                compiled = (self._compile_window(*args, steps, fetch_mode)
-                            if window else self._compile(*args))
-                plan_s = time.perf_counter() - plan_t0
+                block = (self._compile_window(*args, steps, fetch_mode)
+                         if window else self._compile(*args))
                 if use_program_cache:
-                    self._cache[key] = compiled
+                    self._cache[key] = block
+                return block
+
+            if compiled is None:
+                plan_t0 = time.perf_counter()
+                compiled = build()
+                plan_s = time.perf_counter() - plan_t0
             call = lambda: compiled.fn(feed_vals, state_vals,
                                        np.uint32(rng_counter))
 
-        out, launch = self._launch(
-            program, compiled, call, key, mode, steps, feed_vals, state_vals,
-            rng_counter, plan_s, prog_label, place_label)
+        launch_args = (key, mode, steps, feed_vals, state_vals, rng_counter,
+                       plan_s, prog_label, place_label)
+        try:
+            out, launch = self._launch(program, compiled, call, *launch_args)
+        except Exception as e:
+            if compiled is None or not self._kept_too_much(
+                    program, state_vals, e):
+                raise
+            compiled = build()      # traced anew: every segment replayed
+            out, launch = self._launch(program, compiled, call, *launch_args)
         # a window has no sequence fetches (_WindowUnsupported)
         fetch_vals, fetch_lens, new_state = \
             (out[0], {}, out[1]) if window else out
@@ -1415,25 +1434,6 @@ class Executor:
                 "executor_compiles_total", "block traces/compiles",
                 labels=("program", "place")).labels(
                     program=prog_label, place=place_label).inc()
-            replayed = replayed_ops(program)
-            if replayed:
-                telemetry.counter(
-                    "recompute_segments_total",
-                    "segments of forward ops a compiled block replays in "
-                    "its backward (append_backward(checkpoints=)), a compile",
-                    labels=("program",)).labels(program=prog_label).inc(
-                        len(replayed))
-                # what runs again: an op handed the outputs its first
-                # run kept books itself where it is lowered
-                # (registry.handed_on: recompute_kept_total, _bytes)
-                by_type = telemetry.counter(
-                    "recompute_ops_total",
-                    "forward ops that run again in the backward, a "
-                    "compile, by op type", labels=("program", "type"))
-                for types in replayed_ops(program, handed_on=False).values():
-                    for op_type in types:
-                        by_type.labels(program=prog_label,
-                                       type=op_type).inc()
             telemetry.counter(
                 "executor_compile_seconds_total",
                 "XLA compile wall seconds spent inside Executor.run",
@@ -1737,6 +1737,7 @@ class Executor:
         dynamics_mod.drain(wait=True)           # and their dynamics rows
         self._cache.clear()
         self._analysis_cache.clear()
+        self._replay_plans.clear()
 
     # --- analysis -----------------------------------------------------------
     @staticmethod
@@ -1972,6 +1973,59 @@ class Executor:
                                    ctx.layout_overrides)
         ctx.env = prev_env
 
+    def recompute_plan(self, program) -> Optional[recompute_mod.Plan]:
+        """What the last trace of `program` decided of its recomputation
+        segments (recompute.Plan: kept or replayed a segment, why, and
+        the bytes it was decided from); None before a trace, or where the
+        program holds no segment."""
+        return self._replay_plans.get(
+            (id(program), getattr(program, "_version", 0)))
+
+    def _plan_replay(self, program, env, state_vals) -> frozenset:
+        """The block positions a kept segment's ops stand at: decided at
+        the turn from forward to backward from the traced values' bytes
+        and this device's limit (recompute.plan), booked, and remembered
+        for recompute_plan()."""
+        key = (id(program), getattr(program, "_version", 0))
+        made = recompute_mod.plan(
+            program, env,
+            sum(memory_mod.nbytes_of(v) for v in state_vals.values()),
+            memory_mod.device_limit(self.device),
+            refused=key in self._replay_all)
+        if len(self._replay_plans) > 64:
+            self._replay_plans.clear()
+        self._replay_plans[key] = made
+        recompute_mod.book(program, made)
+        return made.skipped
+
+    def _kept_too_much(self, program, state_vals, error) -> bool:
+        """The safety net under the estimate: did this launch run out of
+        memory while COMPILING a step with segments kept? Then the
+        program is marked to be traced with every segment replayed,
+        counted and warned of; the caller compiles once more. A step that
+        ran out while running has consumed its donated state and is not
+        retried."""
+        made = self.recompute_plan(program)
+        if made is None or not made.skipped or not memory_mod.is_oom(error) \
+                or any(getattr(v, "is_deleted", lambda: False)()
+                       for v in state_vals.values()):
+            return False
+        self._replay_all.add((id(program), getattr(program, "_version", 0)))
+        label = telemetry.program_label(program)
+        telemetry.counter(
+            "recompute_fallback_total",
+            "compiles that ran out of device memory with recomputation "
+            "segments kept and were made again with every segment replayed",
+            labels=("program",)).labels(program=label).inc()
+        warnings.warn(
+            f"paddle_tpu recompute: program '{label}' did not fit with "
+            f"{sum(d.kept for d in made.decisions.values())} "
+            f"segment(s) kept ({made.kept_bytes} B; estimate "
+            f"{made.estimate} of {made.limit} B); compiling again with "
+            f"every segment replayed [recompute_fallback_total]",
+            RuntimeWarning, stacklevel=4)
+        return True
+
     def _trace_block(self, program, feed_vals, state_vals, fetch_names,
                      persist_out, rng_key, lod_map, grab_names=()):
         env: Dict[str, Any] = {}
@@ -2000,24 +2054,46 @@ class Executor:
         # "pd_scope." rule sees it). Metadata only: the compiled step is
         # the same. The account's rows group by it (xplane.provenance):
         # which of 53 conv windows, which layer's mul_grad
+        #
+        # A program built with checkpoints says where the backward MAY
+        # replay; which segments it does is decided once the first forward
+        # is traced and every value's bytes are known (recompute.py). A
+        # kept segment's barrier and replayed ops are bound to the first
+        # forward's values and not lowered.
+        turn = min((seg.barrier for seg in recompute_mod.segments(block)),
+                   default=None)
+        kept_ops: frozenset = frozenset()
+
+        def lower(i, op):
+            if i in kept_ops:
+                recompute_mod.bind(op, env, ctx.layouts)
+            else:
+                self._exec_op(ctx, op, env)
+
         if not groups and oplan is None:
             for i, op in enumerate(block.ops):
+                if i == turn:
+                    kept_ops = self._plan_replay(program, env, state_vals)
                 with jax.named_scope(f"{xplane_mod.AT_SCOPE}{i}"):
-                    self._exec_op(ctx, op, env)
+                    lower(i, op)
         else:
             protected = set(fetch_names) | set(persist_out)
             ops = block.ops
             groups = groups or {}
             i = 0
             while i < len(ops):
+                if i == turn:
+                    kept_ops = self._plan_replay(program, env, state_vals)
                 g = groups.get(i)
+                if g is not None and kept_ops.intersection(range(i, g.end)):
+                    g = None    # a kept segment's ops are bound one by one
                 with jax.named_scope(f"{xplane_mod.AT_SCOPE}{i}"):
                     if g is not None:
                         fusion_mod.execute_group(self, ctx, g, env,
                                                  protected)
                         nxt = g.end
                     else:
-                        self._exec_op(ctx, ops[i], env)
+                        lower(i, ops[i])
                         nxt = i + 1
                 if oplan is not None:
                     # anchors inside a fused window flush after the window

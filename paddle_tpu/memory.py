@@ -55,7 +55,7 @@ __all__ = [
     "ProgramMemory", "MemoryTracker", "HeadroomModel",
     "analyze", "hlo_peak_liveness", "shape_bytes", "nbytes_of",
     "classify", "tracker", "top_live_buffers", "live_array_bytes",
-    "is_oom", "maybe_oom_error", "default_budget",
+    "is_oom", "maybe_oom_error", "default_budget", "device_limit",
     "records", "latest_record", "reset", "memory_report",
     "crash_section", "build_smoke", "on_compile", "on_run",
     "per_shard_param_bytes",
@@ -816,6 +816,32 @@ class HeadroomModel:
         return {"fixed_bytes": int(self.fixed_bytes),
                 "per_item_bytes": round(self.per_item_bytes, 2),
                 "points": self.points}
+
+
+# what the runtime holds back of a chip's HBM: a v5e's allocator reports
+# a bytes_limit of 16,909,336,064 B of its 16 GiB (my chip run, PR 67;
+# 15.75 GiB, and the compiler refuses a step against the same figure:
+# "16.21 of 15.75 GB", PERF.md section 4)
+_RUNTIME_HOLDS = 16 * GiB - 16_909_336_064
+
+
+def device_limit(device) -> Optional[int]:
+    """The bytes a step may fill on `device`: the allocator's bytes_limit
+    where the device reports one; for a device that is a description
+    with no runtime behind it (tools/describe_step.py's v5e) the HBM on
+    record for its kind less what the runtime holds back, so that a step
+    described is the step the chip will run; None where the device
+    reports no limit and none is on record (the CPU). What decides from
+    it (recompute.plan) then decides as if nothing could be kept."""
+    from . import chip
+    try:
+        stats = device.memory_stats()
+    except Exception:   # not addressable: a described device
+        stats = None
+    if stats and stats.get("bytes_limit"):
+        return int(stats["bytes_limit"])
+    row = chip.peaks(device)
+    return row.hbm_bytes - _RUNTIME_HOLDS if row else None
 
 
 def default_budget(device=None) -> int:

@@ -120,8 +120,10 @@ class Optimizer:
                  no_grad_set=None, checkpoints=None) -> Tuple[List, List]:
         """append_backward + regularization + clip + optimizer ops
         (reference optimizer.py Optimizer.minimize). `checkpoints`: the
-        forward variables to keep; what lies between two of them is
-        recomputed in the backward (backward.append_backward)."""
+        forward variables at which the backward may cut; what lies
+        between two of them is recomputed in the backward wherever
+        keeping it would not fit the device (backward.append_backward,
+        recompute.py)."""
         params_grads = append_backward(loss, parameter_list, no_grad_set,
                                        [error_clip_callback],
                                        checkpoints=checkpoints)

@@ -856,11 +856,22 @@ METRIC_CATALOG = {
         "counter", ("program", "reason"),
         "run_steps windows that fell back to per-step execution"),
     "recompute_segments_total": _m(
+        "counter", ("program", "decision", "reason"),
+        "segments between two checkpoints, a trace, by what the executor "
+        "decided from their bytes and the device's limit (recompute.py): "
+        "kept (fits: nothing runs twice) or replayed (budget, no_limit: "
+        "the device reports none, unknown_shape)"),
+    "recompute_fallback_total": _m(
         "counter", ("program",),
-        "segments of forward ops a compiled block replays in its backward"),
+        "compiles that ran out of device memory with segments kept and "
+        "were made again with every segment replayed"),
+    "recompute_segments_kept_bytes": _m(
+        "gauge", ("program",),
+        "bytes the kept segments hold across the turn to the backward, "
+        "as estimated at the last trace"),
     "recompute_ops_total": _m(
         "counter", ("program", "type"),
-        "forward ops that run again in the backward, a compile, by op "
+        "forward ops that run again in the backward, a trace, by op "
         "type"),
     "recompute_kept_total": _m(
         "counter", ("program", "type"),

@@ -67,6 +67,19 @@ def mosaic(monkeypatch, no_persistent_cache):
     monkeypatch.setattr(pallas_conv, "_interpret", lambda: False)
 
 
+@pytest.fixture
+def no_room(monkeypatch):
+    """The described chip with room to keep no recomputation segment (its
+    limit read as the estimate's margin alone): every segment a
+    checkpointed step spells is replayed, as the full-depth Kimi-Linear
+    and Qwen3-Next cells' are. A cut of two or three layers would keep
+    them all under the chip's own 15.75 GiB (recompute.py), and the
+    tests of what a replayed segment compiles to would read nothing."""
+    from paddle_tpu import memory, recompute
+    monkeypatch.setattr(memory, "device_limit",
+                        lambda device: recompute.MARGIN_BYTES)
+
+
 def _float32_under_the_conv(text):
     """The instructions under pd.causal_conv1d or its gradient op that
     write a float32 array of a whole [T, C] activation (2**24 elements
@@ -1040,7 +1053,7 @@ LAGUNA_CELL = "laguna-xs.2.train-gated-swa512-ep8-share"
 
 
 def test_gated_window_step_keeps_no_product_of_a_forward_that_is_replayed(
-        mosaic, one_chip):
+        mosaic, one_chip, no_room):
     """The gated window cell's step at its own 8192-token sequence and
     published widths, the depth cut to the dense layer and two expert
     layers under a checkpoint a layer, the first expert layer replayed
@@ -1119,7 +1132,8 @@ def _no_kv_repeat_around_the_flash_calls(text, booked):
 KDA_CELL = "kimi-linear.train-kda-t8192-ep32-share"
 
 
-def test_delta_rule_step_compiles_with_both_kinds_of_mixer(mosaic, one_chip):
+def test_delta_rule_step_compiles_with_both_kinds_of_mixer(
+        mosaic, one_chip, no_room):
     """The Kimi-Linear cell's step at its own 8192-token sequence and
     published widths, the depth cut to two layers, one of each kind (a
     KDA mixer before the dense feed-forward, latent attention before an
@@ -1189,8 +1203,8 @@ def test_delta_rule_step_compiles_with_both_kinds_of_mixer(mosaic, one_chip):
 LFM2_CELL = "lfm2-24b-a2b.train-shortconv-ep8-share"
 
 
-def test_shortconv_step_keeps_no_float32_rows_around_the_conv(mosaic,
-                                                              one_chip):
+def test_shortconv_step_keeps_no_float32_rows_around_the_conv(
+        mosaic, one_chip, no_room):
     """The LFM2 cell's step at its own 16,384-token sequence and published
     widths, the layers held cut to published layers 0, 2 and 3 (conv +
     dense, attention + experts, conv + experts; the five take four minutes
@@ -1259,8 +1273,8 @@ def test_shortconv_step_keeps_no_float32_rows_around_the_conv(mosaic,
 GDN_CELL = "qwen3-next-80b-a3b.train-gdn-t16k-ep16-share"
 
 
-def test_gated_delta_net_step_reads_q_k_and_the_gate_as_they_are(mosaic,
-                                                                 one_chip):
+def test_gated_delta_net_step_reads_q_k_and_the_gate_as_they_are(
+        mosaic, one_chip, no_room):
     """The Qwen3-Next cell's step at its own 16,384-token sequence and
     published widths, the layers held cut to published layers 2 and 3 (a
     Gated DeltaNet layer and the gated attention layer, each with its
@@ -1361,8 +1375,8 @@ def test_gated_delta_net_step_reads_q_k_and_the_gate_as_they_are(mosaic,
 GRANITE_CELL = "granite-4.0-h-micro.train-ssm-recompute"
 
 
-def test_granite_step_holds_fewer_temporaries_with_checkpoints(mosaic,
-                                                               one_chip):
+def test_granite_step_holds_fewer_temporaries_with_checkpoints(
+        mosaic, one_chip, no_room):
     """The granite cell's step at its own 8192-token sequence and published
     widths, the depth cut to two Mamba layers and the attention layer (the
     ten take five minutes here; tools/describe_step.py sized them: 7.08e9
@@ -1444,7 +1458,8 @@ def test_flash_gate_declines(shape, reason):
 OURO_CELL = "ouro-2.6b.train-loop4-t4096-pp6-stage"
 
 
-def test_looped_step_reads_one_set_of_weights_four_times(mosaic, one_chip):
+def test_looped_step_reads_one_set_of_weights_four_times(mosaic, one_chip,
+                                                         no_room):
     """The Ouro cell's step at its own 4096-token sequence and published
     widths, the depth cut to ONE layer run four times (the eight take
     five minutes here; tools/describe_step.py sized them, PR 59:
@@ -1492,3 +1507,100 @@ def test_looped_step_reads_one_set_of_weights_four_times(mosaic, one_chip):
     assert logits["bf16"] and not logits["f32"]
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 15.75e9
+
+
+# what the executor decides for a described v5e at each checkpointed cell's
+# full depth (recompute.py; PERF.md section 6, PR 67): the segments kept,
+# of how many
+DECIDED = {
+    LFM2_CELL: ([2, 3, 4], 4),
+    GRANITE_CELL: ([0, 6], 10),
+    KDA_CELL: ([1], 4),
+    LAGUNA_CELL: ([1, 4], 4),
+    GDN_CELL: ([], 3),
+    OURO_CELL: ([26, 27, 28, 29, 30, 31], 31),
+}
+
+
+@pytest.mark.parametrize("name", list(DECIDED))
+def test_a_checkpointed_cell_keeps_the_segments_that_fit_a_v5e(mosaic,
+                                                               one_chip,
+                                                               name):
+    """Each checkpointed cell's step at full depth, traced for the
+    described chip and not compiled: the executor's decision is made from
+    the chip's own limit (15.75 GiB: what tools/describe_step.py shows is
+    the step the chip runs), the smallest segments first and of equal
+    ones the later, the estimate and the margin under the limit, and a
+    segment more would not be. The cell whose segments are 3.56e9 B
+    (Qwen3-Next) keeps nothing: its step is the one the IR spells."""
+    from paddle_tpu import memory, recompute
+    kept, segments = DECIDED[name]
+    cell = run.load_json("workloads", name)
+    made = describe_step.replay_plan(
+        cell, run.load_json("configs", cell["config"]), one_chip)
+    assert made.limit == 16_909_336_064 \
+        == memory.device_limit(next(iter(one_chip.device_set)))
+    assert len(made.decisions) == segments
+    assert sorted(i for i, d in made.decisions.items() if d.kept) == kept
+    assert {d.reason for d in made.decisions.values()} <= {"fits", "budget"}
+    assert all(d.reason == "fits" for d in made.decisions.values()
+               if d.kept)
+    assert made.estimate + recompute.MARGIN_BYTES <= made.limit
+    sizes = {i: d.nbytes for i, d in made.decisions.items()}
+    assert made.kept_bytes == sum(sizes[i] for i in kept)
+    replayed = sorted((i for i in sizes if i not in kept),
+                      key=lambda i: (sizes[i], -i))
+    assert not kept or max(sizes[i] for i in kept) <= sizes[replayed[0]]
+    assert recompute.estimate(sizes, made.held, kept + replayed[:1]) \
+        + recompute.MARGIN_BYTES > made.limit
+
+
+def test_shortconv_step_keeps_its_segments_where_they_fit(mosaic, one_chip):
+    """The LFM2 cut of test_shortconv_step_keeps_no_float32_rows_around_
+    the_conv under the described chip's own limit: both segments that
+    may be replayed fit and are kept, so the compiled step holds one
+    gated forward kernel a conv layer and none under a `pd_recompute`
+    scope, no barrier stands in it, the gradient kernels are the replayed
+    step's, and the bytes it holds are under the limit."""
+    from paddle_tpu import memory, telemetry
+    cell = run.load_json("workloads", LFM2_CELL)
+    config = dict(run.load_json("configs", cell["config"]),
+                  layers_held=[0, 2, 3], num_hidden_layers=3)
+    before = dict(telemetry.read_series("recompute_segments_total"))
+    compiled = describe_step.compile_step(cell, config, one_chip)
+    added = {k.split(",", 1)[1]: v - before.get(k, 0) for k, v in
+             telemetry.read_series("recompute_segments_total").items()
+             if v != before.get(k, 0)}
+    assert added == {"decision=kept,reason=fits": 2}
+    text = compiled.as_text()
+    kernels = [re.search(r'op_name="[^"]*?(\w+)\)*/pallas_call', line).group(1)
+               for line in text.splitlines() if KERNEL in line]
+    assert {k: kernels.count(k) for k in set(kernels) if "conv1d" in k} == {
+        "gated_conv1d_fwd": 2, "gated_conv1d_bwd": 2}
+    assert {k: kernels.count(k) for k in set(kernels) if "flash" in k} == {
+        "flash_fwd": 1, "flash_dkv": 1}
+    assert "pd_recompute." not in text and "recompute_barrier" not in text
+    assert not [i for i in xplane.hlo_instructions(text)
+                if i.recompute is not None]
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
+        < memory.device_limit(next(iter(one_chip.device_set)))
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("name", [LFM2_CELL, GDN_CELL, OURO_CELL])
+def test_a_checkpointed_cell_compiles_the_first_time_at_full_depth(
+        mosaic, one_chip, name):
+    """The claimed cell and the two tightest at full depth (four to five
+    minutes each here, so not tier-1's; tools/describe_step.py sized them,
+    PR 67: LFM2 15.71e9 B with three of four segments kept, Ouro 14.61e9
+    with six of 31, Qwen3-Next the parent's step): the chip's compiler
+    takes the step as decided, with room, so the net under the estimate
+    (recompute_fallback_total) is not what a cell runs on."""
+    from paddle_tpu import memory
+    cell = run.load_json("workloads", name)
+    compiled = describe_step.compile_step(
+        cell, run.load_json("configs", cell["config"]), one_chip)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
+        < memory.device_limit(next(iter(one_chip.device_set)))

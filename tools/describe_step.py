@@ -9,7 +9,8 @@ Builds the cell's program from its benchmark files (`benchmarks/` is read,
 never written), traces the step as Executor.run would (state donated, the
 kernels lowered for Mosaic) on avals alone (no startup run, no state on
 the host) and prints the compiler's memory analysis, the Mosaic call
-count and every instruction of the entry computation that writes more
+count, for a step with checkpoints the segments the executor keeps and
+replays under the described chip's memory limit (recompute.py) and every instruction of the entry computation that writes more
 than N elements (default 2**28), widest first, with its `op_name`: the
 buffers that a memory-bound op's traffic is made of. `--set n_layer=1`
 overrides a key of the configuration (a depth, to read one layer fast);
@@ -74,10 +75,9 @@ def described_mesh(axes):
         sizes), tuple(axes))
 
 
-def compile_step(cell, config, where):
-    """The compiled train step of `cell` under `config`, for `where`: the
-    sharding of one described chip, or for a cell with a `mesh` the
-    described mesh the program is planned over first, as
+def build_step(cell, config, where):
+    """(main, startup, loss, feed) of `cell` under `config`; a cell with
+    a `mesh` is planned over the described mesh `where` first, as
     `benchmarks/traffic/train_steps_planned.py` plans it."""
     from benchmarks import run
 
@@ -87,19 +87,40 @@ def compile_step(cell, config, where):
     if cell.get("mesh"):
         from paddle_tpu.parallel import planner
         planner.plan(main, where, startup=startup)
-    return compile_program(main, startup, loss, feed, where)
+    return main, startup, loss, feed
 
 
-def compile_program(main, startup, loss, feed, where):
-    """The train step of `main` as Executor.run would trace it (state
-    donated), compiled from avals alone, the state's from `startup`, the
-    feed's from `feed`: for the device of the sharding `where`, or, where
-    `main` carries a mesh, with the shardings and compiler options the
-    executor gives a planned step."""
+def compile_step(cell, config, where):
+    """The compiled train step of `cell` under `config`, for `where`: the
+    sharding of one described chip, or for a cell with a `mesh` the
+    described mesh."""
+    return compile_program(*build_step(cell, config, where), where)
+
+
+def replay_plan(cell, config, where):
+    """What the executor decides of the cell's recomputation segments for
+    the described chip `where` (recompute.Plan; None for a step without
+    checkpoints): the step traced on avals, nothing compiled."""
+    main, startup, loss, feed = build_step(cell, config, where)
+    exe, step, avals, _ = _step_on_avals(main, startup, loss, feed, where)
+    jax.eval_shape(step, *avals)
+    return exe.recompute_plan(main)
+
+
+def _step_on_avals(main, startup, loss, feed, where):
+    """(executor, step function, avals, jit options) of the train step of
+    `main` as Executor.run would trace it, the state's avals from
+    `startup`, the feed's from `feed`: on the device of the sharding
+    `where`, or, where `main` carries a mesh, with the shardings and
+    compiler options the executor gives a planned step."""
     import paddle_tpu as fluid
     from paddle_tpu.parallel import overlap
 
     exe = fluid.Executor(fluid.CPUPlace())
+    if getattr(main, "_mesh", None) is None:
+        # the executor decides what a checkpointed step replays from its
+        # device's limit: here the described chip's
+        exe.device, = where.device_set
 
     def step_fn(program, fetch):
         return exe._make_step_fn(program, fetch,
@@ -120,10 +141,18 @@ def compile_program(main, startup, loss, feed, where):
         return {n: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=at[n])
                 for n, v in tree.items()}
 
-    return jax.jit(step_fn(main, [loss.name]), donate_argnums=(1,),
-                   **options).lower(
+    return exe, step_fn(main, [loss.name]), (
         avals(feed, feed_at), avals(state, state_at),
-        jax.ShapeDtypeStruct((), np.uint32, sharding=rng_at)).compile()
+        jax.ShapeDtypeStruct((), np.uint32, sharding=rng_at)), options
+
+
+def compile_program(main, startup, loss, feed, where):
+    """The train step of `main` as Executor.run would trace it (state
+    donated), compiled from avals alone (`_step_on_avals`)."""
+    _, step, avals, options = _step_on_avals(main, startup, loss, feed,
+                                             where)
+    return jax.jit(step, donate_argnums=(1,), **options).lower(
+        *avals).compile()
 
 
 def tokens_a_chip(compiled):
@@ -291,9 +320,13 @@ def main(argv=None):
     config = run.load_json("configs", cell["config"])
     config.update((k, json.loads(v)) for k, v in
                   (item.split("=", 1) for item in args.set))
-    compiled = compile_step(
-        cell, config, described_mesh(cell["mesh"]) if cell.get("mesh")
-        else described_chip())
+    where = described_mesh(cell["mesh"]) if cell.get("mesh") \
+        else described_chip()
+    built = build_step(cell, config, where)
+    exe, step, avals, options = _step_on_avals(*built, where)
+    compiled = jax.jit(step, donate_argnums=(1,), **options).lower(
+        *avals).compile()
+    decided = exe.recompute_plan(built[0])
     text = compiled.as_text()
     if args.hlo:
         with open(args.hlo, "w") as f:
@@ -305,7 +338,17 @@ def main(argv=None):
         "argument_bytes": mem.argument_size_in_bytes,
         "alias_bytes": mem.alias_size_in_bytes,
         "output_bytes": mem.output_size_in_bytes,
-        "mosaic_calls": text.count('custom_call_target="tpu_custom_call"')}))
+        "mosaic_calls": text.count('custom_call_target="tpu_custom_call"'),
+        # what the executor decided of the checkpointed segments for the
+        # described chip's limit (recompute.py): the step the chip runs
+        **({} if decided is None else {
+            "segments_kept": sorted(
+                i for i, d in decided.decisions.items() if d.kept),
+            "segments_replayed": sorted(
+                i for i, d in decided.decisions.items() if not d.kept),
+            "kept_bytes": decided.kept_bytes,
+            "estimated_bytes": decided.estimate,
+            "limit_bytes": decided.limit})}))
     if cell.get("mesh"):
         print("%-10s %-20s %-10s %-6s %6s %6s %10s" % (
             "axis", "kind", "what", "dtype", "instrs", "colls", "MB"))
