@@ -11,8 +11,8 @@ __all__ = [
     "Constant", "Uniform", "Normal", "Xavier", "MSRA",
     "ConstantInitializer", "UniformInitializer", "NormalInitializer",
     "XavierInitializer", "MSRAInitializer", "LogOfUniformInitializer",
-    "SoftplusInverseLogUniformInitializer", "force_init_on_cpu",
-    "init_on_cpu",
+    "SoftplusInverseLogUniformInitializer", "ValuesInitializer",
+    "force_init_on_cpu", "init_on_cpu",
 ]
 
 import contextlib
@@ -155,6 +155,21 @@ class SoftplusInverseLogUniformInitializer(Initializer):
         _in_place(block, var, "exp")
         _in_place(block, var, "scale", scale=1.0, bias=-1.0)
         return _in_place(block, var, "log")
+
+
+class ValuesInitializer(Initializer):
+    """The given float values, element for element (a bias that starts
+    at a logit, an identity's multiple): `assign_value` of the list."""
+
+    def __init__(self, values):
+        self.values = [float(v) for v in values]
+
+    def __call__(self, var, block):
+        assert len(self.values) == int(np.prod(var.shape)), var.shape
+        return block.append_op(
+            type="assign_value", outputs={"Out": var},
+            attrs={"shape": list(var.shape), "dtype": var.dtype,
+                   "fp32_values": self.values})
 
 
 Constant = ConstantInitializer

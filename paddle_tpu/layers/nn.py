@@ -13,7 +13,8 @@ import numpy as np
 from ..framework.framework import Variable, name_scope
 from ..initializer import (ConstantInitializer, LogOfUniformInitializer,
                            NormalInitializer,
-                           SoftplusInverseLogUniformInitializer)
+                           SoftplusInverseLogUniformInitializer,
+                           ValuesInitializer)
 from ..layer_helper import LayerHelper
 
 __all__ = [
@@ -39,6 +40,8 @@ __all__ = [
     "mamba2_mixer", "kda_mixer", "gdn_mixer", "short_conv_mixer",
     "moe_block",
     "rotary_embedding", "gated_mlp", "latent_attention", "mtp_block",
+    "hyper_connection_maps", "sinkhorn_knopp", "hc_pre_mix",
+    "hc_post_res_mix",
 
     "hsigmoid", "bilinear_interp", "selective_fc",
 ]
@@ -1616,6 +1619,13 @@ def moe_block(x, num_experts, top_k, expert_width, shared_width=0,
     return out
 
 
+def yarn_mscale(factor, mscale=1.0):
+    """YaRN's magnitude factor 0.1 mscale ln(factor) + 1 (1 at a factor
+    of 1 or less): arXiv:2309.00071 section 3.4 at mscale 1, and
+    DeepSeek-V3's `yarn_get_mscale`."""
+    return 0.1 * float(mscale) * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
 @_under_its_name
 def rotary_embedding(x, theta=10000.0, rotary_dims=None, name=None,
                      scaling=None, rotate_first=False):
@@ -1635,16 +1645,27 @@ def rotary_embedding(x, theta=10000.0, rotary_dims=None, name=None,
     32, and `beta_slow`, default 1, bound the blend), and multiplies the
     cosines and sines by `attention_factor` (default 0.1 ln(factor) + 1):
     the rotated dims of queries and keys come out scaled by it, the
-    others do not. None, or `rope_type` "default": the plain angles. The
+    others do not. A group in the DeepSeek keys (`type` where `rope_type`
+    is absent, `mscale` and `mscale_all_dim`) has no `attention_factor`
+    and gives the cosines and sines m(mscale) / m(mscale_all_dim), m(s) =
+    0.1 s ln(factor) + 1, as DeepSeek-V3's modelling code reads them
+    (yarn_mscale; the scores' own factor is latent_attention's). None, or
+    `rope_type` "default": the plain angles. The
     op's new attributes are written only when set, so a program without
     `scaling` is the one it was."""
     attrs = {"theta": float(theta),
              "rotary_dims": int(rotary_dims or x.shape[-1])}
     if rotate_first:
         attrs["rotate_first"] = True
-    kind = (scaling or {}).get("rope_type", "default")
+    scaling = scaling or {}
+    kind = scaling.get("rope_type", scaling.get("type", "default"))
     if kind == "yarn":
         factor = float(scaling["factor"])
+        if "mscale_all_dim" in scaling:
+            default = yarn_mscale(factor, scaling.get("mscale", 1)) \
+                / yarn_mscale(factor, scaling["mscale_all_dim"])
+        else:
+            default = yarn_mscale(factor)
         attrs.update(
             yarn_factor=factor,
             yarn_original_positions=float(
@@ -1652,7 +1673,7 @@ def rotary_embedding(x, theta=10000.0, rotary_dims=None, name=None,
             yarn_beta_fast=float(scaling.get("beta_fast") or 32),
             yarn_beta_slow=float(scaling.get("beta_slow") or 1),
             attention_factor=float(scaling.get("attention_factor")
-                                   or 0.1 * math.log(factor) + 1.0))
+                                   or default))
     elif kind != "default":
         raise ValueError(f"rotary_embedding knows rope_type 'default' and "
                          f"'yarn', not {kind!r}")
@@ -1676,7 +1697,7 @@ def gated_mlp(x, width, out_scale=0.02, name=None):
 def latent_attention(x, num_heads, q_lora_rank, kv_lora_rank,
                      qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
                      rope_theta=10000.0, epsilon=1e-5, out_scale=0.02,
-                     use_flash="auto", rotate=True):
+                     use_flash="auto", rotate=True, rope_scaling=None):
     """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434 section
     2.1) over x [B, T, D], in its expanded (training) form:
 
@@ -1702,9 +1723,12 @@ def latent_attention(x, num_heads, q_lora_rank, kv_lora_rank,
     multiple of 128 (256), q scaled by sqrt(lanes / (nope + rope)) for
     the op's 1 / sqrt(lanes), and the output's leading v_head_dim lanes
     are taken: scores and outputs are exact, the kernels walk 1.6 times
-    the live products. Every departure is built only when asked for: a
-    program with a latent query, a rotation and equal widths is the one
-    it was."""
+    the live products. `rope_scaling`: the published group, handed to
+    both rotations (rotary_embedding's `scaling`); where it carries
+    `mscale_all_dim` (DeepSeek-V3's YaRN) the scores are also times
+    m(mscale_all_dim)^2 over 1 / sqrt(nope + rope), which q carries.
+    Every departure is built only when asked for: a program with a
+    latent query, a plain rotation and equal widths is the one it was."""
     from .tensor import concat
     seqlen, d_model = int(x.shape[1]), int(x.shape[2])
     qk_dim = qk_nope_head_dim + qk_rope_head_dim
@@ -1714,27 +1738,36 @@ def latent_attention(x, num_heads, q_lora_rank, kv_lora_rank,
                 [-1, seqlen, num_heads, qk_dim])
     if rotate:
         q = rotary_embedding(q, theta=rope_theta,
-                             rotary_dims=qk_rope_head_dim)
+                             rotary_dims=qk_rope_head_dim,
+                             scaling=rope_scaling)
     c_kv, k_rope = split(_linear(x, kv_lora_rank + qk_rope_head_dim),
                          [kv_lora_rank, qk_rope_head_dim], dim=2)
     k_rope = reshape(k_rope, [-1, seqlen, 1, qk_rope_head_dim])
     if rotate:
-        k_rope = rotary_embedding(k_rope, theta=rope_theta)
+        k_rope = rotary_embedding(k_rope, theta=rope_theta,
+                                  scaling=rope_scaling)
     kv = reshape(_linear(rms_norm(c_kv, epsilon=epsilon),
                          num_heads * (qk_nope_head_dim + v_head_dim)),
                  [-1, seqlen, num_heads, qk_nope_head_dim + v_head_dim])
     k_nope, v = split(kv, [qk_nope_head_dim, v_head_dim], dim=3)
     k = concat([k_nope, expand(k_rope, [1, 1, num_heads, 1])], axis=3)
     lanes = max(qk_dim, v_head_dim)
+    # what q carries beside the op's 1 / sqrt(lanes): YaRN's factor of
+    # the scores, and the lanes' own where zeros widen a head
+    q_scale = 1.0
+    if rotate and "mscale_all_dim" in (rope_scaling or {}):
+        q_scale = yarn_mscale(float(rope_scaling["factor"]),
+                              rope_scaling["mscale_all_dim"]) ** 2
     if qk_dim != v_head_dim:
         lanes = -(-lanes // 128) * 128 if lanes > 128 else lanes
-
+        q_scale *= (lanes / qk_dim) ** 0.5
+    if q_scale != 1.0:
+        q = scale(q, scale=q_scale)
+    if qk_dim != v_head_dim:
         def widened(t, width):
             return t if width == lanes else pad(
                 t, [0, 0, 0, 0, 0, 0, 0, lanes - width])
 
-        if lanes != qk_dim:
-            q = scale(q, scale=(lanes / qk_dim) ** 0.5)
         q, k, v = widened(q, qk_dim), widened(k, qk_dim), \
             widened(v, v_head_dim)
     attn = fused_attention(q, k, v, causal=True, use_flash=use_flash)
@@ -1769,6 +1802,107 @@ def mtp_block(hidden, next_tokens, vocab_size, embedding_name, head_name,
                 rms_norm(hidden, epsilon=epsilon)], axis=2)
     u = block(_linear(u, d_model))
     return _linear(rms_norm(u, epsilon=epsilon), vocab_size, name=head_name)
+
+
+def sinkhorn_knopp(logits, iters=20, eps=1e-6, clamp=(-30.0, 30.0),
+                   stats=None, name=None):
+    """[..., n, n] logits -> the map `iters` Sinkhorn-Knopp sweeps make
+    of exp(clamp(logits)), float32: every sweep divides the columns by
+    their sums + `eps`, then the rows (ops/hyper_connection_ops.py; the
+    gradient op runs the sweeps again and keeps none). `stats`: a list
+    that receives this map's (largest |row or column sum - 1|, mean
+    trace / n) Variables, each [1]."""
+    helper = LayerHelper("sinkhorn_knopp", name=name)
+    out = helper.create_tmp_variable("float32")
+    error, mass = (helper.create_tmp_variable("float32", stop_gradient=True)
+                   for _ in range(2))
+    with name_scope("sinkhorn_knopp"):
+        helper.append_op(
+            type="sinkhorn_knopp", inputs={"Logits": [logits]},
+            outputs={"Out": [out], "SumError": [error],
+                     "DiagonalMass": [mass]},
+            attrs={"iters": int(iters), "eps": float(eps),
+                   "clamp_min": float(clamp[0]),
+                   "clamp_max": float(clamp[1])})
+    if stats is not None:
+        stats.append((error, mass))
+    return out
+
+
+@_under_its_name
+def hyper_connection_maps(streams, sinkhorn_iters=20, eps=1e-6,
+                          res_clamp=(-30.0, 30.0), epsilon=1e-6, stats=None,
+                          name=None):
+    """The three maps one sublayer's hyper-connection reads from the
+    token's n residual streams `streams` [B, T, n, C] (manifold-
+    constrained hyper-connections, arXiv:2512.24880):
+
+        u = vec(X) / sqrt(mean(vec(X)^2) + epsilon)     float32, no weight
+        [p | q | r] = u phi                             widths n, n, n^2
+        H_pre  = sigmoid(alpha_pre p + b_pre)           [B, T, n]
+        H_post = 2 sigmoid(alpha_post q + b_post)       [B, T, n]
+        H_res  = sinkhorn_knopp(alpha_res mat(r) + b_res)   [B, T, n, n]
+
+    -> (H_pre, H_post, H_res), float32. Parameters, created in this
+    order: phi [n C, 2 n + n^2] from N(0, 0.02); b [2 n + n^2] = b_pre
+    logit(1 / n) (H_pre starts at 1 / n), b_post 0 (H_post at 1), b_res 4
+    I (H_res near the identity: 0.948 on the diagonal at n = 4); alpha
+    [3] = 0.01 each. The sweeps are built under
+    `hyper_connection_maps.sinkhorn_knopp`."""
+    helper = LayerHelper("hyper_connection_maps", name=name)
+    n, width = int(streams.shape[-2]), int(streams.shape[-1])
+    maps = 2 * n + n * n
+    phi = helper.create_parameter(
+        attr=None, shape=[n * width, maps], dtype="float32",
+        default_initializer=NormalInitializer(scale=0.02))
+    bias = helper.create_parameter(
+        attr=None, shape=[maps], dtype="float32",
+        default_initializer=ValuesInitializer(
+            [-math.log(n - 1.0)] * n + [0.0] * n
+            + [4.0 * (i == j) for i in range(n) for j in range(n)]))
+    alpha = helper.create_parameter(
+        attr=None, shape=[3], dtype="float32",
+        default_initializer=ConstantInitializer(0.01))
+    pre, post, logits = (helper.create_tmp_variable("float32")
+                         for _ in range(3))
+    helper.append_op(
+        type="hyper_connection_maps",
+        inputs={"X": [streams], "Phi": [phi], "Bias": [bias],
+                "Alpha": [alpha]},
+        outputs={"Pre": [pre], "Post": [post], "ResLogits": [logits]},
+        attrs={"epsilon": float(epsilon)})
+    return pre, post, sinkhorn_knopp(logits, sinkhorn_iters, eps, res_clamp,
+                                     stats=stats)
+
+
+@_under_its_name
+def hc_pre_mix(streams, pre, name=None):
+    """What a sublayer behind a hyper-connection reads: sum_j pre[j]
+    streams[j], [B, T, n, C] and [B, T, n] -> [B, T, C] in the
+    activations' dtype, summed in float32 (op hc_pre_mix, with its own
+    gradient op)."""
+    helper = LayerHelper("hc_pre_mix", name=name)
+    out = helper.create_tmp_variable(streams.dtype)
+    helper.append_op(type="hc_pre_mix",
+                     inputs={"X": [streams], "Pre": [pre]},
+                     outputs={"Out": [out]})
+    return out
+
+
+@_under_its_name
+def hc_post_res_mix(streams, y, res, post, name=None):
+    """What a sublayer behind a hyper-connection writes back: X'[i] =
+    sum_j res[i, j] streams[j] + post[i] y, with y [B, T, C] the
+    sublayer's output, -> [B, T, n, C] in the activations' dtype (bf16
+    under AMP O2 whatever the embedding's rows arrive in), summed in
+    float32 (op hc_post_res_mix, with its own gradient op)."""
+    helper = LayerHelper("hc_post_res_mix", name=name)
+    out = helper.create_tmp_variable(streams.dtype)
+    helper.append_op(type="hc_post_res_mix",
+                     inputs={"X": [streams], "Y": [y], "Res": [res],
+                             "Post": [post]},
+                     outputs={"Out": [out]})
+    return out
 
 
 def hsigmoid(input, label, num_classes, param_attr=None, bias_attr=None,

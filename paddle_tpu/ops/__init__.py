@@ -16,6 +16,7 @@ from . import eval_ops       # noqa: F401
 from . import misc_ops       # noqa: F401
 from . import detection_ops  # noqa: F401
 from . import hybrid_ops     # noqa: F401
+from . import hyper_connection_ops  # noqa: F401
 from . import fusion         # noqa: F401  (registers the fused op types)
 
 from .registry import register, op, get, try_get, registered_ops, NO_GRAD

@@ -239,8 +239,31 @@ def _bd_attention_flops(ins, outs, attrs):
     return 4.0 * q[0] * q[2] * q[3] * pairs
 
 
-# ops/hybrid_ops.py: forward flops from the op's concrete shapes
-_HYBRID_COST = {"ssd_scan": _scan_flops, "moe_experts": _experts_flops,
+def _hc_maps_flops(ins, outs, attrs):
+    """hyper_connection_maps: the projection of a token's n C numbers
+    onto the 2 n + n^2 maps, and the norm's sum of squares."""
+    x, phi = _slot_shape(ins, "X"), _slot_shape(ins, "Phi")
+    if x is None or phi is None:
+        return None
+    return 2.0 * _nelems(x) * (phi[1] + 1)
+
+
+def _hc_mix_flops(ins, outs, attrs):
+    """hc_pre_mix: n multiply-adds a channel of x_in; hc_post_res_mix:
+    n + 1 a channel of each of the n rows of X'."""
+    x = _slot_shape(ins, "X")
+    if x is None:
+        return None
+    post = _slot_shape(ins, "Post") is not None
+    return 2.0 * _nelems(x) * (x[-2] + 1 if post else 1)
+
+
+# ops/hybrid_ops.py and ops/hyper_connection_ops.py: forward flops from
+# the op's concrete shapes
+_HYBRID_COST = {"hyper_connection_maps": _hc_maps_flops,
+                "hc_pre_mix": _hc_mix_flops,
+                "hc_post_res_mix": _hc_mix_flops,
+                "ssd_scan": _scan_flops, "moe_experts": _experts_flops,
                 "moe_router": _router_flops, "causal_conv1d": _conv1d_flops,
                 "kda_scan": _kda_flops,
                 "scaled_dot_product_attention": _sdpa_flops,

@@ -949,6 +949,18 @@ METRIC_CATALOG = {
         "mean mass the exits' gate gives one exit of a looped decoder; "
         "the exits' sum to 1 (telemetry side-fetch; models/looped_lm)",
         dynamic=True),
+    "hc_res_sum_error": _m(
+        "gauge", ("program",),
+        "largest |row sum - 1| or |column sum - 1| of any residual map "
+        "H_res the last step's hyper-connections made, over tokens and "
+        "sublayers (telemetry side-fetch; models/mla_moe with hc_mult)",
+        dynamic=True),
+    "hc_res_diagonal_mass": _m(
+        "gauge", ("program",),
+        "mean of trace(H_res) / n over the last step's tokens and "
+        "sublayers: 1 keeps the residual streams apart, 1 / n mixes them "
+        "evenly (telemetry side-fetch; models/mla_moe with hc_mult)",
+        dynamic=True),
     "jax_backend_compiles_total": _m("counter", (),
                                      "XLA backend compiles observed"),
     "jax_backend_compile_seconds_total": _m(
@@ -997,6 +1009,14 @@ METRIC_CATALOG = {
         "counter", ("path", "groups"),
         "of kda_scan_total's lowerings, those with a decay a head, by path "
         "and the value heads that read one key head"),
+    "hyper_connection_sublayers_total": _m(
+        "counter", ("program",),
+        "sublayers behind hyper-connections (a sinkhorn_knopp op each), "
+        "a trace"),
+    "hyper_connection_replays_total": _m(
+        "counter", ("program",),
+        "sublayers behind hyper-connections whose forward the backward "
+        "runs again (a replayed sinkhorn_knopp op each), a trace"),
     "activation_kept_total": _m("counter", ("act",),
                                 "lowerings of an activation evaluated once "
                                 "and kept (ops/math_ops.py KEPT_ACTS)"),

@@ -1509,6 +1509,89 @@ def test_looped_step_reads_one_set_of_weights_four_times(mosaic, one_chip,
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 15.75e9
 
 
+XING4_CELL = "xing4.0-29b-a4b.train-mhc4-mla-ep8-share"
+
+
+def test_hyper_connection_step_keeps_the_streams_in_bf16(mosaic, one_chip,
+                                                         no_room):
+    """The Xing4.0 cell's step at its own 4096 tokens and published
+    widths, the depth cut to the dense block and one expert block (the
+    five take six minutes here: the `slow` test below; tools/
+    describe_step.py sized them, PR 68), every segment replayed: both
+    attention ops on the flash kernels at 256 lanes for 192 | 128 with
+    the replayed one handed its first outputs (PR 54), the experts on gmm
+    / tgmm under the ladder's two rungs; under the four hyper-connection
+    scopes, forward, replayed and gradient, no instruction writes a
+    float32 array of the streams' [4096, 4, 3584] but the cotangent of
+    X_0, the float32 embedding rows' copy (the mixes sum in float32
+    inside their fusions and hand bf16 on behind their operands'
+    barriers; the maps' projection reads the bf16 streams as they are),
+    the sweeps' arrays are [4, 4096]
+    and [16, 4096]-sized float32, and the whole step's bytes are under
+    the chip's limit."""
+    from paddle_tpu import memory, recompute
+    cell = run.load_json("workloads", XING4_CELL)
+    config = dict(run.load_json("configs", cell["config"]),
+                  num_hidden_layers=2)
+    tokens, n, width = config["sequence_length"], config["hc_mult"], \
+        config["hidden_size"]
+    assert (tokens, n, width) == (4096, 4, 3584)
+    compiled = describe_step.compile_step(cell, config, one_chip)
+    text = compiled.as_text()
+    kernels = [re.search(r'op_name="[^"]*?(\w+)\)*/pallas_call', line).group(1)
+               for line in text.splitlines() if KERNEL in line]
+    flash = {k: kernels.count(k) for k in set(kernels) if "flash" in k}
+    assert flash == {"flash_fwd": 2, "flash_dkv": 2}, flash
+    assert "gmm" in kernels and "tgmm" in kernels
+    instrs = xplane.hlo_instructions(text)
+    scopes = ("hyper_connection_maps", "hc_pre_mix", "hc_post_res_mix")
+    under = [i for i in instrs if i.scope
+             and set(scopes) & set(i.scope.split("."))]
+    assert under
+    for op in ("hyper_connection_maps", "sinkhorn_knopp", "hc_pre_mix",
+               "hc_post_res_mix"):
+        for op_type in (op, op + "_grad"):
+            assert any(i.op == op_type for i in under), op_type
+        assert any(i.op == op and i.recompute is not None for i in under), op
+    wide = [(i.name, i.op, i.shape) for i in under
+            if any(np.prod([int(d) for d in dims.split(",")])
+                   >= tokens * n * width
+                   for dims in re.findall(r"f32\[([\d,]+)\]", i.shape))]
+    # but one: the first sublayer reads X_0, the float32 master table's
+    # rows copied into four streams, and its cotangent is X_0's dtype
+    assert [op for _, op, _ in wide] in ([], ["hyper_connection_maps_grad"]), \
+        wide
+    sweeps = [i for i in under if i.op and i.op.startswith("sinkhorn_knopp")]
+    assert sweeps and all(
+        np.prod([int(d) for d in dims.split(",")]) <= tokens * n * n
+        for i in sweeps for dims in re.findall(r"f32\[([\d,]+)\]", i.shape))
+    mem = compiled.memory_analysis()
+    # the fixture's limit is the margin alone: the chip's own is read here
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
+        < 16_909_336_064 - recompute.MARGIN_BYTES // 2
+
+
+@pytest.mark.slow
+def test_hyper_connection_cell_fits_the_chip_at_4096_tokens(mosaic,
+                                                            one_chip):
+    """The sizing rule's compile (PERF.md section 4): the whole step of
+    the Xing4.0 cell at full depth for the described v5e, six minutes
+    here, so not tier-1's (CHANGES.md, PR 68, says so): 9.11e9 B of
+    aliased state and temporaries that leave the step inside
+    memory.device_limit with the replay plan
+    test_a_checkpointed_cell_keeps_the_segments_that_fit_a_v5e pins
+    (the lookup and the fourth block kept, blocks 0 to 2 replayed, the
+    fifth behind the last checkpoint)."""
+    from paddle_tpu import memory
+    cell = run.load_json("workloads", XING4_CELL)
+    compiled = describe_step.compile_step(
+        cell, run.load_json("configs", cell["config"]), one_chip)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes > 9.1e9
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
+        < memory.device_limit(next(iter(one_chip.device_set)))
+
+
 # what the executor decides for a described v5e at each checkpointed cell's
 # full depth (recompute.py; PERF.md section 6, PR 67): the segments kept,
 # of how many
@@ -1519,6 +1602,10 @@ DECIDED = {
     LAGUNA_CELL: ([1, 4], 4),
     GDN_CELL: ([], 3),
     OURO_CELL: ([26, 27, 28, 29, 30, 31], 31),
+    # segment 0 is the lookup (117 MB: its copy into four streams); a
+    # block is 1.31e9 B (the dense one 1.40e9) beside 12.15e9 of state
+    # and gradients: the last of the four behind checkpoints is kept
+    XING4_CELL: ([0, 4], 5),
 }
 
 
