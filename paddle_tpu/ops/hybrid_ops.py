@@ -51,7 +51,9 @@ first exception: moe_experts writes the up product's rows
 (_experts_grad, as nn_ops._sdpa_grad reads LSE), so the gradient runs the
 pulled-back products alone. A layer that holds an eighth of the experts
 or less handles its rows inside a capacity chosen on the device
-(_capacity_ladder): one lax.switch in the op and one in its gradient op,
+(_capacity_ladder: twice a uniform router's share, then, for a sixteenth
+or less, four times it, then every pair): one lax.switch in the op, a
+branch a rung, and one in its gradient op,
 which takes the same rung from the same routed count; a conditional is a
 wall to XLA's merging, which is why nothing is traced twice across it
 (a re-traced forward inside the gradient's branch ran its products a
@@ -1095,36 +1097,48 @@ def _experts_infer(op_, block):
 
 
 def _capacity_ladder(pairs: int, held: int, num_experts: int):
-    """The row counts the expert layer may handle, ascending: `pairs` =
-    N x top_k (every pair: nothing is ever dropped) and before it, if it
-    is at most a quarter of the pairs, the smallest halving of `pairs`
-    that tiles as `pairs` does (so gmm_ineligible answers the same for
-    both) and still holds four times the share a uniform router sends
-    here, pairs * held / num_experts, or, where four times the share is
-    more than a quarter of the pairs, twice the share. A sixteenth of the
-    experts or less has room for four times off balance, an eighth for
-    twice (pairs / 4), and a layer that holds more than an eighth has the
-    one rung and no conditional.
+    """The row counts the expert layer may handle, ascending, three at
+    most: `pairs` = N x top_k last (every pair: nothing is ever dropped)
+    and before it r2 and r4, each kept if it is at most a quarter of the
+    pairs. r2 is the smallest halving of `pairs` that tiles as `pairs`
+    does (so gmm_ineligible answers the same for every rung) and still
+    holds twice the share a uniform router sends here, pairs * held /
+    num_experts; r4 the same for four times the share. A layer that holds
+    more than an eighth of the experts has the one rung and no
+    conditional; an eighth, (pairs / 4, pairs): r4 is half the pairs and
+    falls away; a sixteenth or less, (r2, r4, pairs), or (r4, pairs)
+    where r2 would not tile and the halving stops at r4.
 
-    Two rungs and the quarter are what the chip allowed (PERF.md section
-    6, PR 36): every rung is a branch of the forward and of the gradient
-    to trace, lower and load, seconds of a cell's set-up each, so the
-    rung before the last has to save more than half of the rows' handling
-    when it is taken. The factor: four where it fits (PR 36's routers ran
-    two or three times off balance in their first steps), two for an
-    eighth since the gradient's branch reads the forward's products and
-    runs none again (PERF.md section 6, PR 58: the last rung behind a
-    switch cost a fifth more than the layer without one while it did, and
-    a layer whose router overflows the smaller rung at every step paid
-    that for nothing); routers that train under a balancing rule stay
-    within a fifth of uniform, and one that does not takes the last rung
-    at the price of the layer without a ladder."""
-    factor = 4 if 16 * held <= num_experts else 2
-    rung = pairs
-    while (rung % 2 == 0 and rung // 2 * num_experts >= factor * pairs * held
-           and (rung // 2 % _GMM_ROWS == 0) == (pairs % _GMM_ROWS == 0)):
-        rung //= 2
-    return (rung, pairs) if 4 * rung <= pairs else (pairs,)
+    The quarter is what the chip allowed (PERF.md section 6, PR 36):
+    every rung is a branch of the forward and of the gradient to trace,
+    lower and load, seconds of a cell's set-up each, so a rung before the
+    last has to save more than half of the rows' handling when it is
+    taken. Twice the share first (PR 69): routers that train under a
+    balancing rule send the held experts within a few percent of the
+    uniform share (their busiest single expert runs 1.14 to 1.20 times
+    its mean), so every settled layer-step fits r2 and handles half of
+    r4's rows, which is the case the quarter was written for; an eighth
+    has taken twice the share since the gradient's branch reads the
+    forward's products and runs none again (PERF.md section 6, PR 58:
+    the last rung behind a switch cost a fifth more than the layer
+    without one while it did). Four times the share stays behind it as
+    the second rung: a router that has not settled (PR 36's ran two or
+    three times off balance in their first steps; a step 0 under a
+    balancing rule reads up to a tenth over r2) or that trains under no
+    rule and drifts lands there, at what it cost before r2 was there,
+    and not on the last rung at the price of the layer without a ladder.
+    A conditional's buffers are its largest branch's, so the rungs before
+    the last cost set-up (PR 69: 4 to 5 s of the head-decay delta-rule
+    cell's 110) and tens of MB either way, no more."""
+    def smallest_halving(times):
+        rung = pairs
+        while (rung % 2 == 0
+               and rung // 2 * num_experts >= times * pairs * held
+               and (rung // 2 % _GMM_ROWS == 0) == (pairs % _GMM_ROWS == 0)):
+            rung //= 2
+        return rung
+    rungs = sorted({smallest_halving(2), smallest_halving(4), pairs})
+    return tuple(r for r in rungs[:-1] if 4 * r <= pairs) + (pairs,)
 
 
 # The two maps between the tokens' rows [N, D] and the C rows the grouped
@@ -1407,9 +1421,11 @@ def _moe_experts(ctx, op_, ins):
     The rows gathered, multiplied and gathered back are the first C pairs
     of that order, C the smallest rung of _capacity_ladder that holds the
     step's routed pairs, chosen on the device (lax.switch, one branch a
-    rung, in the gradient op too); the last rung is all N x top_k, so no
-    step drops a row, and a layer that holds more than an eighth of the
-    experts has that rung alone and no conditional.
+    rung, in the gradient op too): twice the share a uniform router
+    sends here, or, a sixteenth of the experts held or less, four times
+    it, where a router that has not settled lands; the last rung is all
+    N x top_k, so no step drops a row, and a layer that holds more than
+    an eighth of the experts has that rung alone and no conditional.
 
     Up, and GateUp of the gated form [N x top_k, F] in the compute dtype:
     the up product's rows and the gate's in sorted order, undefined past

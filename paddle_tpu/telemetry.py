@@ -910,8 +910,9 @@ METRIC_CATALOG = {
     "moe_rows_handled": _m(
         "histogram", ("program", "layer"),
         "rows the expert layer gathered, multiplied and scattered: the "
-        "smallest capacity of its ladder that holds moe_rows_routed, all "
-        "N x top_k at most (telemetry side-fetch)",
+        "smallest capacity of its ladder that holds moe_rows_routed (twice "
+        "a uniform router's share, four times it, or all N x top_k: "
+        "hybrid_ops._capacity_ladder; telemetry side-fetch)",
         dynamic=True),
     "loss": _m(
         "gauge", ("program",),

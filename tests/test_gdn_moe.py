@@ -401,12 +401,16 @@ def test_shares_add_up_to_the_uncut_layer():
 
 def test_a_sixteenth_held_has_two_rungs():
     """32 of 512 under top 10 at 16,384 tokens: the capacity ladder's
-    40,960 | 163,840, the small rung four times a uniform router's 10,240
-    rows; 32 x 16 lanes are pallas_pair_sum's limit exactly."""
+    20,480 | 40,960 | 163,840 since PR 69, the first rung twice a uniform
+    router's 10,240 rows and the first rung of before, four times them,
+    behind it (the name is the parent's: the test is the same test); 32 x
+    16 lanes are pallas_pair_sum's limit exactly, at every rung."""
     from paddle_tpu.ops import hybrid_ops, pallas_pair_sum
     pairs = 16384 * 10
-    assert hybrid_ops._capacity_ladder(pairs, 32, 512) == (40960, 163840)
-    assert pallas_pair_sum.ineligible(16384, 2048, 40960, 32) is None
+    assert hybrid_ops._capacity_ladder(pairs, 32, 512) == (
+        20480, 40960, 163840)
+    for rung in (20480, 40960, 163840):
+        assert pallas_pair_sum.ineligible(16384, rung, 2048, 32) is None
 
 
 # --- 4. what asks for none of it ----------------------------------------------

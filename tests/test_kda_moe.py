@@ -710,16 +710,21 @@ def test_shares_add_up_to_the_uncut_layer():
     assert np.asarray(weight).sum(-1) == pytest.approx(scaling, rel=1e-5)
 
 
-@pytest.mark.parametrize("held,small", [(8, 8192), (16, 16384), (32, 16384)],
+@pytest.mark.parametrize("held,small", [(8, (4096, 8192)),
+                                        (16, (8192, 16384)),
+                                        (32, (16384,))],
                          ids=["a_thirty_second", "a_sixteenth", "an_eighth"])
 def test_the_ladder_has_two_rungs_at_a_thirty_second(held, small):
     """8 held of 256 at 8192 tokens and top 8: the capacity ladder gives
-    8192 | 65536 pairs, and a uniform router's 2048 rows take the small
-    one; a share of 16 has four times its 4096 rows, and one of 32 (the
-    gated window cell's eighth) twice its 8192: a quarter of the pairs."""
+    4096 | 8192 | 65536 pairs since PR 69 (two rungs before it: the name
+    is the parent's), and a uniform router's 2048 rows take the first,
+    twice their count; a share of 16 has twice and four times its 4096
+    rows, and one of 32 (the gated window cell's eighth) twice its 8192
+    alone: a quarter of the pairs, and four times would be half."""
     ladder = hybrid_ops._capacity_ladder(8192 * 8, held, 256)
-    assert list(ladder) == [small, 65536]
-    assert small >= 2 * 8192 * 8 * held // 256
+    assert ladder == small + (65536,)
+    uniform = 8192 * 8 * held // 256
+    assert [c // uniform for c in small] == [2, 4][:len(small)]
 
 
 # --- 5. what asks for none of it ---------------------------------------------

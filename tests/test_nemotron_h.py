@@ -575,26 +575,35 @@ def experts_op(rng, n, d, f, k, held_pairs, gated, wrt=(), held=HELD):
 
 
 LADDERS = {
-    # four times the uniform share where that is at most a quarter of the
-    # pairs: a sixteenth of the experts or less
-    "hybrid_cell": ((24576, 8, 128), (6144, 24576)),
-    "a_sixty_fourth": ((24576, 2, 128), (1536, 24576)),
-    "a_sixteenth": ((16384, 4, 64), (4096, 16384)),
-    "block_diffusion_cell": ((65536, 8, 128), (16384, 65536)),
-    "delta_rule_cell": ((65536, 8, 256), (8192, 65536)),
-    # twice the share for an eighth: a quarter of the pairs
+    # a sixteenth of the experts or less: twice the uniform share, then
+    # four times it (the first rung of before PR 69), then every pair
+    "hybrid_cell": ((24576, 8, 128), (3072, 6144, 24576)),
+    "a_sixty_fourth": ((24576, 2, 128), (768, 1536, 24576)),
+    "a_sixteenth": ((16384, 4, 64), (2048, 4096, 16384)),
+    "block_diffusion_cell": ((65536, 8, 128), (8192, 16384, 65536)),
+    "delta_rule_cell": ((65536, 8, 256), (4096, 8192, 65536)),
+    "head_decay_delta_rule_cell": ((163840, 32, 512),
+                                   (20480, 40960, 163840)),
+    "between_a_thirty_second_and_a_sixteenth": ((65536, 6, 128),
+                                                (8192, 16384, 65536)),
+    "ragged_dot": ((240, 2, 32), (30, 60, 240)),
+    # twice the share for an eighth, a quarter of the pairs: four times
+    # it is half of them and falls away
     "latent_cell": ((16384, 8, 64), (4096, 16384)),
     "gated_window_cell": ((65536, 32, 256), (16384, 65536)),
     "sliding_window_cell": ((49152, 8, 64), (12288, 49152)),
     "an_eighth_on_ragged_dot": ((240, 4, 32), (60, 240)),
+    "between_a_sixteenth_and_an_eighth": ((65536, 12, 128), (16384, 65536)),
     # more than an eighth: the one rung
     "a_quarter": ((16384, 16, 64), (16384,)),
     "over_an_eighth": ((240, 5, 32), (240,)),
     "all_held": ((24576, 128, 128), (24576,)),
-    # the rung tiles as the pairs do
-    "ragged_dot": ((240, 2, 32), (60, 240)),
+    # the rung tiles as the pairs do: where twice the share (64 of 512
+    # at a sixteenth) would not and four times it does, the halving
+    # stops there and the two are one rung, as before PR 69
     "kernel": ((512, 2, 32), (128, 512)),
     "64_would_not_tile": ((512, 1, 64), (128, 512)),
+    "a_sixteenth_on_the_kernel": ((1024, 4, 64), (128, 256, 1024)),
     "125_has_no_half": ((250, 1, 128), (250,)),
 }
 
@@ -604,16 +613,23 @@ def test_the_ladder_is_a_function_of_shapes_and_the_share(case):
     from paddle_tpu.ops.hybrid_ops import _capacity_ladder, gmm_ineligible
     (pairs, held, experts), rungs = LADDERS[case]
     assert _capacity_ladder(pairs, held, experts) == rungs
-    assert rungs[-1] == pairs and len(rungs) <= 2   # no pair is dropped
+    assert rungs[-1] == pairs and len(rungs) <= 3   # no pair is dropped
     assert all(4 * c <= pairs for c in rungs[:-1])
+    assert list(rungs) == sorted(set(rungs))
+    # every rung holds twice a uniform router's share, or every pair
+    assert rungs[0] * experts >= min(2 * held, experts) * pairs
     assert len({gmm_ineligible(c, 2688, 1856) for c in rungs}) == 1
 
 
-# 60 tokens x 4 slots, 2 of 32 experts held (a sixteenth) or 4 (an
-# eighth): rungs 60, 240 on lax.ragged_dot; 128 x 4 at a lane block's
-# widths: 128, 512 on the kernel
-LADDER_CASES = [(60, 24, 40, r) for r in (0, 59, 60, 61, 240)] \
+# 60 tokens x 4 slots, 2 of 32 experts held (a sixteenth: rungs 30, 60,
+# 240) or 4 (an eighth: 60, 240) on lax.ragged_dot; 128 x 4 at a lane
+# block's widths: 128, 512 on the kernel for both (64 would not tile;
+# the kernel's three rungs, 128 | 256 | 1024, are GATHER_CASES' and
+# tests/test_pair_sum.py's)
+LADDER_CASES = [(60, 24, 40, r) for r in (0, 29, 30, 31, 59, 60, 61, 240)] \
     + [(128, 128, 128, r) for r in (100, 129)]
+LADDER_RUNGS = {(60, HELD): (30, 60, 240), (60, 4): (60, 240),
+                (128, HELD): (128, 512), (128, 4): (128, 512)}
 
 
 @pytest.mark.parametrize("held", [HELD, 4], ids=["a_sixteenth", "an_eighth"])
@@ -622,16 +638,18 @@ LADDER_CASES = [(60, 24, 40, r) for r in (0, 59, 60, 61, 240)] \
 def test_a_rung_gives_what_the_whole_layer_gives(n, d, f, held_pairs, gated,
                                                  held, monkeypatch):
     """Nothing routed, one under a rung, exactly a rung, one over it (the
-    full rung taken by overflow) and every pair: Out and every gradient
-    equal those of the layer with the full size as its only rung and no
-    switch, and no routed row is lost."""
+    next rung taken by overflow: the second of three, or the full one)
+    and every pair, under twice the share, between twice and four times
+    it and over that: Out and every gradient of each rung equal those of
+    the layer with the full size as its only rung and no switch, and no
+    routed row is lost."""
     from paddle_tpu.ops import hybrid_ops
     wrt = ("X", "TopkWeight", "WGate", "W1", "W2")
     seed = 1000 * n + held_pairs
     outs, grads, _ = experts_op(np.random.default_rng(seed), n, d, f, 4,
                                 held_pairs, gated, wrt, held)
     rungs = hybrid_ops._capacity_ladder(n * 4, held, EXPERTS)
-    assert rungs == (n, 4 * n)
+    assert rungs == LADDER_RUNGS[n, held]
     assert outs["RowsRouted"][0] == outs["RowsCombined"][0] == held_pairs
     assert outs["RowsHandled"][0] == min(c for c in rungs if c >= held_pairs)
     monkeypatch.setattr(hybrid_ops, "_capacity_ladder",
@@ -656,13 +674,14 @@ class _Attrs:
         return self.attrs.get(name, default)
 
 
-@pytest.mark.parametrize("held,conds", [(EXPERTS, 0), (EXPERTS // 4, 0),
-                                        (EXPERTS // 8, 1), (2, 1)])
-def test_a_layer_that_holds_every_expert_has_no_conditional(held, conds):
+@pytest.mark.parametrize("held,rungs", [(EXPERTS, 1), (EXPERTS // 4, 1),
+                                        (EXPERTS // 8, 2), (2, 3), (1, 3)])
+def test_a_layer_that_holds_every_expert_has_no_conditional(held, rungs):
     """One rung (every expert held, or more than an eighth): the lowering
-    and the gradient op's hold no `cond`; an eighth's or a sixteenth's
-    forward holds one and its gradient op one, its own: it reads the
-    forward's kept products and traces no forward."""
+    and the gradient op's hold no `cond`; an eighth's forward (two rungs)
+    or a sixteenth's (three) holds one and its gradient op one, its own,
+    of a branch a rung: it reads the forward's kept products and traces
+    no forward."""
     import types
     from paddle_tpu.ops import hybrid_ops
     rng = np.random.default_rng(held)
@@ -688,6 +707,8 @@ def test_a_layer_that_holds_every_expert_has_no_conditional(held, conds):
         return hybrid_ops._moe_experts_grad(
             ctx, op_, dict(ins(*operands), **{"Up": [up], "Out@GRAD": [ct]}))
 
+    assert len(hybrid_ops._capacity_ladder(n * k, held, EXPERTS)) == rungs
+    conds = int(rungs > 1)
     forward = jax.make_jaxpr(out)(x, weight, w1, w2)
     assert str(forward).count("cond[") == conds
     outs = out(x, weight, w1, w2)
@@ -698,16 +719,18 @@ def test_a_layer_that_holds_every_expert_has_no_conditional(held, conds):
     # a rung: the forward's two products; in the gradient op the two
     # pulled back to the rows and the two to the matrices, and no
     # forward one
-    assert str(forward).count("ragged_dot_general[") == 2 * (conds + 1)
-    assert str(pulled).count("ragged_dot_general[") == 4 * (conds + 1)
+    assert str(forward).count("ragged_dot_general[") == 2 * rungs
+    assert str(pulled).count("ragged_dot_general[") == 4 * rungs
 
 
-@pytest.mark.parametrize("held_pairs", [0, 1, 59, 60, 61, 239, 240])
+@pytest.mark.parametrize("held_pairs", [0, 1, 29, 30, 31, 59, 60, 61, 239,
+                                        240])
 def test_rows_handled_is_the_smallest_rung_that_holds_the_routed(held_pairs):
     outs, _, _ = experts_op(np.random.default_rng(held_pairs), 60, 24, 40, 4,
                             held_pairs, gated=False)
     assert outs["RowsRouted"][0] == outs["RowsCombined"][0] == held_pairs
-    assert outs["RowsHandled"][0] == (60 if held_pairs <= 60 else 240)
+    assert outs["RowsHandled"][0] == min(
+        c for c in (30, 60, 240) if c >= held_pairs)
 
 
 # --- rows back to their tokens ------------------------------------------------
@@ -744,10 +767,16 @@ GATHER_CASES = {
     "small_rung_taken": (128, 128, 128, 32, 2, 4, 100, True, 2, None),
     "full_rung_taken": (128, 128, 128, 32, 2, 4, 300, False, 2, None),
     "rows_do_not_tile": (50, 24, 40, 16, 4, 6, None, True, 1, "rows"),
-    "rows_do_not_tile_small_rung": (60, 24, 40, 32, 2, 4, 40, False, 2,
+    # a sixteenth on ragged_dot: 30 | 60 | 240
+    "rows_do_not_tile_first_rung": (60, 24, 40, 32, 2, 4, 25, True, 3,
                                     "rows"),
-    "rows_do_not_tile_full_rung": (60, 24, 40, 32, 2, 4, 61, True, 2, "rows"),
-    "nothing_routed_here": (60, 24, 40, 32, 2, 4, 0, True, 2, "rows"),
+    "rows_do_not_tile_small_rung": (60, 24, 40, 32, 2, 4, 40, False, 3,
+                                    "rows"),
+    "rows_do_not_tile_full_rung": (60, 24, 40, 32, 2, 4, 61, True, 3, "rows"),
+    "nothing_routed_here": (60, 24, 40, 32, 2, 4, 0, True, 3, "rows"),
+    # a sixteenth on the kernel: 128 | 256 | 1024
+    "first_of_three_rungs_taken": (256, 128, 128, 32, 2, 4, 120, True, 3,
+                                   None),
     # an eighth held: twice the uniform share is the small rung
     "an_eighth_small_rung_taken": (128, 128, 128, 32, 4, 4, 120, True, 2,
                                    None),
@@ -996,9 +1025,10 @@ def test_routing_statistics_reach_telemetry_by_layer():
 def test_a_model_trains_the_same_with_the_ladder_as_with_one_rung(
         experts, monkeypatch):
     """The tiny model as a 1/16 share or an eighth (4 of 64 or of 32
-    experts held: rungs 144 and 576 of its 192 tokens x 3 slots), three
-    Adam steps in float32: the losses equal those of the same model with
-    the full size as its only rung, and the steps took the first rung."""
+    experts held: rungs 72, 144 and 576, or 144 and 576, of its 192
+    tokens x 3 slots), three Adam steps in float32: the losses equal
+    those of the same model with the full size as its only rung, and the
+    steps took the first rung."""
     from paddle_tpu import telemetry
     from paddle_tpu.ops import hybrid_ops
     config = dict(run.load_json("configs", "tiny-nemotron-h", DATA),
@@ -1020,15 +1050,15 @@ def test_a_model_trains_the_same_with_the_ladder_as_with_one_rung(
             "moe_rows_handled", program=telemetry.program_label(main),
             layer="0")
 
-    assert hybrid_ops._capacity_ladder(feed["tok"].size * 3, 4, experts) == (
-        144, 576)
+    rungs = hybrid_ops._capacity_ladder(feed["tok"].size * 3, 4, experts)
+    assert rungs == {64: (72, 144, 576), 32: (144, 576)}[experts]
     with_ladder, handled = losses()
     monkeypatch.setattr(hybrid_ops, "_capacity_ladder",
                         lambda pairs, held, experts: (pairs,))
     one_rung, whole = losses()
     assert with_ladder[0] > with_ladder[-1]            # it trains
     np.testing.assert_allclose(with_ladder, one_rung, rtol=2e-6)
-    assert handled == {"count": 3, "sum": 3 * 144.0}
+    assert handled == {"count": 3, "sum": 3.0 * rungs[0]}
     assert whole == {"count": 3, "sum": 3 * 576.0}
 
 

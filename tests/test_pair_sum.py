@@ -145,26 +145,30 @@ def _booked():
 
 @pytest.mark.parametrize("held", [HELD, 4], ids=["a_sixteenth", "an_eighth"])
 @pytest.mark.parametrize("gated", [False, True], ids=["relu2", "gated"])
-@pytest.mark.parametrize("held_pairs", [100, 600], ids=["rung0", "rung1"])
+@pytest.mark.parametrize("held_pairs", [100, 200, 600],
+                         ids=["rung0", "between", "rung1"])
 def test_the_layers_gradient_through_both_rungs(held_pairs, gated, held,
                                                 monkeypatch):
-    """moe_experts over a two-rung ladder (256 | 1024 pairs: four times
-    a sixteenth's share, twice an eighth's) with the kernel on both of
-    the token side's maps, forward and pulled back, the small rung and
-    the full one taken by overflow, against the same op with the gate
-    declined: Out and every gradient to 1e-6 in float32, the choice
-    booked once a forward lowering of the op (run_op lowers it in two
-    programs) and never by the gradient op's."""
+    """moe_experts over its ladder of 1024 pairs (128 | 256 | 1024 at a
+    sixteenth: twice its share, four times it, every pair; 256 | 1024
+    at an eighth: twice its share) with the kernel on both of the token
+    side's maps, forward and pulled back, the first rung, the one
+    between (the first still, at an eighth) and the full one taken by
+    overflow, against the same op with the gate declined: Out and every
+    gradient to 1e-6 in float32, the choice booked once a forward
+    lowering of the op (run_op lowers it in two programs) and never by
+    the gradient op's."""
     n, d, f, k = 256, 128, 128, 4
     wrt = ("X", "TopkWeight", "WGate", "W1", "W2")
-    assert hybrid_ops._capacity_ladder(n * k, held, EXPERTS) == (256, 1024)
+    rungs = hybrid_ops._capacity_ladder(n * k, held, EXPERTS)
+    assert rungs == ((128, 256, 1024) if held == HELD else (256, 1024))
     before = _booked()
     outs, grads, _ = experts_op(np.random.default_rng(held_pairs), n, d, f, k,
                                 held_pairs, gated, wrt, held)
     lowered, took, declined = _booked() - before
     assert lowered == took > 0 == declined
     assert outs["RowsRouted"][0] == outs["RowsCombined"][0] == held_pairs
-    assert outs["RowsHandled"][0] == (256 if held_pairs <= 256 else 1024)
+    assert outs["RowsHandled"][0] == min(c for c in rungs if c >= held_pairs)
 
     monkeypatch.setattr(pallas_pair_sum, "ineligible",
                         lambda *shape: "tokens")

@@ -363,11 +363,12 @@ def _kv_groups_booked():
 
 
 @pytest.mark.parametrize("rows,dtype", [
-    (4096 * 6, BF16), (4096 * 6, jnp.float32), (6144, BF16)],
-    ids=["bf16", "float32_no_amp", "first_rung"])
+    (4096 * 6, BF16), (4096 * 6, jnp.float32), (6144, BF16), (3072, BF16)],
+    ids=["bf16", "float32_no_amp", "second_rung", "first_rung"])
 def test_grouped_expert_products_compile(mosaic, one_chip, rows, dtype):
     """The hybrid cell's expert layer: 4096 tokens x top-6 rows of 2688
-    (and the first rung of its capacity ladder, PR 36) through 8 held
+    (and the rungs of its capacity ladder before them: 6144, PR 36's
+    first and the second since PR 69, and 3072, the first) through 8 held
     experts of width 1856 and back, at the tiles the sweep chose: the up
     product forward (the down product's result is not needed for a
     gradient of its sum), and for each of the two its backward products
@@ -388,19 +389,25 @@ def test_grouped_expert_products_compile(mosaic, one_chip, rows, dtype):
 
 @pytest.mark.parametrize("under_switch", [False, True],
                          ids=["alone", "in_a_switch_branch"])
-@pytest.mark.parametrize("n,k,d,c", [(8192, 8, 2048, 16384),
-                                     (8192, 6, 2560, 49152)],
-                         ids=["block_diffusion_cell", "sliding_window_cell"])
-def test_pair_sum_compiles(mosaic, one_chip, n, k, d, c, under_switch):
+@pytest.mark.parametrize("n,k,d,c,held,experts", [
+    (8192, 8, 2048, 16384, 8, 128), (8192, 6, 2560, 49152, 8, 64),
+    (8192, 8, 2048, 8192, 8, 128), (16384, 10, 2048, 20480, 32, 512)],
+    ids=["block_diffusion_cell", "sliding_window_cell",
+         "block_diffusion_cell_first_rung", "head_decay_cell_first_rung"])
+def test_pair_sum_compiles(mosaic, one_chip, n, k, d, c, held, experts,
+                           under_switch):
     """The token side of the two cells without a shared expert (PR 47):
     8192 positions x top 8 of 2048 over the 16384-row rung, and 8192
     tokens x top 6 of 2560 over all 49152 pairs, 8 experts held: the
     forward's weighted map from the grouped product's bf16 rows to
     float32 and the gradient's from the bf16 cotangent to bf16, as
     hybrid_ops hands them to the kernel, alone and as the work of a
-    lax.switch branch (the ladder's)."""
+    lax.switch branch (the ladder's). Since PR 69 also the rungs at
+    twice a uniform router's share: the first cell's 8192 rows, and the
+    20480 of 16384 tokens x top 10 over 32 held experts (the head-decay
+    delta-rule cell's), 32 x 16 lanes being the kernel's limit."""
     from paddle_tpu.ops import pallas_pair_sum
-    held = 8
+    assert c in hybrid_ops._capacity_ladder(n * k, held, experts)
     assert pallas_pair_sum.ineligible(n, c, d, held) is None
     tiles = n // pallas_pair_sum._TILE
 
@@ -641,12 +648,12 @@ def _hybrid_mixer_and_expert_step(one_chip):
 
 def test_hybrid_expert_layer_step_holds_one_switch_each_way(mosaic, one_chip):
     """One mixer and one expert layer of the hybrid cell: the expert
-    layer's forward and its gradient op are one conditional each, two
-    branches (the rungs 6144 and 24576); a forward branch runs the two
+    layer's forward and its gradient op are one conditional each, three
+    branches (the rungs 3072, 6144 and 24576); a forward branch runs the two
     grouped products and a gradient branch their two partners on the rows
     and two on the weights and NO forward product (PR 58: it reads the up
     product's rows the forward kept); the token side's kernel runs once in
-    each of the four branches (PR 47: the forward's weighted map, the
+    each of the six branches (PR 47: the forward's weighted map, the
     gradient's pulled-back one). The mixer's scan is one forward kernel
     (the one the gradient op traces again merged with it) and one gradient
     kernel."""
@@ -654,7 +661,7 @@ def test_hybrid_expert_layer_step_holds_one_switch_each_way(mosaic, one_chip):
     # (the step's one other conditional is the executor's own, outside
     # any op: `jit(fn)/cond`)
     switches = [line for line in text.splitlines()
-                if " conditional(" in line and line.count("%region") == 2
+                if " conditional(" in line and line.count("%region") == 3
                 and 'op_name="jit(fn)/cond"' not in line]
     assert len(switches) == 2, switches
     assert sum("pd.moe_experts/cond" in line for line in switches) == 1
@@ -662,10 +669,10 @@ def test_hybrid_expert_layer_step_holds_one_switch_each_way(mosaic, one_chip):
     kernels = [re.search(r'op_name="[^"]*?(\w+)\)*/pallas_call', line).group(1)
                for line in text.splitlines() if KERNEL in line]
     assert {k: kernels.count(k) for k in set(kernels)} == {
-        "gmm": 2 * (2 + 2), "tgmm": 2 * 2, "pair_sum": 2 * 2,
-        # the small rung's kept product at the head of a buffer nothing
+        "gmm": 3 * (2 + 2), "tgmm": 3 * 2, "pair_sum": 3 * 2,
+        # each smaller rung's kept product at the head of a buffer nothing
         # fills (hybrid_ops._over_all_pairs)
-        "unwritten_rows": 1,
+        "unwritten_rows": 2,
         "ssd_scan_fwd": 1, "ssd_scan_bwd": 1,
         "causal_conv1d_fwd": 1, "causal_conv1d_bwd": 1}
     assert not _float32_under_the_conv(text)
@@ -966,7 +973,7 @@ def test_block_diffusion_step_compiles_with_no_square_of_scores(
     temporaries + 5.03 GB of aliased state): the attention op on the
     flash kernels, two runs of each (the clean half and the noisy half's
     view of the clean keys), the experts on gmm / tgmm behind the ladder's
-    switch (8 of 128 held: two rungs), and under the attention's scope no
+    switch (8 of 128 held: three rungs), and under the attention's scope no
     array larger than the op's own operands: nothing of [., L, L] or
     [., 2L, 2L] reaches HBM."""
     from paddle_tpu import xplane
@@ -981,8 +988,9 @@ def test_block_diffusion_step_compiles_with_no_square_of_scores(
     flash = {k: kernels.count(k) for k in set(kernels) if "flash" in k}
     assert flash == {"flash_fwd": 2, "flash_dkv": 2}
     assert "gmm" in kernels and "tgmm" in kernels
-    # the token side, forward and pulled back, in both rungs (PR 47)
-    assert kernels.count("pair_sum") == 2 * 2
+    # the token side, forward and pulled back, in each of the three rungs
+    # (PR 47; PR 69: 8192 | 16384 | 65536)
+    assert kernels.count("pair_sum") == 2 * 3
     assert "pd.moe_experts/cond" in text
     scoped = [i for i in xplane.hlo_instructions(text)
               if "block_diffusion_attention" in (i.op_name or "")]
@@ -1142,7 +1150,7 @@ def test_delta_rule_step_compiles_with_both_kinds_of_mixer(
     state): latent attention at keys 192 wide beside values 128 wide on
     the flash kernels at one 256-lane block a head with the fused
     backward; the experts on gmm / tgmm under the ladder's one switch
-    each way (8 of 256 held: two rungs); the delta rule on the kernels of
+    each way (8 of 256 held: three rungs); the delta rule on the kernels of
     ops/pallas_kda.py (PR 56): under the op and its gradient the forward
     kernel once (the first forward's, whose inverses are kept), the
     given-inverse forward kernel once (the replayed op's, which reads them
@@ -1168,8 +1176,9 @@ def test_delta_rule_step_compiles_with_both_kinds_of_mixer(
     flash = {k: kernels.count(k) for k in set(kernels) if "flash" in k}
     assert flash == {"flash_fwd": 1, "flash_dkv": 1}
     assert "gmm" in kernels and "tgmm" in kernels
-    # the token side, forward and pulled back, in each of the two rungs
-    assert kernels.count("pair_sum") == 2 * 2
+    # the token side, forward and pulled back, in each of the three rungs
+    # (PR 69: 4096 | 8192 | 65536)
+    assert kernels.count("pair_sum") == 2 * 3
     assert "pd.moe_experts/cond" in text
     delta = {k: kernels.count(k) for k in set(kernels) if "kda" in k}
     assert delta == {"kda_scan_fwd": 1, "kda_scan_fwd_given": 1,
@@ -1293,7 +1302,9 @@ def test_gated_delta_net_step_reads_q_k_and_the_gate_as_they_are(
     and V at their own 2 heads (`form=kernel`) and the backward in two
     calls, dq and dkv (`form=split`, `reason=vmem`: at 16,384 rows of 256
     lanes the fused call's dQ accumulator does not fit); 32 of 512
-    experts held on gmm / tgmm under the ladder's two rungs."""
+    experts held on gmm / tgmm under the ladder's three rungs (20,480 |
+    40,960 | 163,840: PR 69), the token side's kernel in each, forward
+    and pulled back."""
     from paddle_tpu import telemetry
     cell = run.load_json("workloads", GDN_CELL)
     config = dict(run.load_json("configs", cell["config"]),
@@ -1321,6 +1332,8 @@ def test_gated_delta_net_step_reads_q_k_and_the_gate_as_they_are(
         pallas_attention._MAJOR) == "vmem"
     assert flash == {"flash_fwd": 1, "flash_dq": 1, "flash_dkv": 1}, flash
     assert "gmm" in kernels and "tgmm" in kernels
+    # two expert layers: forward and pulled back in each of three rungs
+    assert kernels.count("pair_sum") == 2 * 2 * 3
     assert "pd.moe_experts/cond" in text
     added = {name: {k: v - booked[name].get(k, 0) for k, v in dict(
         telemetry.read_series(name)).items() if v != booked[name].get(k, 0)}
